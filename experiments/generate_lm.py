@@ -187,6 +187,9 @@ def main() -> None:
     args = p.parse_args()
     if not args.checkpoint_dir and not args.no_checkpoint:
         p.error("pass --checkpoint-dir or --no-checkpoint")
+    from learning_at_home_tpu.utils.chip import enable_compile_cache
+
+    enable_compile_cache()
     if args.swarm:
         if args.temperature > 0 or args.no_cache:
             p.error("--swarm decodes greedily through the KV decoder "

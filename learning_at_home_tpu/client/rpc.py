@@ -99,8 +99,7 @@ def ensure_sync_cpu_dispatch() -> None:
 
 # --------------------------------------------------------------------------
 # dispatch-wait watchdog (ISSUE 5 satellite): the jitted-client
-# io_callback deadlock class (ROUND5_NOTES "hazards") presents as a
-# SILENT hang — the host thread blocks in client_loop().run() forever
+# io_callback deadlock class presents as a SILENT hang — the host thread blocks in client_loop().run() forever
 # while the loop waits on buffers the blocked thread will never release.
 # A watchdog timer armed around the dispatch wait turns that into a
 # diagnosable event: one WARNING per process, with every thread's stack.
@@ -146,8 +145,8 @@ def _watchdog_fire(budget: float, what: str) -> None:
     logger.warning(
         "dispatch-wait watchdog: %s has waited > %.2fs (watchdog budget = "
         "LAH_DISPATCH_WATCHDOG_MULT x pool RTT-EMA).  If this never "
-        "completes, suspect the jitted-client io_callback deadlock "
-        "(ROUND5_NOTES hazards).  Thread stacks:\n%s",
+        "completes, suspect the jitted-client io_callback deadlock.  "
+        "Thread stacks:\n%s",
         what, budget, _all_thread_stacks(),
     )
 

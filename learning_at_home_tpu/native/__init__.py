@@ -3,8 +3,10 @@
 ``FramePump`` wraps ``framepump.cpp`` — a GIL-free epoll thread that owns
 all socket work for the framed tensor RPC protocol (wire-compatible with
 ``utils/serialization.py``).  The shared library is built on demand with
-the toolchain baked into the image (g++); the build is cached next to the
-source and rebuilt when the source is newer.
+the toolchain baked into the image (g++) and cached next to the source
+under a name that carries the source's content hash: a library is reused
+only if it was built from exactly this source (file times mean nothing
+after a checkout or a copy).
 
 Falls back cleanly: ``native_available()`` returns False when compilation
 fails (no compiler, non-Linux), and ``Server(transport="native")`` raises
@@ -15,6 +17,7 @@ from __future__ import annotations
 
 import contextlib
 import ctypes
+import hashlib
 import logging
 import os
 import subprocess
@@ -27,45 +30,55 @@ logger = logging.getLogger(__name__)
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "framepump.cpp")
-_SO = os.path.join(_HERE, "_framepump.so")
 
 _lib = None
 _lib_lock = sanitizer.lock("native.lib")
 
 
+def _so_path() -> str:
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_HERE, f"_framepump.{digest}.so")
+
+
 def _build() -> Optional[str]:
     """Compile the pump, safely under concurrent processes: an exclusive
-    flock serializes builders (a multi-server swarm starts N processes at
-    once) and the compiler writes to a temp path that is atomically
-    renamed into place, so no process can ever dlopen a half-written .so."""
-    if os.path.exists(_SO) and os.path.getmtime(_SO) >= os.path.getmtime(_SRC):
-        return _SO
+    flock on the source serializes builders (a multi-server swarm starts N
+    processes at once) and the compiler writes to a temp path that is
+    atomically renamed into place, so no process can ever dlopen a
+    half-written .so."""
+    so = _so_path()
+    if os.path.exists(so):
+        return so
     import fcntl
+    import glob
 
-    tmp = f"{_SO}.{os.getpid()}.tmp"
+    tmp = f"{so[:-3]}.{os.getpid()}.tmp.so"  # *.so: git-ignored if orphaned
     cmd = ["g++", "-O2", "-shared", "-fPIC", "-pthread", _SRC, "-o", tmp]
     try:
-        with open(_SO + ".lock", "w") as lockf:
+        with open(_SRC, "rb") as lockf:
             fcntl.flock(lockf, fcntl.LOCK_EX)
             # another process may have finished the build while we waited
-            if os.path.exists(_SO) and (
-                os.path.getmtime(_SO) >= os.path.getmtime(_SRC)
-            ):
-                return _SO
+            if os.path.exists(so):
+                return so
             r = subprocess.run(cmd, capture_output=True, text=True, timeout=120)
             if r.returncode != 0:
                 logger.warning(
                     "native framepump build failed:\n%s", r.stderr[-2000:]
                 )
                 return None
-            os.replace(tmp, _SO)
+            os.replace(tmp, so)
+            for stale in glob.glob(os.path.join(_HERE, "_framepump.*.so")):
+                if stale != so:  # built from a source that no longer exists
+                    with contextlib.suppress(OSError):
+                        os.unlink(stale)
     except (OSError, subprocess.TimeoutExpired) as e:
         logger.warning("native framepump build failed to run: %s", e)
         return None
     finally:
         with contextlib.suppress(OSError):
             os.unlink(tmp)
-    return _SO
+    return so
 
 
 def _load():

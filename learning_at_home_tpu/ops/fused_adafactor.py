@@ -40,12 +40,6 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 
-# optax renamed safe_int32_increment → safe_increment (and the old name
-# back again in some releases); accept whichever this install ships
-_safe_increment = getattr(
-    optax, "safe_increment", None
-) or optax.safe_int32_increment
-
 
 class FusedAdafactorState(NamedTuple):
     count: jax.Array  # int32 scalar
@@ -184,7 +178,7 @@ def fused_adafactor(
         out = jax.tree.map(_leaf, grads, state.v_row, state.v_col, state.v, params)
         first = jax.tree.map(lambda _, o: o[0], params, out)
         new_state = FusedAdafactorState(
-            count=_safe_increment(step),
+            count=optax.safe_increment(step),
             v_row=jax.tree.map(lambda _, o: o[1], params, out),
             v_col=jax.tree.map(lambda _, o: o[2], params, out),
             v=jax.tree.map(lambda _, o: o[3], params, out),

@@ -65,14 +65,19 @@ def opt_state_shardings(abstract_opt_state, param_shardings, params, mesh: Mesh)
 
     Optimizer states (optax) embed sub-trees shaped like the params (mu/nu
     in Adam); those leaves inherit the matching param's sharding — found by
-    matching each opt-state leaf's key-path SUFFIX against param key-paths
-    AND requiring the leaf's shape to equal the param's shape.  The shape
-    check matters for factored optimizers (adafactor): its ``v_row/v_col/v``
-    sub-trees reuse the param key paths but hold reduced-rank statistics,
-    which must be replicated, not given the param's (higher-rank) spec.
-    Everything else (step counts, scalars) is replicated.  Needed because
-    ``jit(opt.init)`` does not propagate NamedShardings to its outputs, and
-    a checkpoint restored onto mismatched devices poisons the train step.
+    matching each opt-state leaf's key-path SUFFIX against param key-paths.
+    A leaf with the param's shape takes the param's spec.  A factored
+    statistic (adafactor's ``v_row``/``v_col``: the param's shape with ONE
+    axis reduced away) takes the param's spec with that axis dropped — so
+    the statistics of an expert stack stay split over ``expert``, which is
+    how the train step returns them: placed replicated instead, the second
+    step saw new input shardings and compiled the whole step again (31–35
+    s at the flagship on four v5e chips, PR 21).  When the reduced axis
+    cannot be told (equal dims) and the candidates disagree, and for
+    everything else (step counts, scalars, ``[1]`` sentinels), the leaf is
+    replicated.  Needed because ``jit(opt.init)`` does not propagate
+    NamedShardings to its outputs, and a checkpoint restored onto
+    mismatched devices poisons the train step.
 
     LIMIT of the heuristic (round-2 advisor): the suffix+shape match is
     positional-blind — an optimizer whose state leaf coincidentally has
@@ -99,6 +104,18 @@ def opt_state_shardings(abstract_opt_state, param_shardings, params, mesh: Mesh)
     param_map = {k: (shard_map_[k], shape_map[k]) for k in shard_map_}
     repl = NamedSharding(mesh, P())
 
+    def reduced(sharding, shape, leaf_shape):
+        """Spec of ``shape`` with one axis reduced away to ``leaf_shape``."""
+        spec = tuple(sharding.spec) + (None,) * (len(shape) - len(sharding.spec))
+        candidates = {
+            spec[:axis] + spec[axis + 1:]
+            for axis in range(len(shape))
+            if shape[:axis] + shape[axis + 1:] == leaf_shape
+        }
+        if len(candidates) == 1:
+            return NamedSharding(mesh, P(*candidates.pop()))
+        return repl
+
     def assign(path, leaf):
         for i in range(len(path)):
             suffix = jax.tree_util.keystr(path[i:])
@@ -106,7 +123,7 @@ def opt_state_shardings(abstract_opt_state, param_shardings, params, mesh: Mesh)
                 sharding, shape = param_map[suffix]
                 if tuple(leaf.shape) == shape:
                     return sharding
-                return repl
+                return reduced(sharding, shape, tuple(leaf.shape))
         return repl
 
     return jax.tree_util.tree_map_with_path(assign, abstract_opt_state)

@@ -27,7 +27,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from learning_at_home_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 
 
 def _online_softmax_update(o, l, m, scores, v_chunk):

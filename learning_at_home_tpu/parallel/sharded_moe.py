@@ -31,7 +31,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from learning_at_home_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 
 from learning_at_home_tpu.ops.moe_dispatch import (
     choose_dispatch_impl,

@@ -101,6 +101,11 @@ def main() -> None:
         level=logging.INFO, format="%(asctime)s %(name)s: %(message)s"
     )
 
+    from learning_at_home_tpu.utils.chip import enable_compile_cache
+
+    enable_compile_cache()  # before the first jit: warm-up is all compiles
+
+    import jax
     import optax
 
     from learning_at_home_tpu.dht import DHT
@@ -163,6 +168,13 @@ def main() -> None:
         ),
     )
     experts = server.experts
+    # where the parameters actually live, read off a hosted expert's own
+    # arrays before serving can donate them (an empty replica host has
+    # none yet: its default device)
+    leaves = jax.tree_util.tree_leaves(
+        [backend.params for backend in experts.values()]
+    )
+    device = next(iter(leaves[0].devices())) if leaves else jax.devices()[0]
     # replicas installed via the ``replica`` RPC and the drain fallback
     # restore from THIS server's checkpoint root (never peer-supplied)
     server.replica_checkpoint_root = args.checkpoint_dir
@@ -196,7 +208,8 @@ def main() -> None:
     print(
         f"serving {len(experts)} {args.expert_cls!r} experts "
         f"{span}on "
-        f"{server.endpoint[0]}:{server.endpoint[1]} "
+        f"{server.endpoint[0]}:{server.endpoint[1]}, parameters on "
+        f"{device.platform} [{device.device_kind}] "
         f"(metrics http://{server.endpoint[0]}:{server.metrics_port}/metrics)",
         flush=True,
     )

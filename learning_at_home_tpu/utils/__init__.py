@@ -8,7 +8,6 @@ from learning_at_home_tpu.utils.serialization import (
 from learning_at_home_tpu.utils.asyncio_utils import (
     BackgroundLoop,
     run_in_background,
-    switch_to_uvloop,
 )
 from learning_at_home_tpu.utils.timed_storage import TimedStorage, get_dht_time
 
@@ -21,7 +20,6 @@ __all__ = [
     "recv_frame",
     "BackgroundLoop",
     "run_in_background",
-    "switch_to_uvloop",
     "TimedStorage",
     "get_dht_time",
 ]

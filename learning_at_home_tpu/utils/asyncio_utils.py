@@ -70,16 +70,6 @@ else:
             handle.cancel()
 
 
-def switch_to_uvloop() -> asyncio.AbstractEventLoop:
-    """Return a fresh event loop (uvloop if available, stdlib otherwise)."""
-    try:  # pragma: no cover - uvloop not present in this environment
-        import uvloop
-
-        return uvloop.new_event_loop()
-    except ImportError:
-        return asyncio.new_event_loop()
-
-
 def run_in_background(fn: Callable, *args, daemon: bool = True, **kwargs) -> threading.Thread:
     """Run ``fn(*args, **kwargs)`` in a daemon thread; return the thread."""
     thread = threading.Thread(target=fn, args=args, kwargs=kwargs, daemon=daemon)
@@ -123,7 +113,7 @@ class BackgroundLoop:
     """
 
     def __init__(self, name: str = "lah-loop"):
-        self.loop = switch_to_uvloop()
+        self.loop = asyncio.new_event_loop()
         self._started = threading.Event()
         self._shutdown = False
         self.thread = threading.Thread(target=self._run, name=name, daemon=True)

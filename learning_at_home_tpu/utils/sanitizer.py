@@ -672,9 +672,7 @@ def _safe_repr(obj) -> str:
 
 def _install_stall_detector() -> None:
     """Wrap ``asyncio.Handle._run`` so every loop callback is timed.
-    Covers the stdlib loop (all BackgroundLoops here; uvloop, when
-    present, bypasses Handle and is not monitored — documented in
-    docs/CONCURRENCY.md)."""
+    Covers the stdlib loop, which every BackgroundLoop here is."""
     global _monitor_started
     if _monitor_started:
         return

@@ -298,9 +298,9 @@ async def send_frame(writer: asyncio.StreamWriter, payload: bytes) -> None:
 
 async def send_frame_parts(writer: asyncio.StreamWriter, parts: list) -> None:
     """Vectored counterpart of :func:`send_frame`: write a ``pack_frames``
-    result without joining it.  uvloop turns this into ``writev``; the
-    stdlib transport joins once internally — either way the explicit
-    client/server-side ``b"".join`` copy of every payload is gone."""
+    result without joining it.  The stdlib transport joins once
+    internally; the explicit client/server-side ``b"".join`` copy of
+    every payload is gone."""
     if frame_nbytes(parts) - 4 > MAX_FRAME_BYTES:
         raise ValueError(
             f"frame of {frame_nbytes(parts) - 4} bytes exceeds "

@@ -32,12 +32,8 @@ except Exception as e:  # unsupported runtime -> skip, not fail
 
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-
-# version-portable shard_map (jax.experimental.shard_map ↔ jax.shard_map
-# moved between releases — the same drift utils/jax_compat.py shims for
-# the library modules)
-from learning_at_home_tpu.utils.jax_compat import shard_map
 
 from learning_at_home_tpu.parallel import ShardedMixtureOfExperts, make_mesh
 

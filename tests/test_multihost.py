@@ -30,7 +30,7 @@ def _free_port() -> int:
 def test_two_process_distributed_bringup():
     from learning_at_home_tpu.utils.subproc import clean_jax_subprocess_env
 
-    env = clean_jax_subprocess_env(repo_root=REPO)
+    env = clean_jax_subprocess_env(REPO, platform="cpu")
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
     addr = f"127.0.0.1:{_free_port()}"
     nproc = 2

@@ -348,7 +348,7 @@ repo = %(repo)r
 # at the production batch shape so the jitted steps hit a hot bucket
 procs, ports = spawn_expert_servers(
     repo, "jreg", (0.0,), d_model=64, expert_cls="ffn",
-    extra_args=("--warmup", "2048"),
+    extra_args=("--warmup", "2048"), platform="cpu",
 )
 port = ports[0]
 try:
@@ -381,7 +381,7 @@ try:
 finally:
     shutdown_procs(procs)
 """ % {"repo": repo}
-    env = clean_jax_subprocess_env(repo)
+    env = clean_jax_subprocess_env(repo, platform="cpu")
     r = subprocess.run(
         [sys.executable, "-c", script], env=env, capture_output=True,
         text=True, timeout=420, cwd=repo,

@@ -33,27 +33,3 @@ def test_pallas_dispatch_rejects_unaligned_d():
     plan = top_k_gating_indices(jnp.asarray(rs.randn(8, 4).astype(np.float32)), 1, 4)
     with pytest.raises(ValueError, match="128"):
         dispatch_tokens_pallas(x, plan, interpret=True)
-
-
-def test_dispatch_tokens_auto_fallback():
-    from learning_at_home_tpu.ops.pallas_dispatch import dispatch_tokens_auto
-
-    rs = np.random.RandomState(1)
-    # unaligned d: auto must fall back to the XLA gather, not raise
-    x = jnp.asarray(rs.randn(8, 100).astype(np.float32))
-    plan = top_k_gating_indices(
-        jnp.asarray(rs.randn(8, 4).astype(np.float32)), 1, 4
-    )
-    out = dispatch_tokens_auto(x, plan, use_pallas=True)
-    np.testing.assert_allclose(
-        np.asarray(out), np.asarray(dispatch_tokens_indexed(x, plan)), atol=1e-6
-    )
-    # aligned d with pallas requested: uses the kernel (interpret on CPU)
-    x2 = jnp.asarray(rs.randn(8, 128).astype(np.float32))
-    plan2 = top_k_gating_indices(
-        jnp.asarray(rs.randn(8, 4).astype(np.float32)), 1, 4
-    )
-    out2 = dispatch_tokens_auto(x2, plan2, use_pallas=True, interpret=True)
-    np.testing.assert_allclose(
-        np.asarray(out2), np.asarray(dispatch_tokens_indexed(x2, plan2)), atol=1e-6
-    )

@@ -72,7 +72,7 @@ def test_multiprocess_server_roundtrip():
     """Launch the server CLI as a REAL separate process and call it."""
     from learning_at_home_tpu.utils.subproc import clean_jax_subprocess_env
 
-    env = clean_jax_subprocess_env(REPO)
+    env = clean_jax_subprocess_env(REPO, platform="cpu")
     port = 43219
     proc = subprocess.Popen(
         [

@@ -43,7 +43,7 @@ def swarm():
     from learning_at_home_tpu.utils.subproc import clean_jax_subprocess_env
 
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = clean_jax_subprocess_env(repo)
+    env = clean_jax_subprocess_env(repo, platform="cpu")
     port = 43311
     proc = subprocess.Popen(
         [

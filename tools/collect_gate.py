@@ -67,7 +67,7 @@ def orphan_guard() -> int:
     ``learning_at_home_tpu.server`` orphans are alive: they load the
     single core and every timing this gate (and the tier-1 run after
     it) takes would be corrupted — the round-4 churn servers silently
-    poisoned ~6 h of round-5 numbers (ROUND5_NOTES hazards).  Kill the
+    poisoned ~6 h of round-5 numbers.  Kill the
     PIDs and re-run, or set LAH_IGNORE_ORPHANS=1 to proceed anyway."""
     sys.path.insert(0, REPO)
     try:
@@ -769,7 +769,7 @@ def overlap_smoke() -> int:
         # the ONE shared swarm definition (utils.subproc): the gate must
         # validate exactly the swarm bench.py --overlap-worker measures
         servers, source, cfg = spawn_overlap_swarm(
-            REPO, "ov", (0.05, 0.06)
+            REPO, "ov", (0.05, 0.06), platform="cpu"
         )
     except Exception as e:
         print(f"collect_gate: overlap smoke setup failed: {e}",
@@ -1053,7 +1053,8 @@ def gateway_smoke() -> int:
 
     try:
         procs, ports = spawn_expert_servers(
-            REPO, "gws", (0.0, 0.0), d_model=16, num_experts=2
+            REPO, "gws", (0.0, 0.0), d_model=16, num_experts=2,
+            platform="cpu",
         )
     except Exception as e:
         print(f"collect_gate: gateway smoke setup failed: {e}",
