@@ -597,7 +597,7 @@ class RemoteMixtureOfExperts:
         # stack/dispatch/materialize spans there, and the session carries
         # it into backward — one forward+backward, one joinable trace.
         trace = new_trace_id() if timeline.enabled else None
-        with timeline.span(f"moe.dispatch.{self.uid_prefix}", trace=trace):
+        with timeline.span("moe.dispatch", trace, prefix=self.uid_prefix):
             return self._host_forward_impl(
                 x, logits_concat, store_session, trace
             )
@@ -1172,7 +1172,7 @@ class RemoteMixtureOfExperts:
         self.pack_times.append(dt)
         self.pack_bytes += nbytes
         self.pack_bytes_saved += saved
-        timeline.record(f"client.pack.{kind}", t0, dt, trace=trace)
+        timeline.record("client.pack", t0, dt, trace, kind=kind)
         timeline.count("client.pack.bytes", nbytes)
         timeline.count("client.pack_once.bytes_saved", saved)
         return out_jobs, prepared
@@ -1422,7 +1422,7 @@ class RemoteMixtureOfExperts:
                 "or session evicted (raise max_sessions?)"
             )
         session, fwd_dropped, trace = entry
-        with timeline.span(f"moe.backward.{self.uid_prefix}", trace=trace):
+        with timeline.span("moe.backward", trace, prefix=self.uid_prefix):
             return self._host_backward_impl(session, fwd_dropped, trace, gy)
 
     def _host_backward_impl(self, session, fwd_dropped, trace, gy):

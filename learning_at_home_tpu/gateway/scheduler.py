@@ -883,12 +883,11 @@ class SlotScheduler:
                 self.spec_accepted_total += res["accepted"]
                 self.spec_tokens_total += len(res["tokens"])
                 if profiled:
-                    # accepted-k rides the span name: one instant marker
-                    # per stream per verify round (k is bounded by spec_k
-                    # so the name set stays small)
+                    # one instant marker per stream per verify round;
+                    # accepted-k is its attribute
                     timeline.record(
-                        f"gateway.spec.accept.k{res['accepted']}",
-                        now, 0.0, trace=st.trace,
+                        "gateway.spec.accept", now, 0.0, trace=st.trace,
+                        k=res["accepted"],
                     )
                 for tok in res["tokens"]:
                     st.tokens.append(int(tok))

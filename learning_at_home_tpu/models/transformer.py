@@ -275,7 +275,8 @@ class DMoETransformerLM:
                 lp, x, self.cfg.n_heads, impl=self.cfg.attn_impl
             )
         )
-        x = x + attn(lp, layer_norm(lp["ln1"], x))
+        with jax.named_scope("attention"):
+            x = x + attn(lp, layer_norm(lp["ln1"], x))
         b, s, d = x.shape
         moe_in = layer_norm(lp["ln2"], x).reshape(b * s, d)
         # layer index salts the router jitter: decorrelates the
@@ -300,8 +301,9 @@ class DMoETransformerLM:
         real tokens from expert slots.  Attention needs no mask: causality
         already keeps real positions from attending to future padding."""
         cfg = self.cfg
-        x = params["embed"][token_ids].astype(cfg.dtype)
-        x = x + params["pos"][None, : token_ids.shape[1]].astype(cfg.dtype)
+        with jax.named_scope("embed"):
+            x = params["embed"][token_ids].astype(cfg.dtype)
+            x = x + params["pos"][None, : token_ids.shape[1]].astype(cfg.dtype)
         layer_fn = self._layer
         if cfg.remat:
             if cfg.remat_policy == "dots":
@@ -319,7 +321,8 @@ class DMoETransformerLM:
 
         def body(x, lp_idx):
             lp, idx = lp_idx
-            x, aux = layer_fn(lp, x, idx, token_mask)
+            with jax.named_scope("layer"):  # one body for every layer
+                x, aux = layer_fn(lp, x, idx, token_mask)
             return x, aux
 
         if self._zig is not None:
@@ -355,7 +358,8 @@ class DMoETransformerLM:
                     if cfg.stack_layers
                     else params["layers"][i]
                 )
-                x, aux = layer_fn(lp, x, i, token_mask)
+                with jax.named_scope(f"layer_{i}"):
+                    x, aux = layer_fn(lp, x, i, token_mask)
                 aux_total = (
                     aux
                     if aux_total is None
@@ -784,11 +788,12 @@ class DMoETransformerLM:
         """Training loss: mean next-token CE (computed by ``ce_impl``)
         plus the weighted router aux and z losses."""
         x, aux = self._hidden(params, token_ids)
-        head = self._head(params)
-        if self.cfg.ce_impl == "fused":
-            ce = self._fused_ce(x, head, targets)
-        else:
-            ce = self._chunked_ce(x, head, targets)
+        with jax.named_scope("ce"):
+            head = self._head(params)
+            if self.cfg.ce_impl == "fused":
+                ce = self._fused_ce(x, head, targets)
+            else:
+                ce = self._chunked_ce(x, head, targets)
         loss = (
             ce
             + self.cfg.aux_loss_weight * aux["aux_loss"]
@@ -878,7 +883,8 @@ class DMoETransformerLM:
 
         def train_step(params, opt_state, token_ids, targets):
             (loss, metrics), grads = grad_fn(params, token_ids, targets)
-            params, opt_state = apply_fn(params, grads, opt_state)
+            with jax.named_scope("optimizer"):
+                params, opt_state = apply_fn(params, grads, opt_state)
             return params, opt_state, loss, metrics
 
         def accum_step(params, opt_state, token_ids, targets):
@@ -914,7 +920,8 @@ class DMoETransformerLM:
             # (its state dtypes key off the PARAM dtype); the optax
             # fallback's apply_fn casts to param dtype itself
             grads = jax.tree_util.tree_map(lambda g: g * inv, gsum)
-            params, opt_state = apply_fn(params, grads, opt_state)
+            with jax.named_scope("optimizer"):
+                params, opt_state = apply_fn(params, grads, opt_state)
             metrics = jax.tree_util.tree_map(lambda m: m * inv, msum)
             return params, opt_state, lsum * inv, metrics
 

@@ -265,59 +265,72 @@ class ShardedMixtureOfExperts:
             )
 
         # 1) gate + routing plan for MY tokens (logits in f32 for stable softmax)
-        logits = (x.astype(compute) @ params["gate"].astype(compute)).astype(
-            jnp.float32
-        )
-        if self.gating == "expert_choice":
-            plan = expert_choice_gating(logits, capacity, token_mask)
-            x_send = dispatch_tokens_expert_choice(x.astype(compute), plan)
-        elif impl == "gather":
-            plan = top_k_gating_indices(
-                logits, self.k, capacity, jitter=self.router_jitter,
-                jitter_salt=jitter_salt, token_mask=token_mask,
-            )
-            x_send = dispatch_tokens_indexed(x.astype(compute), plan)
-        else:
-            plan = top_k_gating(
-                logits, self.k, capacity, jitter=self.router_jitter,
-                jitter_salt=jitter_salt, token_mask=token_mask,
-            )
-            x_send = dispatch_tokens(x.astype(compute), plan)  # [E, C, d]
-        x_send = x_send.reshape(self.ep, e_local, capacity, d)
-        x_recv = jax.lax.all_to_all(
-            x_send, "expert", split_axis=0, concat_axis=0, tiled=False
-        )  # [ep, e_local, C, d] — slice j = tokens from expert-row peer j
+        with jax.named_scope("router"):
+            logits = (
+                x.astype(compute) @ params["gate"].astype(compute)
+            ).astype(jnp.float32)
+            if self.gating == "expert_choice":
+                plan = expert_choice_gating(logits, capacity, token_mask)
+            elif impl == "gather":
+                plan = top_k_gating_indices(
+                    logits, self.k, capacity, jitter=self.router_jitter,
+                    jitter_salt=jitter_salt, token_mask=token_mask,
+                )
+            else:
+                plan = top_k_gating(
+                    logits, self.k, capacity, jitter=self.router_jitter,
+                    jitter_salt=jitter_salt, token_mask=token_mask,
+                )
+        # 2) my tokens to their experts' devices
+        with jax.named_scope("moe_dispatch"):
+            if self.gating == "expert_choice":
+                x_send = dispatch_tokens_expert_choice(x.astype(compute), plan)
+            elif impl == "gather":
+                x_send = dispatch_tokens_indexed(x.astype(compute), plan)
+            else:
+                x_send = dispatch_tokens(x.astype(compute), plan)  # [E, C, d]
+            x_send = x_send.reshape(self.ep, e_local, capacity, d)
+            x_recv = jax.lax.all_to_all(
+                x_send, "expert", split_axis=0, concat_axis=0, tiled=False
+            )  # [ep, e_local, C, d] — slice j = tokens from expert-row peer j
 
         # 3) batched expert FFN on the MXU (one einsum over the local stack).
         # With tensor parallelism the FFN dim f is sharded over 'model':
         # column-split w1 -> local activations, row-split w2 -> partial
         # sums, one psum completes the contraction (Megatron pattern).
-        xe = x_recv.transpose(1, 0, 2, 3).reshape(e_local, self.ep * capacity, d)
-        w1 = params["w1"].astype(compute)
-        b1 = params["b1"].astype(compute)
-        w2 = params["w2"].astype(compute)
-        b2 = params["b2"].astype(compute)
-        h = jax.nn.gelu(jnp.einsum("egd,edf->egf", xe, w1) + b1[:, None, :])
-        ye = jnp.einsum("egf,efd->egd", h, w2)
-        if self.tp > 1:
-            ye = jax.lax.psum(ye, "model")
-        ye = ye + b2[:, None, :]
+        with jax.named_scope("experts"):
+            xe = x_recv.transpose(1, 0, 2, 3).reshape(
+                e_local, self.ep * capacity, d
+            )
+            w1 = params["w1"].astype(compute)
+            b1 = params["b1"].astype(compute)
+            w2 = params["w2"].astype(compute)
+            b2 = params["b2"].astype(compute)
+            h = jax.nn.gelu(
+                jnp.einsum("egd,edf->egf", xe, w1) + b1[:, None, :]
+            )
+            ye = jnp.einsum("egf,efd->egd", h, w2)
+            if self.tp > 1:
+                ye = jax.lax.psum(ye, "model")
+            ye = ye + b2[:, None, :]
 
-        # 4) return outputs to their source devices
-        y_send = ye.reshape(e_local, self.ep, capacity, d).transpose(1, 0, 2, 3)
-        y_recv = jax.lax.all_to_all(
-            y_send, "expert", split_axis=0, concat_axis=0, tiled=False
-        ).reshape(self.num_experts, capacity, d)
-
+        # 4) return outputs to their source devices, and
         # 5) gate-weighted combine for MY tokens
-        if self.gating == "expert_choice":
-            y = combine_outputs_expert_choice(
-                y_recv, plan, x.shape[0]
-            ).astype(x.dtype)
-        elif impl == "gather":
-            y = combine_outputs_indexed(y_recv, plan).astype(x.dtype)
-        else:
-            y = combine_outputs(y_recv, plan).astype(x.dtype)
+        with jax.named_scope("moe_combine"):
+            y_send = ye.reshape(
+                e_local, self.ep, capacity, d
+            ).transpose(1, 0, 2, 3)
+            y_recv = jax.lax.all_to_all(
+                y_send, "expert", split_axis=0, concat_axis=0, tiled=False
+            ).reshape(self.num_experts, capacity, d)
+            if self.gating == "expert_choice":
+                y = combine_outputs_expert_choice(
+                    y_recv, plan, x.shape[0]
+                ).astype(x.dtype)
+            elif impl == "gather":
+                y = combine_outputs_indexed(y_recv, plan).astype(x.dtype)
+            else:
+                y = combine_outputs(y_recv, plan).astype(x.dtype)
 
         axes = self._shard
         # router z-loss (ST-MoE): penalizes logit magnitude so the softmax

@@ -411,11 +411,12 @@ class ConnectionHandler:
         # reply prepare is an O(#tensors) spec walk over zero-copy
         # memoryviews — the O(bytes) work (encode/downcast) already ran
         # off-loop or in the executor above
-        return pack_frames(
-            "result",
-            WireTensors.prepare(reply_tensors),  # lah-lint: ignore[R1]
-            reply_meta, rid=rid,
-        )
+        with timeline.span("server.encode", trace):
+            return pack_frames(
+                "result",
+                WireTensors.prepare(reply_tensors),  # lah-lint: ignore[R1]
+                reply_meta, rid=rid,
+            )
 
     def _server_stats(self, include_spans: bool = False) -> dict:
         """Server-WIDE counters in one round trip (the ``info`` op is
@@ -511,6 +512,16 @@ class ConnectionHandler:
         the reply payload is never joined into one bytestring on this
         loop.  ``rid`` (protocol v2) is echoed into the reply header.
 
+        ``server.request`` is the request's whole stay in the server,
+        from here to the reply frame built; ``server.decode`` and
+        ``server.encode`` are the codec's two sides inside it."""
+        with timeline.span("server.request") as span:
+            return await self._serve(payload, rid, span)
+
+    async def _serve(self, payload: bytes, rid, span) -> list:
+        """``_dispatch``'s body; ``span`` is its ``server.request`` span,
+        which gets the message type and the trace id once they are read.
+
         A ``{"trace": id}`` meta entry (distributed tracing) is
         peer-supplied: it is structurally validated, stamped onto this
         request's server-side spans and the downstream pool/runtime
@@ -531,21 +542,36 @@ class ConnectionHandler:
             downcast needs no meta, its dtype is in the tensor specs)."""
             tensors, rwire = result
             meta = {"wire": rwire} if isinstance(rwire, dict) else None
-            return reply("result", tensors, meta)
+            with timeline.span("server.encode", trace):
+                return reply("result", tensors, meta)
 
-        try:
-            msg_type, tensors, meta = unpack_message(payload)
-            if not isinstance(meta, dict):
-                raise ValueError(
-                    f"meta must be a map, got {type(meta).__name__}"
-                )
-        except Exception as e:
-            return reply("error", meta={"message": f"malformed request: {e}"})
+        malformed = None
+        with timeline.span("server.decode", nbytes=len(payload)) as decode:
+            try:
+                msg_type, tensors, meta = unpack_message(payload)
+                if not isinstance(meta, dict):
+                    raise ValueError(
+                        f"meta must be a map, got {type(meta).__name__}"
+                    )
+            except Exception as e:
+                msg_type, malformed = None, e
+            data_plane = msg_type in ("forward", "backward", "multi")
+            if not data_plane:
+                # the stage reservoirs are the data plane's: a scrape, a
+                # hand-off part or a malformed frame is none of its requests
+                decode.exclude()
+                span.exclude()
+        if malformed is not None:
+            return reply(
+                "error", meta={"message": f"malformed request: {malformed}"}
+            )
         uid = meta.get("uid")
         wire = meta.get("wire")
         trace = meta.get("trace")
         if not (isinstance(trace, str) and 0 < len(trace) <= 64):
             trace = None  # malformed/absent: never trust peer-supplied meta
+        span.trace = trace
+        span.attrs["type"] = msg_type
         if isinstance(wire, str) and wire not in WIRE_DTYPES:
             return reply(
                 "error",
@@ -564,151 +590,149 @@ class ConnectionHandler:
                 meta={"message": "malformed wire meta: expected a dtype "
                       "string or a codec map"},
             )
-        data_plane = msg_type in ("forward", "backward", "multi")
         if data_plane:
             self._count_wire_bytes(wire, len(payload), "rx")
         try:
-            with timeline.span(f"server.request.{msg_type}", trace=trace):
-                if msg_type == "forward":
-                    out = wire_reply(
-                        await self._run_forward(uid, tensors, wire, trace)
+            if msg_type == "forward":
+                out = wire_reply(
+                    await self._run_forward(uid, tensors, wire, trace)
+                )
+                self._count_wire_bytes(wire, frame_nbytes(out), "tx")
+                return out
+            elif msg_type == "backward":
+                out = wire_reply(
+                    await self._run_backward(
+                        uid, tensors, meta.get("n_inputs"), wire, trace
                     )
-                    self._count_wire_bytes(wire, frame_nbytes(out), "tx")
-                    return out
-                elif msg_type == "backward":
-                    out = wire_reply(
-                        await self._run_backward(
-                            uid, tensors, meta.get("n_inputs"), wire, trace
-                        )
+                )
+                self._count_wire_bytes(wire, frame_nbytes(out), "tx")
+                return out
+            elif msg_type == "multi":
+                out = await self._run_multi(tensors, meta, rid, trace)
+                self._count_wire_bytes(wire, frame_nbytes(out), "tx")
+                return out
+            elif msg_type == "info":
+                backend = self.server.experts.get(uid)
+                if backend is None:
+                    raise ValueError(f"unknown expert uid: {uid!r}")
+                return reply("result", meta=backend.get_info())
+            elif msg_type == "replica":
+                # rebalancer control plane (ISSUE 8): host a replica
+                # of ``uid`` here.  The request carries ONLY the uid
+                # (+ the sync flag) — checkpoint location is this
+                # server's own configuration, never peer-supplied.
+                if not isinstance(uid, str) or not uid:
+                    raise ValueError("replica request needs a uid")
+                installed = await self.server.add_replica_async(
+                    uid, sync=bool(meta.get("sync"))
+                )
+                return reply(
+                    "result",
+                    meta={
+                        "uid": uid,
+                        "installed": bool(installed),
+                        "hosted": uid in self.server.experts,
+                    },
+                )
+            elif msg_type == "handoff":
+                # live expert migration (ISSUE 9): a draining peer
+                # streams one expert's params+opt state here in
+                # sequential parts; the receiver installs and
+                # declares the uid only after a bitwise-verified
+                # install.  Always the RAW wire — a quantized
+                # payload cannot be bitwise by construction.
+                if wire is not None:
+                    raise ValueError(
+                        "handoff must travel the raw wire (no wire "
+                        "meta): migration is bitwise or it failed"
                     )
-                    self._count_wire_bytes(wire, frame_nbytes(out), "tx")
-                    return out
-                elif msg_type == "multi":
-                    out = await self._run_multi(tensors, meta, rid, trace)
-                    self._count_wire_bytes(wire, frame_nbytes(out), "tx")
-                    return out
-                elif msg_type == "info":
-                    backend = self.server.experts.get(uid)
-                    if backend is None:
-                        raise ValueError(f"unknown expert uid: {uid!r}")
-                    return reply("result", meta=backend.get_info())
-                elif msg_type == "replica":
-                    # rebalancer control plane (ISSUE 8): host a replica
-                    # of ``uid`` here.  The request carries ONLY the uid
-                    # (+ the sync flag) — checkpoint location is this
-                    # server's own configuration, never peer-supplied.
-                    if not isinstance(uid, str) or not uid:
-                        raise ValueError("replica request needs a uid")
-                    installed = await self.server.add_replica_async(
-                        uid, sync=bool(meta.get("sync"))
+                return reply(
+                    "result",
+                    meta=await self.server.handoff.handle_part(
+                        meta, tensors
+                    ),
+                )
+            elif msg_type == "migrate":
+                # placement actuation (ISSUE 16): move ONE hosted
+                # expert to an explicit target over the handoff
+                # wire, on the lah-migrate thread — handoff first,
+                # retire only after the bitwise-verified install
+                # (run_drain's per-uid order), so the uid's hoster
+                # count never dips mid-move.  Reply is immediate;
+                # callers watch the stats RPC's placement section.
+                if not isinstance(uid, str) or not uid:
+                    raise ValueError("migrate request needs a uid")
+                target = meta["target"]
+                if not (
+                    isinstance(target, (list, tuple))
+                    and len(target) == 2
+                    and isinstance(target[0], str)
+                    and isinstance(target[1], int)
+                ):
+                    raise ValueError(
+                        "migrate target must be [host, port]"
                     )
-                    return reply(
-                        "result",
-                        meta={
-                            "uid": uid,
-                            "installed": bool(installed),
-                            "hosted": uid in self.server.experts,
-                        },
+                kwargs = {}
+                timeout_s = meta.get("timeout")
+                if timeout_s is not None:
+                    kwargs["timeout"] = min(
+                        600.0, max(1.0, float(timeout_s))
                     )
-                elif msg_type == "handoff":
-                    # live expert migration (ISSUE 9): a draining peer
-                    # streams one expert's params+opt state here in
-                    # sequential parts; the receiver installs and
-                    # declares the uid only after a bitwise-verified
-                    # install.  Always the RAW wire — a quantized
-                    # payload cannot be bitwise by construction.
-                    if wire is not None:
-                        raise ValueError(
-                            "handoff must travel the raw wire (no wire "
-                            "meta): migration is bitwise or it failed"
-                        )
-                    return reply(
-                        "result",
-                        meta=await self.server.handoff.handle_part(
-                            meta, tensors
-                        ),
-                    )
-                elif msg_type == "migrate":
-                    # placement actuation (ISSUE 16): move ONE hosted
-                    # expert to an explicit target over the handoff
-                    # wire, on the lah-migrate thread — handoff first,
-                    # retire only after the bitwise-verified install
-                    # (run_drain's per-uid order), so the uid's hoster
-                    # count never dips mid-move.  Reply is immediate;
-                    # callers watch the stats RPC's placement section.
-                    if not isinstance(uid, str) or not uid:
-                        raise ValueError("migrate request needs a uid")
-                    target = meta["target"]
+                started = self.server.start_migration(
+                    uid, (target[0], target[1]), **kwargs
+                )
+                return reply(
+                    "result",
+                    meta={
+                        "uid": uid,
+                        "started": bool(started),
+                        "state": self.server.lifecycle_state,
+                    },
+                )
+            elif msg_type == "drain":
+                # graceful-drain trigger (ISSUE 9): flip the server
+                # into the drain sequence on its lah-drain thread
+                # and reply immediately — callers watch the stats
+                # RPC's lifecycle section (or process exit)
+                kwargs = {}
+                successor = meta.get("successor")
+                if successor is not None:
                     if not (
-                        isinstance(target, (list, tuple))
-                        and len(target) == 2
-                        and isinstance(target[0], str)
-                        and isinstance(target[1], int)
+                        isinstance(successor, (list, tuple))
+                        and len(successor) == 2
+                        and isinstance(successor[0], str)
+                        and isinstance(successor[1], int)
                     ):
                         raise ValueError(
-                            "migrate target must be [host, port]"
+                            "drain successor must be [host, port]"
                         )
-                    kwargs = {}
-                    timeout_s = meta.get("timeout")
-                    if timeout_s is not None:
-                        kwargs["timeout"] = min(
-                            600.0, max(1.0, float(timeout_s))
-                        )
-                    started = self.server.start_migration(
-                        uid, (target[0], target[1]), **kwargs
-                    )
-                    return reply(
-                        "result",
-                        meta={
-                            "uid": uid,
-                            "started": bool(started),
-                            "state": self.server.lifecycle_state,
-                        },
-                    )
-                elif msg_type == "drain":
-                    # graceful-drain trigger (ISSUE 9): flip the server
-                    # into the drain sequence on its lah-drain thread
-                    # and reply immediately — callers watch the stats
-                    # RPC's lifecycle section (or process exit)
-                    kwargs = {}
-                    successor = meta.get("successor")
-                    if successor is not None:
-                        if not (
-                            isinstance(successor, (list, tuple))
-                            and len(successor) == 2
-                            and isinstance(successor[0], str)
-                            and isinstance(successor[1], int)
-                        ):
-                            raise ValueError(
-                                "drain successor must be [host, port]"
-                            )
-                        kwargs["successor"] = (successor[0], successor[1])
-                    grace = meta.get("grace")
-                    if grace is not None:
-                        kwargs["grace"] = float(grace)
-                    if meta.get("handoff") is not None:
-                        kwargs["handoff"] = bool(meta.get("handoff"))
-                    started = self.server.start_drain(**kwargs)
-                    return reply(
-                        "result",
-                        meta={
-                            "draining": True,
-                            "started": bool(started),
-                            "state": self.server.lifecycle_state,
-                        },
-                    )
-                elif msg_type == "stats":
-                    return reply(
-                        "result",
-                        meta=self._server_stats(
-                            include_spans=bool(meta.get("spans"))
-                        ),
-                    )
-                else:
-                    return reply(
-                        "error",
-                        meta={"message": f"unknown message type {msg_type!r}"},
-                    )
+                    kwargs["successor"] = (successor[0], successor[1])
+                grace = meta.get("grace")
+                if grace is not None:
+                    kwargs["grace"] = float(grace)
+                if meta.get("handoff") is not None:
+                    kwargs["handoff"] = bool(meta.get("handoff"))
+                started = self.server.start_drain(**kwargs)
+                return reply(
+                    "result",
+                    meta={
+                        "draining": True,
+                        "started": bool(started),
+                        "state": self.server.lifecycle_state,
+                    },
+                )
+            elif msg_type == "stats":
+                return reply(
+                    "result",
+                    meta=self._server_stats(
+                        include_spans=bool(meta.get("spans"))
+                    ),
+                )
+            else:
+                return reply(
+                    "error",
+                    meta={"message": f"unknown message type {msg_type!r}"},
+                )
         except Exception as e:
             logger.exception("request %s failed (expert %s)", msg_type, uid)
             return reply("error", meta={"message": f"{type(e).__name__}: {e}"})

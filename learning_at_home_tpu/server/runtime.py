@@ -46,14 +46,6 @@ logger = logging.getLogger(__name__)
 _SENTINEL = (float("-inf"), -1, None)
 
 
-def _job_trace(job: BatchJob) -> Optional[str]:
-    """The batch's trace id for span stamping: the single distinct
-    non-None task trace, or None when the batch merged several traced
-    requests (no single owner) or carried none."""
-    distinct = {t for t in getattr(job, "traces", ()) if t}
-    return distinct.pop() if len(distinct) == 1 else None
-
-
 @dataclass
 class _Inflight:
     """A dispatched-but-not-materialized job (the second pipeline stage)."""
@@ -61,9 +53,8 @@ class _Inflight:
     job: BatchJob
     raw_outputs: list
     staging: list = field(default_factory=list)
-    started: float = 0.0
     dispatch_s: float = 0.0  # duration of the process_fn call itself
-    trace: Optional[str] = None  # distributed-tracing id (see _job_trace)
+    trace: Optional[str] = None  # the batch's BatchJob.owner_trace()
 
 
 class Runtime:
@@ -109,7 +100,12 @@ class Runtime:
         pending: Optional[_Inflight] = None
         while True:
             if pending is None:
-                item = self._queue.get()
+                # nothing in flight and nothing to do until a pool forms
+                # a batch: the pace is set upstream of this thread
+                with timeline.span("runtime.idle") as idle:
+                    item = self._queue.get()
+                    if item[2] is None:
+                        idle.exclude()  # waited for shutdown, not for work
             else:
                 try:
                     # don't wait: if no new job is ready, spend the idle
@@ -152,30 +148,34 @@ class Runtime:
         """Stage one: stack the batch into staging buffers and dispatch the
         jitted call.  Returns the in-flight record, or None if the job
         failed (error already delivered)."""
-        started = time.monotonic()
-        self.queue_time += started - job.formed_at
+        pool = job.pool
+        queued = time.monotonic() - job.formed_at
+        self.queue_time += queued
         buffers: list = []
-        trace = _job_trace(job)
+        # trace ids exist only on profiled requests: see BatchJob
+        trace = job.owner_trace() if timeline.enabled else None
+        timeline.record(
+            "runtime.queue", job.formed_at, queued, trace, pool=pool.name
+        )
         try:
-            with timeline.span(f"runtime.stack.{job.pool.name}", trace=trace):
-                inputs, buffers = job.stack(self.staging)
-            stacked = time.monotonic()
-            self.stack_time += stacked - started
-            job.pool.stack_time += stacked - started
             with timeline.span(
-                f"runtime.dispatch.{job.pool.name}", trace=trace
-            ):
-                raw = list(job.pool.process_fn(inputs))
-            dispatched = time.monotonic()
+                "runtime.stack", trace, pool=pool.name, rows=job.n_rows,
+                bucket=job.target_rows,
+            ) as stack:
+                inputs, buffers = job.stack(self.staging)
+            self.stack_time += stack.duration
+            pool.stack_time += stack.duration
+            with timeline.span(
+                "runtime.dispatch", trace, pool=pool.name
+            ) as launch:
+                raw = list(pool.process_fn(inputs))
         except BaseException as e:  # deliver, don't kill the device loop
-            logger.exception("runtime job failed in pool %s", job.pool.name)
+            logger.exception("runtime job failed in pool %s", pool.name)
             self.staging.release(buffers)
             self.jobs_processed += 1
             self._deliver(job, None, e)
             return None
-        return _Inflight(
-            job, raw, buffers, started, dispatched - stacked, trace
-        )
+        return _Inflight(job, raw, buffers, launch.duration, trace)
 
     def _finish(self, inflight: _Inflight) -> None:
         """Stage two: materialize the outputs (blocks until the device
@@ -183,11 +183,10 @@ class Runtime:
         recycle the staging buffers, deliver to the pool's futures."""
         job = inflight.job
         outputs, error = None, None
-        t0 = time.monotonic()
         try:
             with timeline.span(
-                f"runtime.materialize.{job.pool.name}", trace=inflight.trace
-            ):
+                "runtime.materialize", inflight.trace, pool=job.pool.name
+            ) as materialize:
                 outputs = []
                 for o in inflight.raw_outputs:
                     arr = np.asarray(o)
@@ -205,20 +204,14 @@ class Runtime:
                 "runtime job failed to materialize in pool %s", job.pool.name
             )
             error = e
-        now = time.monotonic()
-        self.materialize_time += now - t0
+        self.materialize_time += materialize.duration
         # device_time keeps its pre-pipeline meaning — process_fn call +
         # output materialization, the job's own busy time.  Under overlap,
         # wall time from dispatch to materialized also contains the NEXT
         # job's stack/dispatch; folding that in would double-count and
         # make the pipelined runtime read as a device-time regression.
-        busy = inflight.dispatch_s + (now - t0)
-        self.device_time += busy
+        self.device_time += inflight.dispatch_s + materialize.duration
         self.jobs_processed += 1
-        timeline.record(
-            f"runtime.{job.pool.name}", inflight.started, busy,
-            trace=inflight.trace,
-        )
         self.staging.release(inflight.staging)
         self._deliver(job, outputs, error)
 
@@ -236,9 +229,15 @@ class Runtime:
             "queue_depth": self.queue_depth,
             "queue_depth_max": self.queue_depth_max,
             "staging": self.staging.stats(),
+            # the request's life by stage, every stage over the same recent
+            # seconds (count, p50_ms, p95_ms, share, extent_s: stage_stats
+            # in utils/profiling.py), where the *_time_ms sums above run
+            # from process start
+            "stages": timeline.stage_stats(("server.", "pool.", "runtime.")),
         }
 
     def _deliver(self, job: BatchJob, outputs, error) -> None:
+        job.finished_at = time.monotonic()  # runtime.deliver starts here
         try:
             self._loop.call_soon_threadsafe(job.pool.deliver, job, outputs, error)
         except RuntimeError:

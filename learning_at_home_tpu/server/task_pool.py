@@ -36,6 +36,7 @@ from typing import Any, Callable, Optional, Sequence
 import numpy as np
 
 from learning_at_home_tpu.utils import sanitizer
+from learning_at_home_tpu.utils.profiling import timeline
 from learning_at_home_tpu.utils.serialization import LazyDecode
 
 logger = logging.getLogger(__name__)
@@ -83,6 +84,16 @@ class BatchJob:
     # one distinct trace — a merged multi-trainer batch has no single
     # owner and stays unstamped
     traces: list = field(compare=False, default_factory=list)
+    # set by the Runtime when it hands the results back to the loop: the
+    # start of the ``runtime.deliver`` span that ``TaskPool.deliver`` ends
+    finished_at: float = field(compare=False, default=0.0)
+
+    def owner_trace(self) -> Optional[str]:
+        """The batch's trace id for span stamping: the single distinct
+        non-None task trace, or None when the batch merged several traced
+        requests (no single owner) or carried none."""
+        distinct = {t for t in self.traces if t}
+        return distinct.pop() if len(distinct) == 1 else None
 
     @sanitizer.runs_on("runtime", site="BatchJob.stack")
     def stack(self, staging) -> tuple[list, list]:
@@ -298,10 +309,22 @@ class TaskPool:
             formed_at=time.monotonic(),
             traces=[t.trace for t in batch],
         )
+        for t in batch:  # the batching delay, one span a task
+            timeline.record(
+                "pool.wait", t.arrived, job.formed_at - t.arrived, t.trace,
+                pool=self.name,
+            )
         runtime.submit(job)
 
     # called back on the event loop by the Runtime after device execution
     def deliver(self, job: BatchJob, outputs, error: Optional[BaseException]) -> None:
+        if job.finished_at:  # how long this loop took to notice
+            timeline.record(
+                "runtime.deliver", job.finished_at,
+                time.monotonic() - job.finished_at,
+                job.owner_trace() if timeline.enabled else None,
+                pool=self.name,
+            )
         for future, start, stop in job.row_spans:
             if future.cancelled():
                 continue

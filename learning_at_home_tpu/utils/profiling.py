@@ -4,18 +4,31 @@ The reference has nothing beyond logging and its benchmark scripts
 (SURVEY.md §5.1); the TPU build prescribes jax.profiler traces plus
 per-RPC timing spans.  This module provides both:
 
-- a process-wide :class:`Timeline` of timing spans (bounded ring buffer,
-  thread-safe, ~100ns overhead when disabled) used by the RPC client, the
-  task pools, and the MoE dispatcher;
+- a process-wide :class:`Timeline` of timing spans used by the RPC client,
+  the expert server and the MoE dispatcher.  One primitive
+  (:meth:`Timeline.span`, a small class with ``__enter__`` / ``__exit__``;
+  :meth:`Timeline.record` where both clock readings are already in hand)
+  with three sinks: a bounded **reservoir of recent spans per name**
+  (always on: :meth:`Timeline.recent`, :meth:`Timeline.stage_stats`), a
+  ``jax.profiler.TraceAnnotation`` of the same name (records only while a
+  profiler session is live, and then sits in the ``.xplane.pb`` on the
+  device trace's clock), and the full record with trace id, thread and
+  attributes (only under ``LAH_PROFILE=1``);
 - named **event counters** on the same Timeline (:meth:`Timeline.count`)
   for hot-path pipeline telemetry — overlapped dispatches, staging-buffer
   reuse, per-bucket cache hits — where a duration span is the wrong shape;
 - :func:`device_trace`, a thin wrapper over ``jax.profiler.trace`` that
   captures an XLA/TensorBoard trace directory for the jitted compute.
 
-Enable collection with ``LAH_PROFILE=1`` in the environment or
-``timeline.enable()``; read results with ``timeline.summary()`` /
-``timeline.counters()``.
+Enable the full records and the counters with ``LAH_PROFILE=1`` in the
+environment or ``timeline.enable()``; read them with
+``timeline.summary()`` / ``timeline.counters()``.  The reservoirs need
+neither.
+
+A span's NAME is its stage (``runtime.stack``), fixed by the code that
+takes it; what is data (``pool``, ``rows``, ``bucket``, the message
+``type``) goes into its attributes, so one stage has one reservoir and
+one p50 however many pools a server hosts.
 
 **Distributed tracing** (ISSUE 4): spans may carry a compact *trace id*
 (:func:`new_trace_id`, 16 hex chars) allocated once per logical operation
@@ -29,14 +42,15 @@ to the wall clock at export, so traces merged from multiple processes on
 one machine align.  Trace ids are only allocated while the timeline is
 enabled — disabled-path requests carry no extra meta and record nothing.
 
-The server Runtime emits one span per pipeline stage per batch —
-``runtime.stack.<pool>`` (staging-buffer copy), ``runtime.dispatch.<pool>``
-(jitted call dispatch), ``runtime.materialize.<pool>`` (device wait) — plus
-an umbrella ``runtime.<pool>`` span covering dispatch→materialized, so a
-summary shows exactly where hot-path time goes.
+The expert server times a request's life stage by stage —
+``server.decode`` / ``server.request`` / ``server.encode`` on the loop,
+``pool.wait`` in the task pool, ``runtime.queue`` / ``runtime.stack`` /
+``runtime.dispatch`` / ``runtime.materialize`` / ``runtime.idle`` on the
+Runtime thread and ``runtime.deliver`` back to the loop; the table of
+every span, its thread and its boundaries is in docs/OBSERVABILITY.md.
 
 The CLIENT dispatch pipeline (PR 2) mirrors this: per-dispatch
-``client.pack.forward`` / ``client.pack.backward`` spans (host-thread
+``client.pack`` spans (``kind`` forward / backward; host-thread
 serialization — off the event loop by construction), counters
 ``client.pack.bytes`` and ``client.pack_once.bytes_saved`` (duplicated
 wire-encode bytes the pack-once fan-out avoided), and per-RPC
@@ -66,7 +80,7 @@ degraded fraction) also surface without profiling via
 Headline counters do NOT live here: the always-on cheap metrics a
 production peer exports by default belong to the registry in
 ``utils/metrics.py`` (which also re-exports this timeline's counters as
-a collector).  The Timeline is the opt-in, span-granular layer.
+a collector).
 """
 
 from __future__ import annotations
@@ -75,9 +89,11 @@ import contextlib
 import json
 import os
 import re
+import sys
 import threading
 import time
 from collections import defaultdict, deque
+from itertools import chain
 from typing import Iterator, Optional
 
 import numpy as np
@@ -100,18 +116,97 @@ def valid_trace_id(value: object) -> bool:
     return isinstance(value, str) and bool(_TRACE_ID_RE.match(value))
 
 
+# Spans kept per name in the always-on reservoirs: at 730 batches a second
+# (PERF.md, ffnserver-infer-small) the last five or six seconds of a stage.
+RESERVOIR_LEN = 4096
+# The longest stretch of recent time ``stage_stats`` describes: a stage with
+# a few spans a second (a ``multi`` request, the runtime's idle waits) must
+# not reach back into a server's start-up for its sample.
+STAGE_WINDOW_S = 30.0
+
+_annotation_cls = None  # jax.profiler.TraceAnnotation, once jax is loaded
+
+
+def _resolve_annotation_cls():
+    """``jax.profiler.TraceAnnotation`` if this process has imported jax,
+    else None: a process that never imports jax does not import it here."""
+    global _annotation_cls
+    if "jax" in sys.modules:
+        try:
+            from jax.profiler import TraceAnnotation
+        except ImportError:  # jax is half imported on another thread
+            return None
+        _annotation_cls = TraceAnnotation
+    return _annotation_cls
+
+
+class Span:
+    """One timed stage: ``with timeline.span(name, pool=..., rows=...)``.
+
+    On exit it goes to the Timeline's three sinks (module docstring).
+    ``trace`` and ``attrs`` may be set until then: a request's trace id
+    is known only once its meta is decoded.  ``duration`` (seconds) is
+    there after the exit, for a caller that also keeps a running sum."""
+
+    __slots__ = ("_timeline", "_reservoir", "name", "trace", "attrs", "_t0",
+                 "_annotation", "duration")
+
+    def __init__(self, timeline: "Timeline", reservoir: deque, name: str,
+                 trace: Optional[str], attrs: dict):
+        self._timeline = timeline
+        self._reservoir = reservoir
+        self.name = name
+        self.trace = trace
+        self.attrs = attrs
+
+    def __enter__(self) -> "Span":
+        # a profiler annotation only while a profiler session is live:
+        # outside one a span pays the ``is_enabled()`` call and no more
+        cls = _annotation_cls or _resolve_annotation_cls()
+        if cls is not None and cls.is_enabled():
+            self._annotation = cls(self.name, **self.attrs)
+            self._annotation.__enter__()
+        else:
+            self._annotation = None
+        self._t0 = time.monotonic()
+        return self
+
+    def exclude(self) -> None:
+        """Keep this span out of its stage's reservoir: what it timed is
+        not that stage's work (a wait that ended in shutdown, a
+        control-plane request among the data plane's).  With profiling on
+        its full record is still kept, attributes and all."""
+        self._reservoir = None
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        t0 = self._t0
+        self.duration = duration = time.monotonic() - t0
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
+        if self._reservoir is not None:
+            self._reservoir.append((t0, duration))  # atomic under the GIL
+        if self._timeline.enabled:
+            self._timeline._record_full(
+                self.name, t0, duration, self.trace, self.attrs
+            )
+
+
 class Timeline:
-    """Bounded, thread-safe collection of (name, start, duration) spans.
+    """Thread-safe collection of timing spans and event counters.
 
-    Spans optionally carry a trace id (distributed tracing) and always
-    record the emitting thread id — both consumed by the Chrome
-    ``trace_event`` exporter; the summary/counter surfaces ignore them.
+    Every span lands in a bounded **reservoir** of the most recent
+    ``(start, duration)`` under its name, profiling on or off
+    (:meth:`recent`, :meth:`stage_stats`).  While :attr:`enabled`, it is
+    also recorded in full — ``(name, start, duration, trace id, thread
+    id, attributes)`` — for :meth:`spans`, :meth:`summary` and the Chrome
+    ``trace_event`` exporter.
 
-    Distinct COUNTER keys are capped (``max_counter_keys``): per-bucket /
-    per-pool counter names are data-dependent, and a long-lived server
-    with many shape buckets must not grow the dict without bound.  Counts
-    for keys beyond the cap fold into one ``timeline.overflow`` bucket
-    and each folded call increments ``timeline.dropped_keys``.
+    Distinct COUNTER keys and span NAMES are capped (``max_counter_keys``
+    each): a name that embeds data must not grow a long-lived server's
+    tables without bound.  Counts for keys beyond the cap fold into one
+    ``timeline.overflow`` bucket and each folded call increments
+    ``timeline.dropped_keys``; spans under names beyond the cap fold into
+    the ``timeline.overflow`` reservoir.
     """
 
     # counter names that must survive even at the cap (they ARE the
@@ -119,14 +214,14 @@ class Timeline:
     _RESERVED_KEYS = ("timeline.overflow", "timeline.dropped_keys")
 
     def __init__(self, maxlen: int = 100_000, max_counter_keys: int = 512):
-        # (name, start_monotonic, duration_s, trace_id|None, thread_id)
-        self._spans: deque[tuple[str, float, float, Optional[str], int]] = (
-            deque(maxlen=maxlen)
-        )
+        # (name, start_monotonic, duration_s, trace_id|None, thread_id,
+        #  attributes)
+        self._spans: deque[
+            tuple[str, float, float, Optional[str], int, dict]
+        ] = deque(maxlen=maxlen)
+        self._recent: dict[str, deque[tuple[float, float]]] = {}
         self._counters: defaultdict[str, float] = defaultdict(float)
-        self.max_counter_keys = int(
-            os.environ.get("LAH_TIMELINE_MAX_KEYS", max_counter_keys)
-        )
+        self.max_counter_keys = max_counter_keys
         self._lock = sanitizer.lock("profiling.timeline")
         self.enabled = os.environ.get("LAH_PROFILE", "") not in ("", "0")
         # rebase for cross-process merges: monotonic + offset ≈ wall clock
@@ -141,16 +236,112 @@ class Timeline:
     def clear(self) -> None:
         with self._lock:
             self._spans.clear()
+            self._recent = {}  # a span in flight keeps its old reservoir
             self._counters.clear()
+
+    def span(self, name: str, trace: Optional[str] = None, **attrs) -> Span:
+        """A context manager timing the enclosed code as one span."""
+        reservoir = self._recent.get(name)
+        if reservoir is None:
+            reservoir = self._new_reservoir(name)
+        return Span(self, reservoir, name, trace, attrs)
 
     def record(
         self, name: str, start: float, duration: float,
-        trace: Optional[str] = None,
+        trace: Optional[str] = None, **attrs,
     ) -> None:
+        """A span whose two ``time.monotonic`` readings the caller has
+        already (a wait that is known only once it is over)."""
+        reservoir = self._recent.get(name)
+        if reservoir is None:
+            reservoir = self._new_reservoir(name)
+        reservoir.append((start, duration))
         if self.enabled:
-            entry = (name, start, duration, trace, threading.get_ident())
-            with self._lock:
-                self._spans.append(entry)
+            self._record_full(name, start, duration, trace, attrs)
+
+    def _record_full(self, name, start, duration, trace, attrs) -> None:
+        entry = (name, start, duration, trace, threading.get_ident(), attrs)
+        with self._lock:
+            self._spans.append(entry)
+
+    def _new_reservoir(self, name: str) -> deque:
+        with self._lock:
+            if (
+                name not in self._recent
+                and len(self._recent) >= self.max_counter_keys
+            ):
+                name = "timeline.overflow"
+            return self._recent.setdefault(name, deque(maxlen=RESERVOIR_LEN))
+
+    def recent(self, name: str) -> list[tuple[float, float]]:
+        """The most recent ``(start, duration)`` spans under ``name``
+        (``time.monotonic`` seconds), oldest first; at most
+        ``RESERVOIR_LEN``, from process start or the last ``clear()``."""
+        reservoir = self._recent.get(name)
+        # list(deque) copies without releasing the GIL: no append can
+        # fall inside it
+        return list(reservoir) if reservoir is not None else []
+
+    def stage_stats(
+        self, prefix: str | tuple = "", window_s: float = STAGE_WINDOW_S,
+        skip_tail_s: float = 0.0,
+    ) -> dict[str, dict]:
+        """The span names under ``prefix`` (one, or a tuple of several),
+        all read over ONE common extent of time, so that a stage with two
+        spans a second and one with seven hundred describe the same
+        seconds: per name ``count``, ``p50_ms``, ``p95_ms`` of the spans
+        that ended inside the extent, ``share``, the part of the extent
+        the stage was running (a span that reaches over either end counts
+        with its part inside), and ``extent_s`` itself.
+
+        The extent ends ``skip_tail_s`` before the last span any of the
+        names ended (a reader that knows the run closed with traffic of
+        another kind leaves that out) and is at most ``window_s`` long; it
+        starts no earlier than their first span, nor than the first entry
+        of any FULL reservoir among them, which has forgotten what ended
+        before that.  A name with no span in the extent has ``count`` 0,
+        ``share`` 0 and no percentiles."""
+        spans_of = {}
+        for name in list(self._recent):
+            spans = self.recent(name) if name.startswith(prefix) else []
+            if spans:
+                # fromiter over the flattened pairs: half the cost of
+                # np.asarray(list of tuples), and this runs on a serving loop
+                starts, durations = np.fromiter(
+                    chain.from_iterable(spans), float, 2 * len(spans)
+                ).reshape(-1, 2).T
+                spans_of[name] = (starts, durations, starts + durations)
+        if not spans_of:
+            return {}
+        end = max(float(e.max()) for _, _, e in spans_of.values()) - skip_tail_s
+        first = min(float(s.min()) for s, _, _ in spans_of.values())
+        begin = max(end - window_s, first)
+        for _, _, ends in spans_of.values():
+            if len(ends) == RESERVOIR_LEN:  # appended in the order of ends
+                begin = max(begin, float(ends[0]))
+        extent = end - begin
+        if extent <= 0:
+            return {}
+        out = {}
+        for name, (starts, durations, ends) in spans_of.items():
+            inside = (ends >= begin) & (ends <= end)
+            count = int(inside.sum())
+            # a stage that did not run in the extent has a share, 0, and
+            # no median
+            p50, p95 = (
+                (round(float(q) * 1e3, 4)
+                 for q in np.percentile(durations[inside], (50, 95)))
+                if count else (None, None)
+            )
+            running = np.clip(ends, begin, end) - np.clip(starts, begin, end)
+            out[name] = {
+                "count": count,
+                "p50_ms": p50,
+                "p95_ms": p95,
+                "share": round(float(running.sum()) / extent, 6),
+                "extent_s": round(extent, 4),
+            }
+        return out
 
     def count(self, name: str, value: float = 1.0) -> None:
         """Accumulate a named event counter (no duration semantics).
@@ -178,28 +369,18 @@ class Timeline:
                 if name.startswith(prefix)
             }
 
-    @contextlib.contextmanager
-    def span(self, name: str, trace: Optional[str] = None) -> Iterator[None]:
-        if not self.enabled:
-            yield
-            return
-        t0 = time.monotonic()
-        try:
-            yield
-        finally:
-            self.record(name, t0, time.monotonic() - t0, trace=trace)
-
     def spans(
         self, prefix: str = ""
-    ) -> list[tuple[str, float, float, Optional[str], int]]:
+    ) -> list[tuple[str, float, float, Optional[str], int, dict]]:
         with self._lock:
             return [s for s in self._spans if s[0].startswith(prefix)]
 
     def summary(self) -> dict[str, dict]:
-        """Per-span-name count / total / p50 / p99 (milliseconds)."""
+        """Per-span-name count / total / p50 / p99 (milliseconds) of the
+        full records (profiling on)."""
         groups: dict[str, list[float]] = defaultdict(list)
         with self._lock:
-            for name, _, duration, _, _ in self._spans:
+            for name, _, duration, *_ in self._spans:
                 groups[name].append(duration * 1000)
         out = {}
         for name, durs in groups.items():
@@ -219,7 +400,8 @@ class Timeline:
         events.  ``ts`` is wall-clock microseconds (monotonic start +
         the offset captured at construction), so event lists exported by
         several processes on one machine merge into one aligned trace;
-        spans that carried a trace id get ``args: {"trace": id}``.
+        a span's attributes and its trace id, where it carried one, are
+        its ``args`` (``{"pool": ..., "trace": id}``).
         ``pid`` is the real OS pid and ``tid`` the recording thread —
         chrome://tracing nests same-tid events by time containment."""
         pid = os.getpid()
@@ -229,7 +411,7 @@ class Timeline:
                 "args": {"name": process_name or f"lah-{pid}"},
             }
         ]
-        for name, start, duration, trace, tid in self.spans():
+        for name, start, duration, trace, tid, attrs in self.spans():
             ev = {
                 "ph": "X",
                 "name": name,
@@ -239,8 +421,11 @@ class Timeline:
                 "ts": (start + self._clock_offset) * 1e6,
                 "dur": duration * 1e6,
             }
+            args = dict(attrs)
             if trace is not None:
-                ev["args"] = {"trace": trace}
+                args["trace"] = trace
+            if args:
+                ev["args"] = args
             events.append(ev)
         return events
 

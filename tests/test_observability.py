@@ -63,21 +63,24 @@ def _fwd_bwd(moe):
     jax.grad(loss)(gate, jax.numpy.asarray(x))
 
 
-def _traces_by_name(spans):
-    """{span_name: set(trace ids)} over spans that carry one."""
+def _traces_by_name(spans, **attrs):
+    """{span_name: set(trace ids)} over spans that carry one (and, where
+    given, these attributes: a span's name is its stage, the pool and the
+    message type are attributes)."""
     out = {}
-    for name, _, _, trace, _ in spans:
-        if trace is not None:
+    for name, _, _, trace, _, span_attrs in spans:
+        if trace is not None and attrs.items() <= span_attrs.items():
             out.setdefault(name, set()).add(trace)
     return out
 
 
-def _interval(spans, name, trace):
-    """(start, end) of the one span with this name+trace."""
+def _interval(spans, name, trace, **attrs):
+    """(start, end) of the one span with this name+trace+attributes."""
     match = [
-        (s, s + d) for n, s, d, t, _ in spans if n == name and t == trace
+        (s, s + d) for n, s, d, t, _, a in spans
+        if n == name and t == trace and attrs.items() <= a.items()
     ]
-    assert match, f"no span {name!r} with trace {trace}"
+    assert match, f"no span {name!r} {attrs} with trace {trace}"
     return match[0]
 
 
@@ -98,30 +101,40 @@ def test_trace_joins_client_and_server_spans_v2_merged(profiled):
     spans = timeline.spans()
     by_name = _traces_by_name(spans)
     # the dispatch umbrella carries exactly one trace id per dispatch
-    assert "moe.dispatch.ffn" in by_name
-    (trace,) = by_name["moe.dispatch.ffn"]
-    for name in (
-        "client.pack.forward",
-        "rpc.multi",
-        "server.request.multi",
-        "runtime.stack.ffn.0.forward",
-        "runtime.dispatch.ffn.0.forward",
-        "runtime.materialize.ffn.0.forward",
+    assert "moe.dispatch" in _traces_by_name(spans, prefix="ffn")
+    (trace,) = by_name["moe.dispatch"]
+    forward, backward = {"pool": "ffn.0.forward"}, {"pool": "ffn.0.backward"}
+    for name, attrs in (
+        ("client.pack", {"kind": "forward"}),
+        ("rpc.multi", {}),
+        ("server.request", {"type": "multi"}),
+        ("server.encode", {}),
+        ("pool.wait", forward),
+        ("runtime.queue", forward),
+        ("runtime.stack", forward),
+        ("runtime.dispatch", forward),
+        ("runtime.materialize", forward),
+        ("runtime.deliver", forward),
         # backward joins the SAME trace (the session carries it)
-        "moe.backward.ffn",
-        "client.pack.backward",
-        "runtime.dispatch.ffn.0.backward",
+        ("moe.backward", {"prefix": "ffn"}),
+        ("client.pack", {"kind": "backward"}),
+        ("runtime.dispatch", backward),
     ):
-        assert trace in by_name.get(name, set()), (
-            f"{name} not stamped with the dispatch trace; got {by_name}"
+        assert trace in _traces_by_name(spans, **attrs).get(name, set()), (
+            f"{name} {attrs} not stamped with the dispatch trace; "
+            f"got {by_name}"
         )
+    # no span name carries a pool, a prefix or a message type any more
+    assert not [n for n, *_ in spans
+                if n.startswith(("server.", "pool.", "runtime."))
+                and n.count(".") != 1]
     # nesting: server stage spans inside the server request span, which
     # sits inside the client's rpc span (same process, one clock)
     rpc_s, rpc_e = _interval(spans, "rpc.multi", trace)
-    req_s, req_e = _interval(spans, "server.request.multi", trace)
+    req_s, req_e = _interval(spans, "server.request", trace, type="multi")
     assert rpc_s <= req_s and req_e <= rpc_e
-    for stage in ("stack", "dispatch", "materialize"):
-        s, e = _interval(spans, f"runtime.{stage}.ffn.0.forward", trace)
+    for stage in ("queue", "stack", "dispatch", "materialize", "deliver"):
+        s, e = _interval(spans, f"runtime.{stage}", trace, **forward)
         assert req_s <= s and e <= req_e, f"runtime.{stage} escapes request"
 
 
@@ -135,12 +148,14 @@ def test_trace_v1_fallback_roundtrip(profiled):
         moe = _make_moe(srv, endpoint)
         _fwd_bwd(moe)
     by_name = _traces_by_name(timeline.spans())
-    (trace,) = by_name["moe.dispatch.ffn"]
+    (trace,) = by_name["moe.dispatch"]
     assert trace in by_name.get("rpc.multi", set())
-    assert trace in by_name.get("server.request.multi", set())
-    assert trace in by_name.get("runtime.dispatch.ffn.0.forward", set())
+    spans = timeline.spans()
+    assert trace in _traces_by_name(spans, type="multi")["server.request"]
+    assert trace in _traces_by_name(
+        spans, pool="ffn.0.forward")["runtime.dispatch"]
     # legacy mode has no host-thread pack stage, by design
-    assert "client.pack.forward" not in by_name
+    assert "client.pack" not in by_name
 
 
 def test_trace_survives_disaggregated_retry(profiled, monkeypatch):
@@ -168,10 +183,11 @@ def test_trace_survives_disaggregated_retry(profiled, monkeypatch):
         assert np.isfinite(np.asarray(y)).all()
     assert failed["n"] == 1, "the merged call was never failed"
     by_name = _traces_by_name(timeline.spans())
-    (trace,) = by_name["moe.dispatch.ffn"]
+    (trace,) = by_name["moe.dispatch"]
     # the disaggregated singles went out as rpc.forward with the trace
     assert trace in by_name.get("rpc.forward", set())
-    assert trace in by_name.get("server.request.forward", set())
+    assert trace in _traces_by_name(
+        timeline.spans(), type="forward").get("server.request", set())
 
 
 def test_trace_echoed_in_reply_meta_and_always_on_stats(profiled):
@@ -208,7 +224,6 @@ def test_merged_multi_trainer_batch_is_unstamped():
     """A batch that merged tasks from TWO different traces has no single
     owner: the runtime stage spans stay trace-free instead of
     misattributing shared work to one request."""
-    from learning_at_home_tpu.server.runtime import _job_trace
     from learning_at_home_tpu.server.task_pool import BatchJob, TaskPool
 
     def job(traces):
@@ -217,11 +232,11 @@ def test_merged_multi_trainer_batch_is_unstamped():
             task_tensors=[], row_spans=[], n_rows=0, traces=traces,
         )
 
-    assert _job_trace(job(["aa", "aa"])) == "aa"
-    assert _job_trace(job(["aa", None])) == "aa"
-    assert _job_trace(job(["aa", "bb"])) is None
-    assert _job_trace(job([None])) is None
-    assert _job_trace(job([])) is None
+    assert job(["aa", "aa"]).owner_trace() == "aa"
+    assert job(["aa", None]).owner_trace() == "aa"
+    assert job(["aa", "bb"]).owner_trace() is None
+    assert job([None]).owner_trace() is None
+    assert job([]).owner_trace() is None
 
 
 # ---------------------------------------------------------------------------
@@ -316,7 +331,7 @@ def test_two_server_trainer_smoke_chrome_trace_and_lah_top(
         # / rpc / server stack / dispatch / materialize spans share one
         # trace id and nest correctly
         spans = timeline.spans()
-        (trace,) = _traces_by_name(spans)["moe.dispatch.ffn"]
+        (trace,) = _traces_by_name(spans)["moe.dispatch"]
         path = tmp_path / "swarm_trace.json"
         timeline.save_chrome_trace(str(path), process_name="smoke")
         events = json.loads(path.read_text())["traceEvents"]
@@ -325,16 +340,17 @@ def test_two_server_trainer_smoke_chrome_trace_and_lah_top(
             if e.get("ph") == "X" and e.get("args", {}).get("trace") == trace
         ]
         names = {e["name"] for e in traced}
-        assert any(n.startswith("client.pack.forward") for n in names)
+        assert any(e["name"] == "client.pack"
+                   and e["args"]["kind"] == "forward" for e in traced)
         assert any(n.startswith("rpc.") for n in names)
         for stage in ("stack", "dispatch", "materialize"):
             assert any(
-                n.startswith(f"runtime.{stage}.ffn.") for n in names
+                e["name"] == f"runtime.{stage}"
+                and e["args"]["pool"].startswith("ffn.") for e in traced
             ), f"no {stage} span in the exported trace: {names}"
         # nesting in the EXPORTED events (µs timeline)
-        reqs = [e for e in traced if e["name"].startswith("server.request.")]
-        stages = [e for e in traced if e["name"].startswith("runtime.")
-                  and e["name"].count(".") > 2]
+        reqs = [e for e in traced if e["name"] == "server.request"]
+        stages = [e for e in traced if e["name"].startswith("runtime.")]
         assert reqs and stages
         for st in stages:
             assert any(

@@ -41,7 +41,11 @@ def test_spans_capture_rpc_path():
             expert.forward_blocking([x])
         summary = timeline.summary()
         assert summary["rpc.forward"]["count"] == 2
-        assert any(name.startswith("runtime.expert.0") for name in summary)
+        for stage in ("stack", "dispatch", "materialize"):
+            assert summary[f"runtime.{stage}"]["count"] == 2
+        assert {s[5]["pool"] for s in timeline.spans("runtime.dispatch")} == {
+            "expert.0.forward"
+        }
     finally:
         timeline.disable()
         timeline.clear()
@@ -54,7 +58,7 @@ def test_disabled_timeline_records_nothing():
     with tl.span("x"):
         pass
     tl.record("y", 0, 1)
-    assert tl.summary() == {}
+    assert tl.summary() == {} and tl.spans() == []
 
 
 def test_device_trace_captures(tmp_path):

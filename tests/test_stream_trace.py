@@ -144,7 +144,7 @@ def test_one_trace_id_through_preempt_coalesce_and_spec_verify(
         assert "gateway.token.first" in names
         assert names & {"gateway.slot.assign", "gateway.recompute.admit"}
         # spec verify rounds stamp per-stream accepted-k markers
-        assert any(n.startswith("gateway.spec.accept.k") for n in names), (
+        assert "gateway.spec.accept" in names, (
             names
         )
 
@@ -170,8 +170,8 @@ def test_one_trace_id_through_preempt_coalesce_and_spec_verify(
         spans = _spans_by_trace(tid)
         umbrella = [s for s in spans if s[0] == "gateway.stream"]
         assert len(umbrella) == 1
-        _, u_start, u_dur, _, _ = umbrella[0]
-        for name, start, dur, _, _ in spans:
+        _, u_start, u_dur, *_ = umbrella[0]
+        for name, start, dur, *_ in spans:
             if not name.startswith("gateway."):
                 continue
             assert start >= u_start - eps, (name, tid)
