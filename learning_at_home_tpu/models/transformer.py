@@ -809,7 +809,52 @@ class DMoETransformerLM:
         chunk's logits (one extra head matmul ≈ few % FLOPs).  At the
         256-expert flagship shape this is what lifts the per-chip batch
         from 16 to 64 — the f32 logits (+ cotangents) were the dominant
-        activation term."""
+        activation term.
+
+        Which path runs is read off the mesh and the shapes:
+
+        * one device: the scan over all tokens (:meth:`_chunked_ce_sum`).
+        * several devices, and the batch (with a ``seq`` axis also the
+          sequence) divides over the shards of ``batch_sharding(mesh)``
+          — every jitted train step: the same scan per shard under
+          ``shard_map`` (scope ``ce/shard_map``).  Each device scans its
+          own rows against the replicated head, the per-shard sums are
+          added outside, and the head's cotangent is summed over the
+          shards by the ``shard_map`` transpose.  The loss is per token,
+          so any partition that ``x`` and ``targets`` share is right.
+        * otherwise (an eager call with a batch that does not divide):
+          the global scan.  On a sharded ``x`` the partitioner turns
+          its per-chunk ``dynamic_slice`` into gather-then-slice and
+          every device computes every chunk: 62 % of the four-chip
+          flagship step (PERF.md, PR 25).  Correct, never fast."""
+        from learning_at_home_tpu.parallel.mesh import data_axes
+
+        mesh = self.mesh
+        b, s = targets.shape
+        b_shards = int(np.prod([mesh.shape[a] for a in data_axes(mesh)]))
+        s_shards = mesh.shape.get("seq", 1)
+        if mesh.devices.size == 1 or b % b_shards or s % s_shards:
+            return self._chunked_ce_sum(x, head, targets) / (b * s)
+
+        from jax import shard_map
+
+        spec = batch_sharding(mesh).spec  # P(batch axes[, "seq"])
+        ce_sums = shard_map(  # one sum a shard, laid out like the shards
+            lambda xl, hl, tl: self._chunked_ce_sum(xl, hl, tl).reshape(
+                (1,) * len(spec)
+            ),
+            mesh=mesh,
+            in_specs=(P(*spec, None), P(), spec),
+            out_specs=spec,
+            # the scan's carry starts unvarying (a constant 0) and ends
+            # varying over the batch axes: the varying-axes check refuses it
+            check_vma=False,
+        )(x, head, targets)
+        return ce_sums.sum() / (b * s)
+
+    def _chunked_ce_sum(self, x, head, targets):
+        """Sum (f32) of the token CEs of ``x`` [b, s, d], ``ce_chunk``
+        tokens at a time."""
         n = x.shape[0] * x.shape[1]
         flat_x = x.reshape(n, x.shape[-1])
         flat_t = targets.reshape(n)
@@ -839,7 +884,7 @@ class DMoETransformerLM:
             ce_sum, _ = jax.checkpoint(chunk_ce)(
                 ce_sum, (flat_x[main:], flat_t[main:])
             )
-        return ce_sum / n
+        return ce_sum
 
     def init_opt_state(
         self, optimizer: optax.GradientTransformation, params: Params

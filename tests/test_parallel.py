@@ -1,6 +1,9 @@
 """Tests for the ICI tier: sharded MoE + DMoE transformer on the virtual
 8-device CPU mesh (SURVEY.md §4 'TPU-build implication')."""
 
+import dataclasses
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -320,6 +323,149 @@ def test_chunked_ce_matches_full_logits():
             bool(jnp.isfinite(l).all())
             for l in jax.tree_util.tree_leaves(grads)
         )
+
+
+def _loss_layer_ops(hlo_text, opcode_re):
+    """``op_name`` of every instruction of the optimized HLO whose opcode
+    matches.  The step differentiates the scope ``ce``, so a path reads
+    ``jvp(ce)/...`` or ``transpose(jvp(ce))/...``: the transforms'
+    brackets are taken off and ``ce/shard_map`` is what remains."""
+    names = re.findall(
+        r"\s(?:%s)\(.*?op_name=\"([^\"]*)\"" % opcode_re, hlo_text
+    )
+    return [
+        re.sub(r"\b(?:jvp|transpose)\(|\)", "", name) for name in names
+    ]
+
+
+@pytest.mark.parametrize(
+    "axes, batch, chunk",
+    [
+        # tokens a shard / chunk: a scan of 2 and no remainder; a scan of
+        # 2 and a remainder; one chunk and a remainder; less than a chunk
+        ({"data": 2, "expert": 2}, 8, 16),
+        ({"data": 2, "expert": 2}, 12, 20),
+        ({"data": 2, "expert": 4}, 16, 16),
+        ({"data": 2, "expert": 4}, 16, 24),
+        ({"expert": 8}, 16, 16),
+        ({"expert": 8}, 24, 20),
+        ({"expert": 8}, 8, 128),
+    ],
+    ids=lambda v: (
+        "x".join(f"{k}{n}" for k, n in v.items()) if isinstance(v, dict)
+        else str(v)
+    ),
+)
+def test_chunked_ce_per_shard_matches_global_scan(axes, batch, chunk):
+    """On a multi-device mesh the chunked CE scans each shard's own rows
+    under ``shard_map``: the loss is the full-logits loss, and every
+    gradient leaf is that of the global scan over the unsharded arrays."""
+    n_dev = int(np.prod(list(axes.values())))
+    mesh = make_mesh(axes, devices=jax.devices()[:n_dev])
+    _, cfg = _tiny_model(mesh)
+    cfg = dataclasses.replace(cfg, ce_chunk=chunk, n_layers=1)
+    m = DMoETransformerLM(cfg, mesh)
+    params = m.init_params(jax.random.PRNGKey(0))
+    rs = np.random.RandomState(7)
+    ids, tgt = (
+        jax.device_put(
+            jnp.asarray(rs.randint(0, 64, (batch, 16))), batch_sharding(mesh)
+        )
+        for _ in range(2)
+    )
+
+    def with_aux(ce, aux):
+        return (
+            ce
+            + cfg.aux_loss_weight * aux["aux_loss"]
+            + cfg.router_z_weight * aux["router_z_loss"]
+        )
+
+    def per_shard_loss(p):
+        return m.loss_fn(p, ids, tgt)[0]
+
+    def global_scan_loss(p):  # loss_fn with the CE of the one-device path
+        x, aux = m._hidden(p, ids)
+        return with_aux(m._chunked_ce_sum(x, m._head(p), tgt) / tgt.size, aux)
+
+    # the path under test is the per-shard one (the expert layer has a
+    # shard_map of its own, so the loss layer is traced alone)
+    x_head = jax.eval_shape(lambda p: (m._hidden(p, ids)[0], m._head(p)), params)
+    assert "shard_map" in str(
+        jax.make_jaxpr(lambda x, h: m._chunked_ce(x, h, tgt))(*x_head)
+    )
+    loss, grads = jax.jit(jax.value_and_grad(per_shard_loss))(params)
+    ref_loss, ref_grads = jax.jit(jax.value_and_grad(global_scan_loss))(params)
+    logits, aux = jax.jit(m.apply)(params, ids)
+    full = with_aux(
+        optax.softmax_cross_entropy_with_integer_labels(logits, tgt).mean(), aux
+    )
+    assert abs(float(loss) - float(full)) < 1e-5
+    assert abs(float(loss) - float(ref_loss)) < 1e-5
+    # f32 throughout: the two differ only in the order of the last f32
+    # additions (per-shard sums, then across shards; the head's cotangent
+    # summed per shard, then over shards), a few ulp of leaves whose
+    # largest entries are 1e-2..1: 1e-5 absolute is 100 times that and
+    # 1000 times under a wrong 1/n (the shard's token count for the
+    # global one would scale every leaf by the shard count)
+    for (path, g), r in zip(
+        jax.tree_util.tree_flatten_with_path(grads)[0],
+        jax.tree_util.tree_leaves(ref_grads),
+    ):
+        np.testing.assert_allclose(
+            np.asarray(g), np.asarray(r), rtol=0, atol=1e-5,
+            err_msg=jax.tree_util.keystr(path),
+        )
+
+
+def test_train_step_ce_has_no_all_gather_on_a_mesh():
+    """The compiled pod step on ``data=2 x expert=2``: no all-gather under
+    ``ce`` (the global scan's ``dynamic_slice`` of a batch-sharded stack
+    was gather-then-slice, 352 times a flagship step), and the loss
+    layer's matmuls carry ``ce/shard_map``."""
+    mesh = make_mesh({"data": 2, "expert": 2}, devices=jax.devices()[:4])
+    _, cfg = _tiny_model(mesh)
+    # 8 rows x 16 tokens a step, 2 rows a shard: a scan of 2 chunks of 16
+    m = DMoETransformerLM(dataclasses.replace(cfg, ce_chunk=16), mesh)
+    params = m.init_params(jax.random.PRNGKey(0))
+    opt = optax.adam(1e-3)
+    opt_state = m.init_opt_state(opt, params)
+    ids = jax.device_put(jnp.zeros((8, 16), jnp.int32), batch_sharding(mesh))
+    hlo = m.make_train_step(opt).lower(
+        params, opt_state, ids, ids
+    ).compile().as_text()
+    gathers = _loss_layer_ops(hlo, "all-gather(?:-start)?")
+    assert not [n for n in gathers if "/ce/" in n], gathers
+    dots = [n for n in _loss_layer_ops(hlo, "dot|convolution") if "/ce/" in n]
+    assert dots and all("/ce/shard_map/" in n for n in dots), dots
+    assert any("/ce/shard_map/while/body/" in n for n in dots), dots
+
+
+def test_train_step_on_one_device_has_no_shard_map_in_ce():
+    """One device: the scan over all tokens as before.  No location under
+    ``ce`` in the lowered step names a ``shard_map`` and no collective of
+    the compiled one lies under ``ce`` (the expert layer keeps its
+    ``shard_map`` and its ``psum`` on every mesh, so the whole step has
+    both)."""
+    mesh = make_mesh({"expert": 1}, devices=jax.devices()[:1])
+    _, cfg = _tiny_model(mesh)
+    m = DMoETransformerLM(dataclasses.replace(cfg, ce_chunk=16), mesh)
+    params = m.init_params(jax.random.PRNGKey(0))
+    opt = optax.adam(1e-3)
+    opt_state = m.init_opt_state(opt, params)
+    ids = jnp.zeros((8, 16), jnp.int32)
+    lowered = m.make_train_step(opt).lower(params, opt_state, ids, ids)
+    ce_locs = [
+        l for l in lowered.as_text(debug_info=True).splitlines()
+        if re.search(r"\(ce\)+/", l)
+    ]
+    assert any("while" in l for l in ce_locs)  # the scan is there
+    assert not [l for l in ce_locs if "shard_map" in l]
+    collectives = _loss_layer_ops(
+        lowered.compile().as_text(),
+        "all-gather|all-reduce|all-to-all|reduce-scatter|collective-permute",
+    )
+    assert not [n for n in collectives if "/ce/" in n], collectives
 
 
 def test_transformer_remat_matches():
