@@ -28,11 +28,11 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from learning_at_home_tpu.models.trunk import (
     attention_core,
-    causal_attention,
     layer_norm,
     one_query_attention,
     output_projection,
     qkv_projections,
+    rms_norm,
 )
 from learning_at_home_tpu.parallel.mesh import batch_sharding
 from learning_at_home_tpu.parallel.sharded_moe import ShardedMixtureOfExperts
@@ -126,6 +126,28 @@ class DMoETransformerConfig:
     # (per-shard) token count
     ce_block_n: int = 128
     ce_block_v: int = 1024
+    # ---- the block's shape: an architecture's description, not tuning
+    # switches.  The defaults are the DMoE-Transformer of the seed paper;
+    # OLMoE is rmsnorm / rope / qk_norm / gated_silu of width 1024 /
+    # dropless / renormalize False (__graft_entry__.olmoe_one_chip).
+    # 'layernorm' (scale and bias) or 'rmsnorm' (scale only)
+    norm: str = "layernorm"
+    # 'learned': a [seq_len, d] table added to the embeddings; 'rope':
+    # rotary embedding of every layer's queries and keys, no table
+    positions: str = "learned"
+    # RMSNorm (own scales) over the whole d-wide query and key
+    # projections, before the split into heads
+    qk_norm: bool = False
+    # 'gelu' (w1/b1/w2/b2) or 'gated_silu' (w_gate/w_up/w_down, no biases)
+    expert_kind: str = "gelu"
+    # an expert's hidden width; None = 4 * d_model
+    expert_ffn_dim: int | None = None
+    # 'capacity': [E, C, d] slots, overflow dropped (capacity_factor);
+    # 'dropless': sort by expert + grouped matmul, nothing dropped
+    routing: str = "capacity"
+    # top-k gate weights renormalised to sum to 1, or as the softmax
+    # over all experts gives them
+    renormalize: bool = True
 
 
 class DMoETransformerLM:
@@ -146,6 +168,15 @@ class DMoETransformerLM:
         if config.ce_impl not in ("chunked", "fused"):
             raise ValueError(
                 f"ce_impl must be 'chunked' or 'fused', got {config.ce_impl!r}"
+            )
+        if config.norm not in ("layernorm", "rmsnorm"):
+            raise ValueError(
+                f"norm must be 'layernorm' or 'rmsnorm', got {config.norm!r}"
+            )
+        if config.positions not in ("learned", "rope"):
+            raise ValueError(
+                f"positions must be 'learned' or 'rope', got "
+                f"{config.positions!r}"
             )
         if config.scan_layers and not config.stack_layers:
             raise ValueError(
@@ -170,6 +201,10 @@ class DMoETransformerLM:
             param_dtype=config.param_dtype,
             router_jitter=config.router_jitter,
             gating=config.gating,
+            ffn_dim=config.expert_ffn_dim,
+            expert_kind=config.expert_kind,
+            routing=config.routing,
+            renormalize=config.renormalize,
         )
         self._ring = None
         self._zig = self._zig_inv = None
@@ -218,11 +253,13 @@ class DMoETransformerLM:
         pdt = cfg.param_dtype
 
         def ln():
+            if cfg.norm == "rmsnorm":
+                return {"scale": jnp.ones((d,), pdt)}
             return {"scale": jnp.ones((d,), pdt), "bias": jnp.zeros((d,), pdt)}
 
         def init_layer(key):
             ks = jax.random.split(key, 5)
-            return {
+            lp = {
                 "ln1": ln(),
                 "wq": dense(ks[0], (d, d), pdt),
                 "wk": dense(ks[1], (d, d), pdt),
@@ -231,11 +268,18 @@ class DMoETransformerLM:
                 "ln2": ln(),
                 "moe": self.moe.init_params(ks[4], device_put=False),
             }
+            if cfg.qk_norm:
+                lp["q_norm"] = {"scale": jnp.ones((d,), pdt)}
+                lp["k_norm"] = {"scale": jnp.ones((d,), pdt)}
+            return lp
 
         layer_keys = jax.random.split(k_layers, cfg.n_layers)
         params: dict = {
             "embed": embed_init(k_embed, (v, d), pdt),
-            "pos": embed_init(k_pos, (s, d), pdt),
+            **(
+                {"pos": embed_init(k_pos, (s, d), pdt)}
+                if cfg.positions == "learned" else {}
+            ),
             "ln_f": ln(),
             "layers": (
                 jax.vmap(init_layer)(layer_keys)
@@ -265,20 +309,34 @@ class DMoETransformerLM:
 
     # ---- forward ----
 
-    def _ring_attention(self, lp, x):
-        q, k, v = qkv_projections(lp, x, self.cfg.n_heads)
-        return output_projection(lp, self._ring(q, k, v))
+    def _norm(self, p, x):
+        if self.cfg.norm == "rmsnorm":
+            return rms_norm(p, x)
+        return layer_norm(p, x)
+
+    def _qkv(self, lp, x, positions):
+        """Finished q, k, v of a layer whose tokens sit at ``positions``
+        [S] (read only where the block is rotary): what every attention
+        core (xla, flash, ring, one-query) takes."""
+        rope = self.cfg.positions == "rope"
+        return qkv_projections(
+            lp, x, self.cfg.n_heads,
+            positions=jnp.asarray(positions, jnp.int32) if rope else None,
+        )
 
     def _layer(self, lp, x, layer_idx, token_mask=None):
-        attn = self._ring_attention if self._ring is not None else (
-            lambda lp, x: causal_attention(
-                lp, x, self.cfg.n_heads, impl=self.cfg.attn_impl
-            )
-        )
         with jax.named_scope("attention"):
-            x = x + attn(lp, layer_norm(lp["ln1"], x))
+            q, k, v = self._qkv(
+                lp, self._norm(lp["ln1"], x),
+                # under the zigzag ring the stream is in zigzag order
+                np.arange(x.shape[1]) if self._zig is None else self._zig,
+            )
+            core = self._ring if self._ring is not None else (
+                lambda q, k, v: attention_core(q, k, v, self.cfg.attn_impl)
+            )
+            x = x + output_projection(lp, core(q, k, v))
         b, s, d = x.shape
-        moe_in = layer_norm(lp["ln2"], x).reshape(b * s, d)
+        moe_in = self._norm(lp["ln2"], x).reshape(b * s, d)
         # layer index salts the router jitter: decorrelates the
         # deterministic noise pattern across layers (round-2 advisor)
         moe_out, aux = self.moe(
@@ -303,7 +361,10 @@ class DMoETransformerLM:
         cfg = self.cfg
         with jax.named_scope("embed"):
             x = params["embed"][token_ids].astype(cfg.dtype)
-            x = x + params["pos"][None, : token_ids.shape[1]].astype(cfg.dtype)
+            if cfg.positions == "learned":
+                x = x + params["pos"][
+                    None, : token_ids.shape[1]
+                ].astype(cfg.dtype)
         layer_fn = self._layer
         if cfg.remat:
             if cfg.remat_policy == "dots":
@@ -367,7 +428,7 @@ class DMoETransformerLM:
                 )
         if self._zig is not None:
             x = x[:, self._zig_inv]
-        x = layer_norm(params["ln_f"], x)
+        x = self._norm(params["ln_f"], x)
         aux_mean = {k: v / cfg.n_layers for k, v in aux_total.items()}
         return x, aux_mean
 
@@ -635,25 +696,26 @@ class DMoETransformerLM:
 
         # ---- prefill: full forward over the prompt, caches filled ----
         x = params["embed"][prompt_ids].astype(cfg.dtype)
-        x = x + params["pos"][None, :p].astype(cfg.dtype)
+        if cfg.positions == "learned":
+            x = x + params["pos"][None, :p].astype(cfg.dtype)
         k_caches, v_caches = [], []
         for i in range(cfg.n_layers):
             lp = self._layer_params(params, i)
-            h = layer_norm(lp["ln1"], x)
-            q, k, v = qkv_projections(lp, h, cfg.n_heads)
+            h = self._norm(lp["ln1"], x)
+            q, k, v = self._qkv(lp, h, np.arange(p))
             # same impl as the full forward: the parity guarantee vs the
             # re-forward decoder must survive flash-attention configs
             x = x + output_projection(
                 lp, attention_core(q, k, v, cfg.attn_impl)
             )
-            moe_in = layer_norm(lp["ln2"], x).reshape(b * p, cfg.d_model)
+            moe_in = self._norm(lp["ln2"], x).reshape(b * p, cfg.d_model)
             moe_out, _ = self.moe(lp["moe"], moe_in, jitter_salt=i)
             x = x + moe_out.reshape(b, p, cfg.d_model)
             kc = jnp.zeros((b, s_cache, cfg.n_heads, hd), k.dtype)
             vc = jnp.zeros_like(kc)
             k_caches.append(jax.lax.dynamic_update_slice(kc, k, (0, 0, 0, 0)))
             v_caches.append(jax.lax.dynamic_update_slice(vc, v, (0, 0, 0, 0)))
-        x_last = layer_norm(params["ln_f"], x[:, -1:])
+        x_last = self._norm(params["ln_f"], x[:, -1:])
         logits = self._logits(x_last, self._head(params))[:, 0]  # [B, V]
         rng, sub = jax.random.split(rng)
         next_tok = sample(logits, sub).astype(prompt_ids.dtype)
@@ -670,15 +732,16 @@ class DMoETransformerLM:
         def step(carry, t):
             k_caches, v_caches, tok, out_buf, rng = carry
             x = params["embed"][tok].astype(cfg.dtype)  # [B, d]
-            x = x + jnp.take(
-                params["pos"].astype(cfg.dtype), t, axis=0
-            )[None, :]
+            if cfg.positions == "learned":
+                x = x + jnp.take(
+                    params["pos"].astype(cfg.dtype), t, axis=0
+                )[None, :]
             x = x[:, None, :]  # [B, 1, d]
             k_caches, v_caches = list(k_caches), list(v_caches)
             for i in range(cfg.n_layers):
                 lp = self._layer_params(params, i)
-                h = layer_norm(lp["ln1"], x)
-                q, k, v = qkv_projections(lp, h, cfg.n_heads)
+                h = self._norm(lp["ln1"], x)
+                q, k, v = self._qkv(lp, h, t[None])
                 k_caches[i] = jax.lax.dynamic_update_slice(
                     k_caches[i], k, (0, t, 0, 0)
                 )
@@ -688,10 +751,10 @@ class DMoETransformerLM:
                 x = x + self._one_query_attention(
                     lp, q, k_caches[i], v_caches[i], t
                 )
-                moe_in = layer_norm(lp["ln2"], x).reshape(b, cfg.d_model)
+                moe_in = self._norm(lp["ln2"], x).reshape(b, cfg.d_model)
                 moe_out, _ = self.moe(lp["moe"], moe_in, jitter_salt=i)
                 x = x + moe_out.reshape(b, 1, cfg.d_model)
-            x = layer_norm(params["ln_f"], x)
+            x = self._norm(params["ln_f"], x)
             logits = self._logits(x, self._head(params))[:, 0]
             rng, sub = jax.random.split(rng)
             nxt = sample(logits, sub).astype(tok.dtype)

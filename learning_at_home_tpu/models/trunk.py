@@ -22,13 +22,58 @@ def layer_norm(p: dict, x: jax.Array, eps: float = 1e-5) -> jax.Array:
     return (y * p["scale"] + p["bias"]).astype(x.dtype)
 
 
-def qkv_projections(lp: dict, x: jax.Array, n_heads: int):
-    """Shared Q/K/V projections: [B,S,d] → three [B,S,H,hd]."""
+def rms_norm(p: dict, x: jax.Array, eps: float = 1e-5) -> jax.Array:
+    """RMSNorm in float32 (no mean, no bias), cast back to the input dtype."""
+    x32 = x.astype(jnp.float32)
+    ms = jnp.mean(x32 * x32, axis=-1, keepdims=True)
+    return (x32 * jax.lax.rsqrt(ms + eps) * p["scale"]).astype(x.dtype)
+
+
+def rotary(
+    x: jax.Array, positions: jax.Array, theta: float = 10000.0
+) -> jax.Array:
+    """Rotary position embedding, rotate-half convention, on [B,S,H,hd]
+    heads; ``positions`` [S] are the tokens' positions in the sequence
+    (not their place in the buffer).  Angles in float32."""
+    hd = x.shape[-1]
+    inv_freq = 1.0 / (
+        theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd)
+    )
+    angles = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
+    cos = jnp.cos(angles)[None, :, None, :]
+    sin = jnp.sin(angles)[None, :, None, :]
+    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    return jnp.concatenate(
+        [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
+    ).astype(x.dtype)
+
+
+def qkv_projections(
+    lp: dict, x: jax.Array, n_heads: int,
+    positions: jax.Array | None = None,
+):
+    """Shared Q/K/V projections: [B,S,d] → three [B,S,H,hd], finished for
+    any attention core.  The block's own parameters say what finishing
+    is: ``q_norm``/``k_norm`` in ``lp`` → RMSNorm over the WHOLE d-wide
+    query and key projections, before the split into heads (OLMoE);
+    ``positions`` [S] → rotary embedding of q and k after it."""
     b, s, d = x.shape
     hd = d // n_heads
-    q = (x @ lp["wq"].astype(x.dtype)).reshape(b, s, n_heads, hd)
-    k = (x @ lp["wk"].astype(x.dtype)).reshape(b, s, n_heads, hd)
-    v = (x @ lp["wv"].astype(x.dtype)).reshape(b, s, n_heads, hd)
+
+    def project(w: str, norm: str | None) -> jax.Array:
+        y = x @ lp[w].astype(x.dtype)
+        if norm in lp:
+            with jax.named_scope("qk_norm"):
+                y = rms_norm(lp[norm], y)
+        return y.reshape(b, s, n_heads, hd)
+
+    q = project("wq", "q_norm")
+    k = project("wk", "k_norm")
+    v = project("wv", None)
+    if positions is not None:
+        with jax.named_scope("rope"):
+            q = rotary(q, positions)
+            k = rotary(k, positions)
     return q, k, v
 
 

@@ -15,6 +15,7 @@ replace it later if profiling shows it dominating (SURVEY.md §7 M5).
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import NamedTuple
 
@@ -409,4 +410,121 @@ def combine_outputs_expert_choice(
         jnp.zeros((n_tokens, d), y.dtype)
         .at[plan.token_for_slot.reshape(-1)]
         .add(w * y.reshape(e * c, d), mode="drop")
+    )
+
+
+# ---- dropless routing: sort by expert, grouped matmul, unsort ----
+
+
+class DroplessPlan(NamedTuple):
+    """Routing decision with no capacity: every (token, choice) pair is
+    computed.  The n*k assignments, flattened token-major, are sorted by
+    expert; expert e's rows are the ``group_sizes[e]`` consecutive sorted
+    rows after those of the experts before it."""
+
+    order: jax.Array  # [n*k] int32 — flat assignment (token*k + choice) per sorted row
+    inverse: jax.Array  # [n*k] int32 — sorted row of each flat assignment
+    group_sizes: jax.Array  # [E] int32 — rows per expert; sums to n*k
+    weights: jax.Array  # [n, k] float32 — gate weight per choice
+    aux_loss: jax.Array  # [] load-balance auxiliary, as the capacity plans'
+
+
+def dropless_routing(
+    logits: jax.Array, k: int, renormalize: bool = True,
+    token_mask: jax.Array | None = None,
+) -> DroplessPlan:
+    """logits [n, E] float32 → the k largest softmax gates per token (as
+    they come, or renormalised to sum to 1) and the expert-sorted order.
+    ``token_mask`` [n] bool: padding tokens are computed like any other
+    (there is no capacity for them to claim) with weight 0, and stay out
+    of the aux loss."""
+    num_experts = logits.shape[1]
+    gates = jax.nn.softmax(logits, axis=-1)
+    top_w, top_i = _topk_weights(gates, k, renormalize)
+    if token_mask is not None:
+        top_w = jnp.where(token_mask[:, None], top_w, 0.0)
+    flat = top_i.reshape(-1)
+    # stable: within an expert rows keep token order, so the plan (and the
+    # sums the grouped matmul's backward makes) is a function of the
+    # routing alone
+    order = jnp.argsort(flat, stable=True).astype(jnp.int32)
+    inverse = jnp.argsort(order).astype(jnp.int32)
+    group_sizes = jnp.sum(
+        jax.nn.one_hot(flat, num_experts, dtype=jnp.int32), axis=0
+    )
+    return DroplessPlan(
+        order, inverse, group_sizes, top_w,
+        _load_balance_loss(gates, top_i, token_mask),
+    )
+
+
+# The two row gathers below move rows along a permutation, so each one's
+# transpose is the gather along the inverse permutation.  Left to autodiff
+# it is a scatter-add of n*k rows, which the TPU runs ten times slower
+# than the gather (10.6 against 0.9 ms for 131,072 rows of 2048 bf16,
+# v5e, PERF.md PR 27).
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _rows_to_sorted(x, order, inverse, k):
+    return x[order // k]
+
+
+def _rows_to_sorted_fwd(x, order, inverse, k):
+    return x[order // k], (inverse, x.shape[0])
+
+
+def _rows_to_sorted_bwd(k, residuals, g):
+    inverse, n = residuals
+    per_choice = g[inverse].reshape(n, k, g.shape[-1])
+    dx = per_choice.astype(jnp.float32).sum(axis=1).astype(g.dtype)
+    return dx, None, None
+
+
+_rows_to_sorted.defvjp(_rows_to_sorted_fwd, _rows_to_sorted_bwd)
+
+
+@jax.custom_vjp
+def _rows_from_sorted(ys, order, inverse):
+    return ys[inverse]
+
+
+def _rows_from_sorted_fwd(ys, order, inverse):
+    return ys[inverse], order
+
+
+def _rows_from_sorted_bwd(order, g):
+    return g[order], None, None
+
+
+_rows_from_sorted.defvjp(_rows_from_sorted_fwd, _rows_from_sorted_bwd)
+
+
+def sort_tokens(x: jax.Array, plan: DroplessPlan) -> jax.Array:
+    """[n, d] → [n*k, d]: one copy of a token per choice, rows grouped by
+    expert."""
+    return _rows_to_sorted(x, plan.order, plan.inverse, plan.weights.shape[1])
+
+
+def grouped_matmul(
+    lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array
+) -> jax.Array:
+    """lhs [m, a] (rows grouped as ``group_sizes`` says) x rhs [G, a, b] →
+    [m, b]: row i meets the matrix of its group.  ``jax.lax.ragged_dot``:
+    one native grouped-matmul call on the TPU (dense operation count
+    whatever the group sizes), with its own gradients; any row count, any
+    platform."""
+    return jax.lax.ragged_dot(
+        lhs, rhs, group_sizes, preferred_element_type=lhs.dtype
+    )
+
+
+def unsort_combine(ys: jax.Array, plan: DroplessPlan) -> jax.Array:
+    """[n*k, d] sorted expert outputs → [n, d]: each token's k outputs,
+    gate-weighted and summed in float32."""
+    n, k = plan.weights.shape
+    picked = _rows_from_sorted(ys, plan.order, plan.inverse)
+    return jnp.einsum(
+        "nk,nkd->nd", plan.weights,
+        picked.reshape(n, k, ys.shape[-1]).astype(jnp.float32),
     )

@@ -43,12 +43,28 @@ from learning_at_home_tpu.ops.moe_dispatch import (
     expert_choice_gating,
     dispatch_tokens,
     dispatch_tokens_indexed,
+    dropless_routing,
+    grouped_matmul,
+    sort_tokens,
     top_k_gating,
     top_k_gating_indices,
+    unsort_combine,
 )
 from learning_at_home_tpu.parallel.mesh import data_axes
 
 Params = dict[str, jax.Array]
+
+
+def _router_z_loss(
+    logits: jax.Array, token_mask: jax.Array | None
+) -> jax.Array:
+    """Router z-loss (ST-MoE): penalizes logit magnitude so the softmax
+    stays in a well-conditioned regime at scale (real tokens only)."""
+    lse2 = jax.scipy.special.logsumexp(logits, axis=-1) ** 2
+    if token_mask is None:
+        return jnp.mean(lse2)
+    v = token_mask.astype(lse2.dtype)
+    return (lse2 * v).sum() / jnp.maximum(v.sum(), 1.0)
 
 
 class ShardedMixtureOfExperts:
@@ -56,10 +72,21 @@ class ShardedMixtureOfExperts:
 
     Parameters (``init_params``):
       gate  [d, E]            — replicated
+    ``expert_kind="gelu"`` (``w2(gelu(w1 x + b1)) + b2``):
       w1    [E, d, ffn]       — sharded on axis 0 over ``expert``
       b1    [E, ffn]
       w2    [E, ffn, d]
       b2    [E, d]
+    ``expert_kind="gated_silu"`` (``w_down(silu(w_gate x) * (w_up x))``,
+    no biases):
+      w_gate, w_up  [E, d, ffn]
+      w_down        [E, ffn, d]
+
+    ``routing="capacity"``: the ``[E, C, d]`` slot program above, tokens
+    beyond an expert's capacity dropped.  ``routing="dropless"``: the
+    n*k assignments sorted by expert and run through a grouped matmul,
+    nothing dropped (``_local_forward_dropless``); experts must be whole
+    on every device (``expert`` axis 1).
     """
 
     def __init__(
@@ -75,6 +102,10 @@ class ShardedMixtureOfExperts:
         dispatch_impl: str = "auto",
         router_jitter: float = 0.0,
         gating: str = "topk",
+        ffn_dim: int | None = None,
+        expert_kind: str = "gelu",
+        routing: str = "capacity",
+        renormalize: bool = True,
     ):
         if dispatch_impl not in ("auto", "gather", "onehot"):
             raise ValueError(
@@ -91,6 +122,20 @@ class ShardedMixtureOfExperts:
                 "expert_choice is balanced by construction — pass "
                 "router_jitter=0 (a silently ignored setting would make "
                 "recipe comparisons lie)"
+            )
+        if expert_kind not in ("gelu", "gated_silu"):
+            raise ValueError(
+                f"expert_kind must be 'gelu' or 'gated_silu', got "
+                f"{expert_kind!r}"
+            )
+        if routing not in ("capacity", "dropless"):
+            raise ValueError(
+                f"routing must be 'capacity' or 'dropless', got {routing!r}"
+            )
+        if routing == "dropless" and (gating != "topk" or router_jitter):
+            raise ValueError(
+                "routing='dropless' is token-choice top-k on clean gates: "
+                "gating must be 'topk' and router_jitter 0"
             )
         if "expert" not in mesh.axis_names:
             raise ValueError("mesh must have an 'expert' axis")
@@ -110,7 +155,9 @@ class ShardedMixtureOfExperts:
         self.num_experts = num_experts
         self.k = k
         self.capacity_factor = capacity_factor
-        self.ffn_dim = ffn_mult * hidden_dim
+        # an explicit width where it is no multiple of hidden_dim (OLMoE's
+        # 1024 is half of 2048)
+        self.ffn_dim = ffn_mult * hidden_dim if ffn_dim is None else ffn_dim
         if self.ffn_dim % self.tp:
             raise ValueError(
                 f"ffn_dim={self.ffn_dim} must divide over model axis size {self.tp}"
@@ -131,6 +178,18 @@ class ShardedMixtureOfExperts:
         # loss, no capacity drops; routing is batch-dependent — see
         # ops.moe_dispatch.expert_choice_gating for the causality note)
         self.gating = gating
+        self.expert_kind = expert_kind
+        self.routing = routing
+        self.renormalize = renormalize
+        if routing == "dropless" and (self.ep > 1 or self.tp > 1):
+            raise NotImplementedError(
+                f"routing='dropless' on a mesh with expert={self.ep}, "
+                f"model={self.tp}: its sorted rows stay on the device that "
+                "routed them, so every device must hold every expert whole. "
+                "Experts split over 'expert' need the ragged all-to-all "
+                "(group sizes differ per peer), which arrives with the "
+                "olmoe-train-pod4 cell"
+            )
         self._shard = data_axes(mesh)  # axes the token batch is split over
 
     # ---- parameters ----
@@ -146,18 +205,35 @@ class ShardedMixtureOfExperts:
         # measured 0.40-0.48 dropped at init on the 256-expert flagship;
         # small init gives balance a head start and the aux loss keeps it)
         gate_init = jax.nn.initializers.normal(stddev=1e-2)
-        params = {
-            "gate": gate_init(kg, (d, e), self.param_dtype),
-            "w1": init(k1, (e, d, f), self.param_dtype),
-            "b1": jnp.zeros((e, f), self.param_dtype),
-            "w2": init(k2, (e, f, d), self.param_dtype),
-            "b2": jnp.zeros((e, d), self.param_dtype),
-        }
+        if self.expert_kind == "gated_silu":
+            # fan-in of ONE expert's matrix (the gelu stack's lecun_normal
+            # counts the expert axis into its fan-in: kept, its
+            # checkpoints and baselines were made with it)
+            per_expert = jax.nn.initializers.lecun_normal(batch_axis=0)
+            k1a, k1b = jax.random.split(k1)
+            params = {
+                "gate": gate_init(kg, (d, e), self.param_dtype),
+                "w_gate": per_expert(k1a, (e, d, f), self.param_dtype),
+                "w_up": per_expert(k1b, (e, d, f), self.param_dtype),
+                "w_down": per_expert(k2, (e, f, d), self.param_dtype),
+            }
+        else:
+            params = {
+                "gate": gate_init(kg, (d, e), self.param_dtype),
+                "w1": init(k1, (e, d, f), self.param_dtype),
+                "b1": jnp.zeros((e, f), self.param_dtype),
+                "w2": init(k2, (e, f, d), self.param_dtype),
+                "b2": jnp.zeros((e, d), self.param_dtype),
+            }
         if not device_put:
             return params
         return jax.device_put(params, self.param_shardings())
 
     def _expert_param_specs(self) -> dict[str, P]:
+        if self.expert_kind == "gated_silu":
+            col = P("expert", None, "model") if self.tp > 1 else P("expert")
+            row = P("expert", "model", None) if self.tp > 1 else P("expert")
+            return {"w_gate": col, "w_up": col, "w_down": row}
         if self.tp > 1:
             return {
                 "w1": P("expert", None, "model"),  # column split
@@ -210,6 +286,18 @@ class ShardedMixtureOfExperts:
                 f"token count {n_global} must divide across {n_shards} shards"
             )
         n_local = n_global // n_shards
+        if self.routing == "dropless":
+            aux_names = ("aux_loss", "router_z_loss", "dropped_fraction",
+                         "expert_load_max_over_mean")
+            per_token = (x,) if token_mask is None else (x, token_mask)
+            return shard_map(
+                self._local_forward_dropless,
+                mesh=self.mesh,
+                in_specs=(self.param_specs(),)
+                + (P(self._shard),) * len(per_token),
+                out_specs=(P(self._shard), {name: P() for name in aux_names}),
+                check_vma=False,
+            )(params, *per_token)
         capacity = compute_capacity(
             n_local, self.num_experts, self.k, self.capacity_factor
         )
@@ -273,12 +361,14 @@ class ShardedMixtureOfExperts:
                 plan = expert_choice_gating(logits, capacity, token_mask)
             elif impl == "gather":
                 plan = top_k_gating_indices(
-                    logits, self.k, capacity, jitter=self.router_jitter,
+                    logits, self.k, capacity, self.renormalize,
+                    jitter=self.router_jitter,
                     jitter_salt=jitter_salt, token_mask=token_mask,
                 )
             else:
                 plan = top_k_gating(
-                    logits, self.k, capacity, jitter=self.router_jitter,
+                    logits, self.k, capacity, self.renormalize,
+                    jitter=self.router_jitter,
                     jitter_salt=jitter_salt, token_mask=token_mask,
                 )
         # 2) my tokens to their experts' devices
@@ -302,17 +392,28 @@ class ShardedMixtureOfExperts:
             xe = x_recv.transpose(1, 0, 2, 3).reshape(
                 e_local, self.ep * capacity, d
             )
-            w1 = params["w1"].astype(compute)
-            b1 = params["b1"].astype(compute)
-            w2 = params["w2"].astype(compute)
-            b2 = params["b2"].astype(compute)
-            h = jax.nn.gelu(
-                jnp.einsum("egd,edf->egf", xe, w1) + b1[:, None, :]
-            )
-            ye = jnp.einsum("egf,efd->egd", h, w2)
-            if self.tp > 1:
-                ye = jax.lax.psum(ye, "model")
-            ye = ye + b2[:, None, :]
+            if self.expert_kind == "gated_silu":
+                h = jax.nn.silu(
+                    jnp.einsum("egd,edf->egf", xe,
+                               params["w_gate"].astype(compute))
+                ) * jnp.einsum("egd,edf->egf", xe,
+                               params["w_up"].astype(compute))
+                ye = jnp.einsum("egf,efd->egd", h,
+                                params["w_down"].astype(compute))
+                if self.tp > 1:
+                    ye = jax.lax.psum(ye, "model")
+            else:
+                w1 = params["w1"].astype(compute)
+                b1 = params["b1"].astype(compute)
+                w2 = params["w2"].astype(compute)
+                b2 = params["b2"].astype(compute)
+                h = jax.nn.gelu(
+                    jnp.einsum("egd,edf->egf", xe, w1) + b1[:, None, :]
+                )
+                ye = jnp.einsum("egf,efd->egd", h, w2)
+                if self.tp > 1:
+                    ye = jax.lax.psum(ye, "model")
+                ye = ye + b2[:, None, :]
 
         # 4) return outputs to their source devices, and
         # 5) gate-weighted combine for MY tokens
@@ -333,14 +434,7 @@ class ShardedMixtureOfExperts:
                 y = combine_outputs(y_recv, plan).astype(x.dtype)
 
         axes = self._shard
-        # router z-loss (ST-MoE): penalizes logit magnitude so the softmax
-        # stays in a well-conditioned regime at scale (real tokens only)
-        lse2 = jax.scipy.special.logsumexp(logits, axis=-1) ** 2
-        if token_mask is None:
-            router_z = jnp.mean(lse2)
-        else:
-            v = token_mask.astype(lse2.dtype)
-            router_z = (lse2 * v).sum() / jnp.maximum(v.sum(), 1.0)
+        router_z = _router_z_loss(logits, token_mask)
         if self.gating == "expert_choice":
             # perfectly balanced by construction: no balance auxiliary;
             # "dropped_fraction" reports tokens selected by NO expert
@@ -353,5 +447,72 @@ class ShardedMixtureOfExperts:
             "aux_loss": jax.lax.pmean(aux_loss, axes),
             "router_z_loss": jax.lax.pmean(router_z, axes),
             "dropped_fraction": jax.lax.pmean(dropped, axes),
+        }
+        return y, aux
+
+    def _local_forward_dropless(
+        self, params: Params, x: jax.Array,
+        token_mask: jax.Array | None = None,
+    ) -> tuple[jax.Array, dict]:
+        """Dropless top-k for MY tokens, all experts here: route in
+        float32, sort the n*k assignments by expert, grouped matmuls over
+        the sorted rows, unsort, gate-weighted sum.  No capacity, so no
+        slot tensor and ``dropped_fraction`` exactly 0."""
+        compute = self.dtype
+        n = x.shape[0]
+        # the router is 2*d*E operations a token (0.2 % of an OLMoE layer):
+        # float32 operands at full precision, so that which experts are
+        # the k largest does not hang on a bf16 rounding of the logits
+        with jax.named_scope("router"):
+            logits = jnp.dot(
+                x.astype(jnp.float32), params["gate"].astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST,
+            )
+            plan = dropless_routing(
+                logits, self.k, self.renormalize, token_mask
+            )
+        with jax.named_scope("moe_sort"):
+            xs = sort_tokens(x.astype(compute), plan)  # [n*k, d]
+        if self.expert_kind == "gated_silu":
+            with jax.named_scope("experts/gate_up"):
+                h = jax.nn.silu(
+                    grouped_matmul(
+                        xs, params["w_gate"].astype(compute), plan.group_sizes
+                    )
+                ) * grouped_matmul(
+                    xs, params["w_up"].astype(compute), plan.group_sizes
+                )
+            with jax.named_scope("experts/down"):
+                ys = grouped_matmul(
+                    h, params["w_down"].astype(compute), plan.group_sizes
+                )
+        else:
+            expert_of_row = jnp.repeat(
+                jnp.arange(self.num_experts), plan.group_sizes,
+                total_repeat_length=n * self.k,
+            )
+            with jax.named_scope("experts/gate_up"):
+                h = jax.nn.gelu(
+                    grouped_matmul(
+                        xs, params["w1"].astype(compute), plan.group_sizes
+                    ) + params["b1"].astype(compute)[expert_of_row]
+                )
+            with jax.named_scope("experts/down"):
+                ys = grouped_matmul(
+                    h, params["w2"].astype(compute), plan.group_sizes
+                ) + params["b2"].astype(compute)[expert_of_row]
+        with jax.named_scope("moe_combine"):
+            y = unsort_combine(ys, plan).astype(x.dtype)
+
+        router_z = _router_z_loss(logits, token_mask)
+        load = jnp.max(plan.group_sizes).astype(jnp.float32) / (
+            n * self.k / self.num_experts
+        )
+        axes = self._shard
+        aux = {
+            "aux_loss": jax.lax.pmean(plan.aux_loss, axes),
+            "router_z_loss": jax.lax.pmean(router_z, axes),
+            "dropped_fraction": jnp.float32(0),
+            "expert_load_max_over_mean": jax.lax.pmean(load, axes),
         }
         return y, aux

@@ -1,0 +1,487 @@
+"""OLMoE's block in the pod step (``__graft_entry__.olmoe_one_chip``)
+against its plain reference (``benchmarks/configs/olmoe_1b_7b_reference.py``),
+the dropless path's properties, and the benchmark's runner for it.
+
+Tiny sizes on the CPU, except one AOT compile of a layer at published
+widths for a described (not attached) ``v5e`` chip.
+"""
+
+import dataclasses
+import hashlib
+import os
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, os.path.join(REPO, "benchmarks"))
+
+import harness  # noqa: E402  (benchmarks/harness.py: imports no jax)
+
+from __graft_entry__ import flagship_one_chip, olmoe_one_chip  # noqa: E402
+from learning_at_home_tpu.models import transformer, trunk  # noqa: E402
+from learning_at_home_tpu.models.transformer import DMoETransformerLM  # noqa: E402
+from learning_at_home_tpu.ops.moe_dispatch import dropless_routing  # noqa: E402
+from learning_at_home_tpu.parallel.mesh import batch_sharding, make_mesh  # noqa: E402
+from learning_at_home_tpu.parallel.sharded_moe import ShardedMixtureOfExperts  # noqa: E402
+
+reference = harness.load_path(
+    os.path.join(REPO, "benchmarks", "configs", "olmoe_1b_7b_reference.py")
+)
+runner = harness.load_path(
+    os.path.join(REPO, "benchmarks", "runners", "train_recipe.py")
+)
+
+
+def _one_device_mesh():
+    return make_mesh({"expert": 1}, devices=jax.devices()[:1])
+
+
+def _sizes(cfg):
+    return dict(
+        reference.SIZES, n_heads=cfg.n_heads, experts_per_token=cfg.k,
+        aux_loss_weight=cfg.aux_loss_weight,
+        router_z_weight=cfg.router_z_weight,
+    )
+
+
+def _decisive(params):
+    """Seeded weights with a router that decides: the program's init
+    (normal 1e-2) gives near-equal gates at tiny widths, under which the
+    expert layer is 1 % of the stream and a wrong expert layer would hide
+    inside any tolerance.  Norm scales leave 1 so that a missing or
+    misplaced scale shows."""
+    rs = np.random.RandomState(7)
+
+    def leaf(path, a):
+        name = jax.tree_util.keystr(path)
+        if "gate" in name and "w_gate" not in name:
+            return a * 10.0
+        if "scale" in name:
+            return a * jnp.asarray(rs.uniform(0.5, 1.5, a.shape), a.dtype)
+        return a
+
+    return jax.tree_util.tree_map_with_path(leaf, params)
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    """(model, cfg, float32 params, ids, targets) on one device."""
+    model, cfg, _, batch = olmoe_one_chip(_one_device_mesh(), tiny=True)
+    params = _decisive(model.init_params(jax.random.PRNGKey(11)))
+    rs = np.random.RandomState(3)
+    ids = jnp.asarray(rs.randint(0, cfg.vocab_size, (batch, cfg.seq_len + 1)))
+    return model, cfg, params, ids[:, :-1], ids[:, 1:]
+
+
+@pytest.fixture(scope="module")
+def want(tiny):
+    """The reference's logits, loss and gradients on the tiny weights."""
+    _, cfg, params, ids, tgt = tiny
+    logits, _, _ = reference.forward(params, ids, _sizes(cfg))
+    loss, grads = reference.loss_and_grads(params, ids, tgt, _sizes(cfg))
+    return np.asarray(logits), float(loss), grads
+
+
+def test_block_matches_reference_in_float32(tiny, want):
+    """Logits, loss and the gradient of EVERY leaf to 1e-4 of the
+    reference's largest entry of that leaf: float32 on both sides, so the
+    only differences are orders of summation (sorted rows through a
+    grouped matmul against a masked loop over experts; a chunked loss
+    against full logits)."""
+    model, cfg, params, ids, tgt = tiny
+    want_logits, want_loss, want_grads = want
+    logits, _ = jax.jit(model.apply)(params, ids)
+    np.testing.assert_allclose(
+        np.asarray(logits), want_logits, rtol=0,
+        atol=1e-4 * np.abs(want_logits).max(),
+    )
+    (loss, metrics), grads = jax.jit(
+        jax.value_and_grad(model.loss_fn, has_aux=True)
+    )(params, ids, tgt)
+    assert abs(float(loss) - want_loss) <= 1e-4 * abs(want_loss)
+    assert float(metrics["dropped_fraction"]) == 0.0
+    for (path, g), w in zip(
+        jax.tree_util.tree_flatten_with_path(grads)[0],
+        jax.tree_util.tree_leaves(want_grads),
+    ):
+        w = np.asarray(w)
+        assert np.abs(w).max() > 0, jax.tree_util.keystr(path)
+        np.testing.assert_allclose(
+            np.asarray(g), w, rtol=0, atol=1e-4 * np.abs(w).max(),
+            err_msg=jax.tree_util.keystr(path),
+        )
+
+
+def _bf16(model, cfg):
+    return DMoETransformerLM(
+        dataclasses.replace(cfg, dtype=jnp.bfloat16), model.mesh
+    )
+
+
+def test_block_in_bf16_is_inside_the_runner_tolerances(tiny, want):
+    """bf16 compute against the float32 reference, at the limits the
+    benchmark's runner holds the chip to."""
+    model, cfg, params, ids, tgt = tiny
+    m16 = _bf16(model, cfg)
+    logits, _ = jax.jit(m16.apply)(params, ids)
+    loss, _ = jax.jit(m16.loss_fn)(params, ids, tgt)
+    readings = runner.readings(logits, loss, want[0], want[1])
+    assert not runner.over_tolerance(readings), readings
+
+
+def _per_head_norm_projections(lp, x, n_heads, positions=None):
+    """The mutation 'query/key norm per head': each head normalised by its
+    own mean square (with its slice of the scale)."""
+    b, s, d = x.shape
+    hd = d // n_heads
+
+    def project(w, norm):
+        y = (x @ lp[w].astype(x.dtype)).reshape(b, s, n_heads, hd)
+        if norm is not None:
+            y = trunk.rms_norm(
+                {"scale": lp[norm]["scale"].reshape(n_heads, hd)}, y
+            )
+        return y
+
+    q, k, v = project("wq", "q_norm"), project("wk", "k_norm"), project("wv", None)
+    return trunk.rotary(q, positions), trunk.rotary(k, positions), v
+
+
+def _strip_qk_norm(params):
+    return {**params, "layers": tuple(
+        {k: v for k, v in lp.items() if k not in ("q_norm", "k_norm")}
+        for lp in params["layers"]
+    )}
+
+
+MUTATIONS = {
+    # name: (config changes, params transform, (module, attribute, value))
+    "renormalised_top8": ({"renormalize": True}, None, None),
+    "no_qk_norm": ({"qk_norm": False}, _strip_qk_norm, None),
+    "rotary_off": ({}, None, (trunk, "rotary", lambda x, positions: x)),
+    "per_head_qk_norm": (
+        {}, None, (transformer, "qkv_projections", _per_head_norm_projections)
+    ),
+    "gelu_for_silu": ({}, None, (jax.nn, "silu", jax.nn.gelu)),
+}
+
+
+@pytest.mark.parametrize("name", sorted(MUTATIONS))
+def test_a_wrong_block_fails_the_runner_tolerances(tiny, want, name, monkeypatch):
+    """The tolerance is tight: each of five plausible misreadings of the
+    block, computed in bf16 like the program, reads outside it."""
+    changes, transform, patch = MUTATIONS[name]
+    model, cfg, params, ids, tgt = tiny
+    want_logits, want_loss, _ = want  # the reference ran before any patch
+    if patch is not None:
+        monkeypatch.setattr(*patch)
+    m16 = _bf16(model, dataclasses.replace(cfg, **changes))
+    if transform is not None:
+        params = transform(params)
+    logits, _ = jax.jit(m16.apply)(params, ids)
+    loss, _ = jax.jit(m16.loss_fn)(params, ids, tgt)
+    readings = runner.readings(logits, loss, want_logits, want_loss)
+    assert runner.over_tolerance(readings), readings
+
+
+def test_reference_at_a_lower_precision_fails_the_runner_tolerances(tiny, want):
+    """What the runner's docstring promises of its limits: the reference
+    itself with every matmul operand rounded to float8_e4m3, the nearest
+    precision below the configuration's bf16, is outside them; rounded to
+    bf16 it is inside."""
+    _, cfg, params, ids, tgt = tiny
+    for dtype, outside in ((jnp.float8_e4m3fn, True), (jnp.bfloat16, False)):
+        logits, aux, z = reference.forward(params, ids, _sizes(cfg), dtype)
+        loss = (reference.ce_of_logits(logits, tgt)
+                + cfg.aux_loss_weight * aux + cfg.router_z_weight * z)
+        readings = runner.readings(logits, loss, want[0], want[1])
+        assert bool(runner.over_tolerance(readings)) is outside, (dtype, readings)
+
+
+# ---- the dropless path ----
+
+
+def _moe(mesh, **kw):
+    kw = {"expert_kind": "gated_silu", "routing": "dropless",
+          "renormalize": False, **kw}
+    return ShardedMixtureOfExperts(
+        mesh, hidden_dim=32, num_experts=8, k=4, ffn_dim=16,
+        dtype=jnp.float32, **kw,
+    )
+
+
+def test_dropless_plan_counts_every_assignment():
+    logits = jnp.asarray(np.random.RandomState(0).randn(96, 8) * 3, jnp.float32)
+    plan = dropless_routing(logits, 4, renormalize=False)
+    assert int(plan.group_sizes.sum()) == 96 * 4
+    assert sorted(np.asarray(plan.order).tolist()) == list(range(96 * 4))
+    experts = np.asarray(jax.lax.top_k(jax.nn.softmax(logits), 4)[1]).reshape(-1)
+    by_expert = experts[np.asarray(plan.order)]
+    assert (np.diff(by_expert) >= 0).all()  # rows grouped by expert
+    np.testing.assert_array_equal(
+        np.bincount(by_expert, minlength=8), np.asarray(plan.group_sizes)
+    )
+    w = np.asarray(plan.weights)
+    assert (w.sum(axis=1) < 1.0).all()  # as the softmax gives them
+    renormalised = dropless_routing(logits, 4, renormalize=True)
+    np.testing.assert_allclose(
+        np.asarray(renormalised.weights).sum(axis=1), 1.0, rtol=1e-6
+    )
+
+
+def test_dropless_output_does_not_depend_on_token_order():
+    moe = _moe(_one_device_mesh())
+    params = moe.init_params(jax.random.PRNGKey(0))
+    params["gate"] = params["gate"] * 100.0
+    rs = np.random.RandomState(1)
+    x = jnp.asarray(rs.randn(64, 32), jnp.float32)
+    perm = rs.permutation(64)
+    y, aux = jax.jit(moe.__call__)(params, x)
+    y_perm, aux_perm = jax.jit(moe.__call__)(params, x[perm])
+    assert float(aux["dropped_fraction"]) == 0.0
+    assert float(aux["expert_load_max_over_mean"]) >= 1.0
+    np.testing.assert_allclose(
+        np.asarray(y)[perm], np.asarray(y_perm), rtol=0, atol=1e-6
+    )
+    for key in aux:
+        np.testing.assert_allclose(
+            float(aux[key]), float(aux_perm[key]), rtol=1e-6
+        )
+
+
+@pytest.mark.parametrize("expert_kind", ["gated_silu", "gelu"])
+def test_dropless_equals_the_capacity_path_when_nothing_is_dropped(expert_kind):
+    """Both expert kinds, both routings, one set of weights: with room for
+    every assignment the slot program computes what the sorted one does."""
+    mesh = _one_device_mesh()
+    sorted_moe = _moe(mesh, expert_kind=expert_kind, renormalize=True)
+    slot_moe = _moe(mesh, expert_kind=expert_kind, renormalize=True,
+                    routing="capacity", capacity_factor=8.0,
+                    dispatch_impl="gather")
+    params = sorted_moe.init_params(jax.random.PRNGKey(2))
+    params["gate"] = params["gate"] * 100.0
+    if expert_kind == "gelu":  # biases that matter
+        rs = np.random.RandomState(4)
+        params["b1"] = jnp.asarray(rs.randn(*params["b1"].shape) * 0.5, jnp.float32)
+        params["b2"] = jnp.asarray(rs.randn(*params["b2"].shape) * 0.5, jnp.float32)
+    x = jnp.asarray(np.random.RandomState(5).randn(48, 32), jnp.float32)
+
+    def loss(moe):
+        def f(p, x):
+            y, aux = moe(p, x)
+            return (y ** 2).sum() + aux["aux_loss"] + aux["router_z_loss"], y
+        return jax.jit(jax.value_and_grad(f, has_aux=True))
+
+    (l_sorted, y_sorted), g_sorted = loss(sorted_moe)(params, x)
+    (l_slot, y_slot), g_slot = loss(slot_moe)(params, x)
+    assert float(slot_moe(params, x)[1]["dropped_fraction"]) == 0.0
+    np.testing.assert_allclose(np.asarray(y_sorted), np.asarray(y_slot), atol=2e-5)
+    np.testing.assert_allclose(float(l_sorted), float(l_slot), rtol=1e-5)
+    for key in g_sorted:
+        np.testing.assert_allclose(
+            np.asarray(g_sorted[key]), np.asarray(g_slot[key]),
+            atol=1e-4 * float(jnp.abs(g_slot[key]).max()), err_msg=key,
+        )
+
+
+def test_dropless_on_a_data_mesh_equals_one_device(tiny):
+    """``data=2 x expert=1``: each shard sorts its own rows; loss and every
+    gradient equal the one-device step's.  The load-balance loss is left
+    out of this comparison: on every routing it is the mean over shards
+    of a product of per-shard statistics, which is another number than
+    the product of the global ones."""
+    _, cfg, params, ids, tgt = tiny
+    cfg = dataclasses.replace(cfg, aux_loss_weight=0.0)
+    mesh2 = make_mesh({"data": 2, "expert": 1}, devices=jax.devices()[:2])
+    model, model2 = DMoETransformerLM(cfg, _one_device_mesh()), DMoETransformerLM(cfg, mesh2)
+    params2 = jax.device_put(params, model2.param_shardings(params))
+    ids2, tgt2 = (jax.device_put(a, batch_sharding(mesh2)) for a in (ids, tgt))
+    grad = lambda m: jax.jit(jax.value_and_grad(m.loss_fn, has_aux=True))  # noqa: E731
+    (l1, m1), g1 = grad(model)(params, ids, tgt)
+    (l2, m2), g2 = grad(model2)(params2, ids2, tgt2)
+    assert float(m2["dropped_fraction"]) == 0.0
+    np.testing.assert_allclose(float(l2), float(l1), rtol=1e-5)
+    for (path, a), b in zip(
+        jax.tree_util.tree_flatten_with_path(g2)[0], jax.tree_util.tree_leaves(g1)
+    ):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=0,
+            atol=1e-4 * float(np.abs(np.asarray(b)).max()),
+            err_msg=jax.tree_util.keystr(path),
+        )
+
+
+def test_dropless_refuses_experts_split_over_devices():
+    mesh = make_mesh({"expert": 2}, devices=jax.devices()[:2])
+    with pytest.raises(NotImplementedError, match="ragged all-to-all"):
+        _moe(mesh)
+
+
+def test_cached_decode_matches_the_full_forward(tiny):
+    """Rotary positions, the query/key norm and the gated experts reach
+    the KV-cache decoder: greedy tokens equal the re-forward decoder's."""
+    model, cfg, params, ids, _ = tiny
+    prompt = ids[:, :8]
+    full = model.generate(params, prompt, 6)
+    cached = model.generate(params, prompt, 6, use_cache=True)
+    np.testing.assert_array_equal(np.asarray(full), np.asarray(cached))
+
+
+# ---- dmoe256 is the program it was ----
+
+# sha256 of make_train_step(...).lower(...).as_text() of
+# flagship_one_chip(tiny=True) on a one-device CPU mesh, taken on the
+# parent commit (613a39e) with this container's jax 0.9.0
+DMOE_TINY_STEP_SHA256 = (
+    "80bdf4b59a2b64a8496795126dde40ecb9732b772fb0fef07eb25447a243b65b"
+)
+
+
+def test_dmoe256_lowered_step_is_text_identical_to_the_parents():
+    """The block's shape became part of the configuration; under its
+    defaults the seed paper's model lowers to the same StableHLO, letter
+    for letter.  A change that is MEANT to alter that program updates the
+    hash with the reason."""
+    from learning_at_home_tpu.parallel.mesh import opt_state_shardings
+
+    mesh = _one_device_mesh()
+    model, cfg, opt, batch = flagship_one_chip(mesh, tiny=True)
+    shape = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
+    shard = model.param_shardings(shape)
+
+    def placed(tree, shardings):
+        return jax.tree_util.tree_map(
+            lambda s, h: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=h),
+            tree, shardings,
+        )
+
+    p = placed(shape, shard)
+    o = jax.eval_shape(opt.init, p)
+    o = placed(o, opt_state_shardings(o, shard, p, mesh))
+    ids = jax.ShapeDtypeStruct(
+        (batch, cfg.seq_len), jnp.int32, sharding=batch_sharding(mesh)
+    )
+    text = model.make_train_step(opt).lower(p, o, ids, ids).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == DMOE_TINY_STEP_SHA256
+
+
+# ---- the chip's compiler accepts a layer at published widths ----
+
+
+@pytest.fixture(scope="module")
+def v5e_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # no TPU compiler here, or its library is held
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return topo.devices[0]
+
+
+def test_one_olmoe_layer_compiles_for_v5e_at_published_widths(v5e_chip):
+    """Forward and backward of ONE layer of the recipe (2048 wide, 16
+    heads of 128, 64 gated experts of 1024, top-8 dropless, 4 x 4,096
+    tokens) for a described chip: the grouped matmul, the sort and the
+    gathers are accepted at the sizes the cell runs."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    mesh = Mesh(np.array([v5e_chip]), ("expert",))
+    model, cfg, _, batch = olmoe_one_chip(mesh)
+    assert (cfg.d_model, cfg.n_heads, cfg.num_experts, cfg.k,
+            model.moe.ffn_dim, cfg.seq_len, batch) == (2048, 16, 64, 8, 1024, 4096, 4)
+    one = NamedSharding(mesh, P())
+    shapes = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
+    lp = jax.tree_util.tree_map(
+        lambda s, h: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=h),
+        shapes["layers"][0], model.param_shardings(shapes)["layers"][0],
+    )
+    x = jax.ShapeDtypeStruct((batch, cfg.seq_len, cfg.d_model), cfg.dtype, sharding=one)
+
+    def layer_loss(lp, x):
+        y, aux = model._layer(lp, x, 0)
+        return (y.astype(jnp.float32) ** 2).mean() + aux["aux_loss"]
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        compiled = jax.jit(jax.grad(layer_loss, argnums=(0, 1))).lower(lp, x).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+    text = compiled.as_text()
+    assert text.count("ragged-dot-none") >= 9  # 3 forward, 6 backward
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 12e9
+
+
+# ---- the benchmark's files for it ----
+
+
+def test_runner_attributes_device_time_by_scope():
+    """``scope_times``: a traced operation takes the scope its instruction's
+    ``op_name`` names, as a path component or inside the ``jvp(..)`` that
+    differentiation wraps around a top-level scope; the grouped-matmul
+    kernel, whose call loses the path, is the expert layer's by name."""
+    hlo = """
+  %fusion.1 = bf16[8]{0} fusion(%p), kind=kLoop, metadata={op_name="jit(train_step)/jvp(layer_0)/attention/rope/mul"}
+  %fusion.2 = bf16[8]{0} fusion(%p), kind=kLoop, metadata={op_name="jit(train_step)/transpose(jvp(layer_1))/jvp(layer_1)/checkpoint/rematted_computation/experts/gate_up/mul" source_file="x.py"}
+  %ragged-dot-none.7 = bf16[8,8]{1,0} custom-call(%a, %b), custom_call_target="tpu_custom_call", metadata={op_name="ragged-dot-none"}
+  %ragged-dot-metadata.3 = (s32[65]{0}) custom-call(%g), custom_call_target="tpu_custom_call", metadata={op_name="ragged-dot-metadata"}
+  %fusion.3 = f32[] fusion(%p), kind=kLoop, metadata={op_name="jit(train_step)/transpose(jvp(ce))/while/body/dot_general"}
+  ROOT %fusion.4 = bf16[8]{0} fusion(%p), kind=kLoop, metadata={op_name="jit(train_step)/optimizer/mul"}
+  %copy.9 = bf16[8]{0} copy(%p)
+"""
+    s = 10 ** 9  # one second of device time, in the trace's nanoseconds
+    ops = [("fusion.1", 0, s), ("fusion.2", s, 3 * s),
+           ("ragged-dot-none.7", 3 * s, 5 * s), ("ragged-dot-none.7", 5 * s, 7 * s),
+           ("ragged-dot-metadata.3", 7 * s, 8 * s), ("fusion.3", 8 * s, 16 * s),
+           ("fusion.4", 16 * s, 32 * s), ("copy.9", 32 * s, 64 * s)]
+    got = runner.scope_times(ops, hlo)
+    assert got["by_scope"] == {"attention": 1.0, "experts": 7.0, "ce": 8.0,
+                               "optimizer": 16.0, "other": 32.0}
+    assert got["total_s"] == 64.0
+    assert (got["grouped_matmul_s"], got["grouped_matmul_calls"]) == (4.0, 2)
+
+
+def test_benchmark_manifests_pass_selfcheck_and_the_runner_rehearses(tmp_path):
+    """``selfcheck.py`` on the manifest and on the rehearsal's, then the
+    new runner for 2 s at tiny sizes on the CPU: it cannot rot unrun."""
+    from learning_at_home_tpu.utils.subproc import clean_jax_subprocess_env
+
+    env = clean_jax_subprocess_env(REPO, platform="cpu")
+    env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "cache")
+    check = subprocess.run(
+        [sys.executable, "benchmarks/selfcheck.py", "BENCHMARK.json",
+         "benchmarks/rehearsal/manifest.json",
+         "benchmarks/rehearsal/manifest_olmoe.json"],
+        cwd=REPO, env=env, capture_output=True, text=True, timeout=120,
+    )
+    assert check.returncode == 0 and "selfcheck: ok" in check.stdout, check.stdout
+    for trace in ("0", "1"):
+        run = subprocess.run(
+            [sys.executable, "benchmarks/run.py", "--manifest",
+             "benchmarks/rehearsal/manifest_olmoe.json", "--workload",
+             "olmoe-1b-7b-train-zipf4k", "--seed", "2700000001",
+             "--seconds", "2", "--trace", trace],
+            cwd=REPO, env=env, capture_output=True, text=True, timeout=300,
+        )
+        assert run.returncode == 0, run.stderr[-2000:]
+        line = __import__("json").loads(run.stdout.strip().splitlines()[-1])
+        assert line["correct"] and line["failed"] == 0, run.stderr[-2000:]
+        names = set(line["metrics"])
+        if trace == "0":
+            assert names == {"cpu_rehearsal.train_tokens_per_s_per_chip",
+                             "cpu_rehearsal.setup_s"}
+        else:
+            assert line["metrics"]["cpu_rehearsal.olmoe.moe_dropped_share"]["value"] == 0.0
+            assert "cpu_rehearsal.olmoe.expert_load_max_over_mean" in names
