@@ -28,6 +28,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from learning_at_home_tpu.models.trunk import (
     attention_core,
+    flash_block_sizes,
     layer_norm,
     one_query_attention,
     output_projection,
@@ -66,20 +67,12 @@ class DMoETransformerConfig:
     # depends on the batch — see ops.moe_dispatch.expert_choice_gating)
     gating: str = "topk"
     # 'xla' = jax.nn.dot_product_attention (materializes [B,H,S,S]);
-    # 'flash' = TPU Pallas flash-attention kernel (O(S) memory) — TPU
-    # only, seq_len must divide the kernel block (min(512, S));
-    # 'auto' = flash on TPU at seq_len >= 8192, else xla.
-    # Measured table (v5e, 4-layer/64-expert, remat, tok/s): 2048 XLA
-    # 101.7k vs flash 82.3k; 4096 tie (57.9 vs 57.1); 8192 flash 8.6x
-    # (36.7k vs 4.3k — materialized scores hit the HBM cliff); 16384
-    # XLA 24.8k vs flash 21.5k.  Auto still picks flash at 16384 — a
-    # DELIBERATE exception to the measured winner: XLA's win there came
-    # from a batch small enough that [B,H,S,S] fit (B*H*S*S*2 bytes;
-    # at S=16384 even B=2,H=8 is 8.6 GB), and growing batch or heads
-    # re-enters the 8192-style cliff, while flash stays O(S).  Paying
-    # a measured -13% at one swept point buys a path whose memory does
-    # not explode with batch; pass attn_impl='xla' explicitly to take
-    # the 16384 point's winner at small batch.
+    # 'flash' = the TPU Pallas blocked kernel (splash attention under a
+    # causal mask: O(S) memory) at the tiles trunk.flash_block_sizes gives
+    # for the call's shape; a call the kernel cannot take (not a TPU, a
+    # length its tiles do not divide) runs 'xla'; on a mesh of several
+    # devices Mosaic refuses the step (no shard_map around the kernel);
+    # 'auto' = auto_attn_impl below.
     attn_impl: str = "auto"
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
@@ -150,21 +143,50 @@ class DMoETransformerConfig:
     renormalize: bool = True
 
 
+# The shortest sequence at which the blocked kernel, at its tuned tiles,
+# beats the xla core on a TPU v5e for heads of 64 and of 128 alike
+# (forward + backward of the core alone, bf16, 8,192 tokens a call, ms
+# xla / kernel; tools/attention_probe.py cores; PERF.md section 6 "PR 28"):
+#   S       heads of 64 (8)    heads of 128 (16)
+#     256    0.60 /  0.76        2.61 /  2.00   (xla still wins at 64:
+#     512    1.34 /  0.74        4.85 /  1.93    dmoe256's shape)
+#   1,024    3.14 /  1.05        9.03 /  2.77
+#   2,048    5.86 /  1.48       17.21 /  3.65
+#   4,096   16.13 /  2.27       33.26 /  5.16
+#   8,192  241.06 /  3.73      485.28 /  8.21   (xla's [B,H,S,S] scores
+#                                                 fall off the HBM cliff)
+FLASH_MIN_SEQ_LEN = 512
+
+
+def auto_attn_impl(
+    backend: str, n_devices: int, seq_len: int, head_dim: int
+) -> str:
+    """What ``attn_impl="auto"`` resolves to, from the backend, the mesh
+    and the training shape alone: the blocked kernel where it can run and
+    the sequence is long enough for it to win.  It can run on the ``tpu``
+    backend specifically, not merely "not cpu" (Mosaic lowering), at
+    tiles that divide the length, and on a mesh of ONE device: Mosaic
+    refuses to partition a kernel over a mesh ("wrap the call in a
+    shard_map"), so there the step keeps the core XLA can partition."""
+    can_run = (
+        n_devices == 1
+        and flash_block_sizes((1, seq_len, 1, head_dim), backend) is not None
+    )
+    return "flash" if can_run and seq_len >= FLASH_MIN_SEQ_LEN else "xla"
+
+
 class DMoETransformerLM:
     """Functional model: explicit param pytree, jit/pjit-friendly apply."""
 
     def __init__(self, config: DMoETransformerConfig, mesh: Mesh):
         if config.attn_impl == "auto":
-            # the flash kernel is TPU-only (Mosaic lowering): require the
-            # tpu backend specifically, not merely "not cpu"
-            impl = (
-                "flash"
-                if jax.default_backend() == "tpu"
-                and config.seq_len >= 8192
-                and config.seq_len % min(512, config.seq_len) == 0
-                else "xla"
+            config = dataclasses.replace(
+                config,
+                attn_impl=auto_attn_impl(
+                    jax.default_backend(), mesh.devices.size,
+                    config.seq_len, config.d_model // config.n_heads,
+                ),
             )
-            config = dataclasses.replace(config, attn_impl=impl)
         if config.ce_impl not in ("chunked", "fused"):
             raise ValueError(
                 f"ce_impl must be 'chunked' or 'fused', got {config.ce_impl!r}"

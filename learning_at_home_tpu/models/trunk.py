@@ -93,10 +93,12 @@ def causal_attention(
     [B,H,S,S] scores — the API is used so future jax releases/backends
     can substitute fused kernels, NOT for a memory win today.
 
-    impl="flash": the TPU Pallas flash-attention kernel
-    (``jax.experimental.pallas.ops.tpu.flash_attention``) — O(S) memory,
-    block-streamed online softmax on the MXU.  TPU-only; sequence length
-    must divide its block size (512 or S, whichever is smaller).
+    impl="flash": the TPU Pallas blocked kernel
+    (``jax.experimental.pallas.ops.tpu.splash_attention`` under a causal
+    mask) — O(S) memory, block-streamed online softmax on the MXU, with
+    the tiles :func:`flash_block_sizes` gives for the call's shape.  A call the
+    kernel cannot take (another backend than ``tpu``, a length its tiles
+    do not divide) runs the ``xla`` core instead.
 
     For sequences split ACROSS chips use the ring path
     (parallel/ring_attention.py), which shares :func:`qkv_projections` /
@@ -106,28 +108,67 @@ def causal_attention(
     return output_projection(lp, attention_core(q, k, v, impl))
 
 
+# Tile sizes of the blocked kernel (splash attention, fused backward), the
+# fastest of the sweep on a TPU v5e at [4, 16, 4096, 128] bf16 (PERF.md
+# section 6 "PR 28"; tools/attention_probe.py): forward 2.72 ms, forward
+# and backward 8.84 ms, against 68.3 ms for the xla core.  Each is
+# ``min(size, S)``.
+_FLASH_TILES = dict(
+    block_q=1024, block_kv=1024, block_kv_compute=512,
+    block_q_dkv=1024, block_kv_dkv=1024, block_kv_dkv_compute=512,
+)
+
+
+def flash_block_sizes(shape: tuple, backend: str):
+    """The blocked kernel's ``BlockSizes`` for q/k/v of ``shape`` [B, S, H,
+    hd] on ``backend``, or None where the kernel cannot run: a backend
+    other than ``tpu`` (Mosaic lowering), a length that is no multiple of
+    the 128-lane tile or that a tile does not divide, a head size the
+    kernel was never run at.  A pure function of what the call can see."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import BlockSizes
+
+    _, s, _, hd = shape
+    if backend != "tpu" or s % 128 or hd not in (64, 128):
+        return None
+    tiles = {name: min(size, s) for name, size in _FLASH_TILES.items()}
+    if any(s % size for size in tiles.values()):
+        return None
+    return BlockSizes(use_fused_bwd_kernel=True, **tiles)
+
+
 def attention_core(
     q: jax.Array, k: jax.Array, v: jax.Array, impl: str = "xla"
 ) -> jax.Array:
     """The causal attention math on pre-projected [B,S,H,hd] q/k/v —
     shared by :func:`causal_attention` and the KV-cache decoder's prefill
-    so the two paths cannot diverge numerically per ``impl``."""
+    so the two paths cannot diverge numerically per ``impl``.  Both cores
+    take bf16 operands to float32 scores, softmax and accumulators;
+    ``flash`` is the kernel where :func:`flash_block_sizes` has tiles for
+    the call and ``xla`` where it has none."""
     if impl not in ("xla", "flash"):
         raise ValueError(f"impl must be 'xla' or 'flash', got {impl!r}")
-    if impl == "flash":
-        from jax.experimental.pallas.ops.tpu.flash_attention import (
-            flash_attention,
+    sizes = (
+        flash_block_sizes(q.shape, jax.default_backend())
+        if impl == "flash" else None
+    )
+    if sizes is not None:
+        from jax.experimental.pallas.ops.tpu import splash_attention as splash
+
+        _, s, h, hd = q.shape
+        kernel = splash.make_splash_mha_single_device(
+            mask=splash.MultiHeadMask([splash.CausalMask((s, s))] * h),
+            block_sizes=sizes,
         )
 
-        hd = q.shape[-1]
-        # kernel convention is [B, H, S, hd] and applies no scale itself
-        return flash_attention(
-            q.transpose(0, 2, 1, 3),
-            k.transpose(0, 2, 1, 3),
-            v.transpose(0, 2, 1, 3),
-            causal=True,
-            sm_scale=1.0 / (hd ** 0.5),
-        ).transpose(0, 2, 1, 3)
+        # kernel convention: one batch row [H, S, hd], the scale already
+        # on q; blocks wholly above the diagonal are never visited
+        def heads_first(x):
+            return x.transpose(0, 2, 1, 3)
+
+        with jax.named_scope("flash"):
+            return heads_first(jax.vmap(kernel)(
+                heads_first(q) * (1.0 / hd ** 0.5), heads_first(k), heads_first(v)
+            ))
     return jax.nn.dot_product_attention(q, k, v, is_causal=True)
 
 

@@ -1,0 +1,163 @@
+"""The attention core's choice (``trunk.attention_core``): which core
+runs, with which tiles, read from the call's shape and the backend alone.
+
+CPU only: what the kernel computes and how fast is the chip's to say
+(``tools/attention_probe.py``); that Mosaic takes the tiles is an AOT
+compile in ``tests/test_olmoe.py``, beside the topology fixture.
+"""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.experimental.pallas.ops.tpu.splash_attention import BlockSizes
+
+from learning_at_home_tpu.models import trunk
+from learning_at_home_tpu.models.transformer import (
+    FLASH_MIN_SEQ_LEN,
+    DMoETransformerConfig,
+    DMoETransformerLM,
+    auto_attn_impl,
+)
+from learning_at_home_tpu.parallel.mesh import make_mesh
+
+
+@pytest.mark.parametrize("batch", [1, 4])
+@pytest.mark.parametrize("head", [64, 128])
+@pytest.mark.parametrize("seq", [256, 512, 1536, 4096, 8192, 16384])
+def test_tiles_fit_the_shape_or_the_kernel_is_refused(seq, head, batch):
+    """Every answer is a ``BlockSizes`` the kernel's own checks accept
+    (its ``__post_init__``), with the backward sizes, each no longer than
+    the sequence and dividing it, the compute tile dividing its block; or
+    None."""
+    sizes = trunk.flash_block_sizes((batch, seq, 16, head), "tpu")
+    largest = max(trunk._FLASH_TILES.values())
+    if seq % min(largest, seq):
+        assert sizes is None
+        return
+    assert isinstance(sizes, BlockSizes) and sizes.has_backward_blocks
+    tiles = {
+        f.name: getattr(sizes, f.name) for f in dataclasses.fields(sizes)
+        if f.name.startswith("block_") and getattr(sizes, f.name) is not None
+    }
+    assert len(tiles) == 6  # forward and the fused backward, three each
+    assert all(t % 128 == 0 and seq % t == 0 for t in tiles.values()), tiles
+    assert sizes.block_kv % sizes.block_kv_compute == 0
+    assert sizes.block_kv_dkv % sizes.block_kv_dkv_compute == 0
+    # a pure function of the shape: nothing else is read
+    assert sizes == trunk.flash_block_sizes((batch, seq, 2, head), "tpu")
+
+
+@pytest.mark.parametrize("shape, backend", [
+    ((4, 4096, 16, 128), "cpu"),   # Mosaic lowers for a TPU only
+    ((4, 4096, 16, 128), "gpu"),
+    ((2, 13, 16, 128), "tpu"),     # a prompt of any length reaches prefill
+    ((2, 4096 + 64, 16, 128), "tpu"),
+    ((2, 4096, 16, 96), "tpu"),    # a head size the kernel has no rule for
+])
+def test_kernel_is_refused_where_it_cannot_run(shape, backend):
+    assert trunk.flash_block_sizes(shape, backend) is None
+
+
+@pytest.mark.parametrize("backend, n_devices, seq, want", [
+    ("tpu", 1, FLASH_MIN_SEQ_LEN, "flash"),
+    ("tpu", 1, 4 * FLASH_MIN_SEQ_LEN, "flash"),
+    ("tpu", 1, FLASH_MIN_SEQ_LEN // 2, "xla"),
+    ("tpu", 1, 256, "xla"),                      # dmoe256's length
+    ("tpu", 1, FLASH_MIN_SEQ_LEN + 64, "xla"),   # long enough, not divisible
+    ("tpu", 4, 4 * FLASH_MIN_SEQ_LEN, "xla"),    # Mosaic partitions no kernel
+    ("cpu", 1, FLASH_MIN_SEQ_LEN, "xla"),
+    ("cpu", 1, 16 * FLASH_MIN_SEQ_LEN, "xla"),
+    ("gpu", 1, FLASH_MIN_SEQ_LEN, "xla"),
+])
+@pytest.mark.parametrize("head", [64, 128])
+def test_auto_is_a_rule_over_backend_mesh_and_shape(
+    backend, n_devices, seq, head, want
+):
+    assert auto_attn_impl(backend, n_devices, seq, head) == want
+
+
+def _qkv(shape):
+    keys = jax.random.split(jax.random.PRNGKey(0), 3)
+    return [jax.random.normal(k, shape, jnp.float32) for k in keys]
+
+
+@pytest.mark.parametrize("shape, backend", [
+    ((2, 128, 2, 64), "cpu"),   # tiles would fit: the backend refuses
+    ((2, 24, 2, 64), "tpu"),    # the backend would do: the length refuses
+])
+def test_flash_falls_back_to_the_xla_core(shape, backend, monkeypatch):
+    """``impl="flash"`` where the kernel cannot run is the ``xla`` core's
+    result bit for bit, not an error."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    q, k, v = _qkv(shape)
+    got = trunk.attention_core(q, k, v, "flash")
+    np.testing.assert_array_equal(
+        np.asarray(got), np.asarray(trunk.attention_core(q, k, v, "xla"))
+    )
+
+
+def test_only_the_kernel_branch_is_scoped(monkeypatch):
+    """The scope ``flash`` names the kernel's operations and nothing of
+    the ``xla`` branch, whose lowering other programs' hashes hold."""
+    q, k, v = _qkv((1, 128, 2, 64))
+
+    def scopes(impl):
+        jaxpr = jax.make_jaxpr(
+            lambda q, k, v: trunk.attention_core(q, k, v, impl)
+        )(q, k, v)
+        return [str(e.source_info.name_stack) for e in jaxpr.jaxpr.eqns]
+
+    on_xla = scopes("xla")
+    assert not any("flash" in scope for scope in on_xla)
+    assert scopes("flash") == on_xla  # the CPU: one branch
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert all(scope.startswith("flash") for scope in scopes("flash"))
+    assert scopes("xla") == on_xla
+
+
+def test_cached_decode_under_flash_matches_the_full_forward():
+    """``_generate_cached``'s prefill passes a prompt of any length
+    through ``attention_core`` under the model's ``attn_impl``."""
+    mesh = make_mesh({"expert": 1}, devices=jax.devices()[:1])
+    cfg = DMoETransformerConfig(
+        vocab_size=64, d_model=32, n_layers=2, n_heads=4, seq_len=24,
+        num_experts=8, k=2, dtype=jnp.float32, capacity_factor=8.0,
+        attn_impl="flash",
+    )
+    model = DMoETransformerLM(cfg, mesh)
+    assert model.cfg.attn_impl == "flash"  # explicit: left as given
+    params = model.init_params(jax.random.PRNGKey(0))
+    prompt = jnp.asarray([[1, 2, 3, 4, 5], [9, 8, 7, 6, 5]], jnp.int32)
+    full = model.generate(params, prompt, max_new_tokens=6)
+    cached = model.generate(params, prompt, max_new_tokens=6, use_cache=True)
+    np.testing.assert_array_equal(np.asarray(full), np.asarray(cached))
+
+
+def test_kernel_branch_matches_the_xla_core_in_the_interpreter(monkeypatch):
+    """The wiring around the kernel (scale on q, layouts, causal mask, its
+    backward) in Pallas interpret mode on the CPU: output and the three
+    input gradients equal the ``xla`` core's.  What Mosaic makes of the
+    kernel, and how fast, is the chip's to say."""
+    import functools
+
+    from jax.experimental.pallas.ops.tpu import splash_attention as splash
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(
+        splash, "make_splash_mha_single_device",
+        functools.partial(splash.make_splash_mha_single_device, interpret=True),
+    )
+    q, k, v = _qkv((2, 256, 2, 64))
+
+    def loss(impl):
+        return lambda q, k, v: (trunk.attention_core(q, k, v, impl) ** 2).sum()
+
+    want = jax.value_and_grad(loss("xla"), argnums=(0, 1, 2))(q, k, v)
+    got = jax.value_and_grad(loss("flash"), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=0, atol=2e-5 * float(np.abs(b).max())
+        )

@@ -6,9 +6,11 @@ Tiny sizes on the CPU, except one AOT compile of a layer at published
 widths for a described (not attached) ``v5e`` chip.
 """
 
+import contextlib
 import dataclasses
 import hashlib
 import os
+import re
 import subprocess
 import sys
 
@@ -388,15 +390,33 @@ def v5e_chip():
     return topo.devices[0]
 
 
-def test_one_olmoe_layer_compiles_for_v5e_at_published_widths(v5e_chip):
-    """Forward and backward of ONE layer of the recipe (2048 wide, 16
-    heads of 128, 64 gated experts of 1024, top-8 dropless, 4 x 4,096
-    tokens) for a described chip: the grouped matmul, the sort and the
-    gathers are accepted at the sizes the cell runs."""
+@contextlib.contextmanager
+def _no_compile_cache():
+    """An AOT compile for a described chip is written to the persistent
+    cache but cannot be read back without the chip: keep it out."""
     from jax.experimental.compilation_cache import compilation_cache
 
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+
+
+def test_one_olmoe_layer_compiles_for_v5e_at_published_widths(v5e_chip, monkeypatch):
+    """Forward and backward of ONE layer of the recipe (2048 wide, 16
+    heads of 128, 64 gated experts of 1024, top-8 dropless, 4 x 4,096
+    tokens) for a described chip: the grouped matmul, the sort, the
+    gathers and the blocked attention kernel at its tiles are accepted at
+    the sizes the cell runs, and no [B, H, S, S] scores are left."""
+    # the chip is described, not attached: this process's backend is the
+    # CPU, and the recipe would resolve as it does there
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     mesh = Mesh(np.array([v5e_chip]), ("expert",))
     model, cfg, _, batch = olmoe_one_chip(mesh)
+    assert model.cfg.attn_impl == "flash"  # what a user on the chip gets
     assert (cfg.d_model, cfg.n_heads, cfg.num_experts, cfg.k,
             model.moe.ffn_dim, cfg.seq_len, batch) == (2048, 16, 64, 8, 1024, 4096, 4)
     one = NamedSharding(mesh, P())
@@ -411,17 +431,43 @@ def test_one_olmoe_layer_compiles_for_v5e_at_published_widths(v5e_chip):
         y, aux = model._layer(lp, x, 0)
         return (y.astype(jnp.float32) ** 2).mean() + aux["aux_loss"]
 
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    try:
+    with _no_compile_cache():
         compiled = jax.jit(jax.grad(layer_loss, argnums=(0, 1))).lower(lp, x).compile()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", True)
-        compilation_cache.reset_cache()
     text = compiled.as_text()
     assert text.count("ragged-dot-none") >= 9  # 3 forward, 6 backward
+    # forward and the fused backward, under the scope (the kernel writes a
+    # newline into its call's attributes, so the instruction's name and
+    # its op_name sit on different lines of the text)
+    kernels = set(re.findall(
+        r'op_name="[^"]*[/(]attention[/)]+flash/[^"]*/(\w+)/pallas_call"', text))
+    assert len(kernels) == 2 and all(k.startswith("splash_mha") for k in kernels), kernels
+    assert "[4,16,4096,4096]" not in text
     memory = compiled.memory_analysis()
     assert memory.temp_size_in_bytes < 12e9
+
+
+@pytest.mark.parametrize("shape", [
+    (4, 256, 8, 64),     # dmoe256's, were it asked for the kernel
+    (16, 512, 8, 64),    # auto's threshold
+    (8, 1024, 8, 64),
+    (2, 4096, 16, 128),
+    (1, 8192, 16, 128),
+])
+def test_blocked_attention_compiles_for_v5e_at_its_tiles(v5e_chip, monkeypatch, shape):
+    """Mosaic takes the forward kernel and the fused backward kernel at the
+    tiles ``flash_block_sizes`` gives for lengths on both sides of the
+    sweep's."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    one = jax.sharding.SingleDeviceSharding(v5e_chip)
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one)
+
+    def both(q, k, v, do):
+        out, vjp = jax.vjp(lambda q, k, v: trunk.attention_core(q, k, v, "flash"), q, k, v)
+        return out, vjp(do)
+
+    with _no_compile_cache():
+        text = jax.jit(both).lower(x, x, x, x).compile().as_text()
+    assert text.count("tpu_custom_call") >= 2  # forward, the fused backward
 
 
 # ---- the benchmark's files for it ----

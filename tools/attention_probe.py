@@ -1,0 +1,313 @@
+#!/usr/bin/env python3
+"""The attention core on the chip: what ``models/trunk.py`` rests on.
+
+    python tools/attention_probe.py splash     # the blocked kernel's tiles
+    python tools/attention_probe.py tiles      # the other blocked kernel's
+    python tools/attention_probe.py cores      # blocked against xla, by length
+    python tools/attention_probe.py accuracy   # blocked against xla, results
+
+One process a subcommand (a chip belongs to one process), one ``ROW``
+line of JSON a reading, written to ``chiprun_out/attention_probe.jsonl``
+as well.  Every time is the host clock around ``ITERS`` launches that end
+in ``block_until_ready``, after a first call that compiles; a setting the
+kernel or Mosaic refuses is a row with ``refused``.  PERF.md section 6
+("PR 28") holds the readings ``trunk.flash_block_sizes`` and ``auto``'s
+threshold were set from.
+
+- ``splash``: ``splash_attention`` under a causal mask at the OLMoE
+  cell's ``[4, 16, 4096, 128]`` bf16, forward and forward + backward,
+  fused backward or not, then each pass's tiles under the best of the
+  other: the kernel behind ``attention_core``'s ``impl="flash"``.
+- ``tiles``: ``flash_attention`` at the same shape, causal: the forward
+  kernel, the dK/dV kernel and the dQ kernel each over their own tile
+  sizes (the backward kernels through the module's private entry points:
+  the public call runs all three).  The kernel the repo had; at its best
+  tiles 3.3 % of the cell's step behind splash.
+- ``cores``: ``attention_core`` ``flash`` against ``xla``, forward and
+  forward + backward, at the cell's shape and at 8,192 tokens a call for
+  S = 256 ... 8,192 and head sizes 64 and 128: where ``auto``'s
+  threshold comes from.
+- ``accuracy``: ``attention_core`` ``flash`` against ``xla`` at the
+  cell's shape, output and the three input gradients: rms of the
+  difference over rms of the ``xla`` result (limit 1 %), beside what bf16
+  itself costs (each against float32 operands at ``Precision.HIGHEST``).
+"""
+
+from __future__ import annotations
+
+import itertools
+import json
+import os
+import sys
+import time
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+
+ITERS = 10
+CELL = (4, 16, 4096, 128)  # olmoe-1b-7b-train-zipf4k: batch, heads, S, head
+OUT = os.path.join(REPO, "chiprun_out", "attention_probe.jsonl")
+
+
+def row(**facts) -> None:
+    line = json.dumps(facts)
+    print("ROW " + line, flush=True)
+    os.makedirs(os.path.dirname(OUT), exist_ok=True)
+    with open(OUT, "a") as f:
+        f.write(line + "\n")
+
+
+def require_tpu():
+    import jax
+
+    d = jax.devices()[0]
+    print(f"# device: {d.platform} [{d.device_kind}] jax {jax.__version__}",
+          flush=True)
+    if d.platform != "tpu":
+        raise SystemExit(f"attention_probe needs a TPU, found {d.platform!r}")
+
+
+def ms(fn, *args) -> float:
+    """Milliseconds a call, compiled before the clock starts."""
+    import jax
+
+    jax.block_until_ready(fn(*args))
+    t0 = time.perf_counter()
+    out = None
+    for _ in range(ITERS):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    return (time.perf_counter() - t0) / ITERS * 1e3
+
+
+def timed(what: str, settings: dict, fn, *args) -> None:
+    try:
+        row(what=what, **settings, ms=round(ms(fn, *args), 3))
+    except Exception as e:  # the kernel's own refusal, or Mosaic's
+        text = f"{type(e).__name__}: {e}"
+        row(what=what, **settings, refused=text.splitlines()[0][:300])
+
+
+def qkv(shape):
+    """Unit-variance bf16 q, k, v and an output cotangent of ``shape``."""
+    import jax
+    import jax.numpy as jnp
+
+    keys = jax.random.split(jax.random.PRNGKey(0), 4)
+    return tuple(
+        jax.random.normal(k, shape, jnp.float32).astype(jnp.bfloat16)
+        for k in keys
+    )
+
+
+def major_minor(majors, minors):
+    return [(a, b) for a in majors for b in minors if b <= a]
+
+
+def tiles() -> None:
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.pallas.ops.tpu import flash_attention as fa
+
+    require_tpu()
+    b, h, s, hd = CELL
+    q, k, v, do = qkv(CELL)
+    scale = 1.0 / hd ** 0.5
+
+    def forward(bq, bkm, bk, bb):
+        sizes = fa.BlockSizes(block_q=bq, block_k_major=bkm, block_k=bk, block_b=bb)
+        return jax.jit(lambda q, k, v: fa.flash_attention(
+            q, k, v, causal=True, sm_scale=scale, block_sizes=sizes))
+
+    timed("forward", dict(block_q=128, block_k_major=128, block_k=128, block_b=1),
+          forward(128, 128, 128, 1), q, k, v)
+    for bq, (bkm, bk), bb in itertools.product(
+        (256, 512, 1024, 2048),
+        major_minor((256, 512, 1024, 2048), (256, 512, 1024)), (1, 2),
+    ):
+        timed("forward", dict(block_q=bq, block_k_major=bkm, block_k=bk, block_b=bb),
+              forward(bq, bkm, bk, bb), q, k, v)
+
+    # the residuals the backward kernels take, as _flash_attention_bwd does
+    o, l, m = jax.jit(lambda q, k, v: fa._flash_attention_impl(
+        q, k, v, None, None, True, True, scale, 1, 512, 512, 512, False))(q, k, v)
+    di = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1)
+
+    def dkv(bqm, bq, bkm, bk):
+        return jax.jit(lambda *a: fa._flash_attention_bwd_dkv(
+            *a, block_q_major=bqm, block_q=bq, block_k_major=bkm, block_k=bk,
+            sm_scale=scale, causal=True, mask_value=fa.DEFAULT_MASK_VALUE,
+            debug=False))
+
+    def dq(bqm, bkm, bk):
+        return jax.jit(lambda *a: fa._flash_attention_bwd_dq(
+            *a, block_q_major=bqm, block_k_major=bkm, block_k=bk,
+            sm_scale=scale, causal=True, mask_value=fa.DEFAULT_MASK_VALUE,
+            debug=False))
+
+    args = (q, k, v, None, None, l, m, do, di)
+    timed("dkv", dict(block_q_major_dkv=128, block_q_dkv=128,
+                      block_k_major_dkv=128, block_k_dkv=128),
+          dkv(128, 128, 128, 128), *args)
+    pairs = major_minor((256, 512, 1024, 2048), (256, 512, 1024))
+    for (bqm, bq), (bkm, bk) in itertools.product(pairs, pairs):
+        if bq * bk > 512 * 1024:  # four float32 [bq, bk] temporaries a step
+            continue
+        timed("dkv", dict(block_q_major_dkv=bqm, block_q_dkv=bq,
+                          block_k_major_dkv=bkm, block_k_dkv=bk),
+              dkv(bqm, bq, bkm, bk), *args)
+    timed("dq", dict(block_q_dq=128, block_k_major_dq=128, block_k_dq=128),
+          dq(128, 128, 128), *args)
+    for bqm, (bkm, bk) in itertools.product((256, 512, 1024, 2048), pairs):
+        if bqm * bk > 512 * 1024:
+            continue
+        timed("dq", dict(block_q_dq=bqm, block_k_major_dq=bkm, block_k_dq=bk),
+              dq(bqm, bkm, bk), *args)
+
+
+def _splash(forward, backward, fused, heads, s):
+    """``forward``, ``backward``: (block_q, block_kv, block_kv_compute)."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sk,
+        splash_attention_mask as sm,
+    )
+
+    (bq, bkv, bkvc), (bq_b, bkv_b, bkvc_b) = forward, backward
+    sizes = sk.BlockSizes(
+        block_q=bq, block_kv=bkv, block_kv_compute=bkvc,
+        block_q_dkv=bq_b, block_kv_dkv=bkv_b, block_kv_dkv_compute=bkvc_b,
+        block_q_dq=None if fused else bq_b, block_kv_dq=None if fused else bkv_b,
+        use_fused_bwd_kernel=fused,
+    )
+    mask = sm.MultiHeadMask([sm.CausalMask((s, s))] * heads)
+    return sk.make_splash_mha_single_device(mask=mask, block_sizes=sizes)
+
+
+def splash() -> None:
+    import jax
+
+    require_tpu()
+    b, h, s, hd = CELL
+    q, k, v, do = qkv(CELL)
+    scale = 1.0 / hd ** 0.5
+    both_ways = [(sizes, sizes, fused) for sizes, fused in itertools.product(
+        ((512, 512, 512), (512, 1024, 512), (1024, 1024, 512),
+         (1024, 1024, 1024), (1024, 2048, 512), (1024, 2048, 1024),
+         (2048, 2048, 512), (2048, 2048, 1024), (512, 2048, 512)),
+        (False, True),
+    )]
+    # then each pass's own tiles under the best of the other
+    best = (1024, 1024, 512)
+    backward_only = [(best, sizes, True) for sizes in (
+        (512, 1024, 256), (512, 2048, 512), (1024, 512, 512), (1024, 1024, 256),
+        (2048, 512, 512), (2048, 1024, 256), (2048, 1024, 512), (2048, 2048, 256),
+        (4096, 512, 512), (4096, 1024, 256),
+    )]
+    forward_only = [(sizes, best, True) for sizes in (
+        (1024, 1024, 256), (1024, 512, 512), (2048, 1024, 512), (2048, 512, 512),
+        (2048, 1024, 256), (4096, 512, 512),
+    )]
+    for forward, backward, fused in both_ways + backward_only + forward_only:
+        settings = dict(forward=forward, backward=backward, fused_bwd=fused)
+        try:
+            kernel = _splash(forward, backward, fused, h, s)
+        except Exception as e:
+            row(what="splash", **settings, refused=f"{type(e).__name__}: {e}"[:300])
+            continue
+        fwd = jax.jit(lambda q, k, v: jax.vmap(kernel)(q * scale, k, v))
+
+        def both(q, k, v, do):
+            out, vjp = jax.vjp(fwd, q, k, v)
+            return out, vjp(do)
+
+        if backward == best or forward == backward:
+            timed("splash_forward", settings, fwd, q, k, v)
+        timed("splash_forward_backward", settings, jax.jit(both), q, k, v, do)
+
+
+def _core_both(impl):
+    """Forward + backward of ``attention_core`` on [B, S, H, hd]."""
+    import jax
+
+    from learning_at_home_tpu.models.trunk import attention_core
+
+    def both(q, k, v, do):
+        out, vjp = jax.vjp(lambda q, k, v: attention_core(q, k, v, impl), q, k, v)
+        return out, vjp(do)
+
+    return jax.jit(both)
+
+
+def cores() -> None:
+    import jax
+
+    from learning_at_home_tpu.models.trunk import attention_core
+
+    require_tpu()
+    b, h, s, hd = CELL
+    shapes = [(b, s, h, hd)] + [  # the cell's, then 8,192 tokens a call
+        (8192 // s, s, 16 if hd == 128 else 8, hd)
+        for hd, s in itertools.product((64, 128), (256, 512, 1024, 2048, 4096, 8192))
+    ]
+    for shape in shapes:
+        _, s, _, hd = shape
+        q, k, v, do = qkv(shape)
+        for impl in ("xla", "flash"):
+            settings = dict(impl=impl, batch=shape[0], seq=s, heads=shape[2], head=hd)
+            timed("core_forward", settings,
+                  jax.jit(lambda q, k, v, impl=impl: attention_core(q, k, v, impl)),
+                  q, k, v)
+            timed("core_forward_backward", settings, _core_both(impl), q, k, v, do)
+
+
+def accuracy() -> None:
+    import jax
+    import jax.numpy as jnp
+
+    require_tpu()
+    b, h, s, hd = CELL
+    shape = (b, s, h, hd)
+    q, k, v, do = qkv(shape)
+
+    def exact(q, k, v, do):  # float32 operands, no bf16 pass on the MXU
+        def core(q, k, v):
+            hi = jax.lax.Precision.HIGHEST
+            scores = jnp.einsum("bqhd,bkhd->bhqk", q, k, precision=hi) / hd ** 0.5
+            mask = jnp.tril(jnp.ones((s, s), bool))
+            w = jax.nn.softmax(jnp.where(mask, scores, -jnp.inf), axis=-1)
+            return jnp.einsum("bhqk,bkhd->bqhd", w, v, precision=hi)
+
+        out, vjp = jax.vjp(core, q, k, v)
+        return out, vjp(do)
+
+    def flat(result):
+        out, (dq, dk, dv) = result
+        return dict(out=out, dq=dq, dk=dk, dv=dv)
+
+    got = {impl: flat(_core_both(impl)(q, k, v, do)) for impl in ("xla", "flash")}
+    f32 = [a.astype(jnp.float32) for a in (q, k, v, do)]
+    one = jax.jit(exact)
+    rows = [flat(one(*(a[i:i + 1] for a in f32))) for i in range(b)]  # 1 GB of scores a row
+    want = {name: jnp.concatenate([r[name] for r in rows]) for name in rows[0]}
+
+    def rel(a, ref):
+        a, ref = a.astype(jnp.float32), ref.astype(jnp.float32)
+        return float(jnp.sqrt(jnp.mean((a - ref) ** 2) / jnp.mean(ref ** 2)))
+
+    worst = 0.0
+    for name in ("out", "dq", "dk", "dv"):
+        read = dict(
+            flash_vs_xla=rel(got["flash"][name], got["xla"][name]),
+            xla_vs_exact=rel(got["xla"][name], want[name]),
+            flash_vs_exact=rel(got["flash"][name], want[name]),
+        )
+        worst = max(worst, read["flash_vs_xla"])
+        row(what="accuracy", tensor=name, shape=list(shape), **read)
+    row(what="accuracy_verdict", ok=worst < 0.01, worst_flash_vs_xla=worst, limit=0.01)
+    if not worst < 0.01:
+        raise SystemExit("attention_probe: flash is over 1 % from xla")
+
+
+if __name__ == "__main__":
+    {"tiles": tiles, "splash": splash, "cores": cores,
+     "accuracy": accuracy}[sys.argv[1]]()
