@@ -1,22 +1,17 @@
-"""North-star benchmark: DMoE-Transformer training tokens/sec/chip.
+"""Host-tier benchmark arms: the swarm's CPU/loopback measurements.
 
-Prints ONE JSON line: {"metric", "value", "unit", "vs_baseline", ...extra}.
+Prints ONE JSON line: {"bench": "host-tier", ...one group of fields per arm}.
+The chip's numbers (training tokens/s/chip, the expert server's rates)
+come from ``python3 benchmarks/run.py``; nothing here needs a chip.
 
-- The parent process never initializes a JAX backend (a chip belongs to
-  one process, and the worker needs it).  It runs the training-step worker
-  once, on the ambient platform, and exits non-zero when that worker
-  fails or finds no accelerator: a number taken on the CPU is not a
-  tokens/sec/chip and is not printed as one.
-- The host-tier arms that follow (dispatch, averaging, overlap, routing,
-  gateway, placement, DHT and macro simulators) are CPU/loopback
+- The arms (dispatch, averaging, overlap, routing, gateway, speculative
+  decode, placement, DHT and macro simulators) are CPU/loopback
   measurements by design; each runs in a subprocess that names ``"cpu"``
-  as its platform.
+  as its platform.  The parent never initializes a JAX backend.
 - Workers arm ``faulthandler.dump_traceback_later(..., exit=True)`` so a
   hang becomes a stack dump + clean exit instead of an rc=124 timeout.
-
-``vs_baseline`` is measured against the best prior-round number recorded
-in BASELINE.md (reference's published numbers are unrecoverable in this
-environment — empty mount, no egress; see SURVEY.md §0).
+- An arm that fails or times out is left out of the line; the others
+  still report.
 """
 
 import json
@@ -26,10 +21,6 @@ import sys
 import time
 
 REPO = os.path.dirname(os.path.abspath(__file__))
-
-# Prior-round best to compute vs_baseline against (BASELINE.md): round-3
-# real-chip number (v5e, 256 experts, batch 176, the one-chip recipe).
-BASELINE_TPS = {"tpu": 165040.0}
 
 
 def _tail(s: str, n: int = 800) -> str:
@@ -46,33 +37,6 @@ def _last_json_line(stdout: str | None) -> dict | None:
                 return json.loads(line)
             except json.JSONDecodeError:
                 continue
-    return None
-
-
-def run_worker(env: dict, deadline: int) -> dict | None:
-    """Run ``bench.py --worker`` under ``env``; its last JSON line, or
-    None when it exited non-zero, timed out or printed none."""
-    env = dict(env)
-    env["BENCH_DEADLINE_S"] = str(deadline)
-    try:
-        r = subprocess.run(
-            [sys.executable, os.path.join(REPO, "bench.py"), "--worker"],
-            capture_output=True,
-            text=True,
-            timeout=deadline + 30,
-            cwd=REPO,
-            env=env,
-        )
-    except subprocess.TimeoutExpired as e:
-        print(f"bench: worker timed out after {deadline + 30}s\n"
-              f"{_tail(str(e.stdout))}\n{_tail(str(e.stderr))}", file=sys.stderr)
-        return None
-    result = _last_json_line(r.stdout)
-    if r.returncode == 0 and result is not None:
-        return result
-    print(f"bench: worker rc={r.returncode}\n"
-          f"stdout: {_tail(r.stdout)}\nstderr: {_tail(r.stderr)}",
-          file=sys.stderr)
     return None
 
 
@@ -278,16 +242,10 @@ def main() -> int:
     # guard prints PIDs to stderr and stamps the JSON as box_dirty)
     box_dirty = check_orphan_servers()
 
-    # the ambient platform, whatever it is: the worker itself refuses a
-    # CPU, so a machine without a chip makes this exit non-zero
-    result = run_worker(dict(os.environ), deadline=420)
-    if result is None:
-        print("bench: the training-step worker failed; no result",
-              file=sys.stderr)
-        return 1
+    result: dict = {"bench": "host-tier"}
 
-    # north-star metric #2: swarm dispatch p50 (always CPU/host-side —
-    # the DCN tier's latency does not depend on the accelerator)
+    # swarm dispatch p50 (always CPU/host-side — the DCN tier's latency
+    # does not depend on the accelerator)
     disp = run_dispatch_microbench()
     if disp:
         result.update(disp)
@@ -340,333 +298,10 @@ def main() -> int:
     mac = run_macro_sim_bench()
     if mac:
         result.update(mac)
-    # paper-reference series (learning@home, Table 1): the decode-side
-    # quality gap of a 4096-expert DMoE vs its dense baseline grows with
-    # experts-per-sample — 0.336 nats at k=16, 0.568 at k=32.  Recorded
-    # as a constant so graded artifacts carry the target curve the
-    # placement/routing work is measured against.
-    result["decode_gap_nats_by_experts"] = {"16": 0.336, "32": 0.568}
-    # the sampled path (ISSUE 17) inherits the same curve: gate
-    # affinities are computed from hidden states BEFORE the token is
-    # drawn, and speculative verify recomputes the exact per-position
-    # logits, so temperature/top-p/top-k cannot move the routing gap.
-    # Recorded explicitly so a sampling change that DID touch routing
-    # would have to update this line (standing quality thread).
-    result["decode_gap_nats_by_experts_sampled"] = {
-        "16": 0.336, "32": 0.568,
-    }
     if box_dirty:
         result.update(box_dirty)
     print(json.dumps(result), flush=True)
     return 0
-
-
-# --------------------------------------------------------------------------
-# worker: the actual measurement, run in a subprocess by main()
-# --------------------------------------------------------------------------
-
-
-def _model_flops_per_step(cfg, batch: int) -> float:
-    """Analytic model FLOPs for one train step (fwd+bwd ≈ 3× fwd matmuls)."""
-    d, s, v, L = cfg.d_model, cfg.seq_len, cfg.vocab_size, cfg.n_layers
-    f = 4 * d  # ShardedMixtureOfExperts ffn_mult=4
-    per_token_fwd = (
-        2 * d * v  # logits projection (tied embedding)
-        + L * (8 * d * d + 4 * s * d + cfg.k * 4 * d * f)
-    )
-    return 3.0 * per_token_fwd * batch * s
-
-
-def _tree_bytes(abstract) -> int:
-    import jax
-
-    return sum(
-        l.size * l.dtype.itemsize for l in jax.tree_util.tree_leaves(abstract)
-    )
-
-
-def _static_state_bytes(model, optimizer) -> int:
-    """Exact params+opt-state+grads bytes via ``jax.eval_shape`` (no
-    device allocation, batch-independent)."""
-    import jax
-
-    abstract_params = jax.eval_shape(
-        model.init_params, jax.random.PRNGKey(0)
-    )
-    params_b = _tree_bytes(abstract_params)
-    opt_b = _tree_bytes(jax.eval_shape(optimizer.init, abstract_params))
-    return 2 * params_b + opt_b  # cotangents live alongside params
-
-
-def _activation_bytes(cfg, batch: int) -> int:
-    """Dominant activation terms for one train step (f32 logits fwd+bwd,
-    per-layer residual stream, MoE dispatch buffers)."""
-    import jax.numpy as jnp
-    import numpy as np
-
-    s, v, d, L, E = (
-        cfg.seq_len, cfg.vocab_size, cfg.d_model, cfg.n_layers,
-        cfg.num_experts,
-    )
-    tokens = batch * s
-    cap = int(np.ceil(cfg.capacity_factor * cfg.k * tokens / E))
-    act_dtype = jnp.dtype(cfg.dtype).itemsize
-    ce_chunk = min(getattr(cfg, "ce_chunk", tokens), tokens)
-    if getattr(cfg, "remat", False):
-        # checkpointed layers save only their INPUT; internals (attn
-        # saves, dispatch buffers, router scores) live for one layer at
-        # a time during the recomputing backward
-        per_layer = tokens * d * act_dtype * 2 * L
-        live = (
-            tokens * d * act_dtype * 10
-            + E * cap * d * act_dtype * 4
-            + tokens * E * 4 * 2
-        )
-    else:
-        per_layer = tokens * d * act_dtype * 10 * L
-        live = E * cap * d * act_dtype * 4 * L + tokens * E * 4 * 2
-    return (
-        ce_chunk * v * 4 * 3  # f32 logits+grads+temps, ONE CE chunk at a time
-        + tokens * d * act_dtype * 2  # saved final hidden + its cotangent
-        + per_layer
-        + live
-    )
-
-
-def worker() -> None:
-    import faulthandler
-
-    t_start = time.perf_counter()
-    deadline = int(os.environ.get("BENCH_DEADLINE_S", "420"))
-    faulthandler.dump_traceback_later(deadline, exit=True)
-
-    from learning_at_home_tpu.utils.chip import (
-        enable_compile_cache,
-        hbm_bytes,
-        peak_bf16_flops,
-    )
-
-    enable_compile_cache()
-
-    import dataclasses
-
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-    import optax
-
-    device = jax.devices()[0]
-    platform = device.platform
-    print(f"bench worker: platform={platform} [{device.device_kind}]",
-          file=sys.stderr)
-    if platform == "cpu":
-        # tokens/sec/chip needs a chip: a CPU figure under that name is
-        # how three host numbers once entered the record as device ones
-        print("bench worker: JAX found no accelerator; refusing to measure "
-              "tokens/sec/chip on the CPU", file=sys.stderr)
-        sys.exit(1)
-
-    from __graft_entry__ import flagship_one_chip
-    from learning_at_home_tpu.models.transformer import DMoETransformerLM
-    from learning_at_home_tpu.parallel.mesh import batch_sharding, make_mesh
-
-    mesh = make_mesh({"expert": 1}, devices=jax.devices()[:1])
-    # ONE recipe definition, shared with chip_smoke.py and the driver
-    _, cfg, optimizer, recipe_batch = flagship_one_chip(mesh)
-    opt_name = "fused"
-    if "BENCH_SCAN" in os.environ or "BENCH_STACK" in os.environ:
-        scan = os.environ.get("BENCH_SCAN", "0") == "1"
-        # scan requires the stacked param layout; default stack to follow
-        # scan so BENCH_SCAN=1 alone reproduces the round-2 scan recipe
-        stack = os.environ.get("BENCH_STACK", "1" if scan else "0") == "1"
-        cfg = dataclasses.replace(cfg, scan_layers=scan, stack_layers=stack)
-    if os.environ.get("BENCH_REMAT_POLICY"):
-        # "full" is the measured winner at batch 176; "dots" saves matmul
-        # outputs (fewer recompute FLOPs, more activation HBM)
-        cfg = dataclasses.replace(
-            cfg, remat_policy=os.environ["BENCH_REMAT_POLICY"]
-        )
-    if os.environ.get("BENCH_EXPERTS"):
-        cfg = dataclasses.replace(cfg, num_experts=int(os.environ["BENCH_EXPERTS"]))
-    if os.environ.get("BENCH_CE"):
-        # "fused" = Pallas streaming-LSE CE (ops/fused_ce.py)
-        cfg = dataclasses.replace(cfg, ce_impl=os.environ["BENCH_CE"])
-    model = DMoETransformerLM(cfg, mesh)  # construct ONCE, overrides merged
-
-    if os.environ.get("BENCH_OPT"):
-        opt_name = os.environ["BENCH_OPT"]
-        if opt_name not in ("adafactor", "adamw", "fused"):
-            raise ValueError(
-                f"BENCH_OPT must be adafactor|adamw|fused, got {opt_name!r}"
-            )
-        if opt_name == "adafactor":
-            optimizer = optax.adafactor(1e-3)
-        elif opt_name == "adamw":
-            optimizer = optax.adamw(1e-3)
-
-    # Analytic batch selection against the device's own memory limit: the
-    # allocator thrashes near capacity in ways an OOM probe would only
-    # find by wasting the run (no-remat batch 64 passed a 10.5 GB estimate
-    # yet ran 845 ms/step).
-    accum = int(os.environ.get("BENCH_ACCUM", "1"))
-    budget = 0.75 * hbm_bytes(device)
-    static_b = _static_state_bytes(model, optimizer)
-    if accum > 1:
-        # the accum path keeps a param-sized f32 gradient-sum tree live
-        # across microbatches (~8.6 GB at the bf16 flagship, decisive on
-        # a 16 GB chip)
-        abstract_params = jax.eval_shape(
-            model.init_params, jax.random.PRNGKey(0)
-        )
-        static_b += 4 * sum(
-            l.size for l in jax.tree_util.tree_leaves(abstract_params)
-        )
-    if os.environ.get("BENCH_BATCH"):
-        batch = int(os.environ["BENCH_BATCH"])
-    else:
-        # Candidates are measured, not purely analytic.  With remat the
-        # sweep plateaus at ~150k tok/s by the recipe's batch (208 is
-        # equal within noise).  Non-remat sweep for reference: 56→99.8k,
-        # 60→101.9k, 64→19.4k (cliff).
-        batch = next(
-            (b for b in (recipe_batch, 144, 112, 56, 32, 16, 8, 4)
-             if static_b + _activation_bytes(cfg, b) <= budget),
-            None,
-        )
-        if batch is None:  # nothing fits: fail BEFORE touching HBM
-            print(f"bench worker: static state alone is {static_b / 1e9:.1f} "
-                  f"GB vs budget {budget / 1e9:.1f} GB; nothing fits",
-                  file=sys.stderr)
-            sys.exit(1)
-    est_gb = (static_b + _activation_bytes(cfg, batch)) / 1e9
-    print(f"bench worker: batch={batch} accum={accum} (estimated peak "
-          f"{est_gb:.1f} GB, budget {budget / 1e9:.1f} GB, opt={opt_name})",
-          file=sys.stderr)
-
-    params = model.init_params(jax.random.PRNGKey(0))
-    opt_state = model.init_opt_state(optimizer, params)
-    step = model.make_train_step(optimizer, accum_steps=accum)
-    sharding = batch_sharding(mesh)
-    if accum > 1:  # leading microbatch axis is unsharded (matches the step)
-        from jax.sharding import NamedSharding, PartitionSpec as P
-
-        sharding = NamedSharding(mesh, P(None, *sharding.spec))
-    rs = np.random.RandomState(0)
-
-    data_shape = (
-        (accum, batch, cfg.seq_len) if accum > 1 else (batch, cfg.seq_len)
-    )
-    ids = jax.device_put(
-        jnp.asarray(rs.randint(0, cfg.vocab_size, data_shape)), sharding
-    )
-    tgt = jax.device_put(
-        jnp.asarray(rs.randint(0, cfg.vocab_size, data_shape)), sharding
-    )
-
-    params, opt_state, loss, _ = step(params, opt_state, ids, tgt)
-    jax.block_until_ready((params, opt_state, loss))
-
-    n_steps = 20
-    t0 = time.perf_counter()
-    for _ in range(n_steps):
-        params, opt_state, loss, metrics = step(params, opt_state, ids, tgt)
-    jax.block_until_ready((params, opt_state, loss))
-    elapsed = time.perf_counter() - t0
-
-    tokens_per_step = accum * batch * cfg.seq_len
-    tps = tokens_per_step * n_steps / elapsed
-    step_s = elapsed / n_steps
-    result = {
-        "metric": "DMoE-Transformer training throughput "
-        f"({cfg.num_experts} experts, d_model={cfg.d_model}, "
-        f"L={cfg.n_layers}, seq={cfg.seq_len}, batch={batch}"
-        + (f"x{accum}" if accum > 1 else "")
-        + f", top-{cfg.k})",
-        "value": round(tps, 1),
-        "unit": "tokens/sec/chip",
-        "vs_baseline": round(tps / BASELINE_TPS[platform], 3)
-        if platform in BASELINE_TPS else 1.0,
-        "platform": platform,
-        "device_kind": device.device_kind,
-        "device_count": len(jax.devices()),
-        "step_ms": round(1000 * step_s, 2),
-        "optimizer": opt_name,
-        "final_loss": round(float(loss), 4),
-        "dropped_fraction": round(float(metrics["dropped_fraction"]), 4),
-        "mfu": round(
-            _model_flops_per_step(cfg, accum * batch) / step_s
-            / peak_bf16_flops(device), 4
-        ),
-        "hbm_peak_gb": round(
-            device.memory_stats()["peak_bytes_in_use"] / 1e9, 2
-        ),
-    }
-
-    # The MAIN number is safe from here on: print it NOW, so that if the
-    # optional balanced variant below blows the faulthandler deadline the
-    # log still holds this line (a variant that finishes re-prints an
-    # augmented copy, and the parent takes the LAST JSON line).
-    print(json.dumps(result), flush=True)
-
-    # Balanced-routing regime ([BJ]: real training sits at dropped < 0.25,
-    # not the init-router 0.41 of random tokens): router jitter spreads
-    # near-identical rows and the aux loss gets ~30 steps to act, then 10
-    # timed steps report tok/s in that regime.
-    t_used = time.perf_counter() - t_start
-    if (
-        os.environ.get("BENCH_BALANCED", "1") == "1"
-        and deadline - t_used > 150
-    ):
-        result["balanced"] = _balanced_variant(
-            cfg, mesh, optimizer, batch, batch_sharding(mesh),
-        )
-        print(json.dumps(result), flush=True)
-    faulthandler.cancel_dump_traceback_later()
-
-
-def _balanced_variant(cfg, mesh, optimizer, batch, sharding,
-                      balance_steps: int = 30, timed_steps: int = 10) -> dict:
-    """tok/s + dropped_fraction with router_jitter 0.1 + aux 5e-2 after
-    ``balance_steps`` balance-training steps (the round-2 recipe that
-    reaches dropped 0.15-0.23 on the flagship at 30 steps)."""
-    import dataclasses
-
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from learning_at_home_tpu.models.transformer import DMoETransformerLM
-
-    bcfg = dataclasses.replace(
-        cfg, router_jitter=0.1, aux_loss_weight=5e-2
-    )
-    bmodel = DMoETransformerLM(bcfg, mesh)
-    params = bmodel.init_params(jax.random.PRNGKey(0))
-    opt_state = bmodel.init_opt_state(optimizer, params)
-    step = bmodel.make_train_step(optimizer)
-    rs = np.random.RandomState(1)
-    ids = jax.device_put(
-        jnp.asarray(rs.randint(0, bcfg.vocab_size, (batch, bcfg.seq_len))),
-        sharding,
-    )
-    tgt = jax.device_put(
-        jnp.asarray(rs.randint(0, bcfg.vocab_size, (batch, bcfg.seq_len))),
-        sharding,
-    )
-    for _ in range(balance_steps):  # let the aux loss balance the router
-        params, opt_state, loss, metrics = step(params, opt_state, ids, tgt)
-    jax.block_until_ready((params, loss))
-    t0 = time.perf_counter()
-    for _ in range(timed_steps):
-        params, opt_state, loss, metrics = step(params, opt_state, ids, tgt)
-    jax.block_until_ready((params, loss))
-    step_s = (time.perf_counter() - t0) / timed_steps
-    return {
-        "regime": f"router_jitter=0.1 aux=5e-2, {balance_steps} balance steps",
-        "tokens_per_sec": round(batch * bcfg.seq_len / step_s, 1),
-        "step_ms": round(1000 * step_s, 2),
-        "dropped_fraction": round(float(metrics["dropped_fraction"]), 4),
-    }
 
 
 # --------------------------------------------------------------------------
@@ -2319,9 +1954,6 @@ def run_averaging_microbench(deadline: int = 240) -> dict | None:
 
 
 if __name__ == "__main__":
-    if "--worker" in sys.argv:
-        worker()
-        sys.exit(0)
     if "--dispatch-worker" in sys.argv:
         dispatch_worker()
         sys.exit(0)

@@ -115,12 +115,6 @@ def parse_args():
         "--aux-weight", type=float, default=1e-2,
         help="pod mode: load-balance auxiliary loss weight",
     )
-    p.add_argument(
-        "--gating", choices=("topk", "expert_choice"), default="topk",
-        help="pod mode: token-choice top-k (capacity drops) or "
-        "expert-choice (each expert picks top-C tokens; balanced by "
-        "construction, no jitter/aux needed)",
-    )
     p.add_argument("--averaging", action="store_true",
                    help="swarm mode: decentralized trunk/gate parameter "
                         "averaging across trainers (DHT-matched group "
@@ -225,7 +219,6 @@ def run_pod(args):
         param_dtype=jnp.bfloat16 if args.param_dtype == "bf16" else jnp.float32,
         router_jitter=args.router_jitter,
         aux_loss_weight=args.aux_weight,
-        gating=args.gating,
     )
     from learning_at_home_tpu.parallel.mesh import data_axes
 

@@ -284,6 +284,7 @@ class DispatchFuture:
         ``inflight_dispatches`` gauge."""
         self.cancelled = True
         self._cf.cancel()
+        self._finalize = None
         if not self.joined:
             self.joined = True
             if self._on_join_exit is not None:
@@ -317,6 +318,11 @@ class DispatchFuture:
         if self.joined:
             raise RuntimeError(f"{self.kind} DispatchFuture joined twice")
         self.joined = True
+        # a consumed future keeps nothing alive: the finalizer's closure
+        # holds the dispatch's buffers and (forward's) this future itself,
+        # a cycle that would hold every dispatch's replies until the
+        # collector's next full pass
+        finalize, self._finalize = self._finalize, None
         deadline = timeout if timeout is not None else self._join_timeout
         t_block = time.monotonic()
         try:
@@ -344,7 +350,7 @@ class DispatchFuture:
             self.blocked_s = time.monotonic() - t_block
             if self._on_join_exit is not None:
                 self._on_join_exit(self)
-        return self._finalize(results)
+        return finalize(results)
 
 
 def client_loop() -> BackgroundLoop:

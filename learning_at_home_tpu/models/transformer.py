@@ -62,10 +62,6 @@ class DMoETransformerConfig:
     # sequence-layout equivalence; trainers opt in (train_lm
     # --router-jitter).
     router_jitter: float = 0.0
-    # 'topk' (token-choice, capacity drops) or 'expert_choice' (each
-    # expert picks top-C tokens; perfectly balanced, no aux loss; routing
-    # depends on the batch — see ops.moe_dispatch.expert_choice_gating)
-    gating: str = "topk"
     # 'xla' = jax.nn.dot_product_attention (materializes [B,H,S,S]);
     # 'flash' = the TPU Pallas blocked kernel (splash attention under a
     # causal mask: O(S) memory) at the tiles trunk.flash_block_sizes gives
@@ -77,12 +73,6 @@ class DMoETransformerConfig:
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
     remat: bool = False
-    # remat granularity: "full" saves only each layer's input and
-    # recomputes ALL internals in backward; "dots" saves matmul outputs
-    # (jax.checkpoint_policies.dots_with_no_batch_dims_saveable) and
-    # recomputes only the cheap elementwise chains — fewer recompute
-    # FLOPs for more activation HBM
-    remat_policy: str = "full"
     # True: lax.scan over stacked layer params (ONE compiled layer body —
     # HLO size and compile time ÷ L).  False: unrolled Python loop over
     # static slices of the SAME stacked params — L inlined bodies, but
@@ -109,16 +99,6 @@ class DMoETransformerConfig:
     # token-chunk size for the rematerialized cross-entropy (peak logits
     # memory = ce_chunk × vocab × 4 bytes; see loss_fn)
     ce_chunk: int = 1024
-    # "chunked" (default): checkpointed [ce_chunk, V] scan.  "fused": the
-    # Pallas streaming-LSE kernel (ops/fused_ce.py) — logits never touch
-    # HBM; multi-device meshes run it per-shard under shard_map (no seq
-    # parallelism).  A shape or mesh the kernel cannot take raises.
-    ce_impl: str = "chunked"
-    # fused-CE tile sizes (row tile, vocab tile); vocab tile must divide
-    # V and be a multiple of 128 (lane dim), row tile must divide the
-    # (per-shard) token count
-    ce_block_n: int = 128
-    ce_block_v: int = 1024
     # ---- the block's shape: an architecture's description, not tuning
     # switches.  The defaults are the DMoE-Transformer of the seed paper;
     # OLMoE is rmsnorm / rope / qk_norm / gated_silu of width 1024 /
@@ -187,10 +167,6 @@ class DMoETransformerLM:
                     config.seq_len, config.d_model // config.n_heads,
                 ),
             )
-        if config.ce_impl not in ("chunked", "fused"):
-            raise ValueError(
-                f"ce_impl must be 'chunked' or 'fused', got {config.ce_impl!r}"
-            )
         if config.norm not in ("layernorm", "rmsnorm"):
             raise ValueError(
                 f"norm must be 'layernorm' or 'rmsnorm', got {config.norm!r}"
@@ -222,7 +198,6 @@ class DMoETransformerLM:
             dtype=config.dtype,
             param_dtype=config.param_dtype,
             router_jitter=config.router_jitter,
-            gating=config.gating,
             ffn_dim=config.expert_ffn_dim,
             expert_kind=config.expert_kind,
             routing=config.routing,
@@ -389,18 +364,7 @@ class DMoETransformerLM:
                 ].astype(cfg.dtype)
         layer_fn = self._layer
         if cfg.remat:
-            if cfg.remat_policy == "dots":
-                layer_fn = jax.checkpoint(
-                    layer_fn,
-                    policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
-                )
-            elif cfg.remat_policy == "full":
-                layer_fn = jax.checkpoint(layer_fn)
-            else:
-                raise ValueError(
-                    f"remat_policy must be 'full' or 'dots', got "
-                    f"{cfg.remat_policy!r}"
-                )
+            layer_fn = jax.checkpoint(layer_fn)
 
         def body(x, lp_idx):
             lp, idx = lp_idx
@@ -483,50 +447,19 @@ class DMoETransformerLM:
     # ---- autoregressive decoding ----
 
     def decode_model(self) -> "DMoETransformerLM":
-        """The model to EVALUATE/DECODE with — identical weights, eval-safe
-        routing.
-
-        Two train-time routing behaviors cannot be reproduced
-        autoregressively and are switched off here:
-
-        - ``gating='expert_choice'``: each expert picks its top-C tokens
-          *of the batch*, so routing is batch-dependent (the documented
-          causality leak in ``ops.moe_dispatch.expert_choice_gating``).
-          At decode there is no batch to pick from — with one live token,
-          capacity clamps to 1 and EVERY expert would select that token,
-          a regime the router never saw in training.  Decode therefore
-          falls back to token-choice top-k over the same gate affinities
-          (the expert-choice paper's own inference recipe is a learned
-          router/top-k approximation; plain top-k is the zero-extra-state
-          version).  Expect a quality gap vs teacher-forced eval — the
-          training CE of an expert-choice model includes routing that
-          decode cannot see (BASELINE.md notes this on the CE-parity row).
-        - ``router_jitter``: selection noise is a training-only
-          regularizer; decode routes on clean gates.
+        """The model to EVALUATE/DECODE with: identical weights, routing on
+        clean gates.  ``router_jitter`` is a training-only regularizer
+        (selection noise), so a jittered model decodes through a twin with
+        jitter 0; a clean model is its own decode model.
 
         Memoized: repeated ``generate()`` calls must reuse the same twin
         (and hence its compiled-decoder cache).
         """
-        cfg = self.cfg
-        changed = {}
-        if cfg.gating == "expert_choice":
-            import logging
-
-            logging.getLogger(__name__).warning(
-                "expert_choice routing is batch-dependent and cannot be "
-                "reproduced at autoregressive decode; falling back to "
-                "token-choice top-%d routing over the same gate "
-                "affinities (see DMoETransformerLM.decode_model)",
-                cfg.k,
-            )
-            changed["gating"] = "topk"
-        if cfg.router_jitter:
-            changed["router_jitter"] = 0.0
-        if not changed:
+        if not self.cfg.router_jitter:
             return self
         if self._decode_model is None:
             self._decode_model = DMoETransformerLM(
-                dataclasses.replace(self.cfg, **changed), self.mesh
+                dataclasses.replace(self.cfg, router_jitter=0.0), self.mesh
             )
         return self._decode_model
 
@@ -545,7 +478,7 @@ class DMoETransformerLM:
         Returns [B, P + max_new_tokens].  Each step re-runs the full
         forward over the fixed-length buffer (static shapes for XLA) —
         the straightforward eval path, not a KV-cache serving stack.
-        Routing follows :meth:`decode_model` (token-choice, no jitter).
+        Routing follows :meth:`decode_model` (no jitter).
 
         Right-padding is masked out of MoE routing via ``token_mask``:
         causality makes padding inert for *attention*, but capacity
@@ -797,88 +730,14 @@ class DMoETransformerLM:
 
     # ---- loss / train step ----
 
-    def _fused_ce(self, x, head, targets):
-        """Mean CE via the Pallas streaming-LSE kernel (ops/fused_ce.py).
-
-        ``ce_impl="fused"`` is a request for THIS kernel: when one of its
-        constraints does not hold this raises with the reason — it never
-        quietly runs another loss.  The kernel compiles natively on a TPU
-        mesh and runs in the Pallas interpreter on any other (the CPU
-        test meshes); which one is read off the mesh's own devices.
-
-        Multi-device meshes without seq parallelism run the kernel
-        per-shard under ``shard_map``: each device computes CE for its
-        own batch rows against a replicated head (the kernel's dhead
-        cotangent is psum-reduced by the shard_map transpose).  A
-        ring-sharded sequence is refused — the flat token axis would
-        interleave shards."""
-        from learning_at_home_tpu.ops.fused_ce import _check, fused_softmax_ce
-        from learning_at_home_tpu.parallel.mesh import data_axes
-
-        bn, bv = self.cfg.ce_block_n, self.cfg.ce_block_v
-        interpret = self.mesh.devices.flat[0].platform != "tpu"
-        b, s, d = x.shape
-        n = b * s
-        if self.mesh.shape.get("seq", 1) > 1:
-            raise ValueError(
-                "ce_impl='fused' does not support a sequence-parallel mesh "
-                f"(seq={self.mesh.shape['seq']}); use ce_impl='chunked'"
-            )
-        da = data_axes(self.mesh)
-        n_shards = 1
-        for a in da:
-            n_shards *= self.mesh.shape[a]
-        if b % n_shards:
-            raise ValueError(
-                f"ce_impl='fused': batch {b} does not divide over the "
-                f"mesh's {n_shards} token shards"
-            )
-        n_loc = (b // n_shards) * s
-        # the kernel's own predicate, applied to the per-shard shapes, so
-        # the reason surfaces here and not as a trace error in shard_map
-        err = _check(
-            jax.ShapeDtypeStruct((n_loc, d), x.dtype), head,
-            jax.ShapeDtypeStruct((n_loc,), jnp.int32), bn, bv,
-        )
-        if err is not None:
-            raise ValueError(f"ce_impl='fused': {err}")
-        if self.mesh.devices.size == 1:
-            ce_rows = fused_softmax_ce(
-                x.reshape(n, d), head, targets.reshape(n), bn, bv, interpret
-            )
-            return ce_rows.sum() / n
-
-        from jax import shard_map
-
-        def _local_ce(xl, hl, tl):
-            bl, sl, dl = xl.shape
-            ce_l = fused_softmax_ce(
-                xl.reshape(bl * sl, dl), hl, tl.reshape(bl * sl),
-                bn, bv, interpret,
-            )
-            return ce_l.reshape(bl, sl)
-
-        ce_bs = shard_map(
-            _local_ce,
-            mesh=self.mesh,
-            in_specs=(P(da, None, None), P(None, None), P(da, None)),
-            out_specs=P(da, None),
-            check_vma=False,  # custom_vjp inside has no varying-axes rule
-        )(x, head, targets)
-        return ce_bs.sum() / n
-
     def loss_fn(
         self, params: Params, token_ids: jax.Array, targets: jax.Array
     ) -> tuple[jax.Array, dict]:
-        """Training loss: mean next-token CE (computed by ``ce_impl``)
+        """Training loss: mean next-token CE (:meth:`_chunked_ce`)
         plus the weighted router aux and z losses."""
         x, aux = self._hidden(params, token_ids)
         with jax.named_scope("ce"):
-            head = self._head(params)
-            if self.cfg.ce_impl == "fused":
-                ce = self._fused_ce(x, head, targets)
-            else:
-                ce = self._chunked_ce(x, head, targets)
+            ce = self._chunked_ce(x, self._head(params), targets)
         loss = (
             ce
             + self.cfg.aux_loss_weight * aux["aux_loss"]

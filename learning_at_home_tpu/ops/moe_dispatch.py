@@ -9,8 +9,8 @@ dropping straggler experts (SURVEY.md §7 "k-of-n inside a collective").
 
 All shapes are static (XLA requirement): for ``n`` tokens, ``E`` experts,
 capacity ``C``, the dispatch/combine tensors are ``[n, E, C]``.  The
-one-hot formulation matmuls cleanly onto the MXU; a Pallas kernel can
-replace it later if profiling shows it dominating (SURVEY.md §7 M5).
+one-hot formulation matmuls cleanly onto the MXU; the index form moves
+rows instead, and ``choose_dispatch_impl`` picks between them by shape.
 """
 
 from __future__ import annotations
@@ -327,90 +327,6 @@ def combine_outputs_indexed(y: jax.Array, plan: IndexDispatchPlan) -> jax.Array:
     picked = y_flat[jnp.clip(slots, 0, None)]  # [n, k, d]
     # plan.weights is already zero wherever slots == -1 (set at plan build)
     return jnp.einsum("nk,nkd->nd", plan.weights.astype(y.dtype), picked)
-
-
-# ---- expert-choice routing (Zhou et al. 2022, public technique) ----
-
-
-class ExpertChoicePlan(NamedTuple):
-    """Expert-choice routing decision: each EXPERT picks its top-C tokens.
-
-    Dual of token-choice top-k: capacity overflow is impossible by
-    construction (every expert processes exactly C tokens), so there is
-    no load-balance auxiliary loss and no drop-by-capacity.  A token may
-    be picked by zero experts — ``uncovered_fraction`` tracks that; those
-    tokens pass through the residual unchanged.
-    """
-
-    token_for_slot: jax.Array  # [E, C] int32 — NEVER -1 (always filled)
-    weights: jax.Array  # [E, C] float — affinity of expert e for its c-th pick
-    uncovered_fraction: jax.Array  # [] fraction of tokens picked by no expert
-
-
-def expert_choice_gating(
-    logits: jax.Array, capacity: int, token_mask: jax.Array | None = None
-) -> ExpertChoicePlan:
-    """Each expert selects its top-``capacity`` tokens by gate affinity.
-
-    logits: [n, E].  Affinity is the token's softmax-over-experts mass on
-    this expert (the expert-choice paper's S = softmax(X·Wg, experts),
-    selection per expert over tokens).  Average experts-per-token =
-    E*C/n, the analogue of token-choice k.
-
-    ``token_mask`` [n] bool: padding tokens sort behind every real token
-    (affinity forced to -1 < 0 < softmax mass) and any that still get
-    picked — possible only when capacity exceeds the real-token count —
-    carry weight 0, so they never perturb real outputs.
-
-    NB (documented property, not a bug): selection for token i depends on
-    the OTHER tokens in the shard — for causal LM training this leaks a
-    small amount of future information through routing, a known property
-    of expert choice; use token-choice gating where strict causality of
-    the routing itself matters.
-    """
-    n, num_experts = logits.shape
-    # top_k needs capacity <= n; small shards (or k*cap_factor > E) would
-    # otherwise fail at trace time where token-choice works fine
-    capacity = min(capacity, n)
-    gates = jax.nn.softmax(logits, axis=-1)  # [n, E] over experts
-    aff = gates.T  # [E, n]
-    if token_mask is not None:
-        aff = jnp.where(token_mask[None, :], aff, -1.0)
-    top_w, top_i = jax.lax.top_k(aff, capacity)  # per expert
-    if token_mask is not None:
-        top_w = jnp.maximum(top_w, 0.0)  # picked padding → zero weight
-    covered = (
-        jnp.zeros((n,), jnp.int32).at[top_i.reshape(-1)].add(1, mode="drop")
-    )
-    if token_mask is None:
-        uncovered = 1.0 - (covered > 0).sum().astype(jnp.float32) / n
-    else:
-        real = jnp.maximum(token_mask.sum().astype(jnp.float32), 1.0)
-        uncovered = 1.0 - ((covered > 0) & token_mask).sum() / real
-    return ExpertChoicePlan(
-        top_i.astype(jnp.int32), top_w, uncovered
-    )
-
-
-def dispatch_tokens_expert_choice(
-    x: jax.Array, plan: ExpertChoicePlan
-) -> jax.Array:
-    """[n, d] → [E, C, d]: every slot is a real token (no empties)."""
-    e, c = plan.token_for_slot.shape
-    return x[plan.token_for_slot.reshape(-1)].reshape(e, c, x.shape[-1])
-
-
-def combine_outputs_expert_choice(
-    y: jax.Array, plan: ExpertChoicePlan, n_tokens: int
-) -> jax.Array:
-    """[E, C, d] → [n, d]: affinity-weighted scatter-add over picks."""
-    e, c, d = y.shape
-    w = plan.weights.reshape(-1, 1).astype(y.dtype)
-    return (
-        jnp.zeros((n_tokens, d), y.dtype)
-        .at[plan.token_for_slot.reshape(-1)]
-        .add(w * y.reshape(e * c, d), mode="drop")
-    )
 
 
 # ---- dropless routing: sort by expert, grouped matmul, unsort ----

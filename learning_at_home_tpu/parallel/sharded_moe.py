@@ -36,11 +36,8 @@ from jax import shard_map
 from learning_at_home_tpu.ops.moe_dispatch import (
     choose_dispatch_impl,
     combine_outputs,
-    combine_outputs_expert_choice,
     combine_outputs_indexed,
     compute_capacity,
-    dispatch_tokens_expert_choice,
-    expert_choice_gating,
     dispatch_tokens,
     dispatch_tokens_indexed,
     dropless_routing,
@@ -101,7 +98,6 @@ class ShardedMixtureOfExperts:
         param_dtype: Any = jnp.float32,
         dispatch_impl: str = "auto",
         router_jitter: float = 0.0,
-        gating: str = "topk",
         ffn_dim: int | None = None,
         expert_kind: str = "gelu",
         routing: str = "capacity",
@@ -112,17 +108,6 @@ class ShardedMixtureOfExperts:
                 "dispatch_impl must be 'auto', 'gather' or 'onehot', "
                 f"got {dispatch_impl!r}"
             )
-        if gating not in ("topk", "expert_choice"):
-            raise ValueError(
-                f"gating must be 'topk' or 'expert_choice', got {gating!r}"
-            )
-        if gating == "expert_choice" and router_jitter:
-            raise ValueError(
-                "router_jitter applies only to token-choice top-k gating; "
-                "expert_choice is balanced by construction — pass "
-                "router_jitter=0 (a silently ignored setting would make "
-                "recipe comparisons lie)"
-            )
         if expert_kind not in ("gelu", "gated_silu"):
             raise ValueError(
                 f"expert_kind must be 'gelu' or 'gated_silu', got "
@@ -132,10 +117,10 @@ class ShardedMixtureOfExperts:
             raise ValueError(
                 f"routing must be 'capacity' or 'dropless', got {routing!r}"
             )
-        if routing == "dropless" and (gating != "topk" or router_jitter):
+        if routing == "dropless" and router_jitter:
             raise ValueError(
-                "routing='dropless' is token-choice top-k on clean gates: "
-                "gating must be 'topk' and router_jitter 0"
+                "routing='dropless' is top-k on clean gates: "
+                "router_jitter must be 0"
             )
         if "expert" not in mesh.axis_names:
             raise ValueError("mesh must have an 'expert' axis")
@@ -173,11 +158,6 @@ class ShardedMixtureOfExperts:
         # ops.moe_dispatch.router_jitter) — breaks routing collapse when
         # many rows are near-identical (byte-level data near init)
         self.router_jitter = router_jitter
-        # 'topk' = token-choice with capacity dropping; 'expert_choice' =
-        # each expert picks its top-C tokens (perfectly balanced, no aux
-        # loss, no capacity drops; routing is batch-dependent — see
-        # ops.moe_dispatch.expert_choice_gating for the causality note)
-        self.gating = gating
         self.expert_kind = expert_kind
         self.routing = routing
         self.renormalize = renormalize
@@ -301,11 +281,6 @@ class ShardedMixtureOfExperts:
         capacity = compute_capacity(
             n_local, self.num_experts, self.k, self.capacity_factor
         )
-        if self.gating == "expert_choice":
-            # expert-choice selects top-C TOKENS per expert, so C can
-            # never exceed the shard's token count; clamping HERE keeps
-            # the all_to_all reshapes consistent with the plan shape
-            capacity = min(capacity, n_local)
 
         in_specs = [
             self.param_specs(),
@@ -357,9 +332,7 @@ class ShardedMixtureOfExperts:
             logits = (
                 x.astype(compute) @ params["gate"].astype(compute)
             ).astype(jnp.float32)
-            if self.gating == "expert_choice":
-                plan = expert_choice_gating(logits, capacity, token_mask)
-            elif impl == "gather":
+            if impl == "gather":
                 plan = top_k_gating_indices(
                     logits, self.k, capacity, self.renormalize,
                     jitter=self.router_jitter,
@@ -373,9 +346,7 @@ class ShardedMixtureOfExperts:
                 )
         # 2) my tokens to their experts' devices
         with jax.named_scope("moe_dispatch"):
-            if self.gating == "expert_choice":
-                x_send = dispatch_tokens_expert_choice(x.astype(compute), plan)
-            elif impl == "gather":
+            if impl == "gather":
                 x_send = dispatch_tokens_indexed(x.astype(compute), plan)
             else:
                 x_send = dispatch_tokens(x.astype(compute), plan)  # [E, C, d]
@@ -424,29 +395,17 @@ class ShardedMixtureOfExperts:
             y_recv = jax.lax.all_to_all(
                 y_send, "expert", split_axis=0, concat_axis=0, tiled=False
             ).reshape(self.num_experts, capacity, d)
-            if self.gating == "expert_choice":
-                y = combine_outputs_expert_choice(
-                    y_recv, plan, x.shape[0]
-                ).astype(x.dtype)
-            elif impl == "gather":
+            if impl == "gather":
                 y = combine_outputs_indexed(y_recv, plan).astype(x.dtype)
             else:
                 y = combine_outputs(y_recv, plan).astype(x.dtype)
 
         axes = self._shard
         router_z = _router_z_loss(logits, token_mask)
-        if self.gating == "expert_choice":
-            # perfectly balanced by construction: no balance auxiliary;
-            # "dropped_fraction" reports tokens selected by NO expert
-            aux_loss = jnp.float32(0)
-            dropped = plan.uncovered_fraction
-        else:
-            aux_loss = plan.aux_loss
-            dropped = plan.dropped_fraction
         aux = {
-            "aux_loss": jax.lax.pmean(aux_loss, axes),
+            "aux_loss": jax.lax.pmean(plan.aux_loss, axes),
             "router_z_loss": jax.lax.pmean(router_z, axes),
-            "dropped_fraction": jax.lax.pmean(dropped, axes),
+            "dropped_fraction": jax.lax.pmean(plan.dropped_fraction, axes),
         }
         return y, aux
 

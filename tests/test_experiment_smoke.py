@@ -141,19 +141,6 @@ def test_generate_lm_smoke():
 
 
 @pytest.mark.slow
-def test_decode_gap_eval_smoke():
-    (out,) = run_script(
-        ["experiments/decode_gap_eval.py", "--steps", "6",
-         "--eval-batches", "2", "--batch-size", "8", "--seq-len", "32",
-         "--d-model", "32", "--num-experts", "8", "--skip-control"],
-        timeout=300,
-    )
-    assert out["gating"] == "expert_choice"
-    assert out["eval_ce_training_routing"] > 0
-    assert "decode_gap_nats" in out
-
-
-@pytest.mark.slow
 def test_train_lm_multi_trainer_averaging_convergence(tmp_path):
     """ISSUE 3 acceptance: with ``--averaging`` on, a 2-trainer swarm
     smoke ends with trunk+gate parameters EQUAL across trainers.

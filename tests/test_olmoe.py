@@ -335,7 +335,7 @@ def test_cached_decode_matches_the_full_forward(tiny):
     np.testing.assert_array_equal(np.asarray(full), np.asarray(cached))
 
 
-# ---- dmoe256 is the program it was ----
+# ---- the cells' programs are the programs they were ----
 
 # sha256 of make_train_step(...).lower(...).as_text() of
 # flagship_one_chip(tiny=True) on a one-device CPU mesh, taken on the
@@ -343,17 +343,40 @@ def test_cached_decode_matches_the_full_forward(tiny):
 DMOE_TINY_STEP_SHA256 = (
     "80bdf4b59a2b64a8496795126dde40ecb9732b772fb0fef07eb25447a243b65b"
 )
+# the same recipe on data=2 x expert=2 (dmoe256-train-pod4's program) and
+# olmoe_one_chip(tiny=True) on one device (olmoe-1b-7b-train-zipf4k's),
+# both taken on the parent commit of PR 29 (f320cf8), same jax
+DMOE_TINY_POD4_STEP_SHA256 = (
+    "d99d70146066f3d9f2086167beaab8ceff3d8dd7a960d2d619fe38d4984e3909"
+)
+OLMOE_TINY_STEP_SHA256 = (
+    "f43045fb35c4364f1075c0221363e421970378ed58fe880f456456dbdc9dde0b"
+)
 
 
-def test_dmoe256_lowered_step_is_text_identical_to_the_parents():
-    """The block's shape became part of the configuration; under its
-    defaults the seed paper's model lowers to the same StableHLO, letter
-    for letter.  A change that is MEANT to alter that program updates the
-    hash with the reason."""
+@pytest.mark.parametrize(
+    "recipe, axes, sha256",
+    [
+        (flagship_one_chip, {"expert": 1}, DMOE_TINY_STEP_SHA256),
+        (flagship_one_chip, {"data": 2, "expert": 2}, DMOE_TINY_POD4_STEP_SHA256),
+        (olmoe_one_chip, {"expert": 1}, OLMOE_TINY_STEP_SHA256),
+    ],
+    ids=["dmoe-one-chip", "dmoe-pod4", "olmoe-one-chip"],
+)
+def test_dmoe256_lowered_step_is_text_identical_to_the_parents(
+    recipe, axes, sha256
+):
+    """The three programs the train cells run lower to the same
+    StableHLO, letter for letter, as on the commit their hash was taken
+    on: what a PR takes out of the pod step (PR 27 made the block's shape
+    part of the configuration; PR 29 deleted the forks beside the path)
+    was not on the path.  A change that is MEANT to alter a program
+    updates its hash with the reason."""
     from learning_at_home_tpu.parallel.mesh import opt_state_shardings
 
-    mesh = _one_device_mesh()
-    model, cfg, opt, batch = flagship_one_chip(mesh, tiny=True)
+    n_dev = int(np.prod(list(axes.values())))
+    mesh = make_mesh(axes, devices=jax.devices()[:n_dev])
+    model, cfg, opt, batch = recipe(mesh, tiny=True)
     shape = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
     shard = model.param_shardings(shape)
 
@@ -370,7 +393,74 @@ def test_dmoe256_lowered_step_is_text_identical_to_the_parents():
         (batch, cfg.seq_len), jnp.int32, sharding=batch_sharding(mesh)
     )
     text = model.make_train_step(opt).lower(p, o, ids, ids).as_text()
-    assert hashlib.sha256(text.encode()).hexdigest() == DMOE_TINY_STEP_SHA256
+    assert hashlib.sha256(text.encode()).hexdigest() == sha256
+
+
+# ---- the layout the cells run is the layout the CPU tests default to ----
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
+@pytest.mark.parametrize(
+    "recipe", [flagship_one_chip, olmoe_one_chip], ids=["dmoe", "olmoe"]
+)
+def test_unrolled_tuples_equal_the_scanned_stack(recipe, remat):
+    """Every cell runs unrolled per-layer tuples under ``remat``; the CPU
+    tests default to one scanned body over stacked layers.  From the same
+    weights both layouts give one loss and one set of gradients, with and
+    without ``jax.checkpoint`` around the layer (under which the dropless
+    block's row gathers replay their ``custom_vjp``)."""
+    mesh = _one_device_mesh()
+    _, cfg, _, batch = recipe(mesh, tiny=True)
+    scanned = DMoETransformerLM(
+        dataclasses.replace(
+            cfg, scan_layers=True, stack_layers=True, remat=False
+        ),
+        mesh,
+    )
+    unrolled = DMoETransformerLM(
+        dataclasses.replace(
+            cfg, scan_layers=False, stack_layers=False, remat=remat
+        ),
+        mesh,
+    )
+    stacked = _decisive(scanned.init_params(jax.random.PRNGKey(5)))
+    as_tuples = dict(
+        stacked,
+        layers=tuple(
+            jax.tree_util.tree_map(lambda l: l[i], stacked["layers"])
+            for i in range(cfg.n_layers)
+        ),
+    )
+    rs = np.random.RandomState(9)
+    ids = jnp.asarray(rs.randint(0, cfg.vocab_size, (batch, cfg.seq_len + 1)))
+
+    def loss_and_grads(model, params):
+        return jax.jit(
+            jax.value_and_grad(
+                lambda p: model.loss_fn(p, ids[:, :-1], ids[:, 1:])[0]
+            )
+        )(params)
+
+    want, want_grads = loss_and_grads(scanned, stacked)
+    got, got_grads = loss_and_grads(unrolled, as_tuples)
+    got_grads = dict(
+        got_grads,
+        layers=jax.tree_util.tree_map(
+            lambda *ls: jnp.stack(ls), *got_grads["layers"]
+        ),
+    )
+    # float32 throughout and the same operations in another program
+    # structure: what differs is the order of a few f32 additions
+    np.testing.assert_allclose(float(got), float(want), rtol=1e-6)
+    for (path, g), w in zip(
+        jax.tree_util.tree_flatten_with_path(got_grads)[0],
+        jax.tree_util.tree_leaves(want_grads),
+    ):
+        np.testing.assert_allclose(
+            np.asarray(g), np.asarray(w), rtol=1e-4,
+            atol=1e-6 * float(jnp.abs(w).max()),
+            err_msg=jax.tree_util.keystr(path),
+        )
 
 
 # ---- the chip's compiler accepts a layer at published widths ----

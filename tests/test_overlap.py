@@ -199,6 +199,46 @@ def test_backward_reuses_forward_session_rows():
     reset_client_rpc()
 
 
+@pytest.mark.parametrize("kind", ["forward", "backward"])
+def test_joined_dispatch_is_freed_by_reference_count(kind):
+    """A joined dispatch leaves no cycle behind: its future, and with it
+    the replies and the inputs its finalizer holds, go when the caller
+    drops it, not at the collector's next full pass (a trainer that fires
+    2048-row dispatches back to back would otherwise hold 33 MB more for
+    every dispatch until then)."""
+    import gc
+    import weakref
+
+    with background_server(
+        num_experts=2, hidden_dim=D, expert_prefix="ffn", seed=3
+    ) as (endpoint, srv):
+        source = StaticExpertSource({u: endpoint for u in srv.experts})
+        moe = RemoteMixtureOfExperts(
+            in_features=D, grid_size=(2,), uid_prefix="ffn", source=source,
+            k_best=2, k_min=1, timeout_after_k_min=30.0,
+        )
+        gate = moe.init_gate_params(jax.random.PRNGKey(0))
+        x = np.random.RandomState(0).randn(4, D).astype(np.float32)
+        lc = x @ np.asarray(gate["w0"])
+        gc.collect()
+        gc.disable()
+        try:
+            fut = moe.dispatch_async(x, lc)
+            _, _, _, cid = fut.join()
+            if kind == "backward":
+                with moe._sessions_lock:
+                    session, fwd_dropped, trace = moe._sessions.pop(int(cid))
+                gy = np.ones((4, moe.k_best, D), np.float32)
+                fut = moe.backward_async(session, fwd_dropped, trace, gy)
+                assert fut.join().shape == (4, D)
+            ref = weakref.ref(fut)
+            del fut
+            assert ref() is None, "a joined dispatch is held by a cycle"
+        finally:
+            gc.enable()
+    reset_client_rpc()
+
+
 def test_stalled_pool_join_times_out_cleanly(monkeypatch):
     """ISSUE 7 satellite: a stalled pool (accepts, never replies, ignores
     its own RPC timeout) under the future-based path must make the join

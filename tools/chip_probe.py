@@ -12,21 +12,10 @@ subcommand is one process (a chip belongs to one process), prints one
 ``VERDICT`` line per question, and exits non-zero if any question got
 the wrong answer or no TPU was found.
 
-- ``kernels``: each Pallas path compiled by Mosaic (``interpret=False``)
-  at the flagship's own shapes and compared with its XLA reference.
-  Tolerance, fixed before the first run: normalized max error
-  ``max|a-b| / max|b|`` against an exact (``Precision.HIGHEST``)
-  reference of at most 2e-3 for f32 operands and 2e-2 for bf16 operands.
-  The first run (PR 21) missed both bars — and showed the bar was set
-  against the wrong thing: on the MXU, XLA's own default-precision CE,
-  the path the kernel replaces, sits just as far from exact (f32
-  operands are multiplied in bf16 passes by XLA and Mosaic alike).  So
-  the probe also measures that XLA default path against exact, reports
-  whether the preset bar was met, and answers ``ok`` when the kernel is
-  within the bar OR no further from exact than twice the XLA default.
-  (The exact reference takes the operands upcast to f32: with bf16
-  operands the chunked scan sums its per-chunk dhead contributions in
-  bf16, which made the first "exact" dhead less exact than the kernel's.)
+- ``kernels``: each Pallas path of the train step compiled by Mosaic
+  (``interpret=False``) and compared with its XLA reference: today the
+  blocked attention kernel at seq 8192, whose step-0 loss must sit
+  within 2e-2 relative of the xla core's (bf16 operands).
 - ``client``: can a process that HOLDS the TPU run
   ``RemoteMixtureOfExperts`` forward+grad under ``jit`` (``io_callback``
   inside ``custom_vjp``) against an expert server on the CPU?
@@ -47,7 +36,6 @@ import time
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-TOL = {"float32": 2e-3, "bfloat16": 2e-2}
 FAILED: list[str] = []
 
 
@@ -88,147 +76,9 @@ def _random_ids(rs, cfg, batch: int, mesh):
     )
 
 
-def _nerr(a, b) -> float:
-    import jax.numpy as jnp
-
-    a, b = a.astype(jnp.float32), b.astype(jnp.float32)
-    return float(jnp.abs(a - b).max() / jnp.abs(b).max())
-
-
 # --------------------------------------------------------------------------
 # kernels
 # --------------------------------------------------------------------------
-
-
-def probe_fused_ce(dtype_name: str) -> None:
-    """fused_softmax_ce forward + both backward kernels at the flagship's
-    n = 176*256, d = 512, V = 32768, blocks 128/1024."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-    import optax
-
-    from learning_at_home_tpu.ops.fused_ce import fused_softmax_ce
-
-    name = f"fused_ce[{dtype_name}]"
-    n, d, v, bn, bv, chunk = 176 * 256, 512, 32768, 128, 1024, 1024
-    dtype = jnp.dtype(dtype_name)
-    rs = np.random.RandomState(0)
-    x = jnp.asarray(rs.randn(n, d), dtype)
-    head = jnp.asarray(rs.randn(d, v) / np.sqrt(d), dtype)
-    t = jnp.asarray(rs.randint(0, v, n), jnp.int32)
-    w = jnp.asarray(rs.rand(n) + 0.5, jnp.float32)  # non-uniform dce
-
-    def xla_rows(precision):  # [chunk, V] logits at a time, like loss_fn
-        def rows(x, head):
-            def body(_, xt):
-                xc, tc = xt
-                logits = jnp.einsum(
-                    "nd,dv->nv", xc, head, precision=precision,
-                    preferred_element_type=jnp.float32,
-                )
-                return 0, optax.softmax_cross_entropy_with_integer_labels(
-                    logits, tc
-                )
-
-            _, ce = jax.lax.scan(
-                jax.checkpoint(body), 0,
-                (x.reshape(n // chunk, chunk, d),
-                 t.reshape(n // chunk, chunk)),
-            )
-            return ce.reshape(n)
-
-        return rows
-
-    def fused_rows(x, head):
-        return fused_softmax_ce(x, head, t, bn, bv, False)
-
-    def both(rows_fn):
-        def loss(x, head):
-            ce = rows_fn(x, head)
-            return jnp.sum(ce * w) / n, ce
-
-        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1), has_aux=True))
-
-    try:
-        t0 = time.perf_counter()
-        (lf, ce_f), (dx_f, dh_f) = jax.block_until_ready(
-            both(fused_rows)(x, head)
-        )
-        compile_s = time.perf_counter() - t0
-    except Exception as e:  # the verdict IS the compiler's refusal
-        refused(name, e)
-        return
-    # exact: the same operand VALUES upcast to f32, so that nothing —
-    # not the per-chunk dhead contributions either — is rounded to bf16
-    exact = both(xla_rows(jax.lax.Precision.HIGHEST))(
-        x.astype(jnp.float32), head.astype(jnp.float32)
-    )
-    default = both(xla_rows(None))(x, head)  # what _chunked_ce runs
-
-    def errs(got):
-        (_, ce), (dx, dh) = got
-        (_, ce_e), (dx_e, dh_e) = exact
-        return {"ce": _nerr(ce, ce_e), "dx": _nerr(dx, dx_e),
-                "dhead": _nerr(dh, dh_e)}
-
-    kernel, xla = errs(((lf, ce_f), (dx_f, dh_f))), errs(default)
-    tol = TOL[dtype_name]
-    verdict(
-        name,
-        all(kernel[k] <= max(tol, 2 * xla[k]) for k in kernel),
-        shapes=f"n={n} d={d} V={v} blocks {bn}/{bv}",
-        kernel_err_vs_exact={k: float(f"{e:.3g}") for k, e in kernel.items()},
-        xla_default_err_vs_exact={k: float(f"{e:.3g}") for k, e in xla.items()},
-        preset_tolerance=tol,
-        within_preset_tolerance=max(kernel.values()) <= tol,
-        loss=[float(lf), float(exact[0][0])],
-        compile_and_run_s=round(compile_s, 1),
-    )
-
-
-def probe_fused_ce_train_step() -> None:
-    """ce_impl='fused' through the flagship's real train step (one-chip
-    recipe): loss must match the chunked step's at step 0."""
-    import dataclasses
-
-    import jax
-    import numpy as np
-
-    from __graft_entry__ import flagship_one_chip
-    from learning_at_home_tpu.models.transformer import DMoETransformerLM
-    from learning_at_home_tpu.parallel.mesh import make_mesh
-
-    name = "fused_ce[train_step]"
-    mesh = make_mesh({"expert": 1}, devices=jax.devices()[:1])
-    _, cfg, optimizer, batch = flagship_one_chip(mesh)
-    rs = np.random.RandomState(0)
-    ids, tgt = _random_ids(rs, cfg, batch, mesh), _random_ids(rs, cfg, batch, mesh)
-    out = {}
-    try:
-        for impl in ("chunked", "fused"):
-            model = DMoETransformerLM(
-                dataclasses.replace(cfg, ce_impl=impl), mesh
-            )
-            params = model.init_params(jax.random.PRNGKey(0))
-            opt_state = model.init_opt_state(optimizer, params)
-            step = model.make_train_step(optimizer)
-            times = []
-            for _ in range(4):
-                t0 = time.perf_counter()
-                params, opt_state, loss, _ = step(params, opt_state, ids, tgt)
-                jax.block_until_ready((params, loss))
-                times.append(round(1e3 * (time.perf_counter() - t0), 1))
-                out.setdefault(impl, {"losses": []})["losses"].append(
-                    round(float(loss), 4)
-                )
-            out[impl]["step_ms_after_compile"] = times[1:]
-            del params, opt_state, step
-    except Exception as e:
-        refused(name, e)
-        return
-    l_c, l_f = out["chunked"]["losses"][0], out["fused"]["losses"][0]
-    verdict(name, abs(l_c - l_f) <= 2e-2 * abs(l_c), **out)
 
 
 def probe_flash() -> None:
@@ -279,47 +129,10 @@ def probe_flash() -> None:
     )
 
 
-def probe_pallas_dispatch() -> None:
-    """dispatch_tokens_pallas at n = 4096, slots = 10240, d = 512."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from learning_at_home_tpu.ops import (
-        dispatch_tokens_indexed,
-        top_k_gating_indices,
-    )
-    from learning_at_home_tpu.ops.pallas_dispatch import dispatch_tokens_pallas
-
-    name = "pallas_dispatch"
-    n, d, experts, k, cap = 4096, 512, 256, 2, 40  # 256 * 40 = 10240 slots
-    rs = np.random.RandomState(0)
-    x = jnp.asarray(rs.randn(n, d), jnp.bfloat16)
-    logits = jnp.asarray(rs.randn(n, experts), jnp.float32)
-    plan = top_k_gating_indices(logits, k=k, capacity=cap)
-    try:
-        out = jax.block_until_ready(
-            dispatch_tokens_pallas(x, plan, interpret=False)
-        )
-    except Exception as e:
-        refused(name, e)
-        return
-    ref = dispatch_tokens_indexed(x, plan)
-    verdict(
-        name, bool(jnp.array_equal(out, ref)),
-        shapes=f"n={n} slots={experts * cap} d={d} bf16",
-        exact_match_with_gather=bool(jnp.array_equal(out, ref)),
-    )
-
-
 def kernels(only: str = "") -> None:
     """All kernel probes, or those whose name contains ``only``."""
     require_tpu()
     probes = {
-        "pallas_dispatch": probe_pallas_dispatch,
-        "fused_ce_bfloat16": lambda: probe_fused_ce("bfloat16"),
-        "fused_ce_float32": lambda: probe_fused_ce("float32"),
-        "fused_ce_train_step": probe_fused_ce_train_step,
         "flash": probe_flash,
     }
     for name, probe in probes.items():
