@@ -15,12 +15,14 @@ rows instead, and ``choose_dispatch_impl`` picks between them by shape.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import math
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.xla_metadata import set_xla_metadata
 
 
 class DispatchPlan(NamedTuple):
@@ -422,17 +424,109 @@ def sort_tokens(x: jax.Array, plan: DroplessPlan) -> jax.Array:
     return _rows_to_sorted(x, plan.order, plan.inverse, plan.weights.shape[1])
 
 
+# The weights' gradient of a grouped matmul: rows [m, a] and row cotangents
+# [m, b], contracted over the ragged rows, a group at a time → [G, a, b]
+# (the operation autodiff makes of ``ragged_dot``'s second operand).
+WEIGHTS_GRADIENT = jax.lax.RaggedDotDimensionNumbers(
+    dot_dimension_numbers=(([0], [0]), ([], [])),
+    lhs_ragged_dimensions=[0],
+    rhs_group_dimensions=[],
+)
+
+# the fewest rows tools/grouped_matmul_probe.py measured (the tiles below
+# won at every row count from here to the cell's 131,072): under it the
+# compiler's own 512 x 512 x 512 stays
+GROUPED_MATMUL_MIN_ROWS = 512
+
+
+def grouped_matmul_tiles(
+    m: int, k: int, n: int, dtype, weights_gradient: bool = False
+) -> tuple[int, int, int] | None:
+    """Tile sizes ``(tm, tk, tn)`` of one grouped-matmul call on the TPU
+    (over the rows ``m``, the lhs's other dimension ``k``, the rhs's last
+    dimension ``n``), read from the call's shape; ``None``: no attribute,
+    the compiler's own (512, 512, 512).
+
+    A call ``[m, k] x [G, k, n] → [m, n]`` (the forward call, and the
+    rows' gradient with ``k`` and ``n`` exchanged) holds in VMEM, twice
+    each, a row tile ``[tm, tk]``, a weight tile ``[tk, tn]`` and a result
+    tile ``[tm, tn]``, and a float32 ``[tm, tn]`` accumulator unless
+    ``tk`` is all of ``k``.  Fastest (v5e, PERF.md section 6, PR 30):
+    256 rows, because every group boundary inside a row tile costs one
+    more visit of the whole tile and real loads put 63 of them anywhere;
+    and a weight tile of 2 Mi elements, which at OLMoE's widths is an
+    expert's whole matrix: fetched once a group, no accumulator.  The
+    weights' gradient ``[m, k], [m, n] → [G, k, n]`` holds its result tile
+    ``[tk, tn]`` twice and again in float32, so half as large a tile.  Of
+    16 MB of VMEM both take 10 to 12 (a tile of twice the size is refused).
+
+    ``None`` where a tile would not divide its dimension, for operands
+    other than bf16 (the tiles were measured, and their VMEM counted, at
+    two bytes an element) and under ``GROUPED_MATMUL_MIN_ROWS`` rows."""
+    if jnp.dtype(dtype) != jnp.bfloat16 or m < GROUPED_MATMUL_MIN_ROWS:
+        return None
+    tk = min(k, 1024 if weights_gradient else 2048)
+    tn = min(n, (1 << 20 if weights_gradient else 1 << 21) // tk)
+    if m % 256 or k % tk or n % tn:
+        return None
+    return 256, tk, tn
+
+
+def ragged_dot_tiling(tiles: tuple[int, int, int] | None):
+    """Context under which a ``ragged_dot`` is traced with the frontend
+    attribute ``ragged_dot_tiling``, which the TPU compiler reads as its
+    kernel's tile sizes (the instruction stays ``ragged-dot-none.<n>``);
+    no attribute for ``None``.  Other backends ignore the attribute."""
+    if tiles is None:
+        return contextlib.nullcontext()
+    return set_xla_metadata(ragged_dot_tiling=",".join(map(str, tiles)))
+
+
+@jax.custom_vjp
 def grouped_matmul(
     lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array
 ) -> jax.Array:
     """lhs [m, a] (rows grouped as ``group_sizes`` says) x rhs [G, a, b] →
     [m, b]: row i meets the matrix of its group.  ``jax.lax.ragged_dot``:
-    one native grouped-matmul call on the TPU (dense operation count
-    whatever the group sizes), with its own gradients; any row count, any
-    platform."""
-    return jax.lax.ragged_dot(
-        lhs, rhs, group_sizes, preferred_element_type=lhs.dtype
-    )
+    one native grouped-matmul call on the TPU, any row count, any
+    platform.  The operation count is dense whatever the group sizes:
+    2·m·a·b, as every row meets exactly one matrix.
+
+    On the TPU the call and its two gradients (the two operations autodiff
+    makes of a ``ragged_dot``, written out so that each sits under its own
+    attribute) run at the tiles ``grouped_matmul_tiles`` reads from their
+    shapes; results on other backends are bitwise ``ragged_dot``'s."""
+    m, a = lhs.shape
+    with ragged_dot_tiling(grouped_matmul_tiles(m, a, rhs.shape[-1], lhs.dtype)):
+        return jax.lax.ragged_dot(
+            lhs, rhs, group_sizes, preferred_element_type=lhs.dtype
+        )
+
+
+def _grouped_matmul_fwd(lhs, rhs, group_sizes):
+    return grouped_matmul(lhs, rhs, group_sizes), (lhs, rhs, group_sizes)
+
+
+def _grouped_matmul_bwd(residuals, g):
+    lhs, rhs, group_sizes = residuals
+    (m, a), b = lhs.shape, rhs.shape[-1]
+    # the weights' gradient first, as autodiff orders the two
+    with ragged_dot_tiling(
+        grouped_matmul_tiles(m, a, b, lhs.dtype, weights_gradient=True)
+    ):
+        d_rhs = jax.lax.ragged_dot_general(
+            lhs, g, group_sizes, WEIGHTS_GRADIENT,
+            preferred_element_type=rhs.dtype,
+        )
+    rhs_t = jnp.swapaxes(rhs, 1, 2)
+    with ragged_dot_tiling(grouped_matmul_tiles(m, b, a, g.dtype)):
+        d_lhs = jax.lax.ragged_dot(
+            g, rhs_t, group_sizes, preferred_element_type=lhs.dtype
+        )
+    return d_lhs, d_rhs, None
+
+
+grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)
 
 
 def unsort_combine(ys: jax.Array, plan: DroplessPlan) -> jax.Array:

@@ -6,8 +6,10 @@ Tiny sizes on the CPU, except one AOT compile of a layer at published
 widths for a described (not attached) ``v5e`` chip.
 """
 
+import collections
 import contextlib
 import dataclasses
+import functools
 import hashlib
 import os
 import re
@@ -28,7 +30,11 @@ import harness  # noqa: E402  (benchmarks/harness.py: imports no jax)
 from __graft_entry__ import flagship_one_chip, olmoe_one_chip  # noqa: E402
 from learning_at_home_tpu.models import transformer, trunk  # noqa: E402
 from learning_at_home_tpu.models.transformer import DMoETransformerLM  # noqa: E402
-from learning_at_home_tpu.ops.moe_dispatch import dropless_routing  # noqa: E402
+from learning_at_home_tpu.ops.moe_dispatch import (  # noqa: E402
+    dropless_routing,
+    grouped_matmul,
+    grouped_matmul_tiles,
+)
 from learning_at_home_tpu.parallel.mesh import batch_sharding, make_mesh  # noqa: E402
 from learning_at_home_tpu.parallel.sharded_moe import ShardedMixtureOfExperts  # noqa: E402
 
@@ -335,6 +341,144 @@ def test_cached_decode_matches_the_full_forward(tiny):
     np.testing.assert_array_equal(np.asarray(full), np.asarray(cached))
 
 
+# ---- the grouped matmul's tiles are read from the call's shape ----
+
+CELL_ROWS = 4 * 4096 * 8  # olmoe-1b-7b-train-zipf4k: tokens a step x experts a token
+
+
+@pytest.mark.parametrize(
+    "call, tiles",
+    [
+        # gate and up, and the rows' gradient of down: an expert's whole
+        # matrix is the weight tile
+        ((CELL_ROWS, 2048, 1024, jnp.bfloat16), (256, 2048, 1024)),
+        # down, and the rows' gradient of gate and up
+        ((CELL_ROWS, 1024, 2048, jnp.bfloat16), (256, 1024, 2048)),
+        ((CELL_ROWS, 2048, 1024, jnp.bfloat16, True), (256, 1024, 1024)),
+        ((CELL_ROWS, 1024, 2048, jnp.bfloat16, True), (256, 1024, 1024)),
+        # narrower than a tile: the tile is the dimension
+        ((CELL_ROWS, 512, 256, jnp.bfloat16), (256, 512, 256)),
+        # wider: 2 Mi elements of weights a tile, 1 Mi for their gradient
+        ((8192, 4096, 4096, jnp.bfloat16), (256, 2048, 1024)),
+        ((8192, 512, 4096, jnp.bfloat16, True), (256, 512, 2048)),
+        # 1536 = 1.5 x 1024, 33,000 = 128.9 x 256: a tile does not divide
+        ((CELL_ROWS, 2048, 1536, jnp.bfloat16), None),
+        ((33000, 2048, 1024, jnp.bfloat16), None),
+        # float32 operands were not measured and take twice the VMEM
+        ((CELL_ROWS, 2048, 1024, jnp.float32), None),
+        # the tiny recipe's (olmoe_one_chip(tiny=True): 64 tokens x 8, float32)
+        ((512, 32, 64, jnp.float32), None),
+        # fewer rows than were measured
+        ((256, 2048, 1024, jnp.bfloat16, True), None),
+    ],
+    ids=["gate-up", "down", "gate-up-weights", "down-weights", "narrow",
+         "wide", "wide-weights", "n-not-divided", "m-not-divided", "float32", "tiny", "few-rows"],
+)
+def test_grouped_matmul_tiles_are_read_from_the_shape(call, tiles):
+    assert grouped_matmul_tiles(*call) == tiles
+
+
+def _call_and_gradients(fn, x, w, g, sizes):
+    """(result, rows' gradient, weights' gradient) of ``fn(x, w, sizes)``."""
+    out, vjp = jax.vjp(lambda x, w: fn(x, w, sizes), x, w)
+    return (out,) + vjp(g)
+
+
+def _compiled_tilings(text):
+    """The tiles the compiled grouped-matmul instructions carry."""
+    return set(re.findall(
+        r'%ragged-dot-none[^\n]*ragged_dot_tiling="([\d,]+)"', text))
+
+
+def test_every_grouped_matmul_of_a_layer_carries_its_tiles():
+    """One layer of the recipe at the cell's sizes, traced from shapes
+    and lowered for the TPU platform (nothing compiles, nothing runs):
+    the 3 forward grouped matmuls, the 3 rows' gradients and the 3
+    weights' gradients each carry the ``ragged_dot_tiling`` that
+    ``grouped_matmul_tiles`` reads from their shape, all sit under scope
+    ``experts``, and no other operation carries the attribute."""
+    model, cfg, _, batch = olmoe_one_chip(_one_device_mesh())
+    lp = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))["layers"][0]
+    x = jax.ShapeDtypeStruct((batch, cfg.seq_len, cfg.d_model), cfg.dtype)
+
+    def layer_loss(lp, x):
+        y, aux = model._layer(lp, x, 0)
+        return (y.astype(jnp.float32) ** 2).mean() + aux["aux_loss"]
+
+    text = (
+        jax.jit(jax.value_and_grad(layer_loss, argnums=(0, 1)))
+        .trace(lp, x).lower(lowering_platforms=("tpu",))
+        .as_text(debug_info=True)
+    )
+    where = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
+    carrying = [line for line in text.splitlines() if "ragged_dot_tiling" in line]
+    calls = [line for line in text.splitlines() if '"chlo.ragged_dot"' in line]
+    assert len(calls) == 9 and carrying == calls
+    seen = collections.Counter()
+    for line in calls:
+        tiles = tuple(map(int, re.search(
+            r'ragged_dot_tiling = "([\d,]+)"', line).group(1).split(",")))
+        (m, a), _, out = (
+            tuple(map(int, dims.split("x")))
+            for dims in re.findall(r"tensor<([\dx]+)xbf16>", line)
+        )
+        path = where[re.search(r"loc\((#loc\d+)\)", line).group(1)]
+        assert "/experts/" in path, path
+        weights = len(out) == 3  # [m, a], [m, b] -> [G, a, b]
+        assert tiles == grouped_matmul_tiles(
+            m, a, out[-1], jnp.bfloat16, weights_gradient=weights
+        ), line
+        kind = "weights" if weights else "rows" if "transpose(" in path else "forward"
+        seen[kind, path.split("/")[-2]] += 1
+    assert seen == {
+        (kind, scope): count
+        for kind in ("forward", "rows", "weights")
+        for scope, count in (("gate_up", 2), ("down", 1))
+    }
+
+
+def _group_sizes(how, groups, m):
+    if how == "uniform":
+        return np.full(groups, m // groups, np.int32)
+    sizes = np.full(groups, 3, np.int32)  # one group holds nearly every row
+    sizes[2] = 0
+    sizes[1] = m - sizes.sum() + 3
+    return sizes
+
+
+@pytest.mark.parametrize("how", ["uniform", "collapsed"])
+@pytest.mark.parametrize(
+    "m, a, b, dtype",
+    [(8192, 512, 256, jnp.bfloat16), (96, 32, 16, jnp.float32)],
+    ids=["tiled", "tiny"],
+)
+def test_grouped_matmul_and_its_gradients_are_ragged_dots(m, a, b, dtype, how):
+    """On the CPU the attribute means nothing: result, rows' gradient and
+    weights' gradient are ``jax.lax.ragged_dot``'s, bit for bit, at a
+    shape that has tiles and at one that has none, with a group of no
+    rows among them."""
+    assert (grouped_matmul_tiles(m, a, b, dtype) is not None) == (m == 8192)
+    sizes = jnp.asarray(_group_sizes(how, 8, m))
+    assert int(sizes.sum()) == m
+    keys = jax.random.split(jax.random.PRNGKey(5), 3)
+    x = jax.random.normal(keys[0], (m, a), jnp.float32).astype(dtype)
+    w = jax.random.normal(keys[1], (8, a, b), jnp.float32).astype(dtype)
+    g = jax.random.normal(keys[2], (m, b), jnp.float32).astype(dtype)
+
+    def plain(x, w, sizes):
+        return jax.lax.ragged_dot(x, w, sizes, preferred_element_type=x.dtype)
+
+    got, want = jax.jit(lambda *args: tuple(
+        _call_and_gradients(fn, *args) for fn in (grouped_matmul, plain)
+    ))(x, w, g, sizes)
+    for name, a_, b_ in zip(("result", "rows", "weights"), got, want):
+        assert a_.dtype == b_.dtype == dtype
+        np.testing.assert_array_equal(
+            np.asarray(a_.astype(jnp.float32)), np.asarray(b_.astype(jnp.float32)),
+            err_msg=name,
+        )
+
+
 # ---- the cells' programs are the programs they were ----
 
 # sha256 of make_train_step(...).lower(...).as_text() of
@@ -525,6 +669,10 @@ def test_one_olmoe_layer_compiles_for_v5e_at_published_widths(v5e_chip, monkeypa
         compiled = jax.jit(jax.grad(layer_loss, argnums=(0, 1))).lower(lp, x).compile()
     text = compiled.as_text()
     assert text.count("ragged-dot-none") >= 9  # 3 forward, 6 backward
+    # the compiler took the tiles it was handed (its own 512,512,512 is
+    # nowhere), and they fit VMEM: a refused setting fails the compile
+    assert _compiled_tilings(text) == {
+        "256,2048,1024", "256,1024,2048", "256,1024,1024"}
     # forward and the fused backward, under the scope (the kernel writes a
     # newline into its call's attributes, so the instruction's name and
     # its op_name sit on different lines of the text)
@@ -558,6 +706,33 @@ def test_blocked_attention_compiles_for_v5e_at_its_tiles(v5e_chip, monkeypatch, 
     with _no_compile_cache():
         text = jax.jit(both).lower(x, x, x, x).compile().as_text()
     assert text.count("tpu_custom_call") >= 2  # forward, the fused backward
+
+
+@pytest.mark.parametrize("m, a, b", [
+    (2048, 512, 256),     # the fewest rows that get tiles, narrower than one
+    (8192, 4096, 4096),   # wider than one: an accumulator beside the tiles
+    (4096, 16384, 512),
+    (4096, 512, 16384),
+])
+def test_grouped_matmul_compiles_for_v5e_at_its_tiles(v5e_chip, m, a, b):
+    """The chip's compiler takes the call and both gradients at the tiles
+    ``grouped_matmul_tiles`` gives beyond the cell's widths: a setting
+    that does not fit VMEM fails the compile."""
+    one = jax.sharding.SingleDeviceSharding(v5e_chip)
+    x = jax.ShapeDtypeStruct((m, a), jnp.bfloat16, sharding=one)
+    w = jax.ShapeDtypeStruct((8, a, b), jnp.bfloat16, sharding=one)
+    g = jax.ShapeDtypeStruct((m, b), jnp.bfloat16, sharding=one)
+    sizes = jax.ShapeDtypeStruct((8,), jnp.int32, sharding=one)
+
+    with _no_compile_cache():
+        text = jax.jit(
+            functools.partial(_call_and_gradients, grouped_matmul)
+        ).lower(x, w, g, sizes).compile().as_text()
+    assert _compiled_tilings(text) == {
+        ",".join(map(str, grouped_matmul_tiles(*call)))
+        for call in ((m, a, b, jnp.bfloat16), (m, b, a, jnp.bfloat16),
+                     (m, a, b, jnp.bfloat16, True))
+    }
 
 
 # ---- the benchmark's files for it ----

@@ -1,0 +1,303 @@
+#!/usr/bin/env python3
+"""The dropless expert layer's grouped matmul on the chip: what
+``ops/moe_dispatch.py`` ``grouped_matmul_tiles`` rests on.
+
+    python tools/grouped_matmul_probe.py tiles collapsed   # ragged_dot under the attribute
+    python tools/grouped_matmul_probe.py tiles uniform
+    python tools/grouped_matmul_probe.py tiles collapsed 128   # other row tiles than 256, 512, 1024
+    python tools/grouped_matmul_probe.py gmm collapsed     # megablox at the same tiles
+    python tools/grouped_matmul_probe.py sizes             # fewer rows: where the gain starts
+    python tools/grouped_matmul_probe.py accuracy          # the program's call against float32
+
+One process a subcommand (a chip belongs to one process), one ``ROW``
+line of JSON a reading, written to
+``chiprun_out/grouped_matmul_probe.jsonl`` as well.  Every time is the
+host clock around ``ITERS`` launches that end in ``block_until_ready``,
+after the compile; a setting the compiler refuses is a row with
+``refused``.  PERF.md section 6 ("PR 30") holds the readings the tile
+rule was set from.
+
+The three kinds of call a train step makes of one ``grouped_matmul``
+(``m`` rows in 64 groups, widths ``k`` x ``n``):
+
+- ``forward``:  ``[m, k] x [64, k, n] -> [m, n]``;
+- ``drows``:    the gradient to the rows, ``[m, n] x [64, n, k] -> [m, k]``
+  (the same kind of call on the transposed weights);
+- ``dweights``: the gradient to the weights, ``[m, k], [m, n] -> [64, k, n]``,
+  which contracts over the ragged dimension.
+
+At the OLMoE cell's shapes: ``m`` 131,072, ``k x n`` 2048 x 1024 (gate and
+up) and 1024 x 2048 (down).  Group sizes ``collapsed`` as the cell has
+them (8 of 64 experts hold nearly every row: largest over mean 7.76) or
+``uniform``.
+
+- ``tiles``: ``jax.lax.ragged_dot`` as it is, then under the frontend
+  attribute ``ragged_dot_tiling`` for tm in 256, 512, 1024 and tk, tn in
+  512, 1024, 2048; ``compiled_tiling`` is what the compiled instruction
+  carries.
+- ``gmm``: megablox ``gmm`` (``transpose_rhs`` for ``drows``) and ``tgmm``
+  (with the 537 MB transpose of the rows it needs, and without) at the
+  same tiles.
+- ``sizes``: the default against ``grouped_matmul_tiles``'s choice at 512 ...
+  65,536 rows, both kinds of group sizes: where ``GROUPED_MATMUL_MIN_ROWS``
+  comes from.
+- ``accuracy``: ``grouped_matmul`` (the program's call, its tiles) and
+  plain ``ragged_dot``, result and both gradients, each against float32
+  operands at ``Precision.HIGHEST``: rms of the difference over rms of
+  the exact result.
+"""
+
+from __future__ import annotations
+
+import importlib
+import itertools
+import json
+import os
+import re
+import sys
+import time
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+
+ITERS = 10
+GROUPS, ROWS = 64, 131072  # olmoe-1b-7b-train-zipf4k: experts, 16,384 tokens x 8
+WIDTHS = ((2048, 1024), (1024, 2048))  # gate and up; down
+KINDS = ("forward", "drows", "dweights")
+ROW_TILES, WIDTH_TILES = (256, 512, 1024), (512, 1024, 2048)
+OUT = os.path.join(REPO, "chiprun_out", "grouped_matmul_probe.jsonl")
+
+
+def row(**facts) -> None:
+    line = json.dumps(facts)
+    print("ROW " + line, flush=True)
+    os.makedirs(os.path.dirname(OUT), exist_ok=True)
+    with open(OUT, "a") as f:
+        f.write(line + "\n")
+
+
+def require_tpu():
+    import jax
+
+    d = jax.devices()[0]
+    print(f"# device: {d.platform} [{d.device_kind}] jax {jax.__version__}",
+          flush=True)
+    if d.platform != "tpu":
+        raise SystemExit(f"grouped_matmul_probe needs a TPU, found {d.platform!r}")
+
+
+def group_sizes(how: str, m: int = ROWS):
+    """``collapsed``: 8 experts with 97 % of the rows between them, the
+    rest spread over the other 56 (largest over mean 7.76, as the cell's
+    ``olmoe.expert_load_max_over_mean`` reads); ``uniform``: m / 64 each."""
+    import numpy as np
+
+    if how == "uniform":
+        sizes = np.full(GROUPS, m // GROUPS)
+    else:
+        hot = int(7.76 * m / GROUPS)
+        sizes = np.full(GROUPS, (m - 8 * hot) // (GROUPS - 8))
+        sizes[::8] = hot
+    sizes[-1] += m - sizes.sum()
+    assert sizes.sum() == m and (sizes >= 0).all()
+    return sizes.astype(np.int32)
+
+
+def operands(k: int, n: int, m: int = ROWS):
+    """Unit-variance rows and row cotangents, weights of variance 1 / k."""
+    import jax
+    import jax.numpy as jnp
+
+    kx, kw, kg = jax.random.split(jax.random.PRNGKey(0), 3)
+    x = jax.random.normal(kx, (m, k), jnp.float32).astype(jnp.bfloat16)
+    w = (jax.random.normal(kw, (GROUPS, k, n), jnp.float32) / k ** 0.5).astype(jnp.bfloat16)
+    g = jax.random.normal(kg, (m, n), jnp.float32).astype(jnp.bfloat16)
+    return x, w, g
+
+
+def ragged_call(kind: str, tiles):
+    """One of the three calls through ``jax.lax.ragged_dot``, under the
+    attribute (``tiles``) or as it is (``None``): the operations autodiff
+    makes of ``grouped_matmul``."""
+    import jax
+    import jax.numpy as jnp
+
+    from learning_at_home_tpu.ops.moe_dispatch import (
+        WEIGHTS_GRADIENT,
+        ragged_dot_tiling,
+    )
+
+    def call(x, w, g, sizes):
+        with ragged_dot_tiling(tiles):
+            if kind == "forward":
+                return jax.lax.ragged_dot(x, w, sizes, preferred_element_type=x.dtype)
+            if kind == "drows":
+                return jax.lax.ragged_dot(
+                    g, jnp.swapaxes(w, 1, 2), sizes, preferred_element_type=g.dtype)
+            return jax.lax.ragged_dot_general(
+                x, g, sizes, WEIGHTS_GRADIENT, preferred_element_type=x.dtype)
+
+    return jax.jit(call)
+
+
+def gmm_call(kind: str, tiles, transposed_rows: bool = False):
+    """The same call through megablox.  ``tgmm`` takes the rows as
+    ``[k, m]``: ``transposed_rows`` hands them over so (the kernel
+    alone); otherwise the transpose is inside the timed program."""
+    import jax
+    import jax.numpy as jnp
+    # the package's ``gmm`` is the differentiable wrapper: the kernels'
+    # module has to be asked for by its path
+    mb = importlib.import_module("jax.experimental.pallas.ops.tpu.megablox.gmm")
+
+    def call(x, w, g, sizes):
+        if kind == "forward":
+            return mb.gmm(x, w, sizes, preferred_element_type=x.dtype, tiling=tiles)
+        if kind == "drows":
+            return mb.gmm(g, w, sizes, preferred_element_type=g.dtype, tiling=tiles,
+                          transpose_rhs=True)
+        xt = x if transposed_rows else jnp.swapaxes(x, 0, 1)
+        return mb.tgmm(xt, g, sizes, preferred_element_type=x.dtype, tiling=tiles)
+
+    return jax.jit(call)
+
+
+def program_tiles(kind: str, m: int, k: int, n: int, dtype):
+    """What ``grouped_matmul`` runs that kind of call at."""
+    from learning_at_home_tpu.ops.moe_dispatch import grouped_matmul_tiles
+
+    return grouped_matmul_tiles(
+        *((m, n, k) if kind == "drows" else (m, k, n)), dtype,
+        weights_gradient=kind == "dweights")
+
+
+def timed(what: str, settings: dict, fn, *args) -> None:
+    """Compile ``fn`` for ``args``, read the tiling off the compiled
+    ragged-dot instruction, time ``ITERS`` launches."""
+    import jax
+
+    try:
+        compiled = fn.lower(*args).compile()
+        carried = sorted(set(re.findall(
+            r'%ragged-dot-none[^\n]*ragged_dot_tiling="([0-9,]+)"', compiled.as_text())))
+        jax.block_until_ready(compiled(*args))
+        t0 = time.perf_counter()
+        out = None
+        for _ in range(ITERS):
+            out = compiled(*args)
+        jax.block_until_ready(out)
+        ms = (time.perf_counter() - t0) / ITERS * 1e3
+        facts = dict(ms=round(ms, 3))
+        if carried:
+            facts["compiled_tiling"] = carried
+        row(what=what, **settings, **facts)
+    except Exception as e:  # the compiler's own refusal (VMEM), or the kernel's
+        text = f"{type(e).__name__}: {e}"
+        short = re.search(r"Scoped allocation with size \S+ and limit \S+", text)
+        row(what=what, **settings,
+            refused=short.group(0) if short else text.splitlines()[0][:300])
+
+
+def sweep(what: str, make_call) -> None:
+    """``<groups> [tm ...]``: every setting of the row tiles given (or
+    ``ROW_TILES``) with ``WIDTH_TILES`` for tk and tn."""
+    import jax.numpy as jnp
+
+    require_tpu()
+    how, row_tiles = sys.argv[2], tuple(map(int, sys.argv[3:])) or ROW_TILES
+    settings = list(itertools.product(row_tiles, WIDTH_TILES, WIDTH_TILES))
+    sizes = jnp.asarray(group_sizes(how))
+    for k, n in WIDTHS:
+        x, w, g = operands(k, n)
+        for kind in KINDS:
+            base = dict(kind=kind, groups=how, m=ROWS, k=k, n=n)
+            if what == "ragged_dot":
+                timed(what, dict(base, tiles=None), make_call(kind, None), x, w, g, sizes)
+            for tiles in settings:
+                timed(what, dict(base, tiles=tiles), make_call(kind, tiles), x, w, g, sizes)
+            if what == "gmm" and kind == "dweights":
+                xt = jnp.swapaxes(x, 0, 1)
+                for tiles in settings:
+                    timed("gmm_rows_transposed_before", dict(base, tiles=tiles),
+                          gmm_call(kind, tiles, transposed_rows=True), xt, w, g, sizes)
+
+
+def tiles() -> None:
+    sweep("ragged_dot", ragged_call)
+
+
+def gmm() -> None:
+    sweep("gmm", gmm_call)
+
+
+def sizes() -> None:
+    """Where the gain starts: the compiler's own tiles against
+    ``grouped_matmul_tiles``'s at fewer rows."""
+    import jax.numpy as jnp
+
+    from learning_at_home_tpu.ops import moe_dispatch
+
+    require_tpu()
+    moe_dispatch.GROUPED_MATMUL_MIN_ROWS = 0  # ask the rule below its threshold
+    for m, how in itertools.product((512, 2048, 8192, 32768, 65536), ("uniform", "collapsed")):
+        group = jnp.asarray(group_sizes(how, m))
+        for k, n in WIDTHS:
+            x, w, g = operands(k, n, m)
+            for kind in KINDS:
+                for setting in (None, program_tiles(kind, m, k, n, x.dtype)):
+                    timed("ragged_dot", dict(kind=kind, groups=how, m=m, k=k, n=n,
+                                             tiles=setting),
+                          ragged_call(kind, setting), x, w, g, group)
+
+
+def accuracy() -> None:
+    import jax
+    import jax.numpy as jnp
+
+    from learning_at_home_tpu.ops.moe_dispatch import grouped_matmul
+
+    require_tpu()
+    worst = 0.0
+    for (k, n), how in itertools.product(WIDTHS, ("collapsed", "uniform")):
+        group = jnp.asarray(group_sizes(how))
+        x, w, g = operands(k, n)
+
+        def all_three(fn, x, w, g):
+            out, vjp = jax.vjp(lambda x, w: fn(x, w, group), x, w)
+            return (out,) + vjp(g.astype(out.dtype))
+
+        def plain(x, w, sizes):
+            return jax.lax.ragged_dot(x, w, sizes, preferred_element_type=x.dtype)
+
+        def exact(x, w, sizes):
+            return jax.lax.ragged_dot(x, w, sizes, precision=jax.lax.Precision.HIGHEST)
+
+        got = {
+            "grouped_matmul": jax.jit(lambda *a: all_three(grouped_matmul, *a))(x, w, g),
+            "ragged_dot": jax.jit(lambda *a: all_three(plain, *a))(x, w, g),
+        }
+        want = jax.jit(lambda *a: all_three(exact, *a))(
+            *(a.astype(jnp.float32) for a in (x, w, g)))
+
+        def rel(a, ref):
+            a, ref = a.astype(jnp.float32), ref.astype(jnp.float32)
+            return float(jnp.sqrt(jnp.mean((a - ref) ** 2) / jnp.mean(ref ** 2)))
+
+        for i, kind in enumerate(KINDS):
+            read = dict(
+                grouped_matmul_vs_exact=rel(got["grouped_matmul"][i], want[i]),
+                ragged_dot_vs_exact=rel(got["ragged_dot"][i], want[i]),
+                grouped_matmul_vs_ragged_dot=rel(
+                    got["grouped_matmul"][i], got["ragged_dot"][i]),
+            )
+            worst = max(worst, read["grouped_matmul_vs_exact"])
+            row(what="accuracy", kind=kind, groups=how, m=ROWS, k=k, n=n,
+                tiles=program_tiles(kind, ROWS, k, n, x.dtype), **read)
+    # one bf16 rounding of the result reads 0.17 % rms
+    row(what="accuracy_verdict", ok=worst < 0.005, worst_vs_exact=worst, limit=0.005)
+    if not worst < 0.005:
+        raise SystemExit("grouped_matmul_probe: over 0.5 % from the float32 result")
+
+
+if __name__ == "__main__":
+    {"tiles": tiles, "gmm": gmm, "sizes": sizes, "accuracy": accuracy}[sys.argv[1]]()
