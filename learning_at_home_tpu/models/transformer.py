@@ -42,6 +42,17 @@ Params = Any
 
 
 @dataclasses.dataclass(frozen=True)
+class AttentionLayer:
+    """What one layer's attention is, where layers of a stack differ
+    (``DMoETransformerConfig.layer_pattern``).  ``window``: None = every
+    earlier key (global), w = the w keys that end with the query's own.
+    ``rotary``: whether the layer's queries and keys are rotated."""
+
+    window: int | None = None
+    rotary: bool = True
+
+
+@dataclasses.dataclass(frozen=True)
 class DMoETransformerConfig:
     vocab_size: int = 32768
     d_model: int = 512
@@ -102,16 +113,33 @@ class DMoETransformerConfig:
     # ---- the block's shape: an architecture's description, not tuning
     # switches.  The defaults are the DMoE-Transformer of the seed paper;
     # OLMoE is rmsnorm / rope / qk_norm / gated_silu of width 1024 /
-    # dropless / renormalize False (__graft_entry__.olmoe_one_chip).
+    # dropless / renormalize False (__graft_entry__.olmoe_one_chip);
+    # SmallThinker is rmsnorm (eps 1e-6) / rope (theta 1.5e6) on three
+    # layers in four / 28 heads over 4 key/value heads of 128 / a window of
+    # 4,096 on the same three / gated_relu of width 768 / dropless /
+    # router on the attention's input (smallthinker_one_chip).
     # 'layernorm' (scale and bias) or 'rmsnorm' (scale only)
     norm: str = "layernorm"
+    norm_eps: float = 1e-5
     # 'learned': a [seq_len, d] table added to the embeddings; 'rope':
-    # rotary embedding of every layer's queries and keys, no table
+    # no table, rotary embedding of the queries and keys of every layer
+    # (or of the layers that layer_pattern says)
     positions: str = "learned"
+    rope_theta: float = 10000.0
+    # key/value heads (query head h reads key/value head h // (n_heads /
+    # n_kv_heads)); None = n_heads
+    n_kv_heads: int | None = None
+    # a head's size; None = d_model // n_heads
+    head_dim: int | None = None
+    # where the layers of the stack differ: one AttentionLayer a layer,
+    # or one period of them, repeated; None = every layer global, rotated
+    # where positions == 'rope'
+    layer_pattern: tuple[AttentionLayer, ...] | None = None
     # RMSNorm (own scales) over the whole d-wide query and key
     # projections, before the split into heads
     qk_norm: bool = False
-    # 'gelu' (w1/b1/w2/b2) or 'gated_silu' (w_gate/w_up/w_down, no biases)
+    # 'gelu' (w1/b1/w2/b2), or 'gated_silu' / 'gated_relu'
+    # (w_gate/w_up/w_down, no biases; SiLU or ReLU on the gate branch)
     expert_kind: str = "gelu"
     # an expert's hidden width; None = 4 * d_model
     expert_ffn_dim: int | None = None
@@ -121,6 +149,17 @@ class DMoETransformerConfig:
     # top-k gate weights renormalised to sum to 1, or as the softmax
     # over all experts gives them
     renormalize: bool = True
+    # what the router reads: 'moe_input', the normalized stream the
+    # experts compute on, or 'attention_input', the layer's normalized
+    # input that the attention block reads (a router placed before the
+    # attention: its top-k is known before the attention has run)
+    router_input: str = "moe_input"
+
+    def attention_layer(self, i: int) -> AttentionLayer:
+        """Layer ``i``'s attention."""
+        if self.layer_pattern is None:
+            return AttentionLayer(rotary=self.positions == "rope")
+        return self.layer_pattern[i % len(self.layer_pattern)]
 
 
 # The shortest sequence at which the blocked kernel, at its tuned tiles,
@@ -164,7 +203,8 @@ class DMoETransformerLM:
                 config,
                 attn_impl=auto_attn_impl(
                     jax.default_backend(), mesh.devices.size,
-                    config.seq_len, config.d_model // config.n_heads,
+                    config.seq_len,
+                    config.head_dim or config.d_model // config.n_heads,
                 ),
             )
         if config.norm not in ("layernorm", "rmsnorm"):
@@ -180,6 +220,47 @@ class DMoETransformerLM:
             raise ValueError(
                 "scan_layers=True requires stack_layers=True (lax.scan "
                 "consumes the stacked param pytree)"
+            )
+        if config.router_input not in ("moe_input", "attention_input"):
+            raise ValueError(
+                f"router_input must be 'moe_input' or 'attention_input', "
+                f"got {config.router_input!r}"
+            )
+        kinds = {config.attention_layer(i) for i in range(config.n_layers)}
+        if config.layer_pattern is not None:
+            if config.n_layers % len(config.layer_pattern):
+                raise ValueError(
+                    f"layer_pattern has {len(config.layer_pattern)} entries"
+                    f", which do not divide n_layers={config.n_layers}"
+                )
+            if config.positions != "rope" and any(a.rotary for a in kinds):
+                raise ValueError(
+                    "layer_pattern rotates a layer's queries and keys: "
+                    "positions must be 'rope'"
+                )
+        if config.scan_layers and len(kinds) > 1:
+            raise ValueError(
+                f"scan_layers=True runs ONE traced body for every layer "
+                f"(lax.scan); this layer_pattern has {len(kinds)} kinds of "
+                "layer, and a window or a rotation is part of the traced "
+                "program: run the unrolled loop (scan_layers=False)"
+            )
+        n_kv = config.n_kv_heads or config.n_heads
+        if config.n_heads % n_kv:
+            raise ValueError(
+                f"n_heads={config.n_heads} must be a multiple of "
+                f"n_kv_heads={n_kv}"
+            )
+        # what ring attention and the KV-cache decoder do not take
+        self._grouped_or_windowed = n_kv != config.n_heads or any(
+            a.window is not None for a in kinds
+        )
+        if config.seq_parallel and self._grouped_or_windowed:
+            raise NotImplementedError(
+                "seq_parallel=True (ring attention, parallel/"
+                "ring_attention.py) rotates key/value blocks of as many "
+                "heads as the queries have under a causal mask alone: it "
+                "has no grouped key/value heads and no window"
             )
         self.cfg = config
         self.mesh = mesh
@@ -202,6 +283,7 @@ class DMoETransformerLM:
             expert_kind=config.expert_kind,
             routing=config.routing,
             renormalize=config.renormalize,
+            router_input=config.router_input == "attention_input",
         )
         self._ring = None
         self._zig = self._zig_inv = None
@@ -244,6 +326,8 @@ class DMoETransformerLM:
         compile time by ~L for the 256-expert flagship."""
         cfg = self.cfg
         d, v, s = cfg.d_model, cfg.vocab_size, cfg.seq_len
+        hd = cfg.head_dim or d // cfg.n_heads
+        d_q, d_kv = cfg.n_heads * hd, (cfg.n_kv_heads or cfg.n_heads) * hd
         dense = jax.nn.initializers.lecun_normal()
         embed_init = jax.nn.initializers.normal(1.0 / np.sqrt(d))
         k_embed, k_pos, k_head, k_layers = jax.random.split(rng, 4)
@@ -258,16 +342,16 @@ class DMoETransformerLM:
             ks = jax.random.split(key, 5)
             lp = {
                 "ln1": ln(),
-                "wq": dense(ks[0], (d, d), pdt),
-                "wk": dense(ks[1], (d, d), pdt),
-                "wv": dense(ks[2], (d, d), pdt),
-                "wo": dense(ks[3], (d, d), pdt),
+                "wq": dense(ks[0], (d, d_q), pdt),
+                "wk": dense(ks[1], (d, d_kv), pdt),
+                "wv": dense(ks[2], (d, d_kv), pdt),
+                "wo": dense(ks[3], (d_q, d), pdt),
                 "ln2": ln(),
                 "moe": self.moe.init_params(ks[4], device_put=False),
             }
             if cfg.qk_norm:
-                lp["q_norm"] = {"scale": jnp.ones((d,), pdt)}
-                lp["k_norm"] = {"scale": jnp.ones((d,), pdt)}
+                lp["q_norm"] = {"scale": jnp.ones((d_q,), pdt)}
+                lp["k_norm"] = {"scale": jnp.ones((d_kv,), pdt)}
             return lp
 
         layer_keys = jax.random.split(k_layers, cfg.n_layers)
@@ -308,37 +392,55 @@ class DMoETransformerLM:
 
     def _norm(self, p, x):
         if self.cfg.norm == "rmsnorm":
-            return rms_norm(p, x)
-        return layer_norm(p, x)
+            return rms_norm(p, x, self.cfg.norm_eps)
+        return layer_norm(p, x, self.cfg.norm_eps)
 
-    def _qkv(self, lp, x, positions):
+    def _qkv(self, lp, x, positions, rotary: bool):
         """Finished q, k, v of a layer whose tokens sit at ``positions``
-        [S] (read only where the block is rotary): what every attention
-        core (xla, flash, ring, one-query) takes."""
-        rope = self.cfg.positions == "rope"
+        [S] (read only where the layer is ``rotary``): what every
+        attention core (xla, flash, ring, one-query) takes."""
         return qkv_projections(
             lp, x, self.cfg.n_heads,
-            positions=jnp.asarray(positions, jnp.int32) if rope else None,
+            positions=jnp.asarray(positions, jnp.int32) if rotary else None,
+            rope_theta=self.cfg.rope_theta, norm_eps=self.cfg.norm_eps,
         )
 
-    def _layer(self, lp, x, layer_idx, token_mask=None):
-        with jax.named_scope("attention"):
+    def _layer(self, lp, x, layer_idx, token_mask=None,
+               kind: AttentionLayer | None = None):
+        """One block.  ``kind`` (static) is the layer's attention where
+        the stack's layers differ; None = layer 0's, which every layer of
+        a uniform stack shares (``layer_idx`` may then be traced)."""
+        if kind is None:
+            kind = self.cfg.attention_layer(0)
+        b, s, d = x.shape
+        # where a stack has both kinds, the scope says which this one is
+        scope = "attention" if self.cfg.layer_pattern is None else (
+            "attention/global" if kind.window is None else "attention/window"
+        )
+        with jax.named_scope(scope):
+            attn_in = self._norm(lp["ln1"], x)
             q, k, v = self._qkv(
-                lp, self._norm(lp["ln1"], x),
+                lp, attn_in,
                 # under the zigzag ring the stream is in zigzag order
-                np.arange(x.shape[1]) if self._zig is None else self._zig,
+                np.arange(s) if self._zig is None else self._zig,
+                kind.rotary,
             )
             core = self._ring if self._ring is not None else (
-                lambda q, k, v: attention_core(q, k, v, self.cfg.attn_impl)
+                lambda q, k, v: attention_core(
+                    q, k, v, self.cfg.attn_impl, kind.window
+                )
             )
             x = x + output_projection(lp, core(q, k, v))
-        b, s, d = x.shape
         moe_in = self._norm(lp["ln2"], x).reshape(b * s, d)
         # layer index salts the router jitter: decorrelates the
         # deterministic noise pattern across layers (round-2 advisor)
         moe_out, aux = self.moe(
             lp["moe"], moe_in, jitter_salt=layer_idx,
             token_mask=None if token_mask is None else token_mask.reshape(b * s),
+            router_x=(
+                attn_in.reshape(b * s, d)
+                if self.cfg.router_input == "attention_input" else None
+            ),
         )
         x = x + moe_out.reshape(b, s, d)
         return x, aux
@@ -364,12 +466,13 @@ class DMoETransformerLM:
                 ].astype(cfg.dtype)
         layer_fn = self._layer
         if cfg.remat:
-            layer_fn = jax.checkpoint(layer_fn)
+            # kind is static: a window or a rotation is part of the program
+            layer_fn = jax.checkpoint(layer_fn, static_argnums=(4,))
 
         def body(x, lp_idx):
             lp, idx = lp_idx
             with jax.named_scope("layer"):  # one body for every layer
-                x, aux = layer_fn(lp, x, idx, token_mask)
+                x, aux = layer_fn(lp, x, idx, token_mask, None)
             return x, aux
 
         if self._zig is not None:
@@ -406,7 +509,9 @@ class DMoETransformerLM:
                     else params["layers"][i]
                 )
                 with jax.named_scope(f"layer_{i}"):
-                    x, aux = layer_fn(lp, x, i, token_mask)
+                    x, aux = layer_fn(
+                        lp, x, i, token_mask, cfg.attention_layer(i)
+                    )
                 aux_total = (
                     aux
                     if aux_total is None
@@ -524,6 +629,17 @@ class DMoETransformerLM:
             # buffer and fail at trace time on .at[:, 0]
             return prompt_ids
         if use_cache:
+            if (
+                self._grouped_or_windowed
+                or self.cfg.router_input != "moe_input"
+            ):
+                raise NotImplementedError(
+                    "use_cache=True: the KV-cache decoder keeps one "
+                    "key/value head a query head, masks by position alone "
+                    "and routes on the experts' input: no grouped "
+                    "key/value heads, no window, no router on the "
+                    "attention's input; decode without the cache"
+                )
             if self.cfg.seq_parallel:
                 raise NotImplementedError(
                     "use_cache=True does not compose with seq_parallel "
@@ -640,7 +756,7 @@ class DMoETransformerLM:
         cfg = self.cfg
         b, p = prompt_ids.shape
         s_cache = p + max_new_tokens
-        hd = cfg.d_model // cfg.n_heads
+        hd = cfg.head_dim or cfg.d_model // cfg.n_heads
         if rng is None:
             rng = jax.random.PRNGKey(0)  # unused at temperature == 0
 
@@ -657,7 +773,9 @@ class DMoETransformerLM:
         for i in range(cfg.n_layers):
             lp = self._layer_params(params, i)
             h = self._norm(lp["ln1"], x)
-            q, k, v = self._qkv(lp, h, np.arange(p))
+            q, k, v = self._qkv(
+                lp, h, np.arange(p), cfg.attention_layer(i).rotary
+            )
             # same impl as the full forward: the parity guarantee vs the
             # re-forward decoder must survive flash-attention configs
             x = x + output_projection(
@@ -696,7 +814,9 @@ class DMoETransformerLM:
             for i in range(cfg.n_layers):
                 lp = self._layer_params(params, i)
                 h = self._norm(lp["ln1"], x)
-                q, k, v = self._qkv(lp, h, t[None])
+                q, k, v = self._qkv(
+                    lp, h, t[None], cfg.attention_layer(i).rotary
+                )
                 k_caches[i] = jax.lax.dynamic_update_slice(
                     k_caches[i], k, (0, t, 0, 0)
                 )

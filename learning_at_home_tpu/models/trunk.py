@@ -51,29 +51,34 @@ def rotary(
 def qkv_projections(
     lp: dict, x: jax.Array, n_heads: int,
     positions: jax.Array | None = None,
+    rope_theta: float = 10000.0, norm_eps: float = 1e-5,
 ):
-    """Shared Q/K/V projections: [B,S,d] → three [B,S,H,hd], finished for
-    any attention core.  The block's own parameters say what finishing
-    is: ``q_norm``/``k_norm`` in ``lp`` → RMSNorm over the WHOLE d-wide
-    query and key projections, before the split into heads (OLMoE);
-    ``positions`` [S] → rotary embedding of q and k after it."""
-    b, s, d = x.shape
-    hd = d // n_heads
+    """Shared Q/K/V projections: [B,S,d] → q [B,S,H,hd] and k, v
+    [B,S,Hkv,hd], finished for any attention core.  The block's own
+    parameters say the rest: the head size is ``wq``'s output width over
+    ``n_heads`` (not ``d / n_heads``: 28 heads of 128 leave a stream of
+    2560), the key/value head count ``wk``'s width over the head size
+    (query head h reads key/value head ``h // (H / Hkv)``);
+    ``q_norm``/``k_norm`` in ``lp`` → RMSNorm over the WHOLE query and key
+    projections, before the split into heads (OLMoE); ``positions`` [S] →
+    rotary embedding of q and k after it."""
+    b, s, _ = x.shape
+    hd = lp["wq"].shape[-1] // n_heads
 
     def project(w: str, norm: str | None) -> jax.Array:
         y = x @ lp[w].astype(x.dtype)
         if norm in lp:
             with jax.named_scope("qk_norm"):
-                y = rms_norm(lp[norm], y)
-        return y.reshape(b, s, n_heads, hd)
+                y = rms_norm(lp[norm], y, norm_eps)
+        return y.reshape(b, s, y.shape[-1] // hd, hd)
 
     q = project("wq", "q_norm")
     k = project("wk", "k_norm")
     v = project("wv", None)
     if positions is not None:
         with jax.named_scope("rope"):
-            q = rotary(q, positions)
-            k = rotary(k, positions)
+            q = rotary(q, positions, rope_theta)
+            k = rotary(k, positions, rope_theta)
     return q, k, v
 
 
@@ -86,7 +91,8 @@ def output_projection(lp: dict, out: jax.Array) -> jax.Array:
 def causal_attention(
     lp: dict, x: jax.Array, n_heads: int, impl: str = "xla"
 ) -> jax.Array:
-    """Multi-head causal self-attention.
+    """Multi-head causal self-attention; the head size is ``wq``'s output
+    width over ``n_heads`` (:func:`qkv_projections`).
 
     impl="xla": ``jax.nn.dot_product_attention`` (f32 softmax, 1/sqrt(hd)
     scale).  NB: jax 0.9's default implementation still materializes the
@@ -137,14 +143,19 @@ def flash_block_sizes(shape: tuple, backend: str):
 
 
 def attention_core(
-    q: jax.Array, k: jax.Array, v: jax.Array, impl: str = "xla"
+    q: jax.Array, k: jax.Array, v: jax.Array, impl: str = "xla",
+    window: int | None = None,
 ) -> jax.Array:
-    """The causal attention math on pre-projected [B,S,H,hd] q/k/v —
-    shared by :func:`causal_attention` and the KV-cache decoder's prefill
-    so the two paths cannot diverge numerically per ``impl``.  Both cores
-    take bf16 operands to float32 scores, softmax and accumulators;
-    ``flash`` is the kernel where :func:`flash_block_sizes` has tiles for
-    the call and ``xla`` where it has none."""
+    """The causal attention math on pre-projected q [B,S,H,hd] and k, v
+    [B,S,Hkv,hd] — shared by :func:`causal_attention` and the KV-cache
+    decoder's prefill so the two paths cannot diverge numerically per
+    ``impl``.  Both cores take bf16 operands to float32 scores, softmax
+    and accumulators; ``flash`` is the kernel where
+    :func:`flash_block_sizes` has tiles for the call and ``xla`` where it
+    has none.  Both take fewer key/value heads than query heads as they
+    come (query head h reads key/value head ``h // (H / Hkv)``; no copy
+    of K or V is made), and a ``window``: query i sees the ``window``
+    keys that end with its own, ``i - window < j <= i``."""
     if impl not in ("xla", "flash"):
         raise ValueError(f"impl must be 'xla' or 'flash', got {impl!r}")
     sizes = (
@@ -155,13 +166,17 @@ def attention_core(
         from jax.experimental.pallas.ops.tpu import splash_attention as splash
 
         _, s, h, hd = q.shape
+        mask = (
+            splash.CausalMask((s, s)) if window is None
+            else splash.LocalMask((s, s), (window - 1, 0), offset=0)
+        )
         kernel = splash.make_splash_mha_single_device(
-            mask=splash.MultiHeadMask([splash.CausalMask((s, s))] * h),
-            block_sizes=sizes,
+            mask=splash.MultiHeadMask([mask] * h), block_sizes=sizes,
         )
 
-        # kernel convention: one batch row [H, S, hd], the scale already
-        # on q; blocks wholly above the diagonal are never visited
+        # kernel convention: one batch row [H, S, hd] (k and v [Hkv, S,
+        # hd]), the scale already on q; blocks the mask leaves empty
+        # (above the diagonal, behind the window) are never visited
         def heads_first(x):
             return x.transpose(0, 2, 1, 3)
 
@@ -169,6 +184,10 @@ def attention_core(
             return heads_first(jax.vmap(kernel)(
                 heads_first(q) * (1.0 / hd ** 0.5), heads_first(k), heads_first(v)
             ))
+    if window is not None:
+        return jax.nn.dot_product_attention(
+            q, k, v, is_causal=True, local_window_size=(window - 1, 0)
+        )
     return jax.nn.dot_product_attention(q, k, v, is_causal=True)
 
 

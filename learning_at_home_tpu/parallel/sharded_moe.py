@@ -75,9 +75,15 @@ class ShardedMixtureOfExperts:
       w2    [E, ffn, d]
       b2    [E, d]
     ``expert_kind="gated_silu"`` (``w_down(silu(w_gate x) * (w_up x))``,
-    no biases):
+    no biases) and ``"gated_relu"`` (ReGLU: the same three matrices and
+    code, ``relu`` on the gate branch):
       w_gate, w_up  [E, d, ffn]
       w_down        [E, ffn, d]
+
+    ``router_input=True``: the call takes the router's input beside the
+    experts' (``router_x``: a router placed before the attention block
+    reads that block's normalized input, the experts the stream after
+    it); dropless routing only.
 
     ``routing="capacity"``: the ``[E, C, d]`` slot program above, tokens
     beyond an expert's capacity dropped.  ``routing="dropless"``: the
@@ -102,16 +108,17 @@ class ShardedMixtureOfExperts:
         expert_kind: str = "gelu",
         routing: str = "capacity",
         renormalize: bool = True,
+        router_input: bool = False,
     ):
         if dispatch_impl not in ("auto", "gather", "onehot"):
             raise ValueError(
                 "dispatch_impl must be 'auto', 'gather' or 'onehot', "
                 f"got {dispatch_impl!r}"
             )
-        if expert_kind not in ("gelu", "gated_silu"):
+        if expert_kind not in ("gelu", "gated_silu", "gated_relu"):
             raise ValueError(
-                f"expert_kind must be 'gelu' or 'gated_silu', got "
-                f"{expert_kind!r}"
+                f"expert_kind must be 'gelu', 'gated_silu' or 'gated_relu'"
+                f", got {expert_kind!r}"
             )
         if routing not in ("capacity", "dropless"):
             raise ValueError(
@@ -121,6 +128,13 @@ class ShardedMixtureOfExperts:
             raise ValueError(
                 "routing='dropless' is top-k on clean gates: "
                 "router_jitter must be 0"
+            )
+        if router_input and routing != "dropless":
+            raise NotImplementedError(
+                "a router input of its own (router_input=True) with "
+                "routing='capacity': the slot program's gate, jitter and "
+                "dispatch all read the one token tensor it exchanges over "
+                "'expert'; only the dropless path takes two"
             )
         if "expert" not in mesh.axis_names:
             raise ValueError("mesh must have an 'expert' axis")
@@ -161,6 +175,9 @@ class ShardedMixtureOfExperts:
         self.expert_kind = expert_kind
         self.routing = routing
         self.renormalize = renormalize
+        self.router_input = router_input
+        # the gate branch's activation of the gated kinds
+        self._gate_act = jax.nn.relu if expert_kind == "gated_relu" else jax.nn.silu
         if routing == "dropless" and (self.ep > 1 or self.tp > 1):
             raise NotImplementedError(
                 f"routing='dropless' on a mesh with expert={self.ep}, "
@@ -185,7 +202,7 @@ class ShardedMixtureOfExperts:
         # measured 0.40-0.48 dropped at init on the 256-expert flagship;
         # small init gives balance a head start and the aux loss keeps it)
         gate_init = jax.nn.initializers.normal(stddev=1e-2)
-        if self.expert_kind == "gated_silu":
+        if self.expert_kind != "gelu":
             # fan-in of ONE expert's matrix (the gelu stack's lecun_normal
             # counts the expert axis into its fan-in: kept, its
             # checkpoints and baselines were made with it)
@@ -210,7 +227,7 @@ class ShardedMixtureOfExperts:
         return jax.device_put(params, self.param_shardings())
 
     def _expert_param_specs(self) -> dict[str, P]:
-        if self.expert_kind == "gated_silu":
+        if self.expert_kind != "gelu":
             col = P("expert", None, "model") if self.tp > 1 else P("expert")
             row = P("expert", "model", None) if self.tp > 1 else P("expert")
             return {"w_gate": col, "w_up": col, "w_down": row}
@@ -245,8 +262,12 @@ class ShardedMixtureOfExperts:
         self, params: Params, x: jax.Array,
         jitter_salt: jax.Array | int = 0,
         token_mask: jax.Array | None = None,
+        router_x: jax.Array | None = None,
     ) -> tuple[jax.Array, dict]:
         """x: [n_tokens, d] sharded over the data axes.  Returns (y, aux).
+
+        ``router_x`` [n_tokens, d] (with ``router_input=True``, and only
+        then): what the router reads in place of ``x``.
 
         ``jitter_salt``: static int or traced scalar (e.g. the layer index
         inside a scan-over-layers) folded into the router-jitter key so
@@ -266,18 +287,22 @@ class ShardedMixtureOfExperts:
                 f"token count {n_global} must divide across {n_shards} shards"
             )
         n_local = n_global // n_shards
+        if (router_x is not None) != self.router_input:
+            raise ValueError(
+                f"router_input={self.router_input} but router_x is "
+                f"{'given' if router_x is not None else 'missing'}"
+            )
         if self.routing == "dropless":
             aux_names = ("aux_loss", "router_z_loss", "dropped_fraction",
                          "expert_load_max_over_mean")
-            per_token = (x,) if token_mask is None else (x, token_mask)
             return shard_map(
                 self._local_forward_dropless,
                 mesh=self.mesh,
-                in_specs=(self.param_specs(),)
-                + (P(self._shard),) * len(per_token),
+                # a None among the per-token arguments has no leaf to place
+                in_specs=(self.param_specs(),) + (P(self._shard),) * 3,
                 out_specs=(P(self._shard), {name: P() for name in aux_names}),
                 check_vma=False,
-            )(params, *per_token)
+            )(params, x, token_mask, router_x)
         capacity = compute_capacity(
             n_local, self.num_experts, self.k, self.capacity_factor
         )
@@ -363,8 +388,8 @@ class ShardedMixtureOfExperts:
             xe = x_recv.transpose(1, 0, 2, 3).reshape(
                 e_local, self.ep * capacity, d
             )
-            if self.expert_kind == "gated_silu":
-                h = jax.nn.silu(
+            if self.expert_kind != "gelu":
+                h = self._gate_act(
                     jnp.einsum("egd,edf->egf", xe,
                                params["w_gate"].astype(compute))
                 ) * jnp.einsum("egd,edf->egf", xe,
@@ -412,19 +437,23 @@ class ShardedMixtureOfExperts:
     def _local_forward_dropless(
         self, params: Params, x: jax.Array,
         token_mask: jax.Array | None = None,
+        router_x: jax.Array | None = None,
     ) -> tuple[jax.Array, dict]:
         """Dropless top-k for MY tokens, all experts here: route in
-        float32, sort the n*k assignments by expert, grouped matmuls over
+        float32 (on ``router_x`` where the router has an input of its
+        own), sort the n*k assignments by expert, grouped matmuls over
         the sorted rows, unsort, gate-weighted sum.  No capacity, so no
         slot tensor and ``dropped_fraction`` exactly 0."""
         compute = self.dtype
         n = x.shape[0]
+        if router_x is None:
+            router_x = x
         # the router is 2*d*E operations a token (0.2 % of an OLMoE layer):
         # float32 operands at full precision, so that which experts are
         # the k largest does not hang on a bf16 rounding of the logits
         with jax.named_scope("router"):
             logits = jnp.dot(
-                x.astype(jnp.float32), params["gate"].astype(jnp.float32),
+                router_x.astype(jnp.float32), params["gate"].astype(jnp.float32),
                 precision=jax.lax.Precision.HIGHEST,
             )
             plan = dropless_routing(
@@ -432,9 +461,9 @@ class ShardedMixtureOfExperts:
             )
         with jax.named_scope("moe_sort"):
             xs = sort_tokens(x.astype(compute), plan)  # [n*k, d]
-        if self.expert_kind == "gated_silu":
+        if self.expert_kind != "gelu":
             with jax.named_scope("experts/gate_up"):
-                h = jax.nn.silu(
+                h = self._gate_act(
                     grouped_matmul(
                         xs, params["w_gate"].astype(compute), plan.group_sizes
                     )

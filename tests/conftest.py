@@ -99,3 +99,22 @@ def pytest_sessionfinish(session, exitstatus):
                 fh.write(line + "\n")
         except OSError:
             pass
+
+
+@pytest.fixture(scope="module")
+def v5e_chip():
+    """One device of a described (not attached) ``v5e:2x2``, for AOT
+    compiles at real sizes; the test is skipped where the TPU compiler is
+    absent or its library is held by another process.  Asked for by name
+    (never autouse), so the topology is described only once a test that
+    needs it has started."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # no TPU compiler here, or its library is held
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return topo.devices[0]

@@ -143,7 +143,7 @@ def test_block_in_bf16_is_inside_the_runner_tolerances(tiny, want):
     assert not runner.over_tolerance(readings), readings
 
 
-def _per_head_norm_projections(lp, x, n_heads, positions=None):
+def _per_head_norm_projections(lp, x, n_heads, positions=None, **_):
     """The mutation 'query/key norm per head': each head normalised by its
     own mean square (with its slice of the scale)."""
     b, s, d = x.shape
@@ -172,7 +172,7 @@ MUTATIONS = {
     # name: (config changes, params transform, (module, attribute, value))
     "renormalised_top8": ({"renormalize": True}, None, None),
     "no_qk_norm": ({"qk_norm": False}, _strip_qk_norm, None),
-    "rotary_off": ({}, None, (trunk, "rotary", lambda x, positions: x)),
+    "rotary_off": ({}, None, (trunk, "rotary", lambda x, positions, theta: x)),
     "per_head_qk_norm": (
         {}, None, (transformer, "qkv_projections", _per_head_norm_projections)
     ),
@@ -610,19 +610,8 @@ def test_unrolled_tuples_equal_the_scanned_stack(recipe, remat):
 # ---- the chip's compiler accepts a layer at published widths ----
 
 
-@pytest.fixture(scope="module")
-def v5e_chip():
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    from jax.experimental import topologies
-
-    try:
-        topo = topologies.get_topology_desc(
-            platform="tpu", topology_name="v5e:2x2"
-        )
-    except Exception as e:  # no TPU compiler here, or its library is held
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return topo.devices[0]
-
+# the ``v5e_chip`` fixture is tests/conftest.py's (tests/test_smallthinker.py
+# compiles for it too)
 
 @contextlib.contextmanager
 def _no_compile_cache():

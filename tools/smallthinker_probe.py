@@ -1,0 +1,165 @@
+#!/usr/bin/env python3
+"""Two readings the ``smallthinker-21b-a3b`` configuration rests on.
+
+    python tools/smallthinker_probe.py memory
+    chiprun -- python tools/smallthinker_probe.py float8 [seed ...]
+
+``memory`` (here, no chip): the whole train step of
+``__graft_entry__.smallthinker_one_chip`` at published widths, compiled
+for a described v5e chip; prints the compiler's ``memory_analysis()``
+against the chip's 16,909,334,528 bytes.  Nothing runs.
+
+``float8`` (on the chip): the benchmark runner's own comparison, on seeded
+weights after as many train steps as the cell's window leaves them (48,
+over the cell's pool of 8 seeded rows of 16,384 Zipf ids), of the program
+and then, in the program's place, of the configuration's plain reference
+with every matmul operand rounded to float8_e4m3: the reading that the
+runner's tolerances must refuse (PERF.md section 2).  bf16 operands
+follow, which they must pass.
+"""
+
+import contextlib
+import json
+import os
+import sys
+import time
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path[:0] = [REPO, os.path.join(REPO, "benchmarks")]
+
+CHIP_BYTES = 16_909_334_528  # memory_stats()["bytes_limit"] of a v5e chip
+CONFIG = "benchmarks/configs/smallthinker-21b-a3b.json"
+
+
+@contextlib.contextmanager
+def no_compile_cache():
+    """An AOT compile for a described chip is written to the persistent
+    cache but cannot be read back without the chip: keep it out."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+
+
+def step_memory(chip) -> dict:
+    """The compiler's memory analysis of the whole train step of
+    ``smallthinker_one_chip`` compiled for ``chip``, a described v5e
+    device (the caller makes ``jax.default_backend()`` answer ``tpu``, as
+    on the chip)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from __graft_entry__ import smallthinker_one_chip
+    from learning_at_home_tpu.parallel.mesh import (
+        batch_sharding,
+        opt_state_shardings,
+    )
+
+    mesh = Mesh(np.array([chip]), ("expert",))
+    model, cfg, optimizer, batch = smallthinker_one_chip(mesh)
+    assert model.cfg.attn_impl == "flash"
+
+    def placed(tree, shardings):
+        return jax.tree_util.tree_map(
+            lambda s, h: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=h),
+            tree, shardings,
+        )
+
+    shapes = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
+    shard = model.param_shardings(shapes)
+    p = placed(shapes, shard)
+    o = jax.eval_shape(optimizer.init, p)
+    o = placed(o, opt_state_shardings(o, shard, p, mesh))
+    ids = jax.ShapeDtypeStruct(
+        (batch, cfg.seq_len), jnp.int32, sharding=batch_sharding(mesh))
+    t0 = time.perf_counter()
+    with no_compile_cache():
+        compiled = model.make_train_step(optimizer).lower(p, o, ids, ids).compile()
+    m = compiled.memory_analysis()
+    # params and optimizer state are donated: outputs alias the arguments
+    live = (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    return {
+        "compile_s": time.perf_counter() - t0,
+        "parameters": sum(int(np.prod(leaf.shape))
+                          for leaf in jax.tree_util.tree_leaves(shapes)),
+        "argument_bytes": m.argument_size_in_bytes,
+        "output_bytes": m.output_size_in_bytes,
+        "alias_bytes": m.alias_size_in_bytes,
+        "temp_bytes": m.temp_size_in_bytes,
+        "live_bytes": live, "chip_bytes": CHIP_BYTES,
+        "share_of_chip": live / CHIP_BYTES,
+    }
+
+
+def memory() -> None:
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    import jax
+    from jax.experimental import topologies
+
+    topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    # the chip is described, not attached: the recipe must resolve as on it
+    jax.default_backend = lambda: "tpu"
+    print(json.dumps(step_memory(topo.devices[0])))
+
+
+def float8(seeds: list) -> None:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    import harness
+    from __graft_entry__ import smallthinker_one_chip
+    from learning_at_home_tpu.parallel.mesh import batch_sharding, make_mesh
+
+    manifest = harness.load_manifest("BENCHMARK.json")
+    config = harness.load_json(os.path.join(REPO, CONFIG))
+    runner = harness.load_module(manifest, "runners", config["runner"])
+    recipe = harness.load_module(manifest, "runners", "train_recipe")
+    reference = harness.load_path(os.path.join(REPO, config["reference"]))
+    mesh = make_mesh({"expert": 1}, devices=jax.devices()[:1])
+    model, cfg, optimizer, rows = smallthinker_one_chip(mesh)
+    step = model.make_train_step(optimizer)
+    for seed in seeds:
+        words = harness.seed_words(seed, 4)
+        params = model.init_params(jnp.asarray(words[:2], jnp.uint32))
+        opt_state = model.init_opt_state(optimizer, params)
+        batches = recipe.zipf_batches(
+            np.random.default_rng(words[2:]), cfg.vocab_size, rows, cfg.seq_len, 8)
+        pool = [tuple(jax.device_put(a, batch_sharding(mesh)) for a in pair)
+                for pair in batches]
+        for i in [0, 1] + [(2 + j) % 8 for j in range(45)] + [0]:
+            params, opt_state, _, _ = step(params, opt_state, *pool[i])
+        del opt_state
+        ids, tgt = batches[0][0][:1], batches[0][1][:1]
+        for dtype in (None, jnp.float8_e4m3fn, jnp.bfloat16):
+            read = runner.compare_with_reference(
+                model, params, reference, config, jnp.asarray(ids),
+                jnp.asarray(tgt), operand_dtype=dtype)
+            print("REFERENCE_AT " + json.dumps({
+                "seed": seed,
+                "operands": jnp.dtype(dtype).name if dtype else "the program",
+                **read,
+                "limits": runner.TOLERANCES,
+                "outside": [k for k, lim in runner.TOLERANCES.items()
+                            if not read[k] <= lim],
+            }), flush=True)
+        del params
+
+
+if __name__ == "__main__":
+    if sys.argv[1:2] == ["memory"]:
+        memory()
+    elif sys.argv[1:2] == ["float8"]:
+        float8([int(s) for s in sys.argv[2:]] or [3100000007])
+    else:
+        sys.exit(__doc__)
