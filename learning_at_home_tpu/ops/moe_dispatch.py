@@ -439,6 +439,14 @@ WEIGHTS_GRADIENT = jax.lax.RaggedDotDimensionNumbers(
 GROUPED_MATMUL_MIN_ROWS = 512
 
 
+def _largest_lane_divisor(dim: int, most: int) -> int:
+    """The largest multiple of the 128-lane width that divides ``dim`` and
+    is at most ``most``; 0 where there is none."""
+    return next(
+        (t for t in range(min(dim, most) // 128 * 128, 0, -128) if dim % t == 0), 0
+    )
+
+
 def grouped_matmul_tiles(
     m: int, k: int, n: int, dtype, weights_gradient: bool = False
 ) -> tuple[int, int, int] | None:
@@ -451,25 +459,33 @@ def grouped_matmul_tiles(
     rows' gradient with ``k`` and ``n`` exchanged) holds in VMEM, twice
     each, a row tile ``[tm, tk]``, a weight tile ``[tk, tn]`` and a result
     tile ``[tm, tn]``, and a float32 ``[tm, tn]`` accumulator unless
-    ``tk`` is all of ``k``.  Fastest (v5e, PERF.md section 6, PR 30):
-    256 rows, because every group boundary inside a row tile costs one
-    more visit of the whole tile and real loads put 63 of them anywhere;
-    and a weight tile of 2 Mi elements, which at OLMoE's widths is an
-    expert's whole matrix: fetched once a group, no accumulator.  The
-    weights' gradient ``[m, k], [m, n] → [G, k, n]`` holds its result tile
+    ``tk`` is all of ``k``.  Fastest (v5e, PERF.md section 6, PR 30 and
+    PR 32): 256 rows, because every group boundary inside a row tile costs
+    one more visit of the whole tile and real loads put 63 of them
+    anywhere; and a weight tile of up to 2 Mi elements, which at OLMoE's
+    widths (2048 x 1024) and at SmallThinker's (2560 x 768) is an expert's
+    whole matrix: fetched once a group, no accumulator.  The weights'
+    gradient ``[m, k], [m, n] → [G, k, n]`` holds its result tile
     ``[tk, tn]`` twice and again in float32, so half as large a tile.  Of
     16 MB of VMEM both take 10 to 12 (a tile of twice the size is refused).
 
-    ``None`` where a tile would not divide its dimension, for operands
-    other than bf16 (the tiles were measured, and their VMEM counted, at
-    two bytes an element) and under ``GROUPED_MATMUL_MIN_ROWS`` rows."""
-    if jnp.dtype(dtype) != jnp.bfloat16 or m < GROUPED_MATMUL_MIN_ROWS:
+    A tile divides its dimension (one that does not is padded, not
+    clipped) and is a multiple of the 128 lanes: ``tk`` is the largest
+    such divisor of ``k`` up to the longest measured, 2560 (1280 for the
+    weights' gradient), then ``tn`` the largest such divisor of ``n`` that
+    keeps ``tk * tn`` within the 2 Mi (1 Mi); for a power of two that is
+    ``min(dim, cap)``.
+
+    ``None`` where no multiple of 128 divides a width or 256 the rows,
+    for operands other than bf16 (the tiles were measured, and their VMEM
+    counted, at two bytes an element) and under
+    ``GROUPED_MATMUL_MIN_ROWS`` rows."""
+    if jnp.dtype(dtype) != jnp.bfloat16 or m < GROUPED_MATMUL_MIN_ROWS or m % 256:
         return None
-    tk = min(k, 1024 if weights_gradient else 2048)
-    tn = min(n, (1 << 20 if weights_gradient else 1 << 21) // tk)
-    if m % 256 or k % tk or n % tn:
-        return None
-    return 256, tk, tn
+    budget = 1 << 20 if weights_gradient else 1 << 21
+    tk = _largest_lane_divisor(k, 1280 if weights_gradient else 2560)
+    tn = _largest_lane_divisor(n, budget // tk) if tk else 0
+    return (256, tk, tn) if tn else None
 
 
 def ragged_dot_tiling(tiles: tuple[int, int, int] | None):

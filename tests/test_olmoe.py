@@ -344,6 +344,7 @@ def test_cached_decode_matches_the_full_forward(tiny):
 # ---- the grouped matmul's tiles are read from the call's shape ----
 
 CELL_ROWS = 4 * 4096 * 8  # olmoe-1b-7b-train-zipf4k: tokens a step x experts a token
+SMALLTHINKER_ROWS = 16384 * 6  # smallthinker-21b-a3b-train-zipf16k
 
 
 @pytest.mark.parametrize(
@@ -361,8 +362,20 @@ CELL_ROWS = 4 * 4096 * 8  # olmoe-1b-7b-train-zipf4k: tokens a step x experts a 
         # wider: 2 Mi elements of weights a tile, 1 Mi for their gradient
         ((8192, 4096, 4096, jnp.bfloat16), (256, 2048, 1024)),
         ((8192, 512, 4096, jnp.bfloat16, True), (256, 512, 2048)),
-        # 1536 = 1.5 x 1024, 33,000 = 128.9 x 256: a tile does not divide
-        ((CELL_ROWS, 2048, 1536, jnp.bfloat16), None),
+        # SmallThinker's widths, no powers of two: gate and up, and the
+        # rows' gradient of down, an expert's whole 2560 x 768 matrix
+        ((SMALLTHINKER_ROWS, 2560, 768, jnp.bfloat16), (256, 2560, 768)),
+        # down, and the rows' gradient of gate and up
+        ((SMALLTHINKER_ROWS, 768, 2560, jnp.bfloat16), (256, 768, 2560)),
+        # half of it for the weights' gradients: 1280 = 2560 / 2
+        ((SMALLTHINKER_ROWS, 2560, 768, jnp.bfloat16, True), (256, 1280, 768)),
+        ((SMALLTHINKER_ROWS, 768, 2560, jnp.bfloat16, True), (256, 768, 1280)),
+        # 1536 = 12 x 128: its largest divisor that leaves 2048 x tn in 2 Mi
+        ((CELL_ROWS, 2048, 1536, jnp.bfloat16), (256, 2048, 768)),
+        # 1000 = 7.8 x 128: no multiple of the 128 lanes divides it
+        ((CELL_ROWS, 2048, 1000, jnp.bfloat16), None),
+        ((CELL_ROWS, 1000, 2048, jnp.bfloat16, True), None),
+        # 33,000 = 128.9 x 256: the row tile does not divide
         ((33000, 2048, 1024, jnp.bfloat16), None),
         # float32 operands were not measured and take twice the VMEM
         ((CELL_ROWS, 2048, 1024, jnp.float32), None),
@@ -372,7 +385,10 @@ CELL_ROWS = 4 * 4096 * 8  # olmoe-1b-7b-train-zipf4k: tokens a step x experts a 
         ((256, 2048, 1024, jnp.bfloat16, True), None),
     ],
     ids=["gate-up", "down", "gate-up-weights", "down-weights", "narrow",
-         "wide", "wide-weights", "n-not-divided", "m-not-divided", "float32", "tiny", "few-rows"],
+         "wide", "wide-weights", "smallthinker-gate-up", "smallthinker-down",
+         "smallthinker-gate-up-weights", "smallthinker-down-weights",
+         "n-not-divided", "n-no-lane-multiple", "k-no-lane-multiple",
+         "m-not-divided", "float32", "tiny", "few-rows"],
 )
 def test_grouped_matmul_tiles_are_read_from_the_shape(call, tiles):
     assert grouped_matmul_tiles(*call) == tiles
@@ -702,6 +718,8 @@ def test_blocked_attention_compiles_for_v5e_at_its_tiles(v5e_chip, monkeypatch, 
     (8192, 4096, 4096),   # wider than one: an accumulator beside the tiles
     (4096, 16384, 512),
     (4096, 512, 16384),
+    (4096, 2560, 768),    # SmallThinker's: the largest whole-matrix tile,
+    (4096, 768, 2560),    # and 1280 = 2560 / 2 in the weights' gradients
 ])
 def test_grouped_matmul_compiles_for_v5e_at_its_tiles(v5e_chip, m, a, b):
     """The chip's compiler takes the call and both gradients at the tiles

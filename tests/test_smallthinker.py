@@ -9,6 +9,7 @@ Tiny sizes on the CPU, except the AOT compiles at published widths for a
 described (not attached) ``v5e`` chip.
 """
 
+import collections
 import dataclasses
 import json
 import os
@@ -34,6 +35,7 @@ from learning_at_home_tpu.models.transformer import (  # noqa: E402
     AttentionLayer,
     DMoETransformerLM,
 )
+from learning_at_home_tpu.ops.moe_dispatch import grouped_matmul_tiles  # noqa: E402
 from learning_at_home_tpu.parallel.mesh import make_mesh  # noqa: E402
 from learning_at_home_tpu.parallel.sharded_moe import ShardedMixtureOfExperts  # noqa: E402
 
@@ -584,19 +586,73 @@ def test_benchmark_manifests_pass_selfcheck_and_the_runner_rehearses(tmp_path):
             assert "cpu_rehearsal.smallthinker.expert_load_max_over_mean" in names
 
 
+# ---- every grouped matmul of a layer runs at tiles read from its shape ----
+
+
+def test_every_grouped_matmul_of_a_layer_carries_its_tiles():
+    """One window layer of the recipe at the cell's sizes (98,304 rows,
+    experts of 2560 x 768), traced from shapes and lowered for the TPU
+    platform (nothing compiles, nothing runs): the 3 forward grouped
+    matmuls, the 3 rows' gradients and the 3 weights' gradients each carry
+    the ``ragged_dot_tiling`` that ``grouped_matmul_tiles`` reads from
+    their shape, which at these widths is no power of two; with remat's
+    second forward 12 of a layer's 12 calls, where 4 did (PERF.md section
+    6, PR 32)."""
+    model, cfg, _, batch = smallthinker_one_chip(_one_device_mesh())
+    lp = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))["layers"][1]
+    x = jax.ShapeDtypeStruct((batch, cfg.seq_len, cfg.d_model), cfg.dtype)
+
+    def layer_loss(lp, x):
+        y, aux = model._layer(lp, x, 1, None, cfg.attention_layer(1))
+        return (y.astype(jnp.float32) ** 2).mean() + aux["aux_loss"]
+
+    text = (
+        jax.jit(jax.value_and_grad(layer_loss, argnums=(0, 1)))
+        .trace(lp, x).lower(lowering_platforms=("tpu",)).as_text()
+    )
+    calls = [line for line in text.splitlines() if '"chlo.ragged_dot"' in line]
+    assert calls == [line for line in text.splitlines() if "ragged_dot_tiling" in line]
+    seen = collections.Counter()
+    for line in calls:
+        tiles = tuple(map(int, re.search(
+            r'ragged_dot_tiling = "([\d,]+)"', line).group(1).split(",")))
+        (m, a), _, out = (
+            tuple(map(int, dims.split("x")))
+            for dims in re.findall(r"tensor<([\dx]+)xbf16>", line)
+        )
+        weights = len(out) == 3  # [m, a], [m, b] -> [G, a, b]
+        assert m == batch * cfg.seq_len * cfg.k
+        assert tiles == grouped_matmul_tiles(
+            m, a, out[-1], jnp.bfloat16, weights_gradient=weights
+        ), line
+        seen[tiles] += 1
+    # gate, up and down's rows' gradient; down and the other two rows'
+    # gradients; the weights' gradients of gate and up; of down
+    assert seen == {(256, 2560, 768): 3, (256, 768, 2560): 3,
+                    (256, 1280, 768): 2, (256, 768, 1280): 1}
+
+
 # ---- the chip's compiler accepts the block at published widths ----
 
 
 def test_the_whole_step_fits_the_chip(v5e_chip, monkeypatch):
     """The 4-layer train step at published widths, compiled for a
-    described chip (nothing runs): 2.372 B parameters, and the compiler's
+    described chip (nothing runs): 2.372 B parameters, the compiler's
     own count of what is live in the step is between a quarter of the
-    chip's memory (the benchmark's floor for a cell) and all of it."""
+    chip's memory (the benchmark's floor for a cell) and all of it, and
+    every one of the step's 48 grouped-matmul instructions runs at
+    ``grouped_matmul_tiles``'s answer for its shape (16 did before PR 32)."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     memory = probe.step_memory(v5e_chip)
     assert memory["parameters"] == 2_372_426_240
     assert memory["argument_bytes"] > 2 * memory["parameters"]  # bf16, state
     assert 0.25 < memory["share_of_chip"] < 0.9, memory
+    # a layer's 12 grouped matmuls (remat runs the 3 forward ones twice),
+    # each compiled at the tiles the rule reads from its shape
+    assert memory["grouped_matmul_tilings"] == {
+        "256,2560,768": 4 * 5, "256,768,2560": 4 * 4,
+        "256,1280,768": 4 * 2, "256,768,1280": 4 * 1,
+    }
 
 
 @pytest.mark.parametrize("layer", [0, 1], ids=["global", "window"])

@@ -8,14 +8,17 @@
     python tools/grouped_matmul_probe.py gmm collapsed     # megablox at the same tiles
     python tools/grouped_matmul_probe.py sizes             # fewer rows: where the gain starts
     python tools/grouped_matmul_probe.py accuracy          # the program's call against float32
+    # another cell's shapes (here smallthinker-21b-a3b-train-zipf16k's):
+    python tools/grouped_matmul_probe.py tiles skewed 256 --rows 98304 --widths 2560x768,768x2560
 
-One process a subcommand (a chip belongs to one process), one ``ROW``
-line of JSON a reading, written to
-``chiprun_out/grouped_matmul_probe.jsonl`` as well.  Every time is the
-host clock around ``ITERS`` launches that end in ``block_until_ready``,
+``--rows``, ``--groups`` and ``--widths`` (``k``x``n``, comma-separated)
+give every subcommand its shapes; the default is the OLMoE cell's.  One
+process a subcommand (a chip belongs to one process), one ``ROW`` line of
+JSON a reading, written to ``chiprun_out/grouped_matmul_probe.jsonl`` as
+well.  Every time is the host clock around ``ITERS`` launches that end in ``block_until_ready``,
 after the compile; a setting the compiler refuses is a row with
-``refused``.  PERF.md section 6 ("PR 30") holds the readings the tile
-rule was set from.
+``refused``.  PERF.md section 6 ("PR 30", "PR 32") holds the readings
+the tile rule was set from.
 
 The three kinds of call a train step makes of one ``grouped_matmul``
 (``m`` rows in 64 groups, widths ``k`` x ``n``):
@@ -26,14 +29,21 @@ The three kinds of call a train step makes of one ``grouped_matmul``
 - ``dweights``: the gradient to the weights, ``[m, k], [m, n] -> [64, k, n]``,
   which contracts over the ragged dimension.
 
-At the OLMoE cell's shapes: ``m`` 131,072, ``k x n`` 2048 x 1024 (gate and
-up) and 1024 x 2048 (down).  Group sizes ``collapsed`` as the cell has
-them (8 of 64 experts hold nearly every row: largest over mean 7.76) or
-``uniform``.
+The two cells that run it: ``olmoe-1b-7b-train-zipf4k``, ``m`` 131,072
+(16,384 tokens x 8), ``k x n`` 2048 x 1024 (gate and up) and 1024 x 2048
+(down); ``smallthinker-21b-a3b-train-zipf16k``, ``m`` 98,304 (16,384 x 6),
+2560 x 768 and 768 x 2560; both 64 groups.  Group sizes ``collapsed`` as
+the OLMoE cell has them (8 of 64 experts hold nearly every row: largest
+over mean 7.76), ``skewed`` as the SmallThinker cell has them (a power
+law over the experts whose largest over mean is ``--max-over-mean``, 5.6:
+the cell reads 5.4 to 5.9) or ``uniform``.
 
 - ``tiles``: ``jax.lax.ragged_dot`` as it is, then under the frontend
-  attribute ``ragged_dot_tiling`` for tm in 256, 512, 1024 and tk, tn in
-  512, 1024, 2048; ``compiled_tiling`` is what the compiled instruction
+  attribute ``ragged_dot_tiling`` for tm in 256, 512, 1024 and, for tk
+  and tn, every multiple of 128 from 384 up that divides its dimension
+  (512, 1024, 2048 at OLMoE's widths; 512, 640, 1280, 2560 and 384, 768
+  at SmallThinker's; a tile that does not divide is padded, PR 30, and
+  left out); ``compiled_tiling`` is what the compiled instruction
   carries.
 - ``gmm``: megablox ``gmm`` (``transpose_rhs`` for ``drows``) and ``tgmm``
   (with the 537 MB transpose of the rows it needs, and without) at the
@@ -49,6 +59,7 @@ them (8 of 64 experts hold nearly every row: largest over mean 7.76) or
 
 from __future__ import annotations
 
+import argparse
 import importlib
 import itertools
 import json
@@ -61,10 +72,13 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
 ITERS = 10
-GROUPS, ROWS = 64, 131072  # olmoe-1b-7b-train-zipf4k: experts, 16,384 tokens x 8
-WIDTHS = ((2048, 1024), (1024, 2048))  # gate and up; down
+# the defaults: olmoe-1b-7b-train-zipf4k (experts; 16,384 tokens x 8;
+# gate and up, down); main() takes another cell's from the command line
+GROUPS, ROWS = 64, 131072
+WIDTHS = ((2048, 1024), (1024, 2048))
+MAX_OVER_MEAN = 5.6  # ``skewed``: smallthinker.expert_load_max_over_mean reads 5.4-5.9
 KINDS = ("forward", "drows", "dweights")
-ROW_TILES, WIDTH_TILES = (256, 512, 1024), (512, 1024, 2048)
+ROW_TILES = (256, 512, 1024)
 OUT = os.path.join(REPO, "chiprun_out", "grouped_matmul_probe.jsonl")
 
 
@@ -86,33 +100,51 @@ def require_tpu():
         raise SystemExit(f"grouped_matmul_probe needs a TPU, found {d.platform!r}")
 
 
-def group_sizes(how: str, m: int = ROWS):
-    """``collapsed``: 8 experts with 97 % of the rows between them, the
-    rest spread over the other 56 (largest over mean 7.76, as the cell's
-    ``olmoe.expert_load_max_over_mean`` reads); ``uniform``: m / 64 each."""
+def group_sizes(how: str, m: int = ROWS, groups: int = GROUPS,
+                max_over_mean: float = MAX_OVER_MEAN):
+    """``collapsed``: an eighth of the experts with 97 % of the rows
+    between them, the rest spread over the others (largest over mean
+    7.76, as the cell's ``olmoe.expert_load_max_over_mean`` reads);
+    ``skewed``: sizes that fall as a power of the expert's rank, the
+    power found so that the largest over the mean is ``max_over_mean``;
+    ``uniform``: m / groups each."""
     import numpy as np
 
     if how == "uniform":
-        sizes = np.full(GROUPS, m // GROUPS)
+        sizes = np.full(groups, m // groups)
+    elif how == "skewed":
+        ranks = np.arange(1, groups + 1, dtype=np.float64)
+        lo, hi = 0.0, 8.0  # largest over mean grows with the power: bisect
+        for _ in range(60):
+            power = (lo + hi) / 2
+            share = ranks ** -power
+            lo, hi = (power, hi) if share[0] / share.mean() < max_over_mean else (lo, power)
+        sizes = np.floor(m * share / share.sum()).astype(np.int64)
     else:
-        hot = int(7.76 * m / GROUPS)
-        sizes = np.full(GROUPS, (m - 8 * hot) // (GROUPS - 8))
+        hot = int(7.76 * m / groups)
+        sizes = np.full(groups, (m - groups // 8 * hot) // (groups - groups // 8))
         sizes[::8] = hot
     sizes[-1] += m - sizes.sum()
     assert sizes.sum() == m and (sizes >= 0).all()
     return sizes.astype(np.int32)
 
 
-def operands(k: int, n: int, m: int = ROWS):
+def operands(k: int, n: int, m: int = ROWS, groups: int = GROUPS):
     """Unit-variance rows and row cotangents, weights of variance 1 / k."""
     import jax
     import jax.numpy as jnp
 
     kx, kw, kg = jax.random.split(jax.random.PRNGKey(0), 3)
     x = jax.random.normal(kx, (m, k), jnp.float32).astype(jnp.bfloat16)
-    w = (jax.random.normal(kw, (GROUPS, k, n), jnp.float32) / k ** 0.5).astype(jnp.bfloat16)
+    w = (jax.random.normal(kw, (groups, k, n), jnp.float32) / k ** 0.5).astype(jnp.bfloat16)
     g = jax.random.normal(kg, (m, n), jnp.float32).astype(jnp.bfloat16)
     return x, w, g
+
+
+def width_tiles(dim: int) -> list:
+    """The tiles tried along a width: every multiple of the 128 lanes,
+    from 384 up, that divides it."""
+    return [t for t in range(384, dim + 1, 128) if dim % t == 0]
 
 
 def ragged_call(kind: str, tiles):
@@ -198,19 +230,22 @@ def timed(what: str, settings: dict, fn, *args) -> None:
             refused=short.group(0) if short else text.splitlines()[0][:300])
 
 
-def sweep(what: str, make_call) -> None:
-    """``<groups> [tm ...]``: every setting of the row tiles given (or
-    ``ROW_TILES``) with ``WIDTH_TILES`` for tk and tn."""
+def sweep(what: str, make_call, shape) -> None:
+    """Every setting of the row tiles given (or ``ROW_TILES``) with
+    ``width_tiles`` of the call's two widths for tk and tn (the rows'
+    gradient contracts ``n`` and makes ``k``)."""
     import jax.numpy as jnp
 
     require_tpu()
-    how, row_tiles = sys.argv[2], tuple(map(int, sys.argv[3:])) or ROW_TILES
-    settings = list(itertools.product(row_tiles, WIDTH_TILES, WIDTH_TILES))
-    sizes = jnp.asarray(group_sizes(how))
-    for k, n in WIDTHS:
-        x, w, g = operands(k, n)
+    how, m = shape.group_sizes, shape.rows
+    sizes = jnp.asarray(group_sizes(how, m, shape.groups, shape.max_over_mean))
+    for k, n in shape.widths:
+        x, w, g = operands(k, n, m, shape.groups)
         for kind in KINDS:
-            base = dict(kind=kind, groups=how, m=ROWS, k=k, n=n)
+            a, b = (n, k) if kind == "drows" else (k, n)
+            settings = list(itertools.product(
+                shape.row_tiles or ROW_TILES, width_tiles(a), width_tiles(b)))
+            base = dict(kind=kind, groups=how, m=m, k=k, n=n)
             if what == "ragged_dot":
                 timed(what, dict(base, tiles=None), make_call(kind, None), x, w, g, sizes)
             for tiles in settings:
@@ -222,15 +257,15 @@ def sweep(what: str, make_call) -> None:
                           gmm_call(kind, tiles, transposed_rows=True), xt, w, g, sizes)
 
 
-def tiles() -> None:
-    sweep("ragged_dot", ragged_call)
+def tiles(shape) -> None:
+    sweep("ragged_dot", ragged_call, shape)
 
 
-def gmm() -> None:
-    sweep("gmm", gmm_call)
+def gmm(shape) -> None:
+    sweep("gmm", gmm_call, shape)
 
 
-def sizes() -> None:
+def sizes(shape) -> None:
     """Where the gain starts: the compiler's own tiles against
     ``grouped_matmul_tiles``'s at fewer rows."""
     import jax.numpy as jnp
@@ -239,10 +274,11 @@ def sizes() -> None:
 
     require_tpu()
     moe_dispatch.GROUPED_MATMUL_MIN_ROWS = 0  # ask the rule below its threshold
-    for m, how in itertools.product((512, 2048, 8192, 32768, 65536), ("uniform", "collapsed")):
-        group = jnp.asarray(group_sizes(how, m))
-        for k, n in WIDTHS:
-            x, w, g = operands(k, n, m)
+    hows = dict.fromkeys(("uniform", shape.group_sizes))
+    for m, how in itertools.product((512, 2048, 8192, 32768, 65536), hows):
+        group = jnp.asarray(group_sizes(how, m, shape.groups, shape.max_over_mean))
+        for k, n in shape.widths:
+            x, w, g = operands(k, n, m, shape.groups)
             for kind in KINDS:
                 for setting in (None, program_tiles(kind, m, k, n, x.dtype)):
                     timed("ragged_dot", dict(kind=kind, groups=how, m=m, k=k, n=n,
@@ -250,17 +286,18 @@ def sizes() -> None:
                           ragged_call(kind, setting), x, w, g, group)
 
 
-def accuracy() -> None:
+def accuracy(shape) -> None:
     import jax
     import jax.numpy as jnp
 
     from learning_at_home_tpu.ops.moe_dispatch import grouped_matmul
 
     require_tpu()
-    worst = 0.0
-    for (k, n), how in itertools.product(WIDTHS, ("collapsed", "uniform")):
-        group = jnp.asarray(group_sizes(how))
-        x, w, g = operands(k, n)
+    worst, m = 0.0, shape.rows
+    hows = dict.fromkeys((shape.group_sizes, "uniform"))
+    for (k, n), how in itertools.product(shape.widths, hows):
+        group = jnp.asarray(group_sizes(how, m, shape.groups, shape.max_over_mean))
+        x, w, g = operands(k, n, m, shape.groups)
 
         def all_three(fn, x, w, g):
             out, vjp = jax.vjp(lambda x, w: fn(x, w, group), x, w)
@@ -291,13 +328,33 @@ def accuracy() -> None:
                     got["grouped_matmul"][i], got["ragged_dot"][i]),
             )
             worst = max(worst, read["grouped_matmul_vs_exact"])
-            row(what="accuracy", kind=kind, groups=how, m=ROWS, k=k, n=n,
-                tiles=program_tiles(kind, ROWS, k, n, x.dtype), **read)
+            row(what="accuracy", kind=kind, groups=how, m=m, k=k, n=n,
+                tiles=program_tiles(kind, m, k, n, x.dtype), **read)
     # one bf16 rounding of the result reads 0.17 % rms
     row(what="accuracy_verdict", ok=worst < 0.005, worst_vs_exact=worst, limit=0.005)
     if not worst < 0.005:
         raise SystemExit("grouped_matmul_probe: over 0.5 % from the float32 result")
 
 
+def main() -> None:
+    def widths(text: str):
+        return tuple(tuple(map(int, pair.split("x"))) for pair in text.split(","))
+
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("command", choices=("tiles", "gmm", "sizes", "accuracy"))
+    ap.add_argument("group_sizes", nargs="?", default="collapsed",
+                    choices=("collapsed", "skewed", "uniform"))
+    ap.add_argument("row_tiles", nargs="*", type=int,
+                    help=f"tiles, gmm: row tiles to sweep (default {ROW_TILES})")
+    ap.add_argument("--rows", type=int, default=ROWS)
+    ap.add_argument("--groups", type=int, default=GROUPS)
+    ap.add_argument("--widths", type=widths, default=WIDTHS,
+                    help="kxn of each grouped matmul, comma-separated")
+    ap.add_argument("--max-over-mean", type=float, default=MAX_OVER_MEAN,
+                    help="skewed: the largest group's rows over the mean")
+    shape = ap.parse_args()
+    {"tiles": tiles, "gmm": gmm, "sizes": sizes, "accuracy": accuracy}[shape.command](shape)
+
+
 if __name__ == "__main__":
-    {"tiles": tiles, "gmm": gmm, "sizes": sizes, "accuracy": accuracy}[sys.argv[1]]()
+    main()

@@ -7,7 +7,9 @@
 ``memory`` (here, no chip): the whole train step of
 ``__graft_entry__.smallthinker_one_chip`` at published widths, compiled
 for a described v5e chip; prints the compiler's ``memory_analysis()``
-against the chip's 16,909,334,528 bytes.  Nothing runs.
+against the chip's 16,909,334,528 bytes, and the tiles each of the
+step's 48 grouped-matmul instructions was compiled at (PERF.md section
+3: all of them ``grouped_matmul_tiles``'s since PR 32).  Nothing runs.
 
 ``float8`` (on the chip): the benchmark runner's own comparison, on seeded
 weights after as many train steps as the cell's window leaves them (48,
@@ -18,9 +20,11 @@ runner's tolerances must refuse (PERF.md section 2).  bf16 operands
 follow, which they must pass.
 """
 
+import collections
 import contextlib
 import json
 import os
+import re
 import sys
 import time
 
@@ -52,7 +56,8 @@ def step_memory(chip) -> dict:
     """The compiler's memory analysis of the whole train step of
     ``smallthinker_one_chip`` compiled for ``chip``, a described v5e
     device (the caller makes ``jax.default_backend()`` answer ``tpu``, as
-    on the chip)."""
+    on the chip), and under ``grouped_matmul_tilings`` how many of its
+    grouped-matmul instructions run at which ``tm,tk,tn``."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -98,6 +103,9 @@ def step_memory(chip) -> dict:
         "temp_bytes": m.temp_size_in_bytes,
         "live_bytes": live, "chip_bytes": CHIP_BYTES,
         "share_of_chip": live / CHIP_BYTES,
+        "grouped_matmul_tilings": dict(collections.Counter(re.findall(
+            r'^\s*%ragged-dot-none[.\d]* = [^\n]*ragged_dot_tiling="([\d,]+)"',
+            compiled.as_text(), re.M))),
     }
 
 
