@@ -29,12 +29,14 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from learning_at_home_tpu.models.trunk import (
     attention_core,
     flash_block_sizes,
+    gated_mlp,
     layer_norm,
     one_query_attention,
     output_projection,
     qkv_projections,
     rms_norm,
 )
+from learning_at_home_tpu.ops.moe_dispatch import balanced_bias, level_bias
 from learning_at_home_tpu.parallel.mesh import batch_sharding
 from learning_at_home_tpu.parallel.sharded_moe import ShardedMixtureOfExperts
 
@@ -117,7 +119,13 @@ class DMoETransformerConfig:
     # SmallThinker is rmsnorm (eps 1e-6) / rope (theta 1.5e6) on three
     # layers in four / 28 heads over 4 key/value heads of 128 / a window of
     # 4,096 on the same three / gated_relu of width 768 / dropless /
-    # router on the attention's input (smallthinker_one_chip).
+    # router on the attention's input (smallthinker_one_chip); K-EXAONE is
+    # rmsnorm / rope (theta 1e6) on the window layers (128 keys) of
+    # L L L G / 64 heads over 8 key/value heads of 128 with a norm over
+    # each head / a dense first layer of width 18,432 / then a shared
+    # expert beside gated_silu experts of width 2048, 8 of 128 by sigmoid
+    # scores with a selection bias, weights renormalised times 2.5 /
+    # dropless, a share of the experts held (k_exaone_one_chip).
     # 'layernorm' (scale and bias) or 'rmsnorm' (scale only)
     norm: str = "layernorm"
     norm_eps: float = 1e-5
@@ -135,9 +143,10 @@ class DMoETransformerConfig:
     # or one period of them, repeated; None = every layer global, rotated
     # where positions == 'rope'
     layer_pattern: tuple[AttentionLayer, ...] | None = None
-    # RMSNorm (own scales) over the whole d-wide query and key
-    # projections, before the split into heads
-    qk_norm: bool = False
+    # RMSNorm (own scales) of the queries and keys: True, over the whole
+    # d-wide projections, before the split into heads; 'head', over each
+    # head's own head_dim, one scale shared by the heads
+    qk_norm: bool | str = False
     # 'gelu' (w1/b1/w2/b2), or 'gated_silu' / 'gated_relu'
     # (w_gate/w_up/w_down, no biases; SiLU or ReLU on the gate branch)
     expert_kind: str = "gelu"
@@ -154,6 +163,29 @@ class DMoETransformerConfig:
     # input that the attention block reads (a router placed before the
     # attention: its top-k is known before the attention has run)
     router_input: str = "moe_input"
+    # a layer's feed-forward part, one entry a layer: 'moe' (the mixture)
+    # or 'dense' (one gated block of width dense_ffn_dim that every token
+    # passes, no router); None = every layer 'moe'
+    ffn_pattern: tuple[str, ...] | None = None
+    dense_ffn_dim: int | None = None
+    # experts every token passes beside the routed ones, as one gated block
+    # of width shared_experts * expert_ffn_dim added to the routed sum
+    shared_experts: int = 0
+    # 'softmax': gates from a softmax over all experts; 'sigmoid': every
+    # expert scored on its own, the k largest renormalised (renormalize)
+    # and multiplied by routed_scale
+    router_score: str = "softmax"
+    routed_scale: float = 1.0
+    # a per-expert bias [E] added to the sigmoid scores for the CHOICE of
+    # experts alone: no gradient reaches it, and the train step moves it by
+    # router_bias_rate toward level loads (arXiv:2408.15664)
+    router_bias: bool = False
+    router_bias_rate: float = 0.0
+    # this program holds held_experts of the num_experts each router
+    # scores, from first_held_expert on: one chip's share of layers whose
+    # experts no chip holds whole (None = all of them)
+    held_experts: int | None = None
+    first_held_expert: int = 0
 
     def attention_layer(self, i: int) -> AttentionLayer:
         """Layer ``i``'s attention."""
@@ -245,6 +277,39 @@ class DMoETransformerLM:
                 "layer, and a window or a rotation is part of the traced "
                 "program: run the unrolled loop (scan_layers=False)"
             )
+        if config.qk_norm not in (False, True, "head"):
+            raise ValueError(
+                f"qk_norm must be False, True or 'head', got {config.qk_norm!r}"
+            )
+        ffns = set(config.ffn_pattern or ("moe",))
+        if config.ffn_pattern is not None:
+            if len(config.ffn_pattern) != config.n_layers or ffns - {"moe", "dense"}:
+                raise ValueError(
+                    f"ffn_pattern must name 'moe' or 'dense' for each of "
+                    f"the {config.n_layers} layers, got {config.ffn_pattern}"
+                )
+            if "dense" in ffns and config.dense_ffn_dim is None:
+                raise ValueError("a 'dense' layer needs dense_ffn_dim")
+            if "moe" not in ffns:
+                raise ValueError(
+                    "ffn_pattern names no 'moe' layer: this is the "
+                    "mixture's train step"
+                )
+        if (config.shared_experts or "dense" in ffns) and (
+            config.expert_kind == "gelu"
+        ):
+            raise ValueError(
+                "the dense layer and the shared expert are gated blocks "
+                "with the experts' activation: expert_kind must be a "
+                "gated kind"
+            )
+        if len(ffns) > 1 and (config.scan_layers or config.stack_layers):
+            raise ValueError(
+                "ffn_pattern mixes dense and mixture layers, whose "
+                "parameters differ: neither one stacked tree nor ONE "
+                "traced body holds them (scan_layers=False, "
+                "stack_layers=False)"
+            )
         n_kv = config.n_kv_heads or config.n_heads
         if config.n_heads % n_kv:
             raise ValueError(
@@ -254,6 +319,12 @@ class DMoETransformerLM:
         # what ring attention and the KV-cache decoder do not take
         self._grouped_or_windowed = n_kv != config.n_heads or any(
             a.window is not None for a in kinds
+        )
+        # what the KV-cache decoder's one feed-forward part (a mixture that
+        # holds its experts) is not
+        self._other_ffn = bool(
+            "dense" in ffns or config.shared_experts
+            or config.held_experts not in (None, config.num_experts)
         )
         if config.seq_parallel and self._grouped_or_windowed:
             raise NotImplementedError(
@@ -284,6 +355,11 @@ class DMoETransformerLM:
             routing=config.routing,
             renormalize=config.renormalize,
             router_input=config.router_input == "attention_input",
+            held_experts=config.held_experts,
+            first_held_expert=config.first_held_expert,
+            router_score=config.router_score,
+            router_bias=config.router_bias,
+            routed_scale=config.routed_scale,
         )
         self._ring = None
         self._zig = self._zig_inv = None
@@ -338,7 +414,13 @@ class DMoETransformerLM:
                 return {"scale": jnp.ones((d,), pdt)}
             return {"scale": jnp.ones((d,), pdt), "bias": jnp.zeros((d,), pdt)}
 
-        def init_layer(key):
+        def gated(key, width):
+            kg, ku, kd = jax.random.split(key, 3)
+            return {"w_gate": dense(kg, (d, width), pdt),
+                    "w_up": dense(ku, (d, width), pdt),
+                    "w_down": dense(kd, (width, d), pdt)}
+
+        def init_layer(key, ffn="moe"):
             ks = jax.random.split(key, 5)
             lp = {
                 "ln1": ln(),
@@ -347,14 +429,26 @@ class DMoETransformerLM:
                 "wv": dense(ks[2], (d, d_kv), pdt),
                 "wo": dense(ks[3], (d_q, d), pdt),
                 "ln2": ln(),
-                "moe": self.moe.init_params(ks[4], device_put=False),
             }
+            # the layer's feed-forward part is what its parameters hold:
+            # 'ffn' (dense), or 'moe' and beside it 'shared'
+            if ffn == "dense":
+                lp["ffn"] = gated(ks[4], cfg.dense_ffn_dim)
+            else:
+                lp["moe"] = self.moe.init_params(ks[4], device_put=False)
+                if cfg.shared_experts:
+                    lp["shared"] = gated(
+                        jax.random.fold_in(ks[4], 1),
+                        cfg.shared_experts * self.moe.ffn_dim,
+                    )
             if cfg.qk_norm:
-                lp["q_norm"] = {"scale": jnp.ones((d_q,), pdt)}
-                lp["k_norm"] = {"scale": jnp.ones((d_kv,), pdt)}
+                per_head = cfg.qk_norm == "head"
+                lp["q_norm"] = {"scale": jnp.ones((hd if per_head else d_q,), pdt)}
+                lp["k_norm"] = {"scale": jnp.ones((hd if per_head else d_kv,), pdt)}
             return lp
 
         layer_keys = jax.random.split(k_layers, cfg.n_layers)
+        ffn_of = cfg.ffn_pattern or ("moe",) * cfg.n_layers
         params: dict = {
             "embed": embed_init(k_embed, (v, d), pdt),
             **(
@@ -365,7 +459,7 @@ class DMoETransformerLM:
             "layers": (
                 jax.vmap(init_layer)(layer_keys)
                 if cfg.stack_layers
-                else tuple(init_layer(k) for k in layer_keys)
+                else tuple(init_layer(k, f) for k, f in zip(layer_keys, ffn_of))
             ),
         }
         if not cfg.tie_embeddings:
@@ -409,10 +503,17 @@ class DMoETransformerLM:
                kind: AttentionLayer | None = None):
         """One block.  ``kind`` (static) is the layer's attention where
         the stack's layers differ; None = layer 0's, which every layer of
-        a uniform stack shares (``layer_idx`` may then be traced)."""
+        a uniform stack shares (``layer_idx`` may then be traced).
+        Returns ``(x, aux)``; ``aux`` is None for a dense layer."""
+        x, attn_in = self._attention_block(lp, x, kind)
+        return self._ffn_block(lp, x, attn_in, layer_idx, token_mask)
+
+    def _attention_block(self, lp, x, kind: AttentionLayer | None = None):
+        """The stream after the layer's attention, and the normalized
+        input the attention read (a router placed before it reads that)."""
         if kind is None:
             kind = self.cfg.attention_layer(0)
-        b, s, d = x.shape
+        s = x.shape[1]
         # where a stack has both kinds, the scope says which this one is
         scope = "attention" if self.cfg.layer_pattern is None else (
             "attention/global" if kind.window is None else "attention/window"
@@ -431,7 +532,18 @@ class DMoETransformerLM:
                 )
             )
             x = x + output_projection(lp, core(q, k, v))
-        moe_in = self._norm(lp["ln2"], x).reshape(b * s, d)
+        return x, attn_in
+
+    def _ffn_block(self, lp, x, attn_in, layer_idx, token_mask=None):
+        """The layer's feed-forward part, which its parameters name: one
+        dense gated block (``ffn``: no router, ``aux`` None), or the
+        mixture (``moe``) and beside it the shared expert (``shared``)."""
+        b, s, d = x.shape
+        ffn_in = self._norm(lp["ln2"], x)
+        if "ffn" in lp:
+            with jax.named_scope("dense_ffn"):
+                return x + gated_mlp(lp["ffn"], ffn_in, self.moe._gate_act), None
+        moe_in = ffn_in.reshape(b * s, d)
         # layer index salts the router jitter: decorrelates the
         # deterministic noise pattern across layers (round-2 advisor)
         moe_out, aux = self.moe(
@@ -443,6 +555,9 @@ class DMoETransformerLM:
             ),
         )
         x = x + moe_out.reshape(b, s, d)
+        if "shared" in lp:
+            with jax.named_scope("shared_expert"):
+                x = x + gated_mlp(lp["shared"], ffn_in, self.moe._gate_act)
         return x, aux
 
     def _hidden(
@@ -496,12 +611,15 @@ class DMoETransformerLM:
                 body, x,
                 (params["layers"], jnp.arange(cfg.n_layers, dtype=jnp.int32)),
             )
+            # a mixture layer's assignments per expert stay a layer's own
+            counts = aux_stack.pop("expert_counts", None)  # [layers, E]
             aux_total = {k: jnp.sum(v) for k, v in aux_stack.items()}
         else:
             # unrolled: per-layer params, either static slices of the
             # stacked tree (same checkpoint layout as scan) or direct
             # leaves of the unstacked tuple (no slice-out copies)
             aux_total = None
+            counts = []
             for i in range(cfg.n_layers):
                 lp = (
                     jax.tree_util.tree_map(lambda l: l[i], params["layers"])
@@ -512,6 +630,10 @@ class DMoETransformerLM:
                     x, aux = layer_fn(
                         lp, x, i, token_mask, cfg.attention_layer(i)
                     )
+                if aux is None:  # a dense layer routes nothing
+                    continue
+                if "expert_counts" in aux:
+                    counts.append(aux.pop("expert_counts"))
                 aux_total = (
                     aux
                     if aux_total is None
@@ -520,7 +642,12 @@ class DMoETransformerLM:
         if self._zig is not None:
             x = x[:, self._zig_inv]
         x = self._norm(params["ln_f"], x)
-        aux_mean = {k: v / cfg.n_layers for k, v in aux_total.items()}
+        n_moe = (cfg.ffn_pattern or ("moe",) * cfg.n_layers).count("moe")
+        aux_mean = {k: v / n_moe for k, v in aux_total.items()}
+        if cfg.router_bias:  # [mixture layers, E]: the balancing rule's
+            aux_mean["expert_counts"] = (
+                counts if cfg.scan_layers else jnp.stack(counts)
+            )
         return x, aux_mean
 
     def _head(self, params: Params) -> jax.Array:
@@ -630,15 +757,17 @@ class DMoETransformerLM:
             return prompt_ids
         if use_cache:
             if (
-                self._grouped_or_windowed
+                self._grouped_or_windowed or self._other_ffn
                 or self.cfg.router_input != "moe_input"
             ):
                 raise NotImplementedError(
                     "use_cache=True: the KV-cache decoder keeps one "
-                    "key/value head a query head, masks by position alone "
-                    "and routes on the experts' input: no grouped "
+                    "key/value head a query head, masks by position alone, "
+                    "routes on the experts' input and runs a mixture that "
+                    "holds its experts in every layer: no grouped "
                     "key/value heads, no window, no router on the "
-                    "attention's input; decode without the cache"
+                    "attention's input, no dense layer, shared expert or "
+                    "share of the experts; decode without the cache"
                 )
             if self.cfg.seq_parallel:
                 raise NotImplementedError(
@@ -950,6 +1079,87 @@ class DMoETransformerLM:
             )
         return ce_sum
 
+    # ---- the routers' selection biases ----
+
+    def _router_biases(self, params: Params) -> list | None:
+        """The mixture layers' selection biases, in order (under the
+        stacked layout the one [layers, E] array that holds them all);
+        None where the routers have none."""
+        if not self.cfg.router_bias:
+            return None
+        if self.cfg.stack_layers:
+            return [params["layers"]["moe"]["router_bias"]]
+        return [lp["moe"]["router_bias"] for lp in params["layers"] if "moe" in lp]
+
+    def _balance(self, params: Params, biases: list | None, counts) -> Params:
+        """``params`` with the selection biases as they are after a step:
+        no gradient moves them (what the optimizer made of their zero
+        gradient is discarded: ``biases`` are the step's own, from
+        :meth:`_router_biases`), the balancing rule does,
+        ``router_bias_rate`` toward level on the step's ``counts``
+        [mixture layers, E]."""
+        if biases is None:
+            return params
+        if self.cfg.stack_layers:
+            counts = [counts]
+        with jax.named_scope("router_bias"):
+            moved = iter([
+                balanced_bias(b, c, self.cfg.router_bias_rate)
+                for b, c in zip(biases, counts)
+            ])
+
+        def put(lp):
+            if "moe" not in lp:
+                return lp
+            return {**lp, "moe": {**lp["moe"], "router_bias": next(moved)}}
+
+        layers = params["layers"]
+        return {**params, "layers": (
+            put(layers) if self.cfg.stack_layers else tuple(map(put, layers))
+        )}
+
+    def level_router_bias(self, params: Params, token_batches: list):
+        """``params`` with every mixture layer's selection bias levelled on
+        ``token_batches`` (a list of [B, S] id arrays: the pool a run
+        trains on), and what each layer's largest load over the mean was
+        before and after.  A layer at a time: the layer's router scores
+        on the stream the layers before it leave, ``level_bias`` on them,
+        then the levelled layer's own output on to the next.  Under
+        seeded random weights a router sends a token id's every
+        occurrence to the same few experts; a trained router's bias has
+        levelled that, and this stands in for the training.  A set-up
+        call, outside any step; unrolled per-layer parameters only."""
+        cfg = self.cfg
+        if not cfg.router_bias:
+            return params, []
+        if cfg.stack_layers:
+            raise NotImplementedError(
+                "level_router_bias walks per-layer parameter trees "
+                "(stack_layers=False)"
+            )
+        embed = jax.jit(lambda table, ids: table[ids].astype(cfg.dtype))
+        attend = jax.jit(self._attention_block, static_argnums=(2,))
+        finish = jax.jit(self._ffn_block)
+        scores = jax.jit(lambda lp, x: jax.nn.sigmoid(self.moe.router_logits(
+            lp["moe"], self._norm(lp["ln2"], x).reshape(-1, cfg.d_model))))
+        streams = [embed(params["embed"], ids) for ids in token_batches]
+        layers, loads = list(params["layers"]), []
+        if cfg.router_input != "moe_input":
+            raise NotImplementedError(
+                "level_router_bias reads the router on the experts' input"
+            )
+        for i, lp in enumerate(layers):
+            streams = [attend(lp, x, cfg.attention_layer(i))[0] for x in streams]
+            if "moe" in lp:
+                bias, load = level_bias(
+                    jnp.concatenate([scores(lp, x) for x in streams]),
+                    lp["moe"]["router_bias"], cfg.k,
+                )
+                lp = layers[i] = {**lp, "moe": {**lp["moe"], "router_bias": bias}}
+                loads.append(load)
+            streams = [finish(lp, x, None, i)[0] for x in streams]
+        return {**params, "layers": tuple(layers)}, loads
+
     def init_opt_state(
         self, optimizer: optax.GradientTransformation, params: Params
     ):
@@ -992,8 +1202,12 @@ class DMoETransformerLM:
 
         def train_step(params, opt_state, token_ids, targets):
             (loss, metrics), grads = grad_fn(params, token_ids, targets)
+            biases = self._router_biases(params)
             with jax.named_scope("optimizer"):
                 params, opt_state = apply_fn(params, grads, opt_state)
+            params = self._balance(
+                params, biases, metrics.pop("expert_counts", None)
+            )
             return params, opt_state, loss, metrics
 
         def accum_step(params, opt_state, token_ids, targets):
@@ -1029,8 +1243,13 @@ class DMoETransformerLM:
             # (its state dtypes key off the PARAM dtype); the optax
             # fallback's apply_fn casts to param dtype itself
             grads = jax.tree_util.tree_map(lambda g: g * inv, gsum)
+            biases = self._router_biases(params)
             with jax.named_scope("optimizer"):
                 params, opt_state = apply_fn(params, grads, opt_state)
+            # the microbatches' assignments together
+            params = self._balance(
+                params, biases, msum.pop("expert_counts", None)
+            )
             metrics = jax.tree_util.tree_map(lambda m: m * inv, msum)
             return params, opt_state, lsum * inv, metrics
 

@@ -59,18 +59,25 @@ def qkv_projections(
     ``n_heads`` (not ``d / n_heads``: 28 heads of 128 leave a stream of
     2560), the key/value head count ``wk``'s width over the head size
     (query head h reads key/value head ``h // (H / Hkv)``);
-    ``q_norm``/``k_norm`` in ``lp`` → RMSNorm over the WHOLE query and key
-    projections, before the split into heads (OLMoE); ``positions`` [S] →
+    ``q_norm``/``k_norm`` in ``lp`` → RMSNorm of the queries and keys, over
+    what its scale spans: the WHOLE projection, before the split into
+    heads (OLMoE: a scale as wide as the projection), or each head's own
+    ``hd`` (one scale of ``hd`` shared by the heads); ``positions`` [S] →
     rotary embedding of q and k after it."""
     b, s, _ = x.shape
     hd = lp["wq"].shape[-1] // n_heads
 
     def project(w: str, norm: str | None) -> jax.Array:
         y = x @ lp[w].astype(x.dtype)
-        if norm in lp:
+        whole = norm in lp and lp[norm]["scale"].shape[-1] == y.shape[-1]
+        if whole:
             with jax.named_scope("qk_norm"):
                 y = rms_norm(lp[norm], y, norm_eps)
-        return y.reshape(b, s, y.shape[-1] // hd, hd)
+        y = y.reshape(b, s, y.shape[-1] // hd, hd)
+        if norm in lp and not whole:
+            with jax.named_scope("qk_norm"):
+                y = rms_norm(lp[norm], y, norm_eps)
+        return y
 
     q = project("wq", "q_norm")
     k = project("wk", "k_norm")
@@ -86,6 +93,14 @@ def output_projection(lp: dict, out: jax.Array) -> jax.Array:
     """[B,S,H,hd] → [B,S,d] @ wo."""
     b, s, h, hd = out.shape
     return out.reshape(b, s, h * hd) @ lp["wo"].astype(out.dtype)
+
+
+def gated_mlp(p: dict, x: jax.Array, act=jax.nn.silu) -> jax.Array:
+    """A dense gated feed-forward block on [.., d], no biases:
+    ``(act(x Wg) * (x Wu)) Wd`` (a layer's dense feed-forward part, or the
+    shared expert every token passes beside the routed ones)."""
+    h = act(x @ p["w_gate"].astype(x.dtype)) * (x @ p["w_up"].astype(x.dtype))
+    return h @ p["w_down"].astype(x.dtype)
 
 
 def causal_attention(

@@ -347,18 +347,63 @@ class DroplessPlan(NamedTuple):
     aux_loss: jax.Array  # [] load-balance auxiliary, as the capacity plans'
 
 
+def _expert_counts(top_i: jax.Array, num_experts: int) -> jax.Array:
+    """[E] int32: assignments per expert of the choices ``top_i`` [n, k]."""
+    return jnp.sum(
+        jax.nn.one_hot(top_i.reshape(-1), num_experts, dtype=jnp.int32), axis=0
+    )
+
+
+def router_choice(
+    logits: jax.Array, k: int, renormalize: bool = True,
+    score: str = "softmax", bias: jax.Array | None = None,
+    scale: float = 1.0,
+) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """logits [n, E] float32 → ``(gates [n, E], top_w [n, k], top_i [n, k])``:
+    the k experts a token is sent to, the weight of each, and the scores
+    over ALL experts that the load-balance auxiliary reads (they sum to 1).
+
+    ``score="softmax"``: the k largest of the softmax over all experts,
+    as they come or renormalised to sum to 1.  ``score="sigmoid"``: every
+    expert scored on its own, ``s = sigmoid(logits)``; the k largest of
+    ``s + bias`` are chosen and weighed by ``s`` ALONE (``bias`` [E]
+    selects and does not weigh: it enters nothing differentiable, so its
+    gradient is zero), renormalised over the chosen, times ``scale``."""
+    if score == "softmax":
+        if bias is not None:
+            raise ValueError("a selection bias goes with score='sigmoid'")
+        gates = jax.nn.softmax(logits, axis=-1)
+        top_w, top_i = _topk_weights(gates, k, renormalize)
+    elif score == "sigmoid":
+        s = jax.nn.sigmoid(logits)
+        _, top_i = _top_k(s if bias is None else s + bias, k)
+        top_w = jnp.take_along_axis(s, top_i, axis=-1)
+        if renormalize:
+            top_w = top_w / jnp.maximum(
+                top_w.sum(axis=-1, keepdims=True), jnp.finfo(top_w.dtype).tiny
+            )
+        gates = s / s.sum(axis=-1, keepdims=True)
+    else:
+        raise ValueError(f"score must be 'softmax' or 'sigmoid', got {score!r}")
+    if scale != 1.0:
+        top_w = top_w * scale
+    return gates, top_w, top_i
+
+
 def dropless_routing(
     logits: jax.Array, k: int, renormalize: bool = True,
-    token_mask: jax.Array | None = None,
+    token_mask: jax.Array | None = None, score: str = "softmax",
+    bias: jax.Array | None = None, scale: float = 1.0,
 ) -> DroplessPlan:
-    """logits [n, E] float32 → the k largest softmax gates per token (as
-    they come, or renormalised to sum to 1) and the expert-sorted order.
+    """logits [n, E] float32 → the k experts per token and their weights
+    (:func:`router_choice`) and the expert-sorted order.
     ``token_mask`` [n] bool: padding tokens are computed like any other
     (there is no capacity for them to claim) with weight 0, and stay out
     of the aux loss."""
     num_experts = logits.shape[1]
-    gates = jax.nn.softmax(logits, axis=-1)
-    top_w, top_i = _topk_weights(gates, k, renormalize)
+    gates, top_w, top_i = router_choice(
+        logits, k, renormalize, score, bias, scale
+    )
     if token_mask is not None:
         top_w = jnp.where(token_mask[:, None], top_w, 0.0)
     flat = top_i.reshape(-1)
@@ -374,6 +419,143 @@ def dropless_routing(
         order, inverse, group_sizes, top_w,
         _load_balance_loss(gates, top_i, token_mask),
     )
+
+
+# ---- a share of the experts: route over all, compute the held ones' part ----
+
+
+class SharePlan(NamedTuple):
+    """Routing decision of a layer that holds ``G`` consecutive experts of
+    the ``E`` its router scores.  Every token is routed over all ``E``; the
+    assignments that fall on a held expert are sorted by expert into a
+    buffer of ``R`` rows (a static size: how many land here is decided by
+    the data), expert g's rows the ``group_sizes[g]`` consecutive ones
+    after those of the held experts before it.  Assignments beyond the
+    buffer are dropped and counted; buffer rows beyond the assignments
+    are empty (``valid`` False, weight 0)."""
+
+    token: jax.Array  # [R] int32 — the token each buffer row computes
+    weight: jax.Array  # [R] float32 — its gate weight, as normalised over all k chosen
+    valid: jax.Array  # [R] bool — the row holds an assignment
+    group_sizes: jax.Array  # [G] int32 — rows per held expert; sums to at most R
+    counts: jax.Array  # [E] int32 — assignments per expert, held or not
+    routed_here: jax.Array  # [] int32 — assignments that fall on a held expert
+    aux_loss: jax.Array  # [] load-balance auxiliary over all E, as the other plans'
+
+
+def share_buffer_rows(n: int, k: int, held: int, num_experts: int) -> int:
+    """Rows of a share's sorted buffer, from the shape alone: twice the
+    level share of the ``n * k`` assignments (``held / num_experts`` of
+    them), whole row tiles of the grouped matmul (256) where the rule
+    gives tiles at all."""
+    rows = -(-2 * n * k * held // num_experts)
+    if rows >= GROUPED_MATMUL_MIN_ROWS:
+        rows = -(-rows // 256) * 256
+    return min(rows, n * k)
+
+
+def share_routing(
+    logits: jax.Array, k: int, first: int, held: int, rows: int,
+    renormalize: bool = True, token_mask: jax.Array | None = None,
+    score: str = "softmax", bias: jax.Array | None = None,
+    scale: float = 1.0,
+) -> SharePlan:
+    """logits [n, E] float32 → the plan of the share that holds experts
+    ``first .. first + held - 1`` in a buffer of ``rows`` rows.  The gates
+    are :func:`router_choice`'s over all ``E``: what the absent experts
+    would have added is left out, not renormalised away."""
+    n, num_experts = logits.shape
+    gates, top_w, top_i = router_choice(
+        logits, k, renormalize, score, bias, scale
+    )
+    if token_mask is not None:
+        top_w = jnp.where(token_mask[:, None], top_w, 0.0)
+    local = top_i.reshape(-1) - first
+    # an assignment to an absent expert sorts behind every held one
+    key = jnp.where((local >= 0) & (local < held), local, held)
+    # stable: by held expert, then by token
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)[:rows]
+    counts = _expert_counts(top_i, num_experts)
+    here = jax.lax.dynamic_slice_in_dim(counts, first, held)
+    # the buffer takes the first ``rows``: the groups end where it ends
+    ends = jnp.minimum(jnp.cumsum(here), rows)
+    group_sizes = jnp.diff(ends, prepend=0)
+    valid = jnp.arange(rows, dtype=jnp.int32) < ends[-1]
+    return SharePlan(
+        order // k, jnp.where(valid, top_w.reshape(-1)[order], 0.0), valid,
+        group_sizes, counts, here.sum(),
+        _load_balance_loss(gates, top_i, token_mask),
+    )
+
+
+def share_sort_tokens(x: jax.Array, plan: SharePlan) -> jax.Array:
+    """[n, d] → [R, d]: the token of every assignment held here, rows
+    grouped by expert; empty rows zero (and their cotangent ignored)."""
+    return jnp.where(plan.valid[:, None], x[plan.token], 0)
+
+
+def share_combine(ys: jax.Array, plan: SharePlan, n: int) -> jax.Array:
+    """[R, d] sorted outputs of the held experts → [n, d] float32: each
+    token's gate-weighted sum over its assignments here (zero for a token
+    with none).  Rows outside every group hold whatever the grouped
+    matmul left there: they are masked, not multiplied by 0."""
+    weighted = jnp.where(
+        plan.valid[:, None],
+        plan.weight[:, None] * ys.astype(jnp.float32), 0.0,
+    )
+    return jnp.zeros((n, ys.shape[-1]), jnp.float32).at[plan.token].add(weighted)
+
+
+def balanced_bias(bias: jax.Array, counts: jax.Array, rate: float) -> jax.Array:
+    """One move of a router's selection bias toward level loads (the
+    auxiliary-loss-free rule, arXiv:2408.15664): ``rate`` up for every
+    expert under the mean of ``counts`` [.., E], ``rate`` down for every
+    one over it."""
+    counts = counts.astype(jnp.float32)
+    return bias + rate * jnp.sign(
+        counts.mean(axis=-1, keepdims=True) - counts
+    ).astype(bias.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("k", "moves"))
+def _level_moves(scores, bias, best, best_bias, rate, k: int, moves: int):
+    """``moves`` moves of :func:`balanced_bias` at ``rate`` on the counts of
+    the k largest of ``scores + bias``; beside the bias they end at, the
+    lowest largest-load-over-mean seen (``best``) and the bias that read it."""
+    num_experts = scores.shape[1]
+
+    def move(_, carry):
+        bias, best, best_bias = carry
+        counts = _expert_counts(_top_k(scores + bias, k)[1], num_experts)
+        load = jnp.max(counts) * (num_experts / (scores.shape[0] * k))
+        best_bias = jnp.where(load < best, bias, best_bias)
+        return balanced_bias(bias, counts, rate), jnp.minimum(load, best), best_bias
+
+    return jax.lax.fori_loop(0, moves, move, (bias, best, best_bias))
+
+
+def level_bias(
+    scores: jax.Array, bias: jax.Array, k: int,
+    rate: float = 0.02, moves: int = 24, floor: float = 1e-4,
+) -> tuple[jax.Array, list]:
+    """The selection bias that levels the loads of ``scores`` [n, E] (a
+    router's sigmoid scores on a pool of tokens): :func:`balanced_bias`
+    again and again on the counts of the k largest of ``scores + bias``,
+    ``moves`` moves at a rate from the best bias so far, then at half the
+    rate, until two rates in a row bring the largest load over the mean no
+    lower (or the rate falls under ``floor``).  Returns the bias under
+    which it was lowest, and that load before and after."""
+    inf = jnp.float32(jnp.inf)
+    # a rate of 0 moves nothing: one move reads the load under ``bias``
+    start = best = float(_level_moves(scores, bias, inf, bias, 0.0, k, 1)[1])
+    best_bias, stalled = bias, 0
+    while rate >= floor and stalled < 2:
+        _, low, best_bias = _level_moves(
+            scores, best_bias, jnp.float32(best), best_bias, jnp.float32(rate),
+            k, moves + 1)  # the last move's bias is read by the one after it
+        stalled = 0 if float(low) < best else stalled + 1
+        best, rate = float(low), rate / 2
+    return best_bias, [start, best]
 
 
 # The two row gathers below move rows along a permutation, so each one's

@@ -42,6 +42,10 @@ from learning_at_home_tpu.ops.moe_dispatch import (
     dispatch_tokens_indexed,
     dropless_routing,
     grouped_matmul,
+    share_buffer_rows,
+    share_combine,
+    share_routing,
+    share_sort_tokens,
     sort_tokens,
     top_k_gating,
     top_k_gating_indices,
@@ -90,6 +94,21 @@ class ShardedMixtureOfExperts:
     n*k assignments sorted by expert and run through a grouped matmul,
     nothing dropped (``_local_forward_dropless``); experts must be whole
     on every device (``expert`` axis 1).
+
+    ``held_experts=G`` (dropless only): this layer holds ``G`` of the
+    ``num_experts`` its router scores, experts ``first_held_expert ..
+    first_held_expert + G - 1`` (one chip's share of a layer whose experts
+    no chip holds whole).  It routes over all of them, computes the part
+    of the result that its own give, with the gates as normalised over all
+    k chosen, and leaves out what the absent experts would have added: no
+    exchange, nothing standing in for the other chips.  The expert stacks
+    are ``[G, ...]``, the gate stays ``[d, num_experts]``
+    (``_local_forward_share``).
+
+    ``router_score="sigmoid"``: every expert scored on its own, the k
+    largest chosen and renormalised, times ``routed_scale``;
+    ``router_bias=True`` adds the parameter ``router_bias`` [E] float32 to
+    the scores for the CHOICE alone (``ops.moe_dispatch.router_choice``).
     """
 
     def __init__(
@@ -109,6 +128,11 @@ class ShardedMixtureOfExperts:
         routing: str = "capacity",
         renormalize: bool = True,
         router_input: bool = False,
+        held_experts: int | None = None,
+        first_held_expert: int = 0,
+        router_score: str = "softmax",
+        router_bias: bool = False,
+        routed_scale: float = 1.0,
     ):
         if dispatch_impl not in ("auto", "gather", "onehot"):
             raise ValueError(
@@ -135,6 +159,39 @@ class ShardedMixtureOfExperts:
                 "routing='capacity': the slot program's gate, jitter and "
                 "dispatch all read the one token tensor it exchanges over "
                 "'expert'; only the dropless path takes two"
+            )
+        if router_score not in ("softmax", "sigmoid"):
+            raise ValueError(
+                f"router_score must be 'softmax' or 'sigmoid', got "
+                f"{router_score!r}"
+            )
+        if (router_score != "softmax" or routed_scale != 1.0) and (
+            routing != "dropless"
+        ):
+            raise NotImplementedError(
+                "router_score='sigmoid' / routed_scale with routing="
+                "'capacity': the slot program's gates are a softmax over "
+                "all experts; only the dropless path scores otherwise"
+            )
+        if router_bias and router_score != "sigmoid":
+            raise ValueError(
+                "router_bias=True is the selection bias of a sigmoid router"
+            )
+        held = num_experts if held_experts is None else held_experts
+        if not 0 <= first_held_expert <= num_experts - held or held < 1:
+            raise ValueError(
+                f"experts {first_held_expert}..{first_held_expert + held - 1}"
+                f" are not among the router's {num_experts}"
+            )
+        if held < num_experts and (
+            routing != "dropless" or mesh.shape.get("expert", 1) > 1
+            or expert_kind == "gelu"
+        ):
+            raise NotImplementedError(
+                f"held_experts={held} of {num_experts}: a share is the "
+                "dropless path of the gated kinds on a mesh whose 'expert' "
+                "axis is 1; across chips the shares' rows need the ragged "
+                "all-to-all"
             )
         if "expert" not in mesh.axis_names:
             raise ValueError("mesh must have an 'expert' axis")
@@ -176,6 +233,11 @@ class ShardedMixtureOfExperts:
         self.routing = routing
         self.renormalize = renormalize
         self.router_input = router_input
+        self.held_experts = held
+        self.first_held_expert = first_held_expert
+        self.router_score = router_score
+        self.router_bias = router_bias
+        self.routed_scale = routed_scale
         # the gate branch's activation of the gated kinds
         self._gate_act = jax.nn.relu if expert_kind == "gated_relu" else jax.nn.silu
         if routing == "dropless" and (self.ep > 1 or self.tp > 1):
@@ -195,7 +257,10 @@ class ShardedMixtureOfExperts:
         """``device_put=False`` returns the raw tree (for callers that
         stack layers under vmap and shard the stacked result themselves)."""
         kg, k1, k2 = jax.random.split(rng, 3)
-        d, e, f = self.hidden_dim, self.num_experts, self.ffn_dim
+        # e: the experts whose matrices are here (a share's G; the router
+        # keeps its width)
+        d, e, f = self.hidden_dim, self.held_experts, self.ffn_dim
+        n_scored = self.num_experts
         init = jax.nn.initializers.lecun_normal()
         # near-zero router init: logits start ~flat so top-k routing is
         # near-uniform and the capacity drop starts low (lecun-scale gate
@@ -209,19 +274,23 @@ class ShardedMixtureOfExperts:
             per_expert = jax.nn.initializers.lecun_normal(batch_axis=0)
             k1a, k1b = jax.random.split(k1)
             params = {
-                "gate": gate_init(kg, (d, e), self.param_dtype),
+                "gate": gate_init(kg, (d, n_scored), self.param_dtype),
                 "w_gate": per_expert(k1a, (e, d, f), self.param_dtype),
                 "w_up": per_expert(k1b, (e, d, f), self.param_dtype),
                 "w_down": per_expert(k2, (e, f, d), self.param_dtype),
             }
         else:
             params = {
-                "gate": gate_init(kg, (d, e), self.param_dtype),
+                "gate": gate_init(kg, (d, n_scored), self.param_dtype),
                 "w1": init(k1, (e, d, f), self.param_dtype),
                 "b1": jnp.zeros((e, f), self.param_dtype),
                 "w2": init(k2, (e, f, d), self.param_dtype),
                 "b2": jnp.zeros((e, d), self.param_dtype),
             }
+        if self.router_bias:
+            # float32 whatever the parameters' dtype: it moves by 1e-3 a
+            # step, under bf16's resolution near 1
+            params["router_bias"] = jnp.zeros((n_scored,), jnp.float32)
         if not device_put:
             return params
         return jax.device_put(params, self.param_shardings())
@@ -246,6 +315,8 @@ class ShardedMixtureOfExperts:
         dim for callers that stack layers of MoE params (lax.scan)."""
         specs = dict(self._expert_param_specs())
         specs["gate"] = P()
+        if self.router_bias:
+            specs["router_bias"] = P()
         if stacked:
             specs = {name: P(None, *spec) for name, spec in specs.items()}
         return specs
@@ -295,8 +366,14 @@ class ShardedMixtureOfExperts:
         if self.routing == "dropless":
             aux_names = ("aux_loss", "router_z_loss", "dropped_fraction",
                          "expert_load_max_over_mean")
+            share = self.held_experts < self.num_experts
+            if share:
+                aux_names += ("local_rows_over_level",)
+            if self.router_bias:
+                aux_names += ("expert_counts", "router_bias_abs_max")
             return shard_map(
-                self._local_forward_dropless,
+                self._local_forward_share if share
+                else self._local_forward_dropless,
                 mesh=self.mesh,
                 # a None among the per-token arguments has no leaf to place
                 in_specs=(self.param_specs(),) + (P(self._shard),) * 3,
@@ -452,28 +529,16 @@ class ShardedMixtureOfExperts:
         # float32 operands at full precision, so that which experts are
         # the k largest does not hang on a bf16 rounding of the logits
         with jax.named_scope("router"):
-            logits = jnp.dot(
-                router_x.astype(jnp.float32), params["gate"].astype(jnp.float32),
-                precision=jax.lax.Precision.HIGHEST,
-            )
+            logits = self.router_logits(params, router_x)
             plan = dropless_routing(
-                logits, self.k, self.renormalize, token_mask
+                logits, self.k, self.renormalize, token_mask,
+                self.router_score, params.get("router_bias"),
+                self.routed_scale,
             )
         with jax.named_scope("moe_sort"):
             xs = sort_tokens(x.astype(compute), plan)  # [n*k, d]
         if self.expert_kind != "gelu":
-            with jax.named_scope("experts/gate_up"):
-                h = self._gate_act(
-                    grouped_matmul(
-                        xs, params["w_gate"].astype(compute), plan.group_sizes
-                    )
-                ) * grouped_matmul(
-                    xs, params["w_up"].astype(compute), plan.group_sizes
-                )
-            with jax.named_scope("experts/down"):
-                ys = grouped_matmul(
-                    h, params["w_down"].astype(compute), plan.group_sizes
-                )
+            ys = self._gated_experts(params, xs, plan.group_sizes)
         else:
             expert_of_row = jnp.repeat(
                 jnp.arange(self.num_experts), plan.group_sizes,
@@ -503,4 +568,92 @@ class ShardedMixtureOfExperts:
             "dropped_fraction": jnp.float32(0),
             "expert_load_max_over_mean": jax.lax.pmean(load, axes),
         }
+        if self.router_bias:
+            aux.update(self._bias_aux(params, plan.group_sizes))
+        return y, aux
+
+    def _gated_experts(
+        self, params: Params, xs: jax.Array, group_sizes: jax.Array
+    ) -> jax.Array:
+        """The gated experts on rows sorted by expert: two grouped matmuls,
+        the gate branch's activation, a third."""
+        compute = self.dtype
+        with jax.named_scope("experts/gate_up"):
+            h = self._gate_act(
+                grouped_matmul(xs, params["w_gate"].astype(compute), group_sizes)
+            ) * grouped_matmul(xs, params["w_up"].astype(compute), group_sizes)
+        with jax.named_scope("experts/down"):
+            return grouped_matmul(
+                h, params["w_down"].astype(compute), group_sizes
+            )
+
+    def router_logits(self, params: Params, router_x: jax.Array) -> jax.Array:
+        """[n, d] → [n, E] float32: the dropless router's logits, float32
+        operands at full precision."""
+        return jnp.dot(
+            router_x.astype(jnp.float32), params["gate"].astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST,
+        )
+
+    def _bias_aux(self, params: Params, counts: jax.Array) -> dict:
+        """What the balancing rule reads (the step's assignments per
+        expert, over every token shard) and the bias's largest size."""
+        return {
+            "expert_counts": jax.lax.psum(counts, self._shard),
+            "router_bias_abs_max": jnp.max(jnp.abs(params["router_bias"])),
+        }
+
+    def _local_forward_share(
+        self, params: Params, x: jax.Array,
+        token_mask: jax.Array | None = None,
+        router_x: jax.Array | None = None,
+    ) -> tuple[jax.Array, dict]:
+        """``_local_forward_dropless`` for a layer that holds a share of
+        its experts: route MY tokens over all ``num_experts``, sort the
+        assignments that fall on the held ones into a buffer of
+        ``share_buffer_rows`` rows, grouped matmuls over it, and add each
+        token's gate-weighted outputs (zero for a token with no assignment
+        here).  Assignments beyond the buffer are dropped and counted."""
+        compute = self.dtype
+        n = x.shape[0]
+        held, scored = self.held_experts, self.num_experts
+        if router_x is None:
+            router_x = x
+        with jax.named_scope("router"):
+            logits = self.router_logits(params, router_x)
+            plan = share_routing(
+                logits, self.k, self.first_held_expert, held,
+                share_buffer_rows(n, self.k, held, scored),
+                self.renormalize, token_mask, self.router_score,
+                params.get("router_bias"), self.routed_scale,
+            )
+        with jax.named_scope("moe_sort"):
+            xs = share_sort_tokens(x.astype(compute), plan)  # [R, d]
+        ys = self._gated_experts(params, xs, plan.group_sizes)
+        with jax.named_scope("moe_combine"):
+            y = share_combine(ys, plan, n).astype(x.dtype)
+
+        axes = self._shard
+        here = plan.routed_here.astype(jnp.float32)
+        kept = plan.group_sizes.sum().astype(jnp.float32)
+        aux = {
+            "aux_loss": jax.lax.pmean(plan.aux_loss, axes),
+            "router_z_loss": jax.lax.pmean(
+                _router_z_loss(logits, token_mask), axes
+            ),
+            # of the assignments that fall on a held expert
+            "dropped_fraction": jax.lax.pmean(
+                (here - kept) / jnp.maximum(here, 1.0), axes
+            ),
+            # over all the router's experts, held or not
+            "expert_load_max_over_mean": jax.lax.pmean(
+                jnp.max(plan.counts).astype(jnp.float32)
+                / (n * self.k / scored), axes
+            ),
+            "local_rows_over_level": jax.lax.pmean(
+                here / (n * self.k * held / scored), axes
+            ),
+        }
+        if self.router_bias:
+            aux.update(self._bias_aux(params, plan.counts))
         return y, aux

@@ -1,23 +1,27 @@
 #!/usr/bin/env python3
-"""Two readings the ``smallthinker-21b-a3b`` configuration rests on.
+"""Two readings a one-chip recipe's configuration rests on
+(``smallthinker-21b-a3b`` by default; ``k-exaone-236b-a23b`` by name).
 
-    python tools/smallthinker_probe.py memory
-    chiprun -- python tools/smallthinker_probe.py float8 [seed ...]
+    python tools/smallthinker_probe.py memory [recipe]
+    chiprun -- python tools/smallthinker_probe.py float8 [config.json] [seed ...]
 
-``memory`` (here, no chip): the whole train step of
-``__graft_entry__.smallthinker_one_chip`` at published widths, compiled
-for a described v5e chip; prints the compiler's ``memory_analysis()``
-against the chip's 16,909,334,528 bytes, and the tiles each of the
-step's 48 grouped-matmul instructions was compiled at (PERF.md section
-3: all of them ``grouped_matmul_tiles``'s since PR 32).  Nothing runs.
+``memory`` (here, no chip): the whole train step of a recipe of
+``__graft_entry__`` (``smallthinker_one_chip``, or the one named, such as
+``k_exaone_one_chip``) at published widths, compiled for a described v5e
+chip; prints the compiler's ``memory_analysis()`` against the chip's
+16,909,334,528 bytes, and the tiles each of the step's grouped-matmul
+instructions was compiled at (PERF.md section 3).  Nothing runs.
 
-``float8`` (on the chip): the benchmark runner's own comparison, on seeded
-weights after as many train steps as the cell's window leaves them (48,
-over the cell's pool of 8 seeded rows of 16,384 Zipf ids), of the program
-and then, in the program's place, of the configuration's plain reference
-with every matmul operand rounded to float8_e4m3: the reading that the
-runner's tolerances must refuse (PERF.md section 2).  bf16 operands
-follow, which they must pass.
+``float8`` (on the chip): the benchmark runner's own comparison of a
+configuration (``benchmarks/configs/smallthinker-21b-a3b.json``, or the
+file named), on seeded weights after as many train steps as the cell's
+window leaves them (the configuration's ``probe_steps``, 48 by default,
+over the cell's pool of 8 seeded rows of Zipf ids; the routers' biases
+levelled first where the recipe has them), of the program and then, in
+the program's place, of the configuration's plain reference with every
+matmul operand rounded to float8_e4m3: the reading that the runner's
+tolerances must refuse (PERF.md section 2).  bf16 operands follow, which
+they must pass.
 """
 
 import collections
@@ -52,9 +56,9 @@ def no_compile_cache():
         compilation_cache.reset_cache()
 
 
-def step_memory(chip) -> dict:
+def step_memory(chip, recipe: str = "smallthinker_one_chip") -> dict:
     """The compiler's memory analysis of the whole train step of
-    ``smallthinker_one_chip`` compiled for ``chip``, a described v5e
+    ``__graft_entry__.<recipe>`` compiled for ``chip``, a described v5e
     device (the caller makes ``jax.default_backend()`` answer ``tpu``, as
     on the chip), and under ``grouped_matmul_tilings`` how many of its
     grouped-matmul instructions run at which ``tm,tk,tn``."""
@@ -63,14 +67,14 @@ def step_memory(chip) -> dict:
     import numpy as np
     from jax.sharding import Mesh
 
-    from __graft_entry__ import smallthinker_one_chip
+    import __graft_entry__
     from learning_at_home_tpu.parallel.mesh import (
         batch_sharding,
         opt_state_shardings,
     )
 
     mesh = Mesh(np.array([chip]), ("expert",))
-    model, cfg, optimizer, batch = smallthinker_one_chip(mesh)
+    model, cfg, optimizer, batch = getattr(__graft_entry__, recipe)(mesh)
     assert model.cfg.attn_impl == "flash"
 
     def placed(tree, shardings):
@@ -109,7 +113,7 @@ def step_memory(chip) -> dict:
     }
 
 
-def memory() -> None:
+def memory(recipe: str = "smallthinker_one_chip") -> None:
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     import jax
     from jax.experimental import topologies
@@ -117,25 +121,25 @@ def memory() -> None:
     topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     # the chip is described, not attached: the recipe must resolve as on it
     jax.default_backend = lambda: "tpu"
-    print(json.dumps(step_memory(topo.devices[0])))
+    print(json.dumps(step_memory(topo.devices[0], recipe)))
 
 
-def float8(seeds: list) -> None:
+def float8(seeds: list, config_path: str = CONFIG) -> None:
     import jax
     import jax.numpy as jnp
     import numpy as np
 
+    import __graft_entry__
     import harness
-    from __graft_entry__ import smallthinker_one_chip
     from learning_at_home_tpu.parallel.mesh import batch_sharding, make_mesh
 
     manifest = harness.load_manifest("BENCHMARK.json")
-    config = harness.load_json(os.path.join(REPO, CONFIG))
+    config = harness.load_json(os.path.join(REPO, config_path))
     runner = harness.load_module(manifest, "runners", config["runner"])
     recipe = harness.load_module(manifest, "runners", "train_recipe")
     reference = harness.load_path(os.path.join(REPO, config["reference"]))
     mesh = make_mesh({"expert": 1}, devices=jax.devices()[:1])
-    model, cfg, optimizer, rows = smallthinker_one_chip(mesh)
+    model, cfg, optimizer, rows = getattr(__graft_entry__, config["recipe"])(mesh)
     step = model.make_train_step(optimizer)
     for seed in seeds:
         words = harness.seed_words(seed, 4)
@@ -145,7 +149,10 @@ def float8(seeds: list) -> None:
             np.random.default_rng(words[2:]), cfg.vocab_size, rows, cfg.seq_len, 8)
         pool = [tuple(jax.device_put(a, batch_sharding(mesh)) for a in pair)
                 for pair in batches]
-        for i in [0, 1] + [(2 + j) % 8 for j in range(45)] + [0]:
+        if cfg.router_bias:  # the runner's set-up call
+            params, _ = model.level_router_bias(params, [ids for ids, _ in pool])
+        steps = config.get("probe_steps", 48)
+        for i in [0, 1] + [(2 + j) % 8 for j in range(steps - 3)] + [0]:
             params, opt_state, _, _ = step(params, opt_state, *pool[i])
         del opt_state
         ids, tgt = batches[0][0][:1], batches[0][1][:1]
@@ -166,8 +173,10 @@ def float8(seeds: list) -> None:
 
 if __name__ == "__main__":
     if sys.argv[1:2] == ["memory"]:
-        memory()
+        memory(*sys.argv[2:3])
     elif sys.argv[1:2] == ["float8"]:
-        float8([int(s) for s in sys.argv[2:]] or [3100000007])
+        named = [a for a in sys.argv[2:] if a.endswith(".json")]
+        float8([int(s) for s in sys.argv[2:] if s not in named] or [3100000007],
+               *named[:1])
     else:
         sys.exit(__doc__)
