@@ -18,6 +18,7 @@ with bfloat16 compute, static shapes throughout, optional per-layer remat
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Callable
 
 import jax
@@ -109,8 +110,8 @@ class DMoETransformerConfig:
     # "zigzag" balances causal work across the ring (~2× fewer attention
     # FLOPs at scale); "contiguous" is the plain ring
     seq_layout: str = "zigzag"
-    # token-chunk size for the rematerialized cross-entropy (peak logits
-    # memory = ce_chunk × vocab × 4 bytes; see loss_fn)
+    # token-chunk size of the cross-entropy (peak logits memory =
+    # ce_chunk × vocab × 4 bytes; see loss_fn)
     ce_chunk: int = 1024
     # ---- the block's shape: an architecture's description, not tuning
     # switches.  The defaults are the DMoE-Transformer of the seed paper;
@@ -997,12 +998,15 @@ class DMoETransformerLM:
     def _chunked_ce(self, x, head, targets):
         """Chunked cross-entropy: the [tokens, V] f32 logits are never
         materialized at once.  Token chunks of ``ce_chunk`` go through the
-        head + softmax-CE under ``jax.checkpoint`` inside a ``lax.scan``,
-        so peak logits memory is chunk×V and the backward recomputes each
-        chunk's logits (one extra head matmul ≈ few % FLOPs).  At the
-        256-expert flagship shape this is what lifts the per-chip batch
-        from 16 to 64 — the f32 logits (+ cotangents) were the dominant
-        activation term.
+        head + softmax-CE inside a ``lax.scan``, so peak logits memory is
+        chunk×V.  Under differentiation the same scan takes each chunk's
+        gradients from the logits it has (:func:`_ce_of_chunks`): the
+        forward ends holding the gradients with respect to ``x`` and the
+        head, the backward only multiplies them by its cotangent, and the
+        head is multiplied by three times a step, not four.  At the
+        256-expert flagship shape the chunking is what lifts the per-chip
+        batch from 16 to 64 — the f32 logits (+ cotangents) were the
+        dominant activation term.
 
         Which path runs is read off the mesh and the shapes:
 
@@ -1027,13 +1031,13 @@ class DMoETransformerLM:
         b_shards = int(np.prod([mesh.shape[a] for a in data_axes(mesh)]))
         s_shards = mesh.shape.get("seq", 1)
         if mesh.devices.size == 1 or b % b_shards or s % s_shards:
-            return self._chunked_ce_sum(x, head, targets) / (b * s)
+            return self._chunked_ce_sum(x, head, targets, b * s)
 
         from jax import shard_map
 
         spec = batch_sharding(mesh).spec  # P(batch axes[, "seq"])
         ce_sums = shard_map(  # one sum a shard, laid out like the shards
-            lambda xl, hl, tl: self._chunked_ce_sum(xl, hl, tl).reshape(
+            lambda xl, hl, tl: self._chunked_ce_sum(xl, hl, tl, b * s).reshape(
                 (1,) * len(spec)
             ),
             mesh=mesh,
@@ -1043,41 +1047,17 @@ class DMoETransformerLM:
             # varying over the batch axes: the varying-axes check refuses it
             check_vma=False,
         )(x, head, targets)
-        return ce_sums.sum() / (b * s)
+        return ce_sums.sum()
 
-    def _chunked_ce_sum(self, x, head, targets):
-        """Sum (f32) of the token CEs of ``x`` [b, s, d], ``ce_chunk``
-        tokens at a time."""
+    def _chunked_ce_sum(self, x, head, targets, denominator):
+        """Sum (f32) of the token CEs of ``x`` [b, s, d] over
+        ``denominator`` (the GLOBAL token count, also where ``x`` is one
+        shard's rows), ``ce_chunk`` tokens at a time: :func:`_ce_of_chunks`."""
         n = x.shape[0] * x.shape[1]
-        flat_x = x.reshape(n, x.shape[-1])
-        flat_t = targets.reshape(n)
-        chunk = min(self.cfg.ce_chunk, n)
-
-        def chunk_ce(carry, xt):
-            xc, tc = xt
-            logits = self._logits(xc, head)
-            ce = optax.softmax_cross_entropy_with_integer_labels(logits, tc)
-            return carry + ce.sum(), None
-
-        ce_sum = jnp.float32(0)
-        main = (n // chunk) * chunk
-        if main > chunk:  # scan the divisible prefix in chunk-size pieces
-            xs = (
-                flat_x[:main].reshape(main // chunk, chunk, -1),
-                flat_t[:main].reshape(main // chunk, chunk),
-            )
-            ce_sum, _ = jax.lax.scan(jax.checkpoint(chunk_ce), ce_sum, xs)
-        elif main:
-            ce_sum, _ = jax.checkpoint(chunk_ce)(
-                ce_sum, (flat_x[:main], flat_t[:main])
-            )
-        if n > main:  # sub-chunk remainder: one extra checkpointed call,
-            # so memory stays chunk-bounded for EVERY n (an indivisible n
-            # must not silently re-materialize full [n, V] logits)
-            ce_sum, _ = jax.checkpoint(chunk_ce)(
-                ce_sum, (flat_x[main:], flat_t[main:])
-            )
-        return ce_sum
+        return _ce_of_chunks(
+            x.reshape(n, x.shape[-1]), head, targets.reshape(n),
+            min(self.cfg.ce_chunk, n), denominator,
+        )
 
     # ---- the routers' selection biases ----
 
@@ -1264,3 +1244,122 @@ class DMoETransformerLM:
             in_shardings=(None, None, data_shard, data_shard),
             donate_argnums=(0, 1),
         )
+
+
+# ---- the loss layer: the token CEs a chunk at a time ----
+
+
+def _softmax_ce(logits: jax.Array, targets: jax.Array):
+    """Summed CE (f32) of a chunk's rows from its float32 ``logits``
+    [c, V], with the two pieces the gradient is made of: the exponentials
+    ``e`` [c, V] (of the logits less the row's largest) and their row sums
+    ``s`` [c, 1].  ``softmax = e / s``."""
+    top = logits.max(axis=-1, keepdims=True)
+    e = jnp.exp(logits - top)
+    s = e.sum(axis=-1, keepdims=True)
+    label = jnp.take_along_axis(logits, targets[:, None], axis=-1)
+    return (jnp.log(s) + top - label).sum(), e, s
+
+
+def _over_chunks(step, carry, flat_x, flat_t, chunk: int):
+    """``step(carry, (rows, targets)) -> (carry, out)`` over ``flat_x``
+    [n, d] and ``flat_t`` [n], ``chunk`` rows at a time: a ``lax.scan``
+    over the divisible prefix (one call where that is a single chunk),
+    then one more call for a sub-chunk remainder, so that no call sees
+    more than a chunk for EVERY n (an indivisible n must not silently
+    materialize full [n, V] logits).  The scan takes its last chunk first,
+    the order in which the transpose of a forward scan added the chunks'
+    shares of the head's gradient: in the head's dtype that sum depends on
+    its order, and a train step keeps the bits it had.  Returns the carry
+    and the calls' outputs, their rows in the order of ``flat_x``'s."""
+    n = flat_x.shape[0]
+    main = (n // chunk) * chunk
+    outs = []
+    if main > chunk:
+        xs = (
+            flat_x[:main].reshape(main // chunk, chunk, -1),
+            flat_t[:main].reshape(main // chunk, chunk),
+        )
+        carry, out = jax.lax.scan(step, carry, xs, reverse=True)
+        outs.append(jax.tree_util.tree_map(
+            lambda o: o.reshape(main, *o.shape[2:]), out
+        ))
+    elif main:
+        carry, out = step(carry, (flat_x[:main], flat_t[:main]))
+        outs.append(out)
+    if n > main:
+        carry, out = step(carry, (flat_x[main:], flat_t[main:]))
+        outs.append(out)
+    return carry, outs
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _ce_of_chunks(flat_x, head, flat_t, chunk: int, denominator: int):
+    """Sum (f32) over ``denominator`` of the CEs of the rows ``flat_x``
+    [n, d] against ``head`` [d, V] and the targets ``flat_t`` [n]: one
+    head product and one softmax a chunk, no [n, V] array at any time.
+
+    Differentiated (:func:`_ce_of_chunks_fwd`), the same pass over the
+    same chunks also takes the gradients, from the logits it has: the
+    loss is a scalar, so they need nothing a backward pass would bring,
+    and the head is multiplied by three times a step (logits, and the
+    two gradient products) where recomputing each chunk's logits in the
+    backward made it four."""
+
+    def step(ce_sum, rows_targets):
+        rows, targets = rows_targets
+        ce, _, _ = _softmax_ce(DMoETransformerLM._logits(rows, head), targets)
+        return ce_sum + ce, None
+
+    ce_sum, _ = _over_chunks(step, jnp.float32(0), flat_x, flat_t, chunk)
+    return ce_sum / denominator
+
+
+def _ce_of_chunks_fwd(flat_x, head, flat_t, chunk, denominator):
+    """The value, and as residuals its gradients with respect to
+    ``flat_x`` (a chunk's rows from each step, stacked by the scan) and
+    ``head`` (a carry of the scan, accumulated in the head's dtype as the
+    backward scan's transpose did).  A chunk's ``d = (softmax - onehot) /
+    denominator`` is float32, the divisor on it before any cast, and the
+    two products are the transposes autodiff gives the logits' einsum:
+    float32 operands and results, cast to the rows' and the head's dtype
+    afterwards."""
+    scale = jnp.float32(1) / denominator
+
+    def step(carry, rows_targets):
+        ce_sum, d_head = carry
+        rows, targets = rows_targets
+        # the chunk's rows as an array of their own, as the barrier of
+        # jax.checkpoint held them: sliced out of the stack inside each
+        # product's fusion instead, they are not prefetched, and the head's
+        # gradient product compiles to tiles 29 % slower (PERF.md, PR 34)
+        rows = jax.lax.optimization_barrier(rows)
+        logits, gradient_products = jax.vjp(
+            DMoETransformerLM._logits, rows, head
+        )
+        ce, e, s = _softmax_ce(logits, targets)
+        softmax = e * (scale / s)
+        hit = jnp.arange(logits.shape[-1]) == targets[:, None]
+        d_rows, d_head_chunk = gradient_products(
+            jnp.where(hit, softmax - scale, softmax)
+        )
+        return (ce_sum + ce, d_head + d_head_chunk), d_rows
+
+    (ce_sum, d_head), d_rows = _over_chunks(
+        step, (jnp.float32(0), jnp.zeros_like(head)), flat_x, flat_t, chunk
+    )
+    d_x = d_rows[0] if len(d_rows) == 1 else jnp.concatenate(d_rows)
+    return ce_sum / denominator, (d_x, d_head)
+
+
+def _ce_of_chunks_bwd(chunk, denominator, gradients, cotangent):
+    """The forward's gradients times the scalar cotangent, in float32
+    (a train step's is 1, the mean's divisor being on them already: no
+    bit changes); the integer targets have no gradient."""
+    d_x, d_head = (
+        (g.astype(jnp.float32) * cotangent).astype(g.dtype) for g in gradients
+    )
+    return d_x, d_head, None
+
+
+_ce_of_chunks.defvjp(_ce_of_chunks_fwd, _ce_of_chunks_bwd)

@@ -844,5 +844,6 @@ def test_the_whole_step_fits_the_chip(v5e_chip, monkeypatch):
     assert 0.25 < memory["share_of_chip"] < 0.9, memory
     assert memory["grouped_matmul_tilings"] == {
         "256,2048,1024": 4 * 9, "256,1024,1024": 4 * 3}
+    assert memory["loss_layer_products"] == 3  # of the head's; four before PR 34
     assert moe_dispatch.grouped_matmul_tiles(16384, 6144, 2048, jnp.bfloat16) == (
         256, 2048, 1024)

@@ -497,20 +497,23 @@ def test_grouped_matmul_and_its_gradients_are_ragged_dots(m, a, b, dtype, how):
 
 # ---- the cells' programs are the programs they were ----
 
-# sha256 of make_train_step(...).lower(...).as_text() of
-# flagship_one_chip(tiny=True) on a one-device CPU mesh, taken on the
-# parent commit (613a39e) with this container's jax 0.9.0
+# sha256 of make_train_step(...).lower(...).as_text() with this container's
+# jax 0.9.0: flagship_one_chip(tiny=True) on a one-device CPU mesh, the
+# same recipe on data=2 x expert=2 (dmoe256-train-pod4's program) and
+# olmoe_one_chip(tiny=True) on one device (olmoe-1b-7b-train-zipf4k's).
+# All three re-taken on PR 34's tree (parent 90b8760), which was meant to
+# alter them: the loss layer takes a chunk's gradients in its forward scan
+# (transformer._ce_of_chunks), so the step has three products of the head's
+# where the checkpointed chunks' backward made a fourth.  Before that they
+# stood from 613a39e (the first) and from PR 29's parent f320cf8.
 DMOE_TINY_STEP_SHA256 = (
-    "80bdf4b59a2b64a8496795126dde40ecb9732b772fb0fef07eb25447a243b65b"
+    "e0b11dba135b5aaeec1aecbd588e14995b94e8e11213214b32055da855546846"
 )
-# the same recipe on data=2 x expert=2 (dmoe256-train-pod4's program) and
-# olmoe_one_chip(tiny=True) on one device (olmoe-1b-7b-train-zipf4k's),
-# both taken on the parent commit of PR 29 (f320cf8), same jax
 DMOE_TINY_POD4_STEP_SHA256 = (
-    "d99d70146066f3d9f2086167beaab8ceff3d8dd7a960d2d619fe38d4984e3909"
+    "e58a362779f1640ef78379b9ba2022628da3981a23f8191c889f7cdd57e27390"
 )
 OLMOE_TINY_STEP_SHA256 = (
-    "f43045fb35c4364f1075c0221363e421970378ed58fe880f456456dbdc9dde0b"
+    "a5ef2b58eef22ef79b1198357c93380731ca0f7daa7017d3ef0b0469a7f08651"
 )
 
 

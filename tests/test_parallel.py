@@ -290,13 +290,16 @@ def test_grad_accumulation_matches_mean_of_micro_grads():
     assert 0.0 <= float(metrics["dropped_fraction"]) <= 1.0
 
 
-def _assert_ce_matches_full_logits(m, params, ids, tgt, loss_tol, grad_tol):
+def _assert_ce_matches_full_logits(
+    m, params, ids, tgt, loss_tol, grad_tol, cotangent=1.0
+):
     """``loss_fn``'s chunked CE against the loss over the whole [B, S, V]
     float32 logits: the value, the gradients with respect to the hidden
-    states and the head (the loss layer alone), and the gradients with
-    respect to every parameter (a tied head takes the embedding's
-    cotangent from both ends).  ``grad_tol`` bounds ``max|a-b| / max|b|``
-    per leaf."""
+    states and the head (the loss layer alone, which takes them in its
+    forward scan and multiplies them by the ``cotangent`` that arrives:
+    1 in a train step), and the gradients with respect to every parameter
+    (a tied head takes the embedding's cotangent from both ends).
+    ``grad_tol`` bounds ``max|a-b| / max|b|`` per leaf."""
     cfg = m.cfg
 
     def full_ce(x, head):
@@ -332,9 +335,11 @@ def _assert_ce_matches_full_logits(m, params, ids, tgt, loss_tol, grad_tol):
 
     (loss, grads), ce_grads = both(
         lambda p: m.loss_fn(p, ids, tgt)[0],
-        lambda x, h: m._chunked_ce(x, h, tgt), params,
+        lambda x, h: cotangent * m._chunked_ce(x, h, tgt), params,
     )
-    (ref, ref_grads), ref_ce_grads = both(full_loss, full_ce, params)
+    (ref, ref_grads), ref_ce_grads = both(
+        full_loss, lambda x, h: cotangent * full_ce(x, h), params
+    )
     assert loss.dtype == jnp.float32
     assert abs(float(loss) - float(ref)) < loss_tol
     close(grads, ref_grads)
@@ -343,17 +348,19 @@ def _assert_ce_matches_full_logits(m, params, ids, tgt, loss_tol, grad_tol):
 
 @pytest.mark.parametrize("tied", [True, False], ids=["tied", "untied"])
 @pytest.mark.parametrize(
-    "batch, chunk, dtype",
+    "batch, chunk, dtype, cotangent",
     # n = 128, 80, 48 tokens: divisible, a remainder, less than a chunk;
-    # then bf16 storage, whose statistics must stay float32
-    [(8, 16, "float32"), (5, 16, "float32"), (3, 128, "float32"),
-     (8, 16, "bfloat16")],
+    # then bf16 storage, whose statistics must stay float32; then a
+    # cotangent other than a train step's 1 into the loss layer
+    [(8, 16, "float32", 1.0), (5, 16, "float32", 1.0),
+     (3, 128, "float32", 1.0), (8, 16, "bfloat16", 1.0),
+     (5, 16, "float32", -2.5), (8, 16, "bfloat16", 0.37)],
 )
-def test_chunked_ce_matches_full_logits(batch, chunk, dtype, tied):
-    """loss_fn's rematerialized CE must equal the full-logits loss, value
-    and gradients, for divisible AND indivisible token counts (the
-    indivisible remainder goes through an extra checkpointed chunk, never
-    full [n,V] logits).  With bf16 operands the logits and the softmax
+def test_chunked_ce_matches_full_logits(batch, chunk, dtype, cotangent, tied):
+    """loss_fn's chunked CE must equal the full-logits loss, value and
+    gradients, for divisible AND indivisible token counts (the
+    indivisible remainder goes through one more chunk, never full [n,V]
+    logits).  With bf16 operands the logits and the softmax
     statistics stay float32, so the loss sits within bf16 rounding of the
     float32-logits reference computed from the SAME bf16 inputs (a bf16
     softmax over 64 classes would be 1e-2 away); the gradients are bf16
@@ -372,7 +379,9 @@ def test_chunked_ce_matches_full_logits(batch, chunk, dtype, tied):
     ids = jnp.asarray(rs.randint(0, 64, (batch, 16)))
     tgt = jnp.asarray(rs.randint(0, 64, (batch, 16)))
     loss_tol, grad_tol = (1e-5, 1e-5) if dtype == "float32" else (1e-3, 2e-2)
-    _assert_ce_matches_full_logits(m, params, ids, tgt, loss_tol, grad_tol)
+    _assert_ce_matches_full_logits(
+        m, params, ids, tgt, loss_tol, grad_tol, cotangent
+    )
 
 
 @pytest.mark.parametrize(
@@ -428,6 +437,10 @@ def _loss_layer_ops(hlo_text, opcode_re):
         ({"expert": 8}, 16, 16),
         ({"expert": 8}, 24, 20),
         ({"expert": 8}, 8, 128),
+        # the sequence sharded too, 16 tokens a shard: a scan of 4; a
+        # scan of 2 and a remainder
+        ({"data": 2, "expert": 2, "seq": 2}, 8, 4),
+        ({"expert": 4, "seq": 2}, 8, 6),
     ],
     ids=lambda v: (
         "x".join(f"{k}{n}" for k, n in v.items()) if isinstance(v, dict)
@@ -441,7 +454,9 @@ def test_chunked_ce_per_shard_matches_global_scan(axes, batch, chunk):
     n_dev = int(np.prod(list(axes.values())))
     mesh = make_mesh(axes, devices=jax.devices()[:n_dev])
     _, cfg = _tiny_model(mesh)
-    cfg = dataclasses.replace(cfg, ce_chunk=chunk, n_layers=1)
+    cfg = dataclasses.replace(
+        cfg, ce_chunk=chunk, n_layers=1, seq_parallel="seq" in axes
+    )
     m = DMoETransformerLM(cfg, mesh)
     params = m.init_params(jax.random.PRNGKey(0))
     rs = np.random.RandomState(7)
@@ -464,7 +479,7 @@ def test_chunked_ce_per_shard_matches_global_scan(axes, batch, chunk):
 
     def global_scan_loss(p):  # loss_fn with the CE of the one-device path
         x, aux = m._hidden(p, ids)
-        return with_aux(m._chunked_ce_sum(x, m._head(p), tgt) / tgt.size, aux)
+        return with_aux(m._chunked_ce_sum(x, m._head(p), tgt, tgt.size), aux)
 
     # the path under test is the per-shard one (the expert layer has a
     # shard_map of its own, so the loss layer is traced alone)
@@ -494,6 +509,245 @@ def test_chunked_ce_per_shard_matches_global_scan(axes, batch, chunk):
             np.asarray(g), np.asarray(r), rtol=0, atol=1e-5,
             err_msg=jax.tree_util.keystr(path),
         )
+
+
+def _loss_layer_alone(axes, dtype, chunk, vocab=64, batch=8, seed=11):
+    """A model on ``axes`` whose loss layer is called by itself: hidden
+    states [batch, 16, 32] and targets laid out like a step's batch, and
+    an untied head [32, vocab], in ``dtype``."""
+    n_dev = int(np.prod(list(axes.values())))
+    mesh = make_mesh(axes, devices=jax.devices()[:n_dev])
+    m = DMoETransformerLM(
+        DMoETransformerConfig(
+            vocab_size=vocab, d_model=32, n_layers=1, n_heads=4, seq_len=16,
+            num_experts=8, k=2, dtype=jnp.dtype(dtype), ce_chunk=chunk,
+            tie_embeddings=False, seq_parallel="seq" in axes,
+        ),
+        mesh,
+    )
+    rs = np.random.RandomState(seed)
+    spec = batch_sharding(mesh).spec
+    x = jax.device_put(
+        jnp.asarray(rs.randn(batch, 16, 32), dtype),
+        jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec(*spec, None)),
+    )
+    head = jnp.asarray(0.3 * rs.randn(32, vocab), dtype)
+    tgt = jax.device_put(
+        jnp.asarray(rs.randint(0, vocab, (batch, 16))), batch_sharding(mesh)
+    )
+    return m, x, head, tgt
+
+
+@pytest.mark.parametrize("cotangent", [1.0, -0.75], ids=["one", "other"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize(
+    "axes, chunk",
+    [
+        # tokens a shard / chunk: 32 / 16; 32 / 12 (a remainder); 16 / 128;
+        # with the sequence sharded too: 16 / 4, 32 / 12
+        ({"data": 4, "expert": 1}, 16),
+        ({"data": 2, "expert": 2}, 12),
+        ({"expert": 8}, 128),
+        ({"data": 2, "expert": 2, "seq": 2}, 4),
+        ({"expert": 2, "seq": 2}, 12),
+    ],
+    ids=lambda v: (
+        "x".join(f"{k}{n}" for k, n in v.items()) if isinstance(v, dict)
+        else str(v)
+    ),
+)
+def test_loss_layer_gradients_per_shard_match_full_logits(
+    axes, chunk, dtype, cotangent
+):
+    """The loss layer takes its gradients in its forward scan, per shard
+    on a mesh: the value, and the gradients with respect to the hidden
+    states and the head under any cotangent, are those autodiff gives the
+    loss over the whole float32 logits (float32: 1e-5; bf16 values at
+    bf16's resolution, as ``test_chunked_ce_matches_full_logits``)."""
+    m, x, head, tgt = _loss_layer_alone(axes, dtype, chunk)
+    assert "shard_map" in str(
+        jax.make_jaxpr(lambda x, h: m._chunked_ce(x, h, tgt))(x, head)
+    )
+
+    def full_ce(x, h):
+        return optax.softmax_cross_entropy_with_integer_labels(
+            m._logits(x, h), tgt
+        ).mean()
+
+    got, want = (
+        jax.jit(jax.value_and_grad(
+            lambda x, h: cotangent * ce(x, h), argnums=(0, 1)
+        ))(x, head)
+        for ce in (lambda x, h: m._chunked_ce(x, h, tgt), full_ce)
+    )
+    loss_tol, grad_tol = (1e-5, 1e-5) if dtype == "float32" else (1e-3, 2e-2)
+    assert got[0].dtype == jnp.float32
+    assert abs(float(got[0]) - float(want[0])) < loss_tol
+    for g, w, like in zip(got[1], want[1], (x, head)):
+        assert g.dtype == like.dtype and g.shape == like.shape
+        g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
+        assert np.abs(g - w).max() <= grad_tol * np.abs(w).max()
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize(
+    "batch, chunk",
+    # 128 tokens in 8 chunks; 80 in 5; 88 in 5 and a remainder; 24 in one
+    # and a remainder; 48 under a chunk
+    [(8, 16), (5, 16), (11, 8), (3, 32), (3, 128)],
+)
+def test_loss_layer_gradients_keep_the_bits_of_the_backward_scan(
+    batch, chunk, dtype
+):
+    """Before PR 34 every chunk ran under ``jax.checkpoint`` and autodiff
+    made the gradients in a backward scan that computed each chunk's
+    logits again.  The forward scan that takes them now makes the same
+    products of the same operands and adds the chunks' shares of the
+    head's gradient in the same order and dtype: not a bit of either
+    gradient differs, in float32 or with bf16 storage (where the head's
+    gradient accumulates in bf16, as it did)."""
+    m, x, head, tgt = _loss_layer_alone({"expert": 1}, dtype, chunk, batch=batch)
+
+    def checkpointed_scan(x, head):
+        n = x.shape[0] * x.shape[1]
+        flat_x, flat_t = x.reshape(n, -1), tgt.reshape(n)
+        c = min(chunk, n)
+
+        def chunk_ce(carry, xt):
+            ce = optax.softmax_cross_entropy_with_integer_labels(
+                m._logits(xt[0], head), xt[1]
+            )
+            return carry + ce.sum(), None
+
+        ce_sum, main = jnp.float32(0), (n // c) * c
+        if main > c:
+            ce_sum, _ = jax.lax.scan(
+                jax.checkpoint(chunk_ce), ce_sum,
+                (flat_x[:main].reshape(main // c, c, -1),
+                 flat_t[:main].reshape(main // c, c)),
+            )
+        elif main:
+            ce_sum, _ = jax.checkpoint(chunk_ce)(
+                ce_sum, (flat_x[:main], flat_t[:main])
+            )
+        if n > main:
+            ce_sum, _ = jax.checkpoint(chunk_ce)(
+                ce_sum, (flat_x[main:], flat_t[main:])
+            )
+        return ce_sum / n
+
+    got, want = (
+        jax.jit(jax.value_and_grad(ce, argnums=(0, 1)))(x, head)
+        for ce in (lambda x, h: m._chunked_ce(x, h, tgt), checkpointed_scan)
+    )
+    # the value is the same terms added last chunk first: within 2 ulp
+    np.testing.assert_allclose(float(got[0]), float(want[0]), rtol=3e-7)
+    for g, w in zip(got[1], want[1]):
+        assert g.dtype == w.dtype == jnp.dtype(dtype)
+        np.testing.assert_array_equal(
+            np.asarray(g, np.float32), np.asarray(w, np.float32)
+        )
+
+
+def _head_products(lowered):
+    """``(operand types, result type)`` of every ``dot_general`` of the
+    lowered program that comes from the logits' einsum (``_logits``) or
+    from its transposes, as the StableHLO text has them."""
+    text = lowered.as_text(debug_info=True)
+    names = dict(re.findall(r"^(#loc\d+) = loc\((.*)\)$", text, flags=re.M))
+    found = []
+    for line in text.splitlines():
+        if "stablehlo.dot_general" not in line:
+            continue
+        if "...d,dv->...v" in names[re.findall(r"#loc\d+", line)[-1]]:
+            operands, result = re.search(
+                r": \((.*)\) -> (tensor<[^>]*>)", line
+            ).groups()
+            found.append((tuple(re.findall(r"tensor<([^>]*)>", operands)),
+                          result[len("tensor<"):-1]))
+    return found
+
+
+@pytest.mark.parametrize(
+    "axes", [{"expert": 1}, {"data": 2, "expert": 2}],
+    ids=["one-device", "data2xexpert2"],
+)
+def test_train_step_multiplies_by_the_head_three_times(axes):
+    """The lowered tiny train step has three products of the head's under
+    ``ce``, a scan of chunks on one device and per shard on a mesh: the
+    logits and the two gradients.  Recomputing the logits in the backward
+    made it four.  The compiled step agrees: three matmuls under ``ce``,
+    all in the scan's body."""
+    n_dev = int(np.prod(list(axes.values())))
+    mesh = make_mesh(axes, devices=jax.devices()[:n_dev])
+    _, cfg = _tiny_model(mesh)
+    m = DMoETransformerLM(dataclasses.replace(cfg, ce_chunk=16), mesh)
+    params = m.init_params(jax.random.PRNGKey(0))
+    opt = optax.adam(1e-3)
+    ids = jax.device_put(jnp.zeros((8, 16), jnp.int32), batch_sharding(mesh))
+    lowered = m.make_train_step(opt).lower(
+        params, m.init_opt_state(opt, params), ids, ids
+    )
+    assert sorted(_head_products(lowered)) == sorted([
+        (("16x32xf32", "32x64xf32"), "16x64xf32"),  # logits
+        (("16x64xf32", "16x32xf32"), "64x32xf32"),  # the head's gradient
+        (("16x64xf32", "32x64xf32"), "16x32xf32"),  # the rows' gradient
+    ])
+    # a chunk's rows are an array of their own before the products take
+    # them, as under jax.checkpoint's barrier: fused into each product's
+    # operand, the slice costs the chip's head-gradient product a fifth
+    assert lowered.as_text().count("stablehlo.optimization_barrier") == 1
+    dots = [
+        n for n in _loss_layer_ops(lowered.compile().as_text(), "dot|convolution")
+        if "/ce/" in n
+    ]
+    assert len(dots) == 3 and all("/while/body/" in n for n in dots), dots
+
+
+def test_loss_without_a_gradient_multiplies_by_the_head_once():
+    """Evaluation (and ``jax.eval_shape``) runs the plain scan: one product
+    a chunk, the logits, and nothing of the head's shape [32, 72] or its
+    transpose's is made anywhere (the step, which holds the head's
+    gradient, makes both)."""
+    m, x, head, tgt = _loss_layer_alone({"expert": 1}, "float32", 16, vocab=72)
+
+    def results(lowered):
+        return set(re.findall(
+            r"-> tensor<(\d+x\d+)xf32>", lowered.as_text()
+        ))
+
+    def ce(x, h):
+        return m._chunked_ce(x, h, tgt)
+
+    loss = jax.jit(ce).lower(x, head)
+    assert _head_products(loss) == [(("16x32xf32", "32x72xf32"), "16x72xf32")]
+    assert not results(loss) & {"32x72", "72x32"}
+    assert jax.eval_shape(ce, x, head) == jax.ShapeDtypeStruct((), jnp.float32)
+    grad = jax.jit(jax.grad(ce, argnums=(0, 1))).lower(x, head)
+    assert len(_head_products(grad)) == 3
+    assert results(grad) >= {"32x72", "72x32"}
+
+
+@pytest.mark.parametrize(
+    "dtype, operand", [("bfloat16", "bf16"), ("float32", "f32")]
+)
+def test_head_gradient_products_keep_their_types(dtype, operand):
+    """Read off the lowered step of PR 34's parent (90b8760), bf16 model:
+    the logits are ``(16x32xbf16, 32x64xbf16) -> 16x64xf32``; both gradient
+    products take float32 operands (the float32 ``d`` and the rows or the
+    head converted up) and give float32, ``(16x64xf32, 16x32xf32) ->
+    64x32xf32`` and ``(16x64xf32, 32x64xf32) -> 16x32xf32``, cast to bf16
+    afterwards.  A float32 model is float32 throughout.  Nothing is
+    narrower now."""
+    m, x, head, tgt = _loss_layer_alone({"expert": 1}, dtype, 16)
+    grad = jax.jit(
+        jax.grad(lambda x, h: m._chunked_ce(x, h, tgt), argnums=(0, 1))
+    ).lower(x, head)
+    assert sorted(_head_products(grad)) == sorted([
+        ((f"16x32x{operand}", f"32x64x{operand}"), "16x64xf32"),
+        (("16x64xf32", "16x32xf32"), "64x32xf32"),
+        (("16x64xf32", "32x64xf32"), "16x32xf32"),
+    ])
 
 
 def test_train_step_ce_has_no_all_gather_on_a_mesh():

@@ -653,6 +653,9 @@ def test_the_whole_step_fits_the_chip(v5e_chip, monkeypatch):
         "256,2560,768": 4 * 5, "256,768,2560": 4 * 4,
         "256,1280,768": 4 * 2, "256,768,1280": 4 * 1,
     }
+    # the logits and the two gradient products, in one scan of chunks: the
+    # chip's compiler keeps no fourth product of the head's (PR 34)
+    assert memory["loss_layer_products"] == 3
 
 
 @pytest.mark.parametrize("layer", [0, 1], ids=["global", "window"])

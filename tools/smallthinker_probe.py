@@ -9,8 +9,9 @@
 ``__graft_entry__`` (``smallthinker_one_chip``, or the one named, such as
 ``k_exaone_one_chip``) at published widths, compiled for a described v5e
 chip; prints the compiler's ``memory_analysis()`` against the chip's
-16,909,334,528 bytes, and the tiles each of the step's grouped-matmul
-instructions was compiled at (PERF.md section 3).  Nothing runs.
+16,909,334,528 bytes, the tiles each of the step's grouped-matmul
+instructions was compiled at (PERF.md section 3), and how many products of
+the head's the compiled loss layer holds (three since PR 34).  Nothing runs.
 
 ``float8`` (on the chip): the benchmark runner's own comparison of a
 configuration (``benchmarks/configs/smallthinker-21b-a3b.json``, or the
@@ -60,8 +61,10 @@ def step_memory(chip, recipe: str = "smallthinker_one_chip") -> dict:
     """The compiler's memory analysis of the whole train step of
     ``__graft_entry__.<recipe>`` compiled for ``chip``, a described v5e
     device (the caller makes ``jax.default_backend()`` answer ``tpu``, as
-    on the chip), and under ``grouped_matmul_tilings`` how many of its
-    grouped-matmul instructions run at which ``tm,tk,tn``."""
+    on the chip), under ``grouped_matmul_tilings`` how many of its
+    grouped-matmul instructions run at which ``tm,tk,tn``, and under
+    ``loss_layer_products`` how many of its fusions under scope ``ce`` are
+    matmuls (the logits' einsum and its transposes: the head's products)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -94,6 +97,7 @@ def step_memory(chip, recipe: str = "smallthinker_one_chip") -> dict:
     with no_compile_cache():
         compiled = model.make_train_step(optimizer).lower(p, o, ids, ids).compile()
     m = compiled.memory_analysis()
+    text = compiled.as_text()
     # params and optimizer state are donated: outputs alias the arguments
     live = (m.argument_size_in_bytes + m.output_size_in_bytes
             - m.alias_size_in_bytes + m.temp_size_in_bytes)
@@ -109,7 +113,10 @@ def step_memory(chip, recipe: str = "smallthinker_one_chip") -> dict:
         "share_of_chip": live / CHIP_BYTES,
         "grouped_matmul_tilings": dict(collections.Counter(re.findall(
             r'^\s*%ragged-dot-none[.\d]* = [^\n]*ragged_dot_tiling="([\d,]+)"',
-            compiled.as_text(), re.M))),
+            text, re.M))),
+        "loss_layer_products": len(re.findall(
+            r'^\s*%\S+ = [^\n]* fusion\([^\n]*'
+            r'op_name="[^"\n]*[/(]ce[/)][^"\n]*dot_general"', text, re.M)),
     }
 
 
