@@ -647,6 +647,7 @@ class RemoteMixtureOfExperts:
             timeline.record(
                 "client.dispatch.join",
                 _time.monotonic() - blocked, blocked, trace=trace,
+                kind=fut.kind,
             )
             with self._sessions_lock:
                 self.inflight_dispatches -= 1
@@ -682,7 +683,9 @@ class RemoteMixtureOfExperts:
         x = np.asarray(x)
         logits_concat = np.asarray(logits_concat)
         batch = x.shape[0]
-        with timeline.span("client.dispatch.fire", trace=trace):
+        with timeline.span(
+            "client.dispatch.fire", trace=trace, kind="forward"
+        ):
             logits = [
                 logits_concat[:, off : off + g]
                 for off, g in zip(self._grid_offsets, self.grid_size)
@@ -1173,8 +1176,6 @@ class RemoteMixtureOfExperts:
         self.pack_bytes += nbytes
         self.pack_bytes_saved += saved
         timeline.record("client.pack", t0, dt, trace, kind=kind)
-        timeline.count("client.pack.bytes", nbytes)
-        timeline.count("client.pack_once.bytes_saved", saved)
         return out_jobs, prepared
 
     def _headline_metrics(self) -> dict:
@@ -1269,7 +1270,20 @@ class RemoteMixtureOfExperts:
         per-pool multiplexed in-flight high-water mark.  Plumbed through
         the same ``_headline_metrics`` dict the registry exports (ISSUE
         4: no more hand-rolled parallel dicts) plus the process-wide
-        transport counters from the connection-pool registry."""
+        transport counters from the connection-pool registry.
+
+        Two sources of timings, and which is which: ``pack_p50_ms`` and
+        ``wait_p50_ms`` are THIS mixture's own (its ``pack_times`` /
+        ``wait_times`` deques since construction: one entry a pack and a
+        join, so forward and backward in one median; a trainer with a
+        mixture a layer reads one pair a layer).  ``stages`` is the
+        PROCESS's (``Timeline.stage_stats`` over ``moe.*``, ``client.*``,
+        ``rpc.*``: every mixture's spans of the last seconds over one
+        extent), each stage also by kind: ``client.dispatch.fire:forward``,
+        ``client.dispatch.join:backward``, ``client.pack:forward``,
+        ``rpc.multi:backward``, and the two halves of an exchange,
+        ``rpc.send`` and ``rpc.decode`` (what is left of ``rpc.<type>`` is
+        the wait for the server)."""
         m = self._headline_metrics()
 
         def nz(v):  # deques empty → None, the historical contract
@@ -1279,6 +1293,7 @@ class RemoteMixtureOfExperts:
         return {
             "pack_p50_ms": nz(m["lah_client_pack_p50_ms"]),
             "wait_p50_ms": nz(m["lah_client_wait_p50_ms"]),
+            "stages": timeline.stage_stats(("moe.", "client.", "rpc.")),
             "pack_bytes": int(m["lah_client_pack_bytes_total"]),
             "pack_once_bytes_saved": int(
                 m["lah_client_pack_once_bytes_saved_total"]
@@ -1396,7 +1411,6 @@ class RemoteMixtureOfExperts:
         """Hedge-fire entry point: the primary outlived its RTT-derived
         deadline (or failed) and the backup replica is being dispatched."""
         self.hedge_fires += 1
-        timeline.count("client.hedge.fires")
         flight.record(
             "client", "hedge_fire", primary=str(primary), backup=str(backup)
         )
@@ -1408,7 +1422,6 @@ class RemoteMixtureOfExperts:
         prepared wire form (codec never negotiated) — counted, never
         silently dropped."""
         self.hedges_skipped += 1
-        timeline.count("client.hedge.skipped")
 
     # ---- host side: backward fan-out to exactly the responders ----
 
@@ -1437,7 +1450,9 @@ class RemoteMixtureOfExperts:
         batch = gy.shape[0]
         with self._sessions_lock:
             self.backward_rpcs_sent += len(session)
-        with timeline.span("client.dispatch.fire", trace=trace):
+        with timeline.span(
+            "client.dispatch.fire", trace=trace, kind="backward"
+        ):
             prepared = None
             if dispatch_mode() == "pipelined":
                 uid_jobs, prepared = self._prepare_payloads(

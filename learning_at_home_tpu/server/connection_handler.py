@@ -42,12 +42,13 @@ from __future__ import annotations
 
 import asyncio
 import logging
-from typing import TYPE_CHECKING
+import time
+from typing import TYPE_CHECKING, Optional
 
 import numpy as np
 
 from learning_at_home_tpu.utils import sanitizer
-from learning_at_home_tpu.utils.profiling import timeline
+from learning_at_home_tpu.utils.profiling import KINDS, timeline
 from learning_at_home_tpu.utils.serialization import (
     WIRE_CODECS,
     WIRE_DTYPES,
@@ -58,7 +59,7 @@ from learning_at_home_tpu.utils.serialization import (
     is_float_dtype,
     pack_frames,
     peek_header,
-    recv_frame,
+    recv_frame_length,
     send_frame_parts,
     unpack_message,
     wire_cast,
@@ -155,6 +156,53 @@ async def encode_reply_wire(tensors, wire) -> tuple[list, dict | None]:
         return encode_wire_tensors(tensors, codec)
 
 
+class _Connection:
+    """One TCP connection's writer side and its place in a request's
+    accounting; touched only on the serving loop.
+
+    ``outstanding`` counts the requests from their length prefix read to
+    their reply written.  When the last one's reply is written the
+    connection falls idle, and ``server.conn.idle`` is the time until the
+    next length prefix is read: what the CLIENT spends between its
+    requests, seen from the socket.  It carries the kind of the reply
+    that preceded it.  The wait after a reply of no kind (``hello_ok``, a
+    scrape) is none of the data plane's, and the wait that ends in EOF is
+    never recorded: the extent ``stage_stats`` reads ends with the last
+    request, not with the client's exit."""
+
+    __slots__ = ("writer", "wlock", "tasks", "outstanding", "idle_from",
+                 "idle_kind")
+
+    def __init__(self, writer: asyncio.StreamWriter):
+        self.writer = writer
+        self.wlock = asyncio.Lock()  # one frame at a time on the socket
+        self.tasks: set[asyncio.Task] = set()  # muxed requests in flight
+        self.outstanding = 0
+        self.idle_from: Optional[float] = None
+        self.idle_kind: Optional[str] = None
+
+    async def send(self, parts: list) -> None:
+        async with self.wlock:
+            await send_frame_parts(self.writer, parts)
+
+    def request_begins(self, now: float) -> None:
+        """A frame's length prefix is read at ``now``."""
+        if self.idle_from is not None:
+            timeline.record(
+                "server.conn.idle", self.idle_from, now - self.idle_from,
+                kind=self.idle_kind,
+            )
+            self.idle_from = None
+        self.outstanding += 1
+
+    def request_ends(self, written_at: Optional[float], kind) -> None:
+        """A request has left: its reply of ``kind`` was written at
+        ``written_at`` (None: control plane, dropped or failed)."""
+        self.outstanding -= 1
+        if self.outstanding == 0:
+            self.idle_from, self.idle_kind = written_at, kind
+
+
 class ConnectionHandler:
     """Dispatches one TCP connection's requests to expert task pools."""
 
@@ -166,18 +214,23 @@ class ConnectionHandler:
     ) -> None:
         peer = writer.get_extra_info("peername")
         muxed = False  # becomes True after a ``hello`` negotiates v2
-        wlock = asyncio.Lock()  # one frame at a time on the socket
-        inflight: set[asyncio.Task] = set()
+        conn = _Connection(writer)
         try:
             while True:
                 try:
-                    payload = await recv_frame(reader)
+                    # recv_frame's two awaits, a reading after each: the
+                    # wait for the client's next frame, then server.read
+                    length = await recv_frame_length(reader)
+                    read_start = time.monotonic()
+                    conn.request_begins(read_start)
+                    payload = await reader.readexactly(length)
+                    read = (read_start, time.monotonic() - read_start)
                 except (asyncio.IncompleteReadError, ConnectionResetError):
                     break
                 try:
                     msg_type, rid = peek_header(payload)
                 except Exception:
-                    msg_type, rid = None, None  # _dispatch makes the error reply
+                    msg_type, rid = None, None  # _serve makes the error reply
                 if msg_type == "hello":
                     # protocol v2 feature negotiation: echo the feature
                     # subset we speak; the connection is multiplexed from
@@ -195,41 +248,62 @@ class ConnectionHandler:
                         offered = []
                     common = [f for f in SERVER_FEATURES if f in offered]
                     muxed = "mux" in common
-                    await self._send(
-                        writer, wlock,
-                        pack_frames(
+                    try:
+                        await conn.send(pack_frames(
                             "hello_ok", WireTensors.prepare(),
                             {"features": common}, rid=rid,
-                        ),
-                    )
+                        ))
+                    finally:
+                        conn.request_ends(None, None)
                     continue
                 if muxed and rid is not None:
                     # serve concurrently; each reply carries its request id
                     # so the client can match out-of-order completions
                     task = asyncio.get_running_loop().create_task(
-                        self._serve_muxed(payload, rid, writer, wlock)
+                        self._serve_muxed(conn, payload, rid, read)
                     )
-                    inflight.add(task)
-                    task.add_done_callback(inflight.discard)
+                    conn.tasks.add(task)
+                    task.add_done_callback(conn.tasks.discard)
                     continue
-                reply = await self._dispatch(payload, rid)
-                if self.server.chaos is not None:
-                    if not await self.server.chaos.before_reply(
-                        len(payload) + frame_nbytes(reply) - 4
-                    ):
-                        continue  # injected drop: client sees a timeout
-                await self._send(writer, wlock, reply)
+                await self._respond(conn, payload, rid, read)
         except Exception:
             logger.exception("connection handler failed for peer %s", peer)
         finally:
-            for task in inflight:
+            for task in conn.tasks:
                 task.cancel()
             writer.close()
 
-    @staticmethod
-    async def _send(writer, wlock: asyncio.Lock, parts: list) -> None:
-        async with wlock:
-            await send_frame_parts(writer, parts)
+    async def _respond(
+        self, conn: _Connection, payload: bytes, rid, read: tuple
+    ) -> None:
+        """One request from its frame read to its reply written:
+        ``server.read`` (taken in ``handle_connection``, recorded by
+        ``_serve`` once the kind is known), ``server.request``, then
+        ``server.write`` from the reply frame built to ``send_frame_parts``
+        returned, the wait for the connection's write lock included.  The
+        write starts at the request's end reading, so the two are
+        contiguous (a chaos delay before the reply counts as write).  A
+        request of no kind (control plane, malformed) writes its reply
+        outside the reservoirs."""
+        written_at = kind = None
+        try:
+            with timeline.span("server.request") as span:
+                reply = await self._serve(payload, rid, span, read)
+            if self.server.chaos is not None:
+                if not await self.server.chaos.before_reply(
+                    len(payload) + frame_nbytes(reply) - 4
+                ):
+                    return  # injected drop: client sees a timeout
+            kind = span.attrs.get("kind")
+            with timeline.span(
+                "server.write", span.trace, start=span.end, kind=kind
+            ) as write:
+                if kind is None:
+                    write.exclude()
+                await conn.send(reply)
+            written_at = write.end if kind is not None else None
+        finally:
+            conn.request_ends(written_at, kind)
 
     @staticmethod
     def _count_wire_bytes(wire, nbytes: int, direction: str) -> None:
@@ -246,16 +320,10 @@ class ConnectionHandler:
         ).inc(nbytes, codec=wire_codec_name(wire), direction=direction)
 
     async def _serve_muxed(
-        self, payload: bytes, rid: int, writer, wlock: asyncio.Lock
+        self, conn: _Connection, payload: bytes, rid: int, read: tuple
     ) -> None:
         try:
-            reply = await self._dispatch(payload, rid)
-            if self.server.chaos is not None:
-                if not await self.server.chaos.before_reply(
-                    len(payload) + frame_nbytes(reply) - 4
-                ):
-                    return  # injected drop: client sees a timeout
-            await self._send(writer, wlock, reply)
+            await self._respond(conn, payload, rid, read)
         except asyncio.CancelledError:
             raise
         except Exception:
@@ -411,7 +479,7 @@ class ConnectionHandler:
         # reply prepare is an O(#tensors) spec walk over zero-copy
         # memoryviews — the O(bytes) work (encode/downcast) already ran
         # off-loop or in the executor above
-        with timeline.span("server.encode", trace):
+        with timeline.span("server.encode", trace, kind=op):
             return pack_frames(
                 "result",
                 WireTensors.prepare(reply_tensors),  # lah-lint: ignore[R1]
@@ -514,13 +582,18 @@ class ConnectionHandler:
 
         ``server.request`` is the request's whole stay in the server,
         from here to the reply frame built; ``server.decode`` and
-        ``server.encode`` are the codec's two sides inside it."""
+        ``server.encode`` are the codec's two sides inside it.  The
+        native pump's entry: it reads and writes the socket itself, so its
+        requests have no ``server.read`` / ``server.write``; the asyncio
+        transport goes through ``_respond``."""
         with timeline.span("server.request") as span:
             return await self._serve(payload, rid, span)
 
-    async def _serve(self, payload: bytes, rid, span) -> list:
-        """``_dispatch``'s body; ``span`` is its ``server.request`` span,
-        which gets the message type and the trace id once they are read.
+    async def _serve(self, payload: bytes, rid, span, read=None) -> list:
+        """A request's body; ``span`` is its ``server.request`` span,
+        which gets the message type, the kind and the trace id once they
+        are read, and ``read`` the ``(start, duration)`` of its frame's
+        read, recorded here as ``server.read`` for the same reason.
 
         A ``{"trace": id}`` meta entry (distributed tracing) is
         peer-supplied: it is structurally validated, stamped onto this
@@ -542,7 +615,7 @@ class ConnectionHandler:
             downcast needs no meta, its dtype is in the tensor specs)."""
             tensors, rwire = result
             meta = {"wire": rwire} if isinstance(rwire, dict) else None
-            with timeline.span("server.encode", trace):
+            with timeline.span("server.encode", trace, kind=msg_type):
                 return reply("result", tensors, meta)
 
         malformed = None
@@ -561,6 +634,14 @@ class ConnectionHandler:
                 # hand-off part or a malformed frame is none of its requests
                 decode.exclude()
                 span.exclude()
+            else:
+                kind = msg_type
+                if msg_type == "multi":
+                    # a multi's kind is its op (peer-supplied: only the
+                    # two values of KINDS count, anything else is no kind)
+                    kind = meta.get("op")
+                if kind in KINDS:
+                    span.attrs["kind"] = decode.attrs["kind"] = kind
         if malformed is not None:
             return reply(
                 "error", meta={"message": f"malformed request: {malformed}"}
@@ -572,6 +653,10 @@ class ConnectionHandler:
             trace = None  # malformed/absent: never trust peer-supplied meta
         span.trace = trace
         span.attrs["type"] = msg_type
+        if read is not None and "kind" in span.attrs:
+            timeline.record(
+                "server.read", *read, trace, kind=span.attrs["kind"]
+            )
         if isinstance(wire, str) and wire not in WIRE_DTYPES:
             return reply(
                 "error",

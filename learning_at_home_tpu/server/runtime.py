@@ -97,28 +97,39 @@ class Runtime:
         return self._queue.qsize()
 
     def _run(self) -> None:
+        """The thread's time is one chain of stages, ``runtime.idle | stack
+        | dispatch | materialize | handoff``.  ``mark`` is the reading at
+        which its last stage ended, and ``idle``, ``materialize`` and
+        ``handoff`` start there; ``dispatch`` starts where ``stack`` ended.
+        ``stack`` alone takes a reading of its own, so that it times
+        ``BatchJob.stack`` and nothing else: what precedes it is a few
+        lines of this loop (``runtime.queue`` ends at that reading too).  The queue's next entry is taken inside the
+        hand-off that precedes it (``item``), so the fetch has a name too.
+        A batch costs the thread one clock call a stage and one."""
         pending: Optional[_Inflight] = None
+        item = None  # the queue's next entry, where a hand-off fetched it
+        mark = time.monotonic()
         while True:
-            if pending is None:
+            if item is None and pending is None:
                 # nothing in flight and nothing to do until a pool forms
                 # a batch: the pace is set upstream of this thread
-                with timeline.span("runtime.idle") as idle:
+                with timeline.span("runtime.idle", start=mark) as idle:
                     item = self._queue.get()
                     if item[2] is None:
                         idle.exclude()  # waited for shutdown, not for work
-            else:
-                try:
-                    # don't wait: if no new job is ready, spend the idle
-                    # time materializing the in-flight one instead
-                    item = self._queue.get_nowait()
-                except queue.Empty:
-                    self._finish(pending)
+                mark = idle.end
+            elif item is None:
+                # don't wait: if no new job is ready, spend the idle
+                # time materializing the in-flight one instead
+                item = self._poll()
+                if item is None:
+                    mark, item = self._finish(pending, mark)
                     pending = None
                     continue
-            _, _, job = item
+            (_, _, job), item = item, None
             if job is None or self._stop.is_set():
                 if pending is not None:
-                    self._finish(pending)
+                    self._finish(pending, mark, fetch=False)
                     pending = None
                 if job is not None:
                     self._deliver(job, None, RuntimeError("runtime shut down"))
@@ -129,44 +140,56 @@ class Runtime:
             ):
                 # per-expert serialization: never overlap two jobs of the
                 # same expert/pool — drain the pipeline first
-                self._finish(pending)
+                mark, _ = self._finish(pending, mark, fetch=False)
                 pending = None
             overlapped = pending is not None
-            inflight = self._dispatch_job(job)
+            inflight, mark = self._dispatch_job(job)
             if pending is not None:
-                self._finish(pending)
+                mark, item = self._finish(pending, mark)
                 pending = None
             if inflight is not None and overlapped:
                 self.jobs_overlapped += 1
-                timeline.count("runtime.jobs_overlapped")
             pending = inflight
         if pending is not None:
-            self._finish(pending)
+            self._finish(pending, mark, fetch=False)
         self._drain_remaining()
 
-    def _dispatch_job(self, job: BatchJob) -> Optional[_Inflight]:
+    def _poll(self):
+        """The queue's next entry if one is ready, else None."""
+        try:
+            return self._queue.get_nowait()
+        except queue.Empty:
+            return None
+
+    def _dispatch_job(
+        self, job: BatchJob
+    ) -> tuple[Optional[_Inflight], float]:
         """Stage one: stack the batch into staging buffers and dispatch the
         jitted call.  Returns the in-flight record, or None if the job
-        failed (error already delivered)."""
+        failed (error already delivered), and the reading the thread's
+        next stage starts at.  ``runtime.stack`` reads the clock for
+        itself, and that reading ends the batch's wait in the queue."""
         pool = job.pool
-        queued = time.monotonic() - job.formed_at
-        self.queue_time += queued
         buffers: list = []
         # trace ids exist only on profiled requests: see BatchJob
         trace = job.owner_trace() if timeline.enabled else None
-        timeline.record(
-            "runtime.queue", job.formed_at, queued, trace, pool=pool.name
-        )
         try:
             with timeline.span(
                 "runtime.stack", trace, pool=pool.name, rows=job.n_rows,
-                bucket=job.target_rows,
+                bucket=job.target_rows, kind=pool.kind,
             ) as stack:
                 inputs, buffers = job.stack(self.staging)
             self.stack_time += stack.duration
             pool.stack_time += stack.duration
+            queued = stack.start - job.formed_at
+            self.queue_time += queued
+            timeline.record(
+                "runtime.queue", job.formed_at, queued, trace,
+                pool=pool.name, kind=pool.kind,
+            )
             with timeline.span(
-                "runtime.dispatch", trace, pool=pool.name
+                "runtime.dispatch", trace, start=stack.end, pool=pool.name,
+                kind=pool.kind,
             ) as launch:
                 raw = list(pool.process_fn(inputs))
         except BaseException as e:  # deliver, don't kill the device loop
@@ -174,46 +197,77 @@ class Runtime:
             self.staging.release(buffers)
             self.jobs_processed += 1
             self._deliver(job, None, e)
-            return None
-        return _Inflight(job, raw, buffers, launch.duration, trace)
+            return None, job.finished_at
+        return (
+            _Inflight(job, raw, buffers, launch.duration, trace), launch.end
+        )
 
-    def _finish(self, inflight: _Inflight) -> None:
+    def _finish(
+        self, inflight: _Inflight, mark: float, fetch: bool = True
+    ) -> tuple[float, Optional[tuple]]:
         """Stage two: materialize the outputs (blocks until the device
         finishes — this is the wait the NEXT job's dispatch overlaps),
-        recycle the staging buffers, deliver to the pool's futures."""
+        then hand off: recycle the staging buffers, deliver to the pool's
+        futures and, with ``fetch``, be back at the queue for its next
+        entry if one is ready.  Returns the reading at which the hand-off
+        ended, and that entry or None."""
         job = inflight.job
+        pool = job.pool
         outputs, error = None, None
         try:
             with timeline.span(
-                "runtime.materialize", inflight.trace, pool=job.pool.name
+                "runtime.materialize", inflight.trace, start=mark,
+                pool=pool.name, kind=pool.kind,
             ) as materialize:
-                outputs = []
-                for o in inflight.raw_outputs:
-                    arr = np.asarray(o)
-                    # a pure-host process_fn can return views INTO the
-                    # staging buffers; those must be copied out before the
-                    # buffer is recycled under the delivered results
-                    if inflight.staging and any(
-                        np.may_share_memory(arr, buf)
-                        for buf in inflight.staging
-                    ):
-                        arr = np.array(arr)
-                    outputs.append(arr)
+                outputs = self._to_host(inflight)
         except BaseException as e:
             logger.exception(
-                "runtime job failed to materialize in pool %s", job.pool.name
+                "runtime job failed to materialize in pool %s", pool.name
             )
             error = e
-        self.materialize_time += materialize.duration
-        # device_time keeps its pre-pipeline meaning — process_fn call +
-        # output materialization, the job's own busy time.  Under overlap,
-        # wall time from dispatch to materialized also contains the NEXT
-        # job's stack/dispatch; folding that in would double-count and
-        # make the pipelined runtime read as a device-time regression.
-        self.device_time += inflight.dispatch_s + materialize.duration
-        self.jobs_processed += 1
-        self.staging.release(inflight.staging)
-        self._deliver(job, outputs, error)
+        with timeline.span(
+            "runtime.handoff", inflight.trace, start=materialize.end,
+            pool=pool.name, kind=pool.kind,
+        ) as handoff:
+            self.materialize_time += materialize.duration
+            # device_time keeps its pre-pipeline meaning — process_fn call
+            # + output materialization, the job's own busy time.  Under
+            # overlap, wall time from dispatch to materialized also
+            # contains the NEXT job's stack/dispatch; folding that in
+            # would double-count and make the pipelined runtime read as a
+            # device-time regression.
+            self.device_time += inflight.dispatch_s + materialize.duration
+            self.jobs_processed += 1
+            self.staging.release(inflight.staging)
+            # the request's wait for the loop (runtime.deliver) starts
+            # where its materialization ended: the hand-off is this
+            # thread's time, and no part of the request's goes unnamed
+            self._deliver(job, outputs, error, materialize.end)
+            # the device's output buffers are freed HERE, under a name (20
+            # to 40 us a batch on the chip, and a wait for the GIL where
+            # the free lets it go), not wherever this thread happens to
+            # drop its last reference to them
+            del inflight.raw_outputs[:]
+            item = self._poll() if fetch else None
+        return handoff.end, item
+
+    @staticmethod
+    def _to_host(inflight: _Inflight) -> list:
+        """The in-flight job's outputs as host arrays: blocks until the
+        device has finished, then copies.  (A function of its own so that
+        no local of the caller keeps a device buffer alive past it.)"""
+        outputs = []
+        for o in inflight.raw_outputs:
+            arr = np.asarray(o)
+            # a pure-host process_fn can return views INTO the staging
+            # buffers; those must be copied out before the buffer is
+            # recycled under the delivered results
+            if inflight.staging and any(
+                np.may_share_memory(arr, buf) for buf in inflight.staging
+            ):
+                arr = np.array(arr)
+            outputs.append(arr)
+        return outputs
 
     def stats(self) -> dict:
         """Hot-path telemetry snapshot for the server ``stats`` surface."""
@@ -236,8 +290,12 @@ class Runtime:
             "stages": timeline.stage_stats(("server.", "pool.", "runtime.")),
         }
 
-    def _deliver(self, job: BatchJob, outputs, error) -> None:
-        job.finished_at = time.monotonic()  # runtime.deliver starts here
+    def _deliver(
+        self, job: BatchJob, outputs, error,
+        finished_at: Optional[float] = None,
+    ) -> None:
+        # runtime.deliver starts here
+        job.finished_at = finished_at or time.monotonic()
         try:
             self._loop.call_soon_threadsafe(job.pool.deliver, job, outputs, error)
         except RuntimeError:

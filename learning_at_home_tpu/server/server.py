@@ -95,6 +95,7 @@ class Server:
                 batch_timeout=batch_timeout,
                 serial_key=uid,
                 warm_buckets=warm,
+                kind="forward",
             )
             self.backward_pools[uid] = TaskPool(
                 lambda tensors, b=backend: b.backward(
@@ -105,6 +106,7 @@ class Server:
                 batch_timeout=batch_timeout,
                 serial_key=uid,
                 warm_buckets=warm,
+                kind="backward",
             )
         self._loop: Optional[BackgroundLoop] = None
         self._tcp_server: Optional[asyncio.base_events.Server] = None
@@ -959,7 +961,7 @@ class Server:
             backend.forward, f"{uid}.forward",
             max_batch_size=backend.max_batch_size,
             batch_timeout=self.batch_timeout, serial_key=uid,
-            warm_buckets=warm,
+            warm_buckets=warm, kind="forward",
         )
         bp = TaskPool(
             lambda tensors, b=backend: b.backward(
@@ -967,7 +969,7 @@ class Server:
             ),
             f"{uid}.backward", max_batch_size=backend.max_batch_size,
             batch_timeout=self.batch_timeout, serial_key=uid,
-            warm_buckets=warm,
+            warm_buckets=warm, kind="backward",
         )
         self.experts[uid] = backend
         self.forward_pools[uid] = fp

@@ -163,9 +163,14 @@ class TaskPool:
         pad_buckets: bool = True,
         serial_key: Optional[str] = None,
         warm_buckets: Sequence[int] | Callable[[], Sequence[int]] = (),
+        kind: Optional[str] = None,
     ):
         self.process_fn = process_fn
         self.name = name
+        # "forward" or "backward" (utils/profiling.KINDS): what this pool's
+        # stage spans are also filed under, so that the always-on stage
+        # statistics can tell the two apart (``runtime.queue:backward``)
+        self.kind = kind
         self.max_batch_size = max_batch_size
         self.batch_timeout = batch_timeout
         self.pad_buckets = pad_buckets
@@ -204,7 +209,14 @@ class TaskPool:
         await self._tasks.put(
             _Task(tuple(tensors), future, time.monotonic(), n_rows, trace)
         )
-        return await future
+        delivered_at, outputs = await future
+        # how long the loop took to run this coroutine again once
+        # ``deliver`` had set its result (the other callbacks ahead of it)
+        timeline.record(
+            "server.resume", delivered_at, time.monotonic() - delivered_at,
+            trace, pool=self.name, kind=self.kind,
+        )
+        return outputs
 
     def start(self, runtime) -> None:
         """Begin forming batches and feeding them to ``runtime``."""
@@ -312,18 +324,18 @@ class TaskPool:
         for t in batch:  # the batching delay, one span a task
             timeline.record(
                 "pool.wait", t.arrived, job.formed_at - t.arrived, t.trace,
-                pool=self.name,
+                pool=self.name, kind=self.kind,
             )
         runtime.submit(job)
 
     # called back on the event loop by the Runtime after device execution
     def deliver(self, job: BatchJob, outputs, error: Optional[BaseException]) -> None:
+        now = time.monotonic()  # runtime.deliver ends, server.resume starts
         if job.finished_at:  # how long this loop took to notice
             timeline.record(
-                "runtime.deliver", job.finished_at,
-                time.monotonic() - job.finished_at,
+                "runtime.deliver", job.finished_at, now - job.finished_at,
                 job.owner_trace() if timeline.enabled else None,
-                pool=self.name,
+                pool=self.name, kind=self.kind,
             )
         for future, start, stop in job.row_spans:
             if future.cancelled():
@@ -331,7 +343,9 @@ class TaskPool:
             if error is not None:
                 future.set_exception(error)
             else:
-                future.set_result([np.asarray(o[start:stop]) for o in outputs])
+                future.set_result(
+                    (now, [np.asarray(o[start:stop]) for o in outputs])
+                )
 
     @property
     def padding_waste(self) -> float:

@@ -32,7 +32,7 @@ from typing import Optional, Sequence
 
 from learning_at_home_tpu.utils import sanitizer
 from learning_at_home_tpu.utils.asyncio_utils import asyncio_timeout
-from learning_at_home_tpu.utils.profiling import timeline
+from learning_at_home_tpu.utils.profiling import KINDS, timeline
 from learning_at_home_tpu.utils.serialization import (
     WireTensors,
     decode_wire_tensors,
@@ -322,23 +322,46 @@ class ConnectionPool:
         A ``{"trace": id}`` entry in ``meta`` (distributed tracing,
         docs/OBSERVABILITY.md) stamps this exchange's ``rpc.<msg_type>``
         span with the request's trace id — the client-side anchor the
-        server's stack/dispatch/materialize spans nest inside."""
-        with timeline.span(
-            f"rpc.{msg_type}", trace=(meta or {}).get("trace")
-        ):
+        server's stack/dispatch/materialize spans nest inside.
+
+        The span carries the exchange's ``kind`` (``forward`` /
+        ``backward``: the message type, or a ``multi``'s ``op``), and two
+        children split it: ``rpc.send`` (``pack_frames`` to
+        ``send_frame_parts`` returned) and ``rpc.decode`` (reply payload
+        in hand to ``_finish`` returned).  What is left of
+        ``rpc.<msg_type>`` is the wait for the server and the socket.  An
+        exchange of no kind (control plane, the DHT's) keeps its two
+        halves out of the stage reservoirs."""
+        meta = meta or {}
+        stamp = {"trace": meta.get("trace")}
+        kind = meta.get("op") if msg_type == "multi" else msg_type
+        if kind in KINDS:
+            stamp["kind"] = kind
+        with timeline.span(f"rpc.{msg_type}", **stamp):
             if (self._require_v2 or _v2_enabled()) and self._negotiate_v2:
                 if self._proto is None:
                     await self._negotiate(timeout)
                 if self._proto == 2:
                     try:
-                        return await self._rpc_mux(msg_type, wire, meta, timeout)
+                        return await self._rpc_mux(
+                            msg_type, wire, meta, timeout, stamp
+                        )
                     except _ProtocolDowngraded:
                         pass  # peer restarted as v1 mid-stream: fall through
-            return await self._rpc_v1(msg_type, wire, meta, timeout)
+            return await self._rpc_v1(msg_type, wire, meta, timeout, stamp)
+
+    @staticmethod
+    def _half(name: str, stamp: dict):
+        """``rpc.send`` / ``rpc.decode``: one half of an exchange, under
+        its ``rpc.<msg_type>`` span's trace id and kind."""
+        span = timeline.span(name, **stamp)
+        if "kind" not in stamp:
+            span.exclude()
+        return span
 
     # ---- protocol v1: one RPC per socket ----
 
-    async def _rpc_v1(self, msg_type, wire, meta, timeout):
+    async def _rpc_v1(self, msg_type, wire, meta, timeout, stamp):
         loop = asyncio.get_running_loop()
         async with self._sem:
             writer = None
@@ -346,10 +369,11 @@ class ConnectionPool:
             try:
                 async with asyncio_timeout(timeout):
                     reader, writer = await self._acquire()
-                    parts = pack_frames(msg_type, wire, meta)
-                    sent = frame_nbytes(parts)
-                    self.bytes_sent += sent
-                    await send_frame_parts(writer, parts)
+                    with self._half("rpc.send", stamp):
+                        parts = pack_frames(msg_type, wire, meta)
+                        sent = frame_nbytes(parts)
+                        self.bytes_sent += sent
+                        await send_frame_parts(writer, parts)
                     payload = await recv_frame(reader)
             except BaseException as e:
                 if writer is not None:
@@ -359,7 +383,8 @@ class ConnectionPool:
                 raise
             dt = loop.time() - t0
             self._free.put_nowait((reader, writer))
-        return self._finish(payload, dt, sent)
+        with self._half("rpc.decode", stamp):
+            return self._finish(payload, dt, sent)
 
     # ---- protocol v2: negotiation + multiplexed exchanges ----
 
@@ -474,7 +499,7 @@ class ConnectionPool:
             self._mux = _MuxConnection(reader, writer)
             return self._mux
 
-    async def _rpc_mux(self, msg_type, wire, meta, timeout):
+    async def _rpc_mux(self, msg_type, wire, meta, timeout, stamp):
         loop = asyncio.get_running_loop()
         async with self._mux_sem:
             t0 = loop.time()
@@ -488,11 +513,12 @@ class ConnectionPool:
                     rid = mux.next_rid()
                     fut = loop.create_future()
                     mux.pending[rid] = fut
-                    parts = pack_frames(msg_type, wire, meta, rid=rid)
-                    sent = frame_nbytes(parts)
-                    self.bytes_sent += sent
-                    async with mux.wlock:
-                        await send_frame_parts(mux.writer, parts)
+                    with self._half("rpc.send", stamp):
+                        parts = pack_frames(msg_type, wire, meta, rid=rid)
+                        sent = frame_nbytes(parts)
+                        self.bytes_sent += sent
+                        async with mux.wlock:
+                            await send_frame_parts(mux.writer, parts)
                     payload = await fut
             except _ProtocolDowngraded:
                 raise
@@ -510,7 +536,8 @@ class ConnectionPool:
                 raise
             finally:
                 self.inflight -= 1
-            return self._finish(payload, loop.time() - t0, sent)
+            with self._half("rpc.decode", stamp):
+                return self._finish(payload, loop.time() - t0, sent)
 
     def close(self) -> None:
         while not self._free.empty():

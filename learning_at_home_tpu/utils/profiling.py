@@ -8,15 +8,19 @@ per-RPC timing spans.  This module provides both:
   the expert server and the MoE dispatcher.  One primitive
   (:meth:`Timeline.span`, a small class with ``__enter__`` / ``__exit__``;
   :meth:`Timeline.record` where both clock readings are already in hand)
-  with three sinks: a bounded **reservoir of recent spans per name**
-  (always on: :meth:`Timeline.recent`, :meth:`Timeline.stage_stats`), a
-  ``jax.profiler.TraceAnnotation`` of the same name (records only while a
-  profiler session is live, and then sits in the ``.xplane.pb`` on the
-  device trace's clock), and the full record with trace id, thread and
-  attributes (only under ``LAH_PROFILE=1``);
-- named **event counters** on the same Timeline (:meth:`Timeline.count`)
-  for hot-path pipeline telemetry — overlapped dispatches, staging-buffer
-  reuse, per-bucket cache hits — where a duration span is the wrong shape;
+  with three sinks: a bounded **reservoir of recent spans per name**, and
+  a second one per name and **kind** (always on: :meth:`Timeline.recent`,
+  :meth:`Timeline.stage_stats`), a ``jax.profiler.TraceAnnotation`` of the
+  same name (records only while a profiler session is live, and then sits
+  in the ``.xplane.pb`` on the device trace's clock), and the full record
+  with trace id, thread and attributes (only under ``LAH_PROFILE=1``);
+- named **event counters** on the same Timeline (:meth:`Timeline.count`,
+  only under ``LAH_PROFILE=1``) where a duration span is the wrong shape:
+  the client's per-codec payload counts and bytes
+  (``client.pack.codec.<codec>[.bytes]``) and the averager's
+  ``averaging.rounds`` / ``.degraded_rounds`` / ``.bytes_sent``.  The hot
+  path's headline counts (overlapped jobs, pack bytes, hedges) are the
+  registry's alone: nothing read their Timeline twins;
 - :func:`device_trace`, a thin wrapper over ``jax.profiler.trace`` that
   captures an XLA/TensorBoard trace directory for the jitted compute.
 
@@ -27,8 +31,21 @@ neither.
 
 A span's NAME is its stage (``runtime.stack``), fixed by the code that
 takes it; what is data (``pool``, ``rows``, ``bucket``, the message
-``type``) goes into its attributes, so one stage has one reservoir and
-one p50 however many pools a server hosts.
+``type``, the ``kind``) goes into its attributes, so one stage has one
+reservoir and one p50 however many pools a server hosts.  One attribute
+is also read with profiling off: a ``kind`` of :data:`KINDS` (``forward``
+or ``backward``: a request's message type, a ``multi``'s ``op``, a pool's
+side of its expert) tags the span's entry in its stage's reservoir, and
+``stage_stats`` and ``recent`` read the tagged entries under the key
+``<name>:<kind>`` (``server.request:backward``) beside ``<name>``, so a
+forward can be told from a backward without tier 3.  The key is the
+reader's alone: name, annotation and full record do not carry it, and a
+span pays for its kind with one dictionary lookup, not a second append.
+Like ``trace``, it may be set until the span's exit.
+
+A span may start at a reading the caller already has
+(``span(name, start=previous.end)``): the stages of one thread are then
+contiguous by construction, and each costs one clock call.
 
 **Distributed tracing** (ISSUE 4): spans may carry a compact *trace id*
 (:func:`new_trace_id`, 16 hex chars) allocated once per logical operation
@@ -42,40 +59,38 @@ to the wall clock at export, so traces merged from multiple processes on
 one machine align.  Trace ids are only allocated while the timeline is
 enabled — disabled-path requests carry no extra meta and record nothing.
 
-The expert server times a request's life stage by stage —
-``server.decode`` / ``server.request`` / ``server.encode`` on the loop,
-``pool.wait`` in the task pool, ``runtime.queue`` / ``runtime.stack`` /
-``runtime.dispatch`` / ``runtime.materialize`` / ``runtime.idle`` on the
-Runtime thread and ``runtime.deliver`` back to the loop; the table of
-every span, its thread and its boundaries is in docs/OBSERVABILITY.md.
+The expert server partitions a request's life without a hole, from the
+first byte to the last: ``server.conn.idle`` (the client's time between
+two requests, seen at the socket) | ``server.read`` | ``server.request``
+| ``server.write`` on the loop; inside ``server.request``
+``server.decode``, ``pool.wait`` in the task pool, ``runtime.queue`` /
+``runtime.stack`` / ``runtime.dispatch`` / ``runtime.materialize`` on the
+Runtime thread, ``runtime.deliver`` and ``server.resume`` back on the
+loop, ``server.encode``.  The Runtime thread's own time is the chain
+``runtime.idle | stack | dispatch | materialize | handoff``.  All but
+``runtime.idle`` are filed by kind; the table of every span, its thread
+and its boundaries is in docs/OBSERVABILITY.md.
 
-The CLIENT dispatch pipeline (PR 2) mirrors this: per-dispatch
-``client.pack`` spans (``kind`` forward / backward; host-thread
-serialization — off the event loop by construction), counters
-``client.pack.bytes`` and ``client.pack_once.bytes_saved`` (duplicated
-wire-encode bytes the pack-once fan-out avoided), and per-RPC
-``rpc.<msg_type>`` spans covering the on-loop exchange.  The
-serialize-vs-wait breakdown also surfaces without profiling enabled via
-``RemoteMixtureOfExperts.pack_times`` / ``wait_times`` and
-``dispatch_stats()``.
-
-The FUTURE-BASED dispatch core (ISSUE 7) splits each dispatch into two
-first-class spans: ``client.dispatch.fire`` (selection + payload prep +
-non-blocking fan-out submit, on the host thread) and
-``client.dispatch.join`` (the time the caller actually BLOCKED waiting
-for replies — emitted from the join's finally, so a timed-out join
-still records).  The gap between a dispatch's fire span and its join
-span is trunk compute overlapped with the in-flight RPCs; the
-time-weighted aggregate surfaces always-on as
-``lah_client_overlap_fraction`` (utils/metrics.py, ``dispatch_stats()``)
-— the overlapped swarm step's headline observable.
+The CLIENT mirrors this, by kind too: ``client.dispatch.fire``
+(selection + payload prep + non-blocking fan-out submit, on the host
+thread), ``client.pack`` inside it (host-thread serialization — off the
+event loop by construction), ``client.dispatch.join`` (the time the
+caller actually BLOCKED waiting for replies — emitted from the join's
+finally, so a timed-out join still records), and per exchange on the
+client loop ``rpc.<msg_type>`` with its two halves ``rpc.send`` and
+``rpc.decode`` (what is left of it is the wait for the server and the
+socket).  The gap between a dispatch's fire span and its join span is
+trunk compute overlapped with the in-flight RPCs; the time-weighted
+aggregate surfaces always-on as ``lah_client_overlap_fraction``
+(utils/metrics.py).  ``RemoteMixtureOfExperts.dispatch_stats()`` carries
+the process's client stages (``stages``) beside the mixture's own pack
+and wait medians (``pack_times`` / ``wait_times``).
 
 The trainer-side AVERAGING subsystem (ISSUE 3) records per-round
-``averaging.round`` spans and the counters ``averaging.rounds``,
-``averaging.degraded_rounds``, ``averaging.bytes_sent``; like the client
-dispatch path, its headline numbers (round p50/p99, group sizes,
-degraded fraction) also surface without profiling via
-``DecentralizedAverager.stats()`` / ``AveragingSession.averaging_stats()``.
+``averaging.round`` spans; like the client dispatch path, its headline
+numbers (round p50/p99, group sizes, degraded fraction) also surface
+without profiling via ``DecentralizedAverager.stats()`` /
+``AveragingSession.averaging_stats()``.
 
 Headline counters do NOT live here: the always-on cheap metrics a
 production peer exports by default belong to the registry in
@@ -124,6 +139,15 @@ RESERVOIR_LEN = 4096
 # not reach back into a server's start-up for its sample.
 STAGE_WINDOW_S = 30.0
 
+# The kinds a stage's spans can be told apart by with profiling off: a
+# closed set, so that a stage is read under at most three keys (``<name>``,
+# ``<name>:forward``, ``<name>:backward``) whatever its callers pass.  A
+# reservoir entry is ``(start, duration, code)`` with the kind's code as a
+# float (0.0: none), so that telling the kinds apart costs a span no second
+# append and ``stage_stats`` still reads a reservoir as one flat array.
+KINDS = ("forward", "backward")
+_KIND_CODES = {kind: float(i) for i, kind in enumerate(KINDS, 1)}
+
 _annotation_cls = None  # jax.profiler.TraceAnnotation, once jax is loaded
 
 
@@ -145,19 +169,24 @@ class Span:
 
     On exit it goes to the Timeline's three sinks (module docstring).
     ``trace`` and ``attrs`` may be set until then: a request's trace id
-    is known only once its meta is decoded.  ``duration`` (seconds) is
-    there after the exit, for a caller that also keeps a running sum."""
+    and its ``kind`` are known only once its meta is decoded.  ``start``
+    is the entry's ``time.monotonic`` reading (or the one the caller gave);
+    ``duration`` (seconds) and ``end`` (the exit's reading) are there after
+    the exit: for a caller that keeps a running sum, and for one whose
+    next stage begins where this one ended (``start=``)."""
 
-    __slots__ = ("_timeline", "_reservoir", "name", "trace", "attrs", "_t0",
-                 "_annotation", "duration")
+    __slots__ = ("_timeline", "_reservoir", "name", "trace", "attrs",
+                 "_annotation", "start", "duration", "end")
 
     def __init__(self, timeline: "Timeline", reservoir: deque, name: str,
-                 trace: Optional[str], attrs: dict):
+                 trace: Optional[str], attrs: dict,
+                 start: Optional[float] = None):
         self._timeline = timeline
         self._reservoir = reservoir
         self.name = name
         self.trace = trace
         self.attrs = attrs
+        self.start = start
 
     def __enter__(self) -> "Span":
         # a profiler annotation only while a profiler session is live:
@@ -168,7 +197,8 @@ class Span:
             self._annotation.__enter__()
         else:
             self._annotation = None
-        self._t0 = time.monotonic()
+        if self.start is None:
+            self.start = time.monotonic()
         return self
 
     def exclude(self) -> None:
@@ -179,12 +209,17 @@ class Span:
         self._reservoir = None
 
     def __exit__(self, exc_type, exc, tb) -> None:
-        t0 = self._t0
-        self.duration = duration = time.monotonic() - t0
+        t0 = self.start
+        self.end = end = time.monotonic()
+        self.duration = duration = end - t0
         if self._annotation is not None:
             self._annotation.__exit__(exc_type, exc, tb)
         if self._reservoir is not None:
-            self._reservoir.append((t0, duration))  # atomic under the GIL
+            try:
+                code = _KIND_CODES.get(self.attrs.get("kind"), 0.0)
+            except TypeError:  # an unhashable kind is no kind
+                code = 0.0
+            self._reservoir.append((t0, duration, code))  # atomic: the GIL
         if self._timeline.enabled:
             self._timeline._record_full(
                 self.name, t0, duration, self.trace, self.attrs
@@ -195,7 +230,7 @@ class Timeline:
     """Thread-safe collection of timing spans and event counters.
 
     Every span lands in a bounded **reservoir** of the most recent
-    ``(start, duration)`` under its name, profiling on or off
+    ``(start, duration, kind code)`` under its name, profiling on or off
     (:meth:`recent`, :meth:`stage_stats`).  While :attr:`enabled`, it is
     also recorded in full — ``(name, start, duration, trace id, thread
     id, attributes)`` — for :meth:`spans`, :meth:`summary` and the Chrome
@@ -219,7 +254,7 @@ class Timeline:
         self._spans: deque[
             tuple[str, float, float, Optional[str], int, dict]
         ] = deque(maxlen=maxlen)
-        self._recent: dict[str, deque[tuple[float, float]]] = {}
+        self._recent: dict[str, deque[tuple[float, float, float]]] = {}
         self._counters: defaultdict[str, float] = defaultdict(float)
         self.max_counter_keys = max_counter_keys
         self._lock = sanitizer.lock("profiling.timeline")
@@ -239,12 +274,19 @@ class Timeline:
             self._recent = {}  # a span in flight keeps its old reservoir
             self._counters.clear()
 
-    def span(self, name: str, trace: Optional[str] = None, **attrs) -> Span:
-        """A context manager timing the enclosed code as one span."""
+    def span(
+        self, name: str, trace: Optional[str] = None,
+        start: Optional[float] = None, **attrs,
+    ) -> Span:
+        """A context manager timing the enclosed code as one span.
+        ``start`` is a ``time.monotonic`` reading the caller already has,
+        the ``end`` of the stage before: the two are then contiguous by
+        construction, for one clock call less.  An attribute ``kind`` of
+        :data:`KINDS` makes the span one of ``<name>:<kind>`` too."""
         reservoir = self._recent.get(name)
         if reservoir is None:
             reservoir = self._new_reservoir(name)
-        return Span(self, reservoir, name, trace, attrs)
+        return Span(self, reservoir, name, trace, attrs, start)
 
     def record(
         self, name: str, start: float, duration: float,
@@ -255,7 +297,11 @@ class Timeline:
         reservoir = self._recent.get(name)
         if reservoir is None:
             reservoir = self._new_reservoir(name)
-        reservoir.append((start, duration))
+        try:
+            code = _KIND_CODES.get(attrs.get("kind"), 0.0)
+        except TypeError:  # an unhashable kind is no kind
+            code = 0.0
+        reservoir.append((start, duration, code))
         if self.enabled:
             self._record_full(name, start, duration, trace, attrs)
 
@@ -273,57 +319,73 @@ class Timeline:
                 name = "timeline.overflow"
             return self._recent.setdefault(name, deque(maxlen=RESERVOIR_LEN))
 
-    def recent(self, name: str) -> list[tuple[float, float]]:
-        """The most recent ``(start, duration)`` spans under ``name``
-        (``time.monotonic`` seconds), oldest first; at most
-        ``RESERVOIR_LEN``, from process start or the last ``clear()``."""
+    def recent(self, key: str) -> list[tuple[float, float]]:
+        """The most recent ``(start, duration)`` spans under ``key``
+        (``time.monotonic`` seconds), oldest first: a span name, or
+        ``<name>:<kind>`` for those of its spans that carried that kind.
+        At most ``RESERVOIR_LEN`` a name, from process start or the last
+        ``clear()``."""
+        name, _, kind = key.partition(":")
         reservoir = self._recent.get(name)
+        if reservoir is None or (kind and kind not in KINDS):
+            return []
         # list(deque) copies without releasing the GIL: no append can
         # fall inside it
-        return list(reservoir) if reservoir is not None else []
+        code = _KIND_CODES.get(kind)
+        return [(s, d) for s, d, c in list(reservoir)
+                if code is None or c == code]
 
     def stage_stats(
         self, prefix: str | tuple = "", window_s: float = STAGE_WINDOW_S,
         skip_tail_s: float = 0.0,
     ) -> dict[str, dict]:
-        """The span names under ``prefix`` (one, or a tuple of several),
-        all read over ONE common extent of time, so that a stage with two
+        """The keys under ``prefix`` (one, or a tuple of several), all
+        read over ONE common extent of time, so that a stage with two
         spans a second and one with seven hundred describe the same
-        seconds: per name ``count``, ``p50_ms``, ``p95_ms`` of the spans
-        that ended inside the extent, ``share``, the part of the extent
-        the stage was running (a span that reaches over either end counts
-        with its part inside), and ``extent_s`` itself.
+        seconds.  A key is a span name or, for the spans of it that
+        carried a kind, ``<name>:<kind>``.  Per key ``count``, ``p50_ms``,
+        ``p95_ms`` of the spans that ended inside the extent, ``share``,
+        the part of the extent the stage was running (a span that reaches
+        over either end counts with its part inside), and ``extent_s``
+        itself.
 
         The extent ends ``skip_tail_s`` before the last span any of the
-        names ended (a reader that knows the run closed with traffic of
+        keys ended (a reader that knows the run closed with traffic of
         another kind leaves that out) and is at most ``window_s`` long; it
         starts no earlier than their first span, nor than the first entry
-        of any FULL reservoir among them, which has forgotten what ended
-        before that.  A name with no span in the extent has ``count`` 0,
-        ``share`` 0 and no percentiles."""
-        spans_of = {}
+        of any FULL reservoir among their names, which has forgotten what
+        ended before that.  A key with no span in the extent has ``count``
+        0, ``share`` 0 and no percentiles."""
+        spans_of, forgotten_before = {}, []
         for name in list(self._recent):
-            spans = self.recent(name) if name.startswith(prefix) else []
-            if spans:
-                # fromiter over the flattened pairs: half the cost of
-                # np.asarray(list of tuples), and this runs on a serving loop
-                starts, durations = np.fromiter(
-                    chain.from_iterable(spans), float, 2 * len(spans)
-                ).reshape(-1, 2).T
-                spans_of[name] = (starts, durations, starts + durations)
+            keys = [k for k in (name, *(f"{name}:{kind}" for kind in KINDS))
+                    if k.startswith(prefix)]
+            reservoir = list(self._recent.get(name, ())) if keys else []
+            if not reservoir:
+                continue
+            # fromiter over the flattened triples: half the cost of
+            # np.asarray(list of tuples), and this runs on a serving loop
+            starts, durations, codes = np.fromiter(
+                chain.from_iterable(reservoir), float, 3 * len(reservoir)
+            ).reshape(-1, 3).T
+            ends = starts + durations
+            if len(reservoir) == RESERVOIR_LEN:  # appended in order of ends
+                forgotten_before.append(float(ends[0]))
+            for key in keys:
+                kind = key[len(name) + 1:]
+                of = codes == _KIND_CODES[kind] if kind else slice(None)
+                if len(starts[of]):
+                    spans_of[key] = (starts[of], durations[of], ends[of])
         if not spans_of:
             return {}
         end = max(float(e.max()) for _, _, e in spans_of.values()) - skip_tail_s
         first = min(float(s.min()) for s, _, _ in spans_of.values())
-        begin = max(end - window_s, first)
-        for _, _, ends in spans_of.values():
-            if len(ends) == RESERVOIR_LEN:  # appended in the order of ends
-                begin = max(begin, float(ends[0]))
+        begin = max(end - window_s, first, *forgotten_before)
         extent = end - begin
         if extent <= 0:
             return {}
         out = {}
-        for name, (starts, durations, ends) in spans_of.items():
+        for key, (starts, durations, ends) in spans_of.items():
             inside = (ends >= begin) & (ends <= end)
             count = int(inside.sum())
             # a stage that did not run in the extent has a share, 0, and
@@ -334,7 +396,7 @@ class Timeline:
                 if count else (None, None)
             )
             running = np.clip(ends, begin, end) - np.clip(starts, begin, end)
-            out[name] = {
+            out[key] = {
                 "count": count,
                 "p50_ms": p50,
                 "p95_ms": p95,

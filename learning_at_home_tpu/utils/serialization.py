@@ -310,12 +310,20 @@ async def send_frame_parts(writer: asyncio.StreamWriter, parts: list) -> None:
     await writer.drain()
 
 
-async def recv_frame(reader: asyncio.StreamReader) -> bytes:
-    """Read one length-prefixed frame; raises on EOF or oversized frame."""
+async def recv_frame_length(reader: asyncio.StreamReader) -> int:
+    """Read a frame's length prefix: the wait for a peer's next frame ends
+    here, and what follows is ``reader.readexactly(length)``, the frame's
+    own bytes (the expert server times the two apart: ``server.conn.idle``
+    and ``server.read``).  Raises on EOF or an oversized frame."""
     (length,) = _U32.unpack(await reader.readexactly(4))
     if length > MAX_FRAME_BYTES:
         raise ValueError(f"frame of {length} bytes exceeds MAX_FRAME_BYTES")
-    return await reader.readexactly(length)
+    return length
+
+
+async def recv_frame(reader: asyncio.StreamReader) -> bytes:
+    """Read one length-prefixed frame; raises on EOF or oversized frame."""
+    return await reader.readexactly(await recv_frame_length(reader))
 
 
 # --------------------------------------------------------------------------

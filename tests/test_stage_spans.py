@@ -1,6 +1,9 @@
 """The life of a swarm request on the program's own clock (ISSUE 25):
 always-on stage reservoirs, profiler annotations on the device trace's
 clock, the benchmark's stage reducer, and the pod step's scope names.
+Since ISSUE 35 the life has no hole: the socket's two sides, the gap
+between a client's requests, the hand-off back to the loop, and every
+stage by kind (``<name>:forward`` / ``<name>:backward``).
 
 No chip: every time here is a host time of a CPU run and is compared only
 with other times of the same run.
@@ -16,10 +19,13 @@ import numpy as np
 import pytest
 
 from learning_at_home_tpu.client import RemoteExpert, reset_client_rpc
+from learning_at_home_tpu.client.moe import RemoteMixtureOfExperts
+from learning_at_home_tpu.client.routing import StaticExpertSource
 from learning_at_home_tpu.server import connection_handler
 from learning_at_home_tpu.server.server import background_server
 from learning_at_home_tpu.utils import profiling
 from learning_at_home_tpu.utils.profiling import (
+    KINDS,
     RESERVOIR_LEN,
     Timeline,
     timeline,
@@ -41,11 +47,23 @@ SERIAL_STAGES = (
     "pool.wait", "runtime.queue", "runtime.stack", "runtime.dispatch",
     "runtime.materialize", "runtime.deliver",
 )
-# the runtime thread's own time: it is in exactly one of these, or between
+# the runtime thread's own time: it is in exactly one of these, each
+# starting at the reading the one before ended at
 RUNTIME_THREAD_STAGES = (
     "runtime.idle", "runtime.stack", "runtime.dispatch",
-    "runtime.materialize",
+    "runtime.materialize", "runtime.handoff",
 )
+# ISSUE 35: the socket's two sides, the gap between a client's requests,
+# the coroutine's resumption, the runtime thread's hand-off
+NEW_STAGES = (
+    "server.read", "server.write", "server.conn.idle", "server.resume",
+    "runtime.handoff",
+)
+# every stage but the runtime thread's idle wait is also filed by kind
+KINDED_STAGES = tuple(
+    n for n in SERVER_STAGES + NEW_STAGES if n != "runtime.idle"
+)
+STAGE_PREFIXES = ("server.", "pool.", "runtime.")
 
 
 def _load(relative_path: str):
@@ -87,8 +105,9 @@ def served():
             for e in experts:  # the control plane, among the data plane's
                 e.info()
             seen = {
-                "recent": {n: timeline.recent(n) for n in SERVER_STAGES},
-                "stats": timeline.stage_stats(("server.", "pool.", "runtime.")),
+                "recent": {n: timeline.recent(n)
+                           for n in SERVER_STAGES + NEW_STAGES},
+                "stats": timeline.stage_stats(STAGE_PREFIXES),
                 "runtime_stats": srv.runtime.stats(),
                 "full_spans": timeline.spans(),
                 "metas": list(metas),
@@ -100,7 +119,7 @@ def served():
         reset_client_rpc()
 
 
-@pytest.mark.parametrize("stage", SERVER_STAGES)
+@pytest.mark.parametrize("stage", SERVER_STAGES + NEW_STAGES)
 def test_every_stage_has_a_reservoir_with_profiling_off(served, stage):
     spans = served["recent"][stage]
     assert len(spans) >= 100, f"{stage}: {len(spans)} spans of {REQUESTS}"
@@ -139,11 +158,118 @@ def test_stages_of_the_median_request_fit_inside_server_request(served):
 
 
 def test_runtime_thread_shares_sum_to_its_time(served):
-    """idle + stack + dispatch + materialize is the runtime thread's own
-    time (``runtime.queue`` and ``runtime.deliver`` are waits of others):
-    the shares of their reservoirs' extents sum to about one."""
+    """idle + stack + dispatch + materialize + handoff is the runtime
+    thread's own time (``runtime.queue`` and ``runtime.deliver`` are waits
+    of others), each stage starting at the reading the one before ended
+    at: the shares of their reservoirs' extents sum to one."""
     shares = {n: served["stats"][n]["share"] for n in RUNTIME_THREAD_STAGES}
-    assert 0.8 <= sum(shares.values()) <= 1.02, shares
+    assert 0.95 <= sum(shares.values()) <= 1.02, shares
+
+
+def test_runtime_thread_stages_are_contiguous(served):
+    """By construction, not by luck: put in order, every stage of the
+    runtime thread starts at the very reading its predecessor ended at.
+    ``runtime.stack`` alone reads the clock for itself (it times
+    ``BatchJob.stack`` and nothing else, as it did before the chain): what
+    precedes it is a few lines of the thread's loop."""
+    chain = sorted(
+        (start, start + duration, name)
+        for name in RUNTIME_THREAD_STAGES
+        for start, duration in served["recent"][name]
+    )
+    assert len(chain) >= 4 * REQUESTS  # the thread is not idle before each
+    before_stack, elsewhere = [], []
+    for (_, a_end, _), (b_start, _, b_name) in zip(chain, chain[1:]):
+        (before_stack if b_name == "runtime.stack" else elsewhere).append(
+            b_start - a_end)
+    assert max(abs(h) for h in elsewhere) < 1e-9, max(elsewhere)
+    assert min(before_stack) >= 0
+    assert float(np.median(before_stack)) < 200e-6, np.median(before_stack)
+    # and a batch's wait in the queue ends at the reading its stacking
+    # starts at
+    stacks = {start for start, _ in served["recent"]["runtime.stack"]}
+    queue_ends = [s + d for s, d in served["recent"]["runtime.queue"]]
+    assert all(min(abs(e - s) for s in stacks) < 1e-9 for e in queue_ends)
+
+
+def test_a_connections_time_is_read_request_write_or_idle(served):
+    """One client, one request at a time: from the first frame's length
+    prefix to the last reply's write, the connection is being read,
+    serving, being written or waiting for the client.  What the four
+    leave out is the step from a complete frame to the handler (the
+    header's peek, the muxed request's task): within 5 % and 1 ms a
+    request."""
+    recent = served["recent"]
+    begin = min(s for s, _ in recent["server.read"])
+    end = max(s + d for s, d in recent["server.write"])
+    named = sum(
+        d for name in ("server.read", "server.request", "server.write",
+                       "server.conn.idle")
+        for s, d in recent[name] if begin <= s and s + d <= end + 1e-9
+    )
+    wall = end - begin
+    assert named <= wall * (1 + 1e-6)
+    assert wall - named <= 0.05 * wall, (named, wall)
+    assert (wall - named) / REQUESTS <= 1e-3
+
+
+def test_every_gap_between_two_requests_is_an_idle_span(served):
+    """One ``server.conn.idle`` a gap: before each of the 200 requests
+    (the first follows the warm-up's reply) and before the first ``info``;
+    none after an ``info`` reply, which has no kind."""
+    idle = served["recent"]["server.conn.idle"]
+    assert len(idle) == REQUESTS + 1
+    reads = sorted(s for s, _ in served["recent"]["server.read"])
+    ends = sorted(s + d for s, d in idle)[:REQUESTS]
+    # each gap ends at the reading the next frame's read starts at
+    assert max(abs(e - r) for e, r in zip(ends, reads)) < 1e-9
+
+
+def test_the_wait_that_ends_in_eof_is_no_idle_span():
+    """A client that answers nothing for 0.3 s and then leaves: the wait
+    stays out, so the extent ``stage_stats`` reads ends with the last
+    request, where it ended before the connection's stages existed."""
+    timeline.clear()
+    try:
+        with background_server(
+            num_experts=1, hidden_dim=HID, expert_prefix="ffn", seed=0
+        ) as (endpoint, _srv):
+            expert = RemoteExpert("ffn.0", endpoint, timeout=30.0)
+            x = np.ones((4, HID), np.float32)
+            began = time.monotonic()
+            for _ in range(3):
+                expert.forward_blocking([x])
+            served_s = time.monotonic() - began
+            time.sleep(0.3)
+            reset_client_rpc()  # the pools close: EOF at the server
+            time.sleep(0.2)
+            idle = timeline.recent("server.conn.idle")
+            stats = timeline.stage_stats(STAGE_PREFIXES)
+            last = max(s + d for s, d in timeline.recent("server.write"))
+            ends = [s + d for n in SERVER_STAGES + NEW_STAGES
+                    for s, d in timeline.recent(n)]
+    finally:
+        timeline.clear()
+        reset_client_rpc()
+    assert len(idle) == 2 and max(d for _, d in idle) < 0.25, idle
+    # nothing ends long after the last reply's write (the runtime thread's
+    # hand-off of that batch may: it waits for the loop that is writing)
+    assert 0 <= max(ends) - last < 0.1
+    # the extent is the three requests', without the 0.5 s that followed
+    assert stats["server.request"]["extent_s"] <= served_s + 0.01
+
+
+def test_resume_is_inside_the_request(served):
+    recent = served["recent"]
+    resume = float(np.median([d for _, d in recent["server.resume"]]))
+    request = float(np.median([d for _, d in recent["server.request"]]))
+    assert 0 <= resume <= request
+    requests = sorted(recent["server.request"])
+    for (start, duration), (r_start, r_duration) in zip(
+        sorted(recent["server.resume"]), requests
+    ):  # one part a request here: the n-th resume lies in the n-th request
+        assert r_start <= start
+        assert start + duration <= r_start + r_duration + 1e-9
 
 
 def test_stats_rpc_carries_the_stages(served):
@@ -158,8 +284,237 @@ def test_stats_rpc_carries_the_stages(served):
 
 
 def test_span_names_in_the_server_carry_no_data(served):
-    for name in served["stats"]:
-        assert name.count(".") == 1, name
+    """The names are the stages' closed set; the only suffix a reservoir's
+    key takes is one of the two kinds."""
+    stages = set(SERVER_STAGES + NEW_STAGES)
+    for key in served["stats"]:
+        name, _, kind = key.partition(":")
+        assert name in stages, key
+        assert kind in ("",) + KINDS, key
+
+
+DISPATCHES = 40  # of the mixture: one forward and one backward ``multi``
+SINGLES = 35  # of each kind, by a ``RemoteExpert``: above the reducer's floor
+CLIENT_STAGES = (
+    "client.dispatch.fire", "client.dispatch.join", "client.pack",
+    "rpc.multi", "rpc.send", "rpc.decode",
+)
+
+
+@pytest.fixture(scope="module")
+def served_by_kind():
+    """Both kinds of request in both forms, profiling OFF: 40 jitted
+    forward+grad dispatches of a mixture over two experts (a forward and a
+    backward ``multi`` of two parts each) and 35 plain ``forward`` and
+    ``backward`` requests; yields every reservoir and the mixture's
+    ``dispatch_stats()``."""
+    import jax
+
+    assert not timeline.enabled
+    timeline.clear()
+    try:
+        with background_server(
+            num_experts=2, hidden_dim=HID, expert_prefix="ffn", seed=0
+        ) as (endpoint, srv):
+            moe = RemoteMixtureOfExperts(
+                in_features=HID, grid_size=(2,), uid_prefix="ffn", k_best=2,
+                k_min=1,
+                source=StaticExpertSource({u: endpoint for u in srv.experts}),
+            )
+            gate = moe.init_gate_params(jax.random.PRNGKey(0))
+            x = np.random.RandomState(0).randn(4, HID).astype(np.float32)
+            grad = jax.jit(jax.grad(lambda g, x: jax.numpy.sum(moe(x, g) ** 2)))
+            expert = RemoteExpert("ffn.0", endpoint, timeout=30.0)
+            jax.block_until_ready(grad(gate, x))  # compiles, both sides
+            expert.forward_blocking([x])
+            expert.backward_blocking([x], [x])
+            timeline.clear()
+            for _ in range(DISPATCHES):
+                jax.block_until_ready(grad(gate, x))
+            for _ in range(SINGLES):
+                expert.forward_blocking([x])
+                expert.backward_blocking([x], [x])
+            seen = {
+                "recent": {key: timeline.recent(key)
+                           for key in timeline.stage_stats(window_s=1e9)},
+                "dispatch_stats": moe.dispatch_stats(),
+                "server_stats": srv.runtime.stats()["stages"],
+            }
+        yield seen
+    finally:
+        timeline.clear()
+        reset_client_rpc()
+
+
+@pytest.mark.parametrize("stage", KINDED_STAGES + CLIENT_STAGES)
+def test_a_stage_is_also_filed_by_kind_with_profiling_off(
+    served_by_kind, stage
+):
+    """``<stage>:forward`` and ``<stage>:backward`` exist, share no span,
+    and together are the stage's own reservoir: each holds only its kind."""
+    recent = served_by_kind["recent"]
+    forward, backward = (recent.get(f"{stage}:{k}", []) for k in KINDS)
+    assert forward and backward, sorted(recent)
+    assert not set(forward) & set(backward)
+    assert sorted(forward + backward) == sorted(recent[stage])
+
+
+@pytest.mark.parametrize("stage, forward, backward", [
+    # a request: a ``multi`` a dispatch and kind, and the plain ones
+    ("server.request", DISPATCHES + SINGLES, DISPATCHES + SINGLES),
+    ("server.read", DISPATCHES + SINGLES, DISPATCHES + SINGLES),
+    ("server.write", DISPATCHES + SINGLES, DISPATCHES + SINGLES),
+    # a task: two parts a ``multi``
+    ("pool.wait", 2 * DISPATCHES + SINGLES, 2 * DISPATCHES + SINGLES),
+    ("server.resume", 2 * DISPATCHES + SINGLES, 2 * DISPATCHES + SINGLES),
+    ("rpc.multi", DISPATCHES, DISPATCHES),
+    ("rpc.forward", SINGLES, 0),
+    ("rpc.backward", 0, SINGLES),
+    ("client.dispatch.fire", DISPATCHES, DISPATCHES),
+    ("client.dispatch.join", DISPATCHES, DISPATCHES),
+    ("client.pack", DISPATCHES, DISPATCHES),
+    ("rpc.send", DISPATCHES + SINGLES, DISPATCHES + SINGLES),
+    ("rpc.decode", DISPATCHES + SINGLES, DISPATCHES + SINGLES),
+    # the thread's idle wait has no kind, and the control plane no span
+    ("runtime.idle", 0, 0),
+    ("rpc.hello", 0, 0),
+])
+def test_kinds_hold_what_was_sent(served_by_kind, stage, forward, backward):
+    recent = served_by_kind["recent"]
+    counts = [len(recent.get(f"{stage}:{k}", [])) for k in KINDS]
+    assert counts == [forward, backward]
+
+
+def test_a_backward_request_is_not_a_forward_one(served_by_kind):
+    """Why the kinds exist: a backward stays longer in the server than a
+    forward, and the two-kind median describes neither."""
+    stages = served_by_kind["server_stats"]
+    assert {"server.request:forward", "server.request:backward",
+            "runtime.queue:backward", "server.conn.idle:forward",
+            "server.conn.idle:backward"} <= set(stages)
+    assert stages["server.request:forward"]["count"] == DISPATCHES + SINGLES
+    assert (stages["server.request"]["count"]
+            == 2 * stages["server.request:forward"]["count"])
+
+
+def test_dispatch_stats_carry_the_clients_stages_by_kind(served_by_kind):
+    """``dispatch_stats()["stages"]``: fire, join, pack and the two
+    halves of an exchange, by kind, over one extent; the halves fit
+    inside their exchange.  The mixture's own two medians stay."""
+    import msgpack
+
+    stats = served_by_kind["dispatch_stats"]
+    stages = stats["stages"]
+    for stage in CLIENT_STAGES:
+        for key in (stage, f"{stage}:forward", f"{stage}:backward"):
+            assert stages[key]["count"] >= DISPATCHES, key
+    assert len({s["extent_s"] for s in stages.values()}) == 1
+    for kind in KINDS:
+        halves = (stages[f"rpc.send:{kind}"]["p50_ms"]
+                  + stages[f"rpc.decode:{kind}"]["p50_ms"])
+        assert halves <= stages[f"rpc.multi:{kind}"]["p50_ms"]
+    # every ``multi`` exchange holds its own two halves, to the reading
+    recent = served_by_kind["recent"]
+    for outer_start, outer_s in recent["rpc.multi"]:
+        inside = [
+            d for name in ("rpc.send", "rpc.decode")
+            for s, d in recent[name]
+            if outer_start <= s and s + d <= outer_start + outer_s + 1e-9
+        ]
+        assert len(inside) == 2 and sum(inside) <= outer_s
+    assert stats["pack_p50_ms"] > 0 and stats["wait_p50_ms"] > 0
+    json_safe = msgpack.packb(stages, use_bin_type=True)  # /metrics.json
+    assert json_safe
+
+
+@pytest.mark.parametrize("kind, keys", [
+    ("forward", {"stage", "stage:forward"}),
+    ("backward", {"stage", "stage:backward"}),
+    ("multi", {"stage"}),  # not one of the two: a closed set
+    ("", {"stage"}),
+    (None, {"stage"}),
+    (["forward"], {"stage"}),  # peer-supplied meta can be anything
+    (7, {"stage"}),
+])
+def test_only_the_two_kinds_make_a_key(kind, keys):
+    tl = Timeline()
+    with tl.span("stage", kind=kind):
+        pass
+    tl.record("stage", 1.0, 0.5, kind=kind)
+    with tl.span("stage") as late:  # known only inside, as a request's is
+        late.attrs["kind"] = kind
+    assert set(tl.stage_stats(window_s=float("inf"))) == keys
+    for key in keys:
+        assert len(tl.recent(key)) == 3
+    with tl.span("stage", kind=kind) as aside:
+        aside.exclude()  # out of the stage's reservoir: out of its kind's too
+    assert [len(tl.recent(key)) for key in sorted(keys)] == [3] * len(keys)
+
+
+def test_a_kind_is_an_attribute_in_the_full_record_and_no_name():
+    tl = Timeline()
+    tl.enable()
+    with tl.span("stage", "ab" * 8, kind="backward", pool="p.0"):
+        pass
+    (name, _, _, trace, _, attrs) = tl.spans()[0]
+    assert (name, trace) == ("stage", "ab" * 8)
+    assert attrs == {"kind": "backward", "pool": "p.0"}
+    assert [e["name"] for e in tl.chrome_trace()[1:]] == ["stage"]
+    assert set(tl.summary()) == {"stage"}
+
+
+def test_a_span_can_start_where_the_last_one_ended():
+    tl = Timeline()
+    with tl.span("first") as first:
+        pass
+    with tl.span("second", start=first.end) as second:
+        pass
+    (start, duration), = tl.recent("second")
+    assert start == first.end and second.end == start + duration
+    assert tl.recent("first")[0][0] + first.duration == pytest.approx(
+        first.end, abs=1e-12)
+
+
+def test_the_ten_stages_read_the_same_with_and_without_the_new_names(served):
+    """``stage_stats`` on a recorded set of reservoirs: PR 25's ten stages
+    alone, then with the five new stages and every key by kind beside
+    them.  Counts, medians and seconds of running time are the same; the
+    extent runs from the first request's read to the last reply's write
+    (and the client's gaps around them), not from the first handler entry
+    to the last frame built, and grows by exactly that."""
+    old, new = Timeline(), Timeline()
+    for name in SERVER_STAGES + NEW_STAGES:
+        for start, duration in served["recent"][name]:
+            if name in SERVER_STAGES:
+                old.record(name, start, duration)
+            kind = None if name == "runtime.idle" else "forward"
+            new.record(name, start, duration, kind=kind)
+    before = old.stage_stats(STAGE_PREFIXES)
+    after = new.stage_stats(STAGE_PREFIXES)
+    assert set(before) == set(SERVER_STAGES)
+    assert len(after) == 2 * len(SERVER_STAGES + NEW_STAGES) - 1
+    # the first request's read and the client's gap before it begin
+    # before the first of the ten stages' spans, and the last reply's write
+    # and the gap after it reach beyond the last: all the extent grows by
+    recent = served["recent"]
+    slack = (
+        min(s for n in SERVER_STAGES for s, _ in recent[n])
+        - min(s for n in NEW_STAGES for s, _ in recent[n])
+        + max(s + d for n in NEW_STAGES for s, d in recent[n])
+        - max(s + d for n in SERVER_STAGES for s, d in recent[n])
+    )
+    assert 0 <= slack < 0.1
+    for name in SERVER_STAGES:
+        for key in ("count", "p50_ms", "p95_ms"):
+            assert after[name][key] == before[name][key], (name, key)
+        was, now = before[name], after[name]
+        assert now["extent_s"] - was["extent_s"] == pytest.approx(
+            slack, abs=2e-4)
+        # the same seconds of running time, over an extent that much longer
+        assert now["share"] * now["extent_s"] == pytest.approx(
+            was["share"] * was["extent_s"], rel=1e-3, abs=1e-5)
+        if name != "runtime.idle":
+            assert after[f"{name}:forward"] == now
 
 
 def test_reservoirs_are_bounded_and_names_capped():
@@ -271,9 +626,9 @@ def test_spans_are_profiler_annotations_on_the_device_traces_clock(tmp_path):
         sys.path.remove(os.path.join(REPO, "benchmarks"))
 
     annotated = ("server.decode", "server.request", "server.encode",
-                 "runtime.idle", "runtime.stack", "runtime.dispatch",
-                 "runtime.materialize")
-    recorded = ("pool.wait", "runtime.queue", "runtime.deliver")
+                 "server.write") + RUNTIME_THREAD_STAGES
+    recorded = ("pool.wait", "runtime.queue", "runtime.deliver",
+                "server.read", "server.conn.idle", "server.resume")
     try:
         with background_server(
             num_experts=1, hidden_dim=HID, expert_prefix="ffn", seed=0
@@ -296,6 +651,21 @@ def test_spans_are_profiler_annotations_on_the_device_traces_clock(tmp_path):
     assert not seen & set(recorded)
     for name, start, end in events["host"]:
         assert end >= start
+    # the runtime thread's five stages, read as the benchmark would read
+    # them to name a device gap: from its first annotation to its last
+    # they cover the thread's time (what is between two of them is the
+    # loop's own few lines)
+    thread = sorted(
+        (start, end) for name, start, end in trace_reduce.load_events(
+            trace_reduce.find_xplane(str(tmp_path)),
+            host_spans=RUNTIME_THREAD_STAGES,
+        )["host"]
+    )
+    assert len(thread) >= 5 * 4
+    covered = sum(end - start for start, end in thread)
+    span = thread[-1][1] - thread[0][0]
+    assert covered <= span * (1 + 1e-9)
+    assert covered >= 0.95 * span, (covered, span)
 
 
 @pytest.mark.parametrize("key, scale, want", [
@@ -348,20 +718,96 @@ def test_stage_reducer_reads_the_loaded_module_or_nothing(
         timeline.clear()
 
 
-def test_every_stage_metric_names_the_one_reducer_and_a_server_stage():
+def _stage_metric_specs() -> list:
     import glob
     import json
 
+    specs = [json.load(open(p)) for p in sorted(glob.glob(
+        os.path.join(REPO, "benchmarks/layer_metrics/server.*.json")))]
+    return [s for s in specs if s["source"] == "program_span"]
+
+
+def test_the_stage_metrics_are_the_ten_and_the_fourteen():
+    specs = _stage_metric_specs()
+    assert len(specs) == 24
+    by_reducer = [s["reducer"] for s in specs]
+    assert by_reducer.count("stage_stat") == 23
+    assert by_reducer.count("stage_remainder") == 1
+    keys = [s["args"].get("name") for s in specs if "name" in s["args"]]
+    assert len(set(keys)) == len(keys)  # no stage is read twice
+
+
+@pytest.mark.parametrize(
+    "spec", _stage_metric_specs(), ids=lambda spec: spec["name"])
+def test_every_stage_metric_names_the_one_reducer_and_a_server_stage(spec):
+    """Each file reads a stage the program takes (by a kind, where it
+    says so), through ``stage_stat``; the one remainder reads five of
+    them through it too.  And its entry in the manifest says the same."""
+    import json
+
     module = _load("benchmarks/reducers/stage_stat.py")
-    specs = [json.load(open(p)) for p in glob.glob(
-        os.path.join(REPO, "benchmarks/layer_metrics/server.*.json"))]
-    staged = [s for s in specs if s["source"] == "program_span"]
-    assert len(staged) == 10
-    for spec in staged:
-        assert spec["reducer"] == "stage_stat", spec["name"]
-        assert spec["args"]["name"] in SERVER_STAGES
-        assert spec["args"]["name"].startswith(module.SERVER_STAGES)
+    stages = SERVER_STAGES + NEW_STAGES
+    if spec["reducer"] == "stage_stat":
+        name, _, kind = spec["args"]["name"].partition(":")
+        assert name in stages
+        assert kind in (("",) + KINDS if name != "runtime.idle" else ("",))
+        assert name.startswith(module.SERVER_STAGES)
         assert spec["args"]["key"] in ("p50_ms", "share")
+        assert f"span {name}:" in spec["text"]
+    else:
+        assert spec["reducer"] == "stage_remainder"
+        assert tuple(spec["args"]["names"]) == RUNTIME_THREAD_STAGES
+        assert spec["args"]["scale"] == 100.0 and spec["unit"] == "%"
+    manifest = json.load(open(os.path.join(REPO, "BENCHMARK.json")))
+    (entry,) = [e for e in manifest["per_layer"] if e["name"] == spec["name"]]
+    for key, value in entry.items():
+        assert spec[key] == value, key
+    assert set(entry["workloads"]) <= {
+        "ffnserver-infer-small", "ffnserver-train-bulk"}
+
+
+def test_stage_remainder_is_what_the_stages_leave_or_nothing(monkeypatch):
+    """100 x (1 - the five shares) over ``stage_stat``'s extent; ``None``
+    wherever ``stage_stat`` has none: under the floor, a stage the
+    program does not take (this PR's parent has no ``runtime.handoff``),
+    a program without ``stage_stats``, a cell that never loaded it."""
+    monkeypatch.syspath_prepend(os.path.join(REPO, "benchmarks"))
+    module = _load("benchmarks/reducers/stage_remainder.py")
+    stage_stat = _load("benchmarks/reducers/stage_stat.py")
+    args = {"names": list(RUNTIME_THREAD_STAGES), "scale": 100.0}
+    timeline.clear()
+    try:
+        # a batch every 0.1 s: idle 50 ms, then 10 ms a stage, 10 unnamed
+        for i in range(stage_stat.MIN_SPANS + 25):
+            t = 0.1 * i
+            timeline.record("runtime.idle", t, 0.05)
+            for j, name in enumerate(RUNTIME_THREAD_STAGES[1:4]):
+                timeline.record(name, t + 0.05 + 0.01 * j, 0.01, kind="forward")
+        assert module.reduce({}, **args) is None  # no handoff: the parent
+        one = {"names": args["names"][:4], "scale": 100.0}
+        assert module.reduce({}, **one) == pytest.approx(20.0, abs=0.7)  # edges
+        for i in range(stage_stat.MIN_SPANS + 25):
+            timeline.record("runtime.handoff", 0.1 * i + 0.08, 0.01,
+                            kind="forward")
+        assert module.reduce({}, **args) == pytest.approx(10.0, abs=0.7)
+        shares = [stage_stat.reduce({}, n, "share") for n in args["names"]]
+        assert module.reduce({}, **args) == pytest.approx(
+            100.0 * (1.0 - sum(shares)))
+        # inside a measured window too short for the floor
+        assert stage_stat.reduce({"intervals_s": [4.0]}, "runtime.idle",
+                                 "share") is None
+        assert module.reduce({"intervals_s": [4.0]}, **args) is None
+        monkeypatch.setitem(
+            sys.modules, "learning_at_home_tpu.utils.profiling",
+            types.SimpleNamespace(timeline=object()),
+        )
+        assert module.reduce({}, **args) is None
+        monkeypatch.delitem(
+            sys.modules, "learning_at_home_tpu.utils.profiling"
+        )
+        assert module.reduce({}, **args) is None
+    finally:
+        timeline.clear()
 
 
 POD_STEP_SCOPES = (
@@ -412,9 +858,14 @@ def test_pod_step_scopes_nest_under_their_layer(pod_step_locations):
                    for loc in pod_step_locations), inner
 
 
-def test_span_costs_microseconds_on_the_default_path():
-    """The budget is 1 us a span with profiling off; held at 5 us here so
-    that a slow shared core does not fail it."""
+@pytest.mark.parametrize("attrs", [
+    {"pool": "p.0", "rows": 64},
+    {"pool": "p.0", "rows": 64, "kind": "backward"},  # a tag on the entry
+])
+def test_span_costs_microseconds_on_the_default_path(attrs):
+    """The budget is 1 us a span with profiling off, its kind included (a
+    dictionary lookup, no second append); held at 5 us here so that a
+    slow shared core does not fail it."""
     assert not timeline.enabled
     n = 20_000
     best = float("inf")
@@ -422,9 +873,11 @@ def test_span_costs_microseconds_on_the_default_path():
         for _ in range(5):
             t0 = time.perf_counter()
             for _ in range(n):
-                with timeline.span("bench.span", pool="p.0", rows=64):
+                with timeline.span("bench.span", **attrs):
                     pass
             best = min(best, (time.perf_counter() - t0) / n)
+        assert len(timeline.recent("bench.span:backward")) == (
+            RESERVOIR_LEN if "kind" in attrs else 0)
     finally:
         timeline.clear()
     assert best < 5e-6, f"{best * 1e9:.0f} ns a span"
