@@ -129,23 +129,50 @@ def causal_attention(
     return output_projection(lp, attention_core(q, k, v, impl))
 
 
-# Tile sizes of the blocked kernel (splash attention, fused backward), the
-# fastest of the sweep on a TPU v5e at [4, 16, 4096, 128] bf16 (PERF.md
-# section 6 "PR 28"; tools/attention_probe.py): forward 2.72 ms, forward
-# and backward 8.84 ms, against 68.3 ms for the xla core.  Each is
-# ``min(size, S)``.
+# Tile sizes of the blocked kernel (splash attention), two regimes read
+# from the mask's window.  Under a causal mask, or a window no shorter
+# than the key block: the fastest of the sweep on a TPU v5e at [4, 16,
+# 4096, 128] bf16 under a causal mask, fused backward (PERF.md section 6
+# "PR 28"; tools/attention_probe.py splash): forward 2.72 ms, forward and
+# backward 8.84 ms, against 68.3 ms for the xla core.  Each is ``min(size,
+# S)``.
 _FLASH_TILES = dict(
     block_q=1024, block_kv=1024, block_kv_compute=512,
     block_q_dkv=1024, block_kv_dkv=1024, block_kv_dkv_compute=512,
 )
+# Under a shorter window the backward is unfused (a dK/dV and a dQ kernel
+# over grids that shrink to the mask; the fused kernel's cannot, and it
+# writes S / block_kv partials of the queries' gradient, which XLA sums
+# afterwards) and every block, of the three kernels' queries and keys, is
+# this: the fastest of the sweep on a TPU v5e at [1, 64 over 8, 16384, 128]
+# bf16 under a window of 128 (PERF.md section 6 "PR 36";
+# tools/attention_probe.py window): forward 5.62 ms and forward + backward
+# 21.5 ms where the tiles above take 8.39 and 39.4.  A block visited is a
+# block computed whatever its mask admits, so the key block is the
+# window's cover, but no narrower than this: a grid step costs about 0.6
+# us before it computes, and 128-wide key blocks (8.35 and 32.5 ms) run no
+# faster than 1024-wide ones.  A query block then visits its own key block
+# and the one before under ANY window up to the block, so the reading at
+# 128 is the reading up to 512.  Measured at windows of 128 and 4,096 and
+# S 16,384; nothing between.
+_FLASH_WINDOW_TILE = 512
 
 
-def flash_block_sizes(shape: tuple, backend: str):
+def _dividing_tiles(s: int, at_least: int, at_most: int) -> list:
+    """The multiples of the 128 lanes from ``at_least`` to ``at_most``
+    that divide ``s``, ascending."""
+    first = -(-at_least // 128) * 128
+    return [t for t in range(first, at_most + 1, 128) if s % t == 0]
+
+
+def flash_block_sizes(shape: tuple, backend: str, window: int | None = None):
     """The blocked kernel's ``BlockSizes`` for q/k/v of ``shape`` [B, S, H,
-    hd] on ``backend``, or None where the kernel cannot run: a backend
-    other than ``tpu`` (Mosaic lowering), a length that is no multiple of
-    the 128-lane tile or that a tile does not divide, a head size the
-    kernel was never run at.  A pure function of what the call can see."""
+    hd] on ``backend`` under a causal mask, or under a ``window`` of that
+    many keys, or None where the kernel cannot run: a backend other than
+    ``tpu`` (Mosaic lowering), a length that is no multiple of the
+    128-lane tile or that a tile does not divide, a head size the kernel
+    was never run at (none of which asks for the window).  A pure
+    function of what the call can see."""
     from jax.experimental.pallas.ops.tpu.splash_attention import BlockSizes
 
     _, s, _, hd = shape
@@ -154,7 +181,20 @@ def flash_block_sizes(shape: tuple, backend: str):
     tiles = {name: min(size, s) for name, size in _FLASH_TILES.items()}
     if any(s % size for size in tiles.values()):
         return None
-    return BlockSizes(use_fused_bwd_kernel=True, **tiles)
+    if window is None or window >= tiles["block_kv"]:
+        return BlockSizes(use_fused_bwd_kernel=True, **tiles)
+    # the narrowest key block that covers the window and the measured
+    # tile (the block of the other regime divides s and covers both, so
+    # there is one); the widest query block within the tile
+    tile = min(_FLASH_WINDOW_TILE, s)
+    kv = _dividing_tiles(s, max(window, tile), tiles["block_kv"])[0]
+    q = _dividing_tiles(s, 128, tile)[-1]
+    return BlockSizes(
+        use_fused_bwd_kernel=False,
+        block_q=q, block_kv=kv, block_kv_compute=kv,
+        block_q_dkv=q, block_kv_dkv=kv, block_kv_dkv_compute=kv,
+        block_q_dq=q, block_kv_dq=kv,
+    )
 
 
 def attention_core(
@@ -174,7 +214,7 @@ def attention_core(
     if impl not in ("xla", "flash"):
         raise ValueError(f"impl must be 'xla' or 'flash', got {impl!r}")
     sizes = (
-        flash_block_sizes(q.shape, jax.default_backend())
+        flash_block_sizes(q.shape, jax.default_backend(), window)
         if impl == "flash" else None
     )
     if sizes is not None:

@@ -1,5 +1,6 @@
 """The attention core's choice (``trunk.attention_core``): which core
-runs, with which tiles, read from the call's shape and the backend alone.
+runs, with which tiles, read from the call's shape, the backend and the
+mask's window alone.
 
 CPU only: what the kernel computes and how fast is the chip's to say
 (``tools/attention_probe.py``); that Mosaic takes the tiles is an AOT
@@ -38,16 +39,93 @@ def test_tiles_fit_the_shape_or_the_kernel_is_refused(seq, head, batch):
         assert sizes is None
         return
     assert isinstance(sizes, BlockSizes) and sizes.has_backward_blocks
-    tiles = {
-        f.name: getattr(sizes, f.name) for f in dataclasses.fields(sizes)
-        if f.name.startswith("block_") and getattr(sizes, f.name) is not None
-    }
+    tiles = _tiles(sizes)
     assert len(tiles) == 6  # forward and the fused backward, three each
     assert all(t % 128 == 0 and seq % t == 0 for t in tiles.values()), tiles
     assert sizes.block_kv % sizes.block_kv_compute == 0
     assert sizes.block_kv_dkv % sizes.block_kv_dkv_compute == 0
     # a pure function of the shape: nothing else is read
     assert sizes == trunk.flash_block_sizes((batch, seq, 2, head), "tpu")
+
+
+def _tiles(sizes):
+    return {
+        f.name: getattr(sizes, f.name) for f in dataclasses.fields(sizes)
+        if f.name.startswith("block_") and getattr(sizes, f.name) is not None
+    }
+
+
+# the answer for every call until PR 36, and since then for a causal mask
+# and a window no shorter than the key block
+def _parents(seq):
+    return BlockSizes(
+        block_q=min(1024, seq), block_kv=min(1024, seq),
+        block_kv_compute=min(512, seq), block_q_dkv=min(1024, seq),
+        block_kv_dkv=min(1024, seq), block_kv_dkv_compute=min(512, seq),
+        use_fused_bwd_kernel=True,
+    )
+
+
+@pytest.mark.parametrize("head", [64, 128])
+@pytest.mark.parametrize("seq", [512, 4096, 16384])
+@pytest.mark.parametrize("window", [None, 128, 256, 1024, 4096])
+def test_tiles_read_the_window(window, seq, head):
+    """The rule's answer under a window: a ``BlockSizes`` the kernel's
+    ``__post_init__`` accepts, every tile a multiple of the 128 lanes
+    that divides S, the compute tile dividing its block.  No window, or
+    one no shorter than the key block: the parent's answer field for
+    field (fused backward, six tiles).  A shorter window: key blocks that
+    cover it, no narrower than the measured tile and no wider than the
+    parent's, and an unfused backward with its dQ kernel's tiles (eight
+    tiles).  Neither the batch nor the head count is read."""
+    sizes = trunk.flash_block_sizes((1, seq, 64, head), "tpu", window)
+    assert isinstance(sizes, BlockSizes) and sizes.has_backward_blocks
+    tiles = _tiles(sizes)
+    assert all(t % 128 == 0 and seq % t == 0 for t in tiles.values()), tiles
+    assert sizes.block_kv % sizes.block_kv_compute == 0
+    assert sizes.block_kv_dkv % sizes.block_kv_dkv_compute == 0
+    parent = _parents(seq)
+    if window is None or window >= min(1024, seq):
+        assert sizes == parent and len(tiles) == 6
+    else:
+        assert not sizes.use_fused_bwd_kernel and len(tiles) == 8
+        for name in ("block_kv", "block_kv_dkv", "block_kv_dq"):
+            assert window <= tiles[name] <= parent.block_kv, (name, tiles)
+            assert tiles[name] == min(trunk._FLASH_WINDOW_TILE, seq)
+    assert sizes == trunk.flash_block_sizes((4, seq, 2, head), "tpu", window)
+
+
+@pytest.mark.parametrize("seq, window", [
+    (16384, None), (16384, 4096), (4096, None),  # the three cells' other calls
+    (16384, 1024), (512, 512), (512, 4096),
+])
+def test_a_window_no_shorter_than_the_key_block_changes_nothing(seq, window):
+    """``olmoe`` (no window), ``smallthinker`` (4,096) and the global
+    layers get the ``BlockSizes`` they had, field for field."""
+    sizes = trunk.flash_block_sizes((1, seq, 28, 128), "tpu", window)
+    assert sizes == _parents(seq)  # a dataclass: every field compared
+    assert sizes == trunk.flash_block_sizes((1, seq, 28, 128), "tpu")
+
+
+@pytest.mark.parametrize("seq, window, key_block", [
+    (16384, 128, 512),   # k-exaone's window layers
+    (16384, 1, 512), (16384, 300, 512), (16384, 512, 512), (4096, 128, 512),
+    (16384, 513, 1024),  # 640, 768 and 896 divide no power of two: still unfused
+    (15360, 600, 640), (15360, 700, 768),
+    (1024, 200, 512), (512, 128, 512), (256, 128, 256), (1536, 128, None),
+])
+def test_key_blocks_cover_the_window_and_the_measured_tile(seq, window, key_block):
+    """Key blocks are the window's cover, no narrower than the tile the
+    sweep put first (a grid step's fixed cost: 128-wide key blocks under a
+    window of 128 ran no faster than 1024-wide ones)."""
+    sizes = trunk.flash_block_sizes((1, seq, 8, 128), "tpu", window)
+    if key_block is None:  # the window does not make a length divisible
+        assert sizes is None and trunk.flash_block_sizes((1, seq, 8, 128), "tpu") is None
+        return
+    assert (sizes.block_kv, sizes.block_kv_dkv, sizes.block_kv_dq) == (key_block,) * 3
+    assert not sizes.use_fused_bwd_kernel
+    assert sizes.block_q == sizes.block_q_dkv == sizes.block_q_dq == min(
+        trunk._FLASH_WINDOW_TILE, seq)
 
 
 @pytest.mark.parametrize("shape, backend", [

@@ -847,3 +847,30 @@ def test_the_whole_step_fits_the_chip(v5e_chip, monkeypatch):
     assert memory["loss_layer_products"] == 3  # of the head's; four before PR 34
     assert moe_dispatch.grouped_matmul_tiles(16384, 6144, 2048, jnp.bfloat16) == (
         256, 2048, 1024)
+    # the blocked kernel's tiles read the window (PR 36): the four window
+    # layers' backward is a dK/dV and a dQ kernel (none of the latter in
+    # the step before), the global layer's the fused one
+    assert memory["attention_kernel_calls"] == {
+        "splash_mha_fwd_residuals": 5 * 2,  # remat runs a forward twice
+        "splash_mha_dkv_no_residuals": 5, "splash_mha_dq_no_residuals": 4}
+    tilings = memory["attention_kernel_tilings"]
+    assert {kind: {name: call["calls"] for name, call in calls.items()}
+            for kind, calls in tilings.items()} == {
+        "global": {"splash_mha_fwd_residuals": 2, "splash_mha_dkv_no_residuals": 1},
+        "window": {"splash_mha_fwd_residuals": 8, "splash_mha_dkv_no_residuals": 4,
+                   "splash_mha_dq_no_residuals": 4}}
+    want = trunk.flash_block_sizes((1, 16384, 64, 128), "tpu", 128)
+    assert [(call["block_q"], call["block_kv"]) for call in tilings["window"].values()] == [
+        (want.block_q, want.block_kv), (want.block_q_dkv, want.block_kv_dkv),
+        (want.block_q_dq, want.block_kv_dq)]
+    assert all(call["block_kv"] == 512 for call in tilings["window"].values())
+    # a window layer's key-block axis is the two blocks its mask admits (a
+    # query block's own and the one before), not the 32 of the sequence
+    assert [call["grid"][-1] for call in tilings["window"].values()] == [2, 2, 2]
+    # the queries' gradient once a key block of 1024, [16, 64, 16384, 128]
+    # bf16, is the fused backward's: the global layer keeps it, and no
+    # kernel of a window layer writes anything near it
+    partials = 16 * 64 * 16384 * 128 * 2
+    assert tilings["global"]["splash_mha_dkv_no_residuals"]["largest_result_bytes"] == partials
+    assert all(call["largest_result_bytes"] <= partials // 8
+               for call in tilings["window"].values())

@@ -428,8 +428,14 @@ def test_xla_core_takes_grouped_heads_and_a_window(window):
         np.asarray(_naive_attention(q, k, v, window)), atol=2e-6)
 
 
-@pytest.mark.parametrize("window", [None, 300])
-def test_blocked_kernel_takes_grouped_heads_and_a_window(window, monkeypatch):
+@pytest.mark.parametrize("window, seq, tile", [
+    (None, 512, 128), (300, 512, 128),
+    # through the rule as it is: a window shorter than the key block, so
+    # 512-wide blocks of queries and keys and the unfused backward's two
+    # kernels over grids shrunk to the mask
+    (200, 1024, None),
+])
+def test_blocked_kernel_takes_grouped_heads_and_a_window(window, seq, tile, monkeypatch):
     """The kernel itself (interpreted on the CPU), over several blocks:
     two key/value heads under six query heads, uncopied, under the causal
     and the local mask, forward and backward, against the plain
@@ -439,11 +445,16 @@ def test_blocked_kernel_takes_grouped_heads_and_a_window(window, monkeypatch):
     from jax.experimental.pallas.ops.tpu import splash_attention as splash
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    monkeypatch.setattr(trunk, "_FLASH_TILES", {k: 128 for k in trunk._FLASH_TILES})
+    if tile:
+        monkeypatch.setattr(trunk, "_FLASH_TILES", {k: tile for k in trunk._FLASH_TILES})
+    else:
+        sizes = trunk.flash_block_sizes((1, seq, 6, 64), "tpu", window)
+        assert not sizes.use_fused_bwd_kernel
+        assert window < sizes.block_kv < seq and sizes.block_q < seq
     monkeypatch.setattr(
         splash, "make_splash_mha_single_device",
         functools.partial(splash.make_splash_mha_single_device, interpret=True))
-    q, k, v = _grouped_qkv(512, 6, 2, 64)
+    q, k, v = _grouped_qkv(seq, 6, 2, 64)
 
     def both(core):
         out, vjp = jax.vjp(core, q, k, v)
@@ -656,6 +667,13 @@ def test_the_whole_step_fits_the_chip(v5e_chip, monkeypatch):
     # the logits and the two gradient products, in one scan of chunks: the
     # chip's compiler keeps no fourth product of the head's (PR 34)
     assert memory["loss_layer_products"] == 3
+    # a window of 4,096 is no shorter than the kernel's key block: all four
+    # layers keep the fused backward at 1024-wide blocks (PR 36)
+    assert memory["attention_kernel_calls"] == {
+        "splash_mha_fwd_residuals": 4 * 2, "splash_mha_dkv_no_residuals": 4}
+    assert {(call["block_q"], call["block_kv"])
+            for calls in memory["attention_kernel_tilings"].values()
+            for call in calls.values()} == {(1024, 1024)}
 
 
 @pytest.mark.parametrize("layer", [0, 1], ids=["global", "window"])

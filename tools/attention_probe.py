@@ -5,6 +5,7 @@
     python tools/attention_probe.py tiles      # the other blocked kernel's
     python tools/attention_probe.py cores      # blocked against xla, by length
     python tools/attention_probe.py accuracy   # blocked against xla, results
+    python tools/attention_probe.py window     # the blocked kernel's tiles under a window
 
 One process a subcommand (a chip belongs to one process), one ``ROW``
 line of JSON a reading, written to ``chiprun_out/attention_probe.jsonl``
@@ -27,6 +28,20 @@ threshold were set from.
   forward + backward, at the cell's shape and at 8,192 tokens a call for
   S = 256 ... 8,192 and head sizes 64 and 128: where ``auto``'s
   threshold comes from.
+- ``window``: ``splash_attention`` at the two 16,384-token cells' shapes,
+  grouped heads, forward and forward + backward.  At K-EXAONE's ``[1, 64
+  over 8, 16384, 128]`` under ``LocalMask(127, 0)``: the baseline (1024,
+  1024, 512, fused) beside ``block_q`` in {256, 512, 1024, 2048} x
+  ``block_kv`` in {128, 256, 512} with the compute tile at the block and
+  at half of it, unfused (a dK/dV and a dQ kernel over grids the mask
+  shrinks, no per-key-block partials of the queries' gradient), then each
+  backward kernel's own tiles under the best of the others, a few
+  settings past the sweep's edges (``window beyond``), then the kernel
+  under ``flash_block_sizes``'s answer for the window (``window rule``).
+  At SmallThinker's ``[1, 28 over 4, 16384, 128]`` under a window of 4,096
+  and under the causal mask: the baseline against the unfused form at the
+  same blocks.  PERF.md section 6 ("PR 36") holds the table the rule's
+  short-window regime was set from.
 - ``accuracy``: ``attention_core`` ``flash`` against ``xla`` at the
   cell's shape, output and the three input gradients: rms of the
   difference over rms of the ``xla`` result (limit 1 %), beside what bf16
@@ -80,12 +95,16 @@ def ms(fn, *args) -> float:
     return (time.perf_counter() - t0) / ITERS * 1e3
 
 
-def timed(what: str, settings: dict, fn, *args) -> None:
+def timed(what: str, settings: dict, fn, *args) -> float | None:
+    """The row of one reading; its ms, None where it was refused."""
     try:
-        row(what=what, **settings, ms=round(ms(fn, *args), 3))
+        took = ms(fn, *args)
     except Exception as e:  # the kernel's own refusal, or Mosaic's
         text = f"{type(e).__name__}: {e}"
         row(what=what, **settings, refused=text.splitlines()[0][:300])
+        return None
+    row(what=what, **settings, ms=round(took, 3))
+    return took
 
 
 def qkv(shape):
@@ -165,22 +184,27 @@ def tiles() -> None:
               dq(bqm, bkm, bk), *args)
 
 
-def _splash(forward, backward, fused, heads, s):
-    """``forward``, ``backward``: (block_q, block_kv, block_kv_compute)."""
+def _splash(forward, backward, fused, heads, s, window=None, dq=None):
+    """``forward``, ``backward``: (block_q, block_kv, block_kv_compute);
+    ``dq``: the unfused backward's dQ kernel's (block_q, block_kv), the
+    backward's blocks where none is given."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as sk,
         splash_attention_mask as sm,
     )
 
     (bq, bkv, bkvc), (bq_b, bkv_b, bkvc_b) = forward, backward
+    bq_dq, bkv_dq = (None, None) if fused else dq or (bq_b, bkv_b)
     sizes = sk.BlockSizes(
         block_q=bq, block_kv=bkv, block_kv_compute=bkvc,
         block_q_dkv=bq_b, block_kv_dkv=bkv_b, block_kv_dkv_compute=bkvc_b,
-        block_q_dq=None if fused else bq_b, block_kv_dq=None if fused else bkv_b,
+        block_q_dq=bq_dq, block_kv_dq=bkv_dq,
         use_fused_bwd_kernel=fused,
     )
-    mask = sm.MultiHeadMask([sm.CausalMask((s, s))] * heads)
-    return sk.make_splash_mha_single_device(mask=mask, block_sizes=sizes)
+    one = (sm.CausalMask((s, s)) if window is None
+           else sm.LocalMask((s, s), (window - 1, 0), offset=0))
+    return sk.make_splash_mha_single_device(
+        mask=sm.MultiHeadMask([one] * heads), block_sizes=sizes)
 
 
 def splash() -> None:
@@ -223,6 +247,113 @@ def splash() -> None:
         if backward == best or forward == backward:
             timed("splash_forward", settings, fwd, q, k, v)
         timed("splash_forward_backward", settings, jax.jit(both), q, k, v, do)
+
+
+# batch, query heads, key/value heads, S, head size, window
+KEXAONE = (1, 64, 8, 16384, 128, 128)       # k-exaone-236b-a23b-train-zipf16k
+SMALLTHINKER = (1, 28, 4, 16384, 128, 4096)  # smallthinker-21b-a3b-train-zipf16k
+BASELINE = (1024, 1024, 512)  # trunk._FLASH_TILES: PR 28's sweep, causal
+
+
+def _window_reading(cell, window, forward, backward, fused, dq, args,
+                    forward_too=True, **also):
+    """Rows ``window_forward`` (where ``forward_too``) and
+    ``window_forward_backward`` of one setting at ``cell``'s shape; their
+    ms in that order, None where the kernel or Mosaic refused."""
+    import jax
+
+    b, h, hkv, s, hd, _ = cell
+    dq = None if fused else dq or backward[:2]
+    settings = dict(
+        shape=[b, h, hkv, s, hd], window=window, forward=forward,
+        backward=backward, dq=dq, fused_bwd=fused, **also)
+    t0 = time.perf_counter()
+    try:
+        kernel = _splash(forward, backward, fused, h, s, window, dq)
+    except Exception as e:
+        row(what="window_forward_backward", **settings,
+            refused=f"{type(e).__name__}: {e}"[:300])
+        return None
+    settings["mask_tables_s"] = round(time.perf_counter() - t0, 2)
+    scale = 1.0 / hd ** 0.5
+    fwd = jax.jit(lambda q, k, v: jax.vmap(kernel)(q * scale, k, v))
+
+    def both(q, k, v, do):
+        out, vjp = jax.vjp(fwd, q, k, v)
+        return out, vjp(do)
+
+    read = [timed("window_forward", settings, fwd, *args[:3])] if forward_too else []
+    read.append(timed("window_forward_backward", settings, jax.jit(both), *args))
+    return None if None in read else tuple(read)
+
+
+def _grouped_qkv(cell):
+    """q and the output's cotangent [B, H, S, hd], k and v [B, Hkv, S, hd]."""
+    b, h, hkv, s, hd, _ = cell
+    q, do, _, _ = qkv((b, h, s, hd))
+    k, v, _, _ = qkv((b, hkv, s, hd))
+    return q, k, v, do
+
+
+def window(which: str = "all") -> None:
+    """``which``: ``all``; ``kexaone`` (``sweep``, ``beyond`` and ``rule``,
+    or one of the three alone); ``smallthinker``."""
+    from learning_at_home_tpu.models import trunk
+
+    require_tpu()
+    parts = {"all": ("sweep", "beyond", "rule", "smallthinker"),
+             "kexaone": ("sweep", "beyond", "rule")}.get(which, (which,))
+    if set(parts) & {"sweep", "beyond", "rule"}:
+        cell, w = KEXAONE, KEXAONE[-1]
+        args = _grouped_qkv(cell)
+
+        def read(forward, backward, fused, dq=None, **also):
+            return _window_reading(cell, w, forward, backward, fused, dq, args, **also)
+
+    if "sweep" in parts:
+        for fused in (True, False):  # what unfusing alone is worth
+            read(BASELINE, BASELINE, fused, stage="baseline")
+        grid = [(bq, bkv, c) for bq in (256, 512, 1024, 2048)
+                for bkv in (128, 256, 512) for c in (bkv, bkv // 2) if c >= 128]
+        same = {t: read(t, t, False, stage="same") for t in grid}
+        same = {t: r for t, r in same.items() if r}
+        best_fwd = min(same, key=lambda t: same[t][0])
+        best_bwd = min(same, key=lambda t: same[t][1] - same[t][0])
+        # each backward kernel's own tiles under the best of the others
+        dkv = {t: read(best_fwd, t, False, best_bwd[:2], forward_too=False,
+                       stage="dkv") for t in grid}
+        best_dkv = min((t for t in dkv if dkv[t]), key=lambda t: dkv[t][0])
+        dqs = {t: read(best_fwd, best_dkv, False, t, forward_too=False, stage="dq")
+               for t in sorted({t[:2] for t in grid})}
+        best_dq = min((t for t in dqs if dqs[t]), key=lambda t: dqs[t][0])
+        row(what="window_best", shape=list(cell[:5]), window=w, forward=best_fwd,
+            backward=best_dkv, dq=best_dq, forward_ms=round(same[best_fwd][0], 3),
+            forward_backward_ms=round(dqs[best_dq][0], 3))
+    if "beyond" in parts:
+        # past the sweep's edges: key blocks as wide as the baseline's under
+        # narrower query blocks, unfused; and the fused backward at the
+        # sweep's best blocks (32 partials of the queries' gradient, 8.6 GB)
+        for t in ((256, 1024, 512), (512, 1024, 512), (512, 1024, 1024)):
+            read(t, t, False, stage="beyond")
+        read((512, 512, 512), (512, 512, 512), True, stage="beyond")
+    if "rule" in parts:
+        # the rule's own answer, as attention_core would build it
+        if "sweep" not in parts:
+            read(BASELINE, BASELINE, True, stage="baseline")
+        b, h, _, s, hd, _ = cell
+        sizes = trunk.flash_block_sizes((b, s, h, hd), "tpu", w)
+        read((sizes.block_q, sizes.block_kv, sizes.block_kv_compute),
+             (sizes.block_q_dkv, sizes.block_kv_dkv, sizes.block_kv_dkv_compute),
+             sizes.use_fused_bwd_kernel,
+             None if sizes.use_fused_bwd_kernel else (sizes.block_q_dq, sizes.block_kv_dq),
+             stage="rule")
+    if "smallthinker" in parts:
+        cell = SMALLTHINKER
+        args = _grouped_qkv(cell)
+        for w in (cell[-1], None):  # the window layers' mask, the global layer's
+            for fused in (True, False):
+                _window_reading(cell, w, BASELINE, BASELINE, fused, None, args,
+                                stage="baseline")
 
 
 def _core_both(impl):
@@ -309,5 +440,5 @@ def accuracy() -> None:
 
 
 if __name__ == "__main__":
-    {"tiles": tiles, "splash": splash, "cores": cores,
-     "accuracy": accuracy}[sys.argv[1]]()
+    {"tiles": tiles, "splash": splash, "cores": cores, "accuracy": accuracy,
+     "window": window}[sys.argv[1]](*sys.argv[2:])

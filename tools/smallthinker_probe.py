@@ -10,8 +10,10 @@
 ``k_exaone_one_chip``) at published widths, compiled for a described v5e
 chip; prints the compiler's ``memory_analysis()`` against the chip's
 16,909,334,528 bytes, the tiles each of the step's grouped-matmul
-instructions was compiled at (PERF.md section 3), and how many products of
-the head's the compiled loss layer holds (three since PR 34).  Nothing runs.
+instructions was compiled at (PERF.md section 3), the blocked attention
+kernel's calls with the blocks and the grid each got, by layer kind, and
+how many products of the head's the compiled loss layer holds (three since
+PR 34).  Nothing runs.
 
 ``float8`` (on the chip): the benchmark runner's own comparison of a
 configuration (``benchmarks/configs/smallthinker-21b-a3b.json``, or the
@@ -57,14 +59,62 @@ def no_compile_cache():
         compilation_cache.reset_cache()
 
 
+def _kernel_calls(jaxpr, path: str = ""):
+    """(scope path, equation) of every ``pallas_call`` under ``jaxpr``; an
+    inner equation's name stack is relative to the equation that holds it."""
+    for eqn in jaxpr.eqns:
+        here = f"{path}/{eqn.source_info.name_stack}"
+        if eqn.primitive.name == "pallas_call":
+            yield here, eqn
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (list, tuple)) else [param]:
+                sub = getattr(sub, "jaxpr", sub)  # closed or open
+                if hasattr(sub, "eqns"):
+                    yield from _kernel_calls(sub, here)
+
+
+def attention_kernel_tilings(jaxpr) -> dict:
+    """By layer kind (the scope ``attention/<kind>``, or ``attention``) and
+    kernel name: how many calls the traced step makes, the query and key
+    blocks each got (what ``trunk.flash_block_sizes`` answered for its
+    mask), the grid it walks (the key-block axis already shrunk to the
+    mask where the kernel can) and its largest result in bytes (the fused
+    backward's is the queries' gradient once a key block)."""
+    import numpy as np
+
+    tilings = {}
+    for path, eqn in _kernel_calls(jaxpr):
+        kind = re.search(r"attention(?:/(global|window))?[/)]", path)
+        if not kind or not eqn.params["name"].startswith("splash_mha"):
+            continue
+        mapping = eqn.params["grid_mapping"]
+        # q comes first, [heads, block_q, hd]; k second, [heads, block_kv, hd]
+        (_, bq, _), (_, bkv, _) = (
+            m.block_shape for m in mapping.block_mappings[:2])
+        entry = tilings.setdefault(kind.group(1) or "attention", {}).setdefault(
+            eqn.params["name"], {
+                "calls": 0, "block_q": bq.block_size, "block_kv": bkv.block_size,
+                "grid": list(mapping.grid),
+                "largest_result_bytes": max(
+                    int(np.prod(a.shape)) * a.dtype.itemsize
+                    for a in eqn.params["out_avals"]),
+            })
+        entry["calls"] += 1
+    return tilings
+
+
 def step_memory(chip, recipe: str = "smallthinker_one_chip") -> dict:
     """The compiler's memory analysis of the whole train step of
     ``__graft_entry__.<recipe>`` compiled for ``chip``, a described v5e
     device (the caller makes ``jax.default_backend()`` answer ``tpu``, as
     on the chip), under ``grouped_matmul_tilings`` how many of its
-    grouped-matmul instructions run at which ``tm,tk,tn``, and under
-    ``loss_layer_products`` how many of its fusions under scope ``ce`` are
-    matmuls (the logits' einsum and its transposes: the head's products)."""
+    grouped-matmul instructions run at which ``tm,tk,tn``, under
+    ``attention_kernel_tilings`` what :func:`attention_kernel_tilings`
+    reads off the traced step and under ``attention_kernel_calls`` how
+    many instructions of each of the kernel's names the compiled step
+    holds, and under ``loss_layer_products`` how many of its fusions under
+    scope ``ce`` are matmuls (the logits' einsum and its transposes: the
+    head's products)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -95,7 +145,8 @@ def step_memory(chip, recipe: str = "smallthinker_one_chip") -> dict:
         (batch, cfg.seq_len), jnp.int32, sharding=batch_sharding(mesh))
     t0 = time.perf_counter()
     with no_compile_cache():
-        compiled = model.make_train_step(optimizer).lower(p, o, ids, ids).compile()
+        traced = model.make_train_step(optimizer).trace(p, o, ids, ids)
+        compiled = traced.lower().compile()
     m = compiled.memory_analysis()
     text = compiled.as_text()
     # params and optimizer state are donated: outputs alias the arguments
@@ -114,6 +165,9 @@ def step_memory(chip, recipe: str = "smallthinker_one_chip") -> dict:
         "grouped_matmul_tilings": dict(collections.Counter(re.findall(
             r'^\s*%ragged-dot-none[.\d]* = [^\n]*ragged_dot_tiling="([\d,]+)"',
             text, re.M))),
+        "attention_kernel_tilings": attention_kernel_tilings(traced.jaxpr.jaxpr),
+        "attention_kernel_calls": dict(collections.Counter(re.findall(
+            r"^\s*%(splash_mha\w*?)(?:\.\d+)? = [^\n]*custom-call\(", text, re.M))),
         "loss_layer_products": len(re.findall(
             r'^\s*%\S+ = [^\n]* fusion\([^\n]*'
             r'op_name="[^"\n]*[/(]ce[/)][^"\n]*dot_general"', text, re.M)),
