@@ -31,6 +31,7 @@ from learning_at_home_tpu.models.trunk import (
     attention_core,
     flash_block_sizes,
     gated_mlp,
+    latent_qkv_projections,
     layer_norm,
     one_query_attention,
     output_projection,
@@ -126,7 +127,14 @@ class DMoETransformerConfig:
     # each head / a dense first layer of width 18,432 / then a shared
     # expert beside gated_silu experts of width 2048, 8 of 128 by sigmoid
     # scores with a selection bias, weights renormalised times 2.5 /
-    # dropless, a share of the experts held (k_exaone_one_chip).
+    # dropless, a share of the experts held (k_exaone_one_chip);
+    # GLM-4.7-Flash is rmsnorm / rope (theta 1e6) / 20 heads of 256 whose
+    # queries, keys and values are expanded from latents of 768 and 512,
+    # the last 64 of a head rotated / a dense first layer of width 10,240 /
+    # then a shared expert beside gated_silu experts of width 1536, 4 of 64
+    # by sigmoid scores with a selection bias, renormalised times 1.8 /
+    # dropless, a share held / one block that predicts the next-but-one
+    # token with a loss of its own (glm_4_7_flash_one_chip).
     # 'layernorm' (scale and bias) or 'rmsnorm' (scale only)
     norm: str = "layernorm"
     norm_eps: float = 1e-5
@@ -140,6 +148,15 @@ class DMoETransformerConfig:
     n_kv_heads: int | None = None
     # a head's size; None = d_model // n_heads
     head_dim: int | None = None
+    # latent attention (trunk.latent_qkv_projections): the keys and values
+    # of every head are expanded from one normalized latent of
+    # kv_latent_dim a token, the queries from one of q_latent_dim; of a
+    # head's head_dim the last rope_head_dim are rotated, and the keys'
+    # rotated part is one a token, shared by the heads.  None = the plain
+    # projections
+    kv_latent_dim: int | None = None
+    q_latent_dim: int | None = None
+    rope_head_dim: int | None = None
     # where the layers of the stack differ: one AttentionLayer a layer,
     # or one period of them, repeated; None = every layer global, rotated
     # where positions == 'rope'
@@ -187,6 +204,14 @@ class DMoETransformerConfig:
     # experts no chip holds whole (None = all of them)
     held_experts: int | None = None
     first_held_expert: int = 0
+    # blocks after the stack that predict the next-but-one token (0 or 1):
+    # z = [rms(embed[t_{i+1}]) ; rms(h_i)] W_eh on the stack's final
+    # normalized stream h, one more layer of the model's own kind (a
+    # mixture layer with its own parameters), a norm, and the model's own
+    # head; its mean CE against t_{i+2} joins the loss times
+    # mtp_loss_weight
+    mtp_layers: int = 0
+    mtp_loss_weight: float = 0.0
 
     def attention_layer(self, i: int) -> AttentionLayer:
         """Layer ``i``'s attention."""
@@ -196,7 +221,8 @@ class DMoETransformerConfig:
 
 
 # The shortest sequence at which the blocked kernel, at its tuned tiles,
-# beats the xla core on a TPU v5e for heads of 64 and of 128 alike
+# beats the xla core on a TPU v5e for heads of 64 and of 128 alike (heads
+# of 256 were measured at 16,384 alone, where xla's scores do not fit)
 # (forward + backward of the core alone, bf16, 8,192 tokens a call, ms
 # xla / kernel; tools/attention_probe.py cores; PERF.md section 6 "PR 28"):
 #   S       heads of 64 (8)    heads of 128 (16)
@@ -311,6 +337,39 @@ class DMoETransformerLM:
                 "traced body holds them (scan_layers=False, "
                 "stack_layers=False)"
             )
+        latent = (config.kv_latent_dim, config.q_latent_dim, config.rope_head_dim)
+        if any(size is not None for size in latent):
+            if None in latent or config.head_dim is None:
+                raise ValueError(
+                    "latent attention is kv_latent_dim, q_latent_dim, "
+                    "rope_head_dim and head_dim together, got "
+                    f"{latent} and head_dim={config.head_dim}"
+                )
+            if config.n_kv_heads not in (None, config.n_heads) or config.qk_norm:
+                raise ValueError(
+                    "latent attention expands keys and values for every "
+                    "query head and norms its latents: no n_kv_heads, no "
+                    "qk_norm"
+                )
+            if not 0 < config.rope_head_dim < config.head_dim or (
+                config.rope_head_dim % 2
+            ):
+                raise ValueError(
+                    f"rope_head_dim={config.rope_head_dim} is the rotated, "
+                    f"even part of head_dim={config.head_dim}"
+                )
+        if config.mtp_layers not in (0, 1):
+            raise ValueError(
+                f"mtp_layers must be 0 or 1, got {config.mtp_layers}: one "
+                "block that predicts the next-but-one token is described"
+            )
+        if config.mtp_layers and (config.scan_layers or config.stack_layers):
+            raise ValueError(
+                "mtp_layers: the next-but-one-token block is one more "
+                "layer with parameters of its own after the stack, which "
+                "neither one stacked tree nor ONE traced body holds "
+                "(scan_layers=False, stack_layers=False)"
+            )
         n_kv = config.n_kv_heads or config.n_heads
         if config.n_heads % n_kv:
             raise ValueError(
@@ -327,6 +386,16 @@ class DMoETransformerLM:
             "dense" in ffns or config.shared_experts
             or config.held_experts not in (None, config.num_experts)
         )
+        if config.seq_parallel and (
+            config.kv_latent_dim is not None or config.mtp_layers
+        ):
+            raise NotImplementedError(
+                "seq_parallel=True (ring attention, parallel/"
+                "ring_attention.py) has no latent attention (its ring "
+                "rotates keys and values, not their latent) and no "
+                "next-but-one-token block (mtp_layers: the next ids' "
+                "embeddings are not laid out over the ring)"
+            )
         if config.seq_parallel and self._grouped_or_windowed:
             raise NotImplementedError(
                 "seq_parallel=True (ring attention, parallel/"
@@ -423,11 +492,30 @@ class DMoETransformerLM:
 
         def init_layer(key, ffn="moe"):
             ks = jax.random.split(key, 5)
+            if cfg.kv_latent_dim is None:
+                attention = {
+                    "wq": dense(ks[0], (d, d_q), pdt),
+                    "wk": dense(ks[1], (d, d_kv), pdt),
+                    "wv": dense(ks[2], (d, d_kv), pdt),
+                }
+            else:
+                # the latents' down-projections and norms, their
+                # expansions: a head's columns of wkv_b are [k_nope | v],
+                # its values as wide as its keys
+                c_q, c_kv = cfg.q_latent_dim, cfg.kv_latent_dim
+                rope, nope = cfg.rope_head_dim, hd - cfg.rope_head_dim
+                attention = {
+                    "wq_a": dense(ks[0], (d, c_q), pdt),
+                    "q_a_norm": {"scale": jnp.ones((c_q,), pdt)},
+                    "wq_b": dense(jax.random.fold_in(ks[0], 1), (c_q, d_q), pdt),
+                    "wkv_a": dense(ks[1], (d, c_kv + rope), pdt),
+                    "kv_a_norm": {"scale": jnp.ones((c_kv,), pdt)},
+                    "wkv_b": dense(
+                        ks[2], (c_kv, cfg.n_heads * (nope + hd)), pdt),
+                }
             lp = {
                 "ln1": ln(),
-                "wq": dense(ks[0], (d, d_q), pdt),
-                "wk": dense(ks[1], (d, d_kv), pdt),
-                "wv": dense(ks[2], (d, d_kv), pdt),
+                **attention,
                 "wo": dense(ks[3], (d_q, d), pdt),
                 "ln2": ln(),
             }
@@ -465,6 +553,14 @@ class DMoETransformerLM:
         }
         if not cfg.tie_embeddings:
             params["lm_head"] = dense(k_head, (d, v), pdt)
+        if cfg.mtp_layers:
+            k_mtp = jax.random.fold_in(k_layers, cfg.n_layers)
+            params["mtp"] = {
+                "e_norm": ln(), "h_norm": ln(),
+                "w_eh": dense(jax.random.fold_in(k_mtp, 1), (2 * d, d), pdt),
+                "layer": init_layer(k_mtp),
+                "out_norm": ln(),
+            }
         return jax.device_put(params, self.param_shardings(params))
 
     def param_shardings(self, params_shape: Params) -> Params:
@@ -494,7 +590,10 @@ class DMoETransformerLM:
         """Finished q, k, v of a layer whose tokens sit at ``positions``
         [S] (read only where the layer is ``rotary``): what every
         attention core (xla, flash, ring, one-query) takes."""
-        return qkv_projections(
+        project = (
+            latent_qkv_projections if "wkv_a" in lp else qkv_projections
+        )
+        return project(
             lp, x, self.cfg.n_heads,
             positions=jnp.asarray(positions, jnp.int32) if rotary else None,
             rope_theta=self.cfg.rope_theta, norm_eps=self.cfg.norm_eps,
@@ -564,8 +663,17 @@ class DMoETransformerLM:
     def _hidden(
         self, params: Params, token_ids: jax.Array,
         token_mask: jax.Array | None = None,
-    ) -> tuple[jax.Array, dict]:
+        next_ids: jax.Array | None = None,
+    ) -> tuple:
         """token_ids [B, S] → final-LN hidden states [B, S, d]; aux scalars.
+
+        With ``next_ids`` [B, S] (each position's next token: a training
+        row's targets; only where the config has the next-but-one-token
+        block) a second stream comes between the two: ``(x, x_mtp, aux)``,
+        ``x_mtp`` [B, S, d] the block's normalized output (:meth:`_mtp`),
+        for the same head; ``aux`` then takes in the block's router
+        (the means over one more mixture layer, ``expert_counts`` one more
+        row, the last).
 
         ``token_mask`` [B, S] bool (optional, traced): False marks padding
         positions that must not participate in MoE routing (they claim no
@@ -621,6 +729,19 @@ class DMoETransformerLM:
             # leaves of the unstacked tuple (no slice-out copies)
             aux_total = None
             counts = []
+
+            def add(aux):
+                """A mixture layer's aux into the stack's sums; its
+                assignments per expert stay a row of their own."""
+                nonlocal aux_total
+                if "expert_counts" in aux:
+                    counts.append(aux.pop("expert_counts"))
+                aux_total = (
+                    aux
+                    if aux_total is None
+                    else {k: aux_total[k] + aux[k] for k in aux_total}
+                )
+
             for i in range(cfg.n_layers):
                 lp = (
                     jax.tree_util.tree_map(lambda l: l[i], params["layers"])
@@ -631,25 +752,55 @@ class DMoETransformerLM:
                     x, aux = layer_fn(
                         lp, x, i, token_mask, cfg.attention_layer(i)
                     )
-                if aux is None:  # a dense layer routes nothing
-                    continue
-                if "expert_counts" in aux:
-                    counts.append(aux.pop("expert_counts"))
-                aux_total = (
-                    aux
-                    if aux_total is None
-                    else {k: aux_total[k] + aux[k] for k in aux_total}
-                )
+                if aux is not None:  # a dense layer routes nothing
+                    add(aux)
         if self._zig is not None:
             x = x[:, self._zig_inv]
         x = self._norm(params["ln_f"], x)
         n_moe = (cfg.ffn_pattern or ("moe",) * cfg.n_layers).count("moe")
+        if next_ids is not None:
+            with jax.named_scope("mtp"):
+                x_mtp, aux = self._mtp(
+                    params["mtp"], x, next_ids, params["embed"], layer_fn,
+                    token_mask,
+                )
+            add(aux)
+            n_moe += 1
         aux_mean = {k: v / n_moe for k, v in aux_total.items()}
         if cfg.router_bias:  # [mixture layers, E]: the balancing rule's
             aux_mean["expert_counts"] = (
                 counts if cfg.scan_layers else jnp.stack(counts)
             )
+        if next_ids is not None:
+            return x, x_mtp, aux_mean
         return x, aux_mean
+
+    def _mtp_input(self, mp, h, next_ids, embed):
+        """``[rms(embed[next]) ; rms(h)] W_eh``: what the block's layer
+        reads, the embedding's half first."""
+        with jax.named_scope("combine"):
+            e = embed[next_ids].astype(self.cfg.dtype)
+            return jnp.concatenate(
+                [self._norm(mp["e_norm"], e), self._norm(mp["h_norm"], h)],
+                axis=-1,
+            ) @ mp["w_eh"].astype(self.cfg.dtype)
+
+    def _mtp(self, mp, h, next_ids, embed, layer_fn, token_mask=None):
+        """The next-but-one-token block on the stack's final normalized
+        stream ``h`` [B, S, d] and each position's next token: ``z =
+        [rms(embed[next]) ; rms(h)] W_eh`` (scope ``combine``), one more
+        layer of the model's own kind (``layer_0``: ``layer_fn``, the
+        stack's, under remat where it is), the block's own final norm.
+        Returns the normalized stream for the model's head and the layer's
+        ``aux``."""
+        cfg = self.cfg
+        z = self._mtp_input(mp, h, next_ids, embed)
+        with jax.named_scope("layer_0"):
+            z, aux = layer_fn(
+                mp["layer"], z, cfg.n_layers, token_mask,
+                cfg.attention_layer(cfg.n_layers),
+            )
+        return self._norm(mp["out_norm"], z), aux
 
     def _head(self, params: Params) -> jax.Array:
         # compute dtype (bf16 on TPU), NOT f32: the MXU runs bf16 operands
@@ -757,6 +908,15 @@ class DMoETransformerLM:
             # buffer and fail at trace time on .at[:, 0]
             return prompt_ids
         if use_cache:
+            if self.cfg.kv_latent_dim is not None or self.cfg.mtp_layers:
+                raise NotImplementedError(
+                    "use_cache=True: the KV-cache decoder keeps whole keys "
+                    "and values a head and samples from the stack's head "
+                    "alone: no latent attention (a cache of the latent and "
+                    "the absorbed one-query form are not built) and no "
+                    "next-but-one-token block (mtp_layers); decode without "
+                    "the cache"
+                )
             if (
                 self._grouped_or_windowed or self._other_ffn
                 or self.cfg.router_input != "moe_input"
@@ -985,17 +1145,37 @@ class DMoETransformerLM:
     ) -> tuple[jax.Array, dict]:
         """Training loss: mean next-token CE (:meth:`_chunked_ce`)
         plus the weighted router aux and z losses."""
-        x, aux = self._hidden(params, token_ids)
+        if self.cfg.mtp_layers:
+            x, x_mtp, aux = self._hidden(params, token_ids, next_ids=targets)
+        else:
+            x, aux = self._hidden(params, token_ids)
         with jax.named_scope("ce"):
-            ce = self._chunked_ce(x, self._head(params), targets)
+            head = self._head(params)
+            ce = self._chunked_ce(x, head, targets)
         loss = (
             ce
             + self.cfg.aux_loss_weight * aux["aux_loss"]
             + self.cfg.router_z_weight * aux["router_z_loss"]
         )
-        return loss, {"ce": ce, **aux}
+        metrics = {"ce": ce, **aux}
+        if self.cfg.mtp_layers:
+            # position i of the block's stream against t_{i+2}, the row's
+            # targets shifted by one; the last position has no target (-1:
+            # masked), so the mean is over B (S - 1) and the shapes stay
+            with jax.named_scope("mtp"), jax.named_scope("ce"):
+                b, s = targets.shape
+                after_next = jnp.concatenate(
+                    [targets[:, 1:], jnp.full((b, 1), -1, targets.dtype)],
+                    axis=1,
+                )
+                ce_mtp = self._chunked_ce(
+                    x_mtp, head, after_next, b * (s - 1), masked=True
+                )
+            loss = loss + self.cfg.mtp_loss_weight * ce_mtp
+            metrics["ce_mtp"] = ce_mtp
+        return loss, metrics
 
-    def _chunked_ce(self, x, head, targets):
+    def _chunked_ce(self, x, head, targets, denominator=None, masked=False):
         """Chunked cross-entropy: the [tokens, V] f32 logits are never
         materialized at once.  Token chunks of ``ce_chunk`` go through the
         head + softmax-CE inside a ``lax.scan``, so peak logits memory is
@@ -1006,7 +1186,9 @@ class DMoETransformerLM:
         head is multiplied by three times a step, not four.  At the
         256-expert flagship shape the chunking is what lifts the per-chip
         batch from 16 to 64 — the f32 logits (+ cotangents) were the
-        dominant activation term.
+        dominant activation term.  ``denominator``: what the summed CEs
+        are divided by (None = every token, ``B * S``); ``masked``: a
+        target of -1 marks a position that has none and adds nothing.
 
         Which path runs is read off the mesh and the shapes:
 
@@ -1028,16 +1210,20 @@ class DMoETransformerLM:
 
         mesh = self.mesh
         b, s = targets.shape
+        if denominator is None:
+            denominator = b * s
         b_shards = int(np.prod([mesh.shape[a] for a in data_axes(mesh)]))
         s_shards = mesh.shape.get("seq", 1)
         if mesh.devices.size == 1 or b % b_shards or s % s_shards:
-            return self._chunked_ce_sum(x, head, targets, b * s)
+            return self._chunked_ce_sum(x, head, targets, denominator, masked)
 
         from jax import shard_map
 
         spec = batch_sharding(mesh).spec  # P(batch axes[, "seq"])
         ce_sums = shard_map(  # one sum a shard, laid out like the shards
-            lambda xl, hl, tl: self._chunked_ce_sum(xl, hl, tl, b * s).reshape(
+            lambda xl, hl, tl: self._chunked_ce_sum(
+                xl, hl, tl, denominator, masked
+            ).reshape(
                 (1,) * len(spec)
             ),
             mesh=mesh,
@@ -1049,14 +1235,14 @@ class DMoETransformerLM:
         )(x, head, targets)
         return ce_sums.sum()
 
-    def _chunked_ce_sum(self, x, head, targets, denominator):
+    def _chunked_ce_sum(self, x, head, targets, denominator, masked=False):
         """Sum (f32) of the token CEs of ``x`` [b, s, d] over
         ``denominator`` (the GLOBAL token count, also where ``x`` is one
         shard's rows), ``ce_chunk`` tokens at a time: :func:`_ce_of_chunks`."""
         n = x.shape[0] * x.shape[1]
         return _ce_of_chunks(
             x.reshape(n, x.shape[-1]), head, targets.reshape(n),
-            min(self.cfg.ce_chunk, n), denominator,
+            min(self.cfg.ce_chunk, n), denominator, masked,
         )
 
     # ---- the routers' selection biases ----
@@ -1069,7 +1255,16 @@ class DMoETransformerLM:
             return None
         if self.cfg.stack_layers:
             return [params["layers"]["moe"]["router_bias"]]
-        return [lp["moe"]["router_bias"] for lp in params["layers"] if "moe" in lp]
+        return [lp["moe"]["router_bias"]
+                for lp in self._routed_layers(params) if "moe" in lp]
+
+    @staticmethod
+    def _routed_layers(params: Params) -> list:
+        """The per-layer trees in the order of ``expert_counts``' rows: the
+        stack's, then the next-but-one-token block's layer."""
+        return list(params["layers"]) + (
+            [params["mtp"]["layer"]] if "mtp" in params else []
+        )
 
     def _balance(self, params: Params, biases: list | None, counts) -> Params:
         """``params`` with the selection biases as they are after a step:
@@ -1094,9 +1289,12 @@ class DMoETransformerLM:
             return {**lp, "moe": {**lp["moe"], "router_bias": next(moved)}}
 
         layers = params["layers"]
-        return {**params, "layers": (
+        params = {**params, "layers": (
             put(layers) if self.cfg.stack_layers else tuple(map(put, layers))
         )}
+        if "mtp" in params:
+            params["mtp"] = {**params["mtp"], "layer": put(params["mtp"]["layer"])}
+        return params
 
     def level_router_bias(self, params: Params, token_batches: list):
         """``params`` with every mixture layer's selection bias levelled on
@@ -1138,7 +1336,32 @@ class DMoETransformerLM:
                 lp = layers[i] = {**lp, "moe": {**lp["moe"], "router_bias": bias}}
                 loads.append(load)
             streams = [finish(lp, x, None, i)[0] for x in streams]
-        return {**params, "layers": tuple(layers)}, loads
+        params = {**params, "layers": tuple(layers)}
+        if "mtp" in params:
+            # the block's router too, on what the block's attention leaves
+            # of the levelled stack's final stream and each position's next
+            # id: the row shifted by one (the last position keeps its own)
+            mp = params["mtp"]
+            combine = jax.jit(lambda mp, ln_f, table, x, ids: self._mtp_input(
+                mp, self._norm(ln_f, x),
+                jnp.concatenate([ids[:, 1:], ids[:, -1:]], axis=1), table))
+            streams = [
+                attend(
+                    mp["layer"],
+                    combine(mp, params["ln_f"], params["embed"], x, ids),
+                    cfg.attention_layer(cfg.n_layers),
+                )[0]
+                for x, ids in zip(streams, token_batches)
+            ]
+            lp = mp["layer"]
+            bias, load = level_bias(
+                jnp.concatenate([scores(lp, x) for x in streams]),
+                lp["moe"]["router_bias"], cfg.k,
+            )
+            loads.append(load)
+            params["mtp"] = {**mp, "layer": {
+                **lp, "moe": {**lp["moe"], "router_bias": bias}}}
+        return params, loads
 
     def init_opt_state(
         self, optimizer: optax.GradientTransformation, params: Params
@@ -1249,14 +1472,20 @@ class DMoETransformerLM:
 # ---- the loss layer: the token CEs a chunk at a time ----
 
 
-def _softmax_ce(logits: jax.Array, targets: jax.Array):
+def _softmax_ce(logits: jax.Array, targets: jax.Array, masked: bool = False):
     """Summed CE (f32) of a chunk's rows from its float32 ``logits``
     [c, V], with the two pieces the gradient is made of: the exponentials
     ``e`` [c, V] (of the logits less the row's largest) and their row sums
-    ``s`` [c, 1].  ``softmax = e / s``."""
+    ``s`` [c, 1].  ``softmax = e / s``.  ``masked``: a row whose target is
+    negative has none and adds nothing to the sum."""
     top = logits.max(axis=-1, keepdims=True)
     e = jnp.exp(logits - top)
     s = e.sum(axis=-1, keepdims=True)
+    if masked:
+        label = jnp.take_along_axis(
+            logits, jnp.maximum(targets, 0)[:, None], axis=-1)
+        ces = jnp.where(targets[:, None] >= 0, jnp.log(s) + top - label, 0.0)
+        return ces.sum(), e, s
     label = jnp.take_along_axis(logits, targets[:, None], axis=-1)
     return (jnp.log(s) + top - label).sum(), e, s
 
@@ -1293,8 +1522,9 @@ def _over_chunks(step, carry, flat_x, flat_t, chunk: int):
     return carry, outs
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _ce_of_chunks(flat_x, head, flat_t, chunk: int, denominator: int):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _ce_of_chunks(flat_x, head, flat_t, chunk: int, denominator: int,
+                  masked: bool = False):
     """Sum (f32) over ``denominator`` of the CEs of the rows ``flat_x``
     [n, d] against ``head`` [d, V] and the targets ``flat_t`` [n]: one
     head product and one softmax a chunk, no [n, V] array at any time.
@@ -1304,18 +1534,20 @@ def _ce_of_chunks(flat_x, head, flat_t, chunk: int, denominator: int):
     loss is a scalar, so they need nothing a backward pass would bring,
     and the head is multiplied by three times a step (logits, and the
     two gradient products) where recomputing each chunk's logits in the
-    backward made it four."""
+    backward made it four.  ``masked``: rows whose target is negative have
+    none; they add nothing to the sum and get no gradient."""
 
     def step(ce_sum, rows_targets):
         rows, targets = rows_targets
-        ce, _, _ = _softmax_ce(DMoETransformerLM._logits(rows, head), targets)
+        ce, _, _ = _softmax_ce(
+            DMoETransformerLM._logits(rows, head), targets, masked)
         return ce_sum + ce, None
 
     ce_sum, _ = _over_chunks(step, jnp.float32(0), flat_x, flat_t, chunk)
     return ce_sum / denominator
 
 
-def _ce_of_chunks_fwd(flat_x, head, flat_t, chunk, denominator):
+def _ce_of_chunks_fwd(flat_x, head, flat_t, chunk, denominator, masked=False):
     """The value, and as residuals its gradients with respect to
     ``flat_x`` (a chunk's rows from each step, stacked by the scan) and
     ``head`` (a carry of the scan, accumulated in the head's dtype as the
@@ -1337,11 +1569,15 @@ def _ce_of_chunks_fwd(flat_x, head, flat_t, chunk, denominator):
         logits, gradient_products = jax.vjp(
             DMoETransformerLM._logits, rows, head
         )
-        ce, e, s = _softmax_ce(logits, targets)
-        softmax = e * (scale / s)
+        ce, e, s = _softmax_ce(logits, targets, masked)
+        # a row's weight in the mean: 0 where it has no target
+        weight = (
+            jnp.where(targets[:, None] >= 0, scale, 0.0) if masked else scale
+        )
+        softmax = e * (weight / s)
         hit = jnp.arange(logits.shape[-1]) == targets[:, None]
         d_rows, d_head_chunk = gradient_products(
-            jnp.where(hit, softmax - scale, softmax)
+            jnp.where(hit, softmax - weight, softmax)
         )
         return (ce_sum + ce, d_head + d_head_chunk), d_rows
 
@@ -1352,7 +1588,7 @@ def _ce_of_chunks_fwd(flat_x, head, flat_t, chunk, denominator):
     return ce_sum / denominator, (d_x, d_head)
 
 
-def _ce_of_chunks_bwd(chunk, denominator, gradients, cotangent):
+def _ce_of_chunks_bwd(chunk, denominator, masked, gradients, cotangent):
     """The forward's gradients times the scalar cotangent, in float32
     (a train step's is 1, the mean's divisor being on them already: no
     bit changes); the integer targets have no gradient."""

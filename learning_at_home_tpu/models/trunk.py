@@ -89,6 +89,55 @@ def qkv_projections(
     return q, k, v
 
 
+def latent_qkv_projections(
+    lp: dict, x: jax.Array, n_heads: int,
+    positions: jax.Array | None = None,
+    rope_theta: float = 10000.0, norm_eps: float = 1e-5,
+):
+    """Q/K/V expanded from low-rank latents (multi-head latent attention,
+    the form training and prefill run): [B,S,d] → q, k, v [B,S,H,hd],
+    finished for any attention core.  ``c_q = rms(x Wqa)`` is expanded by
+    ``Wqb`` to the heads' queries; ``x Wkva`` is the keys' and values'
+    latent ``c_kv`` (normalized) beside ONE rotated key part a token,
+    ``k_r``, that every head shares; ``Wkvb`` expands ``c_kv`` to each
+    head's ``[k_nope | v]``.  A head's query and key are ``[nope | rope]``:
+    the last ``rope`` of the query and ``k_r`` are rotated (``positions``
+    [S]; None = no rotation), the rest carries no position.  The block's
+    own parameters say the sizes: the latent's width is ``kv_a_norm``'s
+    scale, the rotated part what ``wkv_a`` gives beyond it, a head's
+    query/key size ``wq_b``'s width over ``n_heads``, its value size what
+    ``wkv_b`` gives a head beyond the unrotated key part.  No biases."""
+    b, s, _ = x.shape
+    kv_rank = lp["kv_a_norm"]["scale"].shape[-1]
+    rope_dim = lp["wkv_a"].shape[-1] - kv_rank
+    hd = lp["wq_b"].shape[-1] // n_heads
+    nope = hd - rope_dim
+    with jax.named_scope("latent_down"):
+        c_q = rms_norm(lp["q_a_norm"], x @ lp["wq_a"].astype(x.dtype), norm_eps)
+        kv = x @ lp["wkv_a"].astype(x.dtype)
+        c_kv = rms_norm(lp["kv_a_norm"], kv[..., :kv_rank], norm_eps)
+        k_rope = kv[..., kv_rank:].reshape(b, s, 1, rope_dim)
+    with jax.named_scope("latent_up"):
+        q = (c_q @ lp["wq_b"].astype(x.dtype)).reshape(b, s, n_heads, hd)
+        # the expansion's columns a head are [k_nope | v]: split the
+        # matrix, not the [B,S,H,.] result
+        wkv_b = lp["wkv_b"].astype(x.dtype).reshape(kv_rank, n_heads, -1)
+        k_nope = jnp.einsum("bsc,chd->bshd", c_kv, wkv_b[..., :nope])
+        v = jnp.einsum("bsc,chd->bshd", c_kv, wkv_b[..., nope:])
+    q_nope, q_rope = q[..., :nope], q[..., nope:]
+    if positions is not None:
+        with jax.named_scope("rope"):
+            q_rope = rotary(q_rope, positions, rope_theta)
+            k_rope = rotary(k_rope, positions, rope_theta)
+    with jax.named_scope("latent_up"):
+        q = jnp.concatenate([q_nope, q_rope], axis=-1)
+        k = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(k_rope, (b, s, n_heads, rope_dim))],
+            axis=-1,
+        )
+    return q, k, v
+
+
 def output_projection(lp: dict, out: jax.Array) -> jax.Array:
     """[B,S,H,hd] → [B,S,d] @ wo."""
     b, s, h, hd = out.shape
@@ -140,6 +189,17 @@ _FLASH_TILES = dict(
     block_q=1024, block_kv=1024, block_kv_compute=512,
     block_q_dkv=1024, block_kv_dkv=1024, block_kv_dkv_compute=512,
 )
+# Heads of 256 (queries, keys and values alike: latent attention expanded,
+# 20 heads): the same blocks, the forward's compute tile 256.  The fastest
+# of the sweep on a TPU v5e at [1, 20, 16384, 256] bf16 under a causal
+# mask (PERF.md section 6 "PR 37"; tools/attention_probe.py latent):
+# forward 17.76 ms (78.6 % of the bf16 peak on the admitted elements) and
+# forward + backward 64.87 ms, where the tiles above take 18.42 and 65.57;
+# the unfused backward's best is 75.9.  Wider blocks do not fit VMEM at
+# this head size (a 2048-wide query or key block, or a 1024-wide compute
+# tile in the fused backward, is refused), narrower ones visit more grid
+# steps: 512-wide key blocks 20.49 and 73.7.
+_FLASH_TILES_256 = dict(_FLASH_TILES, block_kv_compute=256)
 # Under a shorter window the backward is unfused (a dK/dV and a dQ kernel
 # over grids that shrink to the mask; the fused kernel's cannot, and it
 # writes S / block_kv partials of the queries' gradient, which XLA sums
@@ -176,16 +236,19 @@ def flash_block_sizes(shape: tuple, backend: str, window: int | None = None):
     from jax.experimental.pallas.ops.tpu.splash_attention import BlockSizes
 
     _, s, _, hd = shape
-    if backend != "tpu" or s % 128 or hd not in (64, 128):
+    if backend != "tpu" or s % 128 or hd not in (64, 128, 256):
         return None
-    tiles = {name: min(size, s) for name, size in _FLASH_TILES.items()}
+    measured = _FLASH_TILES_256 if hd == 256 else _FLASH_TILES
+    tiles = {name: min(size, s) for name, size in measured.items()}
     if any(s % size for size in tiles.values()):
         return None
     if window is None or window >= tiles["block_kv"]:
         return BlockSizes(use_fused_bwd_kernel=True, **tiles)
     # the narrowest key block that covers the window and the measured
     # tile (the block of the other regime divides s and covers both, so
-    # there is one); the widest query block within the tile
+    # there is one); the widest query block within the tile.  Measured at
+    # heads of 128; at 256 these blocks compile and run, untimed under a
+    # window
     tile = min(_FLASH_WINDOW_TILE, s)
     kv = _dividing_tiles(s, max(window, tile), tiles["block_kv"])[0]
     q = _dividing_tiles(s, 128, tile)[-1]

@@ -6,6 +6,7 @@
     python tools/attention_probe.py cores      # blocked against xla, by length
     python tools/attention_probe.py accuracy   # blocked against xla, results
     python tools/attention_probe.py window     # the blocked kernel's tiles under a window
+    python tools/attention_probe.py latent     # the blocked kernel's tiles at heads of 256
 
 One process a subcommand (a chip belongs to one process), one ``ROW``
 line of JSON a reading, written to ``chiprun_out/attention_probe.jsonl``
@@ -42,6 +43,13 @@ threshold were set from.
   and under the causal mask: the baseline against the unfused form at the
   same blocks.  PERF.md section 6 ("PR 36") holds the table the rule's
   short-window regime was set from.
+- ``latent``: ``splash_attention`` under a causal mask at GLM-4.7-Flash's
+  expanded latent attention, ``[1, 20, 16384, 256]`` bf16 (queries, keys
+  and values of 256 a head), forward and forward + backward: the blocks of
+  heads of 128 and their neighbours, fused backward and not, then the
+  kernel under ``flash_block_sizes``'s answer (``latent rule``).  PERF.md
+  section 6 ("PR 37") holds the table the rule's regime for heads of 256
+  was set from.
 - ``accuracy``: ``attention_core`` ``flash`` against ``xla`` at the
   cell's shape, output and the three input gradients: rms of the
   difference over rms of the ``xla`` result (limit 1 %), beside what bf16
@@ -356,6 +364,46 @@ def window(which: str = "all") -> None:
                                 stage="baseline")
 
 
+GLM47 = (1, 20, 20, 16384, 256, None)  # glm-4.7-flash-train-zipf16k
+
+
+def latent(which: str = "all") -> None:
+    """``which``: ``all``, ``sweep`` or ``rule``."""
+    from learning_at_home_tpu.models import trunk
+
+    require_tpu()
+    cell = GLM47
+    args = _grouped_qkv(cell)
+
+    def read(forward, backward, fused, **also):
+        return _window_reading(cell, None, forward, backward, fused, None, args,
+                               **also)
+
+    if which in ("all", "sweep"):
+        grid = ((1024, 1024, 512), (1024, 1024, 1024), (512, 1024, 512),
+                (512, 512, 512), (1024, 512, 512), (512, 2048, 512),
+                (1024, 2048, 512), (2048, 1024, 512), (2048, 512, 512),
+                (256, 1024, 512), (512, 1024, 256), (1024, 1024, 256))
+        same = {(t, fused): read(t, t, fused, stage="same")
+                for t in grid for fused in (True, False)}
+        same = {t: r for t, r in same.items() if r}
+        best_fwd = min(same, key=lambda t: same[t][0])[0]
+        # the backward's own tiles under the best forward's
+        for t in grid:
+            for fused in (True, False):
+                if t != best_fwd:
+                    read(best_fwd, t, fused, forward_too=False, stage="backward")
+    if which in ("all", "rule"):
+        b, h, _, s, hd, _ = cell
+        sizes = trunk.flash_block_sizes((b, s, h, hd), "tpu")
+        if sizes is None:
+            row(what="latent_rule", refused="flash_block_sizes has no tiles")
+            return
+        read((sizes.block_q, sizes.block_kv, sizes.block_kv_compute),
+             (sizes.block_q_dkv, sizes.block_kv_dkv, sizes.block_kv_dkv_compute),
+             sizes.use_fused_bwd_kernel, stage="rule")
+
+
 def _core_both(impl):
     """Forward + backward of ``attention_core`` on [B, S, H, hd]."""
     import jax
@@ -441,4 +489,4 @@ def accuracy() -> None:
 
 if __name__ == "__main__":
     {"tiles": tiles, "splash": splash, "cores": cores, "accuracy": accuracy,
-     "window": window}[sys.argv[1]](*sys.argv[2:])
+     "window": window, "latent": latent}[sys.argv[1]](*sys.argv[2:])

@@ -1,13 +1,14 @@
 #!/usr/bin/env python3
 """Two readings a one-chip recipe's configuration rests on
-(``smallthinker-21b-a3b`` by default; ``k-exaone-236b-a23b`` by name).
+(``smallthinker-21b-a3b`` by default; ``k-exaone-236b-a23b`` and
+``glm-4.7-flash`` by name).
 
     python tools/smallthinker_probe.py memory [recipe]
     chiprun -- python tools/smallthinker_probe.py float8 [config.json] [seed ...]
 
 ``memory`` (here, no chip): the whole train step of a recipe of
 ``__graft_entry__`` (``smallthinker_one_chip``, or the one named, such as
-``k_exaone_one_chip``) at published widths, compiled for a described v5e
+``k_exaone_one_chip`` or ``glm_4_7_flash_one_chip``) at published widths, compiled for a described v5e
 chip; prints the compiler's ``memory_analysis()`` against the chip's
 16,909,334,528 bytes, the tiles each of the step's grouped-matmul
 instructions was compiled at (PERF.md section 3), the blocked attention
