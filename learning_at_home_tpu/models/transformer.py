@@ -28,6 +28,7 @@ import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from learning_at_home_tpu.models.trunk import (
+    FLASH_RESIDUALS,
     attention_core,
     flash_block_sizes,
     gated_mlp,
@@ -690,8 +691,19 @@ class DMoETransformerLM:
                 ].astype(cfg.dtype)
         layer_fn = self._layer
         if cfg.remat:
-            # kind is static: a window or a rotation is part of the program
-            layer_fn = jax.checkpoint(layer_fn, static_argnums=(4,))
+            # kind is static: a window or a rotation is part of the program.
+            # Kept across the backward pass: the blocked attention
+            # kernel's output and row sums, which its backward kernels
+            # read, so the recompute holds no forward kernel call (a
+            # layer's 68-273 MB against 3-32 ms: PERF.md section 6, PR
+            # 38); a layer whose core is xla names nothing and is
+            # recomputed whole, as under no policy
+            layer_fn = jax.checkpoint(
+                layer_fn, static_argnums=(4,),
+                policy=jax.checkpoint_policies.save_only_these_names(
+                    FLASH_RESIDUALS
+                ),
+            )
 
         def body(x, lp_idx):
             lp, idx = lp_idx

@@ -216,6 +216,13 @@ _FLASH_TILES_256 = dict(_FLASH_TILES, block_kv_compute=256)
 # 128 is the reading up to 512.  Measured at windows of 128 and 4,096 and
 # S 16,384; nothing between.
 _FLASH_WINDOW_TILE = 512
+# The name the kernel's forward gives its output [H, S, hd] and its
+# float32 row sums (logsumexp [H, S]): the two arrays its backward kernels
+# read beside q, k and v.  A ``jax.checkpoint`` whose policy saves this
+# name (the layer's remat: ``DMoETransformerLM._hidden``) keeps them, so
+# the backward pass does not run the forward kernel a second time; outside
+# a checkpoint the name is the identity.
+FLASH_RESIDUALS = "flash_attention_residuals"
 
 
 def _dividing_tiles(s: int, at_least: int, at_most: int) -> list:
@@ -290,6 +297,7 @@ def attention_core(
         )
         kernel = splash.make_splash_mha_single_device(
             mask=splash.MultiHeadMask([mask] * h), block_sizes=sizes,
+            residual_checkpoint_name=FLASH_RESIDUALS,
         )
 
         # kernel convention: one batch row [H, S, hd] (k and v [Hkv, S,

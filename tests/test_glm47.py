@@ -711,12 +711,17 @@ def test_the_whole_step_fits_the_chip(v5e_chip, monkeypatch):
         "256,2048,768": 5 * 5, "256,1536,1024": 5 * 4,
         "256,1024,768": 5 * 2, "256,768,1024": 5}
     assert memory["loss_layer_products"] == 2 * 3
+    # one forward a kernel layer (12 before PR 38): remat keeps the
+    # kernel's output and row sums, 169 MB a layer, so the recompute holds
+    # no forward call; read off the compiled step, and below off the
+    # traced one, where the policy has already taken the call out
     assert memory["attention_kernel_calls"] == {
-        "splash_mha_fwd_residuals": 6 * 2,  # remat runs a forward twice
+        "splash_mha_fwd_residuals": 6,
         "splash_mha_dkv_no_residuals": 6}  # fused: no dQ kernel of its own
+    assert memory["kept_residual_bytes"] == 6 * 20 * 16384 * (256 * 2 + 4)
     calls = memory["attention_kernel_tilings"]["attention"]
     assert {name: (c["calls"], c["block_q"], c["block_kv"]) for name, c in calls.items()} == {
-        "splash_mha_fwd_residuals": (12, 1024, 1024),
+        "splash_mha_fwd_residuals": (6, 1024, 1024),
         "splash_mha_dkv_no_residuals": (6, 1024, 1024)}
     # the queries' gradient once a key block, [16, 20, 16384, 256] bf16
     assert calls["splash_mha_dkv_no_residuals"]["largest_result_bytes"] == (

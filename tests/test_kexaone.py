@@ -850,14 +850,20 @@ def test_the_whole_step_fits_the_chip(v5e_chip, monkeypatch):
     # the blocked kernel's tiles read the window (PR 36): the four window
     # layers' backward is a dK/dV and a dQ kernel (none of the latter in
     # the step before), the global layer's the fused one
+    # and one forward a kernel layer (10 before PR 38): remat keeps the
+    # kernel's output and row sums, 273 MB a layer, so the recompute holds
+    # no forward call.  ``attention_kernel_calls`` reads the compiled
+    # step's instructions, ``attention_kernel_tilings`` the traced step's
+    # equations: the policy takes the call out before the compiler sees it
     assert memory["attention_kernel_calls"] == {
-        "splash_mha_fwd_residuals": 5 * 2,  # remat runs a forward twice
+        "splash_mha_fwd_residuals": 5,
         "splash_mha_dkv_no_residuals": 5, "splash_mha_dq_no_residuals": 4}
+    assert memory["kept_residual_bytes"] == 5 * 64 * 16384 * (128 * 2 + 4)
     tilings = memory["attention_kernel_tilings"]
     assert {kind: {name: call["calls"] for name, call in calls.items()}
             for kind, calls in tilings.items()} == {
-        "global": {"splash_mha_fwd_residuals": 2, "splash_mha_dkv_no_residuals": 1},
-        "window": {"splash_mha_fwd_residuals": 8, "splash_mha_dkv_no_residuals": 4,
+        "global": {"splash_mha_fwd_residuals": 1, "splash_mha_dkv_no_residuals": 1},
+        "window": {"splash_mha_fwd_residuals": 4, "splash_mha_dkv_no_residuals": 4,
                    "splash_mha_dq_no_residuals": 4}}
     want = trunk.flash_block_sizes((1, 16384, 64, 128), "tpu", 128)
     assert [(call["block_q"], call["block_kv"]) for call in tilings["window"].values()] == [

@@ -44,6 +44,7 @@ reference = harness.load_path(
 runner = harness.load_path(
     os.path.join(REPO, "benchmarks", "runners", "train_recipe.py")
 )
+probe = harness.load_path(os.path.join(REPO, "tools", "smallthinker_probe.py"))
 
 
 def _one_device_mesh():
@@ -714,6 +715,132 @@ def test_blocked_attention_compiles_for_v5e_at_its_tiles(v5e_chip, monkeypatch, 
     with _no_compile_cache():
         text = jax.jit(both).lower(x, x, x, x).compile().as_text()
     assert text.count("tpu_custom_call") >= 2  # forward, the fused backward
+
+
+# ---- remat keeps the kernel's output and row sums (PR 38) ----
+
+
+def _the_parents_formula(monkeypatch):
+    """The layer's remat and the kernel's constructor as the parent commit
+    wrote them: ``jax.checkpoint`` under no policy, the kernel's forward
+    naming nothing."""
+    from jax.experimental.pallas.ops.tpu import splash_attention as splash
+
+    checkpoint, make = jax.checkpoint, splash.make_splash_mha_single_device
+    monkeypatch.setattr(
+        jax, "checkpoint", lambda fn, policy=None, **kw: checkpoint(fn, **kw))
+    monkeypatch.setattr(
+        splash, "make_splash_mha_single_device",
+        lambda residual_checkpoint_name=None, **kw: make(**kw))
+
+
+def _kernel_sized(mesh, **changes):
+    """The recipe's tiny block at the smallest shape the blocked kernel
+    takes: 512 positions, heads of 64, bf16."""
+    _, cfg, _, _ = olmoe_one_chip(mesh, tiny=True)
+    cfg = dataclasses.replace(
+        cfg, d_model=256, n_heads=4, seq_len=512,
+        dtype=jnp.bfloat16, param_dtype=jnp.bfloat16, **changes)
+    model = DMoETransformerLM(cfg, mesh)
+    assert model.cfg.attn_impl == "flash"
+    return model, cfg
+
+
+@pytest.mark.parametrize("formula", ["kept", "parents"])
+@pytest.mark.parametrize("layout", ["unrolled", "scanned"])
+def test_remat_recomputes_no_forward_kernel_call(
+    v5e_chip, monkeypatch, layout, formula
+):
+    """The gradient of a two-layer stack under ``remat``, compiled for a
+    described chip at a small kernel shape: the traced step names the
+    kernel's output and its row sums, two arrays a kernel layer, and both
+    the traced and the compiled step hold ONE forward call a layer beside
+    the fused backward's (a scanned stack: one body, so one of each);
+    under the parent's formula the same count reads two forwards a layer,
+    so the count can tell."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    if formula == "parents":
+        _the_parents_formula(monkeypatch)
+    mesh = Mesh(np.array([v5e_chip]), ("expert",))
+    scanned = layout == "scanned"
+    model, cfg = _kernel_sized(
+        mesh, scan_layers=scanned, stack_layers=scanned)
+    assert cfg.remat and cfg.n_layers == 2
+    one = NamedSharding(mesh, P())
+    shapes = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one),
+        jax.eval_shape(model.init_params, jax.random.PRNGKey(0)))
+    ids = jax.ShapeDtypeStruct((2, cfg.seq_len), jnp.int32, sharding=one)
+    traced = jax.jit(jax.value_and_grad(
+        lambda p, i, t: model.loss_fn(p, i, t)[0])).trace(shapes, ids, ids)
+    bodies = 1 if scanned else cfg.n_layers
+    forwards = bodies * (2 if formula == "parents" else 1)
+    named = [eqn.params["name"]
+             for _, eqn in probe._equations(traced.jaxpr.jaxpr, "name")]
+    assert named == (
+        [] if formula == "parents" else [trunk.FLASH_RESIDUALS] * 2 * bodies)
+    # the output [B, H, S, hd] bf16 and the row sums [B, H, S] float32
+    assert probe.kept_residual_bytes(traced.jaxpr.jaxpr) == (
+        0 if formula == "parents" else bodies * 2 * 4 * 512 * (64 * 2 + 4))
+    calls = collections.Counter(
+        eqn.params["name"]
+        for _, eqn in probe._equations(traced.jaxpr.jaxpr, "pallas_call"))
+    want = {"splash_mha_fwd_residuals": forwards,
+            "splash_mha_dkv_no_residuals": bodies}
+    assert calls == want
+    with _no_compile_cache():
+        text = traced.lower().compile().as_text()
+    assert probe.attention_kernel_calls(text) == want
+
+
+@pytest.mark.parametrize("program", ["apply", "cached_prefill"])
+def test_an_undifferentiated_kernel_call_lowers_to_the_parents_text(
+    monkeypatch, program
+):
+    """Outside a checkpoint the name is the identity: the model's forward
+    and the cached decoder's prefill through the kernel lower for the TPU
+    to the text of the parent's formula, letter for letter (the kernel's
+    serialized module with it), so neither is re-keyed or recompiled."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = _one_device_mesh()
+
+    def lowered():
+        model, cfg = _kernel_sized(mesh)
+        shapes = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
+        ids = jax.ShapeDtypeStruct((2, cfg.seq_len), jnp.int32)
+        if program == "apply":
+            fn, args = jax.jit(lambda p, i: model.apply(p, i)[0]), (shapes, ids)
+        else:
+            fn = jax.jit(model.decode_model()._generate_cached, static_argnums=(2, 3))
+            args = (shapes, ids, 4, 0.0, jax.random.PRNGKey(0))
+        return fn.trace(*args).lower(lowering_platforms=("tpu",)).as_text()
+
+    text = lowered()
+    assert text.count("splash_mha_fwd") >= 2  # a kernel call a layer
+    _the_parents_formula(monkeypatch)
+    assert lowered() == text
+
+
+def test_the_whole_step_holds_one_forward_kernel_call_a_layer(v5e_chip, monkeypatch):
+    """The 4-layer train step at published widths, compiled for a
+    described chip (nothing runs): 1.884 B parameters, the compiler's own
+    count of what is live in the step between a quarter of the chip's
+    memory and 0.9 of it (8.93 GB, 52.8 %, when this was written), and the
+    blocked kernel called once forward and once backward a layer (8 and 4
+    before PR 38: remat keeps the kernel's output and row sums, 68 MB a
+    layer, and the recompute holds no forward call)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    memory = probe.step_memory(v5e_chip, "olmoe_one_chip")
+    assert memory["parameters"] == 1_884_325_888
+    assert 0.25 < memory["share_of_chip"] < 0.9, memory
+    assert memory["loss_layer_products"] == 3
+    assert memory["attention_kernel_calls"] == {
+        "splash_mha_fwd_residuals": 4, "splash_mha_dkv_no_residuals": 4}
+    assert memory["kept_residual_bytes"] == 4 * 4 * 16 * 4096 * (128 * 2 + 4)
+    assert {name: (c["calls"], c["block_q"], c["block_kv"])
+            for name, c in memory["attention_kernel_tilings"]["attention"].items()} == {
+        "splash_mha_fwd_residuals": (4, 1024, 1024),
+        "splash_mha_dkv_no_residuals": (4, 1024, 1024)}
 
 
 @pytest.mark.parametrize("m, a, b", [

@@ -813,6 +813,43 @@ def test_transformer_remat_matches():
     np.testing.assert_allclose(float(l1), float(l2), rtol=1e-5)
 
 
+@pytest.mark.parametrize("against", ["no_remat", "the_parents_remat"])
+def test_remat_policy_names_nothing_on_the_xla_core(against, monkeypatch):
+    """``remat`` keeps what the blocked attention kernel names and nothing
+    else (PR 38).  On the ``xla`` core nothing is named: a two-layer
+    model's loss and every gradient are bit for bit those without remat,
+    and those of the parent's formula (``jax.checkpoint`` under no
+    policy), whose lowered program is the same text."""
+    mesh = make_mesh({"data": 2, "expert": 4})
+    model_r, _ = _tiny_model(mesh, remat=True)
+    params = model_r.init_params(jax.random.PRNGKey(0))
+    rs = np.random.RandomState(1)
+    ids = jnp.asarray(rs.randint(0, 64, (4, 16)))
+    tgt = jnp.asarray(rs.randint(0, 64, (4, 16)))
+
+    def loss_and_grads(model):
+        return jax.jit(jax.value_and_grad(
+            lambda p: model.loss_fn(p, ids, tgt)[0]))
+
+    got_fn = loss_and_grads(model_r)
+    got = got_fn(params)
+    if against == "no_remat":
+        want_fn = loss_and_grads(_tiny_model(mesh, remat=False)[0])
+    else:
+        checkpoint = jax.checkpoint
+        monkeypatch.setattr(
+            jax, "checkpoint", lambda fn, policy=None, **kw: checkpoint(fn, **kw))
+        want_fn = loss_and_grads(_tiny_model(mesh, remat=True)[0])
+        assert want_fn.lower(params).as_text() == got_fn.lower(params).as_text()
+    want = want_fn(params)
+    for (path, g), w in zip(
+        jax.tree_util.tree_flatten_with_path(got)[0],
+        jax.tree_util.tree_leaves(want),
+    ):
+        np.testing.assert_array_equal(
+            np.asarray(g), np.asarray(w), err_msg=jax.tree_util.keystr(path))
+
+
 def test_transformer_zigzag_matches_contiguous():
     """Flagship with the model-boundary zigzag permute produces the same
     loss as the contiguous ring on identical params/batch."""

@@ -668,9 +668,18 @@ def test_the_whole_step_fits_the_chip(v5e_chip, monkeypatch):
     # chip's compiler keeps no fourth product of the head's (PR 34)
     assert memory["loss_layer_products"] == 3
     # a window of 4,096 is no shorter than the kernel's key block: all four
-    # layers keep the fused backward at 1024-wide blocks (PR 36)
+    # layers keep the fused backward at 1024-wide blocks (PR 36); one
+    # forward a layer (8 before PR 38): remat keeps the kernel's output and
+    # row sums, 119 MB a layer, and the recompute holds no forward call,
+    # in the compiled step's instructions and in the traced step's
+    # equations (``attention_kernel_tilings``) alike
     assert memory["attention_kernel_calls"] == {
-        "splash_mha_fwd_residuals": 4 * 2, "splash_mha_dkv_no_residuals": 4}
+        "splash_mha_fwd_residuals": 4, "splash_mha_dkv_no_residuals": 4}
+    assert memory["kept_residual_bytes"] == 4 * 28 * 16384 * (128 * 2 + 4)
+    assert {kind: {name: call["calls"] for name, call in calls.items()}
+            for kind, calls in memory["attention_kernel_tilings"].items()} == {
+        "global": {"splash_mha_fwd_residuals": 1, "splash_mha_dkv_no_residuals": 1},
+        "window": {"splash_mha_fwd_residuals": 3, "splash_mha_dkv_no_residuals": 3}}
     assert {(call["block_q"], call["block_kv"])
             for calls in memory["attention_kernel_tilings"].values()
             for call in calls.values()} == {(1024, 1024)}

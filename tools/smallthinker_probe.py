@@ -12,7 +12,8 @@
 chip; prints the compiler's ``memory_analysis()`` against the chip's
 16,909,334,528 bytes, the tiles each of the step's grouped-matmul
 instructions was compiled at (PERF.md section 3), the blocked attention
-kernel's calls with the blocks and the grid each got, by layer kind, and
+kernel's calls with the blocks and the grid each got, by layer kind, the
+bytes remat keeps of them across the backward pass (PR 38), and
 how many products of the head's the compiled loss layer holds (three since
 PR 34).  Nothing runs.
 
@@ -60,18 +61,47 @@ def no_compile_cache():
         compilation_cache.reset_cache()
 
 
-def _kernel_calls(jaxpr, path: str = ""):
-    """(scope path, equation) of every ``pallas_call`` under ``jaxpr``; an
+def _equations(jaxpr, primitive: str, path: str = ""):
+    """(scope path, equation) of every ``primitive`` under ``jaxpr``; an
     inner equation's name stack is relative to the equation that holds it."""
     for eqn in jaxpr.eqns:
         here = f"{path}/{eqn.source_info.name_stack}"
-        if eqn.primitive.name == "pallas_call":
+        if eqn.primitive.name == primitive:
             yield here, eqn
         for param in eqn.params.values():
             for sub in param if isinstance(param, (list, tuple)) else [param]:
                 sub = getattr(sub, "jaxpr", sub)  # closed or open
                 if hasattr(sub, "eqns"):
-                    yield from _kernel_calls(sub, here)
+                    yield from _equations(sub, primitive, here)
+
+
+def _bytes(aval) -> int:
+    import numpy as np
+
+    return int(np.prod(aval.shape)) * aval.dtype.itemsize
+
+
+def kept_residual_bytes(jaxpr) -> int:
+    """The bytes remat's policy keeps across the backward pass: the sum of
+    the arrays the traced step names ``trunk.FLASH_RESIDUALS`` (the blocked
+    kernel's output and row sums, once a kernel layer, in the forward).
+    What they add to the compiled step's live bytes is at most this: the
+    compiler reuses."""
+    from learning_at_home_tpu.models.trunk import FLASH_RESIDUALS
+
+    return sum(
+        _bytes(eqn.outvars[0].aval) for _, eqn in _equations(jaxpr, "name")
+        if eqn.params["name"] == FLASH_RESIDUALS
+    )
+
+
+def attention_kernel_calls(compiled_text: str) -> dict:
+    """How many instructions of each of the blocked kernel's names
+    (``splash_mha_fwd_residuals``, ``_dkv_no_residuals``,
+    ``_dq_no_residuals``) a compiled program's text holds."""
+    return dict(collections.Counter(re.findall(
+        r"^\s*%(splash_mha\w*?)(?:\.\d+)? = [^\n]*custom-call\(",
+        compiled_text, re.M)))
 
 
 def attention_kernel_tilings(jaxpr) -> dict:
@@ -81,24 +111,22 @@ def attention_kernel_tilings(jaxpr) -> dict:
     mask), the grid it walks (the key-block axis already shrunk to the
     mask where the kernel can) and its largest result in bytes (the fused
     backward's is the queries' gradient once a key block)."""
-    import numpy as np
-
     tilings = {}
-    for path, eqn in _kernel_calls(jaxpr):
+    for path, eqn in _equations(jaxpr, "pallas_call"):
         kind = re.search(r"attention(?:/(global|window))?[/)]", path)
         if not kind or not eqn.params["name"].startswith("splash_mha"):
             continue
         mapping = eqn.params["grid_mapping"]
-        # q comes first, [heads, block_q, hd]; k second, [heads, block_kv, hd]
-        (_, bq, _), (_, bkv, _) = (
+        # q comes first, [heads, block_q, hd]; k second, [heads, block_kv,
+        # hd]; a batch of more than one row (vmap) puts its axis before
+        (*_, bq, _), (*_, bkv, _) = (
             m.block_shape for m in mapping.block_mappings[:2])
         entry = tilings.setdefault(kind.group(1) or "attention", {}).setdefault(
             eqn.params["name"], {
                 "calls": 0, "block_q": bq.block_size, "block_kv": bkv.block_size,
                 "grid": list(mapping.grid),
                 "largest_result_bytes": max(
-                    int(np.prod(a.shape)) * a.dtype.itemsize
-                    for a in eqn.params["out_avals"]),
+                    _bytes(a) for a in eqn.params["out_avals"]),
             })
         entry["calls"] += 1
     return tilings
@@ -111,11 +139,13 @@ def step_memory(chip, recipe: str = "smallthinker_one_chip") -> dict:
     on the chip), under ``grouped_matmul_tilings`` how many of its
     grouped-matmul instructions run at which ``tm,tk,tn``, under
     ``attention_kernel_tilings`` what :func:`attention_kernel_tilings`
-    reads off the traced step and under ``attention_kernel_calls`` how
+    reads off the traced step, under ``attention_kernel_calls`` how
     many instructions of each of the kernel's names the compiled step
-    holds, and under ``loss_layer_products`` how many of its fusions under
-    scope ``ce`` are matmuls (the logits' einsum and its transposes: the
-    head's products)."""
+    holds (one forward a kernel layer since PR 38: remat keeps the
+    kernel's residuals), under ``kept_residual_bytes`` what that costs
+    (:func:`kept_residual_bytes`), and under ``loss_layer_products`` how
+    many of its fusions under scope ``ce`` are matmuls (the logits' einsum
+    and its transposes: the head's products)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -167,8 +197,8 @@ def step_memory(chip, recipe: str = "smallthinker_one_chip") -> dict:
             r'^\s*%ragged-dot-none[.\d]* = [^\n]*ragged_dot_tiling="([\d,]+)"',
             text, re.M))),
         "attention_kernel_tilings": attention_kernel_tilings(traced.jaxpr.jaxpr),
-        "attention_kernel_calls": dict(collections.Counter(re.findall(
-            r"^\s*%(splash_mha\w*?)(?:\.\d+)? = [^\n]*custom-call\(", text, re.M))),
+        "attention_kernel_calls": attention_kernel_calls(text),
+        "kept_residual_bytes": kept_residual_bytes(traced.jaxpr.jaxpr),
         "loss_layer_products": len(re.findall(
             r'^\s*%\S+ = [^\n]* fusion\([^\n]*'
             r'op_name="[^"\n]*[/(]ce[/)][^"\n]*dot_general"', text, re.M)),
