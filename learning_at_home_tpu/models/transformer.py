@@ -38,6 +38,7 @@ from learning_at_home_tpu.models.trunk import (
     output_projection,
     qkv_projections,
     rms_norm,
+    ssm_mixer,
 )
 from learning_at_home_tpu.ops.moe_dispatch import balanced_bias, level_bias
 from learning_at_home_tpu.parallel.mesh import batch_sharding
@@ -135,7 +136,14 @@ class DMoETransformerConfig:
     # then a shared expert beside gated_silu experts of width 1536, 4 of 64
     # by sigmoid scores with a selection bias, renormalised times 1.8 /
     # dropless, a share held / one block that predicts the next-but-one
-    # token with a loss of its own (glm_4_7_flash_one_chip).
+    # token with a loss of its own (glm_4_7_flash_one_chip);
+    # Nemotron-Labs-TwoTower's hybrid tower is rmsnorm / no positions /
+    # layers that are ONE mixer each, M E M E M * E M E: Mamba-2 (64 heads
+    # of 64, state 128, 8 groups, chunks of 128), 32 heads over 2 key/value
+    # heads of 128, or un-gated squared-ReLU experts of width 1856, 6 of
+    # 128 by sigmoid scores with a selection bias, renormalised times 2.5,
+    # beside a shared expert of width 3712 / dropless, a share held
+    # (nemotron_labs_twotower_one_chip).
     # 'layernorm' (scale and bias) or 'rmsnorm' (scale only)
     norm: str = "layernorm"
     norm_eps: float = 1e-5
@@ -166,8 +174,9 @@ class DMoETransformerConfig:
     # d-wide projections, before the split into heads; 'head', over each
     # head's own head_dim, one scale shared by the heads
     qk_norm: bool | str = False
-    # 'gelu' (w1/b1/w2/b2), or 'gated_silu' / 'gated_relu'
-    # (w_gate/w_up/w_down, no biases; SiLU or ReLU on the gate branch)
+    # 'gelu' (w1/b1/w2/b2), 'gated_silu' / 'gated_relu'
+    # (w_gate/w_up/w_down, no biases; SiLU or ReLU on the gate branch), or
+    # 'relu2' (w_up/w_down, no gate branch, no biases: relu(x Wu)^2 Wd)
     expert_kind: str = "gelu"
     # an expert's hidden width; None = 4 * d_model
     expert_ffn_dim: int | None = None
@@ -187,9 +196,11 @@ class DMoETransformerConfig:
     # passes, no router); None = every layer 'moe'
     ffn_pattern: tuple[str, ...] | None = None
     dense_ffn_dim: int | None = None
-    # experts every token passes beside the routed ones, as one gated block
-    # of width shared_experts * expert_ffn_dim added to the routed sum
+    # experts every token passes beside the routed ones, as one block of
+    # the experts' kind added to the routed sum, of width shared_expert_dim
+    # (None = shared_experts * expert_ffn_dim)
     shared_experts: int = 0
+    shared_expert_dim: int | None = None
     # 'softmax': gates from a softmax over all experts; 'sigmoid': every
     # expert scored on its own, the k largest renormalised (renormalize)
     # and multiplied by routed_scale
@@ -213,6 +224,32 @@ class DMoETransformerConfig:
     # mtp_loss_weight
     mtp_layers: int = 0
     mtp_loss_weight: float = 0.0
+    # what a layer IS, one entry a layer: None = every layer an attention
+    # block followed by a feed-forward part (ffn_pattern says which); or
+    # layers that are ONE mixer each behind ONE norm, x + Mixer(norm(x)):
+    # 'ssm' (the Mamba-2 state-space mixer, trunk.ssm_mixer), 'attention',
+    # or 'moe' (the mixture, with its shared expert)
+    mixer_pattern: tuple[str, ...] | None = None
+    # the state-space mixer: ssm_heads heads of ssm_head_dim, a state of
+    # ssm_state_dim a head channel, B and C shared by the heads of each of
+    # ssm_groups groups, a causal depthwise convolution of ssm_conv_kernel
+    # taps, the recurrence computed ssm_chunk positions at a time; dt's
+    # bias drawn so that softplus(dt_bias) is log-uniform in ssm_dt_range
+    # and at least ssm_dt_floor
+    ssm_heads: int | None = None
+    ssm_head_dim: int | None = None
+    ssm_state_dim: int | None = None
+    ssm_groups: int = 1
+    ssm_conv_kernel: int = 4
+    ssm_chunk: int = 128
+    ssm_dt_range: tuple[float, float] = (1e-3, 1e-1)
+    ssm_dt_floor: float = 1e-4
+
+    def mixture_layers(self) -> int:
+        """How many of the stack's layers route (hold a mixture)."""
+        if self.mixer_pattern is not None:
+            return self.mixer_pattern.count("moe")
+        return (self.ffn_pattern or ("moe",) * self.n_layers).count("moe")
 
     def attention_layer(self, i: int) -> AttentionLayer:
         """Layer ``i``'s attention."""
@@ -327,10 +364,54 @@ class DMoETransformerLM:
             config.expert_kind == "gelu"
         ):
             raise ValueError(
-                "the dense layer and the shared expert are gated blocks "
-                "with the experts' activation: expert_kind must be a "
-                "gated kind"
+                "the dense layer and the shared expert are blocks without "
+                "biases (gated, or un-gated for 'relu2'), with the experts' "
+                "activation: expert_kind must not be 'gelu'"
             )
+        mixers = config.mixer_pattern
+        if mixers is not None:
+            if len(mixers) != config.n_layers or (
+                set(mixers) - {"ssm", "attention", "moe"}
+            ) or "moe" not in mixers:
+                raise ValueError(
+                    f"mixer_pattern must name 'ssm', 'attention' or 'moe' "
+                    f"for each of the {config.n_layers} layers, one 'moe' "
+                    f"at least (this is the mixture's train step), got "
+                    f"{mixers}"
+                )
+            if "ssm" in mixers and None in (
+                config.ssm_heads, config.ssm_head_dim, config.ssm_state_dim
+            ):
+                raise ValueError(
+                    "an 'ssm' layer needs ssm_heads, ssm_head_dim and "
+                    "ssm_state_dim"
+                )
+            if (
+                config.ffn_pattern is not None or config.mtp_layers
+                or config.router_input != "moe_input"
+            ):
+                raise ValueError(
+                    "mixer_pattern: a layer that is ONE mixer has no "
+                    "feed-forward part beside its attention (ffn_pattern), "
+                    "no attention input for its router (router_input) and "
+                    "no next-but-one-token block built of such layers "
+                    "(mtp_layers)"
+                )
+            if config.scan_layers or config.stack_layers:
+                raise ValueError(
+                    "mixer_pattern: layers of one mixer each differ in "
+                    "their parameters and their program, which neither one "
+                    "stacked tree nor ONE traced body holds "
+                    "(scan_layers=False, stack_layers=False)"
+                )
+            if config.seq_parallel:
+                raise NotImplementedError(
+                    "seq_parallel=True (ring attention, parallel/"
+                    "ring_attention.py) with mixer_pattern: a state-space "
+                    "layer's recurrence and its convolution cross the "
+                    "ring's sequence shards, and nothing carries a state "
+                    "from shard to shard"
+                )
         if len(ffns) > 1 and (config.scan_layers or config.stack_layers):
             raise ValueError(
                 "ffn_pattern mixes dense and mixture layers, whose "
@@ -485,11 +566,71 @@ class DMoETransformerLM:
                 return {"scale": jnp.ones((d,), pdt)}
             return {"scale": jnp.ones((d,), pdt), "bias": jnp.zeros((d,), pdt)}
 
-        def gated(key, width):
+        def dense_block(key, width):
+            """A dense block of the experts' kind: un-gated for 'relu2'."""
             kg, ku, kd = jax.random.split(key, 3)
-            return {"w_gate": dense(kg, (d, width), pdt),
-                    "w_up": dense(ku, (d, width), pdt),
-                    "w_down": dense(kd, (width, d), pdt)}
+            block = {"w_up": dense(ku, (d, width), pdt),
+                     "w_down": dense(kd, (width, d), pdt)}
+            if cfg.expert_kind != "relu2":
+                block["w_gate"] = dense(kg, (d, width), pdt)
+            return block
+
+        def mixture(key):
+            """A layer's mixture and, beside it, its shared expert."""
+            held = {"moe": self.moe.init_params(key, device_put=False)}
+            if cfg.shared_experts:
+                held["shared"] = dense_block(
+                    jax.random.fold_in(key, 1),
+                    cfg.shared_expert_dim
+                    or cfg.shared_experts * self.moe.ffn_dim,
+                )
+            return held
+
+        def ssm(key):
+            """The state-space mixer: ``dt_bias`` so that ``softplus`` of
+            it is log-uniform in ``ssm_dt_range`` and at least
+            ``ssm_dt_floor``, ``A_log = log(uniform(1, 16))``, ``D = 1``
+            (the three float32 whatever the parameters' dtype, as the
+            decays' arithmetic is): decays in a trained model's range."""
+            h, n_groups = cfg.ssm_heads, cfg.ssm_groups
+            d_inner = h * cfg.ssm_head_dim
+            conv_dim = d_inner + 2 * n_groups * cfg.ssm_state_dim
+            k_in, k_conv, k_dt, k_a, k_out = jax.random.split(key, 5)
+            low, high = np.log(cfg.ssm_dt_range)
+            dt = jnp.maximum(
+                jnp.exp(jax.random.uniform(k_dt, (h,), minval=low, maxval=high)),
+                cfg.ssm_dt_floor,
+            )
+            return {
+                "w_in": dense(k_in, (d, d_inner + conv_dim + h), pdt),
+                # one filter a channel: fan-in the taps
+                "conv_w": jax.nn.initializers.lecun_normal(
+                    in_axis=-1, out_axis=-2
+                )(k_conv, (conv_dim, cfg.ssm_conv_kernel), pdt),
+                "conv_b": jnp.zeros((conv_dim,), pdt),
+                "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),  # softplus^-1
+                "A_log": jnp.log(jax.random.uniform(
+                    k_a, (h,), minval=1.0, maxval=16.0)),
+                "D": jnp.ones((h,), jnp.float32),
+                "gate_norm": {"scale": jnp.ones((d_inner,), pdt)},
+                "w_out": dense(k_out, (d_inner, d), pdt),
+            }
+
+        def init_mixer_layer(key, mixer):
+            """A layer that is ONE mixer behind ONE norm; what it holds
+            says which: ``ssm``, ``wq``.., or ``moe`` (and ``shared``)."""
+            ks = jax.random.split(key, 5)
+            if mixer == "ssm":
+                return {"norm": ln(), "ssm": ssm(ks[0])}
+            if mixer == "moe":
+                return {"norm": ln(), **mixture(ks[4])}
+            return {
+                "norm": ln(),
+                "wq": dense(ks[0], (d, d_q), pdt),
+                "wk": dense(ks[1], (d, d_kv), pdt),
+                "wv": dense(ks[2], (d, d_kv), pdt),
+                "wo": dense(ks[3], (d_q, d), pdt),
+            }
 
         def init_layer(key, ffn="moe"):
             ks = jax.random.split(key, 5)
@@ -523,14 +664,9 @@ class DMoETransformerLM:
             # the layer's feed-forward part is what its parameters hold:
             # 'ffn' (dense), or 'moe' and beside it 'shared'
             if ffn == "dense":
-                lp["ffn"] = gated(ks[4], cfg.dense_ffn_dim)
+                lp["ffn"] = dense_block(ks[4], cfg.dense_ffn_dim)
             else:
-                lp["moe"] = self.moe.init_params(ks[4], device_put=False)
-                if cfg.shared_experts:
-                    lp["shared"] = gated(
-                        jax.random.fold_in(ks[4], 1),
-                        cfg.shared_experts * self.moe.ffn_dim,
-                    )
+                lp.update(mixture(ks[4]))
             if cfg.qk_norm:
                 per_head = cfg.qk_norm == "head"
                 lp["q_norm"] = {"scale": jnp.ones((hd if per_head else d_q,), pdt)}
@@ -549,6 +685,8 @@ class DMoETransformerLM:
             "layers": (
                 jax.vmap(init_layer)(layer_keys)
                 if cfg.stack_layers
+                else tuple(map(init_mixer_layer, layer_keys, cfg.mixer_pattern))
+                if cfg.mixer_pattern is not None
                 else tuple(init_layer(k, f) for k, f in zip(layer_keys, ffn_of))
             ),
         }
@@ -606,8 +744,26 @@ class DMoETransformerLM:
         the stack's layers differ; None = layer 0's, which every layer of
         a uniform stack shares (``layer_idx`` may then be traced).
         Returns ``(x, aux)``; ``aux`` is None for a dense layer."""
+        one_mixer = "norm" in lp  # what the layer holds says what it is
+        if "ssm" in lp:
+            return self._ssm_block(lp, x)
+        if one_mixer and "moe" in lp:
+            return self._ffn_block(lp, x, None, layer_idx, token_mask)
         x, attn_in = self._attention_block(lp, x, kind)
+        if one_mixer:
+            return x, None
         return self._ffn_block(lp, x, attn_in, layer_idx, token_mask)
+
+    def _ssm_block(self, lp, x):
+        """``x + Mixer(norm(x))``, the Mamba-2 mixer; ``aux`` is the
+        smallest decay the layer saw."""
+        cfg = self.cfg
+        with jax.named_scope("ssm"):
+            out, _, decay_min = ssm_mixer(
+                lp["ssm"], self._norm(lp["norm"], x), cfg.ssm_heads,
+                cfg.ssm_groups, cfg.ssm_chunk, cfg.norm_eps,
+            )
+        return x + out, {"ssm_decay_min": decay_min}
 
     def _attention_block(self, lp, x, kind: AttentionLayer | None = None):
         """The stream after the layer's attention, and the normalized
@@ -620,7 +776,8 @@ class DMoETransformerLM:
             "attention/global" if kind.window is None else "attention/window"
         )
         with jax.named_scope(scope):
-            attn_in = self._norm(lp["ln1"], x)
+            # a layer of one mixer has ONE norm
+            attn_in = self._norm(lp["ln1" if "ln1" in lp else "norm"], x)
             q, k, v = self._qkv(
                 lp, attn_in,
                 # under the zigzag ring the stream is in zigzag order
@@ -635,12 +792,18 @@ class DMoETransformerLM:
             x = x + output_projection(lp, core(q, k, v))
         return x, attn_in
 
+    @staticmethod
+    def _ffn_norm(lp):
+        """The norm the feed-forward part reads through: the layer's
+        second, or its ONE where the layer is the mixture alone."""
+        return lp["ln2" if "ln2" in lp else "norm"]
+
     def _ffn_block(self, lp, x, attn_in, layer_idx, token_mask=None):
         """The layer's feed-forward part, which its parameters name: one
         dense gated block (``ffn``: no router, ``aux`` None), or the
         mixture (``moe``) and beside it the shared expert (``shared``)."""
         b, s, d = x.shape
-        ffn_in = self._norm(lp["ln2"], x)
+        ffn_in = self._norm(self._ffn_norm(lp), x)
         if "ffn" in lp:
             with jax.named_scope("dense_ffn"):
                 return x + gated_mlp(lp["ffn"], ffn_in, self.moe._gate_act), None
@@ -711,6 +874,7 @@ class DMoETransformerLM:
                 x, aux = layer_fn(lp, x, idx, token_mask, None)
             return x, aux
 
+        decay_mins: list = []  # a state-space layer's least decay, each
         if self._zig is not None:
             if token_ids.shape[1] != len(self._zig):
                 raise ValueError(
@@ -744,8 +908,12 @@ class DMoETransformerLM:
 
             def add(aux):
                 """A mixture layer's aux into the stack's sums; its
-                assignments per expert stay a row of their own."""
+                assignments per expert stay a row of their own.  A
+                state-space layer's is its smallest decay alone."""
                 nonlocal aux_total
+                if "ssm_decay_min" in aux:
+                    decay_mins.append(aux["ssm_decay_min"])
+                    return
                 if "expert_counts" in aux:
                     counts.append(aux.pop("expert_counts"))
                 aux_total = (
@@ -769,7 +937,7 @@ class DMoETransformerLM:
         if self._zig is not None:
             x = x[:, self._zig_inv]
         x = self._norm(params["ln_f"], x)
-        n_moe = (cfg.ffn_pattern or ("moe",) * cfg.n_layers).count("moe")
+        n_moe = cfg.mixture_layers()
         if next_ids is not None:
             with jax.named_scope("mtp"):
                 x_mtp, aux = self._mtp(
@@ -783,6 +951,10 @@ class DMoETransformerLM:
             aux_mean["expert_counts"] = (
                 counts if cfg.scan_layers else jnp.stack(counts)
             )
+        if decay_mins:
+            # the smallest exp(dt A) the step saw: neither frozen at 1 nor
+            # forgetting everything
+            aux_mean["ssm_decay_min"] = jnp.min(jnp.stack(decay_mins))
         if next_ids is not None:
             return x, x_mtp, aux_mean
         return x, aux_mean
@@ -920,6 +1092,14 @@ class DMoETransformerLM:
             # buffer and fail at trace time on .at[:, 0]
             return prompt_ids
         if use_cache:
+            if self.cfg.mixer_pattern is not None:
+                raise NotImplementedError(
+                    "use_cache=True with mixer_pattern: the KV-cache "
+                    "decoder runs an attention block and a mixture in "
+                    "every layer; a state-space layer's recurrent state "
+                    "(and its convolution's last inputs) beside the KV "
+                    "cache is not built; decode without the cache"
+                )
             if self.cfg.kv_latent_dim is not None or self.cfg.mtp_layers:
                 raise NotImplementedError(
                     "use_cache=True: the KV-cache decoder keeps whole keys "
@@ -1330,8 +1510,10 @@ class DMoETransformerLM:
         embed = jax.jit(lambda table, ids: table[ids].astype(cfg.dtype))
         attend = jax.jit(self._attention_block, static_argnums=(2,))
         finish = jax.jit(self._ffn_block)
+        whole = jax.jit(self._layer, static_argnums=(4,))
         scores = jax.jit(lambda lp, x: jax.nn.sigmoid(self.moe.router_logits(
-            lp["moe"], self._norm(lp["ln2"], x).reshape(-1, cfg.d_model))))
+            lp["moe"],
+            self._norm(self._ffn_norm(lp), x).reshape(-1, cfg.d_model))))
         streams = [embed(params["embed"], ids) for ids in token_batches]
         layers, loads = list(params["layers"]), []
         if cfg.router_input != "moe_input":
@@ -1339,7 +1521,10 @@ class DMoETransformerLM:
                 "level_router_bias reads the router on the experts' input"
             )
         for i, lp in enumerate(layers):
-            streams = [attend(lp, x, cfg.attention_layer(i))[0] for x in streams]
+            one_mixer = "norm" in lp  # its router reads the layer's input
+            if not one_mixer:
+                streams = [
+                    attend(lp, x, cfg.attention_layer(i))[0] for x in streams]
             if "moe" in lp:
                 bias, load = level_bias(
                     jnp.concatenate([scores(lp, x) for x in streams]),
@@ -1347,7 +1532,11 @@ class DMoETransformerLM:
                 )
                 lp = layers[i] = {**lp, "moe": {**lp["moe"], "router_bias": bias}}
                 loads.append(load)
-            streams = [finish(lp, x, None, i)[0] for x in streams]
+            streams = [
+                whole(lp, x, i, None, cfg.attention_layer(i))[0] if one_mixer
+                else finish(lp, x, None, i)[0]
+                for x in streams
+            ]
         params = {**params, "layers": tuple(layers)}
         if "mtp" in params:
             # the block's router too, on what the block's attention leaves

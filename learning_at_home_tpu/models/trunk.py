@@ -12,6 +12,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from learning_at_home_tpu.ops.ssd import ssd_chunked
+
 
 def layer_norm(p: dict, x: jax.Array, eps: float = 1e-5) -> jax.Array:
     """Pre-LN in float32, cast back to the input dtype."""
@@ -144,12 +146,83 @@ def output_projection(lp: dict, out: jax.Array) -> jax.Array:
     return out.reshape(b, s, h * hd) @ lp["wo"].astype(out.dtype)
 
 
+def squared_relu(h: jax.Array) -> jax.Array:
+    return jnp.square(jax.nn.relu(h))
+
+
 def gated_mlp(p: dict, x: jax.Array, act=jax.nn.silu) -> jax.Array:
-    """A dense gated feed-forward block on [.., d], no biases:
-    ``(act(x Wg) * (x Wu)) Wd`` (a layer's dense feed-forward part, or the
-    shared expert every token passes beside the routed ones)."""
-    h = act(x @ p["w_gate"].astype(x.dtype)) * (x @ p["w_up"].astype(x.dtype))
+    """A dense feed-forward block on [.., d], no biases (a layer's dense
+    feed-forward part, or the shared expert every token passes beside the
+    routed ones).  Its parameters say which: with ``w_gate`` the gated
+    form ``(act(x Wg) * (x Wu)) Wd``, without it the un-gated
+    ``act(x Wu) Wd`` (``act`` then the squared ReLU)."""
+    if "w_gate" in p:  # traced gate first, as ever: the same program
+        h = act(x @ p["w_gate"].astype(x.dtype)) * (
+            x @ p["w_up"].astype(x.dtype))
+    else:
+        h = act(x @ p["w_up"].astype(x.dtype))
     return h @ p["w_down"].astype(x.dtype)
+
+
+def ssm_mixer(
+    p: dict, u: jax.Array, n_heads: int, n_groups: int, chunk: int,
+    eps: float = 1e-5, decay_dtype=jnp.float32,
+):
+    """The Mamba-2 mixer on the normalized stream ``u`` [B, S, d]:
+    ``(out [B, S, d], the recurrent state after the last position
+    [B, H, P, N] float32, the smallest decay exp(dt A) any position saw)``.
+
+    ``[z | xBC | dt] = u W_in`` (no bias); ``xBC = silu(conv(xBC))``, a
+    causal depthwise convolution with bias over the channels (``xBC[t] =
+    b + sum_j w[:, j] xBC_in[t - (K - 1) + j]``, zeros before the
+    sequence); ``x`` [S, H, P], ``B`` and ``C`` [S, G, N] its three parts,
+    head ``h`` reading group ``h // (H / G)``; ``dt = softplus(dt +
+    dt_bias)``; ``A = -exp(A_log)``; the recurrence ``h_t = exp(dt_t A)
+    h_{t-1} + dt_t x_t B_t^T``, ``y_t = h_t C_t + D x_t`` in chunks of
+    ``chunk`` (:func:`~learning_at_home_tpu.ops.ssd.ssd_chunked`); ``y =
+    RMSNorm(y * silu(z))``, the gate FIRST, then the norm over each of the
+    ``G`` groups of channels under one scale; ``out = y W_out``.
+    ``decay_dtype`` is ``ssd_chunked``'s: float32 in every step.  The
+    parameters say the sizes: the heads' size ``P`` is ``w_out``'s input
+    width over ``n_heads``, the state's ``N`` what ``conv_w``'s channels
+    leave beyond ``x`` over ``2 G``.  Sub-scopes ``in_proj``, ``conv``,
+    ``scan``, ``gate_norm``, ``out_proj``."""
+    b, s, _ = u.shape
+    f32 = jnp.float32
+    d_inner = p["w_out"].shape[0]
+    head_dim = d_inner // n_heads
+    conv_dim, taps = p["conv_w"].shape
+    n_state = (conv_dim - d_inner) // (2 * n_groups)
+    with jax.named_scope("in_proj"):
+        zxbcdt = u @ p["w_in"].astype(u.dtype)
+        z = zxbcdt[..., :d_inner]
+        xbc = zxbcdt[..., d_inner:d_inner + conv_dim]
+        dt = zxbcdt[..., d_inner + conv_dim:]
+    with jax.named_scope("conv"):
+        padded = jnp.pad(xbc.astype(f32), ((0, 0), (taps - 1, 0), (0, 0)))
+        w = p["conv_w"].astype(f32)
+        xbc = jax.nn.silu(p["conv_b"].astype(f32) + sum(
+            w[:, j] * padded[:, j:j + s] for j in range(taps)
+        )).astype(u.dtype)
+    with jax.named_scope("scan"):
+        x = xbc[..., :d_inner].reshape(b, s, n_heads, head_dim)
+        bc = xbc[..., d_inner:].reshape(b, s, 2, n_groups, n_state)
+        dt = jax.nn.softplus(dt.astype(f32) + p["dt_bias"].astype(f32))
+        a = -jnp.exp(p["A_log"].astype(f32))
+        y, state = ssd_chunked(
+            x, dt, a, bc[:, :, 0], bc[:, :, 1], chunk, decay_dtype)
+        y = y.astype(f32) + p["D"].astype(f32)[:, None] * x.astype(f32)
+        decay_min = jnp.exp(jnp.min(dt * a))
+    with jax.named_scope("gate_norm"):
+        y = y.reshape(b, s, d_inner) * jax.nn.silu(z.astype(f32))
+        grouped = y.reshape(b, s, n_groups, d_inner // n_groups)
+        ms = jnp.mean(grouped * grouped, axis=-1, keepdims=True)
+        y = (
+            (grouped * jax.lax.rsqrt(ms + eps)).reshape(b, s, d_inner)
+            * p["gate_norm"]["scale"]
+        ).astype(u.dtype)
+    with jax.named_scope("out_proj"):
+        return y @ p["w_out"].astype(u.dtype), state, decay_min
 
 
 def causal_attention(

@@ -629,6 +629,15 @@ def _largest_lane_divisor(dim: int, most: int) -> int:
     )
 
 
+def _lane_cover(dim: int) -> int:
+    """A width that ends half-way through a lane tile (``dim % 128 == 64``:
+    1,856 is 14.5 of them) at its cover, the next multiple of 128; any
+    other width as it is.  Such a width has no dividing tile, and a tile
+    that does not divide is padded: measured at 1,856 (cover 1,920) alone,
+    where other remainders were not, and keep no tiles."""
+    return dim + 64 if dim % 128 == 64 else dim
+
+
 def grouped_matmul_tiles(
     m: int, k: int, n: int, dtype, weights_gradient: bool = False
 ) -> tuple[int, int, int] | None:
@@ -658,13 +667,26 @@ def grouped_matmul_tiles(
     keeps ``tk * tn`` within the 2 Mi (1 Mi); for a power of two that is
     ``min(dim, cap)``.
 
-    ``None`` where no multiple of 128 divides a width or 256 the rows,
+    A width no multiple of 128 divides because it ends half-way through a
+    lane tile (Nemotron-H's experts: 1,856 = 14.5 x 128) is tiled at its
+    cover (``_lane_cover``: 1,920), the last tile padded.  There the
+    compiler's own choice is (512, 128, 128), and on a share's buffer of
+    49,152 rows in 32 groups (v5e, PERF.md section 6, PR 39) the six calls
+    of a layer read 13.3 to 17.8 ms each where this rule's tiles read 2.56
+    to 4.41: forward 2688 x 1856 at (256, 896, 1920) 3.82 (best of the
+    sweep 3.63), 1856 x 2688 at (256, 1920, 896) 2.56 (the best), the
+    weights' gradients at (256, 896, 640) 4.41 (4.22) and (256, 640, 896)
+    3.34 (3.13).
+
+    ``None`` where no multiple of 128 divides a width (but for that
+    cover) or 256 the rows,
     for operands other than bf16 (the tiles were measured, and their VMEM
     counted, at two bytes an element) and under
     ``GROUPED_MATMUL_MIN_ROWS`` rows."""
     if jnp.dtype(dtype) != jnp.bfloat16 or m < GROUPED_MATMUL_MIN_ROWS or m % 256:
         return None
     budget = 1 << 20 if weights_gradient else 1 << 21
+    k, n = _lane_cover(k), _lane_cover(n)
     tk = _largest_lane_divisor(k, 1280 if weights_gradient else 2560)
     tn = _largest_lane_divisor(n, budget // tk) if tk else 0
     return (256, tk, tn) if tn else None

@@ -51,6 +51,7 @@ from learning_at_home_tpu.ops.moe_dispatch import (
     top_k_gating_indices,
     unsort_combine,
 )
+from learning_at_home_tpu.models.trunk import squared_relu
 from learning_at_home_tpu.parallel.mesh import data_axes
 
 Params = dict[str, jax.Array]
@@ -83,6 +84,10 @@ class ShardedMixtureOfExperts:
     code, ``relu`` on the gate branch):
       w_gate, w_up  [E, d, ffn]
       w_down        [E, ffn, d]
+    ``expert_kind="relu2"`` (``w_down(relu(w_up x)^2)``: no gate branch,
+    no biases; two matrices an expert and two grouped matmuls forward):
+      w_up    [E, d, ffn]
+      w_down  [E, ffn, d]
 
     ``router_input=True``: the call takes the router's input beside the
     experts' (``router_x``: a router placed before the attention block
@@ -139,10 +144,10 @@ class ShardedMixtureOfExperts:
                 "dispatch_impl must be 'auto', 'gather' or 'onehot', "
                 f"got {dispatch_impl!r}"
             )
-        if expert_kind not in ("gelu", "gated_silu", "gated_relu"):
+        if expert_kind not in ("gelu", "gated_silu", "gated_relu", "relu2"):
             raise ValueError(
-                f"expert_kind must be 'gelu', 'gated_silu' or 'gated_relu'"
-                f", got {expert_kind!r}"
+                f"expert_kind must be 'gelu', 'gated_silu', 'gated_relu' or "
+                f"'relu2', got {expert_kind!r}"
             )
         if routing not in ("capacity", "dropless"):
             raise ValueError(
@@ -189,9 +194,9 @@ class ShardedMixtureOfExperts:
         ):
             raise NotImplementedError(
                 f"held_experts={held} of {num_experts}: a share is the "
-                "dropless path of the gated kinds on a mesh whose 'expert' "
-                "axis is 1; across chips the shares' rows need the ragged "
-                "all-to-all"
+                "dropless path of the kinds without biases (not 'gelu') on "
+                "a mesh whose 'expert' axis is 1; across chips the shares' "
+                "rows need the ragged all-to-all"
             )
         if "expert" not in mesh.axis_names:
             raise ValueError("mesh must have an 'expert' axis")
@@ -238,8 +243,11 @@ class ShardedMixtureOfExperts:
         self.router_score = router_score
         self.router_bias = router_bias
         self.routed_scale = routed_scale
-        # the gate branch's activation of the gated kinds
-        self._gate_act = jax.nn.relu if expert_kind == "gated_relu" else jax.nn.silu
+        # the activation of the kinds without biases: on the gate branch
+        # of the gated kinds, on the one branch of 'relu2' (its square)
+        self._gate_act = {
+            "gated_relu": jax.nn.relu, "relu2": squared_relu,
+        }.get(expert_kind, jax.nn.silu)
         if routing == "dropless" and (self.ep > 1 or self.tp > 1):
             raise NotImplementedError(
                 f"routing='dropless' on a mesh with expert={self.ep}, "
@@ -275,10 +283,11 @@ class ShardedMixtureOfExperts:
             k1a, k1b = jax.random.split(k1)
             params = {
                 "gate": gate_init(kg, (d, n_scored), self.param_dtype),
-                "w_gate": per_expert(k1a, (e, d, f), self.param_dtype),
                 "w_up": per_expert(k1b, (e, d, f), self.param_dtype),
                 "w_down": per_expert(k2, (e, f, d), self.param_dtype),
             }
+            if self.expert_kind != "relu2":
+                params["w_gate"] = per_expert(k1a, (e, d, f), self.param_dtype)
         else:
             params = {
                 "gate": gate_init(kg, (d, n_scored), self.param_dtype),
@@ -299,6 +308,8 @@ class ShardedMixtureOfExperts:
         if self.expert_kind != "gelu":
             col = P("expert", None, "model") if self.tp > 1 else P("expert")
             row = P("expert", "model", None) if self.tp > 1 else P("expert")
+            if self.expert_kind == "relu2":
+                return {"w_up": col, "w_down": row}
             return {"w_gate": col, "w_up": col, "w_down": row}
         if self.tp > 1:
             return {
@@ -466,11 +477,14 @@ class ShardedMixtureOfExperts:
                 e_local, self.ep * capacity, d
             )
             if self.expert_kind != "gelu":
-                h = self._gate_act(
-                    jnp.einsum("egd,edf->egf", xe,
-                               params["w_gate"].astype(compute))
-                ) * jnp.einsum("egd,edf->egf", xe,
-                               params["w_up"].astype(compute))
+                def product(w):
+                    return jnp.einsum(
+                        "egd,edf->egf", xe, params[w].astype(compute))
+
+                if self.expert_kind == "relu2":
+                    h = self._gate_act(product("w_up"))
+                else:
+                    h = self._gate_act(product("w_gate")) * product("w_up")
                 ye = jnp.einsum("egf,efd->egd", h,
                                 params["w_down"].astype(compute))
                 if self.tp > 1:
@@ -538,7 +552,7 @@ class ShardedMixtureOfExperts:
         with jax.named_scope("moe_sort"):
             xs = sort_tokens(x.astype(compute), plan)  # [n*k, d]
         if self.expert_kind != "gelu":
-            ys = self._gated_experts(params, xs, plan.group_sizes)
+            ys = self._unbiased_experts(params, xs, plan.group_sizes)
         else:
             expert_of_row = jnp.repeat(
                 jnp.arange(self.num_experts), plan.group_sizes,
@@ -572,16 +586,25 @@ class ShardedMixtureOfExperts:
             aux.update(self._bias_aux(params, plan.group_sizes))
         return y, aux
 
-    def _gated_experts(
+    def _unbiased_experts(
         self, params: Params, xs: jax.Array, group_sizes: jax.Array
     ) -> jax.Array:
-        """The gated experts on rows sorted by expert: two grouped matmuls,
-        the gate branch's activation, a third."""
+        """The experts without biases on rows sorted by expert.  The gated
+        kinds: two grouped matmuls, the gate branch's activation, a third
+        (scopes ``experts/gate_up``, ``experts/down``); ``relu2``: one
+        grouped matmul, its squared ReLU, a second (``experts/up``,
+        ``experts/down``)."""
         compute = self.dtype
-        with jax.named_scope("experts/gate_up"):
-            h = self._gate_act(
-                grouped_matmul(xs, params["w_gate"].astype(compute), group_sizes)
-            ) * grouped_matmul(xs, params["w_up"].astype(compute), group_sizes)
+        if self.expert_kind == "relu2":
+            with jax.named_scope("experts/up"):
+                h = self._gate_act(grouped_matmul(
+                    xs, params["w_up"].astype(compute), group_sizes))
+        else:
+            with jax.named_scope("experts/gate_up"):
+                h = self._gate_act(grouped_matmul(
+                    xs, params["w_gate"].astype(compute), group_sizes)
+                ) * grouped_matmul(
+                    xs, params["w_up"].astype(compute), group_sizes)
         with jax.named_scope("experts/down"):
             return grouped_matmul(
                 h, params["w_down"].astype(compute), group_sizes
@@ -629,7 +652,7 @@ class ShardedMixtureOfExperts:
             )
         with jax.named_scope("moe_sort"):
             xs = share_sort_tokens(x.astype(compute), plan)  # [R, d]
-        ys = self._gated_experts(params, xs, plan.group_sizes)
+        ys = self._unbiased_experts(params, xs, plan.group_sizes)
         with jax.named_scope("moe_combine"):
             y = share_combine(ys, plan, n).astype(x.dtype)
 

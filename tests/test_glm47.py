@@ -627,10 +627,10 @@ def test_benchmark_manifests_pass_selfcheck_and_the_runner_rehearses(tmp_path):
     cell = harness.by_name(manifest["workloads"], CELL, "workload")
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         "glm-4.7-flash", "train-zipf16k", 1)
-    assert manifest["workloads"][-1] == cell and len(manifest["workloads"]) == 8
-    assert manifest["configs"][-1]["name"] == "glm-4.7-flash"
+    assert manifest["workloads"][7] == cell  # the eighth; later PRs append
+    assert manifest["configs"][5]["name"] == "glm-4.7-flash"
     rate = harness.by_name(manifest["end_to_end"], "train_tokens_per_s_per_chip", "metric")
-    assert rate["workloads"][-1] == CELL
+    assert CELL in rate["workloads"]
     reported = [m["name"] for m in harness.metrics_of_cell(manifest["per_layer"], CELL)]
     assert len(reported) == 18 and all(n.startswith("glm47.") for n in reported)
     assert {"glm47.attention_latent_share", "glm47.mtp_share",

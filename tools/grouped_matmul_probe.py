@@ -10,6 +10,9 @@
     python tools/grouped_matmul_probe.py accuracy          # the program's call against float32
     # another cell's shapes (here smallthinker-21b-a3b-train-zipf16k's):
     python tools/grouped_matmul_probe.py tiles skewed 256 --rows 98304 --widths 2560x768,768x2560
+    # a share's buffer, a width no multiple of the lanes divides (nemotron-labs-twotower's):
+    python tools/grouped_matmul_probe.py tiles uniform 256 512 --rows 49152 --counted-rows 24576 \
+        --groups 32 --widths 2688x1856,1856x2688
 
 ``--rows``, ``--groups`` and ``--widths`` (``k``x``n``, comma-separated)
 give every subcommand its shapes; the default is the OLMoE cell's.  One
@@ -143,8 +146,12 @@ def operands(k: int, n: int, m: int = ROWS, groups: int = GROUPS):
 
 def width_tiles(dim: int) -> list:
     """The tiles tried along a width: every multiple of the 128 lanes,
-    from 384 up, that divides it."""
-    return [t for t in range(384, dim + 1, 128) if dim % t == 0]
+    from 384 up, that divides it; or, for a width no multiple of the lanes
+    divides (1,856 is 14.5 of them), that divides its cover, the next
+    multiple of 128 (1,920: 384, 640, 1,920): a tile that does not divide
+    is padded."""
+    cover = -(-dim // 128) * 128
+    return [t for t in range(384, cover + 1, 128) if cover % t == 0]
 
 
 def ragged_call(kind: str, tiles):
@@ -238,7 +245,9 @@ def sweep(what: str, make_call, shape) -> None:
 
     require_tpu()
     how, m = shape.group_sizes, shape.rows
-    sizes = jnp.asarray(group_sizes(how, m, shape.groups, shape.max_over_mean))
+    # a share's buffer holds more rows than its groups count
+    sizes = jnp.asarray(group_sizes(
+        how, shape.counted_rows or m, shape.groups, shape.max_over_mean))
     for k, n in shape.widths:
         x, w, g = operands(k, n, m, shape.groups)
         for kind in KINDS:
@@ -350,6 +359,9 @@ def main() -> None:
     ap.add_argument("--groups", type=int, default=GROUPS)
     ap.add_argument("--widths", type=widths, default=WIDTHS,
                     help="kxn of each grouped matmul, comma-separated")
+    ap.add_argument("--counted-rows", type=int, default=None,
+                    help="tiles, gmm: the rows the groups count, where the "
+                         "buffer (--rows) holds more (a share's)")
     ap.add_argument("--max-over-mean", type=float, default=MAX_OVER_MEAN,
                     help="skewed: the largest group's rows over the mean")
     shape = ap.parse_args()

@@ -1,14 +1,15 @@
 #!/usr/bin/env python3
 """Two readings a one-chip recipe's configuration rests on
-(``smallthinker-21b-a3b`` by default; ``k-exaone-236b-a23b`` and
-``glm-4.7-flash`` by name).
+(``smallthinker-21b-a3b`` by default; ``k-exaone-236b-a23b``,
+``glm-4.7-flash`` and ``nemotron-labs-twotower-30b-a3b`` by name).
 
     python tools/smallthinker_probe.py memory [recipe]
     chiprun -- python tools/smallthinker_probe.py float8 [config.json] [seed ...]
 
 ``memory`` (here, no chip): the whole train step of a recipe of
 ``__graft_entry__`` (``smallthinker_one_chip``, or the one named, such as
-``k_exaone_one_chip`` or ``glm_4_7_flash_one_chip``) at published widths, compiled for a described v5e
+``k_exaone_one_chip``, ``glm_4_7_flash_one_chip`` or
+``nemotron_labs_twotower_one_chip``) at published widths, compiled for a described v5e
 chip; prints the compiler's ``memory_analysis()`` against the chip's
 16,909,334,528 bytes, the tiles each of the step's grouped-matmul
 instructions was compiled at (PERF.md section 3), the blocked attention
@@ -26,11 +27,15 @@ levelled first where the recipe has them), of the program and then, in
 the program's place, of the configuration's plain reference with every
 matmul operand rounded to float8_e4m3: the reading that the runner's
 tolerances must refuse (PERF.md section 2).  bf16 operands follow, which
-they must pass.
+they must pass.  Where the runner's comparison takes a ``decay_dtype``
+(``train_recipe_hybrid``: a state-space scan), one more reading follows:
+the PROGRAM with its scan's decays computed in bf16, which the state-space
+layer's limits must refuse.
 """
 
 import collections
 import contextlib
+import inspect
 import json
 import os
 import re
@@ -248,13 +253,20 @@ def float8(seeds: list, config_path: str = CONFIG) -> None:
             params, opt_state, _, _ = step(params, opt_state, *pool[i])
         del opt_state
         ids, tgt = batches[0][0][:1], batches[0][1][:1]
-        for dtype in (None, jnp.float8_e4m3fn, jnp.bfloat16):
+        readings = [("the program", {})] + [
+            (jnp.dtype(dtype).name, {"operand_dtype": dtype})
+            for dtype in (jnp.float8_e4m3fn, jnp.bfloat16)]
+        if "decay_dtype" in inspect.signature(
+                runner.compare_with_reference).parameters:
+            readings.append(("the program, its scan's decays in bfloat16",
+                             {"decay_dtype": jnp.bfloat16}))
+        for operands, how in readings:
             read = runner.compare_with_reference(
                 model, params, reference, config, jnp.asarray(ids),
-                jnp.asarray(tgt), operand_dtype=dtype)
+                jnp.asarray(tgt), **how)
             print("REFERENCE_AT " + json.dumps({
                 "seed": seed,
-                "operands": jnp.dtype(dtype).name if dtype else "the program",
+                "operands": operands,
                 **read,
                 "limits": runner.TOLERANCES,
                 "outside": [k for k, lim in runner.TOLERANCES.items()
