@@ -41,6 +41,7 @@ from learning_at_home_tpu.models.trunk import (
     ssm_mixer,
 )
 from learning_at_home_tpu.ops.moe_dispatch import balanced_bias, level_bias
+from learning_at_home_tpu.ops.ssd import SSD_RESIDUALS
 from learning_at_home_tpu.parallel.mesh import batch_sharding
 from learning_at_home_tpu.parallel.sharded_moe import ShardedMixtureOfExperts
 
@@ -859,12 +860,13 @@ class DMoETransformerLM:
             # kernel's output and row sums, which its backward kernels
             # read, so the recompute holds no forward kernel call (a
             # layer's 68-273 MB against 3-32 ms: PERF.md section 6, PR
-            # 38); a layer whose core is xla names nothing and is
-            # recomputed whole, as under no policy
+            # 38), and the scan kernel's output and entering states
+            # (ops/ssd.py; PR 40); a layer that runs neither kernel
+            # names nothing and is recomputed whole, as under no policy
             layer_fn = jax.checkpoint(
                 layer_fn, static_argnums=(4,),
                 policy=jax.checkpoint_policies.save_only_these_names(
-                    FLASH_RESIDUALS
+                    FLASH_RESIDUALS, SSD_RESIDUALS
                 ),
             )
 

@@ -34,6 +34,7 @@ from learning_at_home_tpu.models.transformer import (  # noqa: E402
     DMoETransformerLM,
 )
 from learning_at_home_tpu.ops import moe_dispatch  # noqa: E402
+from learning_at_home_tpu.ops import ssd  # noqa: E402
 from learning_at_home_tpu.ops.ssd import ssd_chunked  # noqa: E402
 from learning_at_home_tpu.parallel.mesh import make_mesh  # noqa: E402
 from learning_at_home_tpu.parallel.sharded_moe import ShardedMixtureOfExperts  # noqa: E402
@@ -820,7 +821,8 @@ def test_the_whole_step_fits_the_chip(v5e_chip, monkeypatch):
     described chip (nothing runs): 1,624,837,632 parameters, the
     compiler's own count of what is live in the step between a quarter of
     the chip's memory (the benchmark's floor for a cell) and 0.9 of it
-    (9.92 GB, 58.7 %, when this was written: ISSUE.md expected 47-65 %),
+    (9.92 GB, 58.7 %, when this was written: ISSUE.md expected 47-65 %;
+    10.31 GB, 61.0 %, with the scan's kernels and what remat keeps of them: PR 40),
     the blocked kernel at heads of 128 in the one attention layer, once
     forward (remat keeps its residuals) and once fused backward, and the
     head's three products a pass."""
@@ -843,3 +845,41 @@ def test_the_whole_step_fits_the_chip(v5e_chip, monkeypatch):
         "splash_mha_dkv_no_residuals": (1, 1024, 1024)}
     # 32 query heads over 2 key/value heads, as they come
     assert calls["splash_mha_fwd_residuals"]["grid"][0] == 32
+    # the scan's kernels, once forward (remat keeps the output and the
+    # entering states: the recompute holds no scan) and once backward a
+    # state-space layer, every call under ``ssm/scan``
+    assert memory["scan_kernel_calls"] == {
+        "ssd_chunk_fwd": {"calls": 4, "under_ssm_scan": 4},
+        "ssd_chunk_bwd": {"calls": 4, "under_ssm_scan": 4}}
+    assert memory["kept_scan_bytes"] == 4 * (
+        16384 * 4096 * 2 + 128 * 64 * 64 * 128 * 4)
+
+
+def test_the_scan_kernels_compile_for_the_chip_at_the_cells_shape(v5e_chip):
+    """``ssd_chunk_fwd`` and ``ssd_chunk_bwd`` at ``[1, 16384, 64, 64]``,
+    state 128, 8 groups, chunks of 128, bf16, compiled for a described
+    chip (nothing runs): Mosaic takes the tiles, the transposes and the
+    VMEM the kernels ask for."""
+    one = jax.sharding.SingleDeviceSharding(v5e_chip)
+    s, h, p, g, n = (CELL_FILE[k] for k in (
+        "seq_len", "mamba_num_heads", "mamba_head_dim", "n_groups",
+        "ssm_state_size"))
+    assert (s, h, p, g, n, CELL_FILE["chunk_size"]) == (16384, 64, 64, 8, 128, 128)
+    assert ssd.kernel_fits((1, s, h, p), (1, s, g, n), 128, "tpu")
+
+    def shaped(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    args = (shaped((1, s, h, p), jnp.bfloat16), shaped((1, s, h), jnp.float32),
+            shaped((h,), jnp.float32), shaped((1, s, g, n), jnp.bfloat16),
+            shaped((1, s, g, n), jnp.bfloat16))
+
+    def loss(*a):
+        y, state = ssd.ssd_chunked_kernel(*a, 128)
+        return jnp.sum(y.astype(jnp.float32)) + jnp.sum(state)
+
+    with probe.no_compile_cache():
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+            *args).compile().as_text()
+    assert {name: c["calls"] for name, c in probe.scan_kernel_calls(text).items()} == {
+        "ssd_chunk_fwd": 1, "ssd_chunk_bwd": 1}

@@ -5,6 +5,7 @@
 
     python tools/smallthinker_probe.py memory [recipe]
     chiprun -- python tools/smallthinker_probe.py float8 [config.json] [seed ...]
+    chiprun -- python tools/smallthinker_probe.py ssd [seq_len] [accuracy_len]
 
 ``memory`` (here, no chip): the whole train step of a recipe of
 ``__graft_entry__`` (``smallthinker_one_chip``, or the one named, such as
@@ -14,7 +15,8 @@ chip; prints the compiler's ``memory_analysis()`` against the chip's
 16,909,334,528 bytes, the tiles each of the step's grouped-matmul
 instructions was compiled at (PERF.md section 3), the blocked attention
 kernel's calls with the blocks and the grid each got, by layer kind, the
-bytes remat keeps of them across the backward pass (PR 38), and
+bytes remat keeps of them across the backward pass (PR 38), the scan
+kernel's calls and what remat keeps of those (PR 40), and
 how many products of the head's the compiled loss layer holds (three since
 PR 34).  Nothing runs.
 
@@ -31,6 +33,16 @@ they must pass.  Where the runner's comparison takes a ``decay_dtype``
 (``train_recipe_hybrid``: a state-space scan), one more reading follows:
 the PROGRAM with its scan's decays computed in bf16, which the state-space
 layer's limits must refuse.
+
+``ssd`` (on the chip): the chunked scan of ``ops/ssd.py`` at the
+Nemotron cell's shape (``[1, seq_len, 64, 64]``, state 128, 8 groups,
+chunks of 128, bf16; ``seq_len`` 16,384 by default), the plain form
+against the kernel: milliseconds a call forward and forward + backward
+(a loss that reads the output and the last state), and at
+``accuracy_len`` (4,096 by default: the float32 form's backward holds
+every intermediate) the relative rms of each form's output, last state
+and five gradients against the plain form in float32 at the highest
+matmul precision (PERF.md section 6, PR 40).
 """
 
 import collections
@@ -86,18 +98,38 @@ def _bytes(aval) -> int:
     return int(np.prod(aval.shape)) * aval.dtype.itemsize
 
 
-def kept_residual_bytes(jaxpr) -> int:
-    """The bytes remat's policy keeps across the backward pass: the sum of
-    the arrays the traced step names ``trunk.FLASH_RESIDUALS`` (the blocked
-    kernel's output and row sums, once a kernel layer, in the forward).
-    What they add to the compiled step's live bytes is at most this: the
-    compiler reuses."""
+def kept_residual_bytes(jaxpr, name: str | None = None) -> int:
+    """The bytes remat's policy keeps across the backward pass under
+    ``name``: the sum of the arrays the traced step names
+    ``trunk.FLASH_RESIDUALS`` (the default: the blocked kernel's output
+    and row sums, once a kernel layer, in the forward) or
+    ``ssd.SSD_RESIDUALS`` (the scan kernel's output and the states
+    entering its chunks, once a state-space layer).  What they add to the
+    compiled step's live bytes is at most this: the compiler reuses."""
     from learning_at_home_tpu.models.trunk import FLASH_RESIDUALS
 
     return sum(
         _bytes(eqn.outvars[0].aval) for _, eqn in _equations(jaxpr, "name")
-        if eqn.params["name"] == FLASH_RESIDUALS
+        if eqn.params["name"] == (name or FLASH_RESIDUALS)
     )
+
+
+def scan_kernel_calls(compiled_text: str) -> dict:
+    """By name of the scan's kernels (``ssd_chunk_fwd``, ``ssd_chunk_bwd``):
+    how many instructions a compiled program's text holds (the step's
+    are ``%ssd_chunk_fwd.<n>``; a bare ``jax.grad`` of the kernel names
+    them ``%jvp_ssd_chunk_fwd_``), and how many of them carry ``ssm/scan`` in their ``op_name`` (the benchmark's scope
+    table files a call that lost the path under ``other``)."""
+    calls: dict = {}
+    for name, rest in re.findall(
+            r"^\s*%\w*?(ssd_chunk_(?:fwd|bwd))[\w.]* = [^\n]*custom-call\((.*?)(?=^\s*%|\Z)",
+            compiled_text, re.M | re.S):
+        entry = calls.setdefault(name, {"calls": 0, "under_ssm_scan": 0})
+        entry["calls"] += 1
+        op_name = re.search(r'op_name="([^"]*)"', rest)
+        entry["under_ssm_scan"] += bool(
+            op_name and re.search(r"[/(]ssm/scan[/)]", op_name.group(1)))
+    return calls
 
 
 def attention_kernel_calls(compiled_text: str) -> dict:
@@ -148,7 +180,9 @@ def step_memory(chip, recipe: str = "smallthinker_one_chip") -> dict:
     many instructions of each of the kernel's names the compiled step
     holds (one forward a kernel layer since PR 38: remat keeps the
     kernel's residuals), under ``kept_residual_bytes`` what that costs
-    (:func:`kept_residual_bytes`), and under ``loss_layer_products`` how
+    (:func:`kept_residual_bytes`), under ``scan_kernel_calls`` and
+    ``kept_scan_bytes`` the same two for the state-space scan's kernels
+    (:func:`scan_kernel_calls`; PR 40), and under ``loss_layer_products`` how
     many of its fusions under scope ``ce`` are matmuls (the logits' einsum
     and its transposes: the head's products)."""
     import jax
@@ -157,6 +191,7 @@ def step_memory(chip, recipe: str = "smallthinker_one_chip") -> dict:
     from jax.sharding import Mesh
 
     import __graft_entry__
+    from learning_at_home_tpu.ops.ssd import SSD_RESIDUALS
     from learning_at_home_tpu.parallel.mesh import (
         batch_sharding,
         opt_state_shardings,
@@ -204,6 +239,8 @@ def step_memory(chip, recipe: str = "smallthinker_one_chip") -> dict:
         "attention_kernel_tilings": attention_kernel_tilings(traced.jaxpr.jaxpr),
         "attention_kernel_calls": attention_kernel_calls(text),
         "kept_residual_bytes": kept_residual_bytes(traced.jaxpr.jaxpr),
+        "scan_kernel_calls": scan_kernel_calls(text),
+        "kept_scan_bytes": kept_residual_bytes(traced.jaxpr.jaxpr, SSD_RESIDUALS),
         "loss_layer_products": len(re.findall(
             r'^\s*%\S+ = [^\n]* fusion\([^\n]*'
             r'op_name="[^"\n]*[/(]ce[/)][^"\n]*dot_general"', text, re.M)),
@@ -275,9 +312,81 @@ def float8(seeds: list, config_path: str = CONFIG) -> None:
         del params
 
 
+def ssd(seq_len: int = 16384, accuracy_len: int = 4096, calls: int = 10) -> None:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    import harness
+    from learning_at_home_tpu.ops import ssd as ops
+
+    config = harness.load_json(os.path.join(
+        REPO, "benchmarks/configs/nemotron-labs-twotower-30b-a3b.json"))
+    h, p = config["mamba_num_heads"], config["mamba_head_dim"]
+    g, n, chunk = config["n_groups"], config["ssm_state_size"], config["chunk_size"]
+    f32 = jnp.float32
+
+    def inputs(s, dtype):
+        rs = np.random.default_rng(4000000007)
+        dt = np.exp(rs.uniform(np.log(config["time_step_min"]),
+                               np.log(config["time_step_max"]), (1, s, h)))
+        return (jnp.asarray(rs.standard_normal((1, s, h, p)), dtype),
+                jnp.asarray(dt, f32), -jnp.asarray(rs.uniform(1, 16, h), f32),
+                jnp.asarray(0.5 * rs.standard_normal((1, s, g, n)), dtype),
+                jnp.asarray(0.5 * rs.standard_normal((1, s, g, n)), dtype))
+
+    def loss_of(form, s):
+        rs = np.random.default_rng(40)
+        wy = jnp.asarray(rs.standard_normal((1, s, h, p)), f32)
+        wf = jnp.asarray(rs.standard_normal((1, h, p, n)), f32)
+
+        def loss(*args):
+            y, final = form(*args, chunk)
+            return jnp.sum(y.astype(f32) * wy) + jnp.sum(final * wf)
+        return loss
+
+    def ms(fn, args):
+        jax.block_until_ready(fn(*args))
+        t0 = time.perf_counter()
+        for _ in range(calls):
+            out = fn(*args)
+        jax.block_until_ready(out)
+        return 1e3 * (time.perf_counter() - t0) / calls
+
+    def rel(got, want):
+        got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+        return float(np.sqrt(np.mean((got - want) ** 2) / np.mean(want ** 2)))
+
+    forms = {"plain": ops.ssd_chunked_plain, "kernel": ops.ssd_chunked_kernel}
+    names = ("y", "state", "dx", "ddt", "dA", "dB", "dC")
+
+    def everything(form, s):
+        return jax.jit(lambda *a: (
+            *form(*a, chunk),
+            *jax.grad(loss_of(form, s), argnums=(0, 1, 2, 3, 4))(*a)))
+
+    with jax.default_matmul_precision("highest"):
+        want = jax.device_get(everything(ops.ssd_chunked_plain, accuracy_len)(
+            *inputs(accuracy_len, f32)))
+    for name, form in forms.items():
+        got = jax.device_get(everything(form, accuracy_len)(
+            *inputs(accuracy_len, jnp.bfloat16)))
+        args = inputs(seq_len, jnp.bfloat16)
+        print("SSD " + json.dumps({
+            "form": name, "seq_len": seq_len, "accuracy_len": accuracy_len,
+            "forward_ms": ms(jax.jit(lambda *a, f=form: f(*a, chunk)), args),
+            "forward_backward_ms": ms(jax.jit(jax.grad(
+                loss_of(form, seq_len), argnums=(0, 1, 2, 3, 4))), args),
+            "rms_against_float32": {
+                k: rel(a, b) for k, a, b in zip(names, got, want)},
+        }), flush=True)
+
+
 if __name__ == "__main__":
     if sys.argv[1:2] == ["memory"]:
         memory(*sys.argv[2:3])
+    elif sys.argv[1:2] == ["ssd"]:
+        ssd(*(int(a) for a in sys.argv[2:4]))
     elif sys.argv[1:2] == ["float8"]:
         named = [a for a in sys.argv[2:] if a.endswith(".json")]
         float8([int(s) for s in sys.argv[2:] if s not in named] or [3100000007],
