@@ -13,6 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from learning_at_home_tpu.ops.ssd import ssd_chunked
+from learning_at_home_tpu.ops.ssm_conv import causal_conv_silu
 
 
 def layer_norm(p: dict, x: jax.Array, eps: float = 1e-5) -> jax.Array:
@@ -175,9 +176,10 @@ def ssm_mixer(
     ``[z | xBC | dt] = u W_in`` (no bias); ``xBC = silu(conv(xBC))``, a
     causal depthwise convolution with bias over the channels (``xBC[t] =
     b + sum_j w[:, j] xBC_in[t - (K - 1) + j]``, zeros before the
-    sequence); ``x`` [S, H, P], ``B`` and ``C`` [S, G, N] its three parts,
-    head ``h`` reading group ``h // (H / G)``; ``dt = softplus(dt +
-    dt_bias)``; ``A = -exp(A_log)``; the recurrence ``h_t = exp(dt_t A)
+    sequence: :func:`~learning_at_home_tpu.ops.ssm_conv.causal_conv_silu`,
+    one call for each of the three parts); ``x`` [S, H, P], ``B`` and ``C``
+    [S, G, N] its three parts, head ``h`` reading group ``h // (H / G)``;
+    ``dt = softplus(dt + dt_bias)``; ``A = -exp(A_log)``; the recurrence ``h_t = exp(dt_t A)
     h_{t-1} + dt_t x_t B_t^T``, ``y_t = h_t C_t + D x_t`` in chunks of
     ``chunk`` (:func:`~learning_at_home_tpu.ops.ssd.ssd_chunked`); ``y =
     RMSNorm(y * silu(z))``, the gate FIRST, then the norm over each of the
@@ -191,26 +193,27 @@ def ssm_mixer(
     f32 = jnp.float32
     d_inner = p["w_out"].shape[0]
     head_dim = d_inner // n_heads
-    conv_dim, taps = p["conv_w"].shape
+    conv_dim = p["conv_w"].shape[0]
     n_state = (conv_dim - d_inner) // (2 * n_groups)
     with jax.named_scope("in_proj"):
         zxbcdt = u @ p["w_in"].astype(u.dtype)
         z = zxbcdt[..., :d_inner]
-        xbc = zxbcdt[..., d_inner:d_inner + conv_dim]
         dt = zxbcdt[..., d_inner + conv_dim:]
-    with jax.named_scope("conv"):
-        padded = jnp.pad(xbc.astype(f32), ((0, 0), (taps - 1, 0), (0, 0)))
-        w = p["conv_w"].astype(f32)
-        xbc = jax.nn.silu(p["conv_b"].astype(f32) + sum(
-            w[:, j] * padded[:, j:j + s] for j in range(taps)
-        )).astype(u.dtype)
+    with jax.named_scope("conv"):  # of x, B and C apart (a channel at a
+        # time, so the same numbers): each is read where the product left it
+        # and written as the array the scan takes, and nothing is sliced
+        edges = (0, d_inner, d_inner + n_groups * n_state, conv_dim)
+        x, b_in, c_out = (
+            causal_conv_silu(zxbcdt, p["conv_w"][lo:hi], p["conv_b"][lo:hi],
+                             first=d_inner + lo)
+            for lo, hi in zip(edges, edges[1:]))
     with jax.named_scope("scan"):
-        x = xbc[..., :d_inner].reshape(b, s, n_heads, head_dim)
-        bc = xbc[..., d_inner:].reshape(b, s, 2, n_groups, n_state)
+        x = x.reshape(b, s, n_heads, head_dim)
         dt = jax.nn.softplus(dt.astype(f32) + p["dt_bias"].astype(f32))
         a = -jnp.exp(p["A_log"].astype(f32))
         y, state = ssd_chunked(
-            x, dt, a, bc[:, :, 0], bc[:, :, 1], chunk, decay_dtype)
+            x, dt, a, b_in.reshape(b, s, n_groups, n_state),
+            c_out.reshape(b, s, n_groups, n_state), chunk, decay_dtype)
         y = y.astype(f32) + p["D"].astype(f32)[:, None] * x.astype(f32)
         decay_min = jnp.exp(jnp.min(dt * a))
     with jax.named_scope("gate_norm"):

@@ -35,6 +35,7 @@ from learning_at_home_tpu.models.transformer import (  # noqa: E402
 )
 from learning_at_home_tpu.ops import moe_dispatch  # noqa: E402
 from learning_at_home_tpu.ops import ssd  # noqa: E402
+from learning_at_home_tpu.ops import ssm_conv  # noqa: E402
 from learning_at_home_tpu.ops.ssd import ssd_chunked  # noqa: E402
 from learning_at_home_tpu.parallel.mesh import make_mesh  # noqa: E402
 from learning_at_home_tpu.parallel.sharded_moe import ShardedMixtureOfExperts  # noqa: E402
@@ -753,8 +754,9 @@ def test_benchmark_manifests_pass_selfcheck_and_the_runner_rehearses(tmp_path):
     rate = harness.by_name(manifest["end_to_end"], "train_tokens_per_s_per_chip", "metric")
     assert CELL in rate["workloads"]
     reported = [m["name"] for m in harness.metrics_of_cell(manifest["per_layer"], CELL)]
-    assert len(reported) == 19 and all(n.startswith("nemotron.") for n in reported)
+    assert len(reported) == 20 and all(n.startswith("nemotron.") for n in reported)
     assert {"nemotron.ssm_share", "nemotron.ssm_scan_share", "nemotron.ssm_proj_share",
+            "nemotron.ssm_conv_share",
             "nemotron.ssm_scan_roofline", "nemotron.attention_core_roofline",
             "nemotron.expert_matmul_roofline"} <= set(reported)
     for trace in ("0", "1"):
@@ -853,6 +855,14 @@ def test_the_whole_step_fits_the_chip(v5e_chip, monkeypatch):
         "ssd_chunk_bwd": {"calls": 4, "under_ssm_scan": 4}}
     assert memory["kept_scan_bytes"] == 4 * (
         16384 * 4096 * 2 + 128 * 64 * 64 * 128 * 4)
+    # the convolution's one pass forward, recomputed (remat keeps nothing
+    # of it) and backward, for each of ``x``, ``B`` and ``C`` of a
+    # state-space layer, every call under ``ssm/conv``, and no float32 copy
+    # of ``x B C`` or of a part written there (PR 41)
+    assert memory["conv_kernel_calls"] == {
+        "ssm_conv_fwd": {"calls": 24, "under_ssm_conv": 24},
+        "ssm_conv_bwd": {"calls": 12, "under_ssm_conv": 12}}
+    assert memory["float32_arrays_under_ssm_conv"] == []
 
 
 def test_the_scan_kernels_compile_for_the_chip_at_the_cells_shape(v5e_chip):
@@ -883,3 +893,40 @@ def test_the_scan_kernels_compile_for_the_chip_at_the_cells_shape(v5e_chip):
             *args).compile().as_text()
     assert {name: c["calls"] for name, c in probe.scan_kernel_calls(text).items()} == {
         "ssd_chunk_fwd": 1, "ssd_chunk_bwd": 1}
+
+
+def test_the_convolutions_kernels_compile_for_the_chip_at_the_cells_shape(v5e_chip):
+    """``ssm_conv_fwd`` and ``ssm_conv_bwd`` as the cell's mixer calls them,
+    compiled for a described chip (nothing runs): ``x`` (4,096 channels),
+    ``B`` and ``C`` (1,024 each) read out of the in-projection's
+    ``[1, 16384, 10304]`` bf16 where they lie, four taps: Mosaic takes the
+    blocks at their offsets, the halos' tiles, the rolls along the sublanes
+    and the VMEM the two scratches ask for."""
+    one = jax.sharding.SingleDeviceSharding(v5e_chip)
+    s, taps = CELL_FILE["seq_len"], CELL_FILE["conv_kernel"]
+    d_inner = CELL_FILE["mamba_num_heads"] * CELL_FILE["mamba_head_dim"]
+    group = CELL_FILE["n_groups"] * CELL_FILE["ssm_state_size"]
+    wide = 2 * d_inner + 2 * group + CELL_FILE["mamba_num_heads"]
+    parts = [(d_inner, d_inner), (2 * d_inner, group), (2 * d_inner + group, group)]
+    assert (s, taps, wide, parts) == (
+        16384, 4, 10304, [(4096, 4096), (8192, 1024), (9216, 1024)])
+    assert all(ssm_conv.conv_kernel_fits((1, s, c), taps, "tpu", first)
+               for first, c in parts)
+
+    def shaped(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    def loss(zxbcdt, w, b):  # the square: its cotangent reads the forward's result
+        lo = d_inner
+        return sum(jnp.sum(ssm_conv.causal_conv_silu_kernel(
+            zxbcdt, w[first - lo:first - lo + c], b[first - lo:first - lo + c],
+            first).astype(jnp.float32) ** 2) for first, c in parts)
+
+    c = d_inner + 2 * group
+    with probe.no_compile_cache():
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            shaped((1, s, wide), jnp.bfloat16), shaped((c, taps), jnp.bfloat16),
+            shaped((c,), jnp.bfloat16)).compile().as_text()
+    calls = probe.scan_kernel_calls(text, "ssm_conv", "ssm/conv")
+    assert {name: entry["calls"] for name, entry in calls.items()} == {
+        "ssm_conv_fwd": 3, "ssm_conv_bwd": 3}

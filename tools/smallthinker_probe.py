@@ -6,6 +6,7 @@
     python tools/smallthinker_probe.py memory [recipe]
     chiprun -- python tools/smallthinker_probe.py float8 [config.json] [seed ...]
     chiprun -- python tools/smallthinker_probe.py ssd [seq_len] [accuracy_len]
+    chiprun -- python tools/smallthinker_probe.py conv [rows x channels ...]
 
 ``memory`` (here, no chip): the whole train step of a recipe of
 ``__graft_entry__`` (``smallthinker_one_chip``, or the one named, such as
@@ -43,6 +44,16 @@ against the kernel: milliseconds a call forward and forward + backward
 every intermediate) the relative rms of each form's output, last state
 and five gradients against the plain form in float32 at the highest
 matmul precision (PERF.md section 6, PR 40).
+
+``conv`` (on the chip): the mixer's causal convolution with its SiLU
+(``ops/ssm_conv.py``) at the Nemotron cell's shape (``[1, 16384, 6144]``
+bf16, four taps), the plain form against the kernel (``ssm_conv_fwd`` /
+``ssm_conv_bwd``): milliseconds a call forward and forward + backward (a
+``jax.vjp`` under a given bf16 cotangent: no loss of the probe's own in
+the time), GB/s on the LEAST bytes a pass moves (``x`` in and ``y`` out;
+``x`` and ``dy`` in and ``dx`` out), and the kernel's output and three
+gradients against the plain form's.  ``512x1024``-like arguments time the
+kernel at those blocks (rows x channels) too (PERF.md section 6, PR 41).
 """
 
 import collections
@@ -114,21 +125,25 @@ def kept_residual_bytes(jaxpr, name: str | None = None) -> int:
     )
 
 
-def scan_kernel_calls(compiled_text: str) -> dict:
-    """By name of the scan's kernels (``ssd_chunk_fwd``, ``ssd_chunk_bwd``):
-    how many instructions a compiled program's text holds (the step's
-    are ``%ssd_chunk_fwd.<n>``; a bare ``jax.grad`` of the kernel names
-    them ``%jvp_ssd_chunk_fwd_``), and how many of them carry ``ssm/scan`` in their ``op_name`` (the benchmark's scope
-    table files a call that lost the path under ``other``)."""
+def scan_kernel_calls(compiled_text: str, kernels: str = "ssd_chunk",
+                      scope: str = "ssm/scan") -> dict:
+    """By name of the scan's kernels (``ssd_chunk_fwd``, ``ssd_chunk_bwd``;
+    or another pair's, such as the convolution's ``ssm_conv`` under
+    ``ssm/conv``): how many instructions a compiled program's text holds
+    (the step's are ``%ssd_chunk_fwd.<n>``; a bare ``jax.grad`` of the
+    kernel names them ``%jvp_ssd_chunk_fwd_``), and how many of them carry
+    the scope in their ``op_name`` (the benchmark's scope table files a
+    call that lost the path under ``other``)."""
     calls: dict = {}
+    under = "under_" + scope.replace("/", "_")
     for name, rest in re.findall(
-            r"^\s*%\w*?(ssd_chunk_(?:fwd|bwd))[\w.]* = [^\n]*custom-call\((.*?)(?=^\s*%|\Z)",
+            rf"^\s*%\w*?({kernels}_(?:fwd|bwd))[\w.]* = [^\n]*custom-call\((.*?)(?=^\s*%|\Z)",
             compiled_text, re.M | re.S):
-        entry = calls.setdefault(name, {"calls": 0, "under_ssm_scan": 0})
+        entry = calls.setdefault(name, {"calls": 0, under: 0})
         entry["calls"] += 1
         op_name = re.search(r'op_name="([^"]*)"', rest)
-        entry["under_ssm_scan"] += bool(
-            op_name and re.search(r"[/(]ssm/scan[/)]", op_name.group(1)))
+        entry[under] += bool(
+            op_name and re.search(rf"[/(]{scope}[/)]", op_name.group(1)))
     return calls
 
 
@@ -182,7 +197,11 @@ def step_memory(chip, recipe: str = "smallthinker_one_chip") -> dict:
     kernel's residuals), under ``kept_residual_bytes`` what that costs
     (:func:`kept_residual_bytes`), under ``scan_kernel_calls`` and
     ``kept_scan_bytes`` the same two for the state-space scan's kernels
-    (:func:`scan_kernel_calls`; PR 40), and under ``loss_layer_products`` how
+    (:func:`scan_kernel_calls`; PR 40), under ``conv_kernel_calls`` the
+    convolution's kernels under ``ssm/conv`` and under
+    ``float32_arrays_under_ssm_conv`` the float32 ``[1, S (+ 3), C]``
+    arrays, ``C`` the channels of ``x B C`` or of one of the three, that
+    scope still writes (none: PR 41), and under ``loss_layer_products`` how
     many of its fusions under scope ``ce`` are matmuls (the logits' einsum
     and its transposes: the head's products)."""
     import jax
@@ -240,6 +259,10 @@ def step_memory(chip, recipe: str = "smallthinker_one_chip") -> dict:
         "attention_kernel_calls": attention_kernel_calls(text),
         "kept_residual_bytes": kept_residual_bytes(traced.jaxpr.jaxpr),
         "scan_kernel_calls": scan_kernel_calls(text),
+        "conv_kernel_calls": scan_kernel_calls(text, "ssm_conv", "ssm/conv"),
+        "float32_arrays_under_ssm_conv": sorted(set(re.findall(
+            r'^\s*%\S+ = (f32\[1,1638[47],(?:6144|4096|1024)\])[^\n]*op_name="[^"\n]*ssm/conv',
+            text, re.M))),
         "kept_scan_bytes": kept_residual_bytes(traced.jaxpr.jaxpr, SSD_RESIDUALS),
         "loss_layer_products": len(re.findall(
             r'^\s*%\S+ = [^\n]* fusion\([^\n]*'
@@ -382,9 +405,79 @@ def ssd(seq_len: int = 16384, accuracy_len: int = 4096, calls: int = 10) -> None
         }), flush=True)
 
 
+def conv(blocks: list, calls: int = 20) -> None:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    import harness
+    from learning_at_home_tpu.ops import ssm_conv as ops
+
+    config = harness.load_json(os.path.join(
+        REPO, "benchmarks/configs/nemotron-labs-twotower-30b-a3b.json"))
+    s, taps = config["seq_len"], config["conv_kernel"]
+    c = (config["mamba_num_heads"] * config["mamba_head_dim"]
+         + 2 * config["n_groups"] * config["ssm_state_size"])
+    rs = np.random.default_rng(4100000007)
+    x = jnp.asarray(rs.standard_normal((1, s, c)), jnp.bfloat16)
+    dy = jnp.asarray(rs.standard_normal((1, s, c)), jnp.bfloat16)
+    w = jnp.asarray(0.5 * rs.standard_normal((c, taps)), jnp.float32)
+    b = jnp.asarray(0.1 * rs.standard_normal(c), jnp.float32)
+
+    def ms(fn, *args):
+        jax.block_until_ready(fn(*args))
+        t0 = time.perf_counter()
+        for _ in range(calls):
+            out = fn(*args)
+        jax.block_until_ready(out)
+        return 1e3 * (time.perf_counter() - t0) / calls
+
+    def rel(got, want):
+        got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+        return float(np.sqrt(np.mean((got - want) ** 2) / np.mean(want ** 2)))
+
+    def both(form):
+        def fn(x, w, b, dy):
+            y, back = jax.vjp(form, x, w, b)
+            return (y, *back(dy))
+        return jax.jit(fn)
+
+    least = x.size * x.dtype.itemsize  # one pass over [1, S, C] bf16
+    want = None
+    forms = [("plain", ops.causal_conv_silu_plain, None), ("kernel",
+             ops.causal_conv_silu_kernel, (ops._ROWS, ops._CHANNELS))] + [
+        ("kernel", ops.causal_conv_silu_kernel, tuple(int(n) for n in a.split("x")))
+        for a in blocks]
+    for name, form, at in forms:
+        if at:
+            ops._ROWS, ops._CHANNELS = at
+        try:
+            got = jax.device_get(both(form)(x, w, b, dy))
+            forward = ms(jax.jit(form), x, w, b)
+            forward_backward = ms(both(form), x, w, b, dy)
+        except Exception as e:  # a block the compiler refuses
+            print("CONV " + json.dumps({"form": name, "blocks": at,
+                                        "refused": str(e)[:300]}), flush=True)
+            continue
+        want = want or got
+        print("CONV " + json.dumps({
+            "form": name, "blocks": at, "shape": list(x.shape), "taps": taps,
+            "forward_ms": forward, "forward_backward_ms": forward_backward,
+            "forward_gb_s_on_least_bytes": 2 * least / forward / 1e6,
+            "forward_backward_gb_s_on_least_bytes":
+                5 * least / forward_backward / 1e6,
+            "y_max_abs_against_plain": float(np.max(np.abs(
+                np.asarray(got[0], np.float32) - np.asarray(want[0], np.float32)))),
+            "rms_against_plain": {
+                k: rel(a, b_) for k, a, b_ in zip(("y", "dx", "dw", "db"), got, want)},
+        }), flush=True)
+
+
 if __name__ == "__main__":
     if sys.argv[1:2] == ["memory"]:
         memory(*sys.argv[2:3])
+    elif sys.argv[1:2] == ["conv"]:
+        conv(sys.argv[2:])
     elif sys.argv[1:2] == ["ssd"]:
         ssd(*(int(a) for a in sys.argv[2:4]))
     elif sys.argv[1:2] == ["float8"]:
