@@ -527,9 +527,8 @@ def dispatch_worker() -> None:
         port = int(os.environ["BENCH_DISPATCH_PORT"])
     else:
         # a fixed default port made two concurrent bench runs collide on
-        # one box (the second silently lost the large-dispatch fields —
-        # ADVICE.md): grab a free ephemeral port and hand THAT to the
-        # server instead
+        # one box (the second silently lost the large-dispatch fields):
+        # grab a free ephemeral port and hand THAT to the server instead
         import socket
 
         with socket.socket() as s:

@@ -2224,6 +2224,6 @@ class RemoteMixtureOfExperts:
             # explicit marker (NOT an elapsed-time heuristic): the pool
             # folds the straggler's elapsed wait into its RTT EMA however
             # short the configured grace period, while unmarked teardown
-            # cancels are never mistaken for slowness (ADVICE r5 item 3)
+            # cancels are never mistaken for slowness
             task.cancel(msg=QUORUM_STRAGGLER_CANCEL)
         return results

@@ -19,7 +19,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Callable
+from typing import Any, Callable, ClassVar
 
 import jax
 import jax.numpy as jnp
@@ -80,32 +80,18 @@ class DMoETransformerConfig:
     # sequence-layout equivalence; trainers opt in (train_lm
     # --router-jitter).
     router_jitter: float = 0.0
-    # 'xla' = jax.nn.dot_product_attention (materializes [B,H,S,S]);
-    # 'flash' = the TPU Pallas blocked kernel (splash attention under a
-    # causal mask: O(S) memory) at the tiles trunk.flash_block_sizes gives
-    # for the call's shape; a call the kernel cannot take (not a TPU, a
-    # length its tiles do not divide) runs 'xla'; on a mesh of several
-    # devices Mosaic refuses the step (no shard_map around the kernel);
-    # 'auto' = auto_attn_impl below.
-    attn_impl: str = "auto"
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
     remat: bool = False
-    # True: lax.scan over stacked layer params (ONE compiled layer body —
-    # HLO size and compile time ÷ L).  False: unrolled Python loop over
-    # static slices of the SAME stacked params — L inlined bodies, but
-    # the backward builds the stacked grad with pad+add chains XLA can
-    # simplify instead of scan's per-iteration dynamic-update-slice
-    # writes into a zero-initialized param-sized buffer (measured ~13
-    # ms/step of pure HBM traffic at the 2.15 B-param flagship).
-    scan_layers: bool = True
-    # True: layer params live as ONE stacked pytree (leading n_layers dim
-    # on every leaf) — required by scan_layers.  False: a tuple of
-    # per-layer pytrees; with the unrolled loop the layers consume their
-    # leaves directly, so the per-step slice-out copies of the stacked
-    # layout (~13 ms at the 2.15 B-param flagship: remat saves the
-    # sliced layer params as residuals) disappear.
-    stack_layers: bool = True
+    # Not options: the stack has ONE layout, a tuple of per-layer trees run
+    # by the unrolled loop.  The two names are what the CFG_FIELDS tables of
+    # benchmarks/runners/{train_step,train_recipe,train_recipe_blocks,
+    # train_recipe_share,train_recipe_latent,train_recipe_hybrid}.py read
+    # off a config and compare with their files' `false`, their only
+    # readers; ROADMAP.md Speed 6(f) drops the keys from the benchmark's
+    # files, and then these two lines go.
+    scan_layers: ClassVar[bool] = False
+    stack_layers: ClassVar[bool] = False
     tie_embeddings: bool = True
     # sequence/context parallelism: attention runs as a ring over the
     # mesh's 'seq' axis (parallel/ring_attention.py).  The MoE stays
@@ -278,9 +264,13 @@ FLASH_MIN_SEQ_LEN = 512
 def auto_attn_impl(
     backend: str, n_devices: int, seq_len: int, head_dim: int
 ) -> str:
-    """What ``attn_impl="auto"`` resolves to, from the backend, the mesh
-    and the training shape alone: the blocked kernel where it can run and
-    the sequence is long enough for it to win.  It can run on the ``tpu``
+    """The attention core a model runs (``DMoETransformerLM.attn_impl``),
+    from the backend, the mesh and the training shape alone.  ``'flash'``:
+    the TPU Pallas blocked kernel (splash attention under a causal mask,
+    O(S) memory) at the tiles ``trunk.flash_block_sizes`` gives for the
+    call's shape, where it can run and the sequence is long enough for it
+    to win.  ``'xla'``: ``jax.nn.dot_product_attention`` (materializes
+    [B,H,S,S]), everywhere else.  The kernel can run on the ``tpu``
     backend specifically, not merely "not cpu" (Mosaic lowering), at
     tiles that divide the length, and on a mesh of ONE device: Mosaic
     refuses to partition a kernel over a mesh ("wrap the call in a
@@ -296,15 +286,6 @@ class DMoETransformerLM:
     """Functional model: explicit param pytree, jit/pjit-friendly apply."""
 
     def __init__(self, config: DMoETransformerConfig, mesh: Mesh):
-        if config.attn_impl == "auto":
-            config = dataclasses.replace(
-                config,
-                attn_impl=auto_attn_impl(
-                    jax.default_backend(), mesh.devices.size,
-                    config.seq_len,
-                    config.head_dim or config.d_model // config.n_heads,
-                ),
-            )
         if config.norm not in ("layernorm", "rmsnorm"):
             raise ValueError(
                 f"norm must be 'layernorm' or 'rmsnorm', got {config.norm!r}"
@@ -313,11 +294,6 @@ class DMoETransformerLM:
             raise ValueError(
                 f"positions must be 'learned' or 'rope', got "
                 f"{config.positions!r}"
-            )
-        if config.scan_layers and not config.stack_layers:
-            raise ValueError(
-                "scan_layers=True requires stack_layers=True (lax.scan "
-                "consumes the stacked param pytree)"
             )
         if config.router_input not in ("moe_input", "attention_input"):
             raise ValueError(
@@ -336,13 +312,6 @@ class DMoETransformerLM:
                     "layer_pattern rotates a layer's queries and keys: "
                     "positions must be 'rope'"
                 )
-        if config.scan_layers and len(kinds) > 1:
-            raise ValueError(
-                f"scan_layers=True runs ONE traced body for every layer "
-                f"(lax.scan); this layer_pattern has {len(kinds)} kinds of "
-                "layer, and a window or a rotation is part of the traced "
-                "program: run the unrolled loop (scan_layers=False)"
-            )
         if config.qk_norm not in (False, True, "head"):
             raise ValueError(
                 f"qk_norm must be False, True or 'head', got {config.qk_norm!r}"
@@ -398,13 +367,6 @@ class DMoETransformerLM:
                     "no next-but-one-token block built of such layers "
                     "(mtp_layers)"
                 )
-            if config.scan_layers or config.stack_layers:
-                raise ValueError(
-                    "mixer_pattern: layers of one mixer each differ in "
-                    "their parameters and their program, which neither one "
-                    "stacked tree nor ONE traced body holds "
-                    "(scan_layers=False, stack_layers=False)"
-                )
             if config.seq_parallel:
                 raise NotImplementedError(
                     "seq_parallel=True (ring attention, parallel/"
@@ -413,13 +375,6 @@ class DMoETransformerLM:
                     "ring's sequence shards, and nothing carries a state "
                     "from shard to shard"
                 )
-        if len(ffns) > 1 and (config.scan_layers or config.stack_layers):
-            raise ValueError(
-                "ffn_pattern mixes dense and mixture layers, whose "
-                "parameters differ: neither one stacked tree nor ONE "
-                "traced body holds them (scan_layers=False, "
-                "stack_layers=False)"
-            )
         latent = (config.kv_latent_dim, config.q_latent_dim, config.rope_head_dim)
         if any(size is not None for size in latent):
             if None in latent or config.head_dim is None:
@@ -445,13 +400,6 @@ class DMoETransformerLM:
             raise ValueError(
                 f"mtp_layers must be 0 or 1, got {config.mtp_layers}: one "
                 "block that predicts the next-but-one token is described"
-            )
-        if config.mtp_layers and (config.scan_layers or config.stack_layers):
-            raise ValueError(
-                "mtp_layers: the next-but-one-token block is one more "
-                "layer with parameters of its own after the stack, which "
-                "neither one stacked tree nor ONE traced body holds "
-                "(scan_layers=False, stack_layers=False)"
             )
         n_kv = config.n_kv_heads or config.n_heads
         if config.n_heads % n_kv:
@@ -488,6 +436,10 @@ class DMoETransformerLM:
             )
         self.cfg = config
         self.mesh = mesh
+        self.attn_impl = auto_attn_impl(
+            jax.default_backend(), mesh.devices.size, config.seq_len,
+            config.head_dim or config.d_model // config.n_heads,
+        )
         # compiled decoders (one per decode path) + the memoized
         # eval-routing twin (see generate / decode_model): without these,
         # every generate() call re-traces its whole decode loop — measured
@@ -549,10 +501,9 @@ class DMoETransformerLM:
     # ---- parameters ----
 
     def init_params(self, rng: jax.Array) -> Params:
-        """Layer params are STACKED (leading ``n_layers`` dim on every
-        leaf) and the forward scans over them — one compiled layer body
-        instead of ``n_layers`` inlined copies, which divides HLO size and
-        compile time by ~L for the 256-expert flagship."""
+        """``params["layers"]`` is a tuple of per-layer trees, one a
+        layer, which the forward's unrolled loop reads leaf by leaf; what
+        a layer's tree holds says what the layer is."""
         cfg = self.cfg
         d, v, s = cfg.d_model, cfg.vocab_size, cfg.seq_len
         hd = cfg.head_dim or d // cfg.n_heads
@@ -684,9 +635,7 @@ class DMoETransformerLM:
             ),
             "ln_f": ln(),
             "layers": (
-                jax.vmap(init_layer)(layer_keys)
-                if cfg.stack_layers
-                else tuple(map(init_mixer_layer, layer_keys, cfg.mixer_pattern))
+                tuple(map(init_mixer_layer, layer_keys, cfg.mixer_pattern))
                 if cfg.mixer_pattern is not None
                 else tuple(init_layer(k, f) for k, f in zip(layer_keys, ffn_of))
             ),
@@ -704,9 +653,8 @@ class DMoETransformerLM:
         return jax.device_put(params, self.param_shardings(params))
 
     def param_shardings(self, params_shape: Params) -> Params:
-        """Replicated everywhere except the expert stacks (whose specs gain
-        a leading ``None`` for the stacked layer dim when stack_layers)."""
-        stacked_moe = self.moe.param_shardings(stacked=self.cfg.stack_layers)
+        """Replicated everywhere except the expert stacks."""
+        moe = self.moe.param_shardings()
         repl = NamedSharding(self.mesh, P())
 
         def assign(path, leaf):
@@ -714,7 +662,7 @@ class DMoETransformerLM:
                 name = getattr(p, "key", getattr(p, "name", None))
                 if name == "moe":
                     inner = path[-1]
-                    return stacked_moe[getattr(inner, "key", None)]
+                    return moe[getattr(inner, "key", None)]
             return repl
 
         return jax.tree_util.tree_map_with_path(assign, params_shape)
@@ -739,12 +687,10 @@ class DMoETransformerLM:
             rope_theta=self.cfg.rope_theta, norm_eps=self.cfg.norm_eps,
         )
 
-    def _layer(self, lp, x, layer_idx, token_mask=None,
-               kind: AttentionLayer | None = None):
-        """One block.  ``kind`` (static) is the layer's attention where
-        the stack's layers differ; None = layer 0's, which every layer of
-        a uniform stack shares (``layer_idx`` may then be traced).
-        Returns ``(x, aux)``; ``aux`` is None for a dense layer."""
+    def _layer(self, lp, x, layer_idx, token_mask, kind: AttentionLayer):
+        """One block.  ``kind`` (static) is the layer's attention,
+        ``cfg.attention_layer(layer_idx)``.  Returns ``(x, aux)``; ``aux``
+        is None for a dense layer."""
         one_mixer = "norm" in lp  # what the layer holds says what it is
         if "ssm" in lp:
             return self._ssm_block(lp, x)
@@ -766,11 +712,9 @@ class DMoETransformerLM:
             )
         return x + out, {"ssm_decay_min": decay_min}
 
-    def _attention_block(self, lp, x, kind: AttentionLayer | None = None):
+    def _attention_block(self, lp, x, kind: AttentionLayer):
         """The stream after the layer's attention, and the normalized
         input the attention read (a router placed before it reads that)."""
-        if kind is None:
-            kind = self.cfg.attention_layer(0)
         s = x.shape[1]
         # where a stack has both kinds, the scope says which this one is
         scope = "attention" if self.cfg.layer_pattern is None else (
@@ -787,7 +731,7 @@ class DMoETransformerLM:
             )
             core = self._ring if self._ring is not None else (
                 lambda q, k, v: attention_core(
-                    q, k, v, self.cfg.attn_impl, kind.window
+                    q, k, v, self.attn_impl, kind.window
                 )
             )
             x = x + output_projection(lp, core(q, k, v))
@@ -870,13 +814,6 @@ class DMoETransformerLM:
                 ),
             )
 
-        def body(x, lp_idx):
-            lp, idx = lp_idx
-            with jax.named_scope("layer"):  # one body for every layer
-                x, aux = layer_fn(lp, x, idx, token_mask, None)
-            return x, aux
-
-        decay_mins: list = []  # a state-space layer's least decay, each
         if self._zig is not None:
             if token_ids.shape[1] != len(self._zig):
                 raise ValueError(
@@ -890,52 +827,34 @@ class DMoETransformerLM:
             x = x[:, self._zig]
             if token_mask is not None:
                 token_mask = token_mask[:, self._zig]
-        if cfg.scan_layers:
-            # scan over the stacked layer params: ONE compiled layer body;
-            # the layer index rides along as data (it is traced, so it can
-            # still salt the router-jitter key inside the body)
-            x, aux_stack = jax.lax.scan(
-                body, x,
-                (params["layers"], jnp.arange(cfg.n_layers, dtype=jnp.int32)),
+        aux_total = None
+        counts = []
+        decay_mins: list = []  # a state-space layer's least decay, each
+
+        def add(aux):
+            """A mixture layer's aux into the stack's sums; its
+            assignments per expert stay a row of their own.  A
+            state-space layer's is its smallest decay alone."""
+            nonlocal aux_total
+            if "ssm_decay_min" in aux:
+                decay_mins.append(aux["ssm_decay_min"])
+                return
+            if "expert_counts" in aux:
+                counts.append(aux.pop("expert_counts"))
+            aux_total = (
+                aux
+                if aux_total is None
+                else {k: aux_total[k] + aux[k] for k in aux_total}
             )
-            # a mixture layer's assignments per expert stay a layer's own
-            counts = aux_stack.pop("expert_counts", None)  # [layers, E]
-            aux_total = {k: jnp.sum(v) for k, v in aux_stack.items()}
-        else:
-            # unrolled: per-layer params, either static slices of the
-            # stacked tree (same checkpoint layout as scan) or direct
-            # leaves of the unstacked tuple (no slice-out copies)
-            aux_total = None
-            counts = []
 
-            def add(aux):
-                """A mixture layer's aux into the stack's sums; its
-                assignments per expert stay a row of their own.  A
-                state-space layer's is its smallest decay alone."""
-                nonlocal aux_total
-                if "ssm_decay_min" in aux:
-                    decay_mins.append(aux["ssm_decay_min"])
-                    return
-                if "expert_counts" in aux:
-                    counts.append(aux.pop("expert_counts"))
-                aux_total = (
-                    aux
-                    if aux_total is None
-                    else {k: aux_total[k] + aux[k] for k in aux_total}
+        for i in range(cfg.n_layers):
+            with jax.named_scope(f"layer_{i}"):
+                x, aux = layer_fn(
+                    params["layers"][i], x, i, token_mask,
+                    cfg.attention_layer(i),
                 )
-
-            for i in range(cfg.n_layers):
-                lp = (
-                    jax.tree_util.tree_map(lambda l: l[i], params["layers"])
-                    if cfg.stack_layers
-                    else params["layers"][i]
-                )
-                with jax.named_scope(f"layer_{i}"):
-                    x, aux = layer_fn(
-                        lp, x, i, token_mask, cfg.attention_layer(i)
-                    )
-                if aux is not None:  # a dense layer routes nothing
-                    add(aux)
+            if aux is not None:  # a dense layer routes nothing
+                add(aux)
         if self._zig is not None:
             x = x[:, self._zig_inv]
         x = self._norm(params["ln_f"], x)
@@ -950,9 +869,7 @@ class DMoETransformerLM:
             n_moe += 1
         aux_mean = {k: v / n_moe for k, v in aux_total.items()}
         if cfg.router_bias:  # [mixture layers, E]: the balancing rule's
-            aux_mean["expert_counts"] = (
-                counts if cfg.scan_layers else jnp.stack(counts)
-            )
+            aux_mean["expert_counts"] = jnp.stack(counts)
         if decay_mins:
             # the smallest exp(dt A) the step saw: neither frozen at 1 nor
             # forgetting everything
@@ -1209,12 +1126,6 @@ class DMoETransformerLM:
 
     # ---- incremental (KV-cache) decoding ----
 
-    def _layer_params(self, params: Params, i: int):
-        """Layer i's param tree under either layout (stacked / tuple)."""
-        if self.cfg.stack_layers:
-            return jax.tree_util.tree_map(lambda l: l[i], params["layers"])
-        return params["layers"][i]
-
     @staticmethod
     def _one_query_attention(
         lp, q: jax.Array, k_cache: jax.Array, v_cache: jax.Array, t: jax.Array
@@ -1255,7 +1166,7 @@ class DMoETransformerLM:
             x = x + params["pos"][None, :p].astype(cfg.dtype)
         k_caches, v_caches = [], []
         for i in range(cfg.n_layers):
-            lp = self._layer_params(params, i)
+            lp = params["layers"][i]
             h = self._norm(lp["ln1"], x)
             q, k, v = self._qkv(
                 lp, h, np.arange(p), cfg.attention_layer(i).rotary
@@ -1263,7 +1174,7 @@ class DMoETransformerLM:
             # same impl as the full forward: the parity guarantee vs the
             # re-forward decoder must survive flash-attention configs
             x = x + output_projection(
-                lp, attention_core(q, k, v, cfg.attn_impl)
+                lp, attention_core(q, k, v, self.attn_impl)
             )
             moe_in = self._norm(lp["ln2"], x).reshape(b * p, cfg.d_model)
             moe_out, _ = self.moe(lp["moe"], moe_in, jitter_salt=i)
@@ -1296,7 +1207,7 @@ class DMoETransformerLM:
             x = x[:, None, :]  # [B, 1, d]
             k_caches, v_caches = list(k_caches), list(v_caches)
             for i in range(cfg.n_layers):
-                lp = self._layer_params(params, i)
+                lp = params["layers"][i]
                 h = self._norm(lp["ln1"], x)
                 q, k, v = self._qkv(
                     lp, h, t[None], cfg.attention_layer(i).rotary
@@ -1442,13 +1353,10 @@ class DMoETransformerLM:
     # ---- the routers' selection biases ----
 
     def _router_biases(self, params: Params) -> list | None:
-        """The mixture layers' selection biases, in order (under the
-        stacked layout the one [layers, E] array that holds them all);
-        None where the routers have none."""
+        """The mixture layers' selection biases, in order; None where
+        the routers have none."""
         if not self.cfg.router_bias:
             return None
-        if self.cfg.stack_layers:
-            return [params["layers"]["moe"]["router_bias"]]
         return [lp["moe"]["router_bias"]
                 for lp in self._routed_layers(params) if "moe" in lp]
 
@@ -1469,8 +1377,6 @@ class DMoETransformerLM:
         [mixture layers, E]."""
         if biases is None:
             return params
-        if self.cfg.stack_layers:
-            counts = [counts]
         with jax.named_scope("router_bias"):
             moved = iter([
                 balanced_bias(b, c, self.cfg.router_bias_rate)
@@ -1482,10 +1388,7 @@ class DMoETransformerLM:
                 return lp
             return {**lp, "moe": {**lp["moe"], "router_bias": next(moved)}}
 
-        layers = params["layers"]
-        params = {**params, "layers": (
-            put(layers) if self.cfg.stack_layers else tuple(map(put, layers))
-        )}
+        params = {**params, "layers": tuple(map(put, params["layers"]))}
         if "mtp" in params:
             params["mtp"] = {**params["mtp"], "layer": put(params["mtp"]["layer"])}
         return params
@@ -1500,15 +1403,10 @@ class DMoETransformerLM:
         seeded random weights a router sends a token id's every
         occurrence to the same few experts; a trained router's bias has
         levelled that, and this stands in for the training.  A set-up
-        call, outside any step; unrolled per-layer parameters only."""
+        call, outside any step."""
         cfg = self.cfg
         if not cfg.router_bias:
             return params, []
-        if cfg.stack_layers:
-            raise NotImplementedError(
-                "level_router_bias walks per-layer parameter trees "
-                "(stack_layers=False)"
-            )
         embed = jax.jit(lambda table, ids: table[ids].astype(cfg.dtype))
         attend = jax.jit(self._attention_block, static_argnums=(2,))
         finish = jax.jit(self._ffn_block)

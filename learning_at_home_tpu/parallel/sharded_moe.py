@@ -126,7 +126,6 @@ class ShardedMixtureOfExperts:
         ffn_mult: int = 4,
         dtype: Any = jnp.bfloat16,
         param_dtype: Any = jnp.float32,
-        dispatch_impl: str = "auto",
         router_jitter: float = 0.0,
         ffn_dim: int | None = None,
         expert_kind: str = "gelu",
@@ -139,11 +138,6 @@ class ShardedMixtureOfExperts:
         router_bias: bool = False,
         routed_scale: float = 1.0,
     ):
-        if dispatch_impl not in ("auto", "gather", "onehot"):
-            raise ValueError(
-                "dispatch_impl must be 'auto', 'gather' or 'onehot', "
-                f"got {dispatch_impl!r}"
-            )
         if expert_kind not in ("gelu", "gated_silu", "gated_relu", "relu2"):
             raise ValueError(
                 f"expert_kind must be 'gelu', 'gated_silu', 'gated_relu' or "
@@ -225,11 +219,6 @@ class ShardedMixtureOfExperts:
             )
         self.dtype = dtype
         self.param_dtype = param_dtype
-        # 'gather' moves tokens with index gathers/scatters (O(E*C*d) data
-        # movement); 'onehot' uses the GShard-style [n,E,C] einsums
-        # (O(n*E*C*d) MXU work); 'auto' picks per static shape via
-        # ops.moe_dispatch.choose_dispatch_impl (v5e-measured crossover).
-        self.dispatch_impl = dispatch_impl
         # deterministic multiplicative routing noise (see
         # ops.moe_dispatch.router_jitter) — breaks routing collapse when
         # many rows are near-identical (byte-level data near init)
@@ -262,8 +251,9 @@ class ShardedMixtureOfExperts:
     # ---- parameters ----
 
     def init_params(self, rng: jax.Array, device_put: bool = True) -> Params:
-        """``device_put=False`` returns the raw tree (for callers that
-        stack layers under vmap and shard the stacked result themselves)."""
+        """``device_put=False`` returns the raw tree (for a caller that
+        places it as part of a larger tree: the model's ``init_params``
+        puts the whole model on the mesh in one ``device_put``)."""
         kg, k1, k2 = jax.random.split(rng, 3)
         # e: the experts whose matrices are here (a share's G; the router
         # keeps its width)
@@ -321,21 +311,18 @@ class ShardedMixtureOfExperts:
         return {"w1": P("expert"), "b1": P("expert"),
                 "w2": P("expert"), "b2": P("expert")}
 
-    def param_specs(self, stacked: bool = False) -> dict[str, P]:
-        """PartitionSpec per param; ``stacked=True`` prepends a ``None``
-        dim for callers that stack layers of MoE params (lax.scan)."""
+    def param_specs(self) -> dict[str, P]:
+        """PartitionSpec per param."""
         specs = dict(self._expert_param_specs())
         specs["gate"] = P()
         if self.router_bias:
             specs["router_bias"] = P()
-        if stacked:
-            specs = {name: P(None, *spec) for name, spec in specs.items()}
         return specs
 
-    def param_shardings(self, stacked: bool = False) -> dict[str, NamedSharding]:
+    def param_shardings(self) -> dict[str, NamedSharding]:
         return {
             name: NamedSharding(self.mesh, spec)
-            for name, spec in self.param_specs(stacked).items()
+            for name, spec in self.param_specs().items()
         }
 
     # ---- the sharded program ----
@@ -434,11 +421,11 @@ class ShardedMixtureOfExperts:
         d = self.hidden_dim
         compute = self.dtype
 
-        impl = self.dispatch_impl
-        if impl == "auto":
-            impl = choose_dispatch_impl(
-                x.shape[0], self.num_experts * capacity
-            )
+        # 'gather' moves tokens with index gathers/scatters (O(E*C*d) data
+        # movement); 'onehot' uses the GShard-style [n,E,C] einsums
+        # (O(n*E*C*d) MXU work): chosen per static shape at the crossover
+        # measured on a v5e
+        impl = choose_dispatch_impl(x.shape[0], self.num_experts * capacity)
 
         # 1) gate + routing plan for MY tokens (logits in f32 for stable softmax)
         with jax.named_scope("router"):

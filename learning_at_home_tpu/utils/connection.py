@@ -66,8 +66,7 @@ BW_MIN_SAMPLE_BYTES = 256 << 10
 # explicit marker replaces the old 0.05 s elapsed-time floor: straggler
 # cancels fold their elapsed wait into the RTT EMA however short the
 # configured grace period, and teardown/shutdown cancels (no marker) are
-# never mistaken for slowness evidence however loaded the box is
-# (ADVICE.md round 5, item 3).
+# never mistaken for slowness evidence however loaded the box is.
 QUORUM_STRAGGLER_CANCEL = "lah-quorum-straggler-cancel"
 
 _force_v1 = False

@@ -196,17 +196,21 @@ def test_only_the_kernel_branch_is_scoped(monkeypatch):
     assert scopes("xla") == on_xla
 
 
-def test_cached_decode_under_flash_matches_the_full_forward():
+def test_cached_decode_under_flash_matches_the_full_forward(monkeypatch):
     """``_generate_cached``'s prefill passes a prompt of any length
-    through ``attention_core`` under the model's ``attn_impl``."""
+    through ``attention_core`` under the model's ``attn_impl``: a model
+    that resolved to the kernel (as on the chip) decodes a prompt the
+    kernel has no tiles for."""
     mesh = make_mesh({"expert": 1}, devices=jax.devices()[:1])
     cfg = DMoETransformerConfig(
-        vocab_size=64, d_model=32, n_layers=2, n_heads=4, seq_len=24,
-        num_experts=8, k=2, dtype=jnp.float32, capacity_factor=8.0,
-        attn_impl="flash",
+        vocab_size=64, d_model=128, n_layers=2, n_heads=2,  # heads of 64
+        seq_len=FLASH_MIN_SEQ_LEN, num_experts=8, k=2, dtype=jnp.float32,
+        capacity_factor=8.0,
     )
-    model = DMoETransformerLM(cfg, mesh)
-    assert model.cfg.attn_impl == "flash"  # explicit: left as given
+    with monkeypatch.context() as on_the_chip:
+        on_the_chip.setattr(jax, "default_backend", lambda: "tpu")
+        model = DMoETransformerLM(cfg, mesh)
+    assert model.attn_impl == "flash"
     params = model.init_params(jax.random.PRNGKey(0))
     prompt = jnp.asarray([[1, 2, 3, 4, 5], [9, 8, 7, 6, 5]], jnp.int32)
     full = model.generate(params, prompt, max_new_tokens=6)

@@ -5,12 +5,15 @@ import os
 import jax
 import jax.numpy as jnp
 import numpy as np
-import optax
 import pytest
 
-from learning_at_home_tpu.models.transformer import (
-    DMoETransformerConfig,
-    DMoETransformerLM,
+from __graft_entry__ import (
+    flagship_one_chip,
+    glm_4_7_flash_one_chip,
+    k_exaone_one_chip,
+    nemotron_labs_twotower_one_chip,
+    olmoe_one_chip,
+    smallthinker_one_chip,
 )
 from learning_at_home_tpu.parallel import batch_sharding, make_mesh
 from learning_at_home_tpu.utils.checkpoint import (
@@ -25,21 +28,35 @@ from learning_at_home_tpu.utils.checkpoint import (
 )
 
 
-def test_train_checkpointer_roundtrip_sharded(tmp_path):
-    mesh = make_mesh({"data": 2, "expert": 4})
-    cfg = DMoETransformerConfig(
-        vocab_size=64, d_model=32, n_layers=1, n_heads=4, seq_len=16,
-        num_experts=8, k=2, dtype=jnp.float32,
-    )
-    model = DMoETransformerLM(cfg, mesh)
+@pytest.mark.parametrize("recipe, axes", [
+    (flagship_one_chip, {"data": 2, "expert": 4}),
+    # the dropless recipes hold every expert on every device
+    (olmoe_one_chip, {"data": 2, "expert": 1}),
+    (smallthinker_one_chip, {"data": 2, "expert": 1}),
+    (k_exaone_one_chip, {"data": 2, "expert": 1}),
+    (glm_4_7_flash_one_chip, {"data": 2, "expert": 1}),
+    (nemotron_labs_twotower_one_chip, {"data": 2, "expert": 1}),
+], ids=["dmoe", "olmoe", "smallthinker", "k-exaone", "glm-4.7-flash", "nemotron"])
+def test_train_checkpointer_roundtrip_sharded(tmp_path, recipe, axes):
+    """Each recipe's tiny stack: a tuple of per-layer trees that differ
+    (dense, mixture, state-space; the prediction block's ``mtp`` subtree)
+    and its optimizer's state survive save and restore leaf for leaf,
+    values and shardings, and the resumed step is the original's."""
+    n_dev = int(np.prod(list(axes.values())))
+    mesh = make_mesh(axes, devices=jax.devices()[:n_dev])
+    model, cfg, opt, batch = recipe(mesh, tiny=True)
     params = model.init_params(jax.random.PRNGKey(0))
-    opt = optax.adamw(1e-3)
     opt_state = model.init_opt_state(opt, params)
     step_fn = model.make_train_step(opt)
 
     rs = np.random.RandomState(0)
-    ids = jax.device_put(jnp.asarray(rs.randint(0, 64, (8, 16))), batch_sharding(mesh))
-    tgt = jax.device_put(jnp.asarray(rs.randint(0, 64, (8, 16))), batch_sharding(mesh))
+    ids, tgt = (
+        jax.device_put(
+            jnp.asarray(rs.randint(0, cfg.vocab_size, (batch, cfg.seq_len))),
+            batch_sharding(mesh),
+        )
+        for _ in range(2)
+    )
     params, opt_state, loss1, _ = step_fn(params, opt_state, ids, tgt)
 
     ckpt = TrainCheckpointer(str(tmp_path / "ckpt"), keep_last=2)
@@ -47,22 +64,33 @@ def test_train_checkpointer_roundtrip_sharded(tmp_path):
     assert latest_step(str(tmp_path / "ckpt")) == 1
 
     # fresh model instance restores onto the SAME shardings
-    model2 = DMoETransformerLM(cfg, mesh)
+    model2 = recipe(mesh, tiny=True)[0]
     params2 = model2.init_params(jax.random.PRNGKey(99))  # different values
     opt_state2 = model2.init_opt_state(opt, params2)
     restored = ckpt.restore_latest(params2, opt_state2)
     assert restored is not None
     step, rparams, ropt = restored
     assert step == 1
-    # exact value match
-    for a, b in zip(
-        jax.tree_util.tree_leaves(params), jax.tree_util.tree_leaves(rparams)
-    ):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    # sharding preserved on expert stacks
-    assert rparams["layers"]["moe"]["w1"].sharding.spec == params[
-        "layers"
-    ]["moe"]["w1"].sharding.spec
+    assert isinstance(rparams["layers"], tuple)
+    assert len(rparams["layers"]) == cfg.n_layers
+    for saved, rest in ((params, rparams), (opt_state, ropt)):
+        assert jax.tree_util.tree_structure(saved) == (
+            jax.tree_util.tree_structure(rest))
+        for (path, a), b in zip(
+            jax.tree_util.tree_flatten_with_path(saved)[0],
+            jax.tree_util.tree_leaves(rest),
+        ):
+            name = jax.tree_util.keystr(path)
+            assert a.dtype == b.dtype, name
+            np.testing.assert_array_equal(
+                np.asarray(a.astype(jnp.float32)),
+                np.asarray(b.astype(jnp.float32)), err_msg=name)
+            assert b.sharding.is_equivalent_to(a.sharding, a.ndim), (
+                name, a.sharding, b.sharding)
+    moe = next(lp for lp in rparams["layers"] if "moe" in lp)["moe"]
+    stacks = [name for name in moe if name.startswith("w")]  # the experts'
+    assert stacks and all(
+        "expert" in str(moe[name].sharding.spec) for name in stacks)
     # resumed training continues identically
     _, _, loss_resumed, _ = step_fn(rparams, ropt, ids, tgt)
     _, _, loss_orig, _ = step_fn(params, opt_state, ids, tgt)

@@ -391,7 +391,7 @@ def test_moe_numerics_identical_across_dispatch_modes():
 
 
 # ---------------------------------------------------------------------------
-# quorum-straggler cancel marker (satellite: ADVICE r5 item 3)
+# quorum-straggler cancel marker
 # ---------------------------------------------------------------------------
 
 

@@ -120,15 +120,14 @@ def test_the_tiny_recipe_keeps_the_block(tiny):
 
 @pytest.mark.parametrize("impl", ["xla", "flash"])
 def test_latent_attention_layer_matches_the_reference(tiny, impl):
-    """One layer's attention block alone, float32, through both cores
-    (``flash`` has no tiles off the TPU and runs the xla core: the path a
-    CPU takes), and the projections' q, k, v themselves."""
+    """One layer's attention block alone, float32, the projections' q,
+    k, v themselves, and both cores on them (``flash`` has no tiles off
+    the TPU and runs the xla core: the path a CPU takes)."""
     model, cfg, params, ids, _ = tiny
-    model = DMoETransformerLM(
-        dataclasses.replace(cfg, attn_impl=impl), _one_device_mesh())
     lp = params["layers"][1]
     x = reference.embed(params, ids)
-    got, _ = jax.jit(model._attention_block)(lp, x)
+    got, _ = jax.jit(model._attention_block, static_argnums=(2,))(
+        lp, x, cfg.attention_layer(1))
     _close(got, reference.attention_part(lp, x, SIZES, 1), 1e-5)
     a = trunk.rms_norm(lp["ln1"], x, cfg.norm_eps)
     q, k, v = trunk.latent_qkv_projections(
@@ -137,6 +136,8 @@ def test_latent_attention_layer_matches_the_reference(tiny, impl):
     for got_part, want_part in zip((q, k, v), want):
         assert got_part.shape == (2, 32, 4, 16)
         _close(got_part, want_part, 1e-5)
+    _close(trunk.attention_core(q, k, v, impl),
+           reference.attention(*want, lambda a: a), 1e-5)
     # ONE rotated key part a token: every head's last 4 are the same
     assert np.ptp(np.asarray(k[..., 12:]), axis=2).max() == 0.0
     assert np.ptp(np.asarray(k[..., :12]), axis=2).min() > 0.0
@@ -392,9 +393,6 @@ def test_set_up_levels_the_blocks_router_too(tiny):
 
 
 @pytest.mark.parametrize("changes, error, match", [
-    ({"scan_layers": True, "stack_layers": True, "ffn_pattern": None},
-     ValueError, "mtp_layers"),
-    ({"stack_layers": True, "ffn_pattern": None}, ValueError, "mtp_layers"),
     ({"mtp_layers": 2}, ValueError, "0 or 1"),
     ({"q_latent_dim": None}, ValueError, "together"),
     ({"head_dim": None}, ValueError, "together"),

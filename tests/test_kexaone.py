@@ -464,29 +464,6 @@ def test_the_step_moves_the_bias_by_the_rule_alone(tiny, rate):
     assert np.abs(gate_moved).max() > 0
 
 
-def test_a_scanned_stack_moves_its_stacked_biases_by_the_rule(tiny):
-    """The stacked layout (one [layers, E] bias array under ``lax.scan``):
-    the scan's per-layer counts reach the rule, a layer's bias moves on
-    its own layer's counts."""
-    _, cfg, _, ids, tgt = tiny
-    uniform = dataclasses.replace(
-        cfg, n_layers=2, layer_pattern=None, ffn_pattern=None, held_experts=None,
-        scan_layers=True, stack_layers=True, router_bias_rate=0.5)
-    model = DMoETransformerLM(uniform, _one_device_mesh())
-    params = model.init_params(jax.random.PRNGKey(3))
-    assert params["layers"]["moe"]["router_bias"].shape == (2, 16)
-    counts = np.asarray(jax.jit(model.loss_fn)(params, ids, tgt)[1]["expert_counts"])
-    assert counts.shape == (2, 16) and (counts[0] != counts[1]).any()
-    import optax
-
-    optimizer = optax.sgd(1e-3)
-    step = jax.jit(model.make_train_step(optimizer).__wrapped__)
-    new, _, _, _ = step(params, optimizer.init(params), ids, tgt)
-    np.testing.assert_array_equal(
-        np.asarray(new["layers"]["moe"]["router_bias"]),
-        0.5 * np.sign(counts.mean(-1, keepdims=True) - counts))
-
-
 def test_the_rule_levels_uneven_loads():
     """``level_bias`` on scores that send everything to a few experts:
     the largest load over the mean falls to near 1, the bias it returns is
@@ -595,12 +572,10 @@ def test_the_share_path_equals_masked_dense_experts_and_its_gradients():
             np.asarray(a), np.asarray(b), atol=1e-4 * max(np.abs(b).max(), 1e-6))
 
 
-# ---- refusals, layouts, the other paths ----
+# ---- refusals, the other paths ----
 
 
 @pytest.mark.parametrize("changes, error, match", [
-    ({"stack_layers": True}, ValueError, "mixes dense"),
-    ({"scan_layers": True, "stack_layers": True}, ValueError, "ONE traced body"),
     ({"ffn_pattern": ("dense",) * 4}, ValueError, "each of the 5"),
     ({"ffn_pattern": ("dense",) * 5}, ValueError, "no 'moe' layer"),
     ({"dense_ffn_dim": None}, ValueError, "dense_ffn_dim"),

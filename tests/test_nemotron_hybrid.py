@@ -535,8 +535,6 @@ def test_set_up_levels_the_four_routers_and_only_them(tiny):
 
 
 @pytest.mark.parametrize("changes, error, match", [
-    ({"scan_layers": True, "stack_layers": True}, ValueError, "mixer_pattern"),
-    ({"stack_layers": True}, ValueError, "mixer_pattern"),
     ({"seq_parallel": True}, NotImplementedError, "mixer_pattern"),
     ({"mixer_pattern": ("ssm",) * 9}, ValueError, "one 'moe'"),
     ({"mixer_pattern": ("ssm", "moe")}, ValueError, "each of the 9 layers"),
@@ -568,7 +566,7 @@ def test_a_stack_that_describes_no_mixer_is_the_program_of_before():
     feed-forward part, its parameters under the names they had."""
     cfg = DMoETransformerConfig(
         vocab_size=64, d_model=16, n_layers=2, n_heads=2, seq_len=8,
-        num_experts=4, scan_layers=False, stack_layers=False)
+        num_experts=4)
     assert cfg.mixer_pattern is None and cfg.mixture_layers() == 2
     params = DMoETransformerLM(cfg, _one_device_mesh()).init_params(
         jax.random.PRNGKey(0))

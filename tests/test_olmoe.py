@@ -27,7 +27,14 @@ sys.path.insert(0, os.path.join(REPO, "benchmarks"))
 
 import harness  # noqa: E402  (benchmarks/harness.py: imports no jax)
 
-from __graft_entry__ import flagship_one_chip, olmoe_one_chip  # noqa: E402
+from __graft_entry__ import (  # noqa: E402
+    flagship_one_chip,
+    glm_4_7_flash_one_chip,
+    k_exaone_one_chip,
+    nemotron_labs_twotower_one_chip,
+    olmoe_one_chip,
+    smallthinker_one_chip,
+)
 from learning_at_home_tpu.models import transformer, trunk  # noqa: E402
 from learning_at_home_tpu.models.transformer import DMoETransformerLM  # noqa: E402
 from learning_at_home_tpu.ops.moe_dispatch import (  # noqa: E402
@@ -271,8 +278,7 @@ def test_dropless_equals_the_capacity_path_when_nothing_is_dropped(expert_kind):
     mesh = _one_device_mesh()
     sorted_moe = _moe(mesh, expert_kind=expert_kind, renormalize=True)
     slot_moe = _moe(mesh, expert_kind=expert_kind, renormalize=True,
-                    routing="capacity", capacity_factor=8.0,
-                    dispatch_impl="gather")
+                    routing="capacity", capacity_factor=8.0)
     params = sorted_moe.init_params(jax.random.PRNGKey(2))
     params["gate"] = params["gate"] * 100.0
     if expert_kind == "gelu":  # biases that matter
@@ -419,7 +425,7 @@ def test_every_grouped_matmul_of_a_layer_carries_its_tiles():
     x = jax.ShapeDtypeStruct((batch, cfg.seq_len, cfg.d_model), cfg.dtype)
 
     def layer_loss(lp, x):
-        y, aux = model._layer(lp, x, 0)
+        y, aux = model._layer(lp, x, 0, None, cfg.attention_layer(0))
         return (y.astype(jnp.float32) ** 2).mean() + aux["aux_loss"]
 
     text = (
@@ -516,6 +522,22 @@ DMOE_TINY_POD4_STEP_SHA256 = (
 OLMOE_TINY_STEP_SHA256 = (
     "a5ef2b58eef22ef79b1198357c93380731ca0f7daa7017d3ef0b0469a7f08651"
 )
+# The four newer recipes' tiny steps on one device, first taken on PR 43's
+# parent (1f129aa, PR 41's tree), where they read the same as on PR 43's
+# tree: the scanned/stacked layout, ``attn_impl`` and ``dispatch_impl``
+# went without a letter of any cell's program changing.
+SMALLTHINKER_TINY_STEP_SHA256 = (
+    "a4a7bb6bcd9bb5f6e0cbe00a067c1d9cfdfcb12906ef423f156b5e0e0642e413"
+)
+K_EXAONE_TINY_STEP_SHA256 = (
+    "acf47b0ff668da9c1ffc4bf161aad8ae59b9091dc57c12c1335150f88bd260af"
+)
+GLM_4_7_FLASH_TINY_STEP_SHA256 = (
+    "6d0b221f0ca43b27fa836c5b4812d086bae5b53b53cab2ca764db91d32ff2222"
+)
+NEMOTRON_TINY_STEP_SHA256 = (
+    "2ffcd672b1698783a155a15c6429019277fa51aea581341ecc53faaccb451582"
+)
 
 
 @pytest.mark.parametrize(
@@ -524,13 +546,18 @@ OLMOE_TINY_STEP_SHA256 = (
         (flagship_one_chip, {"expert": 1}, DMOE_TINY_STEP_SHA256),
         (flagship_one_chip, {"data": 2, "expert": 2}, DMOE_TINY_POD4_STEP_SHA256),
         (olmoe_one_chip, {"expert": 1}, OLMOE_TINY_STEP_SHA256),
+        (smallthinker_one_chip, {"expert": 1}, SMALLTHINKER_TINY_STEP_SHA256),
+        (k_exaone_one_chip, {"expert": 1}, K_EXAONE_TINY_STEP_SHA256),
+        (glm_4_7_flash_one_chip, {"expert": 1}, GLM_4_7_FLASH_TINY_STEP_SHA256),
+        (nemotron_labs_twotower_one_chip, {"expert": 1}, NEMOTRON_TINY_STEP_SHA256),
     ],
-    ids=["dmoe-one-chip", "dmoe-pod4", "olmoe-one-chip"],
+    ids=["dmoe-one-chip", "dmoe-pod4", "olmoe-one-chip", "smallthinker-one-chip",
+         "k-exaone-one-chip", "glm-4.7-flash-one-chip", "nemotron-one-chip"],
 )
 def test_dmoe256_lowered_step_is_text_identical_to_the_parents(
     recipe, axes, sha256
 ):
-    """The three programs the train cells run lower to the same
+    """The programs the train cells run lower to the same
     StableHLO, letter for letter, as on the commit their hash was taken
     on: what a PR takes out of the pod step (PR 27 made the block's shape
     part of the configuration; PR 29 deleted the forks beside the path)
@@ -560,71 +587,75 @@ def test_dmoe256_lowered_step_is_text_identical_to_the_parents(
     assert hashlib.sha256(text.encode()).hexdigest() == sha256
 
 
-# ---- the layout the cells run is the layout the CPU tests default to ----
+# ---- remat changes no number; the stack has one layout ----
 
 
-@pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
-@pytest.mark.parametrize(
-    "recipe", [flagship_one_chip, olmoe_one_chip], ids=["dmoe", "olmoe"]
-)
-def test_unrolled_tuples_equal_the_scanned_stack(recipe, remat):
-    """Every cell runs unrolled per-layer tuples under ``remat``; the CPU
-    tests default to one scanned body over stacked layers.  From the same
-    weights both layouts give one loss and one set of gradients, with and
-    without ``jax.checkpoint`` around the layer (under which the dropless
-    block's row gathers replay their ``custom_vjp``)."""
+@pytest.mark.parametrize("recipe", [
+    flagship_one_chip, olmoe_one_chip, smallthinker_one_chip,
+    k_exaone_one_chip, glm_4_7_flash_one_chip,
+    nemotron_labs_twotower_one_chip,
+], ids=["dmoe", "olmoe", "smallthinker", "k-exaone", "glm-4.7-flash", "nemotron"])
+def test_remat_changes_no_loss_or_gradient(recipe):
+    """Every cell runs its per-layer trees under ``remat``; the plain
+    references are compared without it.  From the same weights a recipe's
+    tiny stack gives one loss and one set of gradients with and without
+    ``jax.checkpoint`` around the layer: the dropless block's row gathers
+    replay their ``custom_vjp`` under it, and so do the share's, the
+    latent block's with its prediction block (which runs the same
+    checkpointed layer) and the state-space kernels' plain forms."""
     mesh = _one_device_mesh()
-    _, cfg, _, batch = recipe(mesh, tiny=True)
-    scanned = DMoETransformerLM(
-        dataclasses.replace(
-            cfg, scan_layers=True, stack_layers=True, remat=False
-        ),
-        mesh,
-    )
-    unrolled = DMoETransformerLM(
-        dataclasses.replace(
-            cfg, scan_layers=False, stack_layers=False, remat=remat
-        ),
-        mesh,
-    )
-    stacked = _decisive(scanned.init_params(jax.random.PRNGKey(5)))
-    as_tuples = dict(
-        stacked,
-        layers=tuple(
-            jax.tree_util.tree_map(lambda l: l[i], stacked["layers"])
-            for i in range(cfg.n_layers)
-        ),
-    )
+    under_remat, cfg, _, batch = recipe(mesh, tiny=True)
+    assert cfg.remat
+    plain = DMoETransformerLM(dataclasses.replace(cfg, remat=False), mesh)
+    params = _decisive(plain.init_params(jax.random.PRNGKey(5)))
     rs = np.random.RandomState(9)
     ids = jnp.asarray(rs.randint(0, cfg.vocab_size, (batch, cfg.seq_len + 1)))
 
-    def loss_and_grads(model, params):
-        return jax.jit(
-            jax.value_and_grad(
-                lambda p: model.loss_fn(p, ids[:, :-1], ids[:, 1:])[0]
-            )
-        )(params)
+    def loss_and_grads(model):
+        return jax.jit(jax.value_and_grad(
+            lambda p: model.loss_fn(p, ids[:, :-1], ids[:, 1:])[0]))(params)
 
-    want, want_grads = loss_and_grads(scanned, stacked)
-    got, got_grads = loss_and_grads(unrolled, as_tuples)
-    got_grads = dict(
-        got_grads,
-        layers=jax.tree_util.tree_map(
-            lambda *ls: jnp.stack(ls), *got_grads["layers"]
-        ),
-    )
-    # float32 throughout and the same operations in another program
-    # structure: what differs is the order of a few f32 additions
+    want, want_grads = loss_and_grads(plain)
+    got, got_grads = loss_and_grads(under_remat)
     np.testing.assert_allclose(float(got), float(want), rtol=1e-6)
     for (path, g), w in zip(
         jax.tree_util.tree_flatten_with_path(got_grads)[0],
         jax.tree_util.tree_leaves(want_grads),
     ):
+        # the same operations in another compiled program: the order of a
+        # few additions may differ (on one CPU device they read bit for
+        # bit the same today), so a few ulp of the leaf's scale in the
+        # leaf's own dtype (bf16 in the dmoe recipe)
+        ulp = float(jnp.finfo(w.dtype).eps) * float(jnp.abs(w).max())
         np.testing.assert_allclose(
-            np.asarray(g), np.asarray(w), rtol=1e-4,
-            atol=1e-6 * float(jnp.abs(w).max()),
-            err_msg=jax.tree_util.keystr(path),
+            np.asarray(g.astype(jnp.float32)), np.asarray(w.astype(jnp.float32)),
+            rtol=0, atol=8 * ulp, err_msg=jax.tree_util.keystr(path),
         )
+
+
+@pytest.mark.parametrize("owner, name", [
+    (transformer.DMoETransformerConfig, "scan_layers"),
+    (transformer.DMoETransformerConfig, "stack_layers"),
+    (transformer.DMoETransformerConfig, "attn_impl"),
+    (ShardedMixtureOfExperts, "dispatch_impl"),
+], ids=lambda v: v if isinstance(v, str) else v.__name__)
+def test_the_stack_has_one_layout(owner, name):
+    """The stack's layout, the attention core and the dispatch plan are
+    not options: a tuple of per-layer trees under the unrolled loop,
+    ``auto_attn_impl`` and ``choose_dispatch_impl``.  The constructor
+    refuses the keyword, no dataclass field carries the name, and the two
+    layout names read ``False`` off a config: what the CFG_FIELDS tables
+    of the benchmark's six train runners compare with their files."""
+    build = owner if dataclasses.is_dataclass(owner) else functools.partial(
+        _moe, _one_device_mesh())
+    with pytest.raises(TypeError, match=name):
+        build(**{name: False})
+    if dataclasses.is_dataclass(owner):
+        assert name not in {f.name for f in dataclasses.fields(owner)}
+        with pytest.raises(TypeError, match=name):
+            dataclasses.replace(owner(), **{name: False})
+    if name in runner.CFG_FIELDS:  # the two the benchmark's tables read
+        assert getattr(owner(), name) is False
 
 
 # ---- the chip's compiler accepts a layer at published widths ----
@@ -659,7 +690,7 @@ def test_one_olmoe_layer_compiles_for_v5e_at_published_widths(v5e_chip, monkeypa
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     mesh = Mesh(np.array([v5e_chip]), ("expert",))
     model, cfg, _, batch = olmoe_one_chip(mesh)
-    assert model.cfg.attn_impl == "flash"  # what a user on the chip gets
+    assert model.attn_impl == "flash"  # what a user on the chip gets
     assert (cfg.d_model, cfg.n_heads, cfg.num_experts, cfg.k,
             model.moe.ffn_dim, cfg.seq_len, batch) == (2048, 16, 64, 8, 1024, 4096, 4)
     one = NamedSharding(mesh, P())
@@ -671,7 +702,7 @@ def test_one_olmoe_layer_compiles_for_v5e_at_published_widths(v5e_chip, monkeypa
     x = jax.ShapeDtypeStruct((batch, cfg.seq_len, cfg.d_model), cfg.dtype, sharding=one)
 
     def layer_loss(lp, x):
-        y, aux = model._layer(lp, x, 0)
+        y, aux = model._layer(lp, x, 0, None, cfg.attention_layer(0))
         return (y.astype(jnp.float32) ** 2).mean() + aux["aux_loss"]
 
     with _no_compile_cache():
@@ -742,29 +773,25 @@ def _kernel_sized(mesh, **changes):
         cfg, d_model=256, n_heads=4, seq_len=512,
         dtype=jnp.bfloat16, param_dtype=jnp.bfloat16, **changes)
     model = DMoETransformerLM(cfg, mesh)
-    assert model.cfg.attn_impl == "flash"
+    assert model.attn_impl == "flash"
     return model, cfg
 
 
 @pytest.mark.parametrize("formula", ["kept", "parents"])
-@pytest.mark.parametrize("layout", ["unrolled", "scanned"])
 def test_remat_recomputes_no_forward_kernel_call(
-    v5e_chip, monkeypatch, layout, formula
+    v5e_chip, monkeypatch, formula
 ):
     """The gradient of a two-layer stack under ``remat``, compiled for a
     described chip at a small kernel shape: the traced step names the
     kernel's output and its row sums, two arrays a kernel layer, and both
     the traced and the compiled step hold ONE forward call a layer beside
-    the fused backward's (a scanned stack: one body, so one of each);
-    under the parent's formula the same count reads two forwards a layer,
+    the fused backward's; under the parent's formula the same count reads two forwards a layer,
     so the count can tell."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     if formula == "parents":
         _the_parents_formula(monkeypatch)
     mesh = Mesh(np.array([v5e_chip]), ("expert",))
-    scanned = layout == "scanned"
-    model, cfg = _kernel_sized(
-        mesh, scan_layers=scanned, stack_layers=scanned)
+    model, cfg = _kernel_sized(mesh)
     assert cfg.remat and cfg.n_layers == 2
     one = NamedSharding(mesh, P())
     shapes = jax.tree_util.tree_map(
@@ -773,7 +800,7 @@ def test_remat_recomputes_no_forward_kernel_call(
     ids = jax.ShapeDtypeStruct((2, cfg.seq_len), jnp.int32, sharding=one)
     traced = jax.jit(jax.value_and_grad(
         lambda p, i, t: model.loss_fn(p, i, t)[0])).trace(shapes, ids, ids)
-    bodies = 1 if scanned else cfg.n_layers
+    bodies = cfg.n_layers
     forwards = bodies * (2 if formula == "parents" else 1)
     named = [eqn.params["name"]
              for _, eqn in probe._equations(traced.jaxpr.jaxpr, "name")]

@@ -341,8 +341,9 @@ def test_join_timeout_mode_gating():
 
 def test_fire_join_under_jit(twin_swarms):
     """The fire/join custom-vjp pair compiles and runs under jit (the
-    handle chain keeps the callbacks ordered), and agrees bitwise with
-    the eager serial schedule.  The heavyweight 2048-row repro of the
+    handle chain keeps the callbacks ordered: every join finds its own
+    fire's dispatch, and none is left in flight), and agrees with the
+    eager serial schedule.  The heavyweight 2048-row repro of the
     retired ROUND5 hazard lives in test_jitted_client_regression."""
     (src, _), _ = twin_swarms
     model = SwarmDMoETransformerLM(_cfg(), src)
@@ -354,7 +355,17 @@ def test_fire_join_under_jit(twin_swarms):
         lambda p, i: model.apply_overlapped(p, i, overlap=True)
     )
     out = np.asarray(jitted(params, ids))
-    assert np.array_equal(eager, out)
+    assert all(
+        m.dispatch_stats()["inflight_dispatches"] == 0 for m in model.moes
+    )
+    # two compiled programs of one float32 computation: jit fuses what the
+    # eager call runs op by op, and XLA reassociates the fused sums, so the
+    # logits agree to a few ulp of their scale, not bit for bit (the
+    # bitwise contract is between the two SCHEDULES of one program:
+    # test_serial_overlapped_bitwise_parity).  A join that read another
+    # dispatch's reply would be off by the scale itself.
+    ulp = np.finfo(np.float32).eps * np.abs(eager).max()
+    np.testing.assert_allclose(out, eager, rtol=0, atol=16 * ulp)  # 1.07 seen
 
 
 @pytest.mark.slow

@@ -175,7 +175,7 @@ def test_transformer_trains_and_keeps_shardings():
         losses.append(float(loss))
     assert losses[-1] < losses[0]
     assert np.isfinite(losses).all()
-    w1 = params["layers"]["moe"]["w1"]
+    w1 = params["layers"][0]["moe"]["w1"]
     assert "expert" in str(w1.sharding.spec)
 
 
@@ -213,8 +213,7 @@ def test_opt_state_shardings_factored_optimizer():
     assert np.isfinite(float(loss))
 
 
-@pytest.mark.parametrize("stack", [False, True])
-def test_factored_stats_keep_the_expert_axis(stack):
+def test_factored_stats_keep_the_expert_axis():
     """At a size adafactor factors (dims >= 128), the row/column statistics
     of an expert stack are the param's shape minus one axis: they take the
     param's spec minus that axis, i.e. stay split over 'expert' — where
@@ -229,21 +228,17 @@ def test_factored_stats_keep_the_expert_axis(stack):
     cfg = DMoETransformerConfig(
         vocab_size=64, d_model=128, n_layers=2, n_heads=4, seq_len=16,
         num_experts=4, k=2, dtype=jnp.float32,
-        stack_layers=stack, scan_layers=stack,
     )
     model = DMoETransformerLM(cfg, mesh)
     params = model.init_params(jax.random.PRNGKey(0))
     opt = fused_adafactor(1e-3)
     opt_state = model.init_opt_state(opt, params)
-    layers = opt_state.v_row["layers"]
-    w1_rows = layers["moe"]["w1"] if stack else layers[0]["moe"]["w1"]
-    assert w1_rows.ndim == (3 if stack else 2)  # factored: one axis gone
-    want = NamedSharding(mesh, P(None, "expert") if stack else P("expert"))
+    w1_rows = opt_state.v_row["layers"][0]["moe"]["w1"]
+    assert w1_rows.ndim == 2  # factored: one axis gone
+    want = NamedSharding(mesh, P("expert"))
     assert w1_rows.sharding.is_equivalent_to(want, w1_rows.ndim), (
         w1_rows.sharding
     )
-    if stack:
-        return  # placement checked; one compiled step (below) is enough
     ids = jax.device_put(jnp.zeros((8, 16), jnp.int32), batch_sharding(mesh))
     step = model.make_train_step(opt)
     before = jax.tree_util.tree_leaves((params, opt_state))
@@ -270,7 +265,7 @@ def test_grad_accumulation_matches_mean_of_micro_grads():
     tgt = jnp.asarray(rs.randint(0, 64, (2, 8, 16)))
 
     # reference: average grads of the two microbatches, one update
-    gfn = jax.grad(lambda p, i, t: model.loss_fn(p, i, t)[0])
+    gfn = jax.jit(jax.grad(lambda p, i, t: model.loss_fn(p, i, t)[0]))
     g0 = gfn(params, ids[0], tgt[0])
     g1 = gfn(params, ids[1], tgt[1])
     gavg = jax.tree_util.tree_map(lambda a, b: (a + b) / 2, g0, g1)
@@ -817,9 +812,9 @@ def test_transformer_remat_matches():
 def test_remat_policy_names_nothing_on_the_xla_core(against, monkeypatch):
     """``remat`` keeps what the blocked attention kernel names and nothing
     else (PR 38).  On the ``xla`` core nothing is named: a two-layer
-    model's loss and every gradient are bit for bit those without remat,
-    and those of the parent's formula (``jax.checkpoint`` under no
-    policy), whose lowered program is the same text."""
+    model's loss and every gradient are bit for bit those of the parent's
+    formula (``jax.checkpoint`` under no policy), whose lowered program is
+    the same text, and those without remat to a few ulp."""
     mesh = make_mesh({"data": 2, "expert": 4})
     model_r, _ = _tiny_model(mesh, remat=True)
     params = model_r.init_params(jax.random.PRNGKey(0))
@@ -846,8 +841,15 @@ def test_remat_policy_names_nothing_on_the_xla_core(against, monkeypatch):
         jax.tree_util.tree_flatten_with_path(got)[0],
         jax.tree_util.tree_leaves(want),
     ):
-        np.testing.assert_array_equal(
-            np.asarray(g), np.asarray(w), err_msg=jax.tree_util.keystr(path))
+        # without remat the step is another compiled program: on this
+        # eight-device mesh the backward adds the two layers' cotangents of
+        # the residual stream in another order (1 ulp in a third of the
+        # embedding's gradient), so a few ulp of the leaf's scale, not bits
+        atol = 0.0 if against == "the_parents_remat" else (
+            8 * np.finfo(np.float32).eps * float(jnp.abs(w).max()))
+        np.testing.assert_allclose(
+            np.asarray(g), np.asarray(w), rtol=0, atol=atol,
+            err_msg=jax.tree_util.keystr(path))
 
 
 def test_transformer_zigzag_matches_contiguous():
@@ -885,21 +887,16 @@ def test_transformer_zigzag_matches_contiguous():
         model_z.apply(params, bad)
 
 
-def test_attn_impl_auto_resolves_to_xla_on_cpu():
-    """'auto' must never pick the TPU-only flash kernel on CPU, and an
-    explicit 'xla' stays untouched."""
+def test_attn_impl_resolves_to_xla_on_cpu():
+    """The model never picks the TPU-only flash kernel on CPU, below the
+    kernel's shortest length or above it."""
     import dataclasses
 
     mesh = make_mesh({"expert": 8})
     _, cfg = _tiny_model(mesh)
-    m = DMoETransformerLM(
-        dataclasses.replace(cfg, attn_impl="auto", seq_len=16), mesh
-    )
-    assert m.cfg.attn_impl == "xla"
-    m2 = DMoETransformerLM(
-        dataclasses.replace(cfg, attn_impl="xla"), mesh
-    )
-    assert m2.cfg.attn_impl == "xla"
+    for seq_len in (16, 1024):
+        m = DMoETransformerLM(dataclasses.replace(cfg, seq_len=seq_len), mesh)
+        assert m.attn_impl == "xla"
 
 
 def test_generate_greedy_decode_and_shapes():
@@ -977,15 +974,16 @@ def test_padding_content_cannot_leak_into_decode_logits():
     ids_b = jnp.asarray(np.concatenate([prompt, pad_b], axis=1))
     mask = jnp.asarray(np.arange(16)[None, :] < p).repeat(2, axis=0)
 
-    la, _ = model.apply(params, ids_a, token_mask=mask)
-    lb, _ = model.apply(params, ids_b, token_mask=mask)
+    apply = jax.jit(model.apply)  # eagerly every layer's shard_map compiles anew
+    la, _ = apply(params, ids_a, token_mask=mask)
+    lb, _ = apply(params, ids_b, token_mask=mask)
     np.testing.assert_array_equal(
         np.asarray(la[:, :p]), np.asarray(lb[:, :p])
     )
     # sanity: WITHOUT the mask the tight capacity makes valid logits
     # depend on padding occupancy — the bug the mask exists to fix
-    ua, _ = model.apply(params, ids_a)
-    ub, _ = model.apply(params, ids_b)
+    ua, _ = apply(params, ids_a)
+    ub, _ = apply(params, ids_b)
     assert not np.array_equal(np.asarray(ua[:, :p]), np.asarray(ub[:, :p]))
 
 
@@ -1014,7 +1012,7 @@ def test_kv_cache_decode_matches_full_forward():
     # genuine near-tie fails HERE, naming the position, instead of as an
     # inscrutable token mismatch below.  (capacity_factor=8 ⇒ routing on
     # the teacher-forced full sequence equals the per-step decode regime.)
-    logits_all, _ = model.apply(params, jnp.asarray(full))
+    logits_all, _ = jax.jit(model.apply)(params, jnp.asarray(full))
     p = prompt.shape[1]
     decode_logits = np.asarray(logits_all)[:, p - 1:-1]  # predicts full[:, p:]
     top2 = np.sort(decode_logits, axis=-1)[..., -2:]

@@ -82,8 +82,9 @@ def _random_ids(rs, cfg, batch: int, mesh):
 
 
 def probe_flash() -> None:
-    """attn_impl='flash' through one train step at seq 8192 on the
-    4-layer / 64-expert variant BASELINE.md's long-context row used."""
+    """The blocked attention kernel (what a one-chip model of this length
+    resolves to) through one train step at seq 8192 on the 4-layer /
+    64-expert variant BASELINE.md's long-context row used."""
     import dataclasses
 
     import jax
@@ -96,12 +97,11 @@ def probe_flash() -> None:
     name = "flash_attention[seq8192]"
     mesh = make_mesh({"expert": 1}, devices=jax.devices()[:1])
     _, cfg, optimizer, _ = flagship_one_chip(mesh)
-    cfg = dataclasses.replace(
-        cfg, seq_len=8192, num_experts=64, attn_impl="flash"
-    )
+    cfg = dataclasses.replace(cfg, seq_len=8192, num_experts=64)
     batch = 2
     try:
         model = DMoETransformerLM(cfg, mesh)
+        assert model.attn_impl == "flash", model.attn_impl
         params = model.init_params(jax.random.PRNGKey(0))
         opt_state = model.init_opt_state(optimizer, params)
         step = model.make_train_step(optimizer)
@@ -111,9 +111,8 @@ def probe_flash() -> None:
             params, opt_state, loss, _ = step(params, opt_state, ids, ids)
             losses.append(float(jax.block_until_ready(loss)))
         # the same forward through XLA attention, as the reference
-        xla = DMoETransformerLM(
-            dataclasses.replace(cfg, attn_impl="xla"), mesh
-        )
+        xla = DMoETransformerLM(cfg, mesh)
+        xla.attn_impl = "xla"
         params = xla.init_params(jax.random.PRNGKey(0))
         l_xla = float(jax.jit(xla.loss_fn)(params, ids[:1], ids[:1])[0])
         l_flash = float(jax.jit(model.loss_fn)(params, ids[:1], ids[:1])[0])

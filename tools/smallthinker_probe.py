@@ -218,7 +218,7 @@ def step_memory(chip, recipe: str = "smallthinker_one_chip") -> dict:
 
     mesh = Mesh(np.array([chip]), ("expert",))
     model, cfg, optimizer, batch = getattr(__graft_entry__, recipe)(mesh)
-    assert model.cfg.attn_impl == "flash"
+    assert model.attn_impl == "flash"
 
     def placed(tree, shardings):
         return jax.tree_util.tree_map(
