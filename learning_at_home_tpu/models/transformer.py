@@ -30,7 +30,9 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from learning_at_home_tpu.models.trunk import (
     FLASH_RESIDUALS,
     attention_core,
+    delta_mixer,
     flash_block_sizes,
+    gate_activation,
     gated_mlp,
     latent_qkv_projections,
     layer_norm,
@@ -46,6 +48,10 @@ from learning_at_home_tpu.parallel.mesh import batch_sharding
 from learning_at_home_tpu.parallel.sharded_moe import ShardedMixtureOfExperts
 
 Params = Any
+# what a recurrent layer reports beside the stream, and how the stack's
+# layers' readings join in the step's metrics
+_EXTREMES = {"ssm_decay_min": jnp.min, "delta_decay_min": jnp.min,
+             "delta_beta_max": jnp.max}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -53,10 +59,16 @@ class AttentionLayer:
     """What one layer's attention is, where layers of a stack differ
     (``DMoETransformerConfig.layer_pattern``).  ``window``: None = every
     earlier key (global), w = the w keys that end with the query's own.
-    ``rotary``: whether the layer's queries and keys are rotated."""
+    ``rotary``: whether the layer's queries and keys are rotated.
+    ``mixer``: ``'softmax'`` (the causal softmax attention the two fields
+    above describe) or ``'delta'`` (linear attention by the gated delta
+    rule, ``trunk.delta_mixer``: a recurrent state a head and a short
+    convolution in place of scores over the keys, no window, no
+    rotation)."""
 
     window: int | None = None
     rotary: bool = True
+    mixer: str = "softmax"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -130,10 +142,20 @@ class DMoETransformerConfig:
     # heads of 128, or un-gated squared-ReLU experts of width 1856, 6 of
     # 128 by sigmoid scores with a selection bias, renormalised times 2.5,
     # beside a shared expert of width 3712 / dropless, a share held
-    # (nemotron_labs_twotower_one_chip).
+    # (nemotron_labs_twotower_one_chip); Olmo-Hybrid-7B is rmsnorm (eps
+    # 1e-6) on each part's OUTPUT / no positions / L L L F: three layers of
+    # the gated delta rule (30 heads, keys of 96, values of 192, 4 taps,
+    # chunks of 64) to each of 30 heads of 128 with a norm over the whole
+    # queries and keys / a dense gated_silu block of width 11,008 in every
+    # layer, no mixture at all (olmo_hybrid_7b_one_chip).
     # 'layernorm' (scale and bias) or 'rmsnorm' (scale only)
     norm: str = "layernorm"
     norm_eps: float = 1e-5
+    # where a part's norm sits: 'input', x + Part(norm(x)); or 'output',
+    # x + norm(Part(x)), the part reading the stream as it is (the Olmo
+    # family's order).  The attention, the delta rule and the feed-forward
+    # part honour it alike; the final norm before the head is there in both
+    norm_place: str = "input"
     # 'learned': a [seq_len, d] table added to the embeddings; 'rope':
     # no table, rotary embedding of the queries and keys of every layer
     # (or of the layers that layer_pattern says)
@@ -231,6 +253,15 @@ class DMoETransformerConfig:
     ssm_chunk: int = 128
     ssm_dt_range: tuple[float, float] = (1e-3, 1e-1)
     ssm_dt_floor: float = 1e-4
+    # a layer whose AttentionLayer.mixer is 'delta': n_heads heads with
+    # keys of delta_key_dim and values of delta_value_dim, causal depthwise
+    # convolutions of delta_conv_kernel taps on q, k and v, the recurrence
+    # computed delta_chunk positions at a time; its decays' bias drawn as
+    # the state-space mixer's (ssm_dt_range)
+    delta_key_dim: int | None = None
+    delta_value_dim: int | None = None
+    delta_conv_kernel: int = 4
+    delta_chunk: int = 64
 
     def mixture_layers(self) -> int:
         """How many of the stack's layers route (hold a mixture)."""
@@ -300,7 +331,29 @@ class DMoETransformerLM:
                 f"router_input must be 'moe_input' or 'attention_input', "
                 f"got {config.router_input!r}"
             )
+        if config.norm_place not in ("input", "output"):
+            raise ValueError(
+                f"norm_place must be 'input' or 'output', got "
+                f"{config.norm_place!r}"
+            )
         kinds = {config.attention_layer(i) for i in range(config.n_layers)}
+        self._delta = any(a.mixer == "delta" for a in kinds)
+        if any(a.mixer not in ("softmax", "delta") for a in kinds):
+            raise ValueError(
+                f"an AttentionLayer's mixer is 'softmax' or 'delta', got "
+                f"{sorted({a.mixer for a in kinds})}"
+            )
+        if self._delta and None in (config.delta_key_dim, config.delta_value_dim):
+            raise ValueError(
+                "a 'delta' layer needs delta_key_dim and delta_value_dim"
+            )
+        if self._delta and config.seq_parallel:
+            raise NotImplementedError(
+                "seq_parallel=True (ring attention, parallel/"
+                "ring_attention.py) with a 'delta' layer: the delta rule's "
+                "recurrence and its convolutions cross the ring's sequence "
+                "shards, and nothing carries a state from shard to shard"
+            )
         if config.layer_pattern is not None:
             if config.n_layers % len(config.layer_pattern):
                 raise ValueError(
@@ -325,11 +378,6 @@ class DMoETransformerLM:
                 )
             if "dense" in ffns and config.dense_ffn_dim is None:
                 raise ValueError("a 'dense' layer needs dense_ffn_dim")
-            if "moe" not in ffns:
-                raise ValueError(
-                    "ffn_pattern names no 'moe' layer: this is the "
-                    "mixture's train step"
-                )
         if (config.shared_experts or "dense" in ffns) and (
             config.expert_kind == "gelu"
         ):
@@ -346,8 +394,8 @@ class DMoETransformerLM:
                 raise ValueError(
                     f"mixer_pattern must name 'ssm', 'attention' or 'moe' "
                     f"for each of the {config.n_layers} layers, one 'moe' "
-                    f"at least (this is the mixture's train step), got "
-                    f"{mixers}"
+                    f"at least (no model asks for single mixers without "
+                    f"one), got {mixers}"
                 )
             if "ssm" in mixers and None in (
                 config.ssm_heads, config.ssm_head_dim, config.ssm_state_dim
@@ -358,14 +406,16 @@ class DMoETransformerLM:
                 )
             if (
                 config.ffn_pattern is not None or config.mtp_layers
-                or config.router_input != "moe_input"
+                or config.router_input != "moe_input" or self._delta
+                or config.norm_place != "input"
             ):
                 raise ValueError(
                     "mixer_pattern: a layer that is ONE mixer has no "
                     "feed-forward part beside its attention (ffn_pattern), "
-                    "no attention input for its router (router_input) and "
+                    "no attention input for its router (router_input), "
                     "no next-but-one-token block built of such layers "
-                    "(mtp_layers)"
+                    "(mtp_layers), no 'delta' layer among its attention "
+                    "layers and its ONE norm on its input (norm_place)"
                 )
             if config.seq_parallel:
                 raise NotImplementedError(
@@ -400,6 +450,14 @@ class DMoETransformerLM:
             raise ValueError(
                 f"mtp_layers must be 0 or 1, got {config.mtp_layers}: one "
                 "block that predicts the next-but-one token is described"
+            )
+        if not config.mixture_layers() and (
+            config.mtp_layers or config.router_bias
+        ):
+            raise ValueError(
+                "a stack with no 'moe' layer has no router: no selection "
+                "bias (router_bias) and no next-but-one-token block, whose "
+                "layer is a mixture layer (mtp_layers)"
             )
         n_kv = config.n_kv_heads or config.n_heads
         if config.n_heads % n_kv:
@@ -446,25 +504,29 @@ class DMoETransformerLM:
         # 17.1 s vs 0.07 s compiled for 60 tokens at seq_len 1024 on CPU
         self._gen_jit: dict = {}
         self._decode_model: "DMoETransformerLM | None" = None
-        self.moe = ShardedMixtureOfExperts(
-            mesh,
-            hidden_dim=config.d_model,
-            num_experts=config.num_experts,
-            k=config.k,
-            capacity_factor=config.capacity_factor,
-            dtype=config.dtype,
-            param_dtype=config.param_dtype,
-            router_jitter=config.router_jitter,
-            ffn_dim=config.expert_ffn_dim,
-            expert_kind=config.expert_kind,
-            routing=config.routing,
-            renormalize=config.renormalize,
-            router_input=config.router_input == "attention_input",
-            held_experts=config.held_experts,
-            first_held_expert=config.first_held_expert,
-            router_score=config.router_score,
-            router_bias=config.router_bias,
-            routed_scale=config.routed_scale,
+        self._gate_act = gate_activation(config.expert_kind)
+        # a stack with no mixture layer builds no router and no expert state
+        self.moe = None if not config.mixture_layers() else (
+            ShardedMixtureOfExperts(
+                mesh,
+                hidden_dim=config.d_model,
+                num_experts=config.num_experts,
+                k=config.k,
+                capacity_factor=config.capacity_factor,
+                dtype=config.dtype,
+                param_dtype=config.param_dtype,
+                router_jitter=config.router_jitter,
+                ffn_dim=config.expert_ffn_dim,
+                expert_kind=config.expert_kind,
+                routing=config.routing,
+                renormalize=config.renormalize,
+                router_input=config.router_input == "attention_input",
+                held_experts=config.held_experts,
+                first_held_expert=config.first_held_expert,
+                router_score=config.router_score,
+                router_bias=config.router_bias,
+                routed_scale=config.routed_scale,
+            )
         )
         self._ring = None
         self._zig = self._zig_inv = None
@@ -568,6 +630,30 @@ class DMoETransformerLM:
                 "w_out": dense(k_out, (d_inner, d), pdt),
             }
 
+        def delta(key):
+            """The delta-rule mixer: ``w_in``'s columns are [q | k | v | z |
+            b | a]; the filters of q, k and v lecun-normal over their taps
+            and no bias; ``A_log`` and ``dt_bias`` drawn as the
+            state-space mixer's (float32 whatever the parameters' dtype,
+            as the decays' arithmetic is); one output-norm scale of a
+            head's value size, shared by the heads."""
+            h = cfg.n_heads
+            d_qk, d_v = 2 * h * cfg.delta_key_dim, h * cfg.delta_value_dim
+            k_in, k_conv, k_dt, k_a, k_out = jax.random.split(key, 5)
+            low, high = np.log(cfg.ssm_dt_range)
+            dt = jnp.exp(jax.random.uniform(k_dt, (h,), minval=low, maxval=high))
+            return {
+                "w_in": dense(k_in, (d, d_qk + 2 * d_v + 2 * h), pdt),
+                "conv_w": jax.nn.initializers.lecun_normal(
+                    in_axis=-1, out_axis=-2
+                )(k_conv, (d_qk + d_v, cfg.delta_conv_kernel), pdt),
+                "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),  # softplus^-1
+                "A_log": jnp.log(jax.random.uniform(
+                    k_a, (h,), minval=1.0, maxval=16.0)),
+                "gate_norm": {"scale": jnp.ones((cfg.delta_value_dim,), pdt)},
+                "w_out": dense(k_out, (d_v, d), pdt),
+            }
+
         def init_mixer_layer(key, mixer):
             """A layer that is ONE mixer behind ONE norm; what it holds
             says which: ``ssm``, ``wq``.., or ``moe`` (and ``shared``)."""
@@ -584,13 +670,16 @@ class DMoETransformerLM:
                 "wo": dense(ks[3], (d_q, d), pdt),
             }
 
-        def init_layer(key, ffn="moe"):
+        def init_layer(key, ffn="moe", kind=AttentionLayer()):
             ks = jax.random.split(key, 5)
-            if cfg.kv_latent_dim is None:
+            if kind.mixer == "delta":  # what the layer holds says so
+                attention = {"delta": delta(ks[0])}
+            elif cfg.kv_latent_dim is None:
                 attention = {
                     "wq": dense(ks[0], (d, d_q), pdt),
                     "wk": dense(ks[1], (d, d_kv), pdt),
                     "wv": dense(ks[2], (d, d_kv), pdt),
+                    "wo": dense(ks[3], (d_q, d), pdt),
                 }
             else:
                 # the latents' down-projections and norms, their
@@ -606,20 +695,16 @@ class DMoETransformerLM:
                     "kv_a_norm": {"scale": jnp.ones((c_kv,), pdt)},
                     "wkv_b": dense(
                         ks[2], (c_kv, cfg.n_heads * (nope + hd)), pdt),
+                    "wo": dense(ks[3], (d_q, d), pdt),
                 }
-            lp = {
-                "ln1": ln(),
-                **attention,
-                "wo": dense(ks[3], (d_q, d), pdt),
-                "ln2": ln(),
-            }
+            lp = {"ln1": ln(), **attention, "ln2": ln()}
             # the layer's feed-forward part is what its parameters hold:
             # 'ffn' (dense), or 'moe' and beside it 'shared'
             if ffn == "dense":
                 lp["ffn"] = dense_block(ks[4], cfg.dense_ffn_dim)
             else:
                 lp.update(mixture(ks[4]))
-            if cfg.qk_norm:
+            if cfg.qk_norm and kind.mixer != "delta":
                 per_head = cfg.qk_norm == "head"
                 lp["q_norm"] = {"scale": jnp.ones((hd if per_head else d_q,), pdt)}
                 lp["k_norm"] = {"scale": jnp.ones((hd if per_head else d_kv,), pdt)}
@@ -637,7 +722,9 @@ class DMoETransformerLM:
             "layers": (
                 tuple(map(init_mixer_layer, layer_keys, cfg.mixer_pattern))
                 if cfg.mixer_pattern is not None
-                else tuple(init_layer(k, f) for k, f in zip(layer_keys, ffn_of))
+                else tuple(
+                    init_layer(k, f, cfg.attention_layer(i))
+                    for i, (k, f) in enumerate(zip(layer_keys, ffn_of)))
             ),
         }
         if not cfg.tie_embeddings:
@@ -654,7 +741,7 @@ class DMoETransformerLM:
 
     def param_shardings(self, params_shape: Params) -> Params:
         """Replicated everywhere except the expert stacks."""
-        moe = self.moe.param_shardings()
+        moe = self.moe.param_shardings() if self.moe is not None else {}
         repl = NamedSharding(self.mesh, P())
 
         def assign(path, leaf):
@@ -690,16 +777,47 @@ class DMoETransformerLM:
     def _layer(self, lp, x, layer_idx, token_mask, kind: AttentionLayer):
         """One block.  ``kind`` (static) is the layer's attention,
         ``cfg.attention_layer(layer_idx)``.  Returns ``(x, aux)``; ``aux``
-        is None for a dense layer."""
+        is None for a layer that routes nothing and counts nothing."""
         one_mixer = "norm" in lp  # what the layer holds says what it is
         if "ssm" in lp:
             return self._ssm_block(lp, x)
         if one_mixer and "moe" in lp:
             return self._ffn_block(lp, x, None, layer_idx, token_mask)
-        x, attn_in = self._attention_block(lp, x, kind)
+        if "delta" in lp:
+            x, attn_in, extremes = self._delta_block(lp, x)
+        else:
+            x, attn_in = self._attention_block(lp, x, kind)
+            extremes = {}
         if one_mixer:
             return x, None
-        return self._ffn_block(lp, x, attn_in, layer_idx, token_mask)
+        x, aux = self._ffn_block(lp, x, attn_in, layer_idx, token_mask)
+        return x, {**(aux or {}), **extremes} or None
+
+    def _part_input(self, norm_p, x):
+        """What a part of a layer reads: the normalized stream, or the
+        stream as it is where the norm is on the part's output."""
+        return self._norm(norm_p, x) if self.cfg.norm_place == "input" else x
+
+    def _add_part(self, norm_p, x, out):
+        """The stream after a part gave ``out``: ``x + out``, or ``x +
+        norm(out)`` where the norm is on the part's output."""
+        if self.cfg.norm_place == "input":
+            return x + out
+        return x + self._norm(norm_p, out)
+
+    def _delta_block(self, lp, x):
+        """The stream after the layer's delta-rule mixer, what the mixer
+        read, and the two extremes the step's metrics keep."""
+        cfg = self.cfg
+        with jax.named_scope("delta"):
+            mixer_in = self._part_input(lp["ln1"], x)
+            out, _, decay_min, beta_max = delta_mixer(
+                lp["delta"], mixer_in, cfg.n_heads, cfg.delta_chunk,
+                cfg.norm_eps,
+            )
+            x = self._add_part(lp["ln1"], x, out)
+        return x, mixer_in, {
+            "delta_decay_min": decay_min, "delta_beta_max": beta_max}
 
     def _ssm_block(self, lp, x):
         """``x + Mixer(norm(x))``, the Mamba-2 mixer; ``aux`` is the
@@ -713,8 +831,8 @@ class DMoETransformerLM:
         return x + out, {"ssm_decay_min": decay_min}
 
     def _attention_block(self, lp, x, kind: AttentionLayer):
-        """The stream after the layer's attention, and the normalized
-        input the attention read (a router placed before it reads that)."""
+        """The stream after the layer's attention, and the input the
+        attention read (a router placed before it reads that)."""
         s = x.shape[1]
         # where a stack has both kinds, the scope says which this one is
         scope = "attention" if self.cfg.layer_pattern is None else (
@@ -722,7 +840,8 @@ class DMoETransformerLM:
         )
         with jax.named_scope(scope):
             # a layer of one mixer has ONE norm
-            attn_in = self._norm(lp["ln1" if "ln1" in lp else "norm"], x)
+            norm_p = lp["ln1" if "ln1" in lp else "norm"]
+            attn_in = self._part_input(norm_p, x)
             q, k, v = self._qkv(
                 lp, attn_in,
                 # under the zigzag ring the stream is in zigzag order
@@ -734,7 +853,7 @@ class DMoETransformerLM:
                     q, k, v, self.attn_impl, kind.window
                 )
             )
-            x = x + output_projection(lp, core(q, k, v))
+            x = self._add_part(norm_p, x, output_projection(lp, core(q, k, v)))
         return x, attn_in
 
     @staticmethod
@@ -748,10 +867,13 @@ class DMoETransformerLM:
         dense gated block (``ffn``: no router, ``aux`` None), or the
         mixture (``moe``) and beside it the shared expert (``shared``)."""
         b, s, d = x.shape
-        ffn_in = self._norm(self._ffn_norm(lp), x)
+        norm_p = self._ffn_norm(lp)
+        ffn_in = self._part_input(norm_p, x)
         if "ffn" in lp:
             with jax.named_scope("dense_ffn"):
-                return x + gated_mlp(lp["ffn"], ffn_in, self.moe._gate_act), None
+                return self._add_part(
+                    norm_p, x, gated_mlp(lp["ffn"], ffn_in, self._gate_act)
+                ), None
         moe_in = ffn_in.reshape(b * s, d)
         # layer index salts the router jitter: decorrelates the
         # deterministic noise pattern across layers (round-2 advisor)
@@ -763,11 +885,17 @@ class DMoETransformerLM:
                 if self.cfg.router_input == "attention_input" else None
             ),
         )
-        x = x + moe_out.reshape(b, s, d)
+        if self.cfg.norm_place == "input":
+            x = x + moe_out.reshape(b, s, d)
+            if "shared" in lp:
+                with jax.named_scope("shared_expert"):
+                    x = x + gated_mlp(lp["shared"], ffn_in, self._gate_act)
+            return x, aux
+        out = moe_out.reshape(b, s, d)  # ONE norm over all the part gave
         if "shared" in lp:
             with jax.named_scope("shared_expert"):
-                x = x + gated_mlp(lp["shared"], ffn_in, self.moe._gate_act)
-        return x, aux
+                out = out + gated_mlp(lp["shared"], ffn_in, self._gate_act)
+        return x + self._norm(norm_p, out), aux
 
     def _hidden(
         self, params: Params, token_ids: jax.Array,
@@ -829,23 +957,25 @@ class DMoETransformerLM:
                 token_mask = token_mask[:, self._zig]
         aux_total = None
         counts = []
-        decay_mins: list = []  # a state-space layer's least decay, each
+        extremes: dict = {}  # a recurrent layer's least decay, .., each
 
         def add(aux):
             """A mixture layer's aux into the stack's sums; its
-            assignments per expert stay a row of their own.  A
-            state-space layer's is its smallest decay alone."""
+            assignments per expert stay a row of their own.  What a
+            recurrent layer reports (``_EXTREMES``) is kept a layer each."""
             nonlocal aux_total
-            if "ssm_decay_min" in aux:
-                decay_mins.append(aux["ssm_decay_min"])
-                return
+            aux = dict(aux)
+            for name in _EXTREMES:
+                if name in aux:
+                    extremes.setdefault(name, []).append(aux.pop(name))
             if "expert_counts" in aux:
                 counts.append(aux.pop("expert_counts"))
-            aux_total = (
-                aux
-                if aux_total is None
-                else {k: aux_total[k] + aux[k] for k in aux_total}
-            )
+            if aux:
+                aux_total = (
+                    aux
+                    if aux_total is None
+                    else {k: aux_total[k] + aux[k] for k in aux_total}
+                )
 
         for i in range(cfg.n_layers):
             with jax.named_scope(f"layer_{i}"):
@@ -867,13 +997,14 @@ class DMoETransformerLM:
                 )
             add(aux)
             n_moe += 1
-        aux_mean = {k: v / n_moe for k, v in aux_total.items()}
+        # a stack with no mixture layer has no router sums at all
+        aux_mean = {k: v / n_moe for k, v in (aux_total or {}).items()}
         if cfg.router_bias:  # [mixture layers, E]: the balancing rule's
             aux_mean["expert_counts"] = jnp.stack(counts)
-        if decay_mins:
-            # the smallest exp(dt A) the step saw: neither frozen at 1 nor
-            # forgetting everything
-            aux_mean["ssm_decay_min"] = jnp.min(jnp.stack(decay_mins))
+        for name, values in extremes.items():
+            # over the layers: the smallest decay the step saw (neither
+            # frozen at 1 nor forgetting everything), the largest write
+            aux_mean[name] = _EXTREMES[name](jnp.stack(values))
         if next_ids is not None:
             return x, x_mtp, aux_mean
         return x, aux_mean
@@ -1011,6 +1142,15 @@ class DMoETransformerLM:
             # buffer and fail at trace time on .at[:, 0]
             return prompt_ids
         if use_cache:
+            if self._delta or self.cfg.norm_place != "input":
+                raise NotImplementedError(
+                    "use_cache=True with a 'delta' layer or a norm on a "
+                    "part's output (norm_place): the KV-cache decoder runs "
+                    "softmax attention behind a norm on its input in every "
+                    "layer; a delta layer's recurrent state (and its "
+                    "convolutions' last inputs) beside the KV cache is not "
+                    "built; decode without the cache"
+                )
             if self.cfg.mixer_pattern is not None:
                 raise NotImplementedError(
                     "use_cache=True with mixer_pattern: the KV-cache "
@@ -1257,11 +1397,13 @@ class DMoETransformerLM:
         with jax.named_scope("ce"):
             head = self._head(params)
             ce = self._chunked_ce(x, head, targets)
-        loss = (
-            ce
-            + self.cfg.aux_loss_weight * aux["aux_loss"]
-            + self.cfg.router_z_weight * aux["router_z_loss"]
-        )
+        loss = ce
+        if "aux_loss" in aux:  # a stack with no mixture has no such term
+            loss = (
+                ce
+                + self.cfg.aux_loss_weight * aux["aux_loss"]
+                + self.cfg.router_z_weight * aux["router_z_loss"]
+            )
         metrics = {"ce": ce, **aux}
         if self.cfg.mtp_layers:
             # position i of the block's stream against t_{i+2}, the row's
@@ -1413,7 +1555,7 @@ class DMoETransformerLM:
         whole = jax.jit(self._layer, static_argnums=(4,))
         scores = jax.jit(lambda lp, x: jax.nn.sigmoid(self.moe.router_logits(
             lp["moe"],
-            self._norm(self._ffn_norm(lp), x).reshape(-1, cfg.d_model))))
+            self._part_input(self._ffn_norm(lp), x).reshape(-1, cfg.d_model))))
         streams = [embed(params["embed"], ids) for ids in token_batches]
         layers, loads = list(params["layers"]), []
         if cfg.router_input != "moe_input":

@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from learning_at_home_tpu.ops.delta_rule import gated_delta_chunked
 from learning_at_home_tpu.ops.ssd import ssd_chunked
 from learning_at_home_tpu.ops.ssm_conv import causal_conv_silu
 
@@ -151,6 +152,14 @@ def squared_relu(h: jax.Array) -> jax.Array:
     return jnp.square(jax.nn.relu(h))
 
 
+def gate_activation(expert_kind: str):
+    """The activation of the blocks without biases (an expert, a dense
+    layer's block, the shared expert): on the gate branch of the gated
+    kinds, on the one branch of 'relu2' (its square)."""
+    return {"gated_relu": jax.nn.relu, "relu2": squared_relu}.get(
+        expert_kind, jax.nn.silu)
+
+
 def gated_mlp(p: dict, x: jax.Array, act=jax.nn.silu) -> jax.Array:
     """A dense feed-forward block on [.., d], no biases (a layer's dense
     feed-forward part, or the shared expert every token passes beside the
@@ -226,6 +235,78 @@ def ssm_mixer(
         ).astype(u.dtype)
     with jax.named_scope("out_proj"):
         return y @ p["w_out"].astype(u.dtype), state, decay_min
+
+
+def delta_mixer(
+    p: dict, x: jax.Array, n_heads: int, chunk: int, eps: float = 1e-5,
+    decay_dtype=jnp.float32,
+):
+    """The gated delta-rule mixer (Gated DeltaNet, arXiv:2412.06464) on the
+    stream ``x`` [B, S, d] as the layer hands it over: ``(out [B, S, d],
+    the recurrent state after the last position [B, H, dk, dv] float32,
+    the smallest decay alpha any position saw, the largest write strength
+    beta)``.
+
+    ``[q | k | v | z | b | a] = x W_in`` (no bias; ``b`` and ``a`` in
+    float32, the rest in ``x``'s dtype); ``q, k, v =
+    silu(conv(.))``, a causal depthwise convolution WITHOUT a bias, zeros
+    before the sequence (:func:`~learning_at_home_tpu.ops.ssm_conv.
+    causal_conv_silu`: ``[q | k]`` one call at column 0, ``v`` one at the
+    column where it lies, so each is read where the product left it);
+    per head ``q <- q / sqrt(sum q^2 + 1e-6) / sqrt(dk)`` and ``k <- k /
+    sqrt(sum k^2 + 1e-6)``; ``beta = 2 sigmoid(b)`` (the 2 lets the
+    transition ``alpha (I - beta k k^T)`` have a negative eigenvalue,
+    arXiv:2411.12537); ``g = -exp(A_log) softplus(a + dt_bias)``, float32,
+    ``alpha = exp(g)``; the recurrence ``S_t = alpha_t S_{t-1} + beta_t
+    k_t (v_t - (alpha_t S_{t-1})^T k_t)^T``, ``o_t = S_t^T q_t`` in chunks
+    of ``chunk`` (:func:`~learning_at_home_tpu.ops.delta_rule.
+    gated_delta_chunked`); ``y = RMSNorm(o) * silu(z)``, the norm FIRST
+    (over each head's ``dv``, one scale shared by the heads), then the
+    gate (Mamba-2's mixer gates first); ``out = y W_out``.  The parameters
+    say the sizes: a head's value size ``dv`` is ``w_out``'s input width
+    over ``n_heads``, its key size ``dk`` what the convolution's channels
+    leave beyond ``v`` over ``2 H``.  Sub-scopes ``in_proj``, ``conv``,
+    ``core``, ``gate_norm``, ``out_proj``."""
+    b, s, _ = x.shape
+    f32 = jnp.float32
+    d_v = p["w_out"].shape[0]
+    d_qk = p["conv_w"].shape[0] - d_v  # q's and k's channels together
+    dk, dv = d_qk // (2 * n_heads), d_v // n_heads
+    with jax.named_scope("in_proj"):
+        w_in = p["w_in"].astype(x.dtype)
+        proj = x @ w_in
+        z = proj[..., d_qk + d_v:d_qk + 2 * d_v]
+        # what the write strengths and the decays are made of leaves its
+        # product in float32 (2 H columns: a product of their own): rounded
+        # to bf16, a pre-activation of 10 is off by 0.03, and sigmoid and
+        # exp turn that into 3 % of a small strength and, times exp(A_log),
+        # half of a decay's logarithm, which the state then carries
+        write, step = jnp.split(jnp.einsum(
+            "bsd,dn->bsn", x, w_in[:, d_qk + 2 * d_v:],
+            preferred_element_type=f32), 2, axis=-1)
+    with jax.named_scope("conv"):
+        qk = causal_conv_silu(proj, p["conv_w"][:d_qk], None)
+        v = causal_conv_silu(proj, p["conv_w"][d_qk:], None, first=d_qk)
+    with jax.named_scope("core"):
+        qk = qk.reshape(b, s, 2, n_heads, dk).astype(f32)
+        qk = qk * jax.lax.rsqrt(jnp.sum(qk * qk, axis=-1, keepdims=True) + 1e-6)
+        q = (qk[:, :, 0] * dk ** -0.5).astype(x.dtype)
+        k = qk[:, :, 1].astype(x.dtype)
+        beta = 2.0 * jax.nn.sigmoid(write)
+        g = -jnp.exp(p["A_log"].astype(f32)) * jax.nn.softplus(
+            step + p["dt_bias"].astype(f32))
+        o, state = gated_delta_chunked(
+            q, k, v.reshape(b, s, n_heads, dv), g, beta, chunk, decay_dtype)
+        decay_min, beta_max = jnp.exp(jnp.min(g)), jnp.max(beta)
+    with jax.named_scope("gate_norm"):
+        o = o.astype(f32)
+        ms = jnp.mean(o * o, axis=-1, keepdims=True)
+        y = (
+            o * jax.lax.rsqrt(ms + eps) * p["gate_norm"]["scale"]
+        ).reshape(b, s, d_v) * jax.nn.silu(z.astype(f32))
+    with jax.named_scope("out_proj"):
+        out = y.astype(x.dtype) @ p["w_out"].astype(x.dtype)
+    return out, state, decay_min, beta_max
 
 
 def causal_attention(

@@ -57,14 +57,18 @@ _ROWS, _CHANNELS, _STRIP = 1024, 512, 32
 
 
 def causal_conv_silu(
-    x: jax.Array, w: jax.Array, b: jax.Array, first: int = 0,
+    x: jax.Array, w: jax.Array, b: jax.Array | None, first: int = 0,
 ) -> jax.Array:
     """``silu(conv(x) + b)`` as above, in ``x``'s dtype, of the ``C``
     channels of ``x`` [B, S, >= first + C] that start at ``first`` (a
     projection's output, read where the projection left it: the kernel
-    takes its blocks from the wider array and no slice is written).  The
-    kernel where :func:`conv_kernel_fits` says so, the plain form (on the
-    slice) elsewhere."""
+    takes its blocks from the wider array and no slice is written).  ``b``
+    None: a convolution without a bias (zeros that are no parameter: they
+    are made here and get no gradient a caller could see).  The kernel
+    where :func:`conv_kernel_fits` says so, the plain form (on the slice)
+    elsewhere."""
+    if b is None:
+        b = jnp.zeros((w.shape[0],), jnp.float32)
     shape = (*x.shape[:2], w.shape[0])
     if conv_kernel_fits(shape, w.shape[1], jax.default_backend(), first):
         return causal_conv_silu_kernel(x, w, b, first)

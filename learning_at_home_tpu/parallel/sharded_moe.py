@@ -51,7 +51,7 @@ from learning_at_home_tpu.ops.moe_dispatch import (
     top_k_gating_indices,
     unsort_combine,
 )
-from learning_at_home_tpu.models.trunk import squared_relu
+from learning_at_home_tpu.models.trunk import gate_activation
 from learning_at_home_tpu.parallel.mesh import data_axes
 
 Params = dict[str, jax.Array]
@@ -232,11 +232,7 @@ class ShardedMixtureOfExperts:
         self.router_score = router_score
         self.router_bias = router_bias
         self.routed_scale = routed_scale
-        # the activation of the kinds without biases: on the gate branch
-        # of the gated kinds, on the one branch of 'relu2' (its square)
-        self._gate_act = {
-            "gated_relu": jax.nn.relu, "relu2": squared_relu,
-        }.get(expert_kind, jax.nn.silu)
+        self._gate_act = gate_activation(expert_kind)
         if routing == "dropless" and (self.ep > 1 or self.tp > 1):
             raise NotImplementedError(
                 f"routing='dropless' on a mesh with expert={self.ep}, "

@@ -577,7 +577,7 @@ def test_the_share_path_equals_masked_dense_experts_and_its_gradients():
 
 @pytest.mark.parametrize("changes, error, match", [
     ({"ffn_pattern": ("dense",) * 4}, ValueError, "each of the 5"),
-    ({"ffn_pattern": ("dense",) * 5}, ValueError, "no 'moe' layer"),
+    ({"ffn_pattern": ("dense",) * 5}, ValueError, "has no router"),
     ({"dense_ffn_dim": None}, ValueError, "dense_ffn_dim"),
     ({"qk_norm": "heads"}, ValueError, "qk_norm"),
     ({"routing": "capacity"}, NotImplementedError, "sigmoid"),
@@ -591,6 +591,31 @@ def test_a_configuration_the_step_cannot_run_is_refused_by_name(
     _, cfg, _, _, _ = tiny
     with pytest.raises(error, match=match):
         DMoETransformerLM(dataclasses.replace(cfg, **changes), _one_device_mesh())
+
+
+def test_a_stack_of_dense_layers_alone_builds_and_steps(tiny):
+    """No 'moe' layer at all (the refusal went with PR 45): the same block
+    with a dense feed-forward part in every layer and no selection bias
+    builds no router and no expert, and its step's loss is the
+    cross-entropy alone."""
+    _, cfg, _, ids, tgt = tiny
+    cfg = dataclasses.replace(
+        cfg, ffn_pattern=("dense",) * 5, router_bias=False, shared_experts=0)
+    model = DMoETransformerLM(cfg, _one_device_mesh())
+    assert model.moe is None and cfg.mixture_layers() == 0
+    params = model.init_params(jax.random.PRNGKey(2))
+    assert all("ffn" in lp and "moe" not in lp for lp in params["layers"])
+    from learning_at_home_tpu.ops.fused_adafactor import fused_adafactor
+
+    optimizer = fused_adafactor(1e-3)
+    opt_state = model.init_opt_state(optimizer, params)
+    step = model.make_train_step(optimizer)
+    losses = []
+    for _ in range(4):
+        params, opt_state, loss, metrics = step(params, opt_state, ids, tgt)
+        losses.append(float(loss))
+    assert set(metrics) == {"ce"} and float(metrics["ce"]) == losses[-1]
+    assert losses[-1] < losses[0]
 
 
 def test_a_share_across_chips_and_the_cached_decoder_refuse_by_name(tiny):

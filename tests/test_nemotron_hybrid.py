@@ -928,3 +928,37 @@ def test_the_convolutions_kernels_compile_for_the_chip_at_the_cells_shape(v5e_ch
     calls = probe.scan_kernel_calls(text, "ssm_conv", "ssm/conv")
     assert {name: entry["calls"] for name, entry in calls.items()} == {
         "ssm_conv_fwd": 3, "ssm_conv_bwd": 3}
+
+
+def test_the_convolutions_kernels_compile_at_the_delta_mixers_shape(v5e_chip):
+    """The same two kernels as Olmo-Hybrid's delta mixer calls them
+    (``trunk.delta_mixer``; here because the described chip's library is
+    one file's to load): ``[q | k]`` as ONE call of 5,760 channels at
+    column 0 and ``v`` as one at column 5,760 of the in-projection's ``[1,
+    16384, 17340]`` bf16, four taps, no bias: blocks of 384 channels, the
+    last of a wide array that is no multiple of the lanes."""
+    one = jax.sharding.SingleDeviceSharding(v5e_chip)
+    olmo = harness.load_json(os.path.join(
+        REPO, "benchmarks", "configs", "olmo-hybrid-7b.json"))
+    s, taps = olmo["seq_len"], olmo["linear_conv_kernel_dim"]
+    heads = olmo["linear_num_key_heads"]
+    d_qk = 2 * heads * olmo["linear_key_head_dim"]
+    d_v = heads * olmo["linear_value_head_dim"]
+    wide = d_qk + 2 * d_v + 2 * heads
+    assert (s, taps, d_qk, d_v, wide) == (16384, 4, 5760, 5760, 17340)
+    assert all(ssm_conv.conv_kernel_fits((1, s, 5760), taps, "tpu", first)
+               for first in (0, d_qk))
+
+    def loss(proj, w):
+        return sum(jnp.sum(ssm_conv.causal_conv_silu_kernel(
+            proj, w[first:first + 5760], jnp.zeros((5760,), jnp.float32),
+            first).astype(jnp.float32) ** 2) for first in (0, d_qk))
+
+    with probe.no_compile_cache():
+        text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+            jax.ShapeDtypeStruct((1, s, wide), jnp.bfloat16, sharding=one),
+            jax.ShapeDtypeStruct((d_qk + d_v, taps), jnp.bfloat16, sharding=one),
+        ).compile().as_text()
+    calls = probe.scan_kernel_calls(text, "ssm_conv", "ssm/conv")
+    assert {name: entry["calls"] for name, entry in calls.items()} == {
+        "ssm_conv_fwd": 2, "ssm_conv_bwd": 2}

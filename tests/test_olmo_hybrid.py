@@ -1,0 +1,575 @@
+"""Olmo-Hybrid-7B in the pod step as one chip of a four-chip host
+(``__graft_entry__.olmo_hybrid_7b_one_chip``) against its plain reference
+(``benchmarks/configs/olmo_hybrid_7b_reference.py``): gated delta-rule
+layers three to every full-attention layer, the norm on each part's
+OUTPUT, a stack with no mixture layer; the refusals beside that path; the
+cut's arithmetic; and the benchmark's files for it.
+
+Tiny sizes on the CPU.
+"""
+
+import dataclasses
+import json
+import os
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, os.path.join(REPO, "benchmarks"))
+
+import harness  # noqa: E402  (benchmarks/harness.py: imports no jax)
+import olmohybrid_flops  # noqa: E402
+
+from __graft_entry__ import k_exaone_one_chip, olmo_hybrid_7b_one_chip  # noqa: E402
+from learning_at_home_tpu.models import trunk  # noqa: E402
+from learning_at_home_tpu.models.transformer import (  # noqa: E402
+    AttentionLayer,
+    DMoETransformerLM,
+)
+from learning_at_home_tpu.ops import ssm_conv  # noqa: E402
+from learning_at_home_tpu.parallel.mesh import make_mesh  # noqa: E402
+
+REFERENCE = os.path.join(
+    REPO, "benchmarks", "configs", "olmo_hybrid_7b_reference.py")
+reference = harness.load_path(REFERENCE)
+runner = harness.load_path(os.path.join(
+    REPO, "benchmarks", "runners", "train_recipe_delta.py"))
+TINY_FILE = harness.load_json(os.path.join(
+    REPO, "benchmarks", "rehearsal", "configs", "olmohybrid-tiny.json"))
+CELL_FILE = harness.load_json(os.path.join(
+    REPO, "benchmarks", "configs", "olmo-hybrid-7b.json"))
+CELL = "olmo-hybrid-7b-train-zipf16k"
+SIZES = runner.reference_sizes(TINY_FILE)  # what the runner hands the reference
+
+
+def _one_device_mesh():
+    return make_mesh({"expert": 1}, devices=jax.devices()[:1])
+
+
+def _decisive(params, seed=7):
+    """Seeded weights under which every part of the stack decides: norm
+    scales off 1, and the decays' columns of the delta in-projections
+    (the last, one a head) small enough that states outlive a chunk (the
+    parts read an un-normalized stream: at the init's scale most positions
+    forget everything, and the chunked rule would carry nothing)."""
+    rs = np.random.RandomState(seed)
+
+    def leaf(path, a):
+        name = jax.tree_util.keystr(path)
+        if name.endswith("['scale']"):
+            return a * jnp.asarray(rs.uniform(0.5, 1.5, a.shape), a.dtype)
+        if name.endswith("['delta']['w_in']"):
+            return a.at[:, -4:].multiply(0.02)  # the tiny recipe's 4 heads
+        return a
+
+    return jax.tree_util.tree_map_with_path(leaf, params)
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    model, cfg, _, batch = olmo_hybrid_7b_one_chip(_one_device_mesh(), tiny=True)
+    params = _decisive(model.init_params(jax.random.PRNGKey(0)))
+    rng = np.random.default_rng(11)
+    ids = jnp.asarray(rng.integers(0, cfg.vocab_size, (batch, cfg.seq_len)), jnp.int32)
+    tgt = jnp.asarray(rng.integers(0, cfg.vocab_size, (batch, cfg.seq_len)), jnp.int32)
+    return model, cfg, params, ids, tgt
+
+
+def _close(got, want, tol=1e-4):
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    np.testing.assert_allclose(got, want, atol=tol * max(np.abs(want).max(), 1e-6))
+
+
+# ---- (a) the stack ----
+
+
+def test_the_tiny_recipe_keeps_the_stack(tiny):
+    """Two periods L L L F, both kinds of layer, keys narrower than values,
+    several chunks a row, a dense block in every layer, and no mixture:
+    no router, no expert, no selection bias anywhere in the tree."""
+    model, cfg, params, _, _ = tiny
+    kinds = [cfg.attention_layer(i).mixer for i in range(cfg.n_layers)]
+    assert kinds == ["delta", "delta", "delta", "softmax"] * 2
+    assert cfg.delta_key_dim != cfg.delta_value_dim
+    assert cfg.seq_len // cfg.delta_chunk == 4
+    assert cfg.mixture_layers() == 0 and model.moe is None
+    assert cfg.norm_place == "output" and not cfg.tie_embeddings
+    for i, lp in enumerate(params["layers"]):
+        assert ("delta" in lp) == (kinds[i] == "delta")
+        assert ("wq" in lp) == ("q_norm" in lp) == (kinds[i] == "softmax")
+        assert set(lp) >= {"ln1", "ln2", "ffn"} and "moe" not in lp
+    delta = params["layers"][0]["delta"]
+    h, dk, dv = cfg.n_heads, cfg.delta_key_dim, cfg.delta_value_dim
+    assert delta["w_in"].shape == (cfg.d_model, 2 * h * dk + 2 * h * dv + 2 * h)
+    assert delta["conv_w"].shape == (2 * h * dk + h * dv, 4)
+    assert delta["gate_norm"]["scale"].shape == (dv,)
+    assert "conv_b" not in delta  # no trained bias joins the tree
+    names = "".join(jax.tree_util.keystr(p) for p, _ in
+                    jax.tree_util.tree_flatten_with_path(params)[0])
+    assert "gate'" not in names.replace("w_gate'", "").replace("gate_norm'", "")
+    assert "router" not in names and "moe" not in names
+
+
+def test_the_published_recipe_keeps_its_decays_in_float32():
+    model, cfg, _, batch = olmo_hybrid_7b_one_chip(_one_device_mesh())
+    shapes = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
+    delta = shapes["layers"][0]["delta"]
+    assert {k: v.dtype for k, v in delta.items() if k in ("A_log", "dt_bias")} == {
+        "A_log": jnp.float32, "dt_bias": jnp.float32}
+    assert delta["w_in"].dtype == delta["conv_w"].dtype == jnp.bfloat16
+    assert delta["w_in"].shape == (3840, 17340) and batch == 1
+    assert model.moe is None
+
+
+@pytest.mark.parametrize("chunk", [64, 16, 8])
+def test_the_delta_mixer_matches_the_rule_as_written(tiny, chunk):
+    """The program's mixer (chunks of 64: one; 16: four; 8: eight, with a
+    solve of one block) against the reference's scan over the positions:
+    output and the state after the last position."""
+    model, cfg, params, ids, _ = tiny
+    lp = params["layers"][1]
+    x = jax.random.normal(jax.random.PRNGKey(2), (2, cfg.seq_len, cfg.d_model))
+    got, state, decay_min, beta_max = trunk.delta_mixer(
+        lp["delta"], x, cfg.n_heads, chunk, cfg.norm_eps)
+    want, want_state = reference.delta_part(lp, x, SIZES)
+    _close(got, want)
+    _close(state, want_state)
+    assert 0.0 < float(decay_min) < 1.0 < float(beta_max) <= 2.0
+
+
+def test_the_convolutions_read_zeros_before_the_sequence_and_have_no_bias(tiny):
+    """Position 0 of q, k and v is ``silu(w[:, 3] * input[0])``: nothing
+    before the row, nothing added."""
+    _, cfg, params, _, _ = tiny
+    p = params["layers"][0]["delta"]
+    x = jax.random.normal(jax.random.PRNGKey(3), (1, 8, cfg.d_model))
+    q, k, v, *_ = reference.delta_inputs(
+        jax.tree_util.tree_map(lambda a: a.astype(jnp.float32), p), x, SIZES)
+    proj = x @ p["w_in"]
+    n = p["conv_w"].shape[0]
+    first = jax.nn.silu(p["conv_w"][:, 3] * proj[0, 0, :n])
+    d_qk = 2 * cfg.n_heads * cfg.delta_key_dim
+    _close(v[0, 0].ravel(), first[d_qk:])
+    got = ssm_conv.causal_conv_silu(proj, p["conv_w"][d_qk:], None, first=d_qk)
+    _close(got[0, 0], first[d_qk:])
+    _close(got, v.reshape(1, 8, -1))
+
+
+def test_logits_and_loss_of_the_whole_stack_match_the_reference(tiny):
+    """float32 on both sides: what is left is the order of the sums (the
+    chunked rule against the scan over positions, the chunked
+    cross-entropy against the whole softmax)."""
+    model, _, params, ids, tgt = tiny
+    logits, _ = model.apply(params, ids)
+    _close(logits, reference.forward(params, ids, SIZES), 2e-4)
+    loss, metrics = model.loss_fn(params, ids, tgt)
+    assert abs(float(loss) - float(reference.loss(params, ids, tgt, SIZES))) < 2e-5
+    assert set(metrics) == {"ce", "delta_decay_min", "delta_beta_max"}
+
+
+def test_gradients_of_every_parameter_match_the_reference(tiny):
+    """Every leaf, relative to the leaf's own largest gradient: 1e-3,
+    float32 sums in another order through eight layers and two hundred
+    and fifty-six steps of a recurrence."""
+    model, _, params, ids, tgt = tiny
+    got = jax.grad(lambda p: model.loss_fn(p, ids, tgt)[0])(params)
+    _, want = reference.loss_and_grads(params, ids, tgt, SIZES)
+    flat_got = jax.tree_util.tree_flatten_with_path(got)[0]
+    flat_want = jax.tree_util.tree_leaves(want)
+    assert len(flat_got) == len(flat_want) == len(jax.tree_util.tree_leaves(params))
+    for (path, a), b in zip(flat_got, flat_want):
+        assert float(jnp.abs(b).max()) > 0, jax.tree_util.keystr(path)
+        _close(a, b, 1e-3)
+
+
+# ---- (b) the runner's comparison, and what must fail it ----
+
+
+def _reference_with(**changes):
+    """A copy of the reference module with functions replaced."""
+    broken = harness.load_path(REFERENCE)
+    for name, value in changes.items():
+        setattr(broken, name, value)
+    return broken
+
+
+def _read(model, params, ids, tgt, module=reference, **how):
+    return runner.compare_with_reference(
+        model, params, module, TINY_FILE, ids[:1], tgt[:1], **how)
+
+
+def _outside(read):
+    return [k for k, lim in runner.TOLERANCES.items() if not read[k] <= lim]
+
+
+def test_the_stack_as_it_is_reads_inside_the_runner_tolerances(tiny):
+    model, _, params, ids, tgt = tiny
+    read = _read(model, params, ids, tgt)
+    assert _outside(read) == []
+    assert len(read["embed_and_layers_rms"]) == 9  # the embedding, eight layers
+    assert len(read["delta_layers_rms"]) == len(read["delta_states_rms"]) == 6
+    # the mixer's output by its worst layer, the state by its median layer
+    assert read["delta_rms"] == max(read["delta_layers_rms"])
+    assert read["delta_state_rms"] == pytest.approx(
+        np.median(read["delta_states_rms"]))
+    assert read["delta_state_rms_max"] == max(read["delta_states_rms"])
+    assert read["near_tie_share"] == 0.0
+
+
+WRONG_REFERENCES = {
+    "a_write_strength_without_the_factor_two": (
+        dict(write_strength=jax.nn.sigmoid), ("delta_rms", "delta_state_rms")),
+    "a_gate_applied_before_the_norm": (
+        dict(output_gate=lambda o, z, scale, eps: reference.rms(
+            o * jax.nn.silu(z), scale, eps)), ("delta_rms", "layers_rms")),
+}
+
+
+@pytest.mark.parametrize("name", sorted(WRONG_REFERENCES))
+def test_a_wrong_delta_layer_fails_the_runner_tolerances(tiny, name):
+    """Each read OUTSIDE the tolerance: the comparison can fail.  (The
+    wrong side is the reference's copy; the program is as it is.)"""
+    model, _, params, ids, tgt = tiny
+    changes, outside = WRONG_REFERENCES[name]
+    read = _read(model, params, ids, tgt, _reference_with(**changes))
+    for key in outside:
+        assert not read[key] <= runner.TOLERANCES[key], (key, read[key])
+
+
+@pytest.mark.parametrize("name, changes, outside", [
+    ("the_norm_on_each_parts_input", {"norm_place": "input"},
+     ("layers_rms", "delta_rms")),
+    ("a_rotated_full_attention_layer",
+     {"layer_pattern": (AttentionLayer(None, False, "delta"),) * 3
+      + (AttentionLayer(None, True),)}, ("layers_rms",)),
+])
+def test_a_wrong_program_fails_the_runner_tolerances(tiny, name, changes, outside):
+    """The same weights under a program that norms a part's input, or that
+    rotates the full layers' queries and keys, against the reference as it
+    is."""
+    _, cfg, params, ids, tgt = tiny
+    model = DMoETransformerLM(dataclasses.replace(cfg, **changes), _one_device_mesh())
+    read = _read(model, params, ids, tgt)
+    for key in outside:
+        assert not read[key] <= runner.TOLERANCES[key], (name, key, read[key])
+
+
+def test_a_hidden_that_composes_another_stack_fails_the_runner_tolerances(tiny):
+    """``_hidden`` over a stack whose delta layers are skipped (the layers
+    themselves as they are) reads outside ``hidden_token_median``."""
+    _, cfg, params, ids, tgt = tiny
+    model = DMoETransformerLM(cfg, _one_device_mesh())
+    layer = model._layer
+    model._layer = lambda lp, x, *rest: (
+        (x, None) if "delta" in lp else layer(lp, x, *rest))
+    read = _read(model, params, ids, tgt)
+    assert not read["hidden_token_median"] <= runner.TOLERANCES["hidden_token_median"]
+
+
+def test_lower_precisions_fail_the_runner_tolerances(tiny):
+    """The reference with float8 operands in the program's place reads
+    outside the layer, delta and logits limits, with bf16 operands inside;
+    the program's rule with its decays summed in bf16 reads worse than
+    with float32 sums."""
+    model, _, params, ids, tgt = tiny
+    for dtype, inside in ((jnp.float8_e4m3fn, False), (jnp.bfloat16, True)):
+        read = _read(model, params, ids, tgt, operand_dtype=dtype)
+        for key in ("layers_rms", "delta_rms", "logits_rms"):
+            assert (read[key] <= runner.TOLERANCES[key]) is inside, (dtype, key)
+    exact = _read(model, params, ids, tgt)
+    rough = _read(model, params, ids, tgt, decay_dtype=jnp.bfloat16)
+    assert rough["delta_rms"] > 100 * exact["delta_rms"]
+    assert rough["delta_state_rms"] > 100 * exact["delta_state_rms"]
+
+
+# ---- (c) a stack with no mixture layer ----
+
+
+def test_a_stack_with_no_mixture_trains_and_reports_no_expert_counter(tiny):
+    """The loss falls over a few steps; the step's metrics are the
+    cross-entropy and the delta rule's two counters; neither the parameter
+    tree nor the optimizer's holds a router leaf; the set-up's levelling
+    call and the step's balancing rule return what they were given."""
+    _, _, _, ids, tgt = tiny
+    model, cfg, optimizer, _ = olmo_hybrid_7b_one_chip(_one_device_mesh(), tiny=True)
+    params = model.init_params(jax.random.PRNGKey(1))
+    levelled, loads = model.level_router_bias(params, [ids])
+    assert levelled is params and loads == []
+    assert model._balance(params, model._router_biases(params), None) is params
+    opt_state = model.init_opt_state(optimizer, params)
+    names = "".join(
+        jax.tree_util.keystr(p) for p, _ in
+        jax.tree_util.tree_flatten_with_path((params, opt_state))[0])
+    assert "router" not in names and "'moe'" not in names and "'gate'" not in names
+    step = model.make_train_step(optimizer)
+    losses = []
+    for _ in range(6):
+        params, opt_state, loss, metrics = step(params, opt_state, ids, tgt)
+        losses.append(float(loss))
+    assert losses[-1] < losses[0] - 0.05 and np.isfinite(losses).all()
+    assert set(metrics) == {"ce", "delta_decay_min", "delta_beta_max"}
+    assert float(metrics["ce"]) == pytest.approx(losses[-1])  # no auxiliary term
+    assert 1.0 < float(metrics["delta_beta_max"]) <= 2.0
+    assert 0.0 <= float(metrics["delta_decay_min"]) < 1.0
+
+
+def test_a_stack_with_no_mixture_steps_on_a_data_mesh_as_on_one_device(tiny):
+    """Every leaf replicated, the batch over ``data``: the same loss as on
+    one device (the delta rule's scans and the convolution partition over
+    the rows of the batch)."""
+    from learning_at_home_tpu.parallel.mesh import batch_sharding
+
+    single, _, params, ids, tgt = tiny
+    want, _ = jax.jit(single.loss_fn)(params, ids, tgt)
+    mesh = make_mesh({"data": 2, "expert": 1}, devices=jax.devices()[:2])
+    model, _, optimizer, _ = olmo_hybrid_7b_one_chip(mesh, tiny=True)
+    placed = jax.device_put(  # copies: the step donates what it is given
+        jax.tree_util.tree_map(jnp.copy, params), model.param_shardings(params))
+    opt_state = model.init_opt_state(optimizer, placed)
+    rows = [jax.device_put(a, batch_sharding(mesh)) for a in (ids, tgt)]
+    _, _, loss, metrics = model.make_train_step(optimizer)(placed, opt_state, *rows)
+    assert abs(float(loss) - float(want)) < 1e-5
+    assert set(metrics) == {"ce", "delta_decay_min", "delta_beta_max"}
+
+
+def test_the_norm_on_the_output_of_a_mixture_layer_spans_all_the_part_gave():
+    """``norm_place='output'`` where the feed-forward part is a mixture
+    beside a shared expert: ONE norm over their sum, and the attention's
+    over its out-projection; each part reads the stream as it is."""
+    model, cfg, _, _ = k_exaone_one_chip(_one_device_mesh(), tiny=True)
+    cfg = dataclasses.replace(cfg, norm_place="output")
+    model = DMoETransformerLM(cfg, _one_device_mesh())
+    params = model.init_params(jax.random.PRNGKey(4))
+    lp = params["layers"][1]  # a mixture layer with a shared expert
+    assert "moe" in lp and "shared" in lp
+    x = jax.random.normal(jax.random.PRNGKey(5), (2, cfg.seq_len, cfg.d_model))
+    kind = cfg.attention_layer(1)
+    got, _ = jax.jit(lambda lp, x: model._layer(lp, x, 1, None, kind))(lp, x)
+
+    @jax.jit
+    def by_hand(lp, x):
+        q, k, v = model._qkv(lp, x, np.arange(cfg.seq_len), kind.rotary)
+        h = x + model._norm(lp["ln1"], trunk.output_projection(
+            lp, trunk.attention_core(q, k, v, "xla", kind.window)))
+        routed, _ = model.moe(
+            lp["moe"], h.reshape(-1, cfg.d_model), jitter_salt=1)
+        shared = trunk.gated_mlp(lp["shared"], h, model._gate_act)
+        return h + model._norm(lp["ln2"], routed.reshape(h.shape) + shared)
+
+    want = by_hand(lp, x)
+    _close(got, want, 1e-5)
+    before, _ = jax.jit(lambda lp, x: DMoETransformerLM(
+        dataclasses.replace(cfg, norm_place="input"), _one_device_mesh()
+    )._layer(lp, x, 1, None, kind))(lp, x)
+    assert float(jnp.abs(before - got).max()) > 0.1
+
+
+# ---- (d) refusals ----
+
+
+@pytest.mark.parametrize("changes, error, match", [
+    ({"seq_parallel": True}, NotImplementedError, "'delta' layer"),
+    ({"delta_key_dim": None}, ValueError, "delta_key_dim"),
+    ({"norm_place": "after"}, ValueError, "norm_place"),
+    ({"layer_pattern": (AttentionLayer(None, False, "linear"),)}, ValueError,
+     "'softmax' or 'delta'"),
+    ({"router_bias": True}, ValueError, "no 'moe' layer"),
+    ({"mtp_layers": 1, "mtp_loss_weight": 0.1}, ValueError, "no 'moe' layer"),
+    ({"ffn_pattern": None, "mixer_pattern": ("attention", "moe") * 4},
+     ValueError, "no 'delta' layer"),
+    ({"ffn_pattern": ("dense",) * 7}, ValueError, "each of the 8"),
+])
+def test_a_configuration_the_step_cannot_run_is_refused_by_name(
+        tiny, changes, error, match):
+    _, cfg, _, _, _ = tiny
+    mesh = _one_device_mesh()
+    if changes.get("seq_parallel"):
+        mesh = make_mesh({"seq": 2}, devices=jax.devices()[:2])
+    with pytest.raises(error, match=match):
+        DMoETransformerLM(dataclasses.replace(cfg, **changes), mesh)
+
+
+def test_the_cached_decoder_refuses_the_delta_layer_by_name(tiny):
+    model, _, params, ids, _ = tiny
+    with pytest.raises(NotImplementedError, match="'delta' layer"):
+        model.generate(params, ids[:, :4], 4, use_cache=True)
+    out = model.generate(params, ids[:, :4], 3)  # the re-forward path runs it
+    assert out.shape == (ids.shape[0], 7)
+
+
+# ---- (e) the cut's arithmetic and the benchmark's files ----
+
+
+@pytest.mark.parametrize("first, channels, fits", [
+    (0, 5760, True), (5760, 5760, True), (0, 2880, False)])
+def test_the_convolution_kernel_takes_q_and_k_as_one_call(first, channels, fits):
+    """[q | k] as ONE call of 5,760 channels at column 0 and v as one at
+    column 5,760 of the 17,340-wide in-projection: both on a channel
+    block's edge (384).  2,880 channels are 22.5 lane tiles: the plain
+    path."""
+    assert ssm_conv.conv_kernel_fits(
+        (1, 16384, channels), 4, "tpu", first) is fits
+    assert not ssm_conv.conv_kernel_fits((1, 16384, channels), 4, "cpu", first)
+    if fits:
+        assert ssm_conv._blocks((1, 16384, channels)) == (1024, 384)
+
+
+def test_parameters_and_flops_of_the_cell_are_the_issue_arithmetic():
+    model, cfg, _, _ = olmo_hybrid_7b_one_chip(_one_device_mesh())
+    shapes = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
+
+    def count(tree):
+        return sum(int(np.prod(l.shape)) for l in jax.tree_util.tree_leaves(tree))
+
+    assert count(shapes["layers"][0]["delta"]) == 88_750_332
+    assert count(shapes["layers"][0]) == 215_570_172
+    assert count(shapes["layers"][3]) == 185_809_920
+    assert count(shapes) == 1_857_720_552
+    runner._check_sizes(CELL_FILE, cfg)  # the file's sizes are the program's
+    forward = olmohybrid_flops.forward_flops_per_token(CELL_FILE)
+    matrices = (forward["delta_projections"] + forward["projections"]
+                + forward["dense_ffn"] + forward["head"])
+    assert matrices == 2 * 1_761_024_000  # two operations a matrix parameter
+    assert forward["delta_recurrence"] == 6 * 6 * 30 * 96 * 192
+    assert olmohybrid_flops.train_flops_per_token(CELL_FILE) == pytest.approx(
+        11.381e9, rel=1e-4)
+    least = olmohybrid_flops.delta_core_least_seconds(CELL_FILE, 16384, "TPU v5 lite")
+    assert least == pytest.approx(
+        olmohybrid_flops.delta_core_bytes(CELL_FILE, 16384) / 819e9)
+    assert 0.0110 < least < 0.0113  # the bytes bind: 9.13 GB; 4.97 ms of arithmetic
+
+
+def test_configuration_file_carries_the_catalog_entry():
+    catalog = "/opt/skills/guides/model-configs/architectures.jsonl"
+    if not os.path.isfile(catalog):
+        pytest.skip("no catalog here")
+    row = next(r for r in map(json.loads, open(catalog))
+               if r["name"] == "Olmo-Hybrid-7B")
+    assert CELL_FILE["source"] == row["source_url"]
+    differs = {k for k, v in row["config"].items() if CELL_FILE.get(k) != v}
+    assert differs == {"vocab_size"} <= set(CELL_FILE["reduced"])
+    assert CELL_FILE["reduced"] == ["n_layers", "vocab_size"]
+    assert (CELL_FILE["vocab_size"] * CELL_FILE["chips_sharing_the_vocabulary"]
+            == CELL_FILE["vocab_size_published"] == 100352)
+    assert CELL_FILE["layer_types"][:8] == (
+        ["linear_attention"] * 3 + ["full_attention"]) * 2
+
+
+def test_the_scope_roofline_reducer_reads_the_delta_core_and_nothing_where_there_is_none():
+    reducer = harness.load_path(os.path.join(
+        REPO, "benchmarks", "reducers", "scope_roofline.py"))
+    spec = harness.load_json(os.path.join(
+        REPO, "benchmarks", "layer_metrics", "olmohybrid.delta_core_roofline.json"))
+    obs = {
+        "device_kind": "TPU v5 lite", "sizes": CELL_FILE,
+        "tokens_per_step_per_chip": 16384, "intervals_s": [2.4, 2.41, 2.39],
+        "trace": {"busy_s": 2.97, "span_s": 3.0},
+        "scopes": {"total_s": 3.0, "by_scope": {"delta/core": 0.45, "delta": 0.1}},
+    }
+    least = olmohybrid_flops.delta_core_least_seconds(CELL_FILE, 16384, "TPU v5 lite")
+    want = 100.0 * least / (0.45 / 3.0 * 0.99 * 2.4)
+    assert reducer.reduce(obs, **spec["args"]) == pytest.approx(want)
+    assert 0 < want < 100
+    # a program without the scope (the parent), a CPU: nothing, no error
+    bare = dict(obs, scopes={"total_s": 3.0, "by_scope": {"attention": 1.0}})
+    assert reducer.reduce(bare, **spec["args"]) is None
+    assert reducer.reduce(dict(obs, device_kind="cpu"), **spec["args"]) is None
+    share = harness.load_path(os.path.join(
+        REPO, "benchmarks", "reducers", "scope_share.py"))
+    whole = harness.load_json(os.path.join(
+        REPO, "benchmarks", "layer_metrics", "olmohybrid.delta_share.json"))
+    assert share.reduce(obs, **whole["args"]) == pytest.approx(100 * 0.55 / 3.0)
+
+
+def test_the_window_refuses_counters_the_delta_rule_cannot_give():
+    ok = {"delta_decay_min": [0.0, 1e-9], "delta_beta_max": [1.9, 2.0]}
+    assert runner.delta_problems(ok) == []
+    assert runner.delta_problems({**ok, "delta_decay_min": [float("nan")]})
+    assert runner.delta_problems({**ok, "delta_beta_max": [0.99]})  # no factor 2
+    assert runner.delta_problems({**ok, "delta_beta_max": [2.5]})
+    assert len(runner.delta_problems({})) == 2  # a program without the counters
+
+
+def test_benchmark_manifests_pass_selfcheck_and_the_runner_rehearses(tmp_path):
+    """``selfcheck.py`` on the manifest and on this configuration's
+    rehearsal, then the new runner for 2 s at tiny sizes on the CPU,
+    untraced and traced."""
+    from learning_at_home_tpu.utils.subproc import clean_jax_subprocess_env
+
+    env = clean_jax_subprocess_env(REPO, platform="cpu")
+    env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "cache")
+    check = subprocess.run(
+        [sys.executable, "benchmarks/selfcheck.py", "BENCHMARK.json",
+         "benchmarks/rehearsal/manifest_olmohybrid.json"],
+        cwd=REPO, env=env, capture_output=True, text=True, timeout=120)
+    assert check.returncode == 0 and "selfcheck: ok" in check.stdout, check.stdout
+    manifest = harness.load_json(os.path.join(REPO, "BENCHMARK.json"))
+    cell = harness.by_name(manifest["workloads"], CELL, "workload")
+    assert (cell["config"], cell["traffic"], cell["chips"]) == (
+        "olmo-hybrid-7b", "train-zipf16k", 1)
+    assert manifest["workloads"][9] == cell
+    assert manifest["configs"][7]["name"] == "olmo-hybrid-7b"
+    rate = harness.by_name(manifest["end_to_end"], "train_tokens_per_s_per_chip", "metric")
+    assert rate["workloads"][-1] == CELL
+    reported = [m["name"] for m in harness.metrics_of_cell(manifest["per_layer"], CELL)]
+    assert len(reported) == 14 and all(n.startswith("olmohybrid.") for n in reported)
+    assert [m["name"] for m in manifest["per_layer"][-14:]] == reported
+    assert {"olmohybrid.mfu", "olmohybrid.delta_share", "olmohybrid.delta_core_share",
+            "olmohybrid.delta_core_roofline", "olmohybrid.attention_core_roofline",
+            } <= set(reported)
+    for trace in ("0", "1"):
+        run = subprocess.run(
+            [sys.executable, "benchmarks/run.py", "--manifest",
+             "benchmarks/rehearsal/manifest_olmohybrid.json", "--workload", CELL,
+             "--seed", "4500000007", "--seconds", "2", "--trace", trace],
+            cwd=REPO, env=env, capture_output=True, text=True, timeout=300)
+        assert run.returncode == 0, run.stderr[-2000:]
+        line = json.loads(run.stdout.strip().splitlines()[-1])
+        problems = [l for l in run.stderr.splitlines() if l.startswith("INCORRECT")]
+        assert line["correct"] is True and line["failed"] == 0, problems
+        names = set(line["metrics"])
+        if trace == "0":
+            assert names == {"cpu_rehearsal.train_tokens_per_s_per_chip",
+                             "cpu_rehearsal.setup_s"}
+        else:  # a CPU has no peak: the shares of one are left out
+            assert "cpu_rehearsal.olmohybrid.step_ms_p50" in names
+            assert not any("mfu" in n or "roofline" in n for n in names)
+    lines = {l.split(" ", 1)[0]: l.split(" ", 1)[1] for l in run.stdout.splitlines()
+             if l.startswith(("SETUP ", "COUNTERS ", "REFERENCE "))}
+    setup = json.loads(lines["SETUP"])
+    assert setup["load_max_over_mean_before_and_after_levelling"] == []
+    assert setup["expert_param_bytes"] == 0 and setup["param_bytes_per_device"] > 0
+    assert set(json.loads(lines["COUNTERS"])) == {"delta_decay_min", "delta_beta_max"}
+    read = json.loads(lines["REFERENCE"])
+    assert len(read["delta_layers_rms"]) == len(read["delta_states_rms"]) == 6
+
+
+def test_a_program_without_the_recipe_fails_at_once_with_no_result(tmp_path):
+    """The new runner on a program from before this configuration (no
+    ``olmo_hybrid_7b_one_chip`` in ``__graft_entry__``): ``no recipe``,
+    exit code 2, no result line: what the parent commit does on the new
+    cell."""
+    from learning_at_home_tpu.utils.subproc import clean_jax_subprocess_env
+
+    tiny_file = dict(TINY_FILE, recipe="a_recipe_from_the_future")
+    (tmp_path / "configs").mkdir()
+    path = tmp_path / "configs" / "olmohybrid-tiny.json"
+    path.write_text(json.dumps(tiny_file))
+    manifest = harness.load_json(os.path.join(
+        REPO, "benchmarks", "rehearsal", "manifest_olmohybrid.json"))
+    manifest["configs"][0]["file"] = os.path.relpath(path, REPO)
+    (tmp_path / "manifest.json").write_text(json.dumps(manifest))
+    env = clean_jax_subprocess_env(REPO, platform="cpu")
+    env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "cache")
+    run = subprocess.run(
+        [sys.executable, "benchmarks/run.py", "--manifest",
+         os.path.relpath(tmp_path / "manifest.json", REPO), "--workload", CELL,
+         "--seed", "1", "--seconds", "1", "--trace", "0"],
+        cwd=REPO, env=env, capture_output=True, text=True, timeout=120)
+    assert run.returncode == 2 and "no recipe" in run.stderr
+    assert not run.stdout.strip()

@@ -32,6 +32,7 @@ from __graft_entry__ import (  # noqa: E402
     glm_4_7_flash_one_chip,
     k_exaone_one_chip,
     nemotron_labs_twotower_one_chip,
+    olmo_hybrid_7b_one_chip,
     olmoe_one_chip,
     smallthinker_one_chip,
 )
@@ -538,6 +539,12 @@ GLM_4_7_FLASH_TINY_STEP_SHA256 = (
 NEMOTRON_TINY_STEP_SHA256 = (
     "2ffcd672b1698783a155a15c6429019277fa51aea581341ecc53faaccb451582"
 )
+# Olmo-Hybrid's tiny step, first taken on PR 45's tree, the PR that brought
+# it (delta-rule layers, the norm on outputs, no mixture layer); the seven
+# above read on that tree what they read before it.
+OLMO_HYBRID_TINY_STEP_SHA256 = (
+    "e506501163cf1b35d0cef15a7a5129ab0ce4793175c62a11805933b2ef18d4f6"
+)
 
 
 @pytest.mark.parametrize(
@@ -550,9 +557,11 @@ NEMOTRON_TINY_STEP_SHA256 = (
         (k_exaone_one_chip, {"expert": 1}, K_EXAONE_TINY_STEP_SHA256),
         (glm_4_7_flash_one_chip, {"expert": 1}, GLM_4_7_FLASH_TINY_STEP_SHA256),
         (nemotron_labs_twotower_one_chip, {"expert": 1}, NEMOTRON_TINY_STEP_SHA256),
+        (olmo_hybrid_7b_one_chip, {"expert": 1}, OLMO_HYBRID_TINY_STEP_SHA256),
     ],
     ids=["dmoe-one-chip", "dmoe-pod4", "olmoe-one-chip", "smallthinker-one-chip",
-         "k-exaone-one-chip", "glm-4.7-flash-one-chip", "nemotron-one-chip"],
+         "k-exaone-one-chip", "glm-4.7-flash-one-chip", "nemotron-one-chip",
+         "olmo-hybrid-one-chip"],
 )
 def test_dmoe256_lowered_step_is_text_identical_to_the_parents(
     recipe, axes, sha256

@@ -227,3 +227,47 @@ def test_a_call_the_kernel_cannot_take_returns_the_plain_forms_bits(monkeypatch)
                 ssm_conv.causal_conv_silu_plain(wide[0][..., 64:192], *wide[1:]))
     assert not calls
     assert ssm_conv.causal_conv_silu(*fits) == "the kernel" and len(calls) == 1
+
+
+@pytest.mark.parametrize("rows", [32], indirect=True)
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_a_convolution_without_a_bias_is_the_one_with_zeros(rows, dtype, monkeypatch):
+    """``b=None`` (the delta mixer's convolutions): zeros that are no
+    parameter.  The plain form and the kernel (under ``interpret``, through
+    the path rule as on a TPU) agree with each other and with explicit
+    zeros, forward and in the gradients of ``x`` and ``w``; [q | k] at
+    column 0 and v at a later block of a wider array, as the mixer calls."""
+    c = 128
+    x, w, _ = _inputs(2, 64, 2 * c + 40, dtype, seed=8)
+    zeros = jnp.zeros((c,), jnp.float32)
+    weigh = jnp.asarray(np.random.RandomState(9).randn(2, 64, c), jnp.float32)
+    tol = 2e-6 if dtype == jnp.float32 else 2.0 ** -7
+
+    def loss(form, first):
+        return lambda x, w: jnp.sum(
+            form(x, w, None, first=first).astype(jnp.float32) * weigh)
+
+    for first in (0, c):
+        filters = w[first:first + c]
+        plain = ssm_conv.causal_conv_silu(x, filters, None, first=first)  # the CPU's
+        assert np.array_equal(
+            np.asarray(plain, np.float32),
+            np.asarray(ssm_conv.causal_conv_silu_plain(
+                x[..., first:first + c], filters, zeros), np.float32))
+        want = jax.grad(loss(ssm_conv.causal_conv_silu, first), argnums=(0, 1))(
+            x, filters)
+        with monkeypatch.context() as on_a_tpu:
+            on_a_tpu.setattr(jax, "default_backend", lambda: "tpu")
+            on_a_tpu.setattr(
+                ssm_conv, "causal_conv_silu_kernel",
+                lambda *a, kernel=ssm_conv.causal_conv_silu_kernel: kernel(
+                    *a, interpret=True))
+            assert ssm_conv.conv_kernel_fits((2, 64, c), TAPS, "tpu", first)
+            got = ssm_conv.causal_conv_silu(x, filters, None, first=first)
+            grads = jax.grad(loss(ssm_conv.causal_conv_silu, first), argnums=(0, 1))(
+                x, filters)
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(plain, np.float32), atol=tol, rtol=tol)
+        for g, wnt in zip(grads, want):
+            assert g.shape == wnt.shape
+            assert _rms(g, wnt) < (2e-6 if dtype == jnp.float32 else 2e-3)

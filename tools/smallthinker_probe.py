@@ -7,6 +7,7 @@
     chiprun -- python tools/smallthinker_probe.py float8 [config.json] [seed ...]
     chiprun -- python tools/smallthinker_probe.py ssd [seq_len] [accuracy_len]
     chiprun -- python tools/smallthinker_probe.py conv [rows x channels ...]
+    chiprun -- python tools/smallthinker_probe.py delta [seq_len] [accuracy_len]
 
 ``memory`` (here, no chip): the whole train step of a recipe of
 ``__graft_entry__`` (``smallthinker_one_chip``, or the one named, such as
@@ -54,6 +55,15 @@ the time), GB/s on the LEAST bytes a pass moves (``x`` in and ``y`` out;
 ``x`` and ``dy`` in and ``dx`` out), and the kernel's output and three
 gradients against the plain form's.  ``512x1024``-like arguments time the
 kernel at those blocks (rows x channels) too (PERF.md section 6, PR 41).
+
+``delta`` (on the chip): the chunked gated delta rule of
+``ops/delta_rule.py`` at the Olmo-Hybrid cell's shape (``[1, seq_len, 30,
+96 / 192]`` bf16; ``seq_len`` 16,384 by default) for each way of inverting
+``I + A`` (``blocks``, ``product``, ``triangular``) and chunks of 32, 64 and
+128: milliseconds a call forward and forward + backward, and at
+``accuracy_len`` the relative rms of the output, the last state and the five
+gradients against the rule a position at a time in float32 (PERF.md
+section 6, PR 45): where a kernel for the rule starts from.
 """
 
 import collections
@@ -335,6 +345,25 @@ def float8(seeds: list, config_path: str = CONFIG) -> None:
         del params
 
 
+def _ms(fn, args, calls: int) -> float:
+    """Milliseconds a call of ``fn(*args)``, after one call that compiles."""
+    import jax
+
+    jax.block_until_ready(fn(*args))
+    t0 = time.perf_counter()
+    for _ in range(calls):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    return 1e3 * (time.perf_counter() - t0) / calls
+
+
+def _rel_rms(got, want) -> float:
+    import numpy as np
+
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    return float(np.sqrt(np.mean((got - want) ** 2) / np.mean(want ** 2)))
+
+
 def ssd(seq_len: int = 16384, accuracy_len: int = 4096, calls: int = 10) -> None:
     import jax
     import jax.numpy as jnp
@@ -368,18 +397,6 @@ def ssd(seq_len: int = 16384, accuracy_len: int = 4096, calls: int = 10) -> None
             return jnp.sum(y.astype(f32) * wy) + jnp.sum(final * wf)
         return loss
 
-    def ms(fn, args):
-        jax.block_until_ready(fn(*args))
-        t0 = time.perf_counter()
-        for _ in range(calls):
-            out = fn(*args)
-        jax.block_until_ready(out)
-        return 1e3 * (time.perf_counter() - t0) / calls
-
-    def rel(got, want):
-        got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
-        return float(np.sqrt(np.mean((got - want) ** 2) / np.mean(want ** 2)))
-
     forms = {"plain": ops.ssd_chunked_plain, "kernel": ops.ssd_chunked_kernel}
     names = ("y", "state", "dx", "ddt", "dA", "dB", "dC")
 
@@ -397,11 +414,11 @@ def ssd(seq_len: int = 16384, accuracy_len: int = 4096, calls: int = 10) -> None
         args = inputs(seq_len, jnp.bfloat16)
         print("SSD " + json.dumps({
             "form": name, "seq_len": seq_len, "accuracy_len": accuracy_len,
-            "forward_ms": ms(jax.jit(lambda *a, f=form: f(*a, chunk)), args),
-            "forward_backward_ms": ms(jax.jit(jax.grad(
-                loss_of(form, seq_len), argnums=(0, 1, 2, 3, 4))), args),
+            "forward_ms": _ms(jax.jit(lambda *a, f=form: f(*a, chunk)), args, calls),
+            "forward_backward_ms": _ms(jax.jit(jax.grad(
+                loss_of(form, seq_len), argnums=(0, 1, 2, 3, 4))), args, calls),
             "rms_against_float32": {
-                k: rel(a, b) for k, a, b in zip(names, got, want)},
+                k: _rel_rms(a, b) for k, a, b in zip(names, got, want)},
         }), flush=True)
 
 
@@ -424,18 +441,6 @@ def conv(blocks: list, calls: int = 20) -> None:
     w = jnp.asarray(0.5 * rs.standard_normal((c, taps)), jnp.float32)
     b = jnp.asarray(0.1 * rs.standard_normal(c), jnp.float32)
 
-    def ms(fn, *args):
-        jax.block_until_ready(fn(*args))
-        t0 = time.perf_counter()
-        for _ in range(calls):
-            out = fn(*args)
-        jax.block_until_ready(out)
-        return 1e3 * (time.perf_counter() - t0) / calls
-
-    def rel(got, want):
-        got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
-        return float(np.sqrt(np.mean((got - want) ** 2) / np.mean(want ** 2)))
-
     def both(form):
         def fn(x, w, b, dy):
             y, back = jax.vjp(form, x, w, b)
@@ -453,8 +458,8 @@ def conv(blocks: list, calls: int = 20) -> None:
             ops._ROWS, ops._CHANNELS = at
         try:
             got = jax.device_get(both(form)(x, w, b, dy))
-            forward = ms(jax.jit(form), x, w, b)
-            forward_backward = ms(both(form), x, w, b, dy)
+            forward = _ms(jax.jit(form), (x, w, b), calls)
+            forward_backward = _ms(both(form), (x, w, b, dy), calls)
         except Exception as e:  # a block the compiler refuses
             print("CONV " + json.dumps({"form": name, "blocks": at,
                                         "refused": str(e)[:300]}), flush=True)
@@ -469,8 +474,81 @@ def conv(blocks: list, calls: int = 20) -> None:
             "y_max_abs_against_plain": float(np.max(np.abs(
                 np.asarray(got[0], np.float32) - np.asarray(want[0], np.float32)))),
             "rms_against_plain": {
-                k: rel(a, b_) for k, a, b_ in zip(("y", "dx", "dw", "db"), got, want)},
+                k: _rel_rms(a, b_) for k, a, b_ in zip(("y", "dx", "dw", "db"), got, want)},
         }), flush=True)
+
+
+def delta(seq_len: int = 16384, accuracy_len: int = 1024, calls: int = 5) -> None:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    import harness
+    from learning_at_home_tpu.ops import delta_rule as ops
+
+    config = harness.load_json(os.path.join(
+        REPO, "benchmarks/configs/olmo-hybrid-7b.json"))
+    h, dk, dv = (config["linear_num_key_heads"], config["linear_key_head_dim"],
+                 config["linear_value_head_dim"])
+    f32 = jnp.float32
+
+    def inputs(s, dtype):
+        """As the mixer hands them over: SiLU'd, unit-length keys and
+        queries (they share a positive mean: the solve's hard case),
+        strengths in (0, 2), decays as layer 0's seeded weights give."""
+        rs = np.random.default_rng(4500000007)
+
+        def unit(a):
+            a = a * (1.0 / (1.0 + np.exp(-a)))
+            return a / np.sqrt(np.sum(a * a, axis=-1, keepdims=True) + 1e-6)
+
+        g = -rs.uniform(1, 16, h) * np.exp(rs.uniform(
+            np.log(1e-3), np.log(1e-1), (1, s, h)))
+        return (jnp.asarray(unit(rs.standard_normal((1, s, h, dk))) / dk ** 0.5, dtype),
+                jnp.asarray(unit(rs.standard_normal((1, s, h, dk))), dtype),
+                jnp.asarray(rs.standard_normal((1, s, h, dv)), dtype),
+                jnp.asarray(g, f32),
+                jnp.asarray(2.0 / (1.0 + np.exp(-rs.standard_normal((1, s, h)))), f32))
+
+    def loss_of(form, s):
+        rs = np.random.default_rng(45)
+        wo = jnp.asarray(rs.standard_normal((1, s, h, dv)), f32)
+        wf = jnp.asarray(rs.standard_normal((1, h, dk, dv)), f32)
+
+        def loss(*args):
+            o, final = form(*args)
+            return jnp.sum(o.astype(f32) * wo) + jnp.sum(final * wf)
+        return loss
+
+    names = ("o", "state", "dq", "dk", "dv", "dg", "dbeta")
+
+    def everything(form, s):
+        return jax.jit(lambda *a: (
+            *form(*a), *jax.grad(loss_of(form, s), argnums=(0, 1, 2, 3, 4))(*a)))
+
+    want = jax.device_get(everything(ops.gated_delta_recurrent, accuracy_len)(
+        *inputs(accuracy_len, f32)))
+    for solve in ("blocks", "product", "triangular"):
+        for chunk in (32, 64, 128):
+            def form(*a, solve=solve, chunk=chunk):
+                return ops.gated_delta_chunked(*a, chunk, solve=solve)
+
+            line = {"solve": solve, "chunk": chunk, "seq_len": seq_len,
+                    "accuracy_len": accuracy_len, "shape": [1, seq_len, h, dk, dv]}
+            try:
+                got = jax.device_get(everything(form, accuracy_len)(
+                    *inputs(accuracy_len, jnp.bfloat16)))
+                args = inputs(seq_len, jnp.bfloat16)
+                line.update({
+                    "forward_ms": _ms(jax.jit(form), args, calls),
+                    "forward_backward_ms": _ms(jax.jit(jax.grad(
+                        loss_of(form, seq_len), argnums=(0, 1, 2, 3, 4))), args, calls),
+                    "rms_against_the_recurrence_in_float32": {
+                        k: _rel_rms(a, b) for k, a, b in zip(names, got, want)},
+                })
+            except Exception as e:  # a form the compiler or the memory refuses
+                line["refused"] = str(e)[:300]
+            print("DELTA " + json.dumps(line), flush=True)
 
 
 if __name__ == "__main__":
@@ -480,6 +558,8 @@ if __name__ == "__main__":
         conv(sys.argv[2:])
     elif sys.argv[1:2] == ["ssd"]:
         ssd(*(int(a) for a in sys.argv[2:4]))
+    elif sys.argv[1:2] == ["delta"]:
+        delta(*(int(a) for a in sys.argv[2:4]))
     elif sys.argv[1:2] == ["float8"]:
         named = [a for a in sys.argv[2:] if a.endswith(".json")]
         float8([int(s) for s in sys.argv[2:] if s not in named] or [3100000007],
