@@ -254,13 +254,17 @@ def delta_mixer(
     causal_conv_silu`: ``[q | k]`` one call at column 0, ``v`` one at the
     column where it lies, so each is read where the product left it);
     per head ``q <- q / sqrt(sum q^2 + 1e-6) / sqrt(dk)`` and ``k <- k /
-    sqrt(sum k^2 + 1e-6)``; ``beta = 2 sigmoid(b)`` (the 2 lets the
+    sqrt(sum k^2 + 1e-6)`` (:func:`~learning_at_home_tpu.ops.delta_rule.
+    unit_length`, which the rule applies); ``beta = 2 sigmoid(b)`` (the 2 lets the
     transition ``alpha (I - beta k k^T)`` have a negative eigenvalue,
     arXiv:2411.12537); ``g = -exp(A_log) softplus(a + dt_bias)``, float32,
     ``alpha = exp(g)``; the recurrence ``S_t = alpha_t S_{t-1} + beta_t
     k_t (v_t - (alpha_t S_{t-1})^T k_t)^T``, ``o_t = S_t^T q_t`` in chunks
     of ``chunk`` (:func:`~learning_at_home_tpu.ops.delta_rule.
-    gated_delta_chunked`); ``y = RMSNorm(o) * silu(z)``, the norm FIRST
+    gated_delta_chunked`: on a TPU its two Pallas kernels, forward and
+    backward, where the call's shapes fit their tiles, the plain form
+    elsewhere; ``decay_dtype`` other than float32 is the plain form's,
+    for the probes); ``y = RMSNorm(o) * silu(z)``, the norm FIRST
     (over each head's ``dv``, one scale shared by the heads), then the
     gate (Mamba-2's mixer gates first); ``out = y W_out``.  The parameters
     say the sizes: a head's value size ``dv`` is ``w_out``'s input width
@@ -288,15 +292,16 @@ def delta_mixer(
         qk = causal_conv_silu(proj, p["conv_w"][:d_qk], None)
         v = causal_conv_silu(proj, p["conv_w"][d_qk:], None, first=d_qk)
     with jax.named_scope("core"):
-        qk = qk.reshape(b, s, 2, n_heads, dk).astype(f32)
-        qk = qk * jax.lax.rsqrt(jnp.sum(qk * qk, axis=-1, keepdims=True) + 1e-6)
-        q = (qk[:, :, 0] * dk ** -0.5).astype(x.dtype)
-        k = qk[:, :, 1].astype(x.dtype)
+        qk = qk.reshape(b, s, 2, n_heads, dk)
         beta = 2.0 * jax.nn.sigmoid(write)
         g = -jnp.exp(p["A_log"].astype(f32)) * jax.nn.softplus(
             step + p["dt_bias"].astype(f32))
+        # the rule makes q and k unit-length itself (float32, rounded to
+        # x's dtype): its kernel does so in VMEM, on what the convolution
+        # wrote
         o, state = gated_delta_chunked(
-            q, k, v.reshape(b, s, n_heads, dv), g, beta, chunk, decay_dtype)
+            qk[:, :, 0], qk[:, :, 1], v.reshape(b, s, n_heads, dv), g, beta,
+            chunk, decay_dtype, unit=True)
         decay_min, beta_max = jnp.exp(jnp.min(g)), jnp.max(beta)
     with jax.named_scope("gate_norm"):
         o = o.astype(f32)

@@ -32,20 +32,45 @@ state entering the chunk::
     O      = (Q * exp(gamma)) S + lower_incl((Q K^T) * Gamma) V'
     S_next = exp(gamma_C) S + (K * exp(gamma_C - gamma))^T V'
 
-Everything that does not read the entering state (``A``, the solve, the
-scores) is computed for all chunks at once; a ``lax.scan`` over the ``S /
-C`` chunks carries the state and makes each chunk's ``V'``; the outputs
-are again taken for all chunks at once.  A sequence longer than
-:data:`SEGMENT` is taken a segment at a time, the state handed from
-segment to segment, each under ``jax.checkpoint``: a backward pass then
-holds one segment's intermediates, not the sequence's.  The products take operands of
-``v``'s dtype (bf16 in a train step) and accumulate in float32; the
-decays, the solve and the carried state are float32.  Plain
-``jax.numpy``, every intermediate an array of its own; the backward is
-autodiff's (no ``custom_vjp``: the chunked form is a composition of
-matmuls, masks and one scan, which autodiff transposes as they stand,
-and a hand-written backward belongs to the kernel that ROADMAP.md Reach
-3(f) queues, which will keep its intermediates in VMEM).
+The products take operands of ``v``'s dtype (bf16 in a train step) and
+accumulate in float32; the decays, ``A``, the solve and the carried state
+are float32.  Two forms of it, one rule between them
+(:func:`gated_delta_chunked`, :func:`kernel_fits`: a pure function of what
+the call can see, as ``ops/ssd.py`` chooses its kernel):
+
+* :func:`gated_delta_kernel`, where the backend is ``tpu``, the decays
+  float32, the solve ``blocks`` and the shapes fit the tiles
+  (:func:`_grid`): two Pallas kernels, ``delta_chunk_fwd`` and, behind a
+  ``jax.custom_vjp``, ``delta_chunk_bwd``.  A grid row is up to three heads
+  (heads first in HBM, so a block spans an array's whole last axis and
+  keys of 96 or values of 192 need no lane multiple) and walks their
+  chunks in sequence, each head's state [dk, dv] float32 carried in VMEM
+  (the backward walks them in reverse and carries the state's cotangent,
+  seeded with the last state's).  A frame of 128 positions is taken at a
+  time, its chunks side by side on the diagonal of [128, 128]: ``Gamma``,
+  ``K K^T``, ``A``, the inverse of ``I + A``, ``Q K^T``, ``W``, ``U`` and
+  ``V'`` live and die in VMEM; ``q``, ``k``, ``v``, the sums, ``beta`` and
+  ``o`` cross HBM once, and the states entering the chunks once more as
+  the backward's residual (566 MB a layer at the cell's shape: written by
+  the forward under differentiation alone, so under remat by the
+  recompute, and live for one layer's backward; nothing carries a name
+  for a remat policy to keep).  The gradient through the solve is two
+  more products with the same inverse (``dR = T^T dX``, ``dA = -dR X^T``
+  under the diagonal).  It rounds where the plain form rounds.
+* :func:`gated_delta_plain`, everywhere else (the CPU, a ``decay_dtype`` or
+  a ``solve`` that is being probed, a shape the tiles refuse): plain
+  ``jax.numpy``, every intermediate an array of its own, the backward
+  autodiff's.  What reads no entering state (``A``, the solve, the
+  scores) is computed for all chunks at once; a ``lax.scan`` over the ``S
+  / C`` chunks carries the state and makes each chunk's ``V'``; the
+  outputs are again taken for all chunks at once.  A sequence longer than
+  :data:`SEGMENT` is taken a segment at a time, the state handed from
+  segment to segment, each under ``jax.checkpoint``: a backward pass then
+  holds one segment's intermediates, not the sequence's.  It is the
+  kernel's reference in the tests.
+
+The decays' sums inside the chunks are XLA's in both, and their gradient
+back to ``g`` autodiff's.
 
 **The solve.**  ``I + A`` is unit lower-triangular, so ``A`` is nilpotent
 and ``(I + A)^-1 = (I - A)(I + A^2)(I + A^4)...``: matmuls alone, and no
@@ -57,19 +82,26 @@ float32 has; inside blocks of 16 it still took five of the seven where
 ``a`` nears 2, and the cell's comparison read it (PERF.md section 6, PR
 45).  So the inverses of the diagonal blocks of :data:`SOLVE_BLOCK`
 positions are made by forward substitution, a row at a time (15 small
-steps for all blocks of all chunks at once), and plain forward
-substitution by blocks joins them (:func:`solve_unit_lower`,
-``how="blocks"``): backward-stable whatever the keys.  ``"product"`` (the
-whole chunk at once) and ``"triangular"``
+steps for all blocks at once: of all chunks in the plain form, of a frame
+in the kernel, there on the VPU), and plain forward substitution by blocks
+joins them (:func:`solve_unit_lower`, ``how="blocks"``; the kernel solves
+for the identity, the blocks doubling from 16 to the chunk:
+:func:`_unit_lower_inverse`): backward-stable whatever the keys.
+``"product"`` (the whole chunk at once) and ``"triangular"``
 (``jax.scipy.linalg.solve_triangular``) are there for the probe that
-times and checks the three on the chip (tools/smallthinker_probe.py
-delta).
+times and checks the ways on the chip (tools/smallthinker_probe.py delta).
 """
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from learning_at_home_tpu.ops.ssd import _LANES, _NT, _TN, _columns
 
 # positions of a diagonal block of the solve: (I + A)^-1 a row at a time
 # inside it, forward substitution by blocks between them
@@ -81,6 +113,10 @@ SOLVE_BLOCK = 16
 # step compiled for a described v5e: PERF.md section 6, PR 45)
 SEGMENT = 2048
 _HIGHEST = jax.lax.Precision.HIGHEST
+UNIT_EPS = 1e-6  # under the root of a query's or a key's length
+# the kernel's frame: the positions whose scores and solve one head takes
+# at once, whole chunks side by side on the diagonal of [FRAME, FRAME]
+FRAME = 128
 
 
 def gated_delta_recurrent(
@@ -172,7 +208,7 @@ def solve_unit_lower(a: jax.Array, rhs: jax.Array, how: str = "blocks") -> jax.A
 def gated_delta_chunked(
     q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array, beta: jax.Array,
     chunk: int, decay_dtype=jnp.float32, solve: str = "blocks",
-    segment: int = SEGMENT,
+    segment: int = SEGMENT, unit: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
     """``(o [B, S, H, dv] in v's dtype, the state after the last position
     [B, H, dk, dv] float32)`` of the rule above, ``chunk`` positions at a
@@ -180,8 +216,73 @@ def gated_delta_chunked(
     dtype the decays' sums are kept in; float32 always, but for showing
     what a lower one reads (tools/smallthinker_probe.py).  ``solve``:
     :func:`solve_unit_lower`'s way.  ``segment``: the positions whose
-    intermediates a backward pass holds at a time (a multiple of the chunk
-    that divides ``S``, or the whole sequence where it is no longer)."""
+    intermediates the plain form's backward pass holds at a time.
+    ``unit``: ``q`` and ``k`` come as the mixer's convolution left them and
+    are made unit-length first (:func:`unit_length`).  The kernel where
+    :func:`kernel_fits` says so, the plain form elsewhere."""
+    if kernel_fits(q.shape, v.shape, chunk, jax.default_backend(), decay_dtype,
+                   solve):
+        return gated_delta_kernel(q, k, v, g, beta, chunk, unit=unit)
+    if unit:
+        q, k = unit_length(q, k)
+    return gated_delta_plain(q, k, v, g, beta, chunk, decay_dtype, solve, segment)
+
+
+def unit_length(q: jax.Array, k: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """The heads' queries and keys [.., dk] as the rule takes them: ``x /
+    sqrt(sum x^2 + 1e-6)`` in float32, the queries times ``dk^-1/2``
+    besides, rounded to the dtype they came in."""
+    f32 = jnp.float32
+
+    def unit(x):
+        x = x.astype(f32)
+        return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + UNIT_EPS)
+
+    return ((unit(q) * q.shape[-1] ** -0.5).astype(q.dtype), unit(k).astype(k.dtype))
+
+
+def _grid(h: int, s: int, c: int, dk: int, dv: int):
+    """``(heads a grid row, positions a grid step)`` of the kernel for
+    ``h`` heads a batch row of ``s`` positions in chunks of ``c``, or None
+    where its tiles refuse: the positions in frames of :data:`FRAME` (two a
+    step where they come out even), a chunk that divides the frame and
+    holds whole blocks of the solve, keys whole sublane tiles, and two
+    heads abreast, or one, whose values side by side ([S, hg dv] of
+    ``v``'s [B, S, H dv] as it stands) are whole lane tiles and whose
+    states [hg, dk, dv] float32 stay within 2^16 elements (the backward at
+    five heads of [96, 192] passed the 16 MB of scoped VMEM: an AOT compile
+    for a described v5e, PERF.md section 6, PR 46)."""
+    if s % c or s % FRAME or FRAME % c or c % SOLVE_BLOCK or dk % 8:
+        return None
+    for hg in (2, 1):
+        if h % hg == 0 and (hg * dv) % _LANES == 0 and hg * dk * dv <= 2 ** 16:
+            return hg, 2 * FRAME if s % (2 * FRAME) == 0 else FRAME
+    return None
+
+
+def kernel_fits(q_shape, v_shape, chunk, backend, decay_dtype=jnp.float32,
+                solve: str = "blocks") -> bool:
+    """Whether :func:`gated_delta_kernel` takes a call: a ``tpu`` backend
+    (Mosaic lowering), float32 decays, the ``blocks`` solve (the kernel's
+    own), and shapes :func:`_grid` finds tiles for.  A pure function of
+    what the call can see."""
+    bsz, s, h, dk = q_shape
+    return (
+        backend == "tpu" and jnp.dtype(decay_dtype) == jnp.float32
+        and solve == "blocks"
+        and _grid(h, s, min(chunk, s), dk, v_shape[-1]) is not None)
+
+
+def gated_delta_plain(
+    q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array, beta: jax.Array,
+    chunk: int, decay_dtype=jnp.float32, solve: str = "blocks",
+    segment: int = SEGMENT,
+) -> tuple[jax.Array, jax.Array]:
+    """:func:`gated_delta_chunked` in plain ``jax.numpy``, every
+    intermediate an array of its own; its backward is autodiff's.
+    ``segment``: the positions whose intermediates a backward pass holds
+    at a time (a multiple of the chunk that divides ``S``, or the whole
+    sequence where it is no longer)."""
     bsz, s, h, dk = q.shape
     c = min(chunk, s)
     if s % c:
@@ -271,3 +372,448 @@ def _segment(state, of_segment, c: int, decay_dtype, solve: str):
         "bnhij,bnhjv->bnhiv", (qk * decay).astype(compute), new,
         preferred_element_type=f32)
     return jnp.moveaxis(o, 2, 3).reshape(bsz, s, h, dv).astype(compute), final
+
+
+# ---- the kernel: some heads a grid row, their chunks in sequence ----
+#
+# A grid step holds ``hg`` heads over ``t`` positions: their queries and
+# keys heads first ([B H, S, dk]: a block spans the array's whole last axis,
+# so keys of 96 need no lane multiple), their values and outputs as the
+# mixer has them ([B, S, H dv]: two heads of 192 side by side are three
+# lane tiles, and a head is cut out of them in VMEM), and the heads' decays'
+# sums and strengths a head a ROW ([2 hg, t]: dense in HBM).  It takes them
+# a FRAME of 128 positions at a time: the frame's chunks lie side by side on
+# the diagonal of [128, 128] scores, so ``K K^T``, ``Gamma``, ``A``, the
+# solve and ``Q K^T`` are whole-tile products for all of a frame's chunks
+# at once, masked to the chunks' own blocks; then the chunks in sequence,
+# the heads abreast, each against its state [dk, dv] float32 in a VMEM
+# scratch that the next step of the row finds as this one left it.
+
+
+def _dot(a, b, dims=(((1,), (0,)), ((), ())), precision=None):
+    return jax.lax.dot_general(
+        a, b, dims, precision=precision, preferred_element_type=jnp.float32)
+
+
+def _as_row(col: jax.Array, eye: jax.Array) -> jax.Array:
+    """[F, 1] -> [1, F] with no transpose: the diagonal of its broadcast."""
+    return jnp.sum(jnp.where(eye, col, 0.0), axis=0, keepdims=True)
+
+
+def _as_col(row: jax.Array, eye: jax.Array) -> jax.Array:
+    """[1, F] -> [F, 1]."""
+    return jnp.sum(jnp.where(eye, row, 0.0), axis=1, keepdims=True)
+
+
+def _last_row(col: jax.Array, width: int) -> jax.Array:
+    """The last entry of ``col`` [C, 1] as a row [1, width] (a [1, 1]
+    cannot be broadcast along both axes)."""
+    c = col.shape[0]
+    return jnp.broadcast_to(col, (c, width))[c - 1:c, :]
+
+
+def _unit_lower_inverse(a: jax.Array, i: jax.Array, j: jax.Array, c: int):
+    """``(I + a)^-1`` of ``a`` [F, F] float32, strictly lower-triangular
+    inside diagonal blocks of ``c`` positions and zero outside them, by
+    forward substitution on the identity, as :func:`solve_unit_lower`'s
+    ``blocks``: a row at a time inside the diagonal blocks of
+    :data:`SOLVE_BLOCK` positions (all of them at once, on the VPU: 15
+    steps over sublane tiles of 8 rows), then by blocks between them
+    (``X_2 = -T_22 A_21 T_11``: two products at the highest precision a
+    join), the block doubling up to ``c``.  No partial result is larger than the inverse's own entries."""
+    frame = a.shape[0]
+    block = min(SOLVE_BLOCK, c)
+    blocks = range(frame // block)
+    # the diagonal blocks, one under the other: [F, block]
+    diag = jnp.concatenate(
+        [a[b * block:(b + 1) * block, b * block:(b + 1) * block] for b in blocks],
+        axis=0)
+    eye = jnp.where(i == j, 1.0, 0.0).astype(jnp.float32)  # block-diagonal all along
+    # a sublane tile of 8 rows at a time: one whose rows are all final is
+    # left alone (a quarter of the work, and no array is cut and joined a step)
+    tiles = [eye[r:r + 8, :] for r in range(0, frame, 8)]
+    per = block // 8
+    for step in range(block - 1):
+        # the rows below `step` of every block lose a[., step] times row
+        # `step`, which is final (above its diagonal `a` is zero)
+        for b in blocks:
+            row = jnp.broadcast_to(
+                tiles[b * per + step // 8][step % 8:step % 8 + 1, :], (8, frame))
+            for tile in range(b * per + (step + 1) // 8, (b + 1) * per):
+                tiles[tile] = tiles[tile] - diag[
+                    tile * 8:(tile + 1) * 8, step:step + 1] * row
+    x = jnp.concatenate(tiles, axis=0)
+    width = block
+    while width < c:  # blocks of `width` are inverted: join them two and two
+        below = jnp.where(
+            (i // width == j // width + 1) & (i // (2 * width) == j // (2 * width)),
+            a, 0.0)
+        x = x - _dot(_dot(x, below, precision=_HIGHEST), x, precision=_HIGHEST)
+        width *= 2
+    return x
+
+
+def _exact_dot(a: jax.Array, b: jax.Array) -> jax.Array:
+    """``a b`` to float32's digits, ``a`` [F, F] float32: where ``b`` is
+    bf16 (every digit it has survives a product with a bf16 part), ``a`` as
+    the sum of three bf16 parts (its 24 bits of mantissa, 8 a part), each
+    part's product with ``b`` exact in the float32 accumulator: ONE product
+    of the parts one under the other, three passes of the MXU where the
+    highest precision takes six and splits ``b`` besides."""
+    if b.dtype != jnp.bfloat16:
+        return _dot(a, b.astype(jnp.float32), precision=_HIGHEST)
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    high = a.astype(bf16)
+    rest = a - high.astype(f32)
+    middle = rest.astype(bf16)
+    low = (rest - middle.astype(f32)).astype(bf16)
+    f = a.shape[0]
+    parts = _dot(jnp.concatenate([high, middle, low], axis=0), b)
+    return parts[:f] + parts[f:2 * f] + parts[2 * f:]
+
+
+def _unit(x, scale):
+    """:func:`unit_length` of one head's frame [F, dk]: ``(the rows at unit
+    length times ``scale`` in x's dtype, the same in float32 before the
+    scale, the lengths' inverses [F, 1])``."""
+    x32 = x.astype(jnp.float32)
+    inverse = jax.lax.rsqrt(jnp.sum(x32 * x32, axis=1, keepdims=True) + UNIT_EPS)
+    unit = x32 * inverse
+    return (unit if scale is None else unit * scale).astype(x.dtype), unit, inverse
+
+
+def _through_unit(dy, unit, inverse, scale):
+    """The cotangent of :func:`_unit`'s input from its output's: ``scale /
+    length (dy - unit (unit . dy))``."""
+    dx = inverse * (dy - unit * jnp.sum(unit * dy, axis=1, keepdims=True))
+    return dx if scale is None else dx * scale
+
+
+def _frame(q, k, v, gcol, bcol, i, j, c: int, unit: bool) -> dict:
+    """What forward and backward both make of one head's frame (``q``,
+    ``k`` [F, dk], ``v`` [F, dv], the sums ``gcol`` and the strengths
+    ``bcol`` [F, 1]) before any state is read."""
+    f32 = jnp.float32
+    compute = v.dtype
+    eye = i == j
+    units = None
+    if unit:  # q and k as the convolution left them
+        scale = q.shape[1] ** -0.5
+        q, *of_q = _unit(q, scale)
+        k, *of_k = _unit(k, None)
+        units = (*of_q, scale), (*of_k, None)
+    grow = _as_row(gcol, eye)
+    # exp of a masked difference: above the diagonal the difference is
+    # positive and its exp may overflow before a mask would drop it
+    decay = jnp.exp(jnp.where(
+        (i // c == j // c) & (i >= j), gcol - grow, -jnp.inf))  # Gamma
+    m = jnp.where(i > j, _dot(k, k, _NT) * decay, 0.0)
+    a = bcol * m
+    t = _unit_lower_inverse(a, i, j, c)
+    k32, v32 = k.astype(f32), v.astype(f32)
+    from_start = jnp.exp(gcol)  # [F, 1]
+    last = jnp.concatenate([  # a chunk's last sum under each of its rows
+        jnp.broadcast_to(gcol[n + c - 1:n + c, :], (c, 1))
+        for n in range(0, gcol.shape[0], c)], axis=0)
+    to_end = jnp.exp(last - gcol)
+    k_from_start = k32 * from_start
+    rk = bcol * k_from_start
+    # T R with beta (and e^gamma) moved onto T's columns: the other operand
+    # is then k or v as it came
+    w = _exact_dot(t * _as_row(bcol * from_start, eye), k)
+    return dict(
+        q=q, k=k, units=units,
+        gcol=gcol, bcol=bcol, eye=eye, decay=decay, m=m, a=a, t=t, k32=k32,
+        v32=v32, from_start=from_start, to_end=to_end,
+        k_from_start=k_from_start, rk=rk, w=w,
+        u=_exact_dot(t * _as_row(bcol, eye), v), w_c=w.astype(compute),
+        k_to_end=(k32 * to_end).astype(compute),
+        scores=_dot(q, k, _NT) * decay,
+        q_from_start=(q.astype(f32) * from_start).astype(compute))
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, rows_ref, o_ref, *rest, hg, c, keep, unit):
+    entering_ref = rest[0] if keep else None
+    final_ref, state = rest[-2:]
+
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        state[...] = jnp.zeros_like(state)
+
+    cols = _columns(rows_ref[...])  # lane h: head h's sums; hg + h: its strengths
+    dv = v_ref.shape[1] // hg
+    compute = v_ref.dtype
+    i = jax.lax.broadcasted_iota(jnp.int32, (FRAME, FRAME), 0)
+    j = jax.lax.broadcasted_iota(jnp.int32, (FRAME, FRAME), 1)
+    for first in range(0, q_ref.shape[1], FRAME):
+        at = slice(first, first + FRAME)
+        made = [_frame(q_ref[h, at, :], k_ref[h, at, :],
+                       v_ref[at, h * dv:(h + 1) * dv], cols[at, h:h + 1],
+                       cols[at, hg + h:hg + h + 1], i, j, c, unit)
+                for h in range(hg)]
+        news = [[] for _ in made]
+        answered = [[] for _ in made]
+        for n in range(0, FRAME, c):  # the chunks in sequence, the heads abreast
+            of = slice(n, n + c)
+            for h, x in enumerate(made):
+                s = state[h]
+                if keep:
+                    entering_ref[(first + n) // c, h] = s
+                # what the entering state answers for the keys as the solve
+                # left them and for the queries: one product
+                both = _dot(jnp.concatenate(
+                    [x["w_c"][of], x["q_from_start"][of]], axis=0), s.astype(compute))
+                new = (x["u"][of] - both[:c]).astype(compute)  # V'
+                news[h].append(new)
+                answered[h].append(both[c:])
+                state[h] = jnp.exp(_last_row(x["gcol"][of], dv)) * s + _dot(
+                    x["k_to_end"][of], new, _TN)
+        for h, x in enumerate(made):
+            o_ref[at, h * dv:(h + 1) * dv] = (
+                jnp.concatenate(answered[h], axis=0) + _dot(
+                    x["scores"].astype(compute), jnp.concatenate(news[h], axis=0))
+            ).astype(compute)
+
+    @pl.when(pl.program_id(1) == pl.num_programs(1) - 1)
+    def _():
+        final_ref[...] = state[...]
+
+
+def _bwd_kernel(q_ref, k_ref, v_ref, rows_ref, entering_ref, do_ref, dfinal_ref,
+                dq_ref, dk_ref, dv_ref, drows_ref, dstate, *, hg, c, unit):
+    f32 = jnp.float32
+
+    @pl.when(pl.program_id(1) == 0)  # the row's LAST step: they run in reverse
+    def _():
+        dstate[...] = dfinal_ref[...]
+
+    cols = _columns(rows_ref[...])
+    dv = v_ref.shape[1] // hg
+    compute = v_ref.dtype
+    i = jax.lax.broadcasted_iota(jnp.int32, (FRAME, FRAME), 0)
+    j = jax.lax.broadcasted_iota(jnp.int32, (FRAME, FRAME), 1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (FRAME, _LANES), 1)
+    ends = jax.lax.broadcasted_iota(jnp.int32, (c, 1), 0) == c - 1
+    dcols = []  # as ``cols``, a frame at a time, the last frame first
+    for first in reversed(range(0, q_ref.shape[1], FRAME)):
+        at = slice(first, first + FRAME)
+        made = []
+        for h in range(hg):
+            do = do_ref[at, h * dv:(h + 1) * dv]
+            x = _frame(q_ref[h, at, :], k_ref[h, at, :],
+                       v_ref[at, h * dv:(h + 1) * dv], cols[at, h:h + 1],
+                       cols[at, hg + h:hg + h + 1], i, j, c, unit)
+            # what the chunks' own writes hand back, before any state
+            x.update(do=do, chain={},
+                     dnew=_dot(x["scores"].astype(compute), do, _TN))
+            made.append(x)
+        for n in reversed(range(0, FRAME, c)):  # the chunks in reverse
+            of = slice(n, n + c)
+            for h, x in enumerate(made):
+                s = entering_ref[(first + n) // c, h]
+                entering = s.astype(compute)
+                w_c = x["w_c"][of]
+                new = (x["u"][of] - _dot(w_c, entering)).astype(compute)
+                dleaving = dstate[h]
+                dleaving_c = dleaving.astype(compute)
+                dnew = x["dnew"][of] + _dot(x["k_to_end"][of], dleaving_c)
+                # the cotangents of the queries and of the keys as the solve
+                # left them: one product with the entering state; the
+                # entering state's own: one more
+                upon = jnp.concatenate([x["do"][of], -dnew.astype(compute)], axis=0)
+                both = _dot(upon, entering, _NT)  # [2 C, dk]
+                chunk_decay = jnp.exp(_last_row(x["gcol"][of], dv))  # [1, dv]
+                dstate[h] = chunk_decay * dleaving + _dot(jnp.concatenate(
+                    [x["q_from_start"][of], w_c], axis=0), upon, _TN)
+                x["chain"][n] = dict(
+                    new=new, dnew=dnew, dk_to_end=_dot(new, dleaving_c, _NT),
+                    dq_from_start=both[:c], dw=both[c:],
+                    # the chunk's last sum scales the leaving state whole
+                    dlast=jnp.sum(
+                        jnp.sum(dleaving * s, axis=0, keepdims=True) * chunk_decay,
+                        axis=1, keepdims=True))
+        dcol = jnp.zeros((FRAME, _LANES), f32)
+        for h, x in enumerate(made):
+            q, k, bcol, decay = x["q"], x["k"], x["bcol"], x["decay"]
+            chain = [x["chain"][n] for n in range(0, FRAME, c)]
+            new, dnew, dk_to_end, dq_from_start, dw = (
+                jnp.concatenate([of[name] for of in chain], axis=0)
+                for name in ("new", "dnew", "dk_to_end", "dq_from_start", "dw"))
+            dscores = _dot(x["do"], new, _NT)  # [F, F]
+
+            # through the solve: two more with the same factor
+            drk = _dot(x["t"], dw, _TN, precision=_HIGHEST)
+            drv = _dot(x["t"], dnew, _TN, precision=_HIGHEST)
+            da = -jnp.where(
+                i > j,
+                _dot(drk, x["w"], _NT, precision=_HIGHEST)
+                + _dot(drv, x["u"], _NT, precision=_HIGHEST), 0.0)
+            dbeta = (
+                jnp.sum(drk * x["k_from_start"], axis=1, keepdims=True)
+                + jnp.sum(drv * x["v32"], axis=1, keepdims=True)
+                + jnp.sum(da * x["m"], axis=1, keepdims=True))
+            dv_ref[at, h * dv:(h + 1) * dv] = (bcol * drv).astype(compute)
+            dgamma = jnp.sum(drk * x["rk"], axis=1, keepdims=True)
+            dk32 = drk * (bcol * x["from_start"])
+
+            # through the scores inside the chunks and their decays: what
+            # passes d/d(gamma_i - gamma_j) raises what i reads and lowers
+            # what j hands on.  Both are sums of the same entries
+            dkk = (da * bcol * decay).astype(compute)
+            through_decay = da * x["a"] + dscores * x["scores"]
+            dgamma = dgamma + jnp.sum(through_decay, axis=1, keepdims=True) - _as_col(
+                jnp.sum(through_decay, axis=0, keepdims=True), x["eye"])
+            dqk = (dscores * decay).astype(compute)
+            dk32 = dk32 + _dot(dkk, k) + _dot(dkk, k, _TN) + _dot(dqk, q, _TN)
+            dq32 = _dot(dqk, k) + dq_from_start * x["from_start"]
+            dgamma = dgamma + jnp.sum(
+                dq_from_start * q.astype(f32) * x["from_start"], axis=1, keepdims=True)
+            dk32 = dk32 + dk_to_end * x["to_end"]
+            # a position's sum lowers what it hands to the leaving state; the
+            # chunk's last one raises all of it
+            handed_on = jnp.sum(
+                dk_to_end * x["k32"] * x["to_end"], axis=1, keepdims=True)
+            dgamma = dgamma - handed_on + jnp.concatenate([
+                jnp.where(ends, jnp.broadcast_to(
+                    of["dlast"] + jnp.sum(
+                        handed_on[n:n + c], axis=0, keepdims=True), (c, 1)), 0.0)
+                for n, of in zip(range(0, FRAME, c), chain)], axis=0)
+            if unit:
+                dq32 = _through_unit(dq32, *x["units"][0])
+                dk32 = _through_unit(dk32, *x["units"][1])
+            dq_ref[h, at, :] = dq32.astype(compute)
+            dk_ref[h, at, :] = dk32.astype(compute)
+            dcol = jnp.where(lane == h, dgamma, dcol)
+            dcol = jnp.where(lane == hg + h, dbeta, dcol)
+        dcols.append(dcol)
+    drows_ref[...] = jnp.concatenate(dcols[::-1], axis=0).T[:2 * hg, :]
+
+
+_PARAMS = pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary"))
+
+
+def _layout(q, v, c: int, order) -> tuple:
+    """``(hg, steps a row, dv)`` of a call on ``q`` [B H, S, dk] and ``v``
+    [B, S, H dv] and its block specs for grid row ``r`` (``hg`` heads of one
+    batch row) and step ``n``, which holds positions ``order(n) t``
+    onwards: of ``q``'s kind, of ``v``'s, of the heads' rows [B H / hg, 2
+    hg, S], of the entering states [B H / hg, S / c, hg, dk, dv] and of one
+    state a head."""
+    rows, s, dk = q.shape
+    h = rows // v.shape[0]
+    dv = v.shape[2] // h
+    hg, t = _grid(h, s, c, dk, dv)
+    return (hg, s // t, dv), {
+        "qk": pl.BlockSpec((hg, t, dk), lambda r, n: (r, order(n), 0)),
+        "v": pl.BlockSpec(
+            (None, t, hg * dv),
+            lambda r, n: (r // (h // hg), order(n), r % (h // hg))),
+        "rows": pl.BlockSpec((None, 2 * hg, t), lambda r, n: (r, 0, order(n))),
+        "entering": pl.BlockSpec(
+            (None, t // c, hg, dk, dv), lambda r, n: (r, order(n), 0, 0, 0)),
+        "state": pl.BlockSpec((hg, dk, dv), lambda r, n: (r, 0, 0)),
+    }
+
+
+def _head_rows(gamma: jax.Array, beta: jax.Array, hg: int) -> jax.Array:
+    """Two [B H, S] a head -> [B H / hg, 2 hg, S]: a grid row's heads a
+    row each, ``gamma``'s rows then ``beta``'s."""
+    rows, s = gamma.shape
+    return jnp.concatenate(
+        [gamma.reshape(rows // hg, hg, s), beta.reshape(rows // hg, hg, s)], axis=1)
+
+
+def _forward(q, k, v, gamma, beta, c, unit, keep, interpret):
+    """``(o [B, S, H dv], the state after the last position [B H, dk, dv]
+    float32)`` and, between them where ``keep``, the state entering each
+    chunk [B H / hg, S / c, hg, dk, dv] float32: the backward's residual."""
+    rows, s, dk = q.shape
+    (hg, steps, dv), spec = _layout(q, v, c, lambda n: n)
+    entering = [(spec["entering"], jax.ShapeDtypeStruct(
+        (rows // hg, s // c, hg, dk, dv), jnp.float32))] if keep else []
+    out_specs, out_shape = zip(
+        (spec["v"], jax.ShapeDtypeStruct(v.shape, v.dtype)), *entering,
+        (spec["state"], jax.ShapeDtypeStruct((rows, dk, dv), jnp.float32)))
+    return pl.pallas_call(
+        functools.partial(_fwd_kernel, hg=hg, c=c, keep=keep, unit=unit),
+        grid=(rows // hg, steps),
+        in_specs=[spec["qk"], spec["qk"], spec["v"], spec["rows"]],
+        out_specs=list(out_specs), out_shape=list(out_shape),
+        scratch_shapes=[pltpu.VMEM((hg, dk, dv), jnp.float32)],
+        compiler_params=_PARAMS, interpret=interpret, name="delta_chunk_fwd",
+    )(q, k, v, _head_rows(gamma, beta, hg))
+
+
+def _backward(q, k, v, gamma, beta, entering, do, dfinal, c, unit, interpret):
+    """The gradients of ``q``, ``k``, ``v``, ``gamma`` and ``beta``, the
+    chunks in reverse, the state's cotangent carried as the forward
+    carries the state."""
+    rows, s, _ = q.shape
+    (hg, steps, _), spec = _layout(q, v, c, lambda n: steps - 1 - n)
+    dq, dk, dv, drows = pl.pallas_call(
+        functools.partial(_bwd_kernel, hg=hg, c=c, unit=unit),
+        grid=(rows // hg, steps),
+        in_specs=[spec["qk"], spec["qk"], spec["v"], spec["rows"],
+                  spec["entering"], spec["v"], spec["state"]],
+        out_specs=[spec["qk"], spec["qk"], spec["v"], spec["rows"]],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype),
+                   jax.ShapeDtypeStruct((rows // hg, 2 * hg, s), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((hg, *entering.shape[3:]), jnp.float32)],
+        compiler_params=_PARAMS, interpret=interpret, name="delta_chunk_bwd",
+    )(q, k, v, _head_rows(gamma, beta, hg), entering, do, dfinal)
+    dgamma, dbeta = drows.reshape(rows // hg, 2, hg, s).swapaxes(0, 1)
+    return dq, dk, dv, dgamma.reshape(rows, s), dbeta.reshape(rows, s)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _rule(q, k, v, gamma, beta, c, unit, interpret):
+    return _forward(q, k, v, gamma, beta, c, unit, False, interpret)
+
+
+def _rule_fwd(q, k, v, gamma, beta, c, unit, interpret):
+    o, entering, final = _forward(q, k, v, gamma, beta, c, unit, True, interpret)
+    return (o, final), (q, k, v, gamma, beta, entering)
+
+
+def _rule_bwd(c, unit, interpret, residuals, cotangents):
+    return _backward(*residuals, *cotangents, c, unit, interpret)
+
+
+_rule.defvjp(_rule_fwd, _rule_bwd)
+
+
+def gated_delta_kernel(
+    q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array, beta: jax.Array,
+    chunk: int, interpret: bool = False, unit: bool = False,
+) -> tuple[jax.Array, jax.Array]:
+    """:func:`gated_delta_chunked` as two Pallas TPU kernels
+    (``delta_chunk_fwd`` and, behind a ``jax.custom_vjp``,
+    ``delta_chunk_bwd``) for shapes :func:`kernel_fits` admits;
+    ``interpret`` runs them on any backend.  The decays' sums inside the
+    chunks are made here, in XLA, as the plain form makes them, and their
+    gradient back to ``g`` is autodiff's; so are the transposes that put
+    the heads of ``q`` and ``k`` first (``v`` and ``o`` stay as they are).
+    Under ``unit`` the kernels make ``q`` and ``k`` unit-length in VMEM, so
+    what XLA transposes is the convolution's bf16 output as it stands: left
+    to make them itself and hand them over heads first, it copies the
+    [B, S, 2 H dk] float32 twice a call (377 MB each in the cell)."""
+    bsz, s, h, dk = q.shape
+    dv = v.shape[-1]
+    c = min(chunk, s)
+    if _grid(h, s, c, dk, dv) is None:
+        raise ValueError(
+            f"the delta rule's kernel has no tiles for {q.shape} / {v.shape} "
+            f"in chunks of {c} (ops/delta_rule.py _grid)")
+    f32 = jnp.float32
+
+    def heads_first(a):  # [B, S, H, ..] -> [B H, S, ..]
+        return jnp.moveaxis(a, 2, 1).reshape(bsz * h, s, *a.shape[3:])
+
+    gamma = jnp.cumsum(
+        g.astype(f32).reshape(bsz, s // c, c, h), axis=2).reshape(bsz, s, h)
+    o, final = _rule(
+        heads_first(q), heads_first(k), v.reshape(bsz, s, h * dv),
+        heads_first(gamma), heads_first(beta.astype(f32)), c, unit, interpret)
+    return o.reshape(bsz, s, h, dv), final.reshape(bsz, h, dk, dv)

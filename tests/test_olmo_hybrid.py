@@ -39,6 +39,7 @@ REFERENCE = os.path.join(
 reference = harness.load_path(REFERENCE)
 runner = harness.load_path(os.path.join(
     REPO, "benchmarks", "runners", "train_recipe_delta.py"))
+probe = harness.load_path(os.path.join(REPO, "tools", "smallthinker_probe.py"))
 TINY_FILE = harness.load_json(os.path.join(
     REPO, "benchmarks", "rehearsal", "configs", "olmohybrid-tiny.json"))
 CELL_FILE = harness.load_json(os.path.join(
@@ -573,3 +574,65 @@ def test_a_program_without_the_recipe_fails_at_once_with_no_result(tmp_path):
         cwd=REPO, env=env, capture_output=True, text=True, timeout=120)
     assert run.returncode == 2 and "no recipe" in run.stderr
     assert not run.stdout.strip()
+
+
+# ---- the chip's compiler accepts the rule's kernels and the step ----
+
+
+def test_the_rules_kernels_compile_for_the_chip_at_the_cells_shape(v5e_chip):
+    """``delta_chunk_fwd`` and ``delta_chunk_bwd`` at ``[1, 16384, 30, 96 /
+    192]`` bf16 in chunks of 64, compiled for a described chip (nothing
+    runs): Mosaic takes keys of 96 and values of 192 as blocks that span
+    the arrays' last axis, three heads abreast, the frames' transposes,
+    the products at the highest precision and the VMEM the kernels ask
+    for."""
+    from learning_at_home_tpu.ops import delta_rule
+
+    one = jax.sharding.SingleDeviceSharding(v5e_chip)
+    s, h, dk, dv = (CELL_FILE[k] for k in (
+        "seq_len", "linear_num_key_heads", "linear_key_head_dim",
+        "linear_value_head_dim"))
+    assert (s, h, dk, dv) == (16384, 30, 96, 192)
+    assert delta_rule.kernel_fits((1, s, h, dk), (1, s, h, dv), 64, "tpu")
+
+    def shaped(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    args = (shaped((1, s, h, dk), jnp.bfloat16), shaped((1, s, h, dk), jnp.bfloat16),
+            shaped((1, s, h, dv), jnp.bfloat16), shaped((1, s, h), jnp.float32),
+            shaped((1, s, h), jnp.float32))
+
+    def loss(*a):
+        o, state = delta_rule.gated_delta_kernel(*a, 64)
+        return jnp.sum(o.astype(jnp.float32)) + jnp.sum(state)
+
+    with probe.no_compile_cache():
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+            *args).compile().as_text()
+    calls = probe.scan_kernel_calls(text, "delta_chunk", "delta/core")
+    assert {name: c["calls"] for name, c in calls.items()} == {
+        "delta_chunk_fwd": 1, "delta_chunk_bwd": 1}
+
+
+def test_the_whole_step_fits_the_chip_and_runs_the_rule_as_kernels(
+        v5e_chip, monkeypatch):
+    """The eight-layer train step at published widths, compiled for a
+    described chip (nothing runs): 1,857,720,552 parameters; the
+    compiler's own count of what is live in the step no more than 1 GB
+    above the 12.02 GB the plain rule in checkpointed segments read (11.41
+    GB, 67.5 %, when this was written: PR 46); a delta layer's forward
+    kernel twice (the step's, and remat's, which writes the entering
+    states the backward kernel reads: nothing of the rule is kept across
+    the backward pass) and its backward kernel once, every call under
+    ``delta/core``, and no loop over chunks or segments left there."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    memory = probe.step_memory(v5e_chip, "olmo_hybrid_7b_one_chip")
+    assert memory["parameters"] == 1_857_720_552
+    assert 0.25 < memory["share_of_chip"] and memory["live_bytes"] < 13.02e9, memory
+    assert memory["delta_kernel_calls"] == {
+        "delta_chunk_fwd": {"calls": 2 * 6, "under_delta_core": 2 * 6},
+        "delta_chunk_bwd": {"calls": 6, "under_delta_core": 6}}
+    assert memory["loops_under_delta_core"] == 0
+    assert memory["attention_kernel_calls"] == {
+        "splash_mha_fwd_residuals": 2, "splash_mha_dkv_no_residuals": 2}
+    assert memory["loss_layer_products"] == 3

@@ -541,9 +541,13 @@ NEMOTRON_TINY_STEP_SHA256 = (
 )
 # Olmo-Hybrid's tiny step, first taken on PR 45's tree, the PR that brought
 # it (delta-rule layers, the norm on outputs, no mixture layer); the seven
-# above read on that tree what they read before it.
+# above read on that tree what they read before it.  PR 46: the unit-length
+# scaling of q and k moved out of ``trunk.delta_mixer`` into the rule
+# (``ops/delta_rule.py`` ``unit_length``: q and k apart, the same arithmetic
+# an element; the rule's kernel does it in VMEM), so this one text changed
+# (it read e506501163cf..18d4f6) and the seven above did not.
 OLMO_HYBRID_TINY_STEP_SHA256 = (
-    "e506501163cf1b35d0cef15a7a5129ab0ce4793175c62a11805933b2ef18d4f6"
+    "4d421ce8f6030ad9f27be2483369498e0f26f5204b9f46ea93a0fb3bbf838fea"
 )
 
 

@@ -7,7 +7,8 @@
     chiprun -- python tools/smallthinker_probe.py float8 [config.json] [seed ...]
     chiprun -- python tools/smallthinker_probe.py ssd [seq_len] [accuracy_len]
     chiprun -- python tools/smallthinker_probe.py conv [rows x channels ...]
-    chiprun -- python tools/smallthinker_probe.py delta [seq_len] [accuracy_len]
+    chiprun -- python tools/smallthinker_probe.py delta [seq_len] [accuracy_len] [form ...]
+    chiprun -- python tools/smallthinker_probe.py delta split [seq_len] [chunk]
 
 ``memory`` (here, no chip): the whole train step of a recipe of
 ``__graft_entry__`` (``smallthinker_one_chip``, or the one named, such as
@@ -58,12 +59,17 @@ kernel at those blocks (rows x channels) too (PERF.md section 6, PR 41).
 
 ``delta`` (on the chip): the chunked gated delta rule of
 ``ops/delta_rule.py`` at the Olmo-Hybrid cell's shape (``[1, seq_len, 30,
-96 / 192]`` bf16; ``seq_len`` 16,384 by default) for each way of inverting
-``I + A`` (``blocks``, ``product``, ``triangular``) and chunks of 32, 64 and
-128: milliseconds a call forward and forward + backward, and at
-``accuracy_len`` the relative rms of the output, the last state and the five
-gradients against the rule a position at a time in float32 (PERF.md
-section 6, PR 45): where a kernel for the rule starts from.
+96 / 192]`` bf16; ``seq_len`` 16,384 by default) as its kernels
+(``kernel``: ``delta_chunk_fwd`` / ``delta_chunk_bwd``, PR 46) and in plain
+form for each way of inverting ``I + A`` (``blocks``, ``product``,
+``triangular``; or the forms named), at chunks of 32, 64 and 128: milliseconds a call forward
+and forward + backward, and at ``accuracy_len`` the relative rms of the
+output, the last state and the five gradients against the rule a position
+at a time in float32 (PERF.md section 6, PR 45 and PR 46).  ``delta split
+[seq_len] [chunk]``: where the PLAIN rule's time goes (the whole rule, the
+solve alone, the diagonal blocks' inverses alone, the ``lax.scan`` over
+the chunks alone, the rule with its solve stubbed out): the reading the
+kernel's design started from (PR 46).
 """
 
 import collections
@@ -207,7 +213,10 @@ def step_memory(chip, recipe: str = "smallthinker_one_chip") -> dict:
     kernel's residuals), under ``kept_residual_bytes`` what that costs
     (:func:`kept_residual_bytes`), under ``scan_kernel_calls`` and
     ``kept_scan_bytes`` the same two for the state-space scan's kernels
-    (:func:`scan_kernel_calls`; PR 40), under ``conv_kernel_calls`` the
+    (:func:`scan_kernel_calls`; PR 40), under ``delta_kernel_calls`` the
+    delta rule's kernels under ``delta/core`` and under
+    ``loops_under_delta_core`` the ``while`` instructions that scope still
+    holds (none where the kernel runs: PR 46), under ``conv_kernel_calls`` the
     convolution's kernels under ``ssm/conv`` and under
     ``float32_arrays_under_ssm_conv`` the float32 ``[1, S (+ 3), C]``
     arrays, ``C`` the channels of ``x B C`` or of one of the three, that
@@ -274,6 +283,9 @@ def step_memory(chip, recipe: str = "smallthinker_one_chip") -> dict:
             r'^\s*%\S+ = (f32\[1,1638[47],(?:6144|4096|1024)\])[^\n]*op_name="[^"\n]*ssm/conv',
             text, re.M))),
         "kept_scan_bytes": kept_residual_bytes(traced.jaxpr.jaxpr, SSD_RESIDUALS),
+        "delta_kernel_calls": scan_kernel_calls(text, "delta_chunk", "delta/core"),
+        "loops_under_delta_core": len(re.findall(
+            r'^\s*%\S+ = [^\n]* while\([^\n]*op_name="[^"\n]*delta/core', text, re.M)),
         "loss_layer_products": len(re.findall(
             r'^\s*%\S+ = [^\n]* fusion\([^\n]*'
             r'op_name="[^"\n]*[/(]ce[/)][^"\n]*dot_general"', text, re.M)),
@@ -478,7 +490,8 @@ def conv(blocks: list, calls: int = 20) -> None:
         }), flush=True)
 
 
-def delta(seq_len: int = 16384, accuracy_len: int = 1024, calls: int = 5) -> None:
+def delta(seq_len: int = 16384, accuracy_len: int = 1024, calls: int = 5,
+          forms=("kernel", "blocks", "product", "triangular")) -> None:
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -528,10 +541,12 @@ def delta(seq_len: int = 16384, accuracy_len: int = 1024, calls: int = 5) -> Non
 
     want = jax.device_get(everything(ops.gated_delta_recurrent, accuracy_len)(
         *inputs(accuracy_len, f32)))
-    for solve in ("blocks", "product", "triangular"):
+    for solve in forms:
         for chunk in (32, 64, 128):
             def form(*a, solve=solve, chunk=chunk):
-                return ops.gated_delta_chunked(*a, chunk, solve=solve)
+                if solve == "kernel":  # delta_chunk_fwd / delta_chunk_bwd
+                    return ops.gated_delta_kernel(*a, chunk)
+                return ops.gated_delta_plain(*a, chunk, solve=solve)
 
             line = {"solve": solve, "chunk": chunk, "seq_len": seq_len,
                     "accuracy_len": accuracy_len, "shape": [1, seq_len, h, dk, dv]}
@@ -551,6 +566,79 @@ def delta(seq_len: int = 16384, accuracy_len: int = 1024, calls: int = 5) -> Non
             print("DELTA " + json.dumps(line), flush=True)
 
 
+def delta_split(seq_len: int = 16384, chunk: int = 64, calls: int = 5) -> None:
+    """Where the PLAIN chunked rule's time goes at the cell's shape: the
+    whole rule, then with the solve stubbed out (the right-hand side
+    returned as it stands), the solve alone over every chunk at once, the
+    diagonal blocks' inverses alone, and the ``lax.scan`` over the chunks
+    alone; forward and forward + backward (a sum as the loss)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    import harness
+    from learning_at_home_tpu.ops import delta_rule as ops
+
+    config = harness.load_json(os.path.join(
+        REPO, "benchmarks/configs/olmo-hybrid-7b.json"))
+    h, dk, dv = (config["linear_num_key_heads"], config["linear_key_head_dim"],
+                 config["linear_value_head_dim"])
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    rs = np.random.default_rng(46)
+    nc = seq_len // chunk
+    nb = chunk // ops.SOLVE_BLOCK
+
+    def normal(shape, dtype, scale=1.0):
+        return jnp.asarray(scale * rs.standard_normal(shape), dtype)
+
+    q, k = (normal((1, seq_len, h, dk), bf16, dk ** -0.5) for _ in "qk")
+    v = normal((1, seq_len, h, dv), bf16)
+    g = -jnp.abs(normal((1, seq_len, h), f32, 0.05))
+    beta = jnp.asarray(rs.uniform(0, 2, (1, seq_len, h)), f32)
+    a = jnp.tril(normal((1, nc, h, chunk, chunk), f32, 0.1), -1)
+    rhs = normal((1, nc, h, chunk, dk + dv), f32)
+    blocks = jnp.tril(normal((1, nc, h, nb, 16, 16), f32, 0.1), -1)
+    w, kt = (normal((nc, 1, h, chunk, dk), bf16) for _ in "wk")
+    u = normal((nc, 1, h, chunk, dv), f32)
+    decay = jnp.asarray(rs.uniform(0.5, 1, (nc, 1, h, 1, 1)), f32)
+
+    def scan(w, u, kt, decay):
+        def one_chunk(state, of_chunk):
+            w_c, u_c, k_c, decay_c = of_chunk
+            entering = state.astype(bf16)
+            new = (u_c - jnp.einsum("bhik,bhkv->bhiv", w_c, entering,
+                                    preferred_element_type=f32)).astype(bf16)
+            return decay_c * state + jnp.einsum(
+                "bhik,bhiv->bhkv", k_c, new, preferred_element_type=f32), (
+                    entering, new)
+        return jax.lax.scan(one_chunk, jnp.zeros((1, h, dk, dv), f32),
+                            (w, u, kt, decay))
+
+    def rule(*args):
+        return ops.gated_delta_plain(*args, chunk)
+
+    def both(fn, args, argnums):
+        def loss(*x):
+            return sum(jnp.sum(t.astype(f32)) for t in jax.tree.leaves(fn(*x)))
+        return {"forward_ms": _ms(jax.jit(fn), args, calls),
+                "forward_backward_ms": _ms(
+                    jax.jit(jax.grad(loss, argnums=argnums)), args, calls)}
+
+    line = {"chunk": chunk, "seq_len": seq_len, "shape": [1, seq_len, h, dk, dv]}
+    line["rule"] = both(rule, (q, k, v, g, beta), (0, 1, 2, 3, 4))
+    line["solve"] = both(ops.solve_unit_lower, (a, rhs), (0, 1))
+    line["block_inverses"] = both(ops._substituted_inverse, (blocks,), (0,))
+    line["scan"] = both(scan, (w, u, kt, decay), (0, 1, 2, 3))
+    whole_solve = ops.solve_unit_lower
+    ops.solve_unit_lower = lambda a, rhs, how: rhs + 0.0 * jnp.sum(
+        a, axis=-1, keepdims=True)
+    try:
+        line["rule_without_solve"] = both(rule, (q, k, v, g, beta), (0, 1, 2, 3, 4))
+    finally:
+        ops.solve_unit_lower = whole_solve
+    print("DELTA_SPLIT " + json.dumps(line), flush=True)
+
+
 if __name__ == "__main__":
     if sys.argv[1:2] == ["memory"]:
         memory(*sys.argv[2:3])
@@ -558,8 +646,12 @@ if __name__ == "__main__":
         conv(sys.argv[2:])
     elif sys.argv[1:2] == ["ssd"]:
         ssd(*(int(a) for a in sys.argv[2:4]))
+    elif sys.argv[1:3] == ["delta", "split"]:
+        delta_split(*(int(a) for a in sys.argv[3:5]))
     elif sys.argv[1:2] == ["delta"]:
-        delta(*(int(a) for a in sys.argv[2:4]))
+        named = [a for a in sys.argv[2:] if not a.isdigit()]
+        delta(*(int(a) for a in sys.argv[2:] if a.isdigit()),
+              **({"forms": named} if named else {}))
     elif sys.argv[1:2] == ["float8"]:
         named = [a for a in sys.argv[2:] if a.endswith(".json")]
         float8([int(s) for s in sys.argv[2:] if s not in named] or [3100000007],
