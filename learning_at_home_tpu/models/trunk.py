@@ -13,6 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from learning_at_home_tpu.ops.delta_rule import gated_delta_chunked
+from learning_at_home_tpu.ops.gate_norm import gated_rms_norm
 from learning_at_home_tpu.ops.ssd import ssd_chunked
 from learning_at_home_tpu.ops.ssm_conv import causal_conv_silu
 
@@ -190,9 +191,12 @@ def ssm_mixer(
     [S, G, N] its three parts, head ``h`` reading group ``h // (H / G)``;
     ``dt = softplus(dt + dt_bias)``; ``A = -exp(A_log)``; the recurrence ``h_t = exp(dt_t A)
     h_{t-1} + dt_t x_t B_t^T``, ``y_t = h_t C_t + D x_t`` in chunks of
-    ``chunk`` (:func:`~learning_at_home_tpu.ops.ssd.ssd_chunked`); ``y =
-    RMSNorm(y * silu(z))``, the gate FIRST, then the norm over each of the
-    ``G`` groups of channels under one scale; ``out = y W_out``.
+    ``chunk`` (:func:`~learning_at_home_tpu.ops.ssd.ssd_chunked`, which
+    returns ``h_t C_t``); ``y = RMSNorm((y + D x) * silu(z))``, the gate
+    FIRST, then the norm over each of the ``G`` groups of channels under a
+    scale a channel (:func:`~learning_at_home_tpu.ops.gate_norm.
+    gated_rms_norm` with the skip: on a TPU one pass each way, ``z`` read
+    where the in-projection left it); ``out = y W_out``.
     ``decay_dtype`` is ``ssd_chunked``'s: float32 in every step.  The
     parameters say the sizes: the heads' size ``P`` is ``w_out``'s input
     width over ``n_heads``, the state's ``N`` what ``conv_w``'s channels
@@ -206,7 +210,6 @@ def ssm_mixer(
     n_state = (conv_dim - d_inner) // (2 * n_groups)
     with jax.named_scope("in_proj"):
         zxbcdt = u @ p["w_in"].astype(u.dtype)
-        z = zxbcdt[..., :d_inner]
         dt = zxbcdt[..., d_inner + conv_dim:]
     with jax.named_scope("conv"):  # of x, B and C apart (a channel at a
         # time, so the same numbers): each is read where the product left it
@@ -223,16 +226,13 @@ def ssm_mixer(
         y, state = ssd_chunked(
             x, dt, a, b_in.reshape(b, s, n_groups, n_state),
             c_out.reshape(b, s, n_groups, n_state), chunk, decay_dtype)
-        y = y.astype(f32) + p["D"].astype(f32)[:, None] * x.astype(f32)
         decay_min = jnp.exp(jnp.min(dt * a))
-    with jax.named_scope("gate_norm"):
-        y = y.reshape(b, s, d_inner) * jax.nn.silu(z.astype(f32))
-        grouped = y.reshape(b, s, n_groups, d_inner // n_groups)
-        ms = jnp.mean(grouped * grouped, axis=-1, keepdims=True)
-        y = (
-            (grouped * jax.lax.rsqrt(ms + eps)).reshape(b, s, d_inner)
-            * p["gate_norm"]["scale"]
-        ).astype(u.dtype)
+    with jax.named_scope("gate_norm"):  # y + D x, the gate, the norm: one
+        # pass, z read where the product left it
+        y = gated_rms_norm(
+            y.reshape(b, s, d_inner), zxbcdt, p["gate_norm"]["scale"],
+            d_inner // n_groups, eps, gate_first=True,
+            skip=(x.reshape(b, s, d_inner), p["D"]))
     with jax.named_scope("out_proj"):
         return y @ p["w_out"].astype(u.dtype), state, decay_min
 
@@ -266,7 +266,9 @@ def delta_mixer(
     elsewhere; ``decay_dtype`` other than float32 is the plain form's,
     for the probes); ``y = RMSNorm(o) * silu(z)``, the norm FIRST
     (over each head's ``dv``, one scale shared by the heads), then the
-    gate (Mamba-2's mixer gates first); ``out = y W_out``.  The parameters
+    gate (Mamba-2's mixer gates first; :func:`~learning_at_home_tpu.ops.
+    gate_norm.gated_rms_norm` in its other order, ``z`` read at its column
+    of the in-projection); ``out = y W_out``.  The parameters
     say the sizes: a head's value size ``dv`` is ``w_out``'s input width
     over ``n_heads``, its key size ``dk`` what the convolution's channels
     leave beyond ``v`` over ``2 H``.  Sub-scopes ``in_proj``, ``conv``,
@@ -279,7 +281,6 @@ def delta_mixer(
     with jax.named_scope("in_proj"):
         w_in = p["w_in"].astype(x.dtype)
         proj = x @ w_in
-        z = proj[..., d_qk + d_v:d_qk + 2 * d_v]
         # what the write strengths and the decays are made of leaves its
         # product in float32 (2 H columns: a product of their own): rounded
         # to bf16, a pre-activation of 10 is off by 0.03, and sigmoid and
@@ -303,14 +304,12 @@ def delta_mixer(
             qk[:, :, 0], qk[:, :, 1], v.reshape(b, s, n_heads, dv), g, beta,
             chunk, decay_dtype, unit=True)
         decay_min, beta_max = jnp.exp(jnp.min(g)), jnp.max(beta)
-    with jax.named_scope("gate_norm"):
-        o = o.astype(f32)
-        ms = jnp.mean(o * o, axis=-1, keepdims=True)
-        y = (
-            o * jax.lax.rsqrt(ms + eps) * p["gate_norm"]["scale"]
-        ).reshape(b, s, d_v) * jax.nn.silu(z.astype(f32))
+    with jax.named_scope("gate_norm"):  # z read where the product left it
+        y = gated_rms_norm(
+            o.reshape(b, s, d_v), proj, p["gate_norm"]["scale"], dv, eps,
+            gate_first=False, first=d_qk + d_v)
     with jax.named_scope("out_proj"):
-        out = y.astype(x.dtype) @ p["w_out"].astype(x.dtype)
+        out = y @ p["w_out"].astype(x.dtype)
     return out, state, decay_min, beta_max
 
 

@@ -33,6 +33,7 @@ from learning_at_home_tpu.models.transformer import (  # noqa: E402
     DMoETransformerConfig,
     DMoETransformerLM,
 )
+from learning_at_home_tpu.ops import gate_norm  # noqa: E402
 from learning_at_home_tpu.ops import moe_dispatch  # noqa: E402
 from learning_at_home_tpu.ops import ssd  # noqa: E402
 from learning_at_home_tpu.ops import ssm_conv  # noqa: E402
@@ -185,6 +186,66 @@ def test_the_state_space_mixer_matches_the_recurrence_as_written(tiny, chunks):
     one, one_state, _ = _mixer(cfg, lp, x, cfg.seq_len)
     _close(got, one, 2e-6)
     _close(state, one_state, 2e-6)
+
+
+def _kernel_under_interpret(monkeypatch, calls):
+    """``trunk``'s gate and norm go through the kernel form, interpreted."""
+    def through_the_kernel(y, z, scale, group, eps, gate_first, first=0, skip=None):
+        assert gate_norm.gate_norm_fits(y.shape, group, "tpu", first)
+        calls.append((y.shape, group, gate_first, first, skip is not None))
+        return gate_norm.gated_rms_norm_kernel(
+            y, z, scale, group, eps, gate_first, first, skip, interpret=True)
+
+    monkeypatch.setattr(trunk, "gated_rms_norm", through_the_kernel)
+
+
+@pytest.mark.parametrize("groups", [2, 1])
+def test_the_mixer_through_the_gate_norm_kernel_matches_the_reference(
+        monkeypatch, groups):
+    """The mixer alone at widths the kernel's tiles admit (4 heads of 64:
+    256 channels in groups of 128 or one of 256), float32, with its gate,
+    skip and norm as ``gate_norm_fwd`` under ``interpret``: the output and
+    the state against the recurrence as written, within what the plain
+    form is held to above; and every gradient of the mixer against the
+    plain form's."""
+    d, h, hp, n, s = 32, 4, 64, 16, 32
+    sizes = dict(SIZES, mamba_num_heads=h, mamba_head_dim=hp, ssm_state_size=n,
+                 n_groups=groups)
+    d_inner, conv_dim = h * hp, h * hp + 2 * groups * n
+    rs = np.random.RandomState(5)
+
+    def normal(*shape, scale=1.0):
+        return jnp.asarray(scale * rs.randn(*shape), jnp.float32)
+
+    lp = {"norm": {"scale": 1.0 + normal(d, scale=0.1)}, "ssm": {
+        "w_in": normal(d, d_inner + conv_dim + h, scale=d ** -0.5),
+        "conv_w": normal(conv_dim, 4, scale=0.5), "conv_b": normal(conv_dim, scale=0.1),
+        "dt_bias": normal(h), "A_log": jnp.log(jnp.asarray(rs.uniform(1, 16, h), jnp.float32)),
+        "D": jnp.asarray(rs.uniform(0.5, 1.5, h), jnp.float32),
+        "gate_norm": {"scale": 1.0 + normal(d_inner, scale=0.2)},
+        "w_out": normal(d_inner, d, scale=d_inner ** -0.5)}}
+    x = normal(2, s, d)
+
+    def mixer(lp, x):
+        return trunk.ssm_mixer(
+            lp["ssm"], trunk.rms_norm(lp["norm"], x, 1e-5), h, groups, 16, 1e-5)
+
+    def loss(lp, x):
+        out, state, _ = mixer(lp, x)
+        return jnp.sum(out * jnp.cos(out)) + jnp.sum(state)
+
+    plain = jax.grad(loss, argnums=(0, 1))(lp, x)
+    calls = []
+    _kernel_under_interpret(monkeypatch, calls)
+    want, want_state = reference.ssm_part(lp, x, sizes)
+    got, state, _ = mixer(lp, x)
+    assert calls == [((2, s, d_inner), d_inner // groups, True, 0, True)]
+    _close(got, want, 1e-5)
+    _close(state, want_state, 1e-5)
+    through = jax.grad(loss, argnums=(0, 1))(lp, x)
+    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(through),
+                            jax.tree_util.tree_leaves(plain)):
+        _close(g, w, 1e-5, err_msg=jax.tree_util.keystr(path))
 
 
 def test_the_convolution_reads_zeros_before_the_sequence(tiny):
@@ -861,6 +922,15 @@ def test_the_whole_step_fits_the_chip(v5e_chip, monkeypatch):
         "ssm_conv_fwd": {"calls": 24, "under_ssm_conv": 24},
         "ssm_conv_bwd": {"calls": 12, "under_ssm_conv": 12}}
     assert memory["float32_arrays_under_ssm_conv"] == []
+    # the skip, the gate and the norm as one pass: forward, recomputed
+    # (remat keeps nothing of it) and backward a state-space layer, every
+    # call under ``ssm/gate_norm``, and no float32 ``[1, 16384, 4096]``
+    # written there or under ``ssm/scan`` on their behalf (PR 47; the
+    # parent's live count read 9,878,984,192)
+    assert memory["gate_norm_kernel_calls"] == {
+        "gate_norm_fwd": {"calls": 2 * 4, "under_ssm_gate_norm": 2 * 4},
+        "gate_norm_bwd": {"calls": 4, "under_ssm_gate_norm": 4}}
+    assert memory["float32_arrays_beside_gate_norm"] == []
 
 
 def test_the_scan_kernels_compile_for_the_chip_at_the_cells_shape(v5e_chip):
@@ -928,6 +998,41 @@ def test_the_convolutions_kernels_compile_for_the_chip_at_the_cells_shape(v5e_ch
     calls = probe.scan_kernel_calls(text, "ssm_conv", "ssm/conv")
     assert {name: entry["calls"] for name, entry in calls.items()} == {
         "ssm_conv_fwd": 3, "ssm_conv_bwd": 3}
+
+
+@pytest.mark.parametrize("cell", ["nemotron", "olmo-hybrid"])
+def test_the_gate_norm_kernels_compile_for_the_chip_at_the_cells_shapes(v5e_chip, cell):
+    """``gate_norm_fwd`` and ``gate_norm_bwd`` as the two hybrid cells'
+    mixers call them, compiled for a described chip (nothing runs; here
+    because the described chip's library is one file's to load).  Nemotron:
+    4,096 channels in groups of 512 under a scale a channel, the gate
+    first, ``z`` at column 0 of the in-projection's ``[1, 16384, 10304]``
+    bf16, the skip ``y + D x`` inside.  Olmo-Hybrid: 5,760 channels, heads
+    of 192 two to a block of 384 lanes under one shared scale, the norm
+    first, ``z`` at column 11,520 of ``[1, 16384, 17340]``.  Mosaic takes
+    the blocks at their offsets, the masked sums along the lanes and the
+    partial sums' blocks."""
+    one = jax.sharding.SingleDeviceSharding(v5e_chip)
+    s, bf16 = CELL_FILE["seq_len"], jnp.bfloat16
+    c, wide, first, group, n_scale, gate_first, heads = {
+        "nemotron": (4096, 10304, 0, 512, 4096, True, 64),
+        "olmo-hybrid": (5760, 17340, 11520, 192, 192, False, 0)}[cell]
+    assert gate_norm.gate_norm_fits((1, s, c), group, "tpu", first)
+
+    def shaped(*shape):
+        return jax.ShapeDtypeStruct(shape, bf16, sharding=one)
+
+    def loss(y, z, scale, skip):  # the square: its cotangent reads the result
+        return jnp.sum(gate_norm.gated_rms_norm_kernel(
+            y, z, scale, group, 1e-5, gate_first, first, skip).astype(jnp.float32) ** 2)
+
+    skip = (shaped(1, s, c), shaped(heads)) if heads else None
+    with probe.no_compile_cache():
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3) if heads else (0, 1, 2))).lower(
+            shaped(1, s, c), shaped(1, s, wide), shaped(n_scale), skip).compile().as_text()
+    calls = probe.scan_kernel_calls(text, "gate_norm", "gate_norm")
+    assert {name: entry["calls"] for name, entry in calls.items()} == {
+        "gate_norm_fwd": 1, "gate_norm_bwd": 1}
 
 
 def test_the_convolutions_kernels_compile_at_the_delta_mixers_shape(v5e_chip):

@@ -31,6 +31,7 @@ from learning_at_home_tpu.models.transformer import (  # noqa: E402
     AttentionLayer,
     DMoETransformerLM,
 )
+from learning_at_home_tpu.ops import gate_norm  # noqa: E402
 from learning_at_home_tpu.ops import ssm_conv  # noqa: E402
 from learning_at_home_tpu.parallel.mesh import make_mesh  # noqa: E402
 
@@ -141,6 +142,59 @@ def test_the_delta_mixer_matches_the_rule_as_written(tiny, chunk):
     _close(got, want)
     _close(state, want_state)
     assert 0.0 < float(decay_min) < 1.0 < float(beta_max) <= 2.0
+
+
+@pytest.mark.parametrize("heads", [4, 2])
+def test_the_mixer_through_the_gate_norm_kernel_matches_the_reference(
+        monkeypatch, heads):
+    """The mixer alone at widths the kernel's tiles admit (heads of 48 and
+    192: two heads a block of 384 lanes, ``z`` at a block's edge of the
+    in-projection, as in the cell), float32, with its norm and gate as
+    ``gate_norm_fwd`` under ``interpret``: the output and the state against
+    the rule as written, within what the plain form is held to above; and
+    every gradient of the mixer against the plain form's."""
+    d, dk, dv, s = 32, 48 * 4 // heads, 192, 32
+    sizes = dict(SIZES, n_heads=heads, linear_key_head_dim=dk,
+                 linear_value_head_dim=dv)
+    d_qk, d_v = 2 * heads * dk, heads * dv
+    rs = np.random.RandomState(5)
+
+    def normal(*shape, scale=1.0):
+        return jnp.asarray(scale * rs.randn(*shape), jnp.float32)
+
+    lp = {"delta": {
+        "w_in": normal(d, d_qk + 2 * d_v + 2 * heads, scale=d ** -0.5),
+        "conv_w": normal(d_qk + d_v, 4, scale=0.5), "dt_bias": normal(heads),
+        "A_log": jnp.log(jnp.asarray(rs.uniform(1, 16, heads), jnp.float32)),
+        "gate_norm": {"scale": 1.0 + normal(dv, scale=0.2)},
+        "w_out": normal(d_v, d, scale=d_v ** -0.5)}}
+    x = normal(2, s, d)
+
+    def mixer(lp, x):
+        return trunk.delta_mixer(lp["delta"], x, heads, 16, 1e-6)
+
+    def loss(lp, x):
+        out, state, *_ = mixer(lp, x)
+        return jnp.sum(out * jnp.cos(out)) + jnp.sum(state)
+
+    plain = jax.grad(loss, argnums=(0, 1))(lp, x)
+    calls = []
+
+    def through_the_kernel(y, z, scale, group, eps, gate_first, first=0, skip=None):
+        assert gate_norm.gate_norm_fits(y.shape, group, "tpu", first)
+        calls.append((y.shape, group, gate_first, first, skip))
+        return gate_norm.gated_rms_norm_kernel(
+            y, z, scale, group, eps, gate_first, first, skip, interpret=True)
+
+    monkeypatch.setattr(trunk, "gated_rms_norm", through_the_kernel)
+    want, want_state = reference.delta_part(lp, x, sizes)
+    got, state, *_ = mixer(lp, x)
+    assert calls == [((2, s, d_v), dv, False, d_qk + d_v, None)]
+    _close(got, want)
+    _close(state, want_state)
+    through = jax.grad(loss, argnums=(0, 1))(lp, x)
+    for g, w in zip(jax.tree_util.tree_leaves(through), jax.tree_util.tree_leaves(plain)):
+        _close(g, w, 1e-5)
 
 
 def test_the_convolutions_read_zeros_before_the_sequence_and_have_no_bias(tiny):
@@ -518,11 +572,14 @@ def test_benchmark_manifests_pass_selfcheck_and_the_runner_rehearses(tmp_path):
     rate = harness.by_name(manifest["end_to_end"], "train_tokens_per_s_per_chip", "metric")
     assert rate["workloads"][-1] == CELL
     reported = [m["name"] for m in harness.metrics_of_cell(manifest["per_layer"], CELL)]
-    assert len(reported) == 14 and all(n.startswith("olmohybrid.") for n in reported)
-    assert [m["name"] for m in manifest["per_layer"][-14:]] == reported
+    # PR 45's fourteen and ``delta_gate_norm_share`` (PR 47: the manifest's
+    # 128th per-layer metric, the most it may hold)
+    assert len(reported) == 15 and all(n.startswith("olmohybrid.") for n in reported)
+    assert [m["name"] for m in manifest["per_layer"][-15:]] == reported
+    assert len(manifest["per_layer"]) == 128
     assert {"olmohybrid.mfu", "olmohybrid.delta_share", "olmohybrid.delta_core_share",
             "olmohybrid.delta_core_roofline", "olmohybrid.attention_core_roofline",
-            } <= set(reported)
+            "olmohybrid.delta_gate_norm_share"} <= set(reported)
     for trace in ("0", "1"):
         run = subprocess.run(
             [sys.executable, "benchmarks/run.py", "--manifest",
@@ -633,6 +690,14 @@ def test_the_whole_step_fits_the_chip_and_runs_the_rule_as_kernels(
         "delta_chunk_fwd": {"calls": 2 * 6, "under_delta_core": 2 * 6},
         "delta_chunk_bwd": {"calls": 6, "under_delta_core": 6}}
     assert memory["loops_under_delta_core"] == 0
+    # the norm and the gate as one pass: forward, recomputed (remat keeps
+    # nothing of it) and backward a delta layer, every call under
+    # ``delta/gate_norm``, and no float32 ``[1, 16384, 5760]`` written there
+    # (PR 47; the parent's live count read 11,423,113,216)
+    assert memory["gate_norm_kernel_calls"] == {
+        "gate_norm_fwd": {"calls": 2 * 6, "under_delta_gate_norm": 2 * 6},
+        "gate_norm_bwd": {"calls": 6, "under_delta_gate_norm": 6}}
+    assert memory["float32_arrays_beside_gate_norm"] == []
     assert memory["attention_kernel_calls"] == {
         "splash_mha_fwd_residuals": 2, "splash_mha_dkv_no_residuals": 2}
     assert memory["loss_layer_products"] == 3

@@ -536,8 +536,18 @@ K_EXAONE_TINY_STEP_SHA256 = (
 GLM_4_7_FLASH_TINY_STEP_SHA256 = (
     "6d0b221f0ca43b27fa836c5b4812d086bae5b53b53cab2ca764db91d32ff2222"
 )
+# PR 47: the two hybrids' texts changed and the six above did not.  The
+# mixers' gate and grouped RMSNorm moved into ``ops/gate_norm.py``
+# (``gated_rms_norm_plain`` is the CPU's form): the same arithmetic
+# operations, one for one (the two texts with their value numbers taken out
+# and their lines sorted differ from the parent's in ``stablehlo.reshape``
+# lines alone: the mixer hands the norm ``[B, S, C]`` and the plain form
+# groups it again), but ``z``'s slice of the in-projection now stands in
+# ``gate_norm`` and no longer in ``in_proj``, so every value after it is
+# numbered anew.  Nemotron's read 2ffcd672b169..faaccb451582 (from PR 43's
+# parent on), Olmo-Hybrid's 4d421ce8f603..3bbf838fea (PR 46).
 NEMOTRON_TINY_STEP_SHA256 = (
-    "2ffcd672b1698783a155a15c6429019277fa51aea581341ecc53faaccb451582"
+    "f88020c63901660670b296ecf950f312d2f4503a819d905c3e884a3b98805cd1"
 )
 # Olmo-Hybrid's tiny step, first taken on PR 45's tree, the PR that brought
 # it (delta-rule layers, the norm on outputs, no mixture layer); the seven
@@ -547,7 +557,7 @@ NEMOTRON_TINY_STEP_SHA256 = (
 # an element; the rule's kernel does it in VMEM), so this one text changed
 # (it read e506501163cf..18d4f6) and the seven above did not.
 OLMO_HYBRID_TINY_STEP_SHA256 = (
-    "4d421ce8f6030ad9f27be2483369498e0f26f5204b9f46ea93a0fb3bbf838fea"
+    "b6ddcfc300b4dd3eebc7b9d9b64a7b43e17e80da52a1f3b88ddd396ef36854f0"
 )
 
 

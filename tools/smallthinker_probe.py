@@ -7,6 +7,7 @@
     chiprun -- python tools/smallthinker_probe.py float8 [config.json] [seed ...]
     chiprun -- python tools/smallthinker_probe.py ssd [seq_len] [accuracy_len]
     chiprun -- python tools/smallthinker_probe.py conv [rows x channels ...]
+    chiprun -- python tools/smallthinker_probe.py gate_norm [rows x strip ...]
     chiprun -- python tools/smallthinker_probe.py delta [seq_len] [accuracy_len] [form ...]
     chiprun -- python tools/smallthinker_probe.py delta split [seq_len] [chunk]
 
@@ -56,6 +57,20 @@ the time), GB/s on the LEAST bytes a pass moves (``x`` in and ``y`` out;
 ``x`` and ``dy`` in and ``dx`` out), and the kernel's output and three
 gradients against the plain form's.  ``512x1024``-like arguments time the
 kernel at those blocks (rows x channels) too (PERF.md section 6, PR 41).
+
+``gate_norm`` (on the chip): the mixers' gate and grouped RMSNorm
+(``ops/gate_norm.py``) as the two hybrid cells call it (Nemotron: ``[1,
+16384, 4096]`` bf16, groups of 512 under a scale a channel, the gate first,
+``z`` at column 0 of ``[.., 10304]``, with the skip ``y + D x``;
+Olmo-Hybrid: ``[1, 16384, 5760]``, groups of 192 under one shared scale,
+the norm first, ``z`` at column 11,520 of ``[.., 17340]``), the plain form
+against the kernel (``gate_norm_fwd`` / ``gate_norm_bwd``): milliseconds a
+call forward and forward + backward (a ``jax.vjp`` under a given bf16
+cotangent), GB/s on the LEAST bytes a pass moves (forward ``y``, ``z``
+(and ``x``) in and the result out; backward those and the cotangent in,
+``dy``, ``dz`` (and ``dx``) out), and the kernel's output and gradients
+against the plain form's.  ``256x32``-like arguments time the kernel at
+those row blocks and strips too (PERF.md section 6, PR 47).
 
 ``delta`` (on the chip): the chunked gated delta rule of
 ``ops/delta_rule.py`` at the Olmo-Hybrid cell's shape (``[1, seq_len, 30,
@@ -258,6 +273,7 @@ def step_memory(chip, recipe: str = "smallthinker_one_chip") -> dict:
         compiled = traced.lower().compile()
     m = compiled.memory_analysis()
     text = compiled.as_text()
+    gate_norm = "delta/gate_norm" if "delta/gate_norm" in text else "ssm/gate_norm"
     # params and optimizer state are donated: outputs alias the arguments
     live = (m.argument_size_in_bytes + m.output_size_in_bytes
             - m.alias_size_in_bytes + m.temp_size_in_bytes)
@@ -283,6 +299,12 @@ def step_memory(chip, recipe: str = "smallthinker_one_chip") -> dict:
             r'^\s*%\S+ = (f32\[1,1638[47],(?:6144|4096|1024)\])[^\n]*op_name="[^"\n]*ssm/conv',
             text, re.M))),
         "kept_scan_bytes": kept_residual_bytes(traced.jaxpr.jaxpr, SSD_RESIDUALS),
+        "gate_norm_kernel_calls": scan_kernel_calls(text, "gate_norm", gate_norm),
+        "float32_arrays_beside_gate_norm": sorted({
+            shape for shape, dims in re.findall(
+                r'^\s*%\S+ = (f32\[1,16384,([\d,]+)\])[^\n]*op_name="[^"\n]*'
+                r'(?:ssm/gate_norm|delta/gate_norm|ssm/scan)[/)]', text, re.M)
+            if np.prod([int(n) for n in dims.split(",")]) >= 1024}),
         "delta_kernel_calls": scan_kernel_calls(text, "delta_chunk", "delta/core"),
         "loops_under_delta_core": len(re.findall(
             r'^\s*%\S+ = [^\n]* while\([^\n]*op_name="[^"\n]*delta/core', text, re.M)),
@@ -490,6 +512,78 @@ def conv(blocks: list, calls: int = 20) -> None:
         }), flush=True)
 
 
+def gate_norm(blocks: list, calls: int = 20) -> None:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from learning_at_home_tpu.ops import gate_norm as ops
+
+    s = 16384
+    rs = np.random.default_rng(4700000007)
+    committed = (ops._ROWS, ops._STRIP)
+
+    def normal(*shape, dtype=jnp.bfloat16):
+        return jnp.asarray(rs.standard_normal(shape), dtype)
+
+    # (cell, C, the projection's width, z's column, group, the scale's
+    # length, eps, gate first, heads of the skip)
+    cells = [("nemotron", 4096, 10304, 0, 512, 4096, 1e-5, True, 64),
+             ("olmo-hybrid", 5760, 17340, 11520, 192, 192, 1e-6, False, 0)]
+    for cell, c, wide, first, group, n_scale, eps, gate_first, heads in cells:
+        # the projection's width rounded up to whole lane tiles: an ARGUMENT
+        # of a width that is none gets a layout with the positions minor, and
+        # a transposing copy of it in front of the kernel (in the step the
+        # in-projection writes it channels minor)
+        y, z, dout = normal(1, s, c), normal(1, s, wide + -wide % 128), normal(1, s, c)
+        scale = 1.0 + 0.1 * normal(n_scale, dtype=jnp.float32)
+        skip = (normal(1, s, c), normal(heads, dtype=jnp.float32)) if heads else None
+
+        def plain(y, z, scale, skip):
+            return ops.gated_rms_norm_plain(
+                y, z[..., first:first + c], scale, group, eps, gate_first, skip)
+
+        def kernel(y, z, scale, skip):
+            return ops.gated_rms_norm_kernel(
+                y, z, scale, group, eps, gate_first, first, skip)
+
+        def both(form):
+            def fn(y, z, scale, skip, dout):
+                out, back = jax.vjp(form, y, z, scale, skip)
+                return (out, *jax.tree_util.tree_leaves(back(dout)))
+            return jax.jit(fn)
+
+        least = y.size * y.dtype.itemsize  # one pass over [1, S, C] bf16
+        passes = (4, 11) if heads else (3, 8)  # forward; forward + backward
+        want = None
+        forms = [("plain", plain, None), ("kernel", kernel, committed)] + [
+            ("kernel", kernel, tuple(int(n) for n in a.split("x"))) for a in blocks]
+        for name, form, at in forms:
+            if at:
+                ops._ROWS, ops._STRIP = at
+            try:
+                got = jax.device_get(both(form)(y, z, scale, skip, dout))
+                forward = _ms(jax.jit(form), (y, z, scale, skip), calls)
+                forward_backward = _ms(both(form), (y, z, scale, skip, dout), calls)
+            except Exception as e:  # a block the compiler refuses
+                print("GATE_NORM " + json.dumps({
+                    "cell": cell, "form": name, "rows_strip": at,
+                    "refused": str(e)[:300]}), flush=True)
+                continue
+            want = want or got
+            names = ("out", "dy", "dz", "dscale", "dx", "dD")
+            print("GATE_NORM " + json.dumps({
+                "cell": cell, "form": name, "rows_strip": at, "shape": list(y.shape),
+                "forward_ms": forward, "forward_backward_ms": forward_backward,
+                "backward_ms": forward_backward - forward,
+                "forward_gb_s_on_least_bytes": passes[0] * least / forward / 1e6,
+                "backward_gb_s_on_least_bytes":
+                    (passes[1] - passes[0]) * least / (forward_backward - forward) / 1e6,
+                "rms_against_plain": {
+                    k: _rel_rms(a, b_) for k, a, b_ in zip(names, got, want)},
+            }), flush=True)
+
+
 def delta(seq_len: int = 16384, accuracy_len: int = 1024, calls: int = 5,
           forms=("kernel", "blocks", "product", "triangular")) -> None:
     import jax
@@ -644,6 +738,8 @@ if __name__ == "__main__":
         memory(*sys.argv[2:3])
     elif sys.argv[1:2] == ["conv"]:
         conv(sys.argv[2:])
+    elif sys.argv[1:2] == ["gate_norm"]:
+        gate_norm(sys.argv[2:])
     elif sys.argv[1:2] == ["ssd"]:
         ssd(*(int(a) for a in sys.argv[2:4]))
     elif sys.argv[1:3] == ["delta", "split"]:
