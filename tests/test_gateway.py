@@ -6,9 +6,10 @@ The contracts under test:
 
 - decoder parity: the slot-table KV decoder's greedy tokens match a full
   re-forward argmax chain through ``model.apply`` exactly;
-- coalescing is bitwise-invisible: grouping streams with overlapping
-  expert sets into one dispatch produces BIT-identical per-stream outputs
-  vs one-dispatch-per-stream (selection and combine are row-wise);
+- coalescing is invisible: grouping streams with overlapping expert sets
+  into one dispatch gives each stream the output of its own dispatch
+  (selection and combine are row-wise) to a few ulp, the two being
+  different compiled programs, and the same greedy tokens;
 - admission: a saturated gateway sheds with a well-formed retry-after
   reply instead of queueing unboundedly;
 - churn: streams killed mid-decode free their slot and KV rows — no slot
@@ -52,7 +53,7 @@ def _cfg(**overrides):
         seq_len=SEQ, grid_size=(2,), k_best=2, k_min=2, uid_prefix="ffn",
         timeout_after_k_min=30.0,
         forward_timeout=60.0, backward_timeout=60.0,
-        # pin codec + blind gate: the bitwise contracts here must not
+        # pin codec + blind gate: the equality contracts here must not
         # depend on adaptive wire precision or cost-model bias state
         wire_codec="none", routing_cost_weight=0,
     )
@@ -99,13 +100,27 @@ def test_swarm_decoder_matches_reforward(swarm):
 
 
 # ---------------------------------------------------------------------------
-# coalescing: bitwise-invisible grouping
+# coalescing: invisible grouping
 # ---------------------------------------------------------------------------
 
 
+def _assert_equal_to_a_few_ulp(got, want):
+    """One [4, D] dispatch and four [1, D] dispatches are two compiled
+    programs of one float32 computation, and the server's pool stacks
+    single rows fired together into whatever batches its 2 ms timer catches
+    (a row bucket, so a program, by run): the outputs agree to a few ulp of
+    their scale, not bit for bit (half the runs, a row or two).  A stream
+    handed another stream's row would be off by the scale itself."""
+    ulp = np.finfo(np.float32).eps * np.abs(want).max()
+    np.testing.assert_allclose(got, want, rtol=0, atol=8 * ulp)  # 0.72 seen
+
+
 def test_coalesced_dispatch_bitwise_equals_ungrouped(swarm):
-    """The hook-level contract: one grouped dispatch over many streams'
-    rows returns BIT-identical outputs to per-stream dispatches."""
+    """("bitwise" in the name is historical: it is the ledger's id.  The
+    outputs agree to a few ulp since PR 49, the counters exactly.)  The
+    hook-level contract: one grouped dispatch over many streams' rows
+    returns each stream the output its own dispatch would, and fires ONE
+    dispatch where the ungrouped arm fires one a stream."""
     model, params = swarm
     moe = model.moes[0]
     gate = params["layers"][0]["gate"]
@@ -113,9 +128,9 @@ def test_coalesced_dispatch_bitwise_equals_ungrouped(swarm):
     streams = ["a", "b", "c", "d"]
     grouped = ExpertCoalescer(coalesce=True)
     ungrouped = ExpertCoalescer(coalesce=False)
-    y_g = grouped.dispatch(0, moe, gate, x, streams)
-    y_u = ungrouped.dispatch(0, moe, gate, x, streams)
-    assert np.array_equal(np.asarray(y_g), np.asarray(y_u))
+    y_g = np.asarray(grouped.dispatch(0, moe, gate, x, streams))
+    y_u = np.asarray(ungrouped.dispatch(0, moe, gate, x, streams))
+    _assert_equal_to_a_few_ulp(y_g, y_u)
     # k_best == grid_size here, so every stream shares the expert set:
     # the grouped arm must have fired ONE dispatch for all four streams
     assert grouped.group_dispatches_total == 1
@@ -283,11 +298,27 @@ def test_stream_churn_no_slot_leak(swarm):
     with Gateway(model, params, max_slots=4, max_pending=400,
                  stream_ttl_s=0.5) as gw:
         client = GatewayClient(gw.endpoint)
+
+        def submit(prompt):
+            # a cancel is a mark the decode thread acts on at its next
+            # step, and its first step compiles (seconds, beside five
+            # other workers): a submit that finds the four killed streams
+            # still holding their slots' page reserve is refused (``shed``,
+            # ``retry_after_s``: the admission contract).  Wait as told (a
+            # second at most: the hint counts that compile into its step
+            # time) and ask again.
+            deadline = time.monotonic() + 60
+            while True:
+                r = client.submit(prompt, SEQ - 3)
+                if not r.get("shed") or time.monotonic() > deadline:
+                    break
+                time.sleep(min(r["retry_after_s"], 1.0))
+            assert r.get("accepted"), r
+            return r["sid"]
+
         sids = []
         for i in range(100):
-            r = client.submit([1 + (i % 8), 2], SEQ - 3)
-            assert r.get("accepted"), r
-            sids.append(r["sid"])
+            sids.append(submit([1 + (i % 8), 2]))
             if i % 4 == 3:
                 # let a few decode steps run so cancels land mid-decode,
                 # then kill the whole batch in flight

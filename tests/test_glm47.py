@@ -34,6 +34,7 @@ from learning_at_home_tpu.models.transformer import DMoETransformerLM  # noqa: E
 from learning_at_home_tpu.ops import moe_dispatch  # noqa: E402
 from learning_at_home_tpu.parallel.mesh import make_mesh  # noqa: E402
 from learning_at_home_tpu.parallel.sharded_moe import ShardedMixtureOfExperts  # noqa: E402
+from test_benchmark_cells import layer_metric_file, readings_of_cell  # noqa: E402
 
 REFERENCE = os.path.join(REPO, "benchmarks", "configs", "glm_4_7_flash_reference.py")
 reference = harness.load_path(REFERENCE)
@@ -533,19 +534,14 @@ def test_configuration_file_carries_the_catalog_entry():
 
 
 def test_reducers_read_this_cells_tables_and_nothing_where_there_is_none():
-    """The ``glm47.*`` metrics through the accepted reducers: operations at
-    the rows the step counted; the prediction block's share from the
-    table's ``mtp_s``; ``None`` (the metric is left out) where a program or
-    a trace has nothing to read."""
+    """This cell's readings through the accepted reducers, each from the
+    file of the entry the manifest reports it by: operations at the rows
+    the step counted; the prediction block's share from the table's
+    ``mtp_s``; ``None`` (the metric is left out) where a program or a trace
+    has nothing to read."""
     sys.path.insert(0, os.path.join(REPO, "benchmarks", "reducers"))
-
-    def reducer(name):
-        return harness.load_path(os.path.join(
-            REPO, "benchmarks", "reducers", name + ".py"))
-
-    def spec(metric):
-        return harness.load_json(os.path.join(
-            REPO, "benchmarks", "layer_metrics", f"glm47.{metric}.json"))
+    manifest = harness.load_json(os.path.join(REPO, "BENCHMARK.json"))
+    readings = readings_of_cell(manifest, CELL)
 
     obs = {"tokens_per_s_per_chip": 14000.0, "device_kind": "TPU v5 lite",
            "sizes": CELL_FILE, "tokens_per_step_per_chip": 16384,
@@ -558,9 +554,10 @@ def test_reducers_read_this_cells_tables_and_nothing_where_there_is_none():
                           "global.forward": {"s": 0.4, "calls": 24},
                           "global.backward": {"s": 0.6, "calls": 12}}}}
 
-    def read(metric, observations=obs):
-        s = spec(metric)
-        return reducer(s["reducer"]).reduce(observations, **s["args"])
+    def read(reading, observations=obs):
+        spec = layer_metric_file(manifest, readings[reading])
+        reducer = harness.load_module(manifest, "reducers", spec["reducer"])
+        return reducer.reduce(observations, **spec["args"])
 
     want = glm47_flops.train_flops_per_token(CELL_FILE, 1.25) * 14000 / 197e12
     assert read("mfu") == pytest.approx(100 * want)
@@ -606,60 +603,6 @@ def test_the_scope_table_takes_in_the_prediction_block():
     by_scope = {k: round(v * 1e9) for k, v in table["by_scope"].items()}
     assert by_scope == {"latent_up": 1, "attention": 2, "rope": 3, "mtp": 4, "ce": 5 + 6}
     assert round(table["mtp_s"] * 1e9) == 3 + 4 + 5
-
-
-def test_benchmark_manifests_pass_selfcheck_and_the_runner_rehearses(tmp_path):
-    """``selfcheck.py`` on the manifest and on this configuration's
-    rehearsal, then the new runner for 2 s at tiny sizes on the CPU,
-    untraced and traced."""
-    from learning_at_home_tpu.utils.subproc import clean_jax_subprocess_env
-
-    env = clean_jax_subprocess_env(REPO, platform="cpu")
-    env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "cache")
-    check = subprocess.run(
-        [sys.executable, "benchmarks/selfcheck.py", "BENCHMARK.json",
-         "benchmarks/rehearsal/manifest_glm47.json"],
-        cwd=REPO, env=env, capture_output=True, text=True, timeout=120)
-    assert check.returncode == 0 and "selfcheck: ok" in check.stdout, check.stdout
-    manifest = harness.load_json(os.path.join(REPO, "BENCHMARK.json"))
-    cell = harness.by_name(manifest["workloads"], CELL, "workload")
-    assert (cell["config"], cell["traffic"], cell["chips"]) == (
-        "glm-4.7-flash", "train-zipf16k", 1)
-    assert manifest["workloads"][7] == cell  # the eighth; later PRs append
-    assert manifest["configs"][5]["name"] == "glm-4.7-flash"
-    rate = harness.by_name(manifest["end_to_end"], "train_tokens_per_s_per_chip", "metric")
-    assert CELL in rate["workloads"]
-    reported = [m["name"] for m in harness.metrics_of_cell(manifest["per_layer"], CELL)]
-    assert len(reported) == 18 and all(n.startswith("glm47.") for n in reported)
-    assert {"glm47.attention_latent_share", "glm47.mtp_share",
-            "glm47.attention_core_roofline", "glm47.expert_matmul_roofline"} <= set(reported)
-    for trace in ("0", "1"):
-        run = subprocess.run(
-            [sys.executable, "benchmarks/run.py", "--manifest",
-             "benchmarks/rehearsal/manifest_glm47.json", "--workload", CELL,
-             "--seed", "3700000007", "--seconds", "2", "--trace", trace],
-            cwd=REPO, env=env, capture_output=True, text=True, timeout=300)
-        assert run.returncode == 0, run.stderr[-2000:]
-        line = json.loads(run.stdout.strip().splitlines()[-1])
-        problems = [l for l in run.stderr.splitlines() if l.startswith("INCORRECT")]
-        assert line["correct"] is True and line["failed"] == 0, problems
-        names = set(line["metrics"])
-        if trace == "0":
-            assert names == {"cpu_rehearsal.train_tokens_per_s_per_chip",
-                             "cpu_rehearsal.setup_s"}
-        else:  # a CPU has no peak: the shares of one are left out
-            assert line["metrics"]["cpu_rehearsal.glm47.moe_dropped_share"]["value"] == 0.0
-            assert {"cpu_rehearsal.glm47.local_rows_over_level",
-                    "cpu_rehearsal.glm47.expert_load_max_over_mean",
-                    "cpu_rehearsal.glm47.step_ms_p50"} <= names
-            assert not any("mfu" in n or "roofline" in n for n in names)
-    lines = {l.split(" ", 1)[0]: l.split(" ", 1)[1] for l in run.stdout.splitlines()
-             if l.startswith(("SETUP ", "COUNTERS ", "REFERENCE "))}
-    setup = json.loads(lines["SETUP"])
-    assert "level_router_bias" in setup["phases"]
-    assert len(setup["load_max_over_mean_before_and_after_levelling"]) == 5
-    assert "ce_mtp" in json.loads(lines["COUNTERS"])
-    assert "mtp_logits_rms" in json.loads(lines["REFERENCE"])
 
 
 def test_a_program_without_the_recipe_fails_at_once_with_no_result(tmp_path):

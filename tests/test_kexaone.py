@@ -47,7 +47,6 @@ TINY_FILE = harness.load_json(os.path.join(
     REPO, "benchmarks", "rehearsal", "configs", "kexaone-tiny.json"))
 CELL_FILE = harness.load_json(os.path.join(
     REPO, "benchmarks", "configs", "k-exaone-236b-a23b.json"))
-CELL = "k-exaone-236b-a23b-train-zipf16k"
 SIZES = runner.reference_sizes(TINY_FILE)  # what the runner hands the reference
 
 
@@ -750,51 +749,6 @@ def test_reducers_read_the_counted_rows_and_nothing_where_there_is_none():
     assert mfu.reduce(older, **args) is None
     assert roofline.reduce(older, module="kexaone_flops") is None
     assert roofline.reduce(dict(obs, scopes={}), module="kexaone_flops") is None
-
-
-def test_benchmark_manifests_pass_selfcheck_and_the_runner_rehearses(tmp_path):
-    """``selfcheck.py`` on the manifest and on this configuration's
-    rehearsal, then the new runner for 2 s at tiny sizes on the CPU,
-    untraced and traced."""
-    from learning_at_home_tpu.utils.subproc import clean_jax_subprocess_env
-
-    env = clean_jax_subprocess_env(REPO, platform="cpu")
-    env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "cache")
-    check = subprocess.run(
-        [sys.executable, "benchmarks/selfcheck.py", "BENCHMARK.json",
-         "benchmarks/rehearsal/manifest_kexaone.json"],
-        cwd=REPO, env=env, capture_output=True, text=True, timeout=120)
-    assert check.returncode == 0 and "selfcheck: ok" in check.stdout, check.stdout
-    manifest = harness.load_json(os.path.join(REPO, "BENCHMARK.json"))
-    cell = harness.by_name(manifest["workloads"], CELL, "workload")
-    assert (cell["config"], cell["traffic"], cell["chips"]) == (
-        "k-exaone-236b-a23b", "train-zipf16k", 1)
-    reported = [m["name"] for m in harness.metrics_of_cell(manifest["per_layer"], CELL)]
-    assert len(reported) == 16 and all(n.startswith("kexaone.") for n in reported)
-    for trace in ("0", "1"):
-        run = subprocess.run(
-            [sys.executable, "benchmarks/run.py", "--manifest",
-             "benchmarks/rehearsal/manifest_kexaone.json", "--workload", CELL,
-             "--seed", "3300000007", "--seconds", "2", "--trace", trace],
-            cwd=REPO, env=env, capture_output=True, text=True, timeout=300)
-        assert run.returncode == 0, run.stderr[-2000:]
-        line = json.loads(run.stdout.strip().splitlines()[-1])
-        problems = [l for l in run.stderr.splitlines() if l.startswith("INCORRECT")]
-        assert line["correct"] is True and line["failed"] == 0, problems
-        names = set(line["metrics"])
-        if trace == "0":
-            assert names == {"cpu_rehearsal.train_tokens_per_s_per_chip",
-                             "cpu_rehearsal.setup_s"}
-        else:  # a CPU has no peak: the shares of one are left out
-            assert line["metrics"]["cpu_rehearsal.kexaone.moe_dropped_share"]["value"] == 0.0
-            assert {"cpu_rehearsal.kexaone.local_rows_over_level",
-                    "cpu_rehearsal.kexaone.expert_load_max_over_mean",
-                    "cpu_rehearsal.kexaone.step_ms_p50"} <= names
-            assert not any("mfu" in n or "roofline" in n for n in names)
-    setup = json.loads(next(
-        l for l in run.stdout.splitlines() if l.startswith("SETUP "))[6:])
-    assert "level_router_bias" in setup["phases"]
-    assert len(setup["load_max_over_mean_before_and_after_levelling"]) == 4
 
 
 def test_the_swarm_cells_load_none_of_the_pod_step():

@@ -791,63 +791,6 @@ def test_the_scope_roofline_reducer_reads_a_scope_and_nothing_where_there_is_non
     assert share.reduce(obs, **whole["args"]) == pytest.approx(100 * 0.85 / 3.0)
 
 
-def test_benchmark_manifests_pass_selfcheck_and_the_runner_rehearses(tmp_path):
-    """``selfcheck.py`` on the manifest and on this configuration's
-    rehearsal, then the new runner for 2 s at tiny sizes on the CPU,
-    untraced and traced."""
-    from learning_at_home_tpu.utils.subproc import clean_jax_subprocess_env
-
-    env = clean_jax_subprocess_env(REPO, platform="cpu")
-    env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "cache")
-    check = subprocess.run(
-        [sys.executable, "benchmarks/selfcheck.py", "BENCHMARK.json",
-         "benchmarks/rehearsal/manifest_nemotron.json"],
-        cwd=REPO, env=env, capture_output=True, text=True, timeout=120)
-    assert check.returncode == 0 and "selfcheck: ok" in check.stdout, check.stdout
-    manifest = harness.load_json(os.path.join(REPO, "BENCHMARK.json"))
-    cell = harness.by_name(manifest["workloads"], CELL, "workload")
-    assert (cell["config"], cell["traffic"], cell["chips"]) == (
-        "nemotron-labs-twotower-30b-a3b", "train-zipf16k", 1)
-    assert manifest["workloads"][8] == cell
-    assert manifest["configs"][6]["name"] == "nemotron-labs-twotower-30b-a3b"
-    rate = harness.by_name(manifest["end_to_end"], "train_tokens_per_s_per_chip", "metric")
-    assert CELL in rate["workloads"]
-    reported = [m["name"] for m in harness.metrics_of_cell(manifest["per_layer"], CELL)]
-    assert len(reported) == 20 and all(n.startswith("nemotron.") for n in reported)
-    assert {"nemotron.ssm_share", "nemotron.ssm_scan_share", "nemotron.ssm_proj_share",
-            "nemotron.ssm_conv_share",
-            "nemotron.ssm_scan_roofline", "nemotron.attention_core_roofline",
-            "nemotron.expert_matmul_roofline"} <= set(reported)
-    for trace in ("0", "1"):
-        run = subprocess.run(
-            [sys.executable, "benchmarks/run.py", "--manifest",
-             "benchmarks/rehearsal/manifest_nemotron.json", "--workload", CELL,
-             "--seed", "3900000007", "--seconds", "2", "--trace", trace],
-            cwd=REPO, env=env, capture_output=True, text=True, timeout=300)
-        assert run.returncode == 0, run.stderr[-2000:]
-        line = json.loads(run.stdout.strip().splitlines()[-1])
-        problems = [l for l in run.stderr.splitlines() if l.startswith("INCORRECT")]
-        assert line["correct"] is True and line["failed"] == 0, problems
-        names = set(line["metrics"])
-        if trace == "0":
-            assert names == {"cpu_rehearsal.train_tokens_per_s_per_chip",
-                             "cpu_rehearsal.setup_s"}
-        else:  # a CPU has no peak: the shares of one are left out
-            assert line["metrics"]["cpu_rehearsal.nemotron.moe_dropped_share"]["value"] == 0.0
-            assert {"cpu_rehearsal.nemotron.local_rows_over_level",
-                    "cpu_rehearsal.nemotron.expert_load_max_over_mean",
-                    "cpu_rehearsal.nemotron.step_ms_p50"} <= names
-            assert not any("mfu" in n or "roofline" in n for n in names)
-    lines = {l.split(" ", 1)[0]: l.split(" ", 1)[1] for l in run.stdout.splitlines()
-             if l.startswith(("SETUP ", "COUNTERS ", "REFERENCE "))}
-    setup = json.loads(lines["SETUP"])
-    assert "level_router_bias" in setup["phases"]
-    assert len(setup["load_max_over_mean_before_and_after_levelling"]) == 4
-    assert "ssm_decay_min" in json.loads(lines["COUNTERS"])
-    read = json.loads(lines["REFERENCE"])
-    assert "ssm_rms" in read and "ssm_state_rms" in read and len(read["ssm_layers_rms"]) == 4
-
-
 def test_a_program_without_the_recipe_fails_at_once_with_no_result(tmp_path):
     """The new runner on a program from before this configuration (no
     ``nemotron_labs_twotower_one_chip`` in ``__graft_entry__``): ``no

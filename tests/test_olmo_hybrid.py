@@ -550,63 +550,6 @@ def test_the_window_refuses_counters_the_delta_rule_cannot_give():
     assert len(runner.delta_problems({})) == 2  # a program without the counters
 
 
-def test_benchmark_manifests_pass_selfcheck_and_the_runner_rehearses(tmp_path):
-    """``selfcheck.py`` on the manifest and on this configuration's
-    rehearsal, then the new runner for 2 s at tiny sizes on the CPU,
-    untraced and traced."""
-    from learning_at_home_tpu.utils.subproc import clean_jax_subprocess_env
-
-    env = clean_jax_subprocess_env(REPO, platform="cpu")
-    env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "cache")
-    check = subprocess.run(
-        [sys.executable, "benchmarks/selfcheck.py", "BENCHMARK.json",
-         "benchmarks/rehearsal/manifest_olmohybrid.json"],
-        cwd=REPO, env=env, capture_output=True, text=True, timeout=120)
-    assert check.returncode == 0 and "selfcheck: ok" in check.stdout, check.stdout
-    manifest = harness.load_json(os.path.join(REPO, "BENCHMARK.json"))
-    cell = harness.by_name(manifest["workloads"], CELL, "workload")
-    assert (cell["config"], cell["traffic"], cell["chips"]) == (
-        "olmo-hybrid-7b", "train-zipf16k", 1)
-    assert manifest["workloads"][9] == cell
-    assert manifest["configs"][7]["name"] == "olmo-hybrid-7b"
-    rate = harness.by_name(manifest["end_to_end"], "train_tokens_per_s_per_chip", "metric")
-    assert rate["workloads"][-1] == CELL
-    reported = [m["name"] for m in harness.metrics_of_cell(manifest["per_layer"], CELL)]
-    # PR 45's fourteen and ``delta_gate_norm_share`` (PR 47: the manifest's
-    # 128th per-layer metric, the most it may hold)
-    assert len(reported) == 15 and all(n.startswith("olmohybrid.") for n in reported)
-    assert [m["name"] for m in manifest["per_layer"][-15:]] == reported
-    assert len(manifest["per_layer"]) == 128
-    assert {"olmohybrid.mfu", "olmohybrid.delta_share", "olmohybrid.delta_core_share",
-            "olmohybrid.delta_core_roofline", "olmohybrid.attention_core_roofline",
-            "olmohybrid.delta_gate_norm_share"} <= set(reported)
-    for trace in ("0", "1"):
-        run = subprocess.run(
-            [sys.executable, "benchmarks/run.py", "--manifest",
-             "benchmarks/rehearsal/manifest_olmohybrid.json", "--workload", CELL,
-             "--seed", "4500000007", "--seconds", "2", "--trace", trace],
-            cwd=REPO, env=env, capture_output=True, text=True, timeout=300)
-        assert run.returncode == 0, run.stderr[-2000:]
-        line = json.loads(run.stdout.strip().splitlines()[-1])
-        problems = [l for l in run.stderr.splitlines() if l.startswith("INCORRECT")]
-        assert line["correct"] is True and line["failed"] == 0, problems
-        names = set(line["metrics"])
-        if trace == "0":
-            assert names == {"cpu_rehearsal.train_tokens_per_s_per_chip",
-                             "cpu_rehearsal.setup_s"}
-        else:  # a CPU has no peak: the shares of one are left out
-            assert "cpu_rehearsal.olmohybrid.step_ms_p50" in names
-            assert not any("mfu" in n or "roofline" in n for n in names)
-    lines = {l.split(" ", 1)[0]: l.split(" ", 1)[1] for l in run.stdout.splitlines()
-             if l.startswith(("SETUP ", "COUNTERS ", "REFERENCE "))}
-    setup = json.loads(lines["SETUP"])
-    assert setup["load_max_over_mean_before_and_after_levelling"] == []
-    assert setup["expert_param_bytes"] == 0 and setup["param_bytes_per_device"] > 0
-    assert set(json.loads(lines["COUNTERS"])) == {"delta_decay_min", "delta_beta_max"}
-    read = json.loads(lines["REFERENCE"])
-    assert len(read["delta_layers_rms"]) == len(read["delta_states_rms"]) == 6
-
-
 def test_a_program_without_the_recipe_fails_at_once_with_no_result(tmp_path):
     """The new runner on a program from before this configuration (no
     ``olmo_hybrid_7b_one_chip`` in ``__graft_entry__``): ``no recipe``,

@@ -14,7 +14,6 @@ import dataclasses
 import json
 import os
 import re
-import subprocess
 import sys
 
 import jax
@@ -520,39 +519,6 @@ def test_runner_finds_the_attention_kernel_across_the_newline():
     assert roofline.reduce({"scopes": {}, "device_kind": "TPU v5 lite"},
                            module="smallthinker_flops") is None
     assert share.reduce({}, key="attention_kernel_s") is None
-
-
-def test_benchmark_manifests_pass_selfcheck_and_the_runner_rehearses(tmp_path):
-    """``selfcheck.py`` on the manifest and on this configuration's
-    rehearsal, then the new runner for 2 s at tiny sizes on the CPU."""
-    from learning_at_home_tpu.utils.subproc import clean_jax_subprocess_env
-
-    env = clean_jax_subprocess_env(REPO, platform="cpu")
-    env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "cache")
-    check = subprocess.run(
-        [sys.executable, "benchmarks/selfcheck.py", "BENCHMARK.json",
-         "benchmarks/rehearsal/manifest_smallthinker.json"],
-        cwd=REPO, env=env, capture_output=True, text=True, timeout=120,
-    )
-    assert check.returncode == 0 and "selfcheck: ok" in check.stdout, check.stdout
-    for trace in ("0", "1"):
-        run = subprocess.run(
-            [sys.executable, "benchmarks/run.py", "--manifest",
-             "benchmarks/rehearsal/manifest_smallthinker.json", "--workload",
-             "smallthinker-21b-a3b-train-zipf16k", "--seed", "3100000001",
-             "--seconds", "2", "--trace", trace],
-            cwd=REPO, env=env, capture_output=True, text=True, timeout=300,
-        )
-        assert run.returncode == 0, run.stderr[-2000:]
-        line = json.loads(run.stdout.strip().splitlines()[-1])
-        assert line["correct"] and line["failed"] == 0, run.stderr[-2000:]
-        names = set(line["metrics"])
-        if trace == "0":
-            assert names == {"cpu_rehearsal.train_tokens_per_s_per_chip",
-                             "cpu_rehearsal.setup_s"}
-        else:
-            assert line["metrics"]["cpu_rehearsal.smallthinker.moe_dropped_share"]["value"] == 0.0
-            assert "cpu_rehearsal.smallthinker.expert_load_max_over_mean" in names
 
 
 # ---- every grouped matmul of a layer runs at tiles read from its shape ----

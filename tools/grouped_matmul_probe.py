@@ -79,7 +79,8 @@ ITERS = 10
 # gate and up, down); main() takes another cell's from the command line
 GROUPS, ROWS = 64, 131072
 WIDTHS = ((2048, 1024), (1024, 2048))
-MAX_OVER_MEAN = 5.6  # ``skewed``: smallthinker.expert_load_max_over_mean reads 5.4-5.9
+# ``skewed``: the smallthinker cell's expert_load_max_over_mean reads 5.4-5.9
+MAX_OVER_MEAN = 5.6
 KINDS = ("forward", "drows", "dweights")
 ROW_TILES = (256, 512, 1024)
 OUT = os.path.join(REPO, "chiprun_out", "grouped_matmul_probe.jsonl")
@@ -107,7 +108,7 @@ def group_sizes(how: str, m: int = ROWS, groups: int = GROUPS,
                 max_over_mean: float = MAX_OVER_MEAN):
     """``collapsed``: an eighth of the experts with 97 % of the rows
     between them, the rest spread over the others (largest over mean
-    7.76, as the cell's ``olmoe.expert_load_max_over_mean`` reads);
+    7.76, as the olmoe cell's ``expert_load_max_over_mean`` reads);
     ``skewed``: sizes that fall as a power of the expert's rank, the
     power found so that the largest over the mean is ``max_over_mean``;
     ``uniform``: m / groups each."""
