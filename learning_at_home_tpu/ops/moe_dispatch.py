@@ -24,6 +24,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental.xla_metadata import set_xla_metadata
 
+from learning_at_home_tpu.ops.moe_rows import sum_rows, sum_rows_plain
+
 
 class DispatchPlan(NamedTuple):
     """Static-shape routing decision for one token shard."""
@@ -576,9 +578,7 @@ def _rows_to_sorted_fwd(x, order, inverse, k):
 
 def _rows_to_sorted_bwd(k, residuals, g):
     inverse, n = residuals
-    per_choice = g[inverse].reshape(n, k, g.shape[-1])
-    dx = per_choice.astype(jnp.float32).sum(axis=1).astype(g.dtype)
-    return dx, None, None
+    return sum_rows(g[inverse], None, n, k, g.dtype), None, None
 
 
 _rows_to_sorted.defvjp(_rows_to_sorted_fwd, _rows_to_sorted_bwd)
@@ -749,12 +749,53 @@ def _grouped_matmul_bwd(residuals, g):
 grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)
 
 
-def unsort_combine(ys: jax.Array, plan: DroplessPlan) -> jax.Array:
-    """[n*k, d] sorted expert outputs → [n, d]: each token's k outputs,
-    gate-weighted and summed in float32."""
+# The combine as the chip runs it.  A row gather out of HBM costs 4.3 to
+# 4.6 ms there (131,072 rows of 2048 bf16; 0.9 where its [n, d] source fits
+# VMEM: PERF.md PR 50), so the backward makes ONE, of the token cotangents
+# into sorted order (a source of n rows), scales a row by its gate weight
+# there, and takes the weights' gradient from the rows' dot products with
+# ``ys`` in sorted order: the gathered ``ys[inverse]`` is no residual (remat
+# recomputed its gather), and no [n*k, d] product is gathered.  The sum is
+# ``ops.moe_rows``'s, a kernel where its rule says so.
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _combine_sorted(ys, weights, order, inverse, dtype):
+    n, k = weights.shape
+    return sum_rows(ys[inverse], weights, n, k, dtype)
+
+
+def _combine_sorted_fwd(ys, weights, order, inverse, dtype):
+    out = _combine_sorted(ys, weights, order, inverse, dtype)
+    return out, (ys, weights, order, inverse)
+
+
+def _combine_sorted_bwd(dtype, residuals, g):
+    ys, weights, order, inverse = residuals
+    n, k = weights.shape
+    g_sorted = g[order // k].astype(jnp.float32)  # [n*k, d]
+    d_ys = (weights.reshape(-1)[order][:, None] * g_sorted).astype(ys.dtype)
+    dots = jnp.sum(g_sorted * ys.astype(jnp.float32), axis=-1)
+    return d_ys, dots[inverse].reshape(n, k), None, None
+
+
+_combine_sorted.defvjp(_combine_sorted_fwd, _combine_sorted_bwd)
+
+
+def combine_sorted_fits(dtype, backend: str) -> bool:
+    """Whether :func:`unsort_combine` runs as ``_combine_sorted``: bf16 rows
+    on a ``tpu`` backend, where it was measured; every other call keeps the
+    form (and the lowered text) it had."""
+    return backend == "tpu" and jnp.dtype(dtype) == jnp.bfloat16
+
+
+def unsort_combine(
+    ys: jax.Array, plan: DroplessPlan, dtype=jnp.float32
+) -> jax.Array:
+    """[n*k, d] sorted expert outputs → [n, d] of ``dtype``: each token's k
+    outputs, gate-weighted and summed in float32."""
     n, k = plan.weights.shape
+    if combine_sorted_fits(ys.dtype, jax.default_backend()):
+        return _combine_sorted(ys, plan.weights, plan.order, plan.inverse, dtype)
     picked = _rows_from_sorted(ys, plan.order, plan.inverse)
-    return jnp.einsum(
-        "nk,nkd->nd", plan.weights,
-        picked.reshape(n, k, ys.shape[-1]).astype(jnp.float32),
-    )
+    return sum_rows_plain(picked, plan.weights, n, k, dtype)

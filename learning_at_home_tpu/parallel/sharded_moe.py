@@ -552,7 +552,7 @@ class ShardedMixtureOfExperts:
                     h, params["w2"].astype(compute), plan.group_sizes
                 ) + params["b2"].astype(compute)[expert_of_row]
         with jax.named_scope("moe_combine"):
-            y = unsort_combine(ys, plan).astype(x.dtype)
+            y = unsort_combine(ys, plan, x.dtype)
 
         router_z = _router_z_loss(logits, token_mask)
         load = jnp.max(plan.group_sizes).astype(jnp.float32) / (

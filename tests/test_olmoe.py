@@ -271,6 +271,48 @@ def test_dropless_output_does_not_depend_on_token_order():
         )
 
 
+@pytest.mark.parametrize("k, kernels", [
+    (8, set()),              # OLMoE's: the sum of 8 rows is the compiler's
+    (6, {"moe_rows_sum"}),   # SmallThinker's top-6
+])
+def test_the_chip_form_of_the_sorted_layer_keeps_loss_and_gradients(
+        tiny, monkeypatch, k, kernels):
+    """``_local_forward_dropless`` as a TPU backend runs it (the combine
+    with one gather backward and no gathered residual, ``ops/moe_rows.py``'s
+    kernel under ``interpret`` where its rule admits the shape) against
+    the form every other backend keeps, in bf16 under remat: one loss, and
+    gradients within the bf16 roundings that another order of the same
+    float32 sums can move."""
+    from test_moe_rows_kernel import chip_form
+
+    _, cfg, _, _, _ = tiny
+    cfg = dataclasses.replace(
+        cfg, d_model=128, k=k, dtype=jnp.bfloat16,
+        param_dtype=jnp.bfloat16)  # whole lane tiles, the cells' dtypes
+    model = DMoETransformerLM(cfg, _one_device_mesh())
+    params = _decisive(model.init_params(jax.random.PRNGKey(11)))
+    ids = jnp.asarray(np.random.RandomState(3).randint(
+        0, cfg.vocab_size, (4, cfg.seq_len + 1)))  # 128 tokens a layer
+
+    def loss_and_grads():
+        return jax.value_and_grad(
+            lambda p: model.loss_fn(p, ids[:, :-1], ids[:, 1:])[0])(params)
+
+    want, want_grads = loss_and_grads()
+    called = chip_form(monkeypatch)
+    got, got_grads = loss_and_grads()
+    assert set(called) == kernels
+    np.testing.assert_allclose(float(got), float(want), rtol=2e-4)  # the cell's limit
+    for (path, g), w in zip(
+        jax.tree_util.tree_flatten_with_path(got_grads)[0],
+        jax.tree_util.tree_leaves(want_grads),
+    ):
+        g, w = np.asarray(g.astype(jnp.float32)), np.asarray(w.astype(jnp.float32))
+        np.testing.assert_allclose(
+            g, w, rtol=0, atol=2.0 ** -6 * float(np.abs(w).max()),
+            err_msg=jax.tree_util.keystr(path))
+
+
 @pytest.mark.parametrize("expert_kind", ["gated_silu", "gelu"])
 def test_dropless_equals_the_capacity_path_when_nothing_is_dropped(expert_kind):
     """Both expert kinds, both routings, one set of weights: with room for
@@ -890,6 +932,47 @@ def test_the_whole_step_holds_one_forward_kernel_call_a_layer(v5e_chip, monkeypa
             for name, c in memory["attention_kernel_tilings"]["attention"].items()} == {
         "splash_mha_fwd_residuals": (4, 1024, 1024),
         "splash_mha_dkv_no_residuals": (4, 1024, 1024)}
+    # five row gathers a mixture layer (six before PR 50: the combine's
+    # gathered rows are no residual, so remat gathers them no second time),
+    # no scatter, and the sum of 8 rows left to the compiler
+    assert memory["moe_rows_kernel_calls"] == {
+        "moe_rows_sum": {"calls": 0, "under_moe_sort": 0, "under_moe_combine": 0},
+        "row_gathers": 4 * 5, "row_scatters": 0}
+
+
+@pytest.mark.parametrize("n, k, d, sums", [
+    (16384, 8, 2048, 0),   # olmoe-1b-7b-train-zipf4k: the compiler's sum of 8 rows
+    (16384, 6, 2560, 2),   # smallthinker-21b-a3b-train-zipf16k: ``moe_rows_sum``
+], ids=["olmoe", "smallthinker"])
+def test_the_sorted_layers_row_movements_compile_for_v5e(
+        v5e_chip, monkeypatch, n, k, d, sums):
+    """A layer's sort and combine, forward and backward at a cell's shape,
+    for a described chip: Mosaic accepts the kernel at its blocks where the
+    rule admits ``k``; four row gathers (the sort's, the combine's, one
+    each way: a fifth under remat, the sort's forward again) where the
+    parent's five held the combine's twice, and no scatter."""
+    from learning_at_home_tpu.ops import moe_dispatch
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    one = jax.sharding.SingleDeviceSharding(v5e_chip)
+
+    def through(x, weights, order, inverse):
+        plan = moe_dispatch.DroplessPlan(order, inverse, None, weights, None)
+        with jax.named_scope("moe_sort"):
+            xs = moe_dispatch.sort_tokens(x, plan)
+        with jax.named_scope("moe_combine"):
+            y = moe_dispatch.unsort_combine(xs * 2, plan, x.dtype)
+        return (y.astype(jnp.float32) ** 2).sum()
+
+    shapes = [jax.ShapeDtypeStruct(shape, dtype, sharding=one) for shape, dtype in (
+        ((n, d), jnp.bfloat16), ((n, k), jnp.float32),
+        ((n * k,), jnp.int32), ((n * k,), jnp.int32))]
+    with _no_compile_cache():
+        text = jax.jit(jax.grad(through, argnums=(0, 1))).lower(*shapes).compile().as_text()
+    found = probe.moe_rows_kernel_calls(text)
+    assert found["moe_rows_sum"] == {
+        "calls": sums, "under_moe_sort": sums // 2, "under_moe_combine": sums // 2}
+    assert (found["row_gathers"], found["row_scatters"]) == (4, 0)
 
 
 @pytest.mark.parametrize("m, a, b", [

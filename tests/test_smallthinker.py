@@ -599,6 +599,12 @@ def test_the_whole_step_fits_the_chip(v5e_chip, monkeypatch):
     # equations (``attention_kernel_tilings``) alike
     assert memory["attention_kernel_calls"] == {
         "splash_mha_fwd_residuals": 4, "splash_mha_dkv_no_residuals": 4}
+    # a mixture layer's sums over a token's 6 rows are ``ops/moe_rows.py``'s
+    # kernel, once behind the combine's gather and once behind the sort's
+    # backward; five row gathers a layer (six before PR 50), no scatter
+    assert memory["moe_rows_kernel_calls"] == {
+        "moe_rows_sum": {"calls": 4 * 2, "under_moe_sort": 4, "under_moe_combine": 4},
+        "row_gathers": 4 * 5, "row_scatters": 0}
     assert memory["kept_residual_bytes"] == 4 * 28 * 16384 * (128 * 2 + 4)
     assert {kind: {name: call["calls"] for name, call in calls.items()}
             for kind, calls in memory["attention_kernel_tilings"].items()} == {

@@ -8,6 +8,8 @@
     python tools/grouped_matmul_probe.py gmm collapsed     # megablox at the same tiles
     python tools/grouped_matmul_probe.py sizes             # fewer rows: where the gain starts
     python tools/grouped_matmul_probe.py accuracy          # the program's call against float32
+    python tools/grouped_matmul_probe.py rows              # the row movements around it, a pass at a time
+    python tools/grouped_matmul_probe.py rows uniform 256 1024 --tokens 16384 --choices 6 --width 2560
     # another cell's shapes (here smallthinker-21b-a3b-train-zipf16k's):
     python tools/grouped_matmul_probe.py tiles skewed 256 --rows 98304 --widths 2560x768,768x2560
     # a share's buffer, a width no multiple of the lanes divides (nemotron-labs-twotower's):
@@ -54,6 +56,15 @@ the cell reads 5.4 to 5.9) or ``uniform``.
 - ``sizes``: the default against ``grouped_matmul_tiles``'s choice at 512 ...
   65,536 rows, both kinds of group sizes: where ``GROUPED_MATMUL_MIN_ROWS``
   comes from.
+- ``rows``: the sorted layer's row movements (``ops/moe_dispatch.py``
+  ``sort_tokens`` / ``unsort_combine``, ``ops/moe_rows.py``) at ``--tokens``
+  x ``--choices`` rows of ``--width`` bf16 under a random routing: each
+  gather alone, then each of the four passes (sort and combine, forward
+  and backward) in the parent's form and as ``ops/moe_rows.py``'s rules
+  have it, with the sum alone in both its forms (the kernel at the token
+  blocks given as well) and the backward's scale and dot products alone;
+  ms a call, GB/s on the bytes a pass has to move, and each result
+  against the first form's.
 - ``accuracy``: ``grouped_matmul`` (the program's call, its tiles) and
   plain ``ragged_dot``, result and both gradients, each against float32
   operands at ``Precision.HIGHEST``: rms of the difference over rms of
@@ -346,16 +357,106 @@ def accuracy(shape) -> None:
         raise SystemExit("grouped_matmul_probe: over 0.5 % from the float32 result")
 
 
+def rows(shape) -> None:
+    """The sorted layer's row movements, the parent's forms and the rule's."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from learning_at_home_tpu.ops import moe_dispatch as md
+    from learning_at_home_tpu.ops import moe_rows
+
+    require_tpu()
+    n, k, d = shape.tokens, shape.choices, shape.width
+    rs = np.random.default_rng(50)
+    # every token picks k distinct experts of GROUPS, as a router would
+    picks = np.argsort(rs.random((n, shape.groups)), axis=1)[:, :k]
+    order = jnp.asarray(np.argsort(picks.reshape(-1), kind="stable"), jnp.int32)
+    inverse = jnp.argsort(order).astype(jnp.int32)
+    weights = jnp.asarray(rs.random((n, k)), jnp.float32)
+    x = jnp.asarray(rs.standard_normal((n, d)), jnp.bfloat16)
+    ys = jnp.asarray(rs.standard_normal((n * k, d)), jnp.bfloat16)
+    picked, scale = ys[inverse], weights.reshape(-1)[order]
+    small, large = n * d * 2, n * k * d * 2  # bytes of [n, d] and [n*k, d] bf16
+    bf16 = jnp.bfloat16
+
+    def scale_and_dots(rows, others, scale):  # the combine's backward, behind its gather
+        wide = rows.astype(jnp.float32)
+        return ((scale[:, None] * wide).astype(bf16),
+                jnp.sum(wide * others.astype(jnp.float32), axis=-1))
+
+    def parent_combine(ys, weights):
+        return moe_rows.sum_rows_plain(
+            md._rows_from_sorted(ys, order, inverse), weights, n, k, bf16)
+
+    def combine(ys, weights):
+        return md._combine_sorted(ys, weights, order, inverse, bf16)
+
+    def back(form):
+        return lambda g, *primals: jax.vjp(form, *primals)[1](g)
+
+    def sort_back(sum_rows):
+        return lambda g: sum_rows(g[inverse], None, n, k, g.dtype)
+
+    def sum_at(tokens):
+        def call(picked, weights):
+            moe_rows._TOKENS = tokens
+            return moe_rows.sum_rows_kernel(picked, weights, n, k, bf16)
+        return call
+
+    committed = moe_rows._TOKENS
+    passes = [  # (what, form, its call, arguments, bytes it has to move)
+        ("gather_to_sorted", "xla", lambda x: x[order // k], (x,), small + large),
+        ("gather_from_sorted", "xla", lambda ys: ys[inverse], (ys,), 2 * large),
+        ("sum", "plain", lambda p, w: moe_rows.sum_rows_plain(p, w, n, k, bf16),
+         (picked, weights), large + small),
+        *[("sum", f"kernel@{t}", sum_at(t), (picked, weights), large + small)
+          for t in [committed, *shape.row_tiles]],
+        ("scale_and_dots", "xla", scale_and_dots, (picked, ys, scale), 3 * large),
+        ("sort_backward", "parent", sort_back(moe_rows.sum_rows_plain), (ys,), large + small),
+        ("sort_backward", "rule", sort_back(moe_rows.sum_rows), (ys,), large + small),
+        ("combine_forward", "parent", parent_combine, (ys, weights), large + small),
+        ("combine_forward", "rule", combine, (ys, weights), large + small),
+        ("combine_backward", "parent", back(parent_combine), (x, ys, weights), 2 * large + small),
+        ("combine_backward", "rule", back(combine), (x, ys, weights), 2 * large + small),
+    ]
+    want = {}
+    for what, form, call, args, least in passes:
+        try:
+            compiled = jax.jit(call).lower(*args).compile()
+            got = jax.block_until_ready(compiled(*args))
+            t0 = time.perf_counter()
+            for _ in range(ITERS):
+                out = compiled(*args)
+            jax.block_until_ready(out)
+            ms = (time.perf_counter() - t0) / ITERS * 1e3
+        except Exception as e:  # a block the compiler refuses
+            row(what=what, form=form, refused=f"{type(e).__name__}: {e}"[:300])
+            continue
+        finally:
+            moe_rows._TOKENS = committed
+        facts = dict(ms=round(ms, 3), gb_s_on_least_bytes=round(least / ms / 1e6, 1))
+        first = want.setdefault(what, got)
+        if first is not got:
+            facts["rms_against_first"] = [
+                float(jnp.sqrt(jnp.mean((a.astype(jnp.float32) - b.astype(jnp.float32)) ** 2)
+                               / jnp.mean(b.astype(jnp.float32) ** 2)))
+                for a, b in zip(jax.tree_util.tree_leaves(got),
+                                jax.tree_util.tree_leaves(first))]
+        row(what=what, form=form, tokens=n, choices=k, width=d, **facts)
+
+
 def main() -> None:
     def widths(text: str):
         return tuple(tuple(map(int, pair.split("x"))) for pair in text.split(","))
 
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("command", choices=("tiles", "gmm", "sizes", "accuracy"))
+    ap.add_argument("command", choices=("tiles", "gmm", "sizes", "accuracy", "rows"))
     ap.add_argument("group_sizes", nargs="?", default="collapsed",
                     choices=("collapsed", "skewed", "uniform"))
     ap.add_argument("row_tiles", nargs="*", type=int,
-                    help=f"tiles, gmm: row tiles to sweep (default {ROW_TILES})")
+                    help=f"tiles, gmm: row tiles to sweep (default {ROW_TILES}); "
+                         "rows: token blocks of the kernel beside the rule's")
     ap.add_argument("--rows", type=int, default=ROWS)
     ap.add_argument("--groups", type=int, default=GROUPS)
     ap.add_argument("--widths", type=widths, default=WIDTHS,
@@ -365,8 +466,12 @@ def main() -> None:
                          "buffer (--rows) holds more (a share's)")
     ap.add_argument("--max-over-mean", type=float, default=MAX_OVER_MEAN,
                     help="skewed: the largest group's rows over the mean")
+    ap.add_argument("--tokens", type=int, default=16384, help="rows: n")
+    ap.add_argument("--choices", type=int, default=8, help="rows: k")
+    ap.add_argument("--width", type=int, default=2048, help="rows: d")
     shape = ap.parse_args()
-    {"tiles": tiles, "gmm": gmm, "sizes": sizes, "accuracy": accuracy}[shape.command](shape)
+    {"tiles": tiles, "gmm": gmm, "sizes": sizes, "accuracy": accuracy,
+     "rows": rows}[shape.command](shape)
 
 
 if __name__ == "__main__":

@@ -178,6 +178,32 @@ def scan_kernel_calls(compiled_text: str, kernels: str = "ssd_chunk",
     return calls
 
 
+def moe_rows_kernel_calls(compiled_text: str) -> dict:
+    """The sorted expert layer's row movements in a compiled program's
+    text: how many ``moe_rows_sum`` instructions (``ops/moe_rows.py``) it
+    holds and under which of the layer's two scopes, and how many row
+    gathers and scatters of bf16 or float32 ``[rows, d]`` arrays are left
+    under them (a dropless layer of the kernel form: five gathers, the
+    sort's forward twice under remat, and no scatter)."""
+    found = {"moe_rows_sum": {"calls": 0, "under_moe_sort": 0, "under_moe_combine": 0},
+             "row_gathers": 0, "row_scatters": 0}
+    for name, rest in re.findall(
+            r"^\s*%([\w.\-]+) = (?:bf16|f32)\[\d+,\d+\][^\n]* (?:custom-call|fusion|scatter)"
+            r"\((.*?)(?=^\s*%|\Z)", compiled_text, re.M | re.S):
+        op_name = re.search(r'op_name="([^"]*)"', rest)
+        scope = op_name and re.search(r"[/(](moe_sort|moe_combine)[/)]", op_name.group(1))
+        if not scope:
+            continue
+        if name.startswith("moe_rows_sum"):
+            found["moe_rows_sum"]["calls"] += 1
+            found["moe_rows_sum"]["under_" + scope.group(1)] += 1
+        elif op_name.group(1).endswith("/gather"):
+            found["row_gathers"] += 1
+        elif "scatter" in op_name.group(1).rsplit("/", 1)[-1]:
+            found["row_scatters"] += 1
+    return found
+
+
 def attention_kernel_calls(compiled_text: str) -> dict:
     """How many instructions of each of the blocked kernel's names
     (``splash_mha_fwd_residuals``, ``_dkv_no_residuals``,
@@ -226,7 +252,9 @@ def step_memory(chip, recipe: str = "smallthinker_one_chip") -> dict:
     many instructions of each of the kernel's names the compiled step
     holds (one forward a kernel layer since PR 38: remat keeps the
     kernel's residuals), under ``kept_residual_bytes`` what that costs
-    (:func:`kept_residual_bytes`), under ``scan_kernel_calls`` and
+    (:func:`kept_residual_bytes`), under ``moe_rows_kernel_calls`` the
+    sorted expert layer's row movements (:func:`moe_rows_kernel_calls`;
+    PR 50), under ``scan_kernel_calls`` and
     ``kept_scan_bytes`` the same two for the state-space scan's kernels
     (:func:`scan_kernel_calls`; PR 40), under ``delta_kernel_calls`` the
     delta rule's kernels under ``delta/core`` and under
@@ -292,6 +320,7 @@ def step_memory(chip, recipe: str = "smallthinker_one_chip") -> dict:
             text, re.M))),
         "attention_kernel_tilings": attention_kernel_tilings(traced.jaxpr.jaxpr),
         "attention_kernel_calls": attention_kernel_calls(text),
+        "moe_rows_kernel_calls": moe_rows_kernel_calls(text),
         "kept_residual_bytes": kept_residual_bytes(traced.jaxpr.jaxpr),
         "scan_kernel_calls": scan_kernel_calls(text),
         "conv_kernel_calls": scan_kernel_calls(text, "ssm_conv", "ssm/conv"),
