@@ -298,7 +298,12 @@ def _measure(cell, config, traffic, args, clock, child, server, counter,
         import trace_reduce
 
         try:
-            observations["trace"] = trace_reduce.reduce_dir(trace_dir)
+            # the runtime thread's five stages name a device gap by what
+            # that thread was in at the gap's middle (PERF.md section 5)
+            observations["trace"] = trace_reduce.reduce_dir(
+                trace_dir,
+                host_spans=("runtime.idle", "runtime.stack", "runtime.dispatch",
+                            "runtime.materialize", "runtime.handoff"))
         finally:
             shutil.rmtree(trace_dir, ignore_errors=True)
     return {

@@ -189,17 +189,22 @@ def check_manifest(path: str) -> list:
             say(f"cell {name!r} reports no end-to-end metric but setup_s")
 
     layered = {name: 0 for name in cells}
+    readings: dict = {}  # (cell, the name after the first dot) -> entry's name
     for e in m["per_layer"]:
         if not line_ok(e["layer"]):
             say(f"per-layer {e['name']!r}: layer of 1 to 200 characters")
         mine = cells_of(e)
+        reading = e["name"].split(".", 1)[-1]
         for name in mine:
             layered[name] += 1
             if e["moves"] not in reported[name]:  # PR 22's refusal
                 say(f"per-layer {e['name']!r} is reported in {name!r}, where "
                     f"{e['moves']!r}, which it should move, is not")
-        if len({cells[n]["config"] for n in mine}) > 1:
-            say(f"per-layer {e['name']!r} spans two configurations")
+            first = readings.setdefault((name, reading), e["name"])
+            if first != e["name"]:
+                say(f"per-layer {first!r} and {e['name']!r} are both the "
+                    f"reading {reading!r} in cell {name!r}: a fold that left "
+                    "a copy behind")
         try:
             spec = json.load(open(harness.find_file(
                 m, "layer_metrics", e["name"] + ".json")))
@@ -210,6 +215,25 @@ def check_manifest(path: str) -> list:
                         "from the manifest's")
         except (harness.BenchError, KeyError) as err:
             say(f"per-layer {e['name']!r}: {err}")
+            continue
+        # the file's own copy of where the metric is read, where it keeps
+        # one, says what the manifest says (a folded file keeps none)
+        if "workloads" in spec and spec["workloads"] != e.get("workloads"):
+            say(f"layer_metrics/{e['name']}.json: workloads differs from "
+                "the manifest's list")
+        configs_of_mine = {cells[n]["config"] for n in mine}
+        one = spec.get("config")  # a rehearsal's cells name theirs otherwise
+        if one is not None and (len(configs_of_mine) > 1 or (
+                one in configs and one not in configs_of_mine)):
+            say(f"layer_metrics/{e['name']}.json: config {one!r} is not "
+                "that of every cell the manifest lists")
+        if len(configs_of_mine) > 1 and (
+                {"module", "function"} & set(spec.get("args", {}))):
+            say(f"per-layer {e['name']!r} lists cells of "
+                f"{len(configs_of_mine)} configurations, and its file's args "
+                "name a module or a function: ONE model's count of "
+                "operations, which may list the cells of one configuration "
+                "only")
     for name, n in layered.items():
         if not n:
             say(f"cell {name!r} reports no per-layer metric")
