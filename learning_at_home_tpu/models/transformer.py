@@ -798,12 +798,15 @@ class DMoETransformerLM:
         stream as it is where the norm is on the part's output."""
         return self._norm(norm_p, x) if self.cfg.norm_place == "input" else x
 
+    def _part_output(self, norm_p, out):
+        """What a part of a layer adds to the stream: what it gave, or its
+        norm where the norm is on the part's output."""
+        return out if self.cfg.norm_place == "input" else self._norm(norm_p, out)
+
     def _add_part(self, norm_p, x, out):
         """The stream after a part gave ``out``: ``x + out``, or ``x +
         norm(out)`` where the norm is on the part's output."""
-        if self.cfg.norm_place == "input":
-            return x + out
-        return x + self._norm(norm_p, out)
+        return x + self._part_output(norm_p, out)
 
     def _delta_block(self, lp, x):
         """The stream after the layer's delta-rule mixer, what the mixer
@@ -841,7 +844,8 @@ class DMoETransformerLM:
         with jax.named_scope(scope):
             # a layer of one mixer has ONE norm
             norm_p = lp["ln1" if "ln1" in lp else "norm"]
-            attn_in = self._part_input(norm_p, x)
+            with jax.named_scope("norm"):
+                attn_in = self._part_input(norm_p, x)
             q, k, v = self._qkv(
                 lp, attn_in,
                 # under the zigzag ring the stream is in zigzag order
@@ -853,7 +857,10 @@ class DMoETransformerLM:
                     q, k, v, self.attn_impl, kind.window
                 )
             )
-            x = self._add_part(norm_p, x, output_projection(lp, core(q, k, v)))
+            out = output_projection(lp, core(q, k, v))
+            with jax.named_scope("norm"):  # the norm, wherever it is placed
+                out = self._part_output(norm_p, out)
+            x = x + out  # directly under the attention scope
         return x, attn_in
 
     @staticmethod

@@ -73,12 +73,14 @@ def qkv_projections(
     hd = lp["wq"].shape[-1] // n_heads
 
     def project(w: str, norm: str | None) -> jax.Array:
-        y = x @ lp[w].astype(x.dtype)
+        with jax.named_scope("proj"):
+            y = x @ lp[w].astype(x.dtype)
         whole = norm in lp and lp[norm]["scale"].shape[-1] == y.shape[-1]
         if whole:
             with jax.named_scope("qk_norm"):
                 y = rms_norm(lp[norm], y, norm_eps)
-        y = y.reshape(b, s, y.shape[-1] // hd, hd)
+        with jax.named_scope("proj"):
+            y = y.reshape(b, s, y.shape[-1] // hd, hd)
         if norm in lp and not whole:
             with jax.named_scope("qk_norm"):
                 y = rms_norm(lp[norm], y, norm_eps)
@@ -144,9 +146,10 @@ def latent_qkv_projections(
 
 
 def output_projection(lp: dict, out: jax.Array) -> jax.Array:
-    """[B,S,H,hd] → [B,S,d] @ wo."""
+    """[B,S,H,hd] → [B,S,d] @ wo.  Scope ``out_proj``."""
     b, s, h, hd = out.shape
-    return out.reshape(b, s, h * hd) @ lp["wo"].astype(out.dtype)
+    with jax.named_scope("out_proj"):
+        return out.reshape(b, s, h * hd) @ lp["wo"].astype(out.dtype)
 
 
 def squared_relu(h: jax.Array) -> jax.Array:
@@ -467,10 +470,17 @@ def attention_core(
         def heads_first(x):
             return x.transpose(0, 2, 1, 3)
 
+        # scope ``flash/layout``: the four transposes and the scale; the
+        # kernel's calls stay directly under ``flash``
         with jax.named_scope("flash"):
-            return heads_first(jax.vmap(kernel)(
-                heads_first(q) * (1.0 / hd ** 0.5), heads_first(k), heads_first(v)
-            ))
+            with jax.named_scope("layout"):
+                q, k, v = (
+                    heads_first(q) * (1.0 / hd ** 0.5), heads_first(k),
+                    heads_first(v),
+                )
+            out = jax.vmap(kernel)(q, k, v)
+            with jax.named_scope("layout"):
+                return heads_first(out)
     if window is not None:
         return jax.nn.dot_product_attention(
             q, k, v, is_causal=True, local_window_size=(window - 1, 0)

@@ -7,6 +7,7 @@ CPU only: what the kernel computes and how fast is the chip's to say
 compile in ``tests/test_olmoe.py``, beside the topology fixture.
 """
 
+import collections
 import dataclasses
 
 import jax
@@ -179,7 +180,9 @@ def test_flash_falls_back_to_the_xla_core(shape, backend, monkeypatch):
 
 def test_only_the_kernel_branch_is_scoped(monkeypatch):
     """The scope ``flash`` names the kernel's operations and nothing of
-    the ``xla`` branch, whose lowering other programs' hashes hold."""
+    the ``xla`` branch: no ``attention/flash`` in a step means the ``xla``
+    core ran.  (No hash holds a scope: the lowered text the step hashes of
+    ``tests/test_olmoe.py`` are taken of carries no debug information.)"""
     q, k, v = _qkv((1, 128, 2, 64))
 
     def scopes(impl):
@@ -194,6 +197,29 @@ def test_only_the_kernel_branch_is_scoped(monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert all(scope.startswith("flash") for scope in scopes("flash"))
     assert scopes("xla") == on_xla
+
+
+@pytest.mark.parametrize("window", [None, 64], ids=["causal", "window"])
+def test_the_kernel_branch_names_its_layout_apart_from_the_kernel(
+    monkeypatch, window
+):
+    """Under ``flash``: the four ``heads_first`` transposes and the scale
+    on q read ``flash/layout``, and every other equation (the kernel's
+    call under its ``vmap``) reads ``flash`` and not ``layout``, so a
+    reader by scope can tell the layout's time from the kernel's."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    q, k, v = _qkv((1, 128, 2, 64))
+    jaxpr = jax.make_jaxpr(
+        lambda q, k, v: trunk.attention_core(q, k, v, "flash", window)
+    )(q, k, v)
+    by_scope = collections.Counter(
+        (str(e.source_info.name_stack), e.primitive.name)
+        for e in jaxpr.jaxpr.eqns)
+    layout = {key: n for key, n in by_scope.items() if key[1] in ("transpose", "mul")}
+    assert layout == {("flash/layout", "transpose"): 4, ("flash/layout", "mul"): 1}
+    kernel = [scope for scope, name in by_scope if name not in ("transpose", "mul")]
+    assert kernel and all(
+        scope.startswith("flash") and "layout" not in scope for scope in kernel)
 
 
 def test_cached_decode_under_flash_matches_the_full_forward(monkeypatch):

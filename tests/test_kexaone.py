@@ -813,6 +813,15 @@ def test_the_whole_step_fits_the_chip(v5e_chip, monkeypatch):
         "splash_mha_fwd_residuals": 5,
         "splash_mha_dkv_no_residuals": 5, "splash_mha_dq_no_residuals": 4}
     assert memory["kept_residual_bytes"] == 5 * 64 * 16384 * (128 * 2 + 4)
+    # the optimized HLO's instructions carry the attention part's stages
+    # (PR 52), and the kernel's calls sit under ``flash``, not its ``layout``
+    stages = memory["attention_stages"]
+    assert stages["stages"] == [
+        "flash", "flash/layout", "norm", "out_proj", "proj", "qk_norm", "rope"]
+    assert stages["kernel_scopes"] == [
+        f"attention/flash/vmap(jit(_splash_attention))/{name}/{name}"
+        for name in ("splash_mha_dkv_no_residuals", "splash_mha_dq_no_residuals",
+                     "splash_mha_fwd_residuals")]
     tilings = memory["attention_kernel_tilings"]
     assert {kind: {name: call["calls"] for name, call in calls.items()}
             for kind, calls in tilings.items()} == {

@@ -627,6 +627,13 @@ def test_dmoe256_lowered_step_is_text_identical_to_the_parents(
     part of the configuration; PR 29 deleted the forks beside the path)
     was not on the path.  A change that is MEANT to alter a program
     updates its hash with the reason."""
+    text = lowered_tiny_step(recipe, axes).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == sha256
+
+
+def lowered_tiny_step(recipe, axes):
+    """A recipe's tiny train step on a mesh of ``axes``, lowered for
+    abstract arguments placed as the recipe places them."""
     from learning_at_home_tpu.parallel.mesh import opt_state_shardings
 
     n_dev = int(np.prod(list(axes.values())))
@@ -647,8 +654,7 @@ def test_dmoe256_lowered_step_is_text_identical_to_the_parents(
     ids = jax.ShapeDtypeStruct(
         (batch, cfg.seq_len), jnp.int32, sharding=batch_sharding(mesh)
     )
-    text = model.make_train_step(opt).lower(p, o, ids, ids).as_text()
-    assert hashlib.sha256(text.encode()).hexdigest() == sha256
+    return model.make_train_step(opt).lower(p, o, ids, ids)
 
 
 # ---- remat changes no number; the stack has one layout ----

@@ -213,6 +213,27 @@ def attention_kernel_calls(compiled_text: str) -> dict:
         compiled_text, re.M)))
 
 
+def attention_stages(compiled_text: str) -> dict:
+    """Where a compiled program's instructions under the attention scope
+    lie, fused ones too, as ``tools/scope_tree.py`` folds their paths:
+    under ``stages`` the stages they name (``norm``, ``proj``, ``qk_norm``,
+    ``rope``, ``flash``, ``flash/layout``, ``out_proj``; PR 52), under
+    ``kernel_scopes`` the folded paths of the blocked kernel's calls
+    (instructions ``splash_mha*``), the kind of layer folded too."""
+    import harness
+
+    tree = harness.load_path(os.path.join(REPO, "tools", "scope_tree.py"))
+    blocks = harness.load_path(os.path.join(
+        REPO, "benchmarks", "runners", "train_recipe_blocks.py"))
+    names = blocks.op_names(compiled_text)
+    return {
+        "stages": sorted(tree.attention_stages(names.values())),
+        "kernel_scopes": sorted({
+            "/".join(tree.fold(op_name, also=tree.ATTENTION_KINDS)[0])
+            for name, op_name in names.items() if name.startswith("splash_mha")}),
+    }
+
+
 def attention_kernel_tilings(jaxpr) -> dict:
     """By layer kind (the scope ``attention/<kind>``, or ``attention``) and
     kernel name: how many calls the traced step makes, the query and key
@@ -251,7 +272,9 @@ def step_memory(chip, recipe: str = "smallthinker_one_chip") -> dict:
     reads off the traced step, under ``attention_kernel_calls`` how
     many instructions of each of the kernel's names the compiled step
     holds (one forward a kernel layer since PR 38: remat keeps the
-    kernel's residuals), under ``kept_residual_bytes`` what that costs
+    kernel's residuals), under ``attention_stages`` which stages of the
+    attention part its instructions name (:func:`attention_stages`; PR
+    52), under ``kept_residual_bytes`` what that costs
     (:func:`kept_residual_bytes`), under ``moe_rows_kernel_calls`` the
     sorted expert layer's row movements (:func:`moe_rows_kernel_calls`;
     PR 50), under ``scan_kernel_calls`` and
@@ -320,6 +343,7 @@ def step_memory(chip, recipe: str = "smallthinker_one_chip") -> dict:
             text, re.M))),
         "attention_kernel_tilings": attention_kernel_tilings(traced.jaxpr.jaxpr),
         "attention_kernel_calls": attention_kernel_calls(text),
+        "attention_stages": attention_stages(text),
         "moe_rows_kernel_calls": moe_rows_kernel_calls(text),
         "kept_residual_bytes": kept_residual_bytes(traced.jaxpr.jaxpr),
         "scan_kernel_calls": scan_kernel_calls(text),
