@@ -28,6 +28,7 @@ import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from learning_at_home_tpu.models.trunk import (
+    ATTENTION_PRODUCTS,
     FLASH_RESIDUALS,
     attention_core,
     delta_mixer,
@@ -940,12 +941,20 @@ class DMoETransformerLM:
             # read, so the recompute holds no forward kernel call (a
             # layer's 68-273 MB against 3-32 ms: PERF.md section 6, PR
             # 38), and the scan kernel's output and entering states
-            # (ops/ssd.py; PR 40); a layer that runs neither kernel
-            # names nothing and is recomputed whole, as under no policy
+            # (ops/ssd.py; PR 40); and the results of the attention
+            # part's matrix products, ``x @ wq``, ``x @ wk``, ``x @ wv``
+            # and ``out @ wo`` (trunk.ATTENTION_PRODUCTS; PR 53), which
+            # the backward pass reads (the queries' and keys' norm, the
+            # kernel, the feed-forward part behind the add) and would
+            # otherwise multiply a second time: bf16 [B, S, q + 2 kv + d]
+            # a layer, 537 MB in k-exaone for 28 ms (PERF.md section 6,
+            # PR 53).  A layer on the xla core keeps its products too;
+            # everything else (norms, the rotation, the mixers' and the
+            # experts' products, the dense blocks) is recomputed
             layer_fn = jax.checkpoint(
                 layer_fn, static_argnums=(4,),
                 policy=jax.checkpoint_policies.save_only_these_names(
-                    FLASH_RESIDUALS, SSD_RESIDUALS
+                    FLASH_RESIDUALS, SSD_RESIDUALS, ATTENTION_PRODUCTS
                 ),
             )
 

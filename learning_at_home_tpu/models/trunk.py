@@ -11,6 +11,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
 from learning_at_home_tpu.ops.delta_rule import gated_delta_chunked
 from learning_at_home_tpu.ops.gate_norm import gated_rms_norm
@@ -53,6 +54,21 @@ def rotary(
     ).astype(x.dtype)
 
 
+# The name the attention part gives the results of its matrix products:
+# ``x @ wq``, ``x @ wk``, ``x @ wv`` and ``out @ wo``; in the latent form
+# the two products down to the latents and ``out @ wo``.  The backward
+# pass reads them (the queries' and keys' norm its input, the kernel q, k
+# and v, the feed-forward part the stream after the add), none for its own
+# gradient.  A ``jax.checkpoint`` whose policy saves this name (the layer's
+# remat: ``DMoETransformerLM._hidden``) keeps them, so the backward pass
+# runs none of these products a second time and rebuilds the rest from
+# them (norm, reshape, rotation, the add); outside a checkpoint the name is
+# the identity.  The latent form's three products UP from the latents are
+# not named: kept, their 461 MB a layer cost more than their second run
+# (GLM-4.7-Flash's cell on the chip, PERF.md section 6, PR 53).
+ATTENTION_PRODUCTS = "attention_products"
+
+
 def qkv_projections(
     lp: dict, x: jax.Array, n_heads: int,
     positions: jax.Array | None = None,
@@ -72,18 +88,23 @@ def qkv_projections(
     b, s, _ = x.shape
     hd = lp["wq"].shape[-1] // n_heads
 
+    def heads(y: jax.Array) -> jax.Array:
+        return y.reshape(b, s, y.shape[-1] // hd, hd)
+
     def project(w: str, norm: str | None) -> jax.Array:
         with jax.named_scope("proj"):
             y = x @ lp[w].astype(x.dtype)
-        whole = norm in lp and lp[norm]["scale"].shape[-1] == y.shape[-1]
+            whole = norm in lp and lp[norm]["scale"].shape[-1] == y.shape[-1]
+            # named in the shape its reader takes it (the whole
+            # projection's norm; else a head's norm or the kernel), so
+            # that the product writes the kept array in that layout
+            y = checkpoint_name(y if whole else heads(y), ATTENTION_PRODUCTS)
+        if norm in lp:
+            with jax.named_scope("qk_norm"):
+                y = rms_norm(lp[norm], y, norm_eps)
         if whole:
-            with jax.named_scope("qk_norm"):
-                y = rms_norm(lp[norm], y, norm_eps)
-        with jax.named_scope("proj"):
-            y = y.reshape(b, s, y.shape[-1] // hd, hd)
-        if norm in lp and not whole:
-            with jax.named_scope("qk_norm"):
-                y = rms_norm(lp[norm], y, norm_eps)
+            with jax.named_scope("proj"):
+                y = heads(y)
         return y
 
     q = project("wq", "q_norm")
@@ -120,8 +141,9 @@ def latent_qkv_projections(
     hd = lp["wq_b"].shape[-1] // n_heads
     nope = hd - rope_dim
     with jax.named_scope("latent_down"):
-        c_q = rms_norm(lp["q_a_norm"], x @ lp["wq_a"].astype(x.dtype), norm_eps)
-        kv = x @ lp["wkv_a"].astype(x.dtype)
+        c_q = rms_norm(lp["q_a_norm"], checkpoint_name(
+            x @ lp["wq_a"].astype(x.dtype), ATTENTION_PRODUCTS), norm_eps)
+        kv = checkpoint_name(x @ lp["wkv_a"].astype(x.dtype), ATTENTION_PRODUCTS)
         c_kv = rms_norm(lp["kv_a_norm"], kv[..., :kv_rank], norm_eps)
         k_rope = kv[..., kv_rank:].reshape(b, s, 1, rope_dim)
     with jax.named_scope("latent_up"):
@@ -149,7 +171,9 @@ def output_projection(lp: dict, out: jax.Array) -> jax.Array:
     """[B,S,H,hd] → [B,S,d] @ wo.  Scope ``out_proj``."""
     b, s, h, hd = out.shape
     with jax.named_scope("out_proj"):
-        return out.reshape(b, s, h * hd) @ lp["wo"].astype(out.dtype)
+        return checkpoint_name(
+            out.reshape(b, s, h * hd) @ lp["wo"].astype(out.dtype),
+            ATTENTION_PRODUCTS)
 
 
 def squared_relu(h: jax.Array) -> jax.Array:

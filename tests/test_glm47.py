@@ -660,6 +660,13 @@ def test_the_whole_step_fits_the_chip(v5e_chip, monkeypatch):
         "splash_mha_fwd_residuals": 6,
         "splash_mha_dkv_no_residuals": 6}  # fused: no dQ kernel of its own
     assert memory["kept_residual_bytes"] == 6 * 20 * 16384 * (256 * 2 + 4)
+    # and the results of three of the attention part's six products (PR
+    # 53): the two down to the latents (768, and 512 with the 64 rotated)
+    # and the output projection's, bf16 [16384, 768 + 576 + 2048] a layer,
+    # 0.67 GB; the three products UP from the latents run a second time in
+    # all six layers (kept, their 2.77 GB cost the cell 0.23 % on the chip)
+    assert memory["kept_product_bytes"] == 6 * 16384 * (768 + 576 + 2048) * 2
+    assert memory["recomputed_attention_products"] == 6 * 3
     calls = memory["attention_kernel_tilings"]["attention"]
     assert {name: (c["calls"], c["block_q"], c["block_kv"]) for name, c in calls.items()} == {
         "splash_mha_fwd_residuals": (6, 1024, 1024),

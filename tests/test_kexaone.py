@@ -813,6 +813,12 @@ def test_the_whole_step_fits_the_chip(v5e_chip, monkeypatch):
         "splash_mha_fwd_residuals": 5,
         "splash_mha_dkv_no_residuals": 5, "splash_mha_dq_no_residuals": 4}
     assert memory["kept_residual_bytes"] == 5 * 64 * 16384 * (128 * 2 + 4)
+    # and the results of the attention part's products (PR 53): q, k, v and
+    # the output projection's, bf16 [16384, 8192 + 1024 + 1024 + 6144] a
+    # layer, 2.68 GB (13.79 GB live, 81.6 %, from 12.18: the band above
+    # holds it), and the backward pass runs none of the four a second time
+    assert memory["kept_product_bytes"] == 5 * 16384 * (8192 + 2 * 1024 + 6144) * 2
+    assert memory["recomputed_attention_products"] == 0
     # the optimized HLO's instructions carry the attention part's stages
     # (PR 52), and the kernel's calls sit under ``flash``, not its ``layout``
     stages = memory["attention_stages"]

@@ -843,6 +843,14 @@ def test_the_whole_step_fits_the_chip(v5e_chip, monkeypatch):
     assert memory["attention_kernel_calls"] == {
         "splash_mha_fwd_residuals": 1, "splash_mha_dkv_no_residuals": 1}
     assert memory["kept_residual_bytes"] == 32 * 16384 * (128 * 2 + 4)
+    # and the results of the attention layer's products (PR 53): q, k, v
+    # and the output projection's, bf16 [16384, 4096 + 256 + 256 + 2688],
+    # 0.24 GB NAMED; the backward pass runs none of the four a second time.
+    # Of the output projection's 88 MB nothing is held: in a layer of one
+    # mixer no backward equation reads it, so the checkpoint drops it from
+    # its residuals and the compiled step has no ``reduce_precision`` of it
+    assert memory["kept_product_bytes"] == 16384 * (4096 + 2 * 256 + 2688) * 2
+    assert memory["recomputed_attention_products"] == 0
     calls = memory["attention_kernel_tilings"]["global"]
     assert {name: (c["calls"], c["block_q"], c["block_kv"]) for name, c in calls.items()} == {
         "splash_mha_fwd_residuals": (1, 1024, 1024),

@@ -643,4 +643,10 @@ def test_the_whole_step_fits_the_chip_and_runs_the_rule_as_kernels(
     assert memory["float32_arrays_beside_gate_norm"] == []
     assert memory["attention_kernel_calls"] == {
         "splash_mha_fwd_residuals": 2, "splash_mha_dkv_no_residuals": 2}
+    # the results of the two attention layers' products are kept across the
+    # backward pass (PR 53): q, k, v and the output projection's, bf16
+    # [16384, 4 x 3840] a layer, 1.01 GB, under the band above; the backward
+    # pass runs none of the four a second time
+    assert memory["kept_product_bytes"] == 2 * 16384 * (4 * 3840) * 2
+    assert memory["recomputed_attention_products"] == 0
     assert memory["loss_layer_products"] == 3

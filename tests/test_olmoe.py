@@ -548,57 +548,46 @@ def test_grouped_matmul_and_its_gradients_are_ragged_dots(m, a, b, dtype, how):
 
 # sha256 of make_train_step(...).lower(...).as_text() with this container's
 # jax 0.9.0: flagship_one_chip(tiny=True) on a one-device CPU mesh, the
-# same recipe on data=2 x expert=2 (dmoe256-train-pod4's program) and
-# olmoe_one_chip(tiny=True) on one device (olmoe-1b-7b-train-zipf4k's).
-# All three re-taken on PR 34's tree (parent 90b8760), which was meant to
-# alter them: the loss layer takes a chunk's gradients in its forward scan
-# (transformer._ce_of_chunks), so the step has three products of the head's
-# where the checkpointed chunks' backward made a fourth.  Before that they
-# stood from 613a39e (the first) and from PR 29's parent f320cf8.
+# same recipe on data=2 x expert=2 (dmoe256-train-pod4's program),
+# olmoe_one_chip(tiny=True) on one device (olmoe-1b-7b-train-zipf4k's) and
+# the five newer recipes' tiny steps on one device.
+# ALL EIGHT re-taken on PR 53's tree (parent 950e362), which was meant to
+# alter them: the layer's checkpoint keeps the results of the attention
+# part's matrix products (``trunk.ATTENTION_PRODUCTS``: q, k, v seen as
+# heads where no norm spans the whole projection, and the output
+# projection's; the latent form's two products down to the latents), on the
+# ``xla`` core too, so every step holds a ``reduce_precision`` of each kept
+# result behind its product and no second product in the backward pass.
+# They read before, from the PRs named: dmoe one-chip e0b11dba135b..46
+# and pod4 e58a362779f1..90, olmoe a5ef2b58eef2..51 (PR 34: the loss layer's
+# gradients in its forward scan); smallthinker a4a7bb6bcd9b..13, k-exaone
+# acf47b0ff668..af, glm-4.7-flash 6d0b221f0ca4..22 (first taken on PR 43's
+# parent); nemotron f88020c63901..d1 (PR 47: the gate and grouped RMSNorm
+# moved into ``ops/gate_norm.py``); olmo-hybrid b6ddcfc300b4..f0 (PR 46:
+# the unit-length scaling moved into the rule).
 DMOE_TINY_STEP_SHA256 = (
-    "e0b11dba135b5aaeec1aecbd588e14995b94e8e11213214b32055da855546846"
+    "c35682256a505a684ac98ecf12bc914847891861fcae9af32ae618c94c4d2c54"
 )
 DMOE_TINY_POD4_STEP_SHA256 = (
-    "e58a362779f1640ef78379b9ba2022628da3981a23f8191c889f7cdd57e27390"
+    "6093d5c44a08f09e847f0da05944c2bfc3be22acae613d26cb6b634bdc2142fb"
 )
 OLMOE_TINY_STEP_SHA256 = (
-    "a5ef2b58eef22ef79b1198357c93380731ca0f7daa7017d3ef0b0469a7f08651"
+    "41902b870772c0eeff1f85b4d1fc3db4404f11c2c554912fabd6ffa4e5a53883"
 )
-# The four newer recipes' tiny steps on one device, first taken on PR 43's
-# parent (1f129aa, PR 41's tree), where they read the same as on PR 43's
-# tree: the scanned/stacked layout, ``attn_impl`` and ``dispatch_impl``
-# went without a letter of any cell's program changing.
 SMALLTHINKER_TINY_STEP_SHA256 = (
-    "a4a7bb6bcd9bb5f6e0cbe00a067c1d9cfdfcb12906ef423f156b5e0e0642e413"
+    "25ac4616b8fd1eb481cda9480d25abfe698976401d8b458a7ff1c3b0e9203b94"
 )
 K_EXAONE_TINY_STEP_SHA256 = (
-    "acf47b0ff668da9c1ffc4bf161aad8ae59b9091dc57c12c1335150f88bd260af"
+    "ff9fdb8aaf7b8f9b680c38ab5ac08819ce9089655d81a5d5416dccea14d8e65f"
 )
 GLM_4_7_FLASH_TINY_STEP_SHA256 = (
-    "6d0b221f0ca43b27fa836c5b4812d086bae5b53b53cab2ca764db91d32ff2222"
+    "2745ce8e2222a635da57fe8f5d1f8f2cf1e152326f31723778d6eaba086ef541"
 )
-# PR 47: the two hybrids' texts changed and the six above did not.  The
-# mixers' gate and grouped RMSNorm moved into ``ops/gate_norm.py``
-# (``gated_rms_norm_plain`` is the CPU's form): the same arithmetic
-# operations, one for one (the two texts with their value numbers taken out
-# and their lines sorted differ from the parent's in ``stablehlo.reshape``
-# lines alone: the mixer hands the norm ``[B, S, C]`` and the plain form
-# groups it again), but ``z``'s slice of the in-projection now stands in
-# ``gate_norm`` and no longer in ``in_proj``, so every value after it is
-# numbered anew.  Nemotron's read 2ffcd672b169..faaccb451582 (from PR 43's
-# parent on), Olmo-Hybrid's 4d421ce8f603..3bbf838fea (PR 46).
 NEMOTRON_TINY_STEP_SHA256 = (
-    "f88020c63901660670b296ecf950f312d2f4503a819d905c3e884a3b98805cd1"
+    "56dff9681de0fcee09a3b172dbc956c1a8aac71c19a7ce12525ee130dd0c12e2"
 )
-# Olmo-Hybrid's tiny step, first taken on PR 45's tree, the PR that brought
-# it (delta-rule layers, the norm on outputs, no mixture layer); the seven
-# above read on that tree what they read before it.  PR 46: the unit-length
-# scaling of q and k moved out of ``trunk.delta_mixer`` into the rule
-# (``ops/delta_rule.py`` ``unit_length``: q and k apart, the same arithmetic
-# an element; the rule's kernel does it in VMEM), so this one text changed
-# (it read e506501163cf..18d4f6) and the seven above did not.
 OLMO_HYBRID_TINY_STEP_SHA256 = (
-    "b6ddcfc300b4dd3eebc7b9d9b64a7b43e17e80da52a1f3b88ddd396ef36854f0"
+    "34383f25753571f7f98b0735a82b045f784e5e1f03d7603259aa62623a3db7fb"
 )
 
 
@@ -660,22 +649,34 @@ def lowered_tiny_step(recipe, axes):
 # ---- remat changes no number; the stack has one layout ----
 
 
-@pytest.mark.parametrize("recipe", [
-    flagship_one_chip, olmoe_one_chip, smallthinker_one_chip,
-    k_exaone_one_chip, glm_4_7_flash_one_chip,
-    nemotron_labs_twotower_one_chip,
-], ids=["dmoe", "olmoe", "smallthinker", "k-exaone", "glm-4.7-flash", "nemotron"])
-def test_remat_changes_no_loss_or_gradient(recipe):
+@pytest.mark.parametrize("recipe, norm_place", [
+    (flagship_one_chip, "input"), (olmoe_one_chip, "input"),
+    (smallthinker_one_chip, "input"), (k_exaone_one_chip, "input"),
+    (glm_4_7_flash_one_chip, "input"), (nemotron_labs_twotower_one_chip, "input"),
+    (olmoe_one_chip, "output"), (glm_4_7_flash_one_chip, "output"),
+], ids=["dmoe", "olmoe", "smallthinker", "k-exaone", "glm-4.7-flash", "nemotron",
+        "olmoe-norm-on-outputs", "glm-4.7-flash-norm-on-outputs"])
+def test_remat_changes_no_loss_or_gradient(recipe, norm_place):
     """Every cell runs its per-layer trees under ``remat``; the plain
     references are compared without it.  From the same weights a recipe's
     tiny stack gives one loss and one set of gradients with and without
     ``jax.checkpoint`` around the layer: the dropless block's row gathers
     replay their ``custom_vjp`` under it, and so do the share's, the
     latent block's with its prediction block (which runs the same
-    checkpointed layer) and the state-space kernels' plain forms."""
+    checkpointed layer) and the state-space kernels' plain forms.  The
+    attention part's products are kept across the backward pass and not
+    run again (PR 53: ``trunk.ATTENTION_PRODUCTS``), in both projection
+    functions (``qkv_projections``; ``glm-4.7-flash``:
+    ``latent_qkv_projections``) and with the norm on a part's input, as
+    the six recipes have it, or on its output (``olmo-hybrid``'s place,
+    whose own tiny stack differs by more with and without remat, on the
+    parent too: its delta rule's plain form solves in another order)."""
     mesh = _one_device_mesh()
     under_remat, cfg, _, batch = recipe(mesh, tiny=True)
-    assert cfg.remat
+    assert cfg.remat and cfg.norm_place == "input"
+    if norm_place != cfg.norm_place:
+        cfg = dataclasses.replace(cfg, norm_place=norm_place)
+        under_remat = DMoETransformerLM(cfg, mesh)
     plain = DMoETransformerLM(dataclasses.replace(cfg, remat=False), mesh)
     params = _decisive(plain.init_params(jax.random.PRNGKey(5)))
     rs = np.random.RandomState(9)
@@ -822,9 +823,10 @@ def test_blocked_attention_compiles_for_v5e_at_its_tiles(v5e_chip, monkeypatch, 
 
 
 def _the_parents_formula(monkeypatch):
-    """The layer's remat and the kernel's constructor as the parent commit
-    wrote them: ``jax.checkpoint`` under no policy, the kernel's forward
-    naming nothing."""
+    """The layer's remat, the kernel's constructor and the attention
+    part's products as the commit before any name wrote them (PR 38's
+    parent): ``jax.checkpoint`` under no policy, the kernel's forward and
+    the products (PR 53) naming nothing."""
     from jax.experimental.pallas.ops.tpu import splash_attention as splash
 
     checkpoint, make = jax.checkpoint, splash.make_splash_mha_single_device
@@ -833,35 +835,67 @@ def _the_parents_formula(monkeypatch):
     monkeypatch.setattr(
         splash, "make_splash_mha_single_device",
         lambda residual_checkpoint_name=None, **kw: make(**kw))
+    monkeypatch.setattr(trunk, "checkpoint_name", lambda x, name: x)
 
 
-def _kernel_sized(mesh, **changes):
-    """The recipe's tiny block at the smallest shape the blocked kernel
-    takes: 512 positions, heads of 64, bf16."""
-    _, cfg, _, _ = olmoe_one_chip(mesh, tiny=True)
+# By recipe: the changes that take its tiny block to the smallest shape the
+# blocked kernel takes (512 positions, heads of 64, bf16; the latent form:
+# two layers and the prediction block, heads of [48 | 16 rotated]); what a
+# layer names, in the order it computes (the three products of
+# ``qkv_projections``, the kernel's output and row sums, the output
+# projection; in the latent form the two products down to the latents in
+# the three's place: the three up from them are run again, not kept); its
+# matrix products a layer; the kept results' width (q, k, v and the
+# stream, or the two latents with the rotated key part and the stream).
+_KERNEL_SIZED = {
+    olmoe_one_chip: dict(
+        changes=dict(d_model=256, n_heads=4),
+        names=[trunk.ATTENTION_PRODUCTS] * 3 + [trunk.FLASH_RESIDUALS] * 2
+        + [trunk.ATTENTION_PRODUCTS],
+        products=4, kept_width=4 * 256),
+    glm_4_7_flash_one_chip: dict(
+        changes=dict(n_layers=2, ffn_pattern=("dense", "moe"), head_dim=64,
+                     rope_head_dim=16),
+        names=[trunk.ATTENTION_PRODUCTS] * 2 + [trunk.FLASH_RESIDUALS] * 2
+        + [trunk.ATTENTION_PRODUCTS],
+        products=6, kept_width=24 + (16 + 16) + 64),
+}
+
+
+def _kernel_sized(mesh, recipe=olmoe_one_chip, **changes):
+    """A recipe's tiny block at the smallest shape the blocked kernel
+    takes (``_KERNEL_SIZED``)."""
+    _, cfg, _, _ = recipe(mesh, tiny=True)
     cfg = dataclasses.replace(
-        cfg, d_model=256, n_heads=4, seq_len=512,
-        dtype=jnp.bfloat16, param_dtype=jnp.bfloat16, **changes)
+        cfg, seq_len=512, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16,
+        **(_KERNEL_SIZED[recipe]["changes"] | changes))
     model = DMoETransformerLM(cfg, mesh)
     assert model.attn_impl == "flash"
     return model, cfg
 
 
+@pytest.mark.parametrize(
+    "recipe", list(_KERNEL_SIZED), ids=["qkv_projections", "latent_qkv_projections"])
 @pytest.mark.parametrize("formula", ["kept", "parents"])
 def test_remat_recomputes_no_forward_kernel_call(
-    v5e_chip, monkeypatch, formula
+    v5e_chip, monkeypatch, formula, recipe
 ):
     """The gradient of a two-layer stack under ``remat``, compiled for a
     described chip at a small kernel shape: the traced step names the
     kernel's output and its row sums, two arrays a kernel layer, and both
     the traced and the compiled step hold ONE forward call a layer beside
     the fused backward's; under the parent's formula the same count reads two forwards a layer,
-    so the count can tell."""
+    so the count can tell.  The same of the attention part's matrix
+    products (PR 53): the traced step names their results, the compiled
+    step holds none of them under ``rematted_computation`` (in the latent
+    form the three products up from the latents, which are not kept), and
+    under the parent's formula four a layer (six in the latent form)."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     if formula == "parents":
         _the_parents_formula(monkeypatch)
     mesh = Mesh(np.array([v5e_chip]), ("expert",))
-    model, cfg = _kernel_sized(mesh)
+    model, cfg = _kernel_sized(mesh, recipe)
+    sized = _KERNEL_SIZED[recipe]
     assert cfg.remat and cfg.n_layers == 2
     one = NamedSharding(mesh, P())
     shapes = jax.tree_util.tree_map(
@@ -870,15 +904,17 @@ def test_remat_recomputes_no_forward_kernel_call(
     ids = jax.ShapeDtypeStruct((2, cfg.seq_len), jnp.int32, sharding=one)
     traced = jax.jit(jax.value_and_grad(
         lambda p, i, t: model.loss_fn(p, i, t)[0])).trace(shapes, ids, ids)
-    bodies = cfg.n_layers
+    bodies = cfg.n_layers + cfg.mtp_layers
     forwards = bodies * (2 if formula == "parents" else 1)
     named = [eqn.params["name"]
              for _, eqn in probe._equations(traced.jaxpr.jaxpr, "name")]
-    assert named == (
-        [] if formula == "parents" else [trunk.FLASH_RESIDUALS] * 2 * bodies)
+    assert named == ([] if formula == "parents" else sized["names"] * bodies)
     # the output [B, H, S, hd] bf16 and the row sums [B, H, S] float32
     assert probe.kept_residual_bytes(traced.jaxpr.jaxpr) == (
         0 if formula == "parents" else bodies * 2 * 4 * 512 * (64 * 2 + 4))
+    assert probe.kept_residual_bytes(  # bf16 [B, S, the kept width]
+        traced.jaxpr.jaxpr, trunk.ATTENTION_PRODUCTS
+    ) == (0 if formula == "parents" else bodies * 2 * 512 * sized["kept_width"] * 2)
     calls = collections.Counter(
         eqn.params["name"]
         for _, eqn in probe._equations(traced.jaxpr.jaxpr, "pallas_call"))
@@ -888,16 +924,26 @@ def test_remat_recomputes_no_forward_kernel_call(
     with _no_compile_cache():
         text = traced.lower().compile().as_text()
     assert probe.attention_kernel_calls(text) == want
+    kept = sized["names"].count(trunk.ATTENTION_PRODUCTS)
+    assert probe.recomputed_attention_products(text) == bodies * (
+        sized["products"] - (0 if formula == "parents" else kept))
 
 
 @pytest.mark.parametrize("program", ["apply", "cached_prefill"])
 def test_an_undifferentiated_kernel_call_lowers_to_the_parents_text(
     monkeypatch, program
 ):
-    """Outside a checkpoint the name is the identity: the model's forward
+    """Outside a checkpoint a name is the identity: the model's forward
     and the cached decoder's prefill through the kernel lower for the TPU
-    to the text of the parent's formula, letter for letter (the kernel's
-    serialized module with it), so neither is re-keyed or recompiled."""
+    to the operations of the formula that names nothing, one for one and
+    in order (the kernel's serialized module with them).  Since PR 53 the
+    NUMBER at the end of private functions' symbols moves
+    (``@argsort_<n>``, ``@_splash_attention_<n>``, ..): an equation takes
+    its number from the module's symbol table while it is lowered, a
+    ``name`` equation too, and a second kind of ``name`` equation (the
+    products' beside the kernel's) collides with the first.  So a
+    forward-only program is keyed anew in the compile cache once, and
+    computes what it computed."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     mesh = _one_device_mesh()
 
@@ -912,10 +958,22 @@ def test_an_undifferentiated_kernel_call_lowers_to_the_parents_text(
             args = (shapes, ids, 4, 0.0, jax.random.PRNGKey(0))
         return fn.trace(*args).lower(lowering_platforms=("tpu",)).as_text()
 
-    text = lowered()
+    def unnumbered(text):
+        return re.sub(r"(@[A-Za-z_]+)_\d+\b", r"\1", text)
+
+    # both from ONE line: the kernel's serialized module carries its
+    # callers' line numbers, this one's too where the path is short
+    texts = []
+    for formula in (None, _the_parents_formula):
+        if formula is not None:
+            formula(monkeypatch)
+        texts.append(lowered())
+    text, parents = texts
+    assert text != parents  # the numbers below do move: not the same runs
     assert text.count("splash_mha_fwd") >= 2  # a kernel call a layer
-    _the_parents_formula(monkeypatch)
-    assert lowered() == text
+    assert unnumbered(parents) == unnumbered(text)
+    moved = {a for a, b in zip(text.split(), parents.split()) if a != b}
+    assert all(re.match(r"@[A-Za-z_]+_\d+\b", word) for word in moved), moved
 
 
 def test_the_whole_step_holds_one_forward_kernel_call_a_layer(v5e_chip, monkeypatch):
@@ -934,6 +992,11 @@ def test_the_whole_step_holds_one_forward_kernel_call_a_layer(v5e_chip, monkeypa
     assert memory["attention_kernel_calls"] == {
         "splash_mha_fwd_residuals": 4, "splash_mha_dkv_no_residuals": 4}
     assert memory["kept_residual_bytes"] == 4 * 4 * 16 * 4096 * (128 * 2 + 4)
+    # and the results of the attention part's products (PR 53): q, k, v and
+    # the output projection's, bf16 [4, 4096, 4 x 2048] a layer, 1.07 GB;
+    # the backward pass runs none of the four a second time
+    assert memory["kept_product_bytes"] == 4 * 4 * 4096 * (4 * 2048) * 2
+    assert memory["recomputed_attention_products"] == 0
     assert {name: (c["calls"], c["block_q"], c["block_kv"])
             for name, c in memory["attention_kernel_tilings"]["attention"].items()} == {
         "splash_mha_fwd_residuals": (4, 1024, 1024),

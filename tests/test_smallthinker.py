@@ -606,6 +606,11 @@ def test_the_whole_step_fits_the_chip(v5e_chip, monkeypatch):
         "moe_rows_sum": {"calls": 4 * 2, "under_moe_sort": 4, "under_moe_combine": 4},
         "row_gathers": 4 * 5, "row_scatters": 0}
     assert memory["kept_residual_bytes"] == 4 * 28 * 16384 * (128 * 2 + 4)
+    # and the results of the attention part's products (PR 53): q, k, v and
+    # the output projection's, bf16 [16384, 3584 + 512 + 512 + 2560] a
+    # layer, 0.94 GB; the backward pass runs none of the four a second time
+    assert memory["kept_product_bytes"] == 4 * 16384 * (3584 + 2 * 512 + 2560) * 2
+    assert memory["recomputed_attention_products"] == 0
     assert {kind: {name: call["calls"] for name, call in calls.items()}
             for kind, calls in memory["attention_kernel_tilings"].items()} == {
         "global": {"splash_mha_fwd_residuals": 1, "splash_mha_dkv_no_residuals": 1},

@@ -19,7 +19,12 @@ chip; prints the compiler's ``memory_analysis()`` against the chip's
 16,909,334,528 bytes, the tiles each of the step's grouped-matmul
 instructions was compiled at (PERF.md section 3), the blocked attention
 kernel's calls with the blocks and the grid each got, by layer kind, the
-bytes remat keeps of them across the backward pass (PR 38), the scan
+bytes remat keeps of them across the backward pass (PR 38:
+``kept_residual_bytes``), the bytes it keeps of the attention part's matrix
+products (``kept_product_bytes``) and how many of those products the
+compiled step still runs a second time (``recomputed_attention_products``:
+none, or the latent form's three products up from the latents a layer; PR
+53), the scan
 kernel's calls and what remat keeps of those (PR 40), and
 how many products of the head's the compiled loss layer holds (three since
 PR 34).  Nothing runs.
@@ -144,9 +149,11 @@ def kept_residual_bytes(jaxpr, name: str | None = None) -> int:
     """The bytes remat's policy keeps across the backward pass under
     ``name``: the sum of the arrays the traced step names
     ``trunk.FLASH_RESIDUALS`` (the default: the blocked kernel's output
-    and row sums, once a kernel layer, in the forward) or
+    and row sums, once a kernel layer, in the forward),
     ``ssd.SSD_RESIDUALS`` (the scan kernel's output and the states
-    entering its chunks, once a state-space layer).  What they add to the
+    entering its chunks, once a state-space layer) or
+    ``trunk.ATTENTION_PRODUCTS`` (the results of the attention part's
+    matrix products, once an attention layer; PR 53).  What they add to the
     compiled step's live bytes is at most this: the compiler reuses."""
     from learning_at_home_tpu.models.trunk import FLASH_RESIDUALS
 
@@ -154,6 +161,22 @@ def kept_residual_bytes(jaxpr, name: str | None = None) -> int:
         _bytes(eqn.outvars[0].aval) for _, eqn in _equations(jaxpr, "name")
         if eqn.params["name"] == (name or FLASH_RESIDUALS)
     )
+
+
+def recomputed_attention_products(compiled_text: str) -> int:
+    """How many matrix products of the attention part a compiled program
+    runs a second time: its fusions rooted in a ``dot_general`` whose
+    ``op_name`` lies under ``rematted_computation``, the attention scope
+    and one of its products' stages (``proj``, ``out_proj``; the latent
+    form's ``latent_down``, ``latent_up``).  Four a layer under a remat
+    that keeps no product (six in the latent form); none where the policy
+    keeps ``trunk.ATTENTION_PRODUCTS`` (PR 53), but for the latent form's
+    three products up from the latents, which are not named."""
+    return len(re.findall(
+        r'^\s*%\S+ = [^\n]* fusion\([^\n]*op_name="[^"\n]*rematted_computation/'
+        r'(?:[^"\n]*/)?attention/(?:[^"\n]*/)?'
+        r'(?:proj|out_proj|latent_down|latent_up)/[^"\n]*dot_general"',
+        compiled_text, re.M))
 
 
 def scan_kernel_calls(compiled_text: str, kernels: str = "ssd_chunk",
@@ -275,7 +298,12 @@ def step_memory(chip, recipe: str = "smallthinker_one_chip") -> dict:
     kernel's residuals), under ``attention_stages`` which stages of the
     attention part its instructions name (:func:`attention_stages`; PR
     52), under ``kept_residual_bytes`` what that costs
-    (:func:`kept_residual_bytes`), under ``moe_rows_kernel_calls`` the
+    (:func:`kept_residual_bytes`), under ``kept_product_bytes`` the
+    results of the attention part's matrix products that remat keeps
+    beside them and under ``recomputed_attention_products`` how many of
+    those products the compiled step still runs a second time
+    (:func:`recomputed_attention_products`: none, or the latent form's
+    three up a layer; PR 53), under ``moe_rows_kernel_calls`` the
     sorted expert layer's row movements (:func:`moe_rows_kernel_calls`;
     PR 50), under ``scan_kernel_calls`` and
     ``kept_scan_bytes`` the same two for the state-space scan's kernels
@@ -295,6 +323,7 @@ def step_memory(chip, recipe: str = "smallthinker_one_chip") -> dict:
     from jax.sharding import Mesh
 
     import __graft_entry__
+    from learning_at_home_tpu.models.trunk import ATTENTION_PRODUCTS
     from learning_at_home_tpu.ops.ssd import SSD_RESIDUALS
     from learning_at_home_tpu.parallel.mesh import (
         batch_sharding,
@@ -346,6 +375,9 @@ def step_memory(chip, recipe: str = "smallthinker_one_chip") -> dict:
         "attention_stages": attention_stages(text),
         "moe_rows_kernel_calls": moe_rows_kernel_calls(text),
         "kept_residual_bytes": kept_residual_bytes(traced.jaxpr.jaxpr),
+        "kept_product_bytes": kept_residual_bytes(
+            traced.jaxpr.jaxpr, ATTENTION_PRODUCTS),
+        "recomputed_attention_products": recomputed_attention_products(text),
         "scan_kernel_calls": scan_kernel_calls(text),
         "conv_kernel_calls": scan_kernel_calls(text, "ssm_conv", "ssm/conv"),
         "float32_arrays_under_ssm_conv": sorted(set(re.findall(
