@@ -35,11 +35,11 @@ from learning_at_home_tpu.models.trunk import (
     flash_block_sizes,
     gate_activation,
     gated_mlp,
+    gated_qkv_projections,
     latent_qkv_projections,
     layer_norm,
     one_query_attention,
     output_projection,
-    qkv_projections,
     rms_norm,
     ssm_mixer,
 )
@@ -49,10 +49,12 @@ from learning_at_home_tpu.parallel.mesh import batch_sharding
 from learning_at_home_tpu.parallel.sharded_moe import ShardedMixtureOfExperts
 
 Params = Any
-# what a recurrent layer reports beside the stream, and how the stack's
-# layers' readings join in the step's metrics
+# what a layer reports beside the stream and the router's sums (a recurrent
+# layer's extremes, a gate's mean, a share's empty experts), and how the
+# stack's layers' readings join in the step's metrics
 _EXTREMES = {"ssm_decay_min": jnp.min, "delta_decay_min": jnp.min,
-             "delta_beta_max": jnp.max}
+             "delta_beta_max": jnp.max, "attention_gate_mean": jnp.mean,
+             "shared_gate_mean": jnp.mean, "held_experts_empty": jnp.max}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -148,8 +150,18 @@ class DMoETransformerConfig:
     # the gated delta rule (30 heads, keys of 96, values of 192, 4 taps,
     # chunks of 64) to each of 30 heads of 128 with a norm over the whole
     # queries and keys / a dense gated_silu block of width 11,008 in every
-    # layer, no mixture at all (olmo_hybrid_7b_one_chip).
-    # 'layernorm' (scale and bias) or 'rmsnorm' (scale only)
+    # layer, no mixture at all (olmo_hybrid_7b_one_chip);
+    # Qwen3-Next-80B-A3B is rmsnorm_offset (eps 1e-6) / L L L F: three
+    # layers of the gated delta rule (16 key heads under 32 value heads, keys
+    # and values of 128, beta = sigmoid(b), 4 taps, chunks of 64) to each of
+    # 16 heads over 2 key/value heads of 256 with a norm over each head, the
+    # first 64 of a head rotated (theta 1e7), the output gated / in every
+    # layer a shared gated_silu expert of width 512 under a gate of its own
+    # beside experts of the same width, 10 of 512 by softmax, renormalised /
+    # dropless, a share held (qwen3_next_one_chip).
+    # 'layernorm' (scale and bias), 'rmsnorm' (scale only) or
+    # 'rmsnorm_offset' (Qwen3-Next: the multiplier is 1 + w, w zero from the
+    # seed; every norm of the stack but the delta rule's gate-and-norm)
     norm: str = "layernorm"
     norm_eps: float = 1e-5
     # where a part's norm sits: 'input', x + Part(norm(x)); or 'output',
@@ -176,6 +188,13 @@ class DMoETransformerConfig:
     kv_latent_dim: int | None = None
     q_latent_dim: int | None = None
     rope_head_dim: int | None = None
+    # the plain projections rotate the FIRST rotary_dim columns of a head
+    # alone (Qwen3-Next's partial_rotary_factor: 64 of 256); None = all
+    rotary_dim: int | None = None
+    # a softmax layer's output is multiplied by sigmoid(gate) before wo, the
+    # gate a second half of wq's columns a head (Qwen3-Next's full-attention
+    # layers, trunk.gated_qkv_projections)
+    attention_gate: bool = False
     # where the layers of the stack differ: one AttentionLayer a layer,
     # or one period of them, repeated; None = every layer global, rotated
     # where positions == 'rope'
@@ -211,6 +230,9 @@ class DMoETransformerConfig:
     # (None = shared_experts * expert_ffn_dim)
     shared_experts: int = 0
     shared_expert_dim: int | None = None
+    # the shared expert's output is multiplied by sigmoid(x w_g), one number
+    # a token (Qwen3-Next's shared_expert_gate)
+    shared_expert_gate: bool = False
     # 'softmax': gates from a softmax over all experts; 'sigmoid': every
     # expert scored on its own, the k largest renormalised (renormalize)
     # and multiplied by routed_scale
@@ -263,6 +285,13 @@ class DMoETransformerConfig:
     delta_value_dim: int | None = None
     delta_conv_kernel: int = 4
     delta_chunk: int = 64
+    # the rule's value heads where they are more than its n_heads query/key
+    # heads (Qwen3-Next: 32 over 16, value head j reading query/key head
+    # j // 2); None = n_heads
+    delta_value_heads: int | None = None
+    # beta = 2 sigmoid(b) (Olmo-Hybrid's linear_allow_neg_eigval) or, False,
+    # sigmoid(b) (Qwen3-Next)
+    delta_neg_eigval: bool = True
 
     def mixture_layers(self) -> int:
         """How many of the stack's layers route (hold a mixture)."""
@@ -318,9 +347,10 @@ class DMoETransformerLM:
     """Functional model: explicit param pytree, jit/pjit-friendly apply."""
 
     def __init__(self, config: DMoETransformerConfig, mesh: Mesh):
-        if config.norm not in ("layernorm", "rmsnorm"):
+        if config.norm not in ("layernorm", "rmsnorm", "rmsnorm_offset"):
             raise ValueError(
-                f"norm must be 'layernorm' or 'rmsnorm', got {config.norm!r}"
+                f"norm must be 'layernorm', 'rmsnorm' or 'rmsnorm_offset', "
+                f"got {config.norm!r}"
             )
         if config.positions not in ("learned", "rope"):
             raise ValueError(
@@ -347,6 +377,11 @@ class DMoETransformerLM:
         if self._delta and None in (config.delta_key_dim, config.delta_value_dim):
             raise ValueError(
                 "a 'delta' layer needs delta_key_dim and delta_value_dim"
+            )
+        if self._delta and (config.delta_value_heads or 0) % config.n_heads:
+            raise ValueError(
+                f"delta_value_heads={config.delta_value_heads} must be a "
+                f"multiple of the rule's {config.n_heads} key heads (n_heads)"
             )
         if self._delta and config.seq_parallel:
             raise NotImplementedError(
@@ -447,6 +482,23 @@ class DMoETransformerLM:
                     f"rope_head_dim={config.rope_head_dim} is the rotated, "
                     f"even part of head_dim={config.head_dim}"
                 )
+        if (config.rotary_dim is not None or config.attention_gate) and (
+            config.kv_latent_dim is not None or config.qk_norm is True
+            or config.seq_parallel
+        ):
+            raise ValueError(
+                "rotary_dim and attention_gate belong to the plain "
+                "projections with no norm or one over each head: no latent "
+                "attention, no norm over the whole queries (qk_norm=True), "
+                "no ring (seq_parallel: its projections hand no gate over)"
+            )
+        if config.shared_expert_gate and (
+            not config.shared_experts or config.norm_place != "input"
+        ):
+            raise ValueError(
+                "shared_expert_gate gates a shared expert (shared_experts) "
+                "behind a norm on the part's input"
+            )
         if config.mtp_layers not in (0, 1):
             raise ValueError(
                 f"mtp_layers must be 0 or 1, got {config.mtp_layers}: one "
@@ -576,9 +628,15 @@ class DMoETransformerLM:
         k_embed, k_pos, k_head, k_layers = jax.random.split(rng, 4)
         pdt = cfg.param_dtype
 
+        def rms(width):
+            """An RMSNorm's parameter: what it holds says which it is."""
+            if cfg.norm == "rmsnorm_offset":
+                return {"offset": jnp.zeros((width,), pdt)}
+            return {"scale": jnp.ones((width,), pdt)}
+
         def ln():
-            if cfg.norm == "rmsnorm":
-                return {"scale": jnp.ones((d,), pdt)}
+            if cfg.norm != "layernorm":
+                return rms(d)
             return {"scale": jnp.ones((d,), pdt), "bias": jnp.zeros((d,), pdt)}
 
         def dense_block(key, width):
@@ -599,6 +657,8 @@ class DMoETransformerLM:
                     cfg.shared_expert_dim
                     or cfg.shared_experts * self.moe.ffn_dim,
                 )
+            if cfg.shared_expert_gate:
+                held["shared_gate"] = dense(jax.random.fold_in(key, 2), (d, 1), pdt)
             return held
 
         def ssm(key):
@@ -636,10 +696,11 @@ class DMoETransformerLM:
             b | a]; the filters of q, k and v lecun-normal over their taps
             and no bias; ``A_log`` and ``dt_bias`` drawn as the
             state-space mixer's (float32 whatever the parameters' dtype,
-            as the decays' arithmetic is); one output-norm scale of a
-            head's value size, shared by the heads."""
-            h = cfg.n_heads
-            d_qk, d_v = 2 * h * cfg.delta_key_dim, h * cfg.delta_value_dim
+            as the decays' arithmetic is), one of each a VALUE head; one
+            output-norm scale of a head's value size, shared by the heads
+            (the plain form, scale 1, whatever ``cfg.norm``)."""
+            h = cfg.delta_value_heads or cfg.n_heads
+            d_qk, d_v = 2 * cfg.n_heads * cfg.delta_key_dim, h * cfg.delta_value_dim
             k_in, k_conv, k_dt, k_a, k_out = jax.random.split(key, 5)
             low, high = np.log(cfg.ssm_dt_range)
             dt = jnp.exp(jax.random.uniform(k_dt, (h,), minval=low, maxval=high))
@@ -677,7 +738,9 @@ class DMoETransformerLM:
                 attention = {"delta": delta(ks[0])}
             elif cfg.kv_latent_dim is None:
                 attention = {
-                    "wq": dense(ks[0], (d, d_q), pdt),
+                    # gated: a head's columns are its query's, then its gate's
+                    "wq": dense(
+                        ks[0], (d, 2 * d_q if cfg.attention_gate else d_q), pdt),
                     "wk": dense(ks[1], (d, d_kv), pdt),
                     "wv": dense(ks[2], (d, d_kv), pdt),
                     "wo": dense(ks[3], (d_q, d), pdt),
@@ -707,8 +770,8 @@ class DMoETransformerLM:
                 lp.update(mixture(ks[4]))
             if cfg.qk_norm and kind.mixer != "delta":
                 per_head = cfg.qk_norm == "head"
-                lp["q_norm"] = {"scale": jnp.ones((hd if per_head else d_q,), pdt)}
-                lp["k_norm"] = {"scale": jnp.ones((hd if per_head else d_kv,), pdt)}
+                lp["q_norm"] = rms(hd if per_head else d_q)
+                lp["k_norm"] = rms(hd if per_head else d_kv)
             return lp
 
         layer_keys = jax.random.split(k_layers, cfg.n_layers)
@@ -758,22 +821,24 @@ class DMoETransformerLM:
     # ---- forward ----
 
     def _norm(self, p, x):
-        if self.cfg.norm == "rmsnorm":
+        if self.cfg.norm != "layernorm":
             return rms_norm(p, x, self.cfg.norm_eps)
         return layer_norm(p, x, self.cfg.norm_eps)
 
     def _qkv(self, lp, x, positions, rotary: bool):
         """Finished q, k, v of a layer whose tokens sit at ``positions``
         [S] (read only where the layer is ``rotary``): what every
-        attention core (xla, flash, ring, one-query) takes."""
-        project = (
-            latent_qkv_projections if "wkv_a" in lp else qkv_projections
-        )
-        return project(
-            lp, x, self.cfg.n_heads,
+        attention core (xla, flash, ring, one-query) takes; fourth, the
+        output's gate before its sigmoid, or None where the layer has none
+        (``trunk.gated_qkv_projections``: ``wq``'s width says)."""
+        how = dict(
             positions=jnp.asarray(positions, jnp.int32) if rotary else None,
             rope_theta=self.cfg.rope_theta, norm_eps=self.cfg.norm_eps,
         )
+        if "wkv_a" in lp:
+            return *latent_qkv_projections(lp, x, self.cfg.n_heads, **how), None
+        return gated_qkv_projections(
+            lp, x, self.cfg.n_heads, rotary_dim=self.cfg.rotary_dim, **how)
 
     def _layer(self, lp, x, layer_idx, token_mask, kind: AttentionLayer):
         """One block.  ``kind`` (static) is the layer's attention,
@@ -787,8 +852,7 @@ class DMoETransformerLM:
         if "delta" in lp:
             x, attn_in, extremes = self._delta_block(lp, x)
         else:
-            x, attn_in = self._attention_block(lp, x, kind)
-            extremes = {}
+            x, attn_in, extremes = self._attention_part(lp, x, kind)
         if one_mixer:
             return x, None
         x, aux = self._ffn_block(lp, x, attn_in, layer_idx, token_mask)
@@ -817,7 +881,7 @@ class DMoETransformerLM:
             mixer_in = self._part_input(lp["ln1"], x)
             out, _, decay_min, beta_max = delta_mixer(
                 lp["delta"], mixer_in, cfg.n_heads, cfg.delta_chunk,
-                cfg.norm_eps,
+                cfg.norm_eps, neg_eigval=cfg.delta_neg_eigval,
             )
             x = self._add_part(lp["ln1"], x, out)
         return x, mixer_in, {
@@ -837,6 +901,11 @@ class DMoETransformerLM:
     def _attention_block(self, lp, x, kind: AttentionLayer):
         """The stream after the layer's attention, and the input the
         attention read (a router placed before it reads that)."""
+        return self._attention_part(lp, x, kind)[:2]
+
+    def _attention_part(self, lp, x, kind: AttentionLayer):
+        """:meth:`_attention_block` and, third, what the step's metrics
+        keep of the layer: the mean of a gated output's gate."""
         s = x.shape[1]
         # where a stack has both kinds, the scope says which this one is
         scope = "attention" if self.cfg.layer_pattern is None else (
@@ -847,22 +916,27 @@ class DMoETransformerLM:
             norm_p = lp["ln1" if "ln1" in lp else "norm"]
             with jax.named_scope("norm"):
                 attn_in = self._part_input(norm_p, x)
-            q, k, v = self._qkv(
-                lp, attn_in,
-                # under the zigzag ring the stream is in zigzag order
-                np.arange(s) if self._zig is None else self._zig,
-                kind.rotary,
-            )
             core = self._ring if self._ring is not None else (
                 lambda q, k, v: attention_core(
                     q, k, v, self.attn_impl, kind.window
                 )
             )
-            out = output_projection(lp, core(q, k, v))
+            q, k, v, gate = self._qkv(
+                lp, attn_in,
+                # under the zigzag ring the stream is in zigzag order
+                np.arange(s) if self._zig is None else self._zig,
+                kind.rotary,
+            )
+            out = output_projection(lp, core(q, k, v), gate)
+            extremes = {}
+            if gate is not None:
+                with jax.named_scope("gate"):
+                    extremes["attention_gate_mean"] = jnp.mean(
+                        jax.nn.sigmoid(gate.astype(jnp.float32)))
             with jax.named_scope("norm"):  # the norm, wherever it is placed
                 out = self._part_output(norm_p, out)
             x = x + out  # directly under the attention scope
-        return x, attn_in
+        return x, attn_in, extremes
 
     @staticmethod
     def _ffn_norm(lp):
@@ -895,7 +969,18 @@ class DMoETransformerLM:
         )
         if self.cfg.norm_place == "input":
             x = x + moe_out.reshape(b, s, d)
-            if "shared" in lp:
+            if "shared_gate" in lp:
+                with jax.named_scope("shared_expert"):
+                    shared = gated_mlp(lp["shared"], ffn_in, self._gate_act)
+                    with jax.named_scope("shared_gate"):
+                        # one number a token, float32 as the router's are
+                        gate = jax.nn.sigmoid(jnp.einsum(
+                            "bsd,dn->bsn", ffn_in,
+                            lp["shared_gate"].astype(ffn_in.dtype),
+                            preferred_element_type=jnp.float32))
+                        x = x + (gate * shared.astype(jnp.float32)).astype(x.dtype)
+                        aux = {**aux, "shared_gate_mean": jnp.mean(gate)}
+            elif "shared" in lp:
                 with jax.named_scope("shared_expert"):
                     x = x + gated_mlp(lp["shared"], ffn_in, self._gate_act)
             return x, aux
@@ -1175,6 +1260,17 @@ class DMoETransformerLM:
                     "(and its convolution's last inputs) beside the KV "
                     "cache is not built; decode without the cache"
                 )
+            if (
+                self.cfg.attention_gate or self.cfg.rotary_dim is not None
+                or self.cfg.shared_expert_gate
+            ):
+                raise NotImplementedError(
+                    "use_cache=True with attention_gate, rotary_dim or "
+                    "shared_expert_gate: the KV-cache decoder's attention "
+                    "block multiplies no gate onto its output and rotates "
+                    "whole heads, and its feed-forward part has no gated "
+                    "shared expert; decode without the cache"
+                )
             if self.cfg.kv_latent_dim is not None or self.cfg.mtp_layers:
                 raise NotImplementedError(
                     "use_cache=True: the KV-cache decoder keeps whole keys "
@@ -1324,7 +1420,7 @@ class DMoETransformerLM:
         for i in range(cfg.n_layers):
             lp = params["layers"][i]
             h = self._norm(lp["ln1"], x)
-            q, k, v = self._qkv(
+            q, k, v, _ = self._qkv(
                 lp, h, np.arange(p), cfg.attention_layer(i).rotary
             )
             # same impl as the full forward: the parity guarantee vs the
@@ -1365,7 +1461,7 @@ class DMoETransformerLM:
             for i in range(cfg.n_layers):
                 lp = params["layers"][i]
                 h = self._norm(lp["ln1"], x)
-                q, k, v = self._qkv(
+                q, k, v, _ = self._qkv(
                     lp, h, t[None], cfg.attention_layer(i).rotary
                 )
                 k_caches[i] = jax.lax.dynamic_update_slice(

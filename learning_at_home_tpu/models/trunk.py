@@ -29,10 +29,21 @@ def layer_norm(p: dict, x: jax.Array, eps: float = 1e-5) -> jax.Array:
 
 
 def rms_norm(p: dict, x: jax.Array, eps: float = 1e-5) -> jax.Array:
-    """RMSNorm in float32 (no mean, no bias), cast back to the input dtype."""
+    """RMSNorm in float32 (no mean, no bias), cast back to the input dtype.
+    What ``p`` holds says which: ``scale``, the multiplier itself, or
+    ``offset``, a multiplier of ``1 + offset`` (Qwen3-Next's norms: the
+    parameter starts at zero)."""
     x32 = x.astype(jnp.float32)
     ms = jnp.mean(x32 * x32, axis=-1, keepdims=True)
+    if "offset" in p:
+        return (x32 * jax.lax.rsqrt(ms + eps)
+                * (1.0 + p["offset"].astype(jnp.float32))).astype(x.dtype)
     return (x32 * jax.lax.rsqrt(ms + eps) * p["scale"]).astype(x.dtype)
+
+
+def norm_width(p: dict) -> int:
+    """How many channels an RMSNorm's parameter spans."""
+    return p["offset" if "offset" in p else "scale"].shape[-1]
 
 
 def rotary(
@@ -54,6 +65,18 @@ def rotary(
     ).astype(x.dtype)
 
 
+def rotary_first(
+    x: jax.Array, positions: jax.Array, theta: float, rotary_dim: int
+) -> jax.Array:
+    """:func:`rotary` of the FIRST ``rotary_dim`` columns of each head
+    (pairs ``(j, j + rotary_dim / 2)``, frequencies over ``rotary_dim``);
+    the other columns pass as they are, to the bit (Qwen3-Next: 64 of a
+    head's 256)."""
+    return jnp.concatenate(
+        [rotary(x[..., :rotary_dim], positions, theta), x[..., rotary_dim:]],
+        axis=-1)
+
+
 # The name the attention part gives the results of its matrix products:
 # ``x @ wq``, ``x @ wk``, ``x @ wv`` and ``out @ wo``; in the latent form
 # the two products down to the latents and ``out @ wo``.  The backward
@@ -73,6 +96,7 @@ def qkv_projections(
     lp: dict, x: jax.Array, n_heads: int,
     positions: jax.Array | None = None,
     rope_theta: float = 10000.0, norm_eps: float = 1e-5,
+    rotary_dim: int | None = None,
 ):
     """Shared Q/K/V projections: [B,S,d] → q [B,S,H,hd] and k, v
     [B,S,Hkv,hd], finished for any attention core.  The block's own
@@ -84,21 +108,46 @@ def qkv_projections(
     what its scale spans: the WHOLE projection, before the split into
     heads (OLMoE: a scale as wide as the projection), or each head's own
     ``hd`` (one scale of ``hd`` shared by the heads); ``positions`` [S] →
-    rotary embedding of q and k after it."""
+    rotary embedding of q and k after it, of the whole head or of its
+    first ``rotary_dim`` columns.  A layer whose ``wq`` is twice as wide as
+    ``wo`` is tall has a GATED output (:func:`gated_qkv_projections` hands
+    the gate over; here it is left out)."""
+    return gated_qkv_projections(
+        lp, x, n_heads, positions, rope_theta, norm_eps, rotary_dim)[:3]
+
+
+def gated_qkv_projections(
+    lp: dict, x: jax.Array, n_heads: int,
+    positions: jax.Array | None = None,
+    rope_theta: float = 10000.0, norm_eps: float = 1e-5,
+    rotary_dim: int | None = None,
+):
+    """:func:`qkv_projections` and, fourth, the output's gate [B,S,H,hd]
+    before its sigmoid, or None: where ``wq`` is twice as wide as ``wo`` is
+    tall (Qwen3-Next's full-attention layers), a head's ``2 hd`` columns of
+    ``x @ wq`` are its query's ``hd`` then its gate's ``hd``, one product
+    for both; the norm and the rotation are the query's alone, and
+    :func:`output_projection` multiplies ``sigmoid(gate)`` onto the core's
+    output."""
     b, s, _ = x.shape
-    hd = lp["wq"].shape[-1] // n_heads
+    gated = "wo" in lp and lp["wq"].shape[-1] == 2 * lp["wo"].shape[0]
+    hd = lp["wq"].shape[-1] // (2 * n_heads if gated else n_heads)
 
-    def heads(y: jax.Array) -> jax.Array:
-        return y.reshape(b, s, y.shape[-1] // hd, hd)
+    def heads(y: jax.Array, size: int = hd) -> jax.Array:
+        return y.reshape(b, s, y.shape[-1] // size, size)
 
-    def project(w: str, norm: str | None) -> jax.Array:
+    def project(w: str, norm: str | None, size: int = hd) -> tuple:
+        """``(x @ lp[w]``, named; whether ``norm`` spans it whole)``."""
         with jax.named_scope("proj"):
             y = x @ lp[w].astype(x.dtype)
-            whole = norm in lp and lp[norm]["scale"].shape[-1] == y.shape[-1]
+            whole = norm in lp and norm_width(lp[norm]) == y.shape[-1]
             # named in the shape its reader takes it (the whole
             # projection's norm; else a head's norm or the kernel), so
             # that the product writes the kept array in that layout
-            y = checkpoint_name(y if whole else heads(y), ATTENTION_PRODUCTS)
+            return checkpoint_name(
+                y if whole else heads(y, size), ATTENTION_PRODUCTS), whole
+
+    def finish(y: jax.Array, whole: bool, norm: str | None) -> jax.Array:
         if norm in lp:
             with jax.named_scope("qk_norm"):
                 y = rms_norm(lp[norm], y, norm_eps)
@@ -107,14 +156,25 @@ def qkv_projections(
                 y = heads(y)
         return y
 
-    q = project("wq", "q_norm")
-    k = project("wk", "k_norm")
-    v = project("wv", None)
+    gate = None
+    if gated:  # a head's columns: [query | gate]
+        q, whole = project("wq", None, 2 * hd)
+        with jax.named_scope("gate"):
+            q, gate = q[..., :hd], q[..., hd:]
+        q = finish(q, whole, "q_norm")
+    else:
+        q = finish(*project("wq", "q_norm"), "q_norm")
+    k = finish(*project("wk", "k_norm"), "k_norm")
+    v = finish(*project("wv", None), None)
     if positions is not None:
         with jax.named_scope("rope"):
-            q = rotary(q, positions, rope_theta)
-            k = rotary(k, positions, rope_theta)
-    return q, k, v
+            if rotary_dim is None:
+                q = rotary(q, positions, rope_theta)
+                k = rotary(k, positions, rope_theta)
+            else:
+                q = rotary_first(q, positions, rope_theta, rotary_dim)
+                k = rotary_first(k, positions, rope_theta, rotary_dim)
+    return q, k, v, gate
 
 
 def latent_qkv_projections(
@@ -167,9 +227,17 @@ def latent_qkv_projections(
     return q, k, v
 
 
-def output_projection(lp: dict, out: jax.Array) -> jax.Array:
-    """[B,S,H,hd] → [B,S,d] @ wo.  Scope ``out_proj``."""
+def output_projection(
+    lp: dict, out: jax.Array, gate: jax.Array | None = None
+) -> jax.Array:
+    """[B,S,H,hd] → [B,S,d] @ wo.  Scope ``out_proj``.  With ``gate``
+    [B,S,H,hd] (:func:`gated_qkv_projections`) the core's output is
+    multiplied by ``sigmoid(gate)`` first, in float32 (scope ``gate``)."""
     b, s, h, hd = out.shape
+    if gate is not None:
+        with jax.named_scope("gate"):
+            out = (out.astype(jnp.float32) * jax.nn.sigmoid(
+                gate.astype(jnp.float32))).astype(out.dtype)
     with jax.named_scope("out_proj"):
         return checkpoint_name(
             out.reshape(b, s, h * hd) @ lp["wo"].astype(out.dtype),
@@ -266,13 +334,21 @@ def ssm_mixer(
 
 def delta_mixer(
     p: dict, x: jax.Array, n_heads: int, chunk: int, eps: float = 1e-5,
-    decay_dtype=jnp.float32,
+    decay_dtype=jnp.float32, neg_eigval: bool = True,
 ):
     """The gated delta-rule mixer (Gated DeltaNet, arXiv:2412.06464) on the
     stream ``x`` [B, S, d] as the layer hands it over: ``(out [B, S, d],
     the recurrent state after the last position [B, H, dk, dv] float32,
     the smallest decay alpha any position saw, the largest write strength
     beta)``.
+
+    ``n_heads`` is the heads of ``q`` and ``k``; ``v``, ``z``, ``b`` and
+    ``a`` have as many heads as ``b`` has columns, the same or a multiple
+    (Qwen3-Next: 32 value heads over 16 key heads), and value head ``j``
+    reads query/key head ``j // (Hv / H)``: the convolved ``q`` and ``k``
+    are repeated a value head each before the rule, which runs over the
+    value heads (the gradient's sum over a key head's value heads is
+    autodiff's).
 
     ``[q | k | v | z | b | a] = x W_in`` (no bias; ``b`` and ``a`` in
     float32, the rest in ``x``'s dtype); ``q, k, v =
@@ -284,7 +360,8 @@ def delta_mixer(
     sqrt(sum k^2 + 1e-6)`` (:func:`~learning_at_home_tpu.ops.delta_rule.
     unit_length`, which the rule applies); ``beta = 2 sigmoid(b)`` (the 2 lets the
     transition ``alpha (I - beta k k^T)`` have a negative eigenvalue,
-    arXiv:2411.12537); ``g = -exp(A_log) softplus(a + dt_bias)``, float32,
+    arXiv:2411.12537; ``neg_eigval`` False: ``beta = sigmoid(b)``); ``g =
+    -exp(A_log) softplus(a + dt_bias)``, float32,
     ``alpha = exp(g)``; the recurrence ``S_t = alpha_t S_{t-1} + beta_t
     k_t (v_t - (alpha_t S_{t-1})^T k_t)^T``, ``o_t = S_t^T q_t`` in chunks
     of ``chunk`` (:func:`~learning_at_home_tpu.ops.delta_rule.
@@ -296,15 +373,17 @@ def delta_mixer(
     gate (Mamba-2's mixer gates first; :func:`~learning_at_home_tpu.ops.
     gate_norm.gated_rms_norm` in its other order, ``z`` read at its column
     of the in-projection); ``out = y W_out``.  The parameters
-    say the sizes: a head's value size ``dv`` is ``w_out``'s input width
-    over ``n_heads``, its key size ``dk`` what the convolution's channels
-    leave beyond ``v`` over ``2 H``.  Sub-scopes ``in_proj``, ``conv``,
+    say the sizes: the value heads ``Hv`` are half of what ``w_in`` gives
+    beyond ``q``, ``k``, ``v`` and ``z``, a head's value size ``dv``
+    ``w_out``'s input width over ``Hv``, its key size ``dk`` what the
+    convolution's channels leave beyond ``v`` over ``2 H``.  Sub-scopes ``in_proj``, ``conv``,
     ``core``, ``gate_norm``, ``out_proj``."""
     b, s, _ = x.shape
     f32 = jnp.float32
     d_v = p["w_out"].shape[0]
     d_qk = p["conv_w"].shape[0] - d_v  # q's and k's channels together
-    dk, dv = d_qk // (2 * n_heads), d_v // n_heads
+    n_value = (p["w_in"].shape[-1] - d_qk - 2 * d_v) // 2
+    dk, dv = d_qk // (2 * n_heads), d_v // n_value
     with jax.named_scope("in_proj"):
         w_in = p["w_in"].astype(x.dtype)
         proj = x @ w_in
@@ -321,14 +400,18 @@ def delta_mixer(
         v = causal_conv_silu(proj, p["conv_w"][d_qk:], None, first=d_qk)
     with jax.named_scope("core"):
         qk = qk.reshape(b, s, 2, n_heads, dk)
-        beta = 2.0 * jax.nn.sigmoid(write)
+        if n_value != n_heads:  # value head j reads key head j // ratio
+            qk = jnp.repeat(qk, n_value // n_heads, axis=3)
+        beta = jax.nn.sigmoid(write)
+        if neg_eigval:
+            beta = 2.0 * beta
         g = -jnp.exp(p["A_log"].astype(f32)) * jax.nn.softplus(
             step + p["dt_bias"].astype(f32))
         # the rule makes q and k unit-length itself (float32, rounded to
         # x's dtype): its kernel does so in VMEM, on what the convolution
         # wrote
         o, state = gated_delta_chunked(
-            qk[:, :, 0], qk[:, :, 1], v.reshape(b, s, n_heads, dv), g, beta,
+            qk[:, :, 0], qk[:, :, 1], v.reshape(b, s, n_value, dv), g, beta,
             chunk, decay_dtype, unit=True)
         decay_min, beta_max = jnp.exp(jnp.min(g)), jnp.max(beta)
     with jax.named_scope("gate_norm"):  # z read where the product left it

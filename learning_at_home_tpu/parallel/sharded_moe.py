@@ -365,6 +365,8 @@ class ShardedMixtureOfExperts:
                 aux_names += ("local_rows_over_level",)
             if self.router_bias:
                 aux_names += ("expert_counts", "router_bias_abs_max")
+            elif share:  # no counts a step to read the empty experts off
+                aux_names += ("held_experts_empty",)
             return shard_map(
                 self._local_forward_share if share
                 else self._local_forward_dropless,
@@ -662,4 +664,7 @@ class ShardedMixtureOfExperts:
         }
         if self.router_bias:
             aux.update(self._bias_aux(params, plan.counts))
+        else:  # a share with no bias to level: its load is what the data gives
+            aux["held_experts_empty"] = jax.lax.pmax(
+                jnp.sum(plan.group_sizes == 0).astype(jnp.float32), axes)
         return y, aux

@@ -73,6 +73,20 @@ def _olmo_hybrid_lines(setup, counters, reference):
     assert len(reference["delta_layers_rms"]) == len(reference["delta_states_rms"]) == 6
 
 
+def _qwen3next_lines(setup, counters, reference):
+    assert setup["expert_param_bytes"] > 0 and "level_router_bias" in setup["phases"]
+    assert {"dropped_fraction", "held_experts_empty", "delta_decay_min",
+            "delta_beta_max", "attention_gate_mean", "shared_gate_mean"} <= set(counters)
+    assert counters["delta_beta_max"]["max"] <= 1.0  # sigmoid(b): no factor 2
+    assert len(reference["delta_layers_rms"]) == len(reference["delta_states_rms"]) == 3
+    assert len(reference["attention_layers_rms"]) == 1
+    assert len(reference["router_logits_layers_rms"]) == 4  # every layer routes
+    # the backward pass and the update, float32 here: every leaf of a period
+    assert len(reference["grad_stream_layers_rms"]) == 5
+    for name in ("grads_rms", "grad_stream_rms", "step_grad_norms", "update_norm"):
+        assert 0.0 <= reference[name] < 1e-4, (name, reference[name])
+
+
 class Row(NamedTuple):
     cell: str
     config: str
@@ -112,6 +126,13 @@ ROWS = (
         ("mfu", "delta_share", "delta_core_share", "delta_core_roofline",
          "attention_core_roofline", "delta_gate_norm_share"),
         ("step_ms_p50",), 0, _olmo_hybrid_lines),  # no mixture layer to level
+    Row("qwen3-next-80b-a3b-train-zipf16k", "qwen3-next-80b-a3b", "train-zipf16k",
+        1, "manifest_qwen3next.json", 5500000007, 22,
+        ("mfu", "delta_share", "delta_core_share", "delta_core_roofline",
+         "attention_core_roofline", "expert_matmul_roofline",
+         "delta_gate_norm_share", "attention_gate_share", "shared_expert_share"),
+        ("step_ms_p50", "expert_load_max_over_mean", "local_rows_over_level"),
+        0, _qwen3next_lines),  # a share with no selection bias: nothing to level
 )
 
 

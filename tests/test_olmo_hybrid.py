@@ -408,7 +408,7 @@ def test_the_norm_on_the_output_of_a_mixture_layer_spans_all_the_part_gave():
 
     @jax.jit
     def by_hand(lp, x):
-        q, k, v = model._qkv(lp, x, np.arange(cfg.seq_len), kind.rotary)
+        q, k, v, _ = model._qkv(lp, x, np.arange(cfg.seq_len), kind.rotary)
         h = x + model._norm(lp["ln1"], trunk.output_projection(
             lp, trunk.attention_core(q, k, v, "xla", kind.window)))
         routed, _ = model.moe(

@@ -33,6 +33,7 @@ from __graft_entry__ import (  # noqa: E402
     nemotron_labs_twotower_one_chip,
     olmo_hybrid_7b_one_chip,
     olmoe_one_chip,
+    qwen3_next_one_chip,
     smallthinker_one_chip,
 )
 from learning_at_home_tpu.models import transformer, trunk  # noqa: E402
@@ -181,8 +182,9 @@ MUTATIONS = {
     "renormalised_top8": ({"renormalize": True}, None, None),
     "no_qk_norm": ({"qk_norm": False}, _strip_qk_norm, None),
     "rotary_off": ({}, None, (trunk, "rotary", lambda x, positions, theta: x)),
-    "per_head_qk_norm": (
-        {}, None, (transformer, "qkv_projections", _per_head_norm_projections)
+    "per_head_qk_norm": (  # what _qkv calls; the fourth result is the gate
+        {}, None, (transformer, "gated_qkv_projections", lambda *args, **how: (
+            *_per_head_norm_projections(*args, **how), None))
     ),
     "gelu_for_silu": ({}, None, (jax.nn, "silu", jax.nn.gelu)),
 }
@@ -589,6 +591,12 @@ NEMOTRON_TINY_STEP_SHA256 = (
 OLMO_HYBRID_TINY_STEP_SHA256 = (
     "34383f25753571f7f98b0735a82b045f784e5e1f03d7603259aa62623a3db7fb"
 )
+# first taken on PR 55's tree, which brought the recipe (the eight above are
+# as PR 53 left them: this model's fields changed no other program); its tiny
+# preset is ONE period of the stack (two were 5d41bf4d...)
+QWEN3_NEXT_TINY_STEP_SHA256 = (
+    "0e9b8ba91a6b316bdcc7c6daec550e4ce3f0e28789508693aa777546a9ec2640"
+)
 
 
 @pytest.mark.parametrize(
@@ -602,10 +610,11 @@ OLMO_HYBRID_TINY_STEP_SHA256 = (
         (glm_4_7_flash_one_chip, {"expert": 1}, GLM_4_7_FLASH_TINY_STEP_SHA256),
         (nemotron_labs_twotower_one_chip, {"expert": 1}, NEMOTRON_TINY_STEP_SHA256),
         (olmo_hybrid_7b_one_chip, {"expert": 1}, OLMO_HYBRID_TINY_STEP_SHA256),
+        (qwen3_next_one_chip, {"expert": 1}, QWEN3_NEXT_TINY_STEP_SHA256),
     ],
     ids=["dmoe-one-chip", "dmoe-pod4", "olmoe-one-chip", "smallthinker-one-chip",
          "k-exaone-one-chip", "glm-4.7-flash-one-chip", "nemotron-one-chip",
-         "olmo-hybrid-one-chip"],
+         "olmo-hybrid-one-chip", "qwen3-next-one-chip"],
 )
 def test_dmoe256_lowered_step_is_text_identical_to_the_parents(
     recipe, axes, sha256

@@ -36,6 +36,7 @@ def swarm():
     import os
     import subprocess
     import sys
+    import tempfile
     import time
 
     from learning_at_home_tpu.client import RemoteExpert
@@ -45,6 +46,9 @@ def swarm():
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = clean_jax_subprocess_env(repo, platform="cpu")
     port = 43311
+    # a file, not a pipe: nobody reads while the server runs, and a full
+    # pipe stops it (XLA's loader writes 2 KB a cached program on some hosts)
+    log = tempfile.TemporaryFile(mode="w+")
     proc = subprocess.Popen(
         [
             sys.executable, "-m", "learning_at_home_tpu.server",
@@ -53,7 +57,7 @@ def swarm():
             "--optimizer", "adam", "--lr", "1e-3",
             "--max-batch-size", "2048", "--warmup", "32", "64",
         ],
-        env=env, stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True,
+        env=env, stdout=log, stderr=subprocess.STDOUT, text=True,
     )
     uids = ["ffn0.0", "ffn0.1"]
     endpoint = ("127.0.0.1", port)
@@ -62,7 +66,8 @@ def swarm():
     up = False
     while time.time() < deadline:
         if proc.poll() is not None:
-            raise AssertionError(f"server died: {proc.stdout.read()[-2000:]}")
+            log.seek(0)
+            raise AssertionError(f"server died: {log.read()[-2000:]}")
         try:
             probe.info()
             up = True

@@ -41,7 +41,13 @@ tolerances must refuse (PERF.md section 2).  bf16 operands follow, which
 they must pass.  Where the runner's comparison takes a ``decay_dtype``
 (``train_recipe_hybrid``: a state-space scan), one more reading follows:
 the PROGRAM with its scan's decays computed in bf16, which the state-space
-layer's limits must refuse.
+layer's limits must refuse.  Where the runner names ``WRONG_PROGRAMS``
+(``train_recipe_qwen3next``: bf16 decays, a bf16 router, either gate
+missing; a ``_hidden`` of another rule, a step on half the loss, a step
+that leaves a leaf as it was), each is read in the program's place too (PR
+55).  Words after the seeds choose the readings whose names hold one of
+them (``float8 config.json 7 float8 _hidden "the step"``); the program
+itself is always read.
 
 ``ssd`` (on the chip): the chunked scan of ``ops/ssd.py`` at the
 Nemotron cell's shape (``[1, seq_len, 64, 64]``, state 128, 8 groups,
@@ -410,7 +416,10 @@ def memory(recipe: str = "smallthinker_one_chip") -> None:
     print(json.dumps(step_memory(topo.devices[0], recipe)))
 
 
-def float8(seeds: list, config_path: str = CONFIG) -> None:
+def float8(seeds: list, config_path: str = CONFIG, only: list = ()) -> None:
+    from learning_at_home_tpu.utils.chip import enable_compile_cache
+
+    enable_compile_cache()  # a reading compiles what the one before it did
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -449,6 +458,11 @@ def float8(seeds: list, config_path: str = CONFIG) -> None:
                 runner.compare_with_reference).parameters:
             readings.append(("the program, its scan's decays in bfloat16",
                              {"decay_dtype": jnp.bfloat16}))
+        # what a runner names as programs that must fall outside its limits
+        readings += list(getattr(runner, "WRONG_PROGRAMS", {}).items())
+        if only:  # the program itself, and the readings whose names hold a word
+            readings = readings[:1] + [
+                r for r in readings[1:] if any(word in r[0] for word in only)]
         for operands, how in readings:
             read = runner.compare_with_reference(
                 model, params, reference, config, jnp.asarray(ids),
@@ -835,7 +849,8 @@ if __name__ == "__main__":
               **({"forms": named} if named else {}))
     elif sys.argv[1:2] == ["float8"]:
         named = [a for a in sys.argv[2:] if a.endswith(".json")]
-        float8([int(s) for s in sys.argv[2:] if s not in named] or [3100000007],
-               *named[:1])
+        words = [a for a in sys.argv[2:] if a not in named and not a.isdigit()]
+        float8([int(s) for s in sys.argv[2:] if s.isdigit()] or [3100000007],
+               *(named[:1] or [CONFIG]), only=words)
     else:
         sys.exit(__doc__)
