@@ -1,0 +1,218 @@
+"""The guard of a public-model cell of the benchmark, once: ``selfcheck.py``
+passes on the manifest and on the cell's rehearsal; the cell is its
+configuration under its traffic on its chips and the rate lists it; it
+reports its count of readings and the ones named; and the runner rehearses
+at tiny sizes on the CPU, untraced and traced, ``correct``, with what its
+row says of the result line and of ``SETUP`` / ``COUNTERS`` / ``REFERENCE``.
+
+Everything is said in READINGS, the part of a metric's name after the
+first dot (``olmoe.step_ms_p50`` and ``train.step_ms_p50`` are both
+``step_ms_p50``), and looked up by name: what a cell should hold is read
+from the manifest, so a fold that renames, reorders or merges entries is
+followed and one that loses a reading is caught.
+
+This module holds ``Row``, ``ROWS`` and the guard (``rehearse``) and is no
+test module itself.  A rehearsal is two cold runs of ``benchmarks/run.py``,
+30 to 90 s, and under ``--dist loadfile`` a file is one worker's: so each
+row runs from a module of its own, ``tests/test_benchmark_cell_<model>.py``,
+which is one call of ``rehearsal_of``.  A new configuration adds a row to
+``ROWS`` HERE and such a module; ``tests/test_benchmark_cells.py`` fails
+while a row has no module or a module no row.
+"""
+
+import json
+import os
+import subprocess
+import sys
+from typing import Callable, NamedTuple
+
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, os.path.join(REPO, "benchmarks"))
+
+import harness  # noqa: E402  (benchmarks/harness.py: imports no jax)
+
+LEVELLING = "load_max_over_mean_before_and_after_levelling"
+
+
+def reading_of(entry: dict) -> str:
+    return entry["name"].split(".", 1)[-1]
+
+
+def readings_of_cell(manifest: dict, cell: str) -> dict:
+    """``{reading: entry}`` of the per-layer metrics reported in ``cell``;
+    two entries with one reading there (a fold that left a copy behind)
+    fail."""
+    readings: dict = {}
+    for entry in harness.metrics_of_cell(manifest["per_layer"], cell):
+        reading = reading_of(entry)
+        assert reading not in readings, (
+            f"{cell}: {readings[reading]['name']!r} and {entry['name']!r} "
+            f"are both the reading {reading!r}")
+        readings[reading] = entry
+    return readings
+
+
+def layer_metric_file(manifest: dict, entry: dict) -> dict:
+    return harness.load_json(harness.find_file(
+        manifest, "layer_metrics", entry["name"] + ".json"))
+
+
+# ---- what a row says of the SETUP, COUNTERS and REFERENCE lines ----
+
+
+def _glm_lines(setup, counters, reference):
+    assert "ce_mtp" in counters
+    assert "mtp_logits_rms" in reference
+
+
+def _nemotron_lines(setup, counters, reference):
+    assert "ssm_decay_min" in counters
+    assert "ssm_rms" in reference and "ssm_state_rms" in reference
+    assert len(reference["ssm_layers_rms"]) == 4
+
+
+def _olmo_hybrid_lines(setup, counters, reference):
+    assert setup["expert_param_bytes"] == 0 and setup["param_bytes_per_device"] > 0
+    assert set(counters) == {"delta_decay_min", "delta_beta_max"}
+    assert len(reference["delta_layers_rms"]) == len(reference["delta_states_rms"]) == 6
+
+
+def _qwen3next_lines(setup, counters, reference):
+    assert setup["expert_param_bytes"] > 0 and "level_router_bias" in setup["phases"]
+    assert {"dropped_fraction", "held_experts_empty", "delta_decay_min",
+            "delta_beta_max", "attention_gate_mean", "shared_gate_mean"} <= set(counters)
+    assert counters["delta_beta_max"]["max"] <= 1.0  # sigmoid(b): no factor 2
+    assert len(reference["delta_layers_rms"]) == len(reference["delta_states_rms"]) == 3
+    assert len(reference["attention_layers_rms"]) == 1
+    assert len(reference["router_logits_layers_rms"]) == 4  # every layer routes
+    # the backward pass and the update, float32 here: every leaf of a period
+    assert len(reference["grad_stream_layers_rms"]) == 5
+    for name in ("grads_rms", "grad_stream_rms", "step_grad_norms", "update_norm"):
+        assert 0.0 <= reference[name] < 1e-4, (name, reference[name])
+
+
+class Row(NamedTuple):
+    cell: str
+    config: str
+    traffic: str
+    chips: int
+    rehearsal: str  # its manifest under benchmarks/rehearsal/
+    seed: int
+    count: int  # readings the cell reports
+    named: tuple = ()  # readings it must report
+    traced: tuple = ("expert_load_max_over_mean",)  # its traced line must hold
+    levelled: int | None = None  # mixture layers SETUP says were levelled
+    lines: Callable | None = None  # what else SETUP, COUNTERS, REFERENCE hold
+
+
+_LEVELLED = ("local_rows_over_level", "expert_load_max_over_mean", "step_ms_p50")
+ROWS = (
+    Row("olmoe-1b-7b-train-zipf4k", "olmoe-1b-7b", "train-zipf4k", 1,
+        "manifest_olmoe.json", 2700000001, 10),
+    Row("smallthinker-21b-a3b-train-zipf16k", "smallthinker-21b-a3b",
+        "train-zipf16k", 1, "manifest_smallthinker.json", 3100000001, 13),
+    Row("k-exaone-236b-a23b-train-zipf16k", "k-exaone-236b-a23b",
+        "train-zipf16k", 1, "manifest_kexaone.json", 3300000007, 16,
+        traced=_LEVELLED, levelled=4),
+    Row("glm-4.7-flash-train-zipf16k", "glm-4.7-flash", "train-zipf16k", 1,
+        "manifest_glm47.json", 3700000007, 18,
+        ("attention_latent_share", "mtp_share", "attention_core_roofline",
+         "expert_matmul_roofline"),
+        _LEVELLED, 5, _glm_lines),
+    Row("nemotron-labs-twotower-30b-a3b-train-zipf16k",
+        "nemotron-labs-twotower-30b-a3b", "train-zipf16k", 1,
+        "manifest_nemotron.json", 3900000007, 20,
+        ("ssm_share", "ssm_scan_share", "ssm_proj_share", "ssm_conv_share",
+         "ssm_scan_roofline", "attention_core_roofline", "expert_matmul_roofline"),
+        _LEVELLED, 4, _nemotron_lines),
+    Row("olmo-hybrid-7b-train-zipf16k", "olmo-hybrid-7b", "train-zipf16k", 1,
+        "manifest_olmohybrid.json", 4500000007, 15,
+        ("mfu", "delta_share", "delta_core_share", "delta_core_roofline",
+         "attention_core_roofline", "delta_gate_norm_share"),
+        ("step_ms_p50",), 0, _olmo_hybrid_lines),  # no mixture layer to level
+    Row("qwen3-next-80b-a3b-train-zipf16k", "qwen3-next-80b-a3b", "train-zipf16k",
+        1, "manifest_qwen3next.json", 5500000007, 22,
+        ("mfu", "delta_share", "delta_core_share", "delta_core_roofline",
+         "attention_core_roofline", "expert_matmul_roofline",
+         "delta_gate_norm_share", "attention_gate_share", "shared_expert_share"),
+        ("step_ms_p50", "expert_load_max_over_mean", "local_rows_over_level"),
+        0, _qwen3next_lines),  # a share with no selection bias: nothing to level
+)
+
+
+def rehearse(row: Row, tmp_path) -> None:
+    """``selfcheck.py`` on the manifest and on the cell's rehearsal, the
+    cell and its readings as the manifest has them, then the cell's runner
+    for 2 s at tiny sizes on the CPU, untraced and traced: it cannot rot
+    unrun."""
+    from learning_at_home_tpu.utils.subproc import clean_jax_subprocess_env
+
+    env = clean_jax_subprocess_env(REPO, platform="cpu")
+    env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "cache")
+    rehearsal = "benchmarks/rehearsal/" + row.rehearsal
+    check = subprocess.run(
+        [sys.executable, "benchmarks/selfcheck.py", "BENCHMARK.json",
+         "benchmarks/rehearsal/manifest.json", rehearsal],
+        cwd=REPO, env=env, capture_output=True, text=True, timeout=120)
+    assert check.returncode == 0 and "selfcheck: ok" in check.stdout, check.stdout
+
+    manifest = harness.load_json(os.path.join(REPO, "BENCHMARK.json"))
+    cell = harness.by_name(manifest["workloads"], row.cell, "workload")
+    assert (cell["config"], cell["traffic"], cell["chips"]) == (
+        row.config, row.traffic, row.chips)
+    rate = harness.by_name(manifest["end_to_end"], "train_tokens_per_s_per_chip", "metric")
+    assert row.cell in rate["workloads"]
+    readings = readings_of_cell(manifest, row.cell)
+    assert len(readings) == row.count, sorted(readings)
+    assert set(row.named) <= set(readings), set(row.named) - set(readings)
+
+    # the names a rehearsal's line holds are its OWN manifest's for the cell
+    rehearsed = readings_of_cell(harness.load_json(os.path.join(REPO, rehearsal)), row.cell)
+
+    def printed(reading):
+        return "cpu_rehearsal." + rehearsed[reading]["name"]
+
+    for trace in ("0", "1"):
+        run = subprocess.run(
+            [sys.executable, "benchmarks/run.py", "--manifest", rehearsal,
+             "--workload", row.cell, "--seed", str(row.seed), "--seconds", "2",
+             "--trace", trace],
+            cwd=REPO, env=env, capture_output=True, text=True, timeout=300)
+        assert run.returncode == 0, run.stderr[-2000:]
+        line = json.loads(run.stdout.strip().splitlines()[-1])
+        problems = [l for l in run.stderr.splitlines() if l.startswith("INCORRECT")]
+        assert line["correct"] is True and line["failed"] == 0, (
+            problems or run.stderr[-2000:])
+        names = set(line["metrics"])
+        if trace == "0":
+            assert names == {"cpu_rehearsal.train_tokens_per_s_per_chip",
+                             "cpu_rehearsal.setup_s"}
+        else:  # a CPU has no peak: the shares of one are left out
+            if "moe_dropped_share" in rehearsed:  # a stack with a mixture layer
+                assert line["metrics"][printed("moe_dropped_share")]["value"] == 0.0
+            assert {printed(reading) for reading in row.traced} <= names
+            assert not any("mfu" in n or "roofline" in n for n in names)
+    if row.levelled is None:
+        return
+    said = {l.split(" ", 1)[0]: json.loads(l.split(" ", 1)[1])
+            for l in run.stdout.splitlines()
+            if l.startswith(("SETUP ", "COUNTERS ", "REFERENCE "))}
+    assert len(said["SETUP"][LEVELLING]) == row.levelled
+    if row.levelled:
+        assert "level_router_bias" in said["SETUP"]["phases"]
+    if row.lines:
+        row.lines(said["SETUP"], said["COUNTERS"], said["REFERENCE"])
+
+
+def rehearsal_of(cell: str) -> Callable:
+    """The test a row's module holds: ``rehearse`` on the row of ``cell``,
+    under the name and the id it had as one case of seven."""
+    row = next(row for row in ROWS if row.cell == cell)
+
+    @pytest.mark.parametrize("row", [row], ids=[cell])
+    def test_benchmark_manifests_pass_selfcheck_and_the_runner_rehearses(row, tmp_path):
+        rehearse(row, tmp_path)
+
+    return test_benchmark_manifests_pass_selfcheck_and_the_runner_rehearses

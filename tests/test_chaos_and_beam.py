@@ -199,35 +199,56 @@ def test_partial_checkpoint_invisible(tmp_path):
 
 
 def test_quorum_under_latency_and_stragglers():
-    """Injected jitter + stragglers: quorum returns without waiting for the
-    stragglers (grace timeout), throughput degrades gracefully."""
-    chaos = ChaosConfig(
-        base_latency=0.01, jitter=0.02, straggler_prob=0.3,
-        straggler_delay=1.5, seed=42,
-    )
+    """Injected jitter + a straggler: the quorum returns on the replies of
+    the peer that answers (grace timeout) and drops the straggler.  Said in
+    counters, not in seconds: two of each sample's four experts sit on a
+    peer that holds EVERY reply back for 60 s, its first ``hello`` among
+    them; the dispatch comes back with every sample at quorum, and not one
+    byte has come from the straggler when it does."""
+    from learning_at_home_tpu.client.rpc import pool_registry
+
+    fast = ChaosConfig(base_latency=0.01, jitter=0.02, seed=42)
+    slow = ChaosConfig(straggler_prob=1.0, straggler_delay=60.0, seed=42)
     with background_server(
-        num_experts=4, hidden_dim=HID, expert_prefix="ffn", seed=5, chaos=chaos
-    ) as (endpoint, srv):
-        source = StaticExpertSource({uid: endpoint for uid in srv.experts})
+        num_experts=2, hidden_dim=HID, expert_prefix="ffn", seed=5, chaos=fast
+    ) as (ep_fast, srv_fast), background_server(
+        num_experts=2, hidden_dim=HID, expert_prefix="ffn", expert_offset=2,
+        seed=5, chaos=slow,
+    ) as (ep_slow, srv_slow):
+        experts = {uid: ep_fast for uid in srv_fast.experts}
+        experts.update({uid: ep_slow for uid in srv_slow.experts})
+        assert sorted(experts) == ["ffn.0", "ffn.1", "ffn.2", "ffn.3"]
         moe = RemoteMixtureOfExperts(
-            in_features=HID, grid_size=(4,), uid_prefix="ffn", source=source,
-            k_best=4, k_min=1, timeout_after_k_min=0.15, forward_timeout=5.0,
+            in_features=HID, grid_size=(4,), uid_prefix="ffn",
+            source=StaticExpertSource(experts), k_best=4, k_min=1,
+            timeout_after_k_min=0.15, forward_timeout=120.0,
         )
         gate = moe.init_gate_params(jax.random.PRNGKey(0))
         x = jnp.asarray(np.random.RandomState(1).randn(4, HID).astype(np.float32))
-        t0 = time.monotonic()
         out = np.asarray(moe(x, gate))
-        elapsed = time.monotonic() - t0
-        assert np.isfinite(out).all()
-        # must NOT have waited for all stragglers (1.5s each, serial worst
-        # case >> grace); quorum+grace bounds the wait
-        assert elapsed < 1.5 + 1.0, f"took {elapsed}s — straggler not dropped?"
-        assert srv.chaos.injected_delays + srv.chaos.injected_stragglers > 0
+        assert np.isfinite(out).all() and np.abs(out).max() > 0
+        # quorum reached: every sample had its k_min replies, none masked
+        assert (moe.samples_total, moe.samples_dropped) == (4, 0)
+        # the straggler dropped, not awaited: the peer that answers has
+        # answered, the other's held reply never arrived
+        assert pool_registry().get(ep_fast).bytes_received > 0
+        assert pool_registry().get(ep_slow).bytes_received == 0
+        assert srv_fast.chaos.injected_delays > 0
+        for _ in range(2000):  # the straggler's own loop counts it: wait for it
+            if srv_slow.chaos.injected_stragglers:
+                break
+            time.sleep(0.005)
+        assert srv_slow.chaos.injected_stragglers > 0
     reset_client_rpc()
 
 
 def test_quorum_under_drops():
-    """Reply drops look like timeouts; k_min=1 still succeeds eventually."""
+    """Reply drops look like timeouts; k_min=1 still succeeds eventually:
+    the merged call's reply is dropped (the seed's first draw), its four
+    experts are asked again one by one, and the replies that are not dropped
+    carry every sample to quorum.  A reply is waited for 5 s, not 1: on a
+    loaded machine an answer that was NOT dropped took longer than a second,
+    and every call counted as lost."""
     chaos = ChaosConfig(drop_prob=0.4, seed=7)
     with background_server(
         num_experts=4, hidden_dim=HID, expert_prefix="ffn", seed=6, chaos=chaos
@@ -235,11 +256,12 @@ def test_quorum_under_drops():
         source = StaticExpertSource({uid: endpoint for uid in srv.experts})
         moe = RemoteMixtureOfExperts(
             in_features=HID, grid_size=(4,), uid_prefix="ffn", source=source,
-            k_best=4, k_min=1, timeout_after_k_min=0.1, forward_timeout=1.0,
+            k_best=4, k_min=1, timeout_after_k_min=0.1, forward_timeout=5.0,
         )
         gate = moe.init_gate_params(jax.random.PRNGKey(0))
         x = jnp.asarray(np.random.RandomState(2).randn(3, HID).astype(np.float32))
         out = np.asarray(moe(x, gate))
-        assert np.isfinite(out).all()
+        assert np.isfinite(out).all() and np.abs(out).max() > 0
         assert srv.chaos.injected_drops > 0
+        assert (moe.samples_total, moe.samples_dropped) == (3, 0)
     reset_client_rpc()

@@ -33,6 +33,15 @@ def _inputs(seed=0, b=2, s=64, h=3, dk=8, dv=12, g_scale=0.5, dtype=jnp.float32)
     return q.astype(dtype), k.astype(dtype), v.astype(dtype), g, beta
 
 
+def _chunked(chunk, **how):
+    """The chunked rule as ONE compiled program (operation by operation the
+    CPU takes seconds a call and half a minute a gradient)."""
+    return jax.jit(lambda *a: gated_delta_chunked(*a, chunk, **how))
+
+
+_recurrent = jax.jit(gated_delta_recurrent)
+
+
 def _rel(got, want):
     got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
     return np.abs(got - want).max() / np.abs(want).max()
@@ -44,8 +53,8 @@ def test_the_chunked_rule_is_the_rule_a_position_at_a_time(chunk, s):
     """Output and final state, a sequence of one chunk and of many."""
     args = _inputs(seed=chunk + s, s=s)
     assert args[4].max() > 1.9  # strengths beyond 1 are in the draw
-    want_o, want_state = gated_delta_recurrent(*args)
-    got_o, got_state = gated_delta_chunked(*args, chunk)
+    want_o, want_state = _recurrent(*args)
+    got_o, got_state = _chunked(chunk)(*args)
     assert got_o.shape == want_o.shape and got_state.shape == (2, 3, 8, 12)
     assert _rel(got_o, want_o) < 1e-5
     assert _rel(got_state, want_state) < 1e-5
@@ -66,9 +75,9 @@ def test_every_gradient_of_the_chunked_rule_is_the_recurrent_ones(chunk, segment
             return jnp.sum(o * weights) + jnp.sum(jnp.sin(state))
         return of
 
-    got = jax.grad(loss(lambda *a: gated_delta_chunked(*a, chunk, segment=segment)),
-                   argnums=(0, 1, 2, 3, 4))(*args)
-    want = jax.grad(loss(gated_delta_recurrent), argnums=(0, 1, 2, 3, 4))(*args)
+    got = jax.jit(jax.grad(loss(_chunked(chunk, segment=segment)),
+                           argnums=(0, 1, 2, 3, 4)))(*args)
+    want = jax.jit(jax.grad(loss(gated_delta_recurrent), argnums=(0, 1, 2, 3, 4)))(*args)
     for name, a, b in zip("q k v g beta".split(), got, want):
         assert _rel(a, b) < 2e-5, name
 
@@ -80,12 +89,12 @@ def test_decays_down_to_exp_minus_twenty_a_chunk_stay_finite(chunk):
     are still the recurrence's."""
     args = _inputs(seed=3, s=128, g_scale=2 * 20.0 / chunk)
     assert float(jnp.min(jnp.sum(args[3][:, :chunk], axis=1))) < -15.0
-    want_o, want_state = gated_delta_recurrent(*args)
-    got_o, got_state = gated_delta_chunked(*args, chunk)
+    want_o, want_state = _recurrent(*args)
+    got_o, got_state = _chunked(chunk)(*args)
     assert _rel(got_o, want_o) < 1e-5 and _rel(got_state, want_state) < 1e-5
-    grads = jax.grad(
-        lambda *a: jnp.sum(gated_delta_chunked(*a, chunk)[0] ** 2),
-        argnums=(0, 1, 2, 3, 4))(*args)
+    grads = jax.jit(jax.grad(
+        lambda *a: jnp.sum(_chunked(chunk)(*a)[0] ** 2),
+        argnums=(0, 1, 2, 3, 4)))(*args)
     assert all(bool(jnp.isfinite(g).all()) for g in grads)
 
 
@@ -100,8 +109,7 @@ def test_a_write_strength_of_two_flips_the_state_along_the_key():
     written = np.arange(1.0, 7.0)
     for second, sign in ((2.0, -1.0), (1.0, 0.0)):
         beta = jnp.zeros((1, 32, 1)).at[:, 0].set(1.0).at[:, 17].set(second)
-        for rule in (gated_delta_recurrent,
-                     lambda *a: gated_delta_chunked(*a, 16)):
+        for rule in (_recurrent, _chunked(16)):
             o, state = rule(k, k, v, g, beta)
             np.testing.assert_allclose(o[0, 16, 0], written, atol=1e-6)
             np.testing.assert_allclose(o[0, 17, 0], sign * written, atol=1e-6)
@@ -147,9 +155,9 @@ def test_the_product_over_a_whole_chunk_loses_to_the_blocks_where_keys_are_alike
     k = k + 0.6
     k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
     args = (q, k, v, 0.01 * g, beta)
-    want, _ = gated_delta_recurrent(*args)
-    blocks, _ = gated_delta_chunked(*args, 64, solve="blocks")
-    product, _ = gated_delta_chunked(*args, 64, solve="product")
+    want, _ = _recurrent(*args)
+    blocks, _ = _chunked(64, solve="blocks")(*args)
+    product, _ = _chunked(64, solve="product")(*args)
     assert _rel(blocks, want) < 1e-5
     assert not _rel(product, want) < 1e-1  # inf or nan among them
 
@@ -157,32 +165,32 @@ def test_the_product_over_a_whole_chunk_loses_to_the_blocks_where_keys_are_alike
 def test_bf16_inputs_give_a_bf16_output_and_a_float32_state():
     args = _inputs(seed=5, s=64, dtype=jnp.bfloat16)
     exact = tuple(a.astype(jnp.float32) for a in args)
-    want_o, want_state = gated_delta_recurrent(*exact)
-    got_o, got_state = gated_delta_chunked(*args, 32)
+    want_o, want_state = _recurrent(*exact)
+    got_o, got_state = _chunked(32)(*args)
     assert got_o.dtype == jnp.bfloat16 and got_state.dtype == jnp.float32
     assert _rel(got_o, want_o) < 3e-2 and _rel(got_state, want_state) < 3e-2
 
 
 def test_decays_summed_in_bf16_read_worse_than_in_float32():
     args = _inputs(seed=6, s=128, g_scale=0.3)
-    want, _ = gated_delta_recurrent(*args)
-    exact, _ = gated_delta_chunked(*args, 64)
-    rough, _ = gated_delta_chunked(*args, 64, decay_dtype=jnp.bfloat16)
+    want, _ = _recurrent(*args)
+    exact, _ = _chunked(64)(*args)
+    rough, _ = _chunked(64, decay_dtype=jnp.bfloat16)(*args)
     assert _rel(rough, want) > 100 * _rel(exact, want)
 
 
 def test_segments_hand_the_state_on_and_give_the_same_numbers():
     args = _inputs(seed=8, s=192)
-    whole_o, whole_state = gated_delta_chunked(*args, 16)
-    o, state = gated_delta_chunked(*args, 16, segment=48)
+    whole_o, whole_state = _chunked(16)(*args)
+    o, state = _chunked(16, segment=48)(*args)
     np.testing.assert_allclose(o, whole_o, atol=1e-6)
     np.testing.assert_allclose(state, whole_state, atol=1e-6)
     with pytest.raises(ValueError, match="segments of 40"):
-        gated_delta_chunked(*args, 16, segment=40)
+        _chunked(16, segment=40)(*args)
 
 
 def test_a_chunk_that_does_not_divide_the_sequence_is_refused():
     with pytest.raises(ValueError, match="divide"):
-        gated_delta_chunked(*_inputs(s=48), 32)
+        _chunked(32)(*_inputs(s=48))
     with pytest.raises(ValueError, match="'blocks'"):
         solve_unit_lower(jnp.zeros((24, 24)), jnp.zeros((24, 2)))

@@ -104,7 +104,7 @@ def test_the_kernel_matches_the_plain_form_and_the_recurrence(shape, dtype):
     assert state.dtype == jnp.float32 and state.shape == want_state.shape
     tol = 1e-5 if dtype == jnp.float32 else 4e-3
     assert _rms(o, want_o) < tol and _rms(state, want_state) < tol
-    exact_o, exact_state = delta_rule.gated_delta_recurrent(*args)
+    exact_o, exact_state = jax.jit(delta_rule.gated_delta_recurrent)(*args)
     tol = 1e-5 if dtype == jnp.float32 else 1e-2
     assert _rms(o, exact_o) < tol and _rms(state, exact_state) < tol
 

@@ -122,8 +122,10 @@ def test_the_kernels_gradients_match_autodiff_of_the_plain_form(
         return lambda *a: jnp.sum(form(*a).astype(jnp.float32) * weigh)
 
     argnums = (0, 1, 2, 3) if args[3] is not None else (0, 1, 2)
-    want = jax.tree_util.tree_leaves(jax.grad(loss(plain), argnums)(*args))
-    got = jax.tree_util.tree_leaves(jax.grad(loss(kernel), argnums)(*args))
+    # each side one compiled program (80 cases: operation by operation the
+    # two gradients are most of this file's time)
+    want = jax.tree_util.tree_leaves(jax.jit(jax.grad(loss(plain), argnums))(*args))
+    got = jax.tree_util.tree_leaves(jax.jit(jax.grad(loss(kernel), argnums))(*args))
     assert len(got) == len(want) == (5 if args[3] is not None else 3)
     for name, g, w in zip(("dy", "dz", "dscale", "dx", "dD"), got, want):
         assert g.dtype == w.dtype and g.shape == w.shape, name

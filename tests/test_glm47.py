@@ -6,8 +6,11 @@ predicts the next-but-one token with its loss, a share of sigmoid-routed
 experts; the masked cross-entropy; the kernel's tiles at heads of 256; the
 refusals beside that path; and the benchmark's files for it.
 
-Tiny sizes on the CPU, except the AOT compile at published widths for a
-described (not attached) ``v5e`` chip.
+Tiny sizes on the CPU.  Here: the block against its reference, what must
+fail that comparison, the masked cross-entropy and the benchmark's files; the
+share, the refusals and the tiles are ``tests/test_glm47_share.py``'s, the AOT
+compile at published widths for a described (not attached) ``v5e`` chip
+``tests/test_glm47_chip.py``'s.
 """
 
 import dataclasses
@@ -31,10 +34,8 @@ import harness  # noqa: E402  (benchmarks/harness.py: imports no jax)
 from __graft_entry__ import glm_4_7_flash_one_chip  # noqa: E402
 from learning_at_home_tpu.models import transformer, trunk  # noqa: E402
 from learning_at_home_tpu.models.transformer import DMoETransformerLM  # noqa: E402
-from learning_at_home_tpu.ops import moe_dispatch  # noqa: E402
 from learning_at_home_tpu.parallel.mesh import make_mesh  # noqa: E402
-from learning_at_home_tpu.parallel.sharded_moe import ShardedMixtureOfExperts  # noqa: E402
-from test_benchmark_cells import layer_metric_file, readings_of_cell  # noqa: E402
+from benchmark_cells import layer_metric_file, readings_of_cell  # noqa: E402
 
 REFERENCE = os.path.join(REPO, "benchmarks", "configs", "glm_4_7_flash_reference.py")
 reference = harness.load_path(REFERENCE)
@@ -78,6 +79,19 @@ def tiny():
     rs = np.random.RandomState(3)
     ids = jnp.asarray(rs.randint(0, cfg.vocab_size, (batch, cfg.seq_len + 1)))
     return model, cfg, params, ids[:, :-1], ids[:, 1:]
+
+
+@pytest.fixture(scope="module")
+def want(tiny):
+    """The reference on the tiny weights, each one compiled program, once a
+    module: both heads' float32 logits, the three losses, the gradients."""
+    _, _, params, ids, tgt = tiny
+    logits, logits_mtp = jax.jit(
+        lambda p: reference.forward(p, ids, tgt, SIZES)[:2])(params)
+    losses = jax.jit(lambda p: reference.losses(p, ids, tgt, SIZES))(params)
+    _, grads = jax.jit(
+        lambda p: reference.loss_and_grads(p, ids, tgt, SIZES))(params)
+    return logits, logits_mtp, losses, grads
 
 
 def _close(got, want, tol=1e-4, **kw):
@@ -144,16 +158,15 @@ def test_latent_attention_layer_matches_the_reference(tiny, impl):
     assert np.ptp(np.asarray(k[..., :12]), axis=2).min() > 0.0
 
 
-def test_both_heads_logits_and_both_losses_match_the_reference(tiny):
+def test_both_heads_logits_and_both_losses_match_the_reference(tiny, want):
     model, cfg, params, ids, tgt = tiny
-    want, want_mtp, _, _ = reference.forward(params, ids, tgt, SIZES)
+    want, want_mtp, (want_loss, want_ce, want_ce_mtp), _ = want
     logits, _ = jax.jit(model.apply)(params, ids)
     _close(logits, want)
     x, x_mtp, aux = jax.jit(
         lambda p, i, t: model._hidden(p, i, next_ids=t))(params, ids, tgt)
     _close(model._logits(x, model._head(params)), want)
     _close(model._logits(x_mtp, model._head(params)), want_mtp)
-    want_loss, want_ce, want_ce_mtp = reference.losses(params, ids, tgt, SIZES)
     loss, metrics = jax.jit(model.loss_fn)(params, ids, tgt)
     for got, wanted in ((loss, want_loss), (metrics["ce"], want_ce),
                         (metrics["ce_mtp"], want_ce_mtp)):
@@ -171,17 +184,16 @@ def test_both_heads_logits_and_both_losses_match_the_reference(tiny):
         stack_aux["expert_counts"], aux["expert_counts"][:4])
 
 
-def test_gradients_of_every_parameter_match_the_reference(tiny):
+def test_gradients_of_every_parameter_match_the_reference(tiny, want):
     """The gradient of EVERY leaf (the block's and the shared table's and
     head's among them) to 1e-4 of the reference's largest entry of that
     leaf; the selection biases' are exactly zero on both sides."""
     model, _, params, ids, tgt = tiny
     grads = jax.jit(jax.grad(lambda p: model.loss_fn(p, ids, tgt)[0]))(params)
-    _, want = reference.loss_and_grads(params, ids, tgt, SIZES)
     names = []
     for (path, g), w in zip(
         jax.tree_util.tree_flatten_with_path(grads)[0],
-        jax.tree_util.tree_leaves(want),
+        jax.tree_util.tree_leaves(want[3]),
     ):
         name, w = jax.tree_util.keystr(path), np.asarray(w)
         names.append(name)
@@ -303,160 +315,6 @@ def test_the_masked_cross_entropy_leaves_out_positions_without_a_target(n, chunk
     same = [transformer._ce_of_chunks(x, head, every, chunk, n, flag)
             for flag in (False, True)]
     assert float(same[0]) == float(same[1])
-
-
-# ---- (d) the share ----
-
-
-def _layer_of_all_experts(seed=5, d=32, f=16, experts=16, k=4, n=96):
-    rs = np.random.RandomState(seed)
-
-    def w(*shape):
-        return jnp.asarray(rs.randn(*shape) / np.sqrt(shape[-2]), jnp.float32)
-
-    moe = {"gate": w(d, experts) * 4, "w_gate": w(experts, d, f),
-           "w_up": w(experts, d, f), "w_down": w(experts, f, d),
-           "router_bias": jnp.asarray(rs.uniform(-0.1, 0.1, experts), jnp.float32)}
-    lp = {"ln2": {"scale": jnp.asarray(rs.uniform(0.5, 1.5, d), jnp.float32)},
-          "moe": moe,
-          "shared": {"w_gate": w(d, f), "w_up": w(d, f), "w_down": w(f, d)}}
-    h = jnp.asarray(rs.randn(1, n, d), jnp.float32)
-    sizes = dict(SIZES, experts_per_token=k, held=None, first_k_dense_replace=0)
-    # loads levelled, as the set-up leaves them: no share's buffer overflows
-    m = reference.rms(h, lp["ln2"]["scale"], sizes["norm_eps"]).reshape(-1, d)
-    moe["router_bias"], _ = moe_dispatch.level_bias(
-        jax.nn.sigmoid(m @ moe["gate"]), moe["router_bias"], k)
-    return lp, h, sizes
-
-
-def test_the_two_shares_add_up_to_the_uncut_layer():
-    """The routed parts both shares give (each its own half of the 16
-    experts, through the program's share path), with the shared expert
-    counted once, equal the uncut reference's layer; so do the
-    reference's own shares."""
-    lp, h, sizes = _layer_of_all_experts()
-    d, experts, held, k = h.shape[-1], 16, 8, 4
-    want, _, _ = reference.ffn_part(lp, h, sizes, 0)
-    m = reference.rms(h, lp["ln2"]["scale"], sizes["norm_eps"]).reshape(-1, d)
-    total = trunk.gated_mlp(lp["shared"], m)  # what both chips compute alike: once
-    ref_total = reference.gated(lp["shared"], m, lambda a: a)
-    for first in (0, held):
-        cut = {**lp["moe"], **{name: lp["moe"][name][first:first + held]
-                               for name in ("w_gate", "w_up", "w_down")}}
-        share = ShardedMixtureOfExperts(
-            _one_device_mesh(), hidden_dim=d, num_experts=experts, k=k,
-            dtype=jnp.float32, ffn_dim=16, expert_kind="gated_silu",
-            routing="dropless", router_score="sigmoid", router_bias=True,
-            routed_scale=1.8, held_experts=held, first_held_expert=first)
-        part, aux = jax.jit(share)(cut, m)
-        assert float(aux["dropped_fraction"]) == 0.0, first
-        total = total + part
-        ref_total = ref_total + reference.routed_part(
-            cut, m, dict(sizes, held=(first, held)))
-    scale = np.abs(np.asarray(want - h)).max()
-    for summed in (total, ref_total):
-        np.testing.assert_allclose(
-            np.asarray(h + summed.reshape(h.shape)), np.asarray(want), rtol=0,
-            atol=1e-5 * scale)
-
-
-def test_set_up_levels_the_blocks_router_too(tiny):
-    """``level_router_bias`` levels five routers, the block's the last, on
-    the next ids the rows themselves give; the stack's layers' levelled
-    biases are what they are without the block."""
-    model, cfg, params, ids, _ = tiny
-    pool = [ids, jnp.roll(ids, 5, axis=1)]
-    levelled, loads = model.level_router_bias(params, pool)
-    assert len(loads) == 5
-    assert all(after <= before and after < 1.3 for before, after in loads)
-    was = params["mtp"]["layer"]["moe"]["router_bias"]
-    now = levelled["mtp"]["layer"]["moe"]["router_bias"]
-    assert float(jnp.abs(now - was).max()) > 0
-    stack_alone = {k: v for k, v in params.items() if k != "mtp"}
-    alone, loads_alone = model.level_router_bias(stack_alone, pool)
-    assert loads_alone == loads[:4]
-    for a, b in zip(alone["layers"][1:], levelled["layers"][1:]):
-        np.testing.assert_array_equal(a["moe"]["router_bias"], b["moe"]["router_bias"])
-    # and the step's rule moves the block's bias as it moves the others
-    _, _, optimizer, _ = glm_4_7_flash_one_chip(_one_device_mesh(), tiny=True)
-    before = np.asarray(now)
-    own = jax.tree_util.tree_map(jnp.copy, levelled)  # the step donates them
-    opt_state = model.init_opt_state(optimizer, own)
-    stepped, _, _, metrics = model.make_train_step(optimizer)(
-        own, opt_state, ids, jnp.roll(ids, -1, axis=1))
-    moved = np.asarray(stepped["mtp"]["layer"]["moe"]["router_bias"]) - before
-    np.testing.assert_allclose(np.abs(moved[moved != 0]), 0.001, rtol=1e-4)
-    assert (moved != 0).any()
-    assert "expert_counts" not in metrics and "ce_mtp" in metrics
-
-
-# ---- (e) the refusals beside the path ----
-
-
-@pytest.mark.parametrize("changes, error, match", [
-    ({"mtp_layers": 2}, ValueError, "0 or 1"),
-    ({"q_latent_dim": None}, ValueError, "together"),
-    ({"head_dim": None}, ValueError, "together"),
-    ({"n_kv_heads": 2}, ValueError, "latent attention"),
-    ({"qk_norm": "head"}, ValueError, "latent attention"),
-    ({"rope_head_dim": 16}, ValueError, "rope_head_dim"),
-    ({"seq_parallel": True}, NotImplementedError, "latent attention"),
-])
-def test_a_configuration_the_step_cannot_run_is_refused_by_name(
-        tiny, changes, error, match):
-    _, cfg, _, _, _ = tiny
-    with pytest.raises(error, match=match):
-        DMoETransformerLM(dataclasses.replace(cfg, **changes), _one_device_mesh())
-
-
-def test_the_cached_decoder_refuses_the_block_by_name(tiny):
-    model, cfg, params, ids, _ = tiny
-    with pytest.raises(NotImplementedError, match="latent attention"):
-        model.generate(params, ids[:, :4], 2, use_cache=True)
-    plain = dataclasses.replace(
-        cfg, kv_latent_dim=None, q_latent_dim=None, rope_head_dim=None)
-    with pytest.raises(NotImplementedError, match="next-but-one-token block"):
-        DMoETransformerLM(plain, _one_device_mesh()).generate(
-            params, ids[:, :4], 2, use_cache=True)
-    out = model.generate(params, ids[:1, :4], 2)  # the full forward decodes
-    assert out.shape == (1, 6)
-
-
-# ---- (f) the kernel's tiles and the grouped matmul's at this model's shapes ----
-
-
-def test_flash_block_sizes_at_heads_of_256():
-    sizes = trunk.flash_block_sizes((1, 16384, 20, 256), "tpu")
-    assert sizes.use_fused_bwd_kernel
-    assert (sizes.block_q, sizes.block_kv, sizes.block_kv_compute) == (1024, 1024, 256)
-    assert (sizes.block_q_dkv, sizes.block_kv_dkv, sizes.block_kv_dkv_compute) == (
-        1024, 1024, 512)
-    assert trunk.flash_block_sizes((1, 16384, 20, 256), "cpu") is None
-    assert trunk.flash_block_sizes((1, 16384, 20, 192), "tpu") is None
-    short = trunk.flash_block_sizes((1, 128, 20, 256), "tpu")
-    assert (short.block_q, short.block_kv_compute) == (128, 128)
-    # heads of 64 and 128 as before
-    for hd in (64, 128):
-        was = trunk.flash_block_sizes((4, 4096, 16, hd), "tpu")
-        assert (was.block_q, was.block_kv, was.block_kv_compute,
-                was.block_kv_dkv_compute, was.use_fused_bwd_kernel) == (
-            1024, 1024, 512, 512, True)
-    window = trunk.flash_block_sizes((1, 16384, 64, 128), "tpu", 128)
-    assert (window.block_q, window.block_kv, window.use_fused_bwd_kernel) == (
-        512, 512, False)
-    assert transformer.auto_attn_impl("tpu", 1, 16384, 256) == "flash"
-    assert transformer.auto_attn_impl("cpu", 1, 16384, 256) == "xla"
-    assert transformer.auto_attn_impl("tpu", 4, 16384, 256) == "xla"
-
-
-def test_grouped_matmul_tiles_at_2048_by_1536():
-    tiles = moe_dispatch.grouped_matmul_tiles
-    assert tiles(65536, 2048, 1536, jnp.bfloat16) == (256, 2048, 768)
-    assert tiles(65536, 1536, 2048, jnp.bfloat16) == (256, 1536, 1024)
-    assert tiles(65536, 2048, 1536, jnp.bfloat16, weights_gradient=True) == (
-        256, 1024, 768)
-    assert tiles(65536, 1536, 2048, jnp.bfloat16, weights_gradient=True) == (
-        256, 768, 1024)
 
 
 # ---- (g) the benchmark's files ----
@@ -629,48 +487,3 @@ def test_a_program_without_the_recipe_fails_at_once_with_no_result(tmp_path):
         cwd=REPO, env=env, capture_output=True, text=True, timeout=120)
     assert run.returncode == 2 and "no recipe" in run.stderr
     assert not run.stdout.strip()
-
-
-# ---- (h) the chip's compiler accepts the step at published widths ----
-
-
-def test_the_whole_step_fits_the_chip(v5e_chip, monkeypatch):
-    """The 5-layer train step with the prediction block at published
-    widths, compiled for a described chip (nothing runs): 1.839 B
-    parameters, the compiler's own count of what is live in the step
-    between a quarter of the chip's memory (the benchmark's floor for a
-    cell) and 0.9 of it (8.50 GB, 50.3 %, when this was written: ISSUE.md
-    expected 59-74 %), every grouped matmul of the five mixture layers at
-    the tile rule's answers for 2048 x 1536 over a buffer of 65,536 rows,
-    the blocked kernel at heads of 256 in all six layers, and the head's
-    three products a pass, two passes."""
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    memory = probe.step_memory(v5e_chip, "glm_4_7_flash_one_chip")
-    assert memory["parameters"] == 1_838_980_928
-    assert 0.25 < memory["share_of_chip"] < 0.9, memory
-    assert memory["grouped_matmul_tilings"] == {
-        "256,2048,768": 5 * 5, "256,1536,1024": 5 * 4,
-        "256,1024,768": 5 * 2, "256,768,1024": 5}
-    assert memory["loss_layer_products"] == 2 * 3
-    # one forward a kernel layer (12 before PR 38): remat keeps the
-    # kernel's output and row sums, 169 MB a layer, so the recompute holds
-    # no forward call; read off the compiled step, and below off the
-    # traced one, where the policy has already taken the call out
-    assert memory["attention_kernel_calls"] == {
-        "splash_mha_fwd_residuals": 6,
-        "splash_mha_dkv_no_residuals": 6}  # fused: no dQ kernel of its own
-    assert memory["kept_residual_bytes"] == 6 * 20 * 16384 * (256 * 2 + 4)
-    # and the results of three of the attention part's six products (PR
-    # 53): the two down to the latents (768, and 512 with the 64 rotated)
-    # and the output projection's, bf16 [16384, 768 + 576 + 2048] a layer,
-    # 0.67 GB; the three products UP from the latents run a second time in
-    # all six layers (kept, their 2.77 GB cost the cell 0.23 % on the chip)
-    assert memory["kept_product_bytes"] == 6 * 16384 * (768 + 576 + 2048) * 2
-    assert memory["recomputed_attention_products"] == 6 * 3
-    calls = memory["attention_kernel_tilings"]["attention"]
-    assert {name: (c["calls"], c["block_q"], c["block_kv"]) for name, c in calls.items()} == {
-        "splash_mha_fwd_residuals": (6, 1024, 1024),
-        "splash_mha_dkv_no_residuals": (6, 1024, 1024)}
-    # the queries' gradient once a key block, [16, 20, 16384, 256] bf16
-    assert calls["splash_mha_dkv_no_residuals"]["largest_result_bytes"] == (
-        16 * 20 * 16384 * 256 * 2)

@@ -1,0 +1,78 @@
+"""K-EXAONE's train step at published widths, AOT-compiled for a described
+(not attached) ``v5e`` chip: nothing runs.  A module apart from
+``tests/test_kexaone.py``'s CPU cases, so that ``--dist loadfile`` can give
+the compile a worker of its own.
+"""
+
+import jax
+import jax.numpy as jnp
+
+from test_kexaone import probe
+from learning_at_home_tpu.models import trunk
+from learning_at_home_tpu.ops import moe_dispatch
+
+
+def test_the_whole_step_fits_the_chip(v5e_chip, monkeypatch):
+    """The 5-layer train step at published widths, compiled for a
+    described chip (nothing runs): 2.504 B parameters, the compiler's own
+    count of what is live in the step between a quarter of the chip's
+    memory (the benchmark's floor for a cell) and all of it, and every
+    grouped matmul of the four mixture layers at the tile rule's answers
+    for 6144 x 2048 over a buffer of 16,384 rows."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    memory = probe.step_memory(v5e_chip, "k_exaone_one_chip")
+    assert memory["parameters"] == 2_504_068_864
+    assert 0.25 < memory["share_of_chip"] < 0.9, memory
+    assert memory["grouped_matmul_tilings"] == {
+        "256,2048,1024": 4 * 9, "256,1024,1024": 4 * 3}
+    assert memory["loss_layer_products"] == 3  # of the head's; four before PR 34
+    assert moe_dispatch.grouped_matmul_tiles(16384, 6144, 2048, jnp.bfloat16) == (
+        256, 2048, 1024)
+    # the blocked kernel's tiles read the window (PR 36): the four window
+    # layers' backward is a dK/dV and a dQ kernel (none of the latter in
+    # the step before), the global layer's the fused one
+    # and one forward a kernel layer (10 before PR 38): remat keeps the
+    # kernel's output and row sums, 273 MB a layer, so the recompute holds
+    # no forward call.  ``attention_kernel_calls`` reads the compiled
+    # step's instructions, ``attention_kernel_tilings`` the traced step's
+    # equations: the policy takes the call out before the compiler sees it
+    assert memory["attention_kernel_calls"] == {
+        "splash_mha_fwd_residuals": 5,
+        "splash_mha_dkv_no_residuals": 5, "splash_mha_dq_no_residuals": 4}
+    assert memory["kept_residual_bytes"] == 5 * 64 * 16384 * (128 * 2 + 4)
+    # and the results of the attention part's products (PR 53): q, k, v and
+    # the output projection's, bf16 [16384, 8192 + 1024 + 1024 + 6144] a
+    # layer, 2.68 GB (13.79 GB live, 81.6 %, from 12.18: the band above
+    # holds it), and the backward pass runs none of the four a second time
+    assert memory["kept_product_bytes"] == 5 * 16384 * (8192 + 2 * 1024 + 6144) * 2
+    assert memory["recomputed_attention_products"] == 0
+    # the optimized HLO's instructions carry the attention part's stages
+    # (PR 52), and the kernel's calls sit under ``flash``, not its ``layout``
+    stages = memory["attention_stages"]
+    assert stages["stages"] == [
+        "flash", "flash/layout", "norm", "out_proj", "proj", "qk_norm", "rope"]
+    assert stages["kernel_scopes"] == [
+        f"attention/flash/vmap(jit(_splash_attention))/{name}/{name}"
+        for name in ("splash_mha_dkv_no_residuals", "splash_mha_dq_no_residuals",
+                     "splash_mha_fwd_residuals")]
+    tilings = memory["attention_kernel_tilings"]
+    assert {kind: {name: call["calls"] for name, call in calls.items()}
+            for kind, calls in tilings.items()} == {
+        "global": {"splash_mha_fwd_residuals": 1, "splash_mha_dkv_no_residuals": 1},
+        "window": {"splash_mha_fwd_residuals": 4, "splash_mha_dkv_no_residuals": 4,
+                   "splash_mha_dq_no_residuals": 4}}
+    want = trunk.flash_block_sizes((1, 16384, 64, 128), "tpu", 128)
+    assert [(call["block_q"], call["block_kv"]) for call in tilings["window"].values()] == [
+        (want.block_q, want.block_kv), (want.block_q_dkv, want.block_kv_dkv),
+        (want.block_q_dq, want.block_kv_dq)]
+    assert all(call["block_kv"] == 512 for call in tilings["window"].values())
+    # a window layer's key-block axis is the two blocks its mask admits (a
+    # query block's own and the one before), not the 32 of the sequence
+    assert [call["grid"][-1] for call in tilings["window"].values()] == [2, 2, 2]
+    # the queries' gradient once a key block of 1024, [16, 64, 16384, 128]
+    # bf16, is the fused backward's: the global layer keeps it, and no
+    # kernel of a window layer writes anything near it
+    partials = 16 * 64 * 16384 * 128 * 2
+    assert tilings["global"]["splash_mha_dkv_no_residuals"]["largest_result_bytes"] == partials
+    assert all(call["largest_result_bytes"] <= partials // 8
+               for call in tilings["window"].values())

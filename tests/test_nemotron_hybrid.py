@@ -6,8 +6,11 @@ position at a time, layers that are ONE mixer each, un-gated squared-ReLU
 experts on the share; the refusals beside that path; the cut's arithmetic;
 and the benchmark's files for it.
 
-Tiny sizes on the CPU, except the AOT compile at published widths for a
-described (not attached) ``v5e`` chip.
+Tiny sizes on the CPU.  Here: the stack against its reference, the cut's
+arithmetic and the benchmark's files; what must fail the runner's comparison
+is ``tests/test_nemotron_hybrid_comparison.py``'s, the share, the refusals and the tiles are ``tests/test_nemotron_hybrid_share.py``'s,
+the AOT compiles at published widths for a described (not attached) ``v5e``
+chip ``tests/test_nemotron_hybrid_chip.py``'s.
 """
 
 import dataclasses
@@ -29,17 +32,10 @@ import nemotron_flops  # noqa: E402
 
 from __graft_entry__ import nemotron_labs_twotower_one_chip  # noqa: E402
 from learning_at_home_tpu.models import trunk  # noqa: E402
-from learning_at_home_tpu.models.transformer import (  # noqa: E402
-    DMoETransformerConfig,
-    DMoETransformerLM,
-)
+from learning_at_home_tpu.models.transformer import DMoETransformerLM  # noqa: E402
 from learning_at_home_tpu.ops import gate_norm  # noqa: E402
-from learning_at_home_tpu.ops import moe_dispatch  # noqa: E402
-from learning_at_home_tpu.ops import ssd  # noqa: E402
-from learning_at_home_tpu.ops import ssm_conv  # noqa: E402
 from learning_at_home_tpu.ops.ssd import ssd_chunked  # noqa: E402
 from learning_at_home_tpu.parallel.mesh import make_mesh  # noqa: E402
-from learning_at_home_tpu.parallel.sharded_moe import ShardedMixtureOfExperts  # noqa: E402
 
 REFERENCE = os.path.join(
     REPO, "benchmarks", "configs", "nemotron_labs_twotower_30b_a3b_reference.py")
@@ -88,6 +84,17 @@ def tiny():
     rs = np.random.RandomState(3)
     ids = jnp.asarray(rs.randint(0, cfg.vocab_size, (batch, cfg.seq_len + 1)))
     return model, cfg, params, ids[:, :-1], ids[:, 1:]
+
+
+@pytest.fixture(scope="module")
+def want(tiny):
+    """The reference's float32 logits, loss and gradients on the tiny
+    weights, each one compiled program, once a module."""
+    _, _, params, ids, tgt = tiny
+    logits = jax.jit(lambda p: reference.forward(p, ids, SIZES)[0])(params)
+    loss, grads = jax.jit(
+        lambda p: reference.loss_and_grads(p, ids, tgt, SIZES))(params)
+    return np.asarray(logits), float(loss), grads
 
 
 def _close(got, want, tol=1e-4, **kw):
@@ -183,7 +190,8 @@ def test_the_state_space_mixer_matches_the_recurrence_as_written(tiny, chunks):
     _close(state, want_state, 1e-5)
     assert 0.0 < float(decay_min) < 1.0
     # the chunk is how the program gets there, not what it computes
-    one, one_state, _ = _mixer(cfg, lp, x, cfg.seq_len)
+    one, one_state, _ = jax.jit(
+        lambda lp, x: _mixer(cfg, lp, x, cfg.seq_len))(lp, x)
     _close(got, one, 2e-6)
     _close(state, one_state, 2e-6)
 
@@ -234,15 +242,15 @@ def test_the_mixer_through_the_gate_norm_kernel_matches_the_reference(
         out, state, _ = mixer(lp, x)
         return jnp.sum(out * jnp.cos(out)) + jnp.sum(state)
 
-    plain = jax.grad(loss, argnums=(0, 1))(lp, x)
+    plain = jax.jit(jax.grad(loss, argnums=(0, 1)))(lp, x)
     calls = []
     _kernel_under_interpret(monkeypatch, calls)
-    want, want_state = reference.ssm_part(lp, x, sizes)
-    got, state, _ = mixer(lp, x)
+    want, want_state = jax.jit(lambda lp, x: reference.ssm_part(lp, x, sizes))(lp, x)
+    got, state, _ = jax.jit(mixer)(lp, x)  # traced once: one call of the kernel
     assert calls == [((2, s, d_inner), d_inner // groups, True, 0, True)]
     _close(got, want, 1e-5)
     _close(state, want_state, 1e-5)
-    through = jax.grad(loss, argnums=(0, 1))(lp, x)
+    through = jax.jit(jax.grad(loss, argnums=(0, 1)))(lp, x)
     for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(through),
                             jax.tree_util.tree_leaves(plain)):
         _close(g, w, 1e-5, err_msg=jax.tree_util.keystr(path))
@@ -327,43 +335,45 @@ def test_the_chunked_scans_gradients_match_the_sequential_references(tiny):
         _close(g, w, err_msg=jax.tree_util.keystr(path))
 
 
-def test_logits_and_loss_of_the_whole_stack_match_the_reference(tiny):
+def test_logits_and_loss_of_the_whole_stack_match_the_reference(tiny, want):
     model, cfg, params, ids, tgt = tiny
-    want, _, _ = reference.forward(params, ids, SIZES)
+    want_logits, want_loss, _ = want
     logits, aux = jax.jit(model.apply)(params, ids)
-    _close(logits, want)
-    want_loss = reference.loss(params, ids, tgt, SIZES)
+    _close(logits, want_logits)
     loss, metrics = jax.jit(model.loss_fn)(params, ids, tgt)
-    assert abs(float(loss) - float(want_loss)) <= 1e-5 * abs(float(want_loss))
+    assert abs(float(loss) - want_loss) <= 1e-5 * abs(want_loss)
     # the step's counters: four routers' rows, and the least decay
     assert metrics["expert_counts"].shape == (4, 16)
     assert int(metrics["expert_counts"].sum()) == 4 * ids.size * cfg.k
     assert float(metrics["dropped_fraction"]) == 0.0
     assert 0.0 < float(metrics["ssm_decay_min"]) < 1.0
-    least = min(float(_mixer(cfg, lp, x)[2]) for lp, x in _streams(model, params, ids)
+    decay_min = jax.jit(lambda lp, x: _mixer(cfg, lp, x)[2])
+    least = min(float(decay_min(lp, x)) for lp, x in _streams(model, params, ids)
                 if "ssm" in lp)
     assert float(metrics["ssm_decay_min"]) == pytest.approx(least, rel=1e-5)
 
 
 def _streams(model, params, ids):
-    """(layer's parameters, the stream that enters it), the program's."""
+    """(layer's parameters, the stream that enters it), the program's: a
+    layer a compiled call (one program a kind of layer), as
+    ``level_router_bias`` runs them."""
+    layer = jax.jit(model._layer, static_argnums=(4,))
     x = params["embed"][ids].astype(model.cfg.dtype)
     for index, lp in enumerate(params["layers"]):
         yield lp, x
-        x, _ = model._layer(lp, x, index, None, model.cfg.attention_layer(index))
+        x, _ = layer(lp, x, index, None, model.cfg.attention_layer(index))
 
 
-def test_gradients_of_every_parameter_match_the_reference(tiny):
+def test_gradients_of_every_parameter_match_the_reference(tiny, want):
     """The gradient of EVERY leaf of the nine layers, the table and the
     head to 1e-4 of the reference's largest entry of that leaf; the
     selection biases' are exactly zero on both sides."""
     model, _, params, ids, tgt = tiny
     grads = jax.jit(jax.grad(lambda p: model.loss_fn(p, ids, tgt)[0]))(params)
-    _, want = reference.loss_and_grads(params, ids, tgt, SIZES)
     names = []
     for (path, g), w in zip(
         jax.tree_util.tree_flatten_with_path(grads)[0],
-        jax.tree_util.tree_leaves(want),
+        jax.tree_util.tree_leaves(want[2]),
     ):
         name, w = jax.tree_util.keystr(path), np.asarray(w)
         names.append(name)
@@ -375,321 +385,6 @@ def test_gradients_of_every_parameter_match_the_reference(tiny):
     assert "['layers'][0]['ssm']['A_log']" in names
     assert "['layers'][7]['ssm']['conv_w']" in names
     assert "['layers'][5]['wk']" in names and "['layers'][8]['shared']['w_up']" in names
-
-
-# ---- (b) the negatives: the comparison can fail ----
-
-
-def _reference_with(**changes):
-    """A copy of the reference module with functions replaced."""
-    broken = harness.load_path(REFERENCE)
-    for name, value in changes.items():
-        setattr(broken, name, value)
-    return broken
-
-
-def _norm_then_gate(y, z, scale, groups, eps):
-    b, s, d_inner = y.shape
-    normed = reference.rms(
-        y.reshape(b, s, groups, d_inner // groups), 1.0, eps)
-    return normed.reshape(b, s, d_inner) * scale * jax.nn.silu(z)
-
-
-NEGATIVES = {
-    "a_scan_without_the_d_x_term": (
-        dict(skip_term=lambda d, x: 0.0 * x), ("ssm_rms", "layers_rms")),
-    "a_gate_applied_after_the_norm": (
-        dict(gated_norm=_norm_then_gate), ("ssm_rms", "layers_rms")),
-    "a_decay_taken_from_dt_without_softplus": (
-        dict(step_sizes=lambda dt, dt_bias: jnp.abs(dt + dt_bias)),
-        ("ssm_rms", "ssm_state_rms")),
-    "an_expert_with_relu_in_place_of_its_square": (
-        dict(activation=jax.nn.relu), ("layers_rms",)),
-}
-
-
-def test_the_stack_as_it_is_reads_inside_the_runner_tolerances(tiny):
-    model, _, params, ids, tgt = tiny
-    read = runner.compare_with_reference(
-        model, params, reference, TINY_FILE, ids[:1], tgt[:1])
-    limits = {**runner.TOLERANCES, "near_tie_share": 1.0}  # 32 positions
-    assert [k for k, lim in limits.items() if not read[k] <= lim] == []
-    assert len(read["embed_and_layers_rms"]) == 10  # the embedding, nine layers
-    assert len(read["ssm_layers_rms"]) == len(read["ssm_states_rms"]) == 4
-    assert len(read["near_tie_shares"]) == 4
-
-
-@pytest.mark.parametrize("name", sorted(NEGATIVES))
-def test_a_wrong_stack_fails_the_runner_tolerances(tiny, name):
-    """Each read OUTSIDE the tolerance: the comparison can fail.  (The
-    wrong side is the reference's copy; the program is as it is.)"""
-    model, _, params, ids, tgt = tiny
-    changes, outside = NEGATIVES[name]
-    read = runner.compare_with_reference(
-        model, params, _reference_with(**changes), TINY_FILE, ids[:1], tgt[:1])
-    for key in outside:
-        assert not read[key] <= runner.TOLERANCES[key], (key, read[key])
-
-
-def test_a_hidden_that_composes_another_stack_fails_the_runner_tolerances(tiny):
-    """``_hidden`` over a stack whose state-space layers are skipped (the
-    layers themselves as they are) reads outside ``hidden_token_median``."""
-    _, cfg, params, ids, tgt = tiny
-    model = DMoETransformerLM(cfg, _one_device_mesh())
-    layer = model._layer
-    model._layer = lambda lp, x, *rest: (
-        (x, None) if "ssm" in lp and x.shape[0] != 1 else layer(lp, x, *rest))
-    read = runner.compare_with_reference(
-        model, params, reference, TINY_FILE, ids[:1], tgt[:1])
-    assert read["hidden_token_median"] <= runner.TOLERANCES["hidden_token_median"]
-    model._layer = lambda lp, x, *rest: (
-        (x, None) if "ssm" in lp else layer(lp, x, *rest))
-    whole = model._hidden(params, ids[:1])[0]
-    right = DMoETransformerLM(cfg, _one_device_mesh())._hidden(params, ids[:1])[0]
-    rel = np.median(np.linalg.norm(np.asarray(whole - right), axis=-1)
-                    / np.linalg.norm(np.asarray(right), axis=-1))
-    assert rel > runner.TOLERANCES["hidden_token_median"]
-
-
-def test_lower_precisions_fail_the_runner_tolerances(tiny):
-    """The reference with float8 operands in the program's place reads
-    outside the layer and logits limits, with bf16 operands inside; the
-    program's scan with bf16 decays reads worse than with float32 ones."""
-    model, _, params, ids, tgt = tiny
-    for dtype, inside in ((jnp.float8_e4m3fn, False), (jnp.bfloat16, True)):
-        read = runner.compare_with_reference(
-            model, params, reference, TINY_FILE, ids[:1], tgt[:1],
-            operand_dtype=dtype)
-        for key in ("layers_rms", "ssm_rms", "logits_rms"):
-            assert (read[key] <= runner.TOLERANCES[key]) is inside, (dtype, key)
-    exact = runner.compare_with_reference(
-        model, params, reference, TINY_FILE, ids[:1], tgt[:1])
-    rough = runner.compare_with_reference(
-        model, params, reference, TINY_FILE, ids[:1], tgt[:1],
-        decay_dtype=jnp.bfloat16)
-    assert rough["ssm_rms"] > 100 * exact["ssm_rms"]
-    assert rough["ssm_state_rms"] > 100 * exact["ssm_state_rms"]
-
-
-# ---- (c) the share ----
-
-
-def _layer_of_all_experts(seed=5, d=32, f=16, f_shared=24, experts=16, k=3, n=96):
-    rs = np.random.RandomState(seed)
-
-    def w(*shape):
-        return jnp.asarray(rs.randn(*shape) / np.sqrt(shape[-2]), jnp.float32)
-
-    moe = {"gate": w(d, experts) * 4, "w_up": w(experts, d, f),
-           "w_down": w(experts, f, d),
-           "router_bias": jnp.asarray(rs.uniform(-0.1, 0.1, experts), jnp.float32)}
-    lp = {"norm": {"scale": jnp.asarray(rs.uniform(0.5, 1.5, d), jnp.float32)},
-          "moe": moe, "shared": {"w_up": w(d, f_shared), "w_down": w(f_shared, d)}}
-    x = jnp.asarray(rs.randn(1, n, d), jnp.float32)
-    sizes = dict(SIZES, pattern="E", experts_per_token=k, held=None)
-    # loads levelled, as the set-up leaves them: no share's buffer overflows
-    m = reference.rms(x, lp["norm"]["scale"], sizes["norm_eps"]).reshape(-1, d)
-    moe["router_bias"], _ = moe_dispatch.level_bias(
-        jax.nn.sigmoid(m @ moe["gate"]), moe["router_bias"], k)
-    return lp, x, sizes
-
-
-def test_the_four_shares_add_up_to_the_uncut_layer():
-    """The routed parts the four shares give (each its own quarter of the
-    16 experts, through the program's share path), with the shared expert
-    counted once, equal the uncut reference's layer; so do the reference's
-    own shares."""
-    lp, x, sizes = _layer_of_all_experts()
-    d, experts, held, k = x.shape[-1], 16, 4, 3
-    want, _, _ = reference.layer(lp, x, sizes, 0)
-    m = reference.rms(x, lp["norm"]["scale"], sizes["norm_eps"]).reshape(-1, d)
-    # what the four chips compute alike: once
-    total = trunk.gated_mlp(lp["shared"], m, trunk.squared_relu)
-    ref_total = reference.relu2_mlp(
-        lp["shared"]["w_up"], lp["shared"]["w_down"], m, lambda a: a)
-    _close(total, ref_total, 1e-5)
-    for first in range(0, experts, held):
-        cut = {**lp["moe"], **{name: lp["moe"][name][first:first + held]
-                               for name in ("w_up", "w_down")}}
-        share = ShardedMixtureOfExperts(
-            _one_device_mesh(), hidden_dim=d, num_experts=experts, k=k,
-            dtype=jnp.float32, ffn_dim=16, expert_kind="relu2",
-            routing="dropless", router_score="sigmoid", router_bias=True,
-            routed_scale=2.5, held_experts=held, first_held_expert=first)
-        part, aux = jax.jit(share)(cut, m)
-        assert float(aux["dropped_fraction"]) == 0.0, first
-        total = total + part
-        ref_total = ref_total + reference.routed_part(
-            cut, m, dict(sizes, held=(first, held)))
-    scale = np.abs(np.asarray(want - x)).max()
-    for summed in (total, ref_total):
-        np.testing.assert_allclose(
-            np.asarray(x + summed.reshape(x.shape)), np.asarray(want), rtol=0,
-            atol=1e-5 * scale)
-
-
-def test_the_ungated_kind_whole_runs_two_grouped_matmuls_forward():
-    """``relu2`` on the dropless path with every expert here: the result is
-    the reference's, the expert stack holds two matrices, and the traced
-    forward holds two ``ragged_dot`` where a gated kind holds three."""
-    lp, x, sizes = _layer_of_all_experts()
-    d = x.shape[-1]
-    m = reference.rms(x, lp["norm"]["scale"], sizes["norm_eps"]).reshape(-1, d)
-    calls = {}
-    for kind in ("relu2", "gated_silu"):
-        moe = ShardedMixtureOfExperts(
-            _one_device_mesh(), hidden_dim=d, num_experts=16, k=3,
-            dtype=jnp.float32, ffn_dim=16, expert_kind=kind, routing="dropless",
-            router_score="sigmoid", router_bias=True, routed_scale=2.5)
-        p = moe.init_params(jax.random.PRNGKey(0))
-        assert ("w_gate" in p) is (kind != "relu2")
-        calls[kind] = str(jax.make_jaxpr(moe)(p, m)).count("ragged_dot_general[")
-    assert calls == {"relu2": 2, "gated_silu": 3}
-    whole = ShardedMixtureOfExperts(
-        _one_device_mesh(), hidden_dim=d, num_experts=16, k=3,
-        dtype=jnp.float32, ffn_dim=16, expert_kind="relu2", routing="dropless",
-        router_score="sigmoid", router_bias=True, routed_scale=2.5)
-    got, _ = jax.jit(whole)(lp["moe"], m)
-    _close(got, reference.routed_part(lp["moe"], m, sizes), 1e-5)
-
-
-def test_set_up_levels_the_four_routers_and_only_them(tiny):
-    """``level_router_bias`` levels the four mixture layers' biases on the
-    stream each layer's own input is (its router reads the layer's ONE
-    norm), touches no other leaf, and the step's rule then moves them."""
-    model, cfg, params, ids, _ = tiny
-    pool = [ids, jnp.roll(ids, 5, axis=1)]
-    levelled, loads = model.level_router_bias(params, pool)
-    assert len(loads) == 4
-    assert all(after <= before and after < 1.8 for before, after in loads)
-    changed = [
-        jax.tree_util.keystr(path)
-        for (path, a), b in zip(
-            jax.tree_util.tree_flatten_with_path(params)[0],
-            jax.tree_util.tree_leaves(levelled))
-        if not np.array_equal(np.asarray(a), np.asarray(b))]
-    assert changed == [f"['layers'][{i}]['moe']['router_bias']" for i in (1, 3, 6, 8)]
-    # the first mixture layer's bias is level_bias on its own input's scores
-    lp = params["layers"][1]
-    streams = [x for p in pool for i, (_, x) in enumerate(_streams(model, params, p)) if i == 1]
-    scores = jnp.concatenate([jax.nn.sigmoid(model.moe.router_logits(
-        lp["moe"], model._norm(lp["norm"], x).reshape(-1, cfg.d_model)))
-        for x in streams])
-    want, _ = moe_dispatch.level_bias(scores, lp["moe"]["router_bias"], cfg.k)
-    np.testing.assert_allclose(
-        np.asarray(levelled["layers"][1]["moe"]["router_bias"]), np.asarray(want),
-        atol=1e-6)
-    _, _, optimizer, _ = nemotron_labs_twotower_one_chip(_one_device_mesh(), tiny=True)
-    before = [np.asarray(levelled["layers"][i]["moe"]["router_bias"]) for i in (1, 3, 6, 8)]
-    own = jax.tree_util.tree_map(jnp.copy, levelled)  # the step donates them
-    opt_state = model.init_opt_state(optimizer, own)
-    stepped, _, _, metrics = model.make_train_step(optimizer)(
-        own, opt_state, ids, jnp.roll(ids, -1, axis=1))
-    for was, i in zip(before, (1, 3, 6, 8)):
-        moved = np.asarray(stepped["layers"][i]["moe"]["router_bias"]) - was
-        np.testing.assert_allclose(np.abs(moved[moved != 0]), 0.001, rtol=1e-4)
-        assert (moved != 0).any()
-    assert "expert_counts" not in metrics and "ssm_decay_min" in metrics
-
-
-# ---- (d) the refusals beside the path ----
-
-
-@pytest.mark.parametrize("changes, error, match", [
-    ({"seq_parallel": True}, NotImplementedError, "mixer_pattern"),
-    ({"mixer_pattern": ("ssm",) * 9}, ValueError, "one 'moe'"),
-    ({"mixer_pattern": ("ssm", "moe")}, ValueError, "each of the 9 layers"),
-    ({"mixer_pattern": ("ssm", "dense") + ("moe",) * 7}, ValueError, "'ssm', 'attention' or 'moe'"),
-    ({"ssm_state_dim": None}, ValueError, "ssm_state_dim"),
-    ({"ffn_pattern": ("moe",) * 9}, ValueError, "ONE mixer"),
-    ({"mtp_layers": 1}, ValueError, "ONE mixer"),
-    ({"expert_kind": "gelu"}, ValueError, "must not be 'gelu'"),
-    ({"expert_kind": "gelu", "shared_experts": 0}, NotImplementedError, "not 'gelu'"),
-    ({"expert_kind": "relu3"}, ValueError, "'relu2'"),
-])
-def test_a_configuration_the_step_cannot_run_is_refused_by_name(
-        tiny, changes, error, match):
-    _, cfg, _, _, _ = tiny
-    with pytest.raises(error, match=match):
-        DMoETransformerLM(dataclasses.replace(cfg, **changes), _one_device_mesh())
-
-
-def test_the_cached_decoder_refuses_the_stack_by_name(tiny):
-    model, _, params, ids, _ = tiny
-    with pytest.raises(NotImplementedError, match="recurrent state"):
-        model.generate(params, ids[:, :4], 2, use_cache=True)
-    out = model.generate(params, ids[:1, :4], 2)  # the full forward decodes
-    assert out.shape == (1, 6)
-
-
-def test_a_stack_that_describes_no_mixer_is_the_program_of_before():
-    """``mixer_pattern=None``: every layer an attention block and a
-    feed-forward part, its parameters under the names they had."""
-    cfg = DMoETransformerConfig(
-        vocab_size=64, d_model=16, n_layers=2, n_heads=2, seq_len=8,
-        num_experts=4)
-    assert cfg.mixer_pattern is None and cfg.mixture_layers() == 2
-    params = DMoETransformerLM(cfg, _one_device_mesh()).init_params(
-        jax.random.PRNGKey(0))
-    assert sorted(params["layers"][0]) == [
-        "ln1", "ln2", "moe", "wk", "wo", "wq", "wv"]
-
-
-# ---- (e) the kernel's tiles and the grouped matmul's at this model's shapes ----
-
-
-def test_flash_block_sizes_at_32_heads_of_128():
-    sizes = trunk.flash_block_sizes((1, 16384, 32, 128), "tpu")
-    assert (sizes.block_q, sizes.block_kv, sizes.block_kv_compute) == (1024, 1024, 512)
-    assert (sizes.block_q_dkv, sizes.block_kv_dkv, sizes.block_kv_dkv_compute) == (
-        1024, 1024, 512)
-    assert sizes.use_fused_bwd_kernel
-    # 16 query heads a key/value head: the shape rule asks for neither count
-    assert trunk.flash_block_sizes((1, 16384, 2, 128), "tpu") == sizes
-
-
-GROUPED_MATMUL_ANSWERS_BEFORE = [
-    # (m, k, n, weights_gradient) -> tiles: every answer a cell rests on
-    ((131072, 2048, 1024, False), (256, 2048, 1024)),
-    ((131072, 1024, 2048, False), (256, 1024, 2048)),
-    ((131072, 2048, 1024, True), (256, 1024, 1024)),
-    ((98304, 2560, 768, False), (256, 2560, 768)),
-    ((98304, 768, 2560, False), (256, 768, 2560)),
-    ((98304, 2560, 768, True), (256, 1280, 768)),
-    ((16384, 6144, 2048, False), (256, 2048, 1024)),
-    ((65536, 2048, 1536, False), (256, 2048, 768)),
-    ((65536, 1536, 2048, False), (256, 1536, 1024)),
-    ((65536, 2048, 1536, True), (256, 1024, 768)),
-    ((256, 2048, 1024, False), None),  # under GROUPED_MATMUL_MIN_ROWS
-    # 1000 = 7.8 x 128: no multiple of the lanes divides, and no half lane
-    ((131072, 2048, 1000, False), None),
-    ((131072, 1000, 2048, True), None),
-]
-
-
-@pytest.mark.parametrize("shape, tiles", GROUPED_MATMUL_ANSWERS_BEFORE)
-def test_grouped_matmul_tiles_answers_of_before_are_unchanged(shape, tiles):
-    m, k, n, weights_gradient = shape
-    assert moe_dispatch.grouped_matmul_tiles(
-        m, k, n, jnp.bfloat16, weights_gradient) == tiles
-
-
-@pytest.mark.parametrize("shape, tiles", [
-    # this model's six calls a layer, over the share's buffer of 49,152 rows:
-    # 1,856 = 14.5 x 128 is tiled at its cover, 1,920 (PERF.md section 6, PR 39)
-    ((49152, 2688, 1856, False), (256, 896, 1920)),  # up; down's rows' gradient
-    ((49152, 1856, 2688, False), (256, 1920, 896)),  # down; up's rows' gradient
-    ((49152, 2688, 1856, True), (256, 896, 640)),
-    ((49152, 1856, 2688, True), (256, 640, 896)),
-    ((49152, 2688, 1856 + 1, False), None),  # any other remainder: none
-    ((256, 2688, 1856, False), None),
-])
-def test_grouped_matmul_tiles_at_a_width_of_half_a_lane_tile(shape, tiles):
-    m, k, n, weights_gradient = shape
-    assert moe_dispatch.share_buffer_rows(16384, 6, 32, 128) == 49152
-    assert moe_dispatch.grouped_matmul_tiles(
-        m, k, n, jnp.bfloat16, weights_gradient) == tiles
-    assert moe_dispatch.grouped_matmul_tiles(m, k, n, jnp.float32) is None
 
 
 def test_flops_of_the_cell_are_the_issue_arithmetic():
@@ -815,206 +510,3 @@ def test_a_program_without_the_recipe_fails_at_once_with_no_result(tmp_path):
         cwd=REPO, env=env, capture_output=True, text=True, timeout=120)
     assert run.returncode == 2 and "no recipe" in run.stderr
     assert not run.stdout.strip()
-
-
-# ---- (f) the chip's compiler accepts the step at published widths ----
-
-
-def test_the_whole_step_fits_the_chip(v5e_chip, monkeypatch):
-    """The nine-layer train step at published widths, compiled for a
-    described chip (nothing runs): 1,624,837,632 parameters, the
-    compiler's own count of what is live in the step between a quarter of
-    the chip's memory (the benchmark's floor for a cell) and 0.9 of it
-    (9.92 GB, 58.7 %, when this was written: ISSUE.md expected 47-65 %;
-    10.31 GB, 61.0 %, with the scan's kernels and what remat keeps of them: PR 40),
-    the blocked kernel at heads of 128 in the one attention layer, once
-    forward (remat keeps its residuals) and once fused backward, and the
-    head's three products a pass."""
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    memory = probe.step_memory(v5e_chip, "nemotron_labs_twotower_one_chip")
-    assert memory["parameters"] == 1_624_837_632
-    assert 0.25 < memory["share_of_chip"] < 0.9, memory
-    # four mixture layers x (2 forward + 2 recomputed + 4 backward) calls,
-    # each at the tile rule's answer for a width of 1,856 at its cover
-    assert memory["grouped_matmul_tilings"] == {
-        "256,896,1920": 4 * 3, "256,1920,896": 4 * 3,
-        "256,896,640": 4, "256,640,896": 4}
-    assert memory["loss_layer_products"] == 3
-    assert memory["attention_kernel_calls"] == {
-        "splash_mha_fwd_residuals": 1, "splash_mha_dkv_no_residuals": 1}
-    assert memory["kept_residual_bytes"] == 32 * 16384 * (128 * 2 + 4)
-    # and the results of the attention layer's products (PR 53): q, k, v
-    # and the output projection's, bf16 [16384, 4096 + 256 + 256 + 2688],
-    # 0.24 GB NAMED; the backward pass runs none of the four a second time.
-    # Of the output projection's 88 MB nothing is held: in a layer of one
-    # mixer no backward equation reads it, so the checkpoint drops it from
-    # its residuals and the compiled step has no ``reduce_precision`` of it
-    assert memory["kept_product_bytes"] == 16384 * (4096 + 2 * 256 + 2688) * 2
-    assert memory["recomputed_attention_products"] == 0
-    calls = memory["attention_kernel_tilings"]["global"]
-    assert {name: (c["calls"], c["block_q"], c["block_kv"]) for name, c in calls.items()} == {
-        "splash_mha_fwd_residuals": (1, 1024, 1024),
-        "splash_mha_dkv_no_residuals": (1, 1024, 1024)}
-    # 32 query heads over 2 key/value heads, as they come
-    assert calls["splash_mha_fwd_residuals"]["grid"][0] == 32
-    # the scan's kernels, once forward (remat keeps the output and the
-    # entering states: the recompute holds no scan) and once backward a
-    # state-space layer, every call under ``ssm/scan``
-    assert memory["scan_kernel_calls"] == {
-        "ssd_chunk_fwd": {"calls": 4, "under_ssm_scan": 4},
-        "ssd_chunk_bwd": {"calls": 4, "under_ssm_scan": 4}}
-    assert memory["kept_scan_bytes"] == 4 * (
-        16384 * 4096 * 2 + 128 * 64 * 64 * 128 * 4)
-    # the convolution's one pass forward, recomputed (remat keeps nothing
-    # of it) and backward, for each of ``x``, ``B`` and ``C`` of a
-    # state-space layer, every call under ``ssm/conv``, and no float32 copy
-    # of ``x B C`` or of a part written there (PR 41)
-    assert memory["conv_kernel_calls"] == {
-        "ssm_conv_fwd": {"calls": 24, "under_ssm_conv": 24},
-        "ssm_conv_bwd": {"calls": 12, "under_ssm_conv": 12}}
-    assert memory["float32_arrays_under_ssm_conv"] == []
-    # the skip, the gate and the norm as one pass: forward, recomputed
-    # (remat keeps nothing of it) and backward a state-space layer, every
-    # call under ``ssm/gate_norm``, and no float32 ``[1, 16384, 4096]``
-    # written there or under ``ssm/scan`` on their behalf (PR 47; the
-    # parent's live count read 9,878,984,192)
-    assert memory["gate_norm_kernel_calls"] == {
-        "gate_norm_fwd": {"calls": 2 * 4, "under_ssm_gate_norm": 2 * 4},
-        "gate_norm_bwd": {"calls": 4, "under_ssm_gate_norm": 4}}
-    assert memory["float32_arrays_beside_gate_norm"] == []
-
-
-def test_the_scan_kernels_compile_for_the_chip_at_the_cells_shape(v5e_chip):
-    """``ssd_chunk_fwd`` and ``ssd_chunk_bwd`` at ``[1, 16384, 64, 64]``,
-    state 128, 8 groups, chunks of 128, bf16, compiled for a described
-    chip (nothing runs): Mosaic takes the tiles, the transposes and the
-    VMEM the kernels ask for."""
-    one = jax.sharding.SingleDeviceSharding(v5e_chip)
-    s, h, p, g, n = (CELL_FILE[k] for k in (
-        "seq_len", "mamba_num_heads", "mamba_head_dim", "n_groups",
-        "ssm_state_size"))
-    assert (s, h, p, g, n, CELL_FILE["chunk_size"]) == (16384, 64, 64, 8, 128, 128)
-    assert ssd.kernel_fits((1, s, h, p), (1, s, g, n), 128, "tpu")
-
-    def shaped(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
-
-    args = (shaped((1, s, h, p), jnp.bfloat16), shaped((1, s, h), jnp.float32),
-            shaped((h,), jnp.float32), shaped((1, s, g, n), jnp.bfloat16),
-            shaped((1, s, g, n), jnp.bfloat16))
-
-    def loss(*a):
-        y, state = ssd.ssd_chunked_kernel(*a, 128)
-        return jnp.sum(y.astype(jnp.float32)) + jnp.sum(state)
-
-    with probe.no_compile_cache():
-        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
-            *args).compile().as_text()
-    assert {name: c["calls"] for name, c in probe.scan_kernel_calls(text).items()} == {
-        "ssd_chunk_fwd": 1, "ssd_chunk_bwd": 1}
-
-
-def test_the_convolutions_kernels_compile_for_the_chip_at_the_cells_shape(v5e_chip):
-    """``ssm_conv_fwd`` and ``ssm_conv_bwd`` as the cell's mixer calls them,
-    compiled for a described chip (nothing runs): ``x`` (4,096 channels),
-    ``B`` and ``C`` (1,024 each) read out of the in-projection's
-    ``[1, 16384, 10304]`` bf16 where they lie, four taps: Mosaic takes the
-    blocks at their offsets, the halos' tiles, the rolls along the sublanes
-    and the VMEM the two scratches ask for."""
-    one = jax.sharding.SingleDeviceSharding(v5e_chip)
-    s, taps = CELL_FILE["seq_len"], CELL_FILE["conv_kernel"]
-    d_inner = CELL_FILE["mamba_num_heads"] * CELL_FILE["mamba_head_dim"]
-    group = CELL_FILE["n_groups"] * CELL_FILE["ssm_state_size"]
-    wide = 2 * d_inner + 2 * group + CELL_FILE["mamba_num_heads"]
-    parts = [(d_inner, d_inner), (2 * d_inner, group), (2 * d_inner + group, group)]
-    assert (s, taps, wide, parts) == (
-        16384, 4, 10304, [(4096, 4096), (8192, 1024), (9216, 1024)])
-    assert all(ssm_conv.conv_kernel_fits((1, s, c), taps, "tpu", first)
-               for first, c in parts)
-
-    def shaped(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
-
-    def loss(zxbcdt, w, b):  # the square: its cotangent reads the forward's result
-        lo = d_inner
-        return sum(jnp.sum(ssm_conv.causal_conv_silu_kernel(
-            zxbcdt, w[first - lo:first - lo + c], b[first - lo:first - lo + c],
-            first).astype(jnp.float32) ** 2) for first, c in parts)
-
-    c = d_inner + 2 * group
-    with probe.no_compile_cache():
-        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
-            shaped((1, s, wide), jnp.bfloat16), shaped((c, taps), jnp.bfloat16),
-            shaped((c,), jnp.bfloat16)).compile().as_text()
-    calls = probe.scan_kernel_calls(text, "ssm_conv", "ssm/conv")
-    assert {name: entry["calls"] for name, entry in calls.items()} == {
-        "ssm_conv_fwd": 3, "ssm_conv_bwd": 3}
-
-
-@pytest.mark.parametrize("cell", ["nemotron", "olmo-hybrid"])
-def test_the_gate_norm_kernels_compile_for_the_chip_at_the_cells_shapes(v5e_chip, cell):
-    """``gate_norm_fwd`` and ``gate_norm_bwd`` as the two hybrid cells'
-    mixers call them, compiled for a described chip (nothing runs; here
-    because the described chip's library is one file's to load).  Nemotron:
-    4,096 channels in groups of 512 under a scale a channel, the gate
-    first, ``z`` at column 0 of the in-projection's ``[1, 16384, 10304]``
-    bf16, the skip ``y + D x`` inside.  Olmo-Hybrid: 5,760 channels, heads
-    of 192 two to a block of 384 lanes under one shared scale, the norm
-    first, ``z`` at column 11,520 of ``[1, 16384, 17340]``.  Mosaic takes
-    the blocks at their offsets, the masked sums along the lanes and the
-    partial sums' blocks."""
-    one = jax.sharding.SingleDeviceSharding(v5e_chip)
-    s, bf16 = CELL_FILE["seq_len"], jnp.bfloat16
-    c, wide, first, group, n_scale, gate_first, heads = {
-        "nemotron": (4096, 10304, 0, 512, 4096, True, 64),
-        "olmo-hybrid": (5760, 17340, 11520, 192, 192, False, 0)}[cell]
-    assert gate_norm.gate_norm_fits((1, s, c), group, "tpu", first)
-
-    def shaped(*shape):
-        return jax.ShapeDtypeStruct(shape, bf16, sharding=one)
-
-    def loss(y, z, scale, skip):  # the square: its cotangent reads the result
-        return jnp.sum(gate_norm.gated_rms_norm_kernel(
-            y, z, scale, group, 1e-5, gate_first, first, skip).astype(jnp.float32) ** 2)
-
-    skip = (shaped(1, s, c), shaped(heads)) if heads else None
-    with probe.no_compile_cache():
-        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3) if heads else (0, 1, 2))).lower(
-            shaped(1, s, c), shaped(1, s, wide), shaped(n_scale), skip).compile().as_text()
-    calls = probe.scan_kernel_calls(text, "gate_norm", "gate_norm")
-    assert {name: entry["calls"] for name, entry in calls.items()} == {
-        "gate_norm_fwd": 1, "gate_norm_bwd": 1}
-
-
-def test_the_convolutions_kernels_compile_at_the_delta_mixers_shape(v5e_chip):
-    """The same two kernels as Olmo-Hybrid's delta mixer calls them
-    (``trunk.delta_mixer``; here because the described chip's library is
-    one file's to load): ``[q | k]`` as ONE call of 5,760 channels at
-    column 0 and ``v`` as one at column 5,760 of the in-projection's ``[1,
-    16384, 17340]`` bf16, four taps, no bias: blocks of 384 channels, the
-    last of a wide array that is no multiple of the lanes."""
-    one = jax.sharding.SingleDeviceSharding(v5e_chip)
-    olmo = harness.load_json(os.path.join(
-        REPO, "benchmarks", "configs", "olmo-hybrid-7b.json"))
-    s, taps = olmo["seq_len"], olmo["linear_conv_kernel_dim"]
-    heads = olmo["linear_num_key_heads"]
-    d_qk = 2 * heads * olmo["linear_key_head_dim"]
-    d_v = heads * olmo["linear_value_head_dim"]
-    wide = d_qk + 2 * d_v + 2 * heads
-    assert (s, taps, d_qk, d_v, wide) == (16384, 4, 5760, 5760, 17340)
-    assert all(ssm_conv.conv_kernel_fits((1, s, 5760), taps, "tpu", first)
-               for first in (0, d_qk))
-
-    def loss(proj, w):
-        return sum(jnp.sum(ssm_conv.causal_conv_silu_kernel(
-            proj, w[first:first + 5760], jnp.zeros((5760,), jnp.float32),
-            first).astype(jnp.float32) ** 2) for first in (0, d_qk))
-
-    with probe.no_compile_cache():
-        text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
-            jax.ShapeDtypeStruct((1, s, wide), jnp.bfloat16, sharding=one),
-            jax.ShapeDtypeStruct((d_qk + d_v, taps), jnp.bfloat16, sharding=one),
-        ).compile().as_text()
-    calls = probe.scan_kernel_calls(text, "ssm_conv", "ssm/conv")
-    assert {name: entry["calls"] for name, entry in calls.items()} == {
-        "ssm_conv_fwd": 2, "ssm_conv_bwd": 2}

@@ -5,7 +5,10 @@ layers three to every full-attention layer, the norm on each part's
 OUTPUT, a stack with no mixture layer; the refusals beside that path; the
 cut's arithmetic; and the benchmark's files for it.
 
-Tiny sizes on the CPU.
+Tiny sizes on the CPU.  The runner's comparison and what must fail it is
+``tests/test_olmo_hybrid_comparison.py``'s, the stack without a mixture layer
+under its train step ``tests/test_olmo_hybrid_no_mixture.py``'s, the AOT compiles at
+published widths ``tests/test_olmo_hybrid_chip.py``'s.
 """
 
 import dataclasses
@@ -25,7 +28,7 @@ sys.path.insert(0, os.path.join(REPO, "benchmarks"))
 import harness  # noqa: E402  (benchmarks/harness.py: imports no jax)
 import olmohybrid_flops  # noqa: E402
 
-from __graft_entry__ import k_exaone_one_chip, olmo_hybrid_7b_one_chip  # noqa: E402
+from __graft_entry__ import olmo_hybrid_7b_one_chip  # noqa: E402
 from learning_at_home_tpu.models import trunk  # noqa: E402
 from learning_at_home_tpu.models.transformer import (  # noqa: E402
     AttentionLayer,
@@ -82,6 +85,17 @@ def tiny():
     return model, cfg, params, ids, tgt
 
 
+@pytest.fixture(scope="module")
+def want(tiny):
+    """The reference's float32 logits, loss and gradients on the tiny
+    weights, each one compiled program, once a module."""
+    _, _, params, ids, tgt = tiny
+    logits = jax.jit(lambda p: reference.forward(p, ids, SIZES))(params)
+    loss, grads = jax.jit(
+        lambda p: reference.loss_and_grads(p, ids, tgt, SIZES))(params)
+    return logits, float(loss), grads
+
+
 def _close(got, want, tol=1e-4):
     got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
     np.testing.assert_allclose(got, want, atol=tol * max(np.abs(want).max(), 1e-6))
@@ -136,9 +150,9 @@ def test_the_delta_mixer_matches_the_rule_as_written(tiny, chunk):
     model, cfg, params, ids, _ = tiny
     lp = params["layers"][1]
     x = jax.random.normal(jax.random.PRNGKey(2), (2, cfg.seq_len, cfg.d_model))
-    got, state, decay_min, beta_max = trunk.delta_mixer(
-        lp["delta"], x, cfg.n_heads, chunk, cfg.norm_eps)
-    want, want_state = reference.delta_part(lp, x, SIZES)
+    got, state, decay_min, beta_max = jax.jit(lambda p, x: trunk.delta_mixer(
+        p, x, cfg.n_heads, chunk, cfg.norm_eps))(lp["delta"], x)
+    want, want_state = jax.jit(lambda lp, x: reference.delta_part(lp, x, SIZES))(lp, x)
     _close(got, want)
     _close(state, want_state)
     assert 0.0 < float(decay_min) < 1.0 < float(beta_max) <= 2.0
@@ -177,7 +191,7 @@ def test_the_mixer_through_the_gate_norm_kernel_matches_the_reference(
         out, state, *_ = mixer(lp, x)
         return jnp.sum(out * jnp.cos(out)) + jnp.sum(state)
 
-    plain = jax.grad(loss, argnums=(0, 1))(lp, x)
+    plain = jax.jit(jax.grad(loss, argnums=(0, 1)))(lp, x)
     calls = []
 
     def through_the_kernel(y, z, scale, group, eps, gate_first, first=0, skip=None):
@@ -187,12 +201,12 @@ def test_the_mixer_through_the_gate_norm_kernel_matches_the_reference(
             y, z, scale, group, eps, gate_first, first, skip, interpret=True)
 
     monkeypatch.setattr(trunk, "gated_rms_norm", through_the_kernel)
-    want, want_state = reference.delta_part(lp, x, sizes)
-    got, state, *_ = mixer(lp, x)
+    want, want_state = jax.jit(lambda lp, x: reference.delta_part(lp, x, sizes))(lp, x)
+    got, state, *_ = jax.jit(mixer)(lp, x)  # traced once: one call of the kernel
     assert calls == [((2, s, d_v), dv, False, d_qk + d_v, None)]
     _close(got, want)
     _close(state, want_state)
-    through = jax.grad(loss, argnums=(0, 1))(lp, x)
+    through = jax.jit(jax.grad(loss, argnums=(0, 1)))(lp, x)
     for g, w in zip(jax.tree_util.tree_leaves(through), jax.tree_util.tree_leaves(plain)):
         _close(g, w, 1e-5)
 
@@ -215,213 +229,30 @@ def test_the_convolutions_read_zeros_before_the_sequence_and_have_no_bias(tiny):
     _close(got, v.reshape(1, 8, -1))
 
 
-def test_logits_and_loss_of_the_whole_stack_match_the_reference(tiny):
+def test_logits_and_loss_of_the_whole_stack_match_the_reference(tiny, want):
     """float32 on both sides: what is left is the order of the sums (the
     chunked rule against the scan over positions, the chunked
     cross-entropy against the whole softmax)."""
     model, _, params, ids, tgt = tiny
-    logits, _ = model.apply(params, ids)
-    _close(logits, reference.forward(params, ids, SIZES), 2e-4)
-    loss, metrics = model.loss_fn(params, ids, tgt)
-    assert abs(float(loss) - float(reference.loss(params, ids, tgt, SIZES))) < 2e-5
+    logits, _ = jax.jit(model.apply)(params, ids)
+    _close(logits, want[0], 2e-4)
+    loss, metrics = jax.jit(model.loss_fn)(params, ids, tgt)
+    assert abs(float(loss) - want[1]) < 2e-5
     assert set(metrics) == {"ce", "delta_decay_min", "delta_beta_max"}
 
 
-def test_gradients_of_every_parameter_match_the_reference(tiny):
+def test_gradients_of_every_parameter_match_the_reference(tiny, want):
     """Every leaf, relative to the leaf's own largest gradient: 1e-3,
     float32 sums in another order through eight layers and two hundred
     and fifty-six steps of a recurrence."""
     model, _, params, ids, tgt = tiny
-    got = jax.grad(lambda p: model.loss_fn(p, ids, tgt)[0])(params)
-    _, want = reference.loss_and_grads(params, ids, tgt, SIZES)
+    got = jax.jit(jax.grad(lambda p: model.loss_fn(p, ids, tgt)[0]))(params)
     flat_got = jax.tree_util.tree_flatten_with_path(got)[0]
-    flat_want = jax.tree_util.tree_leaves(want)
+    flat_want = jax.tree_util.tree_leaves(want[2])
     assert len(flat_got) == len(flat_want) == len(jax.tree_util.tree_leaves(params))
     for (path, a), b in zip(flat_got, flat_want):
         assert float(jnp.abs(b).max()) > 0, jax.tree_util.keystr(path)
         _close(a, b, 1e-3)
-
-
-# ---- (b) the runner's comparison, and what must fail it ----
-
-
-def _reference_with(**changes):
-    """A copy of the reference module with functions replaced."""
-    broken = harness.load_path(REFERENCE)
-    for name, value in changes.items():
-        setattr(broken, name, value)
-    return broken
-
-
-def _read(model, params, ids, tgt, module=reference, **how):
-    return runner.compare_with_reference(
-        model, params, module, TINY_FILE, ids[:1], tgt[:1], **how)
-
-
-def _outside(read):
-    return [k for k, lim in runner.TOLERANCES.items() if not read[k] <= lim]
-
-
-def test_the_stack_as_it_is_reads_inside_the_runner_tolerances(tiny):
-    model, _, params, ids, tgt = tiny
-    read = _read(model, params, ids, tgt)
-    assert _outside(read) == []
-    assert len(read["embed_and_layers_rms"]) == 9  # the embedding, eight layers
-    assert len(read["delta_layers_rms"]) == len(read["delta_states_rms"]) == 6
-    # the mixer's output by its worst layer, the state by its median layer
-    assert read["delta_rms"] == max(read["delta_layers_rms"])
-    assert read["delta_state_rms"] == pytest.approx(
-        np.median(read["delta_states_rms"]))
-    assert read["delta_state_rms_max"] == max(read["delta_states_rms"])
-    assert read["near_tie_share"] == 0.0
-
-
-WRONG_REFERENCES = {
-    "a_write_strength_without_the_factor_two": (
-        dict(write_strength=jax.nn.sigmoid), ("delta_rms", "delta_state_rms")),
-    "a_gate_applied_before_the_norm": (
-        dict(output_gate=lambda o, z, scale, eps: reference.rms(
-            o * jax.nn.silu(z), scale, eps)), ("delta_rms", "layers_rms")),
-}
-
-
-@pytest.mark.parametrize("name", sorted(WRONG_REFERENCES))
-def test_a_wrong_delta_layer_fails_the_runner_tolerances(tiny, name):
-    """Each read OUTSIDE the tolerance: the comparison can fail.  (The
-    wrong side is the reference's copy; the program is as it is.)"""
-    model, _, params, ids, tgt = tiny
-    changes, outside = WRONG_REFERENCES[name]
-    read = _read(model, params, ids, tgt, _reference_with(**changes))
-    for key in outside:
-        assert not read[key] <= runner.TOLERANCES[key], (key, read[key])
-
-
-@pytest.mark.parametrize("name, changes, outside", [
-    ("the_norm_on_each_parts_input", {"norm_place": "input"},
-     ("layers_rms", "delta_rms")),
-    ("a_rotated_full_attention_layer",
-     {"layer_pattern": (AttentionLayer(None, False, "delta"),) * 3
-      + (AttentionLayer(None, True),)}, ("layers_rms",)),
-])
-def test_a_wrong_program_fails_the_runner_tolerances(tiny, name, changes, outside):
-    """The same weights under a program that norms a part's input, or that
-    rotates the full layers' queries and keys, against the reference as it
-    is."""
-    _, cfg, params, ids, tgt = tiny
-    model = DMoETransformerLM(dataclasses.replace(cfg, **changes), _one_device_mesh())
-    read = _read(model, params, ids, tgt)
-    for key in outside:
-        assert not read[key] <= runner.TOLERANCES[key], (name, key, read[key])
-
-
-def test_a_hidden_that_composes_another_stack_fails_the_runner_tolerances(tiny):
-    """``_hidden`` over a stack whose delta layers are skipped (the layers
-    themselves as they are) reads outside ``hidden_token_median``."""
-    _, cfg, params, ids, tgt = tiny
-    model = DMoETransformerLM(cfg, _one_device_mesh())
-    layer = model._layer
-    model._layer = lambda lp, x, *rest: (
-        (x, None) if "delta" in lp else layer(lp, x, *rest))
-    read = _read(model, params, ids, tgt)
-    assert not read["hidden_token_median"] <= runner.TOLERANCES["hidden_token_median"]
-
-
-def test_lower_precisions_fail_the_runner_tolerances(tiny):
-    """The reference with float8 operands in the program's place reads
-    outside the layer, delta and logits limits, with bf16 operands inside;
-    the program's rule with its decays summed in bf16 reads worse than
-    with float32 sums."""
-    model, _, params, ids, tgt = tiny
-    for dtype, inside in ((jnp.float8_e4m3fn, False), (jnp.bfloat16, True)):
-        read = _read(model, params, ids, tgt, operand_dtype=dtype)
-        for key in ("layers_rms", "delta_rms", "logits_rms"):
-            assert (read[key] <= runner.TOLERANCES[key]) is inside, (dtype, key)
-    exact = _read(model, params, ids, tgt)
-    rough = _read(model, params, ids, tgt, decay_dtype=jnp.bfloat16)
-    assert rough["delta_rms"] > 100 * exact["delta_rms"]
-    assert rough["delta_state_rms"] > 100 * exact["delta_state_rms"]
-
-
-# ---- (c) a stack with no mixture layer ----
-
-
-def test_a_stack_with_no_mixture_trains_and_reports_no_expert_counter(tiny):
-    """The loss falls over a few steps; the step's metrics are the
-    cross-entropy and the delta rule's two counters; neither the parameter
-    tree nor the optimizer's holds a router leaf; the set-up's levelling
-    call and the step's balancing rule return what they were given."""
-    _, _, _, ids, tgt = tiny
-    model, cfg, optimizer, _ = olmo_hybrid_7b_one_chip(_one_device_mesh(), tiny=True)
-    params = model.init_params(jax.random.PRNGKey(1))
-    levelled, loads = model.level_router_bias(params, [ids])
-    assert levelled is params and loads == []
-    assert model._balance(params, model._router_biases(params), None) is params
-    opt_state = model.init_opt_state(optimizer, params)
-    names = "".join(
-        jax.tree_util.keystr(p) for p, _ in
-        jax.tree_util.tree_flatten_with_path((params, opt_state))[0])
-    assert "router" not in names and "'moe'" not in names and "'gate'" not in names
-    step = model.make_train_step(optimizer)
-    losses = []
-    for _ in range(6):
-        params, opt_state, loss, metrics = step(params, opt_state, ids, tgt)
-        losses.append(float(loss))
-    assert losses[-1] < losses[0] - 0.05 and np.isfinite(losses).all()
-    assert set(metrics) == {"ce", "delta_decay_min", "delta_beta_max"}
-    assert float(metrics["ce"]) == pytest.approx(losses[-1])  # no auxiliary term
-    assert 1.0 < float(metrics["delta_beta_max"]) <= 2.0
-    assert 0.0 <= float(metrics["delta_decay_min"]) < 1.0
-
-
-def test_a_stack_with_no_mixture_steps_on_a_data_mesh_as_on_one_device(tiny):
-    """Every leaf replicated, the batch over ``data``: the same loss as on
-    one device (the delta rule's scans and the convolution partition over
-    the rows of the batch)."""
-    from learning_at_home_tpu.parallel.mesh import batch_sharding
-
-    single, _, params, ids, tgt = tiny
-    want, _ = jax.jit(single.loss_fn)(params, ids, tgt)
-    mesh = make_mesh({"data": 2, "expert": 1}, devices=jax.devices()[:2])
-    model, _, optimizer, _ = olmo_hybrid_7b_one_chip(mesh, tiny=True)
-    placed = jax.device_put(  # copies: the step donates what it is given
-        jax.tree_util.tree_map(jnp.copy, params), model.param_shardings(params))
-    opt_state = model.init_opt_state(optimizer, placed)
-    rows = [jax.device_put(a, batch_sharding(mesh)) for a in (ids, tgt)]
-    _, _, loss, metrics = model.make_train_step(optimizer)(placed, opt_state, *rows)
-    assert abs(float(loss) - float(want)) < 1e-5
-    assert set(metrics) == {"ce", "delta_decay_min", "delta_beta_max"}
-
-
-def test_the_norm_on_the_output_of_a_mixture_layer_spans_all_the_part_gave():
-    """``norm_place='output'`` where the feed-forward part is a mixture
-    beside a shared expert: ONE norm over their sum, and the attention's
-    over its out-projection; each part reads the stream as it is."""
-    model, cfg, _, _ = k_exaone_one_chip(_one_device_mesh(), tiny=True)
-    cfg = dataclasses.replace(cfg, norm_place="output")
-    model = DMoETransformerLM(cfg, _one_device_mesh())
-    params = model.init_params(jax.random.PRNGKey(4))
-    lp = params["layers"][1]  # a mixture layer with a shared expert
-    assert "moe" in lp and "shared" in lp
-    x = jax.random.normal(jax.random.PRNGKey(5), (2, cfg.seq_len, cfg.d_model))
-    kind = cfg.attention_layer(1)
-    got, _ = jax.jit(lambda lp, x: model._layer(lp, x, 1, None, kind))(lp, x)
-
-    @jax.jit
-    def by_hand(lp, x):
-        q, k, v, _ = model._qkv(lp, x, np.arange(cfg.seq_len), kind.rotary)
-        h = x + model._norm(lp["ln1"], trunk.output_projection(
-            lp, trunk.attention_core(q, k, v, "xla", kind.window)))
-        routed, _ = model.moe(
-            lp["moe"], h.reshape(-1, cfg.d_model), jitter_salt=1)
-        shared = trunk.gated_mlp(lp["shared"], h, model._gate_act)
-        return h + model._norm(lp["ln2"], routed.reshape(h.shape) + shared)
-
-    want = by_hand(lp, x)
-    _close(got, want, 1e-5)
-    before, _ = jax.jit(lambda lp, x: DMoETransformerLM(
-        dataclasses.replace(cfg, norm_place="input"), _one_device_mesh()
-    )._layer(lp, x, 1, None, kind))(lp, x)
-    assert float(jnp.abs(before - got).max()) > 0.1
 
 
 # ---- (d) refusals ----
@@ -574,79 +405,3 @@ def test_a_program_without_the_recipe_fails_at_once_with_no_result(tmp_path):
         cwd=REPO, env=env, capture_output=True, text=True, timeout=120)
     assert run.returncode == 2 and "no recipe" in run.stderr
     assert not run.stdout.strip()
-
-
-# ---- the chip's compiler accepts the rule's kernels and the step ----
-
-
-def test_the_rules_kernels_compile_for_the_chip_at_the_cells_shape(v5e_chip):
-    """``delta_chunk_fwd`` and ``delta_chunk_bwd`` at ``[1, 16384, 30, 96 /
-    192]`` bf16 in chunks of 64, compiled for a described chip (nothing
-    runs): Mosaic takes keys of 96 and values of 192 as blocks that span
-    the arrays' last axis, three heads abreast, the frames' transposes,
-    the products at the highest precision and the VMEM the kernels ask
-    for."""
-    from learning_at_home_tpu.ops import delta_rule
-
-    one = jax.sharding.SingleDeviceSharding(v5e_chip)
-    s, h, dk, dv = (CELL_FILE[k] for k in (
-        "seq_len", "linear_num_key_heads", "linear_key_head_dim",
-        "linear_value_head_dim"))
-    assert (s, h, dk, dv) == (16384, 30, 96, 192)
-    assert delta_rule.kernel_fits((1, s, h, dk), (1, s, h, dv), 64, "tpu")
-
-    def shaped(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
-
-    args = (shaped((1, s, h, dk), jnp.bfloat16), shaped((1, s, h, dk), jnp.bfloat16),
-            shaped((1, s, h, dv), jnp.bfloat16), shaped((1, s, h), jnp.float32),
-            shaped((1, s, h), jnp.float32))
-
-    def loss(*a):
-        o, state = delta_rule.gated_delta_kernel(*a, 64)
-        return jnp.sum(o.astype(jnp.float32)) + jnp.sum(state)
-
-    with probe.no_compile_cache():
-        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
-            *args).compile().as_text()
-    calls = probe.scan_kernel_calls(text, "delta_chunk", "delta/core")
-    assert {name: c["calls"] for name, c in calls.items()} == {
-        "delta_chunk_fwd": 1, "delta_chunk_bwd": 1}
-
-
-def test_the_whole_step_fits_the_chip_and_runs_the_rule_as_kernels(
-        v5e_chip, monkeypatch):
-    """The eight-layer train step at published widths, compiled for a
-    described chip (nothing runs): 1,857,720,552 parameters; the
-    compiler's own count of what is live in the step no more than 1 GB
-    above the 12.02 GB the plain rule in checkpointed segments read (11.41
-    GB, 67.5 %, when this was written: PR 46); a delta layer's forward
-    kernel twice (the step's, and remat's, which writes the entering
-    states the backward kernel reads: nothing of the rule is kept across
-    the backward pass) and its backward kernel once, every call under
-    ``delta/core``, and no loop over chunks or segments left there."""
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    memory = probe.step_memory(v5e_chip, "olmo_hybrid_7b_one_chip")
-    assert memory["parameters"] == 1_857_720_552
-    assert 0.25 < memory["share_of_chip"] and memory["live_bytes"] < 13.02e9, memory
-    assert memory["delta_kernel_calls"] == {
-        "delta_chunk_fwd": {"calls": 2 * 6, "under_delta_core": 2 * 6},
-        "delta_chunk_bwd": {"calls": 6, "under_delta_core": 6}}
-    assert memory["loops_under_delta_core"] == 0
-    # the norm and the gate as one pass: forward, recomputed (remat keeps
-    # nothing of it) and backward a delta layer, every call under
-    # ``delta/gate_norm``, and no float32 ``[1, 16384, 5760]`` written there
-    # (PR 47; the parent's live count read 11,423,113,216)
-    assert memory["gate_norm_kernel_calls"] == {
-        "gate_norm_fwd": {"calls": 2 * 6, "under_delta_gate_norm": 2 * 6},
-        "gate_norm_bwd": {"calls": 6, "under_delta_gate_norm": 6}}
-    assert memory["float32_arrays_beside_gate_norm"] == []
-    assert memory["attention_kernel_calls"] == {
-        "splash_mha_fwd_residuals": 2, "splash_mha_dkv_no_residuals": 2}
-    # the results of the two attention layers' products are kept across the
-    # backward pass (PR 53): q, k, v and the output projection's, bf16
-    # [16384, 4 x 3840] a layer, 1.01 GB, under the band above; the backward
-    # pass runs none of the four a second time
-    assert memory["kept_product_bytes"] == 2 * 16384 * (4 * 3840) * 2
-    assert memory["recomputed_attention_products"] == 0
-    assert memory["loss_layer_products"] == 3

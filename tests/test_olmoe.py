@@ -2,12 +2,12 @@
 against its plain reference (``benchmarks/configs/olmoe_1b_7b_reference.py``),
 the dropless path's properties, and the benchmark's runner for it.
 
-Tiny sizes on the CPU, except one AOT compile of a layer at published
-widths for a described (not attached) ``v5e`` chip.
+Tiny sizes on the CPU.  The AOT compiles at published widths for a described
+(not attached) ``v5e`` chip are ``tests/test_olmoe_chip.py``'s, and remat
+against no remat, recipe by recipe, ``tests/test_olmoe_remat.py``'s.
 """
 
 import collections
-import contextlib
 import dataclasses
 import functools
 import hashlib
@@ -19,7 +19,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(REPO, "benchmarks"))
@@ -100,8 +99,9 @@ def tiny():
 def want(tiny):
     """The reference's logits, loss and gradients on the tiny weights."""
     _, cfg, params, ids, tgt = tiny
-    logits, _, _ = reference.forward(params, ids, _sizes(cfg))
-    loss, grads = reference.loss_and_grads(params, ids, tgt, _sizes(cfg))
+    logits = jax.jit(lambda p: reference.forward(p, ids, _sizes(cfg))[0])(params)
+    loss, grads = jax.jit(
+        lambda p: reference.loss_and_grads(p, ids, tgt, _sizes(cfg)))(params)
     return np.asarray(logits), float(loss), grads
 
 
@@ -296,9 +296,9 @@ def test_the_chip_form_of_the_sorted_layer_keeps_loss_and_gradients(
     ids = jnp.asarray(np.random.RandomState(3).randint(
         0, cfg.vocab_size, (4, cfg.seq_len + 1)))  # 128 tokens a layer
 
-    def loss_and_grads():
-        return jax.value_and_grad(
-            lambda p: model.loss_fn(p, ids[:, :-1], ids[:, 1:])[0])(params)
+    def loss_and_grads():  # a new program a call: traced under the form of the hour
+        return jax.jit(jax.value_and_grad(
+            lambda p: model.loss_fn(p, ids[:, :-1], ids[:, 1:])[0]))(params)
 
     want, want_grads = loss_and_grads()
     called = chip_form(monkeypatch)
@@ -334,12 +334,15 @@ def test_dropless_equals_the_capacity_path_when_nothing_is_dropped(expert_kind):
     def loss(moe):
         def f(p, x):
             y, aux = moe(p, x)
-            return (y ** 2).sum() + aux["aux_loss"] + aux["router_z_loss"], y
+            return (y ** 2).sum() + aux["aux_loss"] + aux["router_z_loss"], (
+                y, aux["dropped_fraction"])
         return jax.jit(jax.value_and_grad(f, has_aux=True))
 
-    (l_sorted, y_sorted), g_sorted = loss(sorted_moe)(params, x)
-    (l_slot, y_slot), g_slot = loss(slot_moe)(params, x)
-    assert float(slot_moe(params, x)[1]["dropped_fraction"]) == 0.0
+    (l_sorted, (y_sorted, _)), g_sorted = loss(sorted_moe)(params, x)
+    (l_slot, (y_slot, dropped)), g_slot = loss(slot_moe)(params, x)
+    # no assignment of the 48 x 4 is dropped: one would read 1/192, and the
+    # compiled quotient of two equal float32 counts an ulp of 1 off zero
+    assert abs(float(dropped)) < 0.5 / (48 * 4)
     np.testing.assert_allclose(np.asarray(y_sorted), np.asarray(y_slot), atol=2e-5)
     np.testing.assert_allclose(float(l_sorted), float(l_slot), rtol=1e-5)
     for key in g_sorted:
@@ -655,62 +658,7 @@ def lowered_tiny_step(recipe, axes):
     return model.make_train_step(opt).lower(p, o, ids, ids)
 
 
-# ---- remat changes no number; the stack has one layout ----
-
-
-@pytest.mark.parametrize("recipe, norm_place", [
-    (flagship_one_chip, "input"), (olmoe_one_chip, "input"),
-    (smallthinker_one_chip, "input"), (k_exaone_one_chip, "input"),
-    (glm_4_7_flash_one_chip, "input"), (nemotron_labs_twotower_one_chip, "input"),
-    (olmoe_one_chip, "output"), (glm_4_7_flash_one_chip, "output"),
-], ids=["dmoe", "olmoe", "smallthinker", "k-exaone", "glm-4.7-flash", "nemotron",
-        "olmoe-norm-on-outputs", "glm-4.7-flash-norm-on-outputs"])
-def test_remat_changes_no_loss_or_gradient(recipe, norm_place):
-    """Every cell runs its per-layer trees under ``remat``; the plain
-    references are compared without it.  From the same weights a recipe's
-    tiny stack gives one loss and one set of gradients with and without
-    ``jax.checkpoint`` around the layer: the dropless block's row gathers
-    replay their ``custom_vjp`` under it, and so do the share's, the
-    latent block's with its prediction block (which runs the same
-    checkpointed layer) and the state-space kernels' plain forms.  The
-    attention part's products are kept across the backward pass and not
-    run again (PR 53: ``trunk.ATTENTION_PRODUCTS``), in both projection
-    functions (``qkv_projections``; ``glm-4.7-flash``:
-    ``latent_qkv_projections``) and with the norm on a part's input, as
-    the six recipes have it, or on its output (``olmo-hybrid``'s place,
-    whose own tiny stack differs by more with and without remat, on the
-    parent too: its delta rule's plain form solves in another order)."""
-    mesh = _one_device_mesh()
-    under_remat, cfg, _, batch = recipe(mesh, tiny=True)
-    assert cfg.remat and cfg.norm_place == "input"
-    if norm_place != cfg.norm_place:
-        cfg = dataclasses.replace(cfg, norm_place=norm_place)
-        under_remat = DMoETransformerLM(cfg, mesh)
-    plain = DMoETransformerLM(dataclasses.replace(cfg, remat=False), mesh)
-    params = _decisive(plain.init_params(jax.random.PRNGKey(5)))
-    rs = np.random.RandomState(9)
-    ids = jnp.asarray(rs.randint(0, cfg.vocab_size, (batch, cfg.seq_len + 1)))
-
-    def loss_and_grads(model):
-        return jax.jit(jax.value_and_grad(
-            lambda p: model.loss_fn(p, ids[:, :-1], ids[:, 1:])[0]))(params)
-
-    want, want_grads = loss_and_grads(plain)
-    got, got_grads = loss_and_grads(under_remat)
-    np.testing.assert_allclose(float(got), float(want), rtol=1e-6)
-    for (path, g), w in zip(
-        jax.tree_util.tree_flatten_with_path(got_grads)[0],
-        jax.tree_util.tree_leaves(want_grads),
-    ):
-        # the same operations in another compiled program: the order of a
-        # few additions may differ (on one CPU device they read bit for
-        # bit the same today), so a few ulp of the leaf's scale in the
-        # leaf's own dtype (bf16 in the dmoe recipe)
-        ulp = float(jnp.finfo(w.dtype).eps) * float(jnp.abs(w).max())
-        np.testing.assert_allclose(
-            np.asarray(g.astype(jnp.float32)), np.asarray(w.astype(jnp.float32)),
-            rtol=0, atol=8 * ulp, err_msg=jax.tree_util.keystr(path),
-        )
+# ---- the stack has one layout ----
 
 
 @pytest.mark.parametrize("owner, name", [
@@ -736,350 +684,6 @@ def test_the_stack_has_one_layout(owner, name):
             dataclasses.replace(owner(), **{name: False})
     if name in runner.CFG_FIELDS:  # the two the benchmark's tables read
         assert getattr(owner(), name) is False
-
-
-# ---- the chip's compiler accepts a layer at published widths ----
-
-
-# the ``v5e_chip`` fixture is tests/conftest.py's (tests/test_smallthinker.py
-# compiles for it too)
-
-@contextlib.contextmanager
-def _no_compile_cache():
-    """An AOT compile for a described chip is written to the persistent
-    cache but cannot be read back without the chip: keep it out."""
-    from jax.experimental.compilation_cache import compilation_cache
-
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    try:
-        yield
-    finally:
-        jax.config.update("jax_enable_compilation_cache", True)
-        compilation_cache.reset_cache()
-
-
-def test_one_olmoe_layer_compiles_for_v5e_at_published_widths(v5e_chip, monkeypatch):
-    """Forward and backward of ONE layer of the recipe (2048 wide, 16
-    heads of 128, 64 gated experts of 1024, top-8 dropless, 4 x 4,096
-    tokens) for a described chip: the grouped matmul, the sort, the
-    gathers and the blocked attention kernel at its tiles are accepted at
-    the sizes the cell runs, and no [B, H, S, S] scores are left."""
-    # the chip is described, not attached: this process's backend is the
-    # CPU, and the recipe would resolve as it does there
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    mesh = Mesh(np.array([v5e_chip]), ("expert",))
-    model, cfg, _, batch = olmoe_one_chip(mesh)
-    assert model.attn_impl == "flash"  # what a user on the chip gets
-    assert (cfg.d_model, cfg.n_heads, cfg.num_experts, cfg.k,
-            model.moe.ffn_dim, cfg.seq_len, batch) == (2048, 16, 64, 8, 1024, 4096, 4)
-    one = NamedSharding(mesh, P())
-    shapes = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
-    lp = jax.tree_util.tree_map(
-        lambda s, h: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=h),
-        shapes["layers"][0], model.param_shardings(shapes)["layers"][0],
-    )
-    x = jax.ShapeDtypeStruct((batch, cfg.seq_len, cfg.d_model), cfg.dtype, sharding=one)
-
-    def layer_loss(lp, x):
-        y, aux = model._layer(lp, x, 0, None, cfg.attention_layer(0))
-        return (y.astype(jnp.float32) ** 2).mean() + aux["aux_loss"]
-
-    with _no_compile_cache():
-        compiled = jax.jit(jax.grad(layer_loss, argnums=(0, 1))).lower(lp, x).compile()
-    text = compiled.as_text()
-    assert text.count("ragged-dot-none") >= 9  # 3 forward, 6 backward
-    # the compiler took the tiles it was handed (its own 512,512,512 is
-    # nowhere), and they fit VMEM: a refused setting fails the compile
-    assert _compiled_tilings(text) == {
-        "256,2048,1024", "256,1024,2048", "256,1024,1024"}
-    # forward and the fused backward, under the scope (the kernel writes a
-    # newline into its call's attributes, so the instruction's name and
-    # its op_name sit on different lines of the text)
-    kernels = set(re.findall(
-        r'op_name="[^"]*[/(]attention[/)]+flash/[^"]*/(\w+)/pallas_call"', text))
-    assert len(kernels) == 2 and all(k.startswith("splash_mha") for k in kernels), kernels
-    assert "[4,16,4096,4096]" not in text
-    memory = compiled.memory_analysis()
-    assert memory.temp_size_in_bytes < 12e9
-
-
-@pytest.mark.parametrize("shape", [
-    (4, 256, 8, 64),     # dmoe256's, were it asked for the kernel
-    (16, 512, 8, 64),    # auto's threshold
-    (8, 1024, 8, 64),
-    (2, 4096, 16, 128),
-    (1, 8192, 16, 128),
-])
-def test_blocked_attention_compiles_for_v5e_at_its_tiles(v5e_chip, monkeypatch, shape):
-    """Mosaic takes the forward kernel and the fused backward kernel at the
-    tiles ``flash_block_sizes`` gives for lengths on both sides of the
-    sweep's."""
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    one = jax.sharding.SingleDeviceSharding(v5e_chip)
-    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one)
-
-    def both(q, k, v, do):
-        out, vjp = jax.vjp(lambda q, k, v: trunk.attention_core(q, k, v, "flash"), q, k, v)
-        return out, vjp(do)
-
-    with _no_compile_cache():
-        text = jax.jit(both).lower(x, x, x, x).compile().as_text()
-    assert text.count("tpu_custom_call") >= 2  # forward, the fused backward
-
-
-# ---- remat keeps the kernel's output and row sums (PR 38) ----
-
-
-def _the_parents_formula(monkeypatch):
-    """The layer's remat, the kernel's constructor and the attention
-    part's products as the commit before any name wrote them (PR 38's
-    parent): ``jax.checkpoint`` under no policy, the kernel's forward and
-    the products (PR 53) naming nothing."""
-    from jax.experimental.pallas.ops.tpu import splash_attention as splash
-
-    checkpoint, make = jax.checkpoint, splash.make_splash_mha_single_device
-    monkeypatch.setattr(
-        jax, "checkpoint", lambda fn, policy=None, **kw: checkpoint(fn, **kw))
-    monkeypatch.setattr(
-        splash, "make_splash_mha_single_device",
-        lambda residual_checkpoint_name=None, **kw: make(**kw))
-    monkeypatch.setattr(trunk, "checkpoint_name", lambda x, name: x)
-
-
-# By recipe: the changes that take its tiny block to the smallest shape the
-# blocked kernel takes (512 positions, heads of 64, bf16; the latent form:
-# two layers and the prediction block, heads of [48 | 16 rotated]); what a
-# layer names, in the order it computes (the three products of
-# ``qkv_projections``, the kernel's output and row sums, the output
-# projection; in the latent form the two products down to the latents in
-# the three's place: the three up from them are run again, not kept); its
-# matrix products a layer; the kept results' width (q, k, v and the
-# stream, or the two latents with the rotated key part and the stream).
-_KERNEL_SIZED = {
-    olmoe_one_chip: dict(
-        changes=dict(d_model=256, n_heads=4),
-        names=[trunk.ATTENTION_PRODUCTS] * 3 + [trunk.FLASH_RESIDUALS] * 2
-        + [trunk.ATTENTION_PRODUCTS],
-        products=4, kept_width=4 * 256),
-    glm_4_7_flash_one_chip: dict(
-        changes=dict(n_layers=2, ffn_pattern=("dense", "moe"), head_dim=64,
-                     rope_head_dim=16),
-        names=[trunk.ATTENTION_PRODUCTS] * 2 + [trunk.FLASH_RESIDUALS] * 2
-        + [trunk.ATTENTION_PRODUCTS],
-        products=6, kept_width=24 + (16 + 16) + 64),
-}
-
-
-def _kernel_sized(mesh, recipe=olmoe_one_chip, **changes):
-    """A recipe's tiny block at the smallest shape the blocked kernel
-    takes (``_KERNEL_SIZED``)."""
-    _, cfg, _, _ = recipe(mesh, tiny=True)
-    cfg = dataclasses.replace(
-        cfg, seq_len=512, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16,
-        **(_KERNEL_SIZED[recipe]["changes"] | changes))
-    model = DMoETransformerLM(cfg, mesh)
-    assert model.attn_impl == "flash"
-    return model, cfg
-
-
-@pytest.mark.parametrize(
-    "recipe", list(_KERNEL_SIZED), ids=["qkv_projections", "latent_qkv_projections"])
-@pytest.mark.parametrize("formula", ["kept", "parents"])
-def test_remat_recomputes_no_forward_kernel_call(
-    v5e_chip, monkeypatch, formula, recipe
-):
-    """The gradient of a two-layer stack under ``remat``, compiled for a
-    described chip at a small kernel shape: the traced step names the
-    kernel's output and its row sums, two arrays a kernel layer, and both
-    the traced and the compiled step hold ONE forward call a layer beside
-    the fused backward's; under the parent's formula the same count reads two forwards a layer,
-    so the count can tell.  The same of the attention part's matrix
-    products (PR 53): the traced step names their results, the compiled
-    step holds none of them under ``rematted_computation`` (in the latent
-    form the three products up from the latents, which are not kept), and
-    under the parent's formula four a layer (six in the latent form)."""
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    if formula == "parents":
-        _the_parents_formula(monkeypatch)
-    mesh = Mesh(np.array([v5e_chip]), ("expert",))
-    model, cfg = _kernel_sized(mesh, recipe)
-    sized = _KERNEL_SIZED[recipe]
-    assert cfg.remat and cfg.n_layers == 2
-    one = NamedSharding(mesh, P())
-    shapes = jax.tree_util.tree_map(
-        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one),
-        jax.eval_shape(model.init_params, jax.random.PRNGKey(0)))
-    ids = jax.ShapeDtypeStruct((2, cfg.seq_len), jnp.int32, sharding=one)
-    traced = jax.jit(jax.value_and_grad(
-        lambda p, i, t: model.loss_fn(p, i, t)[0])).trace(shapes, ids, ids)
-    bodies = cfg.n_layers + cfg.mtp_layers
-    forwards = bodies * (2 if formula == "parents" else 1)
-    named = [eqn.params["name"]
-             for _, eqn in probe._equations(traced.jaxpr.jaxpr, "name")]
-    assert named == ([] if formula == "parents" else sized["names"] * bodies)
-    # the output [B, H, S, hd] bf16 and the row sums [B, H, S] float32
-    assert probe.kept_residual_bytes(traced.jaxpr.jaxpr) == (
-        0 if formula == "parents" else bodies * 2 * 4 * 512 * (64 * 2 + 4))
-    assert probe.kept_residual_bytes(  # bf16 [B, S, the kept width]
-        traced.jaxpr.jaxpr, trunk.ATTENTION_PRODUCTS
-    ) == (0 if formula == "parents" else bodies * 2 * 512 * sized["kept_width"] * 2)
-    calls = collections.Counter(
-        eqn.params["name"]
-        for _, eqn in probe._equations(traced.jaxpr.jaxpr, "pallas_call"))
-    want = {"splash_mha_fwd_residuals": forwards,
-            "splash_mha_dkv_no_residuals": bodies}
-    assert calls == want
-    with _no_compile_cache():
-        text = traced.lower().compile().as_text()
-    assert probe.attention_kernel_calls(text) == want
-    kept = sized["names"].count(trunk.ATTENTION_PRODUCTS)
-    assert probe.recomputed_attention_products(text) == bodies * (
-        sized["products"] - (0 if formula == "parents" else kept))
-
-
-@pytest.mark.parametrize("program", ["apply", "cached_prefill"])
-def test_an_undifferentiated_kernel_call_lowers_to_the_parents_text(
-    monkeypatch, program
-):
-    """Outside a checkpoint a name is the identity: the model's forward
-    and the cached decoder's prefill through the kernel lower for the TPU
-    to the operations of the formula that names nothing, one for one and
-    in order (the kernel's serialized module with them).  Since PR 53 the
-    NUMBER at the end of private functions' symbols moves
-    (``@argsort_<n>``, ``@_splash_attention_<n>``, ..): an equation takes
-    its number from the module's symbol table while it is lowered, a
-    ``name`` equation too, and a second kind of ``name`` equation (the
-    products' beside the kernel's) collides with the first.  So a
-    forward-only program is keyed anew in the compile cache once, and
-    computes what it computed."""
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    mesh = _one_device_mesh()
-
-    def lowered():
-        model, cfg = _kernel_sized(mesh)
-        shapes = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
-        ids = jax.ShapeDtypeStruct((2, cfg.seq_len), jnp.int32)
-        if program == "apply":
-            fn, args = jax.jit(lambda p, i: model.apply(p, i)[0]), (shapes, ids)
-        else:
-            fn = jax.jit(model.decode_model()._generate_cached, static_argnums=(2, 3))
-            args = (shapes, ids, 4, 0.0, jax.random.PRNGKey(0))
-        return fn.trace(*args).lower(lowering_platforms=("tpu",)).as_text()
-
-    def unnumbered(text):
-        return re.sub(r"(@[A-Za-z_]+)_\d+\b", r"\1", text)
-
-    # both from ONE line: the kernel's serialized module carries its
-    # callers' line numbers, this one's too where the path is short
-    texts = []
-    for formula in (None, _the_parents_formula):
-        if formula is not None:
-            formula(monkeypatch)
-        texts.append(lowered())
-    text, parents = texts
-    assert text != parents  # the numbers below do move: not the same runs
-    assert text.count("splash_mha_fwd") >= 2  # a kernel call a layer
-    assert unnumbered(parents) == unnumbered(text)
-    moved = {a for a, b in zip(text.split(), parents.split()) if a != b}
-    assert all(re.match(r"@[A-Za-z_]+_\d+\b", word) for word in moved), moved
-
-
-def test_the_whole_step_holds_one_forward_kernel_call_a_layer(v5e_chip, monkeypatch):
-    """The 4-layer train step at published widths, compiled for a
-    described chip (nothing runs): 1.884 B parameters, the compiler's own
-    count of what is live in the step between a quarter of the chip's
-    memory and 0.9 of it (8.93 GB, 52.8 %, when this was written), and the
-    blocked kernel called once forward and once backward a layer (8 and 4
-    before PR 38: remat keeps the kernel's output and row sums, 68 MB a
-    layer, and the recompute holds no forward call)."""
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    memory = probe.step_memory(v5e_chip, "olmoe_one_chip")
-    assert memory["parameters"] == 1_884_325_888
-    assert 0.25 < memory["share_of_chip"] < 0.9, memory
-    assert memory["loss_layer_products"] == 3
-    assert memory["attention_kernel_calls"] == {
-        "splash_mha_fwd_residuals": 4, "splash_mha_dkv_no_residuals": 4}
-    assert memory["kept_residual_bytes"] == 4 * 4 * 16 * 4096 * (128 * 2 + 4)
-    # and the results of the attention part's products (PR 53): q, k, v and
-    # the output projection's, bf16 [4, 4096, 4 x 2048] a layer, 1.07 GB;
-    # the backward pass runs none of the four a second time
-    assert memory["kept_product_bytes"] == 4 * 4 * 4096 * (4 * 2048) * 2
-    assert memory["recomputed_attention_products"] == 0
-    assert {name: (c["calls"], c["block_q"], c["block_kv"])
-            for name, c in memory["attention_kernel_tilings"]["attention"].items()} == {
-        "splash_mha_fwd_residuals": (4, 1024, 1024),
-        "splash_mha_dkv_no_residuals": (4, 1024, 1024)}
-    # five row gathers a mixture layer (six before PR 50: the combine's
-    # gathered rows are no residual, so remat gathers them no second time),
-    # no scatter, and the sum of 8 rows left to the compiler
-    assert memory["moe_rows_kernel_calls"] == {
-        "moe_rows_sum": {"calls": 0, "under_moe_sort": 0, "under_moe_combine": 0},
-        "row_gathers": 4 * 5, "row_scatters": 0}
-
-
-@pytest.mark.parametrize("n, k, d, sums", [
-    (16384, 8, 2048, 0),   # olmoe-1b-7b-train-zipf4k: the compiler's sum of 8 rows
-    (16384, 6, 2560, 2),   # smallthinker-21b-a3b-train-zipf16k: ``moe_rows_sum``
-], ids=["olmoe", "smallthinker"])
-def test_the_sorted_layers_row_movements_compile_for_v5e(
-        v5e_chip, monkeypatch, n, k, d, sums):
-    """A layer's sort and combine, forward and backward at a cell's shape,
-    for a described chip: Mosaic accepts the kernel at its blocks where the
-    rule admits ``k``; four row gathers (the sort's, the combine's, one
-    each way: a fifth under remat, the sort's forward again) where the
-    parent's five held the combine's twice, and no scatter."""
-    from learning_at_home_tpu.ops import moe_dispatch
-
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    one = jax.sharding.SingleDeviceSharding(v5e_chip)
-
-    def through(x, weights, order, inverse):
-        plan = moe_dispatch.DroplessPlan(order, inverse, None, weights, None)
-        with jax.named_scope("moe_sort"):
-            xs = moe_dispatch.sort_tokens(x, plan)
-        with jax.named_scope("moe_combine"):
-            y = moe_dispatch.unsort_combine(xs * 2, plan, x.dtype)
-        return (y.astype(jnp.float32) ** 2).sum()
-
-    shapes = [jax.ShapeDtypeStruct(shape, dtype, sharding=one) for shape, dtype in (
-        ((n, d), jnp.bfloat16), ((n, k), jnp.float32),
-        ((n * k,), jnp.int32), ((n * k,), jnp.int32))]
-    with _no_compile_cache():
-        text = jax.jit(jax.grad(through, argnums=(0, 1))).lower(*shapes).compile().as_text()
-    found = probe.moe_rows_kernel_calls(text)
-    assert found["moe_rows_sum"] == {
-        "calls": sums, "under_moe_sort": sums // 2, "under_moe_combine": sums // 2}
-    assert (found["row_gathers"], found["row_scatters"]) == (4, 0)
-
-
-@pytest.mark.parametrize("m, a, b", [
-    (2048, 512, 256),     # the fewest rows that get tiles, narrower than one
-    (8192, 4096, 4096),   # wider than one: an accumulator beside the tiles
-    (4096, 16384, 512),
-    (4096, 512, 16384),
-    (4096, 2560, 768),    # SmallThinker's: the largest whole-matrix tile,
-    (4096, 768, 2560),    # and 1280 = 2560 / 2 in the weights' gradients
-])
-def test_grouped_matmul_compiles_for_v5e_at_its_tiles(v5e_chip, m, a, b):
-    """The chip's compiler takes the call and both gradients at the tiles
-    ``grouped_matmul_tiles`` gives beyond the cell's widths: a setting
-    that does not fit VMEM fails the compile."""
-    one = jax.sharding.SingleDeviceSharding(v5e_chip)
-    x = jax.ShapeDtypeStruct((m, a), jnp.bfloat16, sharding=one)
-    w = jax.ShapeDtypeStruct((8, a, b), jnp.bfloat16, sharding=one)
-    g = jax.ShapeDtypeStruct((m, b), jnp.bfloat16, sharding=one)
-    sizes = jax.ShapeDtypeStruct((8,), jnp.int32, sharding=one)
-
-    with _no_compile_cache():
-        text = jax.jit(
-            functools.partial(_call_and_gradients, grouped_matmul)
-        ).lower(x, w, g, sizes).compile().as_text()
-    assert _compiled_tilings(text) == {
-        ",".join(map(str, grouped_matmul_tiles(*call)))
-        for call in ((m, a, b, jnp.bfloat16), (m, b, a, jnp.bfloat16),
-                     (m, a, b, jnp.bfloat16, True))
-    }
 
 
 # ---- the benchmark's files for it ----

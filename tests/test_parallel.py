@@ -1,8 +1,9 @@
 """Tests for the ICI tier: sharded MoE + DMoE transformer on the virtual
-8-device CPU mesh (SURVEY.md §4 'TPU-build implication')."""
+8-device CPU mesh (SURVEY.md §4 'TPU-build implication').  The chunked
+cross-entropy against the full logits is
+``tests/test_parallel_chunked_ce.py``'s and ``tests/test_parallel_ce_per_shard.py``'s."""
 
 import dataclasses
-import functools
 import re
 
 import jax
@@ -285,128 +286,6 @@ def test_grad_accumulation_matches_mean_of_micro_grads():
     assert 0.0 <= float(metrics["dropped_fraction"]) <= 1.0
 
 
-def _assert_ce_matches_full_logits(
-    m, params, ids, tgt, loss_tol, grad_tol, cotangent=1.0
-):
-    """``loss_fn``'s chunked CE against the loss over the whole [B, S, V]
-    float32 logits: the value, the gradients with respect to the hidden
-    states and the head (the loss layer alone, which takes them in its
-    forward scan and multiplies them by the ``cotangent`` that arrives:
-    1 in a train step), and the gradients with respect to every parameter
-    (a tied head takes the embedding's cotangent from both ends).
-    ``grad_tol`` bounds ``max|a-b| / max|b|`` per leaf."""
-    cfg = m.cfg
-
-    def full_ce(x, head):
-        return optax.softmax_cross_entropy_with_integer_labels(
-            m._logits(x, head), tgt
-        ).mean()
-
-    def full_loss(p):
-        x, aux = m._hidden(p, ids)
-        return (
-            full_ce(x, m._head(p))
-            + cfg.aux_loss_weight * aux["aux_loss"]
-            + cfg.router_z_weight * aux["router_z_loss"]
-        )
-
-    def close(got, want):
-        for (path, g), w in zip(
-            jax.tree_util.tree_flatten_with_path(got)[0],
-            jax.tree_util.tree_leaves(want),
-        ):
-            g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
-            assert np.abs(g - w).max() <= grad_tol * np.abs(w).max(), (
-                jax.tree_util.keystr(path)
-            )
-
-    @functools.partial(jax.jit, static_argnums=(0, 1))  # eager: 30 s a case
-    def both(loss_of_params, ce_of_x_head, p):
-        x, head = m._hidden(p, ids)[0], m._head(p)
-        return (
-            jax.value_and_grad(loss_of_params)(p),
-            jax.grad(ce_of_x_head, argnums=(0, 1))(x, head),
-        )
-
-    (loss, grads), ce_grads = both(
-        lambda p: m.loss_fn(p, ids, tgt)[0],
-        lambda x, h: cotangent * m._chunked_ce(x, h, tgt), params,
-    )
-    (ref, ref_grads), ref_ce_grads = both(
-        full_loss, lambda x, h: cotangent * full_ce(x, h), params
-    )
-    assert loss.dtype == jnp.float32
-    assert abs(float(loss) - float(ref)) < loss_tol
-    close(grads, ref_grads)
-    close(ce_grads, ref_ce_grads)
-
-
-@pytest.mark.parametrize("tied", [True, False], ids=["tied", "untied"])
-@pytest.mark.parametrize(
-    "batch, chunk, dtype, cotangent",
-    # n = 128, 80, 48 tokens: divisible, a remainder, less than a chunk;
-    # then bf16 storage, whose statistics must stay float32; then a
-    # cotangent other than a train step's 1 into the loss layer
-    [(8, 16, "float32", 1.0), (5, 16, "float32", 1.0),
-     (3, 128, "float32", 1.0), (8, 16, "bfloat16", 1.0),
-     (5, 16, "float32", -2.5), (8, 16, "bfloat16", 0.37)],
-)
-def test_chunked_ce_matches_full_logits(batch, chunk, dtype, cotangent, tied):
-    """loss_fn's chunked CE must equal the full-logits loss, value and
-    gradients, for divisible AND indivisible token counts (the
-    indivisible remainder goes through one more chunk, never full [n,V]
-    logits).  With bf16 operands the logits and the softmax
-    statistics stay float32, so the loss sits within bf16 rounding of the
-    float32-logits reference computed from the SAME bf16 inputs (a bf16
-    softmax over 64 classes would be 1e-2 away); the gradients are bf16
-    values, compared at bf16's resolution."""
-    mesh = make_mesh({"data": 2, "expert": 4})
-    _, cfg = _tiny_model(mesh)
-    m = DMoETransformerLM(
-        dataclasses.replace(
-            cfg, ce_chunk=chunk, tie_embeddings=tied, dtype=jnp.dtype(dtype),
-            n_layers=1,
-        ),
-        mesh,
-    )
-    params = m.init_params(jax.random.PRNGKey(0))
-    rs = np.random.RandomState(3)
-    ids = jnp.asarray(rs.randint(0, 64, (batch, 16)))
-    tgt = jnp.asarray(rs.randint(0, 64, (batch, 16)))
-    loss_tol, grad_tol = (1e-5, 1e-5) if dtype == "float32" else (1e-3, 2e-2)
-    _assert_ce_matches_full_logits(
-        m, params, ids, tgt, loss_tol, grad_tol, cotangent
-    )
-
-
-@pytest.mark.parametrize(
-    "axes", [{"expert": 1}, {"data": 2, "expert": 2}],
-    ids=["one-device", "data2xexpert2"],
-)
-def test_chunked_ce_at_a_vocabulary_no_chunk_divides(axes):
-    """OLMoE's vocabulary is 50,304 = 128 x 393: no multiple of the chunk
-    or of 1,024.  The chunked CE tiles tokens, never the vocabulary, so
-    393 classes give the full-logits loss and gradients on one device
-    (the scan) and on pod4's mesh (the scan per shard)."""
-    n_dev = int(np.prod(list(axes.values())))
-    mesh = make_mesh(axes, devices=jax.devices()[:n_dev])
-    cfg = DMoETransformerConfig(
-        vocab_size=393, d_model=32, n_layers=1, n_heads=4, seq_len=16,
-        num_experts=4, k=2, dtype=jnp.float32, ce_chunk=24,
-        tie_embeddings=False,
-    )
-    m = DMoETransformerLM(cfg, mesh)
-    params = m.init_params(jax.random.PRNGKey(0))
-    rs = np.random.RandomState(5)
-    ids, tgt = (
-        jax.device_put(
-            jnp.asarray(rs.randint(0, 393, (8, 16))), batch_sharding(mesh)
-        )
-        for _ in range(2)
-    )
-    _assert_ce_matches_full_logits(m, params, ids, tgt, 1e-5, 1e-5)
-
-
 def _loss_layer_ops(hlo_text, opcode_re):
     """``op_name`` of every instruction of the optimized HLO whose opcode
     matches.  The step differentiates the scope ``ce``, so a path reads
@@ -418,92 +297,6 @@ def _loss_layer_ops(hlo_text, opcode_re):
     return [
         re.sub(r"\b(?:jvp|transpose)\(|\)", "", name) for name in names
     ]
-
-
-@pytest.mark.parametrize(
-    "axes, batch, chunk",
-    [
-        # tokens a shard / chunk: a scan of 2 and no remainder; a scan of
-        # 2 and a remainder; one chunk and a remainder; less than a chunk
-        ({"data": 2, "expert": 2}, 8, 16),
-        ({"data": 2, "expert": 2}, 12, 20),
-        ({"data": 2, "expert": 4}, 16, 16),
-        ({"data": 2, "expert": 4}, 16, 24),
-        ({"expert": 8}, 16, 16),
-        ({"expert": 8}, 24, 20),
-        ({"expert": 8}, 8, 128),
-        # the sequence sharded too, 16 tokens a shard: a scan of 4; a
-        # scan of 2 and a remainder
-        ({"data": 2, "expert": 2, "seq": 2}, 8, 4),
-        ({"expert": 4, "seq": 2}, 8, 6),
-    ],
-    ids=lambda v: (
-        "x".join(f"{k}{n}" for k, n in v.items()) if isinstance(v, dict)
-        else str(v)
-    ),
-)
-def test_chunked_ce_per_shard_matches_global_scan(axes, batch, chunk):
-    """On a multi-device mesh the chunked CE scans each shard's own rows
-    under ``shard_map``: the loss is the full-logits loss, and every
-    gradient leaf is that of the global scan over the unsharded arrays."""
-    n_dev = int(np.prod(list(axes.values())))
-    mesh = make_mesh(axes, devices=jax.devices()[:n_dev])
-    _, cfg = _tiny_model(mesh)
-    cfg = dataclasses.replace(
-        cfg, ce_chunk=chunk, n_layers=1, seq_parallel="seq" in axes
-    )
-    m = DMoETransformerLM(cfg, mesh)
-    params = m.init_params(jax.random.PRNGKey(0))
-    rs = np.random.RandomState(7)
-    ids, tgt = (
-        jax.device_put(
-            jnp.asarray(rs.randint(0, 64, (batch, 16))), batch_sharding(mesh)
-        )
-        for _ in range(2)
-    )
-
-    def with_aux(ce, aux):
-        return (
-            ce
-            + cfg.aux_loss_weight * aux["aux_loss"]
-            + cfg.router_z_weight * aux["router_z_loss"]
-        )
-
-    def per_shard_loss(p):
-        return m.loss_fn(p, ids, tgt)[0]
-
-    def global_scan_loss(p):  # loss_fn with the CE of the one-device path
-        x, aux = m._hidden(p, ids)
-        return with_aux(m._chunked_ce_sum(x, m._head(p), tgt, tgt.size), aux)
-
-    # the path under test is the per-shard one (the expert layer has a
-    # shard_map of its own, so the loss layer is traced alone)
-    x_head = jax.eval_shape(lambda p: (m._hidden(p, ids)[0], m._head(p)), params)
-    assert "shard_map" in str(
-        jax.make_jaxpr(lambda x, h: m._chunked_ce(x, h, tgt))(*x_head)
-    )
-    loss, grads = jax.jit(jax.value_and_grad(per_shard_loss))(params)
-    ref_loss, ref_grads = jax.jit(jax.value_and_grad(global_scan_loss))(params)
-    logits, aux = jax.jit(m.apply)(params, ids)
-    full = with_aux(
-        optax.softmax_cross_entropy_with_integer_labels(logits, tgt).mean(), aux
-    )
-    assert abs(float(loss) - float(full)) < 1e-5
-    assert abs(float(loss) - float(ref_loss)) < 1e-5
-    # f32 throughout: the two differ only in the order of the last f32
-    # additions (per-shard sums, then across shards; the head's cotangent
-    # summed per shard, then over shards), a few ulp of leaves whose
-    # largest entries are 1e-2..1: 1e-5 absolute is 100 times that and
-    # 1000 times under a wrong 1/n (the shard's token count for the
-    # global one would scale every leaf by the shard count)
-    for (path, g), r in zip(
-        jax.tree_util.tree_flatten_with_path(grads)[0],
-        jax.tree_util.tree_leaves(ref_grads),
-    ):
-        np.testing.assert_allclose(
-            np.asarray(g), np.asarray(r), rtol=0, atol=1e-5,
-            err_msg=jax.tree_util.keystr(path),
-        )
 
 
 def _loss_layer_alone(axes, dtype, chunk, vocab=64, batch=8, seed=11):
@@ -531,117 +324,6 @@ def _loss_layer_alone(axes, dtype, chunk, vocab=64, batch=8, seed=11):
         jnp.asarray(rs.randint(0, vocab, (batch, 16))), batch_sharding(mesh)
     )
     return m, x, head, tgt
-
-
-@pytest.mark.parametrize("cotangent", [1.0, -0.75], ids=["one", "other"])
-@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-@pytest.mark.parametrize(
-    "axes, chunk",
-    [
-        # tokens a shard / chunk: 32 / 16; 32 / 12 (a remainder); 16 / 128;
-        # with the sequence sharded too: 16 / 4, 32 / 12
-        ({"data": 4, "expert": 1}, 16),
-        ({"data": 2, "expert": 2}, 12),
-        ({"expert": 8}, 128),
-        ({"data": 2, "expert": 2, "seq": 2}, 4),
-        ({"expert": 2, "seq": 2}, 12),
-    ],
-    ids=lambda v: (
-        "x".join(f"{k}{n}" for k, n in v.items()) if isinstance(v, dict)
-        else str(v)
-    ),
-)
-def test_loss_layer_gradients_per_shard_match_full_logits(
-    axes, chunk, dtype, cotangent
-):
-    """The loss layer takes its gradients in its forward scan, per shard
-    on a mesh: the value, and the gradients with respect to the hidden
-    states and the head under any cotangent, are those autodiff gives the
-    loss over the whole float32 logits (float32: 1e-5; bf16 values at
-    bf16's resolution, as ``test_chunked_ce_matches_full_logits``)."""
-    m, x, head, tgt = _loss_layer_alone(axes, dtype, chunk)
-    assert "shard_map" in str(
-        jax.make_jaxpr(lambda x, h: m._chunked_ce(x, h, tgt))(x, head)
-    )
-
-    def full_ce(x, h):
-        return optax.softmax_cross_entropy_with_integer_labels(
-            m._logits(x, h), tgt
-        ).mean()
-
-    got, want = (
-        jax.jit(jax.value_and_grad(
-            lambda x, h: cotangent * ce(x, h), argnums=(0, 1)
-        ))(x, head)
-        for ce in (lambda x, h: m._chunked_ce(x, h, tgt), full_ce)
-    )
-    loss_tol, grad_tol = (1e-5, 1e-5) if dtype == "float32" else (1e-3, 2e-2)
-    assert got[0].dtype == jnp.float32
-    assert abs(float(got[0]) - float(want[0])) < loss_tol
-    for g, w, like in zip(got[1], want[1], (x, head)):
-        assert g.dtype == like.dtype and g.shape == like.shape
-        g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
-        assert np.abs(g - w).max() <= grad_tol * np.abs(w).max()
-
-
-@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-@pytest.mark.parametrize(
-    "batch, chunk",
-    # 128 tokens in 8 chunks; 80 in 5; 88 in 5 and a remainder; 24 in one
-    # and a remainder; 48 under a chunk
-    [(8, 16), (5, 16), (11, 8), (3, 32), (3, 128)],
-)
-def test_loss_layer_gradients_keep_the_bits_of_the_backward_scan(
-    batch, chunk, dtype
-):
-    """Before PR 34 every chunk ran under ``jax.checkpoint`` and autodiff
-    made the gradients in a backward scan that computed each chunk's
-    logits again.  The forward scan that takes them now makes the same
-    products of the same operands and adds the chunks' shares of the
-    head's gradient in the same order and dtype: not a bit of either
-    gradient differs, in float32 or with bf16 storage (where the head's
-    gradient accumulates in bf16, as it did)."""
-    m, x, head, tgt = _loss_layer_alone({"expert": 1}, dtype, chunk, batch=batch)
-
-    def checkpointed_scan(x, head):
-        n = x.shape[0] * x.shape[1]
-        flat_x, flat_t = x.reshape(n, -1), tgt.reshape(n)
-        c = min(chunk, n)
-
-        def chunk_ce(carry, xt):
-            ce = optax.softmax_cross_entropy_with_integer_labels(
-                m._logits(xt[0], head), xt[1]
-            )
-            return carry + ce.sum(), None
-
-        ce_sum, main = jnp.float32(0), (n // c) * c
-        if main > c:
-            ce_sum, _ = jax.lax.scan(
-                jax.checkpoint(chunk_ce), ce_sum,
-                (flat_x[:main].reshape(main // c, c, -1),
-                 flat_t[:main].reshape(main // c, c)),
-            )
-        elif main:
-            ce_sum, _ = jax.checkpoint(chunk_ce)(
-                ce_sum, (flat_x[:main], flat_t[:main])
-            )
-        if n > main:
-            ce_sum, _ = jax.checkpoint(chunk_ce)(
-                ce_sum, (flat_x[main:], flat_t[main:])
-            )
-        return ce_sum / n
-
-    got, want = (
-        jax.jit(jax.value_and_grad(ce, argnums=(0, 1)))(x, head)
-        for ce in (lambda x, h: m._chunked_ce(x, h, tgt), checkpointed_scan)
-    )
-    # the value is the same terms added last chunk first: within 2 ulp
-    np.testing.assert_allclose(float(got[0]), float(want[0]), rtol=3e-7)
-    for g, w in zip(got[1], want[1]):
-        assert g.dtype == w.dtype == jnp.dtype(dtype)
-        np.testing.assert_array_equal(
-            np.asarray(g, np.float32), np.asarray(w, np.float32)
-        )
 
 
 def _head_products(lowered):

@@ -98,11 +98,17 @@ def test_unknown_expert_raises(server):
         )
 
 
-def test_cross_client_batching(server):
-    """Many concurrent remote calls get batched into few device batches."""
+def test_cross_client_batching(server, monkeypatch):
+    """Many concurrent remote calls get batched into few device batches.
+    The pool is held open until the sixteenth request is in (its window
+    closes on the row count, 16 x 2, not on its 2 ms timer: under load the
+    requests arrive further apart than that), so the count is exact: 16
+    requests, ONE batch."""
     endpoint, srv = server
     expert = RemoteExpert("expert.1", endpoint)
     pool = srv.forward_pools["expert.1"]
+    monkeypatch.setattr(pool, "batch_timeout", 60.0)
+    monkeypatch.setattr(pool, "max_batch_size", 16 * 2)
     formed_before = pool.batches_formed
 
     import concurrent.futures as cf
@@ -116,8 +122,9 @@ def test_cross_client_batching(server):
         np.testing.assert_allclose(
             out, np.asarray(apply_fn(live, x)), atol=1e-4, rtol=1e-4
         )
-    formed = srv.forward_pools["expert.1"].batches_formed - formed_before
-    assert formed < 16  # if batching broke, every request would form its own batch
+    # if batching broke, every request would form its own batch
+    assert pool.batches_formed - formed_before == 1
+    assert pool.bucket_batches.get(32, 0) >= 1
 
 
 def test_server_create_classmethod():

@@ -118,13 +118,15 @@ def test_hedge_win_cancels_straggler_primary_marked():
     50 ms deadline, and the loser primary's cancel carries the straggler
     marker — its elapsed wait folds into its RTT EMA (the pool learns
     the slowness), per the QUORUM_STRAGGLER_CANCEL contract."""
+    # the primary holds every reply back for a minute: the backup wins
+    # however long a loaded machine takes over its first (compiling) call
     ctx_a, ctx_b = _replica_pair(
-        chaos_a=ChaosConfig(base_latency=0.5, seed=0)
+        chaos_a=ChaosConfig(base_latency=60.0, seed=0)
     )
     with ctx_a as (ep_a, _), ctx_b as (ep_b, _):
-        moe = _replicated_moe(ep_a, ep_b)
+        moe = _replicated_moe(ep_a, ep_b, forward_timeout=120.0)
         pool_a = _seed_rtt(ep_a, 0.001)  # deadline = floor = 0.05 s
-        _seed_rtt(ep_b, 0.4)
+        pool_b = _seed_rtt(ep_b, 0.4)
         gate = moe.init_gate_params(jax.random.PRNGKey(0))
         jax.block_until_ready(moe(_x(), gate))
         routing = moe.dispatch_stats()["routing"]
@@ -134,8 +136,9 @@ def test_hedge_win_cancels_straggler_primary_marked():
         # marked cancel folded the ≥50 ms elapsed wait into the EMA:
         # 0.8 × 0.001 + 0.2 × ≥0.05 ≥ 0.0108 ≫ the seeded 0.001
         assert pool_a.rtt_ema > 0.005, pool_a.rtt_ema
-        # the whole dispatch beat the primary's 0.5 s injected latency
-        assert moe.dispatch_times[-1] < 0.45, list(moe.dispatch_times)
+        # the dispatch did not wait for the primary: it is done, and every
+        # byte that came back came from the backup
+        assert pool_a.bytes_received == 0 and pool_b.bytes_received > 0
 
 
 def test_hedge_loser_backup_ema_never_poisoned():
@@ -262,12 +265,19 @@ def test_replica_kill_mid_training_costs_less_than_one_round():
             assert losses[-1] < losses[3], losses
 
 
-def test_loss_parity_cost_model_bias_vs_blind():
+def test_loss_parity_cost_model_bias_vs_blind(monkeypatch):
     """Decode-gap guard (ROADMAP standing item): the cost-model bias at
     DEFAULT strength must not measurably degrade the smoke loss curve vs
     the bias=0 blind gate.  Two identical 4-expert swarms (same seeds →
     identical expert params), same data, same gate init; only the
-    routing_cost_weight differs."""
+    routing_cost_weight differs.  The bias is a function of the pools' RTT
+    EMAs, so they are PINNED at what two loopback peers read on a quiet
+    machine (1.0 and 1.2 ms): on a loaded one a peer's first exchange (a
+    compile on its server) reads a second, and a bias of that size is
+    another routing, not the near-tie breaker this guards."""
+    from learning_at_home_tpu.utils.connection import ConnectionPool
+
+    monkeypatch.setattr(ConnectionPool, "_update_rtt", lambda self, dt: None)
 
     def run(weight):
         with background_server(
@@ -280,6 +290,8 @@ def test_loss_parity_cost_model_bias_vs_blind():
             ) as (ep_b, srv_b):
                 experts = {uid: ep_a for uid in srv_a.experts}
                 experts.update({uid: ep_b for uid in srv_b.experts})
+                _seed_rtt(ep_a, 1.0e-3)
+                _seed_rtt(ep_b, 1.2e-3)
                 moe = RemoteMixtureOfExperts(
                     in_features=HID, grid_size=(4,), uid_prefix="par",
                     source=StaticExpertSource(experts), k_best=2, k_min=1,
@@ -309,9 +321,10 @@ def test_loss_parity_cost_model_bias_vs_blind():
     cost, cost_applied = run(DEFAULT_COST_WEIGHT)
     assert blind_applied == 0  # the A/B arm really is the blind gate
     assert cost_applied > 0    # and the cost arm really biased selection
-    # parity: the biased arm's final loss is within noise of the blind
-    # arm's (loopback peers are near-identical, so the bias should only
-    # resolve near-ties, never distort the mixture measurably)
+    # parity: two different routings are compared, so a tolerance: the
+    # biased arm's final loss within a tenth of the blind arm's (loopback
+    # peers are near-identical, so the bias should only resolve near-ties,
+    # never distort the mixture measurably)
     assert cost[-1] <= blind[-1] + max(0.1 * abs(blind[-1]), 0.02), (
         blind, cost,
     )

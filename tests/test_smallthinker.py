@@ -5,8 +5,9 @@ key/value heads, window and global layers in one stack, rotary on the
 window layers alone, ReGLU experts routed top-6 from the attention block's
 input; the refusals beside that path; and the benchmark's files for it.
 
-Tiny sizes on the CPU, except the AOT compiles at published widths for a
-described (not attached) ``v5e`` chip.
+Tiny sizes on the CPU; the attention cores at these heads are
+``tests/test_smallthinker_attention.py``'s, the AOT compiles at published widths for a described
+(not attached) ``v5e`` chip are ``tests/test_smallthinker_chip.py``'s.
 """
 
 import collections
@@ -20,7 +21,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(REPO, "benchmarks"))
@@ -29,7 +29,6 @@ import harness  # noqa: E402  (benchmarks/harness.py: imports no jax)
 import smallthinker_flops  # noqa: E402
 
 from __graft_entry__ import smallthinker_one_chip  # noqa: E402
-from learning_at_home_tpu.models import trunk  # noqa: E402
 from learning_at_home_tpu.models.transformer import (  # noqa: E402
     AttentionLayer,
     DMoETransformerLM,
@@ -89,8 +88,9 @@ SIZES = runner.reference_sizes(TINY_FILE)  # what the runner hands the reference
 def want(tiny):
     """The reference's logits, loss and gradients on the tiny weights."""
     _, _, params, ids, tgt = tiny
-    logits, _, _ = reference.forward(params, ids, SIZES)
-    loss, grads = reference.loss_and_grads(params, ids, tgt, SIZES)
+    logits = jax.jit(lambda p: reference.forward(p, ids, SIZES)[0])(params)
+    loss, grads = jax.jit(
+        lambda p: reference.loss_and_grads(p, ids, tgt, SIZES))(params)
     return np.asarray(logits), float(loss), grads
 
 
@@ -241,15 +241,16 @@ def test_readings_from_blocks_equal_readings_from_whole_logits(tiny, monkeypatch
     monkeypatch.setattr(runner, "LOGIT_BLOCK", 8)
     read = runner.compare_with_reference(
         m16, params, reference, TINY_FILE, ids[:1], tgt[:1])
+    layer = jax.jit(m16._layer, static_argnums=(2, 4))
     x = params["embed"][ids[:1]].astype(jnp.bfloat16)
     for i, lp in enumerate(params["layers"]):
-        x, _ = m16._layer(lp, x, i, None, cfg.attention_layer(i))
-    got = np.asarray(m16._logits(m16._norm(params["ln_f"], x), m16._head(params)),
-                     np.float64)
+        x, _ = layer(lp, x, i, None, cfg.attention_layer(i))
+    got = np.asarray(jax.jit(lambda p, x: m16._logits(
+        m16._norm(p["ln_f"], x), m16._head(p)))(params, x), np.float64)
     ref = np.asarray(reference.head(params, x.astype(jnp.float32), SIZES), np.float64)
     scale = np.sqrt(np.mean(ref ** 2))
     diff = np.abs(got - ref)
-    # (the runner's layers are compiled, these are not: bf16 roundings differ)
+    # (other compiled programs than the runner's: bf16 roundings may differ)
     np.testing.assert_allclose(read["reference_logits_rms"], scale, rtol=1e-3)
     np.testing.assert_allclose(
         read["logits_rms"], np.sqrt(np.mean(diff ** 2)) / scale, rtol=0.1)
@@ -345,84 +346,18 @@ def test_router_input_is_taken_only_where_it_is_declared():
     params = own.init_params(jax.random.PRNGKey(0))
     params["gate"] = params["gate"] * 100.0
     shared = ShardedMixtureOfExperts(mesh, **kw)
-    y_shared, _ = shared(params, x)
-    y_same, _ = own(params, x, router_x=x)
-    np.testing.assert_array_equal(np.asarray(y_same), np.asarray(y_shared))
-    y_other, _ = own(params, x, router_x=x[::-1])
+    y_shared, _ = jax.jit(shared)(params, x)
+    routed = jax.jit(lambda p, x, router_x: own(p, x, router_x=router_x))
+    y_same, _ = routed(params, x, x)
+    # two compiled programs of the same sums: an ulp or two apart at most
+    np.testing.assert_allclose(
+        np.asarray(y_same), np.asarray(y_shared), rtol=1e-6, atol=1e-6)
+    y_other, _ = routed(params, x, x[::-1])
     assert not np.allclose(np.asarray(y_other), np.asarray(y_shared))
     with pytest.raises(ValueError, match="router_x is missing"):
         own(params, x)
     with pytest.raises(ValueError, match="router_x is given"):
         shared(params, x, router_x=x)
-
-
-# ---- the attention cores ----
-
-
-def _naive_attention(q, k, v, window):
-    b, s, h, hd = q.shape
-    group = h // k.shape[2]
-    k, v = (jnp.repeat(a, group, axis=2) for a in (k, v))
-    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(hd)
-    i, j = np.arange(s)[:, None], np.arange(s)[None, :]
-    allowed = (j <= i) if window is None else (j <= i) & (j > i - window)
-    scores = jnp.where(allowed[None, None], scores, -jnp.inf)
-    return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(scores, axis=-1), v)
-
-
-def _grouped_qkv(s, h, kv, hd):
-    keys = jax.random.split(jax.random.PRNGKey(1), 3)
-    return (jax.random.normal(keys[0], (1, s, h, hd), jnp.float32),
-            jax.random.normal(keys[1], (1, s, kv, hd), jnp.float32),
-            jax.random.normal(keys[2], (1, s, kv, hd), jnp.float32))
-
-
-@pytest.mark.parametrize("window", [None, 1, 5, 24, 100])
-def test_xla_core_takes_grouped_heads_and_a_window(window):
-    q, k, v = _grouped_qkv(24, 6, 2, 16)
-    np.testing.assert_allclose(
-        np.asarray(trunk.attention_core(q, k, v, "xla", window)),
-        np.asarray(_naive_attention(q, k, v, window)), atol=2e-6)
-
-
-@pytest.mark.parametrize("window, seq, tile", [
-    (None, 512, 128), (300, 512, 128),
-    # through the rule as it is: a window shorter than the key block, so
-    # 512-wide blocks of queries and keys and the unfused backward's two
-    # kernels over grids shrunk to the mask
-    (200, 1024, None),
-])
-def test_blocked_kernel_takes_grouped_heads_and_a_window(window, seq, tile, monkeypatch):
-    """The kernel itself (interpreted on the CPU), over several blocks:
-    two key/value heads under six query heads, uncopied, under the causal
-    and the local mask, forward and backward, against the plain
-    mathematics."""
-    import functools
-
-    from jax.experimental.pallas.ops.tpu import splash_attention as splash
-
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    if tile:
-        monkeypatch.setattr(trunk, "_FLASH_TILES", {k: tile for k in trunk._FLASH_TILES})
-    else:
-        sizes = trunk.flash_block_sizes((1, seq, 6, 64), "tpu", window)
-        assert not sizes.use_fused_bwd_kernel
-        assert window < sizes.block_kv < seq and sizes.block_q < seq
-    monkeypatch.setattr(
-        splash, "make_splash_mha_single_device",
-        functools.partial(splash.make_splash_mha_single_device, interpret=True))
-    q, k, v = _grouped_qkv(seq, 6, 2, 64)
-
-    def both(core):
-        out, vjp = jax.vjp(core, q, k, v)
-        return (out,) + vjp(jnp.cos(out))
-
-    got = both(lambda q, k, v: trunk.attention_core(q, k, v, "flash", window))
-    want = both(lambda q, k, v: _naive_attention(q, k, v, window))
-    for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
-        np.testing.assert_allclose(
-            np.asarray(a), np.asarray(b), atol=2e-3 * float(jnp.abs(b).max()),
-            err_msg=name)
 
 
 # ---- the benchmark's files for it ----
@@ -565,100 +500,3 @@ def test_every_grouped_matmul_of_a_layer_carries_its_tiles():
     # gradients; the weights' gradients of gate and up; of down
     assert seen == {(256, 2560, 768): 3, (256, 768, 2560): 3,
                     (256, 1280, 768): 2, (256, 768, 1280): 1}
-
-
-# ---- the chip's compiler accepts the block at published widths ----
-
-
-def test_the_whole_step_fits_the_chip(v5e_chip, monkeypatch):
-    """The 4-layer train step at published widths, compiled for a
-    described chip (nothing runs): 2.372 B parameters, the compiler's
-    own count of what is live in the step is between a quarter of the
-    chip's memory (the benchmark's floor for a cell) and all of it, and
-    every one of the step's 48 grouped-matmul instructions runs at
-    ``grouped_matmul_tiles``'s answer for its shape (16 did before PR 32)."""
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    memory = probe.step_memory(v5e_chip)
-    assert memory["parameters"] == 2_372_426_240
-    assert memory["argument_bytes"] > 2 * memory["parameters"]  # bf16, state
-    assert 0.25 < memory["share_of_chip"] < 0.9, memory
-    # a layer's 12 grouped matmuls (remat runs the 3 forward ones twice),
-    # each compiled at the tiles the rule reads from its shape
-    assert memory["grouped_matmul_tilings"] == {
-        "256,2560,768": 4 * 5, "256,768,2560": 4 * 4,
-        "256,1280,768": 4 * 2, "256,768,1280": 4 * 1,
-    }
-    # the logits and the two gradient products, in one scan of chunks: the
-    # chip's compiler keeps no fourth product of the head's (PR 34)
-    assert memory["loss_layer_products"] == 3
-    # a window of 4,096 is no shorter than the kernel's key block: all four
-    # layers keep the fused backward at 1024-wide blocks (PR 36); one
-    # forward a layer (8 before PR 38): remat keeps the kernel's output and
-    # row sums, 119 MB a layer, and the recompute holds no forward call,
-    # in the compiled step's instructions and in the traced step's
-    # equations (``attention_kernel_tilings``) alike
-    assert memory["attention_kernel_calls"] == {
-        "splash_mha_fwd_residuals": 4, "splash_mha_dkv_no_residuals": 4}
-    # a mixture layer's sums over a token's 6 rows are ``ops/moe_rows.py``'s
-    # kernel, once behind the combine's gather and once behind the sort's
-    # backward; five row gathers a layer (six before PR 50), no scatter
-    assert memory["moe_rows_kernel_calls"] == {
-        "moe_rows_sum": {"calls": 4 * 2, "under_moe_sort": 4, "under_moe_combine": 4},
-        "row_gathers": 4 * 5, "row_scatters": 0}
-    assert memory["kept_residual_bytes"] == 4 * 28 * 16384 * (128 * 2 + 4)
-    # and the results of the attention part's products (PR 53): q, k, v and
-    # the output projection's, bf16 [16384, 3584 + 512 + 512 + 2560] a
-    # layer, 0.94 GB; the backward pass runs none of the four a second time
-    assert memory["kept_product_bytes"] == 4 * 16384 * (3584 + 2 * 512 + 2560) * 2
-    assert memory["recomputed_attention_products"] == 0
-    assert {kind: {name: call["calls"] for name, call in calls.items()}
-            for kind, calls in memory["attention_kernel_tilings"].items()} == {
-        "global": {"splash_mha_fwd_residuals": 1, "splash_mha_dkv_no_residuals": 1},
-        "window": {"splash_mha_fwd_residuals": 3, "splash_mha_dkv_no_residuals": 3}}
-    assert {(call["block_q"], call["block_kv"])
-            for calls in memory["attention_kernel_tilings"].values()
-            for call in calls.values()} == {(1024, 1024)}
-
-
-@pytest.mark.parametrize("layer", [0, 1], ids=["global", "window"])
-def test_one_layer_compiles_for_v5e_at_published_widths(v5e_chip, monkeypatch, layer):
-    """Forward and backward of one global and one window layer of the
-    recipe (2560 wide, 28 heads over 4 key/value heads of 128, 64 ReGLU
-    experts of 768 top-6, 1 x 16,384 tokens) for a described chip: the
-    blocked kernel takes the 4 key/value heads as they are under its
-    causal and its local mask, and no [.., 16384, 16384] array is left."""
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    mesh = Mesh(np.array([v5e_chip]), ("expert",))
-    model, cfg, _, batch = smallthinker_one_chip(mesh)
-    assert model.attn_impl == "flash"  # what a user on the chip gets
-    assert (cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, cfg.num_experts,
-            cfg.k, model.moe.ffn_dim, cfg.seq_len, cfg.vocab_size, batch) == (
-        2560, 28, 4, 128, 64, 6, 768, 16384, 151936, 1)
-    kind = cfg.attention_layer(layer)
-    assert kind == (AttentionLayer(None, False), AttentionLayer(4096, True))[layer]
-    one = NamedSharding(mesh, P())
-    shapes = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
-    lp = jax.tree_util.tree_map(
-        lambda s, h: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=h),
-        shapes["layers"][layer], model.param_shardings(shapes)["layers"][layer],
-    )
-    x = jax.ShapeDtypeStruct((batch, cfg.seq_len, cfg.d_model), cfg.dtype, sharding=one)
-
-    def layer_loss(lp, x):
-        y, aux = model._layer(lp, x, layer, None, kind)
-        return (y.astype(jnp.float32) ** 2).mean() + aux["aux_loss"]
-
-    with probe.no_compile_cache():
-        compiled = jax.jit(jax.grad(layer_loss, argnums=(0, 1))).lower(lp, x).compile()
-    text = compiled.as_text()
-    assert text.count("ragged-dot-none") >= 9  # 3 forward, 6 backward
-    scope = "attention/" + ("global", "window")[layer]
-    kernels = set(re.findall(
-        r'op_name="[^"]*[/(]%s[/)]+flash/[^"]*/(\w+)/pallas_call"' % scope, text))
-    assert len(kernels) == 2 and all(k.startswith("splash_mha") for k in kernels), kernels
-    # the kernel reads K and V with their 4 heads: no 28-head copy is made
-    calls = re.findall(r"%splash_mha_fwd\w*(?:\.\d+)? = [^\n]*custom-call\(", text)
-    assert calls and "bf16[4,16384,128]" in text
-    assert ("rope" in text) == kind.rotary
-    assert "16384,16384" not in text
-    assert compiled.memory_analysis().temp_size_in_bytes < 6e9

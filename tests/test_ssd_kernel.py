@@ -56,8 +56,9 @@ def test_the_kernel_matches_the_plain_form(chunks, groups, heads, dtype):
     decays, state and accumulation), so bf16 reads as float32 does: the
     order of a sum's terms apart."""
     args = _inputs(chunks, groups, heads, dtype)
-    want_y, want_state = ssd.ssd_chunked_plain(*args, Q)
-    y, state = ssd.ssd_chunked_kernel(*args, Q, interpret=True)
+    want_y, want_state = jax.jit(lambda *a: ssd.ssd_chunked_plain(*a, Q))(*args)
+    y, state = jax.jit(
+        lambda *a: ssd.ssd_chunked_kernel(*a, Q, interpret=True))(*args)
     assert y.dtype == want_y.dtype and y.shape == want_y.shape
     assert state.dtype == jnp.float32 and state.shape == want_state.shape
     tol = 1e-5 if dtype == jnp.float32 else 4e-3
@@ -85,10 +86,13 @@ def test_the_kernels_gradients_match_autodiff_of_the_plain_form(
                     + jnp.sum(state * weigh_state))
         return of
 
-    want = jax.grad(loss(lambda *a: ssd.ssd_chunked_plain(*a, Q)),
-                    argnums=(0, 1, 2, 3, 4))(*args)
-    got = jax.grad(loss(lambda *a: ssd.ssd_chunked_kernel(*a, Q, interpret=True)),
-                   argnums=(0, 1, 2, 3, 4))(*args)
+    # each side ONE compiled program: operation by operation the plain
+    # form's autodiff takes the CPU 8 s a case
+    want = jax.jit(jax.grad(loss(lambda *a: ssd.ssd_chunked_plain(*a, Q)),
+                            argnums=(0, 1, 2, 3, 4)))(*args)
+    got = jax.jit(jax.grad(
+        loss(lambda *a: ssd.ssd_chunked_kernel(*a, Q, interpret=True)),
+        argnums=(0, 1, 2, 3, 4)))(*args)
     tol = 1e-4 if dtype == jnp.float32 else 1.5e-2
     for name, g, w in zip(("x", "dt", "A", "B", "C"), got, want):
         assert g.dtype == w.dtype and g.shape == w.shape, name

@@ -334,6 +334,14 @@ def served_by_kind():
             for _ in range(SINGLES):
                 expert.forward_blocking([x])
                 expert.backward_blocking([x], [x])
+            # a reply reaches its client before the server's loop closes the
+            # ``server.write`` span around the send: the last request's may
+            # still be open.  Wait for the COUNT (what the tests compare)
+            wanted = 2 * (DISPATCHES + SINGLES)
+            for _ in range(2000):
+                if len(timeline.recent("server.write")) >= wanted:
+                    break
+                time.sleep(0.005)
             seen = {
                 "recent": {key: timeline.recent(key)
                            for key in timeline.stage_stats(window_s=1e9)},

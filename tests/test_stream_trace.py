@@ -186,6 +186,13 @@ def test_cancel_marker_carries_trace(swarm, profiled):
         sub = client.submit([1, 2, 3], 8, trace=tid)
         assert sub.get("accepted") and sub["trace"] == tid
         assert client.cancel(sub["sid"])
+        # ``cancel`` only marks the stream: the scheduler ends it (and writes
+        # the marker) on its next pass.  Wait for that pass, a state and not
+        # a time, before the gateway goes down with the mark unread
+        for _ in range(2000):
+            if client.poll(sub["sid"]).get("done"):
+                break
+            time.sleep(0.005)
     cancels = timeline.spans("gateway.stream.cancel")
     assert any(s[3] == tid for s in cancels)
     # the umbrella still closes, on the same id
