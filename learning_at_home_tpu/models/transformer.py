@@ -31,6 +31,8 @@ from learning_at_home_tpu.models.trunk import (
     ATTENTION_PRODUCTS,
     FLASH_RESIDUALS,
     attention_core,
+    block_diffusion_admitted_pairs,
+    block_diffusion_visited_pairs,
     delta_mixer,
     flash_block_sizes,
     gate_activation,
@@ -292,6 +294,20 @@ class DMoETransformerConfig:
     # beta = 2 sigmoid(b) (Olmo-Hybrid's linear_allow_neg_eigval) or, False,
     # sigmoid(b) (Qwen3-Next)
     delta_neg_eigval: bool = True
+    # what a training row is and what the loss asks of it.  'next_token':
+    # the row's ids, a causal mask, the mean CE of each position's next
+    # token.  'block_diffusion' (BD3-LMs, arXiv:2503.09573, as SDAR trains):
+    # the stack runs a row of 2 * seq_len positions, a noised copy then the
+    # clean copy of the same seq_len ids at rotary positions 0..seq_len-1
+    # twice, under ``trunk.block_diffusion_mask`` with blocks of
+    # diffusion_block tokens; a block draws t ~ U(0, 1) and each of its
+    # tokens becomes the mask id (the vocabulary's last id) with
+    # probability p = DIFFUSION_P_FLOOR + (1 - DIFFUSION_P_FLOOR) t; the
+    # loss is over the noised copy, in place: sum over masked positions of
+    # CE(logits_i, ids_i) / p over batch * seq_len.  seq_len counts the
+    # DATA tokens of a row, under either objective
+    objective: str = "next_token"
+    diffusion_block: int = 4
 
     def mixture_layers(self) -> int:
         """How many of the stack's layers route (hold a mixture)."""
@@ -320,6 +336,9 @@ class DMoETransformerConfig:
 #   8,192  241.06 /  3.73      485.28 /  8.21   (xla's [B,H,S,S] scores
 #                                                 fall off the HBM cliff)
 FLASH_MIN_SEQ_LEN = 512
+# the least masking probability a block draws under objective
+# 'block_diffusion' (BD3-LMs' and LLaDA's floor): 1 / p is the loss's weight
+DIFFUSION_P_FLOOR = 1e-3
 
 
 def auto_attn_impl(
@@ -512,6 +531,32 @@ class DMoETransformerLM:
                 "bias (router_bias) and no next-but-one-token block, whose "
                 "layer is a mixture layer (mtp_layers)"
             )
+        if config.objective not in ("next_token", "block_diffusion"):
+            raise ValueError(
+                f"objective must be 'next_token' or 'block_diffusion', got "
+                f"{config.objective!r}"
+            )
+        self._diffusion = config.objective == "block_diffusion"
+        if self._diffusion:
+            if (
+                config.positions != "rope" or mixers is not None
+                or self._delta or config.mtp_layers or config.seq_parallel
+                or any(a.window is not None for a in kinds)
+            ):
+                raise NotImplementedError(
+                    "objective='block_diffusion' runs softmax attention "
+                    "with rotary positions in every layer over one doubled "
+                    "row: no learned positions (a table of seq_len rows), "
+                    "no window, no recurrent mixer (mixer_pattern, a "
+                    "'delta' layer: a state would run from the noised copy "
+                    "into the clean one), no next-but-one-token block and "
+                    "no ring (seq_parallel)"
+                )
+            if config.seq_len % config.diffusion_block:
+                raise ValueError(
+                    f"diffusion_block={config.diffusion_block} must divide "
+                    f"seq_len={config.seq_len}"
+                )
         n_kv = config.n_kv_heads or config.n_heads
         if config.n_heads % n_kv:
             raise ValueError(
@@ -918,15 +963,16 @@ class DMoETransformerLM:
                 attn_in = self._part_input(norm_p, x)
             core = self._ring if self._ring is not None else (
                 lambda q, k, v: attention_core(
-                    q, k, v, self.attn_impl, kind.window
+                    q, k, v, self.attn_impl, kind.window,
+                    self.cfg.diffusion_block if self._diffusion else None,
                 )
             )
-            q, k, v, gate = self._qkv(
-                lp, attn_in,
+            if self._diffusion:  # the doubled row: both copies at 0..s/2-1
+                positions = np.arange(s) % (s // 2)
+            else:
                 # under the zigzag ring the stream is in zigzag order
-                np.arange(s) if self._zig is None else self._zig,
-                kind.rotary,
-            )
+                positions = np.arange(s) if self._zig is None else self._zig
+            q, k, v, gate = self._qkv(lp, attn_in, positions, kind.rotary)
             out = output_projection(lp, core(q, k, v), gate)
             extremes = {}
             if gate is not None:
@@ -996,6 +1042,8 @@ class DMoETransformerLM:
         next_ids: jax.Array | None = None,
     ) -> tuple:
         """token_ids [B, S] → final-LN hidden states [B, S, d]; aux scalars.
+        Under ``objective='block_diffusion'`` the row is the doubled one,
+        ``[x_t | x_0]`` (:meth:`noised_row`), and so is the stream.
 
         With ``next_ids`` [B, S] (each position's next token: a training
         row's targets; only where the config has the next-but-one-token
@@ -1215,6 +1263,14 @@ class DMoETransformerLM:
         whenever capacity never binds (generous ``capacity_factor``),
         and the per-step regime is what a serving stack does anyway.
         """
+        if self._diffusion:
+            raise NotImplementedError(
+                "generate() with objective='block_diffusion': the model "
+                "generates a block at a time (denoising steps that each "
+                "yield several tokens a sequence, against a cache of the "
+                "finished blocks), which is not built; this decoder yields "
+                "one token a step under a causal mask"
+            )
         b, p = prompt_ids.shape
         s = self.cfg.seq_len
         if p == 0:
@@ -1497,11 +1553,110 @@ class DMoETransformerLM:
 
     # ---- loss / train step ----
 
+    def noise_draws(self, key: jax.Array, batch: int) -> tuple:
+        """The uniform draws block diffusion's noising reads: ``u``
+        [batch, seq_len], one a token, and ``t`` [batch, seq_len /
+        diffusion_block], one a block."""
+        token_key, block_key = jax.random.split(key)
+        s = self.cfg.seq_len
+        return (
+            jax.random.uniform(token_key, (batch, s), jnp.float32),
+            jax.random.uniform(
+                block_key, (batch, s // self.cfg.diffusion_block), jnp.float32),
+        )
+
+    def noised_row(self, token_ids: jax.Array, u: jax.Array, t: jax.Array):
+        """The stack's row and the loss's weights from ``token_ids`` [B, S]
+        and the draws of :meth:`noise_draws`: a block's ``p = floor + (1 -
+        floor) t``; a token is replaced by the mask id where its ``u < p``.
+        Returns ``[x_t | x_0]`` [B, 2 S] and the weights [B, S], ``1 / p``
+        at a masked position and 0 elsewhere."""
+        cfg = self.cfg
+        floor = DIFFUSION_P_FLOOR
+        p = jnp.repeat(floor + (1.0 - floor) * t, cfg.diffusion_block, axis=1)
+        masked = u < p
+        mask_id = jnp.asarray(cfg.vocab_size - 1, token_ids.dtype)  # the last
+        noised = jnp.where(masked, mask_id, token_ids)
+        return (
+            jnp.concatenate([noised, token_ids], axis=1),
+            jnp.where(masked, 1.0 / p, 0.0),
+        )
+
+    def noise_key(self, opt_state, token_ids: jax.Array) -> jax.Array:
+        """The key a train step noises its rows with, derived on the device
+        from what the step already holds: the optimizer state's ``count``
+        (fresh every step) folded into a key made of the rows' own ids (so
+        of the seed that made the data; two rows of a pool differ)."""
+        counts = [
+            leaf for path, leaf in
+            jax.tree_util.tree_flatten_with_path(opt_state)[0]
+            if getattr(path[-1], "name", getattr(path[-1], "key", None))
+            == "count"
+        ]
+        if not counts:
+            raise NotImplementedError(
+                "objective='block_diffusion' folds the optimizer state's "
+                f"'count' into its noise key; {type(opt_state).__name__} "
+                "has none"
+            )
+        ids = token_ids.astype(jnp.uint32).reshape(-1)
+        mark = jnp.sum(ids * (2 * jnp.arange(ids.size, dtype=jnp.uint32) + 1))
+        return jax.random.fold_in(
+            jax.random.fold_in(jax.random.key(0), mark), counts[0])
+
+    def _diffusion_loss_fn(self, params, token_ids, noise_key):
+        """Block diffusion's training loss (``cfg.objective``): the stack
+        over ``[x_t | x_0]``, the head over the noised copy alone, in place
+        (position i predicts ``token_ids[i]``, no shift), each masked
+        position's CE times ``1 / p`` over ``B * S``; plus the weighted
+        router aux and z losses over all ``2 S`` positions."""
+        cfg = self.cfg
+        b, s = token_ids.shape
+        with jax.named_scope("noise"):
+            u, t = self.noise_draws(noise_key, b)
+            row, weights = self.noised_row(token_ids, u, t)
+            masked = (weights > 0).astype(jnp.float32)
+            counters = {
+                "masked_share": jnp.mean(masked),
+                "loss_weight_mean": jnp.sum(weights) / jnp.maximum(
+                    jnp.sum(masked), 1.0),
+            }
+        x, aux = self._hidden(params, row)
+        with jax.named_scope("ce"):
+            ce = self._chunked_ce(
+                x[:, :s], self._head(params), token_ids, weights=weights)
+        loss = (
+            ce
+            + cfg.aux_loss_weight * aux["aux_loss"]
+            + cfg.router_z_weight * aux["router_z_loss"]
+        )
+        head_dim = cfg.head_dim or cfg.d_model // cfg.n_heads
+        shape = (b, 2 * s, cfg.n_heads, head_dim)
+        counters.update(  # static: what the mask admits, what the core computes
+            attention_admitted_pairs=jnp.float32(
+                block_diffusion_admitted_pairs(s, cfg.diffusion_block)),
+            attention_visited_pairs=jnp.float32(block_diffusion_visited_pairs(
+                shape, self.attn_impl, jax.default_backend(),
+                cfg.diffusion_block)),
+        )
+        return loss, {"ce": ce, **aux, **counters}
+
     def loss_fn(
-        self, params: Params, token_ids: jax.Array, targets: jax.Array
+        self, params: Params, token_ids: jax.Array, targets: jax.Array,
+        noise_key: jax.Array | None = None,
     ) -> tuple[jax.Array, dict]:
         """Training loss: mean next-token CE (:meth:`_chunked_ce`)
-        plus the weighted router aux and z losses."""
+        plus the weighted router aux and z losses.  Under
+        ``objective='block_diffusion'`` the row predicts itself under the
+        noise ``noise_key`` draws (:meth:`_diffusion_loss_fn`) and
+        ``targets`` is not read."""
+        if self._diffusion:
+            if noise_key is None:
+                raise ValueError(
+                    "objective='block_diffusion' noises its rows: loss_fn "
+                    "needs a noise_key (a train step derives one: noise_key)"
+                )
+            return self._diffusion_loss_fn(params, token_ids, noise_key)
         if self.cfg.mtp_layers:
             x, x_mtp, aux = self._hidden(params, token_ids, next_ids=targets)
         else:
@@ -1534,7 +1689,8 @@ class DMoETransformerLM:
             metrics["ce_mtp"] = ce_mtp
         return loss, metrics
 
-    def _chunked_ce(self, x, head, targets, denominator=None, masked=False):
+    def _chunked_ce(self, x, head, targets, denominator=None, masked=False,
+                    weights=None):
         """Chunked cross-entropy: the [tokens, V] f32 logits are never
         materialized at once.  Token chunks of ``ce_chunk`` go through the
         head + softmax-CE inside a ``lax.scan``, so peak logits memory is
@@ -1547,7 +1703,10 @@ class DMoETransformerLM:
         batch from 16 to 64 — the f32 logits (+ cotangents) were the
         dominant activation term.  ``denominator``: what the summed CEs
         are divided by (None = every token, ``B * S``); ``masked``: a
-        target of -1 marks a position that has none and adds nothing.
+        target of -1 marks a position that has none and adds nothing;
+        ``weights`` [B, S] float32: each position's CE is multiplied by its
+        weight before the sum (0 at a position the loss does not ask; None
+        = the unweighted code, not a multiply by ones).
 
         Which path runs is read off the mesh and the shapes:
 
@@ -1574,27 +1733,31 @@ class DMoETransformerLM:
         b_shards = int(np.prod([mesh.shape[a] for a in data_axes(mesh)]))
         s_shards = mesh.shape.get("seq", 1)
         if mesh.devices.size == 1 or b % b_shards or s % s_shards:
-            return self._chunked_ce_sum(x, head, targets, denominator, masked)
+            return self._chunked_ce_sum(
+                x, head, targets, denominator, masked, weights)
 
         from jax import shard_map
 
         spec = batch_sharding(mesh).spec  # P(batch axes[, "seq"])
+        # the weights, where the loss has them, are laid out as the targets
+        labels = (targets,) if weights is None else (targets, weights)
         ce_sums = shard_map(  # one sum a shard, laid out like the shards
-            lambda xl, hl, tl: self._chunked_ce_sum(
-                xl, hl, tl, denominator, masked
+            lambda xl, hl, *ll: self._chunked_ce_sum(
+                xl, hl, ll[0], denominator, masked, *ll[1:]
             ).reshape(
                 (1,) * len(spec)
             ),
             mesh=mesh,
-            in_specs=(P(*spec, None), P(), spec),
+            in_specs=(P(*spec, None), P(), *(spec,) * len(labels)),
             out_specs=spec,
             # the scan's carry starts unvarying (a constant 0) and ends
             # varying over the batch axes: the varying-axes check refuses it
             check_vma=False,
-        )(x, head, targets)
+        )(x, head, *labels)
         return ce_sums.sum()
 
-    def _chunked_ce_sum(self, x, head, targets, denominator, masked=False):
+    def _chunked_ce_sum(self, x, head, targets, denominator, masked=False,
+                        weights=None):
         """Sum (f32) of the token CEs of ``x`` [b, s, d] over
         ``denominator`` (the GLOBAL token count, also where ``x`` is one
         shard's rows), ``ce_chunk`` tokens at a time: :func:`_ce_of_chunks`."""
@@ -1602,6 +1765,7 @@ class DMoETransformerLM:
         return _ce_of_chunks(
             x.reshape(n, x.shape[-1]), head, targets.reshape(n),
             min(self.cfg.ce_chunk, n), denominator, masked,
+            None if weights is None else weights.reshape(n),
         )
 
     # ---- the routers' selection biases ----
@@ -1745,6 +1909,12 @@ class DMoETransformerLM:
         averages the gradients, and applies ONE optimizer update —
         effective batch = accum × batch without the activation HBM of
         the large batch."""
+        if self._diffusion and accum_steps > 1:
+            raise NotImplementedError(
+                "accum_steps > 1 with objective='block_diffusion': the "
+                "microbatches of one step share the optimizer's count, and "
+                "no key is derived a microbatch"
+            )
         grad_fn = jax.value_and_grad(self.loss_fn, has_aux=True)
         # FusedOptimizer (ops.fused_adafactor) folds the param add into the
         # optimizer's own final pass — the update tree never hits HBM
@@ -1759,7 +1929,13 @@ class DMoETransformerLM:
                 return optax.apply_updates(params, updates), opt_state
 
         def train_step(params, opt_state, token_ids, targets):
-            (loss, metrics), grads = grad_fn(params, token_ids, targets)
+            if self._diffusion:  # the step's own noise, drawn on the device
+                with jax.named_scope("noise"):
+                    key = self.noise_key(opt_state, token_ids)
+                (loss, metrics), grads = grad_fn(
+                    params, token_ids, targets, key)
+            else:
+                (loss, metrics), grads = grad_fn(params, token_ids, targets)
             biases = self._router_biases(params)
             with jax.named_scope("optimizer"):
                 params, opt_state = apply_fn(params, grads, opt_state)
@@ -1827,12 +2003,14 @@ class DMoETransformerLM:
 # ---- the loss layer: the token CEs a chunk at a time ----
 
 
-def _softmax_ce(logits: jax.Array, targets: jax.Array, masked: bool = False):
+def _softmax_ce(logits: jax.Array, targets: jax.Array, masked: bool = False,
+                weights: jax.Array | None = None):
     """Summed CE (f32) of a chunk's rows from its float32 ``logits``
     [c, V], with the two pieces the gradient is made of: the exponentials
     ``e`` [c, V] (of the logits less the row's largest) and their row sums
     ``s`` [c, 1].  ``softmax = e / s``.  ``masked``: a row whose target is
-    negative has none and adds nothing to the sum."""
+    negative has none and adds nothing to the sum.  ``weights`` [c]: a
+    row's CE times its weight."""
     top = logits.max(axis=-1, keepdims=True)
     e = jnp.exp(logits - top)
     s = e.sum(axis=-1, keepdims=True)
@@ -1840,14 +2018,18 @@ def _softmax_ce(logits: jax.Array, targets: jax.Array, masked: bool = False):
         label = jnp.take_along_axis(
             logits, jnp.maximum(targets, 0)[:, None], axis=-1)
         ces = jnp.where(targets[:, None] >= 0, jnp.log(s) + top - label, 0.0)
-        return ces.sum(), e, s
-    label = jnp.take_along_axis(logits, targets[:, None], axis=-1)
-    return (jnp.log(s) + top - label).sum(), e, s
+    else:
+        label = jnp.take_along_axis(logits, targets[:, None], axis=-1)
+        ces = jnp.log(s) + top - label
+    if weights is not None:
+        ces = ces * weights[:, None]
+    return ces.sum(), e, s
 
 
 def _over_chunks(step, carry, flat_x, flat_t, chunk: int):
     """``step(carry, (rows, targets)) -> (carry, out)`` over ``flat_x``
-    [n, d] and ``flat_t`` [n], ``chunk`` rows at a time: a ``lax.scan``
+    [n, d] and ``flat_t`` [n] (or a tuple of such arrays: the targets and
+    the rows' weights), ``chunk`` rows at a time: a ``lax.scan``
     over the divisible prefix (one call where that is a single chunk),
     then one more call for a sub-chunk remainder, so that no call sees
     more than a chunk for EVERY n (an indivisible n must not silently
@@ -1862,24 +2044,27 @@ def _over_chunks(step, carry, flat_x, flat_t, chunk: int):
     if main > chunk:
         xs = (
             flat_x[:main].reshape(main // chunk, chunk, -1),
-            flat_t[:main].reshape(main // chunk, chunk),
+            jax.tree_util.tree_map(
+                lambda t: t[:main].reshape(main // chunk, chunk), flat_t),
         )
         carry, out = jax.lax.scan(step, carry, xs, reverse=True)
         outs.append(jax.tree_util.tree_map(
             lambda o: o.reshape(main, *o.shape[2:]), out
         ))
     elif main:
-        carry, out = step(carry, (flat_x[:main], flat_t[:main]))
+        carry, out = step(carry, (flat_x[:main], jax.tree_util.tree_map(
+            lambda t: t[:main], flat_t)))
         outs.append(out)
     if n > main:
-        carry, out = step(carry, (flat_x[main:], flat_t[main:]))
+        carry, out = step(carry, (flat_x[main:], jax.tree_util.tree_map(
+            lambda t: t[main:], flat_t)))
         outs.append(out)
     return carry, outs
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def _ce_of_chunks(flat_x, head, flat_t, chunk: int, denominator: int,
-                  masked: bool = False):
+                  masked: bool = False, flat_w=None):
     """Sum (f32) over ``denominator`` of the CEs of the rows ``flat_x``
     [n, d] against ``head`` [d, V] and the targets ``flat_t`` [n]: one
     head product and one softmax a chunk, no [n, V] array at any time.
@@ -1890,19 +2075,37 @@ def _ce_of_chunks(flat_x, head, flat_t, chunk: int, denominator: int,
     and the head is multiplied by three times a step (logits, and the
     two gradient products) where recomputing each chunk's logits in the
     backward made it four.  ``masked``: rows whose target is negative have
-    none; they add nothing to the sum and get no gradient."""
+    none; they add nothing to the sum and get no gradient.  ``flat_w`` [n]
+    float32 (None = no weights, the code as it was): a row's CE and its
+    gradient times its weight; the weights themselves get no gradient."""
 
-    def step(ce_sum, rows_targets):
-        rows, targets = rows_targets
+    def step(ce_sum, rows_labels):
+        rows, labels = rows_labels
         ce, _, _ = _softmax_ce(
-            DMoETransformerLM._logits(rows, head), targets, masked)
+            DMoETransformerLM._logits(rows, head), *_labels(labels, masked))
         return ce_sum + ce, None
 
-    ce_sum, _ = _over_chunks(step, jnp.float32(0), flat_x, flat_t, chunk)
+    ce_sum, _ = _over_chunks(
+        step, jnp.float32(0), flat_x, _with_weights(flat_t, flat_w), chunk)
     return ce_sum / denominator
 
 
-def _ce_of_chunks_fwd(flat_x, head, flat_t, chunk, denominator, masked=False):
+def _with_weights(flat_t, flat_w):
+    """What :func:`_over_chunks` cuts into chunks beside the rows: the
+    targets, or the targets and the weights."""
+    return flat_t if flat_w is None else (flat_t, flat_w)
+
+
+def _labels(labels, masked: bool) -> tuple:
+    """A chunk of :func:`_with_weights` as ``_softmax_ce``'s arguments
+    after the logits: ``(targets, masked, weights or None)``."""
+    if isinstance(labels, tuple):
+        return labels[0], masked, labels[1]
+    return labels, masked, None
+
+
+def _ce_of_chunks_fwd(flat_x, head, flat_t, chunk, denominator, masked=False,
+                      flat_w=None):
     """The value, and as residuals its gradients with respect to
     ``flat_x`` (a chunk's rows from each step, stacked by the scan) and
     ``head`` (a carry of the scan, accumulated in the head's dtype as the
@@ -1913,9 +2116,10 @@ def _ce_of_chunks_fwd(flat_x, head, flat_t, chunk, denominator, masked=False):
     afterwards."""
     scale = jnp.float32(1) / denominator
 
-    def step(carry, rows_targets):
+    def step(carry, rows_labels):
         ce_sum, d_head = carry
-        rows, targets = rows_targets
+        rows, labels = rows_labels
+        targets, _, weights = _labels(labels, masked)
         # the chunk's rows as an array of their own, as the barrier of
         # jax.checkpoint held them: sliced out of the stack inside each
         # product's fusion instead, they are not prefetched, and the head's
@@ -1924,11 +2128,13 @@ def _ce_of_chunks_fwd(flat_x, head, flat_t, chunk, denominator, masked=False):
         logits, gradient_products = jax.vjp(
             DMoETransformerLM._logits, rows, head
         )
-        ce, e, s = _softmax_ce(logits, targets, masked)
+        ce, e, s = _softmax_ce(logits, targets, masked, weights)
         # a row's weight in the mean: 0 where it has no target
         weight = (
             jnp.where(targets[:, None] >= 0, scale, 0.0) if masked else scale
         )
+        if weights is not None:
+            weight = weight * weights[:, None]
         softmax = e * (weight / s)
         hit = jnp.arange(logits.shape[-1]) == targets[:, None]
         d_rows, d_head_chunk = gradient_products(
@@ -1937,10 +2143,12 @@ def _ce_of_chunks_fwd(flat_x, head, flat_t, chunk, denominator, masked=False):
         return (ce_sum + ce, d_head + d_head_chunk), d_rows
 
     (ce_sum, d_head), d_rows = _over_chunks(
-        step, (jnp.float32(0), jnp.zeros_like(head)), flat_x, flat_t, chunk
+        step, (jnp.float32(0), jnp.zeros_like(head)), flat_x,
+        _with_weights(flat_t, flat_w), chunk
     )
     d_x = d_rows[0] if len(d_rows) == 1 else jnp.concatenate(d_rows)
-    return ce_sum / denominator, (d_x, d_head)
+    return ce_sum / denominator, (
+        d_x, d_head, None if flat_w is None else jnp.zeros_like(flat_w))
 
 
 def _ce_of_chunks_bwd(chunk, denominator, masked, gradients, cotangent):
@@ -1948,9 +2156,11 @@ def _ce_of_chunks_bwd(chunk, denominator, masked, gradients, cotangent):
     (a train step's is 1, the mean's divisor being on them already: no
     bit changes); the integer targets have no gradient."""
     d_x, d_head = (
-        (g.astype(jnp.float32) * cotangent).astype(g.dtype) for g in gradients
+        (g.astype(jnp.float32) * cotangent).astype(g.dtype)
+        for g in gradients[:2]
     )
-    return d_x, d_head, None
+    # neither the integer targets nor the weights move with the loss
+    return d_x, d_head, None, gradients[2]
 
 
 _ce_of_chunks.defvjp(_ce_of_chunks_fwd, _ce_of_chunks_bwd)

@@ -8,6 +8,8 @@ live HERE once so they cannot drift.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -496,6 +498,104 @@ _FLASH_WINDOW_TILE = 512
 FLASH_RESIDUALS = "flash_attention_residuals"
 
 
+def block_diffusion_mask(q_ids, kv_ids, half: int, block: int):
+    """Block diffusion's attention mask over a doubled row ``[x_t | x_0]``
+    of ``2 * half`` positions, the noised copy then the clean copy of the
+    same ``half`` tokens (BD3-LMs' vectorised training, arXiv:2503.09573):
+    whether query ``q_ids`` sees key ``kv_ids`` (integer arrays that
+    broadcast; numpy, jax or the kernel's own).  With ``blk(i) = (i mod
+    half) // block``: a noised query sees its own noised block, both
+    directions, and the clean blocks BEFORE its own; a clean query sees
+    the clean blocks up to and including its own; no query sees a noised
+    key outside its own block, and a clean query sees no noised key at
+    all.  Operators alone, so one function serves the three callers;
+    ``block`` divides ``half``."""
+    shift = block.bit_length() - 1
+    blocks = half // block  # a block's index over the doubled row: noised
+    # 0 .. blocks - 1, clean blocks .. 2 blocks - 1.  A shift where the block
+    # length allows: the kernel evaluates this a tile at a time on its
+    # vector unit, ten integer operations an element as written here
+    if block == 1 << shift:
+        q_blk, k_blk = q_ids >> shift, kv_ids >> shift
+    else:
+        q_blk, k_blk = q_ids // block, kv_ids // block
+    # the last clean block a query sees: its own (a clean query's index is
+    # its block's), or the one before its own (a noised query's, offset)
+    last = q_blk + (blocks - 1) * (q_blk < blocks)
+    return (q_blk == k_blk) | ((k_blk >= blocks) & (k_blk <= last))
+
+
+def block_diffusion_admitted_pairs(half: int, block: int) -> int:
+    """Pairs (query, key) :func:`block_diffusion_mask` admits, a head: a
+    query of block ``b``, noised or clean, sees ``block * (b + 1)`` keys."""
+    blocks = half // block
+    return block * block * blocks * (blocks + 1)
+
+
+@functools.cache
+def _block_diffusion_mask_class():
+    """:func:`block_diffusion_mask` as a computable mask of the blocked
+    kernel's library, which is imported by the calls that run the kernel
+    alone: the class is made on first use."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_mask as mask_lib,
+    )
+
+    class BlockDiffusionMask(mask_lib._ComputableMask):
+        def __init__(self, shape: tuple, block: int):
+            self.block = block
+            half = shape[0] // 2
+            super().__init__(
+                shape=shape,
+                mask_function=lambda q_ids, kv_ids: block_diffusion_mask(
+                    q_ids, kv_ids, half, block),
+            )
+
+        def __eq__(self, other):
+            if not isinstance(other, type(self)):
+                return NotImplemented
+            return self.shape == other.shape and self.block == other.block
+
+        def __hash__(self):
+            return hash((type(self), self.shape, self.block))
+
+    return BlockDiffusionMask
+
+
+def _block_diffusion_splash_mask(s: int, block: int):
+    """The kernel's mask over a doubled row of ``s`` positions: evaluated
+    in the kernel a tile at a time, and a block at a time on the host for
+    the table of blocks it visits (a block the mask leaves empty is never
+    visited)."""
+    return _block_diffusion_mask_class()((s, s), block)
+
+
+@functools.cache  # the table is built on the host: once a shape and tiles
+def block_diffusion_visited_pairs(
+    shape: tuple, impl: str, backend: str, block: int
+) -> int:
+    """Pairs a head that the core's FORWARD computes for q/k/v of ``shape``
+    [B, 2 * half, H, hd] under :func:`block_diffusion_mask`: the kernel's
+    table of visited blocks (a block visited is a block computed whatever
+    its mask admits) times a block's area; every pair where the ``xla``
+    core runs.  Static: a function of the shape and the tiles."""
+    _, s, _, _ = shape
+    sizes = flash_block_sizes(shape, backend) if impl == "flash" else None
+    if sizes is None:
+        return s * s
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_mask as mask_lib,
+        splash_attention_mask_info as mask_info_lib,
+    )
+
+    info, _ = mask_info_lib.process_mask(
+        mask_lib.MultiHeadMask([_block_diffusion_splash_mask(s, block)]),
+        (sizes.block_q, sizes.block_kv),
+    )
+    visited = int(np.count_nonzero(np.asarray(info.block_mask)))
+    return visited * sizes.block_q * sizes.block_kv
+
+
 def _dividing_tiles(s: int, at_least: int, at_most: int) -> list:
     """The multiples of the 128 lanes from ``at_least`` to ``at_most``
     that divide ``s``, ascending."""
@@ -540,7 +640,7 @@ def flash_block_sizes(shape: tuple, backend: str, window: int | None = None):
 
 def attention_core(
     q: jax.Array, k: jax.Array, v: jax.Array, impl: str = "xla",
-    window: int | None = None,
+    window: int | None = None, diffusion_block: int | None = None,
 ) -> jax.Array:
     """The causal attention math on pre-projected q [B,S,H,hd] and k, v
     [B,S,Hkv,hd] — shared by :func:`causal_attention` and the KV-cache
@@ -551,9 +651,18 @@ def attention_core(
     has none.  Both take fewer key/value heads than query heads as they
     come (query head h reads key/value head ``h // (H / Hkv)``; no copy
     of K or V is made), and a ``window``: query i sees the ``window``
-    keys that end with its own, ``i - window < j <= i``."""
+    keys that end with its own, ``i - window < j <= i``.  With
+    ``diffusion_block`` the mask is neither: the row is a doubled one,
+    ``[x_t | x_0]``, under :func:`block_diffusion_mask` with blocks of that
+    many tokens (no window then) -- the kernel takes it as a computable
+    mask, the ``xla`` core as a boolean array."""
     if impl not in ("xla", "flash"):
         raise ValueError(f"impl must be 'xla' or 'flash', got {impl!r}")
+    if diffusion_block is not None and (window is not None or q.shape[1] % 2):
+        raise ValueError(
+            "diffusion_block masks a doubled row (an even length) and takes "
+            f"no window, got length {q.shape[1]} and window={window}"
+        )
     sizes = (
         flash_block_sizes(q.shape, jax.default_backend(), window)
         if impl == "flash" else None
@@ -562,10 +671,12 @@ def attention_core(
         from jax.experimental.pallas.ops.tpu import splash_attention as splash
 
         _, s, h, hd = q.shape
-        mask = (
-            splash.CausalMask((s, s)) if window is None
-            else splash.LocalMask((s, s), (window - 1, 0), offset=0)
-        )
+        if diffusion_block is not None:
+            mask = _block_diffusion_splash_mask(s, diffusion_block)
+        elif window is None:
+            mask = splash.CausalMask((s, s))
+        else:
+            mask = splash.LocalMask((s, s), (window - 1, 0), offset=0)
         kernel = splash.make_splash_mha_single_device(
             mask=splash.MultiHeadMask([mask] * h), block_sizes=sizes,
             residual_checkpoint_name=FLASH_RESIDUALS,
@@ -588,6 +699,13 @@ def attention_core(
             out = jax.vmap(kernel)(q, k, v)
             with jax.named_scope("layout"):
                 return heads_first(out)
+    if diffusion_block is not None:
+        ids = jnp.arange(q.shape[1], dtype=jnp.int32)
+        return jax.nn.dot_product_attention(
+            q, k, v, mask=block_diffusion_mask(
+                ids[:, None], ids[None, :], q.shape[1] // 2, diffusion_block
+            )[None, None],
+        )
     if window is not None:
         return jax.nn.dot_product_attention(
             q, k, v, is_causal=True, local_window_size=(window - 1, 0)

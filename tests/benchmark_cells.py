@@ -93,6 +93,26 @@ def _qwen3next_lines(setup, counters, reference):
         assert 0.0 <= reference[name] < 1e-4, (name, reference[name])
 
 
+def _sdar_lines(setup, counters, reference):
+    assert setup["expert_param_bytes"] > 0 and setup["step_programs"] >= 2
+    # the set-up's placement: each layer's rows here over level, before | after
+    assert all(abs(after - 1.0) <= abs(before - 1.0) + 1e-9 and 0.7 < after < 1.3
+               for before, after, _, _ in setup[LEVELLING]), setup[LEVELLING]
+    assert {"dropped_fraction", "held_experts_empty", "masked_share",
+            "loss_weight_mean", "attention_admitted_pairs",
+            "attention_visited_pairs"} <= set(counters)
+    # 8 blocks of 4 a row, static: L'^2 nb (nb + 1); the xla core visits all
+    assert counters["attention_admitted_pairs"]["min"] == 16 * 8 * 9
+    assert counters["attention_visited_pairs"]["max"] == 64 * 64
+    assert reference["noise_mismatches"] == 0.0
+    assert len(reference["attention_layers_rms"]) == 2
+    assert len(reference["grad_stream_layers_rms"]) == 3
+    for name in ("grads_rms", "grad_stream_rms", "step_grad_norms",
+                 "step_gate_grad_norms", "update_norm", "update_over_rule",
+                 "update_total"):
+        assert 0.0 <= reference[name] < 1e-4, (name, reference[name])
+
+
 class Row(NamedTuple):
     cell: str
     config: str
@@ -105,6 +125,7 @@ class Row(NamedTuple):
     traced: tuple = ("expert_load_max_over_mean",)  # its traced line must hold
     levelled: int | None = None  # mixture layers SETUP says were levelled
     lines: Callable | None = None  # what else SETUP, COUNTERS, REFERENCE hold
+    levelling_phase: str = "level_router_bias"  # the set-up phase that levels
 
 
 _LEVELLED = ("local_rows_over_level", "expert_load_max_over_mean", "step_ms_p50")
@@ -139,6 +160,14 @@ ROWS = (
          "delta_gate_norm_share", "attention_gate_share", "shared_expert_share"),
         ("step_ms_p50", "expert_load_max_over_mean", "local_rows_over_level"),
         0, _qwen3next_lines),  # a share with no selection bias: nothing to level
+    Row("sdar-30b-a3b-train-zipf8k", "sdar-30b-a3b", "train-zipf8k", 1,
+        "manifest_sdar.json", 5700000007, 17,
+        ("mfu", "attention_core_roofline", "expert_matmul_roofline",
+         "noise_share", "attention_visited_over_admitted", "masked_share"),
+        ("step_ms_p50", "expert_load_max_over_mean", "local_rows_over_level",
+         "attention_visited_over_admitted", "masked_share"),
+        2, _sdar_lines,  # no selection bias: the runner's own set-up phase
+        "remake_gates_and_place_experts"),
 )
 
 
@@ -201,7 +230,7 @@ def rehearse(row: Row, tmp_path) -> None:
             if l.startswith(("SETUP ", "COUNTERS ", "REFERENCE "))}
     assert len(said["SETUP"][LEVELLING]) == row.levelled
     if row.levelled:
-        assert "level_router_bias" in said["SETUP"]["phases"]
+        assert row.levelling_phase in said["SETUP"]["phases"]
     if row.lines:
         row.lines(said["SETUP"], said["COUNTERS"], said["REFERENCE"])
 

@@ -7,6 +7,7 @@
     python tools/attention_probe.py accuracy   # blocked against xla, results
     python tools/attention_probe.py window     # the blocked kernel's tiles under a window
     python tools/attention_probe.py latent     # the blocked kernel's tiles at heads of 256
+    python tools/attention_probe.py blockdiff  # the blocked kernel's tiles under block diffusion's mask
 
 One process a subcommand (a chip belongs to one process), one ``ROW``
 line of JSON a reading, written to ``chiprun_out/attention_probe.jsonl``
@@ -50,6 +51,16 @@ threshold were set from.
   kernel under ``flash_block_sizes``'s answer (``latent rule``).  PERF.md
   section 6 ("PR 37") holds the table the rule's regime for heads of 256
   was set from.
+- ``blockdiff``: ``splash_attention`` under block diffusion's mask
+  (``trunk.block_diffusion_mask``, the computable form ``attention_core``
+  builds, blocks of 4) at the SDAR cell's doubled row, ``[1, 32 over 4,
+  16384, 128]`` bf16, forward and forward + backward: the causal rule's
+  blocks and their neighbours (narrower blocks visit fewer pairs the mask
+  empties, wider ones fewer grid steps), fused backward and not, each row
+  with the pairs its forward visits over the 67,141,632 the mask admits;
+  the same blocks under the causal mask for scale; then the kernel under
+  ``flash_block_sizes``'s answer (``blockdiff rule``).  PERF.md section 6
+  ("PR 57") holds the table.
 - ``accuracy``: ``attention_core`` ``flash`` against ``xla`` at the
   cell's shape, output and the three input gradients: rms of the
   difference over rms of the ``xla`` result (limit 1 %), beside what bf16
@@ -192,10 +203,12 @@ def tiles() -> None:
               dq(bqm, bkm, bk), *args)
 
 
-def _splash(forward, backward, fused, heads, s, window=None, dq=None):
+def _splash(forward, backward, fused, heads, s, window=None, dq=None,
+            mask=None):
     """``forward``, ``backward``: (block_q, block_kv, block_kv_compute);
     ``dq``: the unfused backward's dQ kernel's (block_q, block_kv), the
-    backward's blocks where none is given."""
+    backward's blocks where none is given; ``mask``: a head's mask where it
+    is neither causal nor a window."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as sk,
         splash_attention_mask as sm,
@@ -209,8 +222,9 @@ def _splash(forward, backward, fused, heads, s, window=None, dq=None):
         block_q_dq=bq_dq, block_kv_dq=bkv_dq,
         use_fused_bwd_kernel=fused,
     )
-    one = (sm.CausalMask((s, s)) if window is None
-           else sm.LocalMask((s, s), (window - 1, 0), offset=0))
+    one = mask if mask is not None else (
+        sm.CausalMask((s, s)) if window is None
+        else sm.LocalMask((s, s), (window - 1, 0), offset=0))
     return sk.make_splash_mha_single_device(
         mask=sm.MultiHeadMask([one] * heads), block_sizes=sizes)
 
@@ -264,7 +278,7 @@ BASELINE = (1024, 1024, 512)  # trunk._FLASH_TILES: PR 28's sweep, causal
 
 
 def _window_reading(cell, window, forward, backward, fused, dq, args,
-                    forward_too=True, **also):
+                    forward_too=True, mask=None, **also):
     """Rows ``window_forward`` (where ``forward_too``) and
     ``window_forward_backward`` of one setting at ``cell``'s shape; their
     ms in that order, None where the kernel or Mosaic refused."""
@@ -277,7 +291,7 @@ def _window_reading(cell, window, forward, backward, fused, dq, args,
         backward=backward, dq=dq, fused_bwd=fused, **also)
     t0 = time.perf_counter()
     try:
-        kernel = _splash(forward, backward, fused, h, s, window, dq)
+        kernel = _splash(forward, backward, fused, h, s, window, dq, mask)
     except Exception as e:
         row(what="window_forward_backward", **settings,
             refused=f"{type(e).__name__}: {e}"[:300])
@@ -487,6 +501,64 @@ def accuracy() -> None:
         raise SystemExit("attention_probe: flash is over 1 % from xla")
 
 
+SDAR = (1, 32, 4, 16384, 128, None)  # sdar-30b-a3b-train-zipf8k: the doubled row
+SDAR_BLOCK = 4
+
+
+def blockdiff(which: str = "all") -> None:
+    """``which``: ``all``, ``sweep`` or ``rule``."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_mask as sm,
+        splash_attention_mask_info as mask_info,
+    )
+
+    from learning_at_home_tpu.models import trunk
+
+    require_tpu()
+    cell = SDAR
+    b, h, _, s, hd, _ = cell
+    args = _grouped_qkv(cell)
+    mask = trunk._block_diffusion_splash_mask(s, SDAR_BLOCK)
+    admitted = trunk.block_diffusion_admitted_pairs(s // 2, SDAR_BLOCK)
+
+    def read(forward, backward, fused, causal=False, **also):
+        info, _ = mask_info.process_mask(
+            sm.MultiHeadMask([sm.CausalMask((s, s)) if causal else mask]),
+            forward[:2])
+        visited = int((info.block_mask != 0).sum()) * forward[0] * forward[1]
+        return _window_reading(
+            cell, None, forward, backward, fused, None, args,
+            mask=None if causal else mask,
+            mask_name="causal" if causal else "block_diffusion",
+            forward_visited_over_admitted=round(visited / admitted, 4), **also)
+
+    if which in ("all", "sweep"):
+        read(BASELINE, BASELINE, True, causal=True, stage="causal")
+        grid = ((1024, 1024, 512), (1024, 1024, 1024), (512, 1024, 512),
+                (512, 512, 512), (1024, 512, 512), (2048, 1024, 512),
+                (1024, 2048, 512), (256, 512, 512), (512, 256, 256),
+                (256, 256, 256))
+        same = {(t, fused): read(t, t, fused, stage="same")
+                for t in grid for fused in (True, False)}
+        same = {t: r for t, r in same.items() if r}
+        best_fwd = min(same, key=lambda t: same[t][0])[0]
+        best_both = min(same, key=lambda t: same[t][1])
+        row(what="blockdiff_best", shape=list(cell[:5]), forward=best_fwd,
+            forward_ms=round(min(r[0] for r in same.values()), 3),
+            forward_backward=list(best_both),
+            forward_backward_ms=round(same[best_both][1], 3))
+        # the fused backward's own tiles under the best forward's
+        for t in grid[:6]:
+            if t != best_fwd:
+                read(best_fwd, t, True, forward_too=False, stage="backward")
+    if which in ("all", "rule"):
+        sizes = trunk.flash_block_sizes((b, s, h, hd), "tpu")
+        read((sizes.block_q, sizes.block_kv, sizes.block_kv_compute),
+             (sizes.block_q_dkv, sizes.block_kv_dkv, sizes.block_kv_dkv_compute),
+             sizes.use_fused_bwd_kernel, stage="rule")
+
+
 if __name__ == "__main__":
     {"tiles": tiles, "splash": splash, "cores": cores, "accuracy": accuracy,
-     "window": window, "latent": latent}[sys.argv[1]](*sys.argv[2:])
+     "window": window, "latent": latent, "blockdiff": blockdiff,
+     }[sys.argv[1]](*sys.argv[2:])

@@ -440,12 +440,17 @@ def float8(seeds: list, config_path: str = CONFIG, only: list = ()) -> None:
         words = harness.seed_words(seed, 4)
         params = model.init_params(jnp.asarray(words[:2], jnp.uint32))
         opt_state = model.init_opt_state(optimizer, params)
+        # a runner whose traffic keeps ids back (a mask id) says how many are drawn
+        vocab = getattr(runner, "data_vocab", lambda cfg: cfg.vocab_size)(cfg)
         batches = recipe.zipf_batches(
-            np.random.default_rng(words[2:]), cfg.vocab_size, rows, cfg.seq_len, 8)
+            np.random.default_rng(words[2:]), vocab, rows, cfg.seq_len, 8)
         pool = [tuple(jax.device_put(a, batch_sharding(mesh)) for a in pair)
                 for pair in batches]
         if cfg.router_bias:  # the runner's set-up call
             params, _ = model.level_router_bias(params, [ids for ids, _ in pool])
+        if hasattr(runner, "route_like_a_trained_model"):  # or its own
+            params, _ = runner.route_like_a_trained_model(
+                model, params, [ids for ids, _ in pool])
         steps = config.get("probe_steps", 48)
         for i in [0, 1] + [(2 + j) % 8 for j in range(steps - 3)] + [0]:
             params, opt_state, _, _ = step(params, opt_state, *pool[i])
