@@ -45,6 +45,7 @@ from learning_at_home_tpu.models.trunk import (
     rms_norm,
     ssm_mixer,
 )
+from learning_at_home_tpu.ops.delta_rule import DELTA_RESIDUALS
 from learning_at_home_tpu.ops.moe_dispatch import balanced_bias, level_bias
 from learning_at_home_tpu.ops.ssd import SSD_RESIDUALS
 from learning_at_home_tpu.parallel.mesh import batch_sharding
@@ -1074,20 +1075,26 @@ class DMoETransformerLM:
             # read, so the recompute holds no forward kernel call (a
             # layer's 68-273 MB against 3-32 ms: PERF.md section 6, PR
             # 38), and the scan kernel's output and entering states
-            # (ops/ssd.py; PR 40); and the results of the attention
-            # part's matrix products, ``x @ wq``, ``x @ wk``, ``x @ wv``
-            # and ``out @ wo`` (trunk.ATTENTION_PRODUCTS; PR 53), which
-            # the backward pass reads (the queries' and keys' norm, the
-            # kernel, the feed-forward part behind the add) and would
-            # otherwise multiply a second time: bf16 [B, S, q + 2 kv + d]
-            # a layer, 537 MB in k-exaone for 28 ms (PERF.md section 6,
-            # PR 53).  A layer on the xla core keeps its products too;
-            # everything else (norms, the rotation, the mixers' and the
-            # experts' products, the dense blocks) is recomputed
+            # (ops/ssd.py; PR 40), and the delta rule's kernel's output
+            # and the ONE state entering each of its grid steps, from
+            # which its backward kernel rebuilds the chunks' in VMEM
+            # (ops/delta_rule.py; a layer's 268-331 MB against a forward
+            # call of 8-10 ms: PERF.md section 6, PR 58); and the results
+            # of the attention part's matrix products, ``x @ wq``, ``x @
+            # wk``, ``x @ wv`` and ``out @ wo`` (trunk.ATTENTION_PRODUCTS;
+            # PR 53), which the backward pass reads (the queries' and
+            # keys' norm, the kernel, the feed-forward part behind the
+            # add) and would otherwise multiply a second time: bf16 [B, S,
+            # q + 2 kv + d] a layer, 537 MB in k-exaone for 28 ms (PERF.md
+            # section 6, PR 53).  A layer on the xla core keeps its
+            # products too; everything else (norms, the rotation, the
+            # mixers' and the experts' products, the dense blocks) is
+            # recomputed
             layer_fn = jax.checkpoint(
                 layer_fn, static_argnums=(4,),
                 policy=jax.checkpoint_policies.save_only_these_names(
-                    FLASH_RESIDUALS, SSD_RESIDUALS, ATTENTION_PRODUCTS
+                    FLASH_RESIDUALS, SSD_RESIDUALS, DELTA_RESIDUALS,
+                    ATTENTION_PRODUCTS,
                 ),
             )
 

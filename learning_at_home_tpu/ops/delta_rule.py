@@ -50,13 +50,19 @@ the call can see, as ``ops/ssd.py`` chooses its kernel):
   time, its chunks side by side on the diagonal of [128, 128]: ``Gamma``,
   ``K K^T``, ``A``, the inverse of ``I + A``, ``Q K^T``, ``W``, ``U`` and
   ``V'`` live and die in VMEM; ``q``, ``k``, ``v``, the sums, ``beta`` and
-  ``o`` cross HBM once, and the states entering the chunks once more as
-  the backward's residual (566 MB a layer at the cell's shape: written by
-  the forward under differentiation alone, so under remat by the
-  recompute, and live for one layer's backward; nothing carries a name
-  for a remat policy to keep).  The gradient through the solve is two
-  more products with the same inverse (``dR = T^T dX``, ``dA = -dR X^T``
-  under the diagonal).  It rounds where the plain form rounds.
+  ``o`` cross HBM once.  The backward's residual is the state entering
+  each GRID STEP (two frames, 256 positions: 142 MB a layer at
+  Olmo-Hybrid's shape, a quarter of a state a chunk), written by the
+  forward under differentiation alone; from it the backward kernel chains
+  the step's chunks FORWARD in VMEM, with the forward kernel's products
+  and roundings (the states it rebuilds are the forward's, to the bit),
+  before it walks them in reverse.  That residual and ``o`` carry the name
+  :data:`DELTA_RESIDUALS` for a remat policy to keep
+  (``models/transformer.py`` ``_hidden``): the recompute then holds no
+  forward kernel call (PERF.md section 6, PR 58).  The gradient through
+  the solve is two more products with the same inverse (``dR = T^T dX``,
+  ``dA = -dR X^T`` under the diagonal).  It rounds where the plain form
+  rounds.
 * :func:`gated_delta_plain`, everywhere else (the CPU, a ``decay_dtype`` or
   a ``solve`` that is being probed, a shape the tiles refuse): plain
   ``jax.numpy``, every intermediate an array of its own, the backward
@@ -98,6 +104,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -117,6 +124,11 @@ UNIT_EPS = 1e-6  # under the root of a query's or a key's length
 # the kernel's frame: the positions whose scores and solve one head takes
 # at once, whole chunks side by side on the diagonal of [FRAME, FRAME]
 FRAME = 128
+# the name of what the kernels' forward hands their backward besides its
+# inputs: the output and the state entering each grid step, for a remat
+# policy to keep (``save_only_these_names``), so that a recompute holds no
+# forward kernel call.  Outside a checkpoint the name is the identity
+DELTA_RESIDUALS = "delta_rule_residuals"
 
 
 def gated_delta_recurrent(
@@ -540,6 +552,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, rows_ref, o_ref, *rest, hg, c, keep, unit):
     def _():
         state[...] = jnp.zeros_like(state)
 
+    if keep:  # the state entering the grid step: the backward's residual
+        entering_ref[...] = state[...]
     cols = _columns(rows_ref[...])  # lane h: head h's sums; hg + h: its strengths
     dv = v_ref.shape[1] // hg
     compute = v_ref.dtype
@@ -557,8 +571,6 @@ def _fwd_kernel(q_ref, k_ref, v_ref, rows_ref, o_ref, *rest, hg, c, keep, unit):
             of = slice(n, n + c)
             for h, x in enumerate(made):
                 s = state[h]
-                if keep:
-                    entering_ref[(first + n) // c, h] = s
                 # what the entering state answers for the keys as the solve
                 # left them and for the queries: one product
                 both = _dot(jnp.concatenate(
@@ -580,7 +592,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, rows_ref, o_ref, *rest, hg, c, keep, unit):
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, rows_ref, entering_ref, do_ref, dfinal_ref,
-                dq_ref, dk_ref, dv_ref, drows_ref, dstate, *, hg, c, unit):
+                dq_ref, dk_ref, dv_ref, drows_ref, dstate, states, *, hg, c, unit):
     f32 = jnp.float32
 
     @pl.when(pl.program_id(1) == 0)  # the row's LAST step: they run in reverse
@@ -594,26 +606,45 @@ def _bwd_kernel(q_ref, k_ref, v_ref, rows_ref, entering_ref, do_ref, dfinal_ref,
     j = jax.lax.broadcasted_iota(jnp.int32, (FRAME, FRAME), 1)
     lane = jax.lax.broadcasted_iota(jnp.int32, (FRAME, _LANES), 1)
     ends = jax.lax.broadcasted_iota(jnp.int32, (c, 1), 0) == c - 1
-    dcols = []  # as ``cols``, a frame at a time, the last frame first
-    for first in reversed(range(0, q_ref.shape[1], FRAME)):
+    frames = range(0, q_ref.shape[1], FRAME)
+    # the step's chunks FORWARD first, from the ONE state the forward kept
+    # of the step: the products and roundings of ``_fwd_kernel``, so the
+    # state entering each chunk is the one the forward carried, to the bit
+    states[0] = entering_ref[...]
+    made = {}
+    for first in frames:
         at = slice(first, first + FRAME)
-        made = []
-        for h in range(hg):
+        made[first] = [dict(_frame(
+            q_ref[h, at, :], k_ref[h, at, :], v_ref[at, h * dv:(h + 1) * dv],
+            cols[at, h:h + 1], cols[at, hg + h:hg + h + 1], i, j, c, unit), news={})
+            for h in range(hg)]
+        for n in range(0, FRAME, c):
+            of = slice(n, n + c)
+            nth = (first + n) // c
+            for h, x in enumerate(made[first]):
+                s = states[nth, h]
+                new = (x["u"][of] - _dot(x["w_c"][of], s.astype(compute))
+                       ).astype(compute)  # V'
+                x["news"][n] = new
+                if nth + 1 < states.shape[0]:  # the step's last state: unread
+                    states[nth + 1, h] = jnp.exp(
+                        _last_row(x["gcol"][of], dv)) * s + _dot(
+                            x["k_to_end"][of], new, _TN)
+    dcols = []  # as ``cols``, a frame at a time, the last frame first
+    for first in reversed(frames):
+        at = slice(first, first + FRAME)
+        for h, x in enumerate(made[first]):
             do = do_ref[at, h * dv:(h + 1) * dv]
-            x = _frame(q_ref[h, at, :], k_ref[h, at, :],
-                       v_ref[at, h * dv:(h + 1) * dv], cols[at, h:h + 1],
-                       cols[at, hg + h:hg + h + 1], i, j, c, unit)
             # what the chunks' own writes hand back, before any state
             x.update(do=do, chain={},
                      dnew=_dot(x["scores"].astype(compute), do, _TN))
-            made.append(x)
         for n in reversed(range(0, FRAME, c)):  # the chunks in reverse
             of = slice(n, n + c)
-            for h, x in enumerate(made):
-                s = entering_ref[(first + n) // c, h]
+            for h, x in enumerate(made[first]):
+                s = states[(first + n) // c, h]
                 entering = s.astype(compute)
                 w_c = x["w_c"][of]
-                new = (x["u"][of] - _dot(w_c, entering)).astype(compute)
+                new = x["news"][n]
                 dleaving = dstate[h]
                 dleaving_c = dleaving.astype(compute)
                 dnew = x["dnew"][of] + _dot(x["k_to_end"][of], dleaving_c)
@@ -626,19 +657,21 @@ def _bwd_kernel(q_ref, k_ref, v_ref, rows_ref, entering_ref, do_ref, dfinal_ref,
                 dstate[h] = chunk_decay * dleaving + _dot(jnp.concatenate(
                     [x["q_from_start"][of], w_c], axis=0), upon, _TN)
                 x["chain"][n] = dict(
-                    new=new, dnew=dnew, dk_to_end=_dot(new, dleaving_c, _NT),
+                    dnew=dnew, dk_to_end=_dot(new, dleaving_c, _NT),
                     dq_from_start=both[:c], dw=both[c:],
                     # the chunk's last sum scales the leaving state whole
                     dlast=jnp.sum(
                         jnp.sum(dleaving * s, axis=0, keepdims=True) * chunk_decay,
                         axis=1, keepdims=True))
         dcol = jnp.zeros((FRAME, _LANES), f32)
-        for h, x in enumerate(made):
+        for h, x in enumerate(made[first]):
             q, k, bcol, decay = x["q"], x["k"], x["bcol"], x["decay"]
             chain = [x["chain"][n] for n in range(0, FRAME, c)]
-            new, dnew, dk_to_end, dq_from_start, dw = (
+            new = jnp.concatenate(
+                [x["news"][n] for n in range(0, FRAME, c)], axis=0)
+            dnew, dk_to_end, dq_from_start, dw = (
                 jnp.concatenate([of[name] for of in chain], axis=0)
-                for name in ("new", "dnew", "dk_to_end", "dq_from_start", "dw"))
+                for name in ("dnew", "dk_to_end", "dq_from_start", "dw"))
             dscores = _dot(x["do"], new, _NT)  # [F, F]
 
             # through the solve: two more with the same factor
@@ -697,8 +730,8 @@ def _layout(q, v, c: int, order) -> tuple:
     [B, S, H dv] and its block specs for grid row ``r`` (``hg`` heads of one
     batch row) and step ``n``, which holds positions ``order(n) t``
     onwards: of ``q``'s kind, of ``v``'s, of the heads' rows [B H / hg, 2
-    hg, S], of the entering states [B H / hg, S / c, hg, dk, dv] and of one
-    state a head."""
+    hg, S], of the states entering the steps [B H / hg, S / t, hg, dk, dv]
+    (a step's own: [hg, dk, dv]) and of one state a head."""
     rows, s, dk = q.shape
     h = rows // v.shape[0]
     dv = v.shape[2] // h
@@ -710,7 +743,7 @@ def _layout(q, v, c: int, order) -> tuple:
             lambda r, n: (r // (h // hg), order(n), r % (h // hg))),
         "rows": pl.BlockSpec((None, 2 * hg, t), lambda r, n: (r, 0, order(n))),
         "entering": pl.BlockSpec(
-            (None, t // c, hg, dk, dv), lambda r, n: (r, order(n), 0, 0, 0)),
+            (None, None, hg, dk, dv), lambda r, n: (r, order(n), 0, 0, 0)),
         "state": pl.BlockSpec((hg, dk, dv), lambda r, n: (r, 0, 0)),
     }
 
@@ -726,11 +759,12 @@ def _head_rows(gamma: jax.Array, beta: jax.Array, hg: int) -> jax.Array:
 def _forward(q, k, v, gamma, beta, c, unit, keep, interpret):
     """``(o [B, S, H dv], the state after the last position [B H, dk, dv]
     float32)`` and, between them where ``keep``, the state entering each
-    chunk [B H / hg, S / c, hg, dk, dv] float32: the backward's residual."""
+    grid step [B H / hg, S / t, hg, dk, dv] float32: the backward's
+    residual (``t`` the step's positions: :func:`_grid`)."""
     rows, s, dk = q.shape
     (hg, steps, dv), spec = _layout(q, v, c, lambda n: n)
     entering = [(spec["entering"], jax.ShapeDtypeStruct(
-        (rows // hg, s // c, hg, dk, dv), jnp.float32))] if keep else []
+        (rows // hg, steps, hg, dk, dv), jnp.float32))] if keep else []
     out_specs, out_shape = zip(
         (spec["v"], jax.ShapeDtypeStruct(v.shape, v.dtype)), *entering,
         (spec["state"], jax.ShapeDtypeStruct((rows, dk, dv), jnp.float32)))
@@ -747,7 +781,8 @@ def _forward(q, k, v, gamma, beta, c, unit, keep, interpret):
 def _backward(q, k, v, gamma, beta, entering, do, dfinal, c, unit, interpret):
     """The gradients of ``q``, ``k``, ``v``, ``gamma`` and ``beta``, the
     chunks in reverse, the state's cotangent carried as the forward
-    carries the state."""
+    carries the state; ``entering`` the state entering each grid step, from
+    which a step rebuilds its chunks' in a VMEM scratch."""
     rows, s, _ = q.shape
     (hg, steps, _), spec = _layout(q, v, c, lambda n: steps - 1 - n)
     dq, dk, dv, drows = pl.pallas_call(
@@ -760,7 +795,9 @@ def _backward(q, k, v, gamma, beta, entering, do, dfinal, c, unit, interpret):
                    jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype),
                    jax.ShapeDtypeStruct((rows // hg, 2 * hg, s), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((hg, *entering.shape[3:]), jnp.float32)],
+        scratch_shapes=[
+            pltpu.VMEM(entering.shape[2:], jnp.float32),  # the state's cotangent
+            pltpu.VMEM((s // steps // c, *entering.shape[2:]), jnp.float32)],
         compiler_params=_PARAMS, interpret=interpret, name="delta_chunk_bwd",
     )(q, k, v, _head_rows(gamma, beta, hg), entering, do, dfinal)
     dgamma, dbeta = drows.reshape(rows // hg, 2, hg, s).swapaxes(0, 1)
@@ -774,6 +811,8 @@ def _rule(q, k, v, gamma, beta, c, unit, interpret):
 
 def _rule_fwd(q, k, v, gamma, beta, c, unit, interpret):
     o, entering, final = _forward(q, k, v, gamma, beta, c, unit, True, interpret)
+    o = checkpoint_name(o, DELTA_RESIDUALS)
+    entering = checkpoint_name(entering, DELTA_RESIDUALS)
     return (o, final), (q, k, v, gamma, beta, entering)
 
 

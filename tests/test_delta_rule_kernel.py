@@ -5,7 +5,9 @@ replaces on a TPU and against the rule a position at a time: under
 frames of 128, keys that are no lane multiple, values of half a lane tile).  What Mosaic
 makes of it at the cell's shape is ``tests/test_olmo_hybrid.py``'s (an AOT
 compile for a described chip) and the chip's
-(``tools/smallthinker_probe.py delta``)."""
+(``tools/smallthinker_probe.py delta``).  What the forward keeps for the
+backward, and what a checkpoint makes of it, is
+``tests/test_delta_rule_kernel_residuals.py``'s."""
 
 import functools
 
@@ -20,9 +22,12 @@ NAMES = ("q", "k", "v", "g", "beta")
 # (batch rows, positions, chunk, heads, a head's values): two chunks in one
 # frame, two heads abreast (the cell's), two batch rows; six over three grid
 # steps (the state crosses them), a head alone; four of 32 in a frame (a
-# chunk that is a frame is the chip's: tools/smallthinker_probe.py delta)
+# chunk that is a frame is the chip's: tools/smallthinker_probe.py delta);
+# eight over two grid steps of two frames, as the cells' steps are (the
+# backward rebuilds three entering states a step from the one kept; two
+# heads abreast in such a step: the case of the rebuilt states below)
 SHAPES = {"2-chunks": (2, 128, 64, 2, 64), "6-chunks": (1, 384, 64, 1, 128),
-          "4-chunks": (1, 128, 32, 1, 128)}
+          "4-chunks": (1, 128, 32, 1, 128), "8-chunks": (1, 512, 64, 1, 128)}
 DK = 24  # no multiple of the 128 lanes
 
 
@@ -90,7 +95,8 @@ CASES = pytest.mark.parametrize("shape, dtype", [
     pytest.param(shape, dtype, id=f"{shape}-{jnp.dtype(dtype).name}")
     for shape, dtype in [
         ("2-chunks", jnp.float32), ("2-chunks", jnp.bfloat16),
-        ("6-chunks", jnp.bfloat16), ("4-chunks", jnp.bfloat16)]])
+        ("6-chunks", jnp.bfloat16), ("4-chunks", jnp.bfloat16),
+        ("8-chunks", jnp.bfloat16)]])
 
 
 @CASES

@@ -49,23 +49,30 @@ def test_the_whole_step_fits_the_chip_and_runs_the_rule_as_kernels(
         v5e_chip, monkeypatch):
     """The eight-layer train step at published widths, compiled for a
     described chip (nothing runs): 1,857,720,552 parameters; the
-    compiler's own count of what is live in the step no more than 1 GB
-    above the 12.02 GB the plain rule in checkpointed segments read (11.41
-    GB, 67.5 %, when this was written: PR 46); a delta layer's forward
-    kernel twice (the step's, and remat's, which writes the entering
-    states the backward kernel reads: nothing of the rule is kept across
-    the backward pass) and its backward kernel once, every call under
-    ``delta/core``, and no loop over chunks or segments left there."""
+    compiler's own count of what is live in the step 14.32 GB, 84.7 % of
+    the chip, when this was written (PR 58; 12.18 GB before it): it now
+    holds, across the backward pass, each delta layer's output (bf16 [16384,
+    5760], 189 MB) and the float32 state entering each of the kernel's 64
+    grid steps of 256 positions (142 MB; the parent's state a CHUNK, 566
+    MB, lived for one layer's backward), 1.98 GB over the six layers, named
+    ``delta_rule.DELTA_RESIDUALS`` for the layer's checkpoint; so a delta
+    layer's forward kernel ONCE (the recompute holds no call: the backward
+    kernel rebuilds the chunks' entering states in VMEM from the step's)
+    and its backward kernel once, every call under ``delta/core``, and no
+    loop over chunks or segments left there."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     memory = probe.step_memory(v5e_chip, "olmo_hybrid_7b_one_chip")
     assert memory["parameters"] == 1_857_720_552
-    assert 0.25 < memory["share_of_chip"] and memory["live_bytes"] < 13.02e9, memory
+    assert 0.25 < memory["share_of_chip"] and memory["live_bytes"] < 14.4e9, memory
     assert memory["delta_kernel_calls"] == {
-        "delta_chunk_fwd": {"calls": 2 * 6, "under_delta_core": 2 * 6},
+        "delta_chunk_fwd": {"calls": 6, "under_delta_core": 6},
         "delta_chunk_bwd": {"calls": 6, "under_delta_core": 6}}
+    assert memory["kept_delta_bytes"] == 6 * (
+        16384 * 5760 * 2 + 64 * 30 * 96 * 192 * 4)
     assert memory["loops_under_delta_core"] == 0
     # the norm and the gate as one pass: forward, recomputed (remat keeps
-    # nothing of it) and backward a delta layer, every call under
+    # nothing of it: its own recompute stays, reading the kept ``o``) and
+    # backward a delta layer, every call under
     # ``delta/gate_norm``, and no float32 ``[1, 16384, 5760]`` written there
     # (PR 47; the parent's live count read 11,423,113,216)
     assert memory["gate_norm_kernel_calls"] == {

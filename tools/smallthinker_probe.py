@@ -8,7 +8,7 @@
     chiprun -- python tools/smallthinker_probe.py ssd [seq_len] [accuracy_len]
     chiprun -- python tools/smallthinker_probe.py conv [rows x channels ...]
     chiprun -- python tools/smallthinker_probe.py gate_norm [rows x strip ...]
-    chiprun -- python tools/smallthinker_probe.py delta [seq_len] [accuracy_len] [form ...]
+    chiprun -- python tools/smallthinker_probe.py delta [seq_len] [accuracy_len] [calls] [form ...] [config ...]
     chiprun -- python tools/smallthinker_probe.py delta split [seq_len] [chunk]
 
 ``memory`` (here, no chip): the whole train step of a recipe of
@@ -85,11 +85,16 @@ those row blocks and strips too (PERF.md section 6, PR 47).
 
 ``delta`` (on the chip): the chunked gated delta rule of
 ``ops/delta_rule.py`` at the Olmo-Hybrid cell's shape (``[1, seq_len, 30,
-96 / 192]`` bf16; ``seq_len`` 16,384 by default) as its kernels
-(``kernel``: ``delta_chunk_fwd`` / ``delta_chunk_bwd``, PR 46) and in plain
-form for each way of inverting ``I + A`` (``blocks``, ``product``,
-``triangular``; or the forms named), at chunks of 32, 64 and 128: milliseconds a call forward
-and forward + backward, and at ``accuracy_len`` the relative rms of the
+96 / 192]`` bf16; ``seq_len`` 16,384 by default), or at that of each
+configuration of ``benchmarks/configs/`` named (``qwen3-next-80b-a3b``:
+``[1, seq_len, 32, 128 / 128]``, the rule over the value heads), as its
+kernels (``kernel``: ``delta_chunk_fwd`` / ``delta_chunk_bwd``, PR 46) and
+in plain form for each way of inverting ``I + A`` (``blocks``, ``product``,
+``triangular``; or the forms named), at chunks of 32, 64 and 128:
+milliseconds a call forward, forward + backward and the backward call
+alone (a ``jax.vjp``'s pullback under given cotangents: what a layer's
+backward pass runs once remat keeps the forward's results, PR 58), and at
+``accuracy_len`` the relative rms of the
 output, the last state and the five gradients against the rule a position
 at a time in float32 (PERF.md section 6, PR 45 and PR 46).  ``delta split
 [seq_len] [chunk]``: where the PLAIN rule's time goes (the whole rule, the
@@ -157,7 +162,9 @@ def kept_residual_bytes(jaxpr, name: str | None = None) -> int:
     ``trunk.FLASH_RESIDUALS`` (the default: the blocked kernel's output
     and row sums, once a kernel layer, in the forward),
     ``ssd.SSD_RESIDUALS`` (the scan kernel's output and the states
-    entering its chunks, once a state-space layer) or
+    entering its chunks, once a state-space layer),
+    ``delta_rule.DELTA_RESIDUALS`` (the delta rule's kernel's output and
+    the state entering each of its grid steps, once a delta layer; PR 58) or
     ``trunk.ATTENTION_PRODUCTS`` (the results of the attention part's
     matrix products, once an attention layer; PR 53).  What they add to the
     compiled step's live bytes is at most this: the compiler reuses."""
@@ -314,7 +321,9 @@ def step_memory(chip, recipe: str = "smallthinker_one_chip") -> dict:
     PR 50), under ``scan_kernel_calls`` and
     ``kept_scan_bytes`` the same two for the state-space scan's kernels
     (:func:`scan_kernel_calls`; PR 40), under ``delta_kernel_calls`` the
-    delta rule's kernels under ``delta/core`` and under
+    delta rule's kernels under ``delta/core`` (one forward a delta layer
+    since PR 58: remat keeps the kernel's output and a state a grid step,
+    ``kept_delta_bytes``) and under
     ``loops_under_delta_core`` the ``while`` instructions that scope still
     holds (none where the kernel runs: PR 46), under ``conv_kernel_calls`` the
     convolution's kernels under ``ssm/conv`` and under
@@ -330,6 +339,7 @@ def step_memory(chip, recipe: str = "smallthinker_one_chip") -> dict:
 
     import __graft_entry__
     from learning_at_home_tpu.models.trunk import ATTENTION_PRODUCTS
+    from learning_at_home_tpu.ops.delta_rule import DELTA_RESIDUALS
     from learning_at_home_tpu.ops.ssd import SSD_RESIDUALS
     from learning_at_home_tpu.parallel.mesh import (
         batch_sharding,
@@ -397,6 +407,7 @@ def step_memory(chip, recipe: str = "smallthinker_one_chip") -> dict:
                 r'(?:ssm/gate_norm|delta/gate_norm|ssm/scan)[/)]', text, re.M)
             if np.prod([int(n) for n in dims.split(",")]) >= 1024}),
         "delta_kernel_calls": scan_kernel_calls(text, "delta_chunk", "delta/core"),
+        "kept_delta_bytes": kept_residual_bytes(traced.jaxpr.jaxpr, DELTA_RESIDUALS),
         "loops_under_delta_core": len(re.findall(
             r'^\s*%\S+ = [^\n]* while\([^\n]*op_name="[^"\n]*delta/core', text, re.M)),
         "loss_layer_products": len(re.findall(
@@ -688,19 +699,27 @@ def gate_norm(blocks: list, calls: int = 20) -> None:
             }), flush=True)
 
 
+def _delta_shape(config: str) -> tuple:
+    """``(heads, a head's keys, a head's values)`` of the rule as a
+    configuration's mixer calls it: over the VALUE heads (the key heads are
+    repeated a value head each before the rule, ``trunk.delta_mixer``)."""
+    import harness
+
+    cell = harness.load_json(os.path.join(REPO, "benchmarks/configs", config + ".json"))
+    return (cell["linear_num_value_heads"], cell["linear_key_head_dim"],
+            cell["linear_value_head_dim"])
+
+
 def delta(seq_len: int = 16384, accuracy_len: int = 1024, calls: int = 5,
-          forms=("kernel", "blocks", "product", "triangular")) -> None:
+          forms=("kernel", "blocks", "product", "triangular"),
+          config: str = "olmo-hybrid-7b") -> None:
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    import harness
     from learning_at_home_tpu.ops import delta_rule as ops
 
-    config = harness.load_json(os.path.join(
-        REPO, "benchmarks/configs/olmo-hybrid-7b.json"))
-    h, dk, dv = (config["linear_num_key_heads"], config["linear_key_head_dim"],
-                 config["linear_value_head_dim"])
+    h, dk, dv = _delta_shape(config)
     f32 = jnp.float32
 
     def inputs(s, dtype):
@@ -737,6 +756,16 @@ def delta(seq_len: int = 16384, accuracy_len: int = 1024, calls: int = 5,
         return jax.jit(lambda *a: (
             *form(*a), *jax.grad(loss_of(form, s), argnums=(0, 1, 2, 3, 4))(*a)))
 
+    def backward_alone(form, s):
+        """The backward call by itself: the pullback of a ``jax.vjp`` taken
+        before the clock starts, under given cotangents (for the kernels:
+        ``delta_chunk_bwd`` and XLA's transposes and sums around it)."""
+        rs = np.random.default_rng(46)
+        cotangents = (jnp.asarray(rs.standard_normal((1, s, h, dv)), jnp.bfloat16),
+                      jnp.asarray(rs.standard_normal((1, h, dk, dv)), f32))
+        pullback = jax.jit(lambda *a: jax.vjp(form, *a)[1])(*inputs(s, jnp.bfloat16))
+        return _ms(jax.jit(lambda pull, ct: pull(ct)), (pullback, cotangents), calls)
+
     want = jax.device_get(everything(ops.gated_delta_recurrent, accuracy_len)(
         *inputs(accuracy_len, f32)))
     for solve in forms:
@@ -756,6 +785,7 @@ def delta(seq_len: int = 16384, accuracy_len: int = 1024, calls: int = 5,
                     "forward_ms": _ms(jax.jit(form), args, calls),
                     "forward_backward_ms": _ms(jax.jit(jax.grad(
                         loss_of(form, seq_len), argnums=(0, 1, 2, 3, 4))), args, calls),
+                    "backward_alone_ms": backward_alone(form, seq_len),
                     "rms_against_the_recurrence_in_float32": {
                         k: _rel_rms(a, b) for k, a, b in zip(names, got, want)},
                 })
@@ -850,8 +880,12 @@ if __name__ == "__main__":
         delta_split(*(int(a) for a in sys.argv[3:5]))
     elif sys.argv[1:2] == ["delta"]:
         named = [a for a in sys.argv[2:] if not a.isdigit()]
-        delta(*(int(a) for a in sys.argv[2:] if a.isdigit()),
-              **({"forms": named} if named else {}))
+        configs = [a for a in named if os.path.exists(os.path.join(
+            REPO, "benchmarks/configs", a + ".json"))]
+        forms = [a for a in named if a not in configs]
+        for config in configs or ["olmo-hybrid-7b"]:
+            delta(*(int(a) for a in sys.argv[2:] if a.isdigit()), config=config,
+                  **({"forms": forms} if forms else {}))
     elif sys.argv[1:2] == ["float8"]:
         named = [a for a in sys.argv[2:] if a.endswith(".json")]
         words = [a for a in sys.argv[2:] if a not in named and not a.isdigit()]
