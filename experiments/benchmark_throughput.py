@@ -35,9 +35,6 @@ def main():
     p.add_argument("--chaos-jitter", type=float, default=0.0)
     p.add_argument("--chaos-straggler-prob", type=float, default=0.0)
     p.add_argument("--seed", type=int, default=0)
-    p.add_argument("--transport", default="asyncio", choices=["asyncio", "native"],
-                   help="server data plane: asyncio loop or the C++ "
-                        "epoll framepump (native/framepump.cpp)")
     args = p.parse_args()
 
     import jax
@@ -68,7 +65,6 @@ def main():
         max_batch_size=args.max_batch_size,
         chaos=chaos,
         seed=args.seed,
-        transport=args.transport,
     ) as (endpoint, srv):
         experts = [
             RemoteExpert(uid, endpoint, timeout=60.0) for uid in srv.experts
@@ -130,20 +126,15 @@ def main():
             "device_kind": device.device_kind,
             "device_time_s": round(srv.runtime.device_time, 2),
             "runtime": srv.runtime.stats(),
-            "transport": args.transport,
             "chaos": vars(chaos) if chaos else None,
         }
         # client dispatch hot path (PR 2): negotiated protocol, bytes
         # handed to the wire, and the multiplexed in-flight high-water
         # mark per endpoint pool
-        from learning_at_home_tpu.client.rpc import (
-            dispatch_mode,
-            pool_registry,
-        )
+        from learning_at_home_tpu.client.rpc import pool_registry
 
         pools = pool_registry().pools()
         result["client"] = {
-            "dispatch_mode": dispatch_mode(),
             "protocol": "v2" if any(p._proto == 2 for p in pools) else "v1",
             "bytes_sent": int(sum(p.bytes_sent for p in pools)),
             "inflight_depth_max": max(

@@ -23,7 +23,7 @@ After the run the harness checks the SLO floors — training throughput
 vs the churn-free warmup baseline, a dispatch-latency p99 ceiling, and
 zero quorum failures inside graceful-drain windows — and exits non-zero
 on violation (``--no-slo-gate`` to observe without gating).  ``--report``
-writes the machine-readable summary the collect gate and bench consume.
+writes the machine-readable summary the collect gate consumes.
 
 Examples:
   python experiments/churn_experiment.py --profile fast --report /tmp/slo.json

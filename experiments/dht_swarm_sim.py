@@ -294,7 +294,7 @@ async def run_size(
 
 
 def check(report: dict, args) -> list[str]:
-    """Floor assertions for --check mode (collect_gate / bench)."""
+    """Floor assertions for --check mode (collect_gate)."""
     problems = []
     sizes = report["sizes"]
     for r in sizes:

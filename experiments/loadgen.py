@@ -35,7 +35,7 @@ Workload shaping (ISSUE 13 — the paged-KV/chunked-prefill A/B knobs):
   (the gateway's counter-based RNG); all-None keeps greedy requests
   with no sampling fields on the wire.
 
-Importable (``run_load``) for bench.py / collect_gate.py, or a CLI::
+Importable (``run_load``) for collect_gate.py, or a CLI::
 
     python experiments/loadgen.py --endpoint 127.0.0.1:31400 \
         --rate 20 --duration 10 \

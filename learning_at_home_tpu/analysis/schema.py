@@ -1,7 +1,7 @@
 """lah-schema: AST extraction of the wire contract from BOTH sides (ISSUE 15).
 
 The swarm's trust boundary is the framed tensor RPC: four dispatcher
-families (expert ``connection_handler._dispatch``, gateway
+families (expert ``connection_handler._serve``, gateway
 ``frontdoor._dispatch``, averaging ``handler._dispatch``, DHT
 ``protocol._serve``) parse peer-supplied meta maps, and a dozen client
 construction sites emit them — across protocol v1/v2 framing and the
@@ -26,7 +26,7 @@ same contract as analysis/lint.py).  It recovers a per-op wire IR:
   op resolves to a string literal, directly or through wrapper chains
   (``GatewayClient._rpc`` -> ``pool.rpc``; ``DHTProtocol._call`` ->
   ``_transport`` -> ``pool.rpc``; ``RemoteExpert._call_blocking`` ->
-  ``_rpc``/``_rpc_prepared``; the MoE fan-out closures whose ``msg_type``
+  ``_rpc_prepared``; the MoE fan-out closures whose ``msg_type``
   is an enclosing function's parameter).  Meta fields are resolved from
   dict literals, local assignments, ``{**meta, ...}`` augmentation,
   conditional ``meta["k"] = v`` writes and single-dict transformer

@@ -93,20 +93,14 @@ class RemoteExpert:
         return meta
 
     def _call_blocking(self, msg_type: str, tensors, meta: dict):
-        """One exchange with serialization on THIS thread (pipelined
-        mode): the wire cast above and the spec/blob walk both run on the
-        host thread already blocked inside io_callback, so the shared
-        ``lah-client`` loop only writes ready buffers.  Legacy mode keeps
-        the old serialize-on-the-loop path (the bench A/B baseline)."""
-        from learning_at_home_tpu.client.rpc import dispatch_mode
+        """One exchange with serialization on THIS thread: the wire cast
+        above and the spec/blob walk both run on the host thread already
+        blocked inside io_callback, so the shared ``lah-client`` loop only
+        writes ready buffers."""
+        from learning_at_home_tpu.utils.serialization import WireTensors
 
-        if dispatch_mode() == "pipelined":
-            from learning_at_home_tpu.utils.serialization import WireTensors
-
-            wire = WireTensors.prepare(tensors)
-            out, _ = client_loop().run(self._rpc_prepared(msg_type, wire, meta))
-        else:
-            out, _ = client_loop().run(self._rpc(msg_type, tensors, meta))
+        wire = WireTensors.prepare(tensors)
+        out, _ = client_loop().run(self._rpc_prepared(msg_type, wire, meta))
         return out
 
     def forward_blocking(self, inputs: Sequence[np.ndarray]) -> list[np.ndarray]:

@@ -53,7 +53,6 @@ from learning_at_home_tpu.client.routing import (
 from learning_at_home_tpu.client.rpc import (
     DispatchFuture,
     client_loop,
-    dispatch_mode,
     pool_registry,
 )
 from learning_at_home_tpu.utils import flight, sanitizer
@@ -167,9 +166,7 @@ class RemoteMixtureOfExperts:
         # pool; the LAH_WIRE_CODEC environment variable overrides both.
         # Quantized codecs are only ever OFFERED to pools whose hello
         # negotiation echoed the "codec" feature (v1 peers and old builds
-        # transparently fall back to the wire_dtype base), and only in
-        # pipelined dispatch mode (the legacy A/B arm keeps the exact
-        # pre-PR-2 wire).
+        # transparently fall back to the wire_dtype base).
         env_codec = os.environ.get("LAH_WIRE_CODEC") or None
         validate_wire_codec(env_codec)
         validate_wire_codec(wire_codec)
@@ -612,16 +609,13 @@ class RemoteMixtureOfExperts:
             x, logits_concat, store_session=store_session, trace=trace
         ).join()
 
-    def _join_timeout(self, kind: str):
-        """Hard join deadline for the future-based path (None = the
-        legacy arm's unbounded watchdog-guarded wait).  Every RPC inside
-        the fan-out is already bounded by rpc_timeout and the quorum
-        grace, so a fan-out that outlives their sum plus the grace slack
-        is stalled, not slow."""
+    def _join_timeout(self, kind: str) -> float:
+        """Hard join deadline of a fan-out.  Every RPC inside it is
+        already bounded by rpc_timeout and the quorum grace, so a fan-out
+        that outlives their sum plus the grace slack is stalled, not
+        slow."""
         from learning_at_home_tpu.client.rpc import JOIN_GRACE_S
 
-        if dispatch_mode() == "legacy":
-            return None
         base = self.forward_timeout if kind == "forward" else self.backward_timeout
         return base + self.timeout_after_k_min + JOIN_GRACE_S
 
@@ -662,8 +656,8 @@ class RemoteMixtureOfExperts:
         session_id: Optional[int] = None,
     ) -> DispatchFuture:
         """FIRE half of a forward dispatch: alive-set lookup, per-sample
-        top-k selection, payload serialization (pipelined mode: pack-once
-        on this host thread) and a non-blocking submit of the quorum
+        top-k selection, payload serialization (pack-once on this host
+        thread) and a non-blocking submit of the quorum
         fan-out to the client loop.  Returns a joinable
         :class:`DispatchFuture` immediately — this path never waits for
         expert replies.  Loop touches are control-plane only: grid
@@ -785,29 +779,20 @@ class RemoteMixtureOfExperts:
                 )
                 for e in jobs
             }
-            prepared = None
-            if dispatch_mode() == "pipelined":
-                # payload slot left empty: _prepare_payloads slices each
-                # expert's rows from the ONE wire-cast batch — materializing
-                # x[rows] here too would double the hot-path memcpy
-                uid_jobs, prepared = self._prepare_payloads(
-                    "forward",
-                    {
-                        alive_uids[e]: (
-                            replica_sets[alive_uids[e]][0], None, rows, slots
-                        )
-                        for e, (rows, slots) in jobs.items()
-                    },
-                    x_full=x,
-                    trace=trace,
-                )
-            else:
-                uid_jobs = {
+            # payload slot left empty: _prepare_payloads slices each
+            # expert's rows from the ONE wire-cast batch — materializing
+            # x[rows] here too would double the hot-path memcpy
+            uid_jobs, prepared = self._prepare_payloads(
+                "forward",
+                {
                     alive_uids[e]: (
-                        replica_sets[alive_uids[e]][0], x[rows], rows, slots
+                        replica_sets[alive_uids[e]][0], None, rows, slots
                     )
                     for e, (rows, slots) in jobs.items()
-                }
+                },
+                x_full=x,
+                trace=trace,
+            )
 
         coro = self._quorum_fanout(
             msg_type="forward",
@@ -817,9 +802,7 @@ class RemoteMixtureOfExperts:
             rpc_timeout=self.forward_timeout,
             prepared=prepared,
             trace=trace,
-            # hedging is a pipelined-path behavior: the legacy arm stays
-            # the exact pre-replica A/B baseline
-            backups=backups if dispatch_mode() == "pipelined" else None,
+            backups=backups,
         )
 
         fut_box: list = []
@@ -840,10 +823,6 @@ class RemoteMixtureOfExperts:
         fut = DispatchFuture(
             "forward", coro, finalize,
             join_timeout=self._join_timeout("forward"),
-            watchdog_rtt=(
-                self._slowest_rtt(uid_jobs)
-                if dispatch_mode() == "legacy" else None
-            ),
             what=f"forward dispatch ({self.uid_prefix}, {batch} rows)",
             on_join_exit=self._make_join_exit(trace),
         )
@@ -931,21 +910,6 @@ class RemoteMixtureOfExperts:
         ).observe(dispatch_s)
         self.dispatches += 1
         return y, idx, mask, np.int32(cid)
-
-    @staticmethod
-    def _slowest_rtt(uid_jobs: dict):
-        """Worst involved pool's RTT EMA (the dispatch-wait watchdog's
-        scale); None when nothing has been measured yet."""
-        registry = pool_registry()
-        worst = None
-        for job in uid_jobs.values():
-            pool = registry.peek(job[0])
-            if pool is not None and pool.rtt_ema is not None:
-                worst = (
-                    pool.rtt_ema if worst is None
-                    else max(worst, pool.rtt_ema)
-                )
-        return worst
 
     # ---- host-thread serialization (the off-loop half of the pipeline) ----
 
@@ -1143,9 +1107,9 @@ class RemoteMixtureOfExperts:
                             xh = {"c": name}
                         else:
                             # form mismatch (adaptive drift between
-                            # directions, or a legacy-mode forward):
-                            # send exact f32 rather than violate the
-                            # all-floats-compressed legacy contract
+                            # directions): send exact f32 rather than
+                            # violate the all-floats-compressed legacy
+                            # contract
                             x_pay = np.asarray(x_pay, np.float32)
                     elif (
                         is_float_dtype(x_pay.dtype)
@@ -1453,16 +1417,9 @@ class RemoteMixtureOfExperts:
         with timeline.span(
             "client.dispatch.fire", trace=trace, kind="backward"
         ):
-            prepared = None
-            if dispatch_mode() == "pipelined":
-                uid_jobs, prepared = self._prepare_payloads(
-                    "backward", session, gy_full=gy, trace=trace
-                )
-            else:
-                uid_jobs = {
-                    uid: (ep, x_rows, rows, slots, gy[rows, slots])
-                    for uid, (ep, x_rows, rows, slots) in session.items()
-                }
+            uid_jobs, prepared = self._prepare_payloads(
+                "backward", session, gy_full=gy, trace=trace
+            )
         coro = self._quorum_fanout(
             msg_type="backward",
             jobs=uid_jobs,
@@ -1482,10 +1439,6 @@ class RemoteMixtureOfExperts:
         fut = DispatchFuture(
             "backward", coro, finalize,
             join_timeout=self._join_timeout("backward"),
-            watchdog_rtt=(
-                self._slowest_rtt(uid_jobs)
-                if dispatch_mode() == "legacy" else None
-            ),
             what=f"backward dispatch ({self.uid_prefix}, {batch} rows)",
             on_join_exit=self._make_join_exit(trace),
         )
@@ -1767,7 +1720,7 @@ class RemoteMixtureOfExperts:
 
     async def _quorum_fanout(
         self, msg_type: str, jobs: dict, batch: int, quorum: int,
-        rpc_timeout: float, prepared: Optional[dict] = None,
+        rpc_timeout: float, prepared: dict,
         trace: Optional[str] = None, backups: Optional[dict] = None,
     ) -> dict:
         """Run the fan-out in parallel; once every sample has ≥ quorum
@@ -1794,7 +1747,7 @@ class RemoteMixtureOfExperts:
         this coarsens to is the real one: co-hosted experts share a
         process, so they die (and straggle) together anyway.
 
-        ``prepared`` (pipelined mode) maps uid → WireTensors serialized on
+        ``prepared`` maps uid → WireTensors serialized on
         the host thread; this coroutine then never casts or packs tensor
         bytes on the loop — merged calls concatenate blob REFERENCES, and
         a disaggregated retry reuses the same buffers."""
@@ -1809,14 +1762,6 @@ class RemoteMixtureOfExperts:
                 (ep, [uid]) for ep, uids in group_list for uid in uids
             ]
 
-        def cast(arr):
-            """Downcast floating payloads to the wire dtype (transport
-            encoding only; replies are upcast back at the accumulation
-            sites via ``np.asarray(reply, dtype)``)."""
-            from learning_at_home_tpu.utils.serialization import wire_cast
-
-            return wire_cast([arr], self.wire_dtype)[0]
-
         async def call_single(endpoint, uid) -> dict:
             meta = (
                 {"uid": uid}
@@ -1829,30 +1774,17 @@ class RemoteMixtureOfExperts:
                 # server stamps it onto its pool/runtime spans
                 meta["trace"] = trace
             pool = registry.get(endpoint)
-            if prepared is not None:
-                wire_obj, wmeta = prepared[uid]
-                if wmeta is not None:
-                    # wmeta is built per-endpoint by the adaptive codec
-                    # selector, which only offers encoded (dict) forms to
-                    # pools whose hello negotiated "codec" — the gate is
-                    # upstream of this function, out of static reach
-                    # lah-lint: ignore[R14]
-                    meta["wire"] = wmeta
-                tensors, _ = await pool.rpc_prepared(
-                    msg_type, wire_obj, meta, timeout=rpc_timeout
-                )
-            else:
-                if self.wire_dtype is not None:
-                    meta["wire"] = self.wire_dtype
-                job = jobs[uid]
-                payload = (
-                    [cast(job[1])]
-                    if msg_type == "forward"
-                    else [cast(job[1]), cast(job[4])]
-                )
-                tensors, _ = await pool.rpc(
-                    msg_type, payload, meta, timeout=rpc_timeout
-                )
+            wire_obj, wmeta = prepared[uid]
+            if wmeta is not None:
+                # wmeta is built per-endpoint by the adaptive codec
+                # selector, which only offers encoded (dict) forms to
+                # pools whose hello negotiated "codec" — the gate is
+                # upstream of this function, out of static reach
+                # lah-lint: ignore[R14]
+                meta["wire"] = wmeta
+            tensors, _ = await pool.rpc_prepared(
+                msg_type, wire_obj, meta, timeout=rpc_timeout
+            )
             return {uid: tensors}
 
         async def call_group(endpoint, uids) -> dict:
@@ -1869,52 +1801,31 @@ class RemoteMixtureOfExperts:
             multi_meta = {"op": msg_type, "parts": parts}
             if trace is not None:
                 multi_meta["trace"] = trace
-            pool = registry.get(endpoint)
-            if prepared is not None:
-                from learning_at_home_tpu.utils.serialization import (
-                    WireTensors,
-                )
+            from learning_at_home_tpu.utils.serialization import WireTensors
 
-                # spec/blob reference concat — the per-uid buffers packed
-                # once on the host thread serve the merged request as-is.
-                # One codec per endpoint (prepared enforces it), so the
-                # merged wire meta is the first uid's form with the
-                # per-tensor headers concatenated in parts order.
-                wire = WireTensors.concat(
-                    [prepared[uid][0] for uid in uids]
-                )
-                wmeta = prepared[uids[0]][1]
-                if isinstance(wmeta, dict):
-                    wmeta = {
-                        "c": wmeta["c"],
-                        "h": [
-                            h for uid in uids for h in prepared[uid][1]["h"]
-                        ],
-                    }
-                if wmeta is not None:
-                    # same contract as call_single: the codec selector
-                    # only prepares dict wire forms for endpoints whose
-                    # hello negotiated "codec", so the supports() gate
-                    # sits upstream of this merged-call path
-                    # lah-lint: ignore[R14]
-                    multi_meta["wire"] = wmeta
-                reply_tensors, reply_meta = await pool.rpc_prepared(
-                    "multi", wire, multi_meta, timeout=rpc_timeout
-                )
-            else:
-                if self.wire_dtype is not None:
-                    multi_meta["wire"] = self.wire_dtype
-                payload = []
-                for uid in uids:
-                    job = jobs[uid]
-                    payload.extend(
-                        [cast(job[1])]
-                        if msg_type == "forward"
-                        else [cast(job[1]), cast(job[4])]
-                    )
-                reply_tensors, reply_meta = await pool.rpc(
-                    "multi", payload, multi_meta, timeout=rpc_timeout
-                )
+            pool = registry.get(endpoint)
+            # spec/blob reference concat — the per-uid buffers packed
+            # once on the host thread serve the merged request as-is.
+            # One codec per endpoint (prepared enforces it), so the
+            # merged wire meta is the first uid's form with the
+            # per-tensor headers concatenated in parts order.
+            wire = WireTensors.concat([prepared[uid][0] for uid in uids])
+            wmeta = prepared[uids[0]][1]
+            if isinstance(wmeta, dict):
+                wmeta = {
+                    "c": wmeta["c"],
+                    "h": [h for uid in uids for h in prepared[uid][1]["h"]],
+                }
+            if wmeta is not None:
+                # same contract as call_single: the codec selector
+                # only prepares dict wire forms for endpoints whose
+                # hello negotiated "codec", so the supports() gate
+                # sits upstream of this merged-call path
+                # lah-lint: ignore[R14]
+                multi_meta["wire"] = wmeta
+            reply_tensors, reply_meta = await pool.rpc_prepared(
+                "multi", wire, multi_meta, timeout=rpc_timeout
+            )
             # reply meta is peer-supplied: any structural lie fails the
             # whole group (equivalent to a failed RPC), never misbinds
             rparts = reply_meta.get("parts")
@@ -1977,8 +1888,6 @@ class RemoteMixtureOfExperts:
             (dict-form) payload needs the backup pool to have negotiated
             the ``codec`` feature — re-encoding on this loop is exactly
             what the pack-once contract forbids."""
-            if prepared is None:
-                return True
             if not any(isinstance(prepared[u][1], dict) for u in uids):
                 return True
             pool = registry.get(backup_ep)

@@ -169,8 +169,8 @@ class SwarmDMoETransformerLM:
         ``overlap=False`` runs the SAME primitive ops in the serial
         schedule (join immediately after fire) — only host-side
         scheduling differs, so serial and overlapped outputs and
-        gradients are bitwise identical; that is the A/B contract
-        bench.py and the parity tests rely on."""
+        gradients are bitwise identical; that is the contract
+        the parity tests rely on."""
         cfg = self.cfg
         b, s = token_ids.shape
         x = params["embed"][token_ids] + params["pos"][None, :s]

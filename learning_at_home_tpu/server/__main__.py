@@ -81,10 +81,6 @@ def main() -> None:
                    help="DHT scope the metrics endpoint is advertised "
                         "under (telemetry.<prefix>); lah_top discovers "
                         "all peers sharing a prefix")
-    p.add_argument("--transport", default="asyncio",
-                   choices=["asyncio", "native"],
-                   help="data plane: asyncio loop, or the C++ epoll "
-                        "framepump (GIL-free socket work; multi-core hosts)")
     p.add_argument("--chaos-latency", type=float, default=0.0,
                    help="inject WAN-like base latency (seconds) per request")
     p.add_argument("--chaos-jitter", type=float, default=0.0)
@@ -151,7 +147,6 @@ def main() -> None:
         port=args.port,
         dht=dht,
         update_period=args.update_period,
-        transport=args.transport,
         telemetry_prefix=args.telemetry_prefix,
         chaos=(
             ChaosConfig(

@@ -71,11 +71,8 @@ if TYPE_CHECKING:
 
 logger = logging.getLogger(__name__)
 
-# Features the asyncio transport speaks; a client ``hello`` gets back the
-# intersection with what it offered.  The native C++ pump does NOT
-# negotiate (its dispatcher replies through handler._dispatch, where
-# ``hello`` lands in the unknown-message error path), so clients fall
-# back to protocol v1 against it — by design, not by accident.
+# Features the server speaks; a client ``hello`` gets back the
+# intersection with what it offered.
 # ``codec``: the request may carry the DICT wire form (quantized 8-bit
 # codecs with per-tensor headers — serialization.py, docs/PROTOCOL.md);
 # clients never offer quantized payloads to peers that did not echo it.
@@ -574,26 +571,18 @@ class ConnectionHandler:
             }
         return stats
 
-    async def _dispatch(self, payload: bytes, rid=None) -> list:
+    async def _serve(self, payload: bytes, rid, span, read: tuple) -> list:
         """Serve one request; returns the reply as vectored frame parts
         (``pack_frames`` output — header buffer + raw tensor blobs), so
         the reply payload is never joined into one bytestring on this
         loop.  ``rid`` (protocol v2) is echoed into the reply header.
 
-        ``server.request`` is the request's whole stay in the server,
-        from here to the reply frame built; ``server.decode`` and
-        ``server.encode`` are the codec's two sides inside it.  The
-        native pump's entry: it reads and writes the socket itself, so its
-        requests have no ``server.read`` / ``server.write``; the asyncio
-        transport goes through ``_respond``."""
-        with timeline.span("server.request") as span:
-            return await self._serve(payload, rid, span)
-
-    async def _serve(self, payload: bytes, rid, span, read=None) -> list:
-        """A request's body; ``span`` is its ``server.request`` span,
-        which gets the message type, the kind and the trace id once they
-        are read, and ``read`` the ``(start, duration)`` of its frame's
-        read, recorded here as ``server.read`` for the same reason.
+        ``span`` is the request's ``server.request`` span (its whole stay
+        in the server, to the reply frame built; ``server.decode`` and
+        ``server.encode`` are the codec's two sides inside it), which gets
+        the message type, the kind and the trace id once they are read,
+        and ``read`` the ``(start, duration)`` of its frame's read,
+        recorded here as ``server.read`` for the same reason.
 
         A ``{"trace": id}`` meta entry (distributed tracing) is
         peer-supplied: it is structurally validated, stamped onto this
@@ -653,7 +642,7 @@ class ConnectionHandler:
             trace = None  # malformed/absent: never trust peer-supplied meta
         span.trace = trace
         span.attrs["type"] = msg_type
-        if read is not None and "kind" in span.attrs:
+        if "kind" in span.attrs:
             timeline.record(
                 "server.read", *read, trace, kind=span.attrs["kind"]
             )

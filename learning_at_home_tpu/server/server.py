@@ -52,22 +52,8 @@ class Server:
         update_period: float = 15.0,
         batch_timeout: float = 0.002,
         chaos: Any = None,
-        transport: str = "asyncio",
         telemetry_prefix: str = "swarm",
     ):
-        if transport not in ("asyncio", "native"):
-            raise ValueError(f"transport must be 'asyncio' or 'native', got {transport!r}")
-        self.transport = transport
-        self._pump = None
-        self._native_threads: list[threading.Thread] = []
-        self._native_stop = threading.Event()
-        # conn_id -> tail future; single-dispatcher-thread state (the one
-        # native worker is the only reader/writer, so no lock is needed —
-        # and a SINGLE popper is what makes per-connection reply order a
-        # guarantee: pop, chain-link, and callback-attach happen in
-        # program order on one thread, while all actual dispatch runs on
-        # the asyncio loop, so extra poppers add no concurrency anyway)
-        self._native_chains: dict[int, Any] = {}
         self.experts = dict(experts)
         self.host, self._requested_port = host, port
         self.dht = dht
@@ -372,27 +358,10 @@ class Server:
 
     async def _start_async(self) -> None:
         handler = ConnectionHandler(self)
-        if self.transport == "native":
-            # GIL-free C++ epoll data plane (native/framepump.cpp): Python
-            # worker threads only see whole frames and bridge them onto the
-            # event loop for task-pool dispatch
-            from learning_at_home_tpu.native import FramePump
-
-            self._pump = FramePump(self.host, self._requested_port)
-            self.port = self._pump.port
-            t = threading.Thread(
-                target=self._native_worker,
-                args=(handler,),
-                name="lah-native-io",
-                daemon=True,
-            )
-            t.start()
-            self._native_threads.append(t)
-        else:
-            self._tcp_server = await asyncio.start_server(
-                handler.handle_connection, self.host, self._requested_port
-            )
-            self.port = self._tcp_server.sockets[0].getsockname()[1]
+        self._tcp_server = await asyncio.start_server(
+            handler.handle_connection, self.host, self._requested_port
+        )
+        self.port = self._tcp_server.sockets[0].getsockname()[1]
         for pool in (*self.forward_pools.values(), *self.backward_pools.values()):
             pool.start(self.runtime)
         asyncio.get_running_loop().create_task(
@@ -460,95 +429,6 @@ class Server:
         if self.drain_summary is not None:
             info["drain_summary"] = self.drain_summary
         return info
-
-    def _native_worker(self, handler: ConnectionHandler) -> None:
-        """THE single dispatcher thread: shovels whole frames from the
-        native pump onto the event loop (task pools are asyncio) WITHOUT
-        waiting for each dispatch — the reply is pushed back to the pump
-        from a done-callback, so in-flight concurrency matches the asyncio
-        transport's one-coroutine-per-request.
-
-        Dispatches are CHAINED per connection: request N+1 on a connection
-        starts only after request N's reply was queued, making in-order
-        replies a server guarantee (the asyncio transport processes each
-        connection serially too) — not merely a property of this repo's
-        one-exchange-at-a-time client.  Being the only popper is what
-        makes the chain sound: pop, link, and callback-attach happen in
-        program order here, with no lock and no second thread to invert
-        frames."""
-        pump = self._pump
-        chains = self._native_chains  # conn_id -> tail future (this thread only)
-
-        async def process(prev, payload: bytes):
-            from learning_at_home_tpu.utils.serialization import frame_payload
-
-            if prev is not None:
-                try:
-                    await asyncio.wrap_future(prev)
-                # lah-lint: ignore[R6] ordering barrier only: the prior
-                # request's failure was already logged (and replied) where
-                # it happened; this await exists to sequence replies
-                except BaseException:
-                    pass
-            # the pump's C side frames replies itself: join the vectored
-            # parts back into one payload (no writev through ctypes)
-            reply = frame_payload(await handler._dispatch(payload))
-            if self.chaos is not None and not await self.chaos.before_reply(
-                len(payload) + len(reply)
-            ):
-                return None  # injected drop: client sees a timeout
-            return reply
-
-        def reply_cb(fut, conn_id):
-            try:
-                reply = fut.result()
-            except BaseException as e:  # incl. CancelledError at shutdown
-                if not isinstance(e, asyncio.CancelledError):
-                    logger.exception("native dispatch failed")
-                return
-            if reply is None:
-                return
-            try:
-                pump.send(conn_id, reply)  # cheap: C memcpy + eventfd
-            except ValueError:
-                logger.error("native reply exceeds frame cap — dropped")
-
-        n_since_cleanup = 0
-        while True:
-            if self._native_stop.is_set():
-                return
-            try:
-                item = pump.next(timeout=0.2)
-            except EOFError:
-                return
-            loop = self._loop  # snapshot: shutdown() nulls the attribute
-            if item is None or loop is None:
-                if loop is None:
-                    return
-                continue
-            conn_id, payload = item
-            prev = chains.get(conn_id)
-            if prev is not None and prev.done():
-                prev = None
-            try:
-                fut = asyncio.run_coroutine_threadsafe(
-                    process(prev, payload), loop.loop
-                )
-            except RuntimeError:  # loop closed mid-shutdown
-                return
-            chains[conn_id] = fut
-            # callback attached HERE, still in the dispatcher: attaching
-            # after releasing ordering control would let reply N land
-            # after N+1 when the dispatcher is preempted between link and
-            # attach (the chain only orders dispatch starts, and reply_cb
-            # for an already-done future runs inline on whichever thread
-            # attaches it)
-            fut.add_done_callback(lambda f, cid=conn_id: reply_cb(f, cid))
-            n_since_cleanup += 1
-            if n_since_cleanup >= 256:  # lazily drop finished chains
-                n_since_cleanup = 0
-                for cid in [c for c, f in chains.items() if f.done()]:
-                    del chains[cid]
 
     async def _monitor_load_forever(self) -> None:
         """Per-expert queue-depth EMA sampler (serving loop; qsize reads
@@ -1079,32 +959,10 @@ class Server:
             self._metrics_loop = None
         if self._tcp_server is not None:
             self._loop.loop.call_soon_threadsafe(self._tcp_server.close)
-        # native teardown ORDER matters (the pump's shutdown frees its C
-        # state): stop workers, drain the loop (all reply callbacks fire on
-        # the loop thread before its join returns), join workers, and only
-        # then destroy the pump — nothing can touch freed memory after.
-        self._native_stop.set()
         self.runtime.shutdown()
         loop = self._loop
-        self._loop = None  # signals native workers' timeout branch
+        self._loop = None
         loop.shutdown()
-        for t in self._native_threads:
-            t.join(timeout=5)
-        wedged = [t for t in self._native_threads if t.is_alive()]
-        self._native_threads.clear()
-        if self._pump is not None:
-            if wedged:
-                # A live worker may still be inside pump.next(); destroying
-                # the C state under it is a use-after-free.  Leaking one
-                # pump beats corrupting the process.
-                logger.error(
-                    "%d native worker(s) did not join; leaking the pump "
-                    "instead of freeing C state under them", len(wedged)
-                )
-            else:
-                with contextlib.suppress(Exception):
-                    self._pump.shutdown()
-            self._pump = None
         logger.info("server shut down")
 
 

@@ -19,8 +19,8 @@ Modules:
   gateways wrapping the real ``SlotScheduler``/``AdmissionController``,
   and the telemetry mirror feeding the real routing cost model;
 - :mod:`~learning_at_home_tpu.sim.runner` — scenario orchestration and
-  the ``python -m learning_at_home_tpu.sim.runner`` CLI behind
-  ``bench.py --macro-sim`` and the collect_gate MACRO_SIM smoke.
+  the ``python -m learning_at_home_tpu.sim.runner`` CLI behind the
+  collect_gate MACRO_SIM smoke.
 """
 
 from learning_at_home_tpu.sim.clock import (  # noqa: F401

@@ -23,7 +23,7 @@ Examples::
     python -m learning_at_home_tpu.sim.runner --nodes 2048 --servers 256 \\
         --gateways 16 --experts 256 \\
         --trace "poisson:180:40,burst:900:10,diurnal:220:50:0.5:25" \\
-        --churn "35:kill:0.1,60:join:26"     # the bench.py --macro-sim shape
+        --churn "35:kill:0.1,60:join:26"
 """
 
 from __future__ import annotations

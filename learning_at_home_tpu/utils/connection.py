@@ -12,9 +12,9 @@ paths:
   multiplex many in-flight RPCs over ONE socket — the fan-out's k calls
   to a peer share a connection instead of burning k sockets, and replies
   may interleave in any order.  Negotiation is a single ``hello``
-  exchange on first contact; servers that don't speak it (old builds,
-  the native C++ pump) answer with an ``error`` frame and the pool falls
-  back to v1 transparently, reusing the probe socket.
+  exchange on first contact; servers that don't speak it (old builds)
+  answer with an ``error`` frame and the pool falls back to v1
+  transparently, reusing the probe socket.
 
 Serialization is the CALLER's job on the hot path: ``rpc_prepared`` takes
 a :class:`WireTensors` built off-loop (host thread) and the loop only
@@ -26,7 +26,6 @@ from __future__ import annotations
 
 import asyncio
 import logging
-import os
 import threading
 from typing import Optional, Sequence
 
@@ -68,20 +67,6 @@ BW_MIN_SAMPLE_BYTES = 256 << 10
 # configured grace period, and teardown/shutdown cancels (no marker) are
 # never mistaken for slowness evidence however loaded the box is.
 QUORUM_STRAGGLER_CANCEL = "lah-quorum-straggler-cancel"
-
-_force_v1 = False
-
-
-def force_protocol_v1(flag: bool) -> None:
-    """Process-wide v1 pin (the legacy half of the dispatch A/B, and an
-    escape hatch for wire debugging).  ``LAH_PROTO=v1`` does the same
-    from the environment."""
-    global _force_v1
-    _force_v1 = bool(flag)
-
-
-def _v2_enabled() -> bool:
-    return not _force_v1 and os.environ.get("LAH_PROTO", "").lower() != "v1"
 
 
 class RemoteCallError(RuntimeError):
@@ -163,10 +148,9 @@ class ConnectionPool:
         # v2 REQUIREMENT for protocols whose semantics depend on
         # out-of-order replies (the averaging subsystem HOLDS avg_part
         # replies until a partition reduces — on v1's one-RPC-per-socket
-        # discipline held replies starve the connection pool).  Such
-        # pools ignore the process-wide legacy/A-B v1 pin
-        # (``force_protocol_v1`` / LAH_CLIENT_PIPELINE=0), which exists
-        # to A/B the DISPATCH path, not to break averaging.
+        # discipline held replies starve the connection pool): such a
+        # pool refuses a peer that does not answer ``hello`` instead of
+        # falling back to v1.
         self._require_v2 = require_v2
         self.max_inflight = max_inflight
         self._free: asyncio.Queue = asyncio.Queue()
@@ -226,9 +210,7 @@ class ConnectionPool:
         its peer, so :meth:`supports` answers definitively before the
         caller commits to a wire encoding (the averaging chunk sender's
         hook; idempotent, serialized on the negotiation lock)."""
-        if self._proto is None and self._negotiate_v2 and (
-            self._require_v2 or _v2_enabled()
-        ):
+        if self._proto is None and self._negotiate_v2:
             await self._negotiate(timeout)
 
     @staticmethod
@@ -337,7 +319,7 @@ class ConnectionPool:
         if kind in KINDS:
             stamp["kind"] = kind
         with timeline.span(f"rpc.{msg_type}", **stamp):
-            if (self._require_v2 or _v2_enabled()) and self._negotiate_v2:
+            if self._negotiate_v2:
                 if self._proto is None:
                     await self._negotiate(timeout)
                 if self._proto == 2:
@@ -396,7 +378,7 @@ class ConnectionPool:
         """One ``hello`` exchange decides the pool's protocol.  A v2
         server echoes the features it speaks (the socket becomes the mux
         connection); anything else — an ``error`` reply from an old
-        server or the native pump — pins v1, and the probe socket is
+        server — pins v1, and the probe socket is
         reused for v1 traffic (its handler already served the error and
         is waiting for the next frame)."""
         async with self._lazy_nego_lock():

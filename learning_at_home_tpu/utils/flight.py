@@ -3,7 +3,7 @@
 The postmortem story for a swarm where the failing peer may already be
 gone: every component appends structured events (sheds with reason,
 preemptions, hedge fires, drain transitions, SLO state changes,
-watchdog/sanitizer trips) into a per-component bounded ring.  Recording
+sanitizer trips) into a per-component bounded ring.  Recording
 is a dict append under one leaf lock — always on, like the metrics
 registry, never gated on ``LAH_PROFILE``.
 
@@ -12,7 +12,7 @@ Surfaces:
 - ``/debug/flight`` on every :class:`~.metrics.MetricsHTTPServer` — the
   live rings as JSON;
 - :func:`dump` — an on-disk JSON artifact written when something is
-  already wrong (SLO PAGE, dispatch-watchdog fire, sanitizer violation).
+  already wrong (SLO PAGE, sanitizer violation).
   Dumps are throttled per reason so a violation storm cannot fill the
   disk; the artifact directory is ``LAH_FLIGHT_DIR`` (defaulting to
   ``<tmp>/lah_flight``).

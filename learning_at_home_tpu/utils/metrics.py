@@ -18,7 +18,7 @@ Surfaces:
 - :meth:`MetricsRegistry.render_prometheus` — Prometheus text exposition
   (v0.0.4): ``# HELP`` / ``# TYPE`` / ``name{label="v"} value`` lines;
 - :meth:`MetricsRegistry.snapshot` — the same data as a JSON/msgpack-safe
-  dict (consumed by the ``stats`` RPC, ``bench.py`` and ``lah_top``);
+  dict (consumed by the ``stats`` RPC and ``lah_top``);
 - :class:`MetricsHTTPServer` — a deliberately tiny asyncio HTTP/1.1
   endpoint serving ``/metrics`` (Prometheus), ``/metrics.json``,
   ``/trace`` (Chrome trace_event JSON of this process's Timeline) and
@@ -49,17 +49,6 @@ from learning_at_home_tpu.utils.profiling import timeline
 from learning_at_home_tpu.utils.sketch import QuantileSketch
 
 logger = logging.getLogger(__name__)
-
-# Histograms also feed a mergeable quantile sketch per label set (ISSUE
-# 19) so lah_top can compute TRUE fleet percentiles instead of the MAX
-# fallback.  The toggle exists for bench.py's observability-parity A/B
-# only — production never turns it off.
-_SKETCH_BACKING = True
-
-
-def set_sketch_backing(on: bool) -> None:
-    global _SKETCH_BACKING
-    _SKETCH_BACKING = bool(on)
 
 _INVALID_NAME_CHARS = re.compile(r"[^a-zA-Z0-9_:]")
 
@@ -163,19 +152,19 @@ class Histogram(_Metric):
             key = self._child_key(labels)
             state = self._values.get(key)
             if state is None:
+                # the mergeable quantile sketch per label set (ISSUE 19)
+                # lets lah_top compute TRUE fleet percentiles instead of
+                # the MAX fallback
                 state = self._values[key] = {
                     "buckets": [0] * len(self.buckets), "sum": 0.0, "count": 0,
+                    "sketch": QuantileSketch(),
                 }
             for i, ub in enumerate(self.buckets):
                 if value <= ub:
                     state["buckets"][i] += 1
             state["sum"] += value
             state["count"] += 1
-            if _SKETCH_BACKING:
-                sk = state.get("sketch")
-                if sk is None:
-                    sk = state["sketch"] = QuantileSketch()
-                sk.add(value)
+            state["sketch"].add(value)
 
     def _items(self) -> list[tuple[tuple, Any]]:
         # deep-copy under the lock: the live sketch/bucket state mutates
@@ -184,14 +173,12 @@ class Histogram(_Metric):
         with self._lock:
             out = []
             for k, st in self._values.items():
-                view: dict[str, Any] = {
+                out.append((k, {
                     "buckets": list(st["buckets"]),
                     "sum": st["sum"],
                     "count": st["count"],
-                }
-                if "sketch" in st:
-                    view["sketch"] = st["sketch"].to_dict()
-                out.append((k, view))
+                    "sketch": st["sketch"].to_dict(),
+                }))
             return out
 
 
@@ -341,12 +328,9 @@ class MetricsRegistry:
                             for ub, n in zip(m.buckets, st["buckets"])
                         },
                         # wire-form sketch (already rendered by _items);
-                        # absent on pre-sketch peers — readers treat that
-                        # as the tagged MAX-fallback signal
-                        **(
-                            {"sketch": st["sketch"]}
-                            if "sketch" in st else {}
-                        ),
+                        # a reader that meets a pre-sketch peer's snapshot
+                        # without one takes the tagged MAX fallback
+                        "sketch": st["sketch"],
                     },
                 )
             elif isinstance(m, Gauge):

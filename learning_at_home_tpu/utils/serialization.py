@@ -218,14 +218,6 @@ def pack_frames(
     return [prefix, *wire.blobs]
 
 
-def frame_payload(parts: list) -> bytes:
-    """Join frame parts and strip the outer length prefix — the payload
-    bytes a non-vectored transport (native pump) expects.  Only the small
-    header part is sliced; the tensor blobs are joined exactly once."""
-    head = bytes(parts[0])[4:]  # parts[0] is prefix+header (small)
-    return b"".join([head, *(bytes(p) for p in parts[1:])])
-
-
 def frame_nbytes(parts: list) -> int:
     """Total frame size of a ``pack_frames`` result, prefix included."""
     return sum(len(p) if isinstance(p, bytes) else p.nbytes for p in parts)

@@ -6,7 +6,7 @@ imports this module and must stay numpy-free for byte-determinism):
 - :func:`percentile` — THE percentile definition for every number this
   repo reports.  ``method="linear"`` replicates ``np.percentile``'s
   default linear interpolation bit-for-bit (same virtual-index formula,
-  same two-sided lerp), so experiments/loadgen.py and bench.py keep
+  same two-sided lerp), so experiments/loadgen.py keeps
   emitting byte-identical values after switching off numpy;
   ``method="nearest"`` replicates the macro-sim's pure-Python
   nearest-rank formula (``sim/runner.py``) including Python banker's

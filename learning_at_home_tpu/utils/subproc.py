@@ -88,8 +88,8 @@ def spawn_expert_servers(
     ports)``; on any boot failure every started server is killed before
     the error propagates.
 
-    Shared by the overlap bench A/B (bench.py) and the collect-gate
-    overlap smoke: SUBPROCESS isolation is load-bearing there — an
+    SUBPROCESS isolation is load-bearing for the collect-gate overlap
+    smoke — an
     in-process server shares the client's GIL, and compute the client
     hides inside the in-flight RPC window starves the server's loops,
     growing the window by exactly the hidden time (observed 2026-08-04).
@@ -183,9 +183,7 @@ def spawn_overlap_swarm(
 ):
     """One subprocess ``nop``-expert server per entry of ``latencies``
     (the per-pool fake-delay WAN proxies) + the matching multi-layer
-    swarm source/config — the ONE definition of the overlap A/B swarm,
-    shared by ``bench.py --overlap-worker`` and the collect-gate overlap
-    smoke so the gate always validates exactly what the bench measures.
+    swarm source/config — the collect-gate overlap smoke's swarm.
     Returns ``(procs, source, cfg)``; tear down with
     :func:`shutdown_procs`."""
     from learning_at_home_tpu.client.routing import StaticExpertSource
@@ -218,9 +216,8 @@ def find_orphan_servers(exclude_descendants_of: Optional[int] = None) -> list:
     from a PRIOR session.  Orphans silently load the (single) core and
     corrupt every absolute CPU timing taken while they live — three
     churn servers once ran ~6 h into the next session and invalidated its
-    morning's numbers.  Timing entry points
-    (bench.py, tools/collect_gate.py) call this BEFORE spawning anything,
-    so every match is by definition not ours.
+    morning's numbers.  ``tools/collect_gate.py`` calls this BEFORE
+    spawning anything, so every match is by definition not ours.
 
     Returns ``[(pid, age_seconds, cmdline), ...]``; empty off-Linux (no
     /proc) — the guard degrades to a no-op rather than guessing.

@@ -334,30 +334,6 @@ def test_chunk_cap_prevents_held_reply_starvation(dht):
         b.shutdown()
 
 
-def test_averaging_survives_global_v1_pin(dht):
-    """The legacy/A-B dispatch switch pins protocol v1 process-wide, but
-    averaging's held replies REQUIRE the v2 out-of-order contract — its
-    pools opt out of the pin (require_v2) and must still negotiate v2."""
-    from learning_at_home_tpu.utils.connection import force_protocol_v1
-
-    cfg = AveragingConfig(min_group_size=2, max_group_size=2,
-                          part_timeout=3.0)
-    a, b = _spawn(dht, 2, cfg)
-    force_protocol_v1(True)
-    try:
-        results, errors = _run_rounds([a, b], [_make_tree(0), _make_tree(1)])
-        assert not errors, errors
-        (tree_a, info_a), (tree_b, _) = results
-        assert not info_a["degraded"], info_a
-        for la, lb in zip(jax.tree.leaves(tree_a), jax.tree.leaves(tree_b)):
-            np.testing.assert_array_equal(np.asarray(la), np.asarray(lb))
-        assert all(p._proto == 2 for p in a._registry.pools())
-    finally:
-        force_protocol_v1(False)
-        a.shutdown()
-        b.shutdown()
-
-
 def test_matchmaking_times_out_alone(dht):
     cfg = AveragingConfig(min_group_size=2, poll=0.1)
     av = _spawn(dht, 1, cfg)[0]

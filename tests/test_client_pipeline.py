@@ -17,11 +17,7 @@ import pytest
 from learning_at_home_tpu.client import RemoteExpert, reset_client_rpc
 from learning_at_home_tpu.client.moe import RemoteMixtureOfExperts
 from learning_at_home_tpu.client.routing import StaticExpertSource
-from learning_at_home_tpu.client.rpc import (
-    client_loop,
-    pool_registry,
-    set_dispatch_mode,
-)
+from learning_at_home_tpu.client.rpc import client_loop, pool_registry
 from learning_at_home_tpu.server import background_server
 from learning_at_home_tpu.utils import serialization as ser
 from learning_at_home_tpu.utils.connection import (
@@ -43,14 +39,6 @@ from learning_at_home_tpu.utils.serialization import (
 )
 
 HID = 16
-
-
-@pytest.fixture(autouse=True)
-def _pipelined_mode():
-    """Every test starts (and the suite continues) in the default mode."""
-    set_dispatch_mode("pipelined")
-    yield
-    set_dispatch_mode("pipelined")
 
 
 # ---------------------------------------------------------------------------
@@ -362,10 +350,11 @@ def test_v1_fallback_against_old_protocol_server():
     asyncio.run(run())
 
 
-def test_moe_numerics_identical_across_dispatch_modes():
-    """Legacy (serialize-on-loop, v1) and pipelined (off-loop pack-once,
-    v2 mux) regimes are transport variants of one contract: identical
-    forward outputs against frozen server params."""
+def test_moe_output_matches_per_expert_reference():
+    """The mixture's output against a plain reference built here: each
+    row's two best experts by gate logit, each expert asked for its rows
+    alone through ``RemoteExpert.forward_blocking``, the replies combined
+    by the softmax of the chosen logits in numpy (frozen server params)."""
     import jax
     import jax.numpy as jnp
 
@@ -379,14 +368,27 @@ def test_moe_numerics_identical_across_dispatch_modes():
             source=source, k_best=2, k_min=2, wire_dtype="bfloat16",
         )
         gate = moe.init_gate_params(jax.random.PRNGKey(0))
-        x = jnp.asarray(
-            np.random.RandomState(2).randn(5, HID).astype(np.float32)
-        )
-        set_dispatch_mode("legacy")
-        y_legacy = np.asarray(moe(x, gate))
-        set_dispatch_mode("pipelined")
-        y_pipe = np.asarray(moe(x, gate))
-        np.testing.assert_allclose(y_legacy, y_pipe, rtol=1e-5, atol=1e-5)
+        x = np.random.RandomState(2).randn(5, HID).astype(np.float32)
+        y = np.asarray(moe(jnp.asarray(x), gate))
+
+        logits = x @ np.asarray(gate["w0"])
+        chosen = np.argsort(-logits, axis=1)[:, :2]
+        scores = np.take_along_axis(logits, chosen, axis=1)
+        weights = np.exp(scores - scores.max(axis=1, keepdims=True))
+        weights /= weights.sum(axis=1, keepdims=True)
+        want = np.zeros_like(x)
+        for e in range(4):
+            rows, slots = np.nonzero(chosen == e)
+            if len(rows):
+                expert = RemoteExpert(f"ffn.{e}", endpoint,
+                                      wire_dtype="bfloat16")
+                (out,) = expert.forward_blocking([x[rows]])
+                want[rows] += (
+                    weights[rows, slots, None] * np.asarray(out, np.float32)
+                )
+        # replies travel as bfloat16: a reply computed in another batch
+        # may round to the neighbouring value, one part in 2**8
+        np.testing.assert_allclose(y, want, rtol=2.0 ** -7, atol=1e-5)
     reset_client_rpc()
 
 

@@ -78,106 +78,3 @@ def test_server_dht_moe_end_to_end():
         client_dht.shutdown()
         bootstrap.shutdown()
         reset_client_rpc()
-
-
-def test_native_transport_parity():
-    """The C++ framepump data plane (transport='native') serves the same
-    protocol: forward/backward replies match the asyncio transport
-    numerically, and wrong requests still error cleanly."""
-    import numpy as np
-    import pytest
-
-    from learning_at_home_tpu.client import RemoteExpert, reset_client_rpc
-    from learning_at_home_tpu.native import native_available
-    from learning_at_home_tpu.utils.connection import RemoteCallError
-
-    if not native_available():
-        pytest.skip("native framepump unavailable (no g++?)")
-
-    rs = np.random.RandomState(0)
-    x = rs.randn(3, 16).astype(np.float32)
-    g = rs.randn(3, 16).astype(np.float32)
-    outs = {}
-    for transport in ("asyncio", "native"):
-        with background_server(
-            num_experts=1, hidden_dim=16, expert_prefix="nt", seed=3,
-            transport=transport,
-        ) as (endpoint, srv):
-            e = RemoteExpert("nt.0", endpoint)
-            fwd = e.forward_blocking([x])[0]
-            bwd = e.backward_blocking([x], [g])[0]
-            info = e.info()
-            assert info["n_inputs"] == 1
-            with pytest.raises(RemoteCallError, match="unknown expert"):
-                RemoteExpert("nt.999", endpoint).forward_blocking([x])
-            outs[transport] = (np.asarray(fwd), np.asarray(bwd))
-        reset_client_rpc()
-    np.testing.assert_allclose(outs["native"][0], outs["asyncio"][0], atol=1e-5)
-    np.testing.assert_allclose(outs["native"][1], outs["asyncio"][1], atol=1e-5)
-
-
-def test_native_transport_pipelined_ordering():
-    """A client that pipelines several requests on ONE connection must get
-    replies in request order — the native plane chains per-connection
-    dispatches, it does not rely on clients being one-in-flight."""
-    import socket
-    import struct
-
-    import numpy as np
-    import pytest
-
-    from learning_at_home_tpu.native import native_available
-    from learning_at_home_tpu.utils.serialization import pack_message, unpack_message
-
-    if not native_available():
-        pytest.skip("native framepump unavailable")
-
-    with background_server(
-        num_experts=4, hidden_dim=8, expert_prefix="ord", seed=4,
-        transport="native",
-    ) as (endpoint, srv):
-        s = socket.create_connection(endpoint)
-        # pipeline 8 info requests for DIFFERENT uids without reading
-        uids = [f"ord.{i % 4}" for i in range(8)]
-        for uid in uids:
-            payload = pack_message("info", (), {"uid": uid})
-            s.sendall(struct.pack("<I", len(payload)) + payload)
-        for uid in uids:
-            (ln,) = struct.unpack("<I", s.recv(4, socket.MSG_WAITALL))
-            buf = b""
-            while len(buf) < ln:
-                buf += s.recv(ln - len(buf))
-            msg_type, _, meta = unpack_message(buf)
-            assert msg_type == "result"
-            assert meta["name"] == uid, (meta["name"], uid)
-        s.close()
-
-
-def test_native_transport_wire_dtype():
-    """bf16 wire compression through the C++ framepump plane: the native
-    dispatcher routes the same meta, so wire-compressed requests must get
-    wire-compressed replies with f32-grade numerics."""
-    import numpy as np
-    import pytest
-
-    from learning_at_home_tpu.client import RemoteExpert, reset_client_rpc
-    from learning_at_home_tpu.native import native_available
-
-    if not native_available():
-        pytest.skip("native framepump unavailable (no g++?)")
-
-    rs = np.random.RandomState(1)
-    x = rs.randn(4, 16).astype(np.float32)
-    with background_server(
-        num_experts=1, hidden_dim=16, expert_prefix="nw", seed=5,
-        transport="native",
-    ) as (endpoint, srv):
-        e32 = RemoteExpert("nw.0", endpoint)
-        e16 = RemoteExpert("nw.0", endpoint, wire_dtype="bfloat16")
-        y32 = np.asarray(e32.forward_blocking([x])[0])
-        reply = e16.forward_blocking([x])[0]
-        assert reply.dtype == np.dtype("bfloat16")
-        np.testing.assert_allclose(
-            np.asarray(reply, np.float32), y32, rtol=0.05, atol=0.05
-        )
-    reset_client_rpc()

@@ -17,11 +17,7 @@ import pytest
 from learning_at_home_tpu.client import RemoteExpert, reset_client_rpc
 from learning_at_home_tpu.client.moe import RemoteMixtureOfExperts
 from learning_at_home_tpu.client.routing import StaticExpertSource
-from learning_at_home_tpu.client.rpc import (
-    client_loop,
-    pool_registry,
-    set_dispatch_mode,
-)
+from learning_at_home_tpu.client.rpc import client_loop, pool_registry
 from learning_at_home_tpu.server import background_server
 from learning_at_home_tpu.utils import connection as conn_mod
 from learning_at_home_tpu.utils.profiling import timeline
@@ -32,14 +28,12 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 @pytest.fixture()
 def profiled():
-    """Timeline on + clean, pipelined mode, pools reset afterwards."""
-    set_dispatch_mode("pipelined")
+    """Timeline on + clean, pools reset afterwards."""
     timeline.enable()
     timeline.clear()
     yield timeline
     timeline.disable()
     timeline.clear()
-    set_dispatch_mode("pipelined")
     reset_client_rpc()
 
 
@@ -158,26 +152,6 @@ def test_trace_joins_client_and_server_spans_v2_merged(profiled):
     read_s, read_e = _interval(spans, "server.read", trace, kind="forward")
     write_s, write_e = _interval(spans, "server.write", trace, kind="forward")
     assert rpc_s <= read_s <= read_e <= req_s and write_s == req_e
-
-
-def test_trace_v1_fallback_roundtrip(profiled):
-    """Legacy mode (protocol v1, serialize-on-loop): the trace id rides
-    the same meta and still stamps server-side spans."""
-    set_dispatch_mode("legacy")
-    with background_server(
-        num_experts=2, hidden_dim=HID, expert_prefix="ffn", seed=0
-    ) as (endpoint, srv):
-        moe = _make_moe(srv, endpoint)
-        _fwd_bwd(moe)
-    by_name = _traces_by_name(timeline.spans())
-    (trace,) = by_name["moe.dispatch"]
-    assert trace in by_name.get("rpc.multi", set())
-    spans = timeline.spans()
-    assert trace in _traces_by_name(spans, type="multi")["server.request"]
-    assert trace in _traces_by_name(
-        spans, pool="ffn.0.forward")["runtime.dispatch"]
-    # legacy mode has no host-thread pack stage, by design
-    assert "client.pack" not in by_name
 
 
 def test_trace_survives_disaggregated_retry(profiled, monkeypatch):

@@ -15,8 +15,7 @@ Covers the four contracts of the fire/join refactor:
   already-encoded session rows (pack-once contract survives the split);
 - clean failure: a stalled pool under the future-based path makes the
   join TIME OUT with a diagnosable error — the ROUND5 io_callback-hang
-  class retired by construction (the legacy arm keeps the PR-5 watchdog,
-  demoted to a regression role).
+  class retired by construction.
 """
 
 import asyncio
@@ -35,10 +34,7 @@ from learning_at_home_tpu.client.moe import (
     RemoteMixtureOfExperts,
 )
 from learning_at_home_tpu.client.routing import StaticExpertSource
-from learning_at_home_tpu.client.rpc import (
-    DispatchJoinTimeout,
-    set_dispatch_mode,
-)
+from learning_at_home_tpu.client.rpc import DispatchJoinTimeout
 from learning_at_home_tpu.models.transformer_swarm import (
     SwarmDMoETransformerLM,
     SwarmTransformerConfig,
@@ -242,8 +238,7 @@ def test_joined_dispatch_is_freed_by_reference_count(kind):
 def test_stalled_pool_join_times_out_cleanly(monkeypatch):
     """ISSUE 7 satellite: a stalled pool (accepts, never replies, ignores
     its own RPC timeout) under the future-based path must make the join
-    time out with a diagnosable error — never hang.  The legacy path
-    keeps the PR-5 watchdog for this (demoted to a regression role)."""
+    time out with a diagnosable error — never hang."""
     from learning_at_home_tpu.utils import connection
 
     async def _stall(self, *args, **kwargs):
@@ -322,21 +317,16 @@ def test_evicted_ticket_drains_inflight_gauge(monkeypatch):
     reset_client_rpc()
 
 
-def test_join_timeout_mode_gating():
-    """Pipelined joins get a hard deadline; the legacy A/B arm keeps the
-    unbounded watchdog-guarded wait (PR-5 semantics)."""
+def test_join_deadline_is_a_number_above_the_rpc_bounds():
+    """Every join has a hard deadline, longer than the fan-out's own
+    bounds (``forward_timeout + timeout_after_k_min``)."""
     source = StaticExpertSource({"ffn.0": ("127.0.0.1", 1)})
     moe = RemoteMixtureOfExperts(
         in_features=8, grid_size=(1,), uid_prefix="ffn", source=source,
         k_best=1, k_min=1, forward_timeout=1.0, timeout_after_k_min=0.5,
     )
-    assert moe._join_timeout("forward") is not None
+    assert isinstance(moe._join_timeout("forward"), float)
     assert moe._join_timeout("forward") > 1.5
-    set_dispatch_mode("legacy")
-    try:
-        assert moe._join_timeout("forward") is None
-    finally:
-        set_dispatch_mode("pipelined")
 
 
 def test_fire_join_under_jit(twin_swarms):

@@ -68,11 +68,15 @@ def test_beam_search_4096_reads_few_records():
 
 
 @pytest.mark.slow
-def test_multiprocess_server_roundtrip():
+def test_multiprocess_server_roundtrip(tmp_path):
     """Launch the server CLI as a REAL separate process and call it."""
     from learning_at_home_tpu.utils.subproc import clean_jax_subprocess_env
 
     env = clean_jax_subprocess_env(REPO, platform="cpu")
+    # a cache of its own: every entry loaded from the checkout's warm cache
+    # prints XLA:CPU's loader warning, and enough of them fill the unread
+    # pipe below before the server listens
+    env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "cache")
     port = 43219
     proc = subprocess.Popen(
         [

@@ -15,8 +15,7 @@ The contracts under test:
   exists;
 - **wire form**: JSON round-trips preserve every query; malformed wire
   dicts degrade to None (lah_top's never-crash contract), never raise;
-- **registry backing**: histograms export a sketch in their snapshot,
-  and ``set_sketch_backing(False)`` removes the cost.
+- **registry backing**: histograms export a sketch in their snapshot.
 """
 
 import json
@@ -26,10 +25,7 @@ import random
 import numpy as np
 import pytest
 
-from learning_at_home_tpu.utils.metrics import (
-    MetricsRegistry,
-    set_sketch_backing,
-)
+from learning_at_home_tpu.utils.metrics import MetricsRegistry
 from learning_at_home_tpu.utils.sketch import (
     QuantileSketch,
     merge_dicts,
@@ -240,32 +236,21 @@ def test_merge_dicts_skips_malformed_and_none_when_nothing_merged():
 # ---------------------------------------------------------------------------
 
 
-def test_registry_histogram_exports_sketch_and_backing_toggle():
+def test_registry_histogram_exports_sketch():
     reg = MetricsRegistry()
-    try:
-        h = reg.histogram("lah_t_lat_seconds")
-        for v in (0.001, 0.002, 0.004, 1.0):
-            h.observe(v)
-        snap = reg.snapshot()
-        wire = snap["histograms"]["lah_t_lat_seconds"]["sketch"]
-        json.dumps(wire)  # JSON-safe in place
-        sk = try_from_dict(wire)
-        assert sk is not None and sk.count == 4
-        # labeled histograms carry one sketch per label variant
-        hl = reg.histogram("lah_t_lbl_seconds")
-        hl.observe(0.5, pool="a")
-        hl.observe(2.5, pool="b")
-        labelled = reg.snapshot()["histograms"]["lah_t_lbl_seconds"]
-        variants = [v for v in labelled.values() if isinstance(v, dict)]
-        assert len(variants) == 2
-        assert all(try_from_dict(v["sketch"]) is not None for v in variants)
-        # backing off: fresh observations stop growing a sketch
-        set_sketch_backing(False)
-        reg2 = MetricsRegistry()
-        h2 = reg2.histogram("lah_t_plain_seconds")
-        h2.observe(0.5)
-        assert "sketch" not in reg2.snapshot()["histograms"][
-            "lah_t_plain_seconds"
-        ]
-    finally:
-        set_sketch_backing(True)
+    h = reg.histogram("lah_t_lat_seconds")
+    for v in (0.001, 0.002, 0.004, 1.0):
+        h.observe(v)
+    snap = reg.snapshot()
+    wire = snap["histograms"]["lah_t_lat_seconds"]["sketch"]
+    json.dumps(wire)  # JSON-safe in place
+    sk = try_from_dict(wire)
+    assert sk is not None and sk.count == 4
+    # labeled histograms carry one sketch per label variant
+    hl = reg.histogram("lah_t_lbl_seconds")
+    hl.observe(0.5, pool="a")
+    hl.observe(2.5, pool="b")
+    labelled = reg.snapshot()["histograms"]["lah_t_lbl_seconds"]
+    variants = [v for v in labelled.values() if isinstance(v, dict)]
+    assert len(variants) == 2
+    assert all(try_from_dict(v["sketch"]) is not None for v in variants)

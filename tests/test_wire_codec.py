@@ -17,7 +17,6 @@ The contract under test (docs/PROTOCOL.md "Wire codecs"):
 """
 
 import asyncio
-import logging
 import threading
 import time
 
@@ -28,12 +27,7 @@ import pytest
 from learning_at_home_tpu.client import reset_client_rpc
 from learning_at_home_tpu.client.moe import RemoteMixtureOfExperts
 from learning_at_home_tpu.client.routing import StaticExpertSource
-from learning_at_home_tpu.client.rpc import (
-    dispatch_wait_watchdog,
-    pool_registry,
-    reset_dispatch_watchdog,
-    set_dispatch_mode,
-)
+from learning_at_home_tpu.client.rpc import pool_registry
 from learning_at_home_tpu.server import background_server
 from learning_at_home_tpu.utils import serialization as ser
 from learning_at_home_tpu.utils.serialization import (
@@ -57,13 +51,6 @@ HID = 16
 SHAPES = [(0, 8), (1,), (), (5, 1), (3, 1024), (2, 1500), (7, 3, 64),
           (2048,), (16, 2, 256), (4, 1)]
 FLOAT_DTYPES = [np.float32, np.float64, "bfloat16", np.float16]
-
-
-@pytest.fixture(autouse=True)
-def _pipelined_mode():
-    set_dispatch_mode("pipelined")
-    yield
-    set_dispatch_mode("pipelined")
 
 
 # ---------------------------------------------------------------------------
@@ -655,68 +642,6 @@ def test_backward_gradient_cosine_blockq8():
         )
         assert cos >= 0.99, f"gradient cosine {cos:.4f} < 0.99"
     reset_client_rpc()
-
-
-# ---------------------------------------------------------------------------
-# dispatch-wait watchdog (satellite)
-# ---------------------------------------------------------------------------
-
-
-class TestDispatchWatchdog:
-    def test_stalled_wait_logs_stacks_once(self, caplog, monkeypatch):
-        """A wait exceeding the RTT-multiple budget must WARN with thread
-        stacks — once per process (a deliberately-stalled fake pool
-        stands in for the silent io_callback deadlock)."""
-        monkeypatch.setenv("LAH_DISPATCH_WATCHDOG_MULT", "2")
-        monkeypatch.setenv("LAH_DISPATCH_WATCHDOG_MIN_S", "0.05")
-        reset_dispatch_watchdog()
-        with caplog.at_level(logging.WARNING,
-                             logger="learning_at_home_tpu.client.rpc"):
-            with dispatch_wait_watchdog(0.01, what="fake stalled pool"):
-                time.sleep(0.3)  # the stalled dispatch wait
-            # filter on the rpc logger: the flight recorder also WARNs
-            # when it dumps its dispatch_watchdog artifact, and that
-            # line legitimately contains "watchdog"
-            records = [
-                r for r in caplog.records
-                if r.name == "learning_at_home_tpu.client.rpc"
-                and "watchdog" in r.getMessage()
-            ]
-            assert len(records) == 1
-            msg = records[0].getMessage()
-            assert "fake stalled pool" in msg
-            assert "thread" in msg and "File" in msg  # real stacks
-            # once per process: a second stall stays silent
-            with dispatch_wait_watchdog(0.01, what="second stall"):
-                time.sleep(0.3)
-            records = [
-                r for r in caplog.records
-                if r.name == "learning_at_home_tpu.client.rpc"
-                and "watchdog" in r.getMessage()
-            ]
-            assert len(records) == 1
-        reset_dispatch_watchdog()
-
-    def test_fast_wait_never_fires(self, caplog, monkeypatch):
-        monkeypatch.setenv("LAH_DISPATCH_WATCHDOG_MULT", "20")
-        monkeypatch.setenv("LAH_DISPATCH_WATCHDOG_MIN_S", "0.2")
-        reset_dispatch_watchdog()
-        with caplog.at_level(logging.WARNING,
-                             logger="learning_at_home_tpu.client.rpc"):
-            with dispatch_wait_watchdog(0.001, what="fast"):
-                time.sleep(0.01)
-            time.sleep(0.3)  # past the budget: timer must be cancelled
-            assert not [
-                r for r in caplog.records if "watchdog" in r.getMessage()
-            ]
-
-    def test_disabled_without_rtt_or_multiple(self, monkeypatch):
-        monkeypatch.setenv("LAH_DISPATCH_WATCHDOG_MULT", "0")
-        with dispatch_wait_watchdog(10.0):
-            pass
-        monkeypatch.setenv("LAH_DISPATCH_WATCHDOG_MULT", "20")
-        with dispatch_wait_watchdog(None):
-            pass
 
 
 # ---------------------------------------------------------------------------

@@ -22,8 +22,8 @@ byte-identical, non-empty, cost-improving plans (rc=8) — the live
 SLO-gated migration driver replays these plans move-for-move.  Then
 ``pytest --collect-only`` on
 CPU exits non-zero on any collection error, then a CLIENT-PATH SMOKE:
-one forward+backward RPC against a local server under BOTH wire
-protocols (legacy/v1 and pipelined/v2), so wire-format breakage fails
+one forward+backward RPC against a local server on the one client path
+(protocol v2 negotiated by ``hello``), so wire-format breakage fails
 here in seconds instead of ten minutes into the tier-1 run, then an
 AVERAGING SMOKE: two in-process trainer-side averaging peers complete
 one DHT-matched all-reduce round and must end with identical parameters
@@ -350,45 +350,32 @@ def placement_stage() -> int:
 
 
 def smoke_worker() -> int:
-    """One fwd+bwd RPC per protocol version against an in-process server;
-    numerics must agree across protocols and v2 must actually negotiate."""
+    """One fwd+bwd RPC against an in-process server, on the one client
+    path; the pool must actually negotiate protocol v2."""
     import numpy as np
 
     sys.path.insert(0, REPO)
     from learning_at_home_tpu.client import RemoteExpert, reset_client_rpc
-    from learning_at_home_tpu.client.rpc import pool_registry, set_dispatch_mode
+    from learning_at_home_tpu.client.rpc import pool_registry
     from learning_at_home_tpu.server.server import background_server
-
-    import optax
 
     with background_server(
         num_experts=1, hidden_dim=8, expert_prefix="gate", seed=0,
-        optimizer=optax.sgd(0.0),  # frozen params: replies must match
     ) as (endpoint, _srv):
         expert = RemoteExpert("gate.0", endpoint, timeout=30.0)
         x = np.random.RandomState(0).randn(2, 8).astype(np.float32)
         g = np.ones((2, 8), np.float32)
-        outs = {}
-        for mode in ("legacy", "pipelined"):
-            set_dispatch_mode(mode)
-            y = expert.forward_blocking([x])[0]
-            gx = expert.backward_blocking([x], [g])[0]
-            assert y.shape == x.shape and gx.shape == x.shape
-            assert np.isfinite(y).all() and np.isfinite(gx).all()
-            outs[mode] = (y, gx)
-        np.testing.assert_allclose(
-            outs["legacy"][0], outs["pipelined"][0], atol=1e-6
-        )
-        np.testing.assert_allclose(  # backward wire path too, not just fwd
-            outs["legacy"][1], outs["pipelined"][1], atol=1e-6
-        )
+        y = expert.forward_blocking([x])[0]
+        gx = expert.backward_blocking([x], [g])[0]  # backward wire path too
+        assert y.shape == x.shape and gx.shape == x.shape
+        assert np.isfinite(y).all() and np.isfinite(gx).all()
         pool = pool_registry().peek(endpoint)
         assert pool is not None and pool._proto == 2, (
-            f"pipelined mode did not negotiate protocol v2 (got "
+            f"the pool did not negotiate protocol v2 (got "
             f"{None if pool is None else pool._proto})"
         )
     reset_client_rpc()
-    print("SMOKE_OK protocols=v1,v2")
+    print("SMOKE_OK protocol=v2")
     # sequence the remaining gates HERE so each smoke stays independently
     # runnable and a failure is attributed to the right one
     rc = averaging_smoke()
@@ -754,7 +741,7 @@ def overlap_smoke() -> int:
     server shares the client's GIL, and the eager attention the schedule
     hides starves the server's loop threads — the reply window then
     GROWS by exactly the hidden compute and the A/B measures nothing
-    (observed 2026-08-04; same reason bench.py's large regimes fork)."""
+    (observed 2026-08-04)."""
     import time
 
     import numpy as np
@@ -766,8 +753,6 @@ def overlap_smoke() -> int:
     )
 
     try:
-        # the ONE shared swarm definition (utils.subproc): the gate must
-        # validate exactly the swarm bench.py --overlap-worker measures
         servers, source, cfg = spawn_overlap_swarm(
             REPO, "ov", (0.05, 0.06), platform="cpu"
         )
