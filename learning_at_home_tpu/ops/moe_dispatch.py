@@ -434,7 +434,9 @@ class SharePlan(NamedTuple):
     the data), expert g's rows the ``group_sizes[g]`` consecutive ones
     after those of the held experts before it.  Assignments beyond the
     buffer are dropped and counted; buffer rows beyond the assignments
-    are empty (``valid`` False, weight 0)."""
+    are empty (``valid`` False, weight 0).  ``slot`` is the same sort read
+    the other way, token-major: the buffer row of every assignment, ``R``
+    (one past the buffer) for one that has none, absent or dropped."""
 
     token: jax.Array  # [R] int32 — the token each buffer row computes
     weight: jax.Array  # [R] float32 — its gate weight, as normalised over all k chosen
@@ -443,6 +445,8 @@ class SharePlan(NamedTuple):
     counts: jax.Array  # [E] int32 — assignments per expert, held or not
     routed_here: jax.Array  # [] int32 — assignments that fall on a held expert
     aux_loss: jax.Array  # [] load-balance auxiliary over all E, as the other plans'
+    slot: jax.Array  # [n, k] int32 — the buffer row of each assignment; R where it has none
+    slot_weight: jax.Array  # [n, k] float32 — ``weight`` in that order; 0 where it has none
 
 
 def share_buffer_rows(n: int, k: int, held: int, num_experts: int) -> int:
@@ -476,36 +480,166 @@ def share_routing(
     # an assignment to an absent expert sorts behind every held one
     key = jnp.where((local >= 0) & (local < held), local, held)
     # stable: by held expert, then by token
-    order = jnp.argsort(key, stable=True).astype(jnp.int32)[:rows]
+    ranked = jnp.argsort(key, stable=True).astype(jnp.int32)
+    order = ranked[:rows]
     counts = _expert_counts(top_i, num_experts)
     here = jax.lax.dynamic_slice_in_dim(counts, first, held)
     # the buffer takes the first ``rows``: the groups end where it ends
     ends = jnp.minimum(jnp.cumsum(here), rows)
     group_sizes = jnp.diff(ends, prepend=0)
     valid = jnp.arange(rows, dtype=jnp.int32) < ends[-1]
+    # the sort's inverse: where each assignment went, if it went anywhere
+    rank = jnp.argsort(ranked).astype(jnp.int32).reshape(n, k)
+    kept = rank < ends[-1]
     return SharePlan(
         order // k, jnp.where(valid, top_w.reshape(-1)[order], 0.0), valid,
         group_sizes, counts, here.sum(),
         _load_balance_loss(gates, top_i, token_mask),
+        jnp.where(kept, rank, rows), jnp.where(kept, top_w, 0.0),
     )
+
+
+# The share's row movements as the chip runs them where the buffer is no
+# smaller than a measured share of the assignments (``share_gather_fits``):
+# every one a gather.  A scatter-add of R rows costs the TPU several times
+# the gather of as many (PERF.md section 6, PR 27, PR 50, PR 60), so the sum
+# over a token's assignments reads them token-major through ``plan.slot``
+# (n*k rows out of the buffer; an assignment with none reads any row, and
+# its weight of 0 masks it) and adds a token's k adjacent rows
+# (``ops.moe_rows``), as the dropless path does since PR 50.  Where n*k is
+# many times R that gather costs more than the scatter-add it replaces, and
+# the form stays what it was.
+
+
+def share_gather_fits(
+    n: int, k: int, rows: int, d: int, dtype, backend: str
+) -> bool:
+    """Whether a share's sums over a token's assignments (the combine, and
+    the sort's backward) run as a gather of the ``n * k`` assignments' rows
+    out of the buffer of ``rows`` and a sum of ``k`` adjacent ones, in place
+    of a scatter-add of ``rows`` rows of ``d``: bf16 rows on a ``tpu``
+    backend, where it was measured, and no more assignments than
+    ``SHARE_GATHER_MOST_ASSIGNMENTS_A_ROW`` times the buffer's rows (``d``
+    moved no answer at 2,048, 2,688 and 6,144)."""
+    return (
+        backend == "tpu" and jnp.dtype(dtype) == jnp.bfloat16
+        and n * k <= SHARE_GATHER_MOST_ASSIGNMENTS_A_ROW * rows
+    )
+
+
+# tools/grouped_matmul_probe.py rows --buffer-rows, v5e (PERF.md section 6,
+# PR 60): a pass in the gather form takes 0.44 to 0.50 of its scatter-add's
+# time at n*k = R, 0.65 to 0.88 at 2 R, 1.44 to 1.67 at 4 R, 1.45 to 1.74 at
+# 8 R
+SHARE_GATHER_MOST_ASSIGNMENTS_A_ROW = 2
+
+
+def _rows_of_slots(rows: jax.Array, slot: jax.Array) -> jax.Array:
+    """[R, d] buffer rows → [n*k, d], token-major: the row of every
+    assignment.  One with none (``slot == R``) reads SOME row, a different
+    one each (65,536 reads of one row cost the gather 0.8 ms more than
+    reads of rows apart, v5e, PR 60), and what it read is anything: a row
+    outside every group holds whatever was left there, so the sum that
+    follows is ``masked`` (0 x NaN is NaN)."""
+    anywhere = jnp.arange(slot.size, dtype=slot.dtype).reshape(slot.shape) % rows.shape[0]
+    return rows[jnp.where(slot < rows.shape[0], slot, anywhere).reshape(-1)]
+
+
+@jax.custom_vjp
+def _share_rows_to_buffer(x, token, valid, slot):
+    return jnp.where(valid[:, None], x[token], 0)
+
+
+def _share_rows_to_buffer_fwd(x, token, valid, slot):
+    return _share_rows_to_buffer(x, token, valid, slot), slot
+
+
+def _share_rows_to_buffer_bwd(slot, g):
+    n, k = slot.shape
+    with jax.named_scope("sum"):
+        held = (slot < g.shape[0]).astype(jnp.float32)  # a weight of 1 or 0
+        d_x = sum_rows(_rows_of_slots(g, slot), held, n, k, g.dtype, masked=True)
+    return d_x, None, None, None
+
+
+_share_rows_to_buffer.defvjp(_share_rows_to_buffer_fwd, _share_rows_to_buffer_bwd)
 
 
 def share_sort_tokens(x: jax.Array, plan: SharePlan) -> jax.Array:
     """[n, d] → [R, d]: the token of every assignment held here, rows
     grouped by expert; empty rows zero (and their cotangent ignored)."""
+    n, k = plan.slot.shape
+    if share_gather_fits(
+        n, k, plan.token.shape[0], x.shape[-1], x.dtype, jax.default_backend()
+    ):
+        return _share_rows_to_buffer(x, plan.token, plan.valid, plan.slot)
     return jnp.where(plan.valid[:, None], x[plan.token], 0)
 
 
-def share_combine(ys: jax.Array, plan: SharePlan, n: int) -> jax.Array:
-    """[R, d] sorted outputs of the held experts → [n, d] float32: each
-    token's gate-weighted sum over its assignments here (zero for a token
-    with none).  Rows outside every group hold whatever the grouped
-    matmul left there: they are masked, not multiplied by 0."""
+def _share_scatter_add(ys, weight, token, valid, n):
     weighted = jnp.where(
-        plan.valid[:, None],
-        plan.weight[:, None] * ys.astype(jnp.float32), 0.0,
+        valid[:, None], weight[:, None] * ys.astype(jnp.float32), 0.0,
     )
-    return jnp.zeros((n, ys.shape[-1]), jnp.float32).at[plan.token].add(weighted)
+    return jnp.zeros((n, ys.shape[-1]), jnp.float32).at[token].add(weighted)
+
+
+# The combine's two forms under one backward.  The backward gathers the
+# token cotangents ONCE, in the layer's dtype (an [n, d] source: 67 MB of
+# bf16 fits VMEM where autodiff's float32 cotangents, 134 MB, do not), scales
+# a row by its gate weight in buffer order and takes the weights' gradient
+# from dot products with ``ys`` where the grouped matmul left it: the
+# gathered [n*k, d] rows are no residual.  ``weight`` and ``slot_weight``
+# are one set of numbers in two orders; the gradient goes back through the
+# buffer's, so the router's backward is one program under both forms.
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def _share_combine(ys, weight, token, valid, slot, slot_weight, dtype, gathered):
+    n, k = slot.shape
+    if gathered:
+        with jax.named_scope("gather"):
+            picked = _rows_of_slots(ys, slot)
+        with jax.named_scope("sum"):
+            return sum_rows(picked, slot_weight, n, k, dtype, masked=True)
+    return _share_scatter_add(ys, weight, token, valid, n).astype(dtype)
+
+
+def _share_combine_fwd(ys, weight, token, valid, slot, slot_weight, dtype, gathered):
+    out = _share_combine(ys, weight, token, valid, slot, slot_weight, dtype, gathered)
+    return out, (ys, weight, token, valid)
+
+
+def _share_combine_bwd(dtype, gathered, residuals, g):
+    ys, weight, token, valid = residuals
+    g_rows = g[token].astype(jnp.float32)  # [R, d]
+    d_ys = weight[:, None] * g_rows  # an empty row's weight is 0
+    dots = jnp.sum(g_rows * ys.astype(jnp.float32), axis=-1)  # and its ys anything
+    return (d_ys.astype(ys.dtype), jnp.where(valid, dots, 0.0),
+            None, None, None, None)
+
+
+_share_combine.defvjp(_share_combine_fwd, _share_combine_bwd)
+
+
+def share_combine(
+    ys: jax.Array, plan: SharePlan, n: int, dtype=jnp.float32
+) -> jax.Array:
+    """[R, d] sorted outputs of the held experts → [n, d] of ``dtype``: each
+    token's gate-weighted sum over its assignments here (zero for a token
+    with none), float32 products and sums cast once.  Rows outside every
+    group hold whatever the grouped matmul left there: they are masked, not
+    multiplied by 0."""
+    backend = jax.default_backend()
+    if combine_sorted_fits(ys.dtype, backend):
+        return _share_combine(
+            ys, plan.weight, plan.token, plan.valid, plan.slot,
+            plan.slot_weight, dtype,
+            share_gather_fits(
+                n, plan.slot.shape[1], ys.shape[0], ys.shape[-1], ys.dtype,
+                backend),
+        )
+    return _share_scatter_add(
+        ys, plan.weight, plan.token, plan.valid, n).astype(dtype)
 
 
 def balanced_bias(bias: jax.Array, counts: jax.Array, rate: float) -> jax.Array:

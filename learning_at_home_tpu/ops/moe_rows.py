@@ -11,8 +11,9 @@ HBM tile).  What a kernel can win is the pass behind ``ys[inverse]`` and
     out[t] = sum over j of w[t, j] * rows[t * k + j]        (w: 1 without)
 
 float32 products added in the order ``j = 0 .. k - 1``, one cast at the
-end.  Where ``k`` is a multiple of the 8 sublanes XLA's reduction over
-``[n, k, d]`` runs at HBM's rate (0.92 ms at k = 8); where it is not it
+end (``masked``: a row whose weight is 0 is not read into the sum, whatever
+it holds; a share's rows, PR 60).  Where ``k`` is a multiple of the 8
+sublanes XLA's reduction over ``[n, k, d]`` runs at HBM's rate (0.92 ms at k = 8); where it is not it
 costs 2.2 ms (k = 4) to 5.0 (k = 6).  ``moe_rows_sum`` reads the rows as
 32-bit words (an even row's element under the odd row's below it), a
 strided load a pair of choices, so that token ``t``'s ``j``-th row arrives
@@ -61,27 +62,35 @@ def sum_rows_fits(n: int, k: int, d: int, dtype, backend: str) -> bool:
 
 def sum_rows(
     rows: jax.Array, weights: jax.Array | None, n: int, k: int, dtype,
+    masked: bool = False,
 ) -> jax.Array:
     """``[n * k, d]`` rows, a token's ``k`` adjacent (and ``weights`` [n, k]
     float32, or None for a plain sum) → ``[n, d]`` of ``dtype``: the kernel
-    where :func:`sum_rows_fits` says so, the plain form elsewhere."""
+    where :func:`sum_rows_fits` says so, the plain form elsewhere.
+    ``masked``: a row of weight 0 adds 0 even where it holds no number
+    (0 x NaN is NaN), by selection."""
     if sum_rows_fits(n, k, rows.shape[-1], rows.dtype, jax.default_backend()):
-        return sum_rows_kernel(rows, weights, n, k, dtype)
-    return sum_rows_plain(rows, weights, n, k, dtype)
+        return sum_rows_kernel(rows, weights, n, k, dtype, masked=masked)
+    return sum_rows_plain(rows, weights, n, k, dtype, masked)
 
 
 def sum_rows_plain(
     rows: jax.Array, weights: jax.Array | None, n: int, k: int, dtype,
+    masked: bool = False,
 ) -> jax.Array:
     """:func:`sum_rows` in plain ``jax.numpy``: what ``ops.moe_dispatch``
     computed before this module, operation for operation."""
     per_choice = rows.reshape(n, k, rows.shape[-1]).astype(jnp.float32)
     if weights is None:
         return per_choice.sum(axis=1).astype(dtype)
+    if masked:
+        weights = weights[:, :, None]
+        return jnp.where(
+            weights != 0, weights * per_choice, 0.0).sum(axis=1).astype(dtype)
     return jnp.einsum("nk,nkd->nd", weights, per_choice).astype(dtype)
 
 
-def _sum_kernel(*refs, k: int):
+def _sum_kernel(*refs, k: int, masked: bool):
     *w_ref, rows_ref, out_ref = refs  # the weights' block first, where there are weights
     # word-row s: row 2s in the low half, row 2s + 1 in the high half
     words = rows_ref.bitcast(jnp.uint32)
@@ -98,7 +107,10 @@ def _sum_kernel(*refs, k: int):
             both = words[pl.ds(at * pairs + pair, _STRIP, stride=pairs), :]
             for j, row in ((2 * pair, widen(both << 16)),
                            (2 * pair + 1, widen(both & jnp.uint32(0xFFFF0000)))):
-                if w is not None:
+                if w is not None and masked:  # a lane tile a choice: no broadcast here
+                    scale = w[:, j * _LANES:(j + 1) * _LANES]
+                    row = jnp.where(scale != 0, scale * row, 0.0)
+                elif w is not None:
                     row = w[:, j:j + 1] * row
                 acc = row if acc is None else acc + row
         out_ref[pl.ds(at, _STRIP), :] = acc.astype(out_ref.dtype)
@@ -109,7 +121,7 @@ def _sum_kernel(*refs, k: int):
 
 def sum_rows_kernel(
     rows: jax.Array, weights: jax.Array | None, n: int, k: int, dtype,
-    interpret: bool = False,
+    interpret: bool = False, masked: bool = False,
 ) -> jax.Array:
     """:func:`sum_rows` as the Pallas TPU kernel ``moe_rows_sum`` for shapes
     :func:`sum_rows_fits` admits; ``interpret`` runs it on any backend.  No
@@ -119,10 +131,17 @@ def sum_rows_kernel(
     operands = [rows]
     specs = [pl.BlockSpec((tokens * k, _LANES), lambda t, c: (t, c))]
     if weights is not None:
-        operands.insert(0, weights.astype(jnp.float32))
-        specs.insert(0, pl.BlockSpec((tokens, k), lambda t, c: (t, 0)))
+        weights = weights.astype(jnp.float32)
+        if masked:
+            # Broadcasting a token's weight over the lanes inside the kernel
+            # is 1.0 of its 1.65 ms at k = 4 (v5e, PERF.md PR 60): the masked
+            # form takes it spread over a lane tile a choice, fetched once a
+            # token block (its block index does not move with the columns).
+            weights = jnp.repeat(weights, _LANES, axis=1)
+        operands.insert(0, weights)
+        specs.insert(0, pl.BlockSpec((tokens, weights.shape[1]), lambda t, c: (t, 0)))
     return pl.pallas_call(
-        functools.partial(_sum_kernel, k=k),
+        functools.partial(_sum_kernel, k=k, masked=masked),
         grid=(n // tokens, d // _LANES),
         in_specs=specs,
         out_specs=pl.BlockSpec((tokens, _LANES), lambda t, c: (t, c)),

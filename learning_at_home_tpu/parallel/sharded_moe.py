@@ -639,7 +639,7 @@ class ShardedMixtureOfExperts:
             xs = share_sort_tokens(x.astype(compute), plan)  # [R, d]
         ys = self._unbiased_experts(params, xs, plan.group_sizes)
         with jax.named_scope("moe_combine"):
-            y = share_combine(ys, plan, n).astype(x.dtype)
+            y = share_combine(ys, plan, n, x.dtype)
 
         axes = self._shard
         here = plan.routed_here.astype(jnp.float32)

@@ -26,6 +26,13 @@ def test_the_whole_step_fits_the_chip(v5e_chip, monkeypatch):
     assert memory["grouped_matmul_tilings"] == {
         "256,2048,768": 5 * 5, "256,1536,1024": 5 * 4,
         "256,1024,768": 5 * 2, "256,768,1024": 5}
+    # the share's row movements by ``share_gather_fits`` (PR 60): n k = R, so
+    # every sum over a token's assignments is a gather (five a mixture layer:
+    # the sort's forward twice under remat, the combine's, and the two
+    # backward) and the masked kernel ``moe_rows_sum``; no scatter-add
+    assert memory["moe_rows_kernel_calls"] == {
+        "moe_rows_sum": {"calls": 10, "under_moe_sort": 5, "under_moe_combine": 5},
+        "row_gathers": 5 * 5, "row_scatters": 0}
     assert memory["loss_layer_products"] == 2 * 3
     # one forward a kernel layer (12 before PR 38): remat keeps the
     # kernel's output and row sums, 169 MB a layer, so the recompute holds

@@ -25,6 +25,13 @@ def test_the_whole_step_fits_the_chip(v5e_chip, monkeypatch):
     assert 0.25 < memory["share_of_chip"] < 0.9, memory
     assert memory["grouped_matmul_tilings"] == {
         "256,2048,1024": 4 * 9, "256,1024,1024": 4 * 3}
+    # the share's row movements by ``share_gather_fits`` (PR 60): n k = 8 R keeps
+    # the two scatter-adds a mixture layer (two instructions each) beside
+    # three gathers: the sort's forward twice under remat, the combine's
+    # backward once, in bf16
+    assert memory["moe_rows_kernel_calls"] == {
+        "moe_rows_sum": {"calls": 0, "under_moe_sort": 0, "under_moe_combine": 0},
+        "row_gathers": 4 * 3, "row_scatters": 4 * 2 * 2}
     assert memory["loss_layer_products"] == 3  # of the head's; four before PR 34
     assert moe_dispatch.grouped_matmul_tiles(16384, 6144, 2048, jnp.bfloat16) == (
         256, 2048, 1024)

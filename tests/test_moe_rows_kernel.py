@@ -28,8 +28,9 @@ def _rows(n, k, d, seed=0):
     return rows, weights
 
 
-def _kernel(rows, weights, n, k, dtype):
-    return moe_rows.sum_rows_kernel(rows, weights, n, k, dtype, interpret=True)
+def _kernel(rows, weights, n, k, dtype, masked=False):
+    return moe_rows.sum_rows_kernel(
+        rows, weights, n, k, dtype, interpret=True, masked=masked)
 
 
 def chip_form(monkeypatch):
@@ -38,11 +39,11 @@ def chip_form(monkeypatch):
     the kernels' names as they are called."""
     called = []
 
-    def sum_rows(rows, weights, n, k, dtype):
+    def sum_rows(rows, weights, n, k, dtype, masked=False):
         if moe_rows.sum_rows_fits(n, k, rows.shape[-1], rows.dtype, "tpu"):
             called.append("moe_rows_sum")
-            return _kernel(rows, weights, n, k, dtype)
-        return moe_rows.sum_rows_plain(rows, weights, n, k, dtype)
+            return _kernel(rows, weights, n, k, dtype, masked)
+        return moe_rows.sum_rows_plain(rows, weights, n, k, dtype, masked)
 
     on_chip = moe_dispatch.combine_sorted_fits
     monkeypatch.setattr(
@@ -78,6 +79,23 @@ def test_the_kernels_sum_is_the_plain_forms(d, k, weighted):
     np.testing.assert_array_equal(
         np.asarray(cast.astype(jnp.float32)),
         np.asarray(got.astype(jnp.bfloat16).astype(jnp.float32)))
+
+
+@pytest.mark.parametrize("form", ["kernel", "plain"])
+@pytest.mark.parametrize("k", CHOICES)
+def test_a_masked_sum_reads_no_row_of_weight_zero(k, form):
+    """A share's rows (PR 60): an assignment with no row reads any row, NaN
+    among them, under a weight of 0; the other rows add as they did."""
+    rows, weights = _rows(N, k, 256, seed=5)
+    dead = np.random.RandomState(6).rand(N, k) < 0.4
+    weights = jnp.where(dead, 0.0, weights)
+    clean = jnp.where(dead.reshape(-1)[:, None], 0, rows)
+    rotten = jnp.where(dead.reshape(-1)[:, None], jnp.nan, rows)
+    sum_rows = _kernel if form == "kernel" else moe_rows.sum_rows_plain
+    got = sum_rows(rotten, weights, N, k, jnp.float32, masked=True)
+    want = sum_rows(clean, weights, N, k, jnp.float32)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-6, atol=1e-6)
+    assert np.isnan(np.asarray(sum_rows(rotten, weights, N, k, jnp.float32))).any()
 
 
 def test_more_tokens_than_a_block_take_a_grid_of_blocks(monkeypatch):

@@ -33,6 +33,12 @@ def test_the_whole_step_fits_the_chip(v5e_chip, monkeypatch):
     assert memory["grouped_matmul_tilings"] == {
         "256,896,1920": 4 * 3, "256,1920,896": 4 * 3,
         "256,896,640": 4, "256,640,896": 4}
+    # the share's row movements by ``share_gather_fits`` (PR 60): n k = 2 R, so
+    # every sum over a token's assignments is a gather and the masked kernel
+    # ``moe_rows_sum`` (k = 6); no scatter-add
+    assert memory["moe_rows_kernel_calls"] == {
+        "moe_rows_sum": {"calls": 8, "under_moe_sort": 4, "under_moe_combine": 4},
+        "row_gathers": 4 * 5, "row_scatters": 0}
     assert memory["loss_layer_products"] == 3
     assert memory["attention_kernel_calls"] == {
         "splash_mha_fwd_residuals": 1, "splash_mha_dkv_no_residuals": 1}

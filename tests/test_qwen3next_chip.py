@@ -28,6 +28,13 @@ def test_the_whole_step_fits_the_chip_and_runs_the_rule_once_a_layer(
     memory = probe.step_memory(v5e_chip, "qwen3_next_one_chip")
     assert memory["parameters"] == 1_978_847_360
     assert 0.25 < memory["share_of_chip"] and memory["live_bytes"] < 11.0e9, memory
+    # the share's row movements by ``share_gather_fits`` (PR 60): n k = 4 R keeps
+    # the two scatter-adds a mixture layer (two instructions each: the sorted
+    # updates' gather and the segment sum) beside three gathers: the sort's
+    # forward twice under remat, the combine's backward once, in bf16
+    assert memory["moe_rows_kernel_calls"] == {
+        "moe_rows_sum": {"calls": 0, "under_moe_sort": 0, "under_moe_combine": 0},
+        "row_gathers": 8 * 3, "row_scatters": 8 * 2 * 2}
     assert memory["delta_kernel_calls"] == {
         "delta_chunk_fwd": {"calls": 6, "under_delta_core": 6},
         "delta_chunk_bwd": {"calls": 6, "under_delta_core": 6}}

@@ -27,6 +27,12 @@ def test_the_whole_step_fits_the_chip(v5e_chip, monkeypatch):
     assert set(memory["grouped_matmul_tilings"]) == {
         "256,2048,768", "256,768,2048", "256,768,1024", "256,1024,768"}
     assert sum(memory["grouped_matmul_tilings"].values()) == 8 * 12
+    # the share's row movements by ``share_gather_fits`` (PR 60): n k = 2 R, so
+    # every sum over a token's assignments is a gather and the compiler's
+    # sum of 8 rows (``k`` fills a sublane tile); no scatter-add
+    assert memory["moe_rows_kernel_calls"] == {
+        "moe_rows_sum": {"calls": 0, "under_moe_sort": 0, "under_moe_combine": 0},
+        "row_gathers": 8 * 5, "row_scatters": 0}
     assert memory["attention_kernel_calls"] == {
         "splash_mha_fwd_residuals": 8, "splash_mha_dkv_no_residuals": 8}
     # remat keeps the kernel's output and row sums, and the four products

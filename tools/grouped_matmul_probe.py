@@ -10,6 +10,9 @@
     python tools/grouped_matmul_probe.py accuracy          # the program's call against float32
     python tools/grouped_matmul_probe.py rows              # the row movements around it, a pass at a time
     python tools/grouped_matmul_probe.py rows uniform 256 1024 --tokens 16384 --choices 6 --width 2560
+    # a share's row movements (glm-4.7-flash's: 32 of 64 experts held, a buffer of 65,536 rows):
+    python tools/grouped_matmul_probe.py rows --tokens 16384 --choices 4 --width 2048 --groups 64 \
+        --buffer-rows 65536
     # another cell's shapes (here smallthinker-21b-a3b-train-zipf16k's):
     python tools/grouped_matmul_probe.py tiles skewed 256 --rows 98304 --widths 2560x768,768x2560
     # a share's buffer, a width no multiple of the lanes divides (nemotron-labs-twotower's):
@@ -64,7 +67,13 @@ the cell reads 5.4 to 5.9) or ``uniform``.
   have it, with the sum alone in both its forms (the kernel at the token
   blocks given as well) and the backward's scale and dot products alone;
   ms a call, GB/s on the bytes a pass has to move, and each result
-  against the first form's.
+  against the first form's.  With ``--buffer-rows R`` the SHARE path's
+  (``share_sort_tokens`` / ``share_combine``: ``--groups`` experts scored,
+  as many held as make ``R`` twice the level share): the combine's forward,
+  the sort's backward and the combine's backward, each alone, as a
+  scatter-add of ``R`` rows and as a gather of the ``--tokens`` x
+  ``--choices`` assignments' rows and a sum (``share_gather_fits`` is set
+  from these), with the gather and the sum alone.
 - ``accuracy``: ``grouped_matmul`` (the program's call, its tiles) and
   plain ``ragged_dot``, result and both gradients, each against float32
   operands at ``Precision.HIGHEST``: rms of the difference over rms of
@@ -357,6 +366,125 @@ def accuracy(shape) -> None:
         raise SystemExit("grouped_matmul_probe: over 0.5 % from the float32 result")
 
 
+def time_passes(passes, **shape_facts) -> None:
+    """``(what, form, call, arguments, least bytes)`` a pass: compile, time
+    ``ITERS`` launches, and hold each result against the first form's."""
+    import jax
+    import jax.numpy as jnp
+
+    want = {}
+    for what, form, call, args, least in passes:
+        try:
+            compiled = jax.jit(call).lower(*args).compile()
+            got = jax.block_until_ready(compiled(*args))
+            t0 = time.perf_counter()
+            for _ in range(ITERS):
+                out = compiled(*args)
+            jax.block_until_ready(out)
+            ms = (time.perf_counter() - t0) / ITERS * 1e3
+        except Exception as e:  # a block the compiler refuses
+            row(what=what, form=form, refused=f"{type(e).__name__}: {e}"[:300])
+            continue
+        facts = dict(ms=round(ms, 3), gb_s_on_least_bytes=round(least / ms / 1e6, 1))
+        first = want.setdefault(what, got)
+        if first is not got:
+            facts["rms_against_first"] = [
+                float(jnp.sqrt(jnp.mean((a.astype(jnp.float32) - b.astype(jnp.float32)) ** 2)
+                               / jnp.mean(b.astype(jnp.float32) ** 2)))
+                for a, b in zip(jax.tree_util.tree_leaves(got),
+                                jax.tree_util.tree_leaves(first))]
+        row(what=what, form=form, **shape_facts, **facts)
+
+
+def share_rows(shape) -> None:
+    """A share's row movements: each pass as the scatter-add of the buffer's
+    rows and as the gather of the assignments' rows and a sum."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from learning_at_home_tpu.ops import moe_dispatch as md
+    from learning_at_home_tpu.ops import moe_rows
+
+    require_tpu()
+    n, k, d, buffer_rows = shape.tokens, shape.choices, shape.width, shape.buffer_rows
+    scored = shape.groups
+    held = buffer_rows * scored // (2 * n * k)  # the buffer is twice the level share
+    if md.share_buffer_rows(n, k, held, scored) != buffer_rows:
+        raise SystemExit(
+            f"no whole number of {scored} experts makes {buffer_rows} rows "
+            f"twice the level share of {n} x {k} assignments")
+    rs = np.random.default_rng(60)
+    plan = jax.jit(
+        lambda logits: md.share_routing(logits, k, scored // 4, held, buffer_rows)
+    )(jnp.asarray(rs.standard_normal((n, scored)), jnp.float32))
+    bf16 = jnp.bfloat16
+    x = jnp.asarray(rs.standard_normal((n, d)), bf16)
+    ys = jnp.asarray(rs.standard_normal((buffer_rows, d)), bf16)
+    buffer, tokens, picked = buffer_rows * d * 2, n * d * 2, n * k * d * 2  # bytes, bf16
+    row(what="share", tokens=n, choices=k, width=d, buffer_rows=buffer_rows,
+        scored=scored, held=held, routed_here=int(plan.routed_here),
+        dropped=int(plan.routed_here) - int(plan.group_sizes.sum()),
+        rule=md.share_gather_fits(n, k, buffer_rows, d, bf16, "tpu"),
+        sum_kernel=moe_rows.sum_rows_fits(n, k, d, bf16, "tpu"))
+
+    def combine(gathered):
+        return lambda ys, weight: md._share_combine(
+            ys, weight, plan.token, plan.valid, plan.slot, plan.slot_weight,
+            bf16, gathered)
+
+    def parent_combine(ys, weight):
+        return md._share_scatter_add(ys, weight, plan.token, plan.valid, n).astype(bf16)
+
+    def selected_combine(ys, weight):  # the sentinel's other cure: a pass that zeroes what it read
+        return moe_rows.sum_rows(
+            jnp.where(held_here, md._rows_of_slots(ys, plan.slot), 0),
+            plan.slot_weight, n, k, bf16)
+
+    def parent_sort(x):
+        return jnp.where(plan.valid[:, None], x[plan.token], 0)
+
+    def rule_sort(x):
+        return md._share_rows_to_buffer(x, plan.token, plan.valid, plan.slot)
+
+    def back(form):
+        return lambda g, *primals: jax.vjp(form, *primals)[1](g)
+
+    gathered = md._rows_of_slots(ys, plan.slot)
+    flat = plan.slot.reshape(-1)
+    held_here = (flat < buffer_rows)[:, None]
+    time_passes([
+        ("gather_to_buffer", "xla", parent_sort, (x,), tokens + buffer),
+        ("gather_of_slots", "rule", lambda ys: md._rows_of_slots(ys, plan.slot), (ys,),
+         buffer + picked),
+        # every assignment with no row reads the buffer's last
+        ("gather_of_slots", "one_row", lambda ys: ys[jnp.minimum(flat, buffer_rows - 1)],
+         (ys,), buffer + picked),
+        ("gather_of_slots", "selected",
+         lambda ys: jnp.where(held_here, md._rows_of_slots(ys, plan.slot), 0), (ys,),
+         buffer + picked),
+        ("sum", "rule", lambda p, w: moe_rows.sum_rows(p, w, n, k, bf16, masked=True),
+         (gathered, plan.slot_weight), picked + tokens),
+        ("sum", "plain", lambda p, w: moe_rows.sum_rows_plain(p, w, n, k, bf16, masked=True),
+         (gathered, plan.slot_weight), picked + tokens),
+        # the dropless layer's two calls: a weight broadcast over the lanes in the kernel, and none
+        ("sum", "not_masked", lambda p, w: moe_rows.sum_rows(p, w, n, k, bf16),
+         (gathered, plan.slot_weight), picked + tokens),
+        ("sum", "no_weights", lambda p: moe_rows.sum_rows(p, None, n, k, bf16),
+         (gathered,), picked + tokens),
+        ("combine_forward", "scatter_add", parent_combine, (ys, plan.weight), buffer + tokens),
+        ("combine_forward", "gather", combine(True), (ys, plan.weight), buffer + tokens),
+        ("combine_forward", "gather_selected", selected_combine,
+         (ys, plan.weight), buffer + tokens),
+        ("sort_backward", "scatter_add", back(parent_sort), (ys, x), buffer + tokens),
+        ("sort_backward", "gather", back(rule_sort), (ys, x), buffer + tokens),
+        ("combine_backward", "autodiff", back(parent_combine), (x, ys, plan.weight),
+         2 * buffer + tokens),
+        ("combine_backward", "rule", back(combine(False)), (x, ys, plan.weight),
+         2 * buffer + tokens),
+    ], tokens=n, choices=k, width=d, buffer_rows=buffer_rows)
+
+
 def rows(shape) -> None:
     """The sorted layer's row movements, the parent's forms and the rule's."""
     import jax
@@ -400,8 +528,11 @@ def rows(shape) -> None:
 
     def sum_at(tokens):
         def call(picked, weights):
-            moe_rows._TOKENS = tokens
-            return moe_rows.sum_rows_kernel(picked, weights, n, k, bf16)
+            moe_rows._TOKENS = tokens  # read while the call is traced
+            try:
+                return moe_rows.sum_rows_kernel(picked, weights, n, k, bf16)
+            finally:
+                moe_rows._TOKENS = committed
         return call
 
     committed = moe_rows._TOKENS
@@ -420,30 +551,7 @@ def rows(shape) -> None:
         ("combine_backward", "parent", back(parent_combine), (x, ys, weights), 2 * large + small),
         ("combine_backward", "rule", back(combine), (x, ys, weights), 2 * large + small),
     ]
-    want = {}
-    for what, form, call, args, least in passes:
-        try:
-            compiled = jax.jit(call).lower(*args).compile()
-            got = jax.block_until_ready(compiled(*args))
-            t0 = time.perf_counter()
-            for _ in range(ITERS):
-                out = compiled(*args)
-            jax.block_until_ready(out)
-            ms = (time.perf_counter() - t0) / ITERS * 1e3
-        except Exception as e:  # a block the compiler refuses
-            row(what=what, form=form, refused=f"{type(e).__name__}: {e}"[:300])
-            continue
-        finally:
-            moe_rows._TOKENS = committed
-        facts = dict(ms=round(ms, 3), gb_s_on_least_bytes=round(least / ms / 1e6, 1))
-        first = want.setdefault(what, got)
-        if first is not got:
-            facts["rms_against_first"] = [
-                float(jnp.sqrt(jnp.mean((a.astype(jnp.float32) - b.astype(jnp.float32)) ** 2)
-                               / jnp.mean(b.astype(jnp.float32) ** 2)))
-                for a, b in zip(jax.tree_util.tree_leaves(got),
-                                jax.tree_util.tree_leaves(first))]
-        row(what=what, form=form, tokens=n, choices=k, width=d, **facts)
+    time_passes(passes, tokens=n, choices=k, width=d)
 
 
 def main() -> None:
@@ -469,9 +577,11 @@ def main() -> None:
     ap.add_argument("--tokens", type=int, default=16384, help="rows: n")
     ap.add_argument("--choices", type=int, default=8, help="rows: k")
     ap.add_argument("--width", type=int, default=2048, help="rows: d")
+    ap.add_argument("--buffer-rows", type=int, default=None,
+                    help="rows: a share's buffer, R; its passes in place of the dropless layer's")
     shape = ap.parse_args()
     {"tiles": tiles, "gmm": gmm, "sizes": sizes, "accuracy": accuracy,
-     "rows": rows}[shape.command](shape)
+     "rows": share_rows if shape.buffer_rows else rows}[shape.command](shape)
 
 
 if __name__ == "__main__":
