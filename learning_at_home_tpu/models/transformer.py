@@ -30,6 +30,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from learning_at_home_tpu.models.trunk import (
     ATTENTION_PRODUCTS,
     FLASH_RESIDUALS,
+    SHORT_CONV_RESULT,
     attention_core,
     block_diffusion_admitted_pairs,
     block_diffusion_visited_pairs,
@@ -43,6 +44,7 @@ from learning_at_home_tpu.models.trunk import (
     one_query_attention,
     output_projection,
     rms_norm,
+    short_conv_mixer,
     ssm_mixer,
 )
 from learning_at_home_tpu.ops.delta_rule import DELTA_RESIDUALS
@@ -57,7 +59,8 @@ Params = Any
 # stack's layers' readings join in the step's metrics
 _EXTREMES = {"ssm_decay_min": jnp.min, "delta_decay_min": jnp.min,
              "delta_beta_max": jnp.max, "attention_gate_mean": jnp.mean,
-             "shared_gate_mean": jnp.mean, "held_experts_empty": jnp.max}
+             "shared_gate_mean": jnp.mean, "held_experts_empty": jnp.max,
+             "shortconv_out_rms": jnp.min}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -67,9 +70,11 @@ class AttentionLayer:
     earlier key (global), w = the w keys that end with the query's own.
     ``rotary``: whether the layer's queries and keys are rotated.
     ``mixer``: ``'softmax'`` (the causal softmax attention the two fields
-    above describe) or ``'delta'`` (linear attention by the gated delta
+    above describe), ``'delta'`` (linear attention by the gated delta
     rule, ``trunk.delta_mixer``: a recurrent state a head and a short
     convolution in place of scores over the keys, no window, no
+    rotation) or ``'conv'`` (a gated short convolution that is the whole
+    mixer, ``trunk.short_conv_mixer``: no scores, no state, no window, no
     rotation)."""
 
     window: int | None = None
@@ -161,7 +166,15 @@ class DMoETransformerConfig:
     # first 64 of a head rotated (theta 1e7), the output gated / in every
     # layer a shared gated_silu expert of width 512 under a gate of its own
     # beside experts of the same width, 10 of 512 by softmax, renormalised /
-    # dropless, a share held (qwen3_next_one_chip).
+    # dropless, a share held (qwen3_next_one_chip); LFM2-8B-A1B is rmsnorm
+    # (eps 1e-5) / rope (theta 1e6) / C A C C C: a gated short convolution
+    # of 3 taps that is the whole mixer (C * conv(B * u), no bias, no
+    # activation, no state) in three layers of four, 32 heads over 8
+    # key/value heads of 64 with a norm over each head in the fourth / a
+    # dense gated_silu block of width 7,168 in the leading layer, then
+    # gated_silu experts of width 1,792, 4 of 32 by sigmoid scores with a
+    # selection bias, renormalised, every expert held / a tied head
+    # (lfm2_8b_a1b_one_chip).
     # 'layernorm' (scale and bias), 'rmsnorm' (scale only) or
     # 'rmsnorm_offset' (Qwen3-Next: the multiplier is 1 + w, w zero from the
     # seed; every norm of the stack but the delta rule's gate-and-norm)
@@ -309,6 +322,10 @@ class DMoETransformerConfig:
     # DATA tokens of a row, under either objective
     objective: str = "next_token"
     diffusion_block: int = 4
+    # a layer whose AttentionLayer.mixer is 'conv': a causal depthwise
+    # convolution of short_conv_kernel taps over d_model channels between
+    # two gates (LFM2's conv_L_cache)
+    short_conv_kernel: int = 3
 
     def mixture_layers(self) -> int:
         """How many of the stack's layers route (hold a mixture)."""
@@ -389,10 +406,24 @@ class DMoETransformerLM:
             )
         kinds = {config.attention_layer(i) for i in range(config.n_layers)}
         self._delta = any(a.mixer == "delta" for a in kinds)
-        if any(a.mixer not in ("softmax", "delta") for a in kinds):
+        self._conv = any(a.mixer == "conv" for a in kinds)
+        if any(a.mixer not in ("softmax", "delta", "conv") for a in kinds):
             raise ValueError(
-                f"an AttentionLayer's mixer is 'softmax' or 'delta', got "
-                f"{sorted({a.mixer for a in kinds})}"
+                f"an AttentionLayer's mixer is 'softmax' or 'delta' or 'conv', "
+                f"got {sorted({a.mixer for a in kinds})}"
+            )
+        if self._conv and (
+            config.seq_parallel or mesh.devices.size > 1
+            or config.norm_place != "input"
+        ):
+            raise NotImplementedError(
+                "a 'conv' layer on a mesh of several chips, under "
+                "seq_parallel=True (ring attention, parallel/"
+                "ring_attention.py) or behind a norm on a part's output "
+                "(norm_place): the short convolution's taps cross a "
+                "sequence shard's edge and nothing hands the rows before "
+                "it over, its kernel is not partitioned over a mesh, and "
+                "the layer is x + Mixer(norm(x))"
             )
         if self._delta and None in (config.delta_key_dim, config.delta_value_dim):
             raise ValueError(
@@ -463,15 +494,16 @@ class DMoETransformerLM:
             if (
                 config.ffn_pattern is not None or config.mtp_layers
                 or config.router_input != "moe_input" or self._delta
-                or config.norm_place != "input"
+                or self._conv or config.norm_place != "input"
             ):
                 raise ValueError(
                     "mixer_pattern: a layer that is ONE mixer has no "
                     "feed-forward part beside its attention (ffn_pattern), "
                     "no attention input for its router (router_input), "
                     "no next-but-one-token block built of such layers "
-                    "(mtp_layers), no 'delta' layer among its attention "
-                    "layers and its ONE norm on its input (norm_place)"
+                    "(mtp_layers), no 'delta' layer or 'conv' layer among "
+                    "its attention layers and its ONE norm on its input "
+                    "(norm_place)"
                 )
             if config.seq_parallel:
                 raise NotImplementedError(
@@ -541,7 +573,8 @@ class DMoETransformerLM:
         if self._diffusion:
             if (
                 config.positions != "rope" or mixers is not None
-                or self._delta or config.mtp_layers or config.seq_parallel
+                or self._delta or self._conv or config.mtp_layers
+                or config.seq_parallel
                 or any(a.window is not None for a in kinds)
             ):
                 raise NotImplementedError(
@@ -550,7 +583,8 @@ class DMoETransformerLM:
                     "row: no learned positions (a table of seq_len rows), "
                     "no window, no recurrent mixer (mixer_pattern, a "
                     "'delta' layer: a state would run from the noised copy "
-                    "into the clean one), no next-but-one-token block and "
+                    "into the clean one; a 'conv' layer's taps likewise), no "
+                    "next-but-one-token block and "
                     "no ring (seq_parallel)"
                 )
             if config.seq_len % config.diffusion_block:
@@ -762,6 +796,18 @@ class DMoETransformerLM:
                 "w_out": dense(k_out, (d_v, d), pdt),
             }
 
+        def short_conv(key):
+            """The gated short convolution: ``w_in``'s columns are [B | C |
+            u], the filter lecun-normal over its taps, no bias anywhere."""
+            k_in, k_conv, k_out = jax.random.split(key, 3)
+            return {
+                "w_in": dense(k_in, (d, 3 * d), pdt),
+                "conv_w": jax.nn.initializers.lecun_normal(
+                    in_axis=-1, out_axis=-2
+                )(k_conv, (d, cfg.short_conv_kernel), pdt),
+                "w_out": dense(k_out, (d, d), pdt),
+            }
+
         def init_mixer_layer(key, mixer):
             """A layer that is ONE mixer behind ONE norm; what it holds
             says which: ``ssm``, ``wq``.., or ``moe`` (and ``shared``)."""
@@ -782,6 +828,8 @@ class DMoETransformerLM:
             ks = jax.random.split(key, 5)
             if kind.mixer == "delta":  # what the layer holds says so
                 attention = {"delta": delta(ks[0])}
+            elif kind.mixer == "conv":
+                attention = {"conv": short_conv(ks[0])}
             elif cfg.kv_latent_dim is None:
                 attention = {
                     # gated: a head's columns are its query's, then its gate's
@@ -814,7 +862,7 @@ class DMoETransformerLM:
                 lp["ffn"] = dense_block(ks[4], cfg.dense_ffn_dim)
             else:
                 lp.update(mixture(ks[4]))
-            if cfg.qk_norm and kind.mixer != "delta":
+            if cfg.qk_norm and kind.mixer == "softmax":
                 per_head = cfg.qk_norm == "head"
                 lp["q_norm"] = rms(hd if per_head else d_q)
                 lp["k_norm"] = rms(hd if per_head else d_kv)
@@ -895,10 +943,7 @@ class DMoETransformerLM:
             return self._ssm_block(lp, x)
         if one_mixer and "moe" in lp:
             return self._ffn_block(lp, x, None, layer_idx, token_mask)
-        if "delta" in lp:
-            x, attn_in, extremes = self._delta_block(lp, x)
-        else:
-            x, attn_in, extremes = self._attention_part(lp, x, kind)
+        x, attn_in, extremes = self._mixer_part(lp, x, kind)
         if one_mixer:
             return x, None
         x, aux = self._ffn_block(lp, x, attn_in, layer_idx, token_mask)
@@ -944,10 +989,31 @@ class DMoETransformerLM:
             )
         return x + out, {"ssm_decay_min": decay_min}
 
+    def _shortconv_block(self, lp, x):
+        """The stream after the layer's gated short convolution, what the
+        mixer read, and the rms of what it gave (a dead gate reads 0)."""
+        with jax.named_scope("shortconv"):
+            mixer_in = self._norm(lp["ln1"], x)
+            out = short_conv_mixer(lp["conv"], mixer_in)
+            rms = jnp.sqrt(jnp.mean(jnp.square(out.astype(jnp.float32))))
+            x = x + out
+        return x, mixer_in, {"shortconv_out_rms": rms}
+
+    def _mixer_part(self, lp, x, kind: AttentionLayer):
+        """The stream after the layer's token mixer, which its parameters
+        name (``delta``, ``conv``, or softmax attention's projections), the
+        input the mixer read and what the step's metrics keep of it."""
+        if "delta" in lp:
+            return self._delta_block(lp, x)
+        if "conv" in lp:
+            return self._shortconv_block(lp, x)
+        return self._attention_part(lp, x, kind)
+
     def _attention_block(self, lp, x, kind: AttentionLayer):
-        """The stream after the layer's attention, and the input the
-        attention read (a router placed before it reads that)."""
-        return self._attention_part(lp, x, kind)[:2]
+        """The stream after the layer's attention (or the mixer in its
+        place), and the input it read (a router placed before it reads
+        that)."""
+        return self._mixer_part(lp, x, kind)[:2]
 
     def _attention_part(self, lp, x, kind: AttentionLayer):
         """:meth:`_attention_block` and, third, what the step's metrics
@@ -1087,14 +1153,15 @@ class DMoETransformerLM:
             # add) and would otherwise multiply a second time: bf16 [B, S,
             # q + 2 kv + d] a layer, 537 MB in k-exaone for 28 ms (PERF.md
             # section 6, PR 53).  A layer on the xla core keeps its
-            # products too; everything else (norms, the rotation, the
-            # mixers' and the experts' products, the dense blocks) is
-            # recomputed
+            # products too; and a conv mixer's gated convolution, which
+            # its out-projection's backward reads (trunk.SHORT_CONV_RESULT;
+            # PR 61); everything else (norms, the rotation, the mixers' and
+            # the experts' products, the dense blocks) is recomputed
             layer_fn = jax.checkpoint(
                 layer_fn, static_argnums=(4,),
                 policy=jax.checkpoint_policies.save_only_these_names(
                     FLASH_RESIDUALS, SSD_RESIDUALS, DELTA_RESIDUALS,
-                    ATTENTION_PRODUCTS,
+                    ATTENTION_PRODUCTS, SHORT_CONV_RESULT,
                 ),
             )
 
@@ -1306,6 +1373,14 @@ class DMoETransformerLM:
             # buffer and fail at trace time on .at[:, 0]
             return prompt_ids
         if use_cache:
+            if self._conv:
+                raise NotImplementedError(
+                    "use_cache=True with a 'conv' layer: the KV-cache "
+                    "decoder runs softmax attention in every layer; a conv "
+                    "layer's cache (the last short_conv_kernel - 1 positions "
+                    "of B * u a layer) beside the KV cache is not built; "
+                    "decode without the cache"
+                )
             if self._delta or self.cfg.norm_place != "input":
                 raise NotImplementedError(
                     "use_cache=True with a 'delta' layer or a norm on a "

@@ -17,6 +17,7 @@ from jax.ad_checkpoint import checkpoint_name
 
 from learning_at_home_tpu.ops.delta_rule import gated_delta_chunked
 from learning_at_home_tpu.ops.gate_norm import gated_rms_norm
+from learning_at_home_tpu.ops.short_conv import gated_short_conv
 from learning_at_home_tpu.ops.ssd import ssd_chunked
 from learning_at_home_tpu.ops.ssm_conv import causal_conv_silu
 
@@ -423,6 +424,38 @@ def delta_mixer(
     with jax.named_scope("out_proj"):
         out = y @ p["w_out"].astype(x.dtype)
     return out, state, decay_min, beta_max
+
+
+# The name the conv mixer gives the result of its gated convolution, ``C *
+# conv(B * u)`` [B, S, d]: the out-projection's backward reads it.  A
+# ``jax.checkpoint`` whose policy saves this name (the layer's remat:
+# ``DMoETransformerLM._hidden``) keeps it, so the backward pass holds no
+# second call of the forward kernel: 67 MB a layer for 0.28 ms, +0.25 % of
+# LFM2-8B-A1B's step on the chip (PERF.md section 6, PR 61; the Mamba-2
+# mixer's convolution, kept, gave +0.034 % for 0.81 GB and is not: PR 41).
+SHORT_CONV_RESULT = "short_conv_result"
+
+
+def short_conv_mixer(p: dict, x: jax.Array) -> jax.Array:
+    """The gated short convolution that is a layer's WHOLE token mixer
+    (LFM2's ``conv`` layers) on the normalized stream ``x`` [B, S, d]: no
+    scores, no recurrent state, no decay, no norm or gate after it.
+    ``[B | C | u] = x W_in`` (the three thirds in that order, no bias); ``v =
+    B * u``; ``c[t] = sum_j w[:, j] v[t - (K - 1) + j]``, a causal depthwise
+    convolution a channel at a time with NO bias and NO activation, zeros
+    before position 0 of every row; ``out = (C * c) W_out``
+    (:func:`~learning_at_home_tpu.ops.short_conv.gated_short_conv`: on a TPU
+    one pass each way, the three thirds read where the product left them).
+    The parameters say the sizes: the channels are ``w_out``'s input width,
+    the taps ``conv_w``'s second axis.  Sub-scopes ``in_proj``, ``core``,
+    ``out_proj``."""
+    with jax.named_scope("in_proj"):
+        bcu = x @ p["w_in"].astype(x.dtype)
+    with jax.named_scope("core"):
+        y = checkpoint_name(
+            gated_short_conv(bcu, p["conv_w"]), SHORT_CONV_RESULT)
+    with jax.named_scope("out_proj"):
+        return y @ p["w_out"].astype(x.dtype)
 
 
 def causal_attention(

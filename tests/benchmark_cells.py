@@ -113,6 +113,19 @@ def _sdar_lines(setup, counters, reference):
         assert 0.0 <= reference[name] < 1e-4, (name, reference[name])
 
 
+def _lfm2_lines(setup, counters, reference):
+    assert setup["expert_param_bytes"] > 0
+    assert {"dropped_fraction", "expert_load_max_over_mean",
+            "router_bias_abs_max", "shortconv_out_rms"} <= set(counters)
+    assert counters["shortconv_out_rms"]["min"] > 0.05  # no dead gate
+    assert len(reference["shortconv_layers_rms"]) == 2  # C A C at tiny sizes
+    assert len(reference["attention_layers_rms"]) == 1
+    assert reference["near_tie_shares"][0] == 0.0  # the dense layer routes nothing
+    assert len(reference["grad_stream_layers_rms"]) == 4
+    for name in ("grads_rms", "grad_stream_rms", "step_grad_norms", "update_norm"):
+        assert 0.0 <= reference[name] < 1e-4, (name, reference[name])
+
+
 class Row(NamedTuple):
     cell: str
     config: str
@@ -168,6 +181,12 @@ ROWS = (
          "attention_visited_over_admitted", "masked_share"),
         2, _sdar_lines,  # no selection bias: the runner's own set-up phase
         "remake_gates_and_place_experts"),
+    Row("lfm2-8b-a1b-train-zipf16k", "lfm2-8b-a1b", "train-zipf16k", 1,
+        "manifest_lfm2.json", 6100000007, 18,
+        ("mfu", "shortconv_share", "shortconv_proj_share", "shortconv_core_share",
+         "shortconv_core_roofline", "attention_core_roofline",
+         "expert_matmul_roofline", "dense_ffn_share"),
+        ("step_ms_p50", "expert_load_max_over_mean"), 2, _lfm2_lines),
 )
 
 

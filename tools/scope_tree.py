@@ -33,7 +33,9 @@ run's own lines come first (``REFERENCE`` .. ``SCOPES``, the result), then:
   scope (the compiled text's fused computations).  ``--under a/b``
   roots the tree at every path that holds the components ``a/b`` (as the
   runners' tables match a name anywhere in a path: the prediction block's
-  ``mtp/attention`` with the stack's ``attention``).
+  ``mtp/attention`` with the stack's ``attention``); a mixer's scope
+  likewise: ``--under delta/core``, ``--under ssm``, ``--under shortconv``
+  (the conv mixer's three parts and the norm and add beside them, PR 61).
 
 The instructions are written to ``chiprun_out/scope_tree.<cell>.<seed>.json``;
 ``--from`` renders such a file again without a run.

@@ -7,6 +7,7 @@
     chiprun -- python tools/smallthinker_probe.py float8 [config.json] [seed ...]
     chiprun -- python tools/smallthinker_probe.py ssd [seq_len] [accuracy_len]
     chiprun -- python tools/smallthinker_probe.py conv [rows x channels ...]
+    chiprun -- python tools/smallthinker_probe.py conv gated [rows x channels x strip ...]
     chiprun -- python tools/smallthinker_probe.py gate_norm [rows x strip ...]
     chiprun -- python tools/smallthinker_probe.py delta [seq_len] [accuracy_len] [calls] [form ...] [config ...]
     chiprun -- python tools/smallthinker_probe.py delta split [seq_len] [chunk]
@@ -68,6 +69,12 @@ the time), GB/s on the LEAST bytes a pass moves (``x`` in and ``y`` out;
 ``x`` and ``dy`` in and ``dx`` out), and the kernel's output and three
 gradients against the plain form's.  ``512x1024``-like arguments time the
 kernel at those blocks (rows x channels) too (PERF.md section 6, PR 41).
+``conv gated``: the same for the gated short convolution that is LFM2's
+whole mixer (``ops/short_conv.py``: ``short_conv_fwd`` / ``short_conv_bwd``) at
+its cell's shape (``[1, 16384, 3 x 2048]`` bf16, three taps), the least
+bytes ``[B | C | u]`` in and ``y`` out forward and those, ``dy`` in and ``d[B
+| C | u]`` out backward; blocks as ``512x512x32`` (rows x channels x strip;
+PERF.md section 6, PR 61).
 
 ``gate_norm`` (on the chip): the mixers' gate and grouped RMSNorm
 (``ops/gate_norm.py``) as the two hybrid cells call it (Nemotron: ``[1,
@@ -627,6 +634,64 @@ def conv(blocks: list, calls: int = 20) -> None:
         }), flush=True)
 
 
+def conv_gated(blocks: list, calls: int = 20) -> None:
+    """``conv gated [rows x channels x strip ...]``: the gated short
+    convolution that is LFM2's conv mixer (``ops/short_conv.py``) at the
+    cell's shape ``[1, 16384, 3 x 2048]`` bf16, 3 taps: the plain form
+    against the kernel at its own blocks and at each named."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    import harness
+    from learning_at_home_tpu.ops import short_conv as ops
+
+    config = harness.load_json(os.path.join(
+        REPO, "benchmarks/configs/lfm2-8b-a1b.json"))
+    s, c, taps = config["seq_len"], config["hidden_size"], config["conv_L_cache"]
+    rs = np.random.default_rng(4100000007)
+    bcu = jnp.asarray(rs.standard_normal((1, s, 3 * c)), jnp.bfloat16)
+    dy = jnp.asarray(rs.standard_normal((1, s, c)), jnp.bfloat16)
+    w = jnp.asarray(0.5 * rs.standard_normal((c, taps)), jnp.float32)
+
+    def both(form):
+        def fn(bcu, w, dy):
+            y, back = jax.vjp(form, bcu, w)
+            return (y, *back(dy))
+        return jax.jit(fn)
+
+    least = dy.size * dy.dtype.itemsize  # one pass over [1, S, C] bf16
+    want = None
+    own = (ops._ROWS, ops._CHANNELS, ops._STRIP)
+    forms = [("plain", ops.gated_short_conv_plain, None),
+             ("kernel", ops.gated_short_conv_kernel, own)] + [
+        ("kernel", ops.gated_short_conv_kernel,
+         tuple(int(n) for n in a.split("x"))) for a in blocks]
+    for name, form, at in forms:
+        if at:
+            ops._ROWS, ops._CHANNELS, ops._STRIP = at
+        try:
+            got = jax.device_get(both(form)(bcu, w, dy))
+            forward = _ms(jax.jit(form), (bcu, w), calls)
+            forward_backward = _ms(both(form), (bcu, w, dy), calls)
+        except Exception as e:  # a block the compiler refuses
+            print("CONV " + json.dumps({"form": name, "blocks": at,
+                                        "refused": str(e)[:300]}), flush=True)
+            continue
+        want = want or got
+        print("CONV " + json.dumps({
+            "form": name, "blocks": at, "shape": list(bcu.shape), "taps": taps,
+            "forward_ms": forward, "forward_backward_ms": forward_backward,
+            # [B | C | u] read and y written; those, dy and d[B | C | u]
+            "forward_gb_s_on_least_bytes": 4 * least / forward / 1e6,
+            "forward_backward_gb_s_on_least_bytes":
+                11 * least / forward_backward / 1e6,
+            "rms_against_plain": {
+                k: _rel_rms(a, b_) for k, a, b_ in zip(("y", "dbcu", "dw"), got, want)},
+        }), flush=True)
+    ops._ROWS, ops._CHANNELS, ops._STRIP = own
+
+
 def gate_norm(blocks: list, calls: int = 20) -> None:
     import jax
     import jax.numpy as jnp
@@ -870,6 +935,8 @@ def delta_split(seq_len: int = 16384, chunk: int = 64, calls: int = 5) -> None:
 if __name__ == "__main__":
     if sys.argv[1:2] == ["memory"]:
         memory(*sys.argv[2:3])
+    elif sys.argv[1:3] == ["conv", "gated"]:
+        conv_gated(sys.argv[3:])
     elif sys.argv[1:2] == ["conv"]:
         conv(sys.argv[2:])
     elif sys.argv[1:2] == ["gate_norm"]:
