@@ -88,11 +88,16 @@ float32 has; inside blocks of 16 it still took five of the seven where
 ``a`` nears 2, and the cell's comparison read it (PERF.md section 6, PR
 45).  So the inverses of the diagonal blocks of :data:`SOLVE_BLOCK`
 positions are made by forward substitution, a row at a time (15 small
-steps for all blocks at once: of all chunks in the plain form, of a frame
-in the kernel, there on the VPU), and plain forward substitution by blocks
-joins them (:func:`solve_unit_lower`, ``how="blocks"``; the kernel solves
-for the identity, the blocks doubling from 16 to the chunk:
-:func:`_unit_lower_inverse`): backward-stable whatever the keys.
+steps for all blocks at once: of all chunks in the plain form; of a frame
+in the kernel, there on the VPU with the frame's eight blocks side by side
+on the lanes of two vector registers, :func:`_block_inverses`), and plain
+forward substitution by blocks joins them (:func:`solve_unit_lower`,
+``how="blocks"``; the kernel solves for the identity, the blocks doubling
+from 16 to the chunk, each join two products at the highest precision over
+the half of the frame's rows that it changes: :func:`_unit_lower_inverse`,
+:func:`_join`): backward-stable whatever the keys.  The kernel's operands
+leave out the inverse's structural zeros and nothing else: an entry's
+arithmetic is the same, and so are its bits (PERF.md section 6, PR 62).
 ``"product"`` (the whole chunk at once) and ``"triangular"``
 (``jax.scipy.linalg.solve_triangular``) are there for the probe that
 times and checks the ways on the chip (tools/smallthinker_probe.py delta).
@@ -424,43 +429,86 @@ def _last_row(col: jax.Array, width: int) -> jax.Array:
     return jnp.broadcast_to(col, (c, width))[c - 1:c, :]
 
 
+def _block_inverses(a: jax.Array, i: jax.Array, j: jax.Array, block: int):
+    """The inverses of ``I + a``'s diagonal blocks of ``block`` positions,
+    on the diagonal of [F, F] and zeros elsewhere: forward substitution on
+    the identity, a row at a time, in the PACKED layout [block, F]: row
+    ``r`` of block ``b`` in lanes ``b block`` onwards, every lane in use, so
+    a frame of 128 in blocks of 16 is two vector registers and a step two
+    multiply-subtracts against a sublane broadcast of row ``step``.  The
+    multiplier of a step is column ``step`` of each block spread over that
+    block's lanes: it reads ``a`` alone, so all of them are made before the
+    chain starts, ONE gather along the lanes each (exact: it moves entries
+    and computes nothing; a chain of rolls costs its depth in the lane
+    unit's latency: PERF.md section 6, PR 62)."""
+    frame = a.shape[0]
+    rows = min(8, block)  # a sublane tile: one whose rows are all final is left alone
+    lane = j[:rows]
+    row = jax.lax.broadcasted_iota(jnp.int32, (rows, frame), 0)
+    first = lane - lane % block  # the first lane of a lane's block
+    x, multipliers = [], []
+    for r in range(0, block, rows):
+        # rows r.. of every block side by side: the last block's, and over
+        # each earlier block's lanes that block's own rows
+        packed = a[frame - block + r:frame - block + r + rows]
+        for n in reversed(range(0, frame - block, block)):
+            packed = jnp.where(first == n, a[n + r:n + r + rows], packed)
+        # the steps whose row lies above this tile's last: `a` is strictly
+        # lower, so a row at or above `step` has a zero multiplier
+        multipliers.append([
+            jnp.take_along_axis(
+                packed, first + step, axis=1, mode="promise_in_bounds")
+            for step in range(min(r + rows, block) - 1)])
+        x.append(jnp.where(lane - first == row + r, 1.0, 0.0))
+    for step in range(block - 1):
+        # the rows below `step` of every block lose a[., step] times row
+        # `step`, which is final (above its diagonal `a` is zero)
+        pivot = jnp.broadcast_to(
+            x[step // rows][step % rows:step % rows + 1, :], (rows, frame))
+        for t in range((step + 1) // rows, len(x)):
+            x[t] = x[t] - multipliers[t][step] * pivot
+    return jnp.where(
+        i // block == j // block,
+        jnp.concatenate(x * (frame // block), axis=0), 0.0)
+
+
+def _join(x: jax.Array, a: jax.Array, i: jax.Array, j: jax.Array, width: int):
+    """``(I + a)^-1`` inside diagonal blocks of ``2 width`` positions from
+    ``x``, the same inside blocks of ``width``: ``X_21 = -T_22 A_21 T_11``.
+    ``x`` is block-diagonal and ``A_21`` lies under the diagonal inside a
+    pair, so the rows of a pair's FIRST block stay as they are: the two
+    products at the highest precision take the second blocks' rows alone
+    (whole sublane tiles, half the frame), the same dot products in the
+    same order for every entry that is not a structural zero."""
+    frame = a.shape[0]
+    below = jnp.where(
+        (i // width == j // width + 1) & (i // (2 * width) == j // (2 * width)),
+        a, 0.0)
+    seconds = range(width, frame, 2 * width)
+    second = jnp.concatenate([x[n:n + width] for n in seconds], axis=0)
+    second = second - _dot(
+        _dot(second, below, precision=_HIGHEST), x, precision=_HIGHEST)
+    return jnp.concatenate([
+        rows for m, n in enumerate(seconds)
+        for rows in (x[n - width:n], second[m * width:(m + 1) * width])], axis=0)
+
+
 def _unit_lower_inverse(a: jax.Array, i: jax.Array, j: jax.Array, c: int):
     """``(I + a)^-1`` of ``a`` [F, F] float32, strictly lower-triangular
     inside diagonal blocks of ``c`` positions and zero outside them, by
     forward substitution on the identity, as :func:`solve_unit_lower`'s
     ``blocks``: a row at a time inside the diagonal blocks of
-    :data:`SOLVE_BLOCK` positions (all of them at once, on the VPU: 15
-    steps over sublane tiles of 8 rows), then by blocks between them
-    (``X_2 = -T_22 A_21 T_11``: two products at the highest precision a
-    join), the block doubling up to ``c``.  No partial result is larger than the inverse's own entries."""
-    frame = a.shape[0]
-    block = min(SOLVE_BLOCK, c)
-    blocks = range(frame // block)
-    # the diagonal blocks, one under the other: [F, block]
-    diag = jnp.concatenate(
-        [a[b * block:(b + 1) * block, b * block:(b + 1) * block] for b in blocks],
-        axis=0)
-    eye = jnp.where(i == j, 1.0, 0.0).astype(jnp.float32)  # block-diagonal all along
-    # a sublane tile of 8 rows at a time: one whose rows are all final is
-    # left alone (a quarter of the work, and no array is cut and joined a step)
-    tiles = [eye[r:r + 8, :] for r in range(0, frame, 8)]
-    per = block // 8
-    for step in range(block - 1):
-        # the rows below `step` of every block lose a[., step] times row
-        # `step`, which is final (above its diagonal `a` is zero)
-        for b in blocks:
-            row = jnp.broadcast_to(
-                tiles[b * per + step // 8][step % 8:step % 8 + 1, :], (8, frame))
-            for tile in range(b * per + (step + 1) // 8, (b + 1) * per):
-                tiles[tile] = tiles[tile] - diag[
-                    tile * 8:(tile + 1) * 8, step:step + 1] * row
-    x = jnp.concatenate(tiles, axis=0)
-    width = block
+    :data:`SOLVE_BLOCK` positions (:func:`_block_inverses`: all of them at
+    once, side by side on full lanes), then by blocks between them
+    (:func:`_join`: two products at the highest precision a join, over the
+    rows a join changes), the block doubling up to ``c``.  The arithmetic
+    of an entry is the plain substitution's; no operand carries its
+    structural zeros.  No partial result is larger than the inverse's own
+    entries."""
+    width = min(SOLVE_BLOCK, c)
+    x = _block_inverses(a, i, j, width)
     while width < c:  # blocks of `width` are inverted: join them two and two
-        below = jnp.where(
-            (i // width == j // width + 1) & (i // (2 * width) == j // (2 * width)),
-            a, 0.0)
-        x = x - _dot(_dot(x, below, precision=_HIGHEST), x, precision=_HIGHEST)
+        x = _join(x, a, i, j, width)
         width *= 2
     return x
 

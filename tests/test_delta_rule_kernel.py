@@ -179,6 +179,72 @@ def test_the_kernels_inverse_holds_where_every_entry_nears_two():
     assert np.array_equal(np.asarray(inverse)[64:, :64], np.zeros((64, 64)))
 
 
+def _parents_inverse(a, i, j, c):
+    """``_unit_lower_inverse`` as PR 61 had it (d38e7a2), the reference of
+    the case below: the identity as 16 tiles of [8, 128], a block's rows
+    in its own 16 lanes and zeros in the other 112, and joins that
+    multiply all 128 rows."""
+    frame = a.shape[0]
+    block = min(delta_rule.SOLVE_BLOCK, c)
+    blocks = range(frame // block)
+    diag = jnp.concatenate(
+        [a[b * block:(b + 1) * block, b * block:(b + 1) * block] for b in blocks],
+        axis=0)
+    eye = jnp.where(i == j, 1.0, 0.0).astype(jnp.float32)
+    tiles = [eye[r:r + 8, :] for r in range(0, frame, 8)]
+    per = block // 8
+    for step in range(block - 1):
+        for b in blocks:
+            row = jnp.broadcast_to(
+                tiles[b * per + step // 8][step % 8:step % 8 + 1, :], (8, frame))
+            for tile in range(b * per + (step + 1) // 8, (b + 1) * per):
+                tiles[tile] = tiles[tile] - diag[
+                    tile * 8:(tile + 1) * 8, step:step + 1] * row
+    x = jnp.concatenate(tiles, axis=0)
+    width = block
+    highest = jax.lax.Precision.HIGHEST
+    while width < c:
+        below = jnp.where(
+            (i // width == j // width + 1) & (i // (2 * width) == j // (2 * width)),
+            a, 0.0)
+        x = x - delta_rule._dot(
+            delta_rule._dot(x, below, precision=highest), x, precision=highest)
+        width *= 2
+    return x
+
+
+@pytest.mark.parametrize("entries", ["as-the-rule-makes-them", "every-entry-near-two"])
+@pytest.mark.parametrize("c", [16, 32, 64, 128])
+def test_the_packed_inverse_is_the_parents_to_the_bit(c, entries):
+    """The frame's inverse from operands without their structural zeros
+    (the 16-blocks side by side on full lanes, the joins over the rows a
+    join changes) against the parent's body: BIT FOR BIT, at every chunk
+    the grid admits, where ``A`` is the rule's own (unit keys, strengths
+    up to 2, decays) and where every entry under the diagonal is 1.8 to 2.
+    The multipliers are made by gathers along the lanes, which move
+    entries and compute nothing, so the CPU's bits are held to as the
+    chip's are."""
+    rs = np.random.RandomState(c)
+    frame = delta_rule.FRAME
+    i, j = np.indices((frame, frame))
+    if entries == "every-entry-near-two":
+        a = rs.uniform(1.8, 2.0, (frame, frame))
+    else:
+        k = rs.randn(frame, DK)
+        k /= np.linalg.norm(k, axis=-1, keepdims=True)
+        gamma = np.cumsum(-0.5 * rs.uniform(size=(frame // c, c)), axis=1).reshape(-1, 1)
+        a = 2.0 * rs.uniform(size=(frame, 1)) * (k @ k.T) * np.exp(
+            np.minimum(gamma - gamma.T, 0.0))
+    a = jnp.asarray(np.where((i // c == j // c) & (i > j), a, 0.0), jnp.float32)
+    i, j = jnp.asarray(i, jnp.int32), jnp.asarray(j, jnp.int32)
+    got = np.asarray(delta_rule._unit_lower_inverse(a, i, j, c))
+    want = np.asarray(_parents_inverse(a, i, j, c))
+    assert np.abs(want - np.eye(frame)).max() > 0.1 and np.all(np.isfinite(want))
+    assert np.array_equal(got, want)
+    assert np.array_equal(got[(i // c != j // c) | (i < j)], np.zeros(
+        int(((i // c != j // c) | (i < j)).sum()), np.float32))
+
+
 def test_a_call_the_kernel_cannot_take_returns_the_plain_forms_bits(monkeypatch):
     """``gated_delta_chunked`` is the plain form on the CPU (its bits),
     and on a TPU under a ``decay_dtype`` other than float32, under another

@@ -114,12 +114,14 @@ def test_a_checkpoint_that_keeps_the_residuals_runs_the_forward_kernel_once():
 
 
 # sha256 of ``jax.jit(gated_delta_kernel under interpret).lower(..).as_text()``
-# of a call that is not differentiated, taken on PR 58's PARENT (793a7a5):
-# keeping a state a grid step and naming the residuals changes the
-# differentiated forward alone
+# of a call that is not differentiated: keeping a state a grid step and
+# naming the residuals changes the differentiated forward alone.  Taken on
+# PR 58's PARENT (793a7a5) and unmoved until PR 62 rewrote the frame's
+# inverse (``_unit_lower_inverse``: the same bits from other operations,
+# ``tests/test_delta_rule_kernel.py``), which moved the text; taken again there
 FORWARD_ONLY_SHA256 = {
-    False: "0e267b6b6dd806571bb0d4cb47c2eb4c8fb63aeeb2bf57db23e177ac837c6dbb",
-    True: "c02f6579e153011b294e24f10473fe1a205e159da51e2535d45df8ac3a3da11d",
+    False: "3e6eb09e676e24b6569ee366f919b1eeb35e70075d86eb944cd162e23d1b7e12",
+    True: "1cabe0f9f578fa8c65a06cd296149c690e919686120b3d3fdf099346a276c463",
 }
 
 

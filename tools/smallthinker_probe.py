@@ -11,6 +11,7 @@
     chiprun -- python tools/smallthinker_probe.py gate_norm [rows x strip ...]
     chiprun -- python tools/smallthinker_probe.py delta [seq_len] [accuracy_len] [calls] [form ...] [config ...]
     chiprun -- python tools/smallthinker_probe.py delta split [seq_len] [chunk]
+    chiprun -- python tools/smallthinker_probe.py delta parts [seq_len] [chunk] [calls] [config ...]
 
 ``memory`` (here, no chip): the whole train step of a recipe of
 ``__graft_entry__`` (``smallthinker_one_chip``, or the one named, such as
@@ -107,7 +108,14 @@ at a time in float32 (PERF.md section 6, PR 45 and PR 46).  ``delta split
 [seq_len] [chunk]``: where the PLAIN rule's time goes (the whole rule, the
 solve alone, the diagonal blocks' inverses alone, the ``lax.scan`` over
 the chunks alone, the rule with its solve stubbed out): the reading the
-kernel's design started from (PR 46).
+kernel's design started from (PR 46).  ``delta parts [seq_len] [chunk]
+[calls] [config ...]``: where the KERNELS' time goes, by ablation: the
+calls whole, then with one part replaced by a stand-in of this file at a
+time (the 16-blocks' row steps, the joins, ``W`` and ``U``'s exact
+products, the backward's four products through the solve, the state's
+chain: :func:`_delta_stand_ins`), forward, forward + backward and the
+backward call alone, and the digest of the whole calls' results (PERF.md
+section 6, PR 62).
 """
 
 import collections
@@ -775,6 +783,30 @@ def _delta_shape(config: str) -> tuple:
             cell["linear_value_head_dim"])
 
 
+def _delta_inputs(s: int, h: int, dk: int, dv: int, dtype) -> tuple:
+    """``(q, k, v, g, beta)`` as the mixer hands them to the rule: SiLU'd,
+    unit-length keys and queries (they share a positive mean: the solve's
+    hard case), strengths in (0, 2), decays as layer 0's seeded weights
+    give."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    rs = np.random.default_rng(4500000007)
+
+    def unit(a):
+        a = a * (1.0 / (1.0 + np.exp(-a)))
+        return a / np.sqrt(np.sum(a * a, axis=-1, keepdims=True) + 1e-6)
+
+    g = -rs.uniform(1, 16, h) * np.exp(rs.uniform(
+        np.log(1e-3), np.log(1e-1), (1, s, h)))
+    return (jnp.asarray(unit(rs.standard_normal((1, s, h, dk))) / dk ** 0.5, dtype),
+            jnp.asarray(unit(rs.standard_normal((1, s, h, dk))), dtype),
+            jnp.asarray(rs.standard_normal((1, s, h, dv)), dtype),
+            jnp.asarray(g, jnp.float32),
+            jnp.asarray(2.0 / (1.0 + np.exp(-rs.standard_normal((1, s, h)))),
+                        jnp.float32))
+
+
 def delta(seq_len: int = 16384, accuracy_len: int = 1024, calls: int = 5,
           forms=("kernel", "blocks", "product", "triangular"),
           config: str = "olmo-hybrid-7b") -> None:
@@ -788,22 +820,7 @@ def delta(seq_len: int = 16384, accuracy_len: int = 1024, calls: int = 5,
     f32 = jnp.float32
 
     def inputs(s, dtype):
-        """As the mixer hands them over: SiLU'd, unit-length keys and
-        queries (they share a positive mean: the solve's hard case),
-        strengths in (0, 2), decays as layer 0's seeded weights give."""
-        rs = np.random.default_rng(4500000007)
-
-        def unit(a):
-            a = a * (1.0 / (1.0 + np.exp(-a)))
-            return a / np.sqrt(np.sum(a * a, axis=-1, keepdims=True) + 1e-6)
-
-        g = -rs.uniform(1, 16, h) * np.exp(rs.uniform(
-            np.log(1e-3), np.log(1e-1), (1, s, h)))
-        return (jnp.asarray(unit(rs.standard_normal((1, s, h, dk))) / dk ** 0.5, dtype),
-                jnp.asarray(unit(rs.standard_normal((1, s, h, dk))), dtype),
-                jnp.asarray(rs.standard_normal((1, s, h, dv)), dtype),
-                jnp.asarray(g, f32),
-                jnp.asarray(2.0 / (1.0 + np.exp(-rs.standard_normal((1, s, h)))), f32))
+        return _delta_inputs(s, h, dk, dv, dtype)
 
     def loss_of(form, s):
         rs = np.random.default_rng(45)
@@ -932,6 +949,126 @@ def delta_split(seq_len: int = 16384, chunk: int = 64, calls: int = 5) -> None:
     print("DELTA_SPLIT " + json.dumps(line), flush=True)
 
 
+class _Unchained:
+    """A kernel's scratch Ref of carried states whose reads of ONE state
+    (``ref[h]``, ``ref[n, h]``) give zeros: every write stays, and every
+    product that read a state is still made, but no chunk waits for the
+    chunk before it.  Whole reads and all writes go to the Ref."""
+
+    def __init__(self, ref):
+        self.ref, self.shape, self.dtype = ref, ref.shape, ref.dtype
+
+    def __getitem__(self, at):
+        import jax.numpy as jnp
+
+        if at is Ellipsis:
+            return self.ref[...]
+        at = at if isinstance(at, tuple) else (at,)
+        return jnp.zeros(self.shape[len(at):], self.dtype)
+
+    def __setitem__(self, at, value):
+        self.ref[at] = value
+
+
+def _delta_stand_ins(ops) -> dict:
+    """``{part: {a name of ops/delta_rule.py: its stand-in}}``: what
+    ``delta parts`` swaps in, a part at a time.  Each stand-in still reads
+    what the part read (no other part falls dead with it) and costs next to
+    nothing beside it: the blocks' inverses as ``I - a`` inside the blocks
+    (no row step), a join that joins nothing, and ONE bf16 pass in place of
+    ``W`` and ``U``'s three and of each of the backward's four products at
+    the highest precision (the pass stays, what the precision adds goes);
+    the chain's reads of a carried state as zeros (:class:`_Unchained`)."""
+    import jax.numpy as jnp
+
+    bf16 = jnp.bfloat16
+    dot = ops._dot
+    plain_dims = inspect.signature(dot).parameters["dims"].default
+
+    def unsolved_blocks(a, i, j, block):
+        return jnp.where(
+            i // block == j // block, jnp.where(i == j, 1.0, 0.0) - a, 0.0)
+
+    def solve_products_in_one_pass(a, b, dims=plain_dims, precision=None):
+        if precision is None or dims == plain_dims:  # a join's, or not the highest
+            return dot(a, b, dims, precision)
+        return dot(a.astype(bf16), b.astype(bf16), dims)
+
+    def unchained(kernel, carried: int):
+        def stub(*refs, **static):
+            return kernel(*refs[:-carried], *map(_Unchained, refs[-carried:]),
+                          **static)
+        return stub
+
+    return {
+        "row_steps": {"_block_inverses": unsolved_blocks},
+        "joins": {"_join": lambda x, a, i, j, width: x},
+        "w_u": {"_exact_dot": lambda a, b: dot(a.astype(bf16), b.astype(bf16))},
+        "solve_products": {"_dot": solve_products_in_one_pass},
+        "chain": {"_fwd_kernel": unchained(ops._fwd_kernel, 1),
+                  "_bwd_kernel": unchained(ops._bwd_kernel, 2)},
+    }
+
+
+def delta_parts(seq_len: int = 16384, chunk: int = 64, calls: int = 10,
+                config: str = "olmo-hybrid-7b") -> None:
+    """The kernels' calls at a configuration's shape, whole and with one
+    part stood in for at a time: ms forward, forward + backward and the
+    backward call alone; ``saves_ms`` is the whole call less that."""
+    import hashlib
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from learning_at_home_tpu.ops import delta_rule as ops
+
+    h, dk, dv = _delta_shape(config)
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    rs = np.random.default_rng(6200000007)
+    args = _delta_inputs(seq_len, h, dk, dv, bf16)
+    cotangents = (jnp.asarray(rs.standard_normal((1, seq_len, h, dv)), bf16),
+                  jnp.asarray(rs.standard_normal((1, h, dk, dv)), f32))
+
+    def read() -> dict:
+        def form(*a):  # made anew a reading: what it traces is what stands in ops now
+            return ops.gated_delta_kernel(*a, chunk)
+
+        def loss(*a):
+            o, final = form(*a)
+            return (jnp.sum(o.astype(f32) * cotangents[0].astype(f32))
+                    + jnp.sum(final * cotangents[1]))
+
+        both = jax.jit(lambda *a: (*form(*a), *jax.grad(
+            loss, argnums=(0, 1, 2, 3, 4))(*a)))
+        pullback = jax.jit(lambda *a: jax.vjp(form, *a)[1])(*args)
+        digest = hashlib.sha256()
+        for result in jax.device_get(both(*args)):
+            digest.update(np.asarray(result).tobytes())
+        return {"forward_ms": _ms(jax.jit(form), args, calls),
+                "forward_backward_ms": _ms(both, args, calls),
+                "backward_alone_ms": _ms(
+                    jax.jit(lambda pull, ct: pull(ct)), (pullback, cotangents), calls),
+                "sha256_of_the_results": digest.hexdigest()[:16]}
+
+    line = {"shape": [1, seq_len, h, dk, dv], "chunk": chunk, "calls": calls}
+    whole = read()
+    print("DELTA_PARTS " + json.dumps({**line, "stood_in": None, **whole}), flush=True)
+    for part, stand_ins in _delta_stand_ins(ops).items():
+        real = {name: getattr(ops, name) for name in stand_ins}
+        for name, stand_in in stand_ins.items():
+            setattr(ops, name, stand_in)
+        try:
+            got = read()
+        finally:
+            for name, fn in real.items():
+                setattr(ops, name, fn)
+        del got["sha256_of_the_results"]  # a stand-in's results mean nothing
+        print("DELTA_PARTS " + json.dumps({
+            **line, "stood_in": part, **got, "saves_ms": {
+                k: whole[k] - v for k, v in got.items()}}), flush=True)
+
+
 if __name__ == "__main__":
     if sys.argv[1:2] == ["memory"]:
         memory(*sys.argv[2:3])
@@ -945,6 +1082,9 @@ if __name__ == "__main__":
         ssd(*(int(a) for a in sys.argv[2:4]))
     elif sys.argv[1:3] == ["delta", "split"]:
         delta_split(*(int(a) for a in sys.argv[3:5]))
+    elif sys.argv[1:3] == ["delta", "parts"]:
+        for config in [a for a in sys.argv[3:] if not a.isdigit()] or ["olmo-hybrid-7b"]:
+            delta_parts(*(int(a) for a in sys.argv[3:] if a.isdigit()), config=config)
     elif sys.argv[1:2] == ["delta"]:
         named = [a for a in sys.argv[2:] if not a.isdigit()]
         configs = [a for a in named if os.path.exists(os.path.join(
