@@ -15,6 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
+from learning_at_home_tpu.ops.band_attention import band_attention, band_kernel_fits
 from learning_at_home_tpu.ops.delta_rule import gated_delta_chunked
 from learning_at_home_tpu.ops.gate_norm import gated_rms_norm
 from learning_at_home_tpu.ops.short_conv import gated_short_conv
@@ -520,7 +521,10 @@ _FLASH_TILES_256 = dict(_FLASH_TILES, block_kv_compute=256)
 # faster than 1024-wide ones.  A query block then visits its own key block
 # and the one before under ANY window up to the block, so the reading at
 # 128 is the reading up to 512.  Measured at windows of 128 and 4,096 and
-# S 16,384; nothing between.
+# S 16,384; nothing between.  Since PR 63 this regime is what a short
+# window gets that the band kernel refuses (``band_kernel_fits``: windows
+# of 513 to 1,023, heads of 64 or 256, a group it was not run at): no cell
+# of the benchmark.
 _FLASH_WINDOW_TILE = 512
 # The name the kernel's forward gives its output [H, S, hd] and its
 # float32 row sums (logsumexp [H, S]): the two arrays its backward kernels
@@ -679,10 +683,14 @@ def attention_core(
     [B,S,Hkv,hd] — shared by :func:`causal_attention` and the KV-cache
     decoder's prefill so the two paths cannot diverge numerically per
     ``impl``.  Both cores take bf16 operands to float32 scores, softmax
-    and accumulators; ``flash`` is the kernel where
-    :func:`flash_block_sizes` has tiles for the call and ``xla`` where it
-    has none.  Both take fewer key/value heads than query heads as they
-    come (query head h reads key/value head ``h // (H / Hkv)``; no copy
+    and accumulators; ``flash`` is a kernel where one takes the call and
+    ``xla`` where none does: the band kernel
+    (:func:`~learning_at_home_tpu.ops.band_attention.band_attention`)
+    where :func:`band_kernel_fits` says so (a window shorter than the
+    blocked kernel's key block, on the arrays as they come), else the
+    blocked kernel where :func:`flash_block_sizes` has tiles.  All take
+    fewer key/value heads than query heads as they come (query head h
+    reads key/value head ``h // (H / Hkv)``; no copy
     of K or V is made), and a ``window``: query i sees the ``window``
     keys that end with its own, ``i - window < j <= i``.  With
     ``diffusion_block`` the mask is neither: the row is a doubled one,
@@ -696,10 +704,12 @@ def attention_core(
             "diffusion_block masks a doubled row (an even length) and takes "
             f"no window, got length {q.shape[1]} and window={window}"
         )
-    sizes = (
-        flash_block_sizes(q.shape, jax.default_backend(), window)
-        if impl == "flash" else None
-    )
+    backend = jax.default_backend()
+    if impl == "flash" and band_kernel_fits(q.shape, k.shape[2], window, backend):
+        # the arrays as they come: no scope ``flash/layout`` on this path
+        with jax.named_scope("flash"):
+            return band_attention(q, k, v, window, FLASH_RESIDUALS)
+    sizes = flash_block_sizes(q.shape, backend, window) if impl == "flash" else None
     if sizes is not None:
         from jax.experimental.pallas.ops.tpu import splash_attention as splash
 

@@ -78,7 +78,14 @@ def test_tiles_read_the_window(window, seq, head):
     field (fused backward, six tiles).  A shorter window: key blocks that
     cover it, no narrower than the measured tile and no wider than the
     parent's, and an unfused backward with its dQ kernel's tiles (eight
-    tiles).  Neither the batch nor the head count is read."""
+    tiles).  Neither the batch nor the head count is read.  Since PR 63
+    the short-window tiles are what a call gets that the band kernel does
+    not take: the core asks ``band_kernel_fits`` first, which takes the
+    windows up to 512 at heads of 128 over lengths its block of 512
+    divides (K-EXAONE's window layers: 128 at 16,384)."""
+    assert trunk.band_kernel_fits((1, seq, 64, head), 8, window, "tpu") is (
+        window in (128, 256) and head == 128)
+    assert not trunk.band_kernel_fits((1, seq, 64, head), 8, window, "cpu")
     sizes = trunk.flash_block_sizes((1, seq, 64, head), "tpu", window)
     assert isinstance(sizes, BlockSizes) and sizes.has_backward_blocks
     tiles = _tiles(sizes)
@@ -103,22 +110,31 @@ def test_tiles_read_the_window(window, seq, head):
 def test_a_window_no_shorter_than_the_key_block_changes_nothing(seq, window):
     """``olmoe`` (no window), ``smallthinker`` (4,096) and the global
     layers get the ``BlockSizes`` they had, field for field."""
+    assert not trunk.band_kernel_fits((1, seq, 28, 128), 4, window, "tpu")
     sizes = trunk.flash_block_sizes((1, seq, 28, 128), "tpu", window)
     assert sizes == _parents(seq)  # a dataclass: every field compared
     assert sizes == trunk.flash_block_sizes((1, seq, 28, 128), "tpu")
 
 
-@pytest.mark.parametrize("seq, window, key_block", [
-    (16384, 128, 512),   # k-exaone's window layers
-    (16384, 1, 512), (16384, 300, 512), (16384, 512, 512), (4096, 128, 512),
-    (16384, 513, 1024),  # 640, 768 and 896 divide no power of two: still unfused
-    (15360, 600, 640), (15360, 700, 768),
-    (1024, 200, 512), (512, 128, 512), (256, 128, 256), (1536, 128, None),
+@pytest.mark.parametrize("seq, window, key_block, band", [
+    (16384, 128, 512, True),   # k-exaone's window layers: the band kernel's
+    (16384, 1, 512, True), (16384, 300, 512, True), (16384, 512, 512, True),
+    (4096, 128, 512, True),
+    # 640, 768 and 896 divide no power of two: still unfused, and past the
+    # band kernel's halo of one block
+    (16384, 513, 1024, False),
+    (15360, 600, 640, False), (15360, 700, 768, False),
+    (1024, 200, 512, True), (512, 128, 512, True),
+    (256, 128, 256, False),    # shorter than the band kernel's block
+    (1536, 128, None, True),   # 512 divides it; the blocked kernel's 1024 does not
 ])
-def test_key_blocks_cover_the_window_and_the_measured_tile(seq, window, key_block):
+def test_key_blocks_cover_the_window_and_the_measured_tile(seq, window, key_block, band):
     """Key blocks are the window's cover, no narrower than the tile the
     sweep put first (a grid step's fixed cost: 128-wide key blocks under a
-    window of 128 ran no faster than 1024-wide ones)."""
+    window of 128 ran no faster than 1024-wide ones).  ``band``: whether
+    the core hands the call to the band kernel before it asks for tiles
+    (PR 63; eight query heads over one key/value head here)."""
+    assert trunk.band_kernel_fits((1, seq, 8, 128), 1, window, "tpu") is band
     sizes = trunk.flash_block_sizes((1, seq, 8, 128), "tpu", window)
     if key_block is None:  # the window does not make a length divisible
         assert sizes is None and trunk.flash_block_sizes((1, seq, 8, 128), "tpu") is None

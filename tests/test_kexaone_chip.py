@@ -35,17 +35,18 @@ def test_the_whole_step_fits_the_chip(v5e_chip, monkeypatch):
     assert memory["loss_layer_products"] == 3  # of the head's; four before PR 34
     assert moe_dispatch.grouped_matmul_tiles(16384, 6144, 2048, jnp.bfloat16) == (
         256, 2048, 1024)
-    # the blocked kernel's tiles read the window (PR 36): the four window
-    # layers' backward is a dK/dV and a dQ kernel (none of the latter in
-    # the step before), the global layer's the fused one
-    # and one forward a kernel layer (10 before PR 38): remat keeps the
-    # kernel's output and row sums, 273 MB a layer, so the recompute holds
-    # no forward call.  ``attention_kernel_calls`` reads the compiled
-    # step's instructions, ``attention_kernel_tilings`` the traced step's
-    # equations: the policy takes the call out before the compiler sees it
+    # the four window layers run the band kernel (PR 63: a window of 128
+    # is shorter than the blocked kernel's key block), one forward and ONE
+    # backward call a layer and no dQ kernel anywhere in the step; the
+    # global layer keeps the blocked kernel's fused backward.  One forward
+    # a kernel layer (10 before PR 38): remat keeps the kernels' output and
+    # row sums, 273 MB a layer, so the recompute holds no forward call.
+    # ``attention_kernel_calls`` reads the compiled step's instructions,
+    # ``attention_kernel_tilings`` the traced step's equations: the policy
+    # takes the call out before the compiler sees it
     assert memory["attention_kernel_calls"] == {
-        "splash_mha_fwd_residuals": 5,
-        "splash_mha_dkv_no_residuals": 5, "splash_mha_dq_no_residuals": 4}
+        "splash_mha_fwd_residuals": 1, "splash_mha_dkv_no_residuals": 1,
+        "band_attention_fwd": 4, "band_attention_bwd": 4}
     assert memory["kept_residual_bytes"] == 5 * 64 * 16384 * (128 * 2 + 4)
     # and the results of the attention part's products (PR 53): q, k, v and
     # the output projection's, bf16 [16384, 8192 + 1024 + 1024 + 6144] a
@@ -59,27 +60,27 @@ def test_the_whole_step_fits_the_chip(v5e_chip, monkeypatch):
     assert stages["stages"] == [
         "flash", "flash/layout", "norm", "out_proj", "proj", "qk_norm", "rope"]
     assert stages["kernel_scopes"] == [
-        f"attention/flash/vmap(jit(_splash_attention))/{name}/{name}"
-        for name in ("splash_mha_dkv_no_residuals", "splash_mha_dq_no_residuals",
-                     "splash_mha_fwd_residuals")]
+        "attention/flash/band_attention_bwd", "attention/flash/band_attention_fwd",
+        *(f"attention/flash/vmap(jit(_splash_attention))/{name}/{name}"
+          for name in ("splash_mha_dkv_no_residuals", "splash_mha_fwd_residuals"))]
     tilings = memory["attention_kernel_tilings"]
     assert {kind: {name: call["calls"] for name, call in calls.items()}
             for kind, calls in tilings.items()} == {
         "global": {"splash_mha_fwd_residuals": 1, "splash_mha_dkv_no_residuals": 1},
-        "window": {"splash_mha_fwd_residuals": 4, "splash_mha_dkv_no_residuals": 4,
-                   "splash_mha_dq_no_residuals": 4}}
-    want = trunk.flash_block_sizes((1, 16384, 64, 128), "tpu", 128)
-    assert [(call["block_q"], call["block_kv"]) for call in tilings["window"].values()] == [
-        (want.block_q, want.block_kv), (want.block_q_dkv, want.block_kv_dkv),
-        (want.block_q_dq, want.block_kv_dq)]
-    assert all(call["block_kv"] == 512 for call in tilings["window"].values())
-    # a window layer's key-block axis is the two blocks its mask admits (a
-    # query block's own and the one before), not the 32 of the sequence
-    assert [call["grid"][-1] for call in tilings["window"].values()] == [2, 2, 2]
+        "window": {"band_attention_fwd": 4, "band_attention_bwd": 4}}
+    assert trunk.band_kernel_fits((1, 16384, 64, 128), 8, 128, "tpu")
+    # a window layer's grid is (batch row, key/value head, block of 512
+    # positions): a step holds the eight query heads of its key/value head
+    # and the whole softmax of its 512 queries; the backward's one step
+    # more writes the last key block's gradients
+    assert [(call["block_q"], call["block_kv"], call["grid"])
+            for call in tilings["window"].values()] == [
+        (512, 512, [1, 8, 32]), (512, 512, [1, 8, 33])]
     # the queries' gradient once a key block of 1024, [16, 64, 16384, 128]
     # bf16, is the fused backward's: the global layer keeps it, and no
-    # kernel of a window layer writes anything near it
+    # kernel of a window layer writes anything larger than the queries'
+    # gradient itself
     partials = 16 * 64 * 16384 * 128 * 2
     assert tilings["global"]["splash_mha_dkv_no_residuals"]["largest_result_bytes"] == partials
-    assert all(call["largest_result_bytes"] <= partials // 8
+    assert all(call["largest_result_bytes"] == partials // 16
                for call in tilings["window"].values())

@@ -43,7 +43,13 @@ threshold were set from.
   At SmallThinker's ``[1, 28 over 4, 16384, 128]`` under a window of 4,096
   and under the causal mask: the baseline against the unfused form at the
   same blocks.  PERF.md section 6 ("PR 36") holds the table the rule's
-  short-window regime was set from.
+  short-window regime was set from.  ``window band``: the band kernel
+  (``ops/band_attention.py``, what ``attention_core`` hands a window
+  shorter than the key block since PR 63) beside that regime at
+  K-EXAONE's shape under windows of 128, 256 and 512, each kernel on
+  arrays in its own layout (heads first; positions last), and the band
+  kernel at 64 over 16 and 64 over 64 heads (groups of 4 and of 1);
+  PERF.md section 6 ("PR 63").
 - ``latent``: ``splash_attention`` under a causal mask at GLM-4.7-Flash's
   expanded latent attention, ``[1, 20, 16384, 256]`` bf16 (queries, keys
   and values of 256 a head), forward and forward + backward: the blocks of
@@ -275,6 +281,7 @@ def splash() -> None:
 KEXAONE = (1, 64, 8, 16384, 128, 128)       # k-exaone-236b-a23b-train-zipf16k
 SMALLTHINKER = (1, 28, 4, 16384, 128, 4096)  # smallthinker-21b-a3b-train-zipf16k
 BASELINE = (1024, 1024, 512)  # trunk._FLASH_TILES: PR 28's sweep, causal
+BAND_WINDOWS = (128, 256, 512)  # what ops.band_attention.band_kernel_fits takes
 
 
 def _window_reading(cell, window, forward, backward, fused, dq, args,
@@ -309,6 +316,53 @@ def _window_reading(cell, window, forward, backward, fused, dq, args,
     return None if None in read else tuple(read)
 
 
+def _rule_reading(cell, window, args, **also):
+    """:func:`_window_reading` of the blocked kernel under
+    ``flash_block_sizes``'s answer for ``window``, as ``attention_core``
+    builds it where the band kernel does not take the call."""
+    from learning_at_home_tpu.models import trunk
+
+    b, h, _, s, hd, _ = cell
+    sizes = trunk.flash_block_sizes((b, s, h, hd), "tpu", window)
+    fused = sizes.use_fused_bwd_kernel
+    return _window_reading(
+        cell, window,
+        (sizes.block_q, sizes.block_kv, sizes.block_kv_compute),
+        (sizes.block_q_dkv, sizes.block_kv_dkv, sizes.block_kv_dkv_compute),
+        fused, None if fused else (sizes.block_q_dq, sizes.block_kv_dq), args,
+        **also)
+
+
+def _band_reading(cell, window, **also):
+    """Rows ``window_forward`` and ``window_forward_backward`` of the band
+    kernel (``ops/band_attention.py``) at ``cell``'s shape, in ITS layout."""
+    import jax
+
+    from learning_at_home_tpu.ops.band_attention import band_attention
+
+    b, h, hkv, s, hd, _ = cell
+    # [B, heads * hd, S], positions last, as the step's projections and
+    # rotations leave them on the chip: the transposes to heads and back
+    # are no instruction, so the reading is the kernel's alone, as the
+    # blocked kernel's is in its layout
+    q, do, _, _ = qkv((b, h * hd, s))
+    k, v, _, _ = qkv((b, hkv * hd, s))
+    settings = dict(shape=[b, h, hkv, s, hd], window=window, kernel="band", **also)
+
+    def heads(x):
+        return x.reshape(b, -1, hd, s).transpose(0, 3, 1, 2)
+
+    fwd = jax.jit(lambda q, k, v: band_attention(
+        heads(q), heads(k), heads(v), window).transpose(0, 2, 3, 1).reshape(q.shape))
+
+    def both(q, k, v, do):
+        out, vjp = jax.vjp(fwd, q, k, v)
+        return out, vjp(do)
+
+    return (timed("window_forward", settings, fwd, q, k, v),
+            timed("window_forward_backward", settings, jax.jit(both), q, k, v, do))
+
+
 def _grouped_qkv(cell):
     """q and the output's cotangent [B, H, S, hd], k and v [B, Hkv, S, hd]."""
     b, h, hkv, s, hd, _ = cell
@@ -318,13 +372,11 @@ def _grouped_qkv(cell):
 
 
 def window(which: str = "all") -> None:
-    """``which``: ``all``; ``kexaone`` (``sweep``, ``beyond`` and ``rule``,
-    or one of the three alone); ``smallthinker``."""
-    from learning_at_home_tpu.models import trunk
-
+    """``which``: ``all``; ``kexaone`` (``sweep``, ``beyond``, ``rule`` and
+    ``band``, or one of the four alone); ``smallthinker``."""
     require_tpu()
-    parts = {"all": ("sweep", "beyond", "rule", "smallthinker"),
-             "kexaone": ("sweep", "beyond", "rule")}.get(which, (which,))
+    parts = {"all": ("sweep", "beyond", "rule", "band", "smallthinker"),
+             "kexaone": ("sweep", "beyond", "rule", "band")}.get(which, (which,))
     if set(parts) & {"sweep", "beyond", "rule"}:
         cell, w = KEXAONE, KEXAONE[-1]
         args = _grouped_qkv(cell)
@@ -362,13 +414,17 @@ def window(which: str = "all") -> None:
         # the rule's own answer, as attention_core would build it
         if "sweep" not in parts:
             read(BASELINE, BASELINE, True, stage="baseline")
-        b, h, _, s, hd, _ = cell
-        sizes = trunk.flash_block_sizes((b, s, h, hd), "tpu", w)
-        read((sizes.block_q, sizes.block_kv, sizes.block_kv_compute),
-             (sizes.block_q_dkv, sizes.block_kv_dkv, sizes.block_kv_dkv_compute),
-             sizes.use_fused_bwd_kernel,
-             None if sizes.use_fused_bwd_kernel else (sizes.block_q_dq, sizes.block_kv_dq),
-             stage="rule")
+        _rule_reading(cell, w, args, stage="rule")
+    if "band" in parts:
+        # the band kernel beside the blocked kernel's short-window regime,
+        # at the cell's shape under each window the band's rule takes and
+        # at its other group sizes under the cell's
+        for w in BAND_WINDOWS:
+            _rule_reading(KEXAONE, w, _grouped_qkv(KEXAONE), stage="band")
+            _band_reading(KEXAONE, w, stage="band")
+        for hkv in (16, 64):  # groups of 4 and of 1
+            cell = (*KEXAONE[:2], hkv, *KEXAONE[3:])
+            _band_reading(cell, cell[-1], stage="band")
     if "smallthinker" in parts:
         cell = SMALLTHINKER
         args = _grouped_qkv(cell)

@@ -255,12 +255,20 @@ def moe_rows_kernel_calls(compiled_text: str) -> dict:
     return found
 
 
+# the attention kernels' instruction names begin with one of these: the
+# blocked kernel's (splash attention) and the band kernel's
+# (``ops/band_attention.py``: a window shorter than the key block, PR 63)
+ATTENTION_KERNELS = ("splash_mha", "band_attention")
+
+
 def attention_kernel_calls(compiled_text: str) -> dict:
-    """How many instructions of each of the blocked kernel's names
-    (``splash_mha_fwd_residuals``, ``_dkv_no_residuals``,
-    ``_dq_no_residuals``) a compiled program's text holds."""
+    """How many instructions of each of the attention kernels' names (the
+    blocked kernel's ``splash_mha_fwd_residuals``, ``_dkv_no_residuals``,
+    ``_dq_no_residuals``; the band kernel's ``band_attention_fwd``,
+    ``_bwd``) a compiled program's text holds."""
+    names = "|".join(ATTENTION_KERNELS)
     return dict(collections.Counter(re.findall(
-        r"^\s*%(splash_mha\w*?)(?:\.\d+)? = [^\n]*custom-call\(",
+        rf"^\s*%((?:{names})\w*?)(?:\.\d+)? = [^\n]*custom-call\(",
         compiled_text, re.M)))
 
 
@@ -269,8 +277,9 @@ def attention_stages(compiled_text: str) -> dict:
     lie, fused ones too, as ``tools/scope_tree.py`` folds their paths:
     under ``stages`` the stages they name (``norm``, ``proj``, ``qk_norm``,
     ``rope``, ``flash``, ``flash/layout``, ``out_proj``; PR 52), under
-    ``kernel_scopes`` the folded paths of the blocked kernel's calls
-    (instructions ``splash_mha*``), the kind of layer folded too."""
+    ``kernel_scopes`` the folded paths of the attention kernels' calls
+    (instructions ``splash_mha*`` and ``band_attention*``), the kind of
+    layer folded too."""
     import harness
 
     tree = harness.load_path(os.path.join(REPO, "tools", "scope_tree.py"))
@@ -281,7 +290,7 @@ def attention_stages(compiled_text: str) -> dict:
         "stages": sorted(tree.attention_stages(names.values())),
         "kernel_scopes": sorted({
             "/".join(tree.fold(op_name, also=tree.ATTENTION_KINDS)[0])
-            for name, op_name in names.items() if name.startswith("splash_mha")}),
+            for name, op_name in names.items() if name.startswith(ATTENTION_KERNELS)}),
     }
 
 
@@ -289,19 +298,25 @@ def attention_kernel_tilings(jaxpr) -> dict:
     """By layer kind (the scope ``attention/<kind>``, or ``attention``) and
     kernel name: how many calls the traced step makes, the query and key
     blocks each got (what ``trunk.flash_block_sizes`` answered for its
-    mask), the grid it walks (the key-block axis already shrunk to the
-    mask where the kernel can) and its largest result in bytes (the fused
-    backward's is the queries' gradient once a key block)."""
+    mask; the band kernel's one block of positions), the grid it walks
+    (the key-block axis already shrunk to the mask where the blocked
+    kernel can; the band kernel's has none) and its largest result in
+    bytes (the fused backward's is the queries' gradient once a key
+    block)."""
     tilings = {}
     for path, eqn in _equations(jaxpr, "pallas_call"):
         kind = re.search(r"attention(?:/(global|window))?[/)]", path)
-        if not kind or not eqn.params["name"].startswith("splash_mha"):
+        if not kind or not eqn.params["name"].startswith(ATTENTION_KERNELS):
             continue
         mapping = eqn.params["grid_mapping"]
-        # q comes first, [heads, block_q, hd]; k second, [heads, block_kv,
-        # hd]; a batch of more than one row (vmap) puts its axis before
-        (*_, bq, _), (*_, bkv, _) = (
-            m.block_shape for m in mapping.block_mappings[:2])
+        q_block, k_block = (m.block_shape for m in mapping.block_mappings[:2])
+        if eqn.params["name"].startswith("band_attention"):
+            # q first, [g * hd, block], and k, [hd, block]: positions last
+            bq, bkv = q_block[-1], k_block[-1]
+        else:
+            # q comes first, [heads, block_q, hd]; k second, [heads, block_kv,
+            # hd]; a batch of more than one row (vmap) puts its axis before
+            bq, bkv = q_block[-2], k_block[-2]
         entry = tilings.setdefault(kind.group(1) or "attention", {}).setdefault(
             eqn.params["name"], {
                 "calls": 0, "block_q": bq.block_size, "block_kv": bkv.block_size,
