@@ -31,6 +31,7 @@ from learning_at_home_tpu.models.trunk import (
     ATTENTION_PRODUCTS,
     FLASH_RESIDUALS,
     SHORT_CONV_RESULT,
+    RopeScaling,
     attention_core,
     block_diffusion_admitted_pairs,
     block_diffusion_visited_pairs,
@@ -39,6 +40,9 @@ from learning_at_home_tpu.models.trunk import (
     gate_activation,
     gated_mlp,
     gated_qkv_projections,
+    hc_coefficients,
+    hc_post,
+    hc_pre,
     latent_qkv_projections,
     layer_norm,
     one_query_attention,
@@ -46,6 +50,7 @@ from learning_at_home_tpu.models.trunk import (
     rms_norm,
     short_conv_mixer,
     ssm_mixer,
+    yarn_scales,
 )
 from learning_at_home_tpu.ops.delta_rule import DELTA_RESIDUALS
 from learning_at_home_tpu.ops.moe_dispatch import balanced_bias, level_bias
@@ -60,7 +65,11 @@ Params = Any
 _EXTREMES = {"ssm_decay_min": jnp.min, "delta_decay_min": jnp.min,
              "delta_beta_max": jnp.max, "attention_gate_mean": jnp.mean,
              "shared_gate_mean": jnp.mean, "held_experts_empty": jnp.max,
-             "shortconv_out_rms": jnp.min}
+             "shortconv_out_rms": jnp.min,
+             # hyper-connections: the largest |row or column sum - 1| of any
+             # part's mixing matrix, and the largest over the smallest rms
+             # of the streams entering a final sum
+             "hc_res_marginal_error": jnp.max, "hc_stream_rms_spread": jnp.max}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -174,7 +183,17 @@ class DMoETransformerConfig:
     # dense gated_silu block of width 7,168 in the leading layer, then
     # gated_silu experts of width 1,792, 4 of 32 by sigmoid scores with a
     # selection bias, renormalised, every expert held / a tied head
-    # (lfm2_8b_a1b_one_chip).
+    # (lfm2_8b_a1b_one_chip); Xing4.0-29B-A4B is rmsnorm (eps 1e-6) / rope
+    # (theta 1e4) under YaRN (factor 64 over 4,096) / FOUR residual streams
+    # a token, every part reading and writing them through
+    # hyper-connections whose 4 x 4 matrix 20 Sinkhorn iterations make
+    # doubly stochastic / 32 heads whose queries, keys and values are
+    # expanded from latents of 768 and 512, queries and keys of 192 (the
+    # last 64 rotated) over values of 128 / a dense leading layer of width
+    # 9,216, then a shared expert beside gated_silu experts of width 1,024,
+    # 4 of 64 by sigmoid scores with a selection bias, renormalised times
+    # 2 / dropless, a share held / one block that predicts the
+    # next-but-one token (xing4_0_29b_a4b_one_chip).
     # 'layernorm' (scale and bias), 'rmsnorm' (scale only) or
     # 'rmsnorm_offset' (Qwen3-Next: the multiplier is 1 + w, w zero from the
     # seed; every norm of the stack but the delta rule's gate-and-norm)
@@ -326,6 +345,28 @@ class DMoETransformerConfig:
     # convolution of short_conv_kernel taps over d_model channels between
     # two gates (LFM2's conv_L_cache)
     short_conv_kernel: int = 3
+    # manifold-constrained hyper-connections (arXiv:2512.24880 over
+    # arXiv:2409.19606): the residual stream is hc_streams streams a token,
+    # [B, S, n, d]; the embedding is copied to them, every part of a layer
+    # (its attention, its feed-forward part; the prediction block's two)
+    # reads a learned, input-dependent mix of them and writes back through
+    # a doubly stochastic n x n matrix made by hc_sinkhorn_iters
+    # Sinkhorn iterations a token a part (trunk.hc_coefficients, hc_pre,
+    # hc_post: hc_eps is what the iterations' sums are kept from zero by,
+    # hc_res_clamp what the matrix's logits are clipped to before their
+    # exponential), and the streams are summed before the final norm.
+    # None = one stream, x + Part(norm(x)), to the bit
+    hc_streams: int | None = None
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_res_clamp: tuple[float, float] = (-30.0, 30.0)
+    # latent attention's values a head where they are not as wide as its
+    # queries and keys (head_dim): 128 under keys of 192; None = head_dim
+    v_head_dim: int | None = None
+    # YaRN's numbers (trunk.RopeScaling): the rotated part's frequencies
+    # are interpolated a pair at a time and the softmax's scale grows by
+    # trunk.yarn_scales' second; None = the plain frequencies
+    rope_scaling: RopeScaling | None = None
 
     def mixture_layers(self) -> int:
         """How many of the stack's layers route (hold a mixture)."""
@@ -360,7 +401,8 @@ DIFFUSION_P_FLOOR = 1e-3
 
 
 def auto_attn_impl(
-    backend: str, n_devices: int, seq_len: int, head_dim: int
+    backend: str, n_devices: int, seq_len: int, head_dim: int,
+    value_dim: int | None = None,
 ) -> str:
     """The attention core a model runs (``DMoETransformerLM.attn_impl``),
     from the backend, the mesh and the training shape alone.  ``'flash'``:
@@ -375,7 +417,8 @@ def auto_attn_impl(
     shard_map"), so there the step keeps the core XLA can partition."""
     can_run = (
         n_devices == 1
-        and flash_block_sizes((1, seq_len, 1, head_dim), backend) is not None
+        and flash_block_sizes(
+            (1, seq_len, 1, head_dim), backend, value_dim=value_dim) is not None
     )
     return "flash" if can_run and seq_len >= FLASH_MIN_SEQ_LEN else "xla"
 
@@ -625,12 +668,64 @@ class DMoETransformerLM:
                 "heads as the queries have under a causal mask alone: it "
                 "has no grouped key/value heads and no window"
             )
+        if (config.v_head_dim is not None or config.rope_scaling is not None) and (
+            config.kv_latent_dim is None
+        ):
+            raise NotImplementedError(
+                "v_head_dim and rope_scaling belong to latent attention "
+                "(kv_latent_dim): the plain projections' values are as wide "
+                "as their keys (wv's columns over the heads) and their "
+                "rotation takes the plain frequencies"
+            )
+        if config.rope_scaling is not None and config.seq_parallel:
+            # (latent attention under the ring is refused above; this names
+            # what the ring itself lacks)
+            raise NotImplementedError(
+                "seq_parallel=True (ring attention, parallel/"
+                "ring_attention.py) with rope_scaling: the ring's core "
+                "scales its scores by 1 / sqrt(head) alone"
+            )
+        if config.hc_streams is not None:
+            if config.hc_streams < 2:
+                raise ValueError(
+                    f"hc_streams={config.hc_streams}: hyper-connections mix "
+                    "two streams or more (None = one stream)"
+                )
+            if config.seq_parallel or mesh.devices.size > 1:
+                raise NotImplementedError(
+                    "hc_streams on a mesh of several chips or under "
+                    "seq_parallel=True (ring attention, parallel/"
+                    "ring_attention.py): the streams [B, S, n, d] and their "
+                    "coefficients are laid out over no mesh axis, and "
+                    "nothing of it was run on one"
+                )
+            if self._diffusion:
+                raise NotImplementedError(
+                    "hc_streams with objective='block_diffusion': the "
+                    "doubled row's two copies would share the streams' "
+                    "coefficients' norm, and nothing of it was run"
+                )
+            if (
+                mixers is not None or self._delta or self._conv
+                or config.norm_place != "input" or config.shared_expert_gate
+            ):
+                raise NotImplementedError(
+                    "hc_streams wraps an attention part and a feed-forward "
+                    "part behind norms on their inputs: no mixer_pattern, "
+                    "no 'delta' or 'conv' layer, no norm on a part's output "
+                    "(norm_place) and no shared_expert_gate pass through "
+                    "the wrapper"
+                )
         self.cfg = config
         self.mesh = mesh
         self.attn_impl = auto_attn_impl(
             jax.default_backend(), mesh.devices.size, config.seq_len,
             config.head_dim or config.d_model // config.n_heads,
+            config.v_head_dim,
         )
+        # the softmax's scale where it is not 1 / sqrt(head): YaRN's
+        self._attn_scale = None if config.rope_scaling is None else (
+            float(yarn_scales(config.rope_scaling)[1] / config.head_dim ** 0.5))
         # compiled decoders (one per decode path) + the memoized
         # eval-routing twin (see generate / decode_model): without these,
         # every generate() call re-traces its whole decode loop — measured
@@ -808,6 +903,32 @@ class DMoETransformerLM:
                 "w_out": dense(k_out, (d, d), pdt),
             }
 
+        def hyper_connection(key):
+            """One part's hyper-connection: ``phi`` [n d, 2 n + n^2] normal
+            of deviation ``0.5 / (alpha sqrt(n d))`` with ``alpha`` 0.01 for
+            each of the three, so that the input-dependent term ``alpha (u
+            phi)`` has deviation 0.5 under the unit-rms ``u`` and moves
+            every coefficient by tens of percent; ``b`` [2 n + n^2] (float32
+            whatever the parameters' dtype, as ``alpha`` is): the read's
+            and the write's logits normal(1), the mixing matrix's 1 on the
+            diagonal plus normal(0.3): after its Sinkhorn iterations
+            neither the identity nor uniform (the diagonal near 0.4, the
+            rest near 0.2), and within 1e-5 of doubly stochastic after 20
+            iterations (a wider spread of logits converges more slowly:
+            deviations of 1 and 0.5 on a diagonal of 2 leave 3e-2)."""
+            n = cfg.hc_streams
+            k_phi, k_b, k_res = jax.random.split(key, 3)
+            alpha = 0.01
+            return {
+                "phi": (jax.random.normal(k_phi, (n * d, 2 * n + n * n))
+                        * (0.5 / (alpha * np.sqrt(n * d)))).astype(pdt),
+                "b": jnp.concatenate([
+                    jax.random.normal(k_b, (2 * n,)),
+                    (jnp.eye(n) + 0.3 * jax.random.normal(
+                        k_res, (n, n))).reshape(-1)]),
+                "alpha": jnp.full((3,), alpha, jnp.float32),
+            }
+
         def init_mixer_layer(key, mixer):
             """A layer that is ONE mixer behind ONE norm; what it holds
             says which: ``ssm``, ``wq``.., or ``moe`` (and ``shared``)."""
@@ -842,9 +963,10 @@ class DMoETransformerLM:
             else:
                 # the latents' down-projections and norms, their
                 # expansions: a head's columns of wkv_b are [k_nope | v],
-                # its values as wide as its keys
+                # its values v_head_dim wide (None: as wide as its keys)
                 c_q, c_kv = cfg.q_latent_dim, cfg.kv_latent_dim
                 rope, nope = cfg.rope_head_dim, hd - cfg.rope_head_dim
+                hd_v = cfg.v_head_dim or hd
                 attention = {
                     "wq_a": dense(ks[0], (d, c_q), pdt),
                     "q_a_norm": {"scale": jnp.ones((c_q,), pdt)},
@@ -852,10 +974,13 @@ class DMoETransformerLM:
                     "wkv_a": dense(ks[1], (d, c_kv + rope), pdt),
                     "kv_a_norm": {"scale": jnp.ones((c_kv,), pdt)},
                     "wkv_b": dense(
-                        ks[2], (c_kv, cfg.n_heads * (nope + hd)), pdt),
-                    "wo": dense(ks[3], (d_q, d), pdt),
+                        ks[2], (c_kv, cfg.n_heads * (nope + hd_v)), pdt),
+                    "wo": dense(ks[3], (cfg.n_heads * hd_v, d), pdt),
                 }
             lp = {"ln1": ln(), **attention, "ln2": ln()}
+            if cfg.hc_streams is not None:  # one a part
+                lp["hc_attn"] = hyper_connection(jax.random.fold_in(key, 11))
+                lp["hc_ffn"] = hyper_connection(jax.random.fold_in(key, 12))
             # the layer's feed-forward part is what its parameters hold:
             # 'ffn' (dense), or 'moe' and beside it 'shared'
             if ffn == "dense":
@@ -930,7 +1055,9 @@ class DMoETransformerLM:
             rope_theta=self.cfg.rope_theta, norm_eps=self.cfg.norm_eps,
         )
         if "wkv_a" in lp:
-            return *latent_qkv_projections(lp, x, self.cfg.n_heads, **how), None
+            return *latent_qkv_projections(
+                lp, x, self.cfg.n_heads, **how,
+                rope_scaling=self.cfg.rope_scaling), None
         return gated_qkv_projections(
             lp, x, self.cfg.n_heads, rotary_dim=self.cfg.rotary_dim, **how)
 
@@ -947,6 +1074,9 @@ class DMoETransformerLM:
         if one_mixer:
             return x, None
         x, aux = self._ffn_block(lp, x, attn_in, layer_idx, token_mask)
+        both = "hc_res_marginal_error"
+        if both in extremes:  # the layer's two parts' larger
+            extremes[both] = jnp.maximum(extremes[both], aux[both])
         return x, {**(aux or {}), **extremes} or None
 
     def _part_input(self, norm_p, x):
@@ -963,6 +1093,33 @@ class DMoETransformerLM:
         """The stream after a part gave ``out``: ``x + out``, or ``x +
         norm(out)`` where the norm is on the part's output."""
         return x + self._part_output(norm_p, out)
+
+    def _hc_read(self, hc, x):
+        """The streams a part mixes, what it reads of them and what its
+        write needs: the stream itself, twice, and None where it is ONE
+        stream (``hc`` None: the layer holds no hyper-connection), else the
+        streams ``x`` [B, S, n, d] (one stream [B, S, d] is copied to the
+        ``n``), ``sum_j pre[j] x[:, :, j]`` and ``(post, res, res's
+        marginal error)`` (``trunk.hc_coefficients``).  Every part of every layer
+        passes through this and :meth:`_hc_write`: the stack's, the
+        prediction block's, and the set-up's (``level_router_bias``)."""
+        if hc is None:
+            return x, x, None
+        cfg = self.cfg
+        if x.ndim == 3:  # ONE stream comes in: the stack's first layer's
+            # embedding, the block's combine.  Copied HERE, inside the
+            # layer's checkpoint, so what remat keeps of it is one stream
+            x = self._hc_copy(x)
+        pre, *write = hc_coefficients(
+            hc, x, cfg.hc_sinkhorn_iters, cfg.hc_eps, cfg.hc_res_clamp,
+            cfg.norm_eps)
+        return x, hc_pre(x, pre), write
+
+    @staticmethod
+    def _hc_write(x, out, write):
+        """The streams ``x`` after the part gave ``out`` [B, S, d]."""
+        post, res, _ = write
+        return hc_post(x, out, post, res)
 
     def _delta_block(self, lp, x):
         """The stream after the layer's delta-rule mixer, what the mixer
@@ -1023,6 +1180,7 @@ class DMoETransformerLM:
         scope = "attention" if self.cfg.layer_pattern is None else (
             "attention/global" if kind.window is None else "attention/window"
         )
+        streams, x, write = self._hc_read(lp.get("hc_attn"), x)
         with jax.named_scope(scope):
             # a layer of one mixer has ONE norm
             norm_p = lp["ln1" if "ln1" in lp else "norm"]
@@ -1032,6 +1190,7 @@ class DMoETransformerLM:
                 lambda q, k, v: attention_core(
                     q, k, v, self.attn_impl, kind.window,
                     self.cfg.diffusion_block if self._diffusion else None,
+                    self._attn_scale,
                 )
             )
             if self._diffusion:  # the doubled row: both copies at 0..s/2-1
@@ -1048,7 +1207,11 @@ class DMoETransformerLM:
                         jax.nn.sigmoid(gate.astype(jnp.float32)))
             with jax.named_scope("norm"):  # the norm, wherever it is placed
                 out = self._part_output(norm_p, out)
-            x = x + out  # directly under the attention scope
+            if write is None:
+                x = x + out  # directly under the attention scope
+        if write is not None:
+            x = self._hc_write(streams, out, write)
+            extremes["hc_res_marginal_error"] = write[2]
         return x, attn_in, extremes
 
     @staticmethod
@@ -1061,14 +1224,17 @@ class DMoETransformerLM:
         """The layer's feed-forward part, which its parameters name: one
         dense gated block (``ffn``: no router, ``aux`` None), or the
         mixture (``moe``) and beside it the shared expert (``shared``)."""
+        streams, x, write = self._hc_read(lp.get("hc_ffn"), x)
         b, s, d = x.shape
         norm_p = self._ffn_norm(lp)
         ffn_in = self._part_input(norm_p, x)
         if "ffn" in lp:
             with jax.named_scope("dense_ffn"):
-                return self._add_part(
-                    norm_p, x, gated_mlp(lp["ffn"], ffn_in, self._gate_act)
-                ), None
+                out = gated_mlp(lp["ffn"], ffn_in, self._gate_act)
+                if write is None:
+                    return self._add_part(norm_p, x, out), None
+            return self._hc_write(streams, out, write), {
+                "hc_res_marginal_error": write[2]}
         moe_in = ffn_in.reshape(b * s, d)
         # layer index salts the router jitter: decorrelates the
         # deterministic noise pattern across layers (round-2 advisor)
@@ -1080,6 +1246,13 @@ class DMoETransformerLM:
                 if self.cfg.router_input == "attention_input" else None
             ),
         )
+        if write is not None:  # the part's sum, then ONE write
+            out = moe_out.reshape(b, s, d)
+            if "shared" in lp:
+                with jax.named_scope("shared_expert"):
+                    out = out + gated_mlp(lp["shared"], ffn_in, self._gate_act)
+            return self._hc_write(streams, out, write), {
+                **aux, "hc_res_marginal_error": write[2]}
         if self.cfg.norm_place == "input":
             x = x + moe_out.reshape(b, s, d)
             if "shared_gate" in lp:
@@ -1157,12 +1330,17 @@ class DMoETransformerLM:
             # its out-projection's backward reads (trunk.SHORT_CONV_RESULT;
             # PR 61); everything else (norms, the rotation, the mixers' and
             # the experts' products, the dense blocks) is recomputed
+            # ... but under hc_streams what a layer keeps is n streams (470
+            # MB at 16,384 tokens of 3,584), and the products' 161 MB a
+            # layer are what does not fit beside them: they are run again
+            # (PERF.md section 6, PR 64)
+            kept = (FLASH_RESIDUALS, SSD_RESIDUALS, DELTA_RESIDUALS,
+                    ATTENTION_PRODUCTS, SHORT_CONV_RESULT)
+            if cfg.hc_streams is not None:
+                kept = tuple(n for n in kept if n != ATTENTION_PRODUCTS)
             layer_fn = jax.checkpoint(
                 layer_fn, static_argnums=(4,),
-                policy=jax.checkpoint_policies.save_only_these_names(
-                    FLASH_RESIDUALS, SSD_RESIDUALS, DELTA_RESIDUALS,
-                    ATTENTION_PRODUCTS, SHORT_CONV_RESULT,
-                ),
+                policy=jax.checkpoint_policies.save_only_these_names(*kept),
             )
 
         if self._zig is not None:
@@ -1210,6 +1388,9 @@ class DMoETransformerLM:
                 add(aux)
         if self._zig is not None:
             x = x[:, self._zig_inv]
+        if cfg.hc_streams is not None:
+            x, spread = self._hc_sum(x)
+            extremes["hc_stream_rms_spread"] = [spread]
         x = self._norm(params["ln_f"], x)
         n_moe = cfg.mixture_layers()
         if next_ids is not None:
@@ -1232,6 +1413,24 @@ class DMoETransformerLM:
             return x, x_mtp, aux_mean
         return x, aux_mean
 
+    def _hc_copy(self, x):
+        """The stream [B, S, d] copied to the ``hc_streams`` streams [B, S,
+        n, d] the layers hand on."""
+        n = self.cfg.hc_streams
+        with jax.named_scope("hc"), jax.named_scope("copy"):
+            return jnp.broadcast_to(x[:, :, None], (*x.shape[:2], n, x.shape[2]))
+
+    @staticmethod
+    def _hc_sum(x):
+        """The streams [B, S, n, d] summed (float32, rounded to their
+        dtype) for the final norm, and the largest over the smallest rms of
+        the streams that entered the sum."""
+        with jax.named_scope("hc"), jax.named_scope("sum"):
+            x32 = x.astype(jnp.float32)
+            ms = jnp.mean(x32 * x32, axis=(0, 1, 3))
+            return (x32.sum(axis=2).astype(x.dtype),
+                    jnp.sqrt(jnp.max(ms) / jnp.min(ms)))
+
     def _mtp_input(self, mp, h, next_ids, embed):
         """``[rms(embed[next]) ; rms(h)] W_eh``: what the block's layer
         reads, the embedding's half first."""
@@ -1247,7 +1446,10 @@ class DMoETransformerLM:
         stream ``h`` [B, S, d] and each position's next token: ``z =
         [rms(embed[next]) ; rms(h)] W_eh`` (scope ``combine``), one more
         layer of the model's own kind (``layer_0``: ``layer_fn``, the
-        stack's, under remat where it is), the block's own final norm.
+        stack's, under remat where it is; under ``hc_streams`` the combine
+        is copied to the streams, the layer mixes them through
+        hyper-connections of its own, and they are summed), the block's own
+        final norm.
         Returns the normalized stream for the model's head and the layer's
         ``aux``."""
         cfg = self.cfg
@@ -1257,6 +1459,9 @@ class DMoETransformerLM:
                 mp["layer"], z, cfg.n_layers, token_mask,
                 cfg.attention_layer(cfg.n_layers),
             )
+        if cfg.hc_streams is not None:  # hyper-connections of the block's own
+            z, spread = self._hc_sum(z)
+            aux = {**aux, "hc_stream_rms_spread": spread}
         return self._norm(mp["out_norm"], z), aux
 
     def _head(self, params: Params) -> jax.Array:
@@ -1373,6 +1578,13 @@ class DMoETransformerLM:
             # buffer and fail at trace time on .at[:, 0]
             return prompt_ids
         if use_cache:
+            if self.cfg.hc_streams is not None:
+                raise NotImplementedError(
+                    "use_cache=True with hc_streams: the KV-cache decoder "
+                    "hands ONE residual stream from layer to layer; several "
+                    "streams and their mixing a token a part are not built "
+                    "in it; decode without the cache"
+                )
             if self._conv:
                 raise NotImplementedError(
                     "use_cache=True with a 'conv' layer: the KV-cache "
@@ -1913,7 +2125,12 @@ class DMoETransformerLM:
         whole = jax.jit(self._layer, static_argnums=(4,))
         scores = jax.jit(lambda lp, x: jax.nn.sigmoid(self.moe.router_logits(
             lp["moe"],
-            self._part_input(self._ffn_norm(lp), x).reshape(-1, cfg.d_model))))
+            self._part_input(
+                self._ffn_norm(lp), self._hc_read(lp.get("hc_ffn"), x)[1]
+            ).reshape(-1, cfg.d_model))))
+        # what the block's combine reads: the streams summed, or the stream
+        total = (lambda x: x) if cfg.hc_streams is None else jax.jit(
+            lambda x: self._hc_sum(x)[0])
         streams = [embed(params["embed"], ids) for ids in token_batches]
         layers, loads = list(params["layers"]), []
         if cfg.router_input != "moe_input":
@@ -1944,7 +2161,7 @@ class DMoETransformerLM:
             # id: the row shifted by one (the last position keeps its own)
             mp = params["mtp"]
             combine = jax.jit(lambda mp, ln_f, table, x, ids: self._mtp_input(
-                mp, self._norm(ln_f, x),
+                mp, self._norm(ln_f, total(x)),
                 jnp.concatenate([ids[:, 1:], ids[:, -1:]], axis=1), table))
             streams = [
                 attend(

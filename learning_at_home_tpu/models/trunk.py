@@ -8,7 +8,9 @@ live HERE once so they cannot drift.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -50,19 +52,83 @@ def norm_width(p: dict) -> int:
     return p["offset" if "offset" in p else "scale"].shape[-1]
 
 
+@dataclasses.dataclass(frozen=True)
+class RopeScaling:
+    """YaRN's numbers as a ``config.json`` gives them under
+    ``rope_scaling`` (``type`` ``yarn``; arXiv:2309.00071, as the
+    DeepSeek-V3 family writes it): :func:`yarn_inv_freq` makes the rotated
+    pairs' frequencies of them, :func:`yarn_scales` what multiplies the
+    cosines and sines and the softmax's scale."""
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+def yarn_inv_freq(dim: int, theta: float, scaling: RopeScaling) -> np.ndarray:
+    """The ``dim / 2`` frequencies of a rotated part of ``dim`` columns
+    under YaRN: pair ``i`` turns at ``1 / theta^(2i/dim)`` where it makes
+    more than ``beta_fast`` turns over the original context (left as it
+    is), at that over ``factor`` where it makes fewer than ``beta_slow``
+    (interpolated), and on a linear ramp between the two pairs where those
+    counts fall (floor and ceiling).  float32, on the host: a function of
+    the configuration alone."""
+    half = dim // 2
+    plain = 1.0 / theta ** (np.arange(half, dtype=np.float64) * 2 / dim)
+
+    def pair_of(turns: float) -> float:
+        return dim * math.log(
+            scaling.original_max_position_embeddings / (turns * 2 * math.pi)
+        ) / (2 * math.log(theta))
+
+    low = max(math.floor(pair_of(scaling.beta_fast)), 0)
+    high = min(math.ceil(pair_of(scaling.beta_slow)), dim - 1)
+    ramp = np.clip(
+        (np.arange(half, dtype=np.float64) - low) / max(high - low, 1e-3), 0, 1)
+    return (plain / scaling.factor * ramp + plain * (1 - ramp)).astype(np.float32)
+
+
+def yarn_scales(scaling: RopeScaling) -> tuple:
+    """``(what multiplies the cosines and sines, what multiplies the
+    softmax's 1 / sqrt(head))``: with ``m(s, w) = 0.1 w ln s + 1`` the
+    first is ``m(factor, mscale) / m(factor, mscale_all_dim)`` and the
+    second ``m(factor, mscale_all_dim)`` squared."""
+
+    def m(weight: float) -> float:
+        if scaling.factor <= 1:
+            return 1.0
+        return 0.1 * weight * math.log(scaling.factor) + 1.0
+
+    return m(scaling.mscale) / m(scaling.mscale_all_dim), m(
+        scaling.mscale_all_dim) ** 2
+
+
 def rotary(
-    x: jax.Array, positions: jax.Array, theta: float = 10000.0
+    x: jax.Array, positions: jax.Array, theta: float = 10000.0,
+    scaling: RopeScaling | None = None,
 ) -> jax.Array:
     """Rotary position embedding, rotate-half convention, on [B,S,H,hd]
     heads; ``positions`` [S] are the tokens' positions in the sequence
-    (not their place in the buffer).  Angles in float32."""
+    (not their place in the buffer).  Angles in float32.  With ``scaling``
+    the frequencies are :func:`yarn_inv_freq`'s and the cosines and sines
+    are multiplied by :func:`yarn_scales`' first."""
     hd = x.shape[-1]
-    inv_freq = 1.0 / (
-        theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd)
-    )
+    if scaling is None:
+        inv_freq = 1.0 / (
+            theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd)
+        )
+        amplitude = 1.0
+    else:
+        inv_freq = jnp.asarray(yarn_inv_freq(hd, theta, scaling))
+        amplitude = yarn_scales(scaling)[0]
     angles = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
     cos = jnp.cos(angles)[None, :, None, :]
     sin = jnp.sin(angles)[None, :, None, :]
+    if amplitude != 1.0:
+        cos, sin = cos * amplitude, sin * amplitude
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     return jnp.concatenate(
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
@@ -185,10 +251,11 @@ def latent_qkv_projections(
     lp: dict, x: jax.Array, n_heads: int,
     positions: jax.Array | None = None,
     rope_theta: float = 10000.0, norm_eps: float = 1e-5,
+    rope_scaling: RopeScaling | None = None,
 ):
     """Q/K/V expanded from low-rank latents (multi-head latent attention,
-    the form training and prefill run): [B,S,d] → q, k, v [B,S,H,hd],
-    finished for any attention core.  ``c_q = rms(x Wqa)`` is expanded by
+    the form training and prefill run): [B,S,d] → q, k [B,S,H,hd] and v
+    [B,S,H,hd_v], finished for any attention core.  ``c_q = rms(x Wqa)`` is expanded by
     ``Wqb`` to the heads' queries; ``x Wkva`` is the keys' and values'
     latent ``c_kv`` (normalized) beside ONE rotated key part a token,
     ``k_r``, that every head shares; ``Wkvb`` expands ``c_kv`` to each
@@ -198,7 +265,9 @@ def latent_qkv_projections(
     own parameters say the sizes: the latent's width is ``kv_a_norm``'s
     scale, the rotated part what ``wkv_a`` gives beyond it, a head's
     query/key size ``wq_b``'s width over ``n_heads``, its value size what
-    ``wkv_b`` gives a head beyond the unrotated key part.  No biases."""
+    ``wkv_b`` gives a head beyond the unrotated key part (as wide as the
+    keys or not: GLM-4.7-Flash's 256 beside keys of 256, or 128 beside keys
+    of 192).  ``rope_scaling``: :func:`rotary`'s.  No biases."""
     b, s, _ = x.shape
     kv_rank = lp["kv_a_norm"]["scale"].shape[-1]
     rope_dim = lp["wkv_a"].shape[-1] - kv_rank
@@ -220,8 +289,8 @@ def latent_qkv_projections(
     q_nope, q_rope = q[..., :nope], q[..., nope:]
     if positions is not None:
         with jax.named_scope("rope"):
-            q_rope = rotary(q_rope, positions, rope_theta)
-            k_rope = rotary(k_rope, positions, rope_theta)
+            q_rope = rotary(q_rope, positions, rope_theta, rope_scaling)
+            k_rope = rotary(k_rope, positions, rope_theta, rope_scaling)
     with jax.named_scope("latent_up"):
         q = jnp.concatenate([q_nope, q_rope], axis=-1)
         k = jnp.concatenate(
@@ -246,6 +315,107 @@ def output_projection(
         return checkpoint_name(
             out.reshape(b, s, h * hd) @ lp["wo"].astype(out.dtype),
             ATTENTION_PRODUCTS)
+
+
+def hc_coefficients(
+    p: dict, x: jax.Array, sinkhorn_iters: int, eps: float,
+    res_clamp: tuple, norm_eps: float,
+):
+    """What one part of a layer reads and writes of a residual stream of
+    ``n`` streams a token (manifold-constrained hyper-connections,
+    arXiv:2512.24880 over arXiv:2409.19606) ``x`` [B, S, n, C]: ``(pre [n,
+    B, S], post [n, B, S], res [n, n, B, S], the largest |row or column
+    sum - 1| of any token's res)``, float32, the streams' axes LEADING (a
+    token's sixteen numbers lie a token apart, so every array here is
+    dense over the tokens).  ``p`` holds ``phi`` [n C, 2 n + n^2], ``b``
+    [2 n + n^2] and ``alpha`` [3]::
+
+        m     = (vec(x) / sqrt(mean(vec(x)^2) + norm_eps)) phi   one norm over
+                the n C numbers of a token, no learned scale
+        pre   = sigmoid(alpha[0] m[:n] + b[:n])
+        post  = 2 sigmoid(alpha[1] m[n:2n] + b[n:2n])
+        res_0 = exp(clip(alpha[2] mat(m[2n:]) + mat(b[2n:]), *res_clamp)),
+                row i the stream WRITTEN
+        res_t = rows(columns(res_{t-1})), t = 1 .. sinkhorn_iters:
+                columns divides each column by (its sum + eps), rows each
+                row alike, so res is doubly stochastic to the iteration's
+                accuracy (the Sinkhorn-Knopp projection)
+
+    The norm's scalar a token is taken out of the product: ``(x phi) *
+    rsqrt(..)``, the stream's own dtype times ``phi`` in it, summed in
+    float32.  Scopes ``hc/coeff`` (the norm, the product, the sigmoids)
+    and ``hc/sinkhorn``."""
+    n = x.shape[2]
+    f32 = jnp.float32
+    with jax.named_scope("hc"):
+        with jax.named_scope("coeff"):
+            x32 = x.astype(f32)
+            inv_rms = jax.lax.rsqrt(
+                jnp.mean(x32 * x32, axis=(2, 3)) + norm_eps)  # [B, S]
+            m = jnp.einsum(
+                "bsnc,nco->obs", x,
+                p["phi"].astype(x.dtype).reshape(n, x.shape[3], -1),
+                preferred_element_type=f32) * inv_rms
+            alpha, b = p["alpha"].astype(f32), p["b"].astype(f32)[:, None, None]
+            pre = jax.nn.sigmoid(alpha[0] * m[:n] + b[:n])
+            post = 2.0 * jax.nn.sigmoid(alpha[1] * m[n:2 * n] + b[n:2 * n])
+            logits = (alpha[2] * m[2 * n:] + b[2 * n:]).reshape(
+                n, n, *m.shape[1:])
+        with jax.named_scope("sinkhorn"):
+            res = sinkhorn(jnp.exp(jnp.clip(logits, *res_clamp)),
+                           sinkhorn_iters, eps)
+            error = jnp.maximum(
+                jnp.max(jnp.abs(res.sum(axis=0) - 1.0)),
+                jnp.max(jnp.abs(res.sum(axis=1) - 1.0)))
+    return pre, post, res, error
+
+
+def sinkhorn(m: jax.Array, iters: int, eps: float) -> jax.Array:
+    """``iters`` rounds of: each column of ``m`` [n, n, ..] (axis 0 the
+    row, axis 1 the column) over its sum plus ``eps``, then each row
+    alike.  The sums are written term by term, so a round is elementwise
+    work on [n, ..] slabs.  The rounds are a ``lax.scan`` of four a trip,
+    not ``iters`` copies of the round: written out, twelve parts' twenty
+    rounds and their transposes were two thirds of the compiled step's
+    instructions and of its compile time (137 s against 57 here for the
+    described chip, PR 64)."""
+    n = m.shape[0]
+
+    def one_round(m, _):
+        m = m / (sum(m[i] for i in range(n)) + eps)[None]
+        m = m / (sum(m[:, j] for j in range(n)) + eps)[:, None]
+        return m, None
+
+    return jax.lax.scan(one_round, m, None, length=iters, unroll=4)[0]
+
+
+def hc_pre(x: jax.Array, pre: jax.Array) -> jax.Array:
+    """What the part reads of the streams ``x`` [B, S, n, C]: ``sum_j
+    pre[j] x[:, :, j]`` [B, S, C], summed in float32 and rounded to the
+    stream's dtype.  Scope ``hc/pre``.  Written as a product broadcast
+    over the channels and a sum over the streams' axis, which XLA fuses
+    into one pass and whose transpose is another such (a sum of slices
+    transposes to pads and adds of whole float32 streams)."""
+    with jax.named_scope("hc"), jax.named_scope("pre"):
+        weights = jnp.moveaxis(pre, 0, -1)[..., None]  # [B, S, n, 1]
+        return jnp.sum(
+            weights * x.astype(jnp.float32), axis=2).astype(x.dtype)
+
+
+def hc_post(
+    x: jax.Array, y: jax.Array, post: jax.Array, res: jax.Array
+) -> jax.Array:
+    """The streams after the part gave ``y`` [B, S, C]: ``x'[:, :, i] =
+    sum_j res[i, j] x[:, :, j] + post[i] y``, float32 inside, the
+    stream's dtype out.  Scope ``hc/post``.  Written as :func:`hc_pre`
+    is."""
+    with jax.named_scope("hc"), jax.named_scope("post"):
+        mix = jnp.moveaxis(res, (0, 1), (2, 3))[..., None]  # [B, S, n, n, 1]
+        write = jnp.moveaxis(post, 0, -1)[..., None]  # [B, S, n, 1]
+        mixed = jnp.sum(
+            mix * x.astype(jnp.float32)[:, :, None], axis=3)  # over j
+        return (mixed + write * y.astype(jnp.float32)[:, :, None]).astype(
+            x.dtype)
 
 
 def squared_relu(h: jax.Array) -> jax.Array:
@@ -507,6 +677,29 @@ _FLASH_TILES = dict(
 # tile in the fused backward, is refused), narrower ones visit more grid
 # steps: 512-wide key blocks 20.49 and 73.7.
 _FLASH_TILES_256 = dict(_FLASH_TILES, block_kv_compute=256)
+# Queries and keys of 192 over values of 128 (latent attention expanded, 32
+# heads: a head's 128 unrotated beside 64 rotated): the kernel takes the
+# two sizes as they come (its ``head_dim_v`` is the values' own; Mosaic
+# lowers the 192-wide contraction).  The backward is UNFUSED: the fused
+# kernel writes S / block_kv float32 partials of the queries' gradient, 8
+# GB at [32, 16384, 192] and key blocks of 1,024 (4 GB at 2,048), which do
+# not fit a step that holds four residual streams a layer; it is the faster
+# kernel alone (85.4 ms forward + backward at key blocks of 2,048, 88.8 at
+# 1,024) and the slower step (none at all).  The fastest of the sweep on a
+# TPU v5e at [1, 32, 16384, 192 | 128] bf16 under a causal mask (PERF.md
+# section 6 "PR 64"; tools/attention_probe.py latent all xing4): forward
+# 24.74 ms (56.4 % of the bf16 peak on the admitted elements) and forward +
+# backward 100.23 ms; the forward's compute tile of 512 takes 25.86 and
+# 101.32, every other block of the three kernels within 1.5 % or slower
+# (512-wide query or key blocks 102.9-105.1), and a 2,048-wide query block
+# or a 4,096-wide key block is refused (VMEM).
+_FLASH_TILES_192_128 = dict(
+    _FLASH_TILES, block_kv_compute=256, block_q_dq=1024, block_kv_dq=1024)
+# the pairs (queries' and keys' head size, values') the kernel was run at
+_FLASH_HEADS = {
+    (64, 64): _FLASH_TILES, (128, 128): _FLASH_TILES,
+    (256, 256): _FLASH_TILES_256, (192, 128): _FLASH_TILES_192_128,
+}
 # Under a shorter window the backward is unfused (a dK/dV and a dQ kernel
 # over grids that shrink to the mask; the fused kernel's cannot, and it
 # writes S / block_kv partials of the queries' gradient, which XLA sums
@@ -640,24 +833,31 @@ def _dividing_tiles(s: int, at_least: int, at_most: int) -> list:
     return [t for t in range(first, at_most + 1, 128) if s % t == 0]
 
 
-def flash_block_sizes(shape: tuple, backend: str, window: int | None = None):
-    """The blocked kernel's ``BlockSizes`` for q/k/v of ``shape`` [B, S, H,
-    hd] on ``backend`` under a causal mask, or under a ``window`` of that
+def flash_block_sizes(
+    shape: tuple, backend: str, window: int | None = None,
+    value_dim: int | None = None,
+):
+    """The blocked kernel's ``BlockSizes`` for q and k of ``shape`` [B, S,
+    H, hd] and values of ``value_dim`` a head (None: as wide as the keys)
+    on ``backend`` under a causal mask, or under a ``window`` of that
     many keys, or None where the kernel cannot run: a backend other than
     ``tpu`` (Mosaic lowering), a length that is no multiple of the
-    128-lane tile or that a tile does not divide, a head size the kernel
-    was never run at (none of which asks for the window).  A pure
+    128-lane tile or that a tile does not divide, a pair of head sizes the
+    kernel was never run at (none of which asks for the window).  A pure
     function of what the call can see."""
     from jax.experimental.pallas.ops.tpu.splash_attention import BlockSizes
 
     _, s, _, hd = shape
-    if backend != "tpu" or s % 128 or hd not in (64, 128, 256):
+    heads = (hd, hd if value_dim is None else value_dim)
+    if backend != "tpu" or s % 128 or heads not in _FLASH_HEADS:
         return None
-    measured = _FLASH_TILES_256 if hd == 256 else _FLASH_TILES
+    measured = _FLASH_HEADS[heads]
     tiles = {name: min(size, s) for name, size in measured.items()}
     if any(s % size for size in tiles.values()):
         return None
     if window is None or window >= tiles["block_kv"]:
+        if "block_q_dq" in tiles:  # an unfused backward's own blocks
+            return BlockSizes(use_fused_bwd_kernel=False, **tiles)
         return BlockSizes(use_fused_bwd_kernel=True, **tiles)
     # the narrowest key block that covers the window and the measured
     # tile (the block of the other regime divides s and covers both, so
@@ -678,11 +878,14 @@ def flash_block_sizes(shape: tuple, backend: str, window: int | None = None):
 def attention_core(
     q: jax.Array, k: jax.Array, v: jax.Array, impl: str = "xla",
     window: int | None = None, diffusion_block: int | None = None,
+    scale: float | None = None,
 ) -> jax.Array:
-    """The causal attention math on pre-projected q [B,S,H,hd] and k, v
-    [B,S,Hkv,hd] — shared by :func:`causal_attention` and the KV-cache
-    decoder's prefill so the two paths cannot diverge numerically per
-    ``impl``.  Both cores take bf16 operands to float32 scores, softmax
+    """The causal attention math on pre-projected q [B,S,H,hd], k
+    [B,S,Hkv,hd] and v [B,S,Hkv,hd_v] (as wide as the keys or not: the
+    result is [B,S,H,hd_v]) — shared by :func:`causal_attention` and the
+    KV-cache decoder's prefill so the two paths cannot diverge numerically
+    per ``impl``.  ``scale`` multiplies the scores (None: ``1 /
+    sqrt(hd)``; YaRN's is that times :func:`yarn_scales`' second).  Both cores take bf16 operands to float32 scores, softmax
     and accumulators; ``flash`` is a kernel where one takes the call and
     ``xla`` where none does: the band kernel
     (:func:`~learning_at_home_tpu.ops.band_attention.band_attention`)
@@ -705,11 +908,17 @@ def attention_core(
             f"no window, got length {q.shape[1]} and window={window}"
         )
     backend = jax.default_backend()
-    if impl == "flash" and band_kernel_fits(q.shape, k.shape[2], window, backend):
+    hd, value_dim = q.shape[-1], v.shape[-1]
+    plain = scale is None and value_dim == hd  # what the band kernel takes
+    if scale is None:
+        scale = 1.0 / hd ** 0.5
+    if impl == "flash" and plain and band_kernel_fits(
+            q.shape, k.shape[2], window, backend):
         # the arrays as they come: no scope ``flash/layout`` on this path
         with jax.named_scope("flash"):
             return band_attention(q, k, v, window, FLASH_RESIDUALS)
-    sizes = flash_block_sizes(q.shape, backend, window) if impl == "flash" else None
+    sizes = flash_block_sizes(
+        q.shape, backend, window, value_dim) if impl == "flash" else None
     if sizes is not None:
         from jax.experimental.pallas.ops.tpu import splash_attention as splash
 
@@ -736,24 +945,32 @@ def attention_core(
         with jax.named_scope("flash"):
             with jax.named_scope("layout"):
                 q, k, v = (
-                    heads_first(q) * (1.0 / hd ** 0.5), heads_first(k),
-                    heads_first(v),
+                    heads_first(q) * scale, heads_first(k), heads_first(v),
                 )
             out = jax.vmap(kernel)(q, k, v)
             with jax.named_scope("layout"):
                 return heads_first(out)
+    how = {} if plain else dict(scale=scale)
+    if value_dim != hd:
+        # values narrower than the keys: the library's core wants one head
+        # size, so the values ride in zero columns up to the keys' and
+        # their part of the result is cut out again (exact: a zero column
+        # of v is a zero column of the weighted sum)
+        v = jnp.pad(v, ((0, 0),) * 3 + ((0, hd - value_dim),))
     if diffusion_block is not None:
         ids = jnp.arange(q.shape[1], dtype=jnp.int32)
-        return jax.nn.dot_product_attention(
+        out = jax.nn.dot_product_attention(
             q, k, v, mask=block_diffusion_mask(
                 ids[:, None], ids[None, :], q.shape[1] // 2, diffusion_block
-            )[None, None],
+            )[None, None], **how,
         )
-    if window is not None:
-        return jax.nn.dot_product_attention(
-            q, k, v, is_causal=True, local_window_size=(window - 1, 0)
+    elif window is not None:
+        out = jax.nn.dot_product_attention(
+            q, k, v, is_causal=True, local_window_size=(window - 1, 0), **how
         )
-    return jax.nn.dot_product_attention(q, k, v, is_causal=True)
+    else:
+        out = jax.nn.dot_product_attention(q, k, v, is_causal=True, **how)
+    return out if value_dim == hd else out[..., :value_dim]
 
 
 def one_query_attention(

@@ -126,6 +126,23 @@ def _lfm2_lines(setup, counters, reference):
         assert 0.0 <= reference[name] < 1e-4, (name, reference[name])
 
 
+def _xing4_lines(setup, counters, reference):
+    assert setup["expert_param_bytes"] > 0
+    assert {"ce_mtp", "hc_res_marginal_error", "hc_stream_rms_spread"} <= set(counters)
+    assert counters["hc_res_marginal_error"]["max"] < 1e-3
+    assert 1.0 <= counters["hc_stream_rms_spread"]["max"] < 4.0
+    # two layers and the prediction block's, at tiny sizes
+    assert len(reference["stream_layers_rms"]) == 3
+    assert len(reference["hc_coeff_layers_rms"]) == 3
+    assert "mtp_logits_rms" in reference
+    assert len(reference["grad_stream_stages_rms"]) == 6
+    for name in ("grads_rms", "grad_stream_rms", "step_grad_norms"):
+        assert 0.0 <= reference[name] < 1e-3, (name, reference[name])
+    # the block's ``phi`` moves by parts in 1e4 of itself a step here: its
+    # change reads 4e-5 to 1e-3 apart by how many steps the 2 s held
+    assert 0.0 <= reference["update_norm"] < 1e-2, reference["update_norm"]
+
+
 class Row(NamedTuple):
     cell: str
     config: str
@@ -187,6 +204,12 @@ ROWS = (
          "shortconv_core_roofline", "attention_core_roofline",
          "expert_matmul_roofline", "dense_ffn_share"),
         ("step_ms_p50", "expert_load_max_over_mean"), 2, _lfm2_lines),
+    Row("xing4.0-29b-a4b-train-zipf16k", "xing4.0-29b-a4b", "train-zipf16k", 1,
+        "manifest_xing4.json", 6400000007, 22,
+        ("mfu", "hc_share", "hc_coeff_share", "hc_mix_share", "hc_mix_roofline",
+         "attention_latent_share", "mtp_share", "attention_core_roofline",
+         "expert_matmul_roofline"),
+        _LEVELLED, 2, _xing4_lines),
 )
 
 

@@ -364,11 +364,15 @@ def _band_reading(cell, window, **also):
 
 
 def _grouped_qkv(cell):
-    """q and the output's cotangent [B, H, S, hd], k and v [B, Hkv, S, hd]."""
+    """q [B, H, S, hd], k [B, Hkv, S, hd], v [B, Hkv, S, hd_v] and the
+    output's cotangent [B, H, S, hd_v]; ``hd_v`` is ``VALUE_DIM``'s for the
+    cell, else ``hd``."""
     b, h, hkv, s, hd, _ = cell
-    q, do, _, _ = qkv((b, h, s, hd))
-    k, v, _, _ = qkv((b, hkv, s, hd))
-    return q, k, v, do
+    hd_v = VALUE_DIM.get(cell, hd)
+    q, k, _, _ = qkv((b, h, s, hd))
+    k = k[:, :hkv]
+    v, do, _, _ = qkv((b, h, s, hd_v))
+    return q, k, v[:, :hkv], do
 
 
 def window(which: str = "all") -> None:
@@ -435,15 +439,25 @@ def window(which: str = "all") -> None:
 
 
 GLM47 = (1, 20, 20, 16384, 256, None)  # glm-4.7-flash-train-zipf16k
+XING4 = (1, 32, 32, 16384, 192, None)  # xing4.0-29b-a4b-train-zipf16k
+VALUE_DIM = {XING4: 128}  # a cell whose values are narrower than its keys
 
 
-def latent(which: str = "all") -> None:
-    """``which``: ``all``, ``sweep`` or ``rule``."""
+def latent(which: str = "all", config: str = "glm47") -> None:
+    """``which``: ``all``, ``sweep`` or ``rule``; ``config``: ``glm47``
+    (heads of 256) or ``xing4`` (queries and keys of 192 over values of
+    128, ``[1, 32, 16384, 192 | 128]``: the fused backward's partials of
+    the queries' gradient, ``S / block_kv_dkv`` float32 copies of ``[32,
+    16384, 192]``, are 8 GB at key blocks of 1,024 and do not fit the
+    step, so the sweep is the unfused backward's, with the fused one at
+    key blocks of 2,048 and 1,024 beside it; ~4 min, PR 64)."""
     from learning_at_home_tpu.models import trunk
 
     require_tpu()
-    cell = GLM47
+    cell = {"glm47": GLM47, "xing4": XING4}[config]
     args = _grouped_qkv(cell)
+    if config == "xing4":
+        return _latent_xing4(which, cell, args)
 
     def read(forward, backward, fused, **also):
         return _window_reading(cell, None, forward, backward, fused, None, args,
@@ -472,6 +486,46 @@ def latent(which: str = "all") -> None:
         read((sizes.block_q, sizes.block_kv, sizes.block_kv_compute),
              (sizes.block_q_dkv, sizes.block_kv_dkv, sizes.block_kv_dkv_compute),
              sizes.use_fused_bwd_kernel, stage="rule")
+
+
+def _latent_xing4(which, cell, args) -> None:
+    from learning_at_home_tpu.models import trunk
+
+    def read(forward, backward, fused, dq=None, **also):
+        return _window_reading(cell, None, forward, backward, fused, dq, args,
+                               **also)
+
+    if which in ("all", "sweep"):
+        forwards = ((1024, 1024, 512), (1024, 1024, 1024), (1024, 1024, 256),
+                    (512, 1024, 512), (2048, 1024, 512), (1024, 2048, 512),
+                    (2048, 2048, 512))
+        same = {t: read(t, (1024, 1024, 512), False, stage="forward")
+                for t in forwards}
+        same = {t: r for t, r in same.items() if r}
+        best = min(same, key=lambda t: same[t][0])
+        for t in ((1024, 1024, 1024), (1024, 1024, 256), (512, 1024, 512),
+                  (1024, 512, 512), (2048, 1024, 512), (1024, 2048, 512),
+                  (2048, 2048, 512), (512, 2048, 512)):
+            read(best, t, False, forward_too=False, stage="unfused")
+        # the dQ kernel's own blocks under the best of the rest
+        for dq in ((512, 1024), (2048, 1024), (1024, 2048), (2048, 2048)):
+            read(best, (1024, 1024, 512), False, dq, forward_too=False,
+                 stage="unfused_dq")
+        for t in ((1024, 2048, 512), (1024, 2048, 1024), (2048, 2048, 512),
+                  (1024, 4096, 512), (1024, 1024, 512)):
+            read(best, t, True, forward_too=False, stage="fused")
+    if which in ("all", "rule"):
+        b, h, _, s, hd, _ = cell
+        sizes = trunk.flash_block_sizes(
+            (b, s, h, hd), "tpu", value_dim=VALUE_DIM[cell])
+        if sizes is None:
+            row(what="latent_rule", refused="flash_block_sizes has no tiles")
+            return
+        fused = sizes.use_fused_bwd_kernel
+        read((sizes.block_q, sizes.block_kv, sizes.block_kv_compute),
+             (sizes.block_q_dkv, sizes.block_kv_dkv, sizes.block_kv_dkv_compute),
+             fused, None if fused else (sizes.block_q_dq, sizes.block_kv_dq),
+             stage="rule")
 
 
 def _core_both(impl):

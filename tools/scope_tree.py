@@ -35,7 +35,10 @@ run's own lines come first (``REFERENCE`` .. ``SCOPES``, the result), then:
   runners' tables match a name anywhere in a path: the prediction block's
   ``mtp/attention`` with the stack's ``attention``); a mixer's scope
   likewise: ``--under delta/core``, ``--under ssm``, ``--under shortconv``
-  (the conv mixer's three parts and the norm and add beside them, PR 61).
+  (the conv mixer's three parts and the norm and add beside them, PR 61),
+  ``--under hc`` (the residual streams' coefficients, Sinkhorn iterations,
+  read and write of every part, the stack's and the prediction block's,
+  forward, remat's second forward and backward, PR 64).
 
 The instructions are written to ``chiprun_out/scope_tree.<cell>.<seed>.json``;
 ``--from`` renders such a file again without a run.
