@@ -1079,6 +1079,29 @@ class DMoETransformerLM:
             extremes[both] = jnp.maximum(extremes[both], aux[both])
         return x, {**(aux or {}), **extremes} or None
 
+    def _folded_layer(self, lp, x, layer_idx, token_mask, kind: AttentionLayer):
+        """:meth:`_layer` as the stack under ``hc_streams`` calls it: the
+        streams come FOLDED (:meth:`_hc_fold`; ONE stream [B, S, d], the
+        embedding or the prediction block's combine, as it is) and go
+        folded, so the fold is what remat keeps of a layer: an array kept
+        across the backward pass is written as it is shaped."""
+        x, aux = self._layer(lp, x, layer_idx, token_mask, kind)
+        return self._hc_fold(x), aux
+
+    @staticmethod
+    def _hc_fold(x):
+        """The streams [B, S, n, d] as ONE row a token, [B, S, n d]: the
+        stream kernels' own tiling (``ops/stream_mix.py``), which [B, S, n,
+        d] with its few streams second to last is not.  This and
+        :meth:`_hc_unfold` are the layout's one place: what is handed from
+        program to program or kept across the backward pass is the fold,
+        and :meth:`_hc_read` takes it."""
+        return x.reshape(*x.shape[:2], -1)
+
+    def _hc_unfold(self, x):
+        """The fold [B, S, n d] as the streams [B, S, n, d]."""
+        return x.reshape(*x.shape[:2], self.cfg.hc_streams, self.cfg.d_model)
+
     def _part_input(self, norm_p, x):
         """What a part of a layer reads: the normalized stream, or the
         stream as it is where the norm is on the part's output."""
@@ -1098,22 +1121,40 @@ class DMoETransformerLM:
         """The streams a part mixes, what it reads of them and what its
         write needs: the stream itself, twice, and None where it is ONE
         stream (``hc`` None: the layer holds no hyper-connection), else the
-        streams ``x`` [B, S, n, d] (one stream [B, S, d] is copied to the
-        ``n``), ``sum_j pre[j] x[:, :, j]`` and ``(post, res, res's
-        marginal error)`` (``trunk.hc_coefficients``).  Every part of every layer
-        passes through this and :meth:`_hc_write`: the stack's, the
-        prediction block's, and the set-up's (``level_router_bias``)."""
+        streams [B, S, n, d], ``sum_j pre[j] x[:, :, j]`` and ``(post,
+        res, res's marginal error)`` (``trunk.hc_coefficients``).  ``x`` is
+        the streams [B, S, n, d], their fold [B, S, n d] (:meth:`_hc_fold`:
+        what the stack and the set-up hand on) or ONE stream [B, S, d],
+        which is copied to the ``n``.  Every part of every layer passes
+        through this and :meth:`_hc_write`: the stack's, the prediction
+        block's, and the set-up's (``level_router_bias``)."""
         if hc is None:
             return x, x, None
         cfg = self.cfg
-        if x.ndim == 3:  # ONE stream comes in: the stack's first layer's
-            # embedding, the block's combine.  Copied HERE, inside the
-            # layer's checkpoint, so what remat keeps of it is one stream
-            x = self._hc_copy(x)
+        if x.ndim == 4:
+            x = self._hc_fold(x)
+        elif x.shape[-1] == cfg.d_model:  # ONE stream comes in: the
+            # stack's first layer's embedding, the block's combine.  Copied
+            # HERE, inside the layer's checkpoint, so what remat keeps of
+            # it is one stream (a fold is ``n`` times as wide a row)
+            x = self._hc_fold(self._hc_copy(x))
+
+        # the coefficients, the read and the write each unfold the streams
+        # for themselves out of the ONE fold: autodiff then adds their
+        # three gradients in the fold, a bitcast of what the kernels give
+        # and take; added as [B, S, n, d] the sum and each of its terms is
+        # copied to another tiling, six passes a part (PERF.md section 6,
+        # PR 65).  The unfolds are named hc/pre: the sum is made where they
+        # are transposed, XLA fuses the read's own gradient ``pre[j] dh``
+        # into it, and the pass is the mixing's (xing4.hc_mix_share)
+        def unfolded():
+            with jax.named_scope("hc"), jax.named_scope("pre"):
+                return self._hc_unfold(x)
+
         pre, *write = hc_coefficients(
-            hc, x, cfg.hc_sinkhorn_iters, cfg.hc_eps, cfg.hc_res_clamp,
-            cfg.norm_eps)
-        return x, hc_pre(x, pre), write
+            hc, unfolded(), cfg.hc_sinkhorn_iters, cfg.hc_eps,
+            cfg.hc_res_clamp, cfg.norm_eps)
+        return unfolded(), hc_pre(unfolded(), pre), write
 
     @staticmethod
     def _hc_write(x, out, write):
@@ -1306,7 +1347,7 @@ class DMoETransformerLM:
                 x = x + params["pos"][
                     None, : token_ids.shape[1]
                 ].astype(cfg.dtype)
-        layer_fn = self._layer
+        layer_fn = self._layer if cfg.hc_streams is None else self._folded_layer
         if cfg.remat:
             # kind is static: a window or a rotation is part of the program.
             # Kept across the backward pass: the blocked attention
@@ -1389,7 +1430,7 @@ class DMoETransformerLM:
         if self._zig is not None:
             x = x[:, self._zig_inv]
         if cfg.hc_streams is not None:
-            x, spread = self._hc_sum(x)
+            x, spread = self._hc_sum(self._hc_unfold(x))
             extremes["hc_stream_rms_spread"] = [spread]
         x = self._norm(params["ln_f"], x)
         n_moe = cfg.mixture_layers()
@@ -1415,10 +1456,16 @@ class DMoETransformerLM:
 
     def _hc_copy(self, x):
         """The stream [B, S, d] copied to the ``hc_streams`` streams [B, S,
-        n, d] the layers hand on."""
+        n, d] the layers hand on.  Written as the FOLD's copy, ``[x | x |
+        ..]`` [B, S, n d], unfolded: what reads the streams folds them
+        again (:meth:`_hc_read`), and the copy's transpose is then a sum of
+        the fold's ``n`` lane-aligned slices; as a broadcast to [B, S, n,
+        d] the gradient's terms were each copied to that shape's tiling
+        before the sum (PERF.md section 6, PR 65)."""
         n = self.cfg.hc_streams
         with jax.named_scope("hc"), jax.named_scope("copy"):
-            return jnp.broadcast_to(x[:, :, None], (*x.shape[:2], n, x.shape[2]))
+            return jnp.concatenate([x] * n, axis=-1).reshape(
+                *x.shape[:2], n, x.shape[2])
 
     @staticmethod
     def _hc_sum(x):
@@ -1460,7 +1507,7 @@ class DMoETransformerLM:
                 cfg.attention_layer(cfg.n_layers),
             )
         if cfg.hc_streams is not None:  # hyper-connections of the block's own
-            z, spread = self._hc_sum(z)
+            z, spread = self._hc_sum(self._hc_unfold(z))
             aux = {**aux, "hc_stream_rms_spread": spread}
         return self._norm(mp["out_norm"], z), aux
 
@@ -2120,9 +2167,22 @@ class DMoETransformerLM:
         if not cfg.router_bias:
             return params, []
         embed = jax.jit(lambda table, ids: table[ids].astype(cfg.dtype))
-        attend = jax.jit(self._attention_block, static_argnums=(2,))
-        finish = jax.jit(self._ffn_block)
-        whole = jax.jit(self._layer, static_argnums=(4,))
+
+        def folded(part):
+            """``part(lp, x, ..) -> (x, ..)`` giving the streams FOLDED, as
+            the step's layers do (:meth:`_folded_layer`; it takes them
+            folded as it is): a program's argument or result [B, S, n, d]
+            lies in that shape's tiling and is copied to the stream
+            kernels' on the way in and on the way out, two arrays of four
+            streams more a program."""
+            def giving_the_fold(lp, x, *rest):
+                x, *others = part(lp, x, *rest)
+                return (self._hc_fold(x), *others)
+            return part if cfg.hc_streams is None else giving_the_fold
+
+        attend = jax.jit(folded(self._attention_block), static_argnums=(2,))
+        finish = jax.jit(folded(self._ffn_block))
+        whole = jax.jit(folded(self._layer), static_argnums=(4,))
         scores = jax.jit(lambda lp, x: jax.nn.sigmoid(self.moe.router_logits(
             lp["moe"],
             self._part_input(
@@ -2130,8 +2190,20 @@ class DMoETransformerLM:
             ).reshape(-1, cfg.d_model))))
         # what the block's combine reads: the streams summed, or the stream
         total = (lambda x: x) if cfg.hc_streams is None else jax.jit(
-            lambda x: self._hc_sum(x)[0])
+            lambda x: self._hc_sum(self._hc_unfold(x))[0])
         streams = [embed(params["embed"], ids) for ids in token_batches]
+
+        def advance(part):
+            """Each batch's stream through ``part`` in its place, TWO
+            programs in flight: a list built beside the old one holds both
+            generations, and eight programs enqueued at once hold eight
+            programs' temporaries (under ``hc_streams`` that was the
+            process's peak, 15.7 GB: PERF.md section 6, PR 65)."""
+            for j in range(len(streams)):
+                streams[j] = part(streams[j])
+                if j:  # the one before: the device is never left waiting
+                    jax.block_until_ready(streams[j - 1])
+
         layers, loads = list(params["layers"]), []
         if cfg.router_input != "moe_input":
             raise NotImplementedError(
@@ -2140,8 +2212,7 @@ class DMoETransformerLM:
         for i, lp in enumerate(layers):
             one_mixer = "norm" in lp  # its router reads the layer's input
             if not one_mixer:
-                streams = [
-                    attend(lp, x, cfg.attention_layer(i))[0] for x in streams]
+                advance(lambda x: attend(lp, x, cfg.attention_layer(i))[0])
             if "moe" in lp:
                 bias, load = level_bias(
                     jnp.concatenate([scores(lp, x) for x in streams]),
@@ -2149,11 +2220,9 @@ class DMoETransformerLM:
                 )
                 lp = layers[i] = {**lp, "moe": {**lp["moe"], "router_bias": bias}}
                 loads.append(load)
-            streams = [
-                whole(lp, x, i, None, cfg.attention_layer(i))[0] if one_mixer
-                else finish(lp, x, None, i)[0]
-                for x in streams
-            ]
+            advance(
+                (lambda x: whole(lp, x, i, None, cfg.attention_layer(i))[0])
+                if one_mixer else (lambda x: finish(lp, x, None, i)[0]))
         params = {**params, "layers": tuple(layers)}
         if "mtp" in params:
             # the block's router too, on what the block's attention leaves
@@ -2163,14 +2232,14 @@ class DMoETransformerLM:
             combine = jax.jit(lambda mp, ln_f, table, x, ids: self._mtp_input(
                 mp, self._norm(ln_f, total(x)),
                 jnp.concatenate([ids[:, 1:], ids[:, -1:]], axis=1), table))
-            streams = [
-                attend(
+            for j, ids in enumerate(token_batches):  # as ``advance``
+                streams[j] = attend(
                     mp["layer"],
-                    combine(mp, params["ln_f"], params["embed"], x, ids),
+                    combine(mp, params["ln_f"], params["embed"], streams[j], ids),
                     cfg.attention_layer(cfg.n_layers),
                 )[0]
-                for x, ids in zip(streams, token_batches)
-            ]
+                if j:
+                    jax.block_until_ready(streams[j - 1])
             lp = mp["layer"]
             bias, load = level_bias(
                 jnp.concatenate([scores(lp, x) for x in streams]),

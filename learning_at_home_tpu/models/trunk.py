@@ -23,6 +23,7 @@ from learning_at_home_tpu.ops.gate_norm import gated_rms_norm
 from learning_at_home_tpu.ops.short_conv import gated_short_conv
 from learning_at_home_tpu.ops.ssd import ssd_chunked
 from learning_at_home_tpu.ops.ssm_conv import causal_conv_silu
+from learning_at_home_tpu.ops.stream_mix import stream_read, stream_write, token_stats
 
 
 def layer_norm(p: dict, x: jax.Array, eps: float = 1e-5) -> jax.Array:
@@ -343,19 +344,22 @@ def hc_coefficients(
 
     The norm's scalar a token is taken out of the product: ``(x phi) *
     rsqrt(..)``, the stream's own dtype times ``phi`` in it, summed in
-    float32.  Scopes ``hc/coeff`` (the norm, the product, the sigmoids)
-    and ``hc/sinkhorn``."""
+    float32.  The mean square and the product are ONE pass over the streams
+    (:func:`~learning_at_home_tpu.ops.stream_mix.token_stats`: on a TPU the
+    kernel ``stream_stats_fwd`` where ``stream_mix_fits`` takes the shape,
+    its backward ``stream_stats_bwd``; the plain form on the CPU and for
+    every other shape, in which XLA passes over the streams once for the
+    norm and once for the product, through a float32 copy of them, three
+    times a part a step: 132 ms of the ``xing4.0-29b-a4b`` step where a
+    read of the streams a pass is 21, PERF.md section 6, PR 64 and PR 65);
+    everything after it is XLA's, on 24 numbers a token.  Scopes
+    ``hc/coeff`` (the statistics, the sigmoids) and ``hc/sinkhorn``."""
     n = x.shape[2]
     f32 = jnp.float32
     with jax.named_scope("hc"):
         with jax.named_scope("coeff"):
-            x32 = x.astype(f32)
-            inv_rms = jax.lax.rsqrt(
-                jnp.mean(x32 * x32, axis=(2, 3)) + norm_eps)  # [B, S]
-            m = jnp.einsum(
-                "bsnc,nco->obs", x,
-                p["phi"].astype(x.dtype).reshape(n, x.shape[3], -1),
-                preferred_element_type=f32) * inv_rms
+            ms, m = token_stats(x, p["phi"])
+            m = m * jax.lax.rsqrt(ms + norm_eps)  # [B, S]
             alpha, b = p["alpha"].astype(f32), p["b"].astype(f32)[:, None, None]
             pre = jax.nn.sigmoid(alpha[0] * m[:n] + b[:n])
             post = 2.0 * jax.nn.sigmoid(alpha[1] * m[n:2 * n] + b[n:2 * n])
@@ -392,14 +396,18 @@ def sinkhorn(m: jax.Array, iters: int, eps: float) -> jax.Array:
 def hc_pre(x: jax.Array, pre: jax.Array) -> jax.Array:
     """What the part reads of the streams ``x`` [B, S, n, C]: ``sum_j
     pre[j] x[:, :, j]`` [B, S, C], summed in float32 and rounded to the
-    stream's dtype.  Scope ``hc/pre``.  Written as a product broadcast
-    over the channels and a sum over the streams' axis, which XLA fuses
-    into one pass and whose transpose is another such (a sum of slices
-    transposes to pads and adds of whole float32 streams)."""
+    stream's dtype.  Scope ``hc/pre``.
+    :func:`~learning_at_home_tpu.ops.stream_mix.stream_read`: on a TPU the
+    kernels ``stream_read_fwd`` / ``stream_read_bwd`` where
+    ``stream_mix_fits`` takes the shape, one read of the streams each; the
+    plain form (a product broadcast over the channels and a sum over the
+    streams' axis) on the CPU and for every other shape.  XLA does NOT fuse
+    the plain form into one pass on the chip: the trace of the
+    ``xing4.0-29b-a4b`` step read 39 ms a step under this scope where the
+    reads and the write are 17 at the HBM's peak (PERF.md section 6, PR 64
+    and PR 65)."""
     with jax.named_scope("hc"), jax.named_scope("pre"):
-        weights = jnp.moveaxis(pre, 0, -1)[..., None]  # [B, S, n, 1]
-        return jnp.sum(
-            weights * x.astype(jnp.float32), axis=2).astype(x.dtype)
+        return stream_read(x, pre)
 
 
 def hc_post(
@@ -407,15 +415,17 @@ def hc_post(
 ) -> jax.Array:
     """The streams after the part gave ``y`` [B, S, C]: ``x'[:, :, i] =
     sum_j res[i, j] x[:, :, j] + post[i] y``, float32 inside, the
-    stream's dtype out.  Scope ``hc/post``.  Written as :func:`hc_pre`
-    is."""
+    stream's dtype out.  Scope ``hc/post``.
+    :func:`~learning_at_home_tpu.ops.stream_mix.stream_write`: on a TPU the
+    kernels ``stream_write_fwd`` / ``stream_write_bwd`` where
+    ``stream_mix_fits`` takes the shape, one read of each array and one
+    write of each result; the plain form, written as :func:`hc_pre`'s,
+    elsewhere.  Of the plain form XLA made fusions that took the time of 29
+    passes over a stream set where the work is 2.25 reads and a write (199
+    ms of the ``xing4.0-29b-a4b`` step: PERF.md section 6, PR 64 and PR
+    65)."""
     with jax.named_scope("hc"), jax.named_scope("post"):
-        mix = jnp.moveaxis(res, (0, 1), (2, 3))[..., None]  # [B, S, n, n, 1]
-        write = jnp.moveaxis(post, 0, -1)[..., None]  # [B, S, n, 1]
-        mixed = jnp.sum(
-            mix * x.astype(jnp.float32)[:, :, None], axis=3)  # over j
-        return (mixed + write * y.astype(jnp.float32)[:, :, None]).astype(
-            x.dtype)
+        return stream_write(x, y, post, res)
 
 
 def squared_relu(h: jax.Array) -> jax.Array:

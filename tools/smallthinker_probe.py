@@ -9,6 +9,7 @@
     chiprun -- python tools/smallthinker_probe.py conv [rows x channels ...]
     chiprun -- python tools/smallthinker_probe.py conv gated [rows x channels x strip ...]
     chiprun -- python tools/smallthinker_probe.py gate_norm [rows x strip ...]
+    chiprun -- python tools/smallthinker_probe.py streams [rows x strip x chunk x stats_rows ...]
     chiprun -- python tools/smallthinker_probe.py delta [seq_len] [accuracy_len] [calls] [form ...] [config ...]
     chiprun -- python tools/smallthinker_probe.py delta split [seq_len] [chunk]
     chiprun -- python tools/smallthinker_probe.py delta parts [seq_len] [chunk] [calls] [config ...]
@@ -90,6 +91,21 @@ cotangent), GB/s on the LEAST bytes a pass moves (forward ``y``, ``z``
 ``dy``, ``dz`` (and ``dx``) out), and the kernel's output and gradients
 against the plain form's.  ``256x32``-like arguments time the kernel at
 those row blocks and strips too (PERF.md section 6, PR 47).
+
+``streams`` (on the chip): the residual streams' three stages
+(``ops/stream_mix.py``) at the ``xing4.0-29b-a4b`` cell's shape (``[1,
+16384, 4, 3584]`` bf16, ``phi`` [14336, 24]), the plain form (on [B, S, n,
+C] arguments, as the step before PR 65 held them) against the kernels (on
+FOLDED arguments [B, S, n C], as the step hands them on since: a bitcast
+of the kernels' view): milliseconds a call forward and forward + backward
+(a ``jax.vjp`` under given cotangents), GB/s on the LEAST bytes a pass
+moves (the write: ``x`` and ``y`` in and ``x'`` out, then those and the
+cotangent in, ``dx`` and ``dy`` out; the read: ``x`` in and ``h`` out, then
+``x`` and ``dh`` in and ``dx`` out; the statistics: ``x`` in, then ``x`` in
+and ``dx`` out), and the kernels' results and gradients against the plain
+form's.  ``64x16x128x256``-like arguments time the kernels at those blocks
+too (the mixing kernels' rows, strip and chunk of lanes, the statistics'
+rows; PERF.md section 6, PR 65).
 
 ``delta`` (on the chip): the chunked gated delta rule of
 ``ops/delta_rule.py`` at the Olmo-Hybrid cell's shape (``[1, seq_len, 30,
@@ -255,6 +271,67 @@ def moe_rows_kernel_calls(compiled_text: str) -> dict:
     return found
 
 
+def stream_kernel_calls(compiled_text: str, jaxpr) -> dict:
+    """The residual streams' kernels (``ops/stream_mix.py``; PR 65) in a
+    compiled step: by scope (``hc/coeff``, ``hc/pre``, ``hc/post``) and
+    kernel name how many instructions the text holds forward, under remat
+    (``rematted_computation`` in the ``op_name``) and backward, with the
+    block of rows and the grid each got in the traced step; under
+    ``outside_the_scopes`` the calls whose ``op_name`` lost the scope (the
+    benchmark's ``xing4.hc_*`` readings would miss them); and under
+    ``stream_sized_results_of_xla`` the results of four streams' size that
+    XLA's own instructions still write under scope ``hc`` (the copy of one
+    stream to the four, a shape the rule refused: the plain form's), by
+    the scope's last two names.  Twelve parts a step: twelve forward calls a
+    stage, twelve under remat (fewer of the write: a layer's last is not
+    read again) and twelve backward where the rule took every call."""
+    found: dict = {}
+    outside = 0
+    for name, rest in re.findall(
+            r"^\s*%(stream_(?:stats|read|write)_(?:fwd|bwd))[\w.]* = [^\n]*custom-call\((.*?)(?=^\s*%|\Z)",
+            compiled_text, re.M | re.S):
+        op_name = re.search(r'op_name="([^"]*)"', rest)
+        scope = op_name and re.search(
+            r"/(hc/(?:coeff|pre|post))/", _bare(op_name.group(1)))
+        if not scope:
+            outside += 1
+            continue
+        way = ("backward" if name.endswith("bwd") else
+               "remat" if "rematted_computation" in op_name.group(1) else "forward")
+        entry = found.setdefault(scope.group(1), {}).setdefault(name, {})
+        entry[way] = entry.get(way, 0) + 1
+    for path, eqn in _equations(jaxpr, "pallas_call"):
+        scope = re.search(r"(hc/(?:coeff|pre|post))", _bare(path))
+        name = eqn.params["name"]
+        if scope and name in found.get(scope.group(1), {}):
+            mapping = eqn.params["grid_mapping"]
+            found[scope.group(1)][name].update(
+                rows=mapping.block_mappings[0].block_shape[0].block_size,
+                grid=list(mapping.grid))
+    sized = collections.Counter()
+    entry = compiled_text[compiled_text.find("\nENTRY"):]  # not the fusions' bodies
+    for dims, op_name in re.findall(
+            r'^\s*%\S+ = (?:bf16|f32)\[([\d,]+)\][^\n]* (?:fusion|copy|reshape|broadcast)\('
+            r'[^\n]*op_name="([^"\n]*)"', entry, re.M):
+        scope = re.search(r"/(hc/\w+)/", _bare(op_name))
+        if scope and _stream_sized(dims):
+            sized[scope.group(1)] += 1
+    return {"calls": found, "outside_the_scopes": outside,
+            "stream_sized_results_of_xla": dict(sized)}
+
+
+def _bare(op_name: str) -> str:
+    """An ``op_name`` without the transformations' brackets: ``jvp(hc)/pre``
+    and ``transpose(jvp(hc))/pre`` are both ``hc/pre``."""
+    return re.sub(r"\w+\(|\)", "", op_name)
+
+
+def _stream_sized(dims: str, tokens: int = 16384, width: int = 4 * 3584) -> bool:
+    import numpy as np
+
+    return int(np.prod([int(n) for n in dims.split(",")])) == tokens * width
+
+
 # the attention kernels' instruction names begin with one of these: the
 # blocked kernel's (splash attention) and the band kernel's
 # (``ops/band_attention.py``: a window shorter than the key block, PR 63)
@@ -348,7 +425,9 @@ def step_memory(chip, recipe: str = "smallthinker_one_chip") -> dict:
     (:func:`recomputed_attention_products`: none, or the latent form's
     three up a layer; PR 53), under ``moe_rows_kernel_calls`` the
     sorted expert layer's row movements (:func:`moe_rows_kernel_calls`;
-    PR 50), under ``scan_kernel_calls`` and
+    PR 50), under ``stream_kernel_calls`` the residual streams' kernels by
+    scope and direction with the block each got
+    (:func:`stream_kernel_calls`; PR 65), under ``scan_kernel_calls`` and
     ``kept_scan_bytes`` the same two for the state-space scan's kernels
     (:func:`scan_kernel_calls`; PR 40), under ``delta_kernel_calls`` the
     delta rule's kernels under ``delta/core`` (one forward a delta layer
@@ -420,6 +499,7 @@ def step_memory(chip, recipe: str = "smallthinker_one_chip") -> dict:
         "attention_kernel_calls": attention_kernel_calls(text),
         "attention_stages": attention_stages(text),
         "moe_rows_kernel_calls": moe_rows_kernel_calls(text),
+        "stream_kernel_calls": stream_kernel_calls(text, traced.jaxpr.jaxpr),
         "kept_residual_bytes": kept_residual_bytes(traced.jaxpr.jaxpr),
         "kept_product_bytes": kept_residual_bytes(
             traced.jaxpr.jaxpr, ATTENTION_PRODUCTS),
@@ -787,6 +867,102 @@ def gate_norm(blocks: list, calls: int = 20) -> None:
             }), flush=True)
 
 
+def streams(blocks: list, calls: int = 10, shape=(1, 16384, 4, 3584)) -> None:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from learning_at_home_tpu.ops import stream_mix as ops
+
+    b, s, n, c = shape
+    o = 2 * n + n * n
+    rs = np.random.default_rng(6500000007)
+    names = ("_ROWS", "_STRIP", "_CHUNK", "_STATS_ROWS")
+    committed = tuple(getattr(ops, name) for name in names)
+
+    def normal(*shape, dtype=jnp.bfloat16, scale=1.0):
+        return jnp.asarray(scale * rs.standard_normal(shape), dtype)
+
+    def uniform(*shape):
+        return jnp.asarray(rs.uniform(0.1, 1.0, shape), jnp.float32)
+
+    x, y = normal(b, s, n, c), normal(b, s, c)
+    f32 = jnp.float32
+    # (stage, plain, kernel, operands, the cotangent, passes over ONE stream
+    # [B, S, C] of the least bytes forward and backward, the results' names)
+    stages = [
+        ("write", ops.stream_write_plain, ops.stream_write_kernel,
+         (x, y, uniform(n, b, s), uniform(n, n, b, s)), normal(b, s, n, c),
+         (2 * n + 1, 3 * n + 2), ("out", "dx", "dy", "dpost", "dres")),
+        ("read", ops.stream_read_plain, ops.stream_read_kernel,
+         (x, uniform(n, b, s)), normal(b, s, c),
+         (n + 1, 2 * n + 1), ("out", "dx", "dpre")),
+        ("stats", ops.token_stats_plain, ops.token_stats_kernel,
+         (x, normal(n * c, o, dtype=f32, scale=(n * c) ** -0.5)),
+         (normal(b, s, dtype=f32), normal(o, b, s, dtype=f32)),
+         (n, 2 * n), ("ms", "m", "dx", "dphi")),
+    ]
+    least = y.size * y.dtype.itemsize  # one pass over [B, S, C] bf16
+
+    def fold_of(a):
+        return a.reshape(b, s, n * c) if a.shape == (b, s, n, c) else a
+
+    def folded(form):
+        """``form`` between FOLDED streams [B, S, n C], as the step hands
+        them on (``Transformer._hc_read``): unfolded inside the program, a
+        bitcast of the kernels' own view; an ARGUMENT [B, S, n, C] comes in
+        that shape's tiling and is copied to the kernels' in front of
+        every call, two passes that no call in the step pays."""
+        def fn(fold, *rest):
+            return jax.tree_util.tree_map(
+                fold_of, form(fold.reshape(b, s, n, c), *rest))
+        return fn
+
+    def both(form):
+        def fn(operands, cotangent):
+            out, back = jax.vjp(form, *operands)
+            return (*jax.tree_util.tree_leaves(out), *back(cotangent))
+        return jax.jit(fn)
+
+    for stage, plain, kernel, operands, cotangent, passes, results in stages:
+        want = None
+        forms = [("plain", plain, None), ("kernel", kernel, committed)] + [
+            ("kernel", kernel, tuple(int(k) for k in a.split("x"))) for a in blocks]
+        for name, form, at in forms:
+            for key, value in zip(names, at or ()):
+                setattr(ops, key, value)
+            args, given = operands, cotangent
+            if name == "kernel":
+                form = folded(form)
+                args = (fold_of(operands[0]), *operands[1:])
+                given = jax.tree_util.tree_map(fold_of, cotangent)
+            try:
+                got = jax.device_get(both(form)(args, given))
+                forward = _ms(jax.jit(lambda *a, form=form: form(*a)), args, calls)
+                forward_backward = _ms(both(form), (args, given), calls)
+            except Exception as e:  # a block the compiler refuses
+                print("STREAMS " + json.dumps({
+                    "stage": stage, "form": name, "rows_strip_chunk_stats_rows": at,
+                    "refused": str(e)[:300]}), flush=True)
+                continue
+            finally:
+                for key, value in zip(names, committed):
+                    setattr(ops, key, value)
+            got = [a.reshape(w.shape) for a, w in zip(got, want or got)]
+            want = want or got
+            print("STREAMS " + json.dumps({
+                "stage": stage, "form": name, "rows_strip_chunk_stats_rows": at,
+                "shape": list(x.shape),
+                "forward_ms": forward, "forward_backward_ms": forward_backward,
+                "backward_ms": forward_backward - forward,
+                "forward_gb_s_on_least_bytes": passes[0] * least / forward / 1e6,
+                "backward_gb_s_on_least_bytes":
+                    passes[1] * least / (forward_backward - forward) / 1e6,
+                "rms_against_plain": {
+                    k: _rel_rms(a, b_) for k, a, b_ in zip(results, got, want)},
+            }), flush=True)
+
+
 def _delta_shape(config: str) -> tuple:
     """``(heads, a head's keys, a head's values)`` of the rule as a
     configuration's mixer calls it: over the VALUE heads (the key heads are
@@ -1093,6 +1269,8 @@ if __name__ == "__main__":
         conv(sys.argv[2:])
     elif sys.argv[1:2] == ["gate_norm"]:
         gate_norm(sys.argv[2:])
+    elif sys.argv[1:2] == ["streams"]:
+        streams(sys.argv[2:])
     elif sys.argv[1:2] == ["ssd"]:
         ssd(*(int(a) for a in sys.argv[2:4]))
     elif sys.argv[1:3] == ["delta", "split"]:
