@@ -36,6 +36,7 @@ from learning_at_home_tpu.models.trunk import (
     block_diffusion_admitted_pairs,
     block_diffusion_visited_pairs,
     delta_mixer,
+    head_gate,
     flash_block_sizes,
     gate_activation,
     gated_mlp,
@@ -52,7 +53,7 @@ from learning_at_home_tpu.models.trunk import (
     ssm_mixer,
     yarn_scales,
 )
-from learning_at_home_tpu.ops.delta_rule import DELTA_RESIDUALS
+from learning_at_home_tpu.ops.delta_rule import DELTA_RESIDUALS, channel_decay_fits
 from learning_at_home_tpu.ops.moe_dispatch import balanced_bias, level_bias
 from learning_at_home_tpu.ops.ssd import SSD_RESIDUALS
 from learning_at_home_tpu.parallel.mesh import batch_sharding
@@ -216,10 +217,12 @@ class DMoETransformerConfig:
     head_dim: int | None = None
     # latent attention (trunk.latent_qkv_projections): the keys and values
     # of every head are expanded from one normalized latent of
-    # kv_latent_dim a token, the queries from one of q_latent_dim; of a
-    # head's head_dim the last rope_head_dim are rotated, and the keys'
-    # rotated part is one a token, shared by the heads.  None = the plain
-    # projections
+    # kv_latent_dim a token, the queries from one of q_latent_dim (None
+    # beside a kv_latent_dim: no query latent and no norm of it, the
+    # queries one plain product, DeepSeek-V2-Lite's form); of a head's
+    # head_dim the last rope_head_dim are rotated, and the keys' rotated
+    # part is one a token, shared by the heads.  kv_latent_dim None = the
+    # plain projections
     kv_latent_dim: int | None = None
     q_latent_dim: int | None = None
     rope_head_dim: int | None = None
@@ -228,8 +231,11 @@ class DMoETransformerConfig:
     rotary_dim: int | None = None
     # a softmax layer's output is multiplied by sigmoid(gate) before wo, the
     # gate a second half of wq's columns a head (Qwen3-Next's full-attention
-    # layers, trunk.gated_qkv_projections)
-    attention_gate: bool = False
+    # layers, trunk.gated_qkv_projections); 'head': by ONE number a head a
+    # token, sigmoid(x w_gate) of a projection [d, H] of its own, on the
+    # plain or the latent projections and on a channel-decayed delta layer
+    # alike (Ling-3.0's gated_attention_proj_granularity_type 'head_wise')
+    attention_gate: bool | str = False
     # where the layers of the stack differ: one AttentionLayer a layer,
     # or one period of them, repeated; None = every layer global, rotated
     # where positions == 'rope'
@@ -278,6 +284,11 @@ class DMoETransformerConfig:
     # router_bias_rate toward level loads (arXiv:2408.15664)
     router_bias: bool = False
     router_bias_rate: float = 0.0
+    # (n_group, topk_group): the sigmoid router chooses its k inside the
+    # topk_group best of n_group groups of consecutive experts, a group
+    # scored by the sum of its two best selection scores (DeepSeek-V3's
+    # group-limited routing, ops.moe_dispatch.group_limited); None = none
+    router_groups: tuple[int, int] | None = None
     # this program holds held_experts of the num_experts each router
     # scores, from first_held_expert on: one chip's share of layers whose
     # experts no chip holds whole (None = all of them)
@@ -327,6 +338,15 @@ class DMoETransformerConfig:
     # beta = 2 sigmoid(b) (Olmo-Hybrid's linear_allow_neg_eigval) or, False,
     # sigmoid(b) (Qwen3-Next)
     delta_neg_eigval: bool = True
+    # None: one decay a head, g = -exp(A_log) softplus(a + dt_bias), out of
+    # the one in-projection (Gated DeltaNet).  A number (Kimi Delta
+    # Attention, arXiv:2510.26692; Ling-3.0's kda_lower_bound -5 under
+    # kda_safe_gate): a decay a KEY CHANNEL from a projection [d, H dk] of
+    # its own, g = floor * sigmoid(exp(A_log) (f + dt_bias)) in (floor, 0),
+    # dt_bias a channel; the in-projection is [q | k | v], the write
+    # strengths a projection [d, H] of their own, and the output's gate one
+    # number a head (attention_gate 'head') after the norm
+    delta_decay_floor: float | None = None
     # what a training row is and what the loss asks of it.  'next_token':
     # the row's ids, a causal mask, the mean CE of each position's next
     # token.  'block_diffusion' (BD3-LMs, arXiv:2503.09573, as SDAR trains):
@@ -556,13 +576,15 @@ class DMoETransformerLM:
                     "ring's sequence shards, and nothing carries a state "
                     "from shard to shard"
                 )
-        latent = (config.kv_latent_dim, config.q_latent_dim, config.rope_head_dim)
-        if any(size is not None for size in latent):
-            if None in latent or config.head_dim is None:
+        latent = (config.kv_latent_dim, config.rope_head_dim, config.head_dim)
+        if any(size is not None for size in (
+                config.kv_latent_dim, config.q_latent_dim, config.rope_head_dim)):
+            if None in latent:  # q_latent_dim None: no query latent
                 raise ValueError(
-                    "latent attention is kv_latent_dim, q_latent_dim, "
-                    "rope_head_dim and head_dim together, got "
-                    f"{latent} and head_dim={config.head_dim}"
+                    "latent attention is kv_latent_dim, rope_head_dim and "
+                    "head_dim together (and q_latent_dim, or None for "
+                    f"queries of one plain product), got {latent} and "
+                    f"q_latent_dim={config.q_latent_dim}"
                 )
             if config.n_kv_heads not in (None, config.n_heads) or config.qk_norm:
                 raise ValueError(
@@ -577,7 +599,12 @@ class DMoETransformerLM:
                     f"rope_head_dim={config.rope_head_dim} is the rotated, "
                     f"even part of head_dim={config.head_dim}"
                 )
-        if (config.rotary_dim is not None or config.attention_gate) and (
+        if config.attention_gate not in (False, True, "head"):
+            raise ValueError(
+                f"attention_gate must be False, True or 'head', got "
+                f"{config.attention_gate!r}"
+            )
+        if (config.rotary_dim is not None or config.attention_gate is True) and (
             config.kv_latent_dim is not None or config.qk_norm is True
             or config.seq_parallel
         ):
@@ -586,6 +613,32 @@ class DMoETransformerLM:
                 "projections with no norm or one over each head: no latent "
                 "attention, no norm over the whole queries (qk_norm=True), "
                 "no ring (seq_parallel: its projections hand no gate over)"
+            )
+        if config.attention_gate == "head" and config.seq_parallel:
+            raise ValueError(
+                "attention_gate='head' under seq_parallel: the ring's "
+                "projections hand no gate over"
+            )
+        channel_decay = config.delta_decay_floor is not None
+        if (channel_decay and not self._delta) or (
+            self._delta and channel_decay != (config.attention_gate == "head")
+        ):
+            raise ValueError(
+                "delta_decay_floor (a decay a key channel) and "
+                "attention_gate='head' go together in a stack with a "
+                "'delta' layer: the channel-decayed mixer's output gate is "
+                "one number a head, the head-decayed mixer's one a channel "
+                f"(got {config.delta_decay_floor} and "
+                f"{config.attention_gate!r})"
+            )
+        if channel_decay and (
+            config.delta_value_heads not in (None, config.n_heads)
+            or not channel_decay_fits(config.delta_decay_floor)
+        ):
+            raise ValueError(
+                f"delta_decay_floor={config.delta_decay_floor}: a decay a "
+                "key channel has as many value heads as key heads and a "
+                "floor that ops.delta_rule.channel_decay_fits admits"
             )
         if config.shared_expert_gate and (
             not config.shared_experts or config.norm_place != "input"
@@ -754,6 +807,7 @@ class DMoETransformerLM:
                 router_score=config.router_score,
                 router_bias=config.router_bias,
                 routed_scale=config.routed_scale,
+                router_groups=config.router_groups,
             )
         )
         self._ring = None
@@ -867,26 +921,56 @@ class DMoETransformerLM:
             }
 
         def delta(key):
-            """The delta-rule mixer: ``w_in``'s columns are [q | k | v | z |
-            b | a]; the filters of q, k and v lecun-normal over their taps
-            and no bias; ``A_log`` and ``dt_bias`` drawn as the
-            state-space mixer's (float32 whatever the parameters' dtype,
-            as the decays' arithmetic is), one of each a VALUE head; one
-            output-norm scale of a head's value size, shared by the heads
-            (the plain form, scale 1, whatever ``cfg.norm``)."""
+            """The delta-rule mixer.  Head-decayed (``delta_decay_floor``
+            None): ``w_in``'s columns are [q | k | v | z | b | a] and
+            ``dt_bias`` is one a VALUE head.  Channel-decayed: ``w_in`` is
+            [q | k | v], the decays' projection ``w_decay`` [d, H dk], the
+            write strengths' ``w_beta`` and the output gate's ``w_gate``
+            [d, H] are their own, and ``dt_bias`` is one a CHANNEL.  In
+            both the filters of q, k and v are lecun-normal over their taps
+            with no bias; ``A_log`` (a head) is drawn as the state-space
+            mixer's and so is the head-decayed ``dt_bias``; the
+            channel-decayed ``dt_bias`` puts the bounded gate at rest
+            uniformly over its range (float32 whatever the parameters'
+            dtype, as the decays' arithmetic is); one output-norm scale of
+            a head's value size, shared by the heads (the plain form, scale
+            1, whatever ``cfg.norm``)."""
             h = cfg.delta_value_heads or cfg.n_heads
             d_qk, d_v = 2 * cfg.n_heads * cfg.delta_key_dim, h * cfg.delta_value_dim
             k_in, k_conv, k_dt, k_a, k_out = jax.random.split(key, 5)
             low, high = np.log(cfg.ssm_dt_range)
-            dt = jnp.exp(jax.random.uniform(k_dt, (h,), minval=low, maxval=high))
+            channel = cfg.delta_decay_floor is not None
+            a = jax.random.uniform(k_a, (h,), minval=1.0, maxval=16.0)
+            if channel:
+                projections = {
+                    "w_in": dense(k_in, (d, d_qk + d_v), pdt),
+                    "w_decay": dense(
+                        jax.random.fold_in(k_in, 1), (d, d_qk // 2), pdt),
+                    "w_beta": dense(jax.random.fold_in(k_in, 2), (d, h), pdt),
+                    "w_gate": dense(jax.random.fold_in(k_in, 3), (d, h), pdt),
+                }
+                # the bounded gate AT REST (f = 0), floor * sigmoid(A dt_bias),
+                # uniform over the middle 96 % of (floor, 0) a channel.  The
+                # softplus draw below would put sigmoid(A dt_bias) at 1e-7 to
+                # 0.4: a gate that hardly decays, under which a rule whose
+                # sums are kept in a lower precision reads as the program
+                # does (PERF.md section 6, PR 66, after the review)
+                rest = jax.random.uniform(
+                    k_dt, (h, d_qk // (2 * h)), minval=0.02, maxval=0.98)
+                dt_bias = ((jnp.log(rest) - jnp.log1p(-rest)) / a[:, None]).ravel()
+            else:
+                projections = {
+                    "w_in": dense(k_in, (d, d_qk + 2 * d_v + 2 * h), pdt)}
+                dt = jnp.exp(jax.random.uniform(
+                    k_dt, (h,), minval=low, maxval=high))
+                dt_bias = dt + jnp.log(-jnp.expm1(-dt))  # softplus^-1
             return {
-                "w_in": dense(k_in, (d, d_qk + 2 * d_v + 2 * h), pdt),
+                **projections,
                 "conv_w": jax.nn.initializers.lecun_normal(
                     in_axis=-1, out_axis=-2
                 )(k_conv, (d_qk + d_v, cfg.delta_conv_kernel), pdt),
-                "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),  # softplus^-1
-                "A_log": jnp.log(jax.random.uniform(
-                    k_a, (h,), minval=1.0, maxval=16.0)),
+                "dt_bias": dt_bias,
+                "A_log": jnp.log(a),
                 "gate_norm": {"scale": jnp.ones((cfg.delta_value_dim,), pdt)},
                 "w_out": dense(k_out, (d_v, d), pdt),
             }
@@ -955,7 +1039,8 @@ class DMoETransformerLM:
                 attention = {
                     # gated: a head's columns are its query's, then its gate's
                     "wq": dense(
-                        ks[0], (d, 2 * d_q if cfg.attention_gate else d_q), pdt),
+                        ks[0],
+                        (d, 2 * d_q if cfg.attention_gate is True else d_q), pdt),
                     "wk": dense(ks[1], (d, d_kv), pdt),
                     "wv": dense(ks[2], (d, d_kv), pdt),
                     "wo": dense(ks[3], (d_q, d), pdt),
@@ -968,15 +1053,21 @@ class DMoETransformerLM:
                 rope, nope = cfg.rope_head_dim, hd - cfg.rope_head_dim
                 hd_v = cfg.v_head_dim or hd
                 attention = {
-                    "wq_a": dense(ks[0], (d, c_q), pdt),
-                    "q_a_norm": {"scale": jnp.ones((c_q,), pdt)},
-                    "wq_b": dense(jax.random.fold_in(ks[0], 1), (c_q, d_q), pdt),
+                    # no query latent: the queries one plain product
+                    **({"wq": dense(ks[0], (d, d_q), pdt)} if c_q is None else {
+                        "wq_a": dense(ks[0], (d, c_q), pdt),
+                        "q_a_norm": {"scale": jnp.ones((c_q,), pdt)},
+                        "wq_b": dense(
+                            jax.random.fold_in(ks[0], 1), (c_q, d_q), pdt)}),
                     "wkv_a": dense(ks[1], (d, c_kv + rope), pdt),
                     "kv_a_norm": {"scale": jnp.ones((c_kv,), pdt)},
                     "wkv_b": dense(
                         ks[2], (c_kv, cfg.n_heads * (nope + hd_v)), pdt),
                     "wo": dense(ks[3], (cfg.n_heads * hd_v, d), pdt),
                 }
+            if cfg.attention_gate == "head" and kind.mixer == "softmax":
+                attention["w_gate"] = dense(
+                    jax.random.fold_in(ks[3], 1), (d, cfg.n_heads), pdt)
             lp = {"ln1": ln(), **attention, "ln2": ln()}
             if cfg.hc_streams is not None:  # one a part
                 lp["hc_attn"] = hyper_connection(jax.random.fold_in(key, 11))
@@ -1055,11 +1146,13 @@ class DMoETransformerLM:
             rope_theta=self.cfg.rope_theta, norm_eps=self.cfg.norm_eps,
         )
         if "wkv_a" in lp:
-            return *latent_qkv_projections(
+            q, k, v = latent_qkv_projections(
                 lp, x, self.cfg.n_heads, **how,
-                rope_scaling=self.cfg.rope_scaling), None
-        return gated_qkv_projections(
+                rope_scaling=self.cfg.rope_scaling)
+            return q, k, v, head_gate(lp, x)
+        q, k, v, gate = gated_qkv_projections(
             lp, x, self.cfg.n_heads, rotary_dim=self.cfg.rotary_dim, **how)
+        return q, k, v, head_gate(lp, x) if gate is None else gate
 
     def _layer(self, lp, x, layer_idx, token_mask, kind: AttentionLayer):
         """One block.  ``kind`` (static) is the layer's attention,
@@ -1171,6 +1264,7 @@ class DMoETransformerLM:
             out, _, decay_min, beta_max = delta_mixer(
                 lp["delta"], mixer_in, cfg.n_heads, cfg.delta_chunk,
                 cfg.norm_eps, neg_eigval=cfg.delta_neg_eigval,
+                decay_floor=cfg.delta_decay_floor,
             )
             x = self._add_part(lp["ln1"], x, out)
         return x, mixer_in, {
@@ -2205,6 +2299,7 @@ class DMoETransformerLM:
                     jax.block_until_ready(streams[j - 1])
 
         layers, loads = list(params["layers"]), []
+        groups = dict(zip(("n_group", "topk_group"), cfg.router_groups or ()))
         if cfg.router_input != "moe_input":
             raise NotImplementedError(
                 "level_router_bias reads the router on the experts' input"
@@ -2216,7 +2311,7 @@ class DMoETransformerLM:
             if "moe" in lp:
                 bias, load = level_bias(
                     jnp.concatenate([scores(lp, x) for x in streams]),
-                    lp["moe"]["router_bias"], cfg.k,
+                    lp["moe"]["router_bias"], cfg.k, **groups,
                 )
                 lp = layers[i] = {**lp, "moe": {**lp["moe"], "router_bias": bias}}
                 loads.append(load)
@@ -2243,7 +2338,7 @@ class DMoETransformerLM:
             lp = mp["layer"]
             bias, load = level_bias(
                 jnp.concatenate([scores(lp, x) for x in streams]),
-                lp["moe"]["router_bias"], cfg.k,
+                lp["moe"]["router_bias"], cfg.k, **groups,
             )
             loads.append(load)
             params["mtp"] = {**mp, "layer": {

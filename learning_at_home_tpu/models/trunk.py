@@ -262,26 +262,37 @@ def latent_qkv_projections(
     ``k_r``, that every head shares; ``Wkvb`` expands ``c_kv`` to each
     head's ``[k_nope | v]``.  A head's query and key are ``[nope | rope]``:
     the last ``rope`` of the query and ``k_r`` are rotated (``positions``
-    [S]; None = no rotation), the rest carries no position.  The block's
-    own parameters say the sizes: the latent's width is ``kv_a_norm``'s
-    scale, the rotated part what ``wkv_a`` gives beyond it, a head's
-    query/key size ``wq_b``'s width over ``n_heads``, its value size what
+    [S]; None = no rotation), the rest carries no position.  A block with
+    no ``wq_a`` has NO query latent: its queries are ``x Wq``, one plain
+    product and no norm (DeepSeek-V2-Lite's form, ``q_lora_rank`` null).
+    The block's own parameters say the sizes: the latent's width is
+    ``kv_a_norm``'s scale, the rotated part what ``wkv_a`` gives beyond it,
+    a head's query/key size ``wq_b``'s (or ``wq``'s) width over
+    ``n_heads``, its value size what
     ``wkv_b`` gives a head beyond the unrotated key part (as wide as the
     keys or not: GLM-4.7-Flash's 256 beside keys of 256, or 128 beside keys
     of 192).  ``rope_scaling``: :func:`rotary`'s.  No biases."""
     b, s, _ = x.shape
     kv_rank = lp["kv_a_norm"]["scale"].shape[-1]
     rope_dim = lp["wkv_a"].shape[-1] - kv_rank
-    hd = lp["wq_b"].shape[-1] // n_heads
+    q_latent = "wq_a" in lp
+    hd = lp["wq_b" if q_latent else "wq"].shape[-1] // n_heads
     nope = hd - rope_dim
     with jax.named_scope("latent_down"):
-        c_q = rms_norm(lp["q_a_norm"], checkpoint_name(
-            x @ lp["wq_a"].astype(x.dtype), ATTENTION_PRODUCTS), norm_eps)
+        if q_latent:
+            c_q = rms_norm(lp["q_a_norm"], checkpoint_name(
+                x @ lp["wq_a"].astype(x.dtype), ATTENTION_PRODUCTS), norm_eps)
         kv = checkpoint_name(x @ lp["wkv_a"].astype(x.dtype), ATTENTION_PRODUCTS)
         c_kv = rms_norm(lp["kv_a_norm"], kv[..., :kv_rank], norm_eps)
         k_rope = kv[..., kv_rank:].reshape(b, s, 1, rope_dim)
+    if not q_latent:
+        with jax.named_scope("proj"):
+            q = checkpoint_name(
+                (x @ lp["wq"].astype(x.dtype)).reshape(b, s, n_heads, hd),
+                ATTENTION_PRODUCTS)
     with jax.named_scope("latent_up"):
-        q = (c_q @ lp["wq_b"].astype(x.dtype)).reshape(b, s, n_heads, hd)
+        if q_latent:
+            q = (c_q @ lp["wq_b"].astype(x.dtype)).reshape(b, s, n_heads, hd)
         # the expansion's columns a head are [k_nope | v]: split the
         # matrix, not the [B,S,H,.] result
         wkv_b = lp["wkv_b"].astype(x.dtype).reshape(kv_rank, n_heads, -1)
@@ -301,12 +312,27 @@ def latent_qkv_projections(
     return q, k, v
 
 
+def head_gate(lp: dict, x: jax.Array) -> jax.Array | None:
+    """The output's gate of a block that holds ``w_gate`` [d, H], before its
+    sigmoid: ``x w_gate`` [B,S,H,1] float32, ONE number a head a token
+    (Ling-3.0's ``head_wise`` gate), which :func:`output_projection`
+    broadcasts over the head's values; None where the block holds none.
+    Scope ``gate``."""
+    if "w_gate" not in lp:
+        return None
+    with jax.named_scope("gate"):
+        return jnp.einsum(
+            "bsd,dh->bsh", x, lp["w_gate"].astype(x.dtype),
+            preferred_element_type=jnp.float32)[..., None]
+
+
 def output_projection(
     lp: dict, out: jax.Array, gate: jax.Array | None = None
 ) -> jax.Array:
     """[B,S,H,hd] → [B,S,d] @ wo.  Scope ``out_proj``.  With ``gate``
-    [B,S,H,hd] (:func:`gated_qkv_projections`) the core's output is
-    multiplied by ``sigmoid(gate)`` first, in float32 (scope ``gate``)."""
+    [B,S,H,hd] (:func:`gated_qkv_projections`) or [B,S,H,1]
+    (:func:`head_gate`) the core's output is multiplied by
+    ``sigmoid(gate)`` first, in float32 (scope ``gate``)."""
     b, s, h, hd = out.shape
     if gate is not None:
         with jax.named_scope("gate"):
@@ -519,6 +545,7 @@ def ssm_mixer(
 def delta_mixer(
     p: dict, x: jax.Array, n_heads: int, chunk: int, eps: float = 1e-5,
     decay_dtype=jnp.float32, neg_eigval: bool = True,
+    decay_floor: float | None = None,
 ):
     """The gated delta-rule mixer (Gated DeltaNet, arXiv:2412.06464) on the
     stream ``x`` [B, S, d] as the layer hands it over: ``(out [B, S, d],
@@ -561,7 +588,15 @@ def delta_mixer(
     beyond ``q``, ``k``, ``v`` and ``z``, a head's value size ``dv``
     ``w_out``'s input width over ``Hv``, its key size ``dk`` what the
     convolution's channels leave beyond ``v`` over ``2 H``.  Sub-scopes ``in_proj``, ``conv``,
-    ``core``, ``gate_norm``, ``out_proj``."""
+    ``core``, ``gate_norm``, ``out_proj``.
+
+    A mixer that holds ``w_decay`` is the CHANNEL-DECAYED form (Kimi Delta
+    Attention, arXiv:2510.26692: :func:`channel_delta_mixer`), whose gate's
+    bound is ``decay_floor``: the parameters say which, as they say the
+    sizes."""
+    if "w_decay" in p:
+        return channel_delta_mixer(
+            p, x, n_heads, chunk, eps, decay_dtype, neg_eigval, decay_floor)
     b, s, _ = x.shape
     f32 = jnp.float32
     d_v = p["w_out"].shape[0]
@@ -604,6 +639,66 @@ def delta_mixer(
             gate_first=False, first=d_qk + d_v)
     with jax.named_scope("out_proj"):
         out = y @ p["w_out"].astype(x.dtype)
+    return out, state, decay_min, beta_max
+
+
+def channel_delta_mixer(
+    p: dict, x: jax.Array, n_heads: int, chunk: int, eps: float,
+    decay_dtype, neg_eigval: bool, decay_floor: float,
+):
+    """:func:`delta_mixer` whose decay is a number a KEY CHANNEL (Kimi
+    Delta Attention), ``H`` heads of keys ``dk`` and values ``dv``, what it
+    returns the same.
+
+    ``[q | k | v] = x W_in``, then the convolutions and their SiLU, unit
+    lengths and ``beta`` as there, ``beta``'s pre-activation ``x W_beta``
+    [d, H] a product of its own in float32; the decay ``f = x W_decay`` [d,
+    H dk] (float32 accumulation: it is an exponent), ``g = decay_floor *
+    sigmoid(exp(A_log_h) (f + dt_bias))`` in ``(decay_floor, 0)``,
+    ``dt_bias`` a channel (scope ``decay``); ``S_t = (I - beta_t k_t k_t^T)
+    Diag(alpha_t) S_{t-1} + beta_t k_t v_t^T``, ``o_t = S_t^T q_t``
+    (:func:`~learning_at_home_tpu.ops.delta_rule.gated_delta_chunked` with
+    a decay of rank 4); ``y = RMSNorm(o) * sigmoid(x W_gate)_h``, the norm
+    over a head's ``dv`` under one scale shared by the heads, the gate ONE
+    number a head a token, float32 (scope ``gate_norm``); ``out = y
+    W_out``.  The bounded gate is what lets 16 positions' log-decays be
+    summed and exponentiated apart (``channel_decay_fits``)."""
+    b, s, _ = x.shape
+    f32 = jnp.float32
+    d_v = p["w_out"].shape[0]
+    d_qk = p["conv_w"].shape[0] - d_v
+    dk, dv = d_qk // (2 * n_heads), d_v // n_heads
+    with jax.named_scope("in_proj"):
+        proj = x @ p["w_in"].astype(x.dtype)
+        write = jnp.einsum(
+            "bsd,dn->bsn", x, p["w_beta"].astype(x.dtype),
+            preferred_element_type=f32)
+    with jax.named_scope("conv"):
+        qk = causal_conv_silu(proj, p["conv_w"][:d_qk], None)
+        v = causal_conv_silu(proj, p["conv_w"][d_qk:], None, first=d_qk)
+    with jax.named_scope("decay"):
+        step = jnp.einsum(
+            "bsd,dn->bsn", x, p["w_decay"].astype(x.dtype),
+            preferred_element_type=f32).reshape(b, s, n_heads, dk)
+        g = decay_floor * jax.nn.sigmoid(
+            jnp.exp(p["A_log"].astype(f32))[:, None]
+            * (step + p["dt_bias"].astype(f32).reshape(n_heads, dk)))
+    with jax.named_scope("core"):
+        qk = qk.reshape(b, s, 2, n_heads, dk)
+        beta = jax.nn.sigmoid(write)
+        if neg_eigval:
+            beta = 2.0 * beta
+        o, state = gated_delta_chunked(
+            qk[:, :, 0], qk[:, :, 1], v.reshape(b, s, n_heads, dv), g, beta,
+            chunk, decay_dtype, unit=True, decay_floor=decay_floor)
+        decay_min, beta_max = jnp.exp(jnp.min(g)), jnp.max(beta)
+    with jax.named_scope("gate_norm"):
+        o32 = o.astype(f32)
+        y = (o32 * jax.lax.rsqrt(jnp.mean(o32 * o32, axis=-1, keepdims=True) + eps)
+             * p["gate_norm"]["scale"].astype(f32)
+             * jax.nn.sigmoid(head_gate(p, x))).astype(x.dtype)
+    with jax.named_scope("out_proj"):
+        out = y.reshape(b, s, d_v) @ p["w_out"].astype(x.dtype)
     return out, state, decay_min, beta_max
 
 

@@ -147,7 +147,9 @@ def gated_delta_recurrent(
 
     def one_position(state, at):
         q_t, k_t, v_t, g_t, beta_t = at  # [B, H, .]
-        decayed = jnp.exp(g_t)[..., None, None] * state
+        # a decay a head scales the state whole, one a key channel its rows
+        decayed = (jnp.exp(g_t)[..., None, None] if g_t.ndim == 2
+                   else jnp.exp(g_t)[..., :, None]) * state
         answered = jnp.einsum("bhkv,bhk->bhv", decayed, k_t, precision=_HIGHEST)
         state = decayed + (
             (beta_t[..., None] * k_t)[..., :, None]
@@ -226,10 +228,17 @@ def gated_delta_chunked(
     q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array, beta: jax.Array,
     chunk: int, decay_dtype=jnp.float32, solve: str = "blocks",
     segment: int = SEGMENT, unit: bool = False,
+    decay_floor: float | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """``(o [B, S, H, dv] in v's dtype, the state after the last position
     [B, H, dk, dv] float32)`` of the rule above, ``chunk`` positions at a
-    time (``min(chunk, S)``; it must divide ``S``).  ``decay_dtype``: the
+    time (``min(chunk, S)``; it must divide ``S``).  ``g`` [B, S, H] is a
+    decay a head; ``g`` [B, S, H, dk] a decay a KEY CHANNEL (Kimi Delta
+    Attention, arXiv:2510.26692: ``S_t = (I - beta_t k_t k_t^T)
+    Diag(alpha_t) S_{t-1} + beta_t k_t v_t^T``), whose ``decay_floor`` is
+    the least a position's ``g`` can be (the mixer's bounded gate: see
+    :func:`channel_decay_fits`); the decay's rank says which form runs, no
+    flag.  ``decay_dtype``: the
     dtype the decays' sums are kept in; float32 always, but for showing
     what a lower one reads (tools/smallthinker_probe.py).  ``solve``:
     :func:`solve_unit_lower`'s way.  ``segment``: the positions whose
@@ -237,12 +246,47 @@ def gated_delta_chunked(
     ``unit``: ``q`` and ``k`` come as the mixer's convolution left them and
     are made unit-length first (:func:`unit_length`).  The kernel where
     :func:`kernel_fits` says so, the plain form elsewhere."""
+    channel = g.ndim == 4
+    if channel and not channel_decay_fits(decay_floor, decay_dtype):
+        raise ValueError(
+            f"a decay a key channel of decay_floor={decay_floor} kept in "
+            f"{jnp.dtype(decay_dtype).name}: {SOLVE_BLOCK} positions' sum "
+            f"must stay above -{EXPONENT_MOST} (ops/delta_rule.py "
+            "channel_decay_fits)")
     if kernel_fits(q.shape, v.shape, chunk, jax.default_backend(), decay_dtype,
-                   solve):
+                   solve, channel):
         return gated_delta_kernel(q, k, v, g, beta, chunk, unit=unit)
     if unit:
         q, k = unit_length(q, k)
     return gated_delta_plain(q, k, v, g, beta, chunk, decay_dtype, solve, segment)
+
+
+# the largest exponent the channel decays' scores take: a 16-block's rows
+# and columns are scaled by e^{gamma - gamma_r} and e^{gamma_r - gamma} with
+# gamma_r the block's first row, so one of the two is up to the block's
+# whole decay.  e^80 = 5.5e34 is finite in float32 and in bf16 (both end at
+# 3.4e38), and a product with a row factor down to e^-80 = 1.8e-35 keeps
+# every digit of an element over 6.5e-4 (the least normal number is 1.2e-38
+# in both; a unit key's 128 elements are 0.09 each at the root of their mean
+# square, and what falls under is lost only where 16 positions ALL sit on the
+# floor: up to 2e-3 of one score, under bf16's rounding of the rest:
+# tests/test_kda_rule.py, the gates saturated at the floor)
+EXPONENT_MOST = 80.0
+
+
+def channel_decay_fits(decay_floor, decay_dtype=jnp.float32) -> bool:
+    """Whether a decay a key channel can be taken in chunks: ``decay_floor``
+    (the least a position's log-decay can be: the mixer's bounded gate, -5
+    for ``kda_lower_bound`` -5) keeps :data:`SOLVE_BLOCK` positions' sum
+    within :data:`EXPONENT_MOST`, in a dtype with float32's exponents.  An
+    unbounded gate (None) is refused, never clamped.  The ONE bound serves
+    both references: the plain form's (a 16-block's first row: up to 15
+    positions' decay on a factor) and the kernels' (a 32-span's middle row:
+    up to 16 positions' on either side of it), so neither derives its own."""
+    return (
+        decay_floor is not None and decay_floor <= 0.0
+        and -decay_floor * SOLVE_BLOCK <= EXPONENT_MOST
+        and jnp.dtype(decay_dtype) in (jnp.dtype(jnp.float32), jnp.dtype(jnp.bfloat16)))
 
 
 def unit_length(q: jax.Array, k: jax.Array) -> tuple[jax.Array, jax.Array]:
@@ -278,16 +322,28 @@ def _grid(h: int, s: int, c: int, dk: int, dv: int):
 
 
 def kernel_fits(q_shape, v_shape, chunk, backend, decay_dtype=jnp.float32,
-                solve: str = "blocks") -> bool:
+                solve: str = "blocks", channel: bool = False) -> bool:
     """Whether :func:`gated_delta_kernel` takes a call: a ``tpu`` backend
     (Mosaic lowering), float32 decays, the ``blocks`` solve (the kernel's
-    own), and shapes :func:`_grid` finds tiles for.  A pure function of
-    what the call can see."""
+    own), and shapes :func:`_grid` finds tiles for; under a decay a key
+    channel (``channel``) what :func:`channel_kernel_fits` asks besides.  A
+    pure function of what the call can see."""
     bsz, s, h, dk = q_shape
     return (
         backend == "tpu" and jnp.dtype(decay_dtype) == jnp.float32
         and solve == "blocks"
-        and _grid(h, s, min(chunk, s), dk, v_shape[-1]) is not None)
+        and _grid(h, s, min(chunk, s), dk, v_shape[-1]) is not None
+        and (not channel or channel_kernel_fits(min(chunk, s), dk)))
+
+
+def channel_kernel_fits(c: int, dk: int) -> bool:
+    """What the kernels under a decay a key channel ask besides
+    :func:`_grid`: a chunk of one span of their references or two (a
+    chunk's second span refers to its first row, and a third would need
+    a reference of its own against each span before it), and keys of whole
+    lane tiles (the sums [S, dk] are a block of their own and ``e^{gamma_C}``
+    is turned from a row into the state's rows' column)."""
+    return c <= 4 * SOLVE_BLOCK and dk % _LANES == 0
 
 
 def gated_delta_plain(
@@ -329,6 +385,8 @@ def _segment(state, of_segment, c: int, decay_dtype, solve: str):
     """The rule over one segment ``(q, k, v, g, beta)`` [B, S, H, ..] from
     the state that enters it: ``(o, the state that leaves it)``."""
     q, k, v, g, beta = of_segment
+    if g.ndim == 4:
+        return _segment_channel(state, of_segment, c, decay_dtype, solve)
     bsz, s, h, dk = q.shape
     dv = v.shape[-1]
     nc = s // c
@@ -387,6 +445,97 @@ def _segment(state, of_segment, c: int, decay_dtype, solve: str):
         entering, preferred_element_type=f32,
     ) + jnp.einsum(
         "bnhij,bnhjv->bnhiv", (qk * decay).astype(compute), new,
+        preferred_element_type=f32)
+    return jnp.moveaxis(o, 2, 3).reshape(bsz, s, h, dv).astype(compute), final
+
+
+def _segment_channel(state, of_segment, c: int, decay_dtype, solve: str):
+    """:func:`_segment` under a decay a KEY CHANNEL, ``g`` [B, S, H, dk]:
+    ``gamma`` [.., C, dk] the sums a channel inside a chunk, and::
+
+        A      = strictly_lower(diag(beta) sum_d K_id K_jd e^{gamma_id - gamma_jd})
+        W, U   = (I + A)^-1 diag(beta) [K * e^gamma | V]
+        V'     = U - W S
+        O      = (Q * e^gamma) S + lower_incl(sum_d Q_id K_jd e^{gamma_id - gamma_jd}) V'
+        S_next = Diag(e^{gamma_C}) S + (K * e^{gamma_C - gamma})^T V'
+
+    ``Gamma`` is no masked difference of one sum a head but a product over
+    the channels, so the decays go onto the operands: the rows of a
+    :data:`SOLVE_BLOCK` of positions times ``e^{gamma - gamma_r}``,
+    ``gamma_r`` the block's first row's sums (exponents in ``[-floor x 15,
+    0]``), against the columns up to the block's end times ``e^{gamma_r -
+    gamma}``: at most 0 before the block, at most ``floor x 15`` inside it
+    (:func:`channel_decay_fits`), and the columns after it, where it would
+    overflow, are not made at all (a masked infinity's gradient is not a
+    number)."""
+    q, k, v, g, beta = of_segment
+    bsz, s, h, dk = q.shape
+    dv = v.shape[-1]
+    nc = s // c
+    compute = v.dtype
+    f32 = jnp.float32
+    block = min(SOLVE_BLOCK, c)
+    if c % block:
+        raise ValueError(
+            f"a decay a key channel takes chunks of whole blocks of {block} "
+            f"positions, got chunk={c}")
+
+    def by_chunk(a):  # [B, S, H, ..] -> [B, nc, H, C, ..]
+        return jnp.moveaxis(a.reshape(bsz, nc, c, *a.shape[2:]), 3, 2)
+
+    qc, kc, vc = by_chunk(q), by_chunk(k), by_chunk(v)
+    beta_c = by_chunk(beta.astype(f32))  # [B, nc, H, C]
+    gamma = jnp.cumsum(
+        by_chunk(g.astype(f32)).astype(decay_dtype), axis=-2)  # [B, nc, H, C, dk]
+    last = gamma[..., -1:, :]
+    q32, k32, v32 = qc.astype(f32), kc.astype(f32), vc.astype(f32)
+    i = jnp.arange(c)
+
+    kk, qk = [], []  # a block of rows at a time: [.., block, C]
+    for first in range(0, c, block):
+        rows, cols = slice(first, first + block), slice(0, first + block)
+        reference = gamma[..., first:first + 1, :]
+        from_reference = jnp.exp(gamma[..., rows, :] - reference).astype(f32)
+        to_reference = (k32[..., cols, :] * jnp.exp(
+            reference - gamma[..., cols, :]).astype(f32)).astype(compute)
+        beyond = [(0, 0)] * (to_reference.ndim - 2) + [(0, c - first - block), (0, 0)]
+        to_reference = jnp.pad(to_reference, beyond)
+        for scores, of in ((kk, k32), (qk, q32)):
+            scores.append(jnp.einsum(
+                "bnhik,bnhjk->bnhij",
+                (of[..., rows, :] * from_reference).astype(compute),
+                to_reference, preferred_element_type=f32))
+    kk, qk = jnp.concatenate(kk, axis=-2), jnp.concatenate(qk, axis=-2)
+    a = jnp.where(i[:, None] > i[None, :], beta_c[..., None] * kk, 0.0)
+    scores = jnp.where(i[:, None] >= i[None, :], qk, 0.0)
+    from_start = jnp.exp(gamma).astype(f32)  # [B, nc, H, C, dk]
+    wu = solve_unit_lower(a, beta_c[..., None] * jnp.concatenate(
+        [k32 * from_start, v32], axis=-1), solve)
+    w, u = wu[..., :dk].astype(compute), wu[..., dk:]
+    k_to_end = (k32 * jnp.exp(last - gamma).astype(f32)).astype(compute)
+    chunk_decay = jnp.moveaxis(jnp.exp(last).astype(f32), -2, -1)  # [B, nc, H, dk, 1]
+
+    def one_chunk(state, of_chunk):
+        w_c, u_c, k_c, decay_c = of_chunk
+        entering = state.astype(compute)
+        new = (u_c - jnp.einsum(
+            "bhik,bhkv->bhiv", w_c, entering, preferred_element_type=f32)
+        ).astype(compute)  # V' [B, H, C, dv]
+        leaving = decay_c * state + jnp.einsum(
+            "bhik,bhiv->bhkv", k_c, new, preferred_element_type=f32)
+        return leaving, (entering, new)
+
+    final, (entering, new) = jax.lax.scan(
+        one_chunk, state,
+        tuple(jnp.moveaxis(t, 1, 0) for t in (w, u, k_to_end, chunk_decay)),
+    )
+    entering = jnp.moveaxis(entering, 0, 1)  # [B, nc, H, dk, dv]
+    new = jnp.moveaxis(new, 0, 1)  # [B, nc, H, C, dv]
+    o = jnp.einsum(
+        "bnhik,bnhkv->bnhiv", (q32 * from_start).astype(compute),
+        entering, preferred_element_type=f32,
+    ) + jnp.einsum(
+        "bnhij,bnhjv->bnhiv", scores.astype(compute), new,
         preferred_element_type=f32)
     return jnp.moveaxis(o, 2, 3).reshape(bsz, s, h, dv).astype(compute), final
 
@@ -770,7 +919,285 @@ def _bwd_kernel(q_ref, k_ref, v_ref, rows_ref, entering_ref, do_ref, dfinal_ref,
     drows_ref[...] = jnp.concatenate(dcols[::-1], axis=0).T[:2 * hg, :]
 
 
+# ---- the kernels under a decay a KEY CHANNEL ----
+#
+# ``Gamma`` is no masked difference of one sum a head: the decays go onto the
+# operands.  A SPAN of ``2 SOLVE_BLOCK`` positions (32) takes ONE reference,
+# the sums of its middle row: rows times ``e^{gamma - gamma_mid}``, columns
+# times ``e^{gamma_mid - gamma}``, both within ``e^{+-16 floor}``
+# (``channel_decay_fits``: 80), their product over the channels the exact
+# ``e^{gamma_i - gamma_j} <= 1`` wherever ``i >= j``; the pairs of a chunk's
+# second span with its first take the second span's FIRST row as reference
+# (both exponents non-positive).  So a frame's scores are two whole-tile
+# products (``[K; Q]`` stacked against the columns), masked to the entries
+# each is right for; what they hold elsewhere (sums of products up to
+# ``e^160``: infinities, not-a-numbers) is dropped by ``where``, never
+# multiplied, and the backward is written out, so no mask's gradient is ever
+# taken.  The state's decay is a ROW scaling, ``Diag(e^{gamma_C}) S``.
+
+
+def _rows_of(gam: jax.Array, firsts, span: int) -> jax.Array:
+    """``gam`` [F, dk]'s row ``firsts[n]`` under each of the ``span`` rows of
+    span ``n``."""
+    return jnp.concatenate([
+        jnp.broadcast_to(gam[m:m + 1, :], (span, gam.shape[1])) for m in firsts],
+        axis=0)
+
+
+def _channel_frame(q, k, v, gam, bcol, i, j, c: int, unit: bool) -> dict:
+    """:func:`_frame` under a decay a key channel: ``gam`` [F, dk] the sums
+    a channel inside the frame's chunks."""
+    f32 = jnp.float32
+    compute = v.dtype
+    frame = gam.shape[0]
+    eye = i == j
+    units = None
+    if unit:  # q and k as the convolution left them
+        scale = q.shape[1] ** -0.5
+        q, *of_q = _unit(q, scale)
+        k, *of_k = _unit(k, None)
+        units = (*of_q, scale), (*of_k, None)
+    q32, k32, v32 = q.astype(f32), k.astype(f32), v.astype(f32)
+    span = min(2 * SOLVE_BLOCK, c)
+    starts = range(0, frame, span)
+    mid = _rows_of(gam, [n + span // 2 for n in starts], span)
+    e_rd, e_cd = jnp.exp(gam - mid), jnp.exp(mid - gam)
+    same_chunk = i // c == j // c
+    same_span = i // span == j // span
+    stacked = jnp.concatenate(
+        [(k32 * e_rd).astype(compute), (q32 * e_rd).astype(compute)], axis=0)
+    k_cd = (k32 * e_cd).astype(compute)
+    both = _dot(stacked, k_cd, _NT)  # [2 F, F]
+    kk = jnp.where(same_span & (i > j), both[:frame], 0.0)
+    qk = jnp.where(same_span & (i >= j), both[frame:], 0.0)
+    made = dict(e_rd=e_rd, e_cd=e_cd, rows_d=stacked, k_cd=k_cd, same_span=same_span)
+    if span < c:  # a chunk's second span against its first
+        first = _rows_of(gam, starts, span)  # a span's first row's sums
+        following = _rows_of(  # the NEXT span's, under a chunk's first span
+            gam, [n + span if (n + span) % c else n for n in starts], span)
+        e_ro = jnp.exp(gam - first)
+        leads = jax.lax.broadcasted_iota(jnp.int32, (frame, 1), 0) % c < span
+        e_co = jnp.where(leads, jnp.exp(following - gam), 0.0)
+        stacked_o = jnp.concatenate(
+            [(k32 * e_ro).astype(compute), (q32 * e_ro).astype(compute)], axis=0)
+        k_co = (k32 * e_co).astype(compute)
+        both_o = _dot(stacked_o, k_co, _NT)
+        off = same_chunk & ~same_span & (i > j)
+        kk = kk + jnp.where(off, both_o[:frame], 0.0)
+        qk = qk + jnp.where(off, both_o[frame:], 0.0)
+        made.update(e_ro=e_ro, e_co=e_co, rows_o=stacked_o, k_co=k_co, off=off)
+    a = bcol * kk
+    t = _unit_lower_inverse(a, i, j, c)
+    from_start = jnp.exp(gam)
+    last = _rows_of(gam, [n + c - 1 for n in range(0, frame, c)], c)
+    to_end = jnp.exp(last - gam)
+    k_from_start = k32 * from_start
+    t_beta = t * _as_row(bcol, eye)
+    w = _exact_dot(t_beta, k_from_start.astype(compute))
+    return dict(
+        made, q=q, k=k, q32=q32, k32=k32, v32=v32, units=units, gam=gam,
+        bcol=bcol, eye=eye, m=kk, a=a, t=t, from_start=from_start,
+        to_end=to_end, k_from_start=k_from_start, rk=bcol * k_from_start, w=w,
+        u=_exact_dot(t_beta, v), w_c=w.astype(compute),
+        k_to_end=(k32 * to_end).astype(compute), scores=qk,
+        q_from_start=(q32 * from_start).astype(compute))
+
+
+def _chunk_decay(x: dict, of: slice, eye_k: jax.Array) -> jax.Array:
+    """``e^{gamma_C}`` of the chunk ``of`` as a column [dk, 1]: what scales
+    the state's rows."""
+    return _as_col(jnp.exp(x["gam"][of.stop - 1:of.stop, :]), eye_k)
+
+
+def _channel_fwd_kernel(q_ref, k_ref, v_ref, g_ref, rows_ref, o_ref, *rest,
+                        hg, c, keep, unit):
+    entering_ref = rest[0] if keep else None
+    final_ref, state = rest[-2:]
+
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        state[...] = jnp.zeros_like(state)
+
+    if keep:  # the state entering the grid step: the backward's residual
+        entering_ref[...] = state[...]
+    cols = _columns(rows_ref[...])  # lane hg + h: head h's strengths
+    dk, dv = q_ref.shape[2], v_ref.shape[1] // hg
+    compute = v_ref.dtype
+    i = jax.lax.broadcasted_iota(jnp.int32, (FRAME, FRAME), 0)
+    j = jax.lax.broadcasted_iota(jnp.int32, (FRAME, FRAME), 1)
+    eye_k = (jax.lax.broadcasted_iota(jnp.int32, (dk, dk), 0)
+             == jax.lax.broadcasted_iota(jnp.int32, (dk, dk), 1))
+    for first in range(0, q_ref.shape[1], FRAME):
+        at = slice(first, first + FRAME)
+        made = [_channel_frame(
+            q_ref[h, at, :], k_ref[h, at, :], v_ref[at, h * dv:(h + 1) * dv],
+            g_ref[h, at, :], cols[at, hg + h:hg + h + 1], i, j, c, unit)
+            for h in range(hg)]
+        news = [[] for _ in made]
+        answered = [[] for _ in made]
+        for n in range(0, FRAME, c):  # the chunks in sequence, the heads abreast
+            of = slice(n, n + c)
+            for h, x in enumerate(made):
+                s = state[h]
+                both = _dot(jnp.concatenate(
+                    [x["w_c"][of], x["q_from_start"][of]], axis=0), s.astype(compute))
+                new = (x["u"][of] - both[:c]).astype(compute)  # V'
+                news[h].append(new)
+                answered[h].append(both[c:])
+                state[h] = _chunk_decay(x, of, eye_k) * s + _dot(
+                    x["k_to_end"][of], new, _TN)
+        for h, x in enumerate(made):
+            o_ref[at, h * dv:(h + 1) * dv] = (
+                jnp.concatenate(answered[h], axis=0) + _dot(
+                    x["scores"].astype(compute), jnp.concatenate(news[h], axis=0))
+            ).astype(compute)
+
+    @pl.when(pl.program_id(1) == pl.num_programs(1) - 1)
+    def _():
+        final_ref[...] = state[...]
+
+
+def _channel_bwd_kernel(q_ref, k_ref, v_ref, g_ref, rows_ref, entering_ref,
+                        do_ref, dfinal_ref, dq_ref, dk_ref, dv_ref, dg_ref,
+                        drows_ref, dstate, states, *, hg, c, unit):
+    f32 = jnp.float32
+
+    @pl.when(pl.program_id(1) == 0)  # the row's LAST step: they run in reverse
+    def _():
+        dstate[...] = dfinal_ref[...]
+
+    cols = _columns(rows_ref[...])
+    dk, dv = q_ref.shape[2], v_ref.shape[1] // hg
+    compute = v_ref.dtype
+    i = jax.lax.broadcasted_iota(jnp.int32, (FRAME, FRAME), 0)
+    j = jax.lax.broadcasted_iota(jnp.int32, (FRAME, FRAME), 1)
+    eye_k = (jax.lax.broadcasted_iota(jnp.int32, (dk, dk), 0)
+             == jax.lax.broadcasted_iota(jnp.int32, (dk, dk), 1))
+    lane = jax.lax.broadcasted_iota(jnp.int32, (FRAME, _LANES), 1)
+    ends = jax.lax.broadcasted_iota(jnp.int32, (c, 1), 0) == c - 1
+    same_chunk = i // c == j // c
+    frames = range(0, q_ref.shape[1], FRAME)
+    chunks = range(0, FRAME, c)
+    # the step's chunks FORWARD first, from the ONE state the forward kept
+    # of the step, with the forward kernel's products and roundings
+    states[0] = entering_ref[...]
+    made = {}
+    for first in frames:
+        at = slice(first, first + FRAME)
+        made[first] = [dict(_channel_frame(
+            q_ref[h, at, :], k_ref[h, at, :], v_ref[at, h * dv:(h + 1) * dv],
+            g_ref[h, at, :], cols[at, hg + h:hg + h + 1], i, j, c, unit), news={})
+            for h in range(hg)]
+        for n in chunks:
+            of = slice(n, n + c)
+            nth = (first + n) // c
+            for h, x in enumerate(made[first]):
+                s = states[nth, h]
+                new = (x["u"][of] - _dot(x["w_c"][of], s.astype(compute))
+                       ).astype(compute)  # V'
+                x["news"][n] = new
+                if nth + 1 < states.shape[0]:  # the step's last state: unread
+                    states[nth + 1, h] = _chunk_decay(x, of, eye_k) * s + _dot(
+                        x["k_to_end"][of], new, _TN)
+    dcols = []  # the strengths' gradients, a frame at a time, the last first
+    for first in reversed(frames):
+        at = slice(first, first + FRAME)
+        for h, x in enumerate(made[first]):
+            do = do_ref[at, h * dv:(h + 1) * dv]
+            x.update(do=do, chain={},
+                     dnew=_dot(x["scores"].astype(compute), do, _TN))
+        for n in reversed(chunks):  # the chunks in reverse
+            of = slice(n, n + c)
+            for h, x in enumerate(made[first]):
+                s = states[(first + n) // c, h]
+                entering = s.astype(compute)
+                w_c = x["w_c"][of]
+                new = x["news"][n]
+                dleaving = dstate[h]
+                dleaving_c = dleaving.astype(compute)
+                dnew = x["dnew"][of] + _dot(x["k_to_end"][of], dleaving_c)
+                upon = jnp.concatenate([x["do"][of], -dnew.astype(compute)], axis=0)
+                both = _dot(upon, entering, _NT)  # [2 C, dk]
+                chunk_decay = _chunk_decay(x, of, eye_k)  # [dk, 1]
+                dstate[h] = chunk_decay * dleaving + _dot(jnp.concatenate(
+                    [x["q_from_start"][of], w_c], axis=0), upon, _TN)
+                x["chain"][n] = dict(
+                    dnew=dnew, dk_to_end=_dot(new, dleaving_c, _NT),
+                    dq_from_start=both[:c], dw=both[c:],
+                    # the chunk's last sums scale the leaving state's rows
+                    dlast=_as_row(jnp.sum(
+                        dleaving * s, axis=1, keepdims=True) * chunk_decay, eye_k))
+        dcol = jnp.zeros((FRAME, _LANES), f32)
+        for h, x in enumerate(made[first]):
+            q32, k32, bcol = x["q32"], x["k32"], x["bcol"]
+            chain = [x["chain"][n] for n in chunks]
+            new = jnp.concatenate([x["news"][n] for n in chunks], axis=0)
+            dnew, dk_to_end, dq_from_start, dw = (
+                jnp.concatenate([of[name] for of in chain], axis=0)
+                for name in ("dnew", "dk_to_end", "dq_from_start", "dw"))
+            dscores = jnp.where(same_chunk & (i >= j), _dot(x["do"], new, _NT), 0.0)
+
+            # through the solve: two more with the same factor
+            drk = _dot(x["t"], dw, _TN, precision=_HIGHEST)
+            drv = _dot(x["t"], dnew, _TN, precision=_HIGHEST)
+            da = -jnp.where(
+                same_chunk & (i > j),
+                _dot(drk, x["w"], _NT, precision=_HIGHEST)
+                + _dot(drv, x["u"], _NT, precision=_HIGHEST), 0.0)
+            dbeta = (
+                jnp.sum(drk * x["k_from_start"], axis=1, keepdims=True)
+                + jnp.sum(drv * x["v32"], axis=1, keepdims=True)
+                + jnp.sum(da * x["m"], axis=1, keepdims=True))
+            dv_ref[at, h * dv:(h + 1) * dv] = (bcol * drv).astype(compute)
+            dgam = drk * x["rk"]
+            dk32 = drk * (bcol * x["from_start"])
+
+            # through the scores: dM's and dP's rows against the columns'
+            # operand, their transposes against the rows', a reference each
+            dm = da * bcol
+
+            def through(which, rows, cols_of, e_r, e_c):
+                dkk = jnp.where(which, dm, 0.0).astype(compute)
+                dqk = jnp.where(which, dscores, 0.0).astype(compute)
+                by_row = _dot(jnp.concatenate([dkk, dqk], axis=0), cols_of)
+                by_col = _dot(jnp.concatenate([dkk, dqk], axis=0), rows, _TN)
+                return (by_row[:FRAME] * e_r, by_row[FRAME:] * e_r, by_col * e_c)
+
+            g_k, g_q, g_col = through(
+                x["same_span"], x["rows_d"], x["k_cd"], x["e_rd"], x["e_cd"])
+            if "off" in x:
+                more = through(x["off"], x["rows_o"], x["k_co"], x["e_ro"], x["e_co"])
+                g_k, g_q, g_col = g_k + more[0], g_q + more[1], g_col + more[2]
+            dk32 = dk32 + g_k + g_col
+            dq32 = g_q + dq_from_start * x["from_start"]
+            dgam = dgam + k32 * (g_k - g_col) + q32 * g_q
+            dgam = dgam + dq_from_start * q32 * x["from_start"]
+            dk32 = dk32 + dk_to_end * x["to_end"]
+            # a position's sums lower what it hands to the leaving state; the
+            # chunk's last raise all of it
+            handed_on = dk_to_end * k32 * x["to_end"]  # [F, dk]
+            dgam = dgam - handed_on + jnp.concatenate([
+                jnp.where(ends, of["dlast"] + jnp.sum(
+                    handed_on[n:n + c], axis=0, keepdims=True), 0.0)
+                for n, of in zip(chunks, chain)], axis=0)
+            if unit:
+                dq32 = _through_unit(dq32, *x["units"][0])
+                dk32 = _through_unit(dk32, *x["units"][1])
+            dq_ref[h, at, :] = dq32.astype(compute)
+            dk_ref[h, at, :] = dk32.astype(compute)
+            dg_ref[h, at, :] = dgam
+            dcol = jnp.where(lane == hg + h, dbeta, dcol)
+        dcols.append(dcol)
+    drows_ref[...] = jnp.concatenate(dcols[::-1], axis=0).T[:2 * hg, :]
+
+
 _PARAMS = pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary"))
+# the channel form's frame holds a dozen [F, dk] float32 arrays more a head
+# (the operands' scalings both ways): beyond the 16 MB Mosaic scopes by
+# default, within the chip's 128 MB of VMEM
+_CHANNEL_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "arbitrary"),
+    vmem_limit_bytes=96 * 1024 * 1024)
 
 
 def _layout(q, v, c: int, order) -> tuple:
@@ -871,6 +1298,74 @@ def _rule_bwd(c, unit, interpret, residuals, cotangents):
 _rule.defvjp(_rule_fwd, _rule_bwd)
 
 
+def _channel_forward(q, k, v, gamma, beta, c, unit, keep, interpret):
+    """:func:`_forward` under a decay a key channel: ``gamma`` [B H, S, dk]
+    the sums a channel, a block of ``q``'s kind."""
+    rows, s, dk = q.shape
+    (hg, steps, dv), spec = _layout(q, v, c, lambda n: n)
+    entering = [(spec["entering"], jax.ShapeDtypeStruct(
+        (rows // hg, steps, hg, dk, dv), jnp.float32))] if keep else []
+    out_specs, out_shape = zip(
+        (spec["v"], jax.ShapeDtypeStruct(v.shape, v.dtype)), *entering,
+        (spec["state"], jax.ShapeDtypeStruct((rows, dk, dv), jnp.float32)))
+    return pl.pallas_call(
+        functools.partial(_channel_fwd_kernel, hg=hg, c=c, keep=keep, unit=unit),
+        grid=(rows // hg, steps),
+        in_specs=[spec["qk"], spec["qk"], spec["v"], spec["qk"], spec["rows"]],
+        out_specs=list(out_specs), out_shape=list(out_shape),
+        scratch_shapes=[pltpu.VMEM((hg, dk, dv), jnp.float32)],
+        compiler_params=_CHANNEL_PARAMS, interpret=interpret,
+        name="delta_channel_fwd",
+    )(q, k, v, gamma, _head_rows(beta, beta, hg))
+
+
+def _channel_backward(q, k, v, gamma, beta, entering, do, dfinal, c, unit,
+                      interpret):
+    """:func:`_backward` under a decay a key channel: the sums' gradient is
+    an array of ``gamma``'s shape."""
+    rows, s, _ = q.shape
+    (hg, steps, _), spec = _layout(q, v, c, lambda n: steps - 1 - n)
+    dq, dk, dv, dgamma, drows = pl.pallas_call(
+        functools.partial(_channel_bwd_kernel, hg=hg, c=c, unit=unit),
+        grid=(rows // hg, steps),
+        in_specs=[spec["qk"], spec["qk"], spec["v"], spec["qk"], spec["rows"],
+                  spec["entering"], spec["v"], spec["state"]],
+        out_specs=[spec["qk"], spec["qk"], spec["v"], spec["qk"], spec["rows"]],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype),
+                   jax.ShapeDtypeStruct(gamma.shape, jnp.float32),
+                   jax.ShapeDtypeStruct((rows // hg, 2 * hg, s), jnp.float32)],
+        scratch_shapes=[
+            pltpu.VMEM(entering.shape[2:], jnp.float32),  # the state's cotangent
+            pltpu.VMEM((s // steps // c, *entering.shape[2:]), jnp.float32)],
+        compiler_params=_CHANNEL_PARAMS, interpret=interpret,
+        name="delta_channel_bwd",
+    )(q, k, v, gamma, _head_rows(beta, beta, hg), entering, do, dfinal)
+    dbeta = drows.reshape(rows // hg, 2, hg, s)[:, 1]
+    return dq, dk, dv, dgamma, dbeta.reshape(rows, s)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _channel_rule(q, k, v, gamma, beta, c, unit, interpret):
+    return _channel_forward(q, k, v, gamma, beta, c, unit, False, interpret)
+
+
+def _channel_rule_fwd(q, k, v, gamma, beta, c, unit, interpret):
+    o, entering, final = _channel_forward(
+        q, k, v, gamma, beta, c, unit, True, interpret)
+    o = checkpoint_name(o, DELTA_RESIDUALS)
+    entering = checkpoint_name(entering, DELTA_RESIDUALS)
+    return (o, final), (q, k, v, gamma, beta, entering)
+
+
+def _channel_rule_bwd(c, unit, interpret, residuals, cotangents):
+    return _channel_backward(*residuals, *cotangents, c, unit, interpret)
+
+
+_channel_rule.defvjp(_channel_rule_fwd, _channel_rule_bwd)
+
+
 def gated_delta_kernel(
     q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array, beta: jax.Array,
     chunk: int, interpret: bool = False, unit: bool = False,
@@ -898,6 +1393,18 @@ def gated_delta_kernel(
     def heads_first(a):  # [B, S, H, ..] -> [B H, S, ..]
         return jnp.moveaxis(a, 2, 1).reshape(bsz * h, s, *a.shape[3:])
 
+    if g.ndim == 4:  # a decay a key channel: the sums are a block of q's kind
+        if not channel_kernel_fits(c, dk):
+            raise ValueError(
+                f"the channel-decayed kernel takes chunks of one or two spans "
+                f"of {2 * SOLVE_BLOCK} positions, got {c}")
+        gamma = jnp.cumsum(
+            g.astype(f32).reshape(bsz, s // c, c, h, dk), axis=2
+        ).reshape(bsz, s, h, dk)
+        o, final = _channel_rule(
+            heads_first(q), heads_first(k), v.reshape(bsz, s, h * dv),
+            heads_first(gamma), heads_first(beta.astype(f32)), c, unit, interpret)
+        return o.reshape(bsz, s, h, dv), final.reshape(bsz, h, dk, dv)
     gamma = jnp.cumsum(
         g.astype(f32).reshape(bsz, s // c, c, h), axis=2).reshape(bsz, s, h)
     o, final = _rule(

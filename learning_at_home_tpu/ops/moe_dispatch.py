@@ -153,17 +153,28 @@ def _small_top_k(x: jax.Array, k: int) -> tuple[jax.Array, jax.Array]:
     return jnp.stack(ws, axis=1), jnp.stack(is_, axis=1).astype(jnp.int32)
 
 
-# beyond this k a real sort wins over sequential argmax passes
+# up to this k sequential argmax passes win over a real sort, whatever E
 _SMALL_TOPK_MAX_K = 4
+# and up to this k where a row holds this many scores or more: a sort's
+# passes grow with E log^2 E where k argmax passes read k E (k = 8 of 512:
+# +2.1 % of a step end to end, and 170 -> 20 s of the levelling's 1,200
+# moves over [131072, 512]; my chip runs, PR 66).  Narrower rows at k = 8
+# (64, 128 experts) are not measured yet: ROADMAP.md queues them
+_WIDE_TOPK_MAX_K, _WIDE_TOPK_MIN_E = 8, 512
 
 
 def _top_k(x: jax.Array, k: int) -> tuple[jax.Array, jax.Array]:
-    """NOT a general ``lax.top_k`` drop-in: for k <= _SMALL_TOPK_MAX_K
-    inputs must not contain ``finfo.min`` (it collides with the argmax
-    mask sentinel and can duplicate indices — see ``_small_top_k``).
-    Every call site here feeds softmax gates, which are strictly
-    positive; pre-masked logits must use ``jax.lax.top_k`` directly."""
-    if k <= _SMALL_TOPK_MAX_K:
+    """NOT a general ``lax.top_k`` drop-in: where it takes the argmax
+    passes (k <= _SMALL_TOPK_MAX_K, or k <= _WIDE_TOPK_MAX_K over
+    _WIDE_TOPK_MIN_E scores or more: decided from k and E alone) inputs
+    must not contain ``finfo.min`` (it collides with the argmax mask
+    sentinel and can duplicate indices — see ``_small_top_k``).  Every
+    call site here feeds softmax gates or sigmoid scores plus a bias,
+    which are finite, or those with whole groups at minus infinity (below
+    the sentinel; the kept groups hold k finite scores at least);
+    pre-masked logits must use ``jax.lax.top_k`` directly."""
+    if k <= _SMALL_TOPK_MAX_K or (
+            k <= _WIDE_TOPK_MAX_K and x.shape[-1] >= _WIDE_TOPK_MIN_E):
         return _small_top_k(x, k)
     return jax.lax.top_k(x, k)
 
@@ -356,10 +367,51 @@ def _expert_counts(top_i: jax.Array, num_experts: int) -> jax.Array:
     )
 
 
+def kept_groups(selection: jax.Array, n_group: int, topk_group: int) -> jax.Array:
+    """[n, n_group] bool: the ``topk_group`` groups a token may choose in
+    (DeepSeek-V3's group-limited routing).  The ``E`` selection scores
+    ``selection`` [n, E] (the sigmoid scores plus the selection bias) are
+    ``n_group`` groups of ``E / n_group`` CONSECUTIVE experts; a group's
+    score is the sum of its two largest; the ``topk_group`` best groups are
+    kept, ties to the lower group.  float32, selection only.  Maxima and
+    masks, no sort: ``lax.top_k`` is a full sort on a TPU (the levelling
+    pass of 131,072 tokens by 512 experts took 140 ms a move with it, 170 s
+    of a run's set-up: my chip runs, PR 66)."""
+    n, num_experts = selection.shape
+    if num_experts % n_group or not 0 < topk_group <= n_group:
+        raise ValueError(
+            f"{n_group} groups of {num_experts} experts, {topk_group} kept: "
+            "the groups are equal and 1..n_group of them are kept")
+    groups = selection.reshape(n, n_group, -1)
+    best = jnp.max(groups, axis=-1, keepdims=True)
+    # the second largest: the largest with the FIRST of the largest taken out
+    first = jnp.argmax(groups, axis=-1)
+    rest = jnp.where(
+        jax.nn.one_hot(first, groups.shape[-1], dtype=bool), -jnp.inf, groups)
+    scores = best[..., 0] + jnp.max(rest, axis=-1)
+    return jnp.any(jax.nn.one_hot(
+        _small_top_k(scores, topk_group)[1], n_group, dtype=bool), axis=1)
+
+
+def group_limited(
+    selection: jax.Array, n_group: int = 1, topk_group: int = 1
+) -> jax.Array:
+    """``selection`` [n, E] with every expert outside a token's kept groups
+    (:func:`kept_groups`) at minus infinity; as it came where ``n_group``
+    is 1 (no groups: every other router of the repo)."""
+    if n_group == 1:
+        return selection
+    with jax.named_scope("groups"):
+        kept = kept_groups(selection, n_group, topk_group)
+        return jnp.where(
+            jnp.repeat(kept, selection.shape[1] // n_group, axis=1),
+            selection, -jnp.inf)
+
+
 def router_choice(
     logits: jax.Array, k: int, renormalize: bool = True,
     score: str = "softmax", bias: jax.Array | None = None,
-    scale: float = 1.0,
+    scale: float = 1.0, n_group: int = 1, topk_group: int = 1,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """logits [n, E] float32 → ``(gates [n, E], top_w [n, k], top_i [n, k])``:
     the k experts a token is sent to, the weight of each, and the scores
@@ -370,15 +422,21 @@ def router_choice(
     expert scored on its own, ``s = sigmoid(logits)``; the k largest of
     ``s + bias`` are chosen and weighed by ``s`` ALONE (``bias`` [E]
     selects and does not weigh: it enters nothing differentiable, so its
-    gradient is zero), renormalised over the chosen, times ``scale``."""
+    gradient is zero), renormalised over the chosen, times ``scale``.
+    ``n_group`` > 1 (the sigmoid router's): the k are chosen inside the
+    ``topk_group`` best of ``n_group`` groups of consecutive experts
+    (:func:`group_limited`; scope ``groups``)."""
     if score == "softmax":
-        if bias is not None:
-            raise ValueError("a selection bias goes with score='sigmoid'")
+        if bias is not None or n_group != 1:
+            raise ValueError(
+                "a selection bias and groups go with score='sigmoid'")
         gates = jax.nn.softmax(logits, axis=-1)
         top_w, top_i = _topk_weights(gates, k, renormalize)
     elif score == "sigmoid":
         s = jax.nn.sigmoid(logits)
-        _, top_i = _top_k(s if bias is None else s + bias, k)
+        selection = group_limited(
+            s if bias is None else s + bias, n_group, topk_group)
+        _, top_i = _top_k(selection, k)
         top_w = jnp.take_along_axis(s, top_i, axis=-1)
         if renormalize:
             top_w = top_w / jnp.maximum(
@@ -392,10 +450,23 @@ def router_choice(
     return gates, top_w, top_i
 
 
+def _groups(n_group: int, topk_group: int) -> dict:
+    """:func:`router_choice`'s group arguments, named, and none at all for a
+    router without groups, which is then called with the six arguments it
+    always had: a stand-in with that signature is put in its place by a
+    benchmark runner's wrong program (``train_recipe_lfm2``'s
+    ``biased_weights``, which tier-1 runs in ``tests/test_lfm2.py``), and a
+    PR that adds a configuration edits no file the benchmark has.  Once
+    that stand-in takes ``**kwargs`` (ROADMAP.md) the two callers pass the
+    arguments straight through and this goes."""
+    return {} if n_group == 1 else dict(n_group=n_group, topk_group=topk_group)
+
+
 def dropless_routing(
     logits: jax.Array, k: int, renormalize: bool = True,
     token_mask: jax.Array | None = None, score: str = "softmax",
     bias: jax.Array | None = None, scale: float = 1.0,
+    n_group: int = 1, topk_group: int = 1,
 ) -> DroplessPlan:
     """logits [n, E] float32 → the k experts per token and their weights
     (:func:`router_choice`) and the expert-sorted order.
@@ -404,7 +475,8 @@ def dropless_routing(
     of the aux loss."""
     num_experts = logits.shape[1]
     gates, top_w, top_i = router_choice(
-        logits, k, renormalize, score, bias, scale
+        logits, k, renormalize, score, bias, scale,
+        **_groups(n_group, topk_group)
     )
     if token_mask is not None:
         top_w = jnp.where(token_mask[:, None], top_w, 0.0)
@@ -464,7 +536,7 @@ def share_routing(
     logits: jax.Array, k: int, first: int, held: int, rows: int,
     renormalize: bool = True, token_mask: jax.Array | None = None,
     score: str = "softmax", bias: jax.Array | None = None,
-    scale: float = 1.0,
+    scale: float = 1.0, n_group: int = 1, topk_group: int = 1,
 ) -> SharePlan:
     """logits [n, E] float32 → the plan of the share that holds experts
     ``first .. first + held - 1`` in a buffer of ``rows`` rows.  The gates
@@ -472,7 +544,8 @@ def share_routing(
     would have added is left out, not renormalised away."""
     n, num_experts = logits.shape
     gates, top_w, top_i = router_choice(
-        logits, k, renormalize, score, bias, scale
+        logits, k, renormalize, score, bias, scale,
+        **_groups(n_group, topk_group)
     )
     if token_mask is not None:
         top_w = jnp.where(token_mask[:, None], top_w, 0.0)
@@ -653,16 +726,20 @@ def balanced_bias(bias: jax.Array, counts: jax.Array, rate: float) -> jax.Array:
     ).astype(bias.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("k", "moves"))
-def _level_moves(scores, bias, best, best_bias, rate, k: int, moves: int):
+@functools.partial(
+    jax.jit, static_argnames=("k", "moves", "n_group", "topk_group"))
+def _level_moves(scores, bias, best, best_bias, rate, k: int, moves: int,
+                 n_group: int = 1, topk_group: int = 1):
     """``moves`` moves of :func:`balanced_bias` at ``rate`` on the counts of
-    the k largest of ``scores + bias``; beside the bias they end at, the
-    lowest largest-load-over-mean seen (``best``) and the bias that read it."""
+    the k largest of ``scores + bias`` (inside a token's kept groups where
+    the router has groups); beside the bias they end at, the lowest
+    largest-load-over-mean seen (``best``) and the bias that read it."""
     num_experts = scores.shape[1]
 
     def move(_, carry):
         bias, best, best_bias = carry
-        counts = _expert_counts(_top_k(scores + bias, k)[1], num_experts)
+        counts = _expert_counts(_top_k(group_limited(
+            scores + bias, n_group, topk_group), k)[1], num_experts)
         load = jnp.max(counts) * (num_experts / (scores.shape[0] * k))
         best_bias = jnp.where(load < best, bias, best_bias)
         return balanced_bias(bias, counts, rate), jnp.minimum(load, best), best_bias
@@ -673,6 +750,7 @@ def _level_moves(scores, bias, best, best_bias, rate, k: int, moves: int):
 def level_bias(
     scores: jax.Array, bias: jax.Array, k: int,
     rate: float = 0.02, moves: int = 24, floor: float = 1e-4,
+    n_group: int = 1, topk_group: int = 1,
 ) -> tuple[jax.Array, list]:
     """The selection bias that levels the loads of ``scores`` [n, E] (a
     router's sigmoid scores on a pool of tokens): :func:`balanced_bias`
@@ -683,12 +761,14 @@ def level_bias(
     which it was lowest, and that load before and after."""
     inf = jnp.float32(jnp.inf)
     # a rate of 0 moves nothing: one move reads the load under ``bias``
-    start = best = float(_level_moves(scores, bias, inf, bias, 0.0, k, 1)[1])
+    start = best = float(_level_moves(
+        scores, bias, inf, bias, 0.0, k, 1, n_group, topk_group)[1])
     best_bias, stalled = bias, 0
     while rate >= floor and stalled < 2:
         _, low, best_bias = _level_moves(
             scores, best_bias, jnp.float32(best), best_bias, jnp.float32(rate),
-            k, moves + 1)  # the last move's bias is read by the one after it
+            k, moves + 1,  # the last move's bias is read by the one after it
+            n_group, topk_group)
         stalled = 0 if float(low) < best else stalled + 1
         best, rate = float(low), rate / 2
     return best_bias, [start, best]

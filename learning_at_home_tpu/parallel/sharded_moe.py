@@ -42,6 +42,7 @@ from learning_at_home_tpu.ops.moe_dispatch import (
     dispatch_tokens_indexed,
     dropless_routing,
     grouped_matmul,
+    kept_groups,
     share_buffer_rows,
     share_combine,
     share_routing,
@@ -113,7 +114,13 @@ class ShardedMixtureOfExperts:
     ``router_score="sigmoid"``: every expert scored on its own, the k
     largest chosen and renormalised, times ``routed_scale``;
     ``router_bias=True`` adds the parameter ``router_bias`` [E] float32 to
-    the scores for the CHOICE alone (``ops.moe_dispatch.router_choice``).
+    the scores for the CHOICE alone (``ops.moe_dispatch.router_choice``);
+    ``router_groups=(n_group, topk_group)``: the k are chosen inside the
+    ``topk_group`` best of ``n_group`` groups of consecutive experts, a
+    group scored by the sum of its two best selection scores
+    (``ops.moe_dispatch.group_limited``); a share then counts, as
+    ``groups_reaching_share``, the share of tokens any of whose kept groups
+    holds a held expert.
     """
 
     def __init__(
@@ -137,6 +144,7 @@ class ShardedMixtureOfExperts:
         router_score: str = "softmax",
         router_bias: bool = False,
         routed_scale: float = 1.0,
+        router_groups: tuple[int, int] | None = None,
     ):
         if expert_kind not in ("gelu", "gated_silu", "gated_relu", "relu2"):
             raise ValueError(
@@ -175,6 +183,16 @@ class ShardedMixtureOfExperts:
         if router_bias and router_score != "sigmoid":
             raise ValueError(
                 "router_bias=True is the selection bias of a sigmoid router"
+            )
+        if router_groups is not None and (
+            router_score != "sigmoid" or num_experts % router_groups[0]
+            or not 0 < router_groups[1] <= router_groups[0]
+            or k > router_groups[1] * (num_experts // router_groups[0])
+        ):
+            raise ValueError(
+                f"router_groups={router_groups} is (n_group, topk_group) of "
+                f"a sigmoid router: equal groups of the {num_experts} "
+                f"experts, the kept ones holding k={k} experts at least"
             )
         held = num_experts if held_experts is None else held_experts
         if not 0 <= first_held_expert <= num_experts - held or held < 1:
@@ -232,6 +250,7 @@ class ShardedMixtureOfExperts:
         self.router_score = router_score
         self.router_bias = router_bias
         self.routed_scale = routed_scale
+        self.router_groups = router_groups or (1, 1)
         self._gate_act = gate_activation(expert_kind)
         if routing == "dropless" and (self.ep > 1 or self.tp > 1):
             raise NotImplementedError(
@@ -367,6 +386,8 @@ class ShardedMixtureOfExperts:
                 aux_names += ("expert_counts", "router_bias_abs_max")
             elif share:  # no counts a step to read the empty experts off
                 aux_names += ("held_experts_empty",)
+            if share and self.router_groups[0] > 1:
+                aux_names += ("groups_reaching_share",)
             return shard_map(
                 self._local_forward_share if share
                 else self._local_forward_dropless,
@@ -532,7 +553,7 @@ class ShardedMixtureOfExperts:
             plan = dropless_routing(
                 logits, self.k, self.renormalize, token_mask,
                 self.router_score, params.get("router_bias"),
-                self.routed_scale,
+                self.routed_scale, *self.router_groups,
             )
         with jax.named_scope("moe_sort"):
             xs = sort_tokens(x.astype(compute), plan)  # [n*k, d]
@@ -634,6 +655,7 @@ class ShardedMixtureOfExperts:
                 share_buffer_rows(n, self.k, held, scored),
                 self.renormalize, token_mask, self.router_score,
                 params.get("router_bias"), self.routed_scale,
+                *self.router_groups,
             )
         with jax.named_scope("moe_sort"):
             xs = share_sort_tokens(x.astype(compute), plan)  # [R, d]
@@ -667,4 +689,15 @@ class ShardedMixtureOfExperts:
         else:  # a share with no bias to level: its load is what the data gives
             aux["held_experts_empty"] = jax.lax.pmax(
                 jnp.sum(plan.group_sizes == 0).astype(jnp.float32), axes)
+        n_group, topk_group = self.router_groups
+        if n_group > 1:  # whole groups are in or out a token
+            with jax.named_scope("router"), jax.named_scope("groups"):
+                size = scored // n_group
+                kept = kept_groups(
+                    jax.nn.sigmoid(logits) + params.get("router_bias", 0.0),
+                    n_group, topk_group)
+                first = self.first_held_expert
+                reaching = kept[:, first // size:(first + held - 1) // size + 1]
+                aux["groups_reaching_share"] = jax.lax.pmean(
+                    jnp.mean(jnp.any(reaching, axis=1).astype(jnp.float32)), axes)
         return y, aux

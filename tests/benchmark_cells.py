@@ -143,6 +143,26 @@ def _xing4_lines(setup, counters, reference):
     assert 0.0 <= reference["update_norm"] < 1e-2, reference["update_norm"]
 
 
+def _ling3_lines(setup, counters, reference):
+    assert setup["expert_param_bytes"] > 0
+    assert {"dropped_fraction", "groups_reaching_share", "delta_decay_min",
+            "delta_beta_max", "attention_gate_mean",
+            "router_bias_abs_max"} <= set(counters)
+    assert counters["delta_beta_max"]["max"] <= 1.0  # sigmoid(b): no factor 2
+    assert counters["delta_decay_min"]["min"] > 6e-3  # the gate's bound: e^-5
+    assert 0.0 < counters["groups_reaching_share"]["min"] <= 1.0
+    # a dense KDA layer, then KDA KDA latent with mixtures, at tiny sizes
+    assert len(reference["delta_layers_rms"]) == len(reference["delta_states_rms"]) == 3
+    assert len(reference["attention_layers_rms"]) == 1
+    assert reference["near_tie_shares"][0] == 0.0  # the dense layer routes nothing
+    # the head and the three layers with a mixture (both kinds of mixer:
+    # train_recipe_ling3.compared_layers); the rest held to having moved
+    assert len(reference["grad_stream_layers_rms"]) == 4
+    assert reference["leaves_held_to_moving"] >= 1
+    for name in ("grads_rms", "grad_stream_rms", "step_grad_norms", "update_norm"):
+        assert 0.0 <= reference[name] < 1e-3, (name, reference[name])
+
+
 class Row(NamedTuple):
     cell: str
     config: str
@@ -210,6 +230,14 @@ ROWS = (
          "attention_latent_share", "mtp_share", "attention_core_roofline",
          "expert_matmul_roofline"),
         _LEVELLED, 2, _xing4_lines),
+    Row("ling-3.0-flash-vl-train-zipf16k", "ling-3.0-flash-vl", "train-zipf16k", 1,
+        "manifest_ling3.json", 6600000007, 26,
+        ("mfu", "kda_share", "kda_core_share", "kda_core_roofline",
+         "kda_decay_share", "kda_proj_share", "kda_conv_share",
+         "kda_gate_norm_share", "attention_latent_share", "router_groups_share",
+         "groups_reaching_share", "attention_core_roofline",
+         "expert_matmul_roofline"),
+        _LEVELLED + ("groups_reaching_share",), 3, _ling3_lines),
 )
 
 

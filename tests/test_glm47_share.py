@@ -111,7 +111,9 @@ def test_set_up_levels_the_blocks_router_too(tiny):
 
 @pytest.mark.parametrize("changes, error, match", [
     ({"mtp_layers": 2}, ValueError, "0 or 1"),
-    ({"q_latent_dim": None}, ValueError, "together"),
+    # (q_latent_dim None beside a kv_latent_dim is a FORM since PR 66: queries
+    # of one plain product, tests/test_ling3.py builds and trains it)
+    ({"rope_head_dim": None}, ValueError, "together"),
     ({"head_dim": None}, ValueError, "together"),
     ({"n_kv_heads": 2}, ValueError, "latent attention"),
     ({"qk_norm": "head"}, ValueError, "latent attention"),

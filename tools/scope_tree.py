@@ -38,7 +38,10 @@ run's own lines come first (``REFERENCE`` .. ``SCOPES``, the result), then:
   (the conv mixer's three parts and the norm and add beside them, PR 61),
   ``--under hc`` (the residual streams' coefficients, Sinkhorn iterations,
   read and write of every part, the stack's and the prediction block's,
-  forward, remat's second forward and backward, PR 64).
+  forward, remat's second forward and backward, PR 64); ``--under delta``
+  in ``ling-3.0-flash-vl``'s cell folds its six channel-decayed (KDA)
+  layers into one node with ``in_proj``, ``conv``, ``decay``, ``core``,
+  ``gate_norm``, ``out_proj`` below it (PR 66).
 
 The instructions are written to ``chiprun_out/scope_tree.<cell>.<seed>.json``;
 ``--from`` renders such a file again without a run.
