@@ -660,9 +660,11 @@ def channel_delta_mixer(
     (:func:`~learning_at_home_tpu.ops.delta_rule.gated_delta_chunked` with
     a decay of rank 4); ``y = RMSNorm(o) * sigmoid(x W_gate)_h``, the norm
     over a head's ``dv`` under one scale shared by the heads, the gate ONE
-    number a head a token, float32 (scope ``gate_norm``); ``out = y
-    W_out``.  The bounded gate is what lets 16 positions' log-decays be
-    summed and exponentiated apart (``channel_decay_fits``)."""
+    number a head a token, float32 (scope ``gate_norm``: ``ops.gate_norm.
+    gated_rms_norm`` under a gate a group, on a TPU one pass each way);
+    ``out = y W_out``.  The bounded gate is what lets 16 positions'
+    log-decays be summed and exponentiated apart
+    (``channel_decay_fits``)."""
     b, s, _ = x.shape
     f32 = jnp.float32
     d_v = p["w_out"].shape[0]
@@ -692,13 +694,12 @@ def channel_delta_mixer(
             qk[:, :, 0], qk[:, :, 1], v.reshape(b, s, n_heads, dv), g, beta,
             chunk, decay_dtype, unit=True, decay_floor=decay_floor)
         decay_min, beta_max = jnp.exp(jnp.min(g)), jnp.max(beta)
-    with jax.named_scope("gate_norm"):
-        o32 = o.astype(f32)
-        y = (o32 * jax.lax.rsqrt(jnp.mean(o32 * o32, axis=-1, keepdims=True) + eps)
-             * p["gate_norm"]["scale"].astype(f32)
-             * jax.nn.sigmoid(head_gate(p, x))).astype(x.dtype)
+    with jax.named_scope("gate_norm"):  # the gate ONE number a head: its shape says so
+        y = gated_rms_norm(
+            o.reshape(b, s, d_v), head_gate(p, x)[..., 0],
+            p["gate_norm"]["scale"], dv, eps, gate_first=False, gate="sigmoid")
     with jax.named_scope("out_proj"):
-        out = y.reshape(b, s, d_v) @ p["w_out"].astype(x.dtype)
+        out = y @ p["w_out"].astype(x.dtype)
     return out, state, decay_min, beta_max
 
 

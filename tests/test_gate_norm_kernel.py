@@ -3,7 +3,9 @@
 plain form it replaces on a TPU: under ``interpret`` on the CPU, at sizes its
 tiles admit, in both orders (the gate first under a scale a channel and a
 skip: Mamba-2; the norm first under one scale shared by the heads: Gated
-DeltaNet).  What Mosaic makes of it at the cells' shapes is
+DeltaNet), and under a gate that is ONE float32 number a group behind a
+sigmoid (Kimi Delta Attention as Ling-3.0 gates it: a block of all the
+channels, a group at a time).  What Mosaic makes of it at the cells' shapes is
 ``tests/test_nemotron_hybrid.py``'s (AOT compiles for a described chip) and
 the chip's (``tools/smallthinker_probe.py gate_norm``)."""
 
@@ -206,19 +208,28 @@ def test_a_call_the_kernel_cannot_take_returns_the_plain_forms_bits(monkeypatch)
         gate_norm.gated_rms_norm_plain(y, z[..., 64:192], scale, 64, EPS, True, skip))
     assert not calls
     assert gate_norm.gated_rms_norm(y, z, scale, 64, EPS, True, 128, skip) == "the kernel"
-    assert calls == [(y, z, scale, 64, EPS, True, 128, skip)]
+    assert calls == [(y, z, scale, 64, EPS, True, 128, skip, "silu")]
 
 
-@pytest.mark.parametrize("gate_first", [True, False])
+@pytest.mark.parametrize("gate_first", [True, False, "a head's sigmoid"])
 def test_the_plain_form_is_the_mixers_arithmetic_of_before(gate_first):
-    """The two mixers' lines as ``models/trunk.py`` had them before PR 47,
-    written out: the plain form returns their bits (float32 in, so that
-    nothing hides in a rounding)."""
+    """The mixers' lines as ``models/trunk.py`` had them before PR 47 (and
+    ``channel_delta_mixer``'s before PR 67), written out: the plain form
+    returns their bits (float32 in, so that nothing hides in a rounding)."""
     f32 = jnp.float32
     b, s, heads, per = 2, 16, 4, 32
     c = heads * per
-    y, z, scale, (x, d) = _inputs(b, s, c, c, c if gate_first else per, heads, f32, seed=4)
-    if gate_first:  # ssm_mixer: groups of two heads' channels, a scale a channel
+    y, z, scale, (x, d) = _inputs(
+        b, s, c, c, c if gate_first is True else per, heads, f32, seed=4)
+    if gate_first == "a head's sigmoid":  # channel_delta_mixer: ONE number a head
+        o32 = y.reshape(b, s, heads, per).astype(f32)
+        head_gate = z[..., :heads, None]
+        was = (o32 * jax.lax.rsqrt(jnp.mean(o32 * o32, axis=-1, keepdims=True) + EPS)
+               * scale.astype(f32) * jax.nn.sigmoid(head_gate)).astype(y.dtype)
+        was = was.reshape(b, s, c)
+        got = gate_norm.gated_rms_norm_plain(
+            y, head_gate[..., 0], scale, per, EPS, False, None, "sigmoid")
+    elif gate_first:  # ssm_mixer: groups of two heads' channels, a scale a channel
         groups = 2
         was = y.reshape(b, s, heads, per).astype(f32) + d.astype(f32)[:, None] * x.reshape(
             b, s, heads, per).astype(f32)
@@ -234,3 +245,146 @@ def test_the_plain_form_is_the_mixers_arithmetic_of_before(gate_first):
             z.astype(f32))
         got = gate_norm.gated_rms_norm_plain(y, z, scale, per, EPS, False)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(was))
+
+
+# ---- a gate that is one number a group (PR 67) ----
+
+
+@pytest.fixture
+def group_rows(request, monkeypatch):
+    """The row block under a gate a group (the module's is 256: a block
+    shorter than S makes the partial sums of several blocks add up)."""
+    monkeypatch.setattr(gate_norm, "_GROUP_ROWS", request.param)
+    return request.param
+
+
+def _group_inputs(bsz, s, c, group, dtype, seed=0):
+    """``(y [B, S, C], the gate [B, S, C / group] float32, scale [group])``."""
+    rs = np.random.RandomState(seed)
+    return (jnp.asarray(rs.randn(bsz, s, c), dtype),
+            jnp.asarray(2.0 * rs.randn(bsz, s, c // group), jnp.float32),
+            jnp.asarray(1.0 + 0.1 * rs.randn(group), jnp.float32))
+
+
+def _group_forms(group, gate="sigmoid"):
+    def plain(y, z, scale):
+        return gate_norm.gated_rms_norm_plain(y, z, scale, group, EPS, False, None, gate)
+
+    def kernel(y, z, scale):
+        return gate_norm.gated_rms_norm_kernel(
+            y, z, scale, group, EPS, False, 0, None, gate, interpret=True)
+
+    return plain, kernel
+
+
+# (row block, B, S, C, group, the gate's function): Ling-3.0's heads of one
+# lane tile in blocks shorter than S over two rows of a batch; a block of
+# the whole length whose groups are two lane tiles; the other function
+GROUP_CASES = pytest.mark.parametrize("group_rows, bsz, s, c, group, gate, dtype", [
+    pytest.param(r, b, s, c, group, gate, dtype,
+                 id=f"{r}-{b}x{s}x{c}-{group}-{gate}-{jnp.dtype(dtype).name}")
+    for r, b, s, c, group, gate in [
+        (64, 2, 128, 512, 128, "sigmoid"), (32, 1, 32, 1024, 256, "sigmoid"),
+        (32, 1, 32, 256, 128, "silu")]
+    for dtype in (jnp.float32, jnp.bfloat16)
+], indirect=["group_rows"])
+
+
+@GROUP_CASES
+def test_under_a_gate_a_group_the_kernel_and_its_gradients_match_the_plain_form(
+        group_rows, bsz, s, c, group, gate, dtype):
+    """The output as above; ``dy``, the GATE's gradient (float32 [B, S, C /
+    group] whatever ``y``'s dtype: one group sum serves it and ``dy``) and
+    ``dscale`` against ``jax.grad`` of the plain form."""
+    args = _group_inputs(bsz, s, c, group, dtype)
+    plain, kernel = _group_forms(group, gate)
+    weigh = jnp.asarray(np.random.RandomState(2).randn(bsz, s, c), jnp.float32)
+
+    def both(form):
+        def fn(*a):
+            out, back = jax.vjp(form, *a)
+            return (out, *back((weigh.astype(dtype))))
+        return jax.jit(fn)
+
+    want, got = both(plain)(*args), both(kernel)(*args)
+    for name, g, w in zip(("out", "dy", "dz", "dscale"), got, want):
+        assert g.dtype == w.dtype and g.shape == w.shape, name
+    assert got[2].dtype == jnp.float32 and got[2].shape == (bsz, s, c // group)
+    out, out_want = (np.asarray(a[0], np.float32) for a in (got, want))
+    if dtype == jnp.float32:
+        np.testing.assert_allclose(out, out_want, atol=2e-6, rtol=2e-6)
+    else:
+        assert np.all(np.abs(out - out_want) <= np.maximum(
+            np.abs(out_want) * 2.0 ** -7, 2e-6))
+        assert np.mean(out != out_want) < 0.01
+    for name, g, w in zip(("dy", "dz", "dscale"), got[1:], want[1:]):
+        rounded = dtype == jnp.bfloat16 and name == "dy"
+        assert _rms(g, w) < (3e-3 if rounded else 3e-6), (name, _rms(g, w))
+
+
+@pytest.mark.parametrize("group_rows", [16], indirect=True)
+def test_a_heads_mean_and_gate_read_their_own_channels_and_no_others(group_rows):
+    """One row with one head's channels scaled a thousandfold, then with
+    that head's gate moved: the other heads' outputs stay as they were."""
+    c, group = 512, 128
+    y, z, scale = _group_inputs(1, 32, c, group, jnp.float32, seed=3)
+    _, kernel = _group_forms(group)
+    still = np.asarray(kernel(y, z, scale))
+    for k in range(c // group):
+        lanes = slice(k * group, (k + 1) * group)
+        for moved in (kernel(y.at[0, 5, lanes].multiply(1000.0), z, scale),
+                      kernel(y, z.at[0, 5, k].add(1.0), scale)):
+            differs = np.any(np.asarray(moved) != still, axis=(0, 1))
+            assert differs[lanes].all() and not np.delete(differs, lanes).any()
+
+
+@pytest.mark.parametrize("shape, group, backend, fits", [
+    ((1, 16384, 4096), 128, "tpu", True),  # ling-3.0: 32 heads of 128
+    ((2, 4096, 1024), 256, "tpu", True),
+    ((1, 64, 512), 128, "tpu", True),  # one block of the whole length
+    ((1, 16384, 4096), 128, "cpu", False),  # the backend
+    ((1, 16384, 4096), 64, "tpu", False),  # a group off the lane tiles
+    ((1, 16384, 5760), 192, "tpu", False),
+    ((1, 16384, 8192), 128, "tpu", False),  # wider than the one block
+    ((1, 16384 + 64, 4096), 128, "tpu", False),  # the row block does not divide
+    ((1, 200, 4096), 128, "tpu", False),  # a block off a 16-bit sublane tile
+    ((1, 96, 4096), 128, "tpu", True),  # no strips here: the block is walked whole
+])
+def test_the_path_rule_under_a_gate_a_group(shape, group, backend, fits):
+    assert gate_norm.gate_norm_fits(shape, group, backend, 0, True) is fits
+    assert gate_norm.gate_a_group(shape, (*shape[:2], shape[2] // group), group)
+    assert not gate_norm.gate_a_group(shape, shape, group)
+
+
+def test_a_gate_a_group_the_kernel_cannot_take_returns_the_plain_forms_bits(
+        monkeypatch):
+    """On the CPU and at heads off the lane tiles ``gated_rms_norm`` is the
+    plain form; where the rule admits the call the kernel gets all of it.
+    The gate a group stands after the norm, at column 0, without a skip:
+    anything else is refused, not built."""
+    y, z, scale = _group_inputs(1, 32, 256, 128, jnp.bfloat16)
+    narrow = _group_inputs(1, 32, 256, 64, jnp.bfloat16)
+    calls = []
+    monkeypatch.setattr(gate_norm, "gated_rms_norm_kernel",
+                        lambda *a: calls.append(a) or "the kernel")
+
+    def same(got, want):
+        return np.array_equal(np.asarray(got, np.float32), np.asarray(want, np.float32))
+
+    want = gate_norm.gated_rms_norm_plain(y, z, scale, 128, EPS, False, None, "sigmoid")
+    assert same(gate_norm.gated_rms_norm(
+        y, z, scale, 128, EPS, False, gate="sigmoid"), want)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert same(
+        gate_norm.gated_rms_norm(*narrow, 64, EPS, False, gate="sigmoid"),
+        gate_norm.gated_rms_norm_plain(*narrow, 64, EPS, False, None, "sigmoid"))
+    assert not calls
+    assert gate_norm.gated_rms_norm(
+        y, z, scale, 128, EPS, False, gate="sigmoid") == "the kernel"
+    assert calls == [(y, z, scale, 128, EPS, False, 0, None, "sigmoid")]
+    for refused in ({"gate_first": True}, {"gate_first": False, "first": 128},
+                    {"gate_first": False, "skip": (y, jnp.ones(2))},
+                    {"gate_first": False, "gate": "tanh"}):
+        with pytest.raises(ValueError):
+            gate_norm.gated_rms_norm(y, z, scale, 128, EPS, **refused)
+    assert len(calls) == 1

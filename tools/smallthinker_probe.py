@@ -79,18 +79,24 @@ bytes ``[B | C | u]`` in and ``y`` out forward and those, ``dy`` in and ``d[B
 PERF.md section 6, PR 61).
 
 ``gate_norm`` (on the chip): the mixers' gate and grouped RMSNorm
-(``ops/gate_norm.py``) as the two hybrid cells call it (Nemotron: ``[1,
+(``ops/gate_norm.py``) as three cells call it (Nemotron: ``[1,
 16384, 4096]`` bf16, groups of 512 under a scale a channel, the gate first,
 ``z`` at column 0 of ``[.., 10304]``, with the skip ``y + D x``;
 Olmo-Hybrid: ``[1, 16384, 5760]``, groups of 192 under one shared scale,
-the norm first, ``z`` at column 11,520 of ``[.., 17340]``), the plain form
+the norm first, ``z`` at column 11,520 of ``[.., 17340]``; Ling-3.0, PR 67:
+``[1, 16384, 4096]``, heads of 128 under one shared scale, the norm first,
+the gate ONE float32 number a head ``[1, 16384, 32]`` under a sigmoid: the
+least bytes are ``y`` in and the result out, and the cotangent in and ``dy``
+out backward), the plain form
 against the kernel (``gate_norm_fwd`` / ``gate_norm_bwd``): milliseconds a
 call forward and forward + backward (a ``jax.vjp`` under a given bf16
 cotangent), GB/s on the LEAST bytes a pass moves (forward ``y``, ``z``
 (and ``x``) in and the result out; backward those and the cotangent in,
 ``dy``, ``dz`` (and ``dx``) out), and the kernel's output and gradients
 against the plain form's.  ``256x32``-like arguments time the kernel at
-those row blocks and strips too (PERF.md section 6, PR 47).
+those row blocks and strips too, a cell's name among the arguments reads
+that cell alone (``gate_norm ling3 64x64 256x32``; PERF.md section 6, PR
+47 and PR 67).
 
 ``streams`` (on the chip): the residual streams' three stages
 (``ops/stream_mix.py``) at the ``xing4.0-29b-a4b`` cell's shape (``[1,
@@ -795,7 +801,7 @@ def conv_gated(blocks: list, calls: int = 20) -> None:
     ops._ROWS, ops._CHANNELS, ops._STRIP = own
 
 
-def gate_norm(blocks: list, calls: int = 20) -> None:
+def gate_norm(words: list, calls: int = 20) -> None:
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -804,31 +810,54 @@ def gate_norm(blocks: list, calls: int = 20) -> None:
 
     s = 16384
     rs = np.random.default_rng(4700000007)
-    committed = (ops._ROWS, ops._STRIP)
 
     def normal(*shape, dtype=jnp.bfloat16):
         return jnp.asarray(rs.standard_normal(shape), dtype)
 
-    # (cell, C, the projection's width, z's column, group, the scale's
-    # length, eps, gate first, heads of the skip)
-    cells = [("nemotron", 4096, 10304, 0, 512, 4096, 1e-5, True, 64),
-             ("olmo-hybrid", 5760, 17340, 11520, 192, 192, 1e-6, False, 0)]
-    for cell, c, wide, first, group, n_scale, eps, gate_first, heads in cells:
+    # (cell, C, the gate's array's width and dtype, z's column, group, the
+    # scale's length, eps, gate first, heads of the skip, the gate's function)
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    cells = [("nemotron", 4096, 10304, bf16, 0, 512, 4096, 1e-5, True, 64, "silu"),
+             ("olmo-hybrid", 5760, 17340, bf16, 11520, 192, 192, 1e-6, False, 0, "silu"),
+             ("ling3", 4096, 32, f32, 0, 128, 128, 1e-6, False, 0, "sigmoid")]
+    named = [w for w in words if w in {cell[0] for cell in cells}]
+    blocks = [tuple(int(n) for n in w.split("x")) for w in words if w not in named]
+    for cell, c, wide, of_gate, first, group, n_scale, eps, gate_first, heads, gate in cells:
+        if named and cell not in named:
+            continue
+        a_group = wide == c // group
+        # which of the module's blocks this call reads: a gate a group has
+        # its own rows and no strips (``256x0``: the second number is not read)
+        at_names = ("_GROUP_ROWS",) if a_group else ("_ROWS", "_STRIP")
+        committed = tuple(getattr(ops, name) for name in at_names)
         # the projection's width rounded up to whole lane tiles: an ARGUMENT
         # of a width that is none gets a layout with the positions minor, and
         # a transposing copy of it in front of the kernel (in the step the
-        # in-projection writes it channels minor)
-        y, z, dout = normal(1, s, c), normal(1, s, wide + -wide % 128), normal(1, s, c)
+        # in-projection writes it channels minor).  A gate a group is so
+        # narrow that it is MADE inside the timed program, as the step makes
+        # it: ``z`` is then the two factors of a small product ([1, S, 128]
+        # by [128, C / group], float32 out), in both forms alike
+        width = 128 if a_group else wide + -wide % 128
+        y, z, dout = normal(1, s, c), normal(1, s, width), normal(1, s, c)
+        if a_group:
+            z = (z, 0.2 * normal(width, wide))
         scale = 1.0 + 0.1 * normal(n_scale, dtype=jnp.float32)
         skip = (normal(1, s, c), normal(heads, dtype=jnp.float32)) if heads else None
 
+        def gate_of(z):
+            if not a_group:
+                return z
+            return jnp.einsum("bsd,dh->bsh", *z, preferred_element_type=of_gate)
+
         def plain(y, z, scale, skip):
+            z = gate_of(z)
             return ops.gated_rms_norm_plain(
-                y, z[..., first:first + c], scale, group, eps, gate_first, skip)
+                y, z if a_group else z[..., first:first + c], scale, group, eps,
+                gate_first, skip, gate)
 
         def kernel(y, z, scale, skip):
             return ops.gated_rms_norm_kernel(
-                y, z, scale, group, eps, gate_first, first, skip)
+                y, gate_of(z), scale, group, eps, gate_first, first, skip, gate)
 
         def both(form):
             def fn(y, z, scale, skip, dout):
@@ -837,16 +866,20 @@ def gate_norm(blocks: list, calls: int = 20) -> None:
             return jax.jit(fn)
 
         least = y.size * y.dtype.itemsize  # one pass over [1, S, C] bf16
-        passes = (4, 11) if heads else (3, 8)  # forward; forward + backward
+        # forward; forward + backward (a gate a group is 1/64 of a pass)
+        passes = (4, 11) if heads else (2, 5) if a_group else (3, 8)
         want = None
         forms = [("plain", plain, None), ("kernel", kernel, committed)] + [
-            ("kernel", kernel, tuple(int(n) for n in a.split("x"))) for a in blocks]
+            ("kernel", kernel, at) for at in blocks]
         for name, form, at in forms:
-            if at:
-                ops._ROWS, ops._STRIP = at
+            for at_name, n in zip(at_names, at or ()):
+                setattr(ops, at_name, n)
             try:
                 got = jax.device_get(both(form)(y, z, scale, skip, dout))
-                forward = _ms(jax.jit(form), (y, z, scale, skip), calls)
+                # a function of its own a block: ``jax.jit(form)`` would hand
+                # back the program it compiled at the first block
+                forward = _ms(
+                    jax.jit(lambda *a, form=form: form(*a)), (y, z, scale, skip), calls)
                 forward_backward = _ms(both(form), (y, z, scale, skip, dout), calls)
             except Exception as e:  # a block the compiler refuses
                 print("GATE_NORM " + json.dumps({
@@ -854,9 +887,11 @@ def gate_norm(blocks: list, calls: int = 20) -> None:
                     "refused": str(e)[:300]}), flush=True)
                 continue
             want = want or got
-            names = ("out", "dy", "dz", "dscale", "dx", "dD")
+            names = ("out", "dy", "dz", "dz_weights", "dscale") if a_group else (
+                "out", "dy", "dz", "dscale", "dx", "dD")
             print("GATE_NORM " + json.dumps({
                 "cell": cell, "form": name, "rows_strip": at, "shape": list(y.shape),
+                "gate": [[1, s, wide], jnp.dtype(of_gate).name, gate],
                 "forward_ms": forward, "forward_backward_ms": forward_backward,
                 "backward_ms": forward_backward - forward,
                 "forward_gb_s_on_least_bytes": passes[0] * least / forward / 1e6,
@@ -865,6 +900,8 @@ def gate_norm(blocks: list, calls: int = 20) -> None:
                 "rms_against_plain": {
                     k: _rel_rms(a, b_) for k, a, b_ in zip(names, got, want)},
             }), flush=True)
+        for at_name, n in zip(at_names, committed):
+            setattr(ops, at_name, n)
 
 
 def streams(blocks: list, calls: int = 10, shape=(1, 16384, 4, 3584)) -> None:
