@@ -1247,7 +1247,11 @@ class RemoteMixtureOfExperts:
         ``client.dispatch.join:backward``, ``client.pack:forward``,
         ``rpc.multi:backward``, and the two halves of an exchange,
         ``rpc.send`` and ``rpc.decode`` (what is left of ``rpc.<type>`` is
-        the wait for the server)."""
+        the wait for the server).  ``threads`` is the process's too, over
+        those same seconds (``Timeline.thread_stats``): the ``lah-client``
+        loop's ``busy_share``, ``cpu_share``, ``turns_per_s`` and
+        ``turn_ms_mean``, and ``process_cpu_cores``, the CPU the whole
+        process took, the calling threads' included."""
         m = self._headline_metrics()
 
         def nz(v):  # deques empty → None, the historical contract
@@ -1257,7 +1261,7 @@ class RemoteMixtureOfExperts:
         return {
             "pack_p50_ms": nz(m["lah_client_pack_p50_ms"]),
             "wait_p50_ms": nz(m["lah_client_wait_p50_ms"]),
-            "stages": timeline.stage_stats(("moe.", "client.", "rpc.")),
+            **timeline.stages_and_threads(("moe.", "client.", "rpc.")),
             "pack_bytes": int(m["lah_client_pack_bytes_total"]),
             "pack_once_bytes_saved": int(
                 m["lah_client_pack_once_bytes_saved_total"]

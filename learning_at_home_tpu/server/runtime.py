@@ -105,7 +105,13 @@ class Runtime:
         ``BatchJob.stack`` and nothing else: what precedes it is a few
         lines of this loop (``runtime.queue`` ends at that reading too).  The queue's next entry is taken inside the
         hand-off that precedes it (``item``), so the fetch has a name too.
-        A batch costs the thread one clock call a stage and one."""
+        A batch costs the thread one clock call a stage and one.
+
+        The thread's CPU seconds are its clock's (``stats()["threads"]``),
+        ticked at readings the chain already has: the end of every
+        ``runtime.idle`` and of every hand-off.  Its busy time is what the
+        spans say, so it keeps no sums of its own."""
+        self._clock = timeline.register_thread(threading.current_thread().name)
         pending: Optional[_Inflight] = None
         item = None  # the queue's next entry, where a hand-off fetched it
         mark = time.monotonic()
@@ -118,6 +124,8 @@ class Runtime:
                     if item[2] is None:
                         idle.exclude()  # waited for shutdown, not for work
                 mark = idle.end
+                if mark >= self._clock.due:
+                    self._clock.tick(mark)
             elif item is None:
                 # don't wait: if no new job is ready, spend the idle
                 # time materializing the in-flight one instead
@@ -249,6 +257,8 @@ class Runtime:
             # drop its last reference to them
             del inflight.raw_outputs[:]
             item = self._poll() if fetch else None
+        if handoff.end >= self._clock.due:
+            self._clock.tick(handoff.end)
         return handoff.end, item
 
     @staticmethod
@@ -286,8 +296,11 @@ class Runtime:
             # the request's life by stage, every stage over the same recent
             # seconds (count, p50_ms, p95_ms, share, extent_s: stage_stats
             # in utils/profiling.py), where the *_time_ms sums above run
-            # from process start
-            "stages": timeline.stage_stats(("server.", "pool.", "runtime.")),
+            # from process start; and "threads", over those same seconds
+            # the loop's and this thread's CPU seconds beside their wall
+            # seconds (thread_stats): device_time_ms above is this thread's
+            # WALL time in launch plus materialize, not the chip's busy time
+            **timeline.stages_and_threads(("server.", "pool.", "runtime.")),
         }
 
     def _deliver(

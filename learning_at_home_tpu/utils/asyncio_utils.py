@@ -15,7 +15,10 @@ import asyncio
 import concurrent.futures
 import contextlib
 import threading
+import time
 from typing import Any, Awaitable, Callable, Optional
+
+from learning_at_home_tpu.utils.profiling import live_annotation, timeline
 
 if hasattr(asyncio, "timeout"):  # Python >= 3.11
     asyncio_timeout = asyncio.timeout
@@ -103,6 +106,11 @@ def run_forever(
     return run_in_background(loop), stop
 
 
+# A loop reads the CPU seconds of one blocking select in this many, and
+# counts it for as many: ``time.thread_time`` is a system call.
+_WAIT_CPU_EVERY = 16
+
+
 class BackgroundLoop:
     """An asyncio event loop running forever in a dedicated thread.
 
@@ -110,6 +118,17 @@ class BackgroundLoop:
     background loops; synchronous JAX host code submits coroutines with
     :meth:`run` / :meth:`submit`.  This replaces the reference's
     process-per-component + mp.Pipe architecture.
+
+    The loop thread's time is the chain ``loop.select | loop.run``:
+    ``loop.select`` is the thread inside a select that may block (no
+    callback is ready: it waits for a socket or a timer), ``loop.run`` the
+    rest, from such a select's return to the next one's entry: the passes'
+    callbacks, and the polls between two passes while callbacks are ready.
+    Both are running SUMS on the thread's clock (``Timeline.thread_stats``:
+    ``busy_share``, ``turn_ms_mean``, and the thread's CPU seconds in
+    ``loop.run`` beside them), not spans: a pass costs a clock reading or
+    two, and no reservoir entry.  While a profiler session is live each
+    ``loop.run`` is also a ``jax.profiler.TraceAnnotation`` on this thread.
     """
 
     def __init__(self, name: str = "lah-loop"):
@@ -122,8 +141,59 @@ class BackgroundLoop:
 
     def _run(self) -> None:
         asyncio.set_event_loop(self.loop)
+        self._time_turns()
         self.loop.call_soon(self._started.set)
         self.loop.run_forever()
+
+    def _time_turns(self) -> None:
+        """Wrap the selector's ``select``, the one call a ``SelectorEventLoop``
+        pass makes to wait: ``timeout`` 0 means callbacks are ready and the
+        call is a poll, part of ``loop.run``; any other may block, and the
+        readings round it close one ``loop.run`` and open the next.
+
+        The CPU seconds the thread burns inside those waits (the kernel's
+        sleep and wake-up: a hundred microseconds a select on the chip's
+        virtual machine, which at 600 selects a second is 6 % of a CPU) are
+        left out of its clock, so that ``cpu_share`` is the CPU of
+        ``loop.run`` and stays under ``busy_share``: every sixteenth wait is
+        read on the CPU's clock too, OUTSIDE the wall's readings, and counts
+        for sixteen (``time.thread_time`` is a system call, 7 us on that
+        machine, where ``time.monotonic`` is not).  A loop without a
+        ``_selector`` keeps no chain."""
+        selector = getattr(self.loop, "_selector", None)
+        if selector is None:
+            return
+        clock = timeline.register_thread(self.thread.name)
+        real_select = selector.select
+        monotonic, thread_time = time.monotonic, time.thread_time
+        busy_s, waited_cpu_s, turns, waits = 0.0, 0.0, 0, 0
+        mark, annotation = monotonic(), None
+
+        def select(timeout=None):
+            nonlocal busy_s, waited_cpu_s, turns, waits, mark, annotation
+            turns += 1
+            if timeout is not None and timeout <= 0:
+                now = monotonic()
+                if now >= clock.due:
+                    clock.tick(now, busy_s + (now - mark), turns, waited_cpu_s)
+                return real_select(timeout)
+            waits += 1
+            cpu = None if waits % _WAIT_CPU_EVERY else thread_time()
+            now = monotonic()
+            if annotation is not None:
+                annotation.__exit__(None, None, None)
+            busy_s += now - mark
+            if now >= clock.due:
+                clock.tick(now, busy_s, turns, waited_cpu_s)
+            try:
+                return real_select(timeout)
+            finally:
+                mark = monotonic()
+                if cpu is not None:
+                    waited_cpu_s += _WAIT_CPU_EVERY * (thread_time() - cpu)
+                annotation = live_annotation("loop.run")
+
+        selector.select = select
 
     def submit(self, coro: Awaitable) -> concurrent.futures.Future:
         """Schedule a coroutine; return a concurrent future (non-blocking)."""

@@ -21,6 +21,14 @@ per-RPC timing spans.  This module provides both:
   ``averaging.rounds`` / ``.degraded_rounds`` / ``.bytes_sent``.  The hot
   path's headline counts (overlapped jobs, pack bytes, hedges) are the
   registry's alone: nothing read their Timeline twins;
+- a **thread's clock** (:class:`ThreadClock`, :meth:`Timeline.register_thread`,
+  :meth:`Timeline.thread_stats`): a named thread's CPU seconds
+  (``time.thread_time``) and the process's beside its wall seconds, four
+  samples a second at most, always on.  The spans are WALL time: a thread
+  that is "never idle" by them may be computing, waiting for the device, or
+  waiting for the interpreter lock, and only its CPU seconds tell those
+  apart.  The asyncio loops (utils/asyncio_utils.py: the chain
+  ``loop.select | loop.run``) and the server's Runtime thread tick one;
 - :func:`device_trace`, a thin wrapper over ``jax.profiler.trace`` that
   captures an XLA/TensorBoard trace directory for the jitted compute.
 
@@ -148,6 +156,11 @@ STAGE_WINDOW_S = 30.0
 KINDS = ("forward", "backward")
 _KIND_CODES = {kind: float(i) for i, kind in enumerate(KINDS, 1)}
 
+# A thread's clock: no sample sooner than this after the thread's last one,
+# and the samples kept a thread (at four a second, seventeen minutes).
+THREAD_SAMPLE_S = 0.25
+THREAD_HISTORY_LEN = 4096
+
 _annotation_cls = None  # jax.profiler.TraceAnnotation, once jax is loaded
 
 
@@ -162,6 +175,61 @@ def _resolve_annotation_cls():
             return None
         _annotation_cls = TraceAnnotation
     return _annotation_cls
+
+
+def live_annotation(name: str):
+    """An ENTERED ``jax.profiler.TraceAnnotation`` while a profiler session
+    is live (the caller exits it, on the same thread), else None: what
+    ``Span.__enter__`` does in line, for a stage that is no span."""
+    cls = _annotation_cls or _resolve_annotation_cls()
+    if cls is None or not cls.is_enabled():
+        return None
+    annotation = cls(name)
+    annotation.__enter__()
+    return annotation
+
+
+class ThreadClock:
+    """One thread's CPU seconds beside its wall seconds: a bounded history
+    of ``(monotonic, thread CPU s, process CPU s, busy_s, turns)``, at most
+    one sample in ``THREAD_SAMPLE_S``.  ``busy_s`` and ``turns`` are running
+    sums the thread keeps itself (an asyncio loop's time outside its
+    blocking selects and its passes, utils/asyncio_utils.py), and so is
+    ``waited_cpu_s``, CPU seconds the thread burned inside its own waits and
+    wants left out of its CPU; a thread whose spans already say when it was
+    busy (the Runtime's) passes none.
+
+    Made by :meth:`Timeline.register_thread` ON the thread it times, and
+    ticked by that thread alone: ``time.thread_time`` is the caller's, so a
+    tick from any other thread is dropped.  The samples lie in no reservoir
+    and under no span name (a reservoir entry a turn would fill 4096 slots
+    in a second and cut the extent of every stage read beside it); read
+    them with :meth:`Timeline.thread_stats`."""
+
+    __slots__ = ("name", "ident", "samples", "due", "_thread_time",
+                 "_process_time")
+
+    def __init__(self, name: str, thread_time=time.thread_time,
+                 process_time=time.process_time):
+        self.name = name
+        self.ident = threading.get_ident()
+        self.samples: deque[tuple] = deque(maxlen=THREAD_HISTORY_LEN)
+        self.due = float("-inf")  # a caller may compare before it calls
+        self._thread_time = thread_time
+        self._process_time = process_time
+
+    def tick(self, now: float, busy_s: float = 0.0, turns: int = 0,
+             waited_cpu_s: float = 0.0) -> None:
+        """``now`` is a ``time.monotonic`` reading the thread already has:
+        a tick is one comparison, and a sample two clock calls at most once
+        in ``THREAD_SAMPLE_S``."""
+        if now < self.due or threading.get_ident() != self.ident:
+            return
+        self.due = now + THREAD_SAMPLE_S
+        self.samples.append((  # atomic: the GIL
+            now, self._thread_time() - waited_cpu_s, self._process_time(),
+            busy_s, turns,
+        ))
 
 
 class Span:
@@ -255,6 +323,8 @@ class Timeline:
             tuple[str, float, float, Optional[str], int, dict]
         ] = deque(maxlen=maxlen)
         self._recent: dict[str, deque[tuple[float, float, float]]] = {}
+        # the newest clock of each thread name; a thread keeps its own
+        self._threads: dict[str, ThreadClock] = {}
         self._counters: defaultdict[str, float] = defaultdict(float)
         self.max_counter_keys = max_counter_keys
         self._lock = sanitizer.lock("profiling.timeline")
@@ -272,6 +342,8 @@ class Timeline:
         with self._lock:
             self._spans.clear()
             self._recent = {}  # a span in flight keeps its old reservoir
+            for clock in self._threads.values():
+                clock.samples.clear()  # a live thread goes on ticking it
             self._counters.clear()
 
     def span(
@@ -335,27 +407,60 @@ class Timeline:
         return [(s, d) for s, d, c in list(reservoir)
                 if code is None or c == code]
 
-    def stage_stats(
-        self, prefix: str | tuple = "", window_s: float = STAGE_WINDOW_S,
-        skip_tail_s: float = 0.0,
-    ) -> dict[str, dict]:
-        """The keys under ``prefix`` (one, or a tuple of several), all
-        read over ONE common extent of time, so that a stage with two
-        spans a second and one with seven hundred describe the same
-        seconds.  A key is a span name or, for the spans of it that
-        carried a kind, ``<name>:<kind>``.  Per key ``count``, ``p50_ms``,
-        ``p95_ms`` of the spans that ended inside the extent, ``share``,
-        the part of the extent the stage was running (a span that reaches
-        over either end counts with its part inside), and ``extent_s``
-        itself.
+    def register_thread(
+        self, name: str, thread_time=time.thread_time,
+        process_time=time.process_time,
+    ) -> ThreadClock:
+        """A clock for the CALLING thread, read under ``name`` by
+        :meth:`thread_stats`.  The thread keeps the clock and ticks it; a
+        name is read from the clock registered last, so two live threads
+        of one name (two servers in one process) never mix their samples,
+        and the table holds a clock a name however many threads come and
+        go (names beyond ``max_counter_keys`` get a clock nobody reads)."""
+        clock = ThreadClock(name, thread_time, process_time)
+        with self._lock:
+            if name in self._threads or (
+                len(self._threads) < self.max_counter_keys
+            ):
+                self._threads[name] = clock
+        return clock
 
-        The extent ends ``skip_tail_s`` before the last span any of the
-        keys ended (a reader that knows the run closed with traffic of
-        another kind leaves that out) and is at most ``window_s`` long; it
-        starts no earlier than their first span, nor than the first entry
-        of any FULL reservoir among their names, which has forgotten what
-        ended before that.  A key with no span in the extent has ``count``
-        0, ``share`` 0 and no percentiles."""
+    def thread_stats(self, begin: float, end: float) -> dict[str, dict]:
+        """Every registered thread between its first sample at or after
+        ``begin`` and its last at or before ``end`` (``time.monotonic``
+        seconds; :meth:`stage_extent` gives those the stages are read
+        over): ``cpu_share``, the thread's CPU seconds over the wall
+        seconds between the two samples, ``extent_s``;
+        ``process_cpu_cores``, the whole process's CPU seconds over the
+        same; and of the sums the
+        thread keeps itself ``busy_share``, ``turns_per_s`` and
+        ``turn_ms_mean`` (``busy_s / turns``), None for a thread that keeps
+        none.  Nothing for a thread with fewer than two samples inside."""
+        out = {}
+        for name, clock in list(self._threads.items()):
+            inside = [s for s in list(clock.samples) if begin <= s[0] <= end]
+            if len(inside) < 2:
+                continue
+            wall, cpu, process_cpu, busy, turns = (
+                b - a for a, b in zip(inside[0], inside[-1])
+            )
+            out[name] = {
+                "busy_share": round(busy / wall, 6) if turns else None,
+                "cpu_share": round(cpu / wall, 6),
+                "turns_per_s": round(turns / wall, 3) if turns else None,
+                "turn_ms_mean": round(busy / turns * 1e3, 6) if turns else None,
+                "process_cpu_cores": round(process_cpu / wall, 6),
+                "extent_s": round(wall, 4),
+            }
+        return out
+
+    def _extent(
+        self, prefix: str | tuple, window_s: float, skip_tail_s: float,
+    ) -> Optional[tuple[dict, float, float]]:
+        """THE rule of the common extent (:meth:`stage_stats`' docstring):
+        per key under ``prefix`` the arrays ``(starts, durations, ends)`` of
+        its reservoir, and the extent's ``begin`` and ``end``; None where
+        there is no extent."""
         spans_of, forgotten_before = {}, []
         for name in list(self._recent):
             keys = [k for k in (name, *(f"{name}:{kind}" for kind in KINDS))
@@ -377,13 +482,63 @@ class Timeline:
                 if len(starts[of]):
                     spans_of[key] = (starts[of], durations[of], ends[of])
         if not spans_of:
-            return {}
+            return None
         end = max(float(e.max()) for _, _, e in spans_of.values()) - skip_tail_s
         first = min(float(s.min()) for s, _, _ in spans_of.values())
         begin = max(end - window_s, first, *forgotten_before)
+        return (spans_of, begin, end) if end > begin else None
+
+    def stage_extent(
+        self, prefix: str | tuple = "", window_s: float = STAGE_WINDOW_S,
+        skip_tail_s: float = 0.0,
+    ) -> Optional[tuple[float, float]]:
+        """``(begin, end)`` of the extent :meth:`stage_stats` reads under
+        the same arguments, in ``time.monotonic`` seconds, or None where it
+        reads nothing: what :meth:`thread_stats` takes, so that a thread's
+        shares and the stages' medians describe the same seconds."""
+        read = self._extent(prefix, window_s, skip_tail_s)
+        return read and read[1:]
+
+    def stages_and_threads(
+        self, prefix: str | tuple = "", window_s: float = STAGE_WINDOW_S,
+        skip_tail_s: float = 0.0,
+    ) -> dict[str, dict]:
+        """``{"stages": stage_stats(..), "threads": thread_stats(..)}`` over
+        one extent, from one pass over the reservoirs: what a ``stats``
+        surface carries (``Runtime.stats``, ``dispatch_stats``)."""
+        read = self._extent(prefix, window_s, skip_tail_s)
+        if read is None:
+            return {"stages": {}, "threads": {}}
+        return {"stages": self._stats_over(*read),
+                "threads": self.thread_stats(*read[1:])}
+
+    def stage_stats(
+        self, prefix: str | tuple = "", window_s: float = STAGE_WINDOW_S,
+        skip_tail_s: float = 0.0,
+    ) -> dict[str, dict]:
+        """The keys under ``prefix`` (one, or a tuple of several), all
+        read over ONE common extent of time, so that a stage with two
+        spans a second and one with seven hundred describe the same
+        seconds.  A key is a span name or, for the spans of it that
+        carried a kind, ``<name>:<kind>``.  Per key ``count``, ``p50_ms``,
+        ``p95_ms`` of the spans that ended inside the extent, ``share``,
+        the part of the extent the stage was running (a span that reaches
+        over either end counts with its part inside), and ``extent_s``
+        itself.
+
+        The extent ends ``skip_tail_s`` before the last span any of the
+        keys ended (a reader that knows the run closed with traffic of
+        another kind leaves that out) and is at most ``window_s`` long; it
+        starts no earlier than their first span, nor than the first entry
+        of any FULL reservoir among their names, which has forgotten what
+        ended before that.  A key with no span in the extent has ``count``
+        0, ``share`` 0 and no percentiles."""
+        read = self._extent(prefix, window_s, skip_tail_s)
+        return self._stats_over(*read) if read else {}
+
+    @staticmethod
+    def _stats_over(spans_of: dict, begin: float, end: float) -> dict:
         extent = end - begin
-        if extent <= 0:
-            return {}
         out = {}
         for key, (starts, durations, ends) in spans_of.items():
             inside = (ends >= begin) & (ends <= end)

@@ -621,10 +621,16 @@ def test_span_attributes_trace_and_exclude():
     assert [s[5].get("type") for s in tl.spans()] == ["multi", "stats"]
 
 
-def test_spans_are_profiler_annotations_on_the_device_traces_clock(tmp_path):
-    """Under a profiler session the spans that enclose running code are
-    in the ``.xplane.pb`` by name, read the way the benchmark reads host
-    spans; the ones recorded afterwards from two readings are not."""
+ANNOTATED = ("server.decode", "server.request", "server.encode",
+             "server.write") + RUNTIME_THREAD_STAGES
+RECORDED = ("pool.wait", "runtime.queue", "runtime.deliver",
+            "server.read", "server.conn.idle", "server.resume")
+
+
+@pytest.fixture(scope="module")
+def profiled(tmp_path_factory):
+    """Five forward requests under a CPU profiler session: the benchmark's
+    trace reader, and the path of the ``.xplane.pb`` it would read."""
     import jax
 
     sys.path.insert(0, os.path.join(REPO, "benchmarks"))
@@ -632,11 +638,7 @@ def test_spans_are_profiler_annotations_on_the_device_traces_clock(tmp_path):
         import trace_reduce
     finally:
         sys.path.remove(os.path.join(REPO, "benchmarks"))
-
-    annotated = ("server.decode", "server.request", "server.encode",
-                 "server.write") + RUNTIME_THREAD_STAGES
-    recorded = ("pool.wait", "runtime.queue", "runtime.deliver",
-                "server.read", "server.conn.idle", "server.resume")
+    trace_dir = str(tmp_path_factory.mktemp("profiled"))
     try:
         with background_server(
             num_experts=1, hidden_dim=HID, expert_prefix="ffn", seed=0
@@ -644,15 +646,23 @@ def test_spans_are_profiler_annotations_on_the_device_traces_clock(tmp_path):
             expert = RemoteExpert("ffn.0", endpoint, timeout=30.0)
             x = np.ones((4, HID), np.float32)
             expert.forward_blocking([x])
-            with jax.profiler.trace(str(tmp_path)):
+            with jax.profiler.trace(trace_dir):
                 for _ in range(5):
                     expert.forward_blocking([x])
     finally:
         timeline.clear()
         reset_client_rpc()
+    return trace_reduce, trace_reduce.find_xplane(trace_dir)
+
+
+def test_spans_are_profiler_annotations_on_the_device_traces_clock(profiled):
+    """Under a profiler session the spans that enclose running code are
+    in the ``.xplane.pb`` by name, read the way the benchmark reads host
+    spans; the ones recorded afterwards from two readings are not."""
+    trace_reduce, xplane = profiled
+    annotated, recorded = ANNOTATED, RECORDED
     events = trace_reduce.load_events(
-        trace_reduce.find_xplane(str(tmp_path)),
-        host_spans=annotated + recorded,
+        xplane, host_spans=annotated + recorded,
     )
     seen = {name for name, _, _ in events["host"]}
     assert set(annotated) <= seen, sorted(seen)
@@ -665,8 +675,7 @@ def test_spans_are_profiler_annotations_on_the_device_traces_clock(tmp_path):
     # loop's own few lines)
     thread = sorted(
         (start, end) for name, start, end in trace_reduce.load_events(
-            trace_reduce.find_xplane(str(tmp_path)),
-            host_spans=RUNTIME_THREAD_STAGES,
+            xplane, host_spans=RUNTIME_THREAD_STAGES,
         )["host"]
     )
     assert len(thread) >= 5 * 4
@@ -674,6 +683,40 @@ def test_spans_are_profiler_annotations_on_the_device_traces_clock(tmp_path):
     span = thread[-1][1] - thread[0][0]
     assert covered <= span * (1 + 1e-9)
     assert covered >= 0.95 * span, (covered, span)
+
+
+def test_loop_run_is_an_annotation_on_the_loops_own_thread(profiled):
+    """ISSUE 68: in a profiler session every ``loop.run`` (a blocking
+    select's return to the next one's entry) is in the trace on the loop's
+    thread, read here as ``tools/loop_trace.py`` reads a cell's: the line
+    that carries the handler's ``server.decode`` carries ``loop.run``, the
+    runtime thread's line carries none, the benchmark's reader finds the
+    name, and neither stage of the chain has a reservoir."""
+    trace_reduce, xplane = profiled
+    events = trace_reduce.load_events(
+        xplane, host_spans=("loop.run", "loop.select", "server.decode"))
+    seen = {name for name, _, _ in events["host"]}
+    assert seen == {"loop.run", "server.decode"}  # loop.select: what is left
+    turns = sorted((a, b) for n, a, b in events["host"] if n == "loop.run")
+    assert all(b >= a for a, b in turns)
+    report = _load("tools/loop_trace.py").host_lines(xplane)
+    server = [l for l in report["lines"] if "server.decode" in l["names"]]
+    runtime = [l for l in report["lines"]
+               if set(l["names"]) & set(RUNTIME_THREAD_STAGES)]
+    assert len(server) == 1 and len(runtime) == 1
+    assert "loop.run" not in runtime[0]["names"]
+    assert runtime[0]["loop_run"] is None
+    mine = server[0]
+    assert mine["names"]["server.decode"]["count"] == 5
+    assert mine["names"]["loop.run"]["count"] >= 5
+    # stretches of one thread never overlap: their union is their sum, and
+    # every decode (a callback) runs inside them
+    assert mine["loop_run_union_s"] == pytest.approx(
+        mine["names"]["loop.run"]["seconds"], rel=1e-9)
+    assert mine["loop_run_union_s"] > mine["names"]["server.decode"]["seconds"]
+    assert 0 <= mine["loop_run"][0] < mine["loop_run"][1] <= (
+        report["annotated_span_s"] + 1e-9)
+    assert not timeline.recent("loop.run") and not timeline.recent("loop.select")
 
 
 @pytest.mark.parametrize("key, scale, want", [
@@ -816,6 +859,210 @@ def test_stage_remainder_is_what_the_stages_leave_or_nothing(monkeypatch):
         assert module.reduce({}, **args) is None
     finally:
         timeline.clear()
+
+
+# ---- ISSUE 68: the threads' clocks beside the stages -------------------
+
+THREAD_METRICS = {
+    # entry: (thread, key, scale, unit)
+    "server.loop_busy_share": ("lah-server", "busy_share", 100.0, "%"),
+    "server.loop_cpu_share": ("lah-server", "cpu_share", 100.0, "%"),
+    "server.runtime_cpu_share": ("lah-runtime", "cpu_share", 100.0, "%"),
+    "server.process_cpu_cores": ("lah-server", "process_cpu_cores", 1.0,
+                                 "cores"),
+    "server.loop_turn_ms_mean": ("lah-server", "turn_ms_mean", 1.0, "ms"),
+}
+
+
+def _a_servers_half_minute(tl: Timeline) -> None:
+    """Spans of a server's three prefixes from t=100 to t=130 (a batch
+    every 10 ms, a request every 25), and both threads' clocks ticked
+    every 50 ms of it: the loop busy 80 % and on a CPU 50 %, 0.4 ms a
+    turn; the runtime thread on a CPU 30 %; the process 1.25 cores."""
+    for i in range(3000):
+        t = 100.0 + 0.01 * i
+        tl.record("runtime.idle", t, 0.004)
+        tl.record("runtime.dispatch", t + 0.004, 0.006, kind="forward")
+    for i in range(1200):
+        tl.record("server.request", 100.0 + 0.025 * i, 0.02, kind="forward")
+        tl.record("pool.wait", 100.0 + 0.025 * i, 0.01, kind="forward")
+    at = {"now": 0.0}
+    loop = tl.register_thread(
+        "lah-server", thread_time=lambda: 0.5 * at["now"],
+        process_time=lambda: 1.25 * at["now"])
+    runtime = tl.register_thread(
+        "lah-runtime", thread_time=lambda: 0.3 * at["now"],
+        process_time=lambda: 1.25 * at["now"])
+    for i in range(601):
+        at["now"] = now = 100.0 + 0.05 * i
+        loop.tick(now, busy_s=0.8 * now, turns=int(2000 * now))
+        runtime.tick(now)
+
+
+def test_thread_samples_leave_the_stages_reading_as_it_was():
+    """Thread samples are in no reservoir and under no span name: with
+    them present ``stage_stats`` over the server's prefixes returns the
+    dictionary it returned without, extent and all."""
+    bare, ticked = Timeline(), Timeline()
+    _a_servers_half_minute(bare)
+    bare._threads.clear()  # the same spans, and no thread ever registered
+    _a_servers_half_minute(ticked)
+    assert len(ticked._threads["lah-server"].samples) == 121
+    for kwargs in ({}, {"window_s": 20.0, "skip_tail_s": 2.0},
+                   {"window_s": 3.0}):
+        assert (ticked.stage_stats(STAGE_PREFIXES, **kwargs)
+                == bare.stage_stats(STAGE_PREFIXES, **kwargs) != {})
+    assert set(ticked._recent) == set(bare._recent) == {
+        "runtime.idle", "runtime.dispatch", "server.request", "pool.wait"}
+    assert not any(n.startswith(("loop.", "lah-")) for n in ticked._recent)
+
+
+@pytest.mark.parametrize("kwargs, begin, end", [
+    ({}, 100.0, 130.0),
+    ({"window_s": 20.0, "skip_tail_s": 2.0}, 108.0, 128.0),
+    ({"window_s": 3.0, "skip_tail_s": 0.5}, 126.5, 129.5),
+    ({"window_s": 60.0, "skip_tail_s": 29.0}, 100.0, 101.0),
+])
+def test_one_extent_serves_the_stages_and_the_threads(kwargs, begin, end):
+    """``stage_extent`` is the rule ``stage_stats`` reads by, as two
+    numbers; ``thread_stats`` over it lies inside and at most a sample's
+    spacing from each end, and ``stages_and_threads`` is both at once."""
+    tl = Timeline()
+    _a_servers_half_minute(tl)
+    assert tl.stage_extent(STAGE_PREFIXES, **kwargs) == pytest.approx(
+        (begin, end))
+    stages = tl.stage_stats(STAGE_PREFIXES, **kwargs)
+    assert {s["extent_s"] for s in stages.values()} == {round(end - begin, 4)}
+    threads = tl.thread_stats(*tl.stage_extent(STAGE_PREFIXES, **kwargs))
+    assert set(threads) == {"lah-server", "lah-runtime"}
+    for stat in threads.values():
+        assert end - begin - 2 * 0.25 <= stat["extent_s"] <= end - begin + 1e-9
+        assert stat["process_cpu_cores"] == pytest.approx(1.25)
+    assert threads["lah-server"] == pytest.approx({
+        "busy_share": 0.8, "cpu_share": 0.5, "turns_per_s": 2000.0,
+        "turn_ms_mean": 0.4, "process_cpu_cores": 1.25,
+        "extent_s": threads["lah-server"]["extent_s"]}, abs=2e-3)
+    assert threads["lah-runtime"]["cpu_share"] == pytest.approx(0.3)
+    assert threads["lah-runtime"]["busy_share"] is None
+    assert tl.stages_and_threads(STAGE_PREFIXES, **kwargs) == {
+        "stages": stages, "threads": threads}
+    assert tl.stage_extent("absent.") is None
+    assert tl.stages_and_threads("absent.") == {"stages": {}, "threads": {}}
+
+
+@pytest.mark.parametrize("name", sorted(THREAD_METRICS))
+def test_a_thread_metric_reads_its_thread_or_nothing(monkeypatch, name):
+    """Each of the five files has the keys ``selfcheck`` holds it to and
+    says what its manifest entry says; its reducer reads the program's
+    ``thread_stats`` over ``stage_stat``'s extent, and reads nothing under
+    2 s between the samples, from a program without ``thread_stats`` (this
+    PR's parent) and in a cell that never loaded the module."""
+    import json
+
+    thread, key, scale, unit = THREAD_METRICS[name]
+    spec = json.load(open(os.path.join(
+        REPO, "benchmarks/layer_metrics", name + ".json")))
+    kin = json.load(open(os.path.join(
+        REPO, "benchmarks/layer_metrics/server.queue_wait_ms_p50.json")))
+    assert list(spec) == list(kin)
+    manifest = json.load(open(os.path.join(REPO, "BENCHMARK.json")))
+    (entry,) = [e for e in manifest["per_layer"] if e["name"] == name]
+    assert entry == {k: spec[k] for k in entry}
+    assert manifest["per_layer"].index(entry) >= 119  # appended
+    assert (spec["layer"], spec["config"], spec["moves"], spec["source"],
+            spec["better"], spec["unit"], spec["reducer"]) == (
+        "expert server", "ffnserver", "swarm_samples_per_s",
+        "program_counter", "lower", unit, "thread_stat")
+    assert spec["workloads"] == ["ffnserver-infer-small",
+                                 "ffnserver-train-bulk"]
+    assert spec["args"] == {"thread": thread, "key": key, **(
+        {"scale": scale} if scale != 1.0 else {})}
+    assert "LOCATES" in spec["text"] and "no target" in spec["text"]
+
+    monkeypatch.syspath_prepend(os.path.join(REPO, "benchmarks"))
+    module = _load("benchmarks/reducers/thread_stat.py")
+    want = {"busy_share": 80.0, "cpu_share": 50.0 if "loop" in name else 30.0,
+            "process_cpu_cores": 1.25, "turn_ms_mean": 0.4}[key]
+    timeline.clear()
+    saved = dict(timeline._threads)
+    try:
+        _a_servers_half_minute(timeline)
+        assert module.reduce({}, **spec["args"]) == pytest.approx(
+            want, rel=2e-3)
+        # held inside the measured window as stage_stat holds the stages
+        assert module.reduce({"intervals_s": [5.0, 5.5]}, **spec["args"]) == (
+            pytest.approx(want, rel=5e-3))
+        # 4.3 s of window less the 2 s tail hold samples 2.25 s apart; 3.9 s
+        # hold them 1.75 s apart, under the reducer's floor
+        assert module.reduce({"intervals_s": [4.3]}, **spec["args"]) == (
+            pytest.approx(want, rel=2e-2))
+        assert module.MIN_EXTENT_S == 2.0
+        assert module.reduce({"intervals_s": [3.9]}, **spec["args"]) is None
+        assert module.reduce({"intervals_s": [1.5]}, **spec["args"]) is None
+        # a sum the thread does not keep, a thread that is not there
+        assert module.reduce({}, "lah-runtime", "busy_share") is None
+        assert module.reduce({}, "lah-absent", key) is None
+        # a program without thread_stats (this PR's parent): nothing to read
+        monkeypatch.setitem(
+            sys.modules, "learning_at_home_tpu.utils.profiling",
+            types.SimpleNamespace(timeline=types.SimpleNamespace(
+                stage_stats=timeline.stage_stats)),
+        )
+        assert module.reduce({}, **spec["args"]) is None
+        # a cell that never loaded the module (a train cell)
+        monkeypatch.delitem(
+            sys.modules, "learning_at_home_tpu.utils.profiling"
+        )
+        assert module.reduce({}, **spec["args"]) is None
+        assert "learning_at_home_tpu.utils.profiling" not in sys.modules
+    finally:
+        timeline.clear()
+        timeline._threads.clear()
+        timeline._threads.update(saved)
+
+
+def test_a_real_loops_shares_are_its_callbacks_time_and_cpu():
+    """One wall-clock case, wide on purpose: callbacks that burn 200 ms of
+    CPU and sleep 200 ms in every second read ``busy_share`` 0.4 and
+    ``cpu_share`` 0.2, each within 0.2.  It runs under ``LAH_SANITIZE=1``
+    like all of tier-1, and the sanitizer's stall detector goes on seeing
+    the 400 ms callback through the selector's wrapper."""
+    import asyncio
+
+    from learning_at_home_tpu.utils import sanitizer
+    from learning_at_home_tpu.utils.asyncio_utils import BackgroundLoop
+
+    async def second():
+        t0 = time.monotonic()
+        cpu0 = time.thread_time()
+        while time.thread_time() - cpu0 < 0.2:
+            pass
+        time.sleep(0.2)  # busy, and on no CPU
+        await asyncio.sleep(max(0.0, 1.0 - (time.monotonic() - t0)))
+
+    async def seconds(n):
+        for _ in range(n):
+            await second()
+
+    stalls = sanitizer.stall_stats()["count"]
+    loop = BackgroundLoop(name="lah-test-shares")
+    try:
+        begin = time.monotonic()
+        loop.run(seconds(3), timeout=60)
+        stats = timeline.thread_stats(begin, time.monotonic())
+    finally:
+        loop.shutdown()
+        timeline._threads.pop("lah-test-shares", None)
+    mine = stats["lah-test-shares"]
+    assert mine["extent_s"] >= 1.5
+    assert mine["busy_share"] == pytest.approx(0.4, abs=0.2)
+    assert mine["cpu_share"] == pytest.approx(0.2, abs=0.2)
+    # by construction: the CPU readings lie outside the wall readings
+    assert mine["busy_share"] >= mine["cpu_share"] - 0.002
+    assert mine["turns_per_s"] > 0 and mine["turn_ms_mean"] > 0
+    assert mine["process_cpu_cores"] >= mine["cpu_share"] - 0.02
+    if sanitizer.enabled():
+        assert sanitizer.stall_stats()["count"] >= stalls + 3
 
 
 POD_STEP_SCOPES = (

@@ -476,3 +476,87 @@ def test_many_concurrent_clients_stress():
         assert pool.batches_formed >= 1
 
     run_pool(main())
+
+
+def test_runtime_thread_ticks_its_clock_a_batch():
+    """ISSUE 68: the runtime thread samples its CPU seconds at the end of
+    a hand-off or an idle wait, at most four times a second, on a clock
+    registered from its own thread; it keeps no sums of its own."""
+    import time
+
+    from learning_at_home_tpu.utils.profiling import timeline
+
+    async def main():
+        pool = TaskPool(lambda inputs: [inputs[0] + 1], "p",
+                        max_batch_size=8, batch_timeout=0.001)
+        runtime = Runtime()
+        runtime.attach_loop(asyncio.get_running_loop())
+        runtime.start()
+        pool.start(runtime)
+        begin = time.monotonic()
+        batches = 0
+        while time.monotonic() - begin < 0.7:
+            await pool.submit_task(np.ones((2, 2), np.float32))
+            batches += 1
+        stats = runtime.stats()
+        runtime.shutdown()
+        stats["samples"] = [s for s in runtime._clock.samples if s[0] >= begin]
+        return runtime, begin, batches, stats
+
+    try:
+        runtime, begin, batches, stats = run_pool(main())
+    finally:
+        timeline.clear()
+    clock = runtime._clock
+    assert clock.name == "lah-runtime" and clock.ident == runtime._thread.ident
+    assert timeline._threads["lah-runtime"] is clock
+    samples = stats.pop("samples")
+    assert 3 <= len(samples) <= 4 < batches
+    assert all(b[0] - a[0] >= 0.25 for a, b in zip(samples, samples[1:]))
+    assert all(s[3:] == (0.0, 0) for s in samples)  # no busy_s, no turns
+    assert samples[-1][1] > samples[0][1]  # the thread's own CPU seconds
+    mine = stats["threads"]["lah-runtime"]
+    assert 0 < mine["cpu_share"] <= 1.05 and mine["busy_share"] is None
+    assert mine["extent_s"] <= stats["stages"]["runtime.idle"]["extent_s"]
+
+
+def test_runtime_stats_name_the_loop_and_the_runtime_thread():
+    """``stats()["threads"]`` is read over the extent of ``"stages"`` and
+    names the server's two busy threads; a loop keeps its sums."""
+    import time
+
+    from learning_at_home_tpu.client import RemoteExpert, reset_client_rpc
+    from learning_at_home_tpu.server.server import background_server
+    from learning_at_home_tpu.utils.profiling import timeline
+
+    try:
+        with background_server(
+            num_experts=1, hidden_dim=16, expert_prefix="ffn", seed=0
+        ) as (endpoint, srv):
+            expert = RemoteExpert("ffn.0", endpoint, timeout=30.0)
+            x = np.ones((4, 16), np.float32)
+            expert.forward_blocking([x])  # compiles
+            timeline.clear()  # the stages' extent is this server's alone
+            begin = time.monotonic()
+            while time.monotonic() - begin < 0.8:
+                expert.forward_blocking([x])
+            stats = srv.runtime.stats()
+    finally:
+        timeline.clear()
+        reset_client_rpc()
+    threads, stages = stats["threads"], stats["stages"]
+    assert {"lah-server", "lah-runtime"} <= set(threads)
+    extent = stages["server.request"]["extent_s"]
+    for name in ("lah-server", "lah-runtime"):
+        assert set(threads[name]) == {
+            "busy_share", "cpu_share", "turns_per_s", "turn_ms_mean",
+            "process_cpu_cores", "extent_s"}
+        assert extent - 0.5 - 1e-3 <= threads[name]["extent_s"] <= extent
+    loop = threads["lah-server"]
+    assert 0 < loop["busy_share"] <= 1 and loop["turns_per_s"] > 0
+    assert loop["turn_ms_mean"] == pytest.approx(
+        1e3 * loop["busy_share"] / loop["turns_per_s"], rel=1e-2)
+    assert threads["lah-runtime"]["busy_share"] is None
+    import msgpack
+
+    msgpack.packb(threads, use_bin_type=True)  # the stats reply's wire
