@@ -17,6 +17,10 @@ import jax.numpy as jnp
 import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
+from learning_at_home_tpu.ops.attention_backward import (
+    resident_attention,
+    resident_backward_fits,
+)
 from learning_at_home_tpu.ops.band_attention import band_attention, band_kernel_fits
 from learning_at_home_tpu.ops.delta_rule import gated_delta_chunked
 from learning_at_home_tpu.ops.gate_norm import gated_rms_norm
@@ -786,21 +790,28 @@ _FLASH_TILES_256 = dict(_FLASH_TILES, block_kv_compute=256)
 # Queries and keys of 192 over values of 128 (latent attention expanded, 32
 # heads: a head's 128 unrotated beside 64 rotated): the kernel takes the
 # two sizes as they come (its ``head_dim_v`` is the values' own; Mosaic
-# lowers the 192-wide contraction).  The backward is UNFUSED: the fused
-# kernel writes S / block_kv float32 partials of the queries' gradient, 8
-# GB at [32, 16384, 192] and key blocks of 1,024 (4 GB at 2,048), which do
-# not fit a step that holds four residual streams a layer; it is the faster
-# kernel alone (85.4 ms forward + backward at key blocks of 2,048, 88.8 at
-# 1,024) and the slower step (none at all).  The fastest of the sweep on a
-# TPU v5e at [1, 32, 16384, 192 | 128] bf16 under a causal mask (PERF.md
-# section 6 "PR 64"; tools/attention_probe.py latent all xing4): forward
-# 24.74 ms (56.4 % of the bf16 peak on the admitted elements) and forward +
-# backward 100.23 ms; the forward's compute tile of 512 takes 25.86 and
-# 101.32, every other block of the three kernels within 1.5 % or slower
-# (512-wide query or key blocks 102.9-105.1), and a 2,048-wide query block
-# or a 4,096-wide key block is refused (VMEM).
-_FLASH_TILES_192_128 = dict(
-    _FLASH_TILES, block_kv_compute=256, block_q_dq=1024, block_kv_dq=1024)
+# lowers the 192-wide contraction).  The FORWARD is the library's at these
+# tiles, the fastest of the sweep on a TPU v5e at [1, 32, 16384, 192 | 128]
+# bf16 under a causal mask (PERF.md section 6 "PR 64";
+# tools/attention_probe.py latent all xing4): 24.74 ms (56.4 % of the bf16
+# peak on the admitted elements); a compute tile of 512 takes 25.86, and a
+# 2,048-wide query block or a 4,096-wide key block is refused (VMEM).  The
+# BACKWARD of a call ``resident_backward_fits`` takes (a causal mask, as
+# many key heads as query heads) is the repo's own ONE call a layer,
+# ``ops/attention_backward.py`` (PR 69), in place of the library's two
+# ways: its fused kernel writes S / block_kv float32 partials of the
+# queries' gradient, 8 GB at [32, 16384, 192] and key blocks of 1,024 (4 GB
+# at 2,048), which do not fit a step that holds four residual streams a
+# layer (85.4 ms forward + backward at key blocks of 2,048, 88.8 at 1,024,
+# and no step at all), and its unfused pair, which this pair of sizes ran
+# until PR 69, computes the scores twice (100.23 ms).  The one call reads
+# 45.9 ms alone and 70.1 ms with the forward (PERF.md section 6 "PR 69";
+# tools/attention_probe.py latent resident xing4), 42.5 ms a layer in
+# ``xing4``'s step where the pair took 71.8.  A call of these sizes
+# that the rule refuses (fewer key heads, a length whose ``dq`` a head's
+# VMEM cannot hold) gets the library's fused backward at the tiles below:
+# no cell of the benchmark.
+_FLASH_TILES_192_128 = dict(_FLASH_TILES, block_kv_compute=256)
 # the pairs (queries' and keys' head size, values') the kernel was run at
 _FLASH_HEADS = {
     (64, 64): _FLASH_TILES, (128, 128): _FLASH_TILES,
@@ -962,8 +973,6 @@ def flash_block_sizes(
     if any(s % size for size in tiles.values()):
         return None
     if window is None or window >= tiles["block_kv"]:
-        if "block_q_dq" in tiles:  # an unfused backward's own blocks
-            return BlockSizes(use_fused_bwd_kernel=False, **tiles)
         return BlockSizes(use_fused_bwd_kernel=True, **tiles)
     # the narrowest key block that covers the window and the measured
     # tile (the block of the other regime divides s and covers both, so
@@ -997,7 +1006,10 @@ def attention_core(
     (:func:`~learning_at_home_tpu.ops.band_attention.band_attention`)
     where :func:`band_kernel_fits` says so (a window shorter than the
     blocked kernel's key block, on the arrays as they come), else the
-    blocked kernel where :func:`flash_block_sizes` has tiles.  All take
+    blocked kernel where :func:`flash_block_sizes` has tiles, its backward
+    ONE call a layer
+    (:func:`~learning_at_home_tpu.ops.attention_backward.resident_attention`)
+    where :func:`resident_backward_fits` says so.  All take
     fewer key/value heads than query heads as they come (query head h
     reads key/value head ``h // (H / Hkv)``; no copy
     of K or V is made), and a ``window``: query i sees the ``window``
@@ -1029,16 +1041,23 @@ def attention_core(
         from jax.experimental.pallas.ops.tpu import splash_attention as splash
 
         _, s, h, hd = q.shape
-        if diffusion_block is not None:
-            mask = _block_diffusion_splash_mask(s, diffusion_block)
-        elif window is None:
-            mask = splash.CausalMask((s, s))
+        if resident_backward_fits(
+                q.shape, k.shape[2], value_dim, window, diffusion_block,
+                backend, q.dtype.itemsize):
+            # the library's forward at ``sizes``, ONE backward call
+            kernel = functools.partial(
+                resident_attention, sizes=sizes, residuals=FLASH_RESIDUALS)
         else:
-            mask = splash.LocalMask((s, s), (window - 1, 0), offset=0)
-        kernel = splash.make_splash_mha_single_device(
-            mask=splash.MultiHeadMask([mask] * h), block_sizes=sizes,
-            residual_checkpoint_name=FLASH_RESIDUALS,
-        )
+            if diffusion_block is not None:
+                mask = _block_diffusion_splash_mask(s, diffusion_block)
+            elif window is None:
+                mask = splash.CausalMask((s, s))
+            else:
+                mask = splash.LocalMask((s, s), (window - 1, 0), offset=0)
+            kernel = jax.vmap(splash.make_splash_mha_single_device(
+                mask=splash.MultiHeadMask([mask] * h), block_sizes=sizes,
+                residual_checkpoint_name=FLASH_RESIDUALS,
+            ))
 
         # kernel convention: one batch row [H, S, hd] (k and v [Hkv, S,
         # hd]), the scale already on q; blocks the mask leaves empty
@@ -1053,7 +1072,7 @@ def attention_core(
                 q, k, v = (
                     heads_first(q) * scale, heads_first(k), heads_first(v),
                 )
-            out = jax.vmap(kernel)(q, k, v)
+            out = kernel(q, k, v)
             with jax.named_scope("layout"):
                 return heads_first(out)
     how = {} if plain else dict(scale=scale)

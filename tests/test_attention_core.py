@@ -145,6 +145,32 @@ def test_key_blocks_cover_the_window_and_the_measured_tile(seq, window, key_bloc
         trunk._FLASH_WINDOW_TILE, seq)
 
 
+@pytest.mark.parametrize("shape, kv, value_dim, window, diffusion, compute, resident", [
+    ((1, 16384, 32, 192), 32, 128, None, None, 256, True),    # xing4, ling-3.0
+    ((4, 4096, 16, 128), 16, None, None, None, 512, False),   # olmoe
+    ((1, 16384, 28, 128), 4, None, None, None, 512, False),   # smallthinker
+    ((1, 16384, 28, 128), 4, None, 4096, None, 512, False),
+    ((1, 16384, 64, 128), 8, None, None, None, 512, False),   # k-exaone
+    ((1, 16384, 20, 256), 20, None, None, None, 256, False),  # glm-4.7-flash
+    ((1, 16384, 32, 128), 2, None, None, None, 512, False),   # nemotron
+    ((1, 16384, 16, 256), 2, None, None, None, 256, False),   # qwen3-next
+    ((1, 16384, 32, 128), 4, None, None, 4, 512, False),      # sdar
+    ((1, 16384, 32, 64), 8, None, None, None, 512, False),    # lfm2
+])
+def test_the_one_backward_call_is_for_heads_of_192_over_128_alone(
+        shape, kv, value_dim, window, diffusion, compute, resident):
+    """The cells' calls of the blocked kernel: every one keeps the fused
+    backward's ``BlockSizes`` it had; ``xing4``'s and ``ling-3.0``'s pair of
+    sizes, which ran the unfused pair until PR 69, now gets the forward's
+    tiles with no dQ kernel's, and ``resident_backward_fits`` takes it and
+    no other."""
+    sizes = trunk.flash_block_sizes(shape, "tpu", window, value_dim)
+    assert sizes.use_fused_bwd_kernel and sizes.block_q_dq is None
+    assert sizes == dataclasses.replace(_parents(shape[1]), block_kv_compute=compute)
+    assert trunk.resident_backward_fits(
+        shape, kv, value_dim or shape[3], window, diffusion, "tpu") is resident
+
+
 @pytest.mark.parametrize("shape, backend", [
     ((4, 4096, 16, 128), "cpu"),   # Mosaic lowers for a TPU only
     ((4, 4096, 16, 128), "gpu"),

@@ -252,8 +252,15 @@ def test_heads_of_12_over_values_of_8_match_the_reference(tiny):
     want = reference.attention(q, k, v, lambda a: a, reference.softmax_scale(SIZES))
     _close(got, want, 1e-5)
     assert model._attn_scale == pytest.approx(reference.softmax_scale(SIZES))
+    # the forward's tiles are the library's; the backward is the repo's ONE
+    # call (PR 69), so no tile of an unfused pair's dQ kernel is left
     sizes = trunk.flash_block_sizes((1, 16384, 32, 192), "tpu", value_dim=128)
-    assert sizes is not None and not sizes.use_fused_bwd_kernel
+    assert (sizes.block_q, sizes.block_kv, sizes.block_kv_compute) == (1024, 1024, 256)
+    assert sizes.use_fused_bwd_kernel and sizes.block_q_dq is None
+    assert trunk.resident_backward_fits(
+        (1, 16384, 32, 192), 32, 128, None, None, "tpu")
+    assert not trunk.resident_backward_fits(
+        (1, 16384, 32, 192), 32, 128, None, None, "cpu")
     assert trunk.flash_block_sizes((1, 16384, 32, 192), "tpu") is None
     assert trunk.flash_block_sizes((1, 16384, 32, 128), "tpu", value_dim=64) is None
     assert trunk.flash_block_sizes((1, 16384, 32, 192), "cpu", value_dim=128) is None

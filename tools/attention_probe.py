@@ -56,7 +56,10 @@ threshold were set from.
   heads of 128 and their neighbours, fused backward and not, then the
   kernel under ``flash_block_sizes``'s answer (``latent rule``).  PERF.md
   section 6 ("PR 37") holds the table the rule's regime for heads of 256
-  was set from.
+  was set from.  ``latent all xing4``: the same at Xing4.0's and Ling-3.0's
+  ``[1, 32, 16384, 192 | 128]``, where the backward is the repo's ONE call
+  (``ops/attention_backward.py``) read beside the library's unfused pair
+  and its fused kernel; PERF.md section 6 ("PR 64", "PR 69").
 - ``blockdiff``: ``splash_attention`` under block diffusion's mask
   (``trunk.block_diffusion_mask``, the computable form ``attention_core``
   builds, blocks of 4) at the SDAR cell's doubled row, ``[1, 32 over 4,
@@ -439,22 +442,32 @@ def window(which: str = "all") -> None:
 
 
 GLM47 = (1, 20, 20, 16384, 256, None)  # glm-4.7-flash-train-zipf16k
+# smallthinker's 28 heads of 128 with a key head each (its cell has 4: the
+# one backward call takes no groups), for the kernels' rates at that size
+MHA128 = (1, 28, 28, 16384, 128, None)
 XING4 = (1, 32, 32, 16384, 192, None)  # xing4.0-29b-a4b-train-zipf16k
 VALUE_DIM = {XING4: 128}  # a cell whose values are narrower than its keys
 
 
 def latent(which: str = "all", config: str = "glm47") -> None:
-    """``which``: ``all``, ``sweep`` or ``rule``; ``config``: ``glm47``
-    (heads of 256) or ``xing4`` (queries and keys of 192 over values of
-    128, ``[1, 32, 16384, 192 | 128]``: the fused backward's partials of
-    the queries' gradient, ``S / block_kv_dkv`` float32 copies of ``[32,
-    16384, 192]``, are 8 GB at key blocks of 1,024 and do not fit the
-    step, so the sweep is the unfused backward's, with the fused one at
-    key blocks of 2,048 and 1,024 beside it; ~4 min, PR 64)."""
+    """``which``: ``all``, ``sweep``, ``rule`` or ``resident``: the ONE
+    backward call of ``ops/attention_backward.py`` (PR 69), alone and with
+    the library's forward.  ``config``: ``glm47`` (heads of 256) and
+    ``mha128`` (28 heads of 128, a key head each), where ``resident`` reads
+    it at three settings beside the library's fused backward at the rule's
+    tiles and ``all`` leaves it out; or ``xing4`` (queries and keys of 192
+    over values of 128, ``[1, 32, 16384, 192 | 128]``: the fused backward's
+    partials of the queries' gradient, ``S / block_kv_dkv`` float32 copies
+    of ``[32, 16384, 192]``, are 8 GB at key blocks of 1,024 and do not fit
+    the step, so the library's sweep is the unfused backward's, with the
+    fused one at key blocks of 2,048 and 1,024 beside it; ~4 min, PR 64),
+    where ``resident`` is both grid orders over the blocks (~5 min), ``all``
+    reads it first, and ``rule`` runs what ``resident_backward_fits``
+    answers."""
     from learning_at_home_tpu.models import trunk
 
     require_tpu()
-    cell = {"glm47": GLM47, "xing4": XING4}[config]
+    cell = {"glm47": GLM47, "xing4": XING4, "mha128": MHA128}[config]
     args = _grouped_qkv(cell)
     if config == "xing4":
         return _latent_xing4(which, cell, args)
@@ -463,6 +476,18 @@ def latent(which: str = "all", config: str = "glm47") -> None:
         return _window_reading(cell, None, forward, backward, fused, None, args,
                                **also)
 
+    if which == "resident":
+        # the ONE backward call (PR 69) at sizes no rule hands it, beside
+        # the library's fused backward at the rule's tiles: a table for a
+        # later issue, no rule changes by it
+        b, h, _, s, hd, _ = cell
+        sizes = trunk.flash_block_sizes((b, s, h, hd), "tpu")
+        forward = (sizes.block_q, sizes.block_kv, sizes.block_kv_compute)
+        read(forward, (sizes.block_q_dkv, sizes.block_kv_dkv,
+                       sizes.block_kv_dkv_compute), True, stage="rule")
+        for blocks in ((1024, 1024, 256), (1024, 1024, 512), (512, 1024, 256)):
+            _resident_reading(cell, forward, blocks, True, args, stage="resident")
+        return
     if which in ("all", "sweep"):
         grid = ((1024, 1024, 512), (1024, 1024, 1024), (512, 1024, 512),
                 (512, 512, 512), (1024, 512, 512), (512, 2048, 512),
@@ -488,12 +513,65 @@ def latent(which: str = "all", config: str = "glm47") -> None:
              sizes.use_fused_bwd_kernel, stage="rule")
 
 
+def _resident_reading(cell, forward, blocks, keys_outer, args, **also):
+    """Rows ``resident_backward`` (the ONE backward call of
+    ``ops/attention_backward.py`` alone, on a forward's kept output and row
+    sums) and ``window_forward_backward`` (the library's forward at
+    ``forward`` and that backward, with the scale's two products, as
+    ``attention_backward.resident_attention``'s ``custom_vjp`` runs them) at
+    ``blocks`` = (query block, key block, keys of one product), key blocks
+    outer or query blocks outer; their ms, None where Mosaic refused."""
+    import jax
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sk,
+    )
+
+    from learning_at_home_tpu.ops import attention_backward as ab
+
+    b, h, hkv, s, hd, _ = cell
+    sizes = sk.BlockSizes(
+        block_q=forward[0], block_kv=forward[1], block_kv_compute=forward[2])
+    settings = dict(
+        shape=[b, h, hkv, s, hd], window=None, forward=forward,
+        backward=blocks, bwd="resident", keys_outer=keys_outer, **also)
+    scale = 1.0 / hd ** 0.5
+
+    def backward(q, k, v, o, lse, do):
+        return ab.attention_backward(q, k, v, o, lse, do, blocks, keys_outer)
+
+    def both(q, k, v, do):
+        q = q * scale
+        o, lse = ab._forward(q, k, v, sizes, None, False)
+        dq, dk, dv = backward(q, k, v, o, lse, do)
+        return o, (dq * scale, dk, dv)
+
+    q, k, v, do = args
+    o, lse = jax.jit(lambda q, k, v: ab._forward(
+        q * scale, k, v, sizes, None, False))(q, k, v)
+    read = (timed("resident_backward", settings, jax.jit(backward),
+                  q * scale, k, v, o, lse, do),
+            timed("window_forward_backward", settings, jax.jit(both), *args))
+    return None if None in read else read
+
+
 def _latent_xing4(which, cell, args) -> None:
     from learning_at_home_tpu.models import trunk
+    from learning_at_home_tpu.ops import attention_backward as ab
 
     def read(forward, backward, fused, dq=None, **also):
         return _window_reading(cell, None, forward, backward, fused, dq, args,
                                **also)
+
+    if which in ("all", "resident"):
+        # the ONE backward call (PR 69): both grid orders, the blocks swept
+        forward = (1024, 1024, 256)
+        for blocks in ((1024, 1024, 512), (1024, 1024, 256), (1024, 1024, 1024),
+                       (512, 1024, 512), (1024, 512, 512), (512, 512, 512),
+                       (2048, 1024, 512), (1024, 2048, 512), (2048, 2048, 512),
+                       (512, 2048, 512), (2048, 512, 512)):
+            for keys_outer in (True, False):
+                _resident_reading(cell, forward, blocks, keys_outer, args,
+                                  stage="resident")
 
     if which in ("all", "sweep"):
         forwards = ((1024, 1024, 512), (1024, 1024, 1024), (1024, 1024, 256),
@@ -521,11 +599,15 @@ def _latent_xing4(which, cell, args) -> None:
         if sizes is None:
             row(what="latent_rule", refused="flash_block_sizes has no tiles")
             return
-        fused = sizes.use_fused_bwd_kernel
-        read((sizes.block_q, sizes.block_kv, sizes.block_kv_compute),
+        forward = (sizes.block_q, sizes.block_kv, sizes.block_kv_compute)
+        if ab.resident_backward_fits(
+                (b, s, h, hd), cell[2], VALUE_DIM[cell], None, None, "tpu"):
+            # what ``attention_core`` runs since PR 69: the ONE backward call
+            _resident_reading(cell, forward, ab._BLOCKS, True, args, stage="rule")
+            return
+        read(forward,
              (sizes.block_q_dkv, sizes.block_kv_dkv, sizes.block_kv_dkv_compute),
-             fused, None if fused else (sizes.block_q_dq, sizes.block_kv_dq),
-             stage="rule")
+             True, stage="rule")
 
 
 def _core_both(impl):
