@@ -105,9 +105,17 @@ async def teardown(nodes):
     await asyncio.gather(*(n.shutdown() for n in nodes))
 
 
-def test_swarm_store_get_across_nodes():
+def test_swarm_store_get_across_nodes(monkeypatch):
+    # every peer here is alive: a call waits as long as only a dead peer
+    # would make it (the adaptive timeout, 4 x a loopback RTT with a floor of
+    # 50 ms, was met by LIVE peers under six loaded workers, and two strikes
+    # evict: the lookup then found no holder of the key)
+    from learning_at_home_tpu.dht import protocol
+
+    monkeypatch.setattr(protocol, "ADAPTIVE_TIMEOUT_FLOOR", 30.0)
+
     async def main():
-        nodes = await make_swarm(8, bucket_size=4)
+        nodes = await make_swarm(8, bucket_size=4, rpc_timeout=30.0)
         try:
             ok = await nodes[2].store("the-key", [1, 2, 3], get_dht_time() + 30)
             assert ok

@@ -34,8 +34,15 @@ import harness  # noqa: E402  (benchmarks/harness.py: imports no jax)
 from __graft_entry__ import glm_4_7_flash_one_chip  # noqa: E402
 from learning_at_home_tpu.models import transformer, trunk  # noqa: E402
 from learning_at_home_tpu.models.transformer import DMoETransformerLM  # noqa: E402
-from learning_at_home_tpu.parallel.mesh import make_mesh  # noqa: E402
 from benchmark_cells import layer_metric_file, readings_of_cell  # noqa: E402
+from runner_limits import (  # noqa: E402,F401  (``compiled_once`` is a fixture)
+    close as _close,
+    compiled_once,
+    decisive,
+    Limits,
+    one_device_mesh as _one_device_mesh,
+    tiny_stack,
+)
 
 REFERENCE = os.path.join(REPO, "benchmarks", "configs", "glm_4_7_flash_reference.py")
 reference = harness.load_path(REFERENCE)
@@ -48,37 +55,21 @@ CELL_FILE = harness.load_json(os.path.join(
     REPO, "benchmarks", "configs", "glm-4.7-flash.json"))
 CELL = "glm-4.7-flash-train-zipf16k"
 SIZES = runner.reference_sizes(TINY_FILE)  # what the runner hands the reference
-
-
-def _one_device_mesh():
-    return make_mesh({"expert": 1}, devices=jax.devices()[:1])
+limits = Limits(runner, reference, TINY_FILE)
+pytestmark = pytest.mark.usefixtures("compiled_once")
 
 
 def _decisive(params, seed=7):
     """Seeded weights under which every part of the block decides: a router
     that decides (the program's init gives near-equal scores), selection
     biases off zero, norm scales off 1."""
-    rs = np.random.RandomState(seed)
-
-    def leaf(path, a):
-        name = jax.tree_util.keystr(path)
-        if name.endswith("['scale']"):
-            return a * jnp.asarray(rs.uniform(0.5, 1.5, a.shape), a.dtype)
-        if name.endswith("['router_bias']"):
-            return jnp.asarray(rs.uniform(-0.2, 0.2, a.shape), a.dtype)
-        return a * (20.0 if name.endswith("['gate']") else 1.0)
-
-    return jax.tree_util.tree_map_with_path(leaf, params)
+    return decisive(params, seed, drawn={"['router_bias']": 0.2}, scaled={"['gate']": 20.0})
 
 
 @pytest.fixture(scope="module")
 def tiny():
     """(model, cfg, float32 params, ids, targets) on one device."""
-    model, cfg, _, batch = glm_4_7_flash_one_chip(_one_device_mesh(), tiny=True)
-    params = _decisive(model.init_params(jax.random.PRNGKey(11)))
-    rs = np.random.RandomState(3)
-    ids = jnp.asarray(rs.randint(0, cfg.vocab_size, (batch, cfg.seq_len + 1)))
-    return model, cfg, params, ids[:, :-1], ids[:, 1:]
+    return tiny_stack(glm_4_7_flash_one_chip, _decisive)
 
 
 @pytest.fixture(scope="module")
@@ -92,12 +83,6 @@ def want(tiny):
     _, grads = jax.jit(
         lambda p: reference.loss_and_grads(p, ids, tgt, SIZES))(params)
     return logits, logits_mtp, losses, grads
-
-
-def _close(got, want, tol=1e-4, **kw):
-    want = np.asarray(want)
-    np.testing.assert_allclose(
-        np.asarray(got), want, rtol=0, atol=tol * np.abs(want).max(), **kw)
 
 
 # ---- (a) the program against the reference ----
@@ -208,14 +193,6 @@ def test_gradients_of_every_parameter_match_the_reference(tiny, want):
 # ---- (b) the negatives: the comparison can fail ----
 
 
-def _reference_with(**changes):
-    """A copy of the reference module with functions replaced."""
-    broken = harness.load_path(REFERENCE)
-    for name, value in changes.items():
-        setattr(broken, name, value)
-    return broken
-
-
 def _unshifted(model):
     """A model whose prediction block is fed each position's OWN id."""
     hidden = model._hidden
@@ -227,7 +204,7 @@ def _unshifted(model):
 NEGATIVES = {
     # the keys' shared part left out of the rotation: every layer is wrong
     "keys_lack_the_rotated_part": (
-        lambda cfg: (DMoETransformerLM(cfg, _one_device_mesh()), _reference_with(
+        lambda cfg: (DMoETransformerLM(cfg, _one_device_mesh()), limits.reference_with(
             queries_keys_values=functools.partial(
                 reference.queries_keys_values, rotate_keys=False))),
         ("layers_rms",)),
@@ -240,7 +217,7 @@ NEGATIVES = {
                      reference),
         ("loss", "hidden_token_median")),
     "halves_of_the_concatenation_swapped": (
-        lambda cfg: (DMoETransformerLM(cfg, _one_device_mesh()), _reference_with(
+        lambda cfg: (DMoETransformerLM(cfg, _one_device_mesh()), limits.reference_with(
             mtp_input=functools.partial(
                 reference.mtp_input, embedding_first=False))),
         ("layers_rms",)),
@@ -248,11 +225,8 @@ NEGATIVES = {
 
 
 def test_the_block_as_it_is_reads_inside_the_runner_tolerances(tiny):
-    model, _, params, ids, tgt = tiny
-    read = runner.compare_with_reference(
-        model, params, reference, TINY_FILE, ids[:1], tgt[:1])
-    limits = {**runner.TOLERANCES, "near_tie_share": 1.0}  # 32 positions
-    assert [k for k, lim in limits.items() if not read[k] <= lim] == []
+    read = limits.read(tiny)
+    assert limits.outside(read) == []
     # the embedding, five layers, the block's combine, the block's layer
     assert len(read["embed_and_layers_rms"]) == 8
     assert len(read["near_tie_shares"]) == 6 and read["near_tie_shares"][0] == 0.0
@@ -260,25 +234,19 @@ def test_the_block_as_it_is_reads_inside_the_runner_tolerances(tiny):
 
 @pytest.mark.parametrize("name", sorted(NEGATIVES))
 def test_a_wrong_block_fails_the_runner_tolerances(tiny, name):
-    _, cfg, params, ids, tgt = tiny
     build, outside = NEGATIVES[name]
-    model, ref = build(cfg)
-    read = runner.compare_with_reference(
-        model, params, ref, TINY_FILE, ids[:1], tgt[:1])
-    for key in outside:
-        assert not read[key] <= runner.TOLERANCES[key], (key, read[key])
+    model, ref = build(tiny[1])
+    read = limits.read(tiny, model, ref)
+    assert limits.none_inside(read, *outside), read
 
 
 def test_reference_at_a_lower_precision_fails_the_runner_tolerances(tiny):
     """The reference with float8 operands in the program's place reads
     outside the layer and logits limits; with bf16 operands inside."""
-    model, _, params, ids, tgt = tiny
-    for dtype, inside in ((jnp.float8_e4m3fn, False), (jnp.bfloat16, True)):
-        read = runner.compare_with_reference(
-            model, params, reference, TINY_FILE, ids[:1], tgt[:1],
-            operand_dtype=dtype)
-        for key in ("layers_rms", "logits_rms", "mtp_logits_rms"):
-            assert (read[key] <= runner.TOLERANCES[key]) is inside, (dtype, key)
+    heads = ("layers_rms", "logits_rms", "mtp_logits_rms")
+    assert limits.none_inside(
+        limits.read(tiny, operand_dtype=jnp.float8_e4m3fn), *heads)
+    assert limits.inside(limits.read(tiny, operand_dtype=jnp.bfloat16), *heads)
 
 
 # ---- (c) the loss layer's masked pass ----

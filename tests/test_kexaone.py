@@ -36,7 +36,13 @@ from learning_at_home_tpu.models.transformer import (  # noqa: E402
     AttentionLayer,
     DMoETransformerLM,
 )
-from learning_at_home_tpu.parallel.mesh import make_mesh  # noqa: E402
+from runner_limits import (  # noqa: E402,F401  (``compiled_once`` is a fixture)
+    compiled_once,
+    decisive,
+    Limits,
+    one_device_mesh as _one_device_mesh,
+    tiny_stack,
+)
 
 reference = harness.load_path(os.path.join(
     REPO, "benchmarks", "configs", "k_exaone_236b_a23b_reference.py"))
@@ -48,10 +54,8 @@ TINY_FILE = harness.load_json(os.path.join(
 CELL_FILE = harness.load_json(os.path.join(
     REPO, "benchmarks", "configs", "k-exaone-236b-a23b.json"))
 SIZES = runner.reference_sizes(TINY_FILE)  # what the runner hands the reference
-
-
-def _one_device_mesh():
-    return make_mesh({"expert": 1}, devices=jax.devices()[:1])
+limits = Limits(runner, reference, TINY_FILE)
+pytestmark = pytest.mark.usefixtures("compiled_once")
 
 
 def _decisive(params, seed=7):
@@ -61,28 +65,14 @@ def _decisive(params, seed=7):
     embeddings whose mean square is near the norm's eps, feed-forward
     outputs small enough that the few tokens whose 4th and 5th scores swap
     under bf16 do not swamp the rest of 32, norm scales off 1."""
-    rs = np.random.RandomState(seed)
-    scale = {"['gate']": 20.0, "['embed']": 0.3, "['w_down']": 0.3}
-
-    def leaf(path, a):
-        name = jax.tree_util.keystr(path)
-        if name.endswith("['scale']"):
-            return a * jnp.asarray(rs.uniform(0.5, 1.5, a.shape), a.dtype)
-        if name.endswith("['router_bias']"):
-            return jnp.asarray(rs.uniform(-0.2, 0.2, a.shape), a.dtype)
-        return a * next((v for k, v in scale.items() if name.endswith(k)), 1.0)
-
-    return jax.tree_util.tree_map_with_path(leaf, params)
+    return decisive(params, seed, drawn={"['router_bias']": 0.2}, scaled={
+        "['gate']": 20.0, "['embed']": 0.3, "['w_down']": 0.3})
 
 
 @pytest.fixture(scope="module")
 def tiny():
     """(model, cfg, float32 params, ids, targets) on one device."""
-    model, cfg, _, batch = k_exaone_one_chip(_one_device_mesh(), tiny=True)
-    params = _decisive(model.init_params(jax.random.PRNGKey(11)))
-    rs = np.random.RandomState(3)
-    ids = jnp.asarray(rs.randint(0, cfg.vocab_size, (batch, cfg.seq_len + 1)))
-    return model, cfg, params, ids[:, :-1], ids[:, 1:]
+    return tiny_stack(k_exaone_one_chip, _decisive)
 
 
 @pytest.fixture(scope="module")
@@ -163,9 +153,8 @@ def _bf16_model(cfg, mesh, **changes):
 
 
 def test_block_in_bf16_is_inside_the_runner_tolerances(tiny):
-    model, cfg, params, ids, tgt = tiny
-    read = runner.compare_with_reference(
-        _bf16_model(cfg, model.mesh), params, reference, TINY_FILE, ids[:1], tgt[:1])
+    model, cfg = tiny[:2]
+    read = limits.read(tiny, _bf16_model(cfg, model.mesh))
     assert not runner.over_tolerance(read), read
     assert 0.0 < read["near_tie_share"] and read["near_tie_shares"][0] == 0.0
     assert len(read["embed_and_layers_rms"]) == 1 + cfg.n_layers
@@ -175,11 +164,8 @@ def test_reference_at_a_lower_precision_fails_the_runner_tolerances(tiny):
     """The reference itself with every matmul operand rounded to
     float8_e4m3, the nearest precision below the configuration's bf16, is
     outside the runner's limits; rounded to bf16 it is inside."""
-    model, _, params, ids, tgt = tiny
     for dtype, outside in ((jnp.float8_e4m3fn, True), (jnp.bfloat16, False)):
-        read = runner.compare_with_reference(
-            model, params, reference, TINY_FILE, ids[:1], tgt[:1],
-            operand_dtype=dtype)
+        read = limits.read(tiny, operand_dtype=dtype)
         assert bool(runner.over_tolerance(read)) is outside, (dtype, read)
 
 
@@ -206,9 +192,7 @@ def test_a_token_between_two_experts_is_left_out_where_one_of_them_is_held(
     np.testing.assert_allclose(every, gap[:, 0], rtol=1e-6)
     monkeypatch.setattr(runner, "MARGIN", np.inf)
     with np.errstate(invalid="ignore"):
-        read = runner.compare_with_reference(
-            _bf16_model(cfg, model.mesh), params, reference, TINY_FILE,
-            ids[:1], tgt[:1])
+        read = limits.read(tiny, _bf16_model(cfg, model.mesh))
     assert "near_tie_share" in [p.split()[0] for p in runner.over_tolerance(read)]
     assert read["near_tie_shares"][0] == 0.0  # the dense layer leaves out none
 
@@ -267,22 +251,19 @@ def test_a_wrong_block_fails_the_runner_tolerances(tiny, name):
     """The limits are tight: a stack without its window, its dense layer
     or its shared expert, and each other plausible misreading of the
     block, computed in bf16 like the program, reads outside them."""
-    model, cfg, params, ids, tgt = tiny
-    read = runner.compare_with_reference(
-        MUTATIONS[name](cfg, model.mesh), params, reference, TINY_FILE,
-        ids[:1], tgt[:1])
+    model, cfg = tiny[:2]
+    read = limits.read(tiny, MUTATIONS[name](cfg, model.mesh))
     assert runner.over_tolerance(read), read
 
 
 def test_a_whole_that_composes_other_layers_fails_the_runner_tolerances(tiny):
     """The layers are compared one at a time; ``hidden_token_median`` holds
     ``_hidden`` (what ``apply`` and ``loss_fn`` run) to the same layers."""
-    model, cfg, params, ids, tgt = tiny
+    model, cfg = tiny[:2]
     mixed = _Without(_bf16_model(cfg, model.mesh), None)
     wrong = _bf16_model(cfg, model.mesh, **_no_window(cfg))
     mixed._hidden, mixed.loss_fn = wrong._hidden, wrong.loss_fn
-    read = runner.compare_with_reference(
-        mixed, params, reference, TINY_FILE, ids[:1], tgt[:1])
+    read = limits.read(tiny, mixed)
     assert "hidden_token_median" in [
         p.split()[0] for p in runner.over_tolerance(read)], read
 

@@ -33,6 +33,12 @@ from learning_at_home_tpu.models.transformer import (  # noqa: E402
 )
 from learning_at_home_tpu.ops import short_conv  # noqa: E402
 from learning_at_home_tpu.parallel.mesh import make_mesh  # noqa: E402
+from runner_limits import (  # noqa: E402,F401  (``compiled_once`` is a fixture)
+    compiled_once,
+    decisive,
+    Limits,
+    one_device_mesh as _one_device_mesh,
+)
 
 reference = harness.load_path(os.path.join(
     REPO, "benchmarks", "configs", "lfm2_8b_a1b_reference.py"))
@@ -43,29 +49,16 @@ TINY_FILE = harness.load_json(os.path.join(
 CELL_FILE = harness.load_json(os.path.join(
     REPO, "benchmarks", "configs", "lfm2-8b-a1b.json"))
 SIZES = runner.reference_sizes(TINY_FILE)  # what the runner hands the reference
+limits = Limits(runner, reference, TINY_FILE)
+pytestmark = pytest.mark.usefixtures("compiled_once")
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
-
-
-def _one_device_mesh():
-    return make_mesh({"expert": 1}, devices=jax.devices()[:1])
 
 
 def _decisive(params, seed=7):
     """Seeded weights under which every part of the stack decides: norm
     scales off 1, routers that choose firmly, selection biases off 0."""
-    rs = np.random.RandomState(seed)
-
-    def leaf(path, a):
-        name = jax.tree_util.keystr(path)
-        if name.endswith("['scale']"):
-            return a * jnp.asarray(rs.uniform(0.5, 1.5, a.shape), a.dtype)
-        if name.endswith("['moe']['gate']"):
-            return a * 40.0
-        if name.endswith("['router_bias']"):
-            return jnp.asarray(rs.uniform(-0.3, 0.3, a.shape), a.dtype)
-        return a
-
-    return jax.tree_util.tree_map_with_path(leaf, params)
+    return decisive(params, seed, drawn={"['router_bias']": 0.3},
+                    scaled={"['moe']['gate']": 40.0})
 
 
 @pytest.fixture(scope="module")
@@ -312,9 +305,5 @@ def test_a_wrong_program_falls_outside_a_limit(tiny, wrong):
     the timed step here), in the program's place in the runner's own
     comparison at the tiny size, is outside at least one tolerance; the
     program itself is inside all (the rehearsal holds that)."""
-    model, _, params, ids, tgt = tiny
-    read = runner.compare_with_reference(
-        model, params, reference, TINY_FILE, ids[:1], tgt[:1], wrong=wrong)
-    outside = [k for k, limit in runner.TOLERANCES.items()
-               if k != "near_tie_share" and not read[k] <= limit]
-    assert outside, read
+    read = limits.read(tiny, wrong=wrong)
+    assert limits.outside(read), read

@@ -408,13 +408,19 @@ def test_drain_during_active_dispatch_zero_failures():
     d_a = DHT(initial_peers=[boot.endpoint])
     d_b = DHT(initial_peers=[boot.endpoint])
     d_c = DHT(initial_peers=[boot.endpoint])
+    # a liveness that is a COUNT of heartbeats: a record lives two periods,
+    # so at 2 s a period the successor has to miss 4 s of its heartbeats
+    # before its experts read dead (at 0.4 s, under six loaded workers, one
+    # late pass of its loop was enough, and with the other server draining
+    # no expert was alive)
+    period = 2.0
     srv_a = Server.create(
         expert_uids=["lc.0", "lc.1"], hidden_dim=16, host="127.0.0.1",
-        optimizer=optax.adam(1e-3), dht=d_a, update_period=0.4,
+        optimizer=optax.adam(1e-3), dht=d_a, update_period=period,
     )
     srv_b = Server.create(
         expert_uids=["lc.2", "lc.3"], hidden_dim=16, host="127.0.0.1",
-        optimizer=optax.adam(1e-3), dht=d_b, update_period=0.4,
+        optimizer=optax.adam(1e-3), dht=d_b, update_period=period,
     )
     moe = None
     try:
@@ -423,10 +429,9 @@ def test_drain_during_active_dispatch_zero_failures():
             k_best=3, k_min=1, timeout_after_k_min=0.5,
             forward_timeout=20.0, backward_timeout=20.0, alive_ttl=0.4,
         )
-        deadline = time.time() + 30
-        while time.time() < deadline:
-            if len(d_c._loop.run(d_c._get_alive("lc"))) == 4:
-                break
+        deadline = time.time() + 60
+        while len(d_c._loop.run(d_c._get_alive("lc"))) < 4:
+            assert time.time() < deadline, "the four experts never read alive"
             time.sleep(0.2)
         gate = moe.init_gate_params(jax.random.PRNGKey(0))
         opt = optax.adam(1e-2)
@@ -438,10 +443,14 @@ def test_drain_during_active_dispatch_zero_failures():
         def loss_fn(gate, x, y):
             return jnp.mean((moe(x, gate) - y) ** 2)
 
-        failures = 0
+        # the trainer steps for as long as the drain takes and eight steps
+        # more (while the records the drained server left still name it),
+        # however many steps that is on this machine
+        failures = steps = steps_after = 0
         drained = False
-        for step in range(30):
-            if step == 8:
+        deadline = time.time() + 120
+        while steps_after < 8 and time.time() < deadline:
+            if steps == 8:
                 assert srv_a.start_drain(
                     successor=srv_b.endpoint, grace=0.5,
                     quiesce_timeout=5.0,
@@ -457,7 +466,9 @@ def test_drain_during_active_dispatch_zero_failures():
                 gate = optax.apply_updates(gate, updates)
             except Exception:
                 failures += 1
-        assert srv_a.wait_drained(timeout=30.0), "drain never finished"
+            steps += 1
+            steps_after += drained
+        assert drained, f"drain never finished ({steps} steps)"
         assert failures == 0, f"{failures} quorum failures during drain"
         assert moe.samples_dropped == 0
         assert moe.backward_samples_dropped == 0

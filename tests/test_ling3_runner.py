@@ -1,27 +1,25 @@
 """The limits of ``benchmarks/runners/train_recipe_ling3.py`` on the tiny
-Ling-3.0 stack: the stack as it is reads inside them, each named wrong
-program and the reference at float8 outside, and the counters' limits tell
-a share no group reaches.  A module apart from ``tests/test_ling3.py``
-(whose fixture and files it uses) so that ``--dist loadfile`` can give the
-comparison's compiles a worker of their own."""
+Ling-3.0 stack: the stack as it is reads inside them and each wrong STEP
+outside (the cases that compare the backward pass and the update: they share
+the comparison's compiled programs, ``compiled_once``).  The wrong forwards,
+the reference at float8 and the counters' limits are
+``tests/test_ling3_runner_forwards.py``'s.  Modules apart from
+``tests/test_ling3.py`` (whose fixture and files they use) so that ``--dist
+loadfile`` can give the comparison's compiles workers of their own."""
 
-import jax.numpy as jnp
 import pytest
 
+from runner_limits import Limits, compiled_once  # noqa: F401  (a fixture)
 from test_ling3 import TINY_FILE, reference, runner, tiny  # noqa: F401  (a fixture)
 
-
-def _compare(tiny, **how):
-    model, _, params, ids, tgt = tiny
-    return runner.compare_with_reference(
-        model, params, reference, TINY_FILE, ids[:1], tgt[:1], **how)
+pytestmark = pytest.mark.usefixtures("compiled_once")
+limits = Limits(runner, reference, TINY_FILE)
+STEPS = sorted(name for name in runner.WRONG_PROGRAMS if name.startswith("the step"))
 
 
 def test_the_stack_as_it_is_reads_inside_the_runner_tolerances(tiny):
-    read = _compare(tiny)
-    limits = {**runner.TOLERANCES, "near_tie_share": 1.0}
-    assert all(read[k] <= limit for k, limit in limits.items()), {
-        k: read[k] for k, limit in limits.items() if not read[k] <= limit}
+    read = limits.read(tiny)
+    assert limits.outside(read) == [], read
     assert len(read["delta_layers_rms"]) == 3 and len(read["attention_layers_rms"]) == 1
     assert read["near_tie_shares"][0] == 0.0 and read["step_read"]
     assert max(read[k] for k in runner.GRADIENT_READINGS) < 1e-3
@@ -33,8 +31,7 @@ def test_the_stack_as_it_is_reads_inside_the_runner_tolerances(tiny):
     assert read["leaves_held_to_moving"] >= 1
 
 
-@pytest.mark.parametrize("name", sorted(runner.WRONG_PROGRAMS))
-def test_a_wrong_program_fails_the_runner_tolerances(tiny, name, monkeypatch):
+def a_wrong_program_fails(tiny, name, monkeypatch):
     """Each named fault falls outside one limit at least, at tiny sizes in
     float32 (where a bf16 sum of decays is the only rounding there is)."""
     if "leading layer" in name:
@@ -42,7 +39,7 @@ def test_a_wrong_program_fails_the_runner_tolerances(tiny, name, monkeypatch):
         # cell's 10.5 M: a leaf is held to moving on its own from SMALL_LEAF
         # up (a bf16 norm scale of ones cannot move by 1e-4 of itself)
         monkeypatch.setattr(runner, "SMALL_LEAF", 1024)
-    read = _compare(tiny, **runner.WRONG_PROGRAMS[name])
+    read = limits.read(tiny, **runner.WRONG_PROGRAMS[name])
     if "leading layer" in name:
         assert read["update_norm"] == 1.0  # what a state left unchanged reads
         assert read["update_norm_worst_leaf"] == "['layers'][0]['delta']['w_out']"
@@ -52,25 +49,9 @@ def test_a_wrong_program_fails_the_runner_tolerances(tiny, name, monkeypatch):
         # here the reading is a thousand times the program's own
         assert read["delta_rms"] > 3e-4 and read["delta_state_rms"] > 3e-4
         return
-    over = [k for k, limit in runner.TOLERANCES.items()
-            if k != "near_tie_share" and not read[k] <= limit]
-    assert over, {k: read[k] for k in runner.TOLERANCES}
+    assert limits.outside(read), {k: read[k] for k in runner.TOLERANCES}
 
 
-def test_reference_at_a_lower_precision_fails_the_runner_tolerances(tiny):
-    read = _compare(tiny, operand_dtype=jnp.float8_e4m3fn)
-    over = [k for k, limit in runner.TOLERANCES.items()
-            if k != "near_tie_share" and not read[k] <= limit]
-    assert len(over) >= 4, {k: read[k] for k in runner.TOLERANCES}
-
-
-def test_the_counters_limits_tell_a_share_no_group_reaches():
-    good = {"dropped_fraction": [0.0], "local_rows_over_level": [1.0],
-            "expert_load_max_over_mean": [1.4], "delta_decay_min": [0.02],
-            "delta_beta_max": [0.9], "attention_gate_mean": [0.5],
-            "groups_reaching_share": [0.5]}
-    assert runner.share_problems(good) == []
-    for name, bad in (("groups_reaching_share", 0.0), ("groups_reaching_share", 1.0),
-                      ("delta_beta_max", 1.7), ("delta_decay_min", 0.0),
-                      ("attention_gate_mean", 1.0), ("dropped_fraction", 0.01)):
-        assert runner.share_problems({**good, name: [bad]}), name
+@pytest.mark.parametrize("name", STEPS)
+def test_a_wrong_program_fails_the_runner_tolerances(tiny, name, monkeypatch):
+    a_wrong_program_fails(tiny, name, monkeypatch)

@@ -35,7 +35,12 @@ from learning_at_home_tpu.models import trunk  # noqa: E402
 from learning_at_home_tpu.models.transformer import DMoETransformerLM  # noqa: E402
 from learning_at_home_tpu.ops import gate_norm  # noqa: E402
 from learning_at_home_tpu.ops.ssd import ssd_chunked  # noqa: E402
-from learning_at_home_tpu.parallel.mesh import make_mesh  # noqa: E402
+from runner_limits import (  # noqa: E402
+    close as _close,
+    decisive,
+    one_device_mesh as _one_device_mesh,
+    tiny_stack,
+)
 
 REFERENCE = os.path.join(
     REPO, "benchmarks", "configs", "nemotron_labs_twotower_30b_a3b_reference.py")
@@ -51,39 +56,20 @@ CELL = "nemotron-labs-twotower-30b-a3b-train-zipf16k"
 SIZES = runner.reference_sizes(TINY_FILE)  # what the runner hands the reference
 
 
-def _one_device_mesh():
-    return make_mesh({"expert": 1}, devices=jax.devices()[:1])
-
-
 def _decisive(params, seed=7):
     """Seeded weights under which every part of the stack decides: a router
     that decides (the program's init gives near-equal scores), selection
     biases off zero, norm scales, ``D`` and the convolution's bias off
     their initial values."""
-    rs = np.random.RandomState(seed)
-
-    def leaf(path, a):
-        name = jax.tree_util.keystr(path)
-        if name.endswith(("['scale']", "['D']")):
-            return a * jnp.asarray(rs.uniform(0.5, 1.5, a.shape), a.dtype)
-        if name.endswith("['router_bias']"):
-            return jnp.asarray(rs.uniform(-0.2, 0.2, a.shape), a.dtype)
-        if name.endswith("['conv_b']"):
-            return jnp.asarray(rs.uniform(-0.3, 0.3, a.shape), a.dtype)
-        return a * (20.0 if name.endswith("['gate']") else 1.0)
-
-    return jax.tree_util.tree_map_with_path(leaf, params)
+    return decisive(
+        params, seed, spread=("['scale']", "['D']"),
+        drawn={"['router_bias']": 0.2, "['conv_b']": 0.3}, scaled={"['gate']": 20.0})
 
 
 @pytest.fixture(scope="module")
 def tiny():
     """(model, cfg, float32 params, ids, targets) on one device."""
-    model, cfg, _, batch = nemotron_labs_twotower_one_chip(
-        _one_device_mesh(), tiny=True)
-    params = _decisive(model.init_params(jax.random.PRNGKey(11)))
-    rs = np.random.RandomState(3)
-    ids = jnp.asarray(rs.randint(0, cfg.vocab_size, (batch, cfg.seq_len + 1)))
-    return model, cfg, params, ids[:, :-1], ids[:, 1:]
+    return tiny_stack(nemotron_labs_twotower_one_chip, _decisive)
 
 
 @pytest.fixture(scope="module")
@@ -95,12 +81,6 @@ def want(tiny):
     loss, grads = jax.jit(
         lambda p: reference.loss_and_grads(p, ids, tgt, SIZES))(params)
     return np.asarray(logits), float(loss), grads
-
-
-def _close(got, want, tol=1e-4, **kw):
-    want = np.asarray(want)
-    np.testing.assert_allclose(
-        np.asarray(got), want, rtol=0, atol=tol * np.abs(want).max(), **kw)
 
 
 def _mixer(cfg, lp, x, chunk=None):

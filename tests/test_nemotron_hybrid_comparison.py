@@ -10,27 +10,21 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from runner_limits import Limits, compiled_once  # noqa: F401  (a fixture)
 from test_nemotron_hybrid import (  # noqa: F401  (``tiny`` is a fixture)
-    REFERENCE,
     TINY_FILE,
     _one_device_mesh,
-    harness,
     reference,
     runner,
     tiny,
 )
 from learning_at_home_tpu.models.transformer import DMoETransformerLM
 
+pytestmark = pytest.mark.usefixtures("compiled_once")
+limits = Limits(runner, reference, TINY_FILE)
+
 
 # ---- (b) the negatives: the comparison can fail ----
-
-
-def _reference_with(**changes):
-    """A copy of the reference module with functions replaced."""
-    broken = harness.load_path(REFERENCE)
-    for name, value in changes.items():
-        setattr(broken, name, value)
-    return broken
 
 
 def _norm_then_gate(y, z, scale, groups, eps):
@@ -54,11 +48,8 @@ NEGATIVES = {
 
 
 def test_the_stack_as_it_is_reads_inside_the_runner_tolerances(tiny):
-    model, _, params, ids, tgt = tiny
-    read = runner.compare_with_reference(
-        model, params, reference, TINY_FILE, ids[:1], tgt[:1])
-    limits = {**runner.TOLERANCES, "near_tie_share": 1.0}  # 32 positions
-    assert [k for k, lim in limits.items() if not read[k] <= lim] == []
+    read = limits.read(tiny)
+    assert limits.outside(read) == []
     assert len(read["embed_and_layers_rms"]) == 10  # the embedding, nine layers
     assert len(read["ssm_layers_rms"]) == len(read["ssm_states_rms"]) == 4
     assert len(read["near_tie_shares"]) == 4
@@ -68,12 +59,9 @@ def test_the_stack_as_it_is_reads_inside_the_runner_tolerances(tiny):
 def test_a_wrong_stack_fails_the_runner_tolerances(tiny, name):
     """Each read OUTSIDE the tolerance: the comparison can fail.  (The
     wrong side is the reference's copy; the program is as it is.)"""
-    model, _, params, ids, tgt = tiny
     changes, outside = NEGATIVES[name]
-    read = runner.compare_with_reference(
-        model, params, _reference_with(**changes), TINY_FILE, ids[:1], tgt[:1])
-    for key in outside:
-        assert not read[key] <= runner.TOLERANCES[key], (key, read[key])
+    read = limits.read(tiny, reference=limits.reference_with(**changes))
+    assert limits.none_inside(read, *outside), read
 
 
 def test_a_hidden_that_composes_another_stack_fails_the_runner_tolerances(tiny):
@@ -84,9 +72,8 @@ def test_a_hidden_that_composes_another_stack_fails_the_runner_tolerances(tiny):
     layer = model._layer
     model._layer = lambda lp, x, *rest: (
         (x, None) if "ssm" in lp and x.shape[0] != 1 else layer(lp, x, *rest))
-    read = runner.compare_with_reference(
-        model, params, reference, TINY_FILE, ids[:1], tgt[:1])
-    assert read["hidden_token_median"] <= runner.TOLERANCES["hidden_token_median"]
+    read = limits.read(tiny, model)
+    assert limits.inside(read, "hidden_token_median")
     model._layer = lambda lp, x, *rest: (
         (x, None) if "ssm" in lp else layer(lp, x, *rest))
     whole = jax.jit(lambda p: model._hidden(p, ids[:1])[0])(params)
@@ -100,17 +87,11 @@ def test_lower_precisions_fail_the_runner_tolerances(tiny):
     """The reference with float8 operands in the program's place reads
     outside the layer and logits limits, with bf16 operands inside; the
     program's scan with bf16 decays reads worse than with float32 ones."""
-    model, _, params, ids, tgt = tiny
-    for dtype, inside in ((jnp.float8_e4m3fn, False), (jnp.bfloat16, True)):
-        read = runner.compare_with_reference(
-            model, params, reference, TINY_FILE, ids[:1], tgt[:1],
-            operand_dtype=dtype)
-        for key in ("layers_rms", "ssm_rms", "logits_rms"):
-            assert (read[key] <= runner.TOLERANCES[key]) is inside, (dtype, key)
-    exact = runner.compare_with_reference(
-        model, params, reference, TINY_FILE, ids[:1], tgt[:1])
-    rough = runner.compare_with_reference(
-        model, params, reference, TINY_FILE, ids[:1], tgt[:1],
-        decay_dtype=jnp.bfloat16)
+    held = ("layers_rms", "ssm_rms", "logits_rms")
+    assert limits.none_inside(
+        limits.read(tiny, operand_dtype=jnp.float8_e4m3fn), *held)
+    assert limits.inside(limits.read(tiny, operand_dtype=jnp.bfloat16), *held)
+    exact = limits.read(tiny)
+    rough = limits.read(tiny, decay_dtype=jnp.bfloat16)
     assert rough["ssm_rms"] > 100 * exact["ssm_rms"]
     assert rough["ssm_state_rms"] > 100 * exact["ssm_state_rms"]

@@ -37,6 +37,7 @@ from learning_at_home_tpu.models.transformer import (  # noqa: E402
 from learning_at_home_tpu.ops import gate_norm  # noqa: E402
 from learning_at_home_tpu.ops import ssm_conv  # noqa: E402
 from learning_at_home_tpu.parallel.mesh import make_mesh  # noqa: E402
+from runner_limits import one_device_mesh as _one_device_mesh  # noqa: E402
 
 REFERENCE = os.path.join(
     REPO, "benchmarks", "configs", "olmo_hybrid_7b_reference.py")
@@ -50,10 +51,6 @@ CELL_FILE = harness.load_json(os.path.join(
     REPO, "benchmarks", "configs", "olmo-hybrid-7b.json"))
 CELL = "olmo-hybrid-7b-train-zipf16k"
 SIZES = runner.reference_sizes(TINY_FILE)  # what the runner hands the reference
-
-
-def _one_device_mesh():
-    return make_mesh({"expert": 1}, devices=jax.devices()[:1])
 
 
 def _decisive(params, seed=7):
@@ -86,12 +83,12 @@ def tiny():
 
 
 @pytest.fixture(scope="module")
-def want(tiny):
+def want(tiny, llvm_optimised):
     """The reference's float32 logits, loss and gradients on the tiny
     weights, each one compiled program, once a module."""
     _, _, params, ids, tgt = tiny
     logits = jax.jit(lambda p: reference.forward(p, ids, SIZES))(params)
-    loss, grads = jax.jit(
+    loss, grads = llvm_optimised(  # see the gradients' case
         lambda p: reference.loss_and_grads(p, ids, tgt, SIZES))(params)
     return logits, float(loss), grads
 
@@ -241,12 +238,18 @@ def test_logits_and_loss_of_the_whole_stack_match_the_reference(tiny, want):
     assert set(metrics) == {"ce", "delta_decay_min", "delta_beta_max"}
 
 
-def test_gradients_of_every_parameter_match_the_reference(tiny, want):
+def test_gradients_of_every_parameter_match_the_reference(
+        tiny, want, llvm_optimised):
     """Every leaf, relative to the leaf's own largest gradient: 1e-3,
     float32 sums in another order through eight layers and two hundred
-    and fifty-six steps of a recurrence."""
+    and fifty-six steps of a recurrence.  Both sides keep LLVM's optimised
+    code: layer 2's ``A_log`` (four numbers, each a sum over every position
+    of the row) reads 8.7e-4 under it and 1.27e-3 under the suite's cheap
+    code generation, where the program's own two compiles are 3.0e-4 apart
+    on that leaf (my CPU run, PR 70): the limit is met by the order of a
+    float32 sum, and is left as it was."""
     model, _, params, ids, tgt = tiny
-    got = jax.jit(jax.grad(lambda p: model.loss_fn(p, ids, tgt)[0]))(params)
+    got = llvm_optimised(jax.grad(lambda p: model.loss_fn(p, ids, tgt)[0]))(params)
     flat_got = jax.tree_util.tree_flatten_with_path(got)[0]
     flat_want = jax.tree_util.tree_leaves(want[2])
     assert len(flat_got) == len(flat_want) == len(jax.tree_util.tree_leaves(params))

@@ -12,39 +12,23 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from runner_limits import Limits, compiled_once  # noqa: F401  (a fixture)
 from test_olmo_hybrid import (  # noqa: F401  (``tiny`` is a fixture)
-    REFERENCE,
     TINY_FILE,
     _one_device_mesh,
-    harness,
     reference,
     runner,
     tiny,
 )
 from learning_at_home_tpu.models.transformer import AttentionLayer, DMoETransformerLM
 
-
-def _reference_with(**changes):
-    """A copy of the reference module with functions replaced."""
-    broken = harness.load_path(REFERENCE)
-    for name, value in changes.items():
-        setattr(broken, name, value)
-    return broken
-
-
-def _read(model, params, ids, tgt, module=reference, **how):
-    return runner.compare_with_reference(
-        model, params, module, TINY_FILE, ids[:1], tgt[:1], **how)
-
-
-def _outside(read):
-    return [k for k, lim in runner.TOLERANCES.items() if not read[k] <= lim]
+pytestmark = pytest.mark.usefixtures("compiled_once")
+limits = Limits(runner, reference, TINY_FILE)
 
 
 def test_the_stack_as_it_is_reads_inside_the_runner_tolerances(tiny):
-    model, _, params, ids, tgt = tiny
-    read = _read(model, params, ids, tgt)
-    assert _outside(read) == []
+    read = limits.read(tiny)
+    assert limits.outside(read, near_ties=True) == []
     assert len(read["embed_and_layers_rms"]) == 9  # the embedding, eight layers
     assert len(read["delta_layers_rms"]) == len(read["delta_states_rms"]) == 6
     # the mixer's output by its worst layer, the state by its median layer
@@ -68,11 +52,9 @@ WRONG_REFERENCES = {
 def test_a_wrong_delta_layer_fails_the_runner_tolerances(tiny, name):
     """Each read OUTSIDE the tolerance: the comparison can fail.  (The
     wrong side is the reference's copy; the program is as it is.)"""
-    model, _, params, ids, tgt = tiny
     changes, outside = WRONG_REFERENCES[name]
-    read = _read(model, params, ids, tgt, _reference_with(**changes))
-    for key in outside:
-        assert not read[key] <= runner.TOLERANCES[key], (key, read[key])
+    read = limits.read(tiny, reference=limits.reference_with(**changes))
+    assert limits.none_inside(read, *outside), read
 
 
 @pytest.mark.parametrize("name, changes, outside", [
@@ -86,23 +68,20 @@ def test_a_wrong_program_fails_the_runner_tolerances(tiny, name, changes, outsid
     """The same weights under a program that norms a part's input, or that
     rotates the full layers' queries and keys, against the reference as it
     is."""
-    _, cfg, params, ids, tgt = tiny
-    model = DMoETransformerLM(dataclasses.replace(cfg, **changes), _one_device_mesh())
-    read = _read(model, params, ids, tgt)
-    for key in outside:
-        assert not read[key] <= runner.TOLERANCES[key], (name, key, read[key])
+    model = DMoETransformerLM(
+        dataclasses.replace(tiny[1], **changes), _one_device_mesh())
+    read = limits.read(tiny, model)
+    assert limits.none_inside(read, *outside), (name, read)
 
 
 def test_a_hidden_that_composes_another_stack_fails_the_runner_tolerances(tiny):
     """``_hidden`` over a stack whose delta layers are skipped (the layers
     themselves as they are) reads outside ``hidden_token_median``."""
-    _, cfg, params, ids, tgt = tiny
-    model = DMoETransformerLM(cfg, _one_device_mesh())
+    model = DMoETransformerLM(tiny[1], _one_device_mesh())
     layer = model._layer
     model._layer = lambda lp, x, *rest: (
         (x, None) if "delta" in lp else layer(lp, x, *rest))
-    read = _read(model, params, ids, tgt)
-    assert not read["hidden_token_median"] <= runner.TOLERANCES["hidden_token_median"]
+    assert limits.none_inside(limits.read(tiny, model), "hidden_token_median")
 
 
 def test_lower_precisions_fail_the_runner_tolerances(tiny):
@@ -110,12 +89,11 @@ def test_lower_precisions_fail_the_runner_tolerances(tiny):
     outside the layer, delta and logits limits, with bf16 operands inside;
     the program's rule with its decays summed in bf16 reads worse than
     with float32 sums."""
-    model, _, params, ids, tgt = tiny
-    for dtype, inside in ((jnp.float8_e4m3fn, False), (jnp.bfloat16, True)):
-        read = _read(model, params, ids, tgt, operand_dtype=dtype)
-        for key in ("layers_rms", "delta_rms", "logits_rms"):
-            assert (read[key] <= runner.TOLERANCES[key]) is inside, (dtype, key)
-    exact = _read(model, params, ids, tgt)
-    rough = _read(model, params, ids, tgt, decay_dtype=jnp.bfloat16)
+    held = ("layers_rms", "delta_rms", "logits_rms")
+    assert limits.none_inside(
+        limits.read(tiny, operand_dtype=jnp.float8_e4m3fn), *held)
+    assert limits.inside(limits.read(tiny, operand_dtype=jnp.bfloat16), *held)
+    exact = limits.read(tiny)
+    rough = limits.read(tiny, decay_dtype=jnp.bfloat16)
     assert rough["delta_rms"] > 100 * exact["delta_rms"]
     assert rough["delta_state_rms"] > 100 * exact["delta_state_rms"]

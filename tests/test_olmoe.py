@@ -44,6 +44,10 @@ from learning_at_home_tpu.ops.moe_dispatch import (  # noqa: E402
 )
 from learning_at_home_tpu.parallel.mesh import batch_sharding, make_mesh  # noqa: E402
 from learning_at_home_tpu.parallel.sharded_moe import ShardedMixtureOfExperts  # noqa: E402
+from runner_limits import (  # noqa: E402
+    one_device_mesh as _one_device_mesh,
+    tiny_stack,
+)
 
 reference = harness.load_path(
     os.path.join(REPO, "benchmarks", "configs", "olmoe_1b_7b_reference.py")
@@ -52,10 +56,6 @@ runner = harness.load_path(
     os.path.join(REPO, "benchmarks", "runners", "train_recipe.py")
 )
 probe = harness.load_path(os.path.join(REPO, "tools", "smallthinker_probe.py"))
-
-
-def _one_device_mesh():
-    return make_mesh({"expert": 1}, devices=jax.devices()[:1])
 
 
 def _sizes(cfg):
@@ -88,11 +88,7 @@ def _decisive(params):
 @pytest.fixture(scope="module")
 def tiny():
     """(model, cfg, float32 params, ids, targets) on one device."""
-    model, cfg, _, batch = olmoe_one_chip(_one_device_mesh(), tiny=True)
-    params = _decisive(model.init_params(jax.random.PRNGKey(11)))
-    rs = np.random.RandomState(3)
-    ids = jnp.asarray(rs.randint(0, cfg.vocab_size, (batch, cfg.seq_len + 1)))
-    return model, cfg, params, ids[:, :-1], ids[:, 1:]
+    return tiny_stack(olmoe_one_chip, _decisive)
 
 
 @pytest.fixture(scope="module")

@@ -29,7 +29,10 @@ import qwen3next_flops  # noqa: E402
 from __graft_entry__ import qwen3_next_one_chip  # noqa: E402
 from learning_at_home_tpu.models.transformer import DMoETransformerLM  # noqa: E402
 from learning_at_home_tpu.ops import delta_rule  # noqa: E402
-from learning_at_home_tpu.parallel.mesh import make_mesh  # noqa: E402
+from runner_limits import (  # noqa: E402
+    decisive,
+    one_device_mesh as _one_device_mesh,
+)
 
 REFERENCE = os.path.join(
     REPO, "benchmarks", "configs", "qwen3_next_80b_a3b_reference.py")
@@ -43,29 +46,12 @@ CELL_FILE = harness.load_json(os.path.join(
 SIZES = runner.reference_sizes(TINY_FILE)  # what the runner hands the reference
 
 
-def _one_device_mesh():
-    return make_mesh({"expert": 1}, devices=jax.devices()[:1])
-
-
 def _decisive(params, seed=7):
     """Seeded weights under which every part of the stack decides: norm
     offsets off 0 and the plain scale off 1, routers that choose firmly,
     gates off one half."""
-    rs = np.random.RandomState(seed)
-
-    def leaf(path, a):
-        name = jax.tree_util.keystr(path)
-        if name.endswith("['offset']"):
-            return jnp.asarray(rs.uniform(-0.3, 0.3, a.shape), a.dtype)
-        if name.endswith("['scale']"):
-            return a * jnp.asarray(rs.uniform(0.5, 1.5, a.shape), a.dtype)
-        if name.endswith("['moe']['gate']"):
-            return a * 40.0
-        if name.endswith("['shared_gate']"):
-            return a * 4.0
-        return a
-
-    return jax.tree_util.tree_map_with_path(leaf, params)
+    return decisive(params, seed, drawn={"['offset']": 0.3}, scaled={
+        "['moe']['gate']": 40.0, "['shared_gate']": 4.0})
 
 
 @pytest.fixture(scope="module")

@@ -243,20 +243,28 @@ def test_seeded_loop_stall_is_recorded():
     before = sanitizer.stall_stats()
 
     async def stall():
-        time.sleep(0.3)  # deliberate: blocks this loop's only thread
+        # deliberate: blocks this loop's only thread, UNTIL the monitor's
+        # record of it has landed (a count, not a sleep the monitor thread
+        # has to be scheduled inside of: under six loaded workers it was
+        # not, and the stall went unseen) and 200 ms more
+        deadline = time.monotonic() + 60.0
+        while (sanitizer.stall_stats()["count"] == before["count"]
+               and time.monotonic() < deadline):
+            time.sleep(0.01)
+        time.sleep(0.2)
 
     asyncio.run(stall())
-    time.sleep(0.05)  # let the monitor's record land
     after = sanitizer.stall_stats()
     assert after["count"] > before["count"], (
-        f"seeded 300 ms stall not recorded: {before} -> {after}"
+        f"seeded stall not recorded: {before} -> {after}"
     )
+    # the callback's own end refreshes what the monitor read mid-flight
     assert after["max_ms"] >= 200.0
     last = after["last"]
     assert last is not None
     # the monitor captured the blocked frame: the seeded sleep is in it
     if last.get("stack"):
-        assert "time.sleep(0.3)" in last["stack"] or "stall" in last["stack"]
+        assert "time.sleep(0.01)" in last["stack"] or "stall" in last["stack"]
 
 
 # ---------------------------------------------------------------------------

@@ -27,8 +27,13 @@ import sdar_flops  # noqa: E402
 from __graft_entry__ import sdar_one_chip  # noqa: E402
 from learning_at_home_tpu.models import transformer  # noqa: E402
 from learning_at_home_tpu.models.transformer import DMoETransformerLM  # noqa: E402
-from learning_at_home_tpu.parallel.mesh import make_mesh  # noqa: E402
 from learning_at_home_tpu.parallel.sharded_moe import ShardedMixtureOfExperts  # noqa: E402
+from runner_limits import (  # noqa: E402,F401  (``compiled_once`` is a fixture)
+    compiled_once,
+    decisive,
+    Limits,
+    one_device_mesh as _one_device_mesh,
+)
 
 REFERENCE = os.path.join(REPO, "benchmarks", "configs", "sdar_30b_a3b_reference.py")
 reference = harness.load_path(REFERENCE)
@@ -39,26 +44,14 @@ TINY_FILE = harness.load_json(os.path.join(
 CELL_FILE = harness.load_json(os.path.join(
     REPO, "benchmarks", "configs", "sdar-30b-a3b.json"))
 SIZES = runner.reference_sizes(TINY_FILE)  # what the runner hands the reference
-
-
-def _one_device_mesh():
-    return make_mesh({"expert": 1}, devices=jax.devices()[:1])
+limits = Limits(runner, reference, TINY_FILE, with_targets=False)
+pytestmark = pytest.mark.usefixtures("compiled_once")
 
 
 def _decisive(params, seed=7):
     """Seeded weights under which every part of the stack decides: norm
     scales off 1, routers that choose firmly."""
-    rs = np.random.RandomState(seed)
-
-    def leaf(path, a):
-        name = jax.tree_util.keystr(path)
-        if name.endswith("['scale']"):
-            return a * jnp.asarray(rs.uniform(0.5, 1.5, a.shape), a.dtype)
-        if name.endswith("['moe']['gate']"):
-            return a * 40.0
-        return a
-
-    return jax.tree_util.tree_map_with_path(leaf, params)
+    return decisive(params, seed, scaled={"['moe']['gate']": 40.0})
 
 
 @pytest.fixture(scope="module")
@@ -317,26 +310,9 @@ def test_the_four_shares_add_up_to_the_uncut_layer():
 # ---- (d) the runner's comparison, and what must fail it ----
 
 
-def _outside(read):
-    return [k for k, limit in runner.TOLERANCES.items() if not read[k] <= limit]
-
-
-@pytest.fixture(scope="module")
-def compared(tiny):
-    """The runner's comparison of the tiny stack, one row: the program,
-    then each wrong program by name (each compiles its own pieces)."""
-    model, _, params, ids, _ = tiny
-
-    def read(wrong=None):
-        return runner.compare_with_reference(
-            model, params, reference, TINY_FILE, ids[:1], wrong=wrong)
-
-    return read
-
-
-def test_the_runners_comparison_passes_the_program(compared):
-    read = compared()
-    assert _outside(read) in ([], ["near_tie_share"]), read
+def test_the_runners_comparison_passes_the_program(tiny):
+    read = limits.read(tiny)
+    assert limits.outside(read) == [], read
     assert read["noise_mismatches"] == 0.0
     assert len(read["attention_layers_rms"]) == 2
     assert len(read["grad_stream_layers_rms"]) == 3  # embedding, two layers
@@ -355,13 +331,13 @@ def test_the_runners_comparison_passes_the_program(compared):
     ("the program, its router's logits in bfloat16", "router_logits_rms"),
     ("the step at twice its learning rate", "update_total"),
 ])
-def test_each_wrong_program_reads_outside_a_named_limit(compared, name, outside):
+def test_each_wrong_program_reads_outside_a_named_limit(tiny, name, outside):
     """Each of the runner's ``WRONG_PROGRAMS``, read in the program's place
     at the tiny size: outside the limit that names its fault."""
-    read = compared(**runner.WRONG_PROGRAMS[name])
-    assert outside in _outside(read), read
+    read = limits.read(tiny, **runner.WRONG_PROGRAMS[name])
+    assert outside in limits.outside(read), read
     if outside == "loss":  # its step is held to the chain too
-        assert "step_grad_norms" in _outside(read) or "update_norm" in _outside(read), read
+        assert {"step_grad_norms", "update_norm"} & set(limits.outside(read)), read
 
 
 def test_the_wrong_programs_are_the_issues_six_and_the_routers():

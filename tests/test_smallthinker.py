@@ -36,6 +36,13 @@ from learning_at_home_tpu.models.transformer import (  # noqa: E402
 from learning_at_home_tpu.ops.moe_dispatch import grouped_matmul_tiles  # noqa: E402
 from learning_at_home_tpu.parallel.mesh import make_mesh  # noqa: E402
 from learning_at_home_tpu.parallel.sharded_moe import ShardedMixtureOfExperts  # noqa: E402
+from runner_limits import (  # noqa: E402,F401  (``compiled_once`` is a fixture)
+    compiled_once,
+    decisive,
+    Limits,
+    one_device_mesh as _one_device_mesh,
+    tiny_stack,
+)
 
 reference = harness.load_path(os.path.join(
     REPO, "benchmarks", "configs", "smallthinker_21b_a3b_reference.py"))
@@ -48,10 +55,6 @@ CELL_FILE = harness.load_json(os.path.join(
     REPO, "benchmarks", "configs", "smallthinker-21b-a3b.json"))
 
 
-def _one_device_mesh():
-    return make_mesh({"expert": 1}, devices=jax.devices()[:1])
-
-
 def _decisive(params):
     """Seeded weights under which every part of the block decides and
     bf16 still reads the block as it is: a router that decides (the
@@ -59,29 +62,19 @@ def _decisive(params):
     is near the norm's eps (as at 2560 wide: 1/2560 = 3.9e-4), experts'
     outputs small enough that the few tokens whose 6th and 7th logits
     swap under bf16 do not swamp the rest of 32, norm scales off 1."""
-    rs = np.random.RandomState(7)
-    scale = {"['gate']": 10.0, "['embed']": 0.1, "['w_down']": 0.3}
-
-    def leaf(path, a):
-        name = jax.tree_util.keystr(path)
-        if name.endswith("['scale']"):
-            return a * jnp.asarray(rs.uniform(0.5, 1.5, a.shape), a.dtype)
-        return a * next((v for k, v in scale.items() if name.endswith(k)), 1.0)
-
-    return jax.tree_util.tree_map_with_path(leaf, params)
+    return decisive(params, scaled={
+        "['gate']": 10.0, "['embed']": 0.1, "['w_down']": 0.3})
 
 
 @pytest.fixture(scope="module")
 def tiny():
     """(model, cfg, float32 params, ids, targets) on one device."""
-    model, cfg, _, batch = smallthinker_one_chip(_one_device_mesh(), tiny=True)
-    params = _decisive(model.init_params(jax.random.PRNGKey(11)))
-    rs = np.random.RandomState(3)
-    ids = jnp.asarray(rs.randint(0, cfg.vocab_size, (batch, cfg.seq_len + 1)))
-    return model, cfg, params, ids[:, :-1], ids[:, 1:]
+    return tiny_stack(smallthinker_one_chip, _decisive)
 
 
 SIZES = runner.reference_sizes(TINY_FILE)  # what the runner hands the reference
+limits = Limits(runner, reference, TINY_FILE)
+pytestmark = pytest.mark.usefixtures("compiled_once")
 
 
 @pytest.fixture(scope="module")
@@ -154,19 +147,18 @@ class _OnOtherWeights:
         self._layer = lambda lp, *rest: model._layer(one(lp), *rest)
 
 
-def _bf16_readings(cfg, mesh, params, ids, tgt, transform=None):
+def _bf16_readings(tiny, cfg=None, transform=None):
     """The runner's own comparison (one row, the logits in blocks) of the
     bf16 program of ``cfg`` with the reference given the FILE's sizes."""
-    m16 = DMoETransformerLM(dataclasses.replace(cfg, dtype=jnp.bfloat16), mesh)
+    m16 = DMoETransformerLM(
+        dataclasses.replace(cfg or tiny[1], dtype=jnp.bfloat16), tiny[0].mesh)
     if transform is not None:
         m16 = _OnOtherWeights(m16, transform)
-    return runner.compare_with_reference(
-        m16, params, reference, TINY_FILE, ids[:1], tgt[:1])
+    return limits.read(tiny, m16)
 
 
 def test_block_in_bf16_is_inside_the_runner_tolerances(tiny):
-    model, cfg, params, ids, tgt = tiny
-    read = _bf16_readings(cfg, model.mesh, params, ids, tgt)
+    read = _bf16_readings(tiny)
     assert not runner.over_tolerance(read), read
 
 
@@ -209,12 +201,11 @@ def test_a_wrong_block_fails_the_runner_tolerances(tiny, name):
     """The tolerance is tight: each of nine plausible misreadings of the
     block, computed in bf16 like the program, reads outside it."""
     changes, transform = MUTATIONS[name]
-    model, cfg, params, ids, tgt = tiny
+    cfg = tiny[1]
     wrong = dataclasses.replace(cfg, **changes(cfg))
     assert wrong != cfg or transform is not None
     read = _bf16_readings(
-        wrong, model.mesh, params, ids, tgt,
-        transform and (lambda p: transform(cfg, p)))
+        tiny, wrong, transform and (lambda p: transform(cfg, p)))
     assert runner.over_tolerance(read), read
 
 
@@ -223,11 +214,8 @@ def test_reference_at_a_lower_precision_fails_the_runner_tolerances(tiny):
     every matmul operand rounded to float8_e4m3, the nearest precision
     below the configuration's bf16, is outside them; rounded to bf16 it
     is inside."""
-    model, _, params, ids, tgt = tiny
     for dtype, outside in ((jnp.float8_e4m3fn, True), (jnp.bfloat16, False)):
-        read = runner.compare_with_reference(
-            model, params, reference, TINY_FILE, ids[:1], tgt[:1],
-            operand_dtype=dtype)
+        read = limits.read(tiny, operand_dtype=dtype)
         assert bool(runner.over_tolerance(read)) is outside, (dtype, read)
 
 
@@ -239,8 +227,7 @@ def test_readings_from_blocks_equal_readings_from_whole_logits(tiny, monkeypatch
     model, cfg, params, ids, tgt = tiny
     m16 = DMoETransformerLM(dataclasses.replace(cfg, dtype=jnp.bfloat16), model.mesh)
     monkeypatch.setattr(runner, "LOGIT_BLOCK", 8)
-    read = runner.compare_with_reference(
-        m16, params, reference, TINY_FILE, ids[:1], tgt[:1])
+    read = limits.read(tiny, m16)
     layer = jax.jit(m16._layer, static_argnums=(2, 4))
     x = params["embed"][ids[:1]].astype(jnp.bfloat16)
     for i, lp in enumerate(params["layers"]):
@@ -270,8 +257,7 @@ def test_a_whole_that_composes_other_layers_fails_the_runner_tolerances(tiny):
         cfg, dtype=jnp.bfloat16, layer_pattern=_pattern(cfg, window=None)), model.mesh)
     mixed = _OnOtherWeights(right, lambda p: p)
     mixed._hidden, mixed.loss_fn = wrong._hidden, wrong.loss_fn
-    read = runner.compare_with_reference(
-        mixed, params, reference, TINY_FILE, ids[:1], tgt[:1])
+    read = limits.read(tiny, mixed)
     assert [p.split()[0] for p in runner.over_tolerance(read)] == [
         "loss", "hidden_token_median"], read
 
@@ -281,7 +267,7 @@ def test_a_token_between_two_experts_is_left_out_of_its_layer(tiny, monkeypatch)
     the reference is not compared in that layer; a margin that leaves no
     position to compare is itself outside the limits."""
     model, cfg, params, ids, tgt = tiny
-    read = _bf16_readings(cfg, model.mesh, params, ids, tgt)
+    read = _bf16_readings(tiny)
     assert 0.0 < read["near_tie_share"] < 0.5, read
     x = params["embed"][ids[:1]].astype(jnp.float32)
     margin = np.asarray(reference.router_margin(params["layers"][0], x, SIZES))
@@ -291,7 +277,7 @@ def test_a_token_between_two_experts_is_left_out_of_its_layer(tiny, monkeypatch)
     np.testing.assert_allclose(margin, logits[:, -6] - logits[:, -7], rtol=1e-5, atol=1e-6)
     monkeypatch.setattr(runner, "MARGIN", np.inf)
     with np.errstate(invalid="ignore"):
-        read = _bf16_readings(cfg, model.mesh, params, ids, tgt)
+        read = _bf16_readings(tiny)
     assert [p.split()[0] for p in runner.over_tolerance(read)] == [
         "layers_rms", "near_tie_share"], read
 

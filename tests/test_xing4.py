@@ -29,6 +29,14 @@ import xing4_flops  # noqa: E402
 
 from __graft_entry__ import xing4_0_29b_a4b_one_chip  # noqa: E402
 from benchmark_cells import layer_metric_file, readings_of_cell  # noqa: E402
+from runner_limits import (  # noqa: E402,F401  (``compiled_once`` is a fixture)
+    close as _close,
+    compiled_once,
+    decisive,
+    Limits,
+    one_device_mesh as _one_device_mesh,
+    tiny_stack,
+)
 from learning_at_home_tpu.models import trunk  # noqa: E402
 from learning_at_home_tpu.models.transformer import DMoETransformerLM  # noqa: E402
 from learning_at_home_tpu.parallel.mesh import make_mesh  # noqa: E402
@@ -43,37 +51,21 @@ CELL_FILE = harness.load_json(os.path.join(
     REPO, "benchmarks", "configs", "xing4.0-29b-a4b.json"))
 CELL = "xing4.0-29b-a4b-train-zipf16k"
 SIZES = runner.reference_sizes(TINY_FILE)  # what the runner hands the reference
-
-
-def _one_device_mesh():
-    return make_mesh({"expert": 1}, devices=jax.devices()[:1])
+limits = Limits(runner, reference, TINY_FILE)
+pytestmark = pytest.mark.usefixtures("compiled_once")
 
 
 def _decisive(params, seed=7):
     """Seeded weights under which every part of the block decides: a router
     that decides (the program's init gives near-equal scores), selection
     biases off zero, norm scales off 1."""
-    rs = np.random.RandomState(seed)
-
-    def leaf(path, a):
-        name = jax.tree_util.keystr(path)
-        if name.endswith("['scale']"):
-            return a * jnp.asarray(rs.uniform(0.5, 1.5, a.shape), a.dtype)
-        if name.endswith("['router_bias']"):
-            return jnp.asarray(rs.uniform(-0.2, 0.2, a.shape), a.dtype)
-        return a * (20.0 if name.endswith("['gate']") else 1.0)
-
-    return jax.tree_util.tree_map_with_path(leaf, params)
+    return decisive(params, seed, drawn={"['router_bias']": 0.2}, scaled={"['gate']": 20.0})
 
 
 @pytest.fixture(scope="module")
 def tiny():
     """(model, cfg, float32 params, ids, targets) on one device."""
-    model, cfg, _, batch = xing4_0_29b_a4b_one_chip(_one_device_mesh(), tiny=True)
-    params = _decisive(model.init_params(jax.random.PRNGKey(11)))
-    rs = np.random.RandomState(3)
-    ids = jnp.asarray(rs.randint(0, cfg.vocab_size, (batch, cfg.seq_len + 1)))
-    return model, cfg, params, ids[:, :-1], ids[:, 1:]
+    return tiny_stack(xing4_0_29b_a4b_one_chip, _decisive)
 
 
 @pytest.fixture(scope="module")
@@ -94,12 +86,6 @@ def want(tiny):
     _, grads = jax.jit(
         lambda p: reference.loss_and_grads(p, ids, tgt, SIZES))(params)
     return logits, logits_mtp, streams, losses, grads
-
-
-def _close(got, want, tol=1e-4, **kw):
-    want = np.asarray(want)
-    np.testing.assert_allclose(
-        np.asarray(got), want, rtol=0, atol=tol * np.abs(want).max(), **kw)
 
 
 # ---- (a) the program against the reference ----
@@ -290,9 +276,13 @@ def test_two_shares_parts_add_up_to_the_uncut_part(tiny):
         return {**lp_whole, "moe": moe}
 
     sizes = dict(SIZES, held=None)
-    streams, h, y_whole = reference.hc_part(
-        lp_whole["hc_ffn"], x,
-        lambda h: reference.ffn_output(lp_whole, h, sizes, 1)[0], sizes)
+
+    def part_of(lp, held):  # one compiled program a side, not op by op
+        return jax.jit(lambda lp, x: reference.hc_part(
+            lp["hc_ffn"], x, lambda h: reference.ffn_output(
+                lp, h, dict(sizes, held=held), 1)[0], sizes))(lp, x)
+
+    streams, h, y_whole = part_of(lp_whole, None)
     shared = reference.gated(
         reference._f32(lp_whole["shared"]),
         reference.rms(h, lp_whole["ln2"]["scale"], sizes["norm_eps"]).reshape(-1, 64),
@@ -302,18 +292,15 @@ def test_two_shares_parts_add_up_to_the_uncut_part(tiny):
         share = DMoETransformerLM(dataclasses.replace(
             cfg, held_experts=8, first_held_expert=first), mesh)
         lp = share_of(first)
-        read_streams, read_h, write = share._hc_read(lp["hc_ffn"], x)
+        (_, read_h, write), out = jax.jit(lambda lp, x: (
+            share._hc_read(lp["hc_ffn"], x), share._ffn_block(lp, x, None, 1)[0]))(lp, x)
         _close(read_h, h, 1e-5)
-        out, _ = share._ffn_block(lp, x, None, 1)
         # what the share's part gave: X' = H_res X + H_post y, solved for y
         post, res, _ = write
         mixed = trunk.hc_post(x, jnp.zeros_like(h), post, res)
         y = (out - mixed)[:, :, 0] / jnp.moveaxis(post, 0, -1)[..., :1]
         ys.append(y)
-        want = reference.hc_part(
-            lp["hc_ffn"], x, lambda h: reference.ffn_output(
-                lp, h, dict(sizes, held=(first, 8)), 1)[0], sizes)[0]
-        _close(out, want, 2e-5)
+        _close(out, part_of(lp, (first, 8))[0], 2e-5)
     _close(ys[0] + ys[1] - shared, y_whole, 5e-5)
     _, post_c, res_c = reference.hc_coefficients(lp_whole["hc_ffn"], x, sizes)
     follows = (jnp.einsum("bsij,bsjc->bsic", res_c, x)
@@ -388,11 +375,8 @@ def test_the_ring_refuses_scaled_frequencies_by_name():
 
 
 def test_the_block_as_it_is_reads_inside_the_runner_tolerances(tiny):
-    model, cfg, params, ids, tgt = tiny
-    read = runner.compare_with_reference(
-        model, params, reference, TINY_FILE, ids[:1], tgt[:1])
-    limits = {**runner.TOLERANCES, "near_tie_share": 1.0}  # 32 positions
-    assert [k for k, lim in limits.items() if not read[k] <= lim] == []
+    read = limits.read(tiny)
+    assert limits.outside(read) == []
     # the embedding, two layers, the block's combine, the block's layer
     assert len(read["embed_and_layers_rms"]) == 5
     assert len(read["stream_layers_rms"]) == len(read["hc_coeff_layers_rms"]) == 3
@@ -406,26 +390,17 @@ def test_the_block_as_it_is_reads_inside_the_runner_tolerances(tiny):
 
 @pytest.mark.parametrize("name", sorted(runner.WRONG_PROGRAMS))
 def test_a_wrong_program_fails_the_runner_tolerances(tiny, name):
-    model, _, params, ids, tgt = tiny
-    read = runner.compare_with_reference(
-        model, params, reference, TINY_FILE, ids[:1], tgt[:1],
-        **runner.WRONG_PROGRAMS[name])
-    outside = [k for k, lim in runner.TOLERANCES.items()
-               if k != "near_tie_share" and not read[k] <= lim]
-    assert outside, read
+    read = limits.read(tiny, **runner.WRONG_PROGRAMS[name])
+    assert limits.outside(read), read
 
 
 def test_reference_at_a_lower_precision_fails_the_runner_tolerances(tiny):
     """The reference with float8 operands in the program's place reads
     outside the layer and logits limits."""
-    model, _, params, ids, tgt = tiny
-    read = runner.compare_with_reference(
-        model, params, reference, TINY_FILE, ids[:1], tgt[:1],
-        operand_dtype=jnp.float8_e4m3fn)
-    for key in ("layers_rms", "stream_rms", "logits_rms", "mtp_logits_rms",
-                "grads_rms"):
-        assert not read[key] <= runner.TOLERANCES[key], key
-    assert read["hc_coeff_rms"] <= runner.TOLERANCES["hc_coeff_rms"]  # float32
+    read = limits.read(tiny, operand_dtype=jnp.float8_e4m3fn)
+    assert limits.none_inside(read, "layers_rms", "stream_rms", "logits_rms",
+                              "mtp_logits_rms", "grads_rms"), read
+    assert limits.inside(read, "hc_coeff_rms")  # float32
 
 
 def test_the_counters_limits_tell_a_wrong_residual_path():
